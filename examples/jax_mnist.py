@@ -15,9 +15,9 @@ On CPU, simulate 8 devices:
 
 import argparse
 
-from horovod_tpu.utils import apply_env_platform
+from horovod_tpu.common.compile_cache import place_compile_cache
 
-apply_env_platform()  # honor JAX_PLATFORMS even under site hooks
+place_compile_cache()  # before jax is imported: it reads the variable then
 
 import jax
 import jax.numpy as jnp

@@ -16,9 +16,9 @@ Run 2 stages x 2 DP on one host:
 import argparse
 import time
 
-from horovod_tpu.utils import apply_env_platform
+from horovod_tpu.common.compile_cache import place_compile_cache
 
-apply_env_platform()  # honor JAX_PLATFORMS even under site hooks
+place_compile_cache()  # before jax is imported: it reads the variable then
 
 import jax
 import jax.numpy as jnp

@@ -22,7 +22,8 @@ from typing import Any, Optional, Sequence, Union
 import numpy as np
 
 from horovod_tpu.common import dtypes, metrics
-from horovod_tpu.common.basics import ProcessSet, resolve_process_set
+from horovod_tpu.common.basics import (ProcessSet, chip_assigned,
+                                       resolve_process_set)
 from horovod_tpu.common.config import Config
 
 # Op codes shared with the C++ engine (engine/cc/wire.h OpType).
@@ -423,6 +424,27 @@ def init(comm: Union[Sequence[int], Any, None] = None) -> None:
             "HVD_TPU_AUTOTUNE_FIX pins cross_algo_threshold but the flat "
             "ring has no cross-node hop; set "
             "HVD_TPU_HIERARCHICAL_ALLREDUCE=1 (or drop the pin).")
+    # XLA data plane selection.  Like the reference's NCCL path — which
+    # auto-selected whenever NCCL was compiled in, no runtime flag
+    # (/root/reference/horovod/common/operations.cc:861-914) — the plane
+    # is AUTO-enabled on a rank that was given a chip of its own
+    # (basics.chip_assigned: read from the environment, never by asking
+    # JAX, which would open the chip); HVD_TPU_XLA_DATA_PLANE (or
+    # HOROVOD_XLA_DATA_PLANE) forces it on (=1) or off (=0).  A rank
+    # with no chip of its own never initialises a JAX backend here.
+    auto = cfg.xla_data_plane is None
+    wanted = chip_assigned() if auto else cfg.xla_data_plane
+    # Elastic membership rides the TCP engine only: the XLA plane's
+    # device mesh is fixed at init and cannot survive a reshape.
+    elastic = cfg.elastic or cfg.rejoin
+    # The fabric comes up BEFORE the engine starts.  Opening a chip
+    # freezes the whole process for seconds (the TPU runtime's start-up),
+    # and the engine's heartbeat detector, once it runs, takes a rank
+    # that has been silent for one second for dead: on four v5e chips
+    # the ranks aborted each other inside init with the order reversed.
+    plane = plane_failure = None
+    if wanted and not elastic:
+        plane, plane_failure = _open_xla_plane(ps)
     rc = lib.hvd_tpu_init(
         ps.rank, ps.size, ps.local_rank, ps.local_size,
         (ps.coord_endpoint or "").encode(), data.encode(),
@@ -478,20 +500,11 @@ def init(comm: Union[Sequence[int], Any, None] = None) -> None:
             # stay collectable through the API and the shutdown dump.
             warnings.warn(f"metrics monitor could not bind port {port}: "
                           f"{exc}; continuing without the HTTP endpoint.")
-    # XLA data plane selection.  Like the reference's NCCL path — which
-    # auto-selected whenever NCCL was compiled in, no runtime flag
-    # (/root/reference/horovod/common/operations.cc:861-914) — the plane
-    # is AUTO-enabled when jax reports TPU devices; HVD_TPU_XLA_DATA_PLANE
-    # (or HOROVOD_XLA_DATA_PLANE) forces it on (=1) or off (=0).
     global _xla_plane
-    auto = cfg.xla_data_plane is None
-    enable = _tpu_visible() if auto else cfg.xla_data_plane
-    if cfg.elastic or cfg.rejoin:
-        # Elastic membership rides the TCP engine only: the XLA plane's
-        # device mesh is fixed at init and cannot survive a reshape, and a
-        # standby must not enqueue the init-time plane agreement into a
+    if elastic:
+        # A standby must not enqueue the init-time plane agreement into a
         # job that is not running one.
-        if enable and not auto:
+        if wanted and not auto:
             import warnings
 
             warnings.warn(
@@ -499,38 +512,8 @@ def init(comm: Union[Sequence[int], Any, None] = None) -> None:
                 "support the XLA data plane; eager collectives will use "
                 "the TCP engine.")
         _xla_plane = None
-    elif enable or auto:
-        plane = None
-        if enable:
-            try:
-                from horovod_tpu.jax import eager_mesh
-
-                plane = eager_mesh.initialize(ps)
-            except ImportError as exc:
-                import warnings
-
-                warnings.warn(
-                    f"XLA data plane requested but jax is unavailable "
-                    f"({exc}); eager collectives will use the TCP engine.")
-        if ps.size > 1:
-            # Job-wide agreement over the TCP engine (_xla_plane is still
-            # None, so this allreduce cannot ride the plane): a rank whose
-            # plane init failed — or, in auto mode, that saw no TPU —
-            # must not diverge from ranks that enabled the plane, or the
-            # job deadlocks across two transports.  Auto mode therefore
-            # always votes, even with a local "no".
-            total = allreduce(np.asarray(1 if plane else 0, np.int32),
-                              average=False, name="__xla_plane_agreement__")
-            if int(total) != ps.size:
-                if plane is not None:
-                    import warnings
-
-                    warnings.warn(
-                        "XLA data plane disabled: not every rank could "
-                        "initialize it; eager collectives use the TCP "
-                        "engine.")
-                plane = None
-        _xla_plane = plane
+    elif wanted or auto:
+        _xla_plane = _agree_on_xla_plane(ps, plane, plane_failure)
     # Deterministic fault injection (docs/fault-tolerance.md), armed LAST:
     # init()'s own internal collectives (the plane agreement above) must
     # not consume fault-spec op indices — op=N counts the caller's
@@ -563,16 +546,52 @@ def _cluster_targets(ps: ProcessSet, base_port: int) -> list:
     return targets
 
 
-def _tpu_visible() -> bool:
-    """True when jax is importable and reports at least one TPU device —
-    the auto-enable predicate for the XLA data plane.  Conservative: any
-    failure (no jax, no backend, no devices) means 'no'."""
+def _open_xla_plane(ps: ProcessSet):
+    """Bring the XLA data plane up on this rank, which asked for it.
+    Returns ``(plane, failure)``: the failure is kept, not raised, so that
+    this rank still votes (:func:`_agree_on_xla_plane`) and every rank of
+    the job fails together instead of waiting for one that left."""
     try:
-        import jax
+        from horovod_tpu.jax import eager_mesh
 
-        return any(d.platform == "tpu" for d in jax.devices())
-    except Exception:
-        return False
+        return eager_mesh.initialize(ps), None
+    except Exception as exc:
+        return None, exc
+
+
+def _agree_on_xla_plane(ps: ProcessSet, plane, failure):
+    """Agree job-wide on the plane this rank opened (or did not want);
+    returns the plane or None (TCP engine).
+
+    A plane that was asked for — by pinning or by
+    ``HVD_TPU_XLA_DATA_PLANE=1`` — and cannot form raises: a job that
+    silently rode TCP would report success without ever using the
+    fabric.  Only a rank that did not ask (auto mode, no chip) follows
+    the job onto the TCP engine with a warning."""
+    if ps.size > 1:
+        # Over the TCP engine (_xla_plane is still None, so this
+        # allreduce cannot ride the plane): ranks split across two
+        # transports would deadlock.  Every rank votes, even with a local
+        # "no".
+        total = allreduce(np.asarray(1 if plane else 0, np.int32),
+                          average=False, name="__xla_plane_agreement__")
+        if int(total) != ps.size:
+            if plane is not None:
+                failure = RuntimeError(
+                    f"only {int(total)} of {ps.size} ranks formed it")
+            elif failure is None and int(total):
+                import warnings
+
+                warnings.warn(
+                    "XLA data plane disabled: this rank was given no chip "
+                    "of its own; eager collectives use the TCP engine.")
+            plane = None
+    if failure is not None:
+        shutdown()
+        raise HorovodInternalError(
+            "the XLA data plane was requested (HVD_TPU_XLA_DATA_PLANE=1 or "
+            f"a pinned chip) but could not form: {failure}") from failure
+    return plane
 
 
 def _flush_metrics_file(clear: bool = True) -> None:

@@ -160,6 +160,21 @@ def _from_jax_distributed() -> Optional[ProcessSet]:
         return None
 
 
+def chip_assigned() -> bool:
+    """True when this process was given a TPU chip of its own: the pinning
+    environment of ``hvdrun --tpu-pin`` (runner/tpu_pin.py) or of a process
+    manager doing the same, or — outside the launcher — a multi-process
+    identity resolved from pod-slice metadata.  Reads the environment only:
+    asking JAX would open the chip, and an unpinned ``hvdrun -np N`` job
+    shares one host's chips among N ranks that must not fight over them."""
+    if os.environ.get("TPU_VISIBLE_CHIPS"):
+        return True
+    if _from_launcher_env() is not None:
+        return False
+    ps = _from_tpu_pinned_metadata() or _from_tpu_metadata()
+    return ps is not None and ps.size > 1
+
+
 def comm_ranks(comm, launcher_rank: int) -> list:
     """Map an mpi4py-style communicator to the launcher-rank subset the
     rank-list init path consumes.

@@ -71,7 +71,8 @@ class Config:
     # ring — the TPU mapping of the reference's NCCL data plane
     # (operations.cc:861-1100).  Tri-state, like the reference's NCCL path
     # which needed no runtime flag once compiled in (operations.cc:861-914):
-    # None (env unset) = AUTO — enable when jax reports TPU devices;
+    # None (env unset) = AUTO — enable on ranks given a chip of their own
+    # (basics.chip_assigned; the environment is read, JAX is not asked);
     # True = forced on; False ("0"/"false"/"off") = explicit opt-out.
     # Unsupported dtypes stay on the TCP engine either way.
     xla_data_plane: Optional[bool] = None

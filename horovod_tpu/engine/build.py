@@ -181,13 +181,16 @@ def _sweep_stale_tmp() -> None:
         pass
 
 
-def build(verbose: bool = False) -> str:
+def build(verbose: bool = False, force: bool = False) -> str:
     """Compile the engine; returns the .so path.  Raises on failure.
     ``HVD_TPU_SANITIZE`` selects a sanitized variant (own lib name, own
-    stamp — the normal cached build is never invalidated by it)."""
+    stamp — the normal cached build is never invalidated by it).
+    ``force`` compiles from the sources whatever binary and stamp lie
+    beside them (chip_smoke.py: the binary is not a committed file, so a
+    proof that the tree builds must not lean on one)."""
     mode = sanitize_mode()
     lib = lib_path(mode)
-    if not needs_build(mode):
+    if not force and not needs_build(mode):
         return lib
     _sweep_stale_tmp()
     cxx = os.environ.get("CXX", "g++")

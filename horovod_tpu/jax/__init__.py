@@ -33,7 +33,6 @@ import numpy as np
 from jax import lax
 
 import horovod_tpu.common as _common
-from horovod_tpu.utils.jax_compat import axis_size as _axis_size
 from horovod_tpu.common import (  # noqa: F401  (re-exported process API)
     HorovodInternalError,
     init,
@@ -95,7 +94,7 @@ def allreduce(tensor, average: bool = True, name: Optional[str] = None,
         if average:
             denom = 1
             for a in axes:
-                denom *= _axis_size(a)
+                denom *= lax.axis_size(a)
             out = out / denom
         return out
     if _is_tracer(tensor):

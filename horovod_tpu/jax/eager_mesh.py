@@ -79,7 +79,7 @@ from horovod_tpu.common import metrics as _metrics
 from horovod_tpu.common import postmortem as _postmortem
 
 _lock = threading.Lock()
-_plane = None  # initialized XlaDataPlane, or False if init failed/disabled
+_plane = None  # the initialized XlaDataPlane
 
 # Compiled-executable cache bound (_jit_for): steady-state training reuses
 # a handful of (op, padded length, dtype) keys, but a pathological shape
@@ -989,79 +989,100 @@ def _xla_coordinator(ps) -> Optional[str]:
     return None
 
 
-def initialize(ps) -> Optional[XlaDataPlane]:
+def _ranks_by_process_index(ps, mesh_devices) -> np.ndarray:
+    """The rank of the process at each JAX process index (the rows of
+    ``mesh_devices``), asked of the fabric itself.
+
+    Row r of the plane's mesh must hold rank r's devices: allgather blocks
+    and the broadcast root are addressed by rank.  JAX's process index is
+    not the rank: on a TPU host the runtime numbers the processes itself,
+    whatever ``process_id`` said (four pinned v5e ranks 0..3 came up as
+    processes 0, 2, 3, 1)."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    by_index = Mesh(mesh_devices, ("hvd_proc", "hvd_local"))
+    rank_at = np.asarray(jax.jit(
+        lambda a: a, out_shardings=NamedSharding(by_index, P()))(
+        jax.make_array_from_process_local_data(
+            NamedSharding(by_index, P("hvd_proc")),
+            np.asarray([ps.rank], np.int32), (ps.size,))))
+    if sorted(rank_at.tolist()) != list(range(ps.size)):
+        raise RuntimeError(
+            f"ranks at JAX process indices are {rank_at.tolist()}, "
+            f"expected a permutation of 0..{ps.size - 1}")
+    return rank_at
+
+
+def initialize(ps) -> XlaDataPlane:
     """Connect jax.distributed across the job and build the process mesh.
-    Returns None (with a warning) when the fabric cannot be initialized —
-    callers fall back to the TCP engine."""
+    Raises when the fabric cannot be initialized; the caller
+    (``common._open_xla_plane``) decides what that means for the job.
+
+    In a multi-rank job ``jax.distributed.initialize`` must be the first
+    thing that touches JAX: the installed JAX refuses it once a backend
+    exists, and on a TPU host a backend opened before it would claim the
+    chip without the job's process grid."""
     global _plane
     with _lock:
         if _plane is not None:
-            if _plane:
-                # Re-init in the same process: the engine's tick counter
-                # and applied-parameter history restarted, so tick-keyed
-                # fusion thresholds / compression modes memoized in the
-                # previous lifetime are stale (and, being per-rank
-                # wall-time artifacts, would split ranks into different
-                # bucket plans).  Residuals reset with the engine's.
-                _plane._tick_thresholds.clear()
-                _plane._tick_comp.clear()
-                _plane._residuals.clear()
-            return _plane or None
-        try:
-            import jax
-            from jax.sharding import (Mesh, NamedSharding,
-                                      PartitionSpec as P)
+            # Re-init in the same process: the engine's tick counter
+            # and applied-parameter history restarted, so tick-keyed
+            # fusion thresholds / compression modes memoized in the
+            # previous lifetime are stale (and, being per-rank
+            # wall-time artifacts, would split ranks into different
+            # bucket plans).  Residuals reset with the engine's.
+            _plane._tick_thresholds.clear()
+            _plane._tick_comp.clear()
+            _plane._residuals.clear()
+            return _plane
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-            from horovod_tpu.common.config import Config
+        from horovod_tpu.common.config import Config
 
-            if ps.size > 1:
-                coord = _xla_coordinator(ps)
-                if coord is None:
-                    raise RuntimeError(
-                        "no XLA coordinator endpoint (HVD_TPU_XLA_COORD)")
-                jax.distributed.initialize(
-                    coordinator_address=coord,
-                    num_processes=ps.size, process_id=ps.rank)
-            devices = jax.devices()
-            # A (process, local-chip) 2-D mesh: each process may own
-            # several local devices (the reference ran several GPUs from
-            # one process, test_tensorflow.py:189); with one device per
-            # process this reduces to the 1-D per-process mesh.
-            by_proc = {}
-            for d in devices:
-                by_proc.setdefault(d.process_index, []).append(d)
-            if len(by_proc) != ps.size:
+        if ps.size > 1 and not jax.distributed.is_initialized():
+            coord = _xla_coordinator(ps)
+            if coord is None:
                 raise RuntimeError(
-                    f"{len(by_proc)} processes visible to JAX, expected "
-                    f"{ps.size}")
-            counts = {len(v) for v in by_proc.values()}
-            if len(counts) != 1:
-                raise RuntimeError(
-                    f"uneven device counts per process: "
-                    f"{ {k: len(v) for k, v in by_proc.items()} }")
-            chips = counts.pop()
-            mesh_devices = np.array(
-                [sorted(by_proc[i], key=lambda d: d.id)
-                 for i in sorted(by_proc)])
-            mesh = Mesh(mesh_devices, ("hvd_proc", "hvd_local"))
-            plane = XlaDataPlane(
-                mesh,
-                NamedSharding(mesh, P("hvd_proc", "hvd_local")),
-                NamedSharding(mesh, P()),
-                ps.rank, ps.size,
-                Config.from_env().fusion_threshold,
-                spec_proc_only=NamedSharding(mesh, P("hvd_proc")),
-                local_chips=chips)
-            _plane = plane
-            return plane
-        except Exception as exc:  # fall back to the TCP engine
-            import warnings
-
-            warnings.warn(
-                f"XLA data plane unavailable ({exc}); eager collectives "
-                "will use the TCP engine.")
-            _plane = False
-            return None
+                    "no XLA coordinator endpoint (HVD_TPU_XLA_COORD)")
+            jax.distributed.initialize(
+                coordinator_address=coord,
+                num_processes=ps.size, process_id=ps.rank)
+        devices = jax.devices()
+        # A (process, local-chip) 2-D mesh: each process may own
+        # several local devices (the reference ran several GPUs from
+        # one process, test_tensorflow.py:189); with one device per
+        # process this reduces to the 1-D per-process mesh.
+        by_proc = {}
+        for d in devices:
+            by_proc.setdefault(d.process_index, []).append(d)
+        if len(by_proc) != ps.size:
+            raise RuntimeError(
+                f"{len(by_proc)} processes visible to JAX, expected "
+                f"{ps.size}")
+        counts = {len(v) for v in by_proc.values()}
+        if len(counts) != 1:
+            raise RuntimeError(
+                f"uneven device counts per process: "
+                f"{ {k: len(v) for k, v in by_proc.items()} }")
+        chips = counts.pop()
+        mesh_devices = np.array(
+            [sorted(by_proc[i], key=lambda d: d.id)
+             for i in sorted(by_proc)])
+        if ps.size > 1:
+            mesh_devices = mesh_devices[np.argsort(
+                _ranks_by_process_index(ps, mesh_devices))]
+        mesh = Mesh(mesh_devices, ("hvd_proc", "hvd_local"))
+        _plane = XlaDataPlane(
+            mesh,
+            NamedSharding(mesh, P("hvd_proc", "hvd_local")),
+            NamedSharding(mesh, P()),
+            ps.rank, ps.size,
+            Config.from_env().fusion_threshold,
+            spec_proc_only=NamedSharding(mesh, P("hvd_proc")),
+            local_chips=chips)
+        return _plane
 
 
 def reset() -> None:

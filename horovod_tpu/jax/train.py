@@ -30,23 +30,6 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore  # noqa: E501
-
-
-def shard_map(fn, mesh, in_specs, out_specs, check_vma=True):
-    """shard_map across jax versions: the replication-check kwarg was
-    renamed check_rep -> check_vma; translate for the min-supported jax
-    (CI min-versions leg)."""
-    import inspect
-
-    kw = ("check_vma" if "check_vma"
-          in inspect.signature(_shard_map).parameters else "check_rep")
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{kw: check_vma})
-
 from horovod_tpu.common import metrics as _metrics
 from horovod_tpu.jax import DistributedOptimizer
 
@@ -243,7 +226,14 @@ def build_train_step(loss_fn: Callable, optimizer, mesh: Mesh,
                 params, batch)
         else:
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        updates, opt_state = dist_opt.update(grads, opt_state, params)
+        if check_vma:
+            updates, opt_state = dist_opt.update(grads, opt_state, params)
+        else:
+            # Without the vma machinery autodiff inserts no psum and
+            # hvd.allreduce cannot see which values still vary (it would
+            # take local gradients for reduced ones): average here.
+            grads = jax.tree.map(lambda g: lax.pmean(g, axis_name), grads)
+            updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
         loss = lax.pmean(loss, axis_name)
         if has_aux:
@@ -255,8 +245,10 @@ def build_train_step(loss_fn: Callable, optimizer, mesh: Mesh,
     # check_vma=False is needed for interpret-mode Pallas collectives on
     # CPU test meshes (rdma / fused ring rotation): the interpreter does
     # not propagate the varying-manual-axes annotation through its
-    # internals.  Compiled TPU kernels don't need it.
-    mapped = shard_map(
+    # internals.  Compiled TPU kernels annotate their outputs and run
+    # under the default check (compiled for a described v5e in
+    # tests/test_ops.py; run on four chips by chip_smoke.py --chips 4).
+    mapped = jax.shard_map(
         shard_step, mesh=mesh,
         in_specs=(P(), P(), batch_spec),
         out_specs=(P(),) * n_out,

@@ -18,9 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.utils.jax_compat import axis_size as _axis_size
-from horovod_tpu.utils.jax_compat import vma as _aval_vma
-
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              ring_attention)
 
@@ -43,11 +40,6 @@ def _qkv_project_fwd(x, w):
     return _qkv_project(x, w), (x, w)
 
 
-def _vma(t):
-    """Varying-manual-axes of a value under shard_map (empty outside)."""
-    return frozenset(_aval_vma(t) or ())
-
-
 def _qkv_project_bwd(res, cots):
     x, w = res
     dx = sum(jnp.einsum("bhse,dhe->bsd", c, w[:, j])
@@ -59,10 +51,10 @@ def _qkv_project_bwd(res, cots):
     # only the batch is mapped elsewhere).  A custom_vjp must return
     # cotangents whose varying axes MATCH the primal's — the psum plain
     # autodiff would insert is our job here.
-    extra_w = _vma(dw) - _vma(w)
+    extra_w = jax.typeof(dw).vma - jax.typeof(w).vma
     if extra_w:  # sorted: stable axis order -> stable jaxpr/compile cache
         dw = lax.psum(dw, tuple(sorted(extra_w)))
-    extra_x = _vma(dx) - _vma(x)
+    extra_x = jax.typeof(dx).vma - jax.typeof(x).vma
     if extra_x:
         dx = lax.psum(dx, tuple(sorted(extra_x)))
     return dx, dw
@@ -570,6 +562,6 @@ def next_token_loss(logits, targets, mask=None, axis_name=None):
         axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
         n_shards = 1
         for a in axes:
-            n_shards *= _axis_size(a)
+            n_shards *= lax.axis_size(a)
         count = lax.psum(count, axes) / n_shards
     return (loss * mask).sum() / jnp.maximum(count, 1.0)

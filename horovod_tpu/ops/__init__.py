@@ -15,7 +15,8 @@ collective substrate:
   device's queries stay put (Liu et al., Ring Attention, arXiv:2310.01889).
 * :func:`fused_ring_attention` — ring attention with the rotation DMA
   fused INTO the flash kernel (start DMA -> attend -> wait), one Pallas
-  program per ring step (`ring_attention(..., rotate_impl="fused")`).
+  program per ring step (`ring_attention(..., rotate_impl="fused")`);
+  raises :class:`FusedRingUnsupported` for what the kernel cannot run.
 """
 
 from horovod_tpu.ops.attention import (  # noqa: F401
@@ -24,4 +25,7 @@ from horovod_tpu.ops.attention import (  # noqa: F401
     mha_reference,
 )
 from horovod_tpu.ops.ring_attention import ring_attention  # noqa: F401
-from horovod_tpu.ops.ring_flash import fused_ring_attention  # noqa: F401
+from horovod_tpu.ops.ring_flash import (  # noqa: F401
+    FusedRingUnsupported,
+    fused_ring_attention,
+)

@@ -25,10 +25,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from horovod_tpu.utils.jax_compat import axis_size as _axis_size
-from horovod_tpu.utils.jax_compat import tpu_compiler_params as _compiler_params
-from horovod_tpu.utils.jax_compat import vma as _vma
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30  # big-negative instead of -inf: keeps exp() NaN-free when a
 # whole row is masked (fully-masked causal blocks)
@@ -238,14 +236,6 @@ def blockwise_attention(q, k, v, causal: bool = False,
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel.
 # ---------------------------------------------------------------------------
-
-try:  # Pallas is TPU-oriented; import lazily so CPU-only installs still work
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
 
 
 def _rd(ref):
@@ -530,7 +520,7 @@ def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
         from horovod_tpu.ops.rdma import _device_id
 
         my = jax.lax.axis_index(axis_name)
-        n = _axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         dst, id_type = _device_id(jax.lax.rem(my + 1, n), axis_name,
                                   mesh_axes)
         src, _ = _device_id(jax.lax.rem(my - 1 + n, n), axis_name,
@@ -688,10 +678,9 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
         ]
         scratch_shapes += [pltpu.SemaphoreType.DMA((4,))]
         args += [k_cur, v_cur]
-    vma = _vma(q)
-    if vma is not None:
-        out_shapes = [jax.ShapeDtypeStruct(s.shape, s.dtype, vma=vma)
-                      for s in out_shapes]
+    vma = jax.typeof(q).vma
+    out_shapes = [jax.ShapeDtypeStruct(s.shape, s.dtype, vma=vma)
+                  for s in out_shapes]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bh, num_k, num_q),
@@ -699,7 +688,7 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
     )
-    compiler_params = _compiler_params(
+    compiler_params = pltpu.CompilerParams(
         collective_id=(collective_id if rotate and not interpret
                        else None),
         has_side_effects=rotate)
@@ -726,6 +715,9 @@ def _row_spec(block, d):
                             lambda b, i, j, _r=row: (b, _r(i, j), 0))
 
     return spec
+
+
+_MAX_BLOCK = 1024  # largest block edge the VMEM calibration covers
 
 
 def _pick_block(seq_len: int, maximum: int = 512) -> int:
@@ -908,6 +900,10 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
 
     inner = lambda i, j: j  # noqa: E731  (innermost grid dim)
     outer = lambda i, j: i  # noqa: E731
+    # vma: inside shard_map (build_train_step) the default check refuses
+    # an out_shape that does not say how it varies; as q does.
+    grad_shape = jax.ShapeDtypeStruct((bh, sl, d), grad_dtype,
+                                      vma=jax.typeof(q).vma)
     dkdv = functools.partial(
         _flash_bwd_dkdv_kernel, causal=causal, block_q=block_q,
         block_k=block_k, num_q_blocks=num_q, scale_r=scale_r)
@@ -917,8 +913,7 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
         in_specs=[qspec(inner), qspec(inner), lse_spec(inner),
                   lse_spec(inner), kspec(outer), kspec(outer)],
         out_specs=(kspec(outer), kspec(outer)),
-        out_shape=(jax.ShapeDtypeStruct((bh, sl, d), grad_dtype),
-                   jax.ShapeDtypeStruct((bh, sl, d), grad_dtype)),
+        out_shape=(grad_shape, grad_shape),
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
@@ -933,7 +928,7 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
         in_specs=[qspec(outer), qspec(outer), lse_spec(outer),
                   lse_spec(outer), kspec(inner), kspec(inner)],
         out_specs=qspec(outer),
-        out_shape=jax.ShapeDtypeStruct((bh, sl, d), grad_dtype),
+        out_shape=grad_shape,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
     )(q, do, lse8, delta8, k, v)
@@ -1034,7 +1029,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     qr = (q * p2).astype(q.dtype).reshape(bh, q_len, d)
     kr = k.reshape(bh, k_len, d)
     vr = v.reshape(bh, k_len, d)
-    o_shape = jax.ShapeDtypeStruct((bh, q_len, d), q.dtype)
+    vma = jax.typeof(q).vma  # see _split_bwd_call
     num_q = q_len // block_q
     num_k = k_len // block_k
     qspec, kspec = _row_spec(block_q, d), _row_spec(block_k, d)
@@ -1053,8 +1048,8 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
             pl.BlockSpec((1, 8, block_q), lambda b, qi, ki: (b, 0, qi)),
         ),
         out_shape=(
-            o_shape,
-            jax.ShapeDtypeStruct((bh, 8, q_len), jnp.float32),
+            jax.ShapeDtypeStruct((bh, q_len, d), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, 8, q_len), jnp.float32, vma=vma),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),  # running max
@@ -1104,9 +1099,14 @@ def flash_attention(q, k, v, causal: bool = False,
     lowerable; the option exists so callers never have to think about
     head-major conventions.)
 
-    On TPU this is a Pallas kernel (MXU-tiled blocks, VMEM online-softmax
-    state); elsewhere (and for ragged block tails) it falls back to the
-    mathematically identical :func:`blockwise_attention`.  Differentiable
+    A Pallas kernel (MXU-tiled blocks, VMEM online-softmax state):
+    compiled by Mosaic on a TPU backend, run by the Pallas interpreter
+    elsewhere (``interpret=None`` asks the backend; pass it explicitly to
+    compile for a described chip).  Two documented routings leave the
+    kernel for the mathematically identical :func:`blockwise_attention`
+    scan — ragged block tails, and float16 on the compiled path — so a
+    caller who must know which ran reads the compiled HLO for
+    ``tpu_custom_call`` (chip_smoke.py does).  Differentiable
     with the flash backward (logsumexp residual + per-block recompute,
     O(seq) memory).  Default blocks: up to 1024 each, the largest
     candidate dividing the sequence — measured on v5e at seq 1024,
@@ -1127,8 +1127,6 @@ def flash_attention(q, k, v, causal: bool = False,
                                  block_k=block_k, interpret=interpret))
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    if not _HAS_PALLAS:
-        return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if not interpret and jnp.float16 in (q.dtype, k.dtype, v.dtype):
@@ -1138,16 +1136,21 @@ def flash_attention(q, k, v, causal: bool = False,
         # instead of crashing at compile time.  bf16 is the supported
         # half-precision on TPU.
         return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale)
-    if block_q is None:
+    # An explicit block past _MAX_BLOCK is cut to it as the default is:
+    # the chip's compiler refuses both passes there (scoped VMEM: 2048-row
+    # blocks at seq 2048 fail the backward, 4096 at seq 4096 the forward —
+    # compiled for a described v5e, tests/test_ops.py), whatever the
+    # structural estimates say (ADVICE r5 #2).
+    if block_q is None or block_q > _MAX_BLOCK:
         # 1024-row query blocks: the kernels are grid-overhead-bound at
         # these shapes (~3-5 us of fixed cost per grid step against ~1.4
         # us of MXU work), so halving the grid beats smaller tiles —
         # measured r4 at seq 1024: fwd 965 -> 687 us/call, fwd+bwd -5%
         # vs 512-row blocks.  VMEM peaks ~2 MB at head_dim 64.
-        block_q = _pick_block(q.shape[-2], maximum=1024)
-    if block_k is None:
+        block_q = _pick_block(q.shape[-2], maximum=_MAX_BLOCK)
+    if block_k is None or block_k > _MAX_BLOCK:
         # Whole-k key blocks skip the online-softmax rescale entirely
         # (the kernel's single_k fast path) and the backward's key loop.
-        block_k = _pick_block(k.shape[-2], maximum=1024)
+        block_k = _pick_block(k.shape[-2], maximum=_MAX_BLOCK)
     return _flash_attention(q, k, v, causal, sm_scale, block_q, block_k,
                             interpret)

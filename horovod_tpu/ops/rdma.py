@@ -33,14 +33,18 @@ from jax's tracing order: current jax traces custom_vjp transposes
 grouped per cotangent chain (not interleaved with program order), which
 broke the old global-alternation scheme.
 
-HARDWARE CAVEAT: this module (and ops/ring_flash.py, which shares the
-barrier scheme) has NEVER run on a physical multi-chip slice — every
-round of this project had one chip.  The barrier/phase invariants are
-pinned by interpret-mode tests (tests/test_ops.py
+ON HARDWARE: this module and ops/ring_flash.py, which shares the barrier
+scheme, first ran on physical chips at PR 21 — one four-chip v5e host, the
+512-wide LM at 2048 rows a chip, losses equal to the one-device flash loss
+(chip_smoke.py --chips 4).  The barrier/phase invariants are pinned by
+interpret-mode tests (tests/test_ops.py
 ::test_rdma_phase_alternates_through_backward and
-::test_ring_flash_phase_stream_alternates), but validate on a real slice
-before production use; ``lax.ppermute`` is the default rotation for
-exactly this reason.
+::test_ring_flash_phase_stream_alternates) and by compiles for a described
+four-chip mesh (::test_ring_variants_compile_on_mesh).  One shape on one
+host is not a validation of the scheme: the namespaces of this module's
+chains still repeat from one attention call to the next (ROADMAP.md D3),
+longer rings and other shapes are unrun, and ``lax.ppermute`` stays the
+default rotation.
 
 No reference counterpart (SURVEY §5.7: the reference has no sequence
 parallelism at all); this exceeds it.
@@ -51,20 +55,9 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.experimental.pallas as pl
 from jax import lax
-
-from horovod_tpu.utils.jax_compat import axis_size as _axis_size
-from horovod_tpu.utils.jax_compat import shape_dtype_struct as _shape_dtype_struct
-from horovod_tpu.utils.jax_compat import tpu_compiler_params as _compiler_params
-from horovod_tpu.utils.jax_compat import vma as _vma
-
-try:
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental.pallas import tpu as pltpu
 
 # Barrier namespaces: phases 0/1 = chain A (ids 13/14), phases 2/3 =
 # chain B (ids 17/18).  ``phase ^ 1`` flips within a chain — the VJP's
@@ -88,22 +81,14 @@ def _device_id(ring_idx, ring_axis, mesh_axes):
 def _ambient_mesh_axes(axis_name):
     """Axis names of the surrounding shard_map mesh (falls back to the
     ring axis alone outside any mesh context)."""
-    try:
-        import jax as _jax
-
-        mesh = _jax.sharding.get_abstract_mesh()
-        names = tuple(getattr(mesh, "axis_names", ()) or ())
-        if axis_name in names:
-            return names
-    except Exception:  # pragma: no cover - very old jax
-        pass
-    return (axis_name,)
+    names = tuple(jax.sharding.get_abstract_mesh().axis_names)
+    return names if axis_name in names else (axis_name,)
 
 
 def _permute_kernel(x_ref, o_ref, send_sem, recv_sem, *, axis_name,
                     shift, barrier, mesh_axes):
     my = lax.axis_index(axis_name)
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     dst, id_type = _device_id(lax.rem(my + shift, n), axis_name, mesh_axes)
     if barrier:
         # Ready handshake: I may DMA into `dst` only once `dst` has
@@ -129,20 +114,20 @@ def _permute_kernel(x_ref, o_ref, send_sem, recv_sem, *, axis_name,
 
 
 def _ring_permute_raw(x, axis_name, shift, interpret, phase):
-    shift = shift % _axis_size(axis_name)  # static: axis sizes are known
+    shift = shift % lax.axis_size(axis_name)  # static: axis sizes are known
     kernel = functools.partial(_permute_kernel, axis_name=axis_name,
                                shift=shift, barrier=not interpret,
                                mesh_axes=_ambient_mesh_axes(axis_name))
     # Propagate the varying-mesh-axes annotation so shard_map's vma check
     # accepts the pallas output (the result varies exactly as the input).
-    vma = _vma(x)
     return pl.pallas_call(
         kernel,
-        out_shape=_shape_dtype_struct(x.shape, x.dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       vma=jax.typeof(x).vma),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             collective_id=_COLLECTIVE_IDS[phase % 4],
             has_side_effects=True),
         interpret=interpret,
@@ -191,8 +176,6 @@ def ring_permute(x, axis_name: str, shift: int = 1,
     namespaces; an INDEPENDENT concurrent chain must use the other
     namespace pair (``phase // 2`` differs) — see the module docstring.
     """
-    if not _HAS_PALLAS:
-        raise RuntimeError("ring_permute requires Pallas (TPU jaxlib)")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _ring_permute(x, axis_name, shift, interpret, phase)

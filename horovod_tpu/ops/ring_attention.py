@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.utils.jax_compat import axis_size as _axis_size
-
 from horovod_tpu.ops.attention import (
     NEG_INF,
     _block_attend,
@@ -52,7 +50,11 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
         (:func:`horovod_tpu.ops.ring_flash.fused_ring_attention`: ONE
         Pallas program per ring step that starts the rotation DMA, flash-
         attends the current shard while it flies, and waits at the end —
-        overlap by construction).  Differentiable in every mode.
+        overlap by construction; shapes it cannot run raise
+        ``FusedRingUnsupported``).  Differentiable in every mode.  Which
+        rotation a compiled program really holds is read from its HLO:
+        ``collective-permute`` for ppermute, ``tpu_custom_call`` for the
+        other two (chip_smoke.py --chips 4 reports it).
 
     Returns:
       The local output shard, same shape/dtype as ``q``.
@@ -64,7 +66,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
 
         return fused_ring_attention(q, k, v, axis_name, causal=causal,
                                     sm_scale=sm_scale)
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     seq_local = q.shape[-2]
 

@@ -49,30 +49,26 @@ import functools
 from typing import Optional
 
 import jax
+import jax.experimental.pallas as pl
 import jax.numpy as jnp
 from jax import lax
-
-from horovod_tpu.utils.jax_compat import axis_size as _axis_size
-from horovod_tpu.utils.jax_compat import tpu_compiler_params as _compiler_params
-from horovod_tpu.utils.jax_compat import vma as _vma
+from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu.ops.attention import (NEG_INF, POS_BIG, _attend_block,
                                        _bwd_plan, _combined_bwd_call,
                                        _finalize_flash, _init_state,
                                        _pick_block, _split_scale)
-
-try:
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
+from horovod_tpu.ops.rdma import _ambient_mesh_axes, _device_id
 
 _COLLECTIVE_IDS = (15, 16)  # phase-alternating barrier namespaces
 
-if _HAS_PALLAS:
-    from horovod_tpu.ops.rdma import _ambient_mesh_axes, _device_id
+
+class FusedRingUnsupported(ValueError):
+    """The fused ring kernel cannot run this shape or dtype.  Raised at
+    trace time, naming the cause, instead of rerouting to another ring:
+    a caller who asked for ``rotate_impl="fused"`` and silently got the
+    separable ring would time, and trust, the wrong kernel.  Ask for
+    ``rotate_impl="ppermute"`` where the message says so."""
 
 
 def _step_kernel(*refs, causal, block_q, block_k, num_q_blocks,
@@ -100,7 +96,7 @@ def _step_kernel(*refs, causal, block_q, block_k, num_q_blocks,
 
     if rotate:
         my = lax.axis_index(axis_name)
-        n = _axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         dst, id_type = _device_id(lax.rem(my + 1, n), axis_name, mesh_axes)
         src, _ = _device_id(lax.rem(my - 1 + n, n), axis_name, mesh_axes)
 
@@ -252,10 +248,9 @@ def _ring_flash_step(q, k_cur, v_cur, q_offset, k_offset, *,
         ]
         scratch_shapes += [pltpu.SemaphoreType.DMA((4,))]  # k/v send+recv
         args += [k_cur, v_cur]
-    vma = _vma(q)
-    if vma is not None:
-        out_shapes = [jax.ShapeDtypeStruct(s.shape, s.dtype, vma=vma)
-                      for s in out_shapes]
+    vma = jax.typeof(q).vma
+    out_shapes = [jax.ShapeDtypeStruct(s.shape, s.dtype, vma=vma)
+                  for s in out_shapes]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bh, num_q, num_k),
@@ -264,7 +259,7 @@ def _ring_flash_step(q, k_cur, v_cur, q_offset, k_offset, *,
         scratch_shapes=scratch_shapes,
     )
     barrier = rotate and not interpret
-    compiler_params = _compiler_params(
+    compiler_params = pltpu.CompilerParams(
         # collective_id may only be set when the kernel takes the custom
         # barrier (the non-rotating last step has no barrier).
         collective_id=_COLLECTIVE_IDS[phase % 2] if barrier else None,
@@ -283,9 +278,10 @@ def _ring_flash_step(q, k_cur, v_cur, q_offset, k_offset, *,
     return out, lse[:, 0, :], None, None
 
 
-def _phase_closer_kernel(o_ref, *, axis_name, mesh_axes):
+def _phase_closer_kernel(after_ref, o_ref, *, axis_name, mesh_axes):
+    del after_ref  # an ordering dependence only
     my = lax.axis_index(axis_name)
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     src, id_type = _device_id(lax.rem(my - 1 + n, n), axis_name, mesh_axes)
     bar = pltpu.get_barrier_semaphore()
     pltpu.semaphore_signal(bar, inc=1, device_id=src,
@@ -294,21 +290,32 @@ def _phase_closer_kernel(o_ref, *, axis_name, mesh_axes):
     o_ref[...] = jnp.zeros_like(o_ref)
 
 
-def _phase_closer(axis_name):
+def _phase_closer(axis_name, after):
     """Barrier-only invocation on phase 1: appended when a fused forward
     used an ODD number of rotating steps (even ring sizes), so every
     fused call's barrier-phase stream starts on 0 and ends on 1 — the
     cyclic alternation invariant (ops/rdma.py) then holds across
     repeated executions of the same compiled program (training loops
     re-run the jitted step; the junction last-phase -> first-phase must
-    differ)."""
-    pl.pallas_call(
+    differ).
+
+    Returns a float32 zero that the caller must ADD INTO THE PASS'S
+    RESULT, and takes the last step's output as ``after``.  The installed
+    JAX drops a pallas_call whose outputs nobody reads
+    (``has_side_effects`` only guards against XLA), and without a data
+    dependence on both sides nothing keeps the barrier at the end of the
+    pass; threaded through the dataflow it runs after the last step and
+    before whatever consumes the attention output."""
+    zeros = pl.pallas_call(
         functools.partial(_phase_closer_kernel, axis_name=axis_name,
                           mesh_axes=_ambient_mesh_axes(axis_name)),
-        out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
-        compiler_params=_compiler_params(
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32,
+                                       vma=jax.typeof(after).vma),
+        compiler_params=pltpu.CompilerParams(
             collective_id=_COLLECTIVE_IDS[1], has_side_effects=True),
-    )()
+    )(after)
+    return zeros[0, 0]
 
 
 def _rotation_phases(n: int):
@@ -352,7 +359,7 @@ def _merge(o1, lse1, o2, lse2):
 
 def _fused_forward(q, k, v, axis_name, causal, sm_scale, block_q, block_k,
                    interpret):
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     sl = q.shape[-2]
     batch, heads = q.shape[0], q.shape[1]
@@ -384,7 +391,7 @@ def _fused_forward(q, k, v, axis_name, causal, sm_scale, block_q, block_k,
     if not interpret and needs_closer:
         # Even ring: odd number of rotating steps [0,1,...,0] — close the
         # barrier-phase stream on 1 so repeated executions alternate.
-        _phase_closer(axis_name)
+        out = out + _phase_closer(axis_name, o_t)
     return (out.reshape(q.shape).astype(q.dtype),
             lse.reshape(q.shape[:-1]))
 
@@ -395,7 +402,7 @@ def _fused_backward(q, k, v, out, lse, g, axis_name, causal, sm_scale,
     by in-kernel DMA while computing the shard's dk/dv and dq blocks from
     the saved (out, lse); the float32 dk/dv partials follow their shard
     around the ring as ppermute rotations between kernels."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     batch, heads, sl, d = q.shape
     bh = batch * heads
@@ -442,7 +449,8 @@ def _fused_backward(q, k, v, out, lse, g, axis_name, causal, sm_scale,
         acc_k = lax.ppermute(acc_k, axis_name, perm)
         acc_v = lax.ppermute(acc_v, axis_name, perm)
     if not interpret and needs_closer:
-        _phase_closer(axis_name)  # same stream invariant as the forward
+        # same stream invariant as the forward
+        dq_total = dq_total + _phase_closer(axis_name, dq_t)
     # dq accumulated in q' = p2*q units; rescale once.
     return ((dq_total * p2).reshape(q.shape).astype(q.dtype),
             acc_k.reshape(k.shape).astype(k.dtype),
@@ -481,38 +489,48 @@ def fused_ring_attention(q, k, v, axis_name: str, causal: bool = False,
 
     Same contract as :func:`horovod_tpu.ops.ring_attention` (shards of
     ``(batch, heads, seq_local, head_dim)`` inside ``shard_map`` over
-    ``axis_name``).  Shard lengths that don't factor into MXU-tileable
-    blocks (see ``_pick_block``) fall back to the separable ppermute ring,
-    as :func:`flash_attention` falls back to blockwise.
+    ``axis_name``).  Raises :class:`FusedRingUnsupported` for what the
+    kernel cannot run: float16 (compiled path), shard lengths that do not
+    tile into MXU blocks, and local shards too long for the backward's
+    whole-shard dq scratch.
     """
-    if not _HAS_PALLAS:
-        raise RuntimeError("fused_ring_attention requires Pallas")
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     sl = q.shape[-2]
     d = q.shape[-1]
+    if not interpret and jnp.float16 in (q.dtype, k.dtype, v.dtype):
+        # The chip's compiler refuses float16 kernels outright, as in
+        # flash_attention.
+        raise FusedRingUnsupported(
+            "float16 is not a native TPU type and Mosaic refuses the fused "
+            "ring kernel for it; use bfloat16, or rotate_impl=\"ppermute\"")
     bq, bk = _pick_block(sl, block_q), _pick_block(sl, block_k)
-    off_grid = sl % bq or sl % bk or (not interpret
-                                      and (bq % 128 or bk % 128))
+    if sl % bq or sl % bk or (not interpret and (bq % 128 or bk % 128)):
+        raise FusedRingUnsupported(
+            f"local shard length {sl} does not tile into blocks the MXU "
+            f"takes (multiples of 128; picked {bq}x{bk}); pad the sequence, "
+            "or use rotate_impl=\"ppermute\"")
     # The fused backward step is the combined kernel — whole-shard dq
     # scratch in VMEM.  Long local shards where that cannot compile
     # (attention._bwd_plan, calibrated against the 16 MiB scoped-VMEM
-    # ceiling) route to the separable ppermute ring, whose backward
-    # composes per-step flash backwards, instead of failing at Mosaic
+    # ceiling) are refused here, by name, instead of failing at Mosaic
     # compile time on the backward pass (ADVICE r4).
-    mode, bq, bk = _bwd_plan(sl, d, bq, bk, q.shape[0] * q.shape[1])
-    off_grid = off_grid or mode != "combined" or sl % bq or sl % bk
-    # Interpret-mode (CPU test mesh) remote DMA only supports single-axis
-    # meshes (upstream dma_start_p limitation); a dp x sp mesh on CPU
-    # falls back to the separable ring.  Real TPUs use MESH device ids
-    # and are unaffected.
-    multi_axis_interpret = (interpret
-                            and len(_ambient_mesh_axes(axis_name)) > 1)
-    if off_grid or multi_axis_interpret:
-        # Ragged or non-MXU-tileable shard lengths: the separable ring
-        # handles them (mirrors _flash_forward's blockwise fallback).
+    bh = q.shape[0] * q.shape[1]
+    mode, bq, bk = _bwd_plan(sl, d, bq, bk, bh)
+    if mode != "combined" or sl % bq or sl % bk:
+        raise FusedRingUnsupported(
+            f"local shard length {sl} at head_dim {d} and batch*heads "
+            f"{bh} is past where the fused backward's "
+            "whole-shard dq scratch fits scoped VMEM (attention._bwd_plan "
+            f"chose {mode!r}); use more ring devices, or "
+            "rotate_impl=\"ppermute\"")
+    if interpret and len(_ambient_mesh_axes(axis_name)) > 1:
+        # The CPU interpreter's remote DMA only supports single-axis
+        # meshes (upstream dma_start_p limitation), so a dp x sp test
+        # mesh runs the separable ring.  Compiled kernels use MESH device
+        # ids and never come here.
         from horovod_tpu.ops.ring_attention import ring_attention
 
         return ring_attention(q, k, v, axis_name, causal=causal,

@@ -170,6 +170,12 @@ def make_rank_env(rank: int, size: int, coord: str, data: Sequence[str],
     env["HVD_TPU_DATA"] = ",".join(data)
     if xla_coord:
         env["HVD_TPU_XLA_COORD"] = xla_coord
+    # Ranks that compile share one persistent cache at a path that does not
+    # move (common/compile_cache.py); a rank that never imports JAX ignores
+    # the variable.
+    from horovod_tpu.common.compile_cache import place_compile_cache
+
+    place_compile_cache(env)
     # Sanitized engine builds (docs/contributing.md#sanitized-engine
     # -builds): the instrumented libhvdtpu.<mode>.so needs the sanitizer
     # runtime preloaded into the RANK processes — but preloading the

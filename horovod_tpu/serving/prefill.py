@@ -97,8 +97,6 @@ class RingPrefill:
         import jax
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from horovod_tpu.jax.train import shard_map
-
         if self.n_sp == 1 or padded % self.n_sp:
             model = _engine.build_model(self.spec, capture_kv=True)
 
@@ -121,8 +119,8 @@ class RingPrefill:
             k, v = self._extract_kv(state["intermediates"])
             return logits, k, v
 
-        mapped = shard_map(
-            shard, mesh,
+        mapped = jax.shard_map(
+            shard, mesh=mesh,
             in_specs=(P(None, "sp"),),
             out_specs=(P(None, "sp", None),
                        P(None, None, None, "sp", None),

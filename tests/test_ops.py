@@ -8,14 +8,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              mha_reference, ring_attention)
-
-# The train.py wrapper translates the check_vma/check_rep kwarg rename
-# across jax versions (CI min-versions leg).
-from horovod_tpu.jax.train import shard_map
 
 
 def _qkv(batch=2, heads=2, seq=256, d=64, seed=0, dtype=jnp.float32):
@@ -183,28 +180,180 @@ def test_bwd_plan_fits_vmem_budget(monkeypatch):
     assert attn._fwd_vmem_bytes(8192, 64, *fitted) <= attn._vmem_budget_bytes()
 
 
+# ---------------------------------------------------------------------------
+# The chip's compiler, without the chip: libtpu compiles for a DESCRIBED
+# v5e 2x2 host (jax.experimental.topologies), which refuses what the chip
+# would refuse — scoped-VMEM overruns, tiling violations, a kernel that
+# cannot be partitioned — and interpret mode cannot.  Nothing runs, so
+# these say nothing about results or times.  One file, in-process: libtpu
+# takes a lock file, so two processes cannot describe a topology at once.
+# Code that asks jax.default_backend() still sees the CPU, so the kernels
+# get interpret=False explicitly (or the test steers the backend query).
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The devices of a described (not attached) v5e 2x2 host; skips where
+    libtpu cannot describe one."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu, or another process holds its lock
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc}")
+    return topo.devices
+
+
+def _compile_flash_grad(device, shape, **kwargs):
+    from jax.sharding import SingleDeviceSharding
+
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                             sharding=SingleDeviceSharding(device))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False,
+                               **kwargs).astype(jnp.float32).sum()
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    return grad.lower(q, q, q).compile().as_text()
+
+
 @pytest.mark.parametrize("d", [64, 128])
 @pytest.mark.parametrize("seq", [1024, 4096, 8192, 16384])
-def test_flash_bwd_seq_sweep_compiles(seq, d):
+def test_flash_bwd_seq_sweep_compiles(v5e, seq, d):
     """The documented long-context sweep {1k, 4k, 8k, 16k} x head_dim
     {64, 128} must COMPILE for fwd+bwd at the bench-protocol batch
     (token-constant seq:batch pairs — batch*heads feeds _bwd_plan's bh
-    frontier) — AOT on a real TPU (catches scoped-VMEM OOM, the r4
-    failure), abstract trace elsewhere (catches block/shape mismatches
-    in the plan routing)."""
+    frontier) through the chip's own compiler: a scoped-VMEM OOM (the r4
+    failure) or a block/shape mismatch in the plan routing fails here."""
+    from horovod_tpu.ops.attention import _bwd_plan
+
     batch = {1024: 16, 4096: 4, 8192: 2, 16384: 1}[seq]
-    q = jnp.zeros((batch, 8, seq, d), jnp.bfloat16)
+    text = _compile_flash_grad(v5e[0], (batch, 8, seq, d))
+    # forward + combined backward, or forward + the split dkdv/dq pair
+    mode = _bwd_plan(seq, d, 1024, 1024, batch * 8)[0]
+    assert text.count("tpu_custom_call") == {"combined": 2, "split": 3}[mode]
+
+
+@pytest.mark.parametrize("seq,blocks", [(2048, 2048), (4096, 4096)])
+def test_flash_oversized_explicit_block_compiles(v5e, seq, blocks):
+    """ADVICE r5 #2: an explicit block past the calibrated 1024 passes the
+    divisibility checks but is refused by the chip's compiler (2048-row
+    blocks at seq 2048 fail the backward, 4096 at seq 4096 the forward);
+    flash_attention cuts it to the calibrated maximum as it does the
+    default."""
+    text = _compile_flash_grad(v5e[0], (4, 8, seq, 64), block_q=blocks,
+                               block_k=blocks)
+    assert text.count("tpu_custom_call") == 2
+
+
+def _sp_mesh(devices):
+    return Mesh(np.array(devices).reshape(1, 4), ("dp", "sp"))
+
+
+def test_rdma_ring_permute_compiles_on_mesh(v5e):
+    """The raw remote-DMA rotation compiles for four described chips on a
+    two-axis mesh (MESH device ids) under shard_map's default vma check,
+    forward and transposed."""
+    from jax.sharding import NamedSharding
+
+    from horovod_tpu.ops.rdma import ring_permute
+
+    mesh = _sp_mesh(v5e)
+    spec = P("dp", None, "sp", None)
+    x = jax.ShapeDtypeStruct((2, 8, 8192, 64), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, spec))
+
+    def loss(x):
+        out = shard_map(
+            functools.partial(ring_permute, axis_name="sp", interpret=False),
+            mesh=mesh, in_specs=spec, out_specs=spec)(x)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss)).lower(x).compile().as_text()
+    assert text.count("tpu_custom_call") == 2  # the rotation and its VJP
+    assert "collective-permute" not in text
+
+
+@pytest.mark.parametrize("impl", ["ppermute", "rdma", "fused"])
+def test_ring_variants_compile_on_mesh(v5e, monkeypatch, impl):
+    """Every rotate_impl compiles fwd+bwd for four described chips at
+    (2, 8, 8192, 64) bf16 — 2048 rows a chip — under shard_map's default
+    check_vma=True (the fused ring's barrier-only closer used to fail the
+    check), and the compiled text holds the rotation that was asked for,
+    not a stand-in."""
+    import re
+
+    from jax.sharding import NamedSharding
+
+    # ring_attention resolves interpret mode from the backend; steer that
+    # query here rather than give the program an option for tests.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = _sp_mesh(v5e)
+    spec = P("dp", None, "sp", None)
+    q = jax.ShapeDtypeStruct((2, 8, 8192, 64), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, spec))
+    fn = functools.partial(ring_attention, axis_name="sp", causal=True,
+                           rotate_impl=impl)
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, causal=True,
-                               interpret=jax.default_backend() != "tpu"
-                               ).astype(jnp.float32).sum()
+        out = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                        out_specs=spec)(q, k, v)
+        return (out.astype(jnp.float32) ** 2).sum()
 
-    g = jax.grad(loss, argnums=(0, 1, 2))
-    if jax.default_backend() == "tpu":
-        jax.jit(g).lower(q, q, q).compile()  # real Mosaic compile
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    text = grad.lower(q, q, q).compile().as_text()
+    kernels = text.count("tpu_custom_call")
+    permutes = text.count("collective-permute-start(")
+    if impl == "ppermute":
+        assert kernels == 0 and permutes > 0
+    elif impl == "rdma":
+        # K and V, three rotations each, forward and transposed.
+        assert kernels == 12 and permutes == 0
     else:
-        jax.eval_shape(g, q, q, q)  # trace-only: plan/blocks consistency
+        # Four step kernels and a barrier-only closer per pass; only the
+        # float32 dk/dv partials still travel by collective-permute.  The
+        # barrier namespaces alternate through both passes, closers
+        # included (a dropped closer would leave 15,16,15,15,16,15).
+        assert kernels == 10 and permutes == 8
+        ids = re.findall(r'collective_id\W+(\d+)', text)
+        assert ids == ["15", "16"] * 4, ids
+
+
+@pytest.mark.parametrize("mode", ["combined", "split"])
+def test_flash_inside_shard_map_default_vma_check(monkeypatch, mode):
+    """flash_attention is called inside build_train_step's shard_map, whose
+    default check_vma=True refuses a pallas out_shape that does not say how
+    it varies: forward and both backward plans must carry the annotation
+    (TransformerLM(use_flash=True) through build_train_step failed at trace
+    time without it)."""
+    import horovod_tpu.ops.attention as attn
+
+    monkeypatch.setattr(attn, "_bwd_plan",
+                        lambda q_len, d, bq, bk, bh=1: (mode, 128, 128))
+    mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
+    spec = P("dp")
+    q, k, v = _qkv(batch=2, heads=2, seq=256, d=32, seed=9)
+
+    def loss(q, k, v):
+        out = shard_map(
+            functools.partial(flash_attention, causal=True, block_q=128,
+                              block_k=128),
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
+        return (out ** 2).sum()
+
+    def loss_ref(q, k, v):
+        return (mha_reference(q, k, v, causal=True) ** 2).sum()
+
+    g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g, g_ref):
+        np.testing.assert_allclose(a, b, atol=1e-3, rtol=1e-3)
 
 
 def test_flash_split_backward_matches(monkeypatch):
@@ -458,42 +607,31 @@ def test_fused_ring_flash_matches_dense(causal):
         np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
 
 
-def test_fused_ring_flash_oversized_shard_falls_back(monkeypatch):
-    """Local shards whose combined-backward VMEM plan cannot compile must
-    route to the separable ppermute ring INSTEAD of failing at Mosaic
-    compile time on the backward pass (ADVICE r4: the old predicate only
-    checked block divisibility).  Forced via the plan so it runs at test
-    sizes; the fallback must still match the dense reference."""
-    import importlib
-
+def test_fused_ring_flash_oversized_shard_raises_typed(monkeypatch):
+    """Local shards whose combined-backward VMEM plan cannot compile are
+    refused by name at trace time — neither a Mosaic compile failure on
+    the backward pass (ADVICE r4) nor a quiet reroute to the separable
+    ring, which would let a caller time the wrong kernel.  Forced via the
+    plan so it runs at test sizes.  Ragged shard lengths raise the same
+    type."""
     import horovod_tpu.ops.ring_flash as rf
-
-    # The package re-exports the function under the same name as the
-    # module, so fetch the module itself for monkeypatching.
-    ra_mod = importlib.import_module("horovod_tpu.ops.ring_attention")
-
-    # ring_flash binds _bwd_plan by value at import; patch its binding.
-    monkeypatch.setattr(rf, "_bwd_plan", lambda *a: ("split", 128, 128))
-    calls = []
-    real_ring = ra_mod.ring_attention
-
-    def recording_ring(*args, **kw):
-        calls.append(kw.get("rotate_impl"))
-        return real_ring(*args, **kw)
-
-    monkeypatch.setattr(ra_mod, "ring_attention", recording_ring)
 
     devices = jax.devices()
     mesh = Mesh(np.array(devices[:4]), ("sp",))
-    q, k, v = _qkv(batch=1, heads=2, seq=4 * 32, d=16)
     spec = P(None, None, "sp", None)
     fn = functools.partial(rf.fused_ring_attention, axis_name="sp",
                            causal=True)
-    got = jax.jit(shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                            out_specs=spec, check_vma=False))(q, k, v)
-    assert calls == ["ppermute"], calls  # fused path declined, separable ran
-    want = mha_reference(q, k, v, causal=True)
-    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+    def run(q, k, v):
+        return jax.jit(shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                                 out_specs=spec, check_vma=False))(q, k, v)
+
+    with pytest.raises(rf.FusedRingUnsupported, match="does not tile"):
+        run(*_qkv(batch=1, heads=2, seq=4 * 1100, d=16))
+    # ring_flash binds _bwd_plan by value at import; patch its binding.
+    monkeypatch.setattr(rf, "_bwd_plan", lambda *a: ("split", 128, 128))
+    with pytest.raises(rf.FusedRingUnsupported, match="scoped VMEM"):
+        run(*_qkv(batch=1, heads=2, seq=4 * 32, d=16))
 
 
 @pytest.mark.slow  # ~15s; ring-flash numerics stay tier-1 in

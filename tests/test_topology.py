@@ -390,7 +390,7 @@ def test_hierarchical_mesh_mirrors_two_level_decomposition():
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from horovod_tpu.jax.train import shard_map
+    from jax import shard_map
     from horovod_tpu.parallel import hierarchical_mesh
 
     devices = jax.devices()[:8]

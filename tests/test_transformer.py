@@ -6,13 +6,10 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu.models import TransformerLM, next_token_loss
-
-# The train.py wrapper translates the check_vma/check_rep kwarg rename
-# across jax versions (CI min-versions leg).
-from horovod_tpu.jax.train import shard_map
 
 VOCAB = 64
 

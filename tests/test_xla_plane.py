@@ -14,9 +14,6 @@ def _init_with_plane():
 
     os.environ["HVD_TPU_XLA_DATA_PLANE"] = "1"
     os.environ["JAX_PLATFORMS"] = "cpu"
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     import horovod_tpu as hvd
 
     hvd.init()
@@ -391,35 +388,145 @@ def test_xla_plane_with_rank_subset_falls_back():
 def test_plane_auto_enable_detection(monkeypatch):
     """Default-on selection (VERDICT r3 #3, matching the reference's NCCL
     path needing no runtime flag, operations.cc:861-914): with the env
-    unset the plane is attempted iff jax reports TPU devices; "0" opts
-    out even on TPU; the HOROVOD_XLA_DATA_PLANE alias forces it on."""
+    unset the plane is attempted iff the rank was given a chip of its
+    own (read from the environment, never by asking JAX); "0" opts out
+    even then; the HOROVOD_XLA_DATA_PLANE alias forces it on."""
     import horovod_tpu as hvd
     import horovod_tpu.common as common
     from horovod_tpu.jax import eager_mesh
 
     calls = []
 
+    class FakePlane:
+        pass
+
     def fake_initialize(ps):
         calls.append(ps.rank)
-        return None  # "plane init failed" -> engine fallback, no fabric
+        return FakePlane()
 
     monkeypatch.setattr(eager_mesh, "initialize", fake_initialize)
 
-    def run(env, tpu_visible, expect_attempt):
+    def run(env, pinned, expect_attempt):
         calls.clear()
-        for key in ("HVD_TPU_XLA_DATA_PLANE", "HOROVOD_XLA_DATA_PLANE"):
+        for key in ("HVD_TPU_XLA_DATA_PLANE", "HOROVOD_XLA_DATA_PLANE",
+                    "TPU_VISIBLE_CHIPS"):
             monkeypatch.delenv(key, raising=False)
         if env is not None:
             monkeypatch.setenv(*env)
-        monkeypatch.setattr(common, "_tpu_visible", lambda: tpu_visible)
+        if pinned:
+            monkeypatch.setenv("TPU_VISIBLE_CHIPS", "0")
         hvd.init()
         try:
-            assert bool(calls) == expect_attempt, (env, tpu_visible, calls)
-            assert common._xla_plane is None  # fake init always falls back
+            assert bool(calls) == expect_attempt, (env, pinned, calls)
+            assert (common._xla_plane is not None) == expect_attempt
         finally:
             hvd.shutdown()
 
-    run(None, True, True)      # auto: TPU visible -> plane attempted
-    run(None, False, False)    # auto: no TPU -> engine only
+    run(None, True, True)      # auto: pinned to a chip -> plane attempted
+    run(None, False, False)    # auto: no chip of its own -> engine only
     run(("HVD_TPU_XLA_DATA_PLANE", "0"), True, False)   # explicit opt-out
     run(("HOROVOD_XLA_DATA_PLANE", "1"), False, True)   # alias forces on
+
+
+def test_requested_plane_that_cannot_form_raises(monkeypatch):
+    """A plane asked for (here by the env switch) that cannot form is an
+    error from hvd.init(), not a warning and the TCP engine — and the
+    engine is left shut down so the process can init again."""
+    import horovod_tpu as hvd
+    from horovod_tpu.jax import eager_mesh
+
+    def broken_initialize(ps):
+        raise RuntimeError("no fabric today")
+
+    monkeypatch.setattr(eager_mesh, "initialize", broken_initialize)
+    monkeypatch.setenv("HVD_TPU_XLA_DATA_PLANE", "1")
+    with pytest.raises(hvd.HorovodInternalError, match="no fabric today"):
+        hvd.init()
+    assert not hvd.is_initialized()
+
+
+def _mark_pinned():
+    """The part of runner/tpu_pin.py's env that marks a rank as holding a
+    chip of its own.  With JAX_PLATFORMS=cpu libtpu is never loaded, so
+    the marker alone decides plane selection."""
+    import os
+
+    os.environ.pop("HVD_TPU_XLA_DATA_PLANE", None)
+    os.environ.pop("HOROVOD_XLA_DATA_PLANE", None)
+    from horovod_tpu.runner.tpu_pin import pin_env
+
+    rank, size = int(os.environ["HVD_TPU_RANK"]), int(os.environ["HVD_TPU_SIZE"])
+    addresses = [f"127.0.0.1:{8470 + r}" for r in range(size)]
+    os.environ.update(pin_env(rank, rank, size, 0, 1, addresses))
+
+
+@distributed_test(np_=2, timeout=300.0)
+def test_pinned_ranks_form_plane_before_any_backend():
+    """The repaired init order: a pinned rank, with the env switch unset,
+    calls jax.distributed.initialize while no backend exists yet (the
+    installed JAX refuses it afterwards) and before the engine starts (on
+    real chips opening the device freezes the process for seconds, and the
+    engine's heartbeat detector took the frozen ranks for dead), and the
+    plane carries the eager collectives — in RANK order even where JAX
+    numbers the processes otherwise (a TPU host's runtime numbers them
+    itself; here the spy hands out reversed process ids)."""
+    _mark_pinned()
+    import jax
+    from jax._src import xla_bridge
+
+    import horovod_tpu as hvd
+    import horovod_tpu.common as common
+
+    seen = []
+    real_initialize = jax.distributed.initialize
+
+    def spy(*args, **kwargs):
+        seen.append((xla_bridge.backends_are_initialized(),
+                     hvd.is_initialized()))
+        kwargs["process_id"] = (kwargs["num_processes"] - 1
+                                - kwargs["process_id"])
+        return real_initialize(*args, **kwargs)
+
+    jax.distributed.initialize = spy
+    hvd.init()
+    assert seen == [(False, False)], seen
+    plane = common._xla_plane
+    assert plane is not None, "pinned ranks must form the XLA data plane"
+    r, n = hvd.rank(), hvd.size()
+    assert jax.process_index() == n - 1 - r
+    out = hvd.allreduce(np.full(5, float(r + 1), np.float32),
+                        average=False, name="pin.ar")
+    assert np.allclose(out, sum(range(1, n + 1))), out
+    out = hvd.allgather(np.full((r + 1, 2), float(r), np.float32),
+                        name="pin.ag")  # ragged, blocks in rank order
+    want = np.concatenate([np.full((i + 1, 2), float(i)) for i in range(n)])
+    np.testing.assert_array_equal(out, want)
+    for root in range(n):
+        out = hvd.broadcast(np.arange(4, dtype=np.float32) + r, root,
+                            name=f"pin.bc.{root}")
+        np.testing.assert_array_equal(out, np.arange(4) + root)
+    assert plane.stats["dispatches"] >= 3, plane.stats
+    hvd.shutdown()
+
+
+@distributed_test(np_=2, timeout=120.0)
+def test_unpinned_ranks_leave_jax_backend_alone():
+    """An unpinned multi-rank hvd.init() must not open a device: N ranks
+    of one host would fight over the chip.  It rides the TCP engine."""
+    import os
+
+    for key in ("HVD_TPU_XLA_DATA_PLANE", "HOROVOD_XLA_DATA_PLANE",
+                "TPU_VISIBLE_CHIPS"):
+        os.environ.pop(key, None)
+    import jax  # noqa: F401  (imported, as a binding would; never asked)
+    from jax._src import xla_bridge
+
+    import horovod_tpu as hvd
+    import horovod_tpu.common as common
+
+    hvd.init()
+    assert common._xla_plane is None
+    out = hvd.allreduce(np.ones(3, np.float32), average=False, name="nopin")
+    assert np.allclose(out, hvd.size())
+    assert not xla_bridge.backends_are_initialized()
+    hvd.shutdown()

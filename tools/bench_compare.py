@@ -7,11 +7,14 @@ when the new run regressed by more than the threshold — usable locally
 ("did my change cost throughput?") and as a CI gate between rounds:
 
     python bench.py > /tmp/new.json
-    python tools/bench_compare.py BENCH_r05.json /tmp/new.json --threshold 5
+    python tools/bench_compare.py /tmp/old.json /tmp/new.json --threshold 5
 
 ``--history`` renders the round-over-round trajectory instead of a gate:
 
-    python tools/bench_compare.py --history BENCH_r0*.json
+    python tools/bench_compare.py --history r01.json r02.json ...
+
+(the round files of rounds 1-5, BENCH_r01-r05.json, left the tree with the
+set-up they were taken on; they remain in git history)
 
 one line per round — headline value, vs_baseline ratio, and the delta
 against the previous parseable round.  Rounds whose record failed to

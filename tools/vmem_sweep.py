@@ -51,8 +51,9 @@ def main():
                     help="sweep every block candidate, not just the plan's")
     args = ap.parse_args()
     if jax.default_backend() != "tpu":
-        print("no TPU visible; this sweep only means something on-chip")
-        return
+        # Without the chip flash_attention would run the interpreter and
+        # every config would "compile": that is no calibration.
+        sys.exit("no TPU backend: this sweep calibrates the chip's compiler")
     cands = [(1024, 1024), (512, 1024), (1024, 512), (512, 512),
              (256, 512), (256, 256)]
     # bench-protocol bh (token-constant seq:batch sweep) plus a high-bh
