@@ -14,6 +14,7 @@ import contextlib
 import ctypes
 import json
 import os
+import sys
 import threading
 import time
 import weakref
@@ -1176,6 +1177,18 @@ def _sync_engine_p2p() -> None:
         })
 
 
+def _sync_step_completions() -> None:
+    """Fold into ``step_sec`` the training steps whose loss is ready by
+    now (jax/train.py's waiter would get to them a moment later), so that a
+    snapshot taken after the caller waited for a loss counts that step.
+    Nothing to do where the JAX binding was never imported."""
+    # getattr: a monitor thread may ask while the module is being imported.
+    completions = getattr(sys.modules.get("horovod_tpu.jax.train"),
+                          "_completions", None)
+    if completions is not None:
+        completions.drain()
+
+
 def metrics_snapshot() -> dict:
     """Plain nested dict of the collective metrics registry: op/byte
     counters per data plane, fusion-batch counters, latency/fill
@@ -1200,18 +1213,21 @@ def metrics_snapshot() -> dict:
     _sync_engine_links()
     _sync_engine_anomalies()
     _sync_engine_p2p()
+    _sync_step_completions()
     return metrics.registry.snapshot()
 
 
 def metrics_reset() -> None:
     """Zero every counter, histogram, and stall record (the enabled flag
-    is unaffected).  Outstanding engine stall events are consumed first so
-    they cannot resurface in the next snapshot."""
+    is unaffected).  Outstanding engine stall events and training steps
+    that have completed are consumed first so they cannot resurface in the
+    next snapshot."""
     _sync_engine_stalls()
     _sync_engine_aborts()
     _sync_engine_announces()
     _sync_engine_cache()
     _sync_engine_topology()
+    _sync_step_completions()
     metrics.registry.reset()
 
 
@@ -1344,16 +1360,24 @@ def trace_span(name: str, label: Optional[str] = None):
             batch = next(loader)
 
     The span occupies the trace row ``name`` (same-row spans nest);
-    ``label`` overrides the event label (default: the row name).  A no-op
-    when the timeline is disabled — safe to leave in production code."""
-    if _lib is None or not _lib.hvd_tpu_timeline_enabled():
-        yield
-        return
-    _lib.hvd_tpu_timeline_op_start(name.encode(), (label or name).encode())
-    try:
-        yield
-    finally:
-        _lib.hvd_tpu_timeline_op_end(name.encode(), 0)
+    ``label`` overrides the event label (default: the row name).  Where
+    JAX is already imported the span is also a
+    ``jax.profiler.TraceAnnotation(name)``, so a ``jax.profiler.trace``
+    shows it beside the device's operations, on their clock (this module
+    never imports JAX itself).  A no-op when the timeline is disabled and
+    no profiler session runs — safe to leave in production code."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    timeline = _lib is not None and _lib.hvd_tpu_timeline_enabled()
+    with (profiler.TraceAnnotation(name) if profiler is not None
+          else contextlib.nullcontext()):
+        if timeline:
+            _lib.hvd_tpu_timeline_op_start(name.encode(),
+                                           (label or name).encode())
+        try:
+            yield
+        finally:
+            if timeline:
+                _lib.hvd_tpu_timeline_op_end(name.encode(), 0)
 
 
 def trace_marker(name: str, row: str = "app.markers") -> None:

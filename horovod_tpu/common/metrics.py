@@ -59,7 +59,13 @@ HISTOGRAMS = {
     "bucket_fill": (FILL_BUCKETS,
                     "fusion-bucket fill fraction of the fusion threshold"),
     "step_sec": (LATENCY_BUCKETS,
-                 "jax build_train_step per-call dispatch time"),
+                 "one completed step: jax build_train_step's, from the "
+                 "later of its dispatch and the previous step's completion "
+                 "to its own; the serving engine's, around a step it waits "
+                 "for"),
+    "step_dispatch_sec": (LATENCY_BUCKETS,
+                          "jax build_train_step: host time for one call to "
+                          "return (the enqueue, not the step)"),
     "announce_skew_sec": (LATENCY_BUCKETS,
                           "first-to-last announce skew per negotiated "
                           "collective (rank-0 coordinator view)"),

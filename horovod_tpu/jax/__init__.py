@@ -164,7 +164,12 @@ def allreduce_pytree(tree, average: bool = True,
         return allreduce(leaf, average=average,
                          name=f"{name_prefix}.{_path_str(path)}",
                          axis_name=axis_name)
-    return jax.tree_util.tree_map_with_path(one, tree)
+    if axis_name is None:
+        return jax.tree_util.tree_map_with_path(one, tree)
+    # The compiled exchange carries its name into the HLO's op_name, where
+    # a device trace finds it (docs/timeline.md).
+    with jax.named_scope("hvd_grad_exchange"):
+        return jax.tree_util.tree_map_with_path(one, tree)
 
 
 def _bcast_leaf(path, leaf, root_rank: int, name_prefix: str):

@@ -19,10 +19,24 @@ computes gradients on its slice of the batch and `DistributedOptimizer`'s
 per-leaf `psum` averages them over ICI, overlapped with the backward pass by
 XLA (the compiled equivalent of the reference's hook-driven
 allreduce-during-backprop, /root/reference/horovod/torch/__init__.py:64-89).
+
+What the step says of itself (docs/timeline.md, docs/metrics.md).  In the
+compiled program, as ``jax.named_scope`` names that ride each operation's
+``op_name`` into a device trace at no cost in a step: ``hvd_loss`` around
+``loss_fn`` (so forward operations read ``jvp(hvd_loss)`` and backward ones
+``transpose(jvp(hvd_loss))``), ``hvd_optimizer`` around the update with
+``hvd_grad_exchange`` beneath it, ``hvd_loss_report`` around the averages
+of the loss and aux.  On the host, around every call: a
+``jax.profiler.StepTraceAnnotation("hvd.train_step")`` on the profiler's
+clock, the ``jax.train_step`` timeline span, and two histograms —
+``step_dispatch_sec`` (the call returning: the enqueue) and ``step_sec``
+(the step completing on the device, observed off the caller's thread).
 """
 
 from __future__ import annotations
 
+import collections
+import threading
 import time
 from typing import Callable, Optional
 
@@ -156,38 +170,132 @@ def load_latest_checkpoint(directory: str, collective: bool = True):
     return load_checkpoint(path, collective=collective)
 
 
+class _StepCompletions:
+    """When each dispatched step finished on the device, for ``step_sec``.
+
+    `_TimedStep` hands over a step's loss (an output: nothing donated is
+    held) with the time of its dispatch; one daemon thread, started with
+    the first step handed over, waits for each in turn and observes the
+    time from ``max(previous step's completion, this step's dispatch)`` to
+    its completion — the step itself where the loop runs ahead of the
+    device, dispatch to completion where it waits.  A step is complete when
+    this process's first copy of the loss is: what ``float(loss)`` reads
+    (the other chips' copies follow within the step's last collective).
+    `drain` observes, on the reader's thread, the steps whose loss is ready
+    by now, so that a snapshot taken after the caller has waited for a loss
+    counts that step.  Steps beyond ``LIMIT`` outstanding are not observed;
+    a loss that raises when waited for (a failed step, a deleted array) is
+    dropped."""
+
+    LIMIT = 256
+
+    def __init__(self):
+        self._wake = threading.Condition()
+        self._pending = collections.deque()
+        self._thread = None
+        self._last_done = 0.0
+
+    def submit(self, dispatched: float, loss) -> None:
+        with self._wake:
+            if len(self._pending) >= self.LIMIT:
+                return
+            self._pending.append((dispatched, loss))
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name="hvd-step-completions",
+                    daemon=True)
+                self._thread.start()
+            self._wake.notify()
+
+    def _retire(self, entry, done: Optional[float]) -> None:
+        # The lock is held.  Waiter and drain both work on the head, and
+        # whichever sees it finished first retires it.
+        if not self._pending or self._pending[0] is not entry:
+            return
+        self._pending.popleft()
+        if done is not None:
+            _metrics.registry.observe(
+                "step_sec", done - max(self._last_done, entry[0]))
+            self._last_done = done
+
+    def _run(self) -> None:
+        while True:
+            with self._wake:
+                while not self._pending:
+                    self._wake.wait()
+                entry = self._pending[0]
+            try:
+                entry[1].addressable_data(0).block_until_ready()
+                done = time.perf_counter()
+            except Exception:  # noqa: BLE001 - the thread outlives a step
+                done = None
+            with self._wake:
+                self._retire(entry, done)
+
+    def drain(self) -> None:
+        with self._wake:
+            while self._pending:
+                entry = self._pending[0]
+                try:
+                    if not entry[1].addressable_data(0).is_ready():
+                        return
+                    done = time.perf_counter()
+                except RuntimeError:      # the array was deleted
+                    done = None
+                self._retire(entry, done)
+
+
+_completions = _StepCompletions()
+
+
 class _TimedStep:
-    """Callable proxy over the jitted step that feeds the ``step_sec``
-    histogram of the metrics registry (docs/metrics.md) — per-epoch step
-    summaries for free wherever ``build_train_step`` is used — and, when a
-    timeline is active (docs/timeline.md), wraps each call in a
-    ``jax.train_step`` span on this rank's trace.  The measured interval
-    is the on-host dispatch of one step call (jax dispatch is async);
-    training loops that fetch the loss each step see true step time.
-    Every jit attribute (``lower``, ``trace``, ...) delegates to the
-    wrapped function."""
+    """Callable proxy over the jitted step: the library's own account of a
+    training step (docs/metrics.md, docs/timeline.md).
+
+    Every call runs inside ``jax.profiler.StepTraceAnnotation(
+    "hvd.train_step", step_num=n)`` — ``n`` counts this proxy's calls — so
+    that a ``jax.profiler.trace`` around any loop shows the library's call
+    on ``/host:CPU``, on the device planes' clock; outside a profiler
+    session that is a disabled ``TraceMe``.  When a timeline is active the
+    call is also a ``jax.train_step`` span on this rank's trace.  When the
+    metrics registry is enabled, ``step_dispatch_sec`` takes the host time
+    the call took to return (jax dispatch is asynchronous: the enqueue) and
+    the step's loss goes to `_StepCompletions`, which feeds ``step_sec``
+    with the time the step took to complete.  With all three off a call
+    costs two flag reads and the disabled annotation.  Every jit attribute
+    (``lower``, ``trace``, ...) delegates to the wrapped function."""
 
     def __init__(self, fn):
         self._fn = fn
+        self._calls = 0
 
     def __call__(self, *args, **kwargs):
         from horovod_tpu import common as _common
 
         tl = _common.timeline_enabled()
         mx = _metrics.registry.enabled
-        if not tl and not mx:
-            return self._fn(*args, **kwargs)
-        if tl:
-            _common._trace_begin("jax.train_step", "TRAIN_STEP")
-        t0 = time.perf_counter()
-        try:
-            out = self._fn(*args, **kwargs)
-        finally:
+        step_num = self._calls
+        self._calls = step_num + 1
+        with jax.profiler.StepTraceAnnotation("hvd.train_step",
+                                              step_num=step_num):
+            if not tl and not mx:
+                return self._fn(*args, **kwargs)
             if tl:
-                _common._trace_end("jax.train_step")
-        if mx:
-            _metrics.registry.observe("step_sec", time.perf_counter() - t0)
-        return out
+                _common._trace_begin("jax.train_step", "TRAIN_STEP")
+            t0 = time.perf_counter()
+            try:
+                out = self._fn(*args, **kwargs)
+            finally:
+                if tl:
+                    _common._trace_end("jax.train_step")
+            if mx:
+                _metrics.registry.observe("step_dispatch_sec",
+                                          time.perf_counter() - t0)
+                # Under an outer trace the loss is a tracer: no device
+                # will ever complete it.
+                if hasattr(out[2], "is_ready"):
+                    _completions.submit(t0, out[2])
+            return out
 
     def __getattr__(self, name):
         return getattr(self._fn, name)
@@ -220,26 +328,35 @@ def build_train_step(loss_fn: Callable, optimizer, mesh: Mesh,
 
     dist_opt = DistributedOptimizer(optimizer, axis_name=axis_name)
 
+    # The scopes below are names in the HLO's metadata and nothing else;
+    # `hvd_loss` sits inside what value_and_grad differentiates, so JAX's
+    # own name stack tells the forward pass (`jvp(hvd_loss)`) from the
+    # backward (`transpose(jvp(hvd_loss))`).
+    def scoped_loss(params, batch):
+        with jax.named_scope("hvd_loss"):
+            return loss_fn(params, batch)
+
     def shard_step(params, opt_state, batch):
-        if has_aux:
-            (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, batch)
-        else:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        if check_vma:
-            updates, opt_state = dist_opt.update(grads, opt_state, params)
-        else:
-            # Without the vma machinery autodiff inserts no psum and
-            # hvd.allreduce cannot see which values still vary (it would
-            # take local gradients for reduced ones): average here.
-            grads = jax.tree.map(lambda g: lax.pmean(g, axis_name), grads)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        loss = lax.pmean(loss, axis_name)
-        if has_aux:
-            aux = jax.tree.map(lambda a: lax.pmean(a, axis_name), aux)
-            return params, opt_state, loss, aux
-        return params, opt_state, loss
+        out, grads = jax.value_and_grad(scoped_loss, has_aux=has_aux)(
+            params, batch)
+        with jax.named_scope("hvd_optimizer"):
+            if check_vma:
+                updates, opt_state = dist_opt.update(grads, opt_state,
+                                                     params)
+            else:
+                # Without the vma machinery autodiff inserts no psum and
+                # hvd.allreduce cannot see which values still vary (it
+                # would take local gradients for reduced ones): average
+                # here.
+                with jax.named_scope("hvd_grad_exchange"):
+                    grads = jax.tree.map(
+                        lambda g: lax.pmean(g, axis_name), grads)
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+            params = optax.apply_updates(params, updates)
+        with jax.named_scope("hvd_loss_report"):
+            out = jax.tree.map(lambda x: lax.pmean(x, axis_name), out)
+        return (params, opt_state) + (tuple(out) if has_aux else (out,))
 
     n_out = 4 if has_aux else 3
     # check_vma=False is needed for interpret-mode Pallas collectives on

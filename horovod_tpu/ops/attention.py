@@ -611,12 +611,14 @@ def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
 def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
                        k_offset, *, causal, block_q, block_k, rotate,
                        collective_id, axis_name, mesh_axes, interpret,
-                       scale_r=1.0, grad_dtype=jnp.float32, dq_scale=1.0):
+                       scale_r=1.0, grad_dtype=jnp.float32, dq_scale=1.0,
+                       name="hvd_flash_bwd"):
     """pallas_call wrapper for `_combined_bwd_kernel` over (bh, sl, d)
     operands (q pre-scaled by the pow2 part of sm_scale).  Returns
     (dk, dv, dq[, k_next, v_next]) with the gradients in ``grad_dtype``
     (accumulation is always f32 in scratch; only the flush casts, after
-    applying ``dq_scale`` to dq in f32)."""
+    applying ``dq_scale`` to dq in f32).  ``name`` is the kernel's name in
+    a device trace: the fused ring's backward step passes its own."""
     bh, sl, d = q.shape
     num_q, num_k = sl // block_q, sl // block_k
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
@@ -698,6 +700,7 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
         out_shape=out_shapes,
         compiler_params=compiler_params,
         interpret=interpret,
+        name=name,
     )(*args)
 
 
@@ -917,6 +920,7 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="hvd_flash_bwd_dkdv",
     )(q, do, lse8, delta8, k, v)
     dqk = functools.partial(
         _flash_bwd_dq_kernel, causal=causal, block_q=block_q,
@@ -931,6 +935,7 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
         out_shape=grad_shape,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="hvd_flash_bwd_dq",
     )(q, do, lse8, delta8, k, v)
     return dk, dv, dq
 
@@ -1057,6 +1062,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, d), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
+        name="hvd_flash_fwd",
     )(qr, kr, vr)
     return (out.reshape(batch, heads, q_len, d),
             lse[:, 0, :].reshape(batch, heads, q_len))

@@ -131,6 +131,7 @@ def _ring_permute_raw(x, axis_name, shift, interpret, phase):
             collective_id=_COLLECTIVE_IDS[phase % 4],
             has_side_effects=True),
         interpret=interpret,
+        name="hvd_rdma_permute",
     )(x)
 
 

@@ -184,7 +184,7 @@ def _bwd_ring_step(q, do, lse8, delta8, k_cur, v_cur, q_offset, k_offset, *,
         causal=causal, block_q=block_q, block_k=block_k, rotate=rotate,
         collective_id=_COLLECTIVE_IDS[phase % 2] if barrier else None,
         axis_name=axis_name, mesh_axes=_ambient_mesh_axes(axis_name),
-        interpret=interpret, scale_r=scale_r)
+        interpret=interpret, scale_r=scale_r, name="hvd_ring_flash_bwd")
     if rotate:
         dk, dv, dq, k_next, v_next = results
         return dk, dv, dq, k_next, v_next
@@ -270,6 +270,7 @@ def _ring_flash_step(q, k_cur, v_cur, q_offset, k_offset, *,
         out_shape=out_shapes,
         compiler_params=compiler_params,
         interpret=interpret,
+        name="hvd_ring_flash_fwd",
     )(*args)
     if rotate:
         out, lse, k_next, v_next = results
@@ -314,6 +315,7 @@ def _phase_closer(axis_name, after):
                                        vma=jax.typeof(after).vma),
         compiler_params=pltpu.CompilerParams(
             collective_id=_COLLECTIVE_IDS[1], has_side_effects=True),
+        name="hvd_ring_flash_closer",
     )(after)
     return zeros[0, 0]
 

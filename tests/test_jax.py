@@ -145,6 +145,79 @@ def test_train_step_with_aux(mesh):
     assert float(loss) > 0
 
 
+@pytest.mark.parametrize("has_aux", [False, True])
+@pytest.mark.parametrize("check_vma", [True, False])
+def test_train_step_names_its_phases(mesh, check_vma, has_aux):
+    """The lowered step carries the phase scopes a device trace is sorted
+    by: `hvd_loss` inside what is differentiated (so JAX's own name stack
+    marks the backward pass `transpose(jvp(hvd_loss))`), `hvd_optimizer`
+    with `hvd_grad_exchange` beneath it, `hvd_loss_report`."""
+    def loss_fn(w, batch):
+        x, y = batch
+        loss = jnp.mean((x @ w - y) ** 2)
+        return (loss, {"pred_mean": jnp.mean(x @ w)}) if has_aux else loss
+
+    xs = np.ones((NDEV * 2, 3), np.float32)
+    ys = np.ones(NDEV * 2, np.float32)
+    w0 = jnp.zeros(3, jnp.float32)
+    tx = optax.adam(1e-2)
+    step = build_train_step(loss_fn, tx, mesh, has_aux=has_aux,
+                            check_vma=check_vma)
+    text = step.lower(replicate(mesh, w0), replicate(mesh, tx.init(w0)),
+                      shard_batch(mesh, (xs, ys))).as_text(debug_info=True)
+    for scope in ("jvp(hvd_loss)", "transpose(jvp(hvd_loss))",
+                  "hvd_optimizer", "hvd_optimizer/hvd_grad_exchange",
+                  "hvd_loss_report"):
+        assert scope in text, scope
+
+
+def test_profiler_session_shows_the_librarys_spans(mesh, tmp_path):
+    """A jax.profiler session around a plain loop holds the library's own
+    step span, numbered by the proxy, and an application's hvd.trace_span,
+    on one plane: the clock the device's operations are on."""
+    import glob
+
+    import horovod_tpu.common as common
+
+    def loss_fn(w, batch):
+        x, y = batch
+        return jnp.mean((x @ w - y) ** 2)
+
+    xs = np.ones((NDEV * 2, 3), np.float32)
+    ys = np.ones(NDEV * 2, np.float32)
+    w0 = jnp.zeros(3, jnp.float32)
+    tx = optax.sgd(0.1)
+    step = build_train_step(loss_fn, tx, mesh)
+    params, opt_state = replicate(mesh, w0), replicate(mesh, tx.init(w0))
+    batch = shard_batch(mesh, (xs, ys))
+    params, opt_state, loss = step(params, opt_state, batch)   # compiles
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with common.trace_span("data_loading"):
+            batch = shard_batch(mesh, (xs, ys))
+        for _ in range(3):
+            params, opt_state, loss = step(params, opt_state, batch)
+        jax.block_until_ready(loss)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    profile = jax.profiler.ProfileData.from_file(path)
+    found = {}
+    for plane in profile.planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name in ("hvd.train_step", "data_loading"):
+                    found.setdefault(plane.name, []).append(
+                        (event.start_ns, event.name, dict(event.stats)))
+    assert len(found) == 1, sorted(found)
+    events = sorted(next(iter(found.values())))
+    assert [name for _, name, _ in events] == (["data_loading"]
+                                               + ["hvd.train_step"] * 3)
+    assert [stats["step_num"] for _, _, stats in events[1:]] == [1, 2, 3]
+
+
 def test_eager_collectives_size1(single_process_hvd):
     x = jnp.asarray(np.random.randn(3, 2).astype(np.float32))
     np.testing.assert_array_equal(

@@ -381,6 +381,102 @@ def test_jax_train_step_feeds_step_histogram(hvd_metrics):
     assert after == before + 1
 
 
+def _toy_step(loss_fn, mesh_devices=2):
+    jax = pytest.importorskip("jax")
+    optax = pytest.importorskip("optax")
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from horovod_tpu.jax.train import build_train_step
+
+    mesh = Mesh(np.array(jax.devices()[:mesh_devices]), ("hvd",))
+    tx = optax.sgd(0.1)
+    step = build_train_step(loss_fn, tx, mesh, axis_name="hvd")
+    params = jnp.ones((4,))
+    return step, (params, tx.init(params)), jnp.ones((mesh_devices, 4))
+
+
+def _under(hist, bound):
+    """Observations of a snapshot's histogram at or under `bound`."""
+    return sum(c for b, c in zip(hist["buckets"], hist["counts"])
+               if b <= bound)
+
+
+def test_step_histograms_count_every_step(hvd_metrics):
+    """n steps are n observations of the enqueue (step_dispatch_sec) and,
+    once the caller has waited for the last loss, n completed steps
+    (step_sec), whichever of the waiter and the snapshot saw them first."""
+    import jax.numpy as jnp
+
+    hvd = hvd_metrics
+    step, (params, opt_state), batch = _toy_step(
+        lambda p, b: jnp.mean((b @ p) ** 2))
+    for _ in range(7):
+        params, opt_state, loss = step(params, opt_state, batch)
+    float(loss)
+    hists = hvd.metrics_snapshot()["histograms"]
+    assert hists["step_dispatch_sec"]["count"] == 7
+    assert hists["step_sec"]["count"] == 7
+
+
+def test_step_sec_is_the_step_not_the_enqueue(hvd_metrics):
+    """A loop that runs ahead of a step slowed to 20 ms: step_sec reports
+    the 20 ms, step_dispatch_sec the enqueue."""
+    import time
+
+    import jax
+
+    hvd = hvd_metrics
+
+    def nap(x):
+        time.sleep(0.02)
+        return x
+
+    def loss_fn(params, batch):
+        loss = ((batch @ params) ** 2).mean()
+        # Outside what is differentiated: a callback has no JVP.
+        napped = jax.pure_callback(
+            nap, jax.ShapeDtypeStruct((), loss.dtype,
+                                      vma=jax.typeof(loss).vma),
+            jax.lax.stop_gradient(loss))
+        return loss + 0.0 * napped
+
+    step, (params, opt_state), batch = _toy_step(loss_fn)
+    params, opt_state, loss = step(params, opt_state, batch)    # compiles
+    float(loss)
+    hvd.metrics_reset()
+    for _ in range(6):
+        params, opt_state, loss = step(params, opt_state, batch)
+    float(loss)
+    hists = hvd.metrics_snapshot()["histograms"]
+    assert hists["step_sec"]["count"] == 6
+    assert _under(hists["step_sec"], 0.01) == 0        # no step under 20 ms
+    assert hists["step_sec"]["sum"] >= 6 * 0.02
+    assert hists["step_dispatch_sec"]["count"] == 6
+    assert _under(hists["step_dispatch_sec"], 0.01) >= 3
+
+
+def test_step_completions_idle_while_metrics_are_off(monkeypatch):
+    """With the registry off a step call starts no thread and queues
+    nothing: the proxy reads two flags and enters a disabled annotation."""
+    import jax.numpy as jnp
+
+    from horovod_tpu.common import metrics
+    from horovod_tpu.jax import train
+
+    assert not metrics.registry.enabled
+    fresh = train._StepCompletions()
+    monkeypatch.setattr(train, "_completions", fresh)
+    step, (params, opt_state), batch = _toy_step(
+        lambda p, b: jnp.mean((b @ p) ** 2))
+    for _ in range(3):
+        params, opt_state, loss = step(params, opt_state, batch)
+    float(loss)
+    assert fresh._thread is None and not fresh._pending
+    assert metrics.registry.snapshot()["histograms"]["step_sec"][
+        "count"] == 0
+
+
 def test_skew_section_and_prometheus_families():
     """Straggler attribution plumbing: record_last_announce feeds the
     snapshot's ungated "skew" section and the last_to_announce /

@@ -356,6 +356,77 @@ def test_flash_inside_shard_map_default_vma_check(monkeypatch, mode):
         np.testing.assert_allclose(a, b, atol=1e-3, rtol=1e-3)
 
 
+def _pallas_call_names(jaxpr):
+    """The `name` of every pallas_call equation, sub-programs included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _pallas_call_names(sub)
+    return names
+
+
+def _flash_program(grad, plan=None):
+    q, k, v = _qkv(batch=1, heads=2, seq=256, d=32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=128,
+                               block_k=128).sum()
+
+    return jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1, 2)) if grad else loss)(q, k, v)
+
+
+def _ring_program(grad, impl="fused"):
+    mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))
+    q, k, v = _qkv(batch=1, heads=2, seq=4 * 128, d=16)
+    spec = P(None, None, "sp", None)
+    fn = functools.partial(ring_attention, axis_name="sp", causal=True,
+                           rotate_impl=impl)
+
+    def loss(q, k, v):
+        return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v).sum()
+
+    return jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1, 2)) if grad else loss)(q, k, v)
+
+
+# PERF.md's table of kernel names, a case a pallas_call site: the public
+# function whose program holds the call, the backward plan where one picks
+# the site, and the names a device trace will show.
+@pytest.mark.parametrize("build,plan,names", [
+    (lambda: _flash_program(grad=False), None, {"hvd_flash_fwd"}),
+    (lambda: _flash_program(grad=True), "combined",
+     {"hvd_flash_fwd", "hvd_flash_bwd"}),
+    (lambda: _flash_program(grad=True), "split",
+     {"hvd_flash_fwd", "hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"}),
+    (lambda: _ring_program(grad=False), None,
+     {"hvd_ring_flash_fwd", "hvd_ring_flash_closer"}),
+    (lambda: _ring_program(grad=True), None,
+     {"hvd_ring_flash_fwd", "hvd_ring_flash_bwd",
+      "hvd_ring_flash_closer"}),
+    (lambda: _ring_program(grad=False, impl="rdma"), None,
+     {"hvd_rdma_permute"}),
+], ids=["flash_fwd", "flash_bwd_combined", "flash_bwd_split",
+        "ring_flash_fwd_and_closer", "ring_flash_bwd", "rdma_permute"])
+def test_pallas_calls_are_named(monkeypatch, build, plan, names):
+    """Every pallas_call of ops/ names its kernel: the name reaches the
+    operation's scope path and Mosaic's kernel_name, which is how a device
+    trace tells the forward flash kernel from the backward."""
+    import horovod_tpu.ops.attention as attn
+
+    if plan is not None:
+        monkeypatch.setattr(attn, "_bwd_plan",
+                            lambda q_len, d, bq, bk, bh=1: (plan, 128, 128))
+    # The ring's barrier-only closer exists only in the compiled form;
+    # nothing is lowered here, so steer the backend query as
+    # test_ring_variants_compile_on_mesh does.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert set(_pallas_call_names(build().jaxpr)) == names
+
+
 def test_flash_split_backward_matches(monkeypatch):
     """The split dkdv/dq kernel pair (long-seq path) must match the
     blockwise gradients — forced via the plan so it runs at test sizes."""
