@@ -22,6 +22,18 @@ from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              ring_attention)
 
 
+def _reduced_to_vma_of(primal, cotangent):
+    """``cotangent`` summed over the mapped axes it varies over and
+    ``primal`` does not.  A custom_vjp must return cotangents whose varying
+    axes MATCH the primal's — the psum plain autodiff would insert is the
+    backward rule's job."""
+    extra = jax.typeof(cotangent).vma - jax.typeof(primal).vma
+    if not extra:
+        return cotangent
+    # sorted: stable axis order -> stable jaxpr/compile cache
+    return lax.psum(cotangent, tuple(sorted(extra)))
+
+
 @jax.custom_vjp
 def _qkv_project(x, w):
     """Fused qkv projection returning the UNSTACKED (q, k, v) triple.
@@ -48,16 +60,8 @@ def _qkv_project_bwd(res, cots):
                    axis=1)  # (d, 3, h, e): params-sized, cheap to stack
     # Under shard_map the cotangents vary over the mapped axes while the
     # primal inputs may be replicated (w always is; x can be, e.g. when
-    # only the batch is mapped elsewhere).  A custom_vjp must return
-    # cotangents whose varying axes MATCH the primal's — the psum plain
-    # autodiff would insert is our job here.
-    extra_w = jax.typeof(dw).vma - jax.typeof(w).vma
-    if extra_w:  # sorted: stable axis order -> stable jaxpr/compile cache
-        dw = lax.psum(dw, tuple(sorted(extra_w)))
-    extra_x = jax.typeof(dx).vma - jax.typeof(x).vma
-    if extra_x:
-        dx = lax.psum(dx, tuple(sorted(extra_x)))
-    return dx, dw
+    # only the batch is mapped elsewhere).
+    return _reduced_to_vma_of(x, dx), _reduced_to_vma_of(w, dw)
 
 
 _qkv_project.defvjp(_qkv_project_fwd, _qkv_project_bwd)
@@ -536,6 +540,38 @@ def fused_next_token_loss(hidden, w, targets, dtype=jnp.bfloat16,
     return total / tokens
 
 
+@jax.custom_vjp
+def _token_xent(logits, targets):
+    """Per-token softmax cross-entropy, float32 internals over the logits
+    as stored; see :func:`next_token_loss` for what its backward keeps."""
+    return _token_xent_fwd(logits, targets)[0]
+
+
+def _token_xent_fwd(logits, targets):
+    row_max = logits.max(axis=-1).astype(jnp.float32)
+    sum_exp = jnp.exp(logits.astype(jnp.float32)
+                      - row_max[..., None]).sum(axis=-1)
+    # Gathered from the STORED logits and cast after: a gather from the
+    # float32 cast makes XLA write that cast to HBM.
+    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    loss = jnp.log(sum_exp) - (picked.astype(jnp.float32) - row_max)
+    return loss, (logits, targets, row_max, sum_exp)
+
+
+def _token_xent_bwd(res, g):
+    logits, targets, row_max, sum_exp = res
+    classes = lax.broadcasted_iota(targets.dtype, logits.shape,
+                                   logits.ndim - 1)
+    exp = jnp.exp(logits.astype(jnp.float32) - row_max[..., None])
+    d_logits = (exp * (g / sum_exp)[..., None]
+                - jnp.where(classes == targets[..., None], g[..., None], 0.0)
+                ).astype(logits.dtype)
+    return _reduced_to_vma_of(logits, d_logits), None
+
+
+_token_xent.defvjp(_token_xent_fwd, _token_xent_bwd)
+
+
 def next_token_loss(logits, targets, mask=None, axis_name=None):
     """Mean cross-entropy of ``logits`` against aligned ``targets``.
 
@@ -547,13 +583,24 @@ def next_token_loss(logits, targets, mask=None, axis_name=None):
     of valid tokens, so the local sum is normalized by the *global mean*
     token count per shard — the subsequent `pmean` then reproduces the
     exact global weighted mean instead of over-weighting padded shards.
-    """
-    import optax
 
-    # f32 internals regardless of logits storage dtype (bf16-stored
-    # logits ride a convert that XLA fuses into the reductions).
-    loss = optax.softmax_cross_entropy_with_integer_labels(
-        logits.astype(jnp.float32), targets)
+    The softmax is float32 inside whatever dtype the logits are stored in,
+    and the per-token cross-entropy has its own backward pass
+    (``jax.custom_vjp``).  It keeps the logits AS STORED, the targets, and
+    each token's maximum and sum of exponentials in float32, recomputes the
+    exponentials from them, and writes the logits' cotangent in the logits'
+    dtype (the one rounding autodiff's transpose of ``.astype`` makes) — the
+    arithmetic of plain autodiff through optax's cross-entropy, step for
+    step.  Autodiff itself keeps float32 tensors of the logits' size for
+    the backward pass, which XLA writes out to HBM (4 GB of a 13 GB step at
+    8,192 tokens x 50,304 classes, PERF.md).  The target's logit is gathered
+    from the stored logits and cast after: gathered from the float32 cast,
+    XLA materialises that cast.  A ``custom_vjp`` has no forward mode:
+    ``jax.jvp`` / ``jacfwd`` / ``hessian`` of this loss raise (nothing in
+    ``horovod_tpu/``, ``examples/`` or ``tests/`` takes them).
+    """
+    with jax.named_scope("hvd_token_xent"):
+        loss = _token_xent(logits, targets)
     if mask is None:
         return loss.mean()
     mask = mask.astype(loss.dtype)

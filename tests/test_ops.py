@@ -325,6 +325,59 @@ def test_ring_variants_compile_on_mesh(v5e, monkeypatch, impl):
         assert ids == ["15", "16"] * 4, ids
 
 
+def _written_float32_elements(text):
+    """Element counts of the float32 arrays that instructions OUTSIDE fused
+    computations yield in a compiled program's text: what is written to
+    memory, where a fusion's body holds values that never leave the core."""
+    import math
+    import re
+
+    fused = set(re.findall(r"\bfusion\(.*calls=%([\w.\-]+)", text))
+    counts, skipping = [], False
+    for line in text.splitlines():
+        opened = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if opened:
+            skipping = opened.group(1) in fused
+        elif not skipping and " = " in line:
+            yielded = line.split(" = ", 1)[1].split(", metadata=")[0]
+            counts += [math.prod(map(int, dims.split(",")))
+                       for dims in re.findall(r"\bf32\[([\d,]+)\]", yielded)]
+    return counts
+
+
+def test_lm_loss_keeps_no_float32_logits(v5e):
+    """The gradient of a small TransformerLM under next_token_loss, bf16
+    logits of 2,048 tokens x 8,192 classes, compiled for the described
+    chip, writes no float32 array of the logits' size: the softmax is
+    float32 inside fusions only (plain autodiff of a cross-entropy on
+    ``logits.astype(float32)`` wrote that copy out for its backward)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.models import TransformerLM, next_token_loss
+
+    tokens, vocab = 2048, 8192
+    model = TransformerLM(vocab_size=vocab, d_model=128, n_layers=1,
+                          n_heads=2, d_ff=256, dtype=jnp.bfloat16,
+                          logits_dtype=jnp.bfloat16, use_flash=False)
+    shape = jax.ShapeDtypeStruct((1, tokens), jnp.int32)
+    params = jax.eval_shape(
+        lambda t: model.init(jax.random.PRNGKey(0), t)["params"], shape)
+    on_chip = SingleDeviceSharding(v5e[0])
+    params, inputs = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=on_chip),
+        (params, shape))
+
+    def loss(params, inputs, targets):
+        return next_token_loss(model.apply({"params": params}, inputs),
+                               targets)
+
+    text = jax.jit(jax.grad(loss)).lower(params, inputs,
+                                         inputs).compile().as_text()
+    written = _written_float32_elements(text)
+    assert written, "the text's float32 arrays were not found"
+    assert tokens * vocab not in written
+
+
 @pytest.mark.parametrize("mode", ["combined", "split"])
 def test_flash_inside_shard_map_default_vma_check(monkeypatch, mode):
     """flash_attention is called inside build_train_step's shard_map, whose

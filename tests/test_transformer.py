@@ -366,3 +366,100 @@ def test_qkv_project_custom_vjp_matches_autodiff():
     g_r = jax.grad(loss_ref, argnums=(0, 1))(x, w)
     for a, b in zip(g_c, g_r):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+# --- next_token_loss: a cross-entropy with its own backward pass ----------
+# against optax's, which plain autodiff differentiates.
+
+XENT_SHAPE, XENT_VOCAB = (4, 64), 512
+
+
+def _xent_case(dtype, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    logits = (3.0 * jax.random.normal(keys[0], (*XENT_SHAPE, XENT_VOCAB))
+              ).astype(dtype)
+    targets = jax.random.randint(keys[1], XENT_SHAPE, 0, XENT_VOCAB)
+    mask = jax.random.uniform(keys[2], XENT_SHAPE) > 0.3
+    return logits, targets, mask
+
+
+def _optax_loss(logits, targets, mask=None):
+    loss = optax.softmax_cross_entropy_with_integer_labels(
+        logits.astype(jnp.float32), targets)
+    if mask is None:
+        return loss.mean()
+    mask = mask.astype(loss.dtype)
+    return (loss * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+
+
+def _assert_within_ulps(got, want, units=1):
+    """Element by element, ``units`` in the last place of ``want``'s dtype,
+    which is ``got``'s too."""
+    assert got.dtype == want.dtype and got.shape == want.shape
+    eps = float(jnp.finfo(want.dtype).eps)
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    ulp = np.ldexp(eps, np.frexp(np.abs(want))[1] - 1)
+    worst = np.max(np.abs(got - want) / ulp)
+    assert worst <= units, f"{worst} units in the last place"
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "mask"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_next_token_loss_matches_optax(dtype, masked):
+    """Loss and gradient of next_token_loss equal optax's cross-entropy
+    under plain autodiff, and the logits' cotangent comes back in the
+    logits' own dtype."""
+    logits, targets, mask = _xent_case(dtype)
+    mask = mask if masked else None
+    loss, grad = jax.value_and_grad(next_token_loss)(logits, targets, mask)
+    want_loss, want_grad = jax.value_and_grad(_optax_loss)(logits, targets,
+                                                           mask)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+    assert grad.dtype == logits.dtype
+    _assert_within_ulps(grad, want_grad)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_next_token_loss_masked_under_shard_map(dtype):
+    """The masked loss with ``axis_name`` inside shard_map (dp x sp, the
+    padding unevenly spread over the shards): the pmean of the shards'
+    losses and its gradient equal the global weighted mean's."""
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("dp", "sp"))
+    logits, targets, mask = _xent_case(dtype, seed=1)
+    mask = mask.at[:, 48:].set(False)  # the last sp shard is all padding
+    spec = P("dp", "sp")
+
+    def sharded(logits, targets, mask):
+        loss = next_token_loss(logits, targets, mask,
+                               axis_name=("dp", "sp"))
+        return jax.lax.pmean(loss, ("dp", "sp"))
+
+    fn = jax.jit(jax.value_and_grad(shard_map(
+        sharded, mesh=mesh, in_specs=(spec, spec, spec), out_specs=P())))
+    loss, grad = fn(logits, targets, mask)
+    want_loss, want_grad = jax.value_and_grad(_optax_loss)(logits, targets,
+                                                           mask)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+    # 1 / (count / 8) / 8 is not 1 / count to the last bit: two units.
+    _assert_within_ulps(grad, want_grad, units=2)
+
+
+def test_next_token_loss_replicated_logits_under_shard_map():
+    """Logits replicated over a mapped axis against targets that vary over
+    it: the hand-written backward pass sums the cotangent over that axis,
+    as plain autodiff does."""
+    mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
+    logits, targets, _ = _xent_case(jnp.float32, seed=2)
+    targets = jnp.stack([(targets + i) % XENT_VOCAB for i in range(4)])
+
+    def sharded(logits, targets):
+        return jax.lax.pmean(next_token_loss(logits, targets[0]), "dp")
+
+    grad = jax.jit(jax.grad(shard_map(
+        sharded, mesh=mesh, in_specs=(P(), P("dp")), out_specs=P())))(
+            logits, targets)
+    want = jax.grad(lambda l: jnp.mean(jnp.stack(
+        [_optax_loss(l, t) for t in targets])))(logits)
+    np.testing.assert_allclose(grad, want, rtol=1e-5, atol=1e-9)
