@@ -10,9 +10,16 @@ Run on a pod (or simulate 8 devices on CPU):
     XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
         python examples/jax_transformer_lm.py --dp 2 --sp 4 \
         --seq-len 512 --d-model 64 --n-layers 2 --steps 10
+
+The same lines train a sparse-expert model: `--olmoe-config` takes a
+Hugging Face OLMoE `config.json` (or the benchmark's
+benchmark/configs/olmoe1b7b.json, whose `expert_shard` and `row_bound` it
+honours) and builds the same TransformerLM with `moe=`, `qk_norm=True` and
+the config's widths; the loss gains the router's two terms.
 """
 
 import argparse
+import json
 import time
 
 from horovod_tpu.common.compile_cache import place_compile_cache
@@ -26,7 +33,8 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu.jax.train import build_train_step
-from horovod_tpu.models import TransformerLM, next_token_loss
+from horovod_tpu.models import (MoEConfig, TransformerLM,
+                                moe_next_token_loss, next_token_loss)
 from horovod_tpu.parallel import replicate
 
 parser = argparse.ArgumentParser(description="Sequence-parallel LM example")
@@ -46,6 +54,10 @@ parser.add_argument("--ring-impl", default="ppermute",
                          "Pallas remote DMA, or the fused ring-flash "
                          "kernel (DMA overlapped inside the attention "
                          "program)")
+parser.add_argument("--olmoe-config", default=None,
+                    help="an OLMoE config.json: its widths, depth, vocabulary "
+                         "and experts replace --vocab/--d-model/--n-layers/"
+                         "--n-heads")
 parser.add_argument("--steps", type=int, default=30)
 parser.add_argument("--lr", type=float, default=3e-4)
 args = parser.parse_args()
@@ -60,9 +72,21 @@ def main():
                 ("dp", "sp"))
     print(f"mesh: dp={dp} x sp={sp}, seq/device = {args.seq_len // sp}")
 
-    model = TransformerLM(vocab_size=args.vocab, d_model=args.d_model,
-                          n_layers=args.n_layers, n_heads=args.n_heads,
-                          seq_axis="sp", ring_impl=args.ring_impl)
+    shape = dict(vocab_size=args.vocab, d_model=args.d_model,
+                 n_layers=args.n_layers, n_heads=args.n_heads)
+    if args.olmoe_config:
+        with open(args.olmoe_config) as f:
+            c = json.load(f)
+        shape = dict(
+            vocab_size=c["vocab_size"], d_model=c["hidden_size"],
+            n_layers=c["num_hidden_layers"], n_heads=c["num_attention_heads"],
+            qk_norm=True, norm_eps=c["rms_norm_eps"],
+            moe=MoEConfig(c["num_experts"], c["num_experts_per_tok"],
+                          c["intermediate_size"],
+                          tuple(c.get("expert_shard", (0, 1))),
+                          c.get("row_bound")))
+    args.vocab = shape["vocab_size"]
+    model = TransformerLM(**shape, seq_axis="sp", ring_impl=args.ring_impl)
 
     # A tiny synthetic corpus with learnable structure (token t+1 depends
     # on token t), deterministic across hosts.
@@ -78,15 +102,20 @@ def main():
     targets = np.pad(targets, ((0, 0), (0, pad)))
     mask = np.pad(np.ones((args.batch, args.seq_len)), ((0, 0), (0, pad)))
 
-    params = TransformerLM(
-        vocab_size=args.vocab, d_model=args.d_model,
-        n_layers=args.n_layers, n_heads=args.n_heads).init(
+    params = TransformerLM(**shape).init(
         jax.random.PRNGKey(0), jnp.asarray(inputs[:1, :64]))["params"]
 
     def loss_fn(params, batch):
         inp, tgt, msk = batch
-        logits = model.apply({"params": params}, inp)
-        return next_token_loss(logits, tgt, msk, axis_name=("dp", "sp"))
+        if model.moe is None:
+            logits = model.apply({"params": params}, inp)
+            return next_token_loss(logits, tgt, msk, axis_name=("dp", "sp"))
+        # The sparse-expert layers write their router statistics to the
+        # `router` collection; the loss adds the load-balancing and z terms.
+        logits, wrote = model.apply({"params": params}, inp,
+                                    mutable=["router"])
+        return moe_next_token_loss(logits, tgt, wrote["router"], mask=msk,
+                                   axis_name=("dp", "sp"))
 
     tx = optax.adamw(args.lr)
     spec = P("dp", "sp")

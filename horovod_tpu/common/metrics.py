@@ -323,6 +323,11 @@ class MetricsRegistry:
             "ckpt": {**{e: 0 for e in STATE_CKPT_EVENTS},
                      "shard_bytes": 0},
         }
+        # Sparse-expert layers (models.SparseExperts): rows each layer's
+        # router last sent each local expert, and the rows a bounded buffer
+        # left out since the last reset (gauges a caller mirrors in from the
+        # model's `intermediates`; models.record_expert_rows).
+        self._moe = {"rows_per_local_expert": [], "rows_over_bound": 0}
         self._hists = {name: Histogram(bounds)
                        for name, (bounds, _) in HISTOGRAMS.items()}
 
@@ -401,6 +406,16 @@ class MetricsRegistry:
         idempotent overwrite, like the autotune mirror).  Ungated."""
         with self._lock:
             self._membership = dict(state)
+
+    def set_moe_rows(self, rows_per_local_expert, rows_over_bound: int
+                     ) -> None:
+        """Mirror one forward pass's sparse-expert counters: the rows of
+        every layer's local experts (overwritten) and the rows over the
+        buffer's bound (added up: one is one too many)."""
+        with self._lock:
+            self._moe["rows_per_local_expert"] = [
+                [int(n) for n in layer] for layer in rows_per_local_expert]
+            self._moe["rows_over_bound"] += int(rows_over_bound)
 
     def set_flight(self, state: dict) -> None:
         """Mirror the flight recorders' state (a state copy — idempotent
@@ -742,6 +757,12 @@ class MetricsRegistry:
                     "events": dict(self._flight["events"]),
                     "capacity": self._flight["capacity"],
                 },
+                "moe": {
+                    "rows_per_local_expert": [
+                        list(layer) for layer in
+                        self._moe["rows_per_local_expert"]],
+                    "rows_over_bound": self._moe["rows_over_bound"],
+                },
                 "compression": {
                     "mode": self._compression["mode"],
                     "min_bytes": self._compression["min_bytes"],
@@ -903,6 +924,20 @@ def prometheus_text(snapshot: dict) -> str:
     for plane, per_kind in cache.items():
         out.append(f'hvd_tpu_response_cache_size{{plane="{plane}"}} '
                    f'{per_kind.get("size", 0)}')
+
+    moe = snapshot.get("moe", {})
+    out.append("# HELP hvd_tpu_moe_expert_rows rows the router last sent "
+               "each local expert of each sparse-expert layer")
+    out.append("# TYPE hvd_tpu_moe_expert_rows gauge")
+    for layer, rows in enumerate(moe.get("rows_per_local_expert", [])):
+        for expert, n in enumerate(rows):
+            out.append(f'hvd_tpu_moe_expert_rows{{layer="{layer}",'
+                       f'expert="{expert}"}} {n}')
+    out.append("# HELP hvd_tpu_moe_rows_over_bound_total rows routed to a "
+               "local expert that a bounded buffer left out")
+    out.append("# TYPE hvd_tpu_moe_rows_over_bound_total counter")
+    out.append("hvd_tpu_moe_rows_over_bound_total "
+               f"{moe.get('rows_over_bound', 0)}")
 
     tune = snapshot.get("autotune", {})
     out.append("# HELP hvd_tpu_autotune_enabled "

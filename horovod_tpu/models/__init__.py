@@ -22,8 +22,12 @@ from horovod_tpu.models.resnet import (  # noqa: F401
 )
 from horovod_tpu.models.transformer import (  # noqa: F401
     DecodeContext,
+    MoEConfig,
     TransformerLM,
+    moe_next_token_loss,
     next_token_loss,
+    record_expert_rows,
+    router_losses,
 )
 from horovod_tpu.models.vgg import VGG11, VGG16, VGG19  # noqa: F401
 from horovod_tpu.models.inception import InceptionV3  # noqa: F401

@@ -11,7 +11,7 @@ scales linearly with the ring size.
 
 from __future__ import annotations
 
-from typing import Any, NamedTuple, Optional
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -20,18 +20,8 @@ from jax import lax
 
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              ring_attention)
-
-
-def _reduced_to_vma_of(primal, cotangent):
-    """``cotangent`` summed over the mapped axes it varies over and
-    ``primal`` does not.  A custom_vjp must return cotangents whose varying
-    axes MATCH the primal's — the psum plain autodiff would insert is the
-    backward rule's job."""
-    extra = jax.typeof(cotangent).vma - jax.typeof(primal).vma
-    if not extra:
-        return cotangent
-    # sorted: stable axis order -> stable jaxpr/compile cache
-    return lax.psum(cotangent, tuple(sorted(extra)))
+from horovod_tpu.ops.moe import (dispatch_rows, grouped_matmul,
+                                 reduced_to_vma_of)
 
 
 @jax.custom_vjp
@@ -61,7 +51,7 @@ def _qkv_project_bwd(res, cots):
     # Under shard_map the cotangents vary over the mapped axes while the
     # primal inputs may be replicated (w always is; x can be, e.g. when
     # only the batch is mapped elsewhere).
-    return _reduced_to_vma_of(x, dx), _reduced_to_vma_of(w, dw)
+    return reduced_to_vma_of(x, dx), reduced_to_vma_of(w, dw)
 
 
 _qkv_project.defvjp(_qkv_project_fwd, _qkv_project_bwd)
@@ -159,6 +149,118 @@ def _decode_attention(q, k, v, mask, sm_scale):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
+
+class MoEConfig(NamedTuple):
+    """A sparse-expert MLP in place of the dense one (``TransformerLM(moe=)``).
+
+    ``num_experts`` experts of width ``expert_width`` (gated: ``down(silu(gate
+    x) * up x)``), ``experts_per_token`` of them per token, weighted by the
+    router's float32 softmax over ALL experts as it stands (not renormalised
+    over the chosen ones), no shared expert, no capacity factor.
+
+    ``expert_shard=(i, n)``: this process holds experts ``[i*E/n, (i+1)*E/n)``
+    and computes their part of the layer for the tokens routed to them — the
+    local middle of an expert-parallel layer, whose two all-to-alls are the
+    caller's; ``(0, 1)`` is the whole layer.  The router keeps its full width
+    on every shard, and the n shards' outputs sum to the whole layer's.
+
+    ``row_bound``: rows the sorted buffer holds, as a multiple of the
+    ``tokens * experts_per_token / n`` a balanced router sends this shard
+    (rounded up to 512 rows, at most every pair).  ``None``: every pair, so
+    nothing can fall outside.  Rows over a bound are not computed and are
+    COUNTED (``rows_over_bound`` in the ``intermediates`` collection): a
+    caller that sets a bound checks that count."""
+
+    num_experts: int
+    experts_per_token: int
+    expert_width: int
+    expert_shard: Tuple[int, int] = (0, 1)
+    row_bound: Optional[float] = None
+
+    def buffer_rows(self, tokens: int) -> int:
+        """Rows of the sorted buffer for ``tokens`` tokens."""
+        pairs = tokens * self.experts_per_token
+        if self.row_bound is None:
+            return pairs
+        balanced = self.row_bound * pairs / self.expert_shard[1]
+        return min(pairs, -(-int(balanced) // 512) * 512)
+
+
+class SparseExperts(nn.Module):
+    """Router, dispatch, grouped expert matmuls and combine of one layer
+    (``MoEConfig``), each under a ``jax.named_scope`` a trace can read.
+
+    Writes, where the caller makes the collection mutable: ``router`` —
+    ``choices`` (pairs per expert, all experts), ``prob_sum``, ``z_sum``,
+    ``tokens``: what :func:`router_losses` reads; ``intermediates`` —
+    ``chosen_experts`` (tokens, k), ``rows_per_local_expert``,
+    ``rows_over_bound``."""
+
+    config: MoEConfig
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        shard, n_shards = cfg.expert_shard
+        if cfg.num_experts % n_shards or not 0 <= shard < n_shards:
+            raise ValueError(f"expert_shard {cfg.expert_shard} does not "
+                             f"divide {cfg.num_experts} experts")
+        local = cfg.num_experts // n_shards
+        d, k = x.shape[-1], cfg.experts_per_token
+        flat = x.reshape(-1, d).astype(self.dtype)
+        tokens = flat.shape[0]
+        bound = cfg.buffer_rows(tokens)
+
+        w_router = self.param("router_kernel", nn.initializers.lecun_normal(),
+                              (d, cfg.num_experts), jnp.float32)
+        fan_in = nn.initializers.lecun_normal(in_axis=1, out_axis=2,
+                                              batch_axis=0)
+        w_gate = self.param("gate_kernel", fan_in,
+                            (local, d, cfg.expert_width), jnp.float32)
+        w_up = self.param("up_kernel", fan_in,
+                          (local, d, cfg.expert_width), jnp.float32)
+        w_down = self.param("down_kernel", fan_in,
+                            (local, cfg.expert_width, d), jnp.float32)
+
+        with jax.named_scope("hvd_moe_router"):
+            logits = jnp.dot(flat, w_router.astype(self.dtype),
+                             preferred_element_type=jnp.float32)
+            probs = jax.nn.softmax(logits, axis=-1)
+            weight, expert = lax.top_k(probs, k)
+            self.sow("intermediates", "chosen_experts", expert)
+            choices = (expert[..., None] == jnp.arange(cfg.num_experts)
+                       ).sum(axis=(0, 1), dtype=jnp.float32)
+            self.sow("router", "choices", choices)
+            self.sow("router", "prob_sum", probs.sum(axis=0))
+            self.sow("router", "z_sum",
+                     jnp.square(jax.nn.logsumexp(logits, axis=-1)).sum())
+            self.sow("router", "tokens", jnp.float32(tokens))
+        with jax.named_scope("hvd_moe_dispatch"):
+            sent = dispatch_rows(expert.reshape(-1), shard * local, local,
+                                 bound)
+            token_of_row = sent.pair // k
+            rows = flat[token_of_row]
+            self.sow("intermediates", "rows_per_local_expert",
+                     sent.rows_per_expert)
+            self.sow("intermediates", "rows_over_bound",
+                     sent.rows_over_bound)
+        with jax.named_scope("hvd_moe_experts"):
+            sizes = sent.group_sizes
+            gate = grouped_matmul(rows, w_gate.astype(self.dtype), sizes)
+            up = grouped_matmul(rows, w_up.astype(self.dtype), sizes)
+            out = grouped_matmul(nn.silu(gate) * up,
+                                 w_down.astype(self.dtype), sizes)
+        with jax.named_scope("hvd_moe_combine"):
+            # Rows past the last group are zero (grouped_matmul), so the
+            # weights of pairs held elsewhere multiply nothing.
+            weighted = out.astype(jnp.float32) \
+                * weight.reshape(-1)[sent.pair][:, None]
+            mixed = jnp.zeros((tokens, d), jnp.float32).at[
+                token_of_row].add(weighted)
+        return mixed.astype(self.dtype).reshape(x.shape)
+
+
 class Attention(nn.Module):
     n_heads: int
     dtype: Any = jnp.bfloat16
@@ -172,6 +274,10 @@ class Attention(nn.Module):
     # collection: the sharded ring-prefill path reads them back to fill
     # the serving plane's KV cache (serving/prefill.py).
     capture_kv: bool = False
+    # RMSNorm with a learned scale over the WHOLE d_model-wide q and k
+    # projections, before the split into heads and the rotation (OLMoE).
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x, decode_ctx=None):
@@ -192,6 +298,9 @@ class Attention(nn.Module):
                                w_qkv.astype(self.dtype))
         # (b, heads, seq, head_dim) each; custom VJP avoids the
         # activation-sized cotangent stack the sliced einsum would build.
+        if self.qk_norm:
+            q = self._projection_norm("q_norm_scale", q)
+            k = self._projection_norm("k_norm_scale", k)
 
         new_kv = None
         if decode_ctx is not None:
@@ -231,6 +340,16 @@ class Attention(nn.Module):
         proj = jnp.einsum("bhse,hed->bsd", out, w_o.astype(self.dtype))
         return proj if new_kv is None else (proj, new_kv)
 
+    def _projection_norm(self, name, t):
+        """RMSNorm over heads and head_dim together of ``t`` (b, heads, seq,
+        head_dim), float32 inside; the scale is (heads, head_dim)."""
+        scale = self.param(name, nn.initializers.ones,
+                           (t.shape[1], t.shape[3]), jnp.float32)
+        wide = t.astype(jnp.float32)
+        mean_sq = jnp.mean(jnp.square(wide), axis=(1, 3), keepdims=True)
+        return (wide * lax.rsqrt(mean_sq + self.norm_eps)
+                * scale[:, None, :]).astype(t.dtype)
+
 
 class Block(nn.Module):
     n_heads: int
@@ -240,25 +359,33 @@ class Block(nn.Module):
     use_flash: bool = True
     ring_impl: str = "ppermute"
     capture_kv: bool = False
+    moe: Optional[MoEConfig] = None  # sparse experts instead of up/down
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x, decode_ctx=None):
-        h = nn.RMSNorm(dtype=self.dtype, name="attn_norm")(x)
+        h = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                       name="attn_norm")(x)
         attn = Attention(self.n_heads, self.dtype, self.seq_axis,
                          self.use_flash, self.ring_impl, self.capture_kv,
-                         name="attn")
+                         self.qk_norm, self.norm_eps, name="attn")
         new_kv = None
         if decode_ctx is None:
             x = x + attn(h)
         else:
             a, new_kv = attn(h, decode_ctx)
             x = x + a
-        h = nn.RMSNorm(dtype=self.dtype, name="mlp_norm")(x)
-        h = nn.Dense(self.d_ff, use_bias=False, dtype=self.dtype,
-                     name="up")(h)
-        h = nn.gelu(h)
-        h = nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
-                     name="down")(h)
+        h = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                       name="mlp_norm")(x)
+        if self.moe is not None:
+            h = SparseExperts(self.moe, self.dtype, name="moe")(h)
+        else:
+            h = nn.Dense(self.d_ff, use_bias=False, dtype=self.dtype,
+                         name="up")(h)
+            h = nn.gelu(h)
+            h = nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
+                         name="down")(h)
         x = x + h
         return x if new_kv is None else (x, new_kv)
 
@@ -286,6 +413,15 @@ class TransformerLM(nn.Module):
     # each logit (~0.4% relative); measured +9% tokens/s on v5e
     # (docs/benchmarks.md round-4 log).
     logits_dtype: Any = jnp.float32
+    # Every layer's MLP as sparse experts (MoEConfig; d_ff is then unused),
+    # RMSNorm of the whole q and k projections, and the epsilon of every
+    # RMSNorm.  The defaults are the dense block above, parameter for
+    # parameter.  With ``moe`` the router's statistics are written to the
+    # ``router`` collection: apply with ``mutable=["router"]`` and hand that
+    # collection to :func:`moe_next_token_loss`.
+    moe: Optional[MoEConfig] = None
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
 
     @nn.compact
     def __call__(self, tokens, targets=None, decode_ctx=None):
@@ -308,6 +444,7 @@ class TransformerLM(nn.Module):
         for i in range(self.n_layers):
             block = Block(self.n_heads, d_ff, self.dtype, self.seq_axis,
                           self.use_flash, self.ring_impl, self.capture_kv,
+                          self.moe, self.qk_norm, self.norm_eps,
                           name=f"layer_{i}")
             if decode_ctx is None:
                 x = block(x)
@@ -315,7 +452,8 @@ class TransformerLM(nn.Module):
                 x, (k_new, v_new) = block(x, decode_ctx.layer(i))
                 new_ks.append(k_new)
                 new_vs.append(v_new)
-        x = nn.RMSNorm(dtype=self.dtype, name="final_norm")(x)
+        x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                       name="final_norm")(x)
         # Logits accumulate in float32 for a numerically stable softmax,
         # but the matmul runs in bfloat16 on the MXU: an f32xf32 matmul
         # costs multiple MXU passes, and the lm_head is ~1/3 of the model's
@@ -566,7 +704,7 @@ def _token_xent_bwd(res, g):
     d_logits = (exp * (g / sum_exp)[..., None]
                 - jnp.where(classes == targets[..., None], g[..., None], 0.0)
                 ).astype(logits.dtype)
-    return _reduced_to_vma_of(logits, d_logits), None
+    return reduced_to_vma_of(logits, d_logits), None
 
 
 _token_xent.defvjp(_token_xent_fwd, _token_xent_bwd)
@@ -612,3 +750,64 @@ def next_token_loss(logits, targets, mask=None, axis_name=None):
             n_shards *= lax.axis_size(a)
         count = lax.psum(count, axes) / n_shards
     return (loss * mask).sum() / jnp.maximum(count, 1.0)
+
+
+def _sown(tree, name):
+    """Every value sown under ``name`` anywhere in a flax collection, in
+    layer order (``layer_2`` before ``layer_10``)."""
+    found = []
+    for key in sorted(tree, key=lambda k: (len(k), k)):
+        value = tree[key]
+        if key == name:
+            found.extend(value if isinstance(value, (tuple, list))
+                         else [value])
+        elif hasattr(value, "keys"):
+            found.extend(_sown(value, name))
+    return found
+
+
+def record_expert_rows(intermediates) -> dict:
+    """Read the counters the sparse-expert layers wrote to the
+    ``intermediates`` collection of one ``apply(..., mutable=
+    ["intermediates"])`` — OUTSIDE the compiled step, on concrete arrays —
+    and, when the metrics registry is on (``HVD_TPU_METRICS=1``), mirror them
+    into ``hvd.metrics_snapshot()["moe"]``.  Returns ``{"rows_per_local_
+    expert": [[rows of each local expert] per layer], "rows_over_bound":
+    int}``."""
+    from horovod_tpu.common import metrics as _metrics
+
+    rows = [[int(n) for n in layer]
+            for layer in _sown(intermediates, "rows_per_local_expert")]
+    over = sum(int(n) for n in _sown(intermediates, "rows_over_bound"))
+    if _metrics.registry.enabled:
+        _metrics.registry.set_moe_rows(rows, over)
+    return {"rows_per_local_expert": rows, "rows_over_bound": over}
+
+
+def router_losses(router):
+    """(load-balancing loss, router z-loss) of a sparse-expert model, from
+    the ``router`` collection its layers wrote (``model.apply(...,
+    mutable=["router"])[1]["router"]``), over all layers' tokens together:
+
+    * load balancing, ``E * sum_e f_e * P_e``: ``f_e`` the share of all
+      (token, choice) pairs that chose expert e, ``P_e`` the mean router
+      probability of e; 1 when both are uniform;
+    * z-loss: the mean over tokens of ``logsumexp(router logits)**2``.
+
+    Under data parallelism each shard's statistics are its own tokens'."""
+    choices = sum(_sown(router, "choices"))
+    prob_sum = sum(_sown(router, "prob_sum"))
+    tokens = sum(_sown(router, "tokens"))
+    share = choices / choices.sum()
+    balance = choices.shape[0] * (share * (prob_sum / tokens)).sum()
+    return balance, sum(_sown(router, "z_sum")) / tokens
+
+
+def moe_next_token_loss(logits, targets, router, load_balance_coef=0.01,
+                        z_coef=0.001, mask=None, axis_name=None):
+    """:func:`next_token_loss` plus ``load_balance_coef`` times the
+    load-balancing loss and ``z_coef`` times the router z-loss of
+    :func:`router_losses` (OLMoE: 0.01 and 0.001)."""
+    balance, z = router_losses(router)
+    return (next_token_loss(logits, targets, mask, axis_name)
+            + load_balance_coef * balance + z_coef * z)

@@ -1,0 +1,355 @@
+"""The sparse-expert layer of models.TransformerLM (OLMoE's block) against the
+plain float32 reference the benchmark keeps (benchmark/reference/moe_lm.py):
+a loop over the shard's experts, each applied to every token — no sorting, no
+grouped matmul.  CPU, float32, seeded weights, small sizes.
+
+Tolerances: both sides are float32 and differ in the order of their sums
+(grouped rows against masked whole batches, scatter-add against a loop), so
+they agree to a few units of float32 rounding accumulated over two layers:
+2e-5 relative.  bfloat16 anywhere (2^-8 a rounding) would read 1e-3 to 1e-2
+and fail every case.
+"""
+
+import hashlib
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+
+from benchmark.reference import moe_lm as reference
+from horovod_tpu.jax.train import build_train_step
+from horovod_tpu.common import metrics
+from horovod_tpu.models import (MoEConfig, TransformerLM,
+                                moe_next_token_loss, next_token_loss,
+                                record_expert_rows, router_losses)
+from horovod_tpu.models.transformer import SparseExperts
+from horovod_tpu.ops.moe import dispatch_rows, grouped_matmul
+
+RTOL = 2e-5
+VOCAB, HIDDEN, HEADS, LAYERS, SEQ = 256, 64, 2, 2, 128
+EXPERTS, PER_TOKEN, WIDTH = 8, 2, 32
+SHARDS = [(0, 1), (0, 4), (3, 4)]
+
+
+def lm(shard=(0, 1), use_flash=False):
+    return TransformerLM(
+        vocab_size=VOCAB, d_model=HIDDEN, n_layers=LAYERS, n_heads=HEADS,
+        dtype=jnp.float32, use_flash=use_flash, qk_norm=True, norm_eps=1e-5,
+        moe=MoEConfig(EXPERTS, PER_TOKEN, WIDTH, shard))
+
+
+def reference_config(shard):
+    return dict(num_experts=EXPERTS, experts_per_token=PER_TOKEN,
+                expert_shard=shard, norm_eps=1e-5)
+
+
+def seeded(model, seed=0, batch=1):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    tokens = jax.random.randint(keys[0], (batch, SEQ + 1), 0, VOCAB)
+    params = model.init(keys[1], tokens[:, :-1])["params"]
+    # Scales away from one, so that a norm left out or misplaced shows.
+    params = jax.tree.map(
+        lambda a: a * (1 + 0.1 * jax.random.normal(keys[2], a.shape)),
+        params)
+    return params, (tokens[:, :-1], tokens[:, 1:])
+
+
+def system_terms(model, params, batch):
+    logits, state = model.apply({"params": params}, batch[0],
+                                mutable=["router"])
+    return (next_token_loss(logits, batch[1]),) \
+        + router_losses(state["router"])
+
+
+def system_loss(model, params, batch):
+    logits, state = model.apply({"params": params}, batch[0],
+                                mutable=["router"])
+    return moe_next_token_loss(logits, batch[1], state["router"])
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("term", [0, 1, 2],
+                         ids=["cross_entropy", "load_balance", "router_z"])
+@pytest.mark.parametrize("shard", SHARDS, ids=str)
+def test_loss_terms_match_reference(shard, term):
+    model = lm(shard)
+    params, batch = seeded(model)
+    got = system_terms(model, params, batch)[term]
+    want = reference.loss_terms(params, batch,
+                                **reference_config(shard))[term]
+    assert abs(float(got) - float(want)) <= RTOL * abs(float(want))
+
+
+@pytest.mark.parametrize("shard", SHARDS, ids=str)
+def test_every_gradient_leaf_matches_reference(shard):
+    model = lm(shard)
+    params, batch = seeded(model, seed=1)
+    got = jax.grad(lambda p: system_loss(model, p, batch))(params)
+    want = jax.grad(lambda p: reference.loss(
+        p, batch, **reference_config(shard)))(params)
+    paths = [jax.tree_util.keystr(path) for path, _ in
+             jax.tree_util.tree_flatten_with_path(want)[0]]
+    assert len(paths) == 3 + LAYERS * 10
+    for path, g, w in zip(paths, jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.isfinite(np.asarray(g)).all(), path
+        assert rel(g, w) <= 10 * RTOL, (path, rel(g, w))
+
+
+def test_loss_is_the_three_terms_with_olmoe_coefficients():
+    model = lm()
+    params, batch = seeded(model, seed=2)
+    xent, balance, z = system_terms(model, params, batch)
+    total = system_loss(model, params, batch)
+    assert float(total) == pytest.approx(
+        float(xent + 0.01 * balance + 0.001 * z), rel=1e-6)
+    assert 0.9 < float(balance) < 1.5   # 1 for a uniform router
+
+
+def layer_and_input(shard, seed=3, row_bound=None, tokens=SEQ):
+    layer = SparseExperts(MoEConfig(EXPERTS, PER_TOKEN, WIDTH, shard,
+                                    row_bound), jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    x = jax.random.normal(keys[0], (1, tokens, HIDDEN))
+    return layer, layer.init(keys[1], x)["params"], x
+
+
+def test_shards_sum_to_the_whole_layer():
+    whole, params, x = layer_and_input((0, 1))
+    want = whole.apply({"params": params}, x)
+    local = EXPERTS // 4
+    total = 0.0
+    for i in range(4):
+        part = dict(params, **{
+            name: params[name][i * local:(i + 1) * local]
+            for name in ("gate_kernel", "up_kernel", "down_kernel")})
+        total = total + SparseExperts(
+            MoEConfig(EXPERTS, PER_TOKEN, WIDTH, (i, 4)), jnp.float32).apply(
+                {"params": part}, x)
+    assert rel(total, want) <= RTOL
+
+
+def test_one_expert_takes_every_row_and_one_takes_none():
+    """No drop and no NaN at the extremes of routing: expert 0 is in every
+    token's choice, expert 1 in none."""
+    layer, params, x = layer_and_input((0, 1), seed=4)
+    x = jnp.abs(x)                       # all positive: a column of ones
+    kernel = params["router_kernel"]     # then fixes an expert's rank
+    params = dict(params, router_kernel=kernel.at[:, 0].set(1.0)
+                  .at[:, 1].set(-1.0))
+
+    def run(params, x):
+        out, state = layer.apply({"params": params}, x,
+                                 mutable=["intermediates"])
+        return out, state["intermediates"]
+
+    out, seen = run(params, x)
+    rows = np.asarray(seen["rows_per_local_expert"][0])
+    assert rows[0] == SEQ and rows[1] == 0 and rows.sum() == SEQ * PER_TOKEN
+    assert int(seen["rows_over_bound"][0]) == 0
+    flat = x.reshape(-1, HIDDEN)
+    _, weights, experts = reference.router(flat, params["router_kernel"],
+                                           PER_TOKEN)
+    want = reference.experts_of_shard(flat, params, weights, experts, 0)
+    assert rel(out.reshape(-1, HIDDEN), want) <= RTOL
+    grads = jax.grad(lambda p: run(p, x)[0].sum())(params)
+    assert all(np.isfinite(np.asarray(g)).all()
+               for g in jax.tree.leaves(grads))
+    assert float(jnp.abs(grads["gate_kernel"][1]).max()) == 0.0
+    assert float(jnp.abs(grads["gate_kernel"][0]).max()) > 0.0
+
+
+@pytest.mark.parametrize("row_bound,held", [(None, 1024),
+                                            (1.0, 512), (4.0, 1024)])
+def test_rows_over_a_bound_are_counted(row_bound, held):
+    """Tokens * k = 1,024 pairs, 4 shards: a bound of 1.0 is 256 rows rounded
+    up to 512, and every row the router sends this shard past what the buffer
+    holds is in the counter; with no bound the buffer holds every pair."""
+    tokens = 512
+    layer, params, x = layer_and_input((0, 4), seed=5, row_bound=row_bound,
+                                       tokens=tokens)
+    x = jnp.abs(x)
+    kernel = params["router_kernel"]
+    params = dict(params, router_kernel=kernel.at[:, 0].set(1.0)
+                  .at[:, 1].set(0.9))      # both local experts, every token
+    _, state = layer.apply({"params": params}, x, mutable=["intermediates"])
+    seen = state["intermediates"]
+    routed = int(np.asarray(seen["rows_per_local_expert"][0]).sum())
+    assert routed == tokens * PER_TOKEN
+    assert int(seen["rows_over_bound"][0]) == max(0, routed - held)
+
+
+def test_counters_reach_the_caller_and_the_registry():
+    """Rows per local expert and rows over the bound, per layer, from the
+    `intermediates` collection; mirrored into hvd.metrics when it is on."""
+    model = lm((0, 4))
+    params, batch = seeded(model, seed=8)
+    _, state = model.apply({"params": params}, batch[0],
+                           mutable=["intermediates"])
+    was_on = metrics.registry.enabled
+    metrics.registry.enable()
+    try:
+        seen = record_expert_rows(state["intermediates"])
+        mirrored = metrics.registry.snapshot()["moe"]
+    finally:
+        if not was_on:
+            metrics.registry.disable()
+    rows = np.asarray(seen["rows_per_local_expert"])
+    assert rows.shape == (LAYERS, EXPERTS // 4) and seen["rows_over_bound"] == 0
+    chosen = np.asarray(state["intermediates"]["layer_1"]["moe"]
+                        ["chosen_experts"][0])
+    assert rows[1].tolist() == [(chosen == e).sum() for e in range(2)]
+    assert mirrored["rows_per_local_expert"] == rows.tolist()
+    assert "hvd_tpu_moe_expert_rows{layer=\"1\",expert=\"0\"} " \
+        f"{rows[1][0]}" in metrics.prometheus_text(
+            {**metrics.registry.snapshot(), "moe": mirrored})
+
+
+def test_dispatch_sorts_local_experts_first():
+    experts = jnp.array([5, 2, 7, 3, 2, 0, 3, 6], jnp.int32)
+    sent = dispatch_rows(experts, 2, 2, 8)
+    assert sent.group_sizes.tolist() == [2, 2]
+    assert experts[sent.pair][:4].tolist() == [2, 2, 3, 3]
+    assert sent.pair[:4].tolist() == [1, 4, 3, 6]       # stable
+    assert int(sent.rows_over_bound) == 0
+    cut = dispatch_rows(experts, 2, 2, 3)
+    assert cut.group_sizes.tolist() == [2, 1]
+    assert cut.rows_per_expert.tolist() == [2, 2]
+    assert int(cut.rows_over_bound) == 1
+
+
+@pytest.mark.parametrize("sizes", [[10, 0, 30, 5], [0, 0, 0, 0],
+                                   [64, 0, 0, 0]], ids=str)
+def test_grouped_matmul_and_its_gradients(sizes):
+    keys = jax.random.split(jax.random.PRNGKey(6), 3)
+    rows = jax.random.normal(keys[0], (64, 16))
+    weights = jax.random.normal(keys[1], (4, 16, 8))
+    mix = jax.random.normal(keys[2], (64, 8))
+    sizes = jnp.array(sizes, jnp.int32)
+    group = jnp.searchsorted(jnp.cumsum(sizes), jnp.arange(64), side="right")
+
+    def plain(rows, weights):
+        return sum(jnp.where((group == g)[:, None], rows @ weights[g], 0.0)
+                   for g in range(4))
+
+    np.testing.assert_allclose(grouped_matmul(rows, weights, sizes),
+                               plain(rows, weights), atol=1e-5)
+    got = jax.grad(lambda r, w: (grouped_matmul(r, w, sizes) * mix).sum(),
+                   (0, 1))(rows, weights)
+    want = jax.grad(lambda r, w: (plain(r, w) * mix).sum(),
+                    (0, 1))(rows, weights)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=1e-5)
+
+
+# The dense default: the parameter tree and the program TransformerLM lowers
+# to must be what they were before the sparse-expert layer existed (the
+# three pythia410m cells of the benchmark run it).  The digest is of
+# `jax.jit(grad).lower(...).as_text()` at the parent commit of PR 26 (jax
+# 0.9.0); a later change to the dense block re-records it on purpose.
+DENSE_DIGEST = (
+    "2337d6dbb065945c061bc36f6469d2bd3e634b0488a6ddb83368537b8dfd57a1")
+
+
+def dense_lm():
+    return TransformerLM(vocab_size=VOCAB, d_model=HIDDEN, n_layers=LAYERS,
+                         n_heads=HEADS, d_ff=128, dtype=jnp.bfloat16,
+                         logits_dtype=jnp.bfloat16, use_flash=False)
+
+
+def test_dense_default_parameter_tree_is_unchanged():
+    shapes = jax.eval_shape(
+        lambda: dense_lm().init(jax.random.PRNGKey(0),
+                                jnp.zeros((1, SEQ), jnp.int32))["params"])
+    flat = {jax.tree_util.keystr(path): leaf.shape for path, leaf in
+            jax.tree_util.tree_flatten_with_path(shapes)[0]}
+    layer = {"['attn']['o_kernel']": (HEADS, HIDDEN // HEADS, HIDDEN),
+             "['attn']['qkv_kernel']": (HIDDEN, 3, HEADS, HIDDEN // HEADS),
+             "['attn_norm']['scale']": (HIDDEN,),
+             "['down']['kernel']": (128, HIDDEN),
+             "['mlp_norm']['scale']": (HIDDEN,),
+             "['up']['kernel']": (HIDDEN, 128)}
+    want = {"['embed']['embedding']": (VOCAB, HIDDEN),
+            "['final_norm']['scale']": (HIDDEN,),
+            "['lm_head_kernel']": (HIDDEN, VOCAB)}
+    for i in range(LAYERS):
+        want.update({f"['layer_{i}']{k}": v for k, v in layer.items()})
+    assert flat == want
+
+
+def test_dense_default_lowers_to_the_same_program():
+    model = dense_lm()
+    tokens = jnp.zeros((2, SEQ), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens)["params"])
+
+    def loss(params, tokens):
+        return next_token_loss(model.apply({"params": params}, tokens),
+                               tokens)
+
+    text = jax.jit(jax.grad(loss)).lower(params, tokens).as_text()
+    assert "ragged" not in text and "top_k" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == DENSE_DIGEST
+
+
+def test_trains_through_build_train_step_and_replicas_stay_equal():
+    """Two CPU devices, data parallel: the step of the dense LM, with the
+    sparse-expert loss.  The replicated weights stay equal on both devices
+    and the loss of a repeated batch falls.  The flash kernel (interpreted
+    here), as in the benchmark's step: blockwise_attention does not pass
+    shard_map's vma check (PERF.md section 7)."""
+    model = lm((0, 4), use_flash=True)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("hvd",))
+    params, batch = seeded(model, seed=7, batch=2)
+    tx = optax.adamw(1e-2)
+
+    def loss_fn(params, batch):
+        return system_loss(model, params, batch)
+
+    step = build_train_step(loss_fn, tx, mesh, axis_name="hvd",
+                            batch_spec=(P("hvd"), P("hvd")))
+    state = (params, tx.init(params))
+    losses = []
+    for _ in range(4):
+        *state, loss = step(*state, batch)
+        losses.append(float(loss))
+    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+    for leaf in jax.tree.leaves(state[0]):
+        first, second = (np.asarray(s.data) for s in leaf.addressable_shards)
+        np.testing.assert_array_equal(first, second)
+
+
+def test_lm_example_takes_an_olmoe_config(tmp_path):
+    """examples/jax_transformer_lm.py trains the sparse-expert model through
+    the same TransformerLM + build_train_step lines (here under sequence
+    parallelism over two CPU devices): no second script."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config = tmp_path / "config.json"
+    config.write_text(json.dumps({
+        "vocab_size": VOCAB, "hidden_size": HIDDEN,
+        "num_hidden_layers": LAYERS, "num_attention_heads": HEADS,
+        "rms_norm_eps": 1e-5, "num_experts": EXPERTS,
+        "num_experts_per_tok": PER_TOKEN, "intermediate_size": WIDTH}))
+    env = dict(os.environ, PYTHONPATH=root,
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "examples",
+                                      "jax_transformer_lm.py"),
+         "--dp", "1", "--sp", "2", "--seq-len", "128", "--batch", "2",
+         "--steps", "12", "--olmoe-config", str(config)],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    losses = [float(line.split()[-1]) for line in proc.stdout.splitlines()
+              if line.startswith("step")]
+    assert len(losses) == 3 and losses[-1] < losses[0]

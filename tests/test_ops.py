@@ -3,6 +3,7 @@ ring attention (sequence parallel over the virtual 8-device mesh) vs the
 full-sequence result — values and gradients."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -238,6 +239,49 @@ def test_flash_bwd_seq_sweep_compiles(v5e, seq, d):
     # forward + combined backward, or forward + the split dkdv/dq pair
     mode = _bwd_plan(seq, d, 1024, 1024, batch * 8)[0]
     assert text.count("tpu_custom_call") == {"combined": 2, "split": 3}[mode]
+
+
+def test_flash_head128_at_olmoe_shape_compiles(v5e):
+    """OLMoE's attention as the benchmark's sparse-expert cell runs it — 2
+    sequences x 16 heads of 128 x 4,096 — in the mode _bwd_plan picks
+    (rows128 = 4096, bh = 32: the combined backward at (512, 1024) blocks).
+    The chip's compiler accepts the plan: the band needed no recalibration
+    (PR 26; the whole step of that cell compiles with it too)."""
+    from horovod_tpu.ops.attention import _bwd_plan
+
+    assert _bwd_plan(4096, 128, 1024, 1024, 32) == ("combined", 512, 1024)
+    text = _compile_flash_grad(v5e[0], (2, 16, 4096, 128))
+    assert text.count("tpu_custom_call") == 2
+
+
+def test_grouped_matmul_lowers_to_libtpu_kernels(v5e):
+    """ops.moe.grouped_matmul at the sparse-expert cell's shapes — 24,576
+    rows of 2,048 against 16 experts of 1,024 — forward and both gradients:
+    libtpu lowers each ragged_dot to a Mosaic kernel of its own (custom
+    calls named ragged-dot-*), not to a dense product over every group."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.ops.moe import grouped_matmul
+
+    on_chip = SingleDeviceSharding(v5e[0])
+    rows = jax.ShapeDtypeStruct((24576, 2048), jnp.bfloat16,
+                                sharding=on_chip)
+    weights = jax.ShapeDtypeStruct((16, 2048, 1024), jnp.bfloat16,
+                                   sharding=on_chip)
+    sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=on_chip)
+
+    def loss(rows, weights, sizes):
+        return grouped_matmul(rows, weights, sizes).astype(
+            jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        rows, weights, sizes).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 2
+    assert " while(" not in text
+    # One dense product over the buffer's rows each, not one per group.
+    dense = 2 * 24576 * 2048 * 1024
+    assert 1.9 * dense < compiled.cost_analysis()["flops"] < 2.2 * dense
 
 
 @pytest.mark.parametrize("seq,blocks", [(2048, 2048), (4096, 4096)])

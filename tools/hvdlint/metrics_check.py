@@ -48,6 +48,8 @@ SECTION_FAMILIES = {
                 "hvd_tpu_serving_steps_total"),
     "flight": ("hvd_tpu_flight_events_total",
                "hvd_tpu_flight_ring_capacity"),
+    "moe": ("hvd_tpu_moe_expert_rows",
+            "hvd_tpu_moe_rows_over_bound_total"),
     "compression": ("hvd_tpu_compression_mode",
                     "hvd_tpu_compression_wire_bytes_total",
                     "hvd_tpu_compression_payload_bytes_total",
@@ -131,6 +133,7 @@ def populated_registry():
     reg.set_serving_gauges(queue_depth=1, active=2, kv_blocks_in_use=3,
                            kv_blocks_total=8)
     reg.set_flight({"events": {"engine": 5, "xla": 2}, "capacity": 512})
+    reg.set_moe_rows([[3, 5], [4, 4]], 1)
     reg.set_state_armed(True)
     reg.record_state_snapshot(7, 4096)
     reg.set_state_overlap(0.01, 0.4)
