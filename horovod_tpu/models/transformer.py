@@ -20,8 +20,9 @@ from jax import lax
 
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              ring_attention)
-from horovod_tpu.ops.moe import (dispatch_rows, grouped_matmul,
-                                 reduced_to_vma_of)
+from horovod_tpu.ops.moe import (buffer_rows_to_tokens, dispatch_rows,
+                                 grouped_matmul, reduced_to_vma_of,
+                                 token_rows_to_buffer, top_choices)
 
 
 @jax.custom_vjp
@@ -190,6 +191,15 @@ class SparseExperts(nn.Module):
     """Router, dispatch, grouped expert matmuls and combine of one layer
     (``MoEConfig``), each under a ``jax.named_scope`` a trace can read.
 
+    Rows travel by gathers in both directions (``ops/moe.py``): the (token,
+    choice) pairs are sorted by expert, this shard's first; a token's row is
+    gathered into every buffer row that holds one of its pairs, and the
+    experts' rows are gathered back through each pair's position in the
+    sorted order and summed, weighted, per token.  Pairs of experts held
+    elsewhere and pairs a ``row_bound`` cut off have no row in the buffer and
+    contribute exactly zero, forward and backward.  No scatter is left in
+    the layer's program or in its gradient's.
+
     Writes, where the caller makes the collection mutable: ``router`` —
     ``choices`` (pairs per expert, all experts), ``prob_sum``, ``z_sum``,
     ``tokens``: what :func:`router_losses` reads; ``intermediates`` —
@@ -227,7 +237,7 @@ class SparseExperts(nn.Module):
             logits = jnp.dot(flat, w_router.astype(self.dtype),
                              preferred_element_type=jnp.float32)
             probs = jax.nn.softmax(logits, axis=-1)
-            weight, expert = lax.top_k(probs, k)
+            weight, expert = top_choices(probs, k)
             self.sow("intermediates", "chosen_experts", expert)
             choices = (expert[..., None] == jnp.arange(cfg.num_experts)
                        ).sum(axis=(0, 1), dtype=jnp.float32)
@@ -237,10 +247,8 @@ class SparseExperts(nn.Module):
                      jnp.square(jax.nn.logsumexp(logits, axis=-1)).sum())
             self.sow("router", "tokens", jnp.float32(tokens))
         with jax.named_scope("hvd_moe_dispatch"):
-            sent = dispatch_rows(expert.reshape(-1), shard * local, local,
-                                 bound)
-            token_of_row = sent.pair // k
-            rows = flat[token_of_row]
+            sent = dispatch_rows(expert, shard * local, local, bound)
+            rows = token_rows_to_buffer(flat, sent)
             self.sow("intermediates", "rows_per_local_expert",
                      sent.rows_per_expert)
             self.sow("intermediates", "rows_over_bound",
@@ -252,13 +260,8 @@ class SparseExperts(nn.Module):
             out = grouped_matmul(nn.silu(gate) * up,
                                  w_down.astype(self.dtype), sizes)
         with jax.named_scope("hvd_moe_combine"):
-            # Rows past the last group are zero (grouped_matmul), so the
-            # weights of pairs held elsewhere multiply nothing.
-            weighted = out.astype(jnp.float32) \
-                * weight.reshape(-1)[sent.pair][:, None]
-            mixed = jnp.zeros((tokens, d), jnp.float32).at[
-                token_of_row].add(weighted)
-        return mixed.astype(self.dtype).reshape(x.shape)
+            mixed = buffer_rows_to_tokens(out, weight, sent)
+        return mixed.reshape(x.shape)
 
 
 class Attention(nn.Module):

@@ -12,6 +12,21 @@ chose.  With ``bound`` = every (token, choice) pair nothing can fall outside
 it; with a smaller bound the rows past it are left out AND COUNTED
 (``Dispatch.rows_over_bound``), never silently.
 
+How rows travel: :func:`dispatch_rows` sorts the pairs once and keeps the
+permutation both ways — ``pair[r]``, the pair that buffer row r holds, and
+``position[t, c]``, the place of pair (t, c) in the sorted order.  A pair is
+``valid`` when its place is under ``group_sizes.sum()``: pairs of experts
+held elsewhere and pairs the bound cut off are not, and contribute exactly
+zero (their index is clamped into the buffer, their value masked).  Every
+movement of rows is then a GATHER, forward and backward
+(:func:`token_rows_to_buffer`, :func:`buffer_rows_to_tokens`): the TPU runs
+a scatter-add row by row (2 ms for 24,576 rows of 2,048, PR 26's trace), and
+autodiff turns every gather back into one, so both movers carry a backward
+pass of their own that reads through the other direction's index
+(:func:`top_choices` does the same for the router's top-k).  With every pair
+valid (one shard, no bound) the k-wide gather reads each buffer row once;
+on a shard it reads the clamped row for the pairs held elsewhere.
+
 :func:`grouped_matmul` is ``jax.lax.ragged_dot`` — which libtpu lowers to a
 Mosaic kernel of its own (``%ragged-dot-none`` custom calls, 512-tiles) —
 with the three products of its backward written out, so that every operand
@@ -22,6 +37,7 @@ unwritten, read as zero.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -41,40 +57,153 @@ def reduced_to_vma_of(primal, cotangent):
     return lax.psum(cotangent, tuple(sorted(extra)))
 
 
-class Dispatch(NamedTuple):
-    """Where each row of the sorted buffer came from.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def top_choices(probs, k: int):
+    """``lax.top_k(probs, k)`` over the last axis: (weights, experts).  Its
+    backward hands each chosen expert's probability its weight's cotangent
+    by a compare and a sum over the k choices, where autodiff scatters
+    them."""
+    return lax.top_k(probs, k)
 
-    ``pair``: (bound,) index into the flat (token, choice) pairs, sorted by
-    local expert; past ``group_sizes.sum()`` the pairs of experts held
-    elsewhere (any order).  ``group_sizes``: (local experts,) rows each local
-    expert multiplies, in buffer order.  ``rows_per_expert``: what the router
-    sent each local expert (equal to ``group_sizes`` unless the bound cut
-    some off).  ``rows_over_bound``: scalar, rows routed here that the
-    buffer could not hold."""
+
+def _top_choices_fwd(probs, k):
+    weight, expert = lax.top_k(probs, k)
+    return (weight, expert), (probs, expert)
+
+
+def _top_choices_bwd(k, res, cotangents):
+    probs, expert = res
+    d_weight = cotangents[0]
+    chosen = expert[..., None] == jnp.arange(probs.shape[-1],
+                                             dtype=expert.dtype)
+    d_probs = jnp.where(chosen, d_weight[..., None],
+                        jnp.zeros((), d_weight.dtype)).sum(axis=-2)
+    return (reduced_to_vma_of(probs, d_probs.astype(probs.dtype)),)
+
+
+top_choices.defvjp(_top_choices_fwd, _top_choices_bwd)
+
+
+class Dispatch(NamedTuple):
+    """The sorted order of the (token, choice) pairs, both ways.
+
+    ``pair``: (bound,) index into the flat pairs, sorted by local expert;
+    past ``group_sizes.sum()`` the pairs of experts held elsewhere (any
+    order).  ``position``: the place of every pair in the whole sorted order
+    (a permutation of the flat pairs, shaped as the experts were given:
+    ``pair[position.reshape(-1)[p]] == p`` wherever the place is inside the
+    buffer).  ``valid``: ``position < group_sizes.sum()`` — the pair's row is
+    in the buffer and an expert here multiplies it.  ``group_sizes``: (local
+    experts,) rows each local expert multiplies, in buffer order.
+    ``rows_per_expert``: what the router sent each local expert (equal to
+    ``group_sizes`` unless the bound cut some off).  ``rows_over_bound``:
+    scalar, rows routed here that the buffer could not hold."""
 
     pair: jax.Array
+    position: jax.Array
+    valid: jax.Array
     group_sizes: jax.Array
     rows_per_expert: jax.Array
     rows_over_bound: jax.Array
 
+    @property
+    def token_of_row(self):
+        """(bound,) the token whose row each buffer row is; ``position`` is
+        (tokens, choices)."""
+        return self.pair // self.position.shape[-1]
+
 
 def dispatch_rows(expert_of_pair, first_expert: int, local_experts: int,
                   bound: int) -> Dispatch:
-    """Sort the flat (token, choice) pairs by expert, this shard's experts
+    """Sort the (token, choice) pairs by expert, this shard's experts
     ``[first_expert, first_expert + local_experts)`` first, and keep the
     first ``bound`` of them."""
-    local = expert_of_pair - first_expert
+    local = expert_of_pair.reshape(-1) - first_expert
     held_here = (local >= 0) & (local < local_experts)
     key = jnp.where(held_here, local, local_experts).astype(jnp.int32)
-    pair = jnp.argsort(key, stable=True)[:bound].astype(jnp.int32)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    # The inverse permutation by a second sort (0.044 ms for 65,536 keys on
+    # the v5e; scattering an iota took 0.12 ms more: my chip runs, PR 27).
+    position = jnp.argsort(order).astype(jnp.int32).reshape(
+        expert_of_pair.shape)
     # A compare and a sum, not a scatter-add of ones: the TPU runs a
     # scatter row by row (0.6 ms a layer for 65,536 pairs, PR 26's trace).
     rows = (key[:, None] == jnp.arange(local_experts, dtype=jnp.int32)
             ).sum(axis=0, dtype=jnp.int32)
     ends = jnp.minimum(jnp.cumsum(rows), bound)
     sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
-    return Dispatch(pair, sizes, rows,
+    return Dispatch(order[:bound], position, position < ends[-1], sizes, rows,
                     jnp.maximum(rows.sum() - bound, 0).astype(jnp.int32))
+
+
+def _of_pairs(buffer, sent: Dispatch):
+    """(tokens, choices, ...): the buffer's entry for every pair, zero where
+    the pair has none.  The index of such a pair is clamped into the
+    buffer."""
+    index = jnp.minimum(sent.position, buffer.shape[0] - 1)
+    valid = sent.valid.reshape(sent.valid.shape + (1,) * (buffer.ndim - 1))
+    return jnp.where(valid, buffer[index], jnp.zeros((), buffer.dtype))
+
+
+@jax.custom_vjp
+def token_rows_to_buffer(flat, sent: Dispatch):
+    """``rows[r] = flat[token_of_row[r]]``: each token's row, once for every
+    buffer row that holds one of its pairs.  ``flat`` (tokens, d); the
+    result (bound, d).  Backward: ``d_flat[t]`` is the sum over the token's
+    choices of ``d_rows[position[t, c]]`` where valid — a gather, summed in
+    float32 and rounded once to ``flat``'s dtype."""
+    return flat[sent.token_of_row]
+
+
+def _token_rows_to_buffer_fwd(flat, sent):
+    return flat[sent.token_of_row], (flat, sent)
+
+
+def _token_rows_to_buffer_bwd(res, d_rows):
+    flat, sent = res
+    d_flat = _of_pairs(d_rows, sent).astype(jnp.float32).sum(axis=1)
+    return reduced_to_vma_of(flat, d_flat.astype(flat.dtype)), None
+
+
+token_rows_to_buffer.defvjp(_token_rows_to_buffer_fwd,
+                            _token_rows_to_buffer_bwd)
+
+
+@jax.custom_vjp
+def buffer_rows_to_tokens(out, weight, sent: Dispatch):
+    """``mixed[t] = sum over c of weight[t, c] * out[position[t, c]]`` where
+    valid: the experts' rows back in token order, each weighted by its
+    router weight; products and sum in float32, rounded once to ``out``'s
+    dtype.  ``out`` (bound, d), ``weight`` (tokens, choices) float32.
+    Backward (products in float32 as well):
+    ``d_out[r] = weight[pair[r]] * d_mixed[token_of_row[r]]`` and
+    ``d_weight[t, c] = <d_mixed[t], out[position[t, c]]>`` where valid, zero
+    elsewhere — gathers both."""
+    return _buffer_rows_to_tokens_fwd(out, weight, sent)[0]
+
+
+def _buffer_rows_to_tokens_fwd(out, weight, sent):
+    picked = _of_pairs(out, sent).astype(jnp.float32)
+    mixed = (picked * weight[..., None]).sum(axis=1)
+    return mixed.astype(out.dtype), (out, weight, sent)
+
+
+def _buffer_rows_to_tokens_bwd(res, d_mixed):
+    out, weight, sent = res
+    d_weighted = d_mixed[sent.token_of_row].astype(jnp.float32)
+    d_out = weight.reshape(-1)[sent.pair][:, None] * d_weighted
+    # <d_mixed[t], out[r]> once per buffer row, in the pass that reads both
+    # anyway; each valid pair then picks its row's scalar (gathering the
+    # rows themselves a second time, k-wide, took 1.3 ms a layer more on the
+    # v5e: my chip run, PR 27).
+    along = (out.astype(jnp.float32) * d_weighted).sum(axis=-1)
+    d_weight = _of_pairs(along, sent)
+    return (reduced_to_vma_of(out, d_out.astype(out.dtype)),
+            reduced_to_vma_of(weight, d_weight.astype(weight.dtype)), None)
+
+
+buffer_rows_to_tokens.defvjp(_buffer_rows_to_tokens_fwd,
+                             _buffer_rows_to_tokens_bwd)
 
 
 def _rows_of_groups(rows, group_sizes):

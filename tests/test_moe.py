@@ -30,7 +30,9 @@ from horovod_tpu.models import (MoEConfig, TransformerLM,
                                 moe_next_token_loss, next_token_loss,
                                 record_expert_rows, router_losses)
 from horovod_tpu.models.transformer import SparseExperts
-from horovod_tpu.ops.moe import dispatch_rows, grouped_matmul
+from horovod_tpu.ops.moe import (buffer_rows_to_tokens, dispatch_rows,
+                                 grouped_matmul, token_rows_to_buffer,
+                                 top_choices)
 
 RTOL = 2e-5
 VOCAB, HIDDEN, HEADS, LAYERS, SEQ = 256, 64, 2, 2, 128
@@ -226,6 +228,157 @@ def test_dispatch_sorts_local_experts_first():
     assert cut.group_sizes.tolist() == [2, 1]
     assert cut.rows_per_expert.tolist() == [2, 2]
     assert int(cut.rows_over_bound) == 1
+
+
+# The two row movers against the scatter-add formulation they replaced,
+# written out here as the layer had it (PR 26): the forward gather's autodiff
+# backward scatter-adds in the rows' dtype, the combine scatter-adds float32
+# products.  float32: the two differ in the order of a token's k terms,
+# 2e-6.  bfloat16: the combine's products and sums are float32 on both sides
+# and differ as in float32; the dispatch's backward sum is float32 rounded
+# once where the scatter-add rounds after every row, so the limit there is
+# bfloat16's rounding over k terms (2^-8 each) — and the gather form must be
+# the CLOSER of the two to a float32 sum.
+MOVER_TOKENS, MOVER_WIDTH = 96, 16
+BOUNDS = {"every_pair": None, "cut": 40}
+
+
+def routed(shard, bound, dtype, seed=9):
+    """Seeded routing and rows: (sent, flat, out, weight, d_rows, d_mixed),
+    the last two the cotangents of the buffer's rows and of the tokens'."""
+    i, n = shard
+    local = EXPERTS // n
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    probs = jax.nn.softmax(jax.random.normal(keys[0],
+                                             (MOVER_TOKENS, EXPERTS)))
+    weight, expert = jax.lax.top_k(probs, PER_TOKEN)
+    rows = MOVER_TOKENS * PER_TOKEN if bound is None else bound
+    sent = dispatch_rows(expert, i * local, local, rows)
+    flat = jax.random.normal(keys[1], (MOVER_TOKENS, MOVER_WIDTH), dtype)
+    # As grouped_matmul leaves it and its backward hands it down: rows past
+    # the last group are zero.
+    inside = (jnp.arange(rows) < sent.group_sizes.sum())[:, None]
+    out = jnp.where(inside, jax.random.normal(
+        keys[2], (rows, MOVER_WIDTH), dtype), 0)
+    d_rows = jnp.where(inside, jax.random.normal(
+        keys[3], (rows, MOVER_WIDTH), dtype), 0)
+    d_mixed = jax.random.normal(keys[4], (MOVER_TOKENS, MOVER_WIDTH), dtype)
+    return sent, flat, out, weight, d_rows, d_mixed
+
+
+def scatter_add_dispatch(flat, sent):
+    return flat[sent.pair // PER_TOKEN]
+
+
+def scatter_add_combine(out, weight, sent):
+    weighted = out.astype(jnp.float32) \
+        * weight.reshape(-1)[sent.pair][:, None]
+    return jnp.zeros((MOVER_TOKENS, MOVER_WIDTH), jnp.float32).at[
+        sent.pair // PER_TOKEN].add(weighted).astype(out.dtype)
+
+
+def close(got, want, dtype, what):
+    got, want = (np.asarray(a, np.float64) for a in (got, want))
+    limit = 2e-6 if dtype == jnp.float32 else 2.0 ** -6
+    assert np.abs(got - want).max() <= limit * max(1.0, np.abs(want).max()), \
+        (what, np.abs(got - want).max())
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("bound", BOUNDS.values(), ids=BOUNDS.keys())
+@pytest.mark.parametrize("shard", SHARDS, ids=str)
+def test_token_rows_to_buffer_matches_the_scatter_add_form(shard, bound,
+                                                           dtype):
+    sent, flat, _, _, d_rows, _ = routed(shard, bound, dtype)
+    if bound is not None and shard[1] == 1:
+        assert int(sent.rows_over_bound) > 0
+    got, back = jax.vjp(lambda f: token_rows_to_buffer(f, sent), flat)
+    want, back_scatter = jax.vjp(lambda f: scatter_add_dispatch(f, sent),
+                                 flat)
+    np.testing.assert_array_equal(got, want)
+    (d_flat,), (d_scatter,) = back(d_rows), back_scatter(d_rows)
+    assert d_flat.dtype == flat.dtype
+    close(d_flat, d_scatter, dtype, "d_flat")
+    exact = jax.vjp(lambda f: scatter_add_dispatch(f, sent),
+                    flat.astype(jnp.float32))[1](
+                        d_rows.astype(jnp.float32))[0]
+    assert rel(d_flat, exact) <= rel(d_scatter, exact) + 1e-7
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("bound", BOUNDS.values(), ids=BOUNDS.keys())
+@pytest.mark.parametrize("shard", SHARDS, ids=str)
+def test_buffer_rows_to_tokens_matches_the_scatter_add_form(shard, bound,
+                                                            dtype):
+    sent, _, out, weight, _, d_mixed = routed(shard, bound, dtype)
+    got, back = jax.vjp(lambda o, w: buffer_rows_to_tokens(o, w, sent),
+                        out, weight)
+    want, back_scatter = jax.vjp(
+        lambda o, w: scatter_add_combine(o, w, sent), out, weight)
+    assert got.dtype == out.dtype
+    close(got, want, dtype, "mixed")
+    (d_out, d_weight), (d_out_s, d_weight_s) = back(d_mixed), \
+        back_scatter(d_mixed)
+    assert d_out.dtype == out.dtype and d_weight.dtype == weight.dtype
+    close(d_out, d_out_s, dtype, "d_out")
+    close(d_weight, d_weight_s, jnp.float32, "d_weight")
+    # Pairs with no row in the buffer: exactly zero, not nearly.
+    assert float(jnp.abs(jnp.where(sent.valid, 0.0, d_weight)).max()) == 0.0
+
+
+@pytest.mark.parametrize("bound", BOUNDS.values(), ids=BOUNDS.keys())
+@pytest.mark.parametrize("shard", SHARDS, ids=str)
+def test_position_is_the_inverse_of_the_sorted_order(shard, bound):
+    sent = routed(shard, bound, jnp.float32)[0]
+    pairs = MOVER_TOKENS * PER_TOKEN
+    position = np.asarray(sent.position)
+    assert position.shape == (MOVER_TOKENS, PER_TOKEN)
+    assert sorted(position.reshape(-1).tolist()) == list(range(pairs))
+    valid = np.asarray(sent.valid).reshape(-1)
+    held = int(sent.group_sizes.sum())
+    assert valid.sum() == held == min(int(sent.rows_per_expert.sum()),
+                                      len(sent.pair))
+    flat_position = position.reshape(-1)
+    assert (flat_position[valid] < held).all()
+    assert (np.asarray(sent.pair)[flat_position[valid]]
+            == np.arange(pairs)[valid]).all()
+    # Inside the buffer the inverse holds for pairs of other shards too.
+    inside = flat_position < len(sent.pair)
+    assert (np.asarray(sent.pair)[flat_position[inside]]
+            == np.arange(pairs)[inside]).all()
+    assert (np.asarray(sent.token_of_row)
+            == np.asarray(sent.pair) // PER_TOKEN).all()
+
+
+def test_top_choices_backward_is_top_k_s():
+    probs = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(10),
+                                             (MOVER_TOKENS, EXPERTS)))
+    mix = jax.random.normal(jax.random.PRNGKey(11),
+                            (MOVER_TOKENS, PER_TOKEN))
+    got = jax.grad(lambda p: (top_choices(p, PER_TOKEN)[0] * mix).sum())(
+        probs)
+    want = jax.grad(lambda p: (jax.lax.top_k(p, PER_TOKEN)[0] * mix).sum())(
+        probs)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(top_choices(probs, PER_TOKEN)[1],
+                                  jax.lax.top_k(probs, PER_TOKEN)[1])
+
+
+@pytest.mark.parametrize("row_bound", [None, 1.0], ids=["every_pair", "1.0"])
+@pytest.mark.parametrize("shard", SHARDS, ids=str)
+def test_layer_and_its_gradient_lower_to_no_scatter(shard, row_bound):
+    """Rows move by gathers in both directions: neither the layer's program
+    nor its gradient's holds a scatter, for the whole layer or a shard, with
+    a bound or without."""
+    layer, params, x = layer_and_input(shard, row_bound=row_bound,
+                                       tokens=512)
+    text = jax.jit(jax.grad(
+        lambda p, x: layer.apply({"params": p}, x).sum(),
+        (0, 1))).lower(params, x).as_text()
+    assert "scatter" not in text and "while" not in text
+    assert text.count("gather") >= 4
 
 
 @pytest.mark.parametrize("sizes", [[10, 0, 30, 5], [0, 0, 0, 0],
