@@ -22,7 +22,7 @@ information (what a cold call costs, whether losses fall), not a benchmark.
 
 The phases go through the entry points a user calls — ``hvd.init``,
 ``build_train_step``, ``data_parallel_mesh``, the launcher — at the full
-width of the models the examples and ``bench.py`` train, with seeded random
+width of the models the examples train, with seeded random
 weights and one repeated seeded batch, and they read the compiled HLO for
 the kernels instead of trusting the call that asked for them.
 """
@@ -54,7 +54,8 @@ RING_IMPL_PHASES = ("ring4_ppermute", "ring4_fused", "ring4_rdma")
 # ResNet-50 as examples/jax_imagenet_resnet50.py trains it: a global batch
 # of 64 (the reference benchmark's 64 a device) at 224 x 224.
 RESNET_BATCH, RESNET_IMAGE = 64, 224
-# The LM of bench.py's transformer mode: 512 wide, 8 layers, 8 heads, bf16.
+# A small LM (the benchmark's cells run the published widths): 512 wide,
+# 8 layers, 8 heads, bf16.
 LM = dict(vocab_size=32768, d_model=512, n_layers=8, n_heads=8)
 LM_SHAPES = [(1024, 16), (8192, 2)]  # (seq, batch): 16k tokens a step
 RING_SEQ, RING_BATCH = 8192, 2       # 2048 rows a chip over four chips
@@ -381,7 +382,7 @@ def phase_resnet50(args) -> dict:
 
 def _lm(jax, mesh, seq: int, batch: int, seed: int, axis_name, spec,
         **model_kwargs):
-    """A TransformerLM train step at bench.py's width through
+    """A TransformerLM train step at ``LM``'s width through
     build_train_step; ``model_kwargs`` choose single-shard flash or a ring."""
     import jax.numpy as jnp
     import numpy as np

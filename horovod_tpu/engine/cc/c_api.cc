@@ -533,8 +533,8 @@ int hvd_tpu_steady_active() {
   return GlobalEngine()->SteadyActive() ? 1 : 0;
 }
 
-// Simulated-scale negotiation harness (bench.py
-// BENCH_MODEL=negotiation_scale): run `size` in-process engine ranks
+// Simulated-scale negotiation harness (tests/test_control_plane.py's
+// simscale tests): run `size` in-process engine ranks
 // over loopback and measure per-cycle negotiation latency star-vs-tree
 // and negotiated-vs-steady.  Writes a one-line JSON report into `out`
 // (truncated to out_len); returns 0 on success, 1 when the report

@@ -6,11 +6,12 @@ The role of the framework bindings in the reference (e.g.
 
 * **Inside `jit` / `shard_map`** (pass ``axis_name=``): `allreduce` lowers to
   `lax.psum`/`lax.pmean`, `allgather` to `lax.all_gather(tiled)`, and
-  `broadcast` to a masked `psum` — all compiled by XLA into async collectives
-  over ICI.  Fusion, scheduling, and compute/comm overlap are XLA's job here;
-  this path replaces the reference's background-engine hot loop
+  `broadcast` to a masked `psum` — all compiled by XLA into collectives over
+  ICI.  Fusion and scheduling are XLA's job here; this path replaces the
+  reference's background-engine hot loop
   (/root/reference/horovod/common/operations.cc:696-1229) for compiled
-  programs.
+  programs.  What XLA does with the gradient all-reduces on the chip (how
+  many, in which dtype, beside which compute) is in PERF.md section 6.
 * **Outside `jit`** (no ``axis_name``): values round-trip through the C++
   collective engine (negotiation, fusion, ring transport over DCN), the same
   substrate the numpy/torch APIs use.  This serves eager setup work —
@@ -80,6 +81,12 @@ def allreduce(tensor, average: bool = True, name: Optional[str] = None,
     allreduce is sum→identity / mean→divide-by-N; for still-varying values it
     is a real `psum`/`pmean`.  Either way the result is the reduction of the
     per-shard contributions — allreduce is idempotent, like the engine path.
+
+    So for the gradients of replicated parameters no collective is issued
+    here: the sum over replicas is the one autodiff inserted, taken where
+    each weight first meets the batch and in the dtype it has there (the
+    compute dtype, e.g. bfloat16, when the model casts its weights), and
+    this function only divides by the axis size.
     """
     if axis_name is not None:
         # One mesh axis or several (e.g. ("dp", "sp") for a 2-D mesh).
@@ -218,10 +225,15 @@ def DistributedOptimizer(optimizer, axis_name: Optional[str] = None,
     Counterpart of the reference's optimizer wrappers
     (/root/reference/horovod/tensorflow/__init__.py:134-208,
     horovod/torch/__init__.py:64-124).  Inside `shard_map` pass the mesh
-    ``axis_name``: the gradient average compiles to one XLA `psum` per leaf
-    which XLA fuses and overlaps with the backward pass — the compiled
-    equivalent of the reference's tensor fusion + backprop overlap.  Without
-    ``axis_name`` gradients are averaged eagerly through the engine.
+    ``axis_name``.  Under the default ``check_vma=True`` the gradients of
+    replicated parameters arrive already summed over the axis — by the
+    `psum` that `shard_map`'s autodiff inserts, see :func:`allreduce` — so
+    the wrapper divides by the axis size and issues no collective of its
+    own; a leaf that still varies gets a real `psum`.  XLA decides how
+    those all-reduces are grouped and scheduled: on a v5e host today each
+    runs synchronously, beside no compute (PERF.md section 6; ROADMAP S3,
+    D12).  Without ``axis_name`` gradients are averaged eagerly through the
+    engine.
     """
     import optax
 

@@ -15,10 +15,15 @@ fallback (docs/fault-tolerance.md#elastic-membership).
 The reference's usage recipe (/root/reference/README.md:80-105) — scale LR by
 size, wrap the optimizer, broadcast initial state — becomes one call here:
 ``build_train_step`` returns a jitted SPMD step in which each mesh shard
-computes gradients on its slice of the batch and `DistributedOptimizer`'s
-per-leaf `psum` averages them over ICI, overlapped with the backward pass by
-XLA (the compiled equivalent of the reference's hook-driven
-allreduce-during-backprop, /root/reference/horovod/torch/__init__.py:64-89).
+computes gradients on its slice of the batch and the update sees their
+mean over the mesh axis (the compiled counterpart of the reference's
+hook-driven allreduce-during-backprop,
+/root/reference/horovod/torch/__init__.py:64-89).  Under ``check_vma=True``
+the sum over replicas is the `psum` that `shard_map`'s autodiff inserts
+where each replicated weight first meets the batch — in the compute dtype
+there — and `DistributedOptimizer` only divides by the axis size.  XLA
+runs those all-reduces synchronously on a v5e host today (PERF.md section
+6; ROADMAP S3, D12).
 
 What the step says of itself (docs/timeline.md, docs/metrics.md).  In the
 compiled program, as ``jax.named_scope`` names that ride each operation's
@@ -318,6 +323,15 @@ def build_train_step(loss_fn: Callable, optimizer, mesh: Mesh,
     are replicated.  ``batch_spec`` (default ``P(axis_name)`` over every
     leaf) may be a pytree prefix of PartitionSpecs for batches mixing sharded
     data with replicated state (e.g. batch-norm statistics: ``P()``).
+
+    With ``check_vma=True`` (the default, and what every compiled TPU
+    program runs) the cross-replica gradient sum is the one autodiff
+    inserts, in the dtype each weight has where it first meets the batch,
+    and `DistributedOptimizer` divides by the axis size; with
+    ``check_vma=False`` autodiff inserts none and the step averages the
+    gradients itself with one `pmean` a leaf.  Where those all-reduces
+    land in the step on the chip is measured, not promised: PERF.md
+    section 6.
     """
     axis_name = axis_name or mesh.axis_names[0]
     if batch_spec is None:

@@ -3,10 +3,10 @@ model into ONE fused step-level op.
 
 Why: a ResNet-101 has 104 BatchNorm layers; flax's ``nn.BatchNorm`` updates
 each layer's running mean/var inside the module, which XLA compiles to
-~208 tiny elementwise kernels plus memory-space copies — measured 1.4 ms
-of pure per-op overhead per training step on v5e (docs/benchmarks.md,
-round-3 tuning log).  ``BatchStatsNorm`` instead *writes the raw batch
-statistics* into the ``batch_stats`` collection, and the training step
+~208 tiny elementwise kernels plus memory-space copies (what they cost a
+step is not measured on this machine; PERF.md).  ``BatchStatsNorm``
+instead *writes the raw batch statistics* into the ``batch_stats``
+collection, and the training step
 applies the EMA once over the whole flattened tree
 (:func:`ema_batch_stats`) — numerically identical to per-layer flax BN
 (same formula, same f32 stats), but 2 kernels instead of ~200.
@@ -78,11 +78,11 @@ class BatchStatsNorm(nn.Module):
         # Fold the normalize into a per-channel affine y = x*a + b with the
         # COEFFICIENTS in float32 and the per-element arithmetic in the
         # compute dtype: normalizing in f32 materializes a full f32 copy of
-        # every activation (measured ~40 convert_element_type kernels per
-        # ResNet-101 step, tools/profile_step.py), while the bf16 affine
-        # fuses into the producing conv's epilogue.  Stock flax BN computes
-        # the whole normalize in the compute dtype, so this is strictly
-        # more precise than the nn.BatchNorm path it interchanges with.
+        # every activation (a convert_element_type pass a layer), while the
+        # bf16 affine fuses into the producing conv's epilogue.  Stock flax
+        # BN computes the whole normalize in the compute dtype, so this is
+        # strictly more precise than the nn.BatchNorm path it interchanges
+        # with.
         a = lax.rsqrt(var + self.epsilon) * scale
         b = bias - mean * a
         x = x.astype(self.dtype)  # no-op for conv outputs already in dtype

@@ -135,8 +135,8 @@ class ResNet(nn.Module):
     small_inputs: bool = False  # CIFAR-style stem: 3x3/1, no maxpool
     # Step-level fused running-stats EMA (models/norm.py): the ~104 BN
     # layers' EMAs collapse into one op — the train step must then apply
-    # models.ema_batch_stats to the mutable update.  Same math, ~1.4 ms
-    # less per-op overhead per v5e step (docs/benchmarks.md).
+    # models.ema_batch_stats to the mutable update.  Same math, two
+    # kernels where there were two hundred.
     fused_ema: bool = False
 
     @nn.compact

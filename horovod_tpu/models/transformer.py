@@ -31,8 +31,8 @@ def _qkv_project(x, w):
 
     Functionally identical to slicing ``einsum('bsd,djhe->jbhse')`` —
     but under plain autodiff those three slices transpose to pad+concat
-    of the cotangents into a materialized j-stack (measured ~150 us/step
-    of pure copy in the LM profile).  The custom VJP computes dx as the
+    of the cotangents into a materialized j-stack (an activation-sized
+    copy a layer).  The custom VJP computes dx as the
     sum of three per-slot matmuls and dW by stacking only the (small)
     weight gradients, so no activation-sized stack is ever built."""
     q, k, v = jnp.einsum("bsd,djhe->jbhse", x, w)
@@ -62,12 +62,11 @@ def rope(x, positions, base: float = 10000.0, seq_dim: int = -2):
     """Rotary position embedding, ADJACENT-pair formulation: component
     pairs ``(x[2i], x[2i+1])`` rotate by the i-th frequency.  The pairs
     are reached by a free reshape view instead of the classic
-    [even half | odd half] split's two big slices + concatenate — XLA
-    then fuses the whole rotation into neighbouring ops (measured +6%
-    LM step time; docs/benchmarks.md round-3 log).  The two pairings are
-    the same function up to a fixed permutation of the q/k projections'
-    output axis — :func:`migrate_rope_pairing` converts checkpoints
-    trained under the old pairing exactly.
+    [even half | odd half] split's two big slices + concatenate, so XLA
+    can fuse the whole rotation into neighbouring ops (its effect on the
+    step is not measured on this machine; PERF.md).  The two pairings
+    are the same function up to a fixed permutation of the q/k
+    projections' output axis.
 
     ``positions``: (seq,) global token positions — global, so
     sequence-sharded shards stay consistent — or (batch, seq) when every
@@ -91,25 +90,6 @@ def rope(x, positions, base: float = 10000.0, seq_dim: int = -2):
     rotated = jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
                               axis=-1)
     return rotated.reshape(x.shape).astype(x.dtype)
-
-
-def _rope_half_pairing(x, positions, base: float = 10000.0,
-                       seq_dim: int = -2):
-    """The pre-round-3 [even half | odd half] pairing — kept as the
-    reference the rope-pairing migration test checks against."""
-    d = x.shape[-1]
-    half = d // 2
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]
-    shape = [1] * x.ndim
-    shape[seq_dim] = x.shape[seq_dim]
-    shape[-1] = half
-    cos = jnp.cos(angles).reshape(shape)
-    sin = jnp.sin(angles).reshape(shape)
-    x1, x2 = x[..., :half], x[..., half:]
-    rotated = jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return rotated.astype(x.dtype)
 
 
 class DecodeContext(NamedTuple):
@@ -289,8 +269,7 @@ class Attention(nn.Module):
         # One fused qkv projection whose einsum emits q/k/v *head-major*
         # ('jbhse'): XLA folds the seq<->head transpose into the matmul's
         # output layout, so no standalone copy passes appear around the
-        # attention kernel (they measured ~7% of the LM step at batch 16
-        # on v5e).  The inverse transpose folds into the output
+        # attention kernel.  The inverse transpose folds into the output
         # projection's einsum the same way.  Per-matrix fan-in init
         # matches separate q/k/v Dense layers (fan_in = d).
         w_qkv = self.param(
@@ -413,8 +392,9 @@ class TransformerLM(nn.Module):
     # the softmax-CE, and the two backward matmuls — and the backward
     # matmuls consume bf16 operands anyway).  next_token_loss upcasts to
     # f32 internally, so the only precision loss is one bf16 rounding of
-    # each logit (~0.4% relative); measured +9% tokens/s on v5e
-    # (docs/benchmarks.md round-4 log).
+    # each logit (~0.4% relative).  Against float32 storage its effect
+    # on the step is not measured on this machine: every LM cell of the
+    # benchmark stores bf16 logits (PERF.md).
     logits_dtype: Any = jnp.float32
     # Every layer's MLP as sparse experts (MoEConfig; d_ff is then unused),
     # RMSNorm of the whole q and k projections, and the epsilon of every
@@ -480,167 +460,6 @@ class TransformerLM(nn.Module):
         return logits
 
 
-# Param-layout version stamped into checkpoint wrappers by the migrators
-# (and checked by check_layout): 2 = fused qkv/o/lm_head kernels with the
-# legacy [even half | odd half] rope pairing, 3 = round-3 adjacent-pair
-# rope.  An unversioned tree that skips migrate_rope_pairing still loads
-# and runs — computing a silently different function — so loaders should
-# gate on check_layout rather than on users reading docstrings.
-LAYOUT_VERSION = 3
-
-
-def stamp_layout(variables, version: int = LAYOUT_VERSION):
-    """Return ``variables`` (a ``{"params": ...}``-style checkpoint
-    wrapper) with a ``layout`` collection recording the param-layout
-    version.  flax ``Module.apply`` ignores unused collections, so the
-    stamp rides along transparently; serializers persist it."""
-    if "params" not in variables:
-        raise ValueError("stamp_layout expects a {'params': ...} wrapper "
-                         "(the stamp must not live inside the param tree, "
-                         "where optimizers would treat it as a weight)")
-    return {**variables, "layout": {"version": version}}
-
-
-def check_layout(variables, strict: bool = False):
-    """Gate a loaded checkpoint wrapper on its layout stamp.
-
-    Unversioned trees (no ``layout`` collection) predate round 3 and run
-    under the adjacent-pair rope as a silently different function —
-    warn (or raise with ``strict=True``) and point at the migrators.
-    Returns ``variables`` unchanged so this can wrap a load expression.
-    """
-    version = variables.get("layout", {}).get("version")
-    version = None if version is None else int(version)
-    if version == LAYOUT_VERSION:
-        return variables
-    msg = (
-        "TransformerLM checkpoint has no current layout stamp "
-        f"(found version {version}, current {LAYOUT_VERSION}): trees "
-        "saved before round 3 use the legacy rope pairing and will "
-        "compute a DIFFERENT function if applied unmigrated.  Run "
-        "models.transformer.migrate_params(...) (structure) and "
-        "migrate_rope_pairing(...) (rope) once; both stamp the result."
-    )
-    if strict:
-        raise ValueError(msg)
-    import warnings
-
-    warnings.warn(msg)
-    return variables
-
-
-def migrate_params(params, n_heads: int):
-    """Convert a legacy TransformerLM param tree to the fused layout.
-
-    The fused projections renamed/reshaped parameters relative to earlier
-    revisions of this model (``qkv_kernel``/``o_kernel``/``lm_head_kernel``
-    replaced per-matrix ``q``/``k``/``v``/``o``/``lm_head`` Dense kernels,
-    and an interim revision's single ``qkv`` Dense).  This converter makes
-    old checkpoints loadable — the analogue of how ``SpaceToDepthStem``
-    kept the (7,7,C,F) conv param so ResNet checkpoints stayed loadable.
-
-    Accepts either a bare param dict or a ``{"params": ...}`` wrapper; the
-    layout is detected per-module, so already-migrated trees pass through
-    unchanged.  ``n_heads`` must match the model's head count (the fused
-    kernels are stored head-major).
-
-    Round-1/2 checkpoints were also trained under the old rope pairing:
-    after this structural conversion, apply
-    :func:`migrate_rope_pairing` once to reproduce their function under
-    the round-3 adjacent-pair rope exactly.
-    """
-    if "params" in params and isinstance(params["params"], dict):
-        # Structure migrated but rope still legacy: version 2 (the rope
-        # migrator upgrades the stamp to LAYOUT_VERSION).
-        return stamp_layout(
-            {**params, "params": migrate_params(params["params"], n_heads)},
-            version=2)
-
-    def fuse_attention(attn):
-        if "qkv" in attn:  # interim fused (d, 3d) Dense
-            w = attn["qkv"]["kernel"]
-            d = w.shape[0]
-            qkv = w.reshape(d, 3, n_heads, d // n_heads)
-        elif all(k in attn for k in ("q", "k", "v")):  # per-matrix Dense
-            ws = [attn[k]["kernel"] for k in ("q", "k", "v")]
-            d = ws[0].shape[0]
-            qkv = jnp.stack(ws, axis=1).reshape(d, 3, n_heads,
-                                                d // n_heads)
-        else:
-            return attn  # already fused
-        # Old o Dense consumed the (h, hd)-flattened attention output, so
-        # its input dim unflattens head-major.
-        wo = attn["o"]["kernel"]
-        o = wo.reshape(n_heads, wo.shape[0] // n_heads, wo.shape[1])
-        rest = {key: val for key, val in attn.items()
-                if key not in ("q", "k", "v", "qkv", "o")}
-        return {**rest, "qkv_kernel": qkv, "o_kernel": o}
-
-    out = {}
-    for key, val in params.items():
-        if key == "lm_head" and isinstance(val, dict) and "kernel" in val:
-            out["lm_head_kernel"] = val["kernel"]
-        elif isinstance(val, dict) and ("qkv" in val or "q" in val):
-            out[key] = fuse_attention(val)
-        elif isinstance(val, dict):
-            out[key] = migrate_params(val, n_heads)
-        else:
-            out[key] = val
-    return out
-
-
-def migrate_rope_pairing(params, n_heads: int):
-    """Convert a checkpoint trained under the pre-round-3 rope pairing
-    ([even half | odd half]) to the adjacent-pair formulation, EXACTLY:
-    the pairings differ by a fixed permutation P of the q/k projections'
-    head_dim axis (``new_rope(P x) = P old_rope(x)`` and attention scores
-    are invariant under a shared q/k permutation), so permuting
-    ``qkv_kernel``'s q and k slots reproduces the old model's function to
-    the bit.  v and the output projection are untouched (no rope).
-    Accepts a bare param dict or a ``{"params": ...}`` wrapper.  Apply
-    ONCE per checkpoint (it is its own inverse only for head_dim == 2).
-    """
-    if "params" in params and isinstance(params["params"], dict):
-        return stamp_layout(
-            {**params,
-             "params": migrate_rope_pairing(params["params"], n_heads)})
-
-    converted = [0]
-
-    def permute(tree):
-        out = {}
-        for key, val in tree.items():
-            if isinstance(val, dict) and "qkv_kernel" in val:
-                w = val["qkv_kernel"]  # (d, 3, heads, head_dim)
-                if w.shape[-2] != n_heads:
-                    raise ValueError(
-                        f"qkv_kernel has {w.shape[-2]} heads, caller said "
-                        f"n_heads={n_heads}")
-                head_dim = w.shape[-1]
-                half = head_dim // 2
-                # new output 2i <- old i ; 2i+1 <- old i+half.
-                idx = jnp.stack([jnp.arange(half),
-                                 jnp.arange(half) + half],
-                                axis=1).reshape(-1)
-                qk = w[:, :2, :, :][..., idx]
-                out[key] = {**val,
-                            "qkv_kernel": jnp.concatenate(
-                                [qk, w[:, 2:, :, :]], axis=1)}
-                converted[0] += 1
-            elif isinstance(val, dict):
-                out[key] = permute(val)
-            else:
-                out[key] = val
-        return out
-
-    out = permute(params)
-    if not converted[0]:
-        raise ValueError(
-            "no qkv_kernel found: this tree is still in a legacy layout "
-            "— run migrate_params(...) first, then migrate_rope_pairing")
-    return out
-
-
 def fused_next_token_loss(hidden, w, targets, dtype=jnp.bfloat16,
                           n_chunks: int = 8):
     """Mean cross-entropy computed head-chunk by head-chunk.
@@ -655,10 +474,10 @@ def fused_next_token_loss(hidden, w, targets, dtype=jnp.bfloat16,
     invokes this when ``targets`` is passed to ``__call__``.)
 
     This trades one extra head matmul (the remat recompute) for the logits
-    round-trips: measured on v5e at vocab 32k / batch 8 it is ~8% *slower*
-    than the full-logits path, so use it when the logits tensor does not
-    fit comfortably (long sequences, big vocab, large batch), not as a
-    throughput knob.
+    round-trips, so use it when the logits tensor does not fit comfortably
+    (long sequences, big vocab, large batch), not as a throughput knob: no
+    benchmark cell runs it, and its speed against the full-logits path is
+    not measured on this machine (PERF.md section 7).
     """
     B, S, D = hidden.shape
     tokens = B * S

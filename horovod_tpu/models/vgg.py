@@ -3,8 +3,7 @@
 VGG-16 is one of the reference's three published scaling benchmarks
 (68% efficiency at 512 GPUs, /root/reference/README.md:50,
 docs/benchmarks.md:6) — the hard case, being parameter-heavy: its ~138M
-parameters stress gradient-exchange bandwidth, which is exactly what
-tensor fusion / XLA collective overlap are for.
+parameters stress gradient-exchange bandwidth.
 
 NHWC, bfloat16 compute, float32 params; classifier matches the original
 (4096-4096-classes with dropout).

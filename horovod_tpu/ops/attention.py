@@ -493,8 +493,8 @@ def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
     Grid: (bh, ki, qi) — queries innermost so dk/dv accumulate in scratch
     and flush per key block; dq accumulates in a whole-sequence VMEM
     scratch and flushes once per bh row.  The split dkdv/dq kernel pair
-    pays the s/p/dp/ds recompute twice; sharing it here nearly halves the
-    backward's kernel time (measured on v5e, docs/benchmarks.md r4).
+    pays the s/p/dp/ds recompute twice; here it is paid once (kernel
+    times on today's chip: PERF.md sections 5 and 7).
 
     With ``rotate=True`` this is the fused ring-flash backward step
     (ops/ring_flash.py): the K/V rotation DMA to the right neighbour
@@ -736,7 +736,7 @@ def _pick_block(seq_len: int, maximum: int = 512) -> int:
 
 def _vmem_budget_bytes() -> int:
     """Scoped-VMEM planning budget, bytes.  Default 16 MiB — the v5e
-    scoped-allocation ceiling the r5 sweep calibrated against;
+    scoped-allocation ceiling the compile sweep calibrated against;
     ``HVD_TPU_VMEM_LIMIT_MB`` overrides it for chips with different
     scoped capacity (or to leave headroom under other scoped users)."""
     return int(float(os.environ.get("HVD_TPU_VMEM_LIMIT_MB") or 16.0)
@@ -752,10 +752,11 @@ def _plan_vmem_bytes(mode: str, q_len: int, d: int, block_q: int,
     double-buffered at f32 width with head_dim padded to the 128-lane
     tile, the combined kernel's whole-seq dq charged three ways (scratch
     + a double-buffered output window — the term whose growth is exactly
-    the BENCH_r04 seq-8192 OOM).  Calibrated against the r5 sweep: every
-    measured-pass band lands under 16 MiB here and the measured 23.2 MiB
-    seq-8192/1024-block failure lands over, so clamping to this estimate
-    can only reject plans the frontier also rejects."""
+    the seq-8192 compile failure).  Calibrated against the compile sweep
+    (tools/vmem_sweep.py): every measured-pass band lands under 16 MiB
+    here and the measured 23.2 MiB seq-8192/1024-block failure lands
+    over, so clamping to this estimate can only reject plans the frontier
+    also rejects."""
     lanes = max(d, 128)
     w, db = 4, 2              # f32 worst case; double-buffered windows
     lse = db * w * 8 * 2 * block_q          # lse8 + delta8 windows
@@ -816,8 +817,9 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
     """Choose the flash-backward execution mode and blocks against the
     chip's 16 MiB scoped-VMEM ceiling.
 
-    Calibrated by on-chip compile sweep, v5e r5 (tools/vmem_sweep.py;
-    docs/benchmarks.md).  Mosaic's scoped allocation for the combined
+    Calibrated by a compile sweep for v5e (tools/vmem_sweep.py; the
+    bands the benchmark's cells use are compiled for a described chip in
+    tests/test_ops.py).  Mosaic's scoped allocation for the combined
     kernel is NOT a simple closed form — it grows with the whole-seq dq
     scratch (head_dim <= 128 pads to 128 lanes, so sequence length
     enters as ``q_len * max(d, 128)``), with block size, and
@@ -844,11 +846,12 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
     =====================  ==========  =============================
 
     ``mode`` is ``"combined"`` (one probability recompute per block,
-    whole-seq dq scratch — fastest where it fits: measured ~15% over
-    split at seq 8192) or ``"split"`` (dkdv + dq kernel pair, O(block)
-    scoped memory: full 1024-blocks compile at every probed extreme —
-    seq to 64k, bh to 256, d to 256 — and beat 512-blocks by ~12% at
-    seq 16k)."""
+    whole-seq dq scratch — preferred where it fits because it recomputes
+    once; every benchmark cell runs it, PERF.md section 3) or ``"split"``
+    (dkdv + dq kernel pair, O(block) scoped memory: full 1024-blocks
+    compile at every probed extreme — seq to 64k, bh to 256, d to 256).
+    Split against combined is not measured on this machine: no cell
+    reaches the split plan (PERF.md section 7)."""
     rows128 = q_len * max(d, 128) // 128
     if d <= 128:
         # Each band is gated at its CALIBRATED bh bound (the table
@@ -980,9 +983,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
     lse8 = jnp.broadcast_to(lse.reshape(bh, q_len)[:, None, :],
                             (bh, 8, q_len))
     # Gradients emitted directly in the input dtype, with the pow2 dq
-    # rescale folded into the kernels' f32 flush: the XLA-side
-    # cast+relayout and rescale passes over the 3 gradients measured
-    # ~100 us/layer of pure copy time in the seq-1024 LM step.  The
+    # rescale folded into the kernels' f32 flush, which saves XLA-side
+    # cast+relayout and rescale passes over the 3 gradients.  The
     # f32-multiply-then-cast order also keeps narrow-exponent dtypes
     # (fp16) finite where cast-then-scale could overflow in q' units.
     # Mixed input dtypes keep the old f32 emission (dk must not round
@@ -1115,14 +1117,14 @@ def flash_attention(q, k, v, causal: bool = False,
     ``tpu_custom_call`` (chip_smoke.py does).  Differentiable
     with the flash backward (logsumexp residual + per-block recompute,
     O(seq) memory).  Default blocks: up to 1024 each, the largest
-    candidate dividing the sequence — measured on v5e at seq 1024,
-    1024-row query blocks beat 512 by ~5% fwd+bwd (grid overhead
-    amortizes) and whole-k key blocks skip the online-softmax rescale
-    (the kernel's single_k path).  The BACKWARD re-plans blocks per
-    shape against the 16 MiB scoped-VMEM ceiling and switches to the
-    split dkdv/dq kernel pair for long sequences (see :func:`_bwd_plan`
-    — the r4 regression was exactly a tuned-block choice that did not
-    compile at seq 8192).
+    candidate dividing the sequence — larger blocks amortize the fixed
+    cost of a grid step (the static schedule for a described v5e counts
+    fewest bundles per pair at (1024, 1024): PERF.md section 7) and
+    whole-k key blocks skip the online-softmax rescale (the kernel's
+    single_k path).  The BACKWARD re-plans blocks per shape against the
+    16 MiB scoped-VMEM ceiling and switches to the split dkdv/dq kernel
+    pair for long sequences (see :func:`_bwd_plan`: a tuned block choice
+    that fits the forward need not compile for the backward at seq 8192).
     """
     if layout not in ("bhsd", "bshd"):
         raise ValueError(f"unknown layout {layout!r}")
@@ -1148,11 +1150,9 @@ def flash_attention(q, k, v, causal: bool = False,
     # compiled for a described v5e, tests/test_ops.py), whatever the
     # structural estimates say (ADVICE r5 #2).
     if block_q is None or block_q > _MAX_BLOCK:
-        # 1024-row query blocks: the kernels are grid-overhead-bound at
-        # these shapes (~3-5 us of fixed cost per grid step against ~1.4
-        # us of MXU work), so halving the grid beats smaller tiles —
-        # measured r4 at seq 1024: fwd 965 -> 687 us/call, fwd+bwd -5%
-        # vs 512-row blocks.  VMEM peaks ~2 MB at head_dim 64.
+        # 1024-row query blocks: a grid step has a fixed cost, so the
+        # largest block that compiles does the fewest of them (bundles
+        # per pair by block shape: PERF.md section 7).
         block_q = _pick_block(q.shape[-2], maximum=_MAX_BLOCK)
     if block_k is None or block_k > _MAX_BLOCK:
         # Whole-k key blocks skip the online-softmax rescale entirely

@@ -5,8 +5,9 @@ Transformers, arXiv:2310.01889), absent from the reference (SURVEY §5.7)
 and added here as a first-class TPU capability: the sequence dimension is
 sharded over the mesh axis; each device keeps its query shard and passes
 its key/value shard around the ring with `lax.ppermute` (which XLA lowers
-to ICI neighbour transfers overlapped with the attention compute), merging
-partial results with the same online-softmax statistics the flash kernel
+to ICI neighbour transfers it may schedule beside the attention compute;
+none of the three rotations is timed on the chip yet, PERF.md section 7),
+merging partial results with the same online-softmax statistics the flash kernel
 uses.  Peak memory per device is O(seq/N) — context length scales linearly
 with the ring size.
 
@@ -42,15 +43,15 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
       causal: apply a causal mask over *global* positions.
       sm_scale: softmax scale; default ``head_dim ** -0.5``.
       rotate_impl: how K/V shards travel the ring — ``"ppermute"`` (XLA
-        collective permute, default: the compiler schedules it as an async
-        start/done pair overlapped with compute), ``"rdma"``
+        collective permute, default: the compiler schedules it, as an
+        async start/done pair where it chooses to), ``"rdma"``
         (:func:`horovod_tpu.ops.rdma.ring_permute`: one raw Pallas remote
         DMA per rotation, for hardware where explicit transfer control
         beats XLA's scheduling), or ``"fused"``
         (:func:`horovod_tpu.ops.ring_flash.fused_ring_attention`: ONE
         Pallas program per ring step that starts the rotation DMA, flash-
-        attends the current shard while it flies, and waits at the end —
-        overlap by construction; shapes it cannot run raise
+        attends the current shard while it flies, and waits at the end;
+        shapes it cannot run raise
         ``FusedRingUnsupported``).  Differentiable in every mode.  Which
         rotation a compiled program really holds is read from its HLO:
         ``collective-permute`` for ppermute, ``tpu_custom_call`` for the

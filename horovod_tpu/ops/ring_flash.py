@@ -1,4 +1,4 @@
-"""Fused ring-flash attention: rotation DMA overlapped inside the kernel.
+"""Fused ring-flash attention: rotation DMA issued inside the kernel.
 
 The separable ring attention (`ops/ring_attention.py`) alternates
 whole-shard rotate (ppermute / rdma) and whole-shard attend steps; XLA can
@@ -9,8 +9,10 @@ current K/V shard to the right neighbour, computes the shard's flash
 attention while the DMA flies, and *waits* for the transfer only at the
 final grid step — the start-DMA → attend → wait-DMA pattern of hand-
 written TPU collective kernels (cf. the collective-matmul examples in the
-Pallas guide).  Communication latency hides behind the attention compute
-by construction, not by scheduler luck.
+Pallas guide).  The transfer is issued before the attention compute and
+awaited after it by construction, not by scheduler luck; how much of it
+the compute hides on the chip is not measured (no benchmark cell runs a
+ring: PERF.md section 7).
 
 Per ring step the kernel returns the shard-local attention output and its
 per-row logsumexp; consecutive steps merge at the JAX level with the

@@ -9,10 +9,10 @@ Two halves, split so the scheduler stays a pure-Python unit:
   vLLM/PagedAttention memory model (Kwon et al., SOSP'23) over our engine.
 
 * The paged device store — ONE packed buffer for every layer's K and V
-  (``(n_layers, 2, num_blocks + 1, block_tokens, heads, head_dim)``), the
-  TreePacker move (models/packing.py) applied to the KV cache: 2·L·B
-  per-sequence tensors become one array, gathered per step by block table
-  and scattered by (block, offset).  The last block is a write-off target:
+  (``(n_layers, 2, num_blocks + 1, block_tokens, heads, head_dim)``):
+  2·L·B per-sequence tensors become one array, so the jitted step carries
+  one argument where it would carry hundreds, gathered per step by block
+  table and scattered by (block, offset).  The last block is a write-off target:
   masked lanes of a scatter and table padding both land there, so the
   jitted decode step keeps a fixed shape regardless of which slots are
   live.  :func:`gather_context` / :func:`scatter_new` are pure ``jnp``

@@ -5,11 +5,10 @@ params, interplay with the negotiation response cache across a
 fusion-threshold change (no stale-fusion replay), HVD_TPU_AUTOTUNE_FIX
 pinning, manual injection (hvd.autotune_set), and — the part that must
 never regress — the tuner-off default leaves every existing contract
-untouched.  Plus units for the env-spec parsing, the snapshot/Prometheus
-surface, and tools/bench_compare.py.
+untouched.  Plus units for the env-spec parsing and the
+snapshot/Prometheus surface.
 """
 
-import json
 import os
 import sys
 
@@ -270,7 +269,7 @@ def test_autotune_set_is_rank0_only():
 
 
 # ---------------------------------------------------------------------------
-# Units: env-spec parsing, report shape, metrics surface, bench_compare.
+# Units: env-spec parsing, report shape, metrics surface.
 # ---------------------------------------------------------------------------
 
 
@@ -350,92 +349,3 @@ def test_fusion_grid_mirror_is_log_spaced():
     assert FUSION_GRID[-1] == 256 * 1024 * 1024
     assert 64 * 1024 * 1024 in FUSION_GRID  # the engine default
     assert 5.0 in CYCLE_GRID_MS             # the engine default
-
-
-def test_bench_compare(tmp_path):
-    from tools.bench_compare import load_record, main
-
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps({"metric": "m", "value": 100.0,
-                               "extra_metrics": {"a": 10, "flag": True}}))
-    new.write_text(json.dumps({"metric": "m", "value": 96.0,
-                               "extra_metrics": {"a": 5, "flag": False}}))
-    # 4% off with a 10% threshold: fine; extras not gated by default.
-    assert main([str(old), str(new)]) == 0
-    # 50% regression in an extra fails only with --extras (bools never
-    # compare).
-    assert main([str(old), str(new), "--extras"]) == 1
-    assert main([str(old), str(new), "--threshold", "2"]) == 1
-    # Driver round records (BENCH_r*.json) unwrap via "parsed"; bench.py
-    # JSONL output takes the last (most enriched) line.
-    wrapped = tmp_path / "driver.json"
-    wrapped.write_text(json.dumps(
-        {"rc": 0, "parsed": {"metric": "m", "value": 100.0}}))
-    assert load_record(str(wrapped))["value"] == 100.0
-    lines = tmp_path / "lines.json"
-    lines.write_text('not json\n'
-                     '{"metric": "m", "value": 1.0}\n'
-                     '{"metric": "m", "value": 2.0, "extra_metrics": {}}\n')
-    assert load_record(str(lines))["value"] == 2.0
-    # Different headline metrics are reported, not silently compared.
-    other = tmp_path / "other.json"
-    other.write_text(json.dumps({"metric": "x", "value": 1.0}))
-    assert main([str(old), str(other)]) == 0
-    missing = tmp_path / "missing.json"
-    assert main([str(old), str(missing)]) == 2
-    # Latency extras (unit suffix) gate in the OPPOSITE direction: growth
-    # is the regression (the serving bench's TTFT/per-token metrics),
-    # shrinkage is an improvement.
-    lat_old = tmp_path / "lat_old.json"
-    lat_new = tmp_path / "lat_new.json"
-    lat_old.write_text(json.dumps({"metric": "m", "value": 100.0,
-                                   "extra_metrics": {"ttft_p99_ms": 10.0}}))
-    lat_new.write_text(json.dumps({"metric": "m", "value": 100.0,
-                                   "extra_metrics": {"ttft_p99_ms": 20.0}}))
-    assert main([str(lat_old), str(lat_new), "--extras"]) == 1
-    assert main([str(lat_new), str(lat_old), "--extras"]) == 0
-    # The unit token must not catch rates ("per" prefix) and must catch
-    # mid-name units (the cache bench's negotiation_p50_us_cached).
-    from tools.bench_compare import lower_is_better
-    assert not lower_is_better("cache_off_ops_per_sec")
-    assert not lower_is_better("tokens_per_sec")
-    assert lower_is_better("negotiation_p50_us_cached")
-    assert lower_is_better("token_p50_ms")
-    assert not lower_is_better("cache_hit_rate")
-
-
-def test_bench_compare_history(tmp_path):
-    """Satellite: `bench_compare.py --history BENCH_r0*.json` renders the
-    round-over-round trajectory — one line per driver round record, with
-    deltas computed across gaps (a round whose `parsed` is null, like the
-    real BENCH_r04.json, renders as a gap line and is skipped)."""
-    from tools.bench_compare import main, render_history
-
-    rounds = []
-    for i, parsed in enumerate([
-            {"metric": "steady_p50", "value": 100.0, "unit": "us",
-             "vs_baseline": 1.0},
-            {"metric": "steady_p50", "value": 80.0, "unit": "us",
-             "vs_baseline": 1.25},
-            None,  # a crashed round: rc nonzero, nothing parsed
-            {"metric": "steady_p50", "value": 60.0, "unit": "us",
-             "vs_baseline": 1.67}]):
-        p = tmp_path / f"BENCH_r{i + 1:02d}.json"
-        p.write_text(json.dumps({"n": i + 1, "rc": 0 if parsed else 1,
-                                 "parsed": parsed}))
-        rounds.append(str(p))
-    lines, parsed_rounds = render_history(rounds)
-    assert parsed_rounds == 3
-    text = "\n".join(lines)
-    assert "BENCH_r03.json" in text and "no parsed record, rc 1" in text
-    # Delta of round 2 vs round 1: 80 vs 100 = -20%; round 4's delta
-    # skips the gap and compares against round 2 (60 vs 80 = -25%).
-    assert "-20.0%" in text and "-25.0%" in text, text
-    assert "1.25x" in text and "1.67x" in text, text
-    # CLI: exit 0 with at least one parseable round, 2 with none.
-    assert main(["--history"] + rounds) == 0
-    empty = tmp_path / "BENCH_r99.json"
-    empty.write_text(json.dumps({"rc": 1, "parsed": None}))
-    assert main(["--history", str(empty)]) == 2
-    assert main(["--history"]) == 2  # no files at all

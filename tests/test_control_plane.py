@@ -498,33 +498,68 @@ def _simscale(size, local, ops, warm, steady, threshold, tree, timeout=60.0):
     raise AssertionError(f"simscale failed after retries: {rep}")
 
 
-def test_simscale_smoke_tree_steady():
+# The simulated engines read their switches from the real environment at
+# Init, as launched ranks do; os.environ writes reach the in-process C++
+# getenv through putenv.
+_SIMSCALE_SWITCHES = {
+    "on": {"HVD_TPU_HEARTBEAT_MS": "20"},
+    "heartbeat_off": {"HVD_TPU_HEARTBEAT_MS": "0"},
+    "introspection_off": {"HVD_TPU_HEARTBEAT_MS": "20",
+                          "HVD_TPU_LINK_STATS": "0",
+                          "HVD_TPU_ANOMALY_SIGMA": "0"},
+}
+
+
+@pytest.mark.parametrize("switches", sorted(_SIMSCALE_SWITCHES))
+def test_simscale_smoke_tree_steady(switches, monkeypatch):
     """8 in-process ranks, 2 per simulated host: the tree builds (rank 0
     reads 4 children: 1 node-0 worker + 3 sub-coordinators), steady
-    arms, and the steady window moves ZERO control frames."""
-    rep = _simscale(8, 2, ops=2, warm=25, steady=10, threshold=4, tree=True)
+    arms, the steady window moves ZERO control frames, and rank 0's init
+    clock sync probes its direct children only (O(hosts), never the
+    star's every-rank probe).  Each liveness/introspection switch turned
+    off really is off: no heartbeat frame is sent with the detector
+    disabled, and link accounting (process-cumulative) does not grow
+    over a fleet run with HVD_TPU_LINK_STATS=0."""
+    for key, value in _SIMSCALE_SWITCHES[switches].items():
+        monkeypatch.setenv(key, value)
+    before = None
+    if switches == "introspection_off":
+        # A short fleet under the same switches reads the counter as it
+        # stands; the measured run must leave it there.
+        before = _simscale(4, 2, ops=1, warm=2, steady=1, threshold=0,
+                           tree=True)["link_sends"]
+    rep = _simscale(8, 2, ops=2, warm=25, steady=30, threshold=4, tree=True)
     assert rep["steady_entered"] == 1, rep
     assert rep["steady_frames_delta"] == 0, rep
     assert rep["coord_children"] == 4, rep
     assert rep["steady_cycles"] > 0, rep
+    assert 0 < rep["clock_fanin"] <= 4 + 2, rep  # hosts + local ranks
+    if switches == "heartbeat_off":
+        assert rep["hb_frames_sent"] == 0, rep
+    else:
+        assert rep["hb_frames_sent"] > 0, rep
+    if switches == "introspection_off":
+        assert rep["link_sends"] == before, (before, rep)
+    else:
+        assert rep["link_sends"] > 0, rep
 
 
 def test_simscale_star_baseline_negotiates_every_cycle():
     """The same fleet with the tree and steady disabled keeps the star:
-    rank 0 reads every worker and every cycle moves frames — the
-    baseline curve the scale bench compares against."""
+    rank 0 reads every worker, probes every worker's clock, and every
+    cycle moves frames."""
     rep = _simscale(8, 2, ops=2, warm=15, steady=8, threshold=0, tree=False)
     assert rep["steady_entered"] == 0, rep
     assert rep["coord_children"] == 7, rep
+    assert rep["clock_fanin"] == 7, rep
     assert rep["steady_frames_delta"] > 0, rep
 
 
 @pytest.mark.slow
 def test_simscale_steady_flat_in_ranks():
-    """Scale acceptance shape (the bench runs the full 16-vs-256 sweep;
-    tier-1 keeps a smaller, budget-friendly pair): steady-cycle p50 at
-    64 simulated ranks within 1.5x of 16 ranks, while the star's
-    negotiated cycles grow several-fold over the same span."""
+    """Scale acceptance shape (a budget-friendly pair of sizes):
+    steady-cycle p50 at 64 simulated ranks within 1.5x of 16 ranks, while
+    the star's negotiated cycles grow several-fold over the same span."""
     small = _simscale(16, 4, ops=2, warm=30, steady=25, threshold=6,
                       tree=True, timeout=90.0)
     large = _simscale(64, 8, ops=2, warm=30, steady=25, threshold=6,

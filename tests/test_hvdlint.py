@@ -195,7 +195,6 @@ def _env_tree(tmp_path, doc=_DOC, config=_CONFIG, extra_py=""):
            config + "\nimport os\nK = os.environ.get(\"HVD_TPU_KNOB\")\n")
     if extra_py:
         _write(root, "horovod_tpu/extra.py", extra_py)
-    _write(root, "bench.py", "")
     return root
 
 
@@ -500,8 +499,6 @@ def _scratch_copy(tmp_path):
                     os.path.join(root, "docs"), ignore=ignore)
     shutil.copytree(os.path.join(REPO, "tools"),
                     os.path.join(root, "tools"), ignore=ignore)
-    shutil.copy(os.path.join(REPO, "bench.py"),
-                os.path.join(root, "bench.py"))
     return root
 
 

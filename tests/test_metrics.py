@@ -501,7 +501,7 @@ def test_skew_section_and_prometheus_families():
 
 def _fully_populated_registry():
     """One of everything, so every exposition family renders (shared by
-    the conformance test and mirroring tools/check_metric_names.py)."""
+    the conformance test and mirroring tools/hvdlint/metrics_check.py)."""
     from horovod_tpu.common import metrics
 
     reg = metrics.MetricsRegistry()
@@ -608,13 +608,9 @@ def test_check_metric_names_lint():
     import sys
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ,
-               PYTHONPATH=repo + os.pathsep + os.environ.get(
-                   "PYTHONPATH", ""))
     proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools",
-                                      "check_metric_names.py")],
-        capture_output=True, text=True, env=env, timeout=60)
+        [sys.executable, "-m", "tools.hvdlint", "metrics"],
+        capture_output=True, text=True, cwd=repo, timeout=60)
     assert proc.returncode == 0, proc.stderr
     assert "OK" in proc.stdout, proc.stdout
 
@@ -622,14 +618,8 @@ def test_check_metric_names_lint():
 def test_check_metric_names_lint_detects_violations():
     """The lint rejects camelCase, missing prefixes, and duplicates (a
     lint that passes everything would let names drift silently)."""
-    import importlib.util
+    from tools.hvdlint import metrics_check as mod
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "check_metric_names",
-        os.path.join(repo, "tools", "check_metric_names.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
     bad = ("# HELP hvd_tpu_camelCase_total x\n"
            "# TYPE hvd_tpu_camelCase_total counter\n"
            "hvd_tpu_camelCase_total 1\n"

@@ -106,7 +106,7 @@ def test_flash_gradients_multiblock(causal):
 
 def test_bwd_plan_matches_vmem_calibration():
     """The backward block plan must reproduce the v5e scoped-VMEM compile
-    sweep (r5 calibration, tools/vmem_sweep.py, docs/benchmarks.md): the
+    sweep (tools/vmem_sweep.py): the
     combined kernel's viability depends on sequence rows, head width AND
     the batch*heads grid dim (measured non-monotonic), so the plan bands
     are pinned exactly.  The r4 regression — tuned 1024-blocks that
@@ -141,7 +141,7 @@ def test_bwd_plan_matches_vmem_calibration():
 def test_bwd_plan_fits_vmem_budget(monkeypatch):
     """Every plan the block selection emits must fit the COMPUTED
     scoped-VMEM estimate — the backstop behind the calibrated bands
-    (the BENCH_r04 seq-8192 OOM was a tuned block choice whose scoped
+    (the seq-8192 compile failure was a tuned block choice whose scoped
     footprint nobody computed).  Long-context shapes 8192/16384 are the
     regression region."""
     import horovod_tpu.ops.attention as attn

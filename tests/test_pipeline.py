@@ -223,8 +223,7 @@ def test_local_pipeline_matches_full_model(n_stages, n_chunks):
 def test_send_recv_roundtrip():
     import os
     # Metrics ON: the gated Python-side recording paths (Handle wait
-    # latency, negotiation histogram) must accept p2p ops — the regime
-    # BENCH_MODEL=pipeline runs in.
+    # latency, negotiation histogram) must accept p2p ops.
     os.environ["HVD_TPU_METRICS"] = "1"
     import horovod_tpu as hvd
 

@@ -360,7 +360,7 @@ def test_failed_requests_keep_their_trace():
 
 # ---------------------------------------------------------------------------
 # Tooling: postmortem_dump.py rendering, failure_report pointers, and the
-# extended check_metric_names section lint.
+# extended metric-name section lint.
 # ---------------------------------------------------------------------------
 
 
@@ -428,8 +428,7 @@ def test_failure_report_postmortem_pointers(tmp_path):
 
 
 def test_check_metric_names_section_lint():
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    import check_metric_names as lint_tool
+    from tools.hvdlint import metrics_check as lint_tool
     from horovod_tpu.common import metrics
 
     snapshot = lint_tool.populated_registry().snapshot()

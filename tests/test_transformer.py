@@ -154,98 +154,6 @@ def test_dp_sp_train_step():
     assert losses[-1] < losses[0], losses  # tiny model memorizes the batch
 
 
-def test_migrate_params_legacy_checkpoints():
-    """migrate_params converts both legacy layouts (per-matrix q/k/v/o
-    Dense kernels; interim fused qkv Dense) into the head-major fused
-    layout, producing a tree the current model accepts and that computes
-    the same attention math (ADVICE r2: checkpoint migration path)."""
-    from horovod_tpu.models.transformer import migrate_params
-
-    model = _model()
-    tokens = _tokens()
-    params = model.init(jax.random.PRNGKey(2), tokens)["params"]
-    want = model.apply({"params": params}, tokens)
-
-    def to_legacy(tree, fused_qkv):
-        out = {}
-        for key, val in tree.items():
-            if isinstance(val, dict) and "qkv_kernel" in val:
-                w = val["qkv_kernel"]  # (d, 3, h, hd)
-                d = w.shape[0]
-                o = val["o_kernel"].reshape(d, -1)
-                if fused_qkv:
-                    out[key] = {"qkv": {"kernel": w.reshape(d, 3 * d)},
-                                "o": {"kernel": o}}
-                else:
-                    per = w.reshape(d, 3, d)
-                    out[key] = {
-                        "q": {"kernel": per[:, 0]},
-                        "k": {"kernel": per[:, 1]},
-                        "v": {"kernel": per[:, 2]},
-                        "o": {"kernel": o}}
-            elif isinstance(val, dict):
-                out[key] = to_legacy(val, fused_qkv)
-            else:
-                out[key] = val
-        out2 = {}
-        for key, val in out.items():
-            if key == "lm_head_kernel":
-                out2["lm_head"] = {"kernel": val}
-            else:
-                out2[key] = val
-        return out2
-
-    for fused_qkv in (False, True):
-        legacy = to_legacy(params, fused_qkv)
-        migrated = migrate_params(legacy, n_heads=4)
-        # Exact same tree (structure and values) as the native init.
-        assert jax.tree_util.tree_structure(migrated) == \
-            jax.tree_util.tree_structure(params)
-        got = model.apply({"params": migrated}, tokens)
-        np.testing.assert_allclose(got, want, rtol=1e-6)
-    # Already-migrated trees pass through unchanged.
-    again = migrate_params({"params": params}, n_heads=4)["params"]
-    assert jax.tree_util.tree_structure(again) == \
-        jax.tree_util.tree_structure(params)
-
-
-def test_layout_version_stamp():
-    """ADVICE r3: migrators stamp a layout version into checkpoint
-    wrappers; check_layout warns on unversioned/stale trees (which would
-    silently compute a different function under the adjacent-pair rope)
-    and raises under strict; a stamped wrapper still applies cleanly."""
-    import warnings
-
-    from horovod_tpu.models.transformer import (LAYOUT_VERSION,
-                                                check_layout,
-                                                migrate_params,
-                                                migrate_rope_pairing)
-
-    model = _model()
-    tokens = _tokens()
-    params = model.init(jax.random.PRNGKey(2), tokens)["params"]
-
-    v2 = migrate_params({"params": params}, n_heads=4)
-    assert int(v2["layout"]["version"]) == 2  # structure only: rope legacy
-    v3 = migrate_rope_pairing(v2, n_heads=4)
-    assert int(v3["layout"]["version"]) == LAYOUT_VERSION
-
-    # Current stamp: silent pass-through.
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        assert check_layout(v3) is v3
-    # Unversioned and stale trees: warn (default) or raise (strict).
-    for bad in ({"params": params}, v2):
-        with pytest.warns(UserWarning, match="layout stamp"):
-            check_layout(bad)
-        with pytest.raises(ValueError, match="layout stamp"):
-            check_layout(bad, strict=True)
-    # The stamp rides through apply as an ignored collection.
-    out = model.apply({"params": v3["params"], "layout": v3["layout"]},
-                      tokens)
-    assert np.isfinite(np.asarray(out, np.float32)).all()
-
-
 def test_sequence_parallel_fused_ring_matches():
     """TransformerLM(ring_impl='fused') — the fused ring-flash kernel —
     produces the same logits as the single-device model (the plumbing
@@ -267,36 +175,6 @@ def test_sequence_parallel_fused_ring_matches():
     got = jax.jit(shard_map(fwd, mesh=mesh, in_specs=spec,
                             out_specs=spec, check_vma=False))(tokens)
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
-
-
-def test_migrate_rope_pairing_exact():
-    """migrate_rope_pairing reproduces the old [even|odd]-half rope
-    model's logits EXACTLY (up to float tolerance) under the round-3
-    adjacent-pair rope: the pairings differ by a fixed q/k head_dim
-    permutation that attention scores are invariant to."""
-    import horovod_tpu.models.transformer as T
-    from horovod_tpu.models.transformer import (_rope_half_pairing,
-                                                migrate_rope_pairing)
-
-    model = _model()
-    tokens = _tokens()
-    params = model.init(jax.random.PRNGKey(7), tokens)["params"]
-
-    # Reference: what the old model (same params, half-pairing rope)
-    # computed.
-    new_rope = T.rope
-    T.rope = _rope_half_pairing
-    try:
-        want = model.apply({"params": params}, tokens)
-    finally:
-        T.rope = new_rope
-
-    migrated = migrate_rope_pairing(params, n_heads=4)
-    got = model.apply({"params": migrated}, tokens)
-    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
-    # Param trees stay structurally identical.
-    assert jax.tree_util.tree_structure(migrated) == \
-        jax.tree_util.tree_structure(params)
 
 
 @pytest.mark.slow  # ~26s compile-bound gradient check; forward parity

@@ -39,7 +39,7 @@ SCHEDULER_PY = os.path.join("horovod_tpu", "serving", "scheduler.py")
 CC_DIR = os.path.join("horovod_tpu", "engine", "cc")
 # Python trees whose env reads form the public surface (tests excluded:
 # their HVD_TPU_TEST_* knobs configure the harness, not the framework).
-PY_SCOPE = ["horovod_tpu", "tools", "bench.py"]
+PY_SCOPE = ["horovod_tpu", "tools"]
 
 _READ_PATTERNS = (
     r"os\.environ\.get\(\s*\"(HVD_TPU_\w+)\"",

@@ -1,9 +1,8 @@
 """Checker 6: Prometheus metric-name and section-coverage lint.
 
-The former standalone ``tools/check_metric_names.py``, folded into
-hvdlint (the old CLI remains as a thin shim).  Renders a registry with
-one of everything recorded and verifies: every family is snake_case with
-the ``hvd_tpu_`` prefix, pairs ``# HELP`` with ``# TYPE``, is unique
+Run it alone with ``python -m tools.hvdlint metrics``.  Renders a
+registry with one of everything recorded and verifies: every family is
+snake_case with the ``hvd_tpu_`` prefix, pairs ``# HELP`` with ``# TYPE``, is unique
 across sections; and every ``metrics_snapshot()`` top-level section maps
 to at least one rendered family (SECTION_FAMILIES) and is documented in
 docs/metrics.md.  Unlike the text-parsing checkers this one imports the
@@ -14,7 +13,6 @@ from __future__ import annotations
 
 import os
 import re
-import sys
 from collections import Counter
 from typing import List
 
@@ -335,21 +333,3 @@ def check(root: str) -> List[Violation]:
     return [Violation("metrics", rel, 0, line)
             for line in proc.stdout.splitlines() if line.strip()]
 
-
-def main() -> int:
-    """Standalone CLI (the tools/check_metric_names.py compatibility
-    surface)."""
-    from horovod_tpu.common import metrics
-    from tools.hvdlint import repo_root
-
-    snapshot = populated_registry().snapshot()
-    text = metrics.prometheus_text(snapshot)
-    errors = lint(text)
-    errors += lint_sections(snapshot, text, _metrics_doc_text(repo_root()))
-    for err in errors:
-        print(f"check_metric_names: {err}", file=sys.stderr)
-    if not errors:
-        n = len([l for l in text.splitlines() if l.startswith("# TYPE ")])
-        print(f"check_metric_names: OK ({n} metric families, "
-              f"{len(snapshot) - 1} snapshot sections covered)")
-    return 1 if errors else 0

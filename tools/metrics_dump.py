@@ -15,8 +15,7 @@ Prints the per-op table (ops and bytes per data plane), fusion-batch
 counters, stall events, response-cache hit rates (docs/performance.md),
 and per-histogram count/mean/p50/p99 estimated
 from the fixed buckets (linear interpolation inside the bucket, the
-standard Prometheus histogram_quantile estimate) — made for BENCH_* round
-analysis next to bench.py's throughput numbers.
+standard Prometheus histogram_quantile estimate).
 
 ``--stragglers`` renders the straggler view instead: ranks ordered by
 their share of ``last_to_announce`` (the coordinator's announce-order
