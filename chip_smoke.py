@@ -533,11 +533,15 @@ def phase_dp4(args) -> dict:
         after = jax.device_get(state[0])
         update = jax.tree.map(lambda a, b: np.asarray(a, np.float64)
                               - np.asarray(b, np.float64), after, before)
-        return _hlo(text), shards, losses, jax.tree.leaves(update)
+        # Steady steps, for the record only: many small gradient leaves and
+        # 99 batch-norm all-reduces, the exchange that is all latency.
+        _, _, seconds = _run_steps(jax, step, state, batch, 2, 10)
+        return (_hlo(text), shards, losses, jax.tree.leaves(update), seconds,
+                step.exchange_overlap)
 
     mesh4 = data_parallel_mesh()
     order = _chips_of_2x2(mesh4.devices.flat)
-    hlo4, shards, losses4, update4 = run(mesh4)
+    hlo4, shards, losses4, update4, seconds4, overlap4 = run(mesh4)
     assert hlo4["all_reduce"] > 0, hlo4
     # The batch really is spread, 16 images a chip, and a replicated leaf
     # has its four whole copies.
@@ -548,7 +552,8 @@ def phase_dp4(args) -> dict:
     assert len({device for device, _, _ in shards["a_parameter"]}) == 4
     assert len({(shape, index)
                 for _, shape, index in shards["a_parameter"]}) == 1
-    hlo1, _, losses1, update1 = run(data_parallel_mesh(devices[:1]))
+    hlo1, _, losses1, update1, seconds1, _ = run(
+        data_parallel_mesh(devices[:1]))
     assert hlo1["all_reduce"] == 0, hlo1
     for got, want in zip(losses4, losses1):
         assert abs(got - want) <= DP4_LOSS_RTOL * abs(want), (losses4,
@@ -560,7 +565,9 @@ def phase_dp4(args) -> dict:
                                                                     norm)
     return {"device": _device(devices), "device_order": order,
             "per_chip_batch": RESNET_BATCH // 4, "hlo_four_chips": hlo4,
-            "hlo_one_chip": hlo1, "losses_four_chips": losses4,
+            "hlo_one_chip": hlo1, "exchange_overlap": overlap4,
+            "step_seconds_four_chips": seconds4,
+            "step_seconds_one_chip": seconds1, "losses_four_chips": losses4,
             "losses_one_chip": losses1, "loss_rtol": DP4_LOSS_RTOL,
             "update_error_over_norm": float(error / norm),
             "update_rtol": DP4_UPDATE_RTOL}

@@ -328,6 +328,11 @@ class MetricsRegistry:
         # left out since the last reset (gauges a caller mirrors in from the
         # model's `intermediates`; models.record_expert_rows).
         self._moe = {"rows_per_local_expert": [], "rows_over_bound": 0}
+        # What the compiler made of the last compiled training step's
+        # gradient exchange (jax/train.py `_TimedStep.exchange_overlap`).
+        self._train_step = {"compiler_options": "not applied",
+                            "compiled": False, "async_all_reduces": 0,
+                            "sync_all_reduces": 0}
         self._hists = {name: Histogram(bounds)
                        for name, (bounds, _) in HISTOGRAMS.items()}
 
@@ -416,6 +421,13 @@ class MetricsRegistry:
             self._moe["rows_per_local_expert"] = [
                 [int(n) for n in layer] for layer in rows_per_local_expert]
             self._moe["rows_over_bound"] += int(rows_over_bound)
+
+    def set_train_step(self, exchange_overlap: dict) -> None:
+        """Mirror a compiled training step's account of its gradient
+        exchange: whether it took the overlap options, and its
+        asynchronous and synchronous all-reduces (a state copy)."""
+        with self._lock:
+            self._train_step = dict(exchange_overlap)
 
     def set_flight(self, state: dict) -> None:
         """Mirror the flight recorders' state (a state copy — idempotent
@@ -763,6 +775,7 @@ class MetricsRegistry:
                         self._moe["rows_per_local_expert"]],
                     "rows_over_bound": self._moe["rows_over_bound"],
                 },
+                "train_step": dict(self._train_step),
                 "compression": {
                     "mode": self._compression["mode"],
                     "min_bytes": self._compression["min_bytes"],
@@ -938,6 +951,15 @@ def prometheus_text(snapshot: dict) -> str:
     out.append("# TYPE hvd_tpu_moe_rows_over_bound_total counter")
     out.append("hvd_tpu_moe_rows_over_bound_total "
                f"{moe.get('rows_over_bound', 0)}")
+
+    step = snapshot.get("train_step", {})
+    out.append("# HELP hvd_tpu_train_step_all_reduces all-reduces of the "
+               "last compiled training step, by how the compiler runs them "
+               "(async: beside compute)")
+    out.append("# TYPE hvd_tpu_train_step_all_reduces gauge")
+    for kind in ("async", "sync"):
+        out.append(f'hvd_tpu_train_step_all_reduces{{kind="{kind}"}} '
+                   f"{step.get(kind + '_all_reduces', 0)}")
 
     tune = snapshot.get("autotune", {})
     out.append("# HELP hvd_tpu_autotune_enabled "

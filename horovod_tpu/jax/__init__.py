@@ -229,11 +229,15 @@ def DistributedOptimizer(optimizer, axis_name: Optional[str] = None,
     replicated parameters arrive already summed over the axis — by the
     `psum` that `shard_map`'s autodiff inserts, see :func:`allreduce` — so
     the wrapper divides by the axis size and issues no collective of its
-    own; a leaf that still varies gets a real `psum`.  XLA decides how
-    those all-reduces are grouped and scheduled: on a v5e host today each
-    runs synchronously, beside no compute (PERF.md section 6; ROADMAP S3,
-    D12).  Without ``axis_name`` gradients are averaged eagerly through the
-    engine.
+    own; a leaf that still varies gets a real `psum`.  XLA groups and
+    schedules those all-reduces, under the compiler options the enclosing
+    `jax.jit` carries: `build_train_step` compiles a step over more than
+    one TPU device so that each gradient over a megabyte is an asynchronous
+    all-reduce of its own beside the remaining compute and the small ones
+    share one; a `jax.jit` of your own without those options gets a few
+    combined all-reduces the core waits in (PERF.md section 6, PR 29;
+    ROADMAP S3, D12).  Without ``axis_name`` gradients are averaged eagerly
+    through the engine.
     """
     import optax
 

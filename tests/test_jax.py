@@ -246,3 +246,129 @@ def test_distributed_optimizer_eager_size1(single_process_hvd):
     grads = {"w": jnp.full(3, 0.25)}
     updates, _ = tx.update(grads, state, params)
     np.testing.assert_allclose(np.asarray(updates["w"]), -np.full(3, 0.25))
+
+
+def _linear_problem(n_devices):
+    def loss_fn(w, batch):
+        x, y = batch
+        return jnp.mean((x @ w - y) ** 2)
+
+    sub = data_parallel_mesh(jax.devices()[:n_devices], axis_name="hvd")
+    xs = np.random.RandomState(1).randn(n_devices * 2, 3).astype(np.float32)
+    ys = np.random.RandomState(2).randn(n_devices * 2).astype(np.float32)
+    w0 = jnp.zeros(3, jnp.float32)
+    tx = optax.sgd(0.1)
+    return loss_fn, tx, sub, (replicate(sub, w0), replicate(sub, tx.init(w0)),
+                              shard_batch(sub, (xs, ys)))
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_step_takes_no_compiler_option_off_a_tpu_host(monkeypatch, n_devices):
+    """On CPU meshes of one device and of four `build_train_step` hands
+    `jax.jit` no compiler option (the CPU's compiler refuses the TPU's) and
+    the step lowers to the text of a plain `jax.jit` of the same
+    `shard_map`; its record of the exchange reads "not applied", 0 / 0,
+    before and after a call."""
+    jit, seen = jax.jit, []
+
+    def spy(fn, **kwargs):
+        seen.append((fn, kwargs))
+        return jit(fn, **kwargs)
+
+    loss_fn, tx, sub, args = _linear_problem(n_devices)
+    monkeypatch.setattr(jax, "jit", spy)
+    step = build_train_step(loss_fn, tx, sub)
+    monkeypatch.undo()
+    (mapped, kwargs), = seen
+    assert kwargs == {"donate_argnums": (0, 1)}
+    assert step.lower(*args).as_text() == jit(
+        mapped, donate_argnums=(0, 1)).lower(*args).as_text()
+    untouched = {"compiler_options": "not applied", "compiled": False,
+                 "async_all_reduces": 0, "sync_all_reduces": 0}
+    assert step.exchange_overlap == untouched
+    step(*args)
+    assert step.exchange_overlap == untouched
+
+
+def test_count_all_reduces_tells_fused_pairs_from_waiting_instructions():
+    """`count_all_reduces` on the forms libtpu writes: an all-reduce inside
+    the computation of an `async-collective-start` fusion is asynchronous
+    (once: the fusions that carry it and the done repeat its text), an
+    `all-reduce` instruction outside any fusion is one the core waits in,
+    a tuple all-reduce is one, and another collective's start is neither."""
+    from horovod_tpu.jax.train import count_all_reduces
+
+    text = """HloModule jit_shard_step, is_scheduled=true
+
+%fused_computation.1 (param_0.1: bf16[1024,4096]) -> (bf16[1024,4096], u32[]) {
+  %param_0.1 = bf16[1024,4096]{1,0} parameter(0)
+  %all-reduce.54 = bf16[1024,4096]{1,0} all-reduce(%param_0.1), channel_id=1, to_apply=%region_1.2
+  ROOT %custom-call.9 = (bf16[1024,4096]{1,0}, u32[]) custom-call(%all-reduce.54), custom_call_target="x"
+}
+
+%fused_computation.2 (param_0.2: bf16[1024,4096]) -> bf16[1024,4096] {
+  %param_0.2 = bf16[1024,4096]{1,0} parameter(0)
+  %all-reduce.56 = bf16[1024,4096]{1,0} all-reduce(%param_0.2), channel_id=1, to_apply=%region_1.2
+  ROOT %custom-call.11 = bf16[1024,4096]{1,0} custom-call(%all-reduce.56), custom_call_target="x"
+}
+
+%fused_computation.3 (param_0.3: bf16[8,128]) -> (bf16[8,128], u32[]) {
+  %param_0.3 = bf16[8,128]{1,0} parameter(0)
+  %collective-permute.1 = bf16[8,128]{1,0} collective-permute(%param_0.3), source_target_pairs={{0,1}}
+  ROOT %custom-call.13 = (bf16[8,128]{1,0}, u32[]) custom-call(%collective-permute.1), custom_call_target="x"
+}
+
+ENTRY %main.7 (p0: bf16[1024,4096], p1: f32[1024], p2: f32[]) -> bf16[1024,4096] {
+  %p0 = bf16[1024,4096]{1,0} parameter(0)
+  %p1 = f32[1024]{0} parameter(1)
+  %p2 = f32[]{} parameter(2)
+  %async-collective-start = (bf16[1024,4096]{1,0}, u32[]) fusion(%p0), kind=kCustom, calls=%fused_computation.1
+  %all-reduce.2 = (f32[1024]{0}, f32[]) all-reduce(%p1, %p2), channel_id=2, to_apply=%region_0.1
+  %async-collective-start.1 = (bf16[8,128]{1,0}, u32[]) fusion(%p0), kind=kCustom, calls=%fused_computation.3
+  %all-reduce-start.3 = f32[] all-reduce-start(%p2), channel_id=3, to_apply=%region_0.1
+  %all-reduce-done.3 = f32[] all-reduce-done(%all-reduce-start.3)
+  ROOT %async-collective-done = bf16[1024,4096]{1,0} fusion(%async-collective-start), kind=kCustom, calls=%fused_computation.2
+}
+"""
+    assert count_all_reduces(text) == (2, 1)
+    assert count_all_reduces("ENTRY %main.1 () -> f32[] {\n}\n") == (0, 0)
+
+
+def test_overlap_step_reads_its_own_first_compile(mesh):
+    """A step that took the overlap options compiles at its first call
+    through ``lower().compile()``, counts the all-reduces of that text
+    (here the CPU's: every one synchronous), mirrors the record into the
+    registry when it is on, runs that executable from then on, and hands a
+    call with other shapes to the jit."""
+    from horovod_tpu.common import metrics
+    from horovod_tpu.jax.train import _TimedStep
+
+    loss_fn, tx, sub, (params, opt_state, batch) = _linear_problem(4)
+    plain = build_train_step(loss_fn, tx, sub, donate=False)
+    step = _TimedStep(plain._fn, overlap=True)
+    assert step.exchange_overlap["compiler_options"] == "applied"
+    assert not step.exchange_overlap["compiled"]
+    metrics.registry.reset()
+    metrics.registry.enable()
+    try:
+        first = step(params, opt_state, batch)
+        mirrored = metrics.registry.snapshot()["train_step"]
+    finally:
+        metrics.registry.disable()
+        metrics.registry.reset()
+    assert step.exchange_overlap["compiled"]
+    assert step.exchange_overlap["async_all_reduces"] == 0
+    assert step.exchange_overlap["sync_all_reduces"] >= 1
+    assert mirrored == step.exchange_overlap
+    want = plain(params, opt_state, batch)
+    for got in (first, step(params, opt_state, batch)):
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # A batch of another length: the first executable refuses it and the
+    # jit compiles for it.
+    xs, ys = batch
+    longer = shard_batch(sub, (np.tile(np.asarray(xs), (2, 1)),
+                               np.tile(np.asarray(ys), 2)))
+    again = step(params, opt_state, longer)
+    np.testing.assert_allclose(np.asarray(again[0]), np.asarray(want[0]),
+                               rtol=1e-5)

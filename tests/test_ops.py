@@ -369,23 +369,127 @@ def test_ring_variants_compile_on_mesh(v5e, monkeypatch, impl):
         assert ids == ["15", "16"] * 4, ids
 
 
-def _written_float32_elements(text):
-    """Element counts of the float32 arrays that instructions OUTSIDE fused
-    computations yield in a compiled program's text: what is written to
-    memory, where a fusion's body holds values that never leave the core."""
-    import math
-    import re
+def _compile_lm_step(devices):
+    """A two-layer dense LM at pythia-410m's widths (a smaller vocabulary,
+    512 tokens a chip) through `build_train_step` on a data-parallel mesh
+    of the described ``devices``: (the step, its compiled text, the number
+    of weights whose gradient is over a megabyte in either dtype)."""
+    import optax
+    from jax.sharding import NamedSharding
 
+    from horovod_tpu.jax.train import _EXCHANGE_OVERLAP, build_train_step
+    from horovod_tpu.models import TransformerLM, next_token_loss
+    from horovod_tpu.parallel import data_parallel_mesh
+
+    model = TransformerLM(vocab_size=8192, d_model=1024, n_layers=2,
+                          n_heads=16, d_ff=4096, dtype=jnp.bfloat16,
+                          logits_dtype=jnp.bfloat16, use_flash=True)
+    mesh = data_parallel_mesh(devices, axis_name="hvd")
+    tx = optax.adamw(1e-4)
+
+    def loss_fn(params, batch):
+        return next_token_loss(model.apply({"params": params}, batch[0]),
+                               batch[1])
+
+    def init(key):
+        params = model.init(key, jnp.zeros((1, 128), jnp.int32))["params"]
+        return params, tx.init(params)
+
+    def shaped(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+
+    params, opt_state = shaped(jax.eval_shape(init, jax.random.PRNGKey(0)),
+                               P())
+    tokens = shaped(jax.ShapeDtypeStruct((len(devices), 512), jnp.int32),
+                    P("hvd"))
+    step = build_train_step(loss_fn, tx, mesh, axis_name="hvd")
+    try:
+        text = step.lower(params, opt_state,
+                          (tokens, tokens)).compile().as_text()
+    except Exception as exc:  # noqa: BLE001 - libtpu names the option
+        pytest.fail("this libtpu refuses the step under the compiler options "
+                    f"of jax/train.py _EXCHANGE_OVERLAP "
+                    f"{sorted(_EXCHANGE_OVERLAP)}: {exc}")
+    # Over 2**19 elements a gradient is over a megabyte in bf16 and in f32;
+    # the model's other leaves (norm scales) are under it in both.
+    sizes = [x.size for x in jax.tree.leaves(params)]
+    assert all(n >= 2**19 or n * 4 < 2**20 for n in sizes), sizes
+    return step, text, sum(n >= 2**19 for n in sizes)
+
+
+def test_dp_step_exchanges_large_gradients_asynchronously(v5e, monkeypatch):
+    """What `build_train_step` promises of the gradient exchange, asked of
+    the chip's compiler.  Over four described chips every weight gradient
+    over a megabyte is an `async-collective-start`/`-done` pair of its own,
+    no all-reduce the core waits in has an operand that large (the small
+    leaves and the loss still travel, together), and the text still holds
+    an all-reduce for the benchmark's count; over one described chip the
+    step takes no option and holds neither.  This is the test that fails
+    when a libtpu upgrade renames, drops or re-reads one of the options."""
+    import math
+
+    from horovod_tpu.jax.train import _EXCHANGE_OVERLAP, count_all_reduces
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    options = (f"(jax/train.py _EXCHANGE_OVERLAP: {sorted(_EXCHANGE_OVERLAP)}"
+               "; PERF.md section 6, PR 29)")
+
+    step, text, large = _compile_lm_step(v5e[:4])
+    assert step.exchange_overlap["compiler_options"] == "applied"
+    n_async, n_sync = count_all_reduces(text)
+    dones = len(re.findall(r"^\s*%async-collective-done[\w.\-]* = ", text,
+                           re.M))
+    assert n_async == dones == large, (
+        f"{large} gradients over a megabyte, {n_async} asynchronous "
+        f"all-reduces, {dones} dones: one of the options lost its meaning "
+        f"{options}")
+    assert n_sync >= 1 and re.search(r"\ball-reduce\(", text)
+    waiting = [line.split(" all-reduce(")[0]
+               for line in _instructions_outside_fusions(text)
+               if " all-reduce(" in line]
+    assert len(waiting) == n_sync
+    width = {"bf16": 2, "f32": 4}
+    for result in waiting:
+        for dtype, dims in re.findall(r"\b(bf16|f32)\[([\d,]*)\]", result):
+            nbytes = width[dtype] * math.prod(
+                int(d) for d in dims.split(",") if d)
+            assert nbytes < 2**20, (
+                f"a synchronous all-reduce carries {nbytes} bytes: {result} "
+                f"{options}")
+
+    step, text, _ = _compile_lm_step(v5e[:1])
+    assert step.exchange_overlap["compiler_options"] == "not applied"
+    assert count_all_reduces(text) == (0, 0)
+    assert "async-collective-start" not in text
+    assert "all-reduce" not in text
+
+
+def _instructions_outside_fusions(text):
+    """The instruction lines of a compiled program's text that are not in a
+    fused computation: what the core runs one after another."""
     fused = set(re.findall(r"\bfusion\(.*calls=%([\w.\-]+)", text))
-    counts, skipping = [], False
+    skipping = False
     for line in text.splitlines():
         opened = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
         if opened:
             skipping = opened.group(1) in fused
         elif not skipping and " = " in line:
-            yielded = line.split(" = ", 1)[1].split(", metadata=")[0]
-            counts += [math.prod(map(int, dims.split(",")))
-                       for dims in re.findall(r"\bf32\[([\d,]+)\]", yielded)]
+            yield line
+
+
+def _written_float32_elements(text):
+    """Element counts of the float32 arrays that instructions OUTSIDE fused
+    computations yield in a compiled program's text: what is written to
+    memory, where a fusion's body holds values that never leave the core."""
+    import math
+
+    counts = []
+    for line in _instructions_outside_fusions(text):
+        yielded = line.split(" = ", 1)[1].split(", metadata=")[0]
+        counts += [math.prod(map(int, dims.split(",")))
+                   for dims in re.findall(r"\bf32\[([\d,]+)\]", yielded)]
     return counts
 
 

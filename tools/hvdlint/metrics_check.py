@@ -48,6 +48,7 @@ SECTION_FAMILIES = {
                "hvd_tpu_flight_ring_capacity"),
     "moe": ("hvd_tpu_moe_expert_rows",
             "hvd_tpu_moe_rows_over_bound_total"),
+    "train_step": ("hvd_tpu_train_step_all_reduces",),
     "compression": ("hvd_tpu_compression_mode",
                     "hvd_tpu_compression_wire_bytes_total",
                     "hvd_tpu_compression_payload_bytes_total",
