@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.models.ssm import Mamba2Config, Mamba2Mixer
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              ring_attention)
 from horovod_tpu.ops.moe import (buffer_rows_to_tokens, dispatch_rows,
@@ -150,13 +151,38 @@ class MoEConfig(NamedTuple):
     (rounded up to 512 rows, at most every pair).  ``None``: every pair, so
     nothing can fall outside.  Rows over a bound are not computed and are
     COUNTED (``rows_over_bound`` in the ``intermediates`` collection): a
-    caller that sets a bound checks that count."""
+    caller that sets a bound checks that count.
+
+    The fields after ``row_bound`` default to the layer above; set, they give
+    the latent sparse-expert layer of Nemotron-3:
+
+    * ``scoring="sigmoid"``: the scores are ``sigmoid`` of the router's
+      float32 logits, each expert's its own.  The ``experts_per_token``
+      largest of ``score + bias`` are chosen, ``bias`` being the
+      ``selection_bias`` of the ``buffers`` collection where the caller
+      passes one (a balance bias that is no parameter and takes no gradient;
+      absent, zero); the weights are the chosen scores themselves.
+    * ``renormalize``: the weights divided by their sum over the chosen;
+      ``weight_scale``: then multiplied by it.
+    * ``expert_act="relu2"``: experts ``down(relu(up x)**2)``, no gate.
+    * ``latent_width``: the experts work in that width, between a projection
+      down before the dispatch and one up after the combine, whole on every
+      shard.
+    * ``shared_width``: one more expert of that width (same activation, not
+      routed, not weighted) on the layer's own input, whole on every shard;
+      its output is added."""
 
     num_experts: int
     experts_per_token: int
     expert_width: int
     expert_shard: Tuple[int, int] = (0, 1)
     row_bound: Optional[float] = None
+    scoring: str = "softmax"
+    renormalize: bool = False
+    weight_scale: float = 1.0
+    expert_act: str = "gated_silu"
+    latent_width: Optional[int] = None
+    shared_width: Optional[int] = None
 
     def buffer_rows(self, tokens: int) -> int:
         """Rows of the sorted buffer for ``tokens`` tokens."""
@@ -184,7 +210,11 @@ class SparseExperts(nn.Module):
     ``choices`` (pairs per expert, all experts), ``prob_sum``, ``z_sum``,
     ``tokens``: what :func:`router_losses` reads; ``intermediates`` —
     ``chosen_experts`` (tokens, k), ``rows_per_local_expert``,
-    ``rows_over_bound``."""
+    ``rows_over_bound``.  With sigmoid scoring ``prob_sum`` sums the scores.
+
+    Two more scopes where the configuration asks for them:
+    ``hvd_moe_latent`` (the projections into and out of the experts' width)
+    and ``hvd_moe_shared`` (the shared expert)."""
 
     config: MoEConfig
     dtype: Any = jnp.bfloat16
@@ -202,22 +232,47 @@ class SparseExperts(nn.Module):
         tokens = flat.shape[0]
         bound = cfg.buffer_rows(tokens)
 
+        if cfg.scoring not in ("softmax", "sigmoid") \
+                or cfg.expert_act not in ("gated_silu", "relu2"):
+            raise ValueError(f"unknown scoring or expert_act in {cfg}")
+        gated = cfg.expert_act == "gated_silu"
+        act = nn.silu if gated else (lambda t: jnp.square(nn.relu(t)))
+        inner = cfg.latent_width or d
+
         w_router = self.param("router_kernel", nn.initializers.lecun_normal(),
                               (d, cfg.num_experts), jnp.float32)
         fan_in = nn.initializers.lecun_normal(in_axis=1, out_axis=2,
                                               batch_axis=0)
-        w_gate = self.param("gate_kernel", fan_in,
-                            (local, d, cfg.expert_width), jnp.float32)
+        if gated:
+            w_gate = self.param("gate_kernel", fan_in,
+                                (local, inner, cfg.expert_width), jnp.float32)
         w_up = self.param("up_kernel", fan_in,
-                          (local, d, cfg.expert_width), jnp.float32)
+                          (local, inner, cfg.expert_width), jnp.float32)
         w_down = self.param("down_kernel", fan_in,
-                            (local, cfg.expert_width, d), jnp.float32)
+                            (local, cfg.expert_width, inner), jnp.float32)
 
         with jax.named_scope("hvd_moe_router"):
             logits = jnp.dot(flat, w_router.astype(self.dtype),
                              preferred_element_type=jnp.float32)
-            probs = jax.nn.softmax(logits, axis=-1)
-            weight, expert = top_choices(probs, k)
+            if cfg.scoring == "softmax":
+                probs = jax.nn.softmax(logits, axis=-1)
+                weight, expert = top_choices(probs, k)
+            else:
+                probs = jax.nn.sigmoid(logits)
+                if self.has_variable("buffers", "selection_bias"):
+                    bias = self.get_variable("buffers", "selection_bias")
+                    weight, expert = top_choices(probs + bias, k)
+                    # The chosen experts' own bias by a compare and a sum: the
+                    # TPU gathers scalars one by one (0.6 ms a layer for
+                    # 90,112 of them: my chip runs, PR 30).
+                    chosen = expert[..., None] == jnp.arange(cfg.num_experts)
+                    weight = weight - jnp.where(chosen, bias, 0.0).sum(-1)
+                else:
+                    weight, expert = top_choices(probs, k)
+            if cfg.renormalize:
+                weight = weight / (weight.sum(axis=-1, keepdims=True) + 1e-20)
+            if cfg.weight_scale != 1.0:
+                weight = weight * cfg.weight_scale
             self.sow("intermediates", "chosen_experts", expert)
             choices = (expert[..., None] == jnp.arange(cfg.num_experts)
                        ).sum(axis=(0, 1), dtype=jnp.float32)
@@ -226,21 +281,40 @@ class SparseExperts(nn.Module):
             self.sow("router", "z_sum",
                      jnp.square(jax.nn.logsumexp(logits, axis=-1)).sum())
             self.sow("router", "tokens", jnp.float32(tokens))
+        narrow = flat
+        if cfg.latent_width:
+            with jax.named_scope("hvd_moe_latent"):
+                narrow = nn.Dense(inner, use_bias=False, dtype=self.dtype,
+                                  name="latent_down")(flat)
         with jax.named_scope("hvd_moe_dispatch"):
             sent = dispatch_rows(expert, shard * local, local, bound)
-            rows = token_rows_to_buffer(flat, sent)
+            rows = token_rows_to_buffer(narrow, sent)
             self.sow("intermediates", "rows_per_local_expert",
                      sent.rows_per_expert)
             self.sow("intermediates", "rows_over_bound",
                      sent.rows_over_bound)
         with jax.named_scope("hvd_moe_experts"):
             sizes = sent.group_sizes
-            gate = grouped_matmul(rows, w_gate.astype(self.dtype), sizes)
-            up = grouped_matmul(rows, w_up.astype(self.dtype), sizes)
-            out = grouped_matmul(nn.silu(gate) * up,
-                                 w_down.astype(self.dtype), sizes)
+            if gated:
+                gate = grouped_matmul(rows, w_gate.astype(self.dtype), sizes)
+                up = grouped_matmul(rows, w_up.astype(self.dtype), sizes)
+                hidden = act(gate) * up
+            else:
+                hidden = act(grouped_matmul(rows, w_up.astype(self.dtype),
+                                            sizes))
+            out = grouped_matmul(hidden, w_down.astype(self.dtype), sizes)
         with jax.named_scope("hvd_moe_combine"):
             mixed = buffer_rows_to_tokens(out, weight, sent)
+        if cfg.latent_width:
+            with jax.named_scope("hvd_moe_latent"):
+                mixed = nn.Dense(d, use_bias=False, dtype=self.dtype,
+                                 name="latent_up")(mixed)
+        if cfg.shared_width:
+            with jax.named_scope("hvd_moe_shared"):
+                shared = nn.Dense(cfg.shared_width, use_bias=False,
+                                  dtype=self.dtype, name="shared_up")(flat)
+                mixed = mixed + nn.Dense(d, use_bias=False, dtype=self.dtype,
+                                         name="shared_down")(act(shared))
         return mixed.reshape(x.shape)
 
 
@@ -261,25 +335,72 @@ class Attention(nn.Module):
     # projections, before the split into heads and the rotation (OLMoE).
     qk_norm: bool = False
     norm_eps: float = 1e-6
+    # Grouped-query attention: ``n_kv_heads`` key/value heads, query head j
+    # reading key/value head ``j // (n_heads / n_kv_heads)``.  None: as many
+    # as query heads, in the fused ``qkv_kernel`` above.
+    n_kv_heads: Optional[int] = None
+    # False: no rotary embedding (a model whose other layers carry position).
+    rope: bool = True
+    # ``(i, n)``: this process holds query heads ``[i H/n, (i+1) H/n)`` and
+    # the key/value heads they read (a key/value head that several shards'
+    # queries read is held by each of them) — the local part of a layer that
+    # is tensor-parallel over ``n`` chips, whose ``n`` outputs sum to the
+    # whole layer's; the sum is the caller's.  ``(0, 1)``: the whole layer.
+    head_shard: Tuple[int, int] = (0, 1)
+
+    def _grouped_projections(self, x, head_dim):
+        """(q, k, v), each (b, local query heads, seq, head_dim), from a
+        ``q_kernel`` and a ``kv_kernel`` of this shard's heads; a key/value
+        head is repeated for every query head that reads it (the repeat's
+        transpose sums their cotangents)."""
+        shard, n_shards = self.head_shard
+        kv_heads = self.n_kv_heads or self.n_heads
+        if self.n_heads % kv_heads or self.n_heads % n_shards \
+                or not 0 <= shard < n_shards \
+                or (kv_heads % n_shards and n_shards % kv_heads):
+            raise ValueError(
+                f"head_shard {self.head_shard} does not divide "
+                f"{self.n_heads} query heads over {kv_heads} key/value heads")
+        heads = self.n_heads // n_shards
+        kv_local = max(1, kv_heads // n_shards)
+        d = x.shape[-1]
+        w_q = self.param(
+            "q_kernel", nn.initializers.lecun_normal(in_axis=0,
+                                                     out_axis=(1, 2)),
+            (d, heads, head_dim), jnp.float32)
+        w_kv = self.param(
+            "kv_kernel",
+            nn.initializers.lecun_normal(in_axis=0, out_axis=(1, 2, 3)),
+            (d, 2, kv_local, head_dim), jnp.float32)
+        x = x.astype(self.dtype)
+        q = jnp.einsum("bsd,dhe->bhse", x, w_q.astype(self.dtype))
+        k, v = jnp.einsum("bsd,djhe->jbhse", x, w_kv.astype(self.dtype))
+        return (q, jnp.repeat(k, heads // kv_local, axis=1),
+                jnp.repeat(v, heads // kv_local, axis=1))
 
     @nn.compact
     def __call__(self, x, decode_ctx=None):
         b, s, d = x.shape
         head_dim = d // self.n_heads
-        # One fused qkv projection whose einsum emits q/k/v *head-major*
-        # ('jbhse'): XLA folds the seq<->head transpose into the matmul's
-        # output layout, so no standalone copy passes appear around the
-        # attention kernel.  The inverse transpose folds into the output
-        # projection's einsum the same way.  Per-matrix fan-in init
-        # matches separate q/k/v Dense layers (fan_in = d).
-        w_qkv = self.param(
-            "qkv_kernel",
-            nn.initializers.lecun_normal(in_axis=0, out_axis=(1, 2, 3)),
-            (d, 3, self.n_heads, head_dim), jnp.float32)
-        q, k, v = _qkv_project(x.astype(self.dtype),
-                               w_qkv.astype(self.dtype))
-        # (b, heads, seq, head_dim) each; custom VJP avoids the
-        # activation-sized cotangent stack the sliced einsum would build.
+        n_heads = self.n_heads // self.head_shard[1]
+        rotate = rope if self.rope else (lambda t, positions: t)
+        if self.n_kv_heads is not None or self.head_shard != (0, 1):
+            q, k, v = self._grouped_projections(x, head_dim)
+        else:
+            # One fused qkv projection whose einsum emits q/k/v *head-major*
+            # ('jbhse'): XLA folds the seq<->head transpose into the matmul's
+            # output layout, so no standalone copy passes appear around the
+            # attention kernel.  The inverse transpose folds into the output
+            # projection's einsum the same way.  Per-matrix fan-in init
+            # matches separate q/k/v Dense layers (fan_in = d).
+            w_qkv = self.param(
+                "qkv_kernel",
+                nn.initializers.lecun_normal(in_axis=0, out_axis=(1, 2, 3)),
+                (d, 3, self.n_heads, head_dim), jnp.float32)
+            # (b, heads, seq, head_dim) each; custom VJP avoids the
+            # activation-sized cotangent stack the sliced einsum would build.
+            q, k, v = _qkv_project(x.astype(self.dtype),
+                                   w_qkv.astype(self.dtype))
         if self.qk_norm:
             q = self._projection_norm("q_norm_scale", q)
             k = self._projection_norm("k_norm_scale", k)
@@ -287,7 +408,7 @@ class Attention(nn.Module):
         new_kv = None
         if decode_ctx is not None:
             k_ctx, v_ctx, ctx_mask, positions = decode_ctx
-            q, k = rope(q, positions), rope(k, positions)
+            q, k = rotate(q, positions), rotate(k, positions)
             ctx_len = k_ctx.shape[-2]
             # Context keys all precede the new chunk; within the chunk
             # positions are consecutive, so causality is a lower triangle.
@@ -303,14 +424,14 @@ class Attention(nn.Module):
         elif self.seq_axis is not None:
             offset = lax.axis_index(self.seq_axis) * s
             positions = offset + jnp.arange(s)
-            q, k = rope(q, positions), rope(k, positions)
+            q, k = rotate(q, positions), rotate(k, positions)
             if self.capture_kv:
                 self.sow("intermediates", "kv", (k, v))
             out = ring_attention(q, k, v, axis_name=self.seq_axis,
                                  causal=True, rotate_impl=self.ring_impl)
         else:
             positions = jnp.arange(s)
-            q, k = rope(q, positions), rope(k, positions)
+            q, k = rotate(q, positions), rotate(k, positions)
             if self.capture_kv:
                 self.sow("intermediates", "kv", (k, v))
             out = flash_attention(q, k, v, causal=True) if self.use_flash \
@@ -318,7 +439,7 @@ class Attention(nn.Module):
         w_o = self.param(
             "o_kernel",
             nn.initializers.lecun_normal(in_axis=(0, 1), out_axis=2),
-            (self.n_heads, head_dim, d), jnp.float32)
+            (n_heads, head_dim, d), jnp.float32)
         proj = jnp.einsum("bhse,hed->bsd", out, w_o.astype(self.dtype))
         return proj if new_kv is None else (proj, new_kv)
 
@@ -372,6 +493,50 @@ class Block(nn.Module):
         return x if new_kv is None else (x, new_kv)
 
 
+LAYER_KINDS = ("ssm", "attention", "experts")
+
+
+class MixerLayer(nn.Module):
+    """One layer of a per-layer pattern (``TransformerLM(layers=)``): ONE
+    mixer behind one norm and one residual, ``x + mixer(RMSNorm(x))``.
+    ``kind``: ``"ssm"`` (:class:`~horovod_tpu.models.ssm.Mamba2Mixer`),
+    ``"attention"`` (:class:`Attention`) or ``"experts"``
+    (:class:`SparseExperts`)."""
+
+    kind: str
+    n_heads: int
+    dtype: Any = jnp.bfloat16
+    use_flash: bool = True
+    moe: Optional[MoEConfig] = None
+    ssm: Optional[Mamba2Config] = None
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    n_kv_heads: Optional[int] = None
+    rope: bool = True
+    head_shard: Tuple[int, int] = (0, 1)
+
+    @nn.compact
+    def __call__(self, x):
+        h = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                       name="norm")(x)
+        if self.kind == "ssm":
+            mixer = Mamba2Mixer(*self.ssm, head_shard=self.head_shard,
+                                dtype=self.dtype, norm_eps=self.norm_eps,
+                                name="mixer")
+        elif self.kind == "attention":
+            mixer = Attention(self.n_heads, self.dtype,
+                              use_flash=self.use_flash, qk_norm=self.qk_norm,
+                              norm_eps=self.norm_eps,
+                              n_kv_heads=self.n_kv_heads, rope=self.rope,
+                              head_shard=self.head_shard, name="mixer")
+        elif self.kind == "experts":
+            mixer = SparseExperts(self.moe, self.dtype, name="mixer")
+        else:
+            raise ValueError(f"layer kind {self.kind!r} is none of "
+                             f"{LAYER_KINDS}")
+        return x + mixer(h)
+
+
 class TransformerLM(nn.Module):
     """Causal LM over token ids ``(batch, seq[, sharded over seq_axis])``."""
 
@@ -405,9 +570,28 @@ class TransformerLM(nn.Module):
     moe: Optional[MoEConfig] = None
     qk_norm: bool = False
     norm_eps: float = 1e-6
+    # A per-layer pattern in place of ``n_layers`` blocks: a tuple of layer
+    # kinds (``LAYER_KINDS``), each layer ONE mixer behind one norm and one
+    # residual (:class:`MixerLayer`) — ``"ssm"`` a Mamba-2 mixer of ``ssm``'s
+    # sizes, ``"attention"``, ``"experts"`` the sparse experts of ``moe``.
+    # ``n_kv_heads``, ``rope`` and ``head_shard`` are the attention layers'
+    # (and ``head_shard`` the Mamba-2 mixers') as :class:`Attention` has
+    # them.  Unset, the model is the block above, parameter for parameter.
+    # A pattern trains on one sequence shard and has no cached decode: a
+    # state-space layer's state is no key/value cache.
+    layers: Optional[Tuple[str, ...]] = None
+    ssm: Optional[Mamba2Config] = None
+    n_kv_heads: Optional[int] = None
+    rope: bool = True
+    head_shard: Tuple[int, int] = (0, 1)
 
     @nn.compact
     def __call__(self, tokens, targets=None, decode_ctx=None):
+        if self.layers is not None and (decode_ctx is not None
+                                        or self.seq_axis is not None):
+            raise ValueError(
+                "layers= (a per-layer pattern) composes with neither "
+                "decode_ctx= nor sequence parallelism.")
         if targets is not None and self.seq_axis is not None:
             raise ValueError(
                 "targets= (fused head+loss) is unsupported under sequence "
@@ -424,7 +608,12 @@ class TransformerLM(nn.Module):
         x = nn.Embed(self.vocab_size, self.d_model,
                      dtype=self.dtype, name="embed")(tokens)
         new_ks, new_vs = [], []
-        for i in range(self.n_layers):
+        for i, kind in enumerate(self.layers or ()):
+            x = MixerLayer(kind, self.n_heads, self.dtype, self.use_flash,
+                           self.moe, self.ssm, self.qk_norm, self.norm_eps,
+                           self.n_kv_heads, self.rope, self.head_shard,
+                           name=f"layer_{i}")(x)
+        for i in range(0 if self.layers is not None else self.n_layers):
             block = Block(self.n_heads, d_ff, self.dtype, self.seq_axis,
                           self.use_flash, self.ring_impl, self.capture_kv,
                           self.moe, self.qk_norm, self.norm_eps,

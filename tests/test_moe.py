@@ -455,6 +455,41 @@ def test_dense_default_lowers_to_the_same_program():
     assert hashlib.sha256(text.encode()).hexdigest() == DENSE_DIGEST
 
 
+# The sparse-expert defaults: the program OLMoE's model lowers to must be
+# what it was before MoEConfig grew the latent layer's fields (scoring,
+# renormalised and scaled weights, non-gated experts, a latent width, a shared
+# expert) and Attention its grouped heads, rotary switch and head share: the
+# olmoe1b7b cell runs it.  The digests are of `jax.jit(grad).lower(...)
+# .as_text()` at the parent commit of PR 30 (jax 0.9.0).
+OLMOE_DIGESTS = {
+    ((0, 4), 1.5):
+    "e9fd6466282336a4de5cf04714ac377386f2b81754c9a8a3364ef1cfd1b037a0",
+    ((0, 1), None):
+    "69dda621fcc84cf7dad23434683ac42cbe6df5adb1903cf6058679fadb99da5a"}
+
+
+@pytest.mark.parametrize("shard,row_bound", list(OLMOE_DIGESTS))
+def test_sparse_expert_defaults_lower_to_the_same_program(shard, row_bound):
+    model = TransformerLM(
+        vocab_size=VOCAB, d_model=HIDDEN, n_layers=LAYERS, n_heads=HEADS,
+        dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16, use_flash=False,
+        qk_norm=True, norm_eps=1e-5,
+        moe=MoEConfig(EXPERTS, PER_TOKEN, WIDTH, shard, row_bound))
+    tokens = jnp.zeros((2, SEQ), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens)["params"])
+
+    def loss(params, tokens):
+        logits, wrote = model.apply({"params": params}, tokens,
+                                    mutable=["router", "intermediates"])
+        return moe_next_token_loss(logits, tokens, wrote["router"])
+
+    text = jax.jit(jax.grad(loss)).lower(params, tokens).as_text()
+    assert "sigmoid" not in text and "logistic" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == OLMOE_DIGESTS[shard, row_bound]
+
+
 def test_trains_through_build_train_step_and_replicas_stay_equal():
     """Two CPU devices, data parallel: the step of the dense LM, with the
     sparse-expert loss.  The replicated weights stay equal on both devices

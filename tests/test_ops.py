@@ -526,6 +526,68 @@ def test_lm_loss_keeps_no_float32_logits(v5e):
     assert tokens * vocab not in written
 
 
+def test_hybrid_step_is_products_and_kernels_with_no_loop(v5e, monkeypatch):
+    """A Mamba-2, an attention (4 query heads on 1 key/value head of 128, no
+    rotary) and a latent sparse-expert layer at Nemotron-3's per-head widths
+    through `build_train_step`, compiled for the described chip: the chunked
+    scan is products over chunks (no `while` anywhere in the step), attention
+    is the two flash kernels, the experts are libtpu's grouped-matmul kernels
+    (six and two tile schedules), and every scope of the new layers is in the
+    text forward and backward."""
+    import optax
+    from jax.sharding import NamedSharding
+
+    from horovod_tpu.jax.train import build_train_step
+    from horovod_tpu.models import (Mamba2Config, MoEConfig, TransformerLM,
+                                    next_token_loss)
+    from horovod_tpu.parallel import data_parallel_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(
+        vocab_size=2048, d_model=512, n_heads=4, dtype=jnp.bfloat16,
+        logits_dtype=jnp.bfloat16, use_flash=True, norm_eps=1e-5,
+        layers=("ssm", "experts", "attention"),
+        ssm=Mamba2Config(16, 64, 1, 128, 4, 128), n_kv_heads=1, rope=False,
+        moe=MoEConfig(64, 8, 512, (0, 8), 1.5, "sigmoid", True, 5.0, "relu2",
+                      256, 1024))
+    mesh = data_parallel_mesh(v5e[:1], axis_name="hvd")
+    tx = optax.adamw(1e-4)
+
+    def loss_fn(params, batch):
+        return next_token_loss(model.apply({"params": params}, batch[0]),
+                               batch[1])
+
+    def init(key):
+        params = model.init(key, jnp.zeros((1, 128), jnp.int32))["params"]
+        return params, tx.init(params)
+
+    def shaped(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+
+    params, opt_state = shaped(jax.eval_shape(init, jax.random.PRNGKey(0)),
+                               P())
+    tokens = shaped(jax.ShapeDtypeStruct((1, 2048), jnp.int32), P("hvd"))
+    step = build_train_step(loss_fn, tx, mesh, axis_name="hvd")
+    text = step.lower(params, opt_state,
+                      (tokens, tokens)).compile().as_text()
+    assert not re.search(r"\bwhile\(", text)
+    assert len(re.findall(r"%hvd_flash_fwd[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%hvd_flash_bwd[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 6
+    assert text.count('"tpu_custom_call"') == 2 + 6 + 2
+    for scope in ("hvd_ssm_in_proj", "hvd_ssm_conv", "hvd_ssm_scan",
+                  "hvd_ssm_gate_norm", "hvd_ssm_out_proj", "hvd_moe_latent",
+                  "hvd_moe_shared", "hvd_moe_router", "hvd_moe_dispatch",
+                  "hvd_moe_combine"):
+        assert re.search(rf'op_name="jit\([^"]*/jvp\(hvd_loss\)/[^"]*{scope}/',
+                         text), f"{scope} is not in the forward pass"
+        assert re.search(
+            rf'op_name="jit\([^"]*transpose\(jvp\(hvd_loss\)\)/[^"]*{scope}/',
+            text), f"{scope} is not in the backward pass"
+
+
 @pytest.mark.parametrize("mode", ["combined", "split"])
 def test_flash_inside_shard_map_default_vma_check(monkeypatch, mode):
     """flash_attention is called inside build_train_step's shard_map, whose
