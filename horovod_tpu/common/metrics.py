@@ -326,8 +326,10 @@ class MetricsRegistry:
         # Sparse-expert layers (models.SparseExperts): rows each layer's
         # router last sent each local expert, and the rows a bounded buffer
         # left out since the last reset (gauges a caller mirrors in from the
-        # model's `intermediates`; models.record_expert_rows).
-        self._moe = {"rows_per_local_expert": [], "rows_over_bound": 0}
+        # model's `intermediates`; models.record_expert_rows), and the rows
+        # one pass from each layer's buffer back to its tokens touches.
+        self._moe = {"rows_per_local_expert": [], "rows_over_bound": 0,
+                     "rows_walked": []}
         # What the compiler made of the last compiled training step's
         # gradient exchange (jax/train.py `_TimedStep.exchange_overlap`).
         self._train_step = {"compiler_options": "not applied",
@@ -412,15 +414,17 @@ class MetricsRegistry:
         with self._lock:
             self._membership = dict(state)
 
-    def set_moe_rows(self, rows_per_local_expert, rows_over_bound: int
-                     ) -> None:
+    def set_moe_rows(self, rows_per_local_expert, rows_over_bound: int,
+                     rows_walked=()) -> None:
         """Mirror one forward pass's sparse-expert counters: the rows of
-        every layer's local experts (overwritten) and the rows over the
-        buffer's bound (added up: one is one too many)."""
+        every layer's local experts and the rows a pass back to the tokens
+        walks (overwritten), and the rows over the buffer's bound (added
+        up: one is one too many)."""
         with self._lock:
             self._moe["rows_per_local_expert"] = [
                 [int(n) for n in layer] for layer in rows_per_local_expert]
             self._moe["rows_over_bound"] += int(rows_over_bound)
+            self._moe["rows_walked"] = [int(n) for n in rows_walked]
 
     def set_train_step(self, exchange_overlap: dict) -> None:
         """Mirror a compiled training step's account of its gradient
@@ -774,6 +778,7 @@ class MetricsRegistry:
                         list(layer) for layer in
                         self._moe["rows_per_local_expert"]],
                     "rows_over_bound": self._moe["rows_over_bound"],
+                    "rows_walked": list(self._moe["rows_walked"]),
                 },
                 "train_step": dict(self._train_step),
                 "compression": {
@@ -951,6 +956,12 @@ def prometheus_text(snapshot: dict) -> str:
     out.append("# TYPE hvd_tpu_moe_rows_over_bound_total counter")
     out.append("hvd_tpu_moe_rows_over_bound_total "
                f"{moe.get('rows_over_bound', 0)}")
+    out.append("# HELP hvd_tpu_moe_rows_walked rows one pass from each "
+               "sparse-expert layer's buffer back to its tokens touches "
+               "(the buffer's rows, or every (token, choice) pair)")
+    out.append("# TYPE hvd_tpu_moe_rows_walked gauge")
+    for layer, n in enumerate(moe.get("rows_walked", [])):
+        out.append(f'hvd_tpu_moe_rows_walked{{layer="{layer}"}} {n}')
 
     step = snapshot.get("train_step", {})
     out.append("# HELP hvd_tpu_train_step_all_reduces all-reduces of the "

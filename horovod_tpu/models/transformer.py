@@ -197,24 +197,23 @@ class SparseExperts(nn.Module):
     """Router, dispatch, grouped expert matmuls and combine of one layer
     (``MoEConfig``), each under a ``jax.named_scope`` a trace can read.
 
-    Rows travel by gathers in both directions (``ops/moe.py``): the (token,
+    Rows travel by walking the smaller side (``ops/moe.py``): the (token,
     choice) pairs are sorted by expert, this shard's first; a token's row is
-    gathered into every buffer row that holds one of its pairs, and the
-    experts' rows are gathered back through each pair's position in the
-    sorted order and summed, weighted, per token.  Pairs of experts held
-    elsewhere and pairs a ``row_bound`` cut off have no row in the buffer and
-    contribute exactly zero, forward and backward.  No scatter is left in
-    the layer's program or in its gradient's.
+    gathered into every buffer row that holds one of its pairs; the experts'
+    rows come back summed, weighted, per token — gathered through each pair's
+    place in the sorted order (no scatter in the layer's program or in its
+    gradient's) or, from ``ops.moe.ROW_WALK_PAIRS_PER_ROW`` pairs a buffer
+    row on, scatter-added through each row's token.  Pairs held elsewhere or
+    cut off by a ``row_bound`` contribute exactly zero, forward and backward.
 
     Writes, where the caller makes the collection mutable: ``router`` —
-    ``choices`` (pairs per expert, all experts), ``prob_sum``, ``z_sum``,
-    ``tokens``: what :func:`router_losses` reads; ``intermediates`` —
-    ``chosen_experts`` (tokens, k), ``rows_per_local_expert``,
-    ``rows_over_bound``.  With sigmoid scoring ``prob_sum`` sums the scores.
+    ``choices`` (pairs per expert, all experts), ``prob_sum`` (with sigmoid
+    scoring the scores'), ``z_sum``, ``tokens``: what :func:`router_losses`
+    reads; ``intermediates`` — ``chosen_experts`` (tokens, k), ``rows_per_
+    local_expert``, ``rows_over_bound``, ``rows_walked`` (by one pass back).
 
-    Two more scopes where the configuration asks for them:
-    ``hvd_moe_latent`` (the projections into and out of the experts' width)
-    and ``hvd_moe_shared`` (the shared expert)."""
+    Two more scopes where the configuration asks for them: ``hvd_moe_latent``
+    (both projections around the experts' width) and ``hvd_moe_shared``."""
 
     config: MoEConfig
     dtype: Any = jnp.bfloat16
@@ -293,6 +292,7 @@ class SparseExperts(nn.Module):
                      sent.rows_per_expert)
             self.sow("intermediates", "rows_over_bound",
                      sent.rows_over_bound)
+            self.sow("intermediates", "rows_walked", sent.rows_walked)
         with jax.named_scope("hvd_moe_experts"):
             sizes = sent.group_sizes
             if gated:
@@ -784,15 +784,18 @@ def record_expert_rows(intermediates) -> dict:
     and, when the metrics registry is on (``HVD_TPU_METRICS=1``), mirror them
     into ``hvd.metrics_snapshot()["moe"]``.  Returns ``{"rows_per_local_
     expert": [[rows of each local expert] per layer], "rows_over_bound":
-    int}``."""
+    int, "rows_walked": [rows a pass back to the tokens touches, per
+    layer]}``."""
     from horovod_tpu.common import metrics as _metrics
 
     rows = [[int(n) for n in layer]
             for layer in _sown(intermediates, "rows_per_local_expert")]
     over = sum(int(n) for n in _sown(intermediates, "rows_over_bound"))
+    walked = [int(n) for n in _sown(intermediates, "rows_walked")]
     if _metrics.registry.enabled:
-        _metrics.registry.set_moe_rows(rows, over)
-    return {"rows_per_local_expert": rows, "rows_over_bound": over}
+        _metrics.registry.set_moe_rows(rows, over, walked)
+    return {"rows_per_local_expert": rows, "rows_over_bound": over,
+            "rows_walked": walked}
 
 
 def router_losses(router):

@@ -17,15 +17,29 @@ permutation both ways — ``pair[r]``, the pair that buffer row r holds, and
 ``position[t, c]``, the place of pair (t, c) in the sorted order.  A pair is
 ``valid`` when its place is under ``group_sizes.sum()``: pairs of experts
 held elsewhere and pairs the bound cut off are not, and contribute exactly
-zero (their index is clamped into the buffer, their value masked).  Every
-movement of rows is then a GATHER, forward and backward
-(:func:`token_rows_to_buffer`, :func:`buffer_rows_to_tokens`): the TPU runs
-a scatter-add row by row (2 ms for 24,576 rows of 2,048, PR 26's trace), and
-autodiff turns every gather back into one, so both movers carry a backward
-pass of their own that reads through the other direction's index
-(:func:`top_choices` does the same for the router's top-k).  With every pair
-valid (one shard, no bound) the k-wide gather reads each buffer row once;
-on a shard it reads the clamped row for the pairs held elsewhere.
+zero (their index is clamped into the buffer, their value masked).
+
+Every movement between pair space (tokens x choices) and buffer space
+(``bound`` rows) walks the SMALLER side (:func:`token_rows_to_buffer`,
+:func:`buffer_rows_to_tokens`, each with a backward pass of its own;
+:func:`top_choices` does the same for the router's top-k).  Into the buffer
+that is always a gather of ``bound`` rows.  Back to the tokens it is one
+indexed segment-sum in two forms, chosen from static shapes by
+:func:`walks_rows`:
+
+* under ``ROW_WALK_PAIRS_PER_ROW`` pairs a buffer row (a shard with a
+  quarter of the experts: 2.7) a GATHER through ``position``, k-wide, masked
+  where the pair has no row: the TPU runs a scatter-add row by row (2 ms for
+  24,576 rows of 2,048, PR 26's trace) where the gather costs a sixth as much
+  a row, and autodiff would turn every gather back into a scatter-add;
+* at or over it (a shard with a sixteenth of the experts or less: 35 pairs a
+  row at 8 of 512) a SCATTER-ADD of the buffer's rows through ``token_of_row``:
+  the k-wide gather would read a row for every pair and throw all but
+  ``bound`` of them away.  The weights' cotangent is then a write of at most
+  ``bound`` scalars at distinct places, not a gather of every pair's.
+
+Rows at or past ``group_sizes.sum()`` take no part in either form, by the
+mover's own mask.
 
 :func:`grouped_matmul` is ``jax.lax.ragged_dot`` — which libtpu lowers to a
 Mosaic kernel of its own (``%ragged-dot-none`` custom calls, 512-tiles) —
@@ -112,6 +126,12 @@ class Dispatch(NamedTuple):
         (tokens, choices)."""
         return self.pair // self.position.shape[-1]
 
+    @property
+    def rows_walked(self) -> int:
+        """Rows one pass from the buffer back to the tokens touches: the
+        buffer's where :func:`walks_rows`, every pair's otherwise."""
+        return self.pair.shape[0] if walks_rows(self) else self.position.size
+
 
 def dispatch_rows(expert_of_pair, first_expert: int, local_experts: int,
                   bound: int) -> Dispatch:
@@ -136,6 +156,58 @@ def dispatch_rows(expert_of_pair, first_expert: int, local_experts: int,
                     jnp.maximum(rows.sum() - bound, 0).astype(jnp.int32))
 
 
+# Pairs a buffer row from which the way back to the tokens walks the buffer's
+# rows (two scatter-adds and a scalar scatter a layer) and not the pairs (three
+# k-wide gathers).  On the v5e a gathered pair costs 7-16 ns whatever its
+# width, a scatter-added float32 row ~45 ns a 1,024 elements; the three passes
+# together, alone in a program, ms gathers / ms row walk (my chip runs, PR 31):
+#   2.7 pairs a row (8,192 tokens, k 8, a quarter of 64 experts, 24,576 rows):
+#       2.19 / 5.14 at 2,048 wide, 1.44 / 2.71 at 1,024
+#   5.3 (an eighth, 12,288 rows): 2.19 / 2.93 at 2,048, 1.44 / 1.25 at 1,024;
+#       at 2,048 tokens (3,072 rows of 1,024) 0.56 / 0.22
+#   8 (8,192 rows): 2.19 / 2.23 at 2,048, 1.44 / 0.90 at 1,024
+#   10.7 (a sixteenth, 6,144 rows): 2.19 / 1.66 at 2,048, 1.44 / 0.73 at 1,024
+#   35.2 (4,096 tokens, k 22, 8 of 512 experts, 2,560 rows): 5.32 / 0.77 at
+#       2,048, 3.53 / 0.33 at 1,024
+# 8 is the lowest ratio at which the row walk lost at neither width.
+ROW_WALK_PAIRS_PER_ROW = 8
+
+
+def walks_rows(sent: Dispatch) -> bool:
+    """Whether the passes from the buffer back to the tokens walk the
+    buffer's ``bound`` rows rather than every (token, choice) pair: static
+    shapes only, ``n_shards / row_bound`` of an expert-parallel layout."""
+    return sent.position.size >= ROW_WALK_PAIRS_PER_ROW * sent.pair.shape[0]
+
+
+def _held_or(index, sent: Dispatch, out_of_range: int):
+    """``index[r]`` for the buffer rows under ``group_sizes.sum()``,
+    ``out_of_range`` for the rest: a scatter in ``mode="drop"`` then leaves
+    those out, whatever they hold."""
+    held = lax.iota(jnp.int32, sent.pair.shape[0]) < sent.group_sizes.sum()
+    return jnp.where(held, index, out_of_range)
+
+
+def _rows_summed_by_token(rows, sent: Dispatch):
+    """(tokens, d) float32: ``sum over r < held, token_of_row[r] == t`` of
+    ``rows[r]`` — a scatter-add of the buffer's rows."""
+    tokens = sent.position.shape[0]
+    return jnp.zeros((tokens, rows.shape[1]), jnp.float32).at[
+        _held_or(sent.token_of_row, sent, tokens)].add(
+            rows.astype(jnp.float32), mode="drop")
+
+
+def _rows_scalars_to_pairs(scalars, sent: Dispatch):
+    """(tokens, choices): ``scalars[r]`` at pair ``pair[r]`` for r < held,
+    zero everywhere else — a write of at most ``bound`` scalars, each pair
+    being in one row."""
+    pairs = sent.position.size
+    return jnp.zeros((pairs,), scalars.dtype).at[
+        _held_or(sent.pair, sent, pairs)].set(
+            scalars, mode="drop", unique_indices=True).reshape(
+                sent.position.shape)
+
+
 def _of_pairs(buffer, sent: Dispatch):
     """(tokens, choices, ...): the buffer's entry for every pair, zero where
     the pair has none.  The index of such a pair is clamped into the
@@ -150,8 +222,10 @@ def token_rows_to_buffer(flat, sent: Dispatch):
     """``rows[r] = flat[token_of_row[r]]``: each token's row, once for every
     buffer row that holds one of its pairs.  ``flat`` (tokens, d); the
     result (bound, d).  Backward: ``d_flat[t]`` is the sum over the token's
-    choices of ``d_rows[position[t, c]]`` where valid — a gather, summed in
-    float32 and rounded once to ``flat``'s dtype."""
+    choices of ``d_rows[position[t, c]]`` where valid, summed in float32 and
+    rounded once to ``flat``'s dtype — a k-wide gather, or where
+    :func:`walks_rows` a scatter-add of the rows under
+    ``group_sizes.sum()``."""
     return flat[sent.token_of_row]
 
 
@@ -161,7 +235,10 @@ def _token_rows_to_buffer_fwd(flat, sent):
 
 def _token_rows_to_buffer_bwd(res, d_rows):
     flat, sent = res
-    d_flat = _of_pairs(d_rows, sent).astype(jnp.float32).sum(axis=1)
+    if walks_rows(sent):
+        d_flat = _rows_summed_by_token(d_rows, sent)
+    else:
+        d_flat = _of_pairs(d_rows, sent).astype(jnp.float32).sum(axis=1)
     return reduced_to_vma_of(flat, d_flat.astype(flat.dtype)), None
 
 
@@ -178,13 +255,20 @@ def buffer_rows_to_tokens(out, weight, sent: Dispatch):
     Backward (products in float32 as well):
     ``d_out[r] = weight[pair[r]] * d_mixed[token_of_row[r]]`` and
     ``d_weight[t, c] = <d_mixed[t], out[position[t, c]]>`` where valid, zero
-    elsewhere — gathers both."""
+    elsewhere.  Where :func:`walks_rows` the forward is a scatter-add of the
+    weighted rows under ``group_sizes.sum()`` and ``d_weight`` a write of
+    their scalars; otherwise both are gathers over every pair."""
     return _buffer_rows_to_tokens_fwd(out, weight, sent)[0]
 
 
 def _buffer_rows_to_tokens_fwd(out, weight, sent):
-    picked = _of_pairs(out, sent).astype(jnp.float32)
-    mixed = (picked * weight[..., None]).sum(axis=1)
+    if walks_rows(sent):
+        weighted = weight.reshape(-1)[sent.pair][:, None] \
+            * out.astype(jnp.float32)
+        mixed = _rows_summed_by_token(weighted, sent)
+    else:
+        picked = _of_pairs(out, sent).astype(jnp.float32)
+        mixed = (picked * weight[..., None]).sum(axis=1)
     return mixed.astype(out.dtype), (out, weight, sent)
 
 
@@ -195,9 +279,10 @@ def _buffer_rows_to_tokens_bwd(res, d_mixed):
     # <d_mixed[t], out[r]> once per buffer row, in the pass that reads both
     # anyway; each valid pair then picks its row's scalar (gathering the
     # rows themselves a second time, k-wide, took 1.3 ms a layer more on the
-    # v5e: my chip run, PR 27).
+    # v5e: my chip run, PR 27), or each row writes its own to its pair.
     along = (out.astype(jnp.float32) * d_weighted).sum(axis=-1)
-    d_weight = _of_pairs(along, sent)
+    d_weight = _rows_scalars_to_pairs(along, sent) if walks_rows(sent) \
+        else _of_pairs(along, sent)
     return (reduced_to_vma_of(out, d_out.astype(out.dtype)),
             reduced_to_vma_of(weight, d_weight.astype(weight.dtype)), None)
 

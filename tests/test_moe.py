@@ -30,9 +30,10 @@ from horovod_tpu.models import (MoEConfig, TransformerLM,
                                 moe_next_token_loss, next_token_loss,
                                 record_expert_rows, router_losses)
 from horovod_tpu.models.transformer import SparseExperts
-from horovod_tpu.ops.moe import (buffer_rows_to_tokens, dispatch_rows,
+from horovod_tpu.ops.moe import (ROW_WALK_PAIRS_PER_ROW,
+                                 buffer_rows_to_tokens, dispatch_rows,
                                  grouped_matmul, token_rows_to_buffer,
-                                 top_choices)
+                                 top_choices, walks_rows)
 
 RTOL = 2e-5
 VOCAB, HIDDEN, HEADS, LAYERS, SEQ = 256, 64, 2, 2, 128
@@ -40,11 +41,11 @@ EXPERTS, PER_TOKEN, WIDTH = 8, 2, 32
 SHARDS = [(0, 1), (0, 4), (3, 4)]
 
 
-def lm(shard=(0, 1), use_flash=False):
+def lm(shard=(0, 1), use_flash=False, moe=None):
     return TransformerLM(
         vocab_size=VOCAB, d_model=HIDDEN, n_layers=LAYERS, n_heads=HEADS,
         dtype=jnp.float32, use_flash=use_flash, qk_norm=True, norm_eps=1e-5,
-        moe=MoEConfig(EXPERTS, PER_TOKEN, WIDTH, shard))
+        moe=moe or MoEConfig(EXPERTS, PER_TOKEN, WIDTH, shard))
 
 
 def reference_config(shard):
@@ -106,6 +107,31 @@ def test_every_gradient_leaf_matches_reference(shard):
     for path, g, w in zip(paths, jax.tree.leaves(got), jax.tree.leaves(want)):
         assert np.isfinite(np.asarray(g)).all(), path
         assert rel(g, w) <= 10 * RTOL, (path, rel(g, w))
+
+
+def test_a_shard_that_walks_its_buffer_matches_reference():
+    """A sixteenth of 32 experts, 4 a token, 1,024 tokens for a buffer of 512
+    rows: the way back to the tokens is the scatter-add of the buffer's rows
+    (`ops.moe.walks_rows`), and the three loss terms and every gradient leaf
+    are the reference's, which knows no buffer."""
+    model = lm(moe=MoEConfig(32, 4, WIDTH, (0, 16), 1.0))
+    config = dict(num_experts=32, experts_per_token=4, expert_shard=(0, 16),
+                  norm_eps=1e-5)
+    params, batch = seeded(model, seed=12, batch=8)
+    _, state = model.apply({"params": params}, batch[0],
+                           mutable=["intermediates"])
+    seen = record_expert_rows(state["intermediates"])
+    assert seen["rows_walked"] == [512] * LAYERS
+    assert seen["rows_over_bound"] == 0
+    for got, want in zip(system_terms(model, params, batch),
+                         reference.loss_terms(params, batch, **config)):
+        assert abs(float(got) - float(want)) <= RTOL * abs(float(want))
+    got = jax.grad(lambda p: system_loss(model, p, batch))(params)
+    want = jax.grad(lambda p: reference.loss(p, batch, **config))(params)
+    for (path, w), g in zip(jax.tree_util.tree_flatten_with_path(want)[0],
+                            jax.tree.leaves(got)):
+        assert rel(g, w) <= 10 * RTOL, (jax.tree_util.keystr(path),
+                                        rel(g, w))
 
 
 def test_loss_is_the_three_terms_with_olmoe_coefficients():
@@ -191,11 +217,22 @@ def test_rows_over_a_bound_are_counted(row_bound, held):
     assert int(seen["rows_over_bound"][0]) == max(0, routed - held)
 
 
-def test_counters_reach_the_caller_and_the_registry():
-    """Rows per local expert and rows over the bound, per layer, from the
-    `intermediates` collection; mirrored into hvd.metrics when it is on."""
-    model = lm((0, 4))
-    params, batch = seeded(model, seed=8)
+# A shard with a sixteenth of 32 experts, 4 a token, its buffer bounded at the
+# balanced share: 8 x 128 tokens are 4,096 pairs for 512 rows, 8 pairs a row —
+# the side of `ops.moe.walks_rows` on which the way back walks the buffer.
+FEW_EXPERTS = MoEConfig(32, 4, WIDTH, (0, 16), 1.0)
+
+
+@pytest.mark.parametrize("moe,batch_size,walked", [
+    (MoEConfig(EXPERTS, PER_TOKEN, WIDTH, (0, 4)), 1, SEQ * PER_TOKEN),
+    (FEW_EXPERTS, 8, 512)], ids=["(0, 4)", "(0, 16)"])
+def test_counters_reach_the_caller_and_the_registry(moe, batch_size, walked):
+    """Rows per local expert, rows over the bound and the rows a pass back
+    to the tokens walks (every pair's for a quarter of the experts, the
+    buffer's for a sixteenth), per layer, from the `intermediates`
+    collection; mirrored into hvd.metrics when it is on."""
+    model = lm(moe=moe)
+    params, batch = seeded(model, seed=8, batch=batch_size)
     _, state = model.apply({"params": params}, batch[0],
                            mutable=["intermediates"])
     was_on = metrics.registry.enabled
@@ -207,14 +244,17 @@ def test_counters_reach_the_caller_and_the_registry():
         if not was_on:
             metrics.registry.disable()
     rows = np.asarray(seen["rows_per_local_expert"])
-    assert rows.shape == (LAYERS, EXPERTS // 4) and seen["rows_over_bound"] == 0
+    assert rows.shape == (LAYERS, 2) and seen["rows_over_bound"] == 0
     chosen = np.asarray(state["intermediates"]["layer_1"]["moe"]
                         ["chosen_experts"][0])
     assert rows[1].tolist() == [(chosen == e).sum() for e in range(2)]
     assert mirrored["rows_per_local_expert"] == rows.tolist()
+    assert seen["rows_walked"] == [walked] * LAYERS == mirrored["rows_walked"]
+    text = metrics.prometheus_text(
+        {**metrics.registry.snapshot(), "moe": mirrored})
     assert "hvd_tpu_moe_expert_rows{layer=\"1\",expert=\"0\"} " \
-        f"{rows[1][0]}" in metrics.prometheus_text(
-            {**metrics.registry.snapshot(), "moe": mirrored})
+        f"{rows[1][0]}" in text
+    assert f"hvd_tpu_moe_rows_walked{{layer=\"1\"}} {walked}" in text
 
 
 def test_dispatch_sorts_local_experts_first():
@@ -241,19 +281,33 @@ def test_dispatch_sorts_local_experts_first():
 # the CLOSER of the two to a float32 sum.
 MOVER_TOKENS, MOVER_WIDTH = 96, 16
 BOUNDS = {"every_pair": None, "cut": 40}
+# Beside the layouts above, a shard with a sixteenth of 32 experts, 4 a token:
+# 384 pairs for a buffer of 40 rows (it holds the ~24 routed here) or of 16 (it
+# cuts some off), 9.6 and 24 pairs a row — the side of `walks_rows` on which
+# the way back to the tokens walks the buffer's rows by a scatter-add.
+FEW = (32, 4)
+LAYOUTS = [(EXPERTS, PER_TOKEN, shard, bound) for shard in SHARDS
+           for bound in BOUNDS.values()] \
+    + [FEW + ((0, 16), 40), FEW + ((5, 16), 40), FEW + ((0, 16), 16)]
 
 
-def routed(shard, bound, dtype, seed=9):
+def layout_id(layout):
+    experts, k, shard, bound = layout
+    return f"{experts}x{k}-{shard}-{bound or 'every_pair'}".replace(" ", "")
+
+
+def routed(layout, dtype, seed=9):
     """Seeded routing and rows: (sent, flat, out, weight, d_rows, d_mixed),
     the last two the cotangents of the buffer's rows and of the tokens'."""
-    i, n = shard
-    local = EXPERTS // n
+    experts, k, (i, n), bound = layout
+    local = experts // n
     keys = jax.random.split(jax.random.PRNGKey(seed), 5)
     probs = jax.nn.softmax(jax.random.normal(keys[0],
-                                             (MOVER_TOKENS, EXPERTS)))
-    weight, expert = jax.lax.top_k(probs, PER_TOKEN)
-    rows = MOVER_TOKENS * PER_TOKEN if bound is None else bound
+                                             (MOVER_TOKENS, experts)))
+    weight, expert = jax.lax.top_k(probs, k)
+    rows = MOVER_TOKENS * k if bound is None else bound
     sent = dispatch_rows(expert, i * local, local, rows)
+    assert walks_rows(sent) == (experts == FEW[0])
     flat = jax.random.normal(keys[1], (MOVER_TOKENS, MOVER_WIDTH), dtype)
     # As grouped_matmul leaves it and its backward hands it down: rows past
     # the last group are zero.
@@ -267,14 +321,14 @@ def routed(shard, bound, dtype, seed=9):
 
 
 def scatter_add_dispatch(flat, sent):
-    return flat[sent.pair // PER_TOKEN]
+    return flat[sent.pair // sent.position.shape[-1]]
 
 
 def scatter_add_combine(out, weight, sent):
     weighted = out.astype(jnp.float32) \
         * weight.reshape(-1)[sent.pair][:, None]
     return jnp.zeros((MOVER_TOKENS, MOVER_WIDTH), jnp.float32).at[
-        sent.pair // PER_TOKEN].add(weighted).astype(out.dtype)
+        sent.pair // sent.position.shape[-1]].add(weighted).astype(out.dtype)
 
 
 def close(got, want, dtype, what):
@@ -284,14 +338,17 @@ def close(got, want, dtype, what):
         (what, np.abs(got - want).max())
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("bound", BOUNDS.values(), ids=BOUNDS.keys())
-@pytest.mark.parametrize("shard", SHARDS, ids=str)
-def test_token_rows_to_buffer_matches_the_scatter_add_form(shard, bound,
-                                                           dtype):
-    sent, flat, _, _, d_rows, _ = routed(shard, bound, dtype)
-    if bound is not None and shard[1] == 1:
+DTYPES = pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                                 ids=["float32", "bfloat16"])
+EVERY_LAYOUT = pytest.mark.parametrize("layout", LAYOUTS, ids=layout_id)
+
+
+@DTYPES
+@EVERY_LAYOUT
+def test_token_rows_to_buffer_matches_the_scatter_add_form(layout, dtype):
+    sent, flat, _, _, d_rows, _ = routed(layout, dtype)
+    _, _, (_, n_shards), bound = layout
+    if bound is not None and (n_shards == 1 or bound == 16):
         assert int(sent.rows_over_bound) > 0
     got, back = jax.vjp(lambda f: token_rows_to_buffer(f, sent), flat)
     want, back_scatter = jax.vjp(lambda f: scatter_add_dispatch(f, sent),
@@ -306,13 +363,10 @@ def test_token_rows_to_buffer_matches_the_scatter_add_form(shard, bound,
     assert rel(d_flat, exact) <= rel(d_scatter, exact) + 1e-7
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("bound", BOUNDS.values(), ids=BOUNDS.keys())
-@pytest.mark.parametrize("shard", SHARDS, ids=str)
-def test_buffer_rows_to_tokens_matches_the_scatter_add_form(shard, bound,
-                                                            dtype):
-    sent, _, out, weight, _, d_mixed = routed(shard, bound, dtype)
+@DTYPES
+@EVERY_LAYOUT
+def test_buffer_rows_to_tokens_matches_the_scatter_add_form(layout, dtype):
+    sent, _, out, weight, _, d_mixed = routed(layout, dtype)
     got, back = jax.vjp(lambda o, w: buffer_rows_to_tokens(o, w, sent),
                         out, weight)
     want, back_scatter = jax.vjp(
@@ -326,15 +380,59 @@ def test_buffer_rows_to_tokens_matches_the_scatter_add_form(shard, bound,
     close(d_weight, d_weight_s, jnp.float32, "d_weight")
     # Pairs with no row in the buffer: exactly zero, not nearly.
     assert float(jnp.abs(jnp.where(sent.valid, 0.0, d_weight)).max()) == 0.0
+    assert float(jnp.abs(jnp.where(sent.valid, d_weight, 0.0)).max()) > 0.0
 
 
-@pytest.mark.parametrize("bound", BOUNDS.values(), ids=BOUNDS.keys())
-@pytest.mark.parametrize("shard", SHARDS, ids=str)
-def test_position_is_the_inverse_of_the_sorted_order(shard, bound):
-    sent = routed(shard, bound, jnp.float32)[0]
-    pairs = MOVER_TOKENS * PER_TOKEN
+@DTYPES
+@pytest.mark.parametrize("layout", [
+    (EXPERTS, PER_TOKEN, (0, 4), None), (EXPERTS, PER_TOKEN, (3, 4), None),
+    FEW + ((0, 16), 40), FEW + ((5, 16), 40)], ids=layout_id)
+def test_rows_past_the_held_ones_take_no_part(layout, dtype):
+    """Whatever the buffer holds at and past `group_sizes.sum()` — rows of
+    experts held elsewhere, rows the kernel never wrote — reaches no token
+    and no weight, on either side of `walks_rows`: NaN there changes not a
+    bit of any result."""
+    sent, flat, out, weight, d_rows, d_mixed = routed(layout, dtype)
+    past = (jnp.arange(out.shape[0]) >= sent.group_sizes.sum())[:, None]
+    assert int(past.sum()) > 0
+
+    def results(out, d_rows):
+        mixed, back = jax.vjp(lambda o, w: buffer_rows_to_tokens(o, w, sent),
+                              out, weight)
+        d_flat, = jax.vjp(lambda f: token_rows_to_buffer(f, sent),
+                          flat)[1](d_rows)
+        return (mixed, d_flat) + back(d_mixed)
+
+    clean = results(out, d_rows)
+    dirty = results(jnp.where(past, jnp.nan, out),
+                    jnp.where(past, jnp.nan, d_rows))
+    for a, b in zip(clean, dirty):
+        assert np.isfinite(np.asarray(b, np.float32)).all()
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("pairs,walks", [
+    (ROW_WALK_PAIRS_PER_ROW * 16, True),
+    (ROW_WALK_PAIRS_PER_ROW * 16 - 1, False)], ids=["at", "one_under"])
+def test_the_buffers_rows_are_walked_from_the_constant_on(pairs, walks):
+    """`walks_rows` reads static shapes alone: pairs >= C * bound."""
+    expert = jnp.arange(pairs, dtype=jnp.int32)[:, None] % EXPERTS
+    sent = dispatch_rows(expert, 0, 1, 16)
+    assert walks_rows(sent) is walks
+    assert sent.rows_walked == (16 if walks else pairs)
+    rows = jnp.ones((16, 4), jnp.bfloat16)
+    text = jax.jit(lambda r: buffer_rows_to_tokens(
+        r, jnp.ones((pairs, 1)), sent)).lower(rows).as_text()
+    assert ("scatter" in text) is walks
+
+
+@EVERY_LAYOUT
+def test_position_is_the_inverse_of_the_sorted_order(layout):
+    sent = routed(layout, jnp.float32)[0]
+    k = layout[1]
+    pairs = MOVER_TOKENS * k
     position = np.asarray(sent.position)
-    assert position.shape == (MOVER_TOKENS, PER_TOKEN)
+    assert position.shape == (MOVER_TOKENS, k)
     assert sorted(position.reshape(-1).tolist()) == list(range(pairs))
     valid = np.asarray(sent.valid).reshape(-1)
     held = int(sent.group_sizes.sum())
@@ -349,7 +447,7 @@ def test_position_is_the_inverse_of_the_sorted_order(shard, bound):
     assert (np.asarray(sent.pair)[flat_position[inside]]
             == np.arange(pairs)[inside]).all()
     assert (np.asarray(sent.token_of_row)
-            == np.asarray(sent.pair) // PER_TOKEN).all()
+            == np.asarray(sent.pair) // k).all()
 
 
 def test_top_choices_backward_is_top_k_s():
@@ -379,6 +477,31 @@ def test_layer_and_its_gradient_lower_to_no_scatter(shard, row_bound):
         (0, 1))).lower(params, x).as_text()
     assert "scatter" not in text and "while" not in text
     assert text.count("gather") >= 4
+
+
+@pytest.mark.parametrize("moe,scatters", [
+    (MoEConfig(32, 4, WIDTH, (0, 4), 1.0), 0),
+    (MoEConfig(32, 4, WIDTH, (3, 16), 1.0), 3),
+    (MoEConfig(32, 4, WIDTH, (0, 16), None), 0)],
+    ids=["quarter", "sixteenth", "sixteenth_unbounded"])
+def test_the_way_back_lowers_to_the_form_its_shapes_choose(moe, scatters):
+    """2,048 tokens, 8,192 pairs.  A quarter of the experts (2,048 rows, 4
+    pairs a row) and a sixteenth with a buffer for every pair: gathers alone,
+    as above.  A sixteenth bounded at its share (512 rows, 16 pairs a row):
+    the combine's forward and the dispatch's backward are scatter-adds of
+    the buffer's rows, the weights' cotangent one scatter of their scalars,
+    and no k-wide gather is left."""
+    layer = SparseExperts(moe, jnp.float32)
+    x = jnp.zeros((1, 2048, HIDDEN))
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0),
+                                               x)["params"])
+    text = jax.jit(jax.grad(
+        lambda p, x: jnp.square(layer.apply({"params": p}, x)).sum(),
+        (0, 1))).lower(params, x).as_text()
+    assert "while" not in text
+    assert text.count('"stablehlo.scatter"') == scatters
+    k_wide = text.count(f"tensor<2048x{moe.experts_per_token}x{HIDDEN}x")
+    assert (k_wide == 0) == bool(scatters)
 
 
 @pytest.mark.parametrize("sizes", [[10, 0, 30, 5], [0, 0, 0, 0],
@@ -490,15 +613,21 @@ def test_sparse_expert_defaults_lower_to_the_same_program(shard, row_bound):
         == OLMOE_DIGESTS[shard, row_bound]
 
 
-def test_trains_through_build_train_step_and_replicas_stay_equal():
+@pytest.mark.parametrize("moe,batch_size", [
+    (MoEConfig(EXPERTS, PER_TOKEN, WIDTH, (0, 4)), 2), (FEW_EXPERTS, 16)],
+    ids=["(0, 4)", "(0, 16)"])
+def test_trains_through_build_train_step_and_replicas_stay_equal(moe,
+                                                                 batch_size):
     """Two CPU devices, data parallel: the step of the dense LM, with the
-    sparse-expert loss.  The replicated weights stay equal on both devices
-    and the loss of a repeated batch falls.  The flash kernel (interpreted
-    here), as in the benchmark's step: blockwise_attention does not pass
-    shard_map's vma check (PERF.md section 7)."""
-    model = lm((0, 4), use_flash=True)
+    sparse-expert loss, the way back to the tokens a gather (a quarter of
+    the experts) or a scatter-add of the buffer's rows (a sixteenth: 1,024
+    tokens a device, 8 pairs a row).  The replicated weights stay equal on
+    both devices and the loss of a repeated batch falls.  The flash kernel
+    (interpreted here), as in the benchmark's step: blockwise_attention does
+    not pass shard_map's vma check (PERF.md section 7)."""
+    model = lm(moe=moe, use_flash=True)
     mesh = Mesh(np.array(jax.devices()[:2]), ("hvd",))
-    params, batch = seeded(model, seed=7, batch=2)
+    params, batch = seeded(model, seed=7, batch=batch_size)
     tx = optax.adamw(1e-2)
 
     def loss_fn(params, batch):

@@ -47,7 +47,8 @@ SECTION_FAMILIES = {
     "flight": ("hvd_tpu_flight_events_total",
                "hvd_tpu_flight_ring_capacity"),
     "moe": ("hvd_tpu_moe_expert_rows",
-            "hvd_tpu_moe_rows_over_bound_total"),
+            "hvd_tpu_moe_rows_over_bound_total",
+            "hvd_tpu_moe_rows_walked"),
     "train_step": ("hvd_tpu_train_step_all_reduces",),
     "compression": ("hvd_tpu_compression_mode",
                     "hvd_tpu_compression_wire_bytes_total",
