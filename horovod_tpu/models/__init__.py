@@ -20,9 +20,12 @@ from horovod_tpu.models.resnet import (  # noqa: F401
     ResNet101,
     ResNet152,
 )
+from horovod_tpu.models.delta import DeltaConfig, DeltaMixer  # noqa: F401
 from horovod_tpu.models.ssm import Mamba2Config, Mamba2Mixer  # noqa: F401
 from horovod_tpu.models.transformer import (  # noqa: F401
     DecodeContext,
+    LatentAttention,
+    LatentConfig,
     MoEConfig,
     TransformerLM,
     moe_next_token_loss,

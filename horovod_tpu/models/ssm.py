@@ -62,6 +62,20 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
 
+def causal_depthwise_conv(x, taps, bias=None):
+    """The short convolution both recurrent mixers open with (this one and
+    :class:`~horovod_tpu.models.delta.DeltaMixer`): ``x`` (batch, seq,
+    channels), ``taps`` (conv, channels), each channel its own filter over the
+    token and the ``conv - 1`` before it.  Tap k multiplies the token
+    ``conv - 1 - k`` places back; the last tap the token itself.  Products
+    and sum in float32 (``taps`` and ``bias`` are)."""
+    conv, seq = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (conv - 1, 0), (0, 0)))
+    mixed = sum(taps[k] * lax.dynamic_slice_in_dim(padded, k, seq, axis=1)
+                for k in range(conv))
+    return mixed if bias is None else bias + mixed
+
+
 class Mamba2Mixer(nn.Module):
     """One mixer's share (module docstring), each stage under a
     ``jax.named_scope`` a trace can read: ``hvd_ssm_in_proj``,
@@ -109,13 +123,8 @@ class Mamba2Mixer(nn.Module):
             z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * bc],
                                    axis=-1)
         with jax.named_scope("hvd_ssm_conv"):
-            # Tap k multiplies the token conv - 1 - k places back; the last
-            # tap the token itself.  Products and sum in float32.
-            padded = jnp.pad(xbc, ((0, 0), (self.conv - 1, 0), (0, 0)))
-            xbc = b_conv + sum(
-                w_conv[k] * lax.dynamic_slice_in_dim(padded, k, seq, axis=1)
-                for k in range(self.conv))
-            xbc = nn.silu(xbc).astype(self.dtype)
+            xbc = nn.silu(causal_depthwise_conv(xbc, w_conv, b_conv)).astype(
+                self.dtype)
             x, B, C = jnp.split(xbc, [inner, inner + bc], axis=-1)
         with jax.named_scope("hvd_ssm_scan"):
             y, decay_min = chunked_scan(

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.models.delta import DeltaConfig, DeltaMixer
 from horovod_tpu.models.ssm import Mamba2Config, Mamba2Mixer
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              ring_attention)
@@ -168,9 +169,15 @@ class MoEConfig(NamedTuple):
     * ``latent_width``: the experts work in that width, between a projection
       down before the dispatch and one up after the combine, whole on every
       shard.
-    * ``shared_width``: one more expert of that width (same activation, not
-      routed, not weighted) on the layer's own input, whole on every shard;
-      its output is added."""
+    * ``shared_width``: one more expert of that width (same activation, gated
+      where the routed ones are; not routed, not weighted) on the layer's own
+      input, whole on every shard; its output is added.
+    * ``n_group``, ``topk_group`` (sigmoid scoring): the experts lie in
+      ``n_group`` consecutive groups; a group's score is the sum of its two
+      largest ``score + bias``, the ``topk_group`` best groups stay, and the
+      ``experts_per_token`` largest ``score + bias`` among THEIR experts are
+      chosen (DeepSeek-V3's group-limited choice, arXiv:2412.19437).  One
+      group: a plain top-k, the program above."""
 
     num_experts: int
     experts_per_token: int
@@ -183,6 +190,8 @@ class MoEConfig(NamedTuple):
     expert_act: str = "gated_silu"
     latent_width: Optional[int] = None
     shared_width: Optional[int] = None
+    n_group: int = 1
+    topk_group: int = 1
 
     def buffer_rows(self, tokens: int) -> int:
         """Rows of the sorted buffer for ``tokens`` tokens."""
@@ -191,6 +200,19 @@ class MoEConfig(NamedTuple):
             return pairs
         balanced = self.row_bound * pairs / self.expert_shard[1]
         return min(pairs, -(-int(balanced) // 512) * 512)
+
+
+def _kept_groups(scores, n_group: int, topk_group: int):
+    """(``scores`` (tokens, experts) with every expert outside the token's
+    ``topk_group`` best of ``n_group`` consecutive groups at -inf, those
+    groups (tokens, topk_group)).  A group's score is the sum of its two
+    largest; which groups stay takes no gradient."""
+    grouped = scores.reshape(scores.shape[0], n_group, -1)
+    best = lax.top_k(lax.stop_gradient(grouped), min(2, grouped.shape[-1]))[0]
+    groups = lax.top_k(best.sum(-1), topk_group)[1]
+    kept = (groups[..., None] == jnp.arange(n_group)).any(axis=-2)
+    return jnp.where(kept[..., None], grouped, -jnp.inf).reshape(
+        scores.shape), groups
 
 
 class SparseExperts(nn.Module):
@@ -210,7 +232,8 @@ class SparseExperts(nn.Module):
     ``choices`` (pairs per expert, all experts), ``prob_sum`` (with sigmoid
     scoring the scores'), ``z_sum``, ``tokens``: what :func:`router_losses`
     reads; ``intermediates`` — ``chosen_experts`` (tokens, k), ``rows_per_
-    local_expert``, ``rows_over_bound``, ``rows_walked`` (by one pass back).
+    local_expert``, ``rows_over_bound``, ``rows_walked`` (by one pass back),
+    and with more than one group ``groups_chosen`` (tokens, topk_group).
 
     Two more scopes where the configuration asks for them: ``hvd_moe_latent``
     (both projections around the experts' width) and ``hvd_moe_shared``."""
@@ -234,6 +257,12 @@ class SparseExperts(nn.Module):
         if cfg.scoring not in ("softmax", "sigmoid") \
                 or cfg.expert_act not in ("gated_silu", "relu2"):
             raise ValueError(f"unknown scoring or expert_act in {cfg}")
+        if cfg.n_group > 1 and (cfg.scoring != "sigmoid"
+                                or cfg.num_experts % cfg.n_group
+                                or not 0 < cfg.topk_group <= cfg.n_group):
+            raise ValueError(f"n_group {cfg.n_group} wants sigmoid scoring, "
+                             f"to divide {cfg.num_experts} experts and at "
+                             f"most as many groups kept, not {cfg}")
         gated = cfg.expert_act == "gated_silu"
         act = nn.silu if gated else (lambda t: jnp.square(nn.relu(t)))
         inner = cfg.latent_width or d
@@ -258,16 +287,20 @@ class SparseExperts(nn.Module):
                 weight, expert = top_choices(probs, k)
             else:
                 probs = jax.nn.sigmoid(logits)
-                if self.has_variable("buffers", "selection_bias"):
-                    bias = self.get_variable("buffers", "selection_bias")
-                    weight, expert = top_choices(probs + bias, k)
+                bias = self.get_variable("buffers", "selection_bias") \
+                    if self.has_variable("buffers", "selection_bias") else None
+                biased = probs if bias is None else probs + bias
+                if cfg.n_group > 1:
+                    biased, groups = _kept_groups(biased, cfg.n_group,
+                                                  cfg.topk_group)
+                    self.sow("intermediates", "groups_chosen", groups)
+                weight, expert = top_choices(biased, k)
+                if bias is not None:
                     # The chosen experts' own bias by a compare and a sum: the
                     # TPU gathers scalars one by one (0.6 ms a layer for
                     # 90,112 of them: my chip runs, PR 30).
                     chosen = expert[..., None] == jnp.arange(cfg.num_experts)
                     weight = weight - jnp.where(chosen, bias, 0.0).sum(-1)
-                else:
-                    weight, expert = top_choices(probs, k)
             if cfg.renormalize:
                 weight = weight / (weight.sum(axis=-1, keepdims=True) + 1e-20)
             if cfg.weight_scale != 1.0:
@@ -311,10 +344,14 @@ class SparseExperts(nn.Module):
                                  name="latent_up")(mixed)
         if cfg.shared_width:
             with jax.named_scope("hvd_moe_shared"):
-                shared = nn.Dense(cfg.shared_width, use_bias=False,
-                                  dtype=self.dtype, name="shared_up")(flat)
+                def wide(name):
+                    return nn.Dense(cfg.shared_width, use_bias=False,
+                                    dtype=self.dtype, name=name)(flat)
+
+                shared = act(wide("shared_gate")) * wide("shared_up") \
+                    if gated else act(wide("shared_up"))
                 mixed = mixed + nn.Dense(d, use_bias=False, dtype=self.dtype,
-                                         name="shared_down")(act(shared))
+                                         name="shared_down")(shared)
         return mixed.reshape(x.shape)
 
 
@@ -454,6 +491,124 @@ class Attention(nn.Module):
                 * scale[:, None, :]).astype(t.dtype)
 
 
+class LatentConfig(NamedTuple):
+    """Sizes of a latent-attention layer (``TransformerLM(latent=)``;
+    DeepSeek-V2's multi-head latent attention, arXiv:2405.04434, without a
+    query latent): the key/value latent's ``kv_rank``, a head's non-rotary
+    ``nope_dim`` and rotary ``rope_dim`` of query and key, its value's
+    ``v_dim``, the rotary base."""
+
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float = 10000.0
+
+
+class LatentAttention(nn.Module):
+    """Attention whose keys and values come from one low-rank latent a token,
+    with a head-wise output gate.  ``u`` the layer's normed input, H heads:
+
+        [q_nope | q_rope]_h = u W_q                   nope_dim + rope_dim
+        [c | k_rope] = u W_kva,  c = RMSNorm(c)       kv_rank + rope_dim
+        [k_nope | v]_h = c W_kvb                      nope_dim + v_dim
+        q_h = [q_nope | rope(q_rope)],  k_h = [k_nope | rope(k_rope)]
+        o_h = causal softmax(q_h k_h^T (nope_dim + rope_dim)^-1/2) v_h
+        out = concat_h(o_h * sigmoid(u W_g)_h) W_o
+
+    ONE ``k_rope`` a token serves every head; the rotation is over all of
+    ``rope_dim`` (adjacent pairs, :func:`rope`).  Query and key are
+    ``nope_dim + rope_dim`` wide and the value ``v_dim``: the flash kernels
+    take the two widths as they are (``ops/attention.py``).
+
+    ``head_shard=(i, n)``: heads ``[i H/n, (i+1) H/n)`` — their slices of
+    ``W_q``, ``W_kvb``, ``W_g`` and ``W_o``; ``W_kva`` and the latent's norm
+    are whole on every shard.  The ``n`` outputs sum to the whole layer's;
+    the sum is the caller's.  Scopes: ``hvd_mla_q_proj``,
+    ``hvd_mla_kv_latent``, ``hvd_mla_attend``, ``hvd_mla_out_proj``."""
+
+    n_heads: int
+    config: LatentConfig
+    dtype: Any = jnp.bfloat16
+    use_flash: bool = True
+    norm_eps: float = 1e-6
+    head_shard: Tuple[int, int] = (0, 1)
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        shard, n_shards = self.head_shard
+        if self.n_heads % n_shards or not 0 <= shard < n_shards:
+            raise ValueError(f"head_shard {self.head_shard} does not divide "
+                             f"{self.n_heads} heads")
+        heads = self.n_heads // n_shards
+        b, s, d = x.shape
+        per_head = nn.initializers.lecun_normal(in_axis=0, out_axis=(1, 2))
+        w_q = self.param("q_kernel", per_head,
+                         (d, heads, cfg.nope_dim + cfg.rope_dim), jnp.float32)
+        w_kva = self.param("kv_a_kernel", nn.initializers.lecun_normal(),
+                           (d, cfg.kv_rank + cfg.rope_dim), jnp.float32)
+        latent_scale = self.param("kv_norm_scale", nn.initializers.ones,
+                                  (cfg.kv_rank,), jnp.float32)
+        w_kvb = self.param("kv_b_kernel", per_head,
+                           (cfg.kv_rank, heads, cfg.nope_dim + cfg.v_dim),
+                           jnp.float32)
+        w_gate = self.param("gate_kernel", nn.initializers.lecun_normal(),
+                            (d, heads), jnp.float32)
+        w_o = self.param(
+            "o_kernel",
+            nn.initializers.lecun_normal(in_axis=(0, 1), out_axis=2),
+            (heads, cfg.v_dim, d), jnp.float32)
+        x = x.astype(self.dtype)
+        positions = jnp.arange(s)
+
+        def turned(t):
+            return rope(t, positions, cfg.rope_theta)
+
+        with jax.named_scope("hvd_mla_q_proj"):
+            q = jnp.einsum("bsd,dhe->bhse", x, w_q.astype(self.dtype))
+            q = jnp.concatenate([q[..., :cfg.nope_dim],
+                                 turned(q[..., cfg.nope_dim:])], axis=-1)
+        with jax.named_scope("hvd_mla_kv_latent"):
+            latent, k_rope = jnp.split(
+                jnp.dot(x, w_kva.astype(self.dtype)), [cfg.kv_rank], axis=-1)
+            wide = latent.astype(jnp.float32)
+            mean_sq = jnp.mean(jnp.square(wide), axis=-1, keepdims=True)
+            latent = (wide * lax.rsqrt(mean_sq + self.norm_eps)
+                      * latent_scale).astype(self.dtype)
+            kv = jnp.einsum("bsr,rhe->bhse", latent, w_kvb.astype(self.dtype))
+            shared = jnp.broadcast_to(turned(k_rope[:, None]),
+                                      (b, heads, s, cfg.rope_dim))
+            k = jnp.concatenate([kv[..., :cfg.nope_dim], shared], axis=-1)
+            v = kv[..., cfg.nope_dim:]
+        with jax.named_scope("hvd_mla_attend"):
+            out = flash_attention(q, k, v, causal=True) if self.use_flash \
+                else blockwise_attention(q, k, v, causal=True)
+        with jax.named_scope("hvd_mla_out_proj"):
+            gate = nn.sigmoid(jnp.einsum(
+                "bsd,dh->bhs", x, w_gate.astype(self.dtype),
+                preferred_element_type=jnp.float32))
+            out = (out * gate[..., None]).astype(self.dtype)
+            return jnp.einsum("bhse,hed->bsd", out, w_o.astype(self.dtype))
+
+
+class GatedMLP(nn.Module):
+    """The dense gated MLP ``down(silu(gate x) * up x)`` of width ``d_ff``,
+    no biases."""
+
+    d_ff: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        def wide(name):
+            return nn.Dense(self.d_ff, use_bias=False, dtype=self.dtype,
+                            name=name)(x)
+
+        return nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
+                        name="down")(nn.silu(wide("gate")) * wide("up"))
+
+
 class Block(nn.Module):
     n_heads: int
     d_ff: int
@@ -493,15 +648,20 @@ class Block(nn.Module):
         return x if new_kv is None else (x, new_kv)
 
 
-LAYER_KINDS = ("ssm", "attention", "experts")
+# kind -> the module a MixerLayer of that kind runs.
+LAYER_KINDS = {"ssm": "Mamba2Mixer", "attention": "Attention",
+               "experts": "SparseExperts", "delta": "DeltaMixer",
+               "latent_attention": "LatentAttention", "gated_mlp": "GatedMLP"}
 
 
 class MixerLayer(nn.Module):
-    """One layer of a per-layer pattern (``TransformerLM(layers=)``): ONE
-    mixer behind one norm and one residual, ``x + mixer(RMSNorm(x))``.
-    ``kind``: ``"ssm"`` (:class:`~horovod_tpu.models.ssm.Mamba2Mixer`),
-    ``"attention"`` (:class:`Attention`) or ``"experts"``
-    (:class:`SparseExperts`)."""
+    __doc__ = (
+        """One layer of a per-layer pattern (``TransformerLM(layers=)``): ONE
+    mixer behind one norm and one residual, ``x + mixer(RMSNorm(x))``; a
+    published layer of two sublayers is two consecutive entries.  ``kind``
+    and the mixer it runs: """
+        + ", ".join(f"``{kind!r}`` {module}"
+                    for kind, module in LAYER_KINDS.items()) + ".")
 
     kind: str
     n_heads: int
@@ -514,6 +674,9 @@ class MixerLayer(nn.Module):
     n_kv_heads: Optional[int] = None
     rope: bool = True
     head_shard: Tuple[int, int] = (0, 1)
+    delta: Optional[DeltaConfig] = None
+    latent: Optional[LatentConfig] = None
+    d_ff: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
@@ -531,9 +694,19 @@ class MixerLayer(nn.Module):
                               head_shard=self.head_shard, name="mixer")
         elif self.kind == "experts":
             mixer = SparseExperts(self.moe, self.dtype, name="mixer")
+        elif self.kind == "delta":
+            mixer = DeltaMixer(*self.delta, head_shard=self.head_shard,
+                               dtype=self.dtype, norm_eps=self.norm_eps,
+                               name="mixer")
+        elif self.kind == "latent_attention":
+            mixer = LatentAttention(self.n_heads, self.latent, self.dtype,
+                                    self.use_flash, self.norm_eps,
+                                    self.head_shard, name="mixer")
+        elif self.kind == "gated_mlp":
+            mixer = GatedMLP(self.d_ff, self.dtype, name="mixer")
         else:
             raise ValueError(f"layer kind {self.kind!r} is none of "
-                             f"{LAYER_KINDS}")
+                             f"{tuple(LAYER_KINDS)}")
         return x + mixer(h)
 
 
@@ -571,19 +744,23 @@ class TransformerLM(nn.Module):
     qk_norm: bool = False
     norm_eps: float = 1e-6
     # A per-layer pattern in place of ``n_layers`` blocks: a tuple of layer
-    # kinds (``LAYER_KINDS``), each layer ONE mixer behind one norm and one
-    # residual (:class:`MixerLayer`) — ``"ssm"`` a Mamba-2 mixer of ``ssm``'s
-    # sizes, ``"attention"``, ``"experts"`` the sparse experts of ``moe``.
-    # ``n_kv_heads``, ``rope`` and ``head_shard`` are the attention layers'
-    # (and ``head_shard`` the Mamba-2 mixers') as :class:`Attention` has
-    # them.  Unset, the model is the block above, parameter for parameter.
+    # kinds (the keys of ``LAYER_KINDS``), each layer ONE mixer behind one
+    # norm and one residual (:class:`MixerLayer`) — ``"ssm"`` a Mamba-2 mixer
+    # of ``ssm``'s sizes, ``"attention"``, ``"experts"`` the sparse experts of
+    # ``moe``, ``"delta"`` a Kimi-delta mixer of ``delta``'s sizes,
+    # ``"latent_attention"`` of ``latent``'s, ``"gated_mlp"`` a dense MLP of
+    # ``d_ff``.  ``n_kv_heads`` and ``rope`` are the attention layers' as
+    # :class:`Attention` has them, ``head_shard`` every head-carrying
+    # mixer's.  Unset, the model is the block above, parameter for parameter.
     # A pattern trains on one sequence shard and has no cached decode: a
-    # state-space layer's state is no key/value cache.
+    # recurrent layer's state is no key/value cache.
     layers: Optional[Tuple[str, ...]] = None
     ssm: Optional[Mamba2Config] = None
     n_kv_heads: Optional[int] = None
     rope: bool = True
     head_shard: Tuple[int, int] = (0, 1)
+    delta: Optional[DeltaConfig] = None
+    latent: Optional[LatentConfig] = None
 
     @nn.compact
     def __call__(self, tokens, targets=None, decode_ctx=None):
@@ -612,6 +789,7 @@ class TransformerLM(nn.Module):
             x = MixerLayer(kind, self.n_heads, self.dtype, self.use_flash,
                            self.moe, self.ssm, self.qk_norm, self.norm_eps,
                            self.n_kv_heads, self.rope, self.head_shard,
+                           self.delta, self.latent, d_ff,
                            name=f"layer_{i}")(x)
         for i in range(0 if self.layers is not None else self.n_layers):
             block = Block(self.n_heads, d_ff, self.dtype, self.seq_axis,

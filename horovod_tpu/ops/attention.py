@@ -127,7 +127,7 @@ def _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size, q_offset,
     q_pos = q_offset + jnp.arange(q_len)
     m0 = jnp.full(q.shape[:-1], NEG_INF, jnp.float32)
     l0 = jnp.zeros(q.shape[:-1], jnp.float32)
-    acc0 = jnp.zeros(q.shape[:-2] + (q_len, q.shape[-1]), jnp.float32)
+    acc0 = jnp.zeros(q.shape[:-2] + (q_len, v.shape[-1]), jnp.float32)
 
     def step(carry, inputs):
         m, l, acc = carry
@@ -178,7 +178,8 @@ def _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_size,
     dq, (dkb, dvb) = lax.scan(step, dq0, (jnp.arange(n_blocks), kb, vb))
     # (n_blocks, ..., block, d) -> (..., n_blocks*block, d) -> clip padding
     dk = jnp.moveaxis(dkb, 0, -3).reshape(*k.shape[:-2], n_blocks * block, d)
-    dv = jnp.moveaxis(dvb, 0, -3).reshape(*v.shape[:-2], n_blocks * block, d)
+    dv = jnp.moveaxis(dvb, 0, -3).reshape(*v.shape[:-2], n_blocks * block,
+                                          v.shape[-1])
     return (dq.astype(q.dtype), dk[..., :k_len, :].astype(k.dtype),
             dv[..., :k_len, :].astype(v.dtype))
 
@@ -614,12 +615,15 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
                        scale_r=1.0, grad_dtype=jnp.float32, dq_scale=1.0,
                        name="hvd_flash_bwd"):
     """pallas_call wrapper for `_combined_bwd_kernel` over (bh, sl, d)
-    operands (q pre-scaled by the pow2 part of sm_scale).  Returns
+    operands (q pre-scaled by the pow2 part of sm_scale; ``do`` and ``v_cur``
+    may have another width than ``q`` and ``k_cur``, and ``dv`` then has
+    theirs).  Returns
     (dk, dv, dq[, k_next, v_next]) with the gradients in ``grad_dtype``
     (accumulation is always f32 in scratch; only the flush casts, after
     applying ``dq_scale`` to dq in f32).  ``name`` is the kernel's name in
     a device trace: the fused ring's backward step passes its own."""
     bh, sl, d = q.shape
+    d_v = v_cur.shape[-1]
     num_q, num_k = sl // block_q, sl // block_k
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(k_offset, jnp.int32)])
@@ -631,37 +635,37 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
         axis_name=axis_name, mesh_axes=mesh_axes, scale_r=scale_r,
         dq_scale=dq_scale)
 
-    def qspec(row):
-        return pl.BlockSpec((1, block_q, d),
+    def qspec(row, width=d):
+        return pl.BlockSpec((1, block_q, width),
                             lambda b, ki, qi, s, _r=row: (b, _r(qi, ki), 0))
 
-    def kspec(row):
-        return pl.BlockSpec((1, block_k, d),
+    def kspec(row, width=d):
+        return pl.BlockSpec((1, block_k, width),
                             lambda b, ki, qi, s, _r=row: (b, _r(qi, ki), 0))
 
     inner_q = lambda qi, ki: qi  # noqa: E731
     outer_k = lambda qi, ki: ki  # noqa: E731
     in_specs = [
         qspec(inner_q),                                    # q
-        qspec(inner_q),                                    # do
+        qspec(inner_q, d_v),                               # do
         pl.BlockSpec((1, 8, block_q), lambda b, ki, qi, s: (b, 0, qi)),
         pl.BlockSpec((1, 8, block_q), lambda b, ki, qi, s: (b, 0, qi)),
         kspec(outer_k),                                    # k (blocked)
-        kspec(outer_k),                                    # v (blocked)
+        kspec(outer_k, d_v),                               # v (blocked)
     ]
     out_shapes = [
         jax.ShapeDtypeStruct((bh, sl, d), grad_dtype),     # dk
-        jax.ShapeDtypeStruct((bh, sl, d), grad_dtype),     # dv
+        jax.ShapeDtypeStruct((bh, sl, d_v), grad_dtype),   # dv
         jax.ShapeDtypeStruct((bh, sl, d), grad_dtype),     # dq
     ]
     out_specs = [
         kspec(outer_k),                                    # dk
-        kspec(outer_k),                                    # dv
+        kspec(outer_k, d_v),                               # dv
         pl.BlockSpec((1, sl, d), lambda b, ki, qi, s: (b, 0, 0)),  # dq
     ]
     scratch_shapes = [
         pltpu.VMEM((block_k, d), jnp.float32),             # dk accumulator
-        pltpu.VMEM((block_k, d), jnp.float32),             # dv accumulator
+        pltpu.VMEM((block_k, d_v), jnp.float32),           # dv accumulator
         pltpu.VMEM((sl, d), jnp.float32),                  # whole-seq dq
     ]
     args = [offsets, q, do, lse8, delta8, k_cur, v_cur]
@@ -744,8 +748,10 @@ def _vmem_budget_bytes() -> int:
 
 
 def _plan_vmem_bytes(mode: str, q_len: int, d: int, block_q: int,
-                     block_k: int) -> int:
+                     block_k: int, d_v: Optional[int] = None) -> int:
     """Conservative scoped-VMEM estimate for a backward plan, bytes.
+    ``d`` is the width of q and k (and of dq, dk), ``d_v`` that of v, do and
+    dv where it differs (latent attention: 192 and 128).
 
     Mosaic's real allocation is not a closed form (see _bwd_plan), so
     this models the structural upper bound: every revolving block window
@@ -758,31 +764,33 @@ def _plan_vmem_bytes(mode: str, q_len: int, d: int, block_q: int,
     over, so clamping to this estimate can only reject plans the frontier
     also rejects."""
     lanes = max(d, 128)
+    both = lanes + max(d_v or d, 128)   # a q/k-wide and a v-wide window
     w, db = 4, 2              # f32 worst case; double-buffered windows
     lse = db * w * 8 * 2 * block_q          # lse8 + delta8 windows
     if mode == "combined":
-        wins = db * w * lanes * (2 * block_q + 2 * block_k  # q,do,k,v in
-                                 + 2 * block_k)             # dk,dv out
+        wins = db * w * both * (block_q + block_k  # q,do,k,v in
+                                + block_k)         # dk,dv out
         dq = (db + 1) * w * lanes * q_len   # whole-seq out window + scratch
-        scratch = w * lanes * 2 * block_k   # dk/dv accumulators
+        scratch = w * both * block_k        # dk/dv accumulators
         return wins + lse + dq + scratch
     # Split kernels run back to back; scoped peak is the larger one.
-    dkdv = (db * w * lanes * (2 * block_q + 4 * block_k) + lse
-            + w * lanes * 2 * block_k)
-    dqk = (db * w * lanes * (3 * block_q + 2 * block_k) + lse
+    dkdv = (db * w * both * (block_q + 2 * block_k) + lse
+            + w * both * block_k)
+    dqk = (db * w * (both * (block_q + block_k) + lanes * block_q) + lse
            + w * lanes * block_q)
     return max(dkdv, dqk)
 
 
 def _fwd_vmem_bytes(q_len: int, d: int, block_q: int,
-                    block_k: int) -> int:
+                    block_k: int, d_v: Optional[int] = None) -> int:
     """Same structural estimate for the forward kernel (q in + out + k/v
-    windows, lse output, online-softmax scratch)."""
-    lanes = max(d, 128)
+    windows, lse output, online-softmax scratch); the output and its
+    accumulator have v's width."""
+    lanes_v = max(d_v or d, 128)
     w, db = 4, 2
-    return (db * w * lanes * (2 * block_q + 2 * block_k)
+    return (db * w * (max(d, 128) + lanes_v) * (block_q + block_k)
             + db * w * 8 * block_q                       # lse out
-            + w * block_q * (2 * 128 + lanes))           # m/l/acc scratch
+            + w * block_q * (2 * 128 + lanes_v))         # m/l/acc scratch
 
 
 def _clamp_blocks(mode: str, q_len: int, d: int, block_q: int,
@@ -813,7 +821,7 @@ def _clamp_blocks(mode: str, q_len: int, d: int, block_q: int,
 
 
 def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
-              bh: int = 1):
+              bh: int = 1, d_v: Optional[int] = None):
     """Choose the flash-backward execution mode and blocks against the
     chip's 16 MiB scoped-VMEM ceiling.
 
@@ -850,10 +858,18 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
     once; every benchmark cell runs it, PERF.md section 3) or ``"split"``
     (dkdv + dq kernel pair, O(block) scoped memory: full 1024-blocks
     compile at every probed extreme — seq to 64k, bh to 256, d to 256).
-    Split against combined is not measured on this machine: no cell
-    reaches the split plan (PERF.md section 7)."""
-    rows128 = q_len * max(d, 128) // 128
-    if d <= 128:
+    Split against combined is not measured on this machine (PERF.md
+    section 7).
+
+    ``d_v``: the width of v, do and dv where it is not ``d`` (q, k, dq, dk).
+    The bands above are entered with the WIDER of the two — every probe of
+    the sweep had one width — so latent attention's 192 and 128 take the
+    split pair, whose windows :func:`_plan_vmem_bytes` charges each at its
+    own width."""
+    wide = max(d, d_v or d)
+    estimate = functools.partial(_plan_vmem_bytes, d_v=d_v)
+    rows128 = q_len * max(wide, 128) // 128
+    if wide <= 128:
         # Each band is gated at its CALIBRATED bh bound (the table
         # above); anything beyond falls through to split, which
         # compiles everywhere — never extrapolate the combined kernel
@@ -872,7 +888,8 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
             choice = (_pick_block(q_len, min(block_q, 512)),
                       _pick_block(q_len, min(block_k, 512)))
         if choice is not None:
-            fitted = _clamp_blocks("combined", q_len, d, *choice)
+            fitted = _clamp_blocks("combined", q_len, d, *choice,
+                                   estimate=estimate)
             if fitted is not None:
                 return ("combined",) + fitted
             warnings.warn(
@@ -882,7 +899,7 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
                 "(whole-seq dq scratch); demoting to the split kernels",
                 stacklevel=2)
     fitted = _clamp_blocks("split", q_len, d, _pick_block(q_len, block_q),
-                           _pick_block(q_len, block_k))
+                           _pick_block(q_len, block_k), estimate=estimate)
     return ("split",) + fitted
 
 
@@ -897,8 +914,10 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
     (see _bwd_plan).  Returns (dk, dv, dq) in ``grad_dtype`` (f32
     accumulation in scratch; the flush casts)."""
     bh, sl, d = q.shape
+    d_v = v.shape[-1]              # do, v and dv; q, k, dq and dk have d
     num_q, num_k = sl // block_q, sl // block_k
     qspec, kspec = _row_spec(block_q, d), _row_spec(block_k, d)
+    dospec, vspec = _row_spec(block_q, d_v), _row_spec(block_k, d_v)
 
     def lse_spec(row):
         return pl.BlockSpec((1, 8, block_q), lambda b, i, j, _r=row:
@@ -910,18 +929,20 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
     # an out_shape that does not say how it varies; as q does.
     grad_shape = jax.ShapeDtypeStruct((bh, sl, d), grad_dtype,
                                       vma=jax.typeof(q).vma)
+    dv_shape = jax.ShapeDtypeStruct((bh, sl, d_v), grad_dtype,
+                                    vma=jax.typeof(q).vma)
     dkdv = functools.partial(
         _flash_bwd_dkdv_kernel, causal=causal, block_q=block_q,
         block_k=block_k, num_q_blocks=num_q, scale_r=scale_r)
     dk, dv = pl.pallas_call(
         dkdv,
         grid=(bh, num_k, num_q),  # queries innermost
-        in_specs=[qspec(inner), qspec(inner), lse_spec(inner),
-                  lse_spec(inner), kspec(outer), kspec(outer)],
-        out_specs=(kspec(outer), kspec(outer)),
-        out_shape=(grad_shape, grad_shape),
+        in_specs=[qspec(inner), dospec(inner), lse_spec(inner),
+                  lse_spec(inner), kspec(outer), vspec(outer)],
+        out_specs=(kspec(outer), vspec(outer)),
+        out_shape=(grad_shape, dv_shape),
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, d_v), jnp.float32)],
         interpret=interpret,
         name="hvd_flash_bwd_dkdv",
     )(q, do, lse8, delta8, k, v)
@@ -932,8 +953,8 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
     dq = pl.pallas_call(
         dqk,
         grid=(bh, num_q, num_k),  # keys innermost
-        in_specs=[qspec(outer), qspec(outer), lse_spec(outer),
-                  lse_spec(outer), kspec(inner), kspec(inner)],
+        in_specs=[qspec(outer), dospec(outer), lse_spec(outer),
+                  lse_spec(outer), kspec(inner), vspec(inner)],
         out_specs=qspec(outer),
         out_shape=grad_shape,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -952,15 +973,17 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
     but needs only O(block) scoped memory (long sequences).  Residual
     memory is O(seq) either way (Dao et al. alg. 2)."""
     batch, heads, q_len, d = q.shape
-    k_len = k.shape[2]
+    k_len, d_v = k.shape[2], v.shape[-1]
     block_q = min(block_q, q_len)
     block_k = min(block_k, k_len)
     if (q_len % block_q or k_len % block_k
             or block_q % 128 or block_k % 128 or q_len != k_len):
         return _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale,
                                    max(block_k, 128), 0, 0)
+    # One width: the call as every caller and test stand-in has known it.
+    widths = {} if d_v == d else {"d_v": d_v}
     mode, block_q, block_k = _bwd_plan(q_len, d, block_q, block_k,
-                                       batch * heads)
+                                       batch * heads, **widths)
     if q_len % block_q or k_len % block_k or block_q % 128 or block_k % 128:
         # Plan stepped blocks down past what divides this length (rare
         # non-power-of-two long seqs): the scan impl handles it.
@@ -973,8 +996,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
     p2, scale_r = _split_scale(sm_scale)
     qr = (q * p2).astype(q.dtype).reshape(bh, q_len, d)
     kr = k.reshape(bh, k_len, d)
-    vr = v.reshape(bh, k_len, d)
-    dor = g.reshape(bh, q_len, d)
+    vr = v.reshape(bh, k_len, d_v)
+    dor = g.reshape(bh, q_len, d_v)
     # delta_i = sum_d dOut_id * Out_id; 8 broadcast sublanes keep the
     # (8, 128) tiling legal, same trick as the forward's lse output.
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
@@ -1011,7 +1034,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     """Returns (out, lse); routes off-grid shapes to the blockwise impl."""
     batch, heads, q_len, d = q.shape
-    k_len = k.shape[2]
+    k_len, d_v = k.shape[2], v.shape[-1]
     block_q = min(block_q, q_len)
     block_k = min(block_k, k_len)
     if (q_len % block_q or k_len % block_k
@@ -1026,7 +1049,8 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     # (the default <=1024 blocks peak ~6 MiB and never clamp).
     block_q, block_k = _clamp_blocks(
         "forward", q_len, d, block_q, block_k,
-        estimate=lambda _m, s, dd, bq, bk: _fwd_vmem_bytes(s, dd, bq, bk))
+        estimate=lambda _m, s, dd, bq, bk: _fwd_vmem_bytes(s, dd, bq, bk,
+                                                           d_v))
     bh = batch * heads
     # Pre-scale q by the exact power-of-two part of sm_scale: one
     # (seq, d) multiply here replaces a (seq, seq) pass inside the
@@ -1035,11 +1059,12 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     p2, scale_r = _split_scale(sm_scale)
     qr = (q * p2).astype(q.dtype).reshape(bh, q_len, d)
     kr = k.reshape(bh, k_len, d)
-    vr = v.reshape(bh, k_len, d)
+    vr = v.reshape(bh, k_len, d_v)
     vma = jax.typeof(q).vma  # see _split_bwd_call
     num_q = q_len // block_q
     num_k = k_len // block_k
     qspec, kspec = _row_spec(block_q, d), _row_spec(block_k, d)
+    ospec, vspec = _row_spec(block_q, d_v), _row_spec(block_k, d_v)
     qrow = lambda i, j: i  # noqa: E731
     krow = lambda i, j: j  # noqa: E731
 
@@ -1049,24 +1074,24 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, num_q, num_k),
-        in_specs=[qspec(qrow), kspec(krow), kspec(krow)],
+        in_specs=[qspec(qrow), kspec(krow), vspec(krow)],
         out_specs=(
-            qspec(qrow),
+            ospec(qrow),
             pl.BlockSpec((1, 8, block_q), lambda b, qi, ki: (b, 0, qi)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, q_len, d), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, q_len, d_v), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, 8, q_len), jnp.float32, vma=vma),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),  # running max
             pltpu.VMEM((block_q, 128), jnp.float32),  # running normalizer
-            pltpu.VMEM((block_q, d), jnp.float32),    # output accumulator
+            pltpu.VMEM((block_q, d_v), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
         name="hvd_flash_fwd",
     )(qr, kr, vr)
-    return (out.reshape(batch, heads, q_len, d),
+    return (out.reshape(batch, heads, q_len, d_v),
             lse[:, 0, :].reshape(batch, heads, q_len))
 
 
@@ -1099,7 +1124,10 @@ def flash_attention(q, k, v, causal: bool = False,
                     layout: str = "bhsd"):
     """Fused multi-head attention.
 
-    ``layout="bhsd"`` takes ``(batch, heads, seq, head_dim)``;
+    ``v``'s last axis may differ from ``q``'s and ``k``'s (latent attention:
+    a 192-wide query and key, a 128-wide value); the output has ``v``'s, and
+    nothing is padded.  ``layout="bhsd"`` takes ``(batch, heads, seq,
+    head_dim)``;
     ``layout="bshd"`` accepts ``(batch, seq, heads, head_dim)`` — the
     shape QKV projections naturally produce — and returns the same layout.
     (Internally bshd transposes to bhsd: Mosaic's block tiling cannot

@@ -1,0 +1,485 @@
+"""The pattern kinds Ling-3.0-flash adds to models.TransformerLM (Kimi-delta
+linear attention, latent attention with two head widths, a dense gated MLP,
+group-limited sparse experts) against the plain float32 reference the
+benchmark keeps (benchmark/reference/ling_lm.py): the delta rule one step a
+token, a plain softmax over whole rows, a loop over the shard's experts.  CPU,
+float32, seeded weights, small sizes.
+
+Tolerances: both sides are float32 and differ in the order of their sums
+(products over chunks and a solve against a step a token, grouped rows against
+masked whole batches), so they agree to float32 rounding accumulated over a
+few layers: 2e-5 of the largest value (the chunked delta rule, whose decays
+span e^-5 a step and whose solve multiplies 64 x 64 matrices, 1e-4).  bfloat16
+anywhere would read 1e-3 to 1e-2 and fail every case.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+
+from benchmark.reference import ling_lm as reference
+from horovod_tpu.jax.train import build_train_step
+from horovod_tpu.models import (DeltaConfig, DeltaMixer, LatentAttention,
+                                LatentConfig, MoEConfig, TransformerLM)
+from horovod_tpu.models.transformer import (LAYER_KINDS, GatedMLP, MixerLayer,
+                                            SparseExperts)
+from horovod_tpu.ops import flash_attention, mha_reference
+from horovod_tpu.ops.delta_rule import chunked_delta_rule
+from tests.test_hybrid import (both_ways, close, columns, mixer_case, seeded,
+                         system_loss, trees_close, with_highest)
+
+RTOL = 2e-5
+VOCAB, HIDDEN, SEQ, HEADS, D_FF = 256, 64, 128, 8, 96
+DELTA = DeltaConfig(heads=HEADS, head_dim=8, conv=4, chunk=32)
+LATENT = LatentConfig(kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8,
+                      rope_theta=6e6)
+EXPERTS, PER_TOKEN, WIDTH, SHARED, SCALE, GROUPS, KEPT = 16, 4, 48, 40, 2.5, \
+    4, 2
+# A published layer is a mixer and then an MLP or the experts: the leading
+# dense layer, two delta layers and a latent-attention layer.
+LAYERS = ("delta", "gated_mlp", "delta", "experts", "delta", "experts",
+          "latent_attention", "experts")
+
+
+def moe(shard=(0, 1), row_bound=None, experts=EXPERTS, groups=GROUPS,
+        kept=KEPT):
+    return MoEConfig(experts, PER_TOKEN, WIDTH, shard, row_bound, "sigmoid",
+                     True, SCALE, "gated_silu", None, SHARED, groups, kept)
+
+
+def lm(expert_shard=(0, 1), head_shard=(0, 1), use_flash=False, vocab=VOCAB,
+       chunk=DELTA.chunk):
+    return TransformerLM(
+        vocab_size=vocab, d_model=HIDDEN, n_heads=HEADS, d_ff=D_FF,
+        dtype=jnp.float32, use_flash=use_flash, norm_eps=1e-6,
+        moe=moe(expert_shard), layers=LAYERS,
+        delta=DELTA._replace(chunk=chunk), latent=LATENT,
+        head_shard=head_shard)
+
+
+def routing(**more):
+    return dict(experts_per_token=PER_TOKEN, weight_scale=SCALE,
+                n_group=GROUPS, topk_group=KEPT, **more)
+
+
+def reference_config(expert_shard=(0, 1), **more):
+    return dict(layers=LAYERS, head_dim=DELTA.head_dim,
+                lower_bound=DELTA.lower_bound, nope_dim=LATENT.nope_dim,
+                rope_theta=LATENT.rope_theta, norm_eps=1e-6,
+                num_experts=EXPERTS, expert_shard=expert_shard,
+                **routing(**more))
+
+
+# --- the delta rule --------------------------------------------------------
+
+def delta_inputs(seed, seq=SEQ, d_k=16, d_v=8):
+    """Unit keys, queries at d_k^-1/2, log-decays from 0 down to the gate's
+    bound of -5 a step — every seventh token AT the bound in every channel,
+    so that a chunk's product reaches e^-45 and a sub-block's ratios e^75."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    shape = (2, seq, 3)
+
+    def unit(key):
+        t = jax.random.normal(key, shape + (d_k,))
+        return t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+
+    q, k = unit(keys[0]) * d_k ** -0.5, unit(keys[1])
+    v = jax.random.normal(keys[2], shape + (d_v,))
+    log_alpha = -5.0 * jax.random.uniform(keys[3], shape + (d_k,)) ** 3
+    log_alpha = log_alpha.at[:, ::7].set(-5.0)
+    beta = jax.nn.sigmoid(2.0 * jax.random.normal(keys[4], shape))
+    mix = jax.random.normal(keys[5], shape + (d_v,))
+    return (q, k, v, log_alpha, beta), mix
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_chunked_delta_rule_is_the_recurrence(chunk):
+    args, _ = delta_inputs(chunk)
+    got, decay_min = jax.jit(lambda *a: chunked_delta_rule(*a, chunk))(*args)
+    close(got, jax.jit(reference.delta_recurrence)(*args), 1e-4)
+    summed = args[3].reshape(2, SEQ // chunk, chunk, 3, -1).sum(axis=2)
+    close(decay_min, summed.min())
+    assert float(decay_min) < -5.0 * chunk / 7
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_chunked_delta_rule_gradients_are_the_recurrences(chunk):
+    args, mix = delta_inputs(7 + chunk)
+
+    def total(fn):
+        return lambda *a: (fn(*a) * mix).sum()
+
+    got = jax.jit(jax.grad(total(lambda *a: chunked_delta_rule(*a, chunk)[0]),
+                           argnums=range(5)))(*args)
+    want = jax.jit(jax.grad(total(reference.delta_recurrence),
+                            argnums=range(5)))(*args)
+    for g, w in zip(got, want):
+        assert bool(jnp.isfinite(g).all())
+        close(g, w, 1e-4)
+
+
+@pytest.mark.parametrize("seq,chunk", [(96, 64), (96, 24)])
+def test_chunked_delta_rule_refuses_a_ragged_length(seq, chunk):
+    args, _ = delta_inputs(0, seq=seq)
+    with pytest.raises(ValueError, match="multiple"):
+        chunked_delta_rule(*args, chunk)
+
+
+# --- flash attention with two widths ----------------------------------------
+
+@pytest.mark.parametrize("seq,block", [(256, 128), (384, None), (200, None)])
+def test_flash_attention_takes_a_value_width_of_its_own(seq, block):
+    """Query and key 48 wide, value 32: the kernels (interpreted here; 200
+    tokens take the blockwise scan) against plain attention, values and the
+    three gradients, each of its operand's shape."""
+    keys = jax.random.split(jax.random.PRNGKey(seq), 4)
+    q, k = (jax.random.normal(key, (1, 2, seq, 48)) for key in keys[:2])
+    v, mix = (jax.random.normal(key, (1, 2, seq, 32)) for key in keys[2:])
+
+    def total(fn):
+        return lambda *a: (fn(*a, causal=True) * mix).sum()
+
+    def kernel(*a, causal):
+        return flash_attention(*a, causal=causal, block_q=block,
+                               block_k=block)
+
+    got = jax.value_and_grad(total(kernel), (0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(total(mha_reference), (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for g, w in zip(got[1], want[1]):
+        assert g.shape == w.shape
+        close(g, w)
+
+
+# --- each mixer against the reference's ------------------------------------
+
+@pytest.mark.parametrize("head_shard", [(0, 1), (1, 2), (3, 4)])
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_delta_mixer_is_the_reference(chunk, head_shard):
+    mixer = DeltaMixer(*DELTA._replace(chunk=chunk), head_shard=head_shard,
+                       dtype=jnp.float32)
+    u, params, mix = mixer_case(mixer, chunk + head_shard[0])
+    assert params["in_proj_kernel"].shape == (
+        HIDDEN, (5 * DELTA.head_dim + 1) * HEADS // head_shard[1])
+    both_ways(lambda p, u: mixer.apply({"params": p}, u),
+              lambda p, u: reference.kda(u, p, head_dim=DELTA.head_dim,
+                                         lower_bound=DELTA.lower_bound,
+                                         norm_eps=1e-6), u, params, mix, 1e-4)
+
+
+def test_delta_mixer_writes_its_chunks_decay_inside_the_gates_bound():
+    mixer = DeltaMixer(*DELTA, dtype=jnp.float32)
+    u, params, _ = mixer_case(mixer)
+    _, wrote = mixer.apply({"params": params}, u, mutable=["intermediates"])
+    decay = wrote["intermediates"]["kda_chunk_log_decay_min"][0]
+    assert decay.shape == ()
+    assert DELTA.lower_bound * DELTA.chunk < float(decay) < 0
+
+
+@pytest.mark.parametrize("use_flash", [False, True])
+@pytest.mark.parametrize("head_shard", [(0, 1), (1, 2), (3, 4)])
+def test_latent_attention_is_the_reference(head_shard, use_flash):
+    layer = LatentAttention(HEADS, LATENT, jnp.float32, use_flash=use_flash,
+                            head_shard=head_shard)
+    u, params, mix = mixer_case(layer, head_shard[0] + use_flash)
+    local = HEADS // head_shard[1]
+    assert params["q_kernel"].shape == (HIDDEN, local, 12)
+    assert params["kv_a_kernel"].shape == (HIDDEN, 16 + 4)      # whole
+    assert params["kv_b_kernel"].shape == (16, local, 8 + 8)
+    both_ways(lambda p, u: layer.apply({"params": p}, u),
+              lambda p, u: reference.latent_attention(
+                  u, p, nope_dim=LATENT.nope_dim,
+                  rope_theta=LATENT.rope_theta, norm_eps=1e-6),
+              u, params, mix)
+
+
+def test_gated_mlp_is_the_reference():
+    layer = GatedMLP(D_FF, jnp.float32)
+    u, params, mix = mixer_case(layer)
+    both_ways(lambda p, u: layer.apply({"params": p}, u),
+              lambda p, u: reference.gated_mlp(
+                  u, *(p[n]["kernel"] for n in ("gate", "up", "down"))),
+              u, params, mix)
+
+
+@pytest.mark.parametrize("bias", [False, True])
+@pytest.mark.parametrize("shard", [(0, 1), (0, 4), (3, 4)])
+def test_group_limited_experts_are_the_dense_loop(shard, bias):
+    layer = SparseExperts(moe(shard), jnp.float32)
+    u, params, mix = mixer_case(layer, shard[0] + bias)
+    selection_bias = 0.2 * jax.random.normal(
+        jax.random.PRNGKey(5), (EXPERTS,)) if bias else None
+    config = dict(num_experts=EXPERTS, expert_shard=shard,
+                  **routing(selection_bias=selection_bias))
+    buffers = {"buffers": {"selection_bias": selection_bias}} if bias else {}
+
+    def system(p, u):
+        return layer.apply({"params": p, **buffers}, u)
+
+    def plain(p, u):
+        return reference.sparse_experts(u.reshape(-1, HIDDEN), p,
+                                        **config)[0].reshape(u.shape)
+
+    both_ways(system, plain, u, params, mix)
+    _, wrote = layer.apply({"params": params, **buffers}, u,
+                           mutable=["intermediates"])
+    flat = u.reshape(-1, HIDDEN)
+    _, want, want_groups = with_highest(reference.router)(
+        flat, params["router_kernel"], **routing(
+            selection_bias=selection_bias))
+    chose = wrote["intermediates"]["chosen_experts"][0]
+    groups = wrote["intermediates"]["groups_chosen"][0]
+    np.testing.assert_array_equal(jnp.sort(chose, -1), jnp.sort(want, -1))
+    np.testing.assert_array_equal(jnp.sort(groups, -1),
+                                  jnp.sort(want_groups, -1))
+    # No token holds an expert outside its KEPT groups, and the choice is
+    # not the plain top-k's (the groups bind at these sizes).
+    per_group = EXPERTS // GROUPS
+    assert groups.shape == (flat.shape[0], KEPT)
+    assert bool((chose[..., None] // per_group
+                 == groups[:, None, :]).any(-1).all())
+    scores = jax.nn.sigmoid(flat @ params["router_kernel"])
+    if bias:
+        scores = scores + selection_bias
+    plain_top = jax.lax.top_k(scores, PER_TOKEN)[1]
+    assert not bool((jnp.sort(plain_top, -1) == jnp.sort(chose, -1)).all())
+
+
+def test_one_group_is_the_plain_choice():
+    """`n_group` 1 is the program before the groups: the same jaxpr as a
+    configuration that never names them."""
+    named = MoEConfig(EXPERTS, PER_TOKEN, WIDTH, (0, 4), None, "sigmoid",
+                      True, SCALE, "relu2", 32, SHARED, 1, 1)
+    unnamed = MoEConfig(EXPERTS, PER_TOKEN, WIDTH, (0, 4), None, "sigmoid",
+                        True, SCALE, "relu2", 32, SHARED)
+    u = jnp.zeros((2, SEQ, HIDDEN))
+    texts = []
+    for cfg in (named, unnamed):
+        layer = SparseExperts(cfg, jnp.float32)
+        params = jax.eval_shape(
+            lambda: layer.init(jax.random.PRNGKey(0), u)["params"])
+        texts.append(jax.jit(jax.grad(
+            lambda p, u: layer.apply({"params": p}, u).sum())).lower(
+                params, u).as_text())
+    assert texts[0] == texts[1] and "groups" not in texts[0]
+
+
+@pytest.mark.parametrize("field,value", [("n_group", 3), ("topk_group", 5),
+                                         ("scoring", "softmax")])
+def test_sparse_experts_refuse_groups_they_cannot_form(field, value):
+    cfg = moe()._replace(**{field: value})
+    with pytest.raises(ValueError, match="n_group"):
+        SparseExperts(cfg, jnp.float32).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, HIDDEN)))
+
+
+# --- the whole model ----------------------------------------------------
+
+@pytest.mark.parametrize("chunk", [32, 64])
+@pytest.mark.parametrize("expert_shard,head_shard",
+                         [((0, 1), (0, 1)), ((1, 4), (1, 2))])
+def test_ling_lm_loss_and_gradients_are_the_references(expert_shard,
+                                                       head_shard, chunk):
+    model = lm(expert_shard, head_shard, chunk=chunk)
+    params, batch = seeded(model, seed=chunk)
+    config = reference_config(expert_shard)
+    got, got_grads = jax.jit(jax.value_and_grad(
+        lambda p: system_loss(model, p, batch)))(params)
+    want, want_grads = with_highest(jax.value_and_grad(
+        lambda p: reference.loss(p, batch, **config)))(params)
+    np.testing.assert_allclose(got, want, rtol=RTOL)
+    trees_close(got_grads, want_grads, 1e-4)
+    _, wrote = model.apply({"params": params}, batch[0],
+                           mutable=["intermediates"])
+    chose = jnp.stack([wrote["intermediates"][f"layer_{i}"]["mixer"][
+        "chosen_experts"][0] for i, kind in enumerate(LAYERS)
+        if kind == "experts"])
+    want = with_highest(reference.chosen_experts)(params, batch[0], **config)
+    np.testing.assert_array_equal(jnp.sort(chose, -1), jnp.sort(want, -1))
+
+
+def test_reference_refuses_float8_operands():
+    """The reference against itself with every matmul operand rounded to
+    float8_e4m3fn: the error the benchmark's limits must refuse is far over
+    what float32 reorderings give above."""
+    model = lm()
+    params, batch = seeded(model)
+    losses = [with_highest(jax.value_and_grad(lambda p: reference.loss(
+        p, batch, operand_dtype=dtype, **reference_config())))(params)
+        for dtype in (None, jnp.float8_e4m3fn)]
+    norm = optax.global_norm
+    wrong = norm(jax.tree.map(jnp.subtract, losses[1][1], losses[0][1]))
+    assert float(wrong / norm(losses[0][1])) > 0.05
+
+
+def test_pattern_has_one_norm_and_one_mixer_an_entry():
+    shapes = jax.eval_shape(lambda: lm((0, 4), (0, 2)).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, SEQ), jnp.int32))["params"])
+    assert set(shapes) == {"embed", "final_norm", "lm_head_kernel"} | {
+        f"layer_{i}" for i in range(len(LAYERS))}
+    mixers = {"delta": {"A_log", "conv_kernel", "dt_bias", "in_proj_kernel",
+                        "norm_scale", "out_proj_kernel"},
+              "latent_attention": {"q_kernel", "kv_a_kernel", "kv_norm_scale",
+                                   "kv_b_kernel", "gate_kernel", "o_kernel"},
+              "gated_mlp": {"gate", "up", "down"},
+              "experts": {"router_kernel", "gate_kernel", "up_kernel",
+                          "down_kernel", "shared_gate", "shared_up",
+                          "shared_down"}}
+    for i, kind in enumerate(LAYERS):
+        assert set(shapes[f"layer_{i}"]) == {"norm", "mixer"}
+        assert set(shapes[f"layer_{i}"]["mixer"]) == mixers[kind]
+    # The share: 4 of 8 heads, 4 of 16 experts, the router over all 16.
+    assert shapes["layer_0"]["mixer"]["A_log"].shape == (4,)
+    assert shapes["layer_3"]["mixer"]["up_kernel"].shape == (4, HIDDEN, WIDTH)
+    assert shapes["layer_3"]["mixer"]["router_kernel"].shape == (HIDDEN,
+                                                                 EXPERTS)
+    assert shapes["layer_1"]["mixer"]["up"]["kernel"].shape == (HIDDEN, D_FF)
+
+
+def test_an_unknown_kind_is_refused_with_every_kind_named():
+    with pytest.raises(ValueError) as refused:
+        TransformerLM(vocab_size=VOCAB, d_model=HIDDEN, n_heads=HEADS,
+                      layers=("window",)).init(
+                          jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    for kind in LAYER_KINDS:
+        assert kind in str(refused.value) and kind in MixerLayer.__doc__
+
+
+@pytest.mark.parametrize("mixer", [DeltaMixer(*DELTA, head_shard=(0, 3)),
+                                   LatentAttention(HEADS, LATENT,
+                                                   head_shard=(2, 2))])
+def test_mixers_refuse_a_share_that_does_not_divide(mixer):
+    with pytest.raises(ValueError, match="head_shard"):
+        mixer.init(jax.random.PRNGKey(0), jnp.zeros((1, SEQ, HIDDEN)))
+
+
+def test_trains_through_build_train_step_and_replicas_stay_equal():
+    """Two CPU devices, data parallel: the dense LM's step with the pattern.
+    The replicated weights stay equal and the loss of a repeated batch
+    falls.  The flash kernels (interpreted here), as in the benchmark; the
+    delta rule's scan carries a state that varies over the mesh axis."""
+    model = lm((0, 4), (0, 2), use_flash=True)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("hvd",))
+    params, batch = seeded(model, seed=3)
+    tx = optax.adamw(1e-2)
+    step = build_train_step(lambda p, b: system_loss(model, p, b), tx, mesh,
+                            axis_name="hvd", batch_spec=(P("hvd"), P("hvd")))
+    state = (params, tx.init(params))
+    losses = []
+    for _ in range(4):
+        *state, loss = step(*state, batch)
+        losses.append(float(loss))
+    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+    for leaf in jax.tree.leaves(state[0]):
+        first, second = (np.asarray(s.data) for s in leaf.addressable_shards)
+        np.testing.assert_array_equal(first, second)
+
+
+# --- the shares add up to the uncut layer ---------------------------------
+
+def delta_share(p, shard, n):
+    inner = HEADS * DELTA.head_dim
+
+    def heads(v, width=HEADS):
+        return columns(v, [width], shard, n)
+
+    return {"in_proj_kernel": columns(p["in_proj_kernel"],
+                                      [inner] * 5 + [HEADS], shard, n),
+            "conv_kernel": columns(p["conv_kernel"], [inner] * 3, shard, n),
+            "dt_bias": heads(p["dt_bias"], inner),
+            "A_log": heads(p["A_log"]),
+            "norm_scale": p["norm_scale"],               # one for every head
+            "out_proj_kernel": heads(p["out_proj_kernel"].T, inner).T}
+
+
+@pytest.mark.parametrize("n", [2, 4, 8])
+def test_delta_tensor_shares_add_up_to_the_uncut_layer(n):
+    whole = DeltaMixer(*DELTA, dtype=jnp.float32)
+    u, params, _ = mixer_case(whole, n)
+    parts = [jax.jit(DeltaMixer(*DELTA, head_shard=(i, n),
+                                dtype=jnp.float32).apply)(
+        {"params": delta_share(params, i, n)}, u) for i in range(n)]
+    close(sum(parts), with_highest(reference.kda)(
+        u, params, head_dim=DELTA.head_dim, lower_bound=DELTA.lower_bound,
+        norm_eps=1e-6), 1e-4)
+
+
+@pytest.mark.parametrize("n", [2, 8])
+def test_latent_attention_tensor_shares_add_up_with_the_latent_counted_once(
+        n):
+    """Every share holds the whole `W_kva` and the latent's norm (a chip of
+    the mesh computes the latent alike); the heads' slices of the other four
+    weights partition, and the n outputs sum to the uncut layer's."""
+    whole = LatentAttention(HEADS, LATENT, jnp.float32, use_flash=False)
+    u, params, _ = mixer_case(whole, n)
+    local = HEADS // n
+    parts = []
+    for i in range(n):
+        held = slice(i * local, (i + 1) * local)
+        share = dict(params, q_kernel=params["q_kernel"][:, held],
+                     kv_b_kernel=params["kv_b_kernel"][:, held],
+                     gate_kernel=params["gate_kernel"][:, held],
+                     o_kernel=params["o_kernel"][held])
+        parts.append(jax.jit(LatentAttention(
+            HEADS, LATENT, jnp.float32, use_flash=False,
+            head_shard=(i, n)).apply)({"params": share}, u))
+    close(sum(parts), with_highest(reference.latent_attention)(
+        u, params, nope_dim=LATENT.nope_dim, rope_theta=LATENT.rope_theta,
+        norm_eps=1e-6))
+
+
+@pytest.mark.parametrize("n,experts,groups", [(4, EXPERTS, GROUPS),
+                                              (16, EXPERTS, GROUPS),
+                                              (64, 128, 8)])
+def test_expert_shares_add_up_with_router_and_shared_expert_counted_once(
+        n, experts, groups):
+    """The n shares' outputs each hold the shared expert; their sum holds it
+    n times and the routed part once.  64 shares of 2 experts in 8 groups,
+    4 kept: the deployment's count."""
+    kept = groups // 2
+    whole = SparseExperts(moe(experts=experts, groups=groups, kept=kept),
+                          jnp.float32)
+    u, params, _ = mixer_case(whole, n)
+    local = experts // n
+    parts = []
+    for i in range(n):
+        held = slice(i * local, (i + 1) * local)
+        share = dict(params, **{name: params[name][held] for name in (
+            "gate_kernel", "up_kernel", "down_kernel")})
+        parts.append(jax.jit(SparseExperts(
+            moe((i, n), experts=experts, groups=groups, kept=kept),
+            jnp.float32).apply)({"params": share}, u))
+    flat = u.reshape(-1, HIDDEN)
+    shared = reference.gated_mlp(flat, *(params[name]["kernel"] for name in (
+        "shared_gate", "shared_up", "shared_down"))).reshape(u.shape)
+    want = with_highest(reference.sparse_experts)(
+        flat, params, num_experts=experts, expert_shard=(0, 1),
+        **dict(routing(), n_group=groups, topk_group=kept))[0]
+    close(sum(part - shared for part in parts) + shared,
+          want.reshape(u.shape))
+
+
+@pytest.mark.parametrize("n", [2, 8])
+def test_vocabulary_slices_concatenate_to_the_uncut_head(n):
+    """A sliced vocabulary is a smaller vocabulary: the i-th slice's model —
+    its rows of the embedding, its columns of the head — gives, for ids of
+    the slice, the uncut model's logits of those columns."""
+    model = lm()
+    params, _ = seeded(model)
+    rows = VOCAB // n
+    whole, sliced = jax.jit(model.apply), jax.jit(lm(vocab=rows).apply)
+    width = 0
+    for i in range(n):
+        ids = jax.random.randint(jax.random.PRNGKey(9), (1, SEQ), 0, rows)
+        held = slice(i * rows, (i + 1) * rows)
+        share = dict(params,
+                     embed={"embedding": params["embed"]["embedding"][held]},
+                     lm_head_kernel=params["lm_head_kernel"][:, held])
+        got = sliced({"params": share}, ids)
+        want = whole({"params": params}, ids + i * rows)
+        close(got, want[..., held])
+        width += got.shape[-1]
+    assert width == VOCAB

@@ -254,6 +254,37 @@ def test_flash_head128_at_olmoe_shape_compiles(v5e):
     assert text.count("tpu_custom_call") == 2
 
 
+def test_flash_two_widths_at_latent_attention_shape_compile(v5e):
+    """Latent attention as the Ling-3.0-flash cell runs it — 4 heads, 8,192
+    tokens, query and key 192 wide, value 128 — forward and backward through
+    the chip's compiler with nothing padded: the plan enters its bands with
+    the wider width, so the backward is the split pair at 1024-blocks, and
+    the gradients keep their operands' widths (PR 32)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.ops.attention import _bwd_plan
+
+    assert _bwd_plan(8192, 192, 1024, 1024, 4, 128) == ("split", 1024, 1024)
+    # One width, as every call before PR 32: the same plan with and without.
+    assert _bwd_plan(8192, 64, 1024, 1024, 16, 64) \
+        == _bwd_plan(8192, 64, 1024, 1024, 16) == ("combined", 512, 512)
+    on_chip = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((1, 4, 8192, 192), jnp.bfloat16, sharding=on_chip)
+    v = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16, sharding=on_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, v).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("hvd_flash_fwd", "hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"):
+        assert name in text
+    assert [g.shape[-1] for g in compiled.out_info] == [192, 192, 128]
+
+
 def test_grouped_matmul_lowers_to_libtpu_kernels(v5e):
     """ops.moe.grouped_matmul at the sparse-expert cell's shapes — 24,576
     rows of 2,048 against 16 experts of 1,024 — forward and both gradients:
@@ -581,6 +612,69 @@ def test_hybrid_step_is_products_and_kernels_with_no_loop(v5e, monkeypatch):
                   "hvd_ssm_gate_norm", "hvd_ssm_out_proj", "hvd_moe_latent",
                   "hvd_moe_shared", "hvd_moe_router", "hvd_moe_dispatch",
                   "hvd_moe_combine"):
+        assert re.search(rf'op_name="jit\([^"]*/jvp\(hvd_loss\)/[^"]*{scope}/',
+                         text), f"{scope} is not in the forward pass"
+        assert re.search(
+            rf'op_name="jit\([^"]*transpose\(jvp\(hvd_loss\)\)/[^"]*{scope}/',
+            text), f"{scope} is not in the backward pass"
+
+
+def test_ling_step_is_products_kernels_and_one_loop_a_pass(v5e, monkeypatch):
+    """A Kimi-delta layer, a dense gated MLP, a latent-attention layer (4
+    heads, 192 and 128 wide) and group-limited gated experts with a shared one
+    at Ling-3.0-flash's per-head widths through `build_train_step`, compiled
+    for the described chip: the delta rule's recurrence between chunks is the
+    step's only loops (one `while` forward, one backward), latent attention is
+    the flash forward and the split backward pair at two widths, the experts
+    are libtpu's grouped-matmul kernels (nine and two tile schedules), and
+    every scope of the new layers is in the text forward and backward."""
+    import optax
+    from jax.sharding import NamedSharding
+
+    from horovod_tpu.jax.train import build_train_step
+    from horovod_tpu.models import (DeltaConfig, LatentConfig, MoEConfig,
+                                    TransformerLM, next_token_loss)
+    from horovod_tpu.parallel import data_parallel_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(
+        vocab_size=2048, d_model=512, n_heads=4, d_ff=1024,
+        dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16, use_flash=True,
+        layers=("delta", "gated_mlp", "latent_attention", "experts"),
+        delta=DeltaConfig(4, 128), latent=LatentConfig(512, 128, 64, 128, 6e6),
+        moe=MoEConfig(64, 8, 256, (0, 8), 1.5, "sigmoid", True, 2.5,
+                      shared_width=256, n_group=8, topk_group=4))
+    mesh = data_parallel_mesh(v5e[:1], axis_name="hvd")
+    tx = optax.adamw(1e-4)
+
+    def loss_fn(params, batch):
+        return next_token_loss(model.apply({"params": params}, batch[0]),
+                               batch[1])
+
+    def init(key):
+        params = model.init(key, jnp.zeros((1, 128), jnp.int32))["params"]
+        return params, tx.init(params)
+
+    def shaped(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+
+    params, opt_state = shaped(jax.eval_shape(init, jax.random.PRNGKey(0)),
+                               P())
+    tokens = shaped(jax.ShapeDtypeStruct((1, 2048), jnp.int32), P("hvd"))
+    step = build_train_step(loss_fn, tx, mesh, axis_name="hvd")
+    text = step.lower(params, opt_state,
+                      (tokens, tokens)).compile().as_text()
+    assert len(re.findall(r"\bwhile\(", text)) == 2
+    for kernel in ("hvd_flash_fwd", "hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 9
+    assert text.count('"tpu_custom_call"') == 3 + 9 + 2
+    for scope in ("hvd_kda_in_proj", "hvd_kda_conv", "hvd_kda_gate",
+                  "hvd_kda_scan", "hvd_kda_gate_norm", "hvd_kda_out_proj",
+                  "hvd_mla_q_proj", "hvd_mla_kv_latent", "hvd_mla_attend",
+                  "hvd_mla_out_proj", "hvd_moe_router", "hvd_moe_shared"):
         assert re.search(rf'op_name="jit\([^"]*/jvp\(hvd_loss\)/[^"]*{scope}/',
                          text), f"{scope} is not in the forward pass"
         assert re.search(
