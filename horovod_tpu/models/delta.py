@@ -57,7 +57,8 @@ class DeltaMixer(nn.Module):
     """One mixer's share (module docstring), each stage under a
     ``jax.named_scope`` a trace can read: ``hvd_kda_in_proj``,
     ``hvd_kda_conv`` (with the silu and the two norms), ``hvd_kda_gate``,
-    ``hvd_kda_scan``, ``hvd_kda_gate_norm``, ``hvd_kda_out_proj``.  Writes
+    ``hvd_kda_scan`` (by stage beneath it: ``ops/delta_rule.py``),
+    ``hvd_kda_gate_norm``, ``hvd_kda_out_proj``.  Writes
     ``kda_chunk_log_decay_min`` to the ``intermediates`` collection where the
     caller makes it mutable."""
 
@@ -111,9 +112,12 @@ class DeltaMixer(nn.Module):
                                            + dt_bias).reshape(by_head))
             beta = nn.sigmoid(b.astype(jnp.float32))
         with jax.named_scope("hvd_kda_scan"):
-            o, decay_min = chunked_delta_rule(
-                q.astype(self.dtype), k.astype(self.dtype),
-                v.astype(self.dtype), log_alpha, beta, min(self.chunk, seq))
+            # The casts are the first of the rule's products inside a chunk:
+            # every operation under this scope lies in one of its stages.
+            with jax.named_scope("hvd_kda_scan_chunk"):
+                q, k, v = (t.astype(self.dtype) for t in (q, k, v))
+            o, decay_min = chunked_delta_rule(q, k, v, log_alpha, beta,
+                                              min(self.chunk, seq))
             self.sow("intermediates", "kda_chunk_log_decay_min", decay_min)
         with jax.named_scope("hvd_kda_gate_norm"):
             mean_sq = jnp.mean(jnp.square(o), axis=-1, keepdims=True)

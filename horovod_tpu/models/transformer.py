@@ -362,6 +362,12 @@ class SparseExperts(nn.Module):
 
 
 class Attention(nn.Module):
+    """Causal self-attention of one layer: the q/k/v projections (with the
+    q/k norm where asked for) under the scope ``hvd_attn_qkv``, the rotation
+    and the attention itself (flash, blockwise, ring or cached decode) under
+    ``hvd_attn_attend``, the output projection under ``hvd_attn_out`` — names
+    a trace can read, forward and backward."""
+
     n_heads: int
     dtype: Any = jnp.bfloat16
     seq_axis: Optional[str] = None
@@ -427,63 +433,72 @@ class Attention(nn.Module):
         head_dim = d // self.n_heads
         n_heads = self.n_heads // self.head_shard[1]
         rotate = rope if self.rope else (lambda t, positions: t)
-        if self.n_kv_heads is not None or self.head_shard != (0, 1):
-            q, k, v = self._grouped_projections(x, head_dim)
-        else:
-            # One fused qkv projection whose einsum emits q/k/v *head-major*
-            # ('jbhse'): XLA folds the seq<->head transpose into the matmul's
-            # output layout, so no standalone copy passes appear around the
-            # attention kernel.  The inverse transpose folds into the output
-            # projection's einsum the same way.  Per-matrix fan-in init
-            # matches separate q/k/v Dense layers (fan_in = d).
-            w_qkv = self.param(
-                "qkv_kernel",
-                nn.initializers.lecun_normal(in_axis=0, out_axis=(1, 2, 3)),
-                (d, 3, self.n_heads, head_dim), jnp.float32)
-            # (b, heads, seq, head_dim) each; custom VJP avoids the
-            # activation-sized cotangent stack the sliced einsum would build.
-            q, k, v = _qkv_project(x.astype(self.dtype),
-                                   w_qkv.astype(self.dtype))
-        if self.qk_norm:
-            q = self._projection_norm("q_norm_scale", q)
-            k = self._projection_norm("k_norm_scale", k)
+        with jax.named_scope("hvd_attn_qkv"):
+            if self.n_kv_heads is not None or self.head_shard != (0, 1):
+                q, k, v = self._grouped_projections(x, head_dim)
+            else:
+                # One fused qkv projection whose einsum emits q/k/v
+                # *head-major* ('jbhse'): XLA folds the seq<->head transpose
+                # into the matmul's output layout, so no standalone copy
+                # passes appear around the attention kernel.  The inverse
+                # transpose folds into the output projection's einsum the
+                # same way.  Per-matrix fan-in init matches separate q/k/v
+                # Dense layers (fan_in = d).
+                w_qkv = self.param(
+                    "qkv_kernel",
+                    nn.initializers.lecun_normal(in_axis=0,
+                                                 out_axis=(1, 2, 3)),
+                    (d, 3, self.n_heads, head_dim), jnp.float32)
+                # (b, heads, seq, head_dim) each; custom VJP avoids the
+                # activation-sized cotangent stack the sliced einsum would
+                # build.
+                q, k, v = _qkv_project(x.astype(self.dtype),
+                                       w_qkv.astype(self.dtype))
+            if self.qk_norm:
+                q = self._projection_norm("q_norm_scale", q)
+                k = self._projection_norm("k_norm_scale", k)
 
         new_kv = None
-        if decode_ctx is not None:
-            k_ctx, v_ctx, ctx_mask, positions = decode_ctx
-            q, k = rotate(q, positions), rotate(k, positions)
-            ctx_len = k_ctx.shape[-2]
-            # Context keys all precede the new chunk; within the chunk
-            # positions are consecutive, so causality is a lower triangle.
-            mask = jnp.concatenate([
-                jnp.broadcast_to(ctx_mask[:, None, :], (b, s, ctx_len)),
-                jnp.broadcast_to(jnp.tril(jnp.ones((s, s), bool))[None],
-                                 (b, s, s)),
-            ], axis=-1)
-            keys = jnp.concatenate([k_ctx.astype(k.dtype), k], axis=-2)
-            vals = jnp.concatenate([v_ctx.astype(v.dtype), v], axis=-2)
-            out = _decode_attention(q, keys, vals, mask, head_dim ** -0.5)
-            new_kv = (k, v)
-        elif self.seq_axis is not None:
-            offset = lax.axis_index(self.seq_axis) * s
-            positions = offset + jnp.arange(s)
-            q, k = rotate(q, positions), rotate(k, positions)
-            if self.capture_kv:
-                self.sow("intermediates", "kv", (k, v))
-            out = ring_attention(q, k, v, axis_name=self.seq_axis,
-                                 causal=True, rotate_impl=self.ring_impl)
-        else:
-            positions = jnp.arange(s)
-            q, k = rotate(q, positions), rotate(k, positions)
-            if self.capture_kv:
-                self.sow("intermediates", "kv", (k, v))
-            out = flash_attention(q, k, v, causal=True) if self.use_flash \
-                else blockwise_attention(q, k, v, causal=True)
+        with jax.named_scope("hvd_attn_attend"):
+            if decode_ctx is not None:
+                k_ctx, v_ctx, ctx_mask, positions = decode_ctx
+                q, k = rotate(q, positions), rotate(k, positions)
+                ctx_len = k_ctx.shape[-2]
+                # Context keys all precede the new chunk; within the chunk
+                # positions are consecutive, so causality is a lower
+                # triangle.
+                mask = jnp.concatenate([
+                    jnp.broadcast_to(ctx_mask[:, None, :], (b, s, ctx_len)),
+                    jnp.broadcast_to(jnp.tril(jnp.ones((s, s), bool))[None],
+                                     (b, s, s)),
+                ], axis=-1)
+                keys = jnp.concatenate([k_ctx.astype(k.dtype), k], axis=-2)
+                vals = jnp.concatenate([v_ctx.astype(v.dtype), v], axis=-2)
+                out = _decode_attention(q, keys, vals, mask,
+                                        head_dim ** -0.5)
+                new_kv = (k, v)
+            elif self.seq_axis is not None:
+                offset = lax.axis_index(self.seq_axis) * s
+                positions = offset + jnp.arange(s)
+                q, k = rotate(q, positions), rotate(k, positions)
+                if self.capture_kv:
+                    self.sow("intermediates", "kv", (k, v))
+                out = ring_attention(q, k, v, axis_name=self.seq_axis,
+                                     causal=True, rotate_impl=self.ring_impl)
+            else:
+                positions = jnp.arange(s)
+                q, k = rotate(q, positions), rotate(k, positions)
+                if self.capture_kv:
+                    self.sow("intermediates", "kv", (k, v))
+                out = (flash_attention(q, k, v, causal=True)
+                       if self.use_flash
+                       else blockwise_attention(q, k, v, causal=True))
         w_o = self.param(
             "o_kernel",
             nn.initializers.lecun_normal(in_axis=(0, 1), out_axis=2),
             (n_heads, head_dim, d), jnp.float32)
-        proj = jnp.einsum("bhse,hed->bsd", out, w_o.astype(self.dtype))
+        with jax.named_scope("hvd_attn_out"):
+            proj = jnp.einsum("bhse,hed->bsd", out, w_o.astype(self.dtype))
         return proj if new_kv is None else (proj, new_kv)
 
     def _projection_norm(self, name, t):
@@ -600,7 +615,7 @@ class LatentAttention(nn.Module):
 
 class GatedMLP(nn.Module):
     """The dense gated MLP ``down(silu(gate x) * up x)`` of width ``d_ff``,
-    no biases."""
+    no biases; under the scope ``hvd_mlp``, as :class:`Block`'s dense MLP."""
 
     d_ff: int
     dtype: Any = jnp.bfloat16
@@ -611,8 +626,9 @@ class GatedMLP(nn.Module):
             return nn.Dense(self.d_ff, use_bias=False, dtype=self.dtype,
                             name=name)(x)
 
-        return nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
-                        name="down")(nn.silu(wide("gate")) * wide("up"))
+        with jax.named_scope("hvd_mlp"):
+            return nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
+                            name="down")(nn.silu(wide("gate")) * wide("up"))
 
 
 class Block(nn.Module):
@@ -645,11 +661,12 @@ class Block(nn.Module):
         if self.moe is not None:
             h = SparseExperts(self.moe, self.dtype, name="moe")(h)
         else:
-            h = nn.Dense(self.d_ff, use_bias=False, dtype=self.dtype,
-                         name="up")(h)
-            h = nn.gelu(h)
-            h = nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
-                         name="down")(h)
+            with jax.named_scope("hvd_mlp"):
+                h = nn.Dense(self.d_ff, use_bias=False, dtype=self.dtype,
+                             name="up")(h)
+                h = nn.gelu(h)
+                h = nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
+                             name="down")(h)
         x = x + h
         return x if new_kv is None else (x, new_kv)
 
@@ -717,7 +734,9 @@ class MixerLayer(nn.Module):
 
 
 class TransformerLM(nn.Module):
-    """Causal LM over token ids ``(batch, seq[, sharded over seq_axis])``."""
+    """Causal LM over token ids ``(batch, seq[, sharded over seq_axis])``.
+    The embedding lookup runs under the scope ``hvd_embed`` and the head's
+    matmul under ``hvd_lm_head`` (``final_norm`` is outside both)."""
 
     vocab_size: int
     d_model: int = 512
@@ -788,8 +807,9 @@ class TransformerLM(nn.Module):
                 "targets= nor sequence parallelism: decode is an "
                 "inference-only, single-shard path (docs/inference.md).")
         d_ff = self.d_ff or 4 * self.d_model
-        x = nn.Embed(self.vocab_size, self.d_model,
-                     dtype=self.dtype, name="embed")(tokens)
+        with jax.named_scope("hvd_embed"):
+            x = nn.Embed(self.vocab_size, self.d_model,
+                         dtype=self.dtype, name="embed")(tokens)
         new_ks, new_vs = [], []
         for i, kind in enumerate(self.layers or ()):
             x = MixerLayer(kind, self.n_heads, self.dtype, self.use_flash,
@@ -822,10 +842,11 @@ class TransformerLM(nn.Module):
         if targets is not None:
             # Fused head+loss: see fused_next_token_loss.
             return fused_next_token_loss(x, w, targets, dtype=self.dtype)
-        logits = jnp.einsum("bsd,dv->bsv", x.astype(self.dtype),
-                            w.astype(self.dtype),
-                            preferred_element_type=jnp.float32).astype(
-                                self.logits_dtype)
+        with jax.named_scope("hvd_lm_head"):
+            logits = jnp.einsum("bsd,dv->bsv", x.astype(self.dtype),
+                                w.astype(self.dtype),
+                                preferred_element_type=jnp.float32).astype(
+                                    self.logits_dtype)
         if decode_ctx is not None:
             # (n_layers, batch, heads, new_len, head_dim) each: the new
             # chunk's K/V for the caller to persist into its cache.
@@ -851,14 +872,14 @@ def fused_next_token_loss(hidden, w, targets, dtype=jnp.bfloat16,
     (long sequences, big vocab, large batch), not as a throughput knob: no
     benchmark cell runs it, and its speed against the full-logits path is
     not measured on this machine (PERF.md section 7).
+
+    Head and loss are one loop here, so the whole of it runs under the
+    head's scope, ``hvd_lm_head``; there is no ``hvd_token_xent`` inside.
     """
     B, S, D = hidden.shape
     tokens = B * S
     if tokens % n_chunks:
         n_chunks = 1
-    xc = hidden.reshape(n_chunks, tokens // n_chunks, D)
-    tc = targets.reshape(n_chunks, tokens // n_chunks)
-    wb = w.astype(dtype)
 
     def chunk(total, xt):
         x, t = xt
@@ -868,9 +889,13 @@ def fused_next_token_loss(hidden, w, targets, dtype=jnp.bfloat16,
         correct = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
         return total + (lse - correct).sum(), None
 
-    total, _ = lax.scan(jax.checkpoint(chunk),
-                        jnp.zeros((), jnp.float32), (xc, tc))
-    return total / tokens
+    with jax.named_scope("hvd_lm_head"):
+        xc = hidden.reshape(n_chunks, tokens // n_chunks, D)
+        tc = targets.reshape(n_chunks, tokens // n_chunks)
+        wb = w.astype(dtype)
+        total, _ = lax.scan(jax.checkpoint(chunk),
+                            jnp.zeros((), jnp.float32), (xc, tc))
+        return total / tokens
 
 
 @jax.custom_vjp

@@ -38,6 +38,25 @@ Every log of a product of decays is a sum of log-decays (within a sub-block,
 to its end, over whole sub-blocks between), never a difference of two
 cumulative sums: those reach -320 in a chunk, where float32's spacing is 3e-5.
 
+Four stages, each under a ``jax.named_scope`` of its own beneath the caller's
+(``hvd_kda_scan`` in ``models/delta.py``), so that a device trace tells them
+apart forward and backward; every operation of :func:`chunked_delta_rule` is
+under exactly one:
+
+* ``hvd_kda_scan_decays`` — the sums of log-decays, their exponentials and the
+  ratios against a sub-block's reference;
+* ``hvd_kda_scan_chunk`` — the products inside a chunk: the views of q, k, v
+  and beta by chunk, ``K K^T`` and ``Q K^T`` through the sub-blocks
+  (``against_earlier``), and the decayed ``Q`` and ``K`` the recurrence reads;
+* ``hvd_kda_scan_solve`` — ``T`` (:func:`_unit_lower_inverse`, forward and its
+  written-out backward) and ``W``, ``U0``;
+* ``hvd_kda_scan_carry`` — the ``lax.scan`` between chunks (the ``while``,
+  its body, and the moves of its operands and of ``o``).
+
+The statements stand in the order they were traced in before the stages had
+names, so a stage's scope opens more than once: the lowered program is the
+same to the byte.
+
 Float32: the summed log-decays, their exponentials, ``T`` (by forward
 substitution, exact products), ``W``, ``U0``, ``U`` and the state between
 chunks.  The operands of
@@ -125,45 +144,46 @@ def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int):
         return jnp.moveaxis(
             t.reshape(batch, chunks, chunk, heads, *t.shape[3:]), 3, 2)
 
-    qc, kc = by_chunk(q).astype(f32), by_chunk(k).astype(f32)
-    vc, bc = by_chunk(v).astype(f32), by_chunk(beta.astype(f32))[..., None]
+    with jax.named_scope("hvd_kda_scan_chunk"):
+        qc, kc = by_chunk(q).astype(f32), by_chunk(k).astype(f32)
+        vc, bc = by_chunk(v).astype(f32), by_chunk(beta.astype(f32))[..., None]
 
-    # Sums of log-decays (module docstring): (b, n, h, blocks, sub, d_k) from
-    # here on; i, j, m index sub-blocks.
-    steps = by_chunk(log_alpha.astype(f32)).reshape(
-        batch, chunks, heads, blocks, sub, d_k)
-    first = steps[..., :1, :]
-    after_first = jnp.cumsum(steps.at[..., 0, :].set(0.0), axis=-2)
-    later = jnp.concatenate([steps[..., 1:, :], jnp.zeros_like(first)], -2)
-    tail = lax.cumsum(later, axis=later.ndim - 2, reverse=True)
-    total = after_first[..., -1, :] + first[..., 0, :]       # (b, n, h, i, d)
-    i, j = jnp.arange(blocks)[:, None], jnp.arange(blocks)[None, :]
-    m = jnp.arange(blocks)
+    with jax.named_scope("hvd_kda_scan_decays"):
+        # Sums of log-decays (module docstring): (b, n, h, blocks, sub, d_k)
+        # from here on; i, j, m index sub-blocks.
+        steps = by_chunk(log_alpha.astype(f32)).reshape(
+            batch, chunks, heads, blocks, sub, d_k)
+        first = steps[..., :1, :]
+        after_first = jnp.cumsum(steps.at[..., 0, :].set(0.0), axis=-2)
+        later = jnp.concatenate([steps[..., 1:, :], jnp.zeros_like(first)],
+                                -2)
+        tail = lax.cumsum(later, axis=later.ndim - 2, reverse=True)
+        total = after_first[..., -1, :] + first[..., 0, :]   # (b, n, h, i, d)
+        i, j = jnp.arange(blocks)[:, None], jnp.arange(blocks)[None, :]
+        m = jnp.arange(blocks)
 
-    def summed(indices, mask):
-        """The sub-blocks' totals summed where ``mask[..., m]`` holds."""
-        return jnp.einsum(f"{indices}m,bnhmd->bnh{indices}d",
-                          mask.astype(f32), total, precision="highest")
+        def summed(indices, mask):
+            """The sub-blocks' totals summed where ``mask[..., m]`` holds."""
+            return jnp.einsum(f"{indices}m,bnhmd->bnh{indices}d",
+                              mask.astype(f32), total, precision="highest")
 
-    before, after = summed("i", j < i), summed("i", j > i)
-    between = summed("ij", (m > j[..., None]) & (m < i[..., None]))
-    within = (before[..., None, :] + first + after_first).reshape(
-        batch, chunks, heads, chunk, d_k)
-    to_end = (tail + after[..., None, :]).reshape(within.shape)
-    whole = total.sum(axis=-2)                               # (b, n, h, d_k)
+        before, after = summed("i", j < i), summed("i", j > i)
+        between = summed("ij", (m > j[..., None]) & (m < i[..., None]))
+        within = (before[..., None, :] + first + after_first).reshape(
+            batch, chunks, heads, chunk, d_k)
+        to_end = (tail + after[..., None, :]).reshape(within.shape)
+        whole = total.sum(axis=-2)                           # (b, n, h, d_k)
 
-    # Every G_t / G_s through the first token of t's sub-block i: G_t / G_ref
-    # is at most 1; G_ref / G_s at most 1 for s in an earlier sub-block j and
-    # at most e^75 inside i itself.
-    to_ref = jnp.exp(after_first).reshape(within.shape)
-    from_ref = jnp.exp(jnp.where(
-        (j < i)[..., None, None],
-        tail[..., None, :, :, :] + between[..., None, :]
-        + first[..., :, None, :, :],
-        jnp.where((j == i)[..., None, None],
-                  -after_first[..., None, :, :, :], -jnp.inf)))
-    k_col = (kc[..., None, :, :] * from_ref.reshape(
-        batch, chunks, heads, blocks, chunk, d_k)).astype(dtype)
+        # Every G_t / G_s through the first token of t's sub-block i:
+        # G_t / G_ref is at most 1; G_ref / G_s at most 1 for s in an earlier
+        # sub-block j and at most e^75 inside i itself.
+        to_ref = jnp.exp(after_first).reshape(within.shape)
+        from_ref = jnp.exp(jnp.where(
+            (j < i)[..., None, None],
+            tail[..., None, :, :, :] + between[..., None, :]
+            + first[..., :, None, :, :],
+            jnp.where((j == i)[..., None, None],
+                      -after_first[..., None, :, :, :], -jnp.inf)))
 
     def against_earlier(rows):
         """``rows[t] . G_t`` against every ``k_s / G_s``: (b, n, h, C, C)."""
@@ -172,16 +192,27 @@ def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int):
         return jnp.einsum("bnhitc,bnhisc->bnhits", rows, k_col,
                           **wide).reshape(batch, chunks, heads, chunk, chunk)
 
-    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-    a = jnp.where(lower & ~jnp.eye(chunk, dtype=bool),
-                  bc * against_earlier(kc), 0.0)
-    qk = jnp.where(lower, against_earlier(qc), 0.0).astype(dtype)
-    solve = _unit_lower_inverse(a)
-    decayed = jnp.exp(within)
-    w = jnp.einsum("bnhts,bnhsc->bnhtc", solve, bc * kc * decayed, **exact)
-    u0 = jnp.einsum("bnhts,bnhsv->bnhtv", solve, bc * vc, **exact)
-    q_in = (qc * decayed).astype(dtype)
-    k_end = (kc * jnp.exp(to_end)).astype(dtype)
+    with jax.named_scope("hvd_kda_scan_chunk"):
+        k_col = (kc[..., None, :, :] * from_ref.reshape(
+            batch, chunks, heads, blocks, chunk, d_k)).astype(dtype)
+        lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+        a = jnp.where(lower & ~jnp.eye(chunk, dtype=bool),
+                      bc * against_earlier(kc), 0.0)
+        qk = jnp.where(lower, against_earlier(qc), 0.0).astype(dtype)
+    with jax.named_scope("hvd_kda_scan_solve"):
+        solve = _unit_lower_inverse(a)
+    with jax.named_scope("hvd_kda_scan_decays"):
+        decayed = jnp.exp(within)
+    with jax.named_scope("hvd_kda_scan_solve"):
+        w = jnp.einsum("bnhts,bnhsc->bnhtc", solve, bc * kc * decayed,
+                       **exact)
+        u0 = jnp.einsum("bnhts,bnhsv->bnhtv", solve, bc * vc, **exact)
+    with jax.named_scope("hvd_kda_scan_chunk"):
+        q_in = (qc * decayed).astype(dtype)
+    with jax.named_scope("hvd_kda_scan_decays"):
+        end_decay = jnp.exp(to_end)
+    with jax.named_scope("hvd_kda_scan_chunk"):
+        k_end = (kc * end_decay).astype(dtype)
 
     def chunk_step(state, inputs):
         w, u0, q_in, qk, k_end, carried = inputs
@@ -194,13 +225,20 @@ def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int):
             "bhtc,bhtv->bhcv", k_end, rounded, **wide)
         return state, o
 
-    start = jnp.zeros((batch, heads, d_k, v.shape[-1]), f32)
-    varying = tuple(jax.typeof(k).vma)
-    if varying:      # inside shard_map the carry varies as the inputs do
-        start = lax.pcast(start, varying, to="varying")
-    by_step = [jnp.moveaxis(t, 1, 0) for t in (
-        w.astype(dtype), u0, q_in, qk, k_end, jnp.exp(whole))]
-    _, o = lax.scan(chunk_step, start, tuple(by_step))
-    # (n, b, h, C, d_v) -> (b, seq, h, d_v)
-    o = jnp.moveaxis(o, (0, 3), (1, 2)).reshape(batch, seq, heads, -1)
-    return o, whole.min()
+    with jax.named_scope("hvd_kda_scan_carry"):
+        start = jnp.zeros((batch, heads, d_k, v.shape[-1]), f32)
+        varying = tuple(jax.typeof(k).vma)
+        if varying:  # inside shard_map the carry varies as the inputs do
+            start = lax.pcast(start, varying, to="varying")
+    with jax.named_scope("hvd_kda_scan_solve"):
+        w = w.astype(dtype)
+    with jax.named_scope("hvd_kda_scan_decays"):
+        carried = jnp.exp(whole)
+    with jax.named_scope("hvd_kda_scan_carry"):
+        by_step = [jnp.moveaxis(t, 1, 0)
+                   for t in (w, u0, q_in, qk, k_end, carried)]
+        _, o = lax.scan(chunk_step, start, tuple(by_step))
+        # (n, b, h, C, d_v) -> (b, seq, h, d_v)
+        o = jnp.moveaxis(o, (0, 3), (1, 2)).reshape(batch, seq, heads, -1)
+    with jax.named_scope("hvd_kda_scan_decays"):
+        return o, whole.min()

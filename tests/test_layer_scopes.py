@@ -1,0 +1,202 @@
+"""The model's layers name themselves in the program: every heavy operation of
+the loss of each LM cell's step (the benchmark's builders at their rehearsal
+sizes, lowered with locations and not compiled) lies under exactly one
+family of layer scopes, forward and backward, and the delta rule's four
+stages partition its scope.  The families are listed once, in
+benchmark/layer_metrics/_layers.py, whose readers sort a device trace by
+them (PERF.md section 3 has the table of names)."""
+
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from benchmark import run as harness
+from benchmark.layer_metrics import _layers
+
+# The operations whose time a trace shows on a line of its own: products,
+# row movement, sorts, loops, kernels.
+HEAVY = ("dot_general", "convolution", "gather", "scatter", "sort", "while",
+         "custom_call")
+FORWARD, BACKWARD = "/jvp(hvd_loss)/", "/transpose(jvp(hvd_loss))/"
+# cell -> the families its configuration's model has
+CELLS = {
+    "pythia410m_1chip_4x2k": {"head", "embed", "mlp", "attn_proj"},
+    "pythia410m_1chip_1x8k": {"head", "embed", "mlp", "attn_proj"},
+    "olmoe1b7b_1chip_ep4share_2x4k": {"head", "embed", "attn_proj", "moe"},
+    "nemotron3super120b_1chip_tp8ep64share_1x4k": {
+        "head", "embed", "attn_proj", "moe", "ssm"},
+    "ling3flash_1chip_tp8ep64share_1x8k": {
+        "head", "embed", "mlp", "moe", "kda", "mla"},
+}
+
+_ALIAS = re.compile(r'^(#loc\d*) = loc\((.*)\)$')
+_NAMED = re.compile(r'^"([^"]*)"')
+_USE = re.compile(r"loc\((#loc\d*|\"[^\"]*\"[^)]*)\)\s*$")
+_FUNCTION = re.compile(r"^\s*func\.func (?:public |private )?@([\w.]+)\(")
+_CALL = re.compile(r"= (?:func\.)?call @([\w.]+)\(")
+_OP = re.compile(r'^(\s*)(?:%%[\w:#]+ = )?"?stablehlo\.(%s)\b'
+                 % "|".join(HEAVY))
+
+
+def heavy_operations(text):
+    """[(opcode, scope path)] of the `HEAVY` operations of a module's text
+    printed with locations.  An operation with regions (`while`, `scatter`,
+    `sort`) carries its location where its last region closes, at the
+    indentation it opened at.  Inside a private function (an inner `jit`:
+    `_take`, `argsort`) a path is relative, and is given here once for every
+    call of the function, behind the call's own path."""
+    aliases = {}
+    for line in text.splitlines():
+        alias = _ALIAS.match(line)
+        if alias:
+            aliases[alias.group(1)] = alias.group(2)
+
+    def path(location):
+        for _ in range(64):     # "name"(#child), callsite(#callee at ...)
+            location = aliases.get(location, location)
+            named = _NAMED.match(location)
+            if named:
+                return named.group(1)
+            inner = re.search(r"#loc\d*", location)
+            if not inner:
+                return ""
+            location = inner.group(0)
+        return ""
+
+    found, calls = {}, {}        # function -> [(opcode, path)]; callee ->
+    function, pending = None, []            # [(caller, path)]
+    for line in text.splitlines():
+        defined = _FUNCTION.match(line)
+        opened, used = _OP.match(line), _USE.search(line)
+        called = _CALL.search(line)
+        if defined:
+            function = defined.group(1)
+        elif called and used:
+            calls.setdefault(called.group(1), []).append(
+                (function, path(used.group(1))))
+        elif opened and used:
+            found.setdefault(function, []).append(
+                (opened.group(2), path(used.group(1))))
+        elif opened:
+            pending.append((opened.group(1), opened.group(2)))
+        elif pending and used and line.startswith(pending[-1][0] + "}"):
+            found.setdefault(function, []).append(
+                (pending.pop()[1], path(used.group(1))))
+    assert not pending, pending
+
+    def prefixes(name):
+        if name == "main":
+            return [""]
+        return [f"{outer}{site}/" for caller, site in calls.get(name, [])
+                for outer in prefixes(caller)]
+
+    return [(op, prefix + relative) for name, operations in found.items()
+            for prefix in prefixes(name) for op, relative in operations]
+
+
+def scope_paths(text):
+    """Every scope path a module's text names a location by."""
+    return {named.group(1) for named in (
+        _NAMED.match(alias.group(2)) for alias in map(
+            _ALIAS.match, text.splitlines()) if alias) if named}
+
+
+def lowered_step(cell):
+    spec = harness.load_cell(cell, rehearse=True)
+    config, traffic = spec["config"], spec["traffic"]
+    devices = jax.devices()[:spec["cell"]["chips"]]
+    built = importlib.import_module(
+        f"benchmark.builders.{config['builder']}").build(
+            config, traffic, devices, seed=0)
+
+    def shaped(tree, spec):
+        sharding = NamedSharding(built.mesh, spec)
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sharding), tree)
+
+    state = shaped(jax.eval_shape(built.init_state), P())
+    fields = {f["name"]: jax.ShapeDtypeStruct(
+        (traffic["batch_per_chip"] * len(devices), *f["shape"]), f["dtype"])
+        for f in built.fields}
+    batch = shaped(jax.eval_shape(built.make_batch, fields),
+                   P(built.mesh.axis_names[0]))
+    return built.step.lower(state[0], state[1],
+                            tuple(batch) + tuple(state[2:]))
+
+
+def families_in(path):
+    return {_layers.SCOPES[m.group(1)] for m in _layers._LAYER.finditer(path)}
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_every_heavy_operation_of_the_loss_is_under_one_layer(cell):
+    text = lowered_step(cell).as_text(debug_info=True)
+    operations = heavy_operations(text)
+    def in_loss(path):
+        return FORWARD in path or BACKWARD in path
+
+    inside = [(op, path) for op, path in operations if in_loss(path)]
+    assert len(inside) > 20 and {"dot_general", "gather"} <= {
+        op for op, _ in inside}, "the text's operations were not found"
+    seen = {direction: set() for direction in (FORWARD, BACKWARD)}
+    for op, path in inside:
+        families = families_in(path)
+        assert len(families) == 1, (
+            f"{op} at {path} lies under {sorted(families) or 'no'} layer "
+            "scope: a layer of horovod_tpu/models runs without a scope of "
+            "its own, or two families nest")
+        assert _layers.layer_of(path) in families
+        seen[BACKWARD if BACKWARD in path else FORWARD] |= families
+        if "hvd_kda_scan" in path:
+            stages = set(_layers._STAGE.findall(path))
+            assert len(stages) == 1 and _layers.stage_of(path) in stages, (
+                f"{op} at {path} lies under {sorted(stages) or 'no'} stage "
+                "of the delta rule")
+    assert seen[FORWARD] == seen[BACKWARD] == CELLS[cell]
+    if "kda" in CELLS[cell]:
+        for direction in (FORWARD, BACKWARD):
+            assert {_layers.stage_of(path) for _, path in inside
+                    if direction in path and "hvd_kda_scan" in path} \
+                == set(_layers.STAGES), direction
+        # The stages partition the scope: no operation at all, a cast or a
+        # reshape either, lies under `hvd_kda_scan` and under no stage.
+        under = [path for path in scope_paths(text) if "/hvd_kda_scan/" in path]
+        assert len(under) > 50
+        for path in under:
+            assert len(set(_layers._STAGE.findall(path))) == 1, path
+    # Outside the loss the optimizer's scope, and no layer's.
+    for op, path in operations:
+        assert in_loss(path) or not families_in(path), (op, path)
+
+
+def test_fused_head_and_loss_is_one_loop_under_the_heads_scope():
+    """`TransformerLM(targets=)`: the chunked head-and-loss is a `while`
+    forward and one backward, both under `hvd_lm_head`, with every product
+    and gather of theirs; `hvd_token_xent` does not exist there."""
+    from horovod_tpu.models import TransformerLM
+
+    model = TransformerLM(vocab_size=512, d_model=64, n_layers=1, n_heads=2,
+                          d_ff=128, dtype=jnp.float32, use_flash=False)
+    tokens = jnp.zeros((2, 64), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens)["params"])
+
+    def loss(params, tokens):
+        with jax.named_scope("hvd_loss"):
+            return model.apply({"params": params}, tokens, targets=tokens)
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, tokens).as_text(
+        debug_info=True)
+    assert "hvd_token_xent" not in text
+    operations = heavy_operations(text)
+    loops = [path for op, path in operations
+             if op == "while" and "/hvd_lm_head/" in path]
+    assert len(loops) == 2 and sum("transpose(" in p for p in loops) == 1
+    for op, path in operations:
+        assert len(families_in(path)) == 1, (op, path)
+    head = [op for op, path in operations if "/hvd_lm_head/" in path]
+    assert head.count("dot_general") >= 3 and "gather" in head

@@ -400,11 +400,21 @@ def test_ring_variants_compile_on_mesh(v5e, monkeypatch, impl):
         assert ids == ["15", "16"] * 4, ids
 
 
+_LM_STEPS = {}     # devices -> what _compile_lm_step gave for them
+
+
 def _compile_lm_step(devices):
     """A two-layer dense LM at pythia-410m's widths (a smaller vocabulary,
     512 tokens a chip) through `build_train_step` on a data-parallel mesh
     of the described ``devices``: (the step, its compiled text, the number
-    of weights whose gradient is over a megabyte in either dtype)."""
+    of weights whose gradient is over a megabyte in either dtype).  Compiled
+    once a process for a number of devices."""
+    if len(devices) not in _LM_STEPS:
+        _LM_STEPS[len(devices)] = _compiled_lm_step(devices)
+    return _LM_STEPS[len(devices)]
+
+
+def _compiled_lm_step(devices):
     import optax
     from jax.sharding import NamedSharding
 
@@ -497,6 +507,33 @@ def test_dp_step_exchanges_large_gradients_asynchronously(v5e, monkeypatch):
     assert "all-reduce" not in text
 
 
+def _assert_scopes_forward_and_backward(text, scopes):
+    """Every scope of ``scopes`` is in the compiled text's op_names under
+    `jvp(hvd_loss)` and under `transpose(jvp(hvd_loss))`."""
+    for scope in scopes:
+        assert re.search(rf'op_name="jit\([^"]*/jvp\(hvd_loss\)/[^"]*{scope}/',
+                         text), f"{scope} is not in the forward pass"
+        assert re.search(
+            rf'op_name="jit\([^"]*transpose\(jvp\(hvd_loss\)\)/[^"]*{scope}/',
+            text), f"{scope} is not in the backward pass"
+
+
+def test_dense_step_names_its_layers(v5e, monkeypatch):
+    """The dense LM's step compiled for one described chip: the embedding,
+    the three parts of attention, the MLP, the head and the loss's own pass
+    each keep a scope of their own in the compiled text's op_names, forward
+    and backward (benchmark/layer_metrics/_layers.py sorts a device trace by
+    them), and the flash kernels lie beneath `hvd_attn_attend`."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, text, _ = _compile_lm_step(v5e[:1])
+    _assert_scopes_forward_and_backward(
+        text, ("hvd_embed", "hvd_attn_qkv", "hvd_attn_attend", "hvd_attn_out",
+               "hvd_mlp", "hvd_lm_head", "hvd_token_xent"))
+    for kernel in ("hvd_flash_fwd", "hvd_flash_bwd"):
+        assert re.search(rf'%{kernel}[.\d]* = .*op_name="[^"]*/hvd_attn_attend/'
+                         rf'{kernel}/pallas_call"', text), kernel
+
+
 def _instructions_outside_fusions(text):
     """The instruction lines of a compiled program's text that are not in a
     fused computation: what the core runs one after another."""
@@ -563,7 +600,7 @@ def test_hybrid_step_is_products_and_kernels_with_no_loop(v5e, monkeypatch):
     through `build_train_step`, compiled for the described chip: the chunked
     scan is products over chunks (no `while` anywhere in the step), attention
     is the two flash kernels, the experts are libtpu's grouped-matmul kernels
-    (six and two tile schedules), and every scope of the new layers is in the
+    (six and two tile schedules), and every scope of the layers is in the
     text forward and backward."""
     import optax
     from jax.sharding import NamedSharding
@@ -608,15 +645,12 @@ def test_hybrid_step_is_products_and_kernels_with_no_loop(v5e, monkeypatch):
     assert len(re.findall(r"%hvd_flash_bwd[.\d]* = ", text)) == 1
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 6
     assert text.count('"tpu_custom_call"') == 2 + 6 + 2
-    for scope in ("hvd_ssm_in_proj", "hvd_ssm_conv", "hvd_ssm_scan",
-                  "hvd_ssm_gate_norm", "hvd_ssm_out_proj", "hvd_moe_latent",
-                  "hvd_moe_shared", "hvd_moe_router", "hvd_moe_dispatch",
-                  "hvd_moe_combine"):
-        assert re.search(rf'op_name="jit\([^"]*/jvp\(hvd_loss\)/[^"]*{scope}/',
-                         text), f"{scope} is not in the forward pass"
-        assert re.search(
-            rf'op_name="jit\([^"]*transpose\(jvp\(hvd_loss\)\)/[^"]*{scope}/',
-            text), f"{scope} is not in the backward pass"
+    _assert_scopes_forward_and_backward(
+        text, ("hvd_ssm_in_proj", "hvd_ssm_conv", "hvd_ssm_scan",
+               "hvd_ssm_gate_norm", "hvd_ssm_out_proj", "hvd_moe_latent",
+               "hvd_moe_shared", "hvd_moe_router", "hvd_moe_dispatch",
+               "hvd_moe_combine", "hvd_embed", "hvd_attn_qkv",
+               "hvd_attn_attend", "hvd_attn_out", "hvd_lm_head"))
 
 
 def test_ling_step_is_products_kernels_and_one_loop_a_pass(v5e, monkeypatch):
@@ -627,7 +661,8 @@ def test_ling_step_is_products_kernels_and_one_loop_a_pass(v5e, monkeypatch):
     step's only loops (one `while` forward, one backward), latent attention is
     the flash forward and the split backward pair at two widths, the experts
     are libtpu's grouped-matmul kernels (nine and two tile schedules), and
-    every scope of the new layers is in the text forward and backward."""
+    every scope of the layers and every stage of the delta rule is in the text
+    forward and backward."""
     import optax
     from jax.sharding import NamedSharding
 
@@ -671,15 +706,28 @@ def test_ling_step_is_products_kernels_and_one_loop_a_pass(v5e, monkeypatch):
         assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 9
     assert text.count('"tpu_custom_call"') == 3 + 9 + 2
-    for scope in ("hvd_kda_in_proj", "hvd_kda_conv", "hvd_kda_gate",
-                  "hvd_kda_scan", "hvd_kda_gate_norm", "hvd_kda_out_proj",
-                  "hvd_mla_q_proj", "hvd_mla_kv_latent", "hvd_mla_attend",
-                  "hvd_mla_out_proj", "hvd_moe_router", "hvd_moe_shared"):
-        assert re.search(rf'op_name="jit\([^"]*/jvp\(hvd_loss\)/[^"]*{scope}/',
-                         text), f"{scope} is not in the forward pass"
-        assert re.search(
-            rf'op_name="jit\([^"]*transpose\(jvp\(hvd_loss\)\)/[^"]*{scope}/',
-            text), f"{scope} is not in the backward pass"
+    _assert_scopes_forward_and_backward(
+        text, ("hvd_kda_in_proj", "hvd_kda_conv", "hvd_kda_gate",
+               "hvd_kda_scan", "hvd_kda_gate_norm", "hvd_kda_out_proj",
+               "hvd_mla_q_proj", "hvd_mla_kv_latent", "hvd_mla_attend",
+               "hvd_mla_out_proj", "hvd_moe_router", "hvd_moe_shared",
+               "hvd_embed", "hvd_mlp", "hvd_lm_head",
+               "hvd_kda_scan/hvd_kda_scan_decays",
+               "hvd_kda_scan/hvd_kda_scan_chunk",
+               "hvd_kda_scan/hvd_kda_scan_solve",
+               "hvd_kda_scan/hvd_kda_scan_carry"))
+    # The stages partition the scope: no operation, a cast either, lies under
+    # `hvd_kda_scan` and under no stage (their shares must sum to its own).
+    under = re.findall(r'op_name="([^"]*/hvd_kda_scan/[^"]*)"', text)
+    assert len(under) > 100 and all(re.search(
+        r"/hvd_kda_scan/hvd_kda_scan_(decays|chunk|solve|carry)/", path)
+        for path in under), [p for p in under if "scan_" not in p][:3]
+    # The two loops are the carry stage's, one a pass.
+    loops = re.findall(r'^\s*%[\w.\-]+ = .* while\(.*op_name="([^"]*)"', text,
+                       re.M)
+    assert len(loops) == 2 and all(
+        "/hvd_kda_scan/hvd_kda_scan_carry/" in path for path in loops), loops
+    assert sum("transpose(jvp(hvd_loss))" in path for path in loops) == 1
 
 
 @pytest.mark.parametrize("mode", ["combined", "split"])
