@@ -21,10 +21,21 @@ the state ``S`` enters is (the WY / UT transform; rows are tokens):
     S' = Diag(G_C) S + (K . G_C / G)^T U
 
 :func:`chunked_delta_rule` computes ``A``, ``T``, ``W``, ``U0`` and the masked
-``Q K^T`` for every chunk at once, and then carries ``S`` through the chunks in
-a ``lax.scan`` of three products a step (a ``while`` in the compiled program,
-forward and backward: the recurrence is a true one).  It is differentiable by
-autodiff of that form.
+``Q K^T`` for every chunk at once.  Only ``S`` is a true recurrence, and only
+``S`` is carried: a ``lax.scan`` of two products a step (``U``, then ``S'``; a
+``while`` in the compiled program) leaves the state that entered each chunk,
+and ``O`` is two products over all chunks at once from those states.  An
+iteration of the loop costs what its operations cost to launch, a
+microsecond each whatever they compute, so what need not be in it is not.
+The backward pass (:func:`_carry`, a ``jax.custom_vjp``) has the same shape:
+what ``O``'s cotangent gives ``U`` and the state is found for all chunks at
+once, a reverse scan of two products a step carries the state's cotangent,
+and the operands' cotangents are products over all chunks.  It keeps the
+state that entered each chunk and computes ``U`` again.  Everything outside
+:func:`_carry` and the solve is differentiable by autodiff.
+
+This is the rule on every backend: XLA products and two loops a layer, no
+kernel of ours (``PERF.md`` section 7 has what pins that).
 
 ``k_s / G_s`` is never formed: over a chunk of 64 tokens at the gate's bound of
 -5 a step it is ``e^320``.  A chunk is cut into sub-blocks of
@@ -32,7 +43,10 @@ autodiff of that form.
 at the first token of t's sub-block: ``G_t / G_ref <= 1``, and ``G_ref / G_s``
 is at most 1 for an earlier sub-block and at most ``e^75`` inside t's own (15
 steps of at most 5), which float32 and bfloat16 hold.  A log-decay under about
--5.8 a step would overflow there: the caller's gate bounds it.
+-5.8 a step would overflow there: the caller's gate bounds it.  The rows of
+a sub-block meet the columns of their own and of earlier sub-blocks alone:
+the ratios against later ones, six sub-block pairs of sixteen, would be
+masked and are not computed.
 
 Every log of a product of decays is a sum of log-decays (within a sub-block,
 to its end, over whole sub-blocks between), never a difference of two
@@ -50,12 +64,9 @@ under exactly one:
   (``against_earlier``), and the decayed ``Q`` and ``K`` the recurrence reads;
 * ``hvd_kda_scan_solve`` — ``T`` (:func:`_unit_lower_inverse`, forward and its
   written-out backward) and ``W``, ``U0``;
-* ``hvd_kda_scan_carry`` — the ``lax.scan`` between chunks (the ``while``,
-  its body, and the moves of its operands and of ``o``).
-
-The statements stand in the order they were traced in before the stages had
-names, so a stage's scope opens more than once: the lowered program is the
-same to the byte.
+* ``hvd_kda_scan_carry`` — :func:`_carry`: the two ``while``s and their
+  bodies, the products over all chunks around them, and the move of ``o``
+  back to tokens.
 
 Float32: the summed log-decays, their exponentials, ``T`` (by forward
 substitution, exact products), ``W``, ``U0``, ``U`` and the state between
@@ -77,6 +88,22 @@ def _exact(a, b):
     return jnp.matmul(a, b, precision="highest")
 
 
+def _diagonal_inverses(diagonal):
+    """``(I + d)^-1`` of every strictly lower triangular ``d`` of the last two
+    axes by forward substitution, ``row r = e_r - d[r, :r] . rows[:r]`` in
+    full precision.  The rows are gathered in a list and stacked once: a row
+    written into its block (``.at[..., r, :].add``) copies every block, at a
+    sixteenth of a vector register's lanes, fifteen times."""
+    sub = diagonal.shape[-1]
+    eye = jnp.eye(sub, dtype=diagonal.dtype)
+    rows = [jnp.broadcast_to(eye[0], diagonal.shape[:-1])]
+    for r in range(1, sub):
+        rows.append(eye[r] - jnp.einsum(
+            "...j,...jk->...k", diagonal[..., r, :r], jnp.stack(rows, -2),
+            precision="highest"))
+    return jnp.stack(rows, -2)
+
+
 @jax.custom_vjp
 def _unit_lower_inverse(a):
     """``(I + a)^-1`` for ``a`` strictly lower triangular over its last two
@@ -90,12 +117,8 @@ def _unit_lower_inverse(a):
     size = a.shape[-1]
     sub = min(SUB_BLOCK, size)
     corners = range(0, size, sub)
-    diagonal = jnp.stack([a[..., c:c + sub, c:c + sub] for c in corners], -3)
-    inverses = jnp.broadcast_to(jnp.eye(sub, dtype=a.dtype), diagonal.shape)
-    for row in range(1, sub):
-        inverses = inverses.at[..., row, :].add(-jnp.einsum(
-            "...j,...jk->...k", diagonal[..., row, :row],
-            inverses[..., :row, :], precision="highest"))
+    inverses = _diagonal_inverses(
+        jnp.stack([a[..., c:c + sub, c:c + sub] for c in corners], -3))
     inverse = inverses[..., 0, :, :]
     for block, c in enumerate(corners):
         if block:
@@ -120,6 +143,101 @@ def _inverse_bwd(inverse, g):
 _unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
+_WIDE = dict(preferred_element_type=jnp.float32)
+
+
+def _by_step(*operands):                # (b, n, ...) -> (n, b, ...)
+    return tuple(jnp.moveaxis(t, 1, 0) for t in operands)
+
+
+def _entered_states(w, u0, k_end, carried):
+    """The state that enters each chunk, (b, n, h, d_k, d_v) float32, from a
+    zero state: the one part of the rule that is a recurrence, a ``lax.scan``
+    of two products a step (``U`` of the chunk, then the state it leaves)."""
+    dtype = w.dtype
+
+    def chunk_step(state, inputs):
+        w, u0, k_end, carried = inputs
+        u = u0 - jnp.einsum("bhtc,bhcv->bhtv", w, state.astype(dtype),
+                            **_WIDE)
+        left = carried[..., None] * state + jnp.einsum(
+            "bhtc,bhtv->bhcv", k_end, u.astype(dtype), **_WIDE)
+        return left, state
+
+    batch, _, heads, _, d_k = w.shape
+    start = jnp.zeros((batch, heads, d_k, u0.shape[-1]), jnp.float32)
+    varying = tuple(jax.typeof(w).vma)
+    if varying:  # inside shard_map the carry varies as the inputs do
+        start = lax.pcast(start, varying, to="varying")
+    return _by_step(lax.scan(chunk_step, start,
+                             _by_step(w, u0, k_end, carried))[1])[0]
+
+
+def _rounded_states_and_u(w, u0, entered):
+    """What the products around the recurrence read of a chunk, for every
+    chunk at once: the state that entered it and its ``U``, both rounded."""
+    narrow = entered.astype(w.dtype)
+    u = u0 - jnp.einsum("bnhtc,bnhcv->bnhtv", w, narrow, **_WIDE)
+    return narrow, u.astype(w.dtype)
+
+
+@jax.custom_vjp
+def _carry(w, u0, q_in, qk, k_end, carried):
+    """``O`` of every chunk, (b, n, h, C, d_v) float32, from the chunks' own
+    operands (module docstring), each (b, n, h, ...), from a zero state.
+
+    Only the state is carried from chunk to chunk (:func:`_entered_states`);
+    ``O`` is two products over all chunks at once from the states so found.
+    The backward pass is written out the same way: what ``O``'s cotangent
+    gives ``U`` and the state without knowing the state's own cotangent is
+    computed for all chunks at once, a second scan of two products a step
+    carries the state's cotangent from the last chunk to the first, and the
+    operands' cotangents are products over all chunks after it.  Kept for it:
+    the operands and the state that entered each chunk; ``U`` is computed
+    again."""
+    return _carry_fwd(w, u0, q_in, qk, k_end, carried)[0]
+
+
+def _carry_fwd(w, u0, q_in, qk, k_end, carried):
+    entered = _entered_states(w, u0, k_end, carried)
+    narrow, rounded = _rounded_states_and_u(w, u0, entered)
+    o = jnp.einsum("bnhtc,bnhcv->bnhtv", q_in, narrow, **_WIDE) \
+        + jnp.einsum("bnhts,bnhsv->bnhtv", qk, rounded, **_WIDE)
+    return o, (w, u0, q_in, qk, k_end, carried, entered)
+
+
+def _carry_bwd(kept, d_o):
+    w, u0, q_in, qk, k_end, carried, entered = kept
+    dtype = w.dtype
+    narrow, rounded = _rounded_states_and_u(w, u0, entered)
+    d_o = d_o.astype(dtype)
+    d_q_in = jnp.einsum("bnhtv,bnhcv->bnhtc", d_o, narrow, **_WIDE)
+    d_qk = jnp.einsum("bnhtv,bnhsv->bnhts", d_o, rounded, **_WIDE)
+    u_from_o = jnp.einsum("bnhts,bnhtv->bnhsv", qk, d_o, **_WIDE)
+    state_from_o = jnp.einsum("bnhtc,bnhtv->bnhcv", q_in, d_o, **_WIDE)
+
+    def chunk_step(d_left, inputs):     # d_left: of the state a chunk leaves
+        w, k_end, carried, u_from_o, state_from_o = inputs
+        d_u = u_from_o + jnp.einsum("bhtc,bhcv->bhtv", k_end,
+                                    d_left.astype(dtype), **_WIDE)
+        d_state = carried[..., None] * d_left + state_from_o - jnp.einsum(
+            "bhtc,bhtv->bhcv", w, d_u.astype(dtype), **_WIDE)
+        return d_state, (d_u, d_left)
+
+    d_u, d_left = _by_step(*lax.scan(
+        chunk_step, jnp.zeros_like(entered[:, 0]),
+        _by_step(w, k_end, carried, u_from_o, state_from_o), reverse=True)[1])
+    d_w = -jnp.einsum("bnhtv,bnhcv->bnhtc", d_u.astype(dtype), narrow,
+                      **_WIDE)
+    d_k_end = jnp.einsum("bnhtv,bnhcv->bnhtc", rounded, d_left.astype(dtype),
+                         **_WIDE)
+    return (d_w.astype(dtype), d_u, d_q_in.astype(dtype), d_qk.astype(dtype),
+            d_k_end.astype(dtype), (d_left * entered).sum(axis=-1))
+
+
+_carry.defvjp(_carry_fwd, _carry_bwd)
+
+
 def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int):
     """``o`` of the recurrence above for every token, from a zero state.
 
@@ -137,8 +255,7 @@ def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int):
                          f"of chunk {chunk}, or the chunk of {sub}")
     chunks, blocks = seq // chunk, chunk // sub
     f32, dtype = jnp.float32, q.dtype
-    wide = dict(preferred_element_type=f32)
-    exact = dict(precision="highest", preferred_element_type=f32)
+    exact = dict(precision="highest", **_WIDE)
 
     def by_chunk(t):                    # (b, seq, h, ...) -> (b, n, h, C, ...)
         return jnp.moveaxis(
@@ -154,21 +271,20 @@ def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int):
         steps = by_chunk(log_alpha.astype(f32)).reshape(
             batch, chunks, heads, blocks, sub, d_k)
         first = steps[..., :1, :]
-        after_first = jnp.cumsum(steps.at[..., 0, :].set(0.0), axis=-2)
+        after_first = jnp.cumsum(
+            jnp.where(jnp.arange(sub)[:, None] > 0, steps, 0.0), axis=-2)
         later = jnp.concatenate([steps[..., 1:, :], jnp.zeros_like(first)],
                                 -2)
         tail = lax.cumsum(later, axis=later.ndim - 2, reverse=True)
         total = after_first[..., -1, :] + first[..., 0, :]   # (b, n, h, i, d)
-        i, j = jnp.arange(blocks)[:, None], jnp.arange(blocks)[None, :]
         m = jnp.arange(blocks)
 
-        def summed(indices, mask):
-            """The sub-blocks' totals summed where ``mask[..., m]`` holds."""
-            return jnp.einsum(f"{indices}m,bnhmd->bnh{indices}d",
-                              mask.astype(f32), total, precision="highest")
+        def summed(mask):
+            """The sub-blocks' totals summed where ``mask[i, m]`` holds."""
+            return jnp.einsum("im,bnhmd->bnhid", mask.astype(f32), total,
+                              precision="highest")
 
-        before, after = summed("i", j < i), summed("i", j > i)
-        between = summed("ij", (m > j[..., None]) & (m < i[..., None]))
+        before, after = summed(m < m[:, None]), summed(m > m[:, None])
         within = (before[..., None, :] + first + after_first).reshape(
             batch, chunks, heads, chunk, d_k)
         to_end = (tail + after[..., None, :]).reshape(within.shape)
@@ -178,67 +294,50 @@ def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int):
         # G_t / G_ref is at most 1; G_ref / G_s at most 1 for s in an earlier
         # sub-block j and at most e^75 inside i itself.
         to_ref = jnp.exp(after_first).reshape(within.shape)
-        from_ref = jnp.exp(jnp.where(
-            (j < i)[..., None, None],
-            tail[..., None, :, :, :] + between[..., None, :]
-            + first[..., :, None, :, :],
-            jnp.where((j == i)[..., None, None],
-                      -after_first[..., None, :, :, :], -jnp.inf)))
+        # Rows of sub-block i meet the columns of sub-blocks 0..i alone:
+        # from a column of sub-block j < i to the end of j, over the whole
+        # sub-blocks between, and i's first token; inside i back from the
+        # column to i's first token.
+        from_ref = []
+        for i in range(blocks):
+            pieces = [-after_first[..., i, :, :]]
+            if i:
+                to_first = [first[..., i, 0, :]]     # from the end of i - 1,
+                for j in range(i - 1, 0, -1):        # then of j - 1: over j
+                    to_first.append(to_first[-1] + total[..., j, :])
+                pieces.insert(0, (
+                    tail[..., :i, :, :]
+                    + jnp.stack(to_first[::-1], -2)[..., None, :]).reshape(
+                        batch, chunks, heads, i * sub, d_k))
+            from_ref.append(jnp.exp(jnp.concatenate(pieces, axis=-2)))
+        decayed, end_decay = jnp.exp(within), jnp.exp(to_end)
+        carried, decay_min = jnp.exp(whole), whole.min()
 
     def against_earlier(rows):
-        """``rows[t] . G_t`` against every ``k_s / G_s``: (b, n, h, C, C)."""
-        rows = (rows * to_ref).astype(dtype).reshape(
-            batch, chunks, heads, blocks, sub, d_k)
-        return jnp.einsum("bnhitc,bnhisc->bnhits", rows, k_col,
-                          **wide).reshape(batch, chunks, heads, chunk, chunk)
+        """``rows[t] . G_t`` against every ``k_s / G_s``: (b, n, h, C, C),
+        zero right of the diagonal sub-blocks."""
+        rows = (rows * to_ref).astype(dtype)
+        return jnp.concatenate([jnp.pad(
+            jnp.einsum("bnhtc,bnhsc->bnhts",
+                       rows[..., i * sub:(i + 1) * sub, :], k_col[i], **_WIDE),
+            [(0, 0)] * 4 + [(0, chunk - (i + 1) * sub)])
+            for i in range(blocks)], axis=-2)
 
     with jax.named_scope("hvd_kda_scan_chunk"):
-        k_col = (kc[..., None, :, :] * from_ref.reshape(
-            batch, chunks, heads, blocks, chunk, d_k)).astype(dtype)
+        k_col = [(kc[..., :(i + 1) * sub, :] * from_ref[i]).astype(dtype)
+                 for i in range(blocks)]
         lower = jnp.tril(jnp.ones((chunk, chunk), bool))
         a = jnp.where(lower & ~jnp.eye(chunk, dtype=bool),
                       bc * against_earlier(kc), 0.0)
         qk = jnp.where(lower, against_earlier(qc), 0.0).astype(dtype)
+        q_in = (qc * decayed).astype(dtype)
+        k_end = (kc * end_decay).astype(dtype)
     with jax.named_scope("hvd_kda_scan_solve"):
         solve = _unit_lower_inverse(a)
-    with jax.named_scope("hvd_kda_scan_decays"):
-        decayed = jnp.exp(within)
-    with jax.named_scope("hvd_kda_scan_solve"):
         w = jnp.einsum("bnhts,bnhsc->bnhtc", solve, bc * kc * decayed,
-                       **exact)
+                       **exact).astype(dtype)
         u0 = jnp.einsum("bnhts,bnhsv->bnhtv", solve, bc * vc, **exact)
-    with jax.named_scope("hvd_kda_scan_chunk"):
-        q_in = (qc * decayed).astype(dtype)
-    with jax.named_scope("hvd_kda_scan_decays"):
-        end_decay = jnp.exp(to_end)
-    with jax.named_scope("hvd_kda_scan_chunk"):
-        k_end = (kc * end_decay).astype(dtype)
-
-    def chunk_step(state, inputs):
-        w, u0, q_in, qk, k_end, carried = inputs
-        narrow = state.astype(dtype)
-        u = u0 - jnp.einsum("bhtc,bhcv->bhtv", w, narrow, **wide)
-        rounded = u.astype(dtype)
-        o = jnp.einsum("bhtc,bhcv->bhtv", q_in, narrow, **wide) \
-            + jnp.einsum("bhts,bhsv->bhtv", qk, rounded, **wide)
-        state = carried[..., None] * state + jnp.einsum(
-            "bhtc,bhtv->bhcv", k_end, rounded, **wide)
-        return state, o
-
     with jax.named_scope("hvd_kda_scan_carry"):
-        start = jnp.zeros((batch, heads, d_k, v.shape[-1]), f32)
-        varying = tuple(jax.typeof(k).vma)
-        if varying:  # inside shard_map the carry varies as the inputs do
-            start = lax.pcast(start, varying, to="varying")
-    with jax.named_scope("hvd_kda_scan_solve"):
-        w = w.astype(dtype)
-    with jax.named_scope("hvd_kda_scan_decays"):
-        carried = jnp.exp(whole)
-    with jax.named_scope("hvd_kda_scan_carry"):
-        by_step = [jnp.moveaxis(t, 1, 0)
-                   for t in (w, u0, q_in, qk, k_end, carried)]
-        _, o = lax.scan(chunk_step, start, tuple(by_step))
-        # (n, b, h, C, d_v) -> (b, seq, h, d_v)
-        o = jnp.moveaxis(o, (0, 3), (1, 2)).reshape(batch, seq, heads, -1)
-    with jax.named_scope("hvd_kda_scan_decays"):
-        return o, whole.min()
+        o = _carry(w, u0, q_in, qk, k_end, carried)
+        # (b, n, h, C, d_v) -> (b, seq, h, d_v)
+        return jnp.moveaxis(o, 3, 2).reshape(batch, seq, heads, -1), decay_min

@@ -27,6 +27,7 @@ from horovod_tpu.models import (DeltaConfig, DeltaMixer, LatentAttention,
 from horovod_tpu.models.transformer import (LAYER_KINDS, GatedMLP, MixerLayer,
                                             SparseExperts)
 from horovod_tpu.ops import flash_attention, mha_reference
+from horovod_tpu.ops import delta_rule
 from horovod_tpu.ops.delta_rule import chunked_delta_rule
 from tests.test_hybrid import (both_ways, close, columns, mixer_case, seeded,
                          system_loss, trees_close, with_highest)
@@ -126,6 +127,151 @@ def test_chunked_delta_rule_refuses_a_ragged_length(seq, chunk):
     args, _ = delta_inputs(0, seq=seq)
     with pytest.raises(ValueError, match="multiple"):
         chunked_delta_rule(*args, chunk)
+
+
+# --- the delta rule at the Ling cell's widths: the recurrence that carries
+# only its state, and the solve whose rows are stacked once ------------------
+
+WIDE_HEAD, WIDE_CHUNK = 128, 64
+
+
+def wide_inputs(seed, chunks, regime):
+    """Head width 128, chunks of 64 with sub-blocks of 16.  `bound`:
+    `delta_inputs`' own decays, every seventh token AT the gate's bound of -5
+    in every channel (with EVERY token there the log-decays' gradient, a
+    thousandth of the others', is 7e-3 of its largest value off the
+    recurrence's, before PR 35 as after); `near_zero`: decays of 1 - 1e-3 a
+    step, so the state crosses every chunk whole; `alike`: keys a hundredth
+    apart, so that `K K^T` is all ones and the powers of `A` reach 1e8 (the
+    case that breaks a Neumann-series inverse)."""
+    (q, k, v, log_alpha, beta), mix = delta_inputs(
+        seed, seq=chunks * WIDE_CHUNK, d_k=WIDE_HEAD, d_v=WIDE_HEAD)
+    if regime == "near_zero":
+        log_alpha = log_alpha * 2e-4
+    elif regime == "alike":
+        k = k[:, :1] + 0.01 * k
+        k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    return (q[:1, :, :2], k[:1, :, :2], v[:1, :, :2], log_alpha[:1, :, :2],
+            beta[:1, :, :2]), mix[:1, :, :2]
+
+
+@pytest.mark.parametrize("regime", ["bound", "near_zero", "alike"])
+@pytest.mark.parametrize("chunks", [2, 5])
+def test_wide_delta_rule_and_its_gradients_are_the_recurrences(chunks,
+                                                              regime):
+    args, mix = wide_inputs(chunks, chunks, regime)
+
+    def total(fn):
+        return lambda *a: (fn(*a) * mix).sum()
+
+    got, decay_min = jax.jit(
+        lambda *a: chunked_delta_rule(*a, WIDE_CHUNK))(*args)
+    close(got, jax.jit(reference.delta_recurrence)(*args), 1e-4)
+    close(decay_min, args[3].reshape(1, chunks, WIDE_CHUNK, 2, -1).sum(
+        axis=2).min())
+    grads = jax.jit(jax.grad(total(
+        lambda *a: chunked_delta_rule(*a, WIDE_CHUNK)[0]),
+        argnums=range(5)))(*args)
+    wanted = jax.jit(jax.grad(total(reference.delta_recurrence),
+                              argnums=range(5)))(*args)
+    for g, w in zip(grads, wanted):
+        assert bool(jnp.isfinite(g).all())
+        close(g, w, 1e-4)
+
+
+def plain_carry(w, u0, q_in, qk, k_end, carried):
+    """The recurrence between chunks as it is written down — one scan of the
+    chunk's three products with `O` inside the step, differentiated by
+    autodiff: what `delta_rule._carry` must equal, value and gradients."""
+    def chunk_step(state, inputs):
+        w, u0, q_in, qk, k_end, carried = inputs
+        u = u0 - jnp.einsum("bhtc,bhcv->bhtv", w, state)
+        o = jnp.einsum("bhtc,bhcv->bhtv", q_in, state) \
+            + jnp.einsum("bhts,bhsv->bhtv", qk, u)
+        return carried[..., None] * state + jnp.einsum(
+            "bhtc,bhtv->bhcv", k_end, u), o
+
+    start = jnp.zeros((w.shape[0], w.shape[2], w.shape[-1], u0.shape[-1]))
+    by_step = [jnp.moveaxis(t, 1, 0)
+               for t in (w, u0, q_in, qk, k_end, carried)]
+    return jnp.moveaxis(jax.lax.scan(chunk_step, start, tuple(by_step))[1],
+                        0, 1)
+
+
+def carry_operands(seed, chunks, carried_to):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
+    lead = (2, chunks, 2, WIDE_CHUNK)
+
+    def normal(key, *shape, scale=1.0):
+        return scale * jax.random.normal(key, shape)
+
+    width = WIDE_HEAD ** -0.5
+    w, q_in, k_end = (normal(key, *lead, WIDE_HEAD, scale=width)
+                      for key in keys[:3])
+    u0 = normal(keys[3], *lead, WIDE_HEAD)
+    qk = jnp.tril(normal(keys[4], *lead, WIDE_CHUNK, scale=0.1))
+    carried = jnp.exp(carried_to * jax.random.uniform(
+        keys[5], (2, chunks, 2, WIDE_HEAD)))
+    mix = normal(keys[6], *lead, WIDE_HEAD)
+    return (w, u0, q_in, qk, k_end, carried), mix
+
+
+@pytest.mark.parametrize("carried_to", [-320.0, -1e-3])
+@pytest.mark.parametrize("chunks", [2, 5])
+def test_carry_is_the_scan_with_o_inside_it(chunks, carried_to):
+    """Value and all six cotangents, where nothing crosses a chunk (a decay of
+    e^-320 is a float32 zero) and where everything does."""
+    operands, mix = carry_operands(chunks, chunks, carried_to)
+
+    def total(fn):
+        return lambda *a: (fn(*a) * mix).sum()
+
+    got, wanted = (jax.jit(jax.value_and_grad(total(fn), argnums=range(6)))(
+        *operands) for fn in (delta_rule._carry, plain_carry))
+    close(got[0], wanted[0], RTOL)
+    for g, w in zip(got[1], wanted[1]):
+        assert bool(jnp.isfinite(g).all())
+        close(g, w, RTOL)
+
+
+def scan_bodies(jaxpr):
+    bodies = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            bodies.append(eqn.params["jaxpr"].jaxpr)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            bodies += scan_bodies(sub)
+    return bodies
+
+
+def test_each_pass_carries_the_state_alone_through_two_products_a_chunk():
+    """One scan forward and one backward, each of two products a step: `O`,
+    and every cotangent but the state's, are products over all chunks."""
+    args, mix = wide_inputs(0, 2, "bound")
+    program = jax.make_jaxpr(jax.grad(
+        lambda *a: (chunked_delta_rule(*a, WIDE_CHUNK)[0] * mix).sum(),
+        argnums=range(5)))(*args)
+    bodies = scan_bodies(program.jaxpr)
+    assert [sum(e.primitive.name == "dot_general" for e in body.eqns)
+            for body in bodies] == [2, 2]
+
+
+@pytest.mark.parametrize("size", [8, 16, 64])
+def test_unit_lower_inverse_of_keys_that_resemble_one_another(size):
+    """`(I + A)^-1` with `A = tril(beta K K^T, -1)` of keys a hundredth apart
+    (every entry near beta): against float64, and its cotangent
+    `-T^T g T^T`."""
+    keys = jax.random.split(jax.random.PRNGKey(size), 3)
+    k = jax.random.normal(keys[0], (3, 1, WIDE_HEAD)) \
+        + 0.01 * jax.random.normal(keys[1], (3, size, WIDE_HEAD))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    a = jnp.tril(0.9 * jnp.einsum("bic,bjc->bij", k, k), -1)
+    wanted = np.linalg.inv(np.eye(size) + np.asarray(a, np.float64))
+    close(delta_rule._unit_lower_inverse(a), wanted, RTOL)
+    g = jax.random.normal(keys[2], a.shape)
+    got = jax.grad(lambda a: (delta_rule._unit_lower_inverse(a) * g).sum())(a)
+    transposed = wanted.swapaxes(-1, -2)
+    close(got, -transposed @ np.asarray(g, np.float64) @ transposed, RTOL)
 
 
 # --- flash attention with two widths ----------------------------------------

@@ -658,7 +658,8 @@ def test_ling_step_is_products_kernels_and_one_loop_a_pass(v5e, monkeypatch):
     heads, 192 and 128 wide) and group-limited gated experts with a shared one
     at Ling-3.0-flash's per-head widths through `build_train_step`, compiled
     for the described chip: the delta rule's recurrence between chunks is the
-    step's only loops (one `while` forward, one backward), latent attention is
+    step's only loops (one `while` forward, one backward, each carrying the
+    state alone through a handful of fusions), latent attention is
     the flash forward and the split backward pair at two widths, the experts
     are libtpu's grouped-matmul kernels (nine and two tile schedules), and
     every scope of the layers and every stage of the delta rule is in the text
@@ -728,6 +729,14 @@ def test_ling_step_is_products_kernels_and_one_loop_a_pass(v5e, monkeypatch):
     assert len(loops) == 2 and all(
         "/hvd_kda_scan/hvd_kda_scan_carry/" in path for path in loops), loops
     assert sum("transpose(jvp(hvd_loss))" in path for path in loops) == 1
+    # Each loop carries the state alone: its body is the state's two products
+    # and what stores them; an iteration costs a microsecond a fusion whatever
+    # it computes, so nothing else belongs in it.
+    bodies = re.findall(r"\bwhile\(.*body=%([\w.\-]+)", text)
+    for body in bodies:
+        start = text.index(f"\n%{body} ")
+        fusions = text[start:text.index("\n}", start)].count(" fusion(")
+        assert 2 <= fusions <= 5, (body, fusions)
 
 
 @pytest.mark.parametrize("mode", ["combined", "split"])
