@@ -331,6 +331,10 @@ class MetricsRegistry:
         # that pass's form (one of ops.moe.WAYS_BACK).
         self._moe = {"rows_per_local_expert": [], "rows_over_bound": 0,
                      "rows_walked": [], "way_back": []}
+        # Windowed attention layers (models.Attention(window=)): the (query
+        # block, key block) pairs a head's forward kernel visits, and what
+        # the causal kernel would (models.record_attention_blocks).
+        self._attention = {"blocks_visited": [], "blocks_causal": []}
         # What the compiler made of the last compiled training step's
         # gradient exchange (jax/train.py `_TimedStep.exchange_overlap`).
         self._train_step = {"compiler_options": "not applied",
@@ -427,6 +431,14 @@ class MetricsRegistry:
             self._moe["rows_over_bound"] += int(rows_over_bound)
             self._moe["rows_walked"] = [int(n) for n in rows_walked]
             self._moe["way_back"] = [str(form) for form in way_back]
+
+    def set_attention_blocks(self, blocks_visited, blocks_causal) -> None:
+        """Mirror one forward pass's windowed-attention counters, per
+        windowed layer (overwritten: they are static shapes)."""
+        with self._lock:
+            self._attention = {
+                "blocks_visited": [int(n) for n in blocks_visited],
+                "blocks_causal": [int(n) for n in blocks_causal]}
 
     def set_train_step(self, exchange_overlap: dict) -> None:
         """Mirror a compiled training step's account of its gradient
@@ -783,6 +795,8 @@ class MetricsRegistry:
                     "rows_walked": list(self._moe["rows_walked"]),
                     "way_back": list(self._moe["way_back"]),
                 },
+                "attention": {name: list(blocks) for name, blocks in
+                              self._attention.items()},
                 "train_step": dict(self._train_step),
                 "compression": {
                     "mode": self._compression["mode"],
@@ -973,6 +987,17 @@ def prometheus_text(snapshot: dict) -> str:
     for layer, form in enumerate(moe.get("way_back", [])):
         out.append(f'hvd_tpu_moe_way_back{{layer="{layer}",'
                    f'form="{form}"}} 1')
+
+    attention = snapshot.get("attention", {})
+    out.append("# HELP hvd_tpu_attention_blocks (query block, key block) "
+               "pairs of one head in each windowed attention layer: visited "
+               "by the banded forward kernel, and what the causal kernel "
+               "would visit under the same blocks")
+    out.append("# TYPE hvd_tpu_attention_blocks gauge")
+    for kind in ("visited", "causal"):
+        for layer, n in enumerate(attention.get("blocks_" + kind, [])):
+            out.append(f'hvd_tpu_attention_blocks{{layer="{layer}",'
+                       f'kind="{kind}"}} {n}')
 
     step = snapshot.get("train_step", {})
     out.append("# HELP hvd_tpu_train_step_all_reduces all-reduces of the "

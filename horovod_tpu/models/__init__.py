@@ -30,6 +30,7 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     TransformerLM,
     moe_next_token_loss,
     next_token_loss,
+    record_attention_blocks,
     record_expert_rows,
     router_losses,
 )

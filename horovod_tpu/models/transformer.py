@@ -22,6 +22,7 @@ from horovod_tpu.models.delta import DeltaConfig, DeltaMixer
 from horovod_tpu.models.ssm import Mamba2Config, Mamba2Mixer
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              ring_attention)
+from horovod_tpu.ops.attention import window_blocks
 from horovod_tpu.ops.moe import (WAYS_BACK, buffer_rows_to_tokens,
                                  dispatch_rows, grouped_matmul,
                                  reduced_to_vma_of, token_rows_to_buffer,
@@ -365,8 +366,10 @@ class Attention(nn.Module):
     """Causal self-attention of one layer: the q/k/v projections (with the
     q/k norm where asked for) under the scope ``hvd_attn_qkv``, the rotation
     and the attention itself (flash, blockwise, ring or cached decode) under
-    ``hvd_attn_attend``, the output projection under ``hvd_attn_out`` — names
-    a trace can read, forward and backward."""
+    ``hvd_attn_attend``, the output gate's projection and its
+    sigmoid-multiply, where there is one, under ``hvd_attn_gate``, the output
+    projection under ``hvd_attn_out`` — names a trace can read, forward and
+    backward."""
 
     n_heads: int
     dtype: Any = jnp.bfloat16
@@ -396,6 +399,27 @@ class Attention(nn.Module):
     # is tensor-parallel over ``n`` chips, whose ``n`` outputs sum to the
     # whole layer's; the sum is the caller's.  ``(0, 1)``: the whole layer.
     head_shard: Tuple[int, int] = (0, 1)
+    # A head's width where it is not ``d_model // n_heads`` (the projections
+    # are then ``n_heads * head_dim`` wide, not ``d_model``).
+    head_dim: Optional[int] = None
+    # A sliding window: query ``t`` sees the keys ``s`` with ``0 <= t - s <
+    # window`` (:func:`~horovod_tpu.ops.flash_attention`, whose kernels
+    # neither compute nor fetch a block wholly outside that band).  A layer
+    # with one sows ``attn_blocks_visited`` and ``attn_blocks_causal`` into
+    # ``intermediates``: the (query block, key block) pairs a head's forward
+    # kernel visits, and what the causal kernel would under the same blocks
+    # (:func:`~horovod_tpu.ops.attention.window_blocks`; both the scan's
+    # every-block count where the shape leaves the kernels).  Training only:
+    # no ring, no cached decode.
+    window: Optional[int] = None
+    # RMSNorm over EACH head's ``head_dim`` of q and of k, one learned
+    # ``head_dim``-wide scale for q and one for k that every head shares,
+    # before the rotation (beside ``qk_norm``, which norms the whole
+    # projection at once).
+    head_norm: bool = False
+    # An output gate: ``concat_h(o_h) * sigmoid(x W_g)`` elementwise, ``W_g``
+    # as wide as the query projection, before the output projection.
+    gate: bool = False
 
     def _grouped_projections(self, x, head_dim):
         """(q, k, v), each (b, local query heads, seq, head_dim), from a
@@ -424,17 +448,25 @@ class Attention(nn.Module):
         x = x.astype(self.dtype)
         q = jnp.einsum("bsd,dhe->bhse", x, w_q.astype(self.dtype))
         k, v = jnp.einsum("bsd,djhe->jbhse", x, w_kv.astype(self.dtype))
+        if self.head_norm:       # a key head's norm once, before its repeat
+            q = self._head_norm("q_head_norm_scale", q)
+            k = self._head_norm("k_head_norm_scale", k)
         return (q, jnp.repeat(k, heads // kv_local, axis=1),
                 jnp.repeat(v, heads // kv_local, axis=1))
 
     @nn.compact
     def __call__(self, x, decode_ctx=None):
         b, s, d = x.shape
-        head_dim = d // self.n_heads
+        head_dim = self.head_dim or d // self.n_heads
         n_heads = self.n_heads // self.head_shard[1]
         rotate = rope if self.rope else (lambda t, positions: t)
+        if self.window is not None and (decode_ctx is not None
+                                        or self.seq_axis is not None):
+            raise ValueError("window= composes with neither decode_ctx= nor "
+                             "sequence parallelism")
+        grouped = self.n_kv_heads is not None or self.head_shard != (0, 1)
         with jax.named_scope("hvd_attn_qkv"):
-            if self.n_kv_heads is not None or self.head_shard != (0, 1):
+            if grouped:
                 q, k, v = self._grouped_projections(x, head_dim)
             else:
                 # One fused qkv projection whose einsum emits q/k/v
@@ -457,6 +489,9 @@ class Attention(nn.Module):
             if self.qk_norm:
                 q = self._projection_norm("q_norm_scale", q)
                 k = self._projection_norm("k_norm_scale", k)
+            if self.head_norm and not grouped:
+                q = self._head_norm("q_head_norm_scale", q)
+                k = self._head_norm("k_head_norm_scale", k)
 
         new_kv = None
         with jax.named_scope("hvd_attn_attend"):
@@ -490,9 +525,31 @@ class Attention(nn.Module):
                 q, k = rotate(q, positions), rotate(k, positions)
                 if self.capture_kv:
                     self.sow("intermediates", "kv", (k, v))
-                out = (flash_attention(q, k, v, causal=True)
+                out = (flash_attention(q, k, v, causal=True,
+                                       window=self.window)
                        if self.use_flash
-                       else blockwise_attention(q, k, v, causal=True))
+                       else blockwise_attention(q, k, v, causal=True,
+                                                window=self.window))
+                if self.window is not None:
+                    blocks = window_blocks(s, self.window, head_dim) \
+                        if self.use_flash else None
+                    every = -(-s // min(512, s))       # the scan's blocks
+                    visited, causal = blocks or (every, every)
+                    self.sow("intermediates", "attn_blocks_visited",
+                             jnp.int32(visited))
+                    self.sow("intermediates", "attn_blocks_causal",
+                             jnp.int32(causal))
+        if self.gate:
+            w_g = self.param(
+                "gate_kernel", nn.initializers.lecun_normal(
+                    in_axis=0, out_axis=(1, 2)),
+                (d, n_heads, head_dim), jnp.float32)
+            with jax.named_scope("hvd_attn_gate"):
+                gate = nn.sigmoid(jnp.einsum(
+                    "bsd,dhe->bhse", x.astype(self.dtype),
+                    w_g.astype(self.dtype),
+                    preferred_element_type=jnp.float32))
+                out = (out * gate).astype(self.dtype)
         w_o = self.param(
             "o_kernel",
             nn.initializers.lecun_normal(in_axis=(0, 1), out_axis=2),
@@ -500,6 +557,16 @@ class Attention(nn.Module):
         with jax.named_scope("hvd_attn_out"):
             proj = jnp.einsum("bhse,hed->bsd", out, w_o.astype(self.dtype))
         return proj if new_kv is None else (proj, new_kv)
+
+    def _head_norm(self, name, t):
+        """RMSNorm over the last axis of ``t`` (b, heads, seq, head_dim),
+        float32 inside; ONE (head_dim,) scale for all heads."""
+        scale = self.param(name, nn.initializers.ones, (t.shape[3],),
+                           jnp.float32)
+        wide = t.astype(jnp.float32)
+        mean_sq = jnp.mean(jnp.square(wide), axis=-1, keepdims=True)
+        return (wide * lax.rsqrt(mean_sq + self.norm_eps)
+                * scale).astype(t.dtype)
 
     def _projection_norm(self, name, t):
         """RMSNorm over heads and head_dim together of ``t`` (b, heads, seq,
@@ -674,17 +741,25 @@ class Block(nn.Module):
 # kind -> the module a MixerLayer of that kind runs.
 LAYER_KINDS = {"ssm": "Mamba2Mixer", "attention": "Attention",
                "experts": "SparseExperts", "delta": "DeltaMixer",
-               "latent_attention": "LatentAttention", "gated_mlp": "GatedMLP"}
+               "latent_attention": "LatentAttention", "gated_mlp": "GatedMLP",
+               "window_attention": "Attention"}
 
 
 class MixerLayer(nn.Module):
     __doc__ = (
         """One layer of a per-layer pattern (``TransformerLM(layers=)``): ONE
-    mixer behind one norm and one residual, ``x + mixer(RMSNorm(x))``; a
+    mixer behind one norm and one residual, ``x + mixer(RMSNorm(x))`` — with
+    ``post_norm``, ``x + RMSNorm(mixer(RMSNorm(x)))``, the mixer's output
+    normed again (``post_norm``'s own scale) before the add; a
     published layer of two sublayers is two consecutive entries.  ``kind``
     and the mixer it runs: """
         + ", ".join(f"``{kind!r}`` {module}"
-                    for kind, module in LAYER_KINDS.items()) + ".")
+                    for kind, module in LAYER_KINDS.items())
+        + """.  ``"window_attention"`` is :class:`Attention` with the model's
+    ``window`` and ALWAYS rotated; ``"attention"`` sees every earlier key and
+    rotates where ``rope`` says: one pattern holds rotated windowed layers
+    and unrotated full ones.  Both take ``head_dim``, ``head_norm`` and
+    ``attn_gate``.""")
 
     kind: str
     n_heads: int
@@ -700,6 +775,11 @@ class MixerLayer(nn.Module):
     delta: Optional[DeltaConfig] = None
     latent: Optional[LatentConfig] = None
     d_ff: Optional[int] = None
+    head_dim: Optional[int] = None
+    window: Optional[int] = None
+    head_norm: bool = False
+    attn_gate: bool = False
+    post_norm: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -709,12 +789,20 @@ class MixerLayer(nn.Module):
             mixer = Mamba2Mixer(*self.ssm, head_shard=self.head_shard,
                                 dtype=self.dtype, norm_eps=self.norm_eps,
                                 name="mixer")
-        elif self.kind == "attention":
+        elif self.kind in ("attention", "window_attention"):
+            windowed = self.kind == "window_attention"
+            if windowed and self.window is None:
+                raise ValueError("a 'window_attention' layer wants window=")
             mixer = Attention(self.n_heads, self.dtype,
                               use_flash=self.use_flash, qk_norm=self.qk_norm,
                               norm_eps=self.norm_eps,
-                              n_kv_heads=self.n_kv_heads, rope=self.rope,
-                              head_shard=self.head_shard, name="mixer")
+                              n_kv_heads=self.n_kv_heads,
+                              rope=self.rope or windowed,
+                              head_shard=self.head_shard,
+                              head_dim=self.head_dim,
+                              window=self.window if windowed else None,
+                              head_norm=self.head_norm, gate=self.attn_gate,
+                              name="mixer")
         elif self.kind == "experts":
             mixer = SparseExperts(self.moe, self.dtype, name="mixer")
         elif self.kind == "delta":
@@ -730,7 +818,11 @@ class MixerLayer(nn.Module):
         else:
             raise ValueError(f"layer kind {self.kind!r} is none of "
                              f"{tuple(LAYER_KINDS)}")
-        return x + mixer(h)
+        out = mixer(h)
+        if self.post_norm:
+            out = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                             name="post_norm")(out)
+        return x + out
 
 
 class TransformerLM(nn.Module):
@@ -774,9 +866,17 @@ class TransformerLM(nn.Module):
     # of ``ssm``'s sizes, ``"attention"``, ``"experts"`` the sparse experts of
     # ``moe``, ``"delta"`` a Kimi-delta mixer of ``delta``'s sizes,
     # ``"latent_attention"`` of ``latent``'s, ``"gated_mlp"`` a dense MLP of
-    # ``d_ff``.  ``n_kv_heads`` and ``rope`` are the attention layers' as
-    # :class:`Attention` has them, ``head_shard`` every head-carrying
-    # mixer's.  Unset, the model is the block above, parameter for parameter.
+    # ``d_ff``, ``"window_attention"`` attention under the sliding ``window``,
+    # always rotated.  ``n_kv_heads`` and ``rope`` are the ``"attention"``
+    # layers' as :class:`Attention` has them (so ``rope=False`` with a
+    # ``window`` is full layers without rotation among rotated windowed
+    # ones), ``head_dim``, ``head_norm`` and ``attn_gate`` both attention
+    # kinds' (:class:`Attention`'s ``head_dim``, ``head_norm``, ``gate``),
+    # ``head_shard`` every head-carrying mixer's, ``post_norm`` every
+    # layer's (:class:`MixerLayer`).  ``embed_scale`` multiplies the embedding
+    # rows as they are looked up (a muP model's ``sqrt(d_model)``), patterns
+    # and blocks alike.  Unset, the model is the block above, parameter for
+    # parameter.
     # A pattern trains on one sequence shard and has no cached decode: a
     # recurrent layer's state is no key/value cache.
     layers: Optional[Tuple[str, ...]] = None
@@ -786,6 +886,12 @@ class TransformerLM(nn.Module):
     head_shard: Tuple[int, int] = (0, 1)
     delta: Optional[DeltaConfig] = None
     latent: Optional[LatentConfig] = None
+    head_dim: Optional[int] = None
+    window: Optional[int] = None
+    head_norm: bool = False
+    attn_gate: bool = False
+    post_norm: bool = False
+    embed_scale: Optional[float] = None
 
     @nn.compact
     def __call__(self, tokens, targets=None, decode_ctx=None):
@@ -810,13 +916,16 @@ class TransformerLM(nn.Module):
         with jax.named_scope("hvd_embed"):
             x = nn.Embed(self.vocab_size, self.d_model,
                          dtype=self.dtype, name="embed")(tokens)
+            if self.embed_scale is not None:
+                x = (x * self.embed_scale).astype(self.dtype)
         new_ks, new_vs = [], []
         for i, kind in enumerate(self.layers or ()):
             x = MixerLayer(kind, self.n_heads, self.dtype, self.use_flash,
                            self.moe, self.ssm, self.qk_norm, self.norm_eps,
                            self.n_kv_heads, self.rope, self.head_shard,
-                           self.delta, self.latent, d_ff,
-                           name=f"layer_{i}")(x)
+                           self.delta, self.latent, d_ff, self.head_dim,
+                           self.window, self.head_norm, self.attn_gate,
+                           self.post_norm, name=f"layer_{i}")(x)
         for i in range(0 if self.layers is not None else self.n_layers):
             block = Block(self.n_heads, d_ff, self.dtype, self.seq_axis,
                           self.use_flash, self.ring_impl, self.capture_kv,
@@ -1007,6 +1116,24 @@ def record_expert_rows(intermediates) -> dict:
         _metrics.registry.set_moe_rows(rows, over, walked, forms)
     return {"rows_per_local_expert": rows, "rows_over_bound": over,
             "rows_walked": walked, "way_back": forms}
+
+
+def record_attention_blocks(intermediates) -> dict:
+    """Read what the windowed attention layers wrote to the ``intermediates``
+    collection of one ``apply(..., mutable=["intermediates"])`` — outside the
+    compiled step — and, when the metrics registry is on
+    (``HVD_TPU_METRICS=1``), mirror it into
+    ``hvd.metrics_snapshot()["attention"]``.  Returns ``{"blocks_visited":
+    [the (query block, key block) pairs a head's forward kernel visits, per
+    windowed layer], "blocks_causal": [what the causal kernel would under the
+    same blocks, per layer]}``."""
+    from horovod_tpu.common import metrics as _metrics
+
+    visited = [int(n) for n in _sown(intermediates, "attn_blocks_visited")]
+    causal = [int(n) for n in _sown(intermediates, "attn_blocks_causal")]
+    if _metrics.registry.enabled:
+        _metrics.registry.set_attention_blocks(visited, causal)
+    return {"blocks_visited": visited, "blocks_causal": causal}
 
 
 def router_losses(router):

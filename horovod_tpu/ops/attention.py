@@ -24,6 +24,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -35,8 +36,11 @@ POS_BIG = 1e30   # logsumexp sentinel for fully-masked rows: exp(s - POS_BIG)
 
 
 def mha_reference(q, k, v, causal: bool = False,
-                  sm_scale: Optional[float] = None):
-    """O(seq^2)-memory reference attention (for tests and tiny shapes)."""
+                  sm_scale: Optional[float] = None,
+                  window: Optional[int] = None):
+    """O(seq^2)-memory reference attention (for tests and tiny shapes).
+    ``window`` (with ``causal``): query ``t`` sees the keys ``s`` with
+    ``0 <= t - s < window``, itself and the ``window - 1`` before it."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     # precision="highest": on TPU the default matmul precision truncates f32
@@ -48,6 +52,8 @@ def mha_reference(q, k, v, causal: bool = False,
         q_pos = jnp.arange(q.shape[2])[:, None]
         k_pos = jnp.arange(k.shape[2])[None, :]
         s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        if window is not None:
+            s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
                       precision="highest")
@@ -109,16 +115,18 @@ def _kv_blocks(k, v, block, n_blocks, pad):
     return jnp.moveaxis(kb, -3, 0), jnp.moveaxis(vb, -3, 0)
 
 
-def _block_mask(i, block, q_pos, k_offset, k_len, causal):
+def _block_mask(i, block, q_pos, k_offset, k_len, causal, window=None):
     k_pos = k_offset + i * block + jnp.arange(block)
     mask = (k_pos < k_offset + k_len)[None, :]  # padding rows
     if causal:
         mask = mask & (q_pos[:, None] >= k_pos[None, :])
+    if window is not None:
+        mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
     return mask
 
 
 def _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size, q_offset,
-                        k_offset):
+                        k_offset, window=None):
     """Forward scan; returns (out, lse) with lse the per-row logsumexp."""
     q_len, k_len = q.shape[-2], k.shape[-2]
     block = min(block_size, k_len)
@@ -132,7 +140,7 @@ def _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size, q_offset,
     def step(carry, inputs):
         m, l, acc = carry
         i, kblk, vblk = inputs
-        mask = _block_mask(i, block, q_pos, k_offset, k_len, causal)
+        mask = _block_mask(i, block, q_pos, k_offset, k_len, causal, window)
         m, l, acc = _block_attend(q, kblk, vblk, m, l, acc, mask, sm_scale)
         return (m, l, acc), None
 
@@ -142,7 +150,7 @@ def _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size, q_offset,
 
 
 def _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_size,
-                        q_offset, k_offset):
+                        q_offset, k_offset, window=None):
     """Flash-attention backward: recompute each key block's probabilities
     from (q, k, lse); residual memory O(seq)."""
     q_len, k_len = q.shape[-2], k.shape[-2]
@@ -159,7 +167,7 @@ def _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_size,
         i, kblk, vblk = inputs
         s = jnp.einsum("...qd,...kd->...qk", q, kblk,
                        preferred_element_type=jnp.float32) * sm_scale
-        mask = _block_mask(i, block, q_pos, k_offset, k_len, causal)
+        mask = _block_mask(i, block, q_pos, k_offset, k_len, causal, window)
         s = jnp.where(mask, s, NEG_INF)
         p = jnp.exp(s - lse[..., None])
         p = jnp.where(mask, p, 0.0)
@@ -184,24 +192,26 @@ def _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_size,
             dv[..., :k_len, :].astype(v.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _blockwise(q, k, v, causal, sm_scale, block_size, q_offset, k_offset):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _blockwise(q, k, v, causal, sm_scale, block_size, q_offset, k_offset,
+               window):
     out, _ = _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size,
-                                 q_offset, k_offset)
+                                 q_offset, k_offset, window)
     return out
 
 
 def _blockwise_fwd(q, k, v, causal, sm_scale, block_size, q_offset,
-                   k_offset):
+                   k_offset, window):
     out, lse = _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size,
-                                   q_offset, k_offset)
+                                   q_offset, k_offset, window)
     return out, (q, k, v, out, lse)
 
 
-def _blockwise_bwd(causal, sm_scale, block_size, q_offset, k_offset, res, g):
+def _blockwise_bwd(causal, sm_scale, block_size, q_offset, k_offset, window,
+                   res, g):
     q, k, v, out, lse = res
     return _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale,
-                               block_size, q_offset, k_offset)
+                               block_size, q_offset, k_offset, window)
 
 
 _blockwise.defvjp(_blockwise_fwd, _blockwise_bwd)
@@ -210,8 +220,13 @@ _blockwise.defvjp(_blockwise_fwd, _blockwise_bwd)
 def blockwise_attention(q, k, v, causal: bool = False,
                         sm_scale: Optional[float] = None,
                         block_size: int = 512,
-                        q_offset: int = 0, k_offset: int = 0):
+                        q_offset: int = 0, k_offset: int = 0,
+                        window: Optional[int] = None):
     """Memory-efficient attention as a `lax.scan` over key/value blocks.
+
+    ``window`` (with ``causal``): the sliding window of
+    :func:`flash_attention`, here a mask over the same scan (every block is
+    still walked: the CPU path and the tests use this one).
 
     ``q_offset``/``k_offset`` give the global sequence positions of the
     first query/key row — this is what lets :func:`ring_attention` apply a
@@ -222,16 +237,28 @@ def blockwise_attention(q, k, v, causal: bool = False,
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    window = _checked_window(window, causal, None)
     try:
         q_offset, k_offset = int(q_offset), int(k_offset)
     except (TypeError, jax.errors.ConcretizationTypeError):
         # Traced offsets can't be custom_vjp static args; keep the plain
         # (through-scan) differentiable path for this corner.
         out, _ = _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size,
-                                     q_offset, k_offset)
+                                     q_offset, k_offset, window)
         return out
     return _blockwise(q, k, v, causal, sm_scale, block_size, q_offset,
-                      k_offset)
+                      k_offset, window)
+
+
+def _checked_window(window, causal, k_len):
+    """``window`` as the kernels take it: None where it hides no key a causal
+    mask shows (``window >= k_len``: the causal program itself)."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(f"window={window!r} wants causal=True and at least "
+                         "one key (the query's own)")
+    return None if k_len is not None and window >= k_len else int(window)
 
 
 # ---------------------------------------------------------------------------
@@ -264,9 +291,55 @@ def _split_scale(sm_scale: float):
     return 2.0 ** (e - 1), m * 2.0
 
 
+def _on_host(block):
+    """The band's arithmetic runs on traced block indices inside a kernel or
+    an index map, and on numpy integers where blocks are counted
+    (`window_blocks`, the grids' extents)."""
+    return isinstance(block, (int, np.integer, np.ndarray))
+
+
+def _div(a, b):
+    # Traced block indices are never negative: lax.div spares the scalar
+    # core floor_divide's sign fix-up.
+    return a // b if _on_host(a) else lax.div(a, b)
+
+
+def _keys_of_query_block(qi, block_q, block_k, window):
+    """(first, last) key block that holds a key some query of block ``qi``
+    sees under a window: queries ``[qi bq, (qi+1) bq)``, keys ``s`` with
+    ``0 <= t - s < window``."""
+    xp = np if _on_host(qi) else jnp
+    return (_div(xp.maximum(qi * block_q - (window - 1), 0), block_k),
+            _div(qi * block_q + block_q - 1, block_k))
+
+
+def _queries_of_key_block(ki, block_q, block_k, window, num_q):
+    """(first, last) query block that holds a query seeing some key of
+    block ``ki`` under a window."""
+    xp = np if _on_host(ki) else jnp
+    return (_div(ki * block_k, block_q),
+            xp.minimum(_div(ki * block_k + block_k + window - 2, block_q),
+                       num_q - 1))
+
+
+def _band_steps(first_last) -> int:
+    """The most blocks any one block's band holds: the banded grid's inner
+    extent."""
+    first, last = first_last
+    return int((last - first).max()) + 1
+
+
+def _band_mask(q_start, k_start, block_q, block_k, window):
+    """True where key ``s`` of the block is in query ``t``'s window."""
+    diff = (q_start - k_start) + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0) - jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    return (diff >= 0) & (diff < window)
+
+
 def _attend_block(q_ref, k_ref, v_ref, m_scratch, l_scratch, acc_scratch,
                   q_start, k_start, causal, block_q, block_k,
-                  single_k=False, scale_r=1.0):
+                  single_k=False, scale_r=1.0, window=None):
     """One online-softmax block update of the VMEM (m, l, acc) state.
 
     Shared by the single-shard flash kernel and the fused ring-flash step
@@ -293,7 +366,10 @@ def _attend_block(q_ref, k_ref, v_ref, m_scratch, l_scratch, acc_scratch,
         preferred_element_type=jnp.float32)
     if scale_r != 1.0:
         s *= scale_r
-    if causal:
+    if window is not None:
+        s = jnp.where(_band_mask(q_start, k_start, block_q, block_k, window),
+                      s, NEG_INF)
+    elif causal:
         q_pos = q_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
         k_pos = k_start + jax.lax.broadcasted_iota(
@@ -346,7 +422,11 @@ def _finalize_flash(o_ref, lse_ref, m_scratch, l_scratch, acc_scratch,
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch,
                   acc_scratch, *, causal, block_q, block_k, num_k_blocks,
-                  scale_r=1.0):
+                  scale_r=1.0, window=None):
+    """With a ``window`` the grid's key axis walks only query block ``qi``'s
+    band: ``num_k_blocks`` is the band's steps, step ``ki`` is key block
+    ``first + ki``, and the steps past the band's last block do nothing (the
+    index maps hold them on that block, so nothing is copied either)."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     single_k = num_k_blocks == 1
@@ -357,16 +437,21 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch,
             _init_state(m_scratch, l_scratch, acc_scratch)
 
     q_start = qi * block_q
-    k_start = ki * block_k
-    # Causal pruning: skip key blocks entirely above the diagonal.
-    run = True if not causal else k_start <= q_start + block_q - 1
+    if window is None:
+        k_start = ki * block_k
+        # Causal pruning: skip key blocks entirely above the diagonal.
+        run = True if not causal else k_start <= q_start + block_q - 1
+    else:
+        first, last = _keys_of_query_block(qi, block_q, block_k, window)
+        k_start = (first + ki) * block_k
+        run = first + ki <= last
 
     @pl.when(run)
     def _():
         _attend_block(q_ref, k_ref, v_ref, m_scratch, l_scratch,
                       acc_scratch, q_start, k_start, causal,
                       block_q, block_k, single_k=single_k,
-                      scale_r=scale_r)
+                      scale_r=scale_r, window=window)
 
     @pl.when(ki == num_k_blocks - 1)
     def _():
@@ -375,7 +460,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch,
 
 
 def _bwd_block_math(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                    causal, q_start, k_start, block_q, block_k, scale_r):
+                    causal, q_start, k_start, block_q, block_k, scale_r,
+                    window=None):
     """Shared flash-backward block recompute (Dao et al. alg. 2 inner
     body), used by the combined kernel, both split kernels, and the fused
     ring backward (ops/ring_flash.py).
@@ -401,7 +487,10 @@ def _bwd_block_math(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         preferred_element_type=jnp.float32)
     if scale_r != 1.0:
         s *= scale_r
-    if causal:
+    if window is not None:
+        s = jnp.where(_band_mask(q_start, k_start, block_q, block_k, window),
+                      s, NEG_INF)
+    elif causal:
         q_pos = q_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
         k_pos = k_start + jax.lax.broadcasted_iota(
@@ -419,10 +508,13 @@ def _bwd_block_math(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
 def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                            dk_ref, dv_ref, dk_scratch, dv_scratch, *,
-                           causal, block_q, block_k, num_q_blocks, scale_r):
+                           causal, block_q, block_k, num_q_blocks, scale_r,
+                           band=None):
     """Split backward, dk/dv half: O(block) scoped memory — the long-seq
     path where the combined kernel's whole-seq dq scratch exceeds the
-    chip's scoped-VMEM ceiling (see _bwd_plan)."""
+    chip's scoped-VMEM ceiling (see _bwd_plan).  ``band=(window, query
+    blocks of the sequence)``: the inner axis walks key block ``ki``'s band
+    of ``num_q_blocks`` steps, as `_flash_kernel`'s does."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)  # innermost: accumulates over query blocks
 
@@ -431,15 +523,22 @@ def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dk_scratch[...] = jnp.zeros_like(dk_scratch)
         dv_scratch[...] = jnp.zeros_like(dv_scratch)
 
-    q_start = qi * block_q
-    k_start = ki * block_k
-    run = True if not causal else q_start + block_q - 1 >= k_start
+    if band is None:
+        q_start = qi * block_q
+        k_start = ki * block_k
+        run = True if not causal else q_start + block_q - 1 >= k_start
+    else:
+        first, last = _queries_of_key_block(ki, block_q, block_k, *band)
+        q_start = (first + qi) * block_q
+        k_start = ki * block_k
+        run = first + qi <= last
 
     @pl.when(run)
     def _():
         pb, ds, q, do, _k = _bwd_block_math(
             q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, causal,
-            q_start, k_start, block_q, block_k, scale_r)
+            q_start, k_start, block_q, block_k, scale_r,
+            window=band and band[0])
         dv_scratch[...] += jax.lax.dot_general(
             pb, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -455,9 +554,11 @@ def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
 def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                          dq_ref, dq_scratch, *, causal, block_q,
-                         block_k, num_k_blocks, scale_r, dq_scale=1.0):
+                         block_k, num_k_blocks, scale_r, dq_scale=1.0,
+                         window=None):
     """Split backward, dq half: accumulates one query block over the key
-    loop — O(block) scoped memory (long-seq path, see _bwd_plan)."""
+    loop — O(block) scoped memory (long-seq path, see _bwd_plan).  With a
+    ``window`` the key loop is the band's ``num_k_blocks`` steps."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)  # innermost: accumulates over key blocks
 
@@ -466,14 +567,19 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dq_scratch[...] = jnp.zeros_like(dq_scratch)
 
     q_start = qi * block_q
-    k_start = ki * block_k
-    run = True if not causal else q_start + block_q - 1 >= k_start
+    if window is None:
+        k_start = ki * block_k
+        run = True if not causal else q_start + block_q - 1 >= k_start
+    else:
+        first, last = _keys_of_query_block(qi, block_q, block_k, window)
+        k_start = (first + ki) * block_k
+        run = first + ki <= last
 
     @pl.when(run)
     def _():
         _pb, ds, _q, _do, k = _bwd_block_math(
             q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, causal,
-            q_start, k_start, block_q, block_k, scale_r)
+            q_start, k_start, block_q, block_k, scale_r, window=window)
         dq_scratch[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -488,7 +594,7 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
 def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
                          num_k_blocks, bh, rotate, barrier, axis_name,
-                         mesh_axes, scale_r, dq_scale=1.0):
+                         mesh_axes, scale_r, dq_scale=1.0, band=None):
     """Flash backward with dk/dv AND dq from ONE probability recompute.
 
     Grid: (bh, ki, qi) — queries innermost so dk/dv accumulate in scratch
@@ -504,6 +610,10 @@ def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
     [q_offset, k_offset] for causal masking across shards (zeros for the
     single-shard case).  ``q`` arrives pre-scaled by the pow2 part of
     sm_scale; dq is emitted in q' units (callers rescale once).
+
+    ``band=(window, query blocks of the sequence)``: the inner axis walks key
+    block ``ki``'s band in ``num_q_blocks`` steps, as `_flash_kernel`'s does
+    (single shard only: the ring's offsets move the band between devices).
     """
     if rotate:
         (offsets_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
@@ -552,10 +662,15 @@ def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
         dk_scratch[...] = jnp.zeros_like(dk_scratch)
         dv_scratch[...] = jnp.zeros_like(dv_scratch)
 
+    q_block = qi
+    if band is not None:
+        first, last = _queries_of_key_block(ki, block_q, block_k, *band)
+        q_block = first + qi
     if causal:
-        q_start = offsets_ref[0] + qi * block_q  # absolute positions
+        q_start = offsets_ref[0] + q_block * block_q  # absolute positions
         k_start = offsets_ref[1] + ki * block_k
-        run = q_start + block_q - 1 >= k_start
+        run = q_start + block_q - 1 >= k_start if band is None \
+            else q_block <= last
     else:
         q_start = k_start = 0
         run = True
@@ -564,14 +679,15 @@ def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
     def _():
         pb, ds, q, do, k = _bwd_block_math(
             q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, causal,
-            q_start, k_start, block_q, block_k, scale_r)
+            q_start, k_start, block_q, block_k, scale_r,
+            window=band and band[0])
         dv_scratch[...] += jax.lax.dot_general(
             pb, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dk_scratch[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        row = pl.ds(qi * block_q, block_q)
+        row = pl.ds(q_block * block_q, block_q)
         dq_scratch[row, :] = dq_scratch[row, :] + jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -613,7 +729,7 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
                        k_offset, *, causal, block_q, block_k, rotate,
                        collective_id, axis_name, mesh_axes, interpret,
                        scale_r=1.0, grad_dtype=jnp.float32, dq_scale=1.0,
-                       name="hvd_flash_bwd"):
+                       name="hvd_flash_bwd", window=None):
     """pallas_call wrapper for `_combined_bwd_kernel` over (bh, sl, d)
     operands (q pre-scaled by the pow2 part of sm_scale; ``do`` and ``v_cur``
     may have another width than ``q`` and ``k_cur``, and ``dv`` then has
@@ -621,19 +737,30 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
     (dk, dv, dq[, k_next, v_next]) with the gradients in ``grad_dtype``
     (accumulation is always f32 in scratch; only the flush casts, after
     applying ``dq_scale`` to dq in f32).  ``name`` is the kernel's name in
-    a device trace: the fused ring's backward step passes its own."""
+    a device trace: the fused ring's backward step passes its own.  With a
+    ``window`` (no rotation) the grid's query axis is the band's steps."""
     bh, sl, d = q.shape
     d_v = v_cur.shape[-1]
     num_q, num_k = sl // block_q, sl // block_k
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(k_offset, jnp.int32)])
+    inner_q = lambda qi, ki: qi  # noqa: E731
+    q_steps, band = num_q, None
+    if window is not None:
+        band = (window, num_q)
+        q_steps = _band_steps(_queries_of_key_block(
+            np.arange(num_k), block_q, block_k, *band))
+
+        def inner_q(qi, ki):
+            first, last = _queries_of_key_block(ki, block_q, block_k, *band)
+            return jnp.minimum(first + qi, last)
 
     kernel = functools.partial(
         _combined_bwd_kernel, causal=causal, block_q=block_q,
-        block_k=block_k, num_q_blocks=num_q, num_k_blocks=num_k, bh=bh,
+        block_k=block_k, num_q_blocks=q_steps, num_k_blocks=num_k, bh=bh,
         rotate=rotate, barrier=rotate and not interpret,
         axis_name=axis_name, mesh_axes=mesh_axes, scale_r=scale_r,
-        dq_scale=dq_scale)
+        dq_scale=dq_scale, band=band)
 
     def qspec(row, width=d):
         return pl.BlockSpec((1, block_q, width),
@@ -643,13 +770,14 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
         return pl.BlockSpec((1, block_k, width),
                             lambda b, ki, qi, s, _r=row: (b, _r(qi, ki), 0))
 
-    inner_q = lambda qi, ki: qi  # noqa: E731
     outer_k = lambda qi, ki: ki  # noqa: E731
     in_specs = [
         qspec(inner_q),                                    # q
         qspec(inner_q, d_v),                               # do
-        pl.BlockSpec((1, 8, block_q), lambda b, ki, qi, s: (b, 0, qi)),
-        pl.BlockSpec((1, 8, block_q), lambda b, ki, qi, s: (b, 0, qi)),
+        pl.BlockSpec((1, 8, block_q),
+                     lambda b, ki, qi, s: (b, 0, inner_q(qi, ki))),
+        pl.BlockSpec((1, 8, block_q),
+                     lambda b, ki, qi, s: (b, 0, inner_q(qi, ki))),
         kspec(outer_k),                                    # k (blocked)
         kspec(outer_k, d_v),                               # v (blocked)
     ]
@@ -689,7 +817,7 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
                   for s in out_shapes]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(bh, num_k, num_q),
+        grid=(bh, num_k, q_steps),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
@@ -905,14 +1033,15 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
 
 def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
                     block_k, interpret, scale_r, grad_dtype=jnp.float32,
-                    dq_scale=1.0):
+                    dq_scale=1.0, window=None):
     """Split flash backward over (bh, sl, d) operands (q pre-scaled by
     the pow2 part of sm_scale): two pallas_calls — dk/dv (queries inner)
     and dq (keys inner) — each with O(block) scoped VMEM, so any
     sequence length compiles.  Pays the s/p/dp/ds recompute twice; the
     combined kernel is preferred whenever its whole-seq dq scratch fits
     (see _bwd_plan).  Returns (dk, dv, dq) in ``grad_dtype`` (f32
-    accumulation in scratch; the flush casts)."""
+    accumulation in scratch; the flush casts).  With a ``window`` each
+    kernel's inner axis is its band's steps (`_flash_kernel`)."""
     bh, sl, d = q.shape
     d_v = v.shape[-1]              # do, v and dv; q, k, dq and dk have d
     num_q, num_k = sl // block_q, sl // block_k
@@ -923,8 +1052,23 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
         return pl.BlockSpec((1, 8, block_q), lambda b, i, j, _r=row:
                             (b, 0, _r(i, j)))
 
-    inner = lambda i, j: j  # noqa: E731  (innermost grid dim)
+    inner_q = inner_k = lambda i, j: j  # noqa: E731  (innermost grid dim)
     outer = lambda i, j: i  # noqa: E731
+    q_steps, k_steps, band, suffix = num_q, num_k, None, ""
+    if window is not None:
+        band, suffix = (window, num_q), "_window"
+        q_steps = _band_steps(_queries_of_key_block(
+            np.arange(num_k), block_q, block_k, *band))
+        k_steps = _band_steps(_keys_of_query_block(
+            np.arange(num_q), block_q, block_k, window))
+
+        def inner_q(ki, j):
+            first, last = _queries_of_key_block(ki, block_q, block_k, *band)
+            return jnp.minimum(first + j, last)
+
+        def inner_k(qi, j):
+            first, last = _keys_of_query_block(qi, block_q, block_k, window)
+            return jnp.minimum(first + j, last)
     # vma: inside shard_map (build_train_step) the default check refuses
     # an out_shape that does not say how it varies; as q does.
     grad_shape = jax.ShapeDtypeStruct((bh, sl, d), grad_dtype,
@@ -933,39 +1077,39 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
                                     vma=jax.typeof(q).vma)
     dkdv = functools.partial(
         _flash_bwd_dkdv_kernel, causal=causal, block_q=block_q,
-        block_k=block_k, num_q_blocks=num_q, scale_r=scale_r)
+        block_k=block_k, num_q_blocks=q_steps, scale_r=scale_r, band=band)
     dk, dv = pl.pallas_call(
         dkdv,
-        grid=(bh, num_k, num_q),  # queries innermost
-        in_specs=[qspec(inner), dospec(inner), lse_spec(inner),
-                  lse_spec(inner), kspec(outer), vspec(outer)],
+        grid=(bh, num_k, q_steps),  # queries innermost
+        in_specs=[qspec(inner_q), dospec(inner_q), lse_spec(inner_q),
+                  lse_spec(inner_q), kspec(outer), vspec(outer)],
         out_specs=(kspec(outer), vspec(outer)),
         out_shape=(grad_shape, dv_shape),
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d_v), jnp.float32)],
         interpret=interpret,
-        name="hvd_flash_bwd_dkdv",
+        name="hvd_flash_bwd_dkdv" + suffix,
     )(q, do, lse8, delta8, k, v)
     dqk = functools.partial(
         _flash_bwd_dq_kernel, causal=causal, block_q=block_q,
-        block_k=block_k, num_k_blocks=num_k, scale_r=scale_r,
-        dq_scale=dq_scale)
+        block_k=block_k, num_k_blocks=k_steps, scale_r=scale_r,
+        dq_scale=dq_scale, window=window)
     dq = pl.pallas_call(
         dqk,
-        grid=(bh, num_q, num_k),  # keys innermost
+        grid=(bh, num_q, k_steps),  # keys innermost
         in_specs=[qspec(outer), dospec(outer), lse_spec(outer),
-                  lse_spec(outer), kspec(inner), vspec(inner)],
+                  lse_spec(outer), kspec(inner_k), vspec(inner_k)],
         out_specs=qspec(outer),
         out_shape=grad_shape,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-        name="hvd_flash_bwd_dq",
+        name="hvd_flash_bwd_dq" + suffix,
     )(q, do, lse8, delta8, k, v)
     return dk, dv, dq
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
-                    block_k, interpret):
+                    block_k, interpret, window=None):
     """Pallas flash backward.  Two kernel strategies, chosen per shape by
     :func:`_bwd_plan` against the scoped-VMEM ceiling: the combined
     kernel computes dk/dv AND dq from a single probability recompute per
@@ -979,7 +1123,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
     if (q_len % block_q or k_len % block_k
             or block_q % 128 or block_k % 128 or q_len != k_len):
         return _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale,
-                                   max(block_k, 128), 0, 0)
+                                   max(block_k, 128), 0, 0, window)
     # One width: the call as every caller and test stand-in has known it.
     widths = {} if d_v == d else {"d_v": d_v}
     mode, block_q, block_k = _bwd_plan(q_len, d, block_q, block_k,
@@ -988,7 +1132,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
         # Plan stepped blocks down past what divides this length (rare
         # non-power-of-two long seqs): the scan impl handles it.
         return _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale,
-                                   max(block_k, 128), 0, 0)
+                                   max(block_k, 128), 0, 0, window)
     bh = batch * heads
     # Pre-scaled q (see _flash_forward): exact pow2 factor on q, f32
     # residual inside the kernel; dq comes back in q' units and is
@@ -1020,37 +1164,52 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
             block_q=block_q, block_k=block_k, rotate=False,
             collective_id=None, axis_name=None, mesh_axes=(),
             interpret=interpret, scale_r=scale_r, grad_dtype=grad_dtype,
-            dq_scale=p2)
+            dq_scale=p2, window=window,
+            # A banded call's name keeps the prefix a trace is read by.
+            name="hvd_flash_bwd" + ("" if window is None else "_window"))
     else:
         dk, dv, dq = _split_bwd_call(
             qr, dor, lse8, delta8, kr, vr, causal=causal,
             block_q=block_q, block_k=block_k, interpret=interpret,
-            scale_r=scale_r, grad_dtype=grad_dtype, dq_scale=p2)
+            scale_r=scale_r, grad_dtype=grad_dtype, dq_scale=p2,
+            window=window)
     return (dq.astype(q.dtype).reshape(q.shape),
             dk.astype(k.dtype).reshape(k.shape),
             dv.astype(v.dtype).reshape(v.shape))
 
 
-def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    """Returns (out, lse); routes off-grid shapes to the blockwise impl."""
-    batch, heads, q_len, d = q.shape
-    k_len, d_v = k.shape[2], v.shape[-1]
+def _forward_blocks(q_len, k_len, d, d_v, block_q, block_k, window=None):
+    """The (block_q, block_k) the forward kernel runs ``flash_attention``'s
+    blocks at, or None where the shape leaves the kernel for the scan."""
     block_q = min(block_q, q_len)
     block_k = min(block_k, k_len)
     if (q_len % block_q or k_len % block_k
-            or block_q % 128 or block_k % 128):
+            or block_q % 128 or block_k % 128
+            or (window is not None and q_len != k_len)):
         # Ragged tails or blocks off the TPU tiling grid (the lse output
         # block puts block_q in the 128-lane dimension): the blockwise path
         # handles them without padding gymnastics (the kernel targets the
         # aligned hot path).
-        return _blockwise_fwd_impl(q, k, v, causal, sm_scale,
-                                   max(block_k, 128), 0, 0)
+        return None
     # Backstop explicit oversized blocks against the scoped-VMEM budget
     # (the default <=1024 blocks peak ~6 MiB and never clamp).
-    block_q, block_k = _clamp_blocks(
+    return _clamp_blocks(
         "forward", q_len, d, block_q, block_k,
         estimate=lambda _m, s, dd, bq, bk: _fwd_vmem_bytes(s, dd, bq, bk,
                                                            d_v))
+
+
+def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                   window=None):
+    """Returns (out, lse); routes off-grid shapes to the blockwise impl."""
+    batch, heads, q_len, d = q.shape
+    k_len, d_v = k.shape[2], v.shape[-1]
+    blocks = _forward_blocks(q_len, k_len, d, d_v, block_q, block_k, window)
+    if blocks is None:
+        return _blockwise_fwd_impl(q, k, v, causal, sm_scale,
+                                   max(min(block_k, k_len), 128), 0, 0,
+                                   window)
+    block_q, block_k = blocks
     bh = batch * heads
     # Pre-scale q by the exact power-of-two part of sm_scale: one
     # (seq, d) multiply here replaces a (seq, seq) pass inside the
@@ -1067,10 +1226,21 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     ospec, vspec = _row_spec(block_q, d_v), _row_spec(block_k, d_v)
     qrow = lambda i, j: i  # noqa: E731
     krow = lambda i, j: j  # noqa: E731
+    name = "hvd_flash_fwd"
+    if window is not None:
+        # The key axis is the band's steps; past a band's last block the
+        # index stays on it, and an index that does not move copies nothing.
+        num_k = _band_steps(_keys_of_query_block(
+            np.arange(num_q), block_q, block_k, window))
+        name = "hvd_flash_fwd_window"
+
+        def krow(i, j):
+            first, last = _keys_of_query_block(i, block_q, block_k, window)
+            return jnp.minimum(first + j, last)
 
     kernel = functools.partial(
         _flash_kernel, causal=causal, block_q=block_q,
-        block_k=block_k, num_k_blocks=num_k, scale_r=scale_r)
+        block_k=block_k, num_k_blocks=num_k, scale_r=scale_r, window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, num_q, num_k),
@@ -1089,28 +1259,30 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, d_v), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
-        name="hvd_flash_fwd",
+        name=name,
     )(qr, kr, vr)
     return (out.reshape(batch, heads, q_len, d_v),
             lse[:, 0, :].reshape(batch, heads, q_len))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attention(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_attention(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                     window):
     return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                          interpret)[0]
+                          interpret, window)[0]
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+               window):
     out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                              interpret)
+                              interpret, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window, res, g):
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
-                           block_k, interpret)
+                           block_k, interpret, window)
 
 
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
@@ -1121,8 +1293,24 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    layout: str = "bhsd"):
+                    layout: str = "bhsd",
+                    window: Optional[int] = None):
     """Fused multi-head attention.
+
+    ``window=W`` (with ``causal=True``): a sliding window — query ``t`` sees
+    the keys ``s`` with ``0 <= t - s < W``, itself and the ``W - 1`` before
+    it.  A (query block, key block) pair wholly outside that band is neither
+    computed nor fetched, forward or backward: each kernel's inner grid axis
+    is as long as the widest band of blocks (``W`` over the block, plus the
+    blocks the band's two edges cut) and its index maps walk the band, holding
+    the index still where a band is shorter — an index that does not move
+    issues no copy.  The blocks the edges cut are masked inside.  ``W`` need
+    not divide by the block; ``W >= seq`` IS the causal call, program for
+    program.  The banded kernels are named ``hvd_flash_fwd_window``,
+    ``hvd_flash_bwd_window``, ``hvd_flash_bwd_dkdv_window`` and
+    ``hvd_flash_bwd_dq_window`` in a trace; the backward takes the plan
+    :func:`_bwd_plan` gives the shape (a band needs no more VMEM), and
+    :func:`window_blocks` counts what the forward visits.
 
     ``v``'s last axis may differ from ``q``'s and ``k``'s (latent attention:
     a 192-wide query and key, a 128-wide value); the output has ``v``'s, and
@@ -1160,31 +1348,62 @@ def flash_attention(q, k, v, causal: bool = False,
         t = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
         return t(flash_attention(t(q), t(k), t(v), causal=causal,
                                  sm_scale=sm_scale, block_q=block_q,
-                                 block_k=block_k, interpret=interpret))
+                                 block_k=block_k, interpret=interpret,
+                                 window=window))
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    window = _checked_window(window, causal, k.shape[-2])
     if not interpret and jnp.float16 in (q.dtype, k.dtype, v.dtype):
         # float16 is not a native TPU type and Mosaic refuses the kernel
         # outright (verified on v5e: even the forward fails to compile) —
         # route to the mathematically identical scan implementation
         # instead of crashing at compile time.  bf16 is the supported
         # half-precision on TPU.
-        return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+        return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale,
+                                   window=window)
     # An explicit block past _MAX_BLOCK is cut to it as the default is:
     # the chip's compiler refuses both passes there (scoped VMEM: 2048-row
     # blocks at seq 2048 fail the backward, 4096 at seq 4096 the forward —
     # compiled for a described v5e, tests/test_ops.py), whatever the
     # structural estimates say (ADVICE r5 #2).
+    block_q, block_k = _default_blocks(q.shape[-2], k.shape[-2], block_q,
+                                       block_k)
+    return _flash_attention(q, k, v, causal, sm_scale, block_q, block_k,
+                            interpret, window)
+
+
+def _default_blocks(q_len, k_len, block_q=None, block_k=None):
     if block_q is None or block_q > _MAX_BLOCK:
         # 1024-row query blocks: a grid step has a fixed cost, so the
         # largest block that compiles does the fewest of them (bundles
         # per pair by block shape: PERF.md section 7).
-        block_q = _pick_block(q.shape[-2], maximum=_MAX_BLOCK)
+        block_q = _pick_block(q_len, maximum=_MAX_BLOCK)
     if block_k is None or block_k > _MAX_BLOCK:
         # Whole-k key blocks skip the online-softmax rescale entirely
         # (the kernel's single_k fast path) and the backward's key loop.
-        block_k = _pick_block(k.shape[-2], maximum=_MAX_BLOCK)
-    return _flash_attention(q, k, v, causal, sm_scale, block_q, block_k,
-                            interpret)
+        block_k = _pick_block(k_len, maximum=_MAX_BLOCK)
+    return block_q, block_k
+
+
+def window_blocks(seq: int, window: int, d: int, d_v: Optional[int] = None,
+                  block_q: Optional[int] = None,
+                  block_k: Optional[int] = None):
+    """(visited, causal): the (query block, key block) pairs one head's
+    FORWARD kernel visits for ``flash_attention(causal=True, window=window)``
+    at ``seq`` rows of width ``d`` under the blocks it takes, and the pairs
+    the causal kernel visits under the same blocks — 21 and 36 at 8,192 rows
+    with a window of 2,048 in 1,024-blocks, 70 and 136 in 512-blocks.  None
+    where the call leaves the kernel for the scan, which walks every block."""
+    blocks = _forward_blocks(seq, seq, d, d_v or d,
+                             *_default_blocks(seq, seq, block_q, block_k))
+    if blocks is None:
+        return None
+    rows = np.arange(seq // blocks[0])
+    causal = int((_div(rows * blocks[0] + blocks[0] - 1, blocks[1])
+                  + 1).sum())
+    if _checked_window(window, True, seq) is None:
+        return causal, causal
+    first, last = _keys_of_query_block(rows, *blocks, window)
+    return int((last - first + 1).sum()), causal

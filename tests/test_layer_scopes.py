@@ -31,6 +31,8 @@ CELLS = {
         "head", "embed", "attn_proj", "moe", "ssm"},
     "ling3flash_1chip_tp8ep64share_1x8k": {
         "head", "embed", "mlp", "moe", "kda", "mla"},
+    "trinitymini_1chip_ep8share_1x8k": {
+        "head", "embed", "mlp", "attn_proj", "moe"},
 }
 
 _ALIAS = re.compile(r'^(#loc\d*) = loc\((.*)\)$')

@@ -739,6 +739,90 @@ def test_ling_step_is_products_kernels_and_one_loop_a_pass(v5e, monkeypatch):
         assert 2 <= fusions <= 5, (body, fusions)
 
 
+@pytest.mark.parametrize("plan", ["combined", "split"])
+def test_banded_flash_at_trinity_shape_compiles(v5e, monkeypatch, plan):
+    """The trinitymini cell's windowed layers: 1 x 32 heads of 128 at 8,192
+    rows under a window of 2,048.  The banded forward (1,024-blocks, a band of
+    3 key blocks) and the combined backward the plan gives the shape
+    ((512, 512), a band of 5), and the split pair at 1,024-blocks, compile for
+    the described chip: index maps that divide and clamp, grids as long as the
+    band."""
+    import horovod_tpu.ops.attention as attn
+
+    assert attn._bwd_plan(8192, 128, 1024, 1024, 32) == ("combined", 512, 512)
+    if plan == "split":
+        monkeypatch.setattr(attn, "_bwd_plan",
+                            lambda q_len, d, bq, bk, bh=1: ("split", bq, bk))
+    text = _compile_flash_grad(v5e[0], (1, 32, 8192, 128), window=2048)
+    names = {"combined": ("hvd_flash_fwd_window", "hvd_flash_bwd_window"),
+             "split": ("hvd_flash_fwd_window", "hvd_flash_bwd_dkdv_window",
+                       "hvd_flash_bwd_dq_window")}[plan]
+    # Outside a layer's scope the instruction is named after the whole path
+    # (`%jvp_hvd_flash_fwd_window_.1`).
+    for kernel in names:
+        assert len(re.findall(rf"%\w*?_{kernel}_*\.\d+ = ", text)) == 1, kernel
+    assert text.count('"tpu_custom_call"') == len(names)
+
+
+def test_trinity_step_is_banded_and_causal_kernels_and_a_named_gate(
+        v5e, monkeypatch):
+    """A windowed layer, a dense gated MLP, a full layer and sigmoid-routed
+    experts with a shared one at Trinity-Mini's per-head widths (heads of 128
+    on 2 key/value heads, the gate, the per-head norms, the post-norms, the
+    embedding multiplier) through `build_train_step`, compiled for the
+    described chip: the windowed layer's kernels are the banded ones, the
+    full layer's the causal ones, no loop, and the gate's scope is in the
+    text forward and backward beside the other scopes of `Attention`."""
+    import optax
+    from jax.sharding import NamedSharding
+
+    from horovod_tpu.jax.train import build_train_step
+    from horovod_tpu.models import MoEConfig, TransformerLM, next_token_loss
+    from horovod_tpu.parallel import data_parallel_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(
+        vocab_size=2048, d_model=512, n_heads=8, d_ff=1024,
+        dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16, use_flash=True,
+        norm_eps=1e-5,
+        layers=("window_attention", "gated_mlp", "attention", "experts"),
+        n_kv_heads=2, rope=False, head_dim=128, window=512, head_norm=True,
+        attn_gate=True, post_norm=True, embed_scale=512 ** 0.5,
+        moe=MoEConfig(64, 8, 256, (0, 8), 1.5, "sigmoid", True, 2.826,
+                      shared_width=256))
+    mesh = data_parallel_mesh(v5e[:1], axis_name="hvd")
+    tx = optax.adamw(1e-4)
+
+    def loss_fn(params, batch):
+        return next_token_loss(model.apply({"params": params}, batch[0]),
+                               batch[1])
+
+    def init(key):
+        params = model.init(key, jnp.zeros((1, 128), jnp.int32))["params"]
+        return params, tx.init(params)
+
+    def shaped(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+
+    params, opt_state = shaped(jax.eval_shape(init, jax.random.PRNGKey(0)),
+                               P())
+    tokens = shaped(jax.ShapeDtypeStruct((1, 2048), jnp.int32), P("hvd"))
+    step = build_train_step(loss_fn, tx, mesh, axis_name="hvd")
+    text = step.lower(params, opt_state,
+                      (tokens, tokens)).compile().as_text()
+    assert len(re.findall(r"\bwhile\(", text)) == 0
+    for kernel in ("hvd_flash_fwd_window", "hvd_flash_bwd_window",
+                   "hvd_flash_fwd", "hvd_flash_bwd"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 9
+    _assert_scopes_forward_and_backward(
+        text, ("hvd_attn_qkv", "hvd_attn_attend", "hvd_attn_gate",
+               "hvd_attn_out", "hvd_mlp", "hvd_moe_router", "hvd_moe_shared",
+               "hvd_embed", "hvd_lm_head"))
+
+
 @pytest.mark.parametrize("mode", ["combined", "split"])
 def test_flash_inside_shard_map_default_vma_check(monkeypatch, mode):
     """flash_attention is called inside build_train_step's shard_map, whose

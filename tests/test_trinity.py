@@ -1,0 +1,520 @@
+"""What Trinity-Mini adds — a sliding window in the flash kernels that skips
+the blocks outside its band, `Attention` with a head width of its own, a
+per-head q/k norm, an output gate and a window, a windowed layer kind beside
+the full one, a post-norm in `MixerLayer`, an embedding multiplier — against
+the plain float32 reference the benchmark keeps
+(benchmark/reference/trinity_lm.py): a dense softmax under an explicit band
+mask, a head at a time, the key/value head by index, a loop over the shard's
+experts.  CPU, float32, seeded weights, small sizes; the kernels interpreted.
+
+Tolerances: both sides are float32 and differ in the order of their sums
+(online softmax over blocks against whole rows, grouped rows against masked
+whole batches), so they agree to float32 rounding accumulated over a few
+layers: 2e-5 of the largest value, 1e-4 for the whole model's gradients.
+bfloat16 anywhere would read 1e-3 to 1e-2 and fail every case.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+
+import horovod_tpu.ops.attention as attn
+from benchmark.reference import compare, trinity_lm as reference
+from horovod_tpu.common import metrics
+from horovod_tpu.jax.train import build_train_step
+from horovod_tpu.models import (MoEConfig, TransformerLM,
+                                record_attention_blocks)
+from horovod_tpu.models.transformer import (LAYER_KINDS, Attention,
+                                            MixerLayer, SparseExperts)
+from horovod_tpu.ops import (blockwise_attention, flash_attention,
+                             mha_reference)
+from horovod_tpu.ops.attention import window_blocks
+from tests.test_hybrid import (both_ways, close, mixer_case, seeded,
+                               system_loss, trees_close, with_highest)
+from tests.test_ops import _pallas_call_names
+
+RTOL = 2e-5
+VOCAB, HIDDEN, SEQ, HEADS, KV_HEADS, HEAD_DIM, D_FF = 256, 64, 128, 8, 2, 16, 96
+WINDOW, THETA, EPS = 32, 10000.0, 1e-5
+EXPERTS, PER_TOKEN, WIDTH, SCALE = 16, 4, 48, 2.826
+# A published layer is attention and then an MLP or the experts: the leading
+# dense layer, two windowed layers and a full one.
+LAYERS = ("window_attention", "gated_mlp", "window_attention", "experts",
+          "window_attention", "experts", "attention", "experts")
+
+
+def moe(shard=(0, 1), row_bound=None, experts=EXPERTS):
+    return MoEConfig(experts, PER_TOKEN, WIDTH, shard, row_bound, "sigmoid",
+                     True, SCALE, shared_width=WIDTH)
+
+
+def lm(expert_shard=(0, 1), use_flash=False, vocab=VOCAB):
+    return TransformerLM(
+        vocab_size=vocab, d_model=HIDDEN, n_heads=HEADS, d_ff=D_FF,
+        dtype=jnp.float32, use_flash=use_flash, norm_eps=EPS,
+        moe=moe(expert_shard), layers=LAYERS, n_kv_heads=KV_HEADS, rope=False,
+        head_dim=HEAD_DIM, window=WINDOW, head_norm=True, attn_gate=True,
+        post_norm=True, embed_scale=HIDDEN ** 0.5)
+
+
+def reference_config(expert_shard=(0, 1), **more):
+    return dict(layers=LAYERS, embed_scale=HIDDEN ** 0.5, window=WINDOW,
+                rope_theta=THETA, norm_eps=EPS, num_experts=EXPERTS,
+                experts_per_token=PER_TOKEN, expert_shard=expert_shard,
+                weight_scale=SCALE, **more)
+
+
+# --- the banded kernels ------------------------------------------------------
+
+def masked_softmax(q, k, v, window):
+    """Written out here, a third time: query t sees keys s, 0 <= t - s <
+    window."""
+    seq = q.shape[2]
+    t, s = jnp.arange(seq)[:, None], jnp.arange(seq)[None, :]
+    seen = (s <= t) & (t - s < window)
+    scores = jnp.einsum("bhqe,bhke->bhqk", q, k,
+                        precision="highest") * q.shape[-1] ** -0.5
+    return jnp.einsum("bhqk,bhke->bhqe", jax.nn.softmax(
+        jnp.where(seen, scores, -jnp.inf), axis=-1), v, precision="highest")
+
+
+def qkv(seq, d, seed=0, heads=2):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return [jax.random.normal(key, (1, heads, seq, d)) for key in keys]
+
+
+def plan_of(monkeypatch, mode, block):
+    monkeypatch.setattr(attn, "_bwd_plan", lambda q_len, d, bq, bk, bh=1:
+                        (mode, min(bq, block), min(bk, block)))
+
+
+# seq, window, block_q, block_k, head width: windows that divide the block,
+# that do not, of one key, of every key, past the sequence; two head widths;
+# blocks of two sizes.
+BANDS = [(512, 128, 128, 128, 64), (512, 256, 128, 128, 128),
+         (512, 200, 128, 128, 64), (512, 77, 256, 128, 128),
+         (512, 300, 128, 256, 64), (384, 1, 128, 128, 64),
+         (384, 384, 128, 128, 64), (384, 1000, 128, 128, 128)]
+
+
+@pytest.mark.parametrize("path", ["combined", "split", "blockwise"])
+@pytest.mark.parametrize("seq,window,block_q,block_k,d", BANDS, ids=str)
+def test_banded_attention_is_the_masked_softmax(monkeypatch, seq, window,
+                                                block_q, block_k, d, path):
+    q, k, v, mix = qkv(seq, d, seed=window)
+    if path == "blockwise":
+        def banded(q, k, v):
+            return blockwise_attention(q, k, v, causal=True, window=window,
+                                       block_size=block_k)
+    else:
+        plan_of(monkeypatch, path, 128)
+
+        def banded(q, k, v):
+            return flash_attention(q, k, v, causal=True, window=window,
+                                   block_q=block_q, block_k=block_k,
+                                   interpret=True)
+
+    def total(fn):
+        return lambda *a: (fn(*a) * mix).sum()
+
+    def plain(q, k, v):
+        return masked_softmax(q, k, v, window)
+
+    close(banded(q, k, v), plain(q, k, v))
+    close(mha_reference(q, k, v, causal=True, window=window), plain(q, k, v))
+    got = jax.grad(total(banded), (0, 1, 2))(q, k, v)
+    want = jax.grad(total(plain), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        # A window of one key leaves q and k no gradient at all: rounding
+        # against the values' size (1), not against zero.
+        np.testing.assert_allclose(
+            g, w, atol=RTOL * max(1.0, float(jnp.abs(w).max())), rtol=0)
+
+
+def test_a_ragged_length_takes_the_blockwise_path():
+    q, k, v, mix = qkv(200, 64, seed=3)
+
+    def banded(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=50,
+                               interpret=True)
+
+    program = jax.make_jaxpr(jax.grad(lambda *a: (banded(*a) * mix).sum(),
+                                      (0, 1, 2)))(q, k, v)
+    assert _pallas_call_names(program.jaxpr) == []
+    close(banded(q, k, v), masked_softmax(q, k, v, 50))
+    assert window_blocks(200, 50, 64) is None
+
+
+@pytest.mark.parametrize("window,causal", [(0, True), (-3, True), (64, False)])
+def test_a_window_wants_causal_and_a_key(window, causal):
+    q, k, v, _ = qkv(128, 64)
+    for fn in (flash_attention, blockwise_attention):
+        with pytest.raises(ValueError, match="window"):
+            fn(q, k, v, causal=causal, window=window)
+
+
+def pallas_calls(jaxpr):
+    """{kernel name: grid} of every pallas_call, sub-programs included."""
+    found = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = tuple(
+                eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.update(pallas_calls(sub))
+    return found
+
+
+def touching(seq, window, block_q, block_k):
+    """By brute force over positions: the (query block, key block) pairs that
+    hold at least one (t, s) with 0 <= t - s < window."""
+    t, s = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    seen = (s <= t) & (t - s < window)
+    return {(i, j) for i in range(seq // block_q)
+            for j in range(seq // block_k)
+            if seen[i * block_q:(i + 1) * block_q,
+                    j * block_k:(j + 1) * block_k].any()}
+
+
+# The cell's shape in both block sizes (70 of 136 and 21 of 36), a window that
+# cuts blocks, unequal blocks both ways, a window of one key.
+WALKS = [(8192, 2048, 512, 512), (8192, 2048, 1024, 1024),
+         (2048, 700, 256, 512), (2048, 700, 512, 256), (1024, 1, 128, 128),
+         (1024, 129, 128, 128)]
+
+
+@pytest.mark.parametrize("seq,window,block_q,block_k", WALKS, ids=str)
+def test_the_kernels_visit_exactly_the_bands_blocks(seq, window, block_q,
+                                                    block_k):
+    """Every kernel's index maps and its `run` predicate, walked over the
+    grid as Pallas walks it: the steps that compute are the block pairs that
+    touch the band, once each; a key (or query) block is fetched when the
+    index moves, never for a pair outside the band; and the count is what
+    `window_blocks` and the layer's counter report."""
+    want = touching(seq, window, block_q, block_k)
+    num_q, num_k = seq // block_q, seq // block_k
+    # forward and dq: a query block's band of key blocks
+    first, last = attn._keys_of_query_block(np.arange(num_q), block_q,
+                                            block_k, window)
+    steps = attn._band_steps((first, last))
+    ran, fetched = [], []
+    for i in range(num_q):
+        moved = None
+        for j in range(steps):
+            block = min(first[i] + j, last[i])          # the index map
+            if block != moved:
+                fetched.append((i, int(block)))
+                moved = block
+            if first[i] + j <= last[i]:                 # the kernel's `run`
+                ran.append((i, int(first[i] + j)))
+    assert len(ran) == len(set(ran)) and set(ran) == want
+    assert fetched == ran
+    # dk/dv and the combined backward: a key block's band of query blocks
+    first, last = attn._queries_of_key_block(np.arange(num_k), block_q,
+                                             block_k, window, num_q)
+    steps_q = attn._band_steps((first, last))
+    ran_q = [(int(first[j] + i), j) for j in range(num_k)
+             for i in range(steps_q) if first[j] + i <= last[j]]
+    assert len(ran_q) == len(set(ran_q)) and set(ran_q) == want
+    causal = len(touching(seq, seq, block_q, block_k))
+    assert window_blocks(seq, window, 128, block_q=block_q,
+                         block_k=block_k) == (len(want), causal)
+    # The grids are the bands', not the sequence's.
+    shape = jax.ShapeDtypeStruct((1, 2, seq, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=block_q, block_k=block_k,
+                               interpret=True).astype(jnp.float32).sum()
+
+    grids = pallas_calls(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
+        shape, shape, shape).jaxpr)
+    assert grids["hvd_flash_fwd_window"] == (2, num_q, steps)
+    # The backward re-plans its blocks for the shape (`_bwd_plan`).
+    _, plan_q, plan_k = attn._bwd_plan(seq, 128, block_q, block_k, 2)
+    plan_steps = attn._band_steps(attn._queries_of_key_block(
+        np.arange(seq // plan_k), plan_q, plan_k, window, seq // plan_q))
+    backward = {name: grid for name, grid in grids.items() if "bwd" in name}
+    assert backward and all(name.endswith("_window") for name in backward)
+    for name, grid in backward.items():
+        assert grid == (2, seq // plan_k, plan_steps), (name, grid)
+
+
+def test_the_cells_counts():
+    assert window_blocks(8192, 2048, 128) == (21, 36)
+    assert window_blocks(8192, 2048, 128, block_q=512, block_k=512) \
+        == (70, 136)
+    assert window_blocks(8192, 8192, 128) == (36, 36)
+
+
+@pytest.mark.parametrize("plan", ["combined", "split"])
+def test_no_window_and_a_window_past_the_sequence_are_the_causal_program(
+        monkeypatch, plan):
+    plan_of(monkeypatch, plan, 128)
+    shape = jax.ShapeDtypeStruct((1, 2, 512, 64), jnp.bfloat16)
+
+    def program(**window):
+        def loss(q, k, v):
+            return flash_attention(q, k, v, causal=True, interpret=True,
+                                   **window).astype(jnp.float32).sum()
+        return jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(shape, shape, shape)
+
+    causal = program()
+    assert str(program(window=512)) == str(causal) \
+        == str(program(window=None))
+    names = set(_pallas_call_names(causal.jaxpr))
+    assert names and not any(name.endswith("_window") for name in names)
+    assert set(_pallas_call_names(program(window=511).jaxpr)) \
+        == {name + "_window" for name in names}
+
+
+# --- the layers --------------------------------------------------------------
+
+def attention(window, rope, use_flash):
+    return Attention(HEADS, jnp.float32, use_flash=use_flash, norm_eps=EPS,
+                     n_kv_heads=KV_HEADS, rope=rope, head_dim=HEAD_DIM,
+                     window=window, head_norm=True, gate=True)
+
+
+@pytest.mark.parametrize("use_flash", [False, True])
+@pytest.mark.parametrize("window,rope", [(WINDOW, True), (None, False)],
+                         ids=["windowed_rotated", "full_unrotated"])
+def test_attention_is_the_reference(window, rope, use_flash):
+    """A head width (16) that is not hidden / heads (8), a norm a head with one
+    scale for q and one for k, the gate, 8 query heads on 2 key/value heads."""
+    layer = attention(window, rope, use_flash)
+    u, params, mix = mixer_case(layer, seed=3)
+    assert params["q_kernel"].shape == (HIDDEN, HEADS, HEAD_DIM)
+    assert params["gate_kernel"].shape == (HIDDEN, HEADS, HEAD_DIM)
+    assert params["kv_kernel"].shape == (HIDDEN, 2, KV_HEADS, HEAD_DIM)
+    assert params["q_head_norm_scale"].shape == (HEAD_DIM,)
+    both_ways(lambda p, u: layer.apply({"params": p}, u),
+              lambda p, u: reference.attention_layer(
+                  u, p, window=window, rope_theta=THETA, norm_eps=EPS),
+              u, params, mix)
+
+
+@pytest.mark.parametrize("kind", ["gated_mlp", "window_attention",
+                                  "attention"])
+def test_a_post_norm_layer_is_the_reference(kind):
+    layer = MixerLayer(kind, HEADS, jnp.float32, False, norm_eps=EPS,
+                       n_kv_heads=KV_HEADS, rope=False, d_ff=D_FF,
+                       head_dim=HEAD_DIM, window=WINDOW, head_norm=True,
+                       attn_gate=True, post_norm=True)
+    x, params, mix = mixer_case(layer, seed=5)
+    assert set(params) == {"norm", "mixer", "post_norm"}
+    config = reference_config()
+    config = {name: config[name] for name in config
+              if name not in ("layers", "embed_scale")}
+    both_ways(lambda p, x: layer.apply({"params": p}, x),
+              lambda p, x: reference.layer(x, p, kind, **config)[0],
+              x, params, mix)
+
+
+def test_the_kinds_and_the_defaults():
+    assert LAYER_KINDS["window_attention"] == LAYER_KINDS["attention"] \
+        == "Attention"
+    with pytest.raises(ValueError, match="window="):
+        MixerLayer("window_attention", HEADS).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, SEQ, HIDDEN)))
+    plain = MixerLayer("attention", HEADS, jnp.float32, False)
+    shapes = jax.eval_shape(lambda: plain.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, SEQ, HIDDEN)))["params"])
+    assert set(shapes) == {"norm", "mixer"}
+    assert set(shapes["mixer"]) == {"qkv_kernel", "o_kernel"}
+    assert shapes["mixer"]["o_kernel"].shape == (HEADS, HIDDEN // HEADS,
+                                                 HIDDEN)
+
+
+# --- the model ---------------------------------------------------------------
+
+@pytest.mark.parametrize("expert_shard", [(0, 1), (1, 4)])
+def test_trinity_lm_loss_and_gradients_are_the_references(expert_shard):
+    model = lm(expert_shard)
+    params, batch = seeded(model, seed=expert_shard[1])
+    config = reference_config(expert_shard)
+    got, got_grads = jax.jit(jax.value_and_grad(
+        lambda p: system_loss(model, p, batch)))(params)
+    want, want_grads = with_highest(jax.value_and_grad(
+        lambda p: reference.loss(p, batch, **config)))(params)
+    np.testing.assert_allclose(got, want, rtol=RTOL)
+    trees_close(got_grads, want_grads, 1e-4)
+    _, wrote = model.apply({"params": params}, batch[0],
+                           mutable=["intermediates"])
+    chose = jnp.stack([wrote["intermediates"][f"layer_{i}"]["mixer"][
+        "chosen_experts"][0] for i, kind in enumerate(LAYERS)
+        if kind == "experts"])
+    want = with_highest(reference.loss_and_chosen)(params, batch, **config)[1]
+    np.testing.assert_array_equal(jnp.sort(chose, -1), jnp.sort(want, -1))
+
+
+def test_the_embedding_multiplier_is_the_references():
+    model = lm()
+    params, batch = seeded(model, seed=2)
+    scaled = jax.jit(model.apply)({"params": params}, batch[0])
+    as_one = jax.jit(lm().clone(embed_scale=None).apply)({"params": dict(
+        params, embed={"embedding": params["embed"]["embedding"]
+                       * HIDDEN ** 0.5})}, batch[0])
+    close(scaled, as_one)
+
+
+def test_windowed_layers_count_their_blocks(monkeypatch):
+    model = lm(use_flash=True)
+    params, batch = seeded(model, seed=4)
+    _, wrote = model.apply({"params": params}, batch[0],
+                           mutable=["intermediates"])
+    monkeypatch.setattr(metrics.registry, "enabled", True)
+    seen = record_attention_blocks(wrote["intermediates"])
+    # 128 tokens are one 128-block: the three windowed layers visit it.
+    assert seen == {"blocks_visited": [1, 1, 1], "blocks_causal": [1, 1, 1]}
+    snapshot = metrics.registry.snapshot()
+    assert snapshot["attention"] == seen
+    text = metrics.prometheus_text(snapshot)
+    assert 'hvd_tpu_attention_blocks{layer="2",kind="visited"} 1' in text
+    assert "layer_6" not in wrote["intermediates"]     # the full layer
+
+
+def test_trains_through_build_train_step_and_replicas_stay_equal():
+    """Two CPU devices, data parallel: the dense LM's step with the pattern,
+    the banded and the causal flash kernels (interpreted here) as in the
+    benchmark.  The replicated weights stay equal and the loss of a repeated
+    batch falls."""
+    model = lm((0, 4), use_flash=True)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("hvd",))
+    params, batch = seeded(model, seed=3)
+    tx = optax.adamw(1e-2)
+    step = build_train_step(lambda p, b: system_loss(model, p, b), tx, mesh,
+                            axis_name="hvd", batch_spec=(P("hvd"), P("hvd")))
+    state = (params, tx.init(params))
+    losses = []
+    for _ in range(4):
+        *state, loss = step(*state, batch)
+        losses.append(float(loss))
+    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+    for leaf in jax.tree.leaves(state[0]):
+        first, second = (np.asarray(s.data) for s in leaf.addressable_shards)
+        np.testing.assert_array_equal(first, second)
+
+
+# --- the shares add up to the uncut layer ------------------------------------
+
+@pytest.mark.parametrize("n,experts", [(4, EXPERTS), (8, EXPERTS), (8, 128)])
+def test_expert_shares_add_up_with_router_and_shared_expert_counted_once(
+        n, experts):
+    """The n shares' outputs each hold the shared expert; their sum holds it
+    n times and the routed part once.  8 shares of 16 experts: the
+    deployment's count."""
+    whole = SparseExperts(moe(experts=experts), jnp.float32)
+    u, params, _ = mixer_case(whole, n)
+    local = experts // n
+    parts = []
+    for i in range(n):
+        held = slice(i * local, (i + 1) * local)
+        share = dict(params, **{name: params[name][held] for name in (
+            "gate_kernel", "up_kernel", "down_kernel")})
+        parts.append(jax.jit(SparseExperts(
+            moe((i, n), experts=experts), jnp.float32).apply)(
+                {"params": share}, u))
+    flat = u.reshape(-1, HIDDEN)
+    shared = reference.gated_mlp(flat, *(params[name]["kernel"] for name in (
+        "shared_gate", "shared_up", "shared_down"))).reshape(u.shape)
+    want = with_highest(reference.sparse_experts)(
+        flat, params, num_experts=experts, expert_shard=(0, 1),
+        experts_per_token=PER_TOKEN, weight_scale=SCALE)[0]
+    close(sum(part - shared for part in parts) + shared,
+          want.reshape(u.shape))
+
+
+@pytest.mark.parametrize("n", [2, 8])
+def test_vocabulary_slices_concatenate_to_the_uncut_head(n):
+    """A sliced vocabulary is a smaller vocabulary: the i-th slice's model —
+    its rows of the embedding, its columns of the head — gives, for ids of
+    the slice, the uncut model's logits of those columns."""
+    model = lm()
+    params, _ = seeded(model)
+    rows = VOCAB // n
+    whole, sliced = jax.jit(model.apply), jax.jit(lm(vocab=rows).apply)
+    width = 0
+    for i in range(n):
+        ids = jax.random.randint(jax.random.PRNGKey(9), (1, SEQ), 0, rows)
+        held = slice(i * rows, (i + 1) * rows)
+        share = dict(params,
+                     embed={"embedding": params["embed"]["embedding"][held]},
+                     lm_head_kernel=params["lm_head_kernel"][:, held])
+        got = sliced({"params": share}, ids)
+        want = whole({"params": params}, ids + i * rows)
+        close(got, want[..., held])
+        width += got.shape[-1]
+    assert width == VOCAB
+
+
+# --- the reference refuses the wrong programs --------------------------------
+
+def probe_rows(kernel, window=WINDOW, seq=256):
+    """The builder's kernel comparison at a small size: `kernel` against the
+    reference's masked softmax under the sharpened scale."""
+    sharp = reference.SHARP_SCALE * HEAD_DIM ** -0.5
+    return compare.kernel_against(
+        lambda q, k, v: kernel(q, k, v, sharp),
+        lambda q, k, v: reference.band_attention(q, k, v, window=window,
+                                                 sm_scale=sharp),
+        (1, 4, seq, HEAD_DIM), jnp.float32, 7, reference.WINDOW_FWD_ATOL,
+        reference.WINDOW_GRAD_RTOL, "window_flash_")
+
+
+def test_the_banded_kernels_pass_the_builders_own_rows():
+    rows = probe_rows(lambda q, k, v, scale: flash_attention(
+        q, k, v, causal=True, window=WINDOW, sm_scale=scale, block_q=128,
+        block_k=128, interpret=True))
+    assert len(rows) == 4 and all(row["value"] < 1e-4 * row["limit"]
+                                  for row in rows), rows
+
+
+@pytest.mark.parametrize("wrong", [None, WINDOW + 1, WINDOW - 1],
+                         ids=["causal_for_the_window", "one_key_too_wide",
+                              "one_key_too_narrow"])
+def test_a_wrong_window_fails_the_builders_rows(wrong):
+    """A causal mask where the window is stated, and a window one key off
+    either way, through the kernels themselves: each is over a limit of the
+    cell's comparison, by a wide margin."""
+    rows = probe_rows(lambda q, k, v, scale: flash_attention(
+        q, k, v, causal=True, window=wrong, sm_scale=scale, block_q=128,
+        block_k=128, interpret=True))
+    over = [row for row in rows if row["value"] > 2 * row["limit"]]
+    assert over, rows
+
+
+@pytest.mark.parametrize("wrong", [
+    dict(window_error=1), dict(window_error=-1), dict(causal_for_window=True)],
+    ids=str)
+def test_the_references_wrong_windows_are_other_programs(wrong):
+    """The switches that make the wrong programs do change the loss."""
+    params, batch = seeded(lm())
+    right = with_highest(reference.loss)(params, batch, **reference_config())
+    other = with_highest(reference.loss)(params, batch,
+                                         **reference_config(**wrong))
+    assert abs(float(other - right)) > 1e-6
+
+
+@pytest.mark.parametrize("dtype,least", [(jnp.float8_e4m3fn,
+                                          reference.GRAD_RTOL),
+                                         (jnp.bfloat16, 50 * 1e-4)],
+                         ids=["float8_under_bfloat16",
+                              "bfloat16_under_float32"])
+def test_reference_refuses_the_next_precision_down(dtype, least):
+    """The reference against itself with every matmul operand, and the q, k,
+    v the attention reads, rounded a precision down: float8 where the
+    configuration states bfloat16 is over the cell's gradient limit; bfloat16
+    where float32 is stated (these tests, the rehearsal) is fifty times over
+    what the float32 system is held to above."""
+    model = lm()
+    params, batch = seeded(model)
+    losses = [with_highest(jax.value_and_grad(lambda p: reference.loss(
+        p, batch, operand_dtype=operand, **reference_config())))(params)
+        for operand in (None, dtype)]
+    norm = optax.global_norm
+    wrong = norm(jax.tree.map(jnp.subtract, losses[1][1], losses[0][1]))
+    assert float(wrong / norm(losses[0][1])) > least
