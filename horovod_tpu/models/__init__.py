@@ -28,6 +28,7 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     LatentConfig,
     MoEConfig,
     TransformerLM,
+    masked_diffusion_loss,
     moe_next_token_loss,
     next_token_loss,
     record_attention_blocks,

@@ -22,7 +22,7 @@ from horovod_tpu.models.delta import DeltaConfig, DeltaMixer
 from horovod_tpu.models.ssm import Mamba2Config, Mamba2Mixer
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              ring_attention)
-from horovod_tpu.ops.attention import window_blocks
+from horovod_tpu.ops.attention import blockdiff_blocks, window_blocks
 from horovod_tpu.ops.moe import (WAYS_BACK, buffer_rows_to_tokens,
                                  column_slabs, dispatch_rows, grouped_matmul,
                                  reduced_to_vma_of, scatters_whole_rows,
@@ -452,6 +452,8 @@ class Attention(nn.Module):
     n_kv_heads: Optional[int] = None
     # False: no rotary embedding (a model whose other layers carry position).
     rope: bool = True
+    # The rotary base (:func:`rope`'s).
+    rope_theta: float = 10000.0
     # ``(i, n)``: this process holds query heads ``[i H/n, (i+1) H/n)`` and
     # the key/value heads they read (a key/value head that several shards'
     # queries read is held by each of them) — the local part of a layer that
@@ -479,6 +481,15 @@ class Attention(nn.Module):
     # An output gate: ``concat_h(o_h) * sigmoid(x W_g)`` elementwise, ``W_g``
     # as wide as the query projection, before the output projection.
     gate: bool = False
+    # Block diffusion's training pass: the rows are ``[clean; noised]``, two
+    # copies of ``seq / 2`` positions each; row ``i`` turns at position ``i
+    # mod (seq / 2)`` and sees what the block mask of this block length shows
+    # it (:func:`~horovod_tpu.ops.flash_attention`'s ``block_diffusion``, in
+    # place of the causal mask).  The layer sows ``attn_blocks_visited`` and
+    # ``attn_blocks_causal`` as a windowed one does, the second what a causal
+    # kernel would visit over all ``seq`` rows
+    # (:func:`~horovod_tpu.ops.attention.blockdiff_blocks`).  Training only.
+    block_diffusion: Optional[int] = None
 
     def _grouped_projections(self, x, head_dim):
         """(q, k, v), each (b, local query heads, seq, head_dim), from a
@@ -518,11 +529,17 @@ class Attention(nn.Module):
         b, s, d = x.shape
         head_dim = self.head_dim or d // self.n_heads
         n_heads = self.n_heads // self.head_shard[1]
-        rotate = rope if self.rope else (lambda t, positions: t)
-        if self.window is not None and (decode_ctx is not None
-                                        or self.seq_axis is not None):
-            raise ValueError("window= composes with neither decode_ctx= nor "
-                             "sequence parallelism")
+        rotate = (lambda t, positions: rope(t, positions, self.rope_theta)) \
+            if self.rope else (lambda t, positions: t)
+        if (self.window is not None or self.block_diffusion is not None) \
+                and (decode_ctx is not None or self.seq_axis is not None):
+            raise ValueError("window= and block_diffusion= compose with "
+                             "neither decode_ctx= nor sequence parallelism")
+        if self.block_diffusion is not None and (self.window is not None
+                                                 or s % 2):
+            raise ValueError("block_diffusion= is a mask of its own over an "
+                             "even number of rows, [clean; noised]: it takes "
+                             "no window=")
         grouped = self.n_kv_heads is not None or self.head_shard != (0, 1)
         with jax.named_scope("hvd_attn_qkv"):
             if grouped:
@@ -580,18 +597,23 @@ class Attention(nn.Module):
                 out = ring_attention(q, k, v, axis_name=self.seq_axis,
                                      causal=True, rotate_impl=self.ring_impl)
             else:
-                positions = jnp.arange(s)
+                diffusion = self.block_diffusion
+                # A copy's row i stands at position i of the sequence.
+                positions = jnp.arange(s) if diffusion is None \
+                    else jnp.arange(s) % (s // 2)
                 q, k = rotate(q, positions), rotate(k, positions)
                 if self.capture_kv:
                     self.sow("intermediates", "kv", (k, v))
-                out = (flash_attention(q, k, v, causal=True,
-                                       window=self.window)
-                       if self.use_flash
-                       else blockwise_attention(q, k, v, causal=True,
-                                                window=self.window))
-                if self.window is not None:
-                    blocks = window_blocks(s, self.window, head_dim) \
-                        if self.use_flash else None
+                masks = dict(causal=True, window=self.window) \
+                    if diffusion is None else dict(block_diffusion=diffusion)
+                out = (flash_attention(q, k, v, **masks) if self.use_flash
+                       else blockwise_attention(q, k, v, **masks))
+                if self.window is not None or diffusion is not None:
+                    blocks = None                   # the scan: every block
+                    if self.use_flash and diffusion is None:
+                        blocks = window_blocks(s, self.window, head_dim)
+                    elif self.use_flash:
+                        blocks = blockdiff_blocks(s // 2, diffusion, head_dim)
                     every = -(-s // min(512, s))       # the scan's blocks
                     visited, causal = blocks or (every, every)
                     self.sow("intermediates", "attn_blocks_visited",
@@ -801,7 +823,8 @@ class Block(nn.Module):
 LAYER_KINDS = {"ssm": "Mamba2Mixer", "attention": "Attention",
                "experts": "SparseExperts", "delta": "DeltaMixer",
                "latent_attention": "LatentAttention", "gated_mlp": "GatedMLP",
-               "window_attention": "Attention"}
+               "window_attention": "Attention",
+               "blockdiff_attention": "Attention"}
 
 
 class MixerLayer(nn.Module):
@@ -817,8 +840,10 @@ class MixerLayer(nn.Module):
         + """.  ``"window_attention"`` is :class:`Attention` with the model's
     ``window`` and ALWAYS rotated; ``"attention"`` sees every earlier key and
     rotates where ``rope`` says: one pattern holds rotated windowed layers
-    and unrotated full ones.  Both take ``head_dim``, ``head_norm`` and
-    ``attn_gate``.""")
+    and unrotated full ones.  ``"blockdiff_attention"`` is :class:`Attention`
+    under the model's ``block_diffusion`` mask over ``[clean; noised]`` rows,
+    rotated where ``rope`` says.  All three take ``head_dim``, ``head_norm``
+    and ``attn_gate``.""")
 
     kind: str
     n_heads: int
@@ -839,6 +864,8 @@ class MixerLayer(nn.Module):
     head_norm: bool = False
     attn_gate: bool = False
     post_norm: bool = False
+    block_diffusion: Optional[int] = None
+    rope_theta: float = 10000.0
 
     @nn.compact
     def __call__(self, x):
@@ -848,19 +875,27 @@ class MixerLayer(nn.Module):
             mixer = Mamba2Mixer(*self.ssm, head_shard=self.head_shard,
                                 dtype=self.dtype, norm_eps=self.norm_eps,
                                 name="mixer")
-        elif self.kind in ("attention", "window_attention"):
+        elif self.kind in ("attention", "window_attention",
+                           "blockdiff_attention"):
             windowed = self.kind == "window_attention"
+            diffusion = self.kind == "blockdiff_attention"
             if windowed and self.window is None:
                 raise ValueError("a 'window_attention' layer wants window=")
+            if diffusion and self.block_diffusion is None:
+                raise ValueError("a 'blockdiff_attention' layer wants "
+                                 "block_diffusion=")
             mixer = Attention(self.n_heads, self.dtype,
                               use_flash=self.use_flash, qk_norm=self.qk_norm,
                               norm_eps=self.norm_eps,
                               n_kv_heads=self.n_kv_heads,
                               rope=self.rope or windowed,
+                              rope_theta=self.rope_theta,
                               head_shard=self.head_shard,
                               head_dim=self.head_dim,
                               window=self.window if windowed else None,
                               head_norm=self.head_norm, gate=self.attn_gate,
+                              block_diffusion=self.block_diffusion
+                              if diffusion else None,
                               name="mixer")
         elif self.kind == "experts":
             mixer = SparseExperts(self.moe, self.dtype, name="mixer")
@@ -929,13 +964,19 @@ class TransformerLM(nn.Module):
     # always rotated.  ``n_kv_heads`` and ``rope`` are the ``"attention"``
     # layers' as :class:`Attention` has them (so ``rope=False`` with a
     # ``window`` is full layers without rotation among rotated windowed
-    # ones), ``head_dim``, ``head_norm`` and ``attn_gate`` both attention
+    # ones), ``rope_theta`` every rotated pattern layer's rotary base,
+    # ``head_dim``, ``head_norm`` and ``attn_gate`` both attention
     # kinds' (:class:`Attention`'s ``head_dim``, ``head_norm``, ``gate``),
     # ``head_shard`` every head-carrying mixer's, ``post_norm`` every
     # layer's (:class:`MixerLayer`).  ``embed_scale`` multiplies the embedding
     # rows as they are looked up (a muP model's ``sqrt(d_model)``), patterns
     # and blocks alike.  Unset, the model is the block above, parameter for
-    # parameter.
+    # parameter.  ``block_diffusion`` is the ``"blockdiff_attention"``
+    # layers' block length, and makes the model a block-diffusion one:
+    # ``__call__(tokens, noised=...)`` runs the pattern over ``[tokens;
+    # noised]``, both copies of every sequence in one pass of twice the
+    # positions, and gives logits for the NOISED copy alone (``final_norm`` and
+    # the head run on that half; :func:`masked_diffusion_loss` is its loss).
     # A pattern trains on one sequence shard and has no cached decode: a
     # recurrent layer's state is no key/value cache.
     layers: Optional[Tuple[str, ...]] = None
@@ -951,14 +992,24 @@ class TransformerLM(nn.Module):
     attn_gate: bool = False
     post_norm: bool = False
     embed_scale: Optional[float] = None
+    block_diffusion: Optional[int] = None
+    rope_theta: float = 10000.0
 
     @nn.compact
-    def __call__(self, tokens, targets=None, decode_ctx=None):
+    def __call__(self, tokens, targets=None, decode_ctx=None, noised=None):
         if self.layers is not None and (decode_ctx is not None
                                         or self.seq_axis is not None):
             raise ValueError(
                 "layers= (a per-layer pattern) composes with neither "
                 "decode_ctx= nor sequence parallelism.")
+        if (noised is None) != (self.block_diffusion is None) or (
+                noised is not None and (self.layers is None
+                                        or noised.shape != tokens.shape)):
+            raise ValueError(
+                "block_diffusion= and noised= (the noised copy of tokens, "
+                "shaped alike) come together, over a per-layer pattern.")
+        if noised is not None:
+            tokens = jnp.concatenate([tokens, noised], axis=1)
         if targets is not None and self.seq_axis is not None:
             raise ValueError(
                 "targets= (fused head+loss) is unsupported under sequence "
@@ -984,7 +1035,8 @@ class TransformerLM(nn.Module):
                            self.n_kv_heads, self.rope, self.head_shard,
                            self.delta, self.latent, d_ff, self.head_dim,
                            self.window, self.head_norm, self.attn_gate,
-                           self.post_norm, name=f"layer_{i}")(x)
+                           self.post_norm, self.block_diffusion,
+                           self.rope_theta, name=f"layer_{i}")(x)
         for i in range(0 if self.layers is not None else self.n_layers):
             block = Block(self.n_heads, d_ff, self.dtype, self.seq_axis,
                           self.use_flash, self.ring_impl, self.capture_kv,
@@ -996,6 +1048,8 @@ class TransformerLM(nn.Module):
                 x, (k_new, v_new) = block(x, decode_ctx.layer(i))
                 new_ks.append(k_new)
                 new_vs.append(v_new)
+        if noised is not None:
+            x = x[:, noised.shape[1]:]      # the clean copy is context alone
         x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                        name="final_norm")(x)
         # Logits accumulate in float32 for a numerically stable softmax,
@@ -1140,6 +1194,25 @@ def next_token_loss(logits, targets, mask=None, axis_name=None):
     return (loss * mask).sum() / jnp.maximum(count, 1.0)
 
 
+def masked_diffusion_loss(logits, targets, masked, level):
+    """The masked-diffusion bound of a block-diffusion model (BD3-LM,
+    arXiv:2503.09573), on the logits of the noised copy: ``(1 / L) sum_i m_i /
+    t_i * -log softmax(logits_i)[x_i]`` a sequence, the mean over sequences —
+    ``targets`` the data tokens ``x`` (batch, L), ``masked`` ``m`` (1 where the
+    noised copy holds the mask token in the token's place), ``level`` ``t``
+    (the masking probability of the token's block, in (0, 1]).  The mean is
+    over ALL ``L`` data tokens, not over the masked ones.  With every token
+    masked at ``t = 1`` it is :func:`next_token_loss` of aligned targets.  The
+    per-token cross-entropy is :func:`next_token_loss`'s own (float32 inside,
+    its own backward pass); the whole runs under the scope
+    ``hvd_diffusion_loss``."""
+    with jax.named_scope("hvd_diffusion_loss"):
+        with jax.named_scope("hvd_token_xent"):
+            loss = _token_xent(logits, targets)
+        weight = masked.astype(loss.dtype) / level.astype(loss.dtype)
+        return (loss * weight).mean()
+
+
 def _sown(tree, name):
     """Every value sown under ``name`` anywhere in a flax collection, in
     layer order (``layer_2`` before ``layer_10``)."""
@@ -1178,10 +1251,10 @@ def record_expert_rows(intermediates) -> dict:
 
 
 def record_attention_blocks(intermediates) -> dict:
-    """Read what the windowed attention layers wrote to the ``intermediates``
-    collection of one ``apply(..., mutable=["intermediates"])`` — outside the
-    compiled step — and, when the metrics registry is on
-    (``HVD_TPU_METRICS=1``), mirror it into
+    """Read what the windowed and the block-diffusion attention layers wrote
+    to the ``intermediates`` collection of one ``apply(..., mutable=
+    ["intermediates"])`` — outside the compiled step — and, when the
+    metrics registry is on (``HVD_TPU_METRICS=1``), mirror it into
     ``hvd.metrics_snapshot()["attention"]``.  Returns ``{"blocks_visited":
     [the (query block, key block) pairs a head's forward kernel visits, per
     windowed layer], "blocks_causal": [what the causal kernel would under the

@@ -35,14 +35,35 @@ POS_BIG = 1e30   # logsumexp sentinel for fully-masked rows: exp(s - POS_BIG)
 # underflows to exactly 0 for any finite s
 
 
+def block_diffusion_mask(q_pos, k_pos, block: int, half: int):
+    """True where the query row at ``q_pos`` sees the key row at ``k_pos``
+    under block diffusion: rows ``[0, half)`` are a sequence's clean copy,
+    rows ``[half, 2 half)`` its noised copy, position ``p`` of either copy
+    lies in block ``p // block``.  A clean query sees the clean keys of its
+    own block and of the earlier ones; a noised query sees the clean keys of
+    the EARLIER blocks and the noised keys of its OWN block; no clean query
+    sees a noised key.  ``q_pos`` and ``k_pos`` broadcast against each
+    other."""
+    q_noised, k_noised = q_pos >= half, k_pos >= half
+    q_block = (q_pos - jnp.where(q_noised, half, 0)) // block
+    k_block = (k_pos - jnp.where(k_noised, half, 0)) // block
+    return jnp.where(k_noised, q_noised & (k_block == q_block),
+                     jnp.where(q_noised, k_block < q_block,
+                               k_block <= q_block))
+
+
 def mha_reference(q, k, v, causal: bool = False,
                   sm_scale: Optional[float] = None,
-                  window: Optional[int] = None):
+                  window: Optional[int] = None,
+                  block_diffusion: Optional[int] = None):
     """O(seq^2)-memory reference attention (for tests and tiny shapes).
     ``window`` (with ``causal``): query ``t`` sees the keys ``s`` with
-    ``0 <= t - s < window``, itself and the ``window - 1`` before it."""
+    ``0 <= t - s < window``, itself and the ``window - 1`` before it.
+    ``block_diffusion``: :func:`flash_attention`'s, as an explicit mask."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    blockdiff = _checked_block_diffusion(block_diffusion, causal, window,
+                                         q.shape[2], k.shape[2])
     # precision="highest": on TPU the default matmul precision truncates f32
     # operands to bf16 passes; the reference must be at least as accurate as
     # the kernels it validates.
@@ -54,6 +75,10 @@ def mha_reference(q, k, v, causal: bool = False,
         s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         if window is not None:
             s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
+    if blockdiff is not None:
+        s = jnp.where(block_diffusion_mask(
+            jnp.arange(q.shape[2])[:, None], jnp.arange(k.shape[2])[None, :],
+            *blockdiff), s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
                       precision="highest")
@@ -115,18 +140,22 @@ def _kv_blocks(k, v, block, n_blocks, pad):
     return jnp.moveaxis(kb, -3, 0), jnp.moveaxis(vb, -3, 0)
 
 
-def _block_mask(i, block, q_pos, k_offset, k_len, causal, window=None):
+def _block_mask(i, block, q_pos, k_offset, k_len, causal, window=None,
+                blockdiff=None):
     k_pos = k_offset + i * block + jnp.arange(block)
     mask = (k_pos < k_offset + k_len)[None, :]  # padding rows
     if causal:
         mask = mask & (q_pos[:, None] >= k_pos[None, :])
     if window is not None:
         mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
+    if blockdiff is not None:
+        mask = mask & block_diffusion_mask(q_pos[:, None], k_pos[None, :],
+                                           *blockdiff)
     return mask
 
 
 def _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size, q_offset,
-                        k_offset, window=None):
+                        k_offset, window=None, blockdiff=None):
     """Forward scan; returns (out, lse) with lse the per-row logsumexp."""
     q_len, k_len = q.shape[-2], k.shape[-2]
     block = min(block_size, k_len)
@@ -140,7 +169,8 @@ def _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size, q_offset,
     def step(carry, inputs):
         m, l, acc = carry
         i, kblk, vblk = inputs
-        mask = _block_mask(i, block, q_pos, k_offset, k_len, causal, window)
+        mask = _block_mask(i, block, q_pos, k_offset, k_len, causal, window,
+                           blockdiff)
         m, l, acc = _block_attend(q, kblk, vblk, m, l, acc, mask, sm_scale)
         return (m, l, acc), None
 
@@ -150,7 +180,7 @@ def _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size, q_offset,
 
 
 def _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_size,
-                        q_offset, k_offset, window=None):
+                        q_offset, k_offset, window=None, blockdiff=None):
     """Flash-attention backward: recompute each key block's probabilities
     from (q, k, lse); residual memory O(seq)."""
     q_len, k_len = q.shape[-2], k.shape[-2]
@@ -167,7 +197,8 @@ def _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_size,
         i, kblk, vblk = inputs
         s = jnp.einsum("...qd,...kd->...qk", q, kblk,
                        preferred_element_type=jnp.float32) * sm_scale
-        mask = _block_mask(i, block, q_pos, k_offset, k_len, causal, window)
+        mask = _block_mask(i, block, q_pos, k_offset, k_len, causal, window,
+                           blockdiff)
         s = jnp.where(mask, s, NEG_INF)
         p = jnp.exp(s - lse[..., None])
         p = jnp.where(mask, p, 0.0)
@@ -192,26 +223,27 @@ def _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_size,
             dv[..., :k_len, :].astype(v.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _blockwise(q, k, v, causal, sm_scale, block_size, q_offset, k_offset,
-               window):
+               window, blockdiff):
     out, _ = _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size,
-                                 q_offset, k_offset, window)
+                                 q_offset, k_offset, window, blockdiff)
     return out
 
 
 def _blockwise_fwd(q, k, v, causal, sm_scale, block_size, q_offset,
-                   k_offset, window):
+                   k_offset, window, blockdiff):
     out, lse = _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size,
-                                   q_offset, k_offset, window)
+                                   q_offset, k_offset, window, blockdiff)
     return out, (q, k, v, out, lse)
 
 
 def _blockwise_bwd(causal, sm_scale, block_size, q_offset, k_offset, window,
-                   res, g):
+                   blockdiff, res, g):
     q, k, v, out, lse = res
     return _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale,
-                               block_size, q_offset, k_offset, window)
+                               block_size, q_offset, k_offset, window,
+                               blockdiff)
 
 
 _blockwise.defvjp(_blockwise_fwd, _blockwise_bwd)
@@ -221,12 +253,14 @@ def blockwise_attention(q, k, v, causal: bool = False,
                         sm_scale: Optional[float] = None,
                         block_size: int = 512,
                         q_offset: int = 0, k_offset: int = 0,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None,
+                        block_diffusion: Optional[int] = None):
     """Memory-efficient attention as a `lax.scan` over key/value blocks.
 
-    ``window`` (with ``causal``): the sliding window of
-    :func:`flash_attention`, here a mask over the same scan (every block is
-    still walked: the CPU path and the tests use this one).
+    ``window`` (with ``causal``) and ``block_diffusion``: the sliding window
+    and the block-diffusion mask of :func:`flash_attention`, here a mask over
+    the same scan (every block is still walked: the CPU path and the tests
+    use this one).
 
     ``q_offset``/``k_offset`` give the global sequence positions of the
     first query/key row — this is what lets :func:`ring_attention` apply a
@@ -238,16 +272,20 @@ def blockwise_attention(q, k, v, causal: bool = False,
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     window = _checked_window(window, causal, None)
+    blockdiff = _checked_block_diffusion(block_diffusion, causal, window,
+                                         q.shape[-2], k.shape[-2])
     try:
         q_offset, k_offset = int(q_offset), int(k_offset)
     except (TypeError, jax.errors.ConcretizationTypeError):
         # Traced offsets can't be custom_vjp static args; keep the plain
         # (through-scan) differentiable path for this corner.
         out, _ = _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size,
-                                     q_offset, k_offset, window)
+                                     q_offset, k_offset, window, blockdiff)
         return out
+    if blockdiff is not None and (q_offset or k_offset):
+        raise ValueError("block_diffusion= takes whole sequences: no offsets")
     return _blockwise(q, k, v, causal, sm_scale, block_size, q_offset,
-                      k_offset, window)
+                      k_offset, window, blockdiff)
 
 
 def _checked_window(window, causal, k_len):
@@ -259,6 +297,20 @@ def _checked_window(window, causal, k_len):
         raise ValueError(f"window={window!r} wants causal=True and at least "
                          "one key (the query's own)")
     return None if k_len is not None and window >= k_len else int(window)
+
+
+def _checked_block_diffusion(block, causal, window, q_len, k_len):
+    """``block_diffusion`` as the kernels take it: None, or ``(block length,
+    rows of one copy)`` for ``[clean; noised]`` operands of ``2 L`` rows."""
+    if block is None:
+        return None
+    if causal or window is not None or block < 1 or q_len != k_len \
+            or q_len % 2:
+        raise ValueError(
+            f"block_diffusion={block!r} is a mask of its own over [clean; "
+            "noised] rows (an even number, queries and keys alike): it takes "
+            "neither causal= nor window=")
+    return int(block), q_len // 2
 
 
 # ---------------------------------------------------------------------------
@@ -325,8 +377,7 @@ def _queries_of_key_block(ki, block_q, block_k, window, num_q):
 def _band_steps(first_last) -> int:
     """The most blocks any one block's band holds: the banded grid's inner
     extent."""
-    first, last = first_last
-    return int((last - first).max()) + 1
+    return _walk_steps((first_last,))
 
 
 def _band_mask(q_start, k_start, block_q, block_k, window):
@@ -337,9 +388,147 @@ def _band_mask(q_start, k_start, block_q, block_k, window):
     return (diff >= 0) & (diff < window)
 
 
+def _copy_of_tile(tile, block, half):
+    """(0 for a tile of the clean copy or 1 for one of the noised copy, the
+    position in its copy of the tile's first row); ``block`` divides
+    ``half``, so a tile lies in one copy."""
+    copy = _div(tile, half // block)
+    return copy, (tile - copy * (half // block)) * block
+
+
+def _blockdiff_keys_of_query_block(qi, block_q, block_k, block, half):
+    """The key blocks that hold a key some query of block ``qi`` sees under
+    :func:`block_diffusion_mask`, as TWO runs ``((first, last), (first,
+    last))``: the clean key blocks from 0 on (to the query block's own
+    diffusion block for a clean query block, to the one before it for a
+    noised one: none, ``last = -1``, where that is block 0), then, for a
+    noised query block, the noised key blocks that hold its own diffusion
+    blocks.  An empty second run is ``(last + 1, last)`` of the first."""
+    xp = np if _on_host(qi) else jnp
+    noised, start = _copy_of_tile(qi, block_q, half)
+    blocks_end = _div(start + block_q - 1, block) + 1   # past its last block
+    own_end = xp.minimum(blocks_end * block, half)
+    clean_end = xp.minimum((blocks_end - noised) * block, half)
+    last = _div(clean_end + block_k - 1, block_k) - 1
+    first_own = half // block_k + _div(_div(start, block) * block, block_k)
+    last_own = half // block_k + _div(own_end - 1, block_k)
+    return ((xp.zeros_like(last), last),
+            (xp.where(noised > 0, first_own, last + 1),
+             xp.where(noised > 0, last_own, last)))
+
+
+def _blockdiff_queries_of_key_block(ki, block_q, block_k, block, half):
+    """The query blocks that hold a query seeing some key of block ``ki``
+    under :func:`block_diffusion_mask`, as two runs: for a clean key block
+    the clean query blocks from its first diffusion block on, then the noised
+    query blocks from the NEXT diffusion block on (none where there is no
+    next); for a noised key block the noised query blocks of its own
+    diffusion blocks, and an empty second run."""
+    xp = np if _on_host(ki) else jnp
+    noised, start = _copy_of_tile(ki, block_k, half)
+    per_copy = half // block_q
+    first_block = _div(start, block)
+    own_end = xp.minimum((_div(start + block_k - 1, block) + 1) * block,
+                         half)
+    first = noised * per_copy + _div(first_block * block, block_q)
+    last = xp.where(noised > 0, per_copy + _div(own_end - 1, block_q),
+                    per_copy - 1)
+    later = (first_block + 1) * block     # first position of the next block
+    none = (noised > 0) | (later >= half)
+    return ((first, last),
+            (xp.where(none, last + 1,
+                      per_copy + _div(xp.minimum(later, half - 1), block_q)),
+             xp.where(none, last, 2 * per_copy - 1)))
+
+
+def _tile_of_step(runs, step):
+    """(the block the walk of two ``runs`` — ``(first, last)`` each, walked
+    one after the other — stands on at ``step``, whether the step is one of
+    the walk's).  Past the walk's end the block is its last one."""
+    xp = np if _on_host(step) else jnp
+    (first, last), (first2, last2) = runs
+    steps, steps2 = last - first + 1, last2 - first2 + 1
+    at = xp.minimum(step, steps + steps2 - 1)
+    return (xp.where(at < steps, first + at, first2 + (at - steps)),
+            step < steps + steps2)
+
+
+def _held_tile(runs, step):
+    """The block an index map gives ``step`` of the walk of ``runs`` — one
+    ``(first, last)``, a band, or two: past the walk's end it stays on the
+    last block, and an index that does not move copies nothing."""
+    if len(runs) == 1:
+        (first, last), = runs
+        return jnp.minimum(first + step, last)
+    return _tile_of_step(runs, step)[0]
+
+
+def _walk_steps(runs) -> int:
+    """The most blocks any one block's walk holds (``runs`` on numpy block
+    indices): the grid's inner extent."""
+    return int(sum(last - first + 1 for first, last in runs).max())
+
+
+def _blockdiff_bounds(q_start, k_start, block, half):
+    """(the first query's and the first key's position in their copies, the
+    least and the most ``d`` a seen pair has) for a tile under
+    :func:`block_diffusion_mask`, ``d`` the query's diffusion block less the
+    key's: clean on clean sees ``d >= 0``, noised on clean ``d >= 1``, noised
+    on noised ``d == 0``."""
+    q_noised, k_noised = q_start >= half, k_start >= half
+    return (q_start - jnp.where(q_noised, half, 0),
+            k_start - jnp.where(k_noised, half, 0),
+            jnp.where(q_noised & ~k_noised, 1, 0),
+            jnp.where(k_noised, 0, half))
+
+
+def _blockdiff_whole(q_start, k_start, block_q, block_k, block, half):
+    """Whether every query of the tile sees every key of it (a scalar): such
+    a tile takes the unmasked body."""
+    q_rel, k_rel, least, most = _blockdiff_bounds(q_start, k_start, block,
+                                                  half)
+    return ((_div(q_rel, block) - _div(k_rel + block_k - 1, block) >= least)
+            & (_div(q_rel + block_q - 1, block) - _div(k_rel, block) <= most))
+
+
+def _blockdiff_mask(q_start, k_start, block_q, block_k, block, half):
+    """True where key ``s`` of the tile is seen by query ``t``, from the
+    block ids of the tile's rows (``block_q`` of them) and columns."""
+    q_rel, k_rel, least, most = _blockdiff_bounds(q_start, k_start, block,
+                                                  half)
+
+    def blocks_of(rel, shape, axis):
+        pos = rel + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+        if block & (block - 1) == 0:
+            return jax.lax.shift_right_logical(pos, block.bit_length() - 1)
+        return lax.div(pos, block)
+
+    diff = blocks_of(q_rel, (block_q, 1), 0) \
+        - blocks_of(k_rel, (1, block_k), 1)
+    return (diff >= least) & (diff <= most)
+
+
+def _blockdiff_key_step(qi, step, block_q, block_k, blockdiff):
+    """(first key row, whether the step is live, whether the tile is whole)
+    at ``step`` of query block ``qi``'s walk of its key blocks."""
+    tile, run = _tile_of_step(_blockdiff_keys_of_query_block(
+        qi, block_q, block_k, *blockdiff), step)
+    return tile * block_k, run, _blockdiff_whole(
+        qi * block_q, tile * block_k, block_q, block_k, *blockdiff)
+
+
+def _blockdiff_query_step(ki, step, block_q, block_k, blockdiff):
+    """(query block, whether the step is live, whether the tile is whole) at
+    ``step`` of key block ``ki``'s walk of its query blocks."""
+    tile, run = _tile_of_step(_blockdiff_queries_of_key_block(
+        ki, block_q, block_k, *blockdiff), step)
+    return tile, run, _blockdiff_whole(
+        tile * block_q, ki * block_k, block_q, block_k, *blockdiff)
+
+
 def _attend_block(q_ref, k_ref, v_ref, m_scratch, l_scratch, acc_scratch,
                   q_start, k_start, causal, block_q, block_k,
-                  single_k=False, scale_r=1.0, window=None):
+                  single_k=False, scale_r=1.0, window=None, blockdiff=None):
     """One online-softmax block update of the VMEM (m, l, acc) state.
 
     Shared by the single-shard flash kernel and the fused ring-flash step
@@ -366,7 +555,10 @@ def _attend_block(q_ref, k_ref, v_ref, m_scratch, l_scratch, acc_scratch,
         preferred_element_type=jnp.float32)
     if scale_r != 1.0:
         s *= scale_r
-    if window is not None:
+    if blockdiff is not None:
+        s = jnp.where(_blockdiff_mask(q_start, k_start, block_q, block_k,
+                                      *blockdiff), s, NEG_INF)
+    elif window is not None:
         s = jnp.where(_band_mask(q_start, k_start, block_q, block_k, window),
                       s, NEG_INF)
     elif causal:
@@ -420,13 +612,26 @@ def _finalize_flash(o_ref, lse_ref, m_scratch, l_scratch, acc_scratch,
         lse_ref.shape)
 
 
+def _when_live(run, whole, body):
+    """``body(masked)`` under ``pl.when(run)``.  ``whole`` None: one body,
+    ``body(True)``, whatever mask the kernel has in every tile.  Else (block
+    diffusion) a tile the mask cuts takes the masked body, ``body(True)``, and
+    a whole one the unmasked, ``body(False)``."""
+    if whole is None:
+        pl.when(run)(lambda: body(True))
+    else:
+        pl.when(run & ~whole)(lambda: body(True))
+        pl.when(run & whole)(lambda: body(False))
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch,
                   acc_scratch, *, causal, block_q, block_k, num_k_blocks,
-                  scale_r=1.0, window=None):
+                  scale_r=1.0, window=None, blockdiff=None):
     """With a ``window`` the grid's key axis walks only query block ``qi``'s
     band: ``num_k_blocks`` is the band's steps, step ``ki`` is key block
     ``first + ki``, and the steps past the band's last block do nothing (the
-    index maps hold them on that block, so nothing is copied either)."""
+    index maps hold them on that block, so nothing is copied either).  With
+    ``blockdiff`` it walks the block's two runs the same way."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     single_k = num_k_blocks == 1
@@ -437,7 +642,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch,
             _init_state(m_scratch, l_scratch, acc_scratch)
 
     q_start = qi * block_q
-    if window is None:
+    whole = None
+    if blockdiff is not None:
+        k_start, run, whole = _blockdiff_key_step(qi, ki, block_q, block_k,
+                                                  blockdiff)
+    elif window is None:
         k_start = ki * block_k
         # Causal pruning: skip key blocks entirely above the diagonal.
         run = True if not causal else k_start <= q_start + block_q - 1
@@ -446,12 +655,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch,
         k_start = (first + ki) * block_k
         run = first + ki <= last
 
-    @pl.when(run)
-    def _():
+    def attend(masked):
         _attend_block(q_ref, k_ref, v_ref, m_scratch, l_scratch,
                       acc_scratch, q_start, k_start, causal,
                       block_q, block_k, single_k=single_k,
-                      scale_r=scale_r, window=window)
+                      scale_r=scale_r, window=window,
+                      blockdiff=blockdiff if masked else None)
+
+    _when_live(run, whole, attend)
 
     @pl.when(ki == num_k_blocks - 1)
     def _():
@@ -461,7 +672,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch,
 
 def _bwd_block_math(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                     causal, q_start, k_start, block_q, block_k, scale_r,
-                    window=None):
+                    window=None, blockdiff=None):
     """Shared flash-backward block recompute (Dao et al. alg. 2 inner
     body), used by the combined kernel, both split kernels, and the fused
     ring backward (ops/ring_flash.py).
@@ -487,7 +698,10 @@ def _bwd_block_math(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         preferred_element_type=jnp.float32)
     if scale_r != 1.0:
         s *= scale_r
-    if window is not None:
+    if blockdiff is not None:
+        s = jnp.where(_blockdiff_mask(q_start, k_start, block_q, block_k,
+                                      *blockdiff), s, NEG_INF)
+    elif window is not None:
         s = jnp.where(_band_mask(q_start, k_start, block_q, block_k, window),
                       s, NEG_INF)
     elif causal:
@@ -509,7 +723,7 @@ def _bwd_block_math(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                            dk_ref, dv_ref, dk_scratch, dv_scratch, *,
                            causal, block_q, block_k, num_q_blocks, scale_r,
-                           band=None):
+                           band=None, blockdiff=None):
     """Split backward, dk/dv half: O(block) scoped memory — the long-seq
     path where the combined kernel's whole-seq dq scratch exceeds the
     chip's scoped-VMEM ceiling (see _bwd_plan).  ``band=(window, query
@@ -523,7 +737,12 @@ def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dk_scratch[...] = jnp.zeros_like(dk_scratch)
         dv_scratch[...] = jnp.zeros_like(dv_scratch)
 
-    if band is None:
+    whole = None
+    if blockdiff is not None:
+        tile, run, whole = _blockdiff_query_step(ki, qi, block_q, block_k,
+                                                 blockdiff)
+        q_start, k_start = tile * block_q, ki * block_k
+    elif band is None:
         q_start = qi * block_q
         k_start = ki * block_k
         run = True if not causal else q_start + block_q - 1 >= k_start
@@ -533,18 +752,20 @@ def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         k_start = ki * block_k
         run = first + qi <= last
 
-    @pl.when(run)
-    def _():
+    def accumulate(masked):
         pb, ds, q, do, _k = _bwd_block_math(
             q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, causal,
             q_start, k_start, block_q, block_k, scale_r,
-            window=band and band[0])
+            window=band and band[0],
+            blockdiff=blockdiff if masked else None)
         dv_scratch[...] += jax.lax.dot_general(
             pb, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dk_scratch[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _when_live(run, whole, accumulate)
 
     @pl.when(qi == num_q_blocks - 1)
     def _():
@@ -555,7 +776,7 @@ def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                          dq_ref, dq_scratch, *, causal, block_q,
                          block_k, num_k_blocks, scale_r, dq_scale=1.0,
-                         window=None):
+                         window=None, blockdiff=None):
     """Split backward, dq half: accumulates one query block over the key
     loop — O(block) scoped memory (long-seq path, see _bwd_plan).  With a
     ``window`` the key loop is the band's ``num_k_blocks`` steps."""
@@ -567,7 +788,11 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dq_scratch[...] = jnp.zeros_like(dq_scratch)
 
     q_start = qi * block_q
-    if window is None:
+    whole = None
+    if blockdiff is not None:
+        k_start, run, whole = _blockdiff_key_step(qi, ki, block_q, block_k,
+                                                  blockdiff)
+    elif window is None:
         k_start = ki * block_k
         run = True if not causal else q_start + block_q - 1 >= k_start
     else:
@@ -575,14 +800,16 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         k_start = (first + ki) * block_k
         run = first + ki <= last
 
-    @pl.when(run)
-    def _():
+    def accumulate(masked):
         _pb, ds, _q, _do, k = _bwd_block_math(
             q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, causal,
-            q_start, k_start, block_q, block_k, scale_r, window=window)
+            q_start, k_start, block_q, block_k, scale_r, window=window,
+            blockdiff=blockdiff if masked else None)
         dq_scratch[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _when_live(run, whole, accumulate)
 
     @pl.when(ki == num_k_blocks - 1)
     def _():
@@ -594,7 +821,8 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
 def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
                          num_k_blocks, bh, rotate, barrier, axis_name,
-                         mesh_axes, scale_r, dq_scale=1.0, band=None):
+                         mesh_axes, scale_r, dq_scale=1.0, band=None,
+                         blockdiff=None):
     """Flash backward with dk/dv AND dq from ONE probability recompute.
 
     Grid: (bh, ki, qi) — queries innermost so dk/dv accumulate in scratch
@@ -662,11 +890,15 @@ def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
         dk_scratch[...] = jnp.zeros_like(dk_scratch)
         dv_scratch[...] = jnp.zeros_like(dv_scratch)
 
-    q_block = qi
+    q_block, whole = qi, None
     if band is not None:
         first, last = _queries_of_key_block(ki, block_q, block_k, *band)
         q_block = first + qi
-    if causal:
+    if blockdiff is not None:
+        q_block, run, whole = _blockdiff_query_step(ki, qi, block_q, block_k,
+                                                    blockdiff)
+        q_start, k_start = q_block * block_q, ki * block_k
+    elif causal:
         q_start = offsets_ref[0] + q_block * block_q  # absolute positions
         k_start = offsets_ref[1] + ki * block_k
         run = q_start + block_q - 1 >= k_start if band is None \
@@ -675,12 +907,12 @@ def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
         q_start = k_start = 0
         run = True
 
-    @pl.when(run)
-    def _():
+    def accumulate(masked):
         pb, ds, q, do, k = _bwd_block_math(
             q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, causal,
             q_start, k_start, block_q, block_k, scale_r,
-            window=band and band[0])
+            window=band and band[0],
+            blockdiff=blockdiff if masked else None)
         dv_scratch[...] += jax.lax.dot_general(
             pb, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -691,6 +923,8 @@ def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
         dq_scratch[row, :] = dq_scratch[row, :] + jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _when_live(run, whole, accumulate)
 
     @pl.when(qi == num_q_blocks - 1)
     def _flush_dkdv():
@@ -729,7 +963,7 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
                        k_offset, *, causal, block_q, block_k, rotate,
                        collective_id, axis_name, mesh_axes, interpret,
                        scale_r=1.0, grad_dtype=jnp.float32, dq_scale=1.0,
-                       name="hvd_flash_bwd", window=None):
+                       name="hvd_flash_bwd", window=None, blockdiff=None):
     """pallas_call wrapper for `_combined_bwd_kernel` over (bh, sl, d)
     operands (q pre-scaled by the pow2 part of sm_scale; ``do`` and ``v_cur``
     may have another width than ``q`` and ``k_cur``, and ``dv`` then has
@@ -738,7 +972,8 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
     (accumulation is always f32 in scratch; only the flush casts, after
     applying ``dq_scale`` to dq in f32).  ``name`` is the kernel's name in
     a device trace: the fused ring's backward step passes its own.  With a
-    ``window`` (no rotation) the grid's query axis is the band's steps."""
+    ``window`` or ``blockdiff`` (no rotation) the grid's query axis is the
+    steps of a key block's walk."""
     bh, sl, d = q.shape
     d_v = v_cur.shape[-1]
     num_q, num_k = sl // block_q, sl // block_k
@@ -752,15 +987,22 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
             np.arange(num_k), block_q, block_k, *band))
 
         def inner_q(qi, ki):
-            first, last = _queries_of_key_block(ki, block_q, block_k, *band)
-            return jnp.minimum(first + qi, last)
+            return _held_tile((_queries_of_key_block(
+                ki, block_q, block_k, *band),), qi)
+    elif blockdiff is not None:
+        q_steps = _walk_steps(_blockdiff_queries_of_key_block(
+            np.arange(num_k), block_q, block_k, *blockdiff))
+
+        def inner_q(qi, ki):
+            return _held_tile(_blockdiff_queries_of_key_block(
+                ki, block_q, block_k, *blockdiff), qi)
 
     kernel = functools.partial(
         _combined_bwd_kernel, causal=causal, block_q=block_q,
         block_k=block_k, num_q_blocks=q_steps, num_k_blocks=num_k, bh=bh,
         rotate=rotate, barrier=rotate and not interpret,
         axis_name=axis_name, mesh_axes=mesh_axes, scale_r=scale_r,
-        dq_scale=dq_scale, band=band)
+        dq_scale=dq_scale, band=band, blockdiff=blockdiff)
 
     def qspec(row, width=d):
         return pl.BlockSpec((1, block_q, width),
@@ -1033,7 +1275,7 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
 
 def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
                     block_k, interpret, scale_r, grad_dtype=jnp.float32,
-                    dq_scale=1.0, window=None):
+                    dq_scale=1.0, window=None, blockdiff=None):
     """Split flash backward over (bh, sl, d) operands (q pre-scaled by
     the pow2 part of sm_scale): two pallas_calls — dk/dv (queries inner)
     and dq (keys inner) — each with O(block) scoped VMEM, so any
@@ -1054,21 +1296,35 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
 
     inner_q = inner_k = lambda i, j: j  # noqa: E731  (innermost grid dim)
     outer = lambda i, j: i  # noqa: E731
-    q_steps, k_steps, band, suffix = num_q, num_k, None, ""
+    q_steps, k_steps, band = num_q, num_k, None
+    suffix = _walk_suffix(window, blockdiff)
     if window is not None:
-        band, suffix = (window, num_q), "_window"
+        band = (window, num_q)
         q_steps = _band_steps(_queries_of_key_block(
             np.arange(num_k), block_q, block_k, *band))
         k_steps = _band_steps(_keys_of_query_block(
             np.arange(num_q), block_q, block_k, window))
 
         def inner_q(ki, j):
-            first, last = _queries_of_key_block(ki, block_q, block_k, *band)
-            return jnp.minimum(first + j, last)
+            return _held_tile((_queries_of_key_block(
+                ki, block_q, block_k, *band),), j)
 
         def inner_k(qi, j):
-            first, last = _keys_of_query_block(qi, block_q, block_k, window)
-            return jnp.minimum(first + j, last)
+            return _held_tile((_keys_of_query_block(
+                qi, block_q, block_k, window),), j)
+    elif blockdiff is not None:
+        q_steps = _walk_steps(_blockdiff_queries_of_key_block(
+            np.arange(num_k), block_q, block_k, *blockdiff))
+        k_steps = _walk_steps(_blockdiff_keys_of_query_block(
+            np.arange(num_q), block_q, block_k, *blockdiff))
+
+        def inner_q(ki, j):
+            return _held_tile(_blockdiff_queries_of_key_block(
+                ki, block_q, block_k, *blockdiff), j)
+
+        def inner_k(qi, j):
+            return _held_tile(_blockdiff_keys_of_query_block(
+                qi, block_q, block_k, *blockdiff), j)
     # vma: inside shard_map (build_train_step) the default check refuses
     # an out_shape that does not say how it varies; as q does.
     grad_shape = jax.ShapeDtypeStruct((bh, sl, d), grad_dtype,
@@ -1077,7 +1333,8 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
                                     vma=jax.typeof(q).vma)
     dkdv = functools.partial(
         _flash_bwd_dkdv_kernel, causal=causal, block_q=block_q,
-        block_k=block_k, num_q_blocks=q_steps, scale_r=scale_r, band=band)
+        block_k=block_k, num_q_blocks=q_steps, scale_r=scale_r, band=band,
+        blockdiff=blockdiff)
     dk, dv = pl.pallas_call(
         dkdv,
         grid=(bh, num_k, q_steps),  # queries innermost
@@ -1093,7 +1350,7 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
     dqk = functools.partial(
         _flash_bwd_dq_kernel, causal=causal, block_q=block_q,
         block_k=block_k, num_k_blocks=k_steps, scale_r=scale_r,
-        dq_scale=dq_scale, window=window)
+        dq_scale=dq_scale, window=window, blockdiff=blockdiff)
     dq = pl.pallas_call(
         dqk,
         grid=(bh, num_q, k_steps),  # keys innermost
@@ -1108,8 +1365,26 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
     return dk, dv, dq
 
 
+def _off_grid(q_len, k_len, block_q, block_k, blockdiff=None) -> bool:
+    """Whether the blocks leave the kernels' grid: ragged tails, blocks off
+    the TPU tiling (the lse output block puts ``block_q`` in the 128-lane
+    dimension) or, under block diffusion, a tile that would lie across the two
+    copies (the blocks divide ONE copy's rows)."""
+    if blockdiff is not None:
+        q_len = k_len = blockdiff[1]
+    return bool(q_len % block_q or k_len % block_k
+                or block_q % 128 or block_k % 128)
+
+
+def _walk_suffix(window, blockdiff) -> str:
+    """What a kernel that walks only part of the blocks carries behind its
+    name in a trace."""
+    return "_blockdiff" if blockdiff is not None \
+        else "" if window is None else "_window"
+
+
 def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
-                    block_k, interpret, window=None):
+                    block_k, interpret, window=None, blockdiff=None):
     """Pallas flash backward.  Two kernel strategies, chosen per shape by
     :func:`_bwd_plan` against the scoped-VMEM ceiling: the combined
     kernel computes dk/dv AND dq from a single probability recompute per
@@ -1120,19 +1395,19 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
     k_len, d_v = k.shape[2], v.shape[-1]
     block_q = min(block_q, q_len)
     block_k = min(block_k, k_len)
-    if (q_len % block_q or k_len % block_k
-            or block_q % 128 or block_k % 128 or q_len != k_len):
+    if _off_grid(q_len, k_len, block_q, block_k, blockdiff) \
+            or q_len != k_len:
         return _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale,
-                                   max(block_k, 128), 0, 0, window)
+                                   max(block_k, 128), 0, 0, window, blockdiff)
     # One width: the call as every caller and test stand-in has known it.
     widths = {} if d_v == d else {"d_v": d_v}
     mode, block_q, block_k = _bwd_plan(q_len, d, block_q, block_k,
                                        batch * heads, **widths)
-    if q_len % block_q or k_len % block_k or block_q % 128 or block_k % 128:
+    if _off_grid(q_len, k_len, block_q, block_k, blockdiff):
         # Plan stepped blocks down past what divides this length (rare
         # non-power-of-two long seqs): the scan impl handles it.
         return _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale,
-                                   max(block_k, 128), 0, 0, window)
+                                   max(block_k, 128), 0, 0, window, blockdiff)
     bh = batch * heads
     # Pre-scaled q (see _flash_forward): exact pow2 factor on q, f32
     # residual inside the kernel; dq comes back in q' units and is
@@ -1164,51 +1439,54 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
             block_q=block_q, block_k=block_k, rotate=False,
             collective_id=None, axis_name=None, mesh_axes=(),
             interpret=interpret, scale_r=scale_r, grad_dtype=grad_dtype,
-            dq_scale=p2, window=window,
+            dq_scale=p2, window=window, blockdiff=blockdiff,
             # A banded call's name keeps the prefix a trace is read by.
-            name="hvd_flash_bwd" + ("" if window is None else "_window"))
+            name="hvd_flash_bwd" + _walk_suffix(window, blockdiff))
     else:
         dk, dv, dq = _split_bwd_call(
             qr, dor, lse8, delta8, kr, vr, causal=causal,
             block_q=block_q, block_k=block_k, interpret=interpret,
             scale_r=scale_r, grad_dtype=grad_dtype, dq_scale=p2,
-            window=window)
+            window=window, blockdiff=blockdiff)
     return (dq.astype(q.dtype).reshape(q.shape),
             dk.astype(k.dtype).reshape(k.shape),
             dv.astype(v.dtype).reshape(v.shape))
 
 
-def _forward_blocks(q_len, k_len, d, d_v, block_q, block_k, window=None):
+def _forward_blocks(q_len, k_len, d, d_v, block_q, block_k, window=None,
+                    blockdiff=None):
     """The (block_q, block_k) the forward kernel runs ``flash_attention``'s
     blocks at, or None where the shape leaves the kernel for the scan."""
     block_q = min(block_q, q_len)
     block_k = min(block_k, k_len)
-    if (q_len % block_q or k_len % block_k
-            or block_q % 128 or block_k % 128
-            or (window is not None and q_len != k_len)):
-        # Ragged tails or blocks off the TPU tiling grid (the lse output
-        # block puts block_q in the 128-lane dimension): the blockwise path
+    if _off_grid(q_len, k_len, block_q, block_k, blockdiff) \
+            or (window is not None and q_len != k_len):
+        # Ragged tails or blocks off the TPU tiling grid: the blockwise path
         # handles them without padding gymnastics (the kernel targets the
         # aligned hot path).
         return None
     # Backstop explicit oversized blocks against the scoped-VMEM budget
     # (the default <=1024 blocks peak ~6 MiB and never clamp).
-    return _clamp_blocks(
+    blocks = _clamp_blocks(
         "forward", q_len, d, block_q, block_k,
         estimate=lambda _m, s, dd, bq, bk: _fwd_vmem_bytes(s, dd, bq, bk,
                                                            d_v))
+    if blockdiff is not None and _off_grid(q_len, k_len, *blocks, blockdiff):
+        return None       # clamped past what divides a copy
+    return blocks
 
 
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                   window=None):
+                   window=None, blockdiff=None):
     """Returns (out, lse); routes off-grid shapes to the blockwise impl."""
     batch, heads, q_len, d = q.shape
     k_len, d_v = k.shape[2], v.shape[-1]
-    blocks = _forward_blocks(q_len, k_len, d, d_v, block_q, block_k, window)
+    blocks = _forward_blocks(q_len, k_len, d, d_v, block_q, block_k, window,
+                             blockdiff)
     if blocks is None:
         return _blockwise_fwd_impl(q, k, v, causal, sm_scale,
                                    max(min(block_k, k_len), 128), 0, 0,
-                                   window)
+                                   window, blockdiff)
     block_q, block_k = blocks
     bh = batch * heads
     # Pre-scale q by the exact power-of-two part of sm_scale: one
@@ -1226,21 +1504,29 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     ospec, vspec = _row_spec(block_q, d_v), _row_spec(block_k, d_v)
     qrow = lambda i, j: i  # noqa: E731
     krow = lambda i, j: j  # noqa: E731
-    name = "hvd_flash_fwd"
+    name = "hvd_flash_fwd" + _walk_suffix(window, blockdiff)
     if window is not None:
         # The key axis is the band's steps; past a band's last block the
         # index stays on it, and an index that does not move copies nothing.
         num_k = _band_steps(_keys_of_query_block(
             np.arange(num_q), block_q, block_k, window))
-        name = "hvd_flash_fwd_window"
 
         def krow(i, j):
-            first, last = _keys_of_query_block(i, block_q, block_k, window)
-            return jnp.minimum(first + j, last)
+            return _held_tile((_keys_of_query_block(
+                i, block_q, block_k, window),), j)
+    elif blockdiff is not None:
+        # The key axis walks a query block's two runs the same way.
+        num_k = _walk_steps(_blockdiff_keys_of_query_block(
+            np.arange(num_q), block_q, block_k, *blockdiff))
+
+        def krow(i, j):
+            return _held_tile(_blockdiff_keys_of_query_block(
+                i, block_q, block_k, *blockdiff), j)
 
     kernel = functools.partial(
         _flash_kernel, causal=causal, block_q=block_q,
-        block_k=block_k, num_k_blocks=num_k, scale_r=scale_r, window=window)
+        block_k=block_k, num_k_blocks=num_k, scale_r=scale_r, window=window,
+        blockdiff=blockdiff)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, num_q, num_k),
@@ -1265,24 +1551,25 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
             lse[:, 0, :].reshape(batch, heads, q_len))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_attention(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                     window):
+                     window, blockdiff):
     return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                          interpret, window)[0]
+                          interpret, window, blockdiff)[0]
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-               window):
+               window, blockdiff):
     out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                              interpret, window)
+                              interpret, window, blockdiff)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window, res, g):
+def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
+               blockdiff, res, g):
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
-                           block_k, interpret, window)
+                           block_k, interpret, window, blockdiff)
 
 
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
@@ -1294,8 +1581,26 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     layout: str = "bhsd",
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    block_diffusion: Optional[int] = None):
     """Fused multi-head attention.
+
+    ``block_diffusion=B`` (with neither ``causal`` nor ``window``): the rows
+    are ``[clean; noised]``, two copies of ``L`` positions each, a block-
+    diffusion model's training pass (:func:`block_diffusion_mask`: a clean
+    query sees the clean keys of its block of ``B`` and of the earlier ones,
+    a noised query the clean keys of the earlier blocks and the noised keys
+    of its own).  The mask is no function of ``t - s``, so a query block's
+    key blocks are TWO runs — the clean ones from 0 on, then its own noised
+    ones — and each kernel's inner grid axis walks them one after the other
+    as a band is walked (a key block's query blocks likewise: its clean ones,
+    then the noised ones of the later diffusion blocks): 24 of the 64 pairs
+    of 1,024-blocks at ``L = 4,096``, where a causal walk over ``2 L`` takes
+    36 (:func:`blockdiff_blocks`).  Blocks the mask cuts are masked inside
+    from block ids, whole ones take the unmasked body.  The blocks divide
+    ``L``; other shapes take the scan.  Kernels: ``hvd_flash_fwd_blockdiff``,
+    ``hvd_flash_bwd_blockdiff``, ``hvd_flash_bwd_dkdv_blockdiff``,
+    ``hvd_flash_bwd_dq_blockdiff``.
 
     ``window=W`` (with ``causal=True``): a sliding window — query ``t`` sees
     the keys ``s`` with ``0 <= t - s < W``, itself and the ``W - 1`` before
@@ -1349,12 +1654,15 @@ def flash_attention(q, k, v, causal: bool = False,
         return t(flash_attention(t(q), t(k), t(v), causal=causal,
                                  sm_scale=sm_scale, block_q=block_q,
                                  block_k=block_k, interpret=interpret,
-                                 window=window))
+                                 window=window,
+                                 block_diffusion=block_diffusion))
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     window = _checked_window(window, causal, k.shape[-2])
+    blockdiff = _checked_block_diffusion(block_diffusion, causal, window,
+                                         q.shape[-2], k.shape[-2])
     if not interpret and jnp.float16 in (q.dtype, k.dtype, v.dtype):
         # float16 is not a native TPU type and Mosaic refuses the kernel
         # outright (verified on v5e: even the forward fails to compile) —
@@ -1362,16 +1670,20 @@ def flash_attention(q, k, v, causal: bool = False,
         # instead of crashing at compile time.  bf16 is the supported
         # half-precision on TPU.
         return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                                   window=window)
+                                   window=window,
+                                   block_diffusion=block_diffusion)
     # An explicit block past _MAX_BLOCK is cut to it as the default is:
     # the chip's compiler refuses both passes there (scoped VMEM: 2048-row
     # blocks at seq 2048 fail the backward, 4096 at seq 4096 the forward —
     # compiled for a described v5e, tests/test_ops.py), whatever the
     # structural estimates say (ADVICE r5 #2).
-    block_q, block_k = _default_blocks(q.shape[-2], k.shape[-2], block_q,
+    # Under block diffusion the default blocks are one copy's.
+    copies = 1 if blockdiff is None else 2
+    block_q, block_k = _default_blocks(q.shape[-2] // copies,
+                                       k.shape[-2] // copies, block_q,
                                        block_k)
     return _flash_attention(q, k, v, causal, sm_scale, block_q, block_k,
-                            interpret, window)
+                            interpret, window, blockdiff)
 
 
 def _default_blocks(q_len, k_len, block_q=None, block_k=None):
@@ -1407,3 +1719,26 @@ def window_blocks(seq: int, window: int, d: int, d_v: Optional[int] = None,
         return causal, causal
     first, last = _keys_of_query_block(rows, *blocks, window)
     return int((last - first + 1).sum()), causal
+
+
+def blockdiff_blocks(seq: int, block: int, d: int, d_v: Optional[int] = None,
+                     block_q: Optional[int] = None,
+                     block_k: Optional[int] = None):
+    """(visited, causal over 2 L): the (query block, key block) pairs one
+    head's FORWARD kernel visits for ``flash_attention(block_diffusion=
+    block)`` over the two copies of ``seq`` rows of width ``d`` under the
+    blocks it takes, and the pairs the causal kernel would visit over the same
+    ``2 seq`` rows under the same blocks — 24 and 36 at 4,096 rows a copy in
+    1,024-blocks, 80 and 136 in 512-blocks.  None where the call leaves the
+    kernel for the scan, which walks every block."""
+    blockdiff = (int(block), seq)
+    blocks = _forward_blocks(2 * seq, 2 * seq, d, d_v or d,
+                             *_default_blocks(seq, seq, block_q, block_k),
+                             blockdiff=blockdiff)
+    if blocks is None:
+        return None
+    rows = np.arange(2 * seq // blocks[0])
+    causal = int((_div(rows * blocks[0] + blocks[0] - 1, blocks[1])
+                  + 1).sum())
+    runs = _blockdiff_keys_of_query_block(rows, *blocks, *blockdiff)
+    return int(sum(last - first + 1 for first, last in runs).sum()), causal

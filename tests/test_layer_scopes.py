@@ -33,6 +33,8 @@ CELLS = {
         "head", "embed", "mlp", "moe", "kda", "mla"},
     "trinitymini_1chip_ep8share_1x8k": {
         "head", "embed", "mlp", "attn_proj", "moe"},
+    "sdar30ba3b_1chip_ep8share_1x4k_noised": {
+        "head", "embed", "attn_proj", "moe"},
 }
 
 _ALIAS = re.compile(r'^(#loc\d*) = loc\((.*)\)$')
@@ -202,3 +204,49 @@ def test_fused_head_and_loss_is_one_loop_under_the_heads_scope():
         assert len(families_in(path)) == 1, (op, path)
     head = [op for op, path in operations if "/hvd_lm_head/" in path]
     assert head.count("dot_general") >= 3 and "gather" in head
+
+
+def test_block_diffusion_step_names_its_loss_and_its_kernels(monkeypatch):
+    """The SDAR cell's step: the masked-diffusion loss runs under
+    `hvd_diffusion_loss`, forward and backward, with the per-token
+    cross-entropy's own scope inside it (the head's family); the attention
+    keeps `hvd_attn_qkv`, `hvd_attn_attend`, `hvd_attn_out`; and the four
+    kernels `ops/attention.py` names under `block_diffusion=` are the ones
+    the benchmark's readers look for, filed under `flash`."""
+    import horovod_tpu.ops.attention as attn
+    from benchmark.layer_metrics import _sdar
+    from tests.test_ops import _pallas_call_names
+
+    text = lowered_step("sdar30ba3b_1chip_ep8share_1x4k_noised").as_text(
+        debug_info=True)
+    paths = scope_paths(text)
+    for direction in (FORWARD, BACKWARD):
+        assert any(direction in path and "/hvd_diffusion_loss/hvd_token_xent/"
+                   in path for path in paths), direction
+        for scope in ("hvd_attn_qkv", "hvd_attn_attend", "hvd_attn_out"):
+            assert any(direction in path and f"/{scope}/" in path
+                       for path in paths), (direction, scope)
+    assert _layers.layer_of(
+        "jit(f)/jvp(hvd_loss)/hvd_diffusion_loss/hvd_token_xent/exp") == "head"
+
+    shape = jax.ShapeDtypeStruct((1, 2, 512, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return attn.flash_attention(q, k, v, block_diffusion=4,
+                                    interpret=True).astype(jnp.float32).sum()
+
+    names = set()
+    for plan in ("combined", "split"):
+        monkeypatch.setattr(attn, "_bwd_plan", lambda q_len, d, bq, bk, bh=1,
+                            plan=plan: (plan, min(bq, 128), min(bk, 128)))
+        names |= set(_pallas_call_names(jax.make_jaxpr(jax.grad(
+            loss, (0, 1, 2)))(shape, shape, shape).jaxpr))
+    assert names == {"hvd_flash_fwd_blockdiff", "hvd_flash_bwd_blockdiff",
+                     "hvd_flash_bwd_dkdv_blockdiff",
+                     "hvd_flash_bwd_dq_blockdiff"}
+    for name in names:
+        direction = "fwd" if "fwd" in name else "bwd"
+        assert _sdar.BLOCKDIFF[direction].match(name + ".7"), name
+        assert not _sdar.BLOCKDIFF[direction].match(
+            name.replace("_blockdiff", "_window") + ".7")
+        assert _layers.column_of(f"{name}.7|custom-call|x", None) == "flash"

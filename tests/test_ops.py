@@ -875,6 +875,109 @@ def test_trinity_step_is_banded_and_causal_kernels_and_a_named_gate(
                "hvd_embed", "hvd_lm_head"))
 
 
+@pytest.mark.parametrize("plan", ["combined", "split"])
+@pytest.mark.parametrize("block", [4, 32, 96])
+def test_blockdiff_flash_at_sdar_shape_compiles(v5e, monkeypatch, plan,
+                                                block):
+    """The sdar30ba3b cell's attention: 1 x 32 heads of 128 over the two
+    copies of 4,096 rows under the block mask.  The forward (1,024-tiles, a
+    walk of 5 key tiles: four clean, the tile's own noised one), the combined
+    backward the plan gives the 8,192 rows ((512, 512), a walk of 16) and the
+    split pair at 1,024-tiles compile for the described chip at the cell's
+    block length, at 32, and at a length that is no power of two: index maps
+    that walk two runs, masks from block ids on a column and a row."""
+    import horovod_tpu.ops.attention as attn
+    from jax.sharding import SingleDeviceSharding
+
+    assert attn._bwd_plan(8192, 128, 1024, 1024, 32) == ("combined", 512, 512)
+    if plan == "split":
+        monkeypatch.setattr(attn, "_bwd_plan",
+                            lambda q_len, d, bq, bk, bh=1: ("split", bq, bk))
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e[0]))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, block_diffusion=block,
+                               interpret=False).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).compile().as_text()
+    names = {"combined": ("hvd_flash_fwd_blockdiff",
+                          "hvd_flash_bwd_blockdiff"),
+             "split": ("hvd_flash_fwd_blockdiff",
+                       "hvd_flash_bwd_dkdv_blockdiff",
+                       "hvd_flash_bwd_dq_blockdiff")}[plan]
+    for kernel in names:
+        assert len(re.findall(rf"%\w*?_{kernel}_*\.\d+ = ", text)) == 1, kernel
+    assert text.count('"tpu_custom_call"') == len(names)
+
+
+def test_sdar_step_is_blockdiff_kernels_and_a_named_loss(v5e, monkeypatch):
+    """Two published layers of SDAR's pattern (block-diffusion attention at
+    heads of 128 on 2 key/value heads with the per-head norms, softmax-routed
+    experts with renormalised weights) through `build_train_step` with
+    `masked_diffusion_loss`, compiled for the described chip: every attention
+    kernel is a block-diffusion one, no causal kernel and no loop is in the
+    text, the head's product has the noised half's rows alone, and the loss's
+    scope is in the text forward and backward beside the attention's."""
+    import optax
+    from jax.sharding import NamedSharding
+
+    from horovod_tpu.jax.train import build_train_step
+    from horovod_tpu.models import (MoEConfig, TransformerLM,
+                                    masked_diffusion_loss)
+    from horovod_tpu.parallel import data_parallel_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(
+        vocab_size=2048, d_model=512, n_heads=8, dtype=jnp.bfloat16,
+        logits_dtype=jnp.bfloat16, use_flash=True,
+        layers=("blockdiff_attention", "experts") * 2, n_kv_heads=2,
+        head_dim=128, head_norm=True, block_diffusion=4, rope_theta=1e6,
+        moe=MoEConfig(64, 8, 256, (0, 8), 1.5, renormalize=True))
+    mesh = data_parallel_mesh(v5e[:1], axis_name="hvd")
+    tx = optax.adamw(1e-4)
+
+    def loss_fn(params, batch):
+        tokens, noised, masked, level = batch
+        return masked_diffusion_loss(
+            model.apply({"params": params}, tokens, noised=noised), tokens,
+            masked, level)
+
+    def init(key):
+        blank = jnp.zeros((1, 128), jnp.int32)
+        params = model.init(key, blank, noised=blank)["params"]
+        return params, tx.init(params)
+
+    def shaped(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+
+    params, opt_state = shaped(jax.eval_shape(init, jax.random.PRNGKey(0)),
+                               P())
+    rows = 1024
+    tokens, masked, level = (shaped(jax.ShapeDtypeStruct((1, rows), dtype),
+                                    P("hvd"))
+                             for dtype in (jnp.int32, jnp.bool_, jnp.float32))
+    step = build_train_step(loss_fn, tx, mesh, axis_name="hvd",
+                            batch_spec=(P("hvd"),) * 4)
+    text = step.lower(params, opt_state,
+                      (tokens, tokens, masked, level)).compile().as_text()
+    assert len(re.findall(r"\bwhile\(", text)) == 0
+    for kernel in ("hvd_flash_fwd_blockdiff", "hvd_flash_bwd_blockdiff"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 2, kernel
+    assert not re.search(r"%hvd_flash_(fwd|bwd)[.\d]* = ", text)
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 18
+    # The head over the noised half: logits of 1,024 rows, not 2,048.
+    assert re.search(r"bf16\[(1,)?1024,2048\]", text)
+    assert not re.search(r"bf16\[(1,)?2048,2048\]", text)
+    _assert_scopes_forward_and_backward(
+        text, ("hvd_attn_qkv", "hvd_attn_attend", "hvd_attn_out",
+               "hvd_moe_router", "hvd_embed", "hvd_lm_head",
+               "hvd_diffusion_loss"))
+
+
 @pytest.mark.parametrize("mode", ["combined", "split"])
 def test_flash_inside_shard_map_default_vma_check(monkeypatch, mode):
     """flash_attention is called inside build_train_step's shard_map, whose

@@ -1,0 +1,631 @@
+"""What SDAR's block-diffusion training adds — a block mask over `[clean;
+noised]` rows in the flash kernels, which walk only the tiles it touches (two
+runs of key tiles a query tile, not one band), `Attention` and `TransformerLM`
+with `block_diffusion=`, a head over the noised half alone and
+`masked_diffusion_loss` — against the plain float32 reference the benchmark
+keeps (benchmark/reference/sdar_lm.py): a dense softmax under an explicit
+mask, a head at a time, the key/value head by index, a loop over the shard's
+experts.  CPU, float32, seeded weights, small sizes; the kernels interpreted.
+
+Tolerances: as tests/test_trinity.py's, and for its reasons.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+
+import horovod_tpu.ops.attention as attn
+from benchmark import ops_count_sdar
+from benchmark.reference import compare, sdar_lm as reference
+from horovod_tpu.jax.train import build_train_step
+from horovod_tpu.models import (MoEConfig, TransformerLM,
+                                masked_diffusion_loss, next_token_loss,
+                                record_attention_blocks)
+from horovod_tpu.models.transformer import (LAYER_KINDS, Attention,
+                                            MixerLayer, SparseExperts)
+from horovod_tpu.ops import (blockwise_attention, flash_attention,
+                             mha_reference)
+from horovod_tpu.ops.attention import blockdiff_blocks
+from tests.test_hybrid import (both_ways, close, spread, trees_close,
+                               with_highest)
+from tests.test_ops import _pallas_call_names
+from tests.test_trinity import pallas_calls, plan_of, qkv
+
+RTOL = 2e-5
+VOCAB, HIDDEN, SEQ, HEADS, KV_HEADS, HEAD_DIM = 256, 64, 128, 8, 2, 16
+BLOCK, THETA, EPS = 4, 1e6, 1e-6
+EXPERTS, PER_TOKEN, WIDTH, DEPTH = 16, 4, 48, 2
+LAYERS = ("blockdiff_attention", "experts") * DEPTH
+
+
+def moe(shard=(0, 1), row_bound=None, experts=EXPERTS):
+    return MoEConfig(experts, PER_TOKEN, WIDTH, shard, row_bound,
+                     renormalize=True)
+
+
+def lm(expert_shard=(0, 1), use_flash=False, vocab=VOCAB):
+    return TransformerLM(
+        vocab_size=vocab, d_model=HIDDEN, n_heads=HEADS, dtype=jnp.float32,
+        use_flash=use_flash, norm_eps=EPS, moe=moe(expert_shard),
+        layers=LAYERS, n_kv_heads=KV_HEADS, head_dim=HEAD_DIM, head_norm=True,
+        block_diffusion=BLOCK, rope_theta=THETA)
+
+
+def reference_config(expert_shard=(0, 1), **more):
+    return dict(block_length=BLOCK, rope_theta=THETA, norm_eps=EPS,
+                num_experts=EXPERTS, experts_per_token=PER_TOKEN,
+                expert_shard=expert_shard, **more)
+
+
+def noised_batch(key, batch=2, seq=SEQ, block=BLOCK, vocab=VOCAB):
+    """(tokens, noised, masked, level) as benchmark/builders/sdar_lm.py makes
+    them: one level a block, one draw a token."""
+    keys = jax.random.split(key, 3)
+    tokens = jax.random.randint(keys[0], (batch, seq), 0, vocab - 1)
+    level = 1e-3 + (1 - 1e-3) * jnp.repeat(
+        jax.random.uniform(keys[1], (batch, seq // block)), block, axis=1)
+    masked = jax.random.uniform(keys[2], (batch, seq)) < level
+    return tokens, jnp.where(masked, vocab - 1, tokens), masked, level
+
+
+def seeded(model, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    batch = noised_batch(keys[0])
+    params = spread(model.init(keys[1], batch[0], noised=batch[1])["params"],
+                    seed)
+    return params, batch
+
+
+def system_loss(model, params, batch):
+    tokens, noised, masked, level = batch
+    return masked_diffusion_loss(
+        model.apply({"params": params}, tokens, noised=noised), tokens,
+        masked, level)
+
+
+# --- the mask and the kernels ------------------------------------------------
+
+def seen_by_hand(length, block):
+    """Written out here, a third time, a pair at a time."""
+    seen = np.zeros((2 * length, 2 * length), bool)
+    for r in range(2 * length):
+        for c in range(2 * length):
+            r_block, c_block = (r % length) // block, (c % length) // block
+            if r < length:
+                seen[r, c] = c < length and c_block <= r_block
+            elif c < length:
+                seen[r, c] = c_block < r_block
+            else:
+                seen[r, c] = c_block == r_block
+    return seen
+
+
+def masked_softmax(q, k, v, block):
+    seen = jnp.asarray(seen_by_hand(q.shape[2] // 2, block))
+    scores = jnp.einsum("bhqe,bhke->bhqk", q, k,
+                        precision="highest") * q.shape[-1] ** -0.5
+    return jnp.einsum("bhqk,bhke->bhqe", jax.nn.softmax(
+        jnp.where(seen, scores, -jnp.inf), axis=-1), v, precision="highest")
+
+
+@pytest.mark.parametrize("length,block", [(16, 4), (24, 8), (20, 6), (8, 8)])
+def test_the_three_masks_are_one(length, block):
+    rows = np.arange(2 * length)
+    want = seen_by_hand(length, block)
+    np.testing.assert_array_equal(attn.block_diffusion_mask(
+        jnp.asarray(rows)[:, None], jnp.asarray(rows)[None, :], block,
+        length), want)
+    np.testing.assert_array_equal(reference.seen(
+        jnp.asarray(rows), jnp.asarray(rows), length, block), want)
+    # A row sees itself, a noised row its whole block, and the exact count.
+    assert want.diagonal().all()
+    if length % block == 0:
+        assert want.sum() == ops_count_sdar.blockdiff_pairs(length, block)
+
+
+# copy length, block length, block_q, block_k, head width: block lengths of 4
+# and 32, tiles that hold whole blocks and tiles that cut them (96 and 192 in
+# 128-tiles, 256 in two), unequal tiles both ways, two head widths.
+MASKS = [(256, 4, 128, 128, 64), (256, 32, 128, 128, 128),
+         (512, 4, 256, 128, 64), (512, 32, 128, 256, 128),
+         (384, 96, 128, 128, 64), (384, 192, 128, 128, 64),
+         (256, 256, 128, 128, 64), (512, 4, 512, 512, 64)]
+
+
+@pytest.mark.parametrize("path", ["combined", "split", "blockwise"])
+@pytest.mark.parametrize("length,block,block_q,block_k,d", MASKS, ids=str)
+def test_block_diffusion_attention_is_the_masked_softmax(
+        monkeypatch, length, block, block_q, block_k, d, path):
+    q, k, v, mix = qkv(2 * length, d, seed=block)
+    if path == "blockwise":
+        def masked(q, k, v):
+            return blockwise_attention(q, k, v, block_diffusion=block,
+                                       block_size=block_k)
+    else:
+        plan_of(monkeypatch, path, 256)
+
+        def masked(q, k, v):
+            return flash_attention(q, k, v, block_diffusion=block,
+                                   block_q=block_q, block_k=block_k,
+                                   interpret=True)
+
+        program = jax.make_jaxpr(jax.grad(
+            lambda *a: (masked(*a) * mix).sum(), (0, 1, 2)))(q, k, v)
+        names = set(_pallas_call_names(program.jaxpr))
+        assert names and all(n.endswith("_blockdiff") for n in names), names
+
+    def total(fn):
+        return lambda *a: (fn(*a) * mix).sum()
+
+    def plain(q, k, v):
+        return masked_softmax(q, k, v, block)
+
+    close(masked(q, k, v), plain(q, k, v))
+    close(mha_reference(q, k, v, block_diffusion=block), plain(q, k, v))
+    got = jax.grad(total(masked), (0, 1, 2))(q, k, v)
+    want = jax.grad(total(plain), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        close(g, w)
+
+
+@pytest.mark.parametrize("length,block", [(200, 4), (192, 32)], ids=str)
+def test_a_copy_no_tile_divides_takes_the_scan(length, block):
+    """200 rows a copy are no whole 128-tiles; 192 are one and a half: a tile
+    would lie across the two copies."""
+    q, k, v, mix = qkv(2 * length, 64, seed=3)
+
+    def masked(q, k, v):
+        return flash_attention(q, k, v, block_diffusion=block, interpret=True)
+
+    program = jax.make_jaxpr(jax.grad(lambda *a: (masked(*a) * mix).sum(),
+                                      (0, 1, 2)))(q, k, v)
+    assert _pallas_call_names(program.jaxpr) == []
+    close(masked(q, k, v), masked_softmax(q, k, v, block))
+    assert blockdiff_blocks(length, block, 64) is None
+
+
+@pytest.mark.parametrize("wrong", [dict(causal=True), dict(window=8),
+                                   dict(block_diffusion=0)], ids=str)
+def test_block_diffusion_is_a_mask_of_its_own(wrong):
+    q, k, v, _ = qkv(128, 64)
+    for fn in (flash_attention, blockwise_attention, mha_reference):
+        with pytest.raises(ValueError, match="block_diffusion|window"):
+            fn(q, k, v, **{"block_diffusion": 4, **wrong})
+    with pytest.raises(ValueError, match="block_diffusion"):
+        flash_attention(q[:, :, :127], k[:, :, :127], v[:, :, :127],
+                        block_diffusion=4)
+
+
+def touching(length, block, block_q, block_k):
+    """By brute force over positions: the (query tile, key tile) pairs that
+    hold at least one seen (row, column)."""
+    seen = seen_by_hand(length, block)
+    return {(i, j) for i in range(2 * length // block_q)
+            for j in range(2 * length // block_k)
+            if seen[i * block_q:(i + 1) * block_q,
+                    j * block_k:(j + 1) * block_k].any()}
+
+
+# The cell's shape cut to an eighth in both tile sizes (the same 24 of 36 and
+# 80 of 136 tiles), blocks that tiles cut, unequal tiles both ways, a block as
+# long as the copy.
+WALKS = [(512, 4, 64, 64), (512, 4, 128, 128), (512, 32, 128, 128),
+         (512, 96, 128, 128), (512, 4, 128, 256), (512, 32, 256, 128),
+         (256, 256, 128, 128), (512, 200, 128, 128)]
+
+
+@pytest.mark.parametrize("length,block,block_q,block_k", WALKS, ids=str)
+def test_the_kernels_visit_exactly_the_masks_tiles(length, block, block_q,
+                                                   block_k):
+    """Every kernel's index maps and its `run` predicate, walked over the
+    grid as Pallas walks it: the steps that compute are the tile pairs the
+    mask touches, once each; a tile is fetched when the index moves, never
+    for a pair the mask does not touch; a tile the kernels call whole holds
+    no hidden pair; and the count is what `blockdiff_blocks` reports."""
+    want = touching(length, block, block_q, block_k)
+    seen = seen_by_hand(length, block)
+    num_q, num_k = 2 * length // block_q, 2 * length // block_k
+    every_q, every_k = np.arange(num_q), np.arange(num_k)
+    # forward and dq: a query tile's two runs of key tiles
+    runs = attn._blockdiff_keys_of_query_block(every_q, block_q, block_k,
+                                               block, length)
+    steps = attn._walk_steps(runs)
+    ran, fetched = [], []
+    for j in range(steps):
+        tile, live = attn._tile_of_step(runs, np.full(num_q, j))
+        assert (tile == attn._held_tile(runs, jnp.full(num_q, j))).all()
+        ran += [(i, int(tile[i])) for i in every_q if live[i]]
+        fetched.append(tile)
+    assert len(ran) == len(set(ran)) and set(ran) == want
+    moved = {(i, int(t)) for i in every_q for t in np.stack(fetched)[:, i]}
+    assert moved == want
+    # dk/dv and the combined backward: a key tile's two runs of query tiles
+    runs = attn._blockdiff_queries_of_key_block(every_k, block_q, block_k,
+                                                block, length)
+    steps_q = attn._walk_steps(runs)
+    ran_q = []
+    for i in range(steps_q):
+        tile, live = attn._tile_of_step(runs, np.full(num_k, i))
+        ran_q += [(int(tile[j]), j) for j in every_k if live[j]]
+    assert len(ran_q) == len(set(ran_q)) and set(ran_q) == want
+    # whole tiles, by the kernels' own scalar test
+    for i, j in want:
+        whole = bool(attn._blockdiff_whole(
+            jnp.int32(i * block_q), jnp.int32(j * block_k), block_q, block_k,
+            block, length))
+        assert whole == seen[i * block_q:(i + 1) * block_q,
+                             j * block_k:(j + 1) * block_k].all(), (i, j)
+    if block_q % 128 == 0 and block_k % 128 == 0:
+        rows = 2 * length
+        causal = sum((i * block_q + block_q - 1) // block_k + 1
+                     for i in range(num_q))
+        assert blockdiff_blocks(length, block, 128, block_q=block_q,
+                                block_k=block_k) == (len(want), causal)
+        # The grids are the walks', not the rows'.
+        shape = jax.ShapeDtypeStruct((1, 2, rows, 128), jnp.bfloat16)
+
+        def loss(q, k, v):
+            return flash_attention(
+                q, k, v, block_diffusion=block, block_q=block_q,
+                block_k=block_k, interpret=True).astype(jnp.float32).sum()
+
+        grids = pallas_calls(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
+            shape, shape, shape).jaxpr)
+        assert grids["hvd_flash_fwd_blockdiff"] == (2, num_q, steps)
+        _, plan_q, plan_k = attn._bwd_plan(rows, 128, block_q, block_k, 2)
+        plan_steps = attn._walk_steps(attn._blockdiff_queries_of_key_block(
+            np.arange(rows // plan_k), plan_q, plan_k, block, length))
+        backward = {n: g for n, g in grids.items() if "bwd" in n}
+        assert backward and all(n.endswith("_blockdiff") for n in backward)
+        for name, grid in backward.items():
+            assert grid == (2, rows // plan_k, plan_steps), (name, grid)
+
+
+def test_the_cells_counts():
+    assert blockdiff_blocks(4096, 4, 128) == (24, 36)
+    assert blockdiff_blocks(4096, 4, 128, block_q=512, block_k=512) \
+        == (80, 136)
+    assert blockdiff_blocks(4096, 32, 128) == (24, 36)
+    assert ops_count_sdar.blockdiff_pairs(4096, 4) == 4096 ** 2 + 4096 * 4
+
+
+@pytest.mark.parametrize("plan", ["combined", "split"])
+def test_causal_and_windowed_calls_keep_their_kernels(monkeypatch, plan):
+    """The calls the other cells make name the kernels they named, on the
+    grids they had: nothing of theirs walks two runs."""
+    plan_of(monkeypatch, plan, 128)
+    shape = jax.ShapeDtypeStruct((1, 2, 512, 64), jnp.bfloat16)
+
+    def calls(**mask):
+        def loss(q, k, v):
+            return flash_attention(q, k, v, interpret=True, block_q=128,
+                                   block_k=128,
+                                   **mask).astype(jnp.float32).sum()
+        return pallas_calls(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
+            shape, shape, shape).jaxpr)
+
+    backward = {"combined": ["hvd_flash_bwd"],
+                "split": ["hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"]}[plan]
+    # 256 rows a copy in 128-tiles: a query tile's walk is 3 key tiles at
+    # the most (two clean, its own noised), a clean key tile's 4 query tiles.
+    for mask, suffix, inner in (
+            (dict(causal=True), "", {4}), (dict(), "", {4}),
+            (dict(causal=True, window=128), "_window", {2}),
+            (dict(block_diffusion=4), "_blockdiff", {3, 4})):
+        grids = calls(**mask)
+        assert set(grids) == {n + suffix
+                              for n in ["hvd_flash_fwd"] + backward}
+        assert {g[2] for g in grids.values()} <= inner, (mask, grids)
+
+
+# --- the layers --------------------------------------------------------------
+
+def case(module, seed, rows=2 * SEQ):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    u = jax.random.normal(keys[0], (2, rows, HIDDEN))
+    params = spread(module.init(keys[1], u)["params"], seed)
+    return u, params, jax.random.normal(keys[2], u.shape)
+
+
+@pytest.mark.parametrize("use_flash", [False, True])
+def test_attention_is_the_reference(use_flash):
+    """Rows i and L + i at position i, rotary base 1e6, the block mask, a norm
+    a head, 8 query heads on 2 key/value heads, no gate."""
+    layer = Attention(HEADS, jnp.float32, use_flash=use_flash, norm_eps=EPS,
+                      n_kv_heads=KV_HEADS, head_dim=HEAD_DIM, head_norm=True,
+                      block_diffusion=BLOCK, rope_theta=THETA)
+    u, params, mix = case(layer, seed=3)
+    assert set(params) == {"q_kernel", "kv_kernel", "q_head_norm_scale",
+                           "k_head_norm_scale", "o_kernel"}
+    both_ways(lambda p, u: layer.apply({"params": p}, u),
+              lambda p, u: reference.attention_layer(
+                  u, p, block_length=BLOCK, rope_theta=THETA, norm_eps=EPS),
+              u, params, mix)
+
+
+def test_the_rotary_base_is_the_models():
+    """Base 1e6 and base 1e4 differ, and the default is the old 1e4."""
+    def layer(**theta):
+        return Attention(HEADS, jnp.float32, use_flash=False,
+                         block_diffusion=BLOCK, **theta)
+    u, params, _ = case(layer(), seed=1)
+    old = layer().apply({"params": params}, u)
+    close(layer(rope_theta=10000.0).apply({"params": params}, u), old)
+    new = layer(rope_theta=THETA).apply({"params": params}, u)
+    assert float(jnp.abs(new - old).max()) > 1e-3
+
+
+def test_the_kinds_and_what_they_want():
+    assert LAYER_KINDS["blockdiff_attention"] == "Attention"
+    x = jnp.zeros((1, 2 * SEQ, HIDDEN))
+    with pytest.raises(ValueError, match="block_diffusion="):
+        MixerLayer("blockdiff_attention", HEADS).init(jax.random.PRNGKey(0), x)
+    with pytest.raises(ValueError, match="block_diffusion="):
+        Attention(HEADS, block_diffusion=4, window=8).init(
+            jax.random.PRNGKey(0), x)
+    model, tokens = lm(), jnp.zeros((1, SEQ), jnp.int32)
+    with pytest.raises(ValueError, match="noised="):
+        model.init(jax.random.PRNGKey(0), tokens)
+    with pytest.raises(ValueError, match="noised="):
+        lm().clone(block_diffusion=None).init(jax.random.PRNGKey(0), tokens,
+                                              noised=tokens)
+
+
+# --- the loss ----------------------------------------------------------------
+
+def test_every_token_masked_at_level_one_is_the_mean_cross_entropy():
+    logits = jax.random.normal(jax.random.PRNGKey(0), (2, SEQ, VOCAB))
+    targets = jax.random.randint(jax.random.PRNGKey(1), (2, SEQ), 0, VOCAB)
+    ones = jnp.ones((2, SEQ))
+    np.testing.assert_allclose(
+        masked_diffusion_loss(logits, targets, ones.astype(bool), ones),
+        next_token_loss(logits, targets), rtol=1e-6)
+
+
+def test_the_loss_weighs_masked_tokens_by_their_level():
+    keys = jax.random.split(jax.random.PRNGKey(2), 2)
+    logits = jax.random.normal(keys[0], (2, SEQ, VOCAB))
+    tokens, _, masked, level = noised_batch(keys[1])
+    xent = -jnp.take_along_axis(jax.nn.log_softmax(logits), tokens[..., None],
+                                -1)[..., 0]
+    want = (xent * masked / level).sum(-1).mean() / SEQ
+    np.testing.assert_allclose(
+        masked_diffusion_loss(logits, tokens, masked, level), want, rtol=1e-5)
+    hidden = jax.random.normal(keys[0], (2, SEQ, HIDDEN))
+    head = jax.random.normal(keys[1], (HIDDEN, VOCAB)) * HIDDEN ** -0.5
+    np.testing.assert_allclose(
+        masked_diffusion_loss(hidden @ head, tokens, masked, level),
+        with_highest(reference.diffusion_loss)(hidden, head, tokens, masked,
+                                               level), rtol=1e-5)
+
+
+# --- the model ---------------------------------------------------------------
+
+@pytest.mark.parametrize("expert_shard", [(0, 1), (1, 4)])
+@pytest.mark.parametrize("use_flash", [False, True])
+def test_sdar_lm_loss_and_gradients_are_the_references(expert_shard,
+                                                       use_flash):
+    model = lm(expert_shard, use_flash)
+    params, batch = seeded(model, seed=expert_shard[1])
+    config = reference_config(expert_shard)
+    got, got_grads = jax.jit(jax.value_and_grad(
+        lambda p: system_loss(model, p, batch)))(params)
+    want, want_grads = with_highest(jax.value_and_grad(
+        lambda p: reference.loss(p, batch, **config)))(params)
+    np.testing.assert_allclose(got, want, rtol=RTOL)
+    trees_close(got_grads, want_grads, 1e-4)
+    _, wrote = model.apply({"params": params}, batch[0], noised=batch[1],
+                           mutable=["intermediates"])
+    chose = jnp.stack([wrote["intermediates"][f"layer_{i}"]["mixer"][
+        "chosen_experts"][0] for i in range(1, 2 * DEPTH, 2)])
+    want = with_highest(reference.loss_and_chosen)(params, batch, **config)[1]
+    np.testing.assert_array_equal(jnp.sort(chose, -1), jnp.sort(want, -1))
+
+
+def test_the_head_runs_on_the_noised_half():
+    """Logits for L rows, and none of them moves with the clean copy's LAST
+    block (no later block's noised query sees it), while the noised copy's
+    rows do move their own block's."""
+    model = lm()
+    params, (tokens, noised, _, _) = seeded(model, seed=5)
+    apply = jax.jit(lambda t, n: model.apply({"params": params}, t, noised=n))
+    logits = apply(tokens, noised)
+    assert logits.shape == (2, SEQ, VOCAB)
+    other_tail = tokens.at[:, -BLOCK:].set((tokens[:, -BLOCK:] + 1) % VOCAB)
+    close(apply(other_tail, noised), logits)
+    other_head = tokens.at[:, :BLOCK].set((tokens[:, :BLOCK] + 1) % VOCAB)
+    moved = jnp.abs(apply(other_head, noised) - logits).max(-1)
+    assert float(moved[:, :BLOCK].max()) == 0.0        # its own block: unseen
+    assert float(moved[:, BLOCK:].min()) > 0.0
+    other_noise = noised.at[:, 0].set((noised[:, 0] + 1) % VOCAB)
+    moved = jnp.abs(apply(tokens, other_noise) - logits).max(-1)
+    assert float(moved[:, :BLOCK].min()) > 0.0
+    assert float(moved[:, BLOCK:].max()) == 0.0
+
+
+def test_block_diffusion_layers_count_their_tiles():
+    model = lm(use_flash=True)
+    params, batch = seeded(model, seed=4)
+    _, wrote = model.apply({"params": params}, batch[0], noised=batch[1],
+                           mutable=["intermediates"])
+    seen = record_attention_blocks(wrote["intermediates"])
+    # 128 rows a copy are one 128-tile each: clean on clean, noised on clean,
+    # noised on noised; a causal walk over the 256 rows visits as many.
+    assert seen == {"blocks_visited": [3] * DEPTH,
+                    "blocks_causal": [3] * DEPTH}
+    assert blockdiff_blocks(SEQ, BLOCK, HEAD_DIM) == (3, 3)
+
+
+def test_trains_through_build_train_step_and_replicas_stay_equal():
+    """Two CPU devices, data parallel: the dense LM's step with the pattern
+    and the block-diffusion flash kernels (interpreted here) as in the
+    benchmark.  The replicated weights stay equal and the loss of a repeated
+    batch falls."""
+    model = lm((0, 4), use_flash=True)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("hvd",))
+    params, batch = seeded(model, seed=3)
+    tx = optax.adamw(1e-2)
+    step = build_train_step(lambda p, b: system_loss(model, p, b), tx, mesh,
+                            axis_name="hvd", batch_spec=(P("hvd"),) * 4)
+    state = (params, tx.init(params))
+    losses = []
+    for _ in range(4):
+        *state, loss = step(*state, batch)
+        losses.append(float(loss))
+    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+    for leaf in jax.tree.leaves(state[0]):
+        first, second = (np.asarray(s.data) for s in leaf.addressable_shards)
+        np.testing.assert_array_equal(first, second)
+
+
+# --- the shares add up to the uncut layer ------------------------------------
+
+@pytest.mark.parametrize("n,experts", [(4, EXPERTS), (8, EXPERTS), (8, 128)])
+def test_expert_shares_add_up_with_the_router_counted_once(n, experts):
+    """The n shares' outputs sum to the uncut reference's layer: softmax over
+    all experts and the renormalised weights on every share, each expert on
+    one.  8 shares of 16 experts: the deployment's count."""
+    whole = SparseExperts(moe(experts=experts), jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(n), 2)
+    u = jax.random.normal(keys[0], (2, SEQ, HIDDEN))
+    params = whole.init(keys[1], u)["params"]
+    local = experts // n
+    parts = []
+    for i in range(n):
+        held = slice(i * local, (i + 1) * local)
+        share = dict(params, **{name: params[name][held] for name in (
+            "gate_kernel", "up_kernel", "down_kernel")})
+        parts.append(jax.jit(SparseExperts(
+            moe((i, n), experts=experts), jnp.float32).apply)(
+                {"params": share}, u))
+    flat = u.reshape(-1, HIDDEN)
+    weights, chosen = with_highest(reference.router)(
+        flat, params["router_kernel"], experts_per_token=PER_TOKEN)
+    np.testing.assert_allclose(weights.sum(-1), 1.0, rtol=1e-5)
+    want = with_highest(reference.experts_of_shard)(flat, params, weights,
+                                                    chosen, 0)
+    close(sum(parts), want.reshape(u.shape))
+
+
+def test_the_layers_shares_add_up_with_attention_counted_once():
+    """One published layer: every share computes the attention alike (counted
+    once) and its own experts; attention's output plus the shares' expert
+    outputs is the uncut reference's layer."""
+    n = 4
+    attention = MixerLayer("blockdiff_attention", HEADS, jnp.float32, False,
+                           norm_eps=EPS, n_kv_heads=KV_HEADS,
+                           head_dim=HEAD_DIM, head_norm=True,
+                           block_diffusion=BLOCK, rope_theta=THETA)
+
+    def experts(shard):
+        return MixerLayer("experts", HEADS, jnp.float32, False, moe(shard),
+                          norm_eps=EPS)
+
+    x, p_attention, _ = case(attention, seed=7)
+    p_experts = spread(experts((0, 1)).init(jax.random.PRNGKey(8),
+                                            x)["params"], 8)
+    after = attention.apply({"params": p_attention}, x)
+    local = EXPERTS // n
+    total = after
+    for i in range(n):
+        held = slice(i * local, (i + 1) * local)
+        mixer = dict(p_experts["mixer"], **{
+            name: p_experts["mixer"][name][held]
+            for name in ("gate_kernel", "up_kernel", "down_kernel")})
+        total = total + experts((i, n)).apply(
+            {"params": dict(p_experts, mixer=mixer)}, after) - after
+    want = with_highest(reference.layer)(
+        x, p_attention, p_experts, **reference_config())[0]
+    close(total, want)
+
+
+@pytest.mark.parametrize("n", [2, 8])
+def test_vocabulary_slices_concatenate_to_the_uncut_head(n):
+    """A sliced vocabulary is a smaller vocabulary: the i-th slice's model
+    gives, for ids of the slice, the uncut model's logits of those columns."""
+    model = lm()
+    params, _ = seeded(model)
+    rows = VOCAB // n
+    whole = jax.jit(lambda p, t, m: model.apply({"params": p}, t, noised=m))
+    small = lm(vocab=rows)
+    sliced = jax.jit(lambda p, t, m: small.apply({"params": p}, t, noised=m))
+    for i in range(n):
+        tokens, noised, _, _ = noised_batch(jax.random.PRNGKey(9), vocab=rows)
+        held = slice(i * rows, (i + 1) * rows)
+        share = dict(params,
+                     embed={"embedding": params["embed"]["embedding"][held]},
+                     lm_head_kernel=params["lm_head_kernel"][:, held])
+        close(sliced(share, tokens, noised),
+              whole(params, tokens + i * rows, noised + i * rows)[..., held])
+
+
+# --- the reference refuses the wrong programs --------------------------------
+
+def probe_rows(kernel, length=256):
+    """The builder's kernel comparison at a small size: `kernel` against the
+    reference's masked softmax under the sharpened scale."""
+    sharp = reference.SHARP_SCALE * HEAD_DIM ** -0.5
+    return compare.kernel_against(
+        lambda q, k, v: kernel(q, k, v, sharp),
+        lambda q, k, v: reference.masked_attention(
+            q, k, v, block_length=BLOCK, sm_scale=sharp),
+        (1, 4, 2 * length, HEAD_DIM), jnp.float32, 7,
+        reference.BLOCKDIFF_FWD_ATOL, reference.BLOCKDIFF_GRAD_RTOL,
+        "blockdiff_flash_")
+
+
+def test_the_kernels_pass_the_builders_own_rows():
+    rows = probe_rows(lambda q, k, v, scale: flash_attention(
+        q, k, v, block_diffusion=BLOCK, sm_scale=scale, block_q=128,
+        block_k=128, interpret=True))
+    assert len(rows) == 4 and all(row["value"] < 1e-4 * row["limit"]
+                                  for row in rows), rows
+
+
+@pytest.mark.parametrize("wrong", [dict(causal=True),
+                                   dict(block_diffusion=2 * BLOCK),
+                                   dict(block_diffusion=BLOCK // 2)], ids=str)
+def test_a_wrong_mask_fails_the_builders_rows(wrong):
+    """A causal mask over the 2 L rows, and a block twice or half as long,
+    through the kernels themselves: each is over a limit of the cell's
+    comparison, by a wide margin."""
+    rows = probe_rows(lambda q, k, v, scale: flash_attention(
+        q, k, v, sm_scale=scale, block_q=128, block_k=128, interpret=True,
+        **wrong))
+    over = [row for row in rows if row["value"] > 2 * row["limit"]]
+    assert over, rows
+
+
+@pytest.mark.parametrize("drop", ["level_weight", "renormalize",
+                                  "noised_block"])
+def test_a_dropped_term_is_another_program(drop):
+    """The switches that leave a term out do change the loss and its
+    gradients, by more than the cell's limits allow."""
+    params, batch = seeded(lm())
+    right, wrong = (with_highest(jax.value_and_grad(
+        lambda p: reference.loss(p, batch, **reference_config(drop=d))))(
+            params) for d in (None, drop))
+    norm = optax.global_norm
+    off = norm(jax.tree.map(jnp.subtract, wrong[1], right[1])) / norm(right[1])
+    assert abs(float(wrong[0] / right[0] - 1)) > reference.LOSS_RTOL \
+        or float(off) > reference.GRAD_RTOL, (drop, off)
+    assert float(off) > reference.GRAD_RTOL, (drop, off)
+
+
+@pytest.mark.parametrize("dtype,least", [(jnp.float8_e4m3fn,
+                                          reference.GRAD_RTOL),
+                                         (jnp.bfloat16, 50 * 1e-4)],
+                         ids=["float8_under_bfloat16",
+                              "bfloat16_under_float32"])
+def test_reference_refuses_the_next_precision_down(dtype, least):
+    model = lm()
+    params, batch = seeded(model)
+    losses = [with_highest(jax.value_and_grad(lambda p: reference.loss(
+        p, batch, operand_dtype=operand, **reference_config())))(params)
+        for operand in (None, dtype)]
+    norm = optax.global_norm
+    wrong = norm(jax.tree.map(jnp.subtract, losses[1][1], losses[0][1]))
+    assert float(wrong / norm(losses[0][1])) > least
