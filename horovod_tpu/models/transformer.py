@@ -11,11 +11,13 @@ scales linearly with the ring size.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from horovod_tpu.models.delta import DeltaConfig, DeltaMixer
@@ -121,15 +123,62 @@ def _qkv_project_bwd(res, cots):
 _qkv_project.defvjp(_qkv_project_fwd, _qkv_project_bwd)
 
 
+def _pair_swap(d: int, dtype):
+    """The signed permutation ``S`` (d, d) of the last axis that swaps a
+    pair's two components: ``(x @ S)[2i] = -x[2i+1]``, ``(x @ S)[2i+1] =
+    x[2i]``.  One non-zero a column, so the product is exact in any dtype;
+    ``S^T = -S``."""
+    swap = np.zeros((d, d), np.float32)
+    even = np.arange(0, d, 2)
+    swap[even + 1, even] = -1.0
+    swap[even, even + 1] = 1.0
+    return jnp.asarray(swap, dtype)
+
+
+def _turned(x, positions, base, seq_dim, back: bool):
+    """``x``'s adjacent pairs turned by their angles (``back``: by the
+    negative angles), float32 inside, rounded once; every operand keeps
+    ``x``'s last axis."""
+    d = x.shape[-1]
+    if d % 2:
+        raise ValueError(f"rope turns adjacent pairs: the last axis ({d}) "
+                         "must be even")
+    # (d,): each frequency written twice, beside the pair it turns.
+    freqs = base ** (-(jnp.arange(d) // 2).astype(jnp.float32) / (d // 2))
+    angles = positions[..., None].astype(jnp.float32) * freqs
+    shape = [1] * x.ndim
+    if positions.ndim == 2:  # per-batch-row offsets (decode mode)
+        shape[0] = positions.shape[0]
+    shape[seq_dim] = x.shape[seq_dim]
+    shape[-1] = d
+    cos = jnp.cos(angles).reshape(shape)
+    sin = jnp.sin(-angles if back else angles).reshape(shape)
+    swapped = jnp.einsum("...d,de->...e", x, _pair_swap(d, x.dtype),
+                         precision=lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * cos + swapped * sin).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def rope(x, positions, base: float = 10000.0, seq_dim: int = -2):
     """Rotary position embedding, ADJACENT-pair formulation: component
-    pairs ``(x[2i], x[2i+1])`` rotate by the i-th frequency.  The pairs
-    are reached by a free reshape view instead of the classic
-    [even half | odd half] split's two big slices + concatenate, so XLA
-    can fuse the whole rotation into neighbouring ops (its effect on the
-    step is not measured on this machine; PERF.md).  The two pairings
-    are the same function up to a fixed permutation of the q/k
-    projections' output axis.
+    pairs ``(x[2i], x[2i+1])`` rotate by the i-th frequency, in float32,
+    rounded once to ``x.dtype``.  (The [even half | odd half] pairing is the
+    same function up to a fixed permutation of the q/k projections' output
+    axis: another function of the same weights.)
+
+    The tensor never gets a last axis other than ``head_dim``: ``out = x *
+    cos + (x @ S) * sin`` with the ``(seq, head_dim)`` tables holding each
+    frequency twice and ``S`` the constant signed permutation that swaps a
+    pair (:func:`_pair_swap`; exact, one non-zero a column).  A ``(half, 2)``
+    view of the pairs cannot keep 2 in the TPU's lanes: the compiler then
+    moves the sequence there and back with whole-tensor copies and pads
+    around the flash kernels — on the chip 14 ms of a 213 ms step at 32 heads
+    of 128 on 8,192 rows, and a 137.4 ms step 6.9 ms shorter without them at
+    4 x 2,048 tokens and 16 heads of 64 (my chip runs, PR 42; PERF.md
+    section 6).  The backward is the rotation by the
+    negative angles, written out; the function is linear, and the positions
+    are all it keeps.
 
     ``positions``: (seq,) global token positions — global, so
     sequence-sharded shards stay consistent — or (batch, seq) when every
@@ -137,22 +186,32 @@ def rope(x, positions, base: float = 10000.0, seq_dim: int = -2):
     decode batch, where slot b's next token lives at its own cache
     length).  ``seq_dim`` names the sequence axis of ``x`` (-2 for
     (b, h, s, d), 1 for (b, s, h, d))."""
-    d = x.shape[-1]
-    half = d // 2
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions[..., None].astype(jnp.float32) * freqs
-    shape = [1] * x.ndim
-    if positions.ndim == 2:  # per-batch-row offsets (decode mode)
-        shape[0] = positions.shape[0]
-    shape[seq_dim] = x.shape[seq_dim]
-    shape[-1] = half
-    cos = jnp.cos(angles).reshape(shape)[..., None]
-    sin = jnp.sin(angles).reshape(shape)[..., None]
-    xp = x.reshape(x.shape[:-1] + (half, 2))
-    a, b = xp[..., :1], xp[..., 1:]
-    rotated = jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
-                              axis=-1)
-    return rotated.reshape(x.shape).astype(x.dtype)
+    return _turned(x, positions, base, seq_dim, back=False)
+
+
+def _rope_fwd(x, positions, base, seq_dim):
+    return _turned(x, positions, base, seq_dim, back=False), positions
+
+
+def _rope_bwd(base, seq_dim, positions, d_out):
+    return _turned(d_out, positions, base, seq_dim, back=True), None
+
+
+rope.defvjp(_rope_fwd, _rope_bwd)
+
+
+@jax.custom_vjp
+def _cotangent_written_out(x):
+    """``x``; its cotangent passes a ``lax.optimization_barrier``, so the
+    backward pass of what reads ``x`` ends in a written tensor and is not
+    fused into the operands of the products that read that cotangent.
+    Forward it is nothing."""
+    return x
+
+
+_cotangent_written_out.defvjp(
+    lambda x: (x, None),
+    lambda _, d_x: (lax.optimization_barrier(d_x),))
 
 
 class DecodeContext(NamedTuple):
@@ -423,12 +482,13 @@ class SparseExperts(nn.Module):
 
 class Attention(nn.Module):
     """Causal self-attention of one layer: the q/k/v projections (with the
-    q/k norm where asked for) under the scope ``hvd_attn_qkv``, the rotation
-    and the attention itself (flash, blockwise, ring or cached decode) under
-    ``hvd_attn_attend``, the output gate's projection and its
-    sigmoid-multiply, where there is one, under ``hvd_attn_gate``, the output
-    projection under ``hvd_attn_out`` — names a trace can read, forward and
-    backward."""
+    q/k norm where asked for) under the scope ``hvd_attn_qkv``, each rotation
+    (:func:`rope`: q's, and k's — a grouped layer's in front of its repeat)
+    under ``hvd_attn_rotate``, the attention itself (flash, blockwise, ring
+    or cached decode) under ``hvd_attn_attend``, the output gate's projection
+    and its sigmoid-multiply, where there is one, under ``hvd_attn_gate``,
+    the output projection under ``hvd_attn_out`` — names a trace can read,
+    forward and backward."""
 
     n_heads: int
     dtype: Any = jnp.bfloat16
@@ -491,11 +551,12 @@ class Attention(nn.Module):
     # (:func:`~horovod_tpu.ops.attention.blockdiff_blocks`).  Training only.
     block_diffusion: Optional[int] = None
 
-    def _grouped_projections(self, x, head_dim):
+    def _grouped_projections(self, x, head_dim, rotate):
         """(q, k, v), each (b, local query heads, seq, head_dim), from a
         ``q_kernel`` and a ``kv_kernel`` of this shard's heads; a key/value
-        head is repeated for every query head that reads it (the repeat's
-        transpose sums their cotangents)."""
+        head is normed and turned (``rotate``) once and then repeated for
+        every query head that reads it (the repeat's transpose sums their
+        cotangents before the inverse rotation).  q is returned unturned."""
         shard, n_shards = self.head_shard
         kv_heads = self.n_kv_heads or self.n_heads
         if self.n_heads % kv_heads or self.n_heads % n_shards \
@@ -518,10 +579,10 @@ class Attention(nn.Module):
         x = x.astype(self.dtype)
         q = jnp.einsum("bsd,dhe->bhse", x, w_q.astype(self.dtype))
         k, v = jnp.einsum("bsd,djhe->jbhse", x, w_kv.astype(self.dtype))
-        if self.head_norm:       # a key head's norm once, before its repeat
+        if self.head_norm:
             q = self._head_norm("q_head_norm_scale", q)
             k = self._head_norm("k_head_norm_scale", k)
-        return (q, jnp.repeat(k, heads // kv_local, axis=1),
+        return (q, jnp.repeat(rotate(k), heads // kv_local, axis=1),
                 jnp.repeat(v, heads // kv_local, axis=1))
 
     @nn.compact
@@ -529,8 +590,6 @@ class Attention(nn.Module):
         b, s, d = x.shape
         head_dim = self.head_dim or d // self.n_heads
         n_heads = self.n_heads // self.head_shard[1]
-        rotate = (lambda t, positions: rope(t, positions, self.rope_theta)) \
-            if self.rope else (lambda t, positions: t)
         if (self.window is not None or self.block_diffusion is not None) \
                 and (decode_ctx is not None or self.seq_axis is not None):
             raise ValueError("window= and block_diffusion= compose with "
@@ -540,18 +599,38 @@ class Attention(nn.Module):
             raise ValueError("block_diffusion= is a mask of its own over an "
                              "even number of rows, [clean; noised]: it takes "
                              "no window=")
+        if decode_ctx is not None:
+            k_ctx, v_ctx, ctx_mask, positions = decode_ctx
+        elif self.seq_axis is not None:
+            positions = lax.axis_index(self.seq_axis) * s + jnp.arange(s)
+        elif self.block_diffusion is not None:
+            # A copy's row i stands at position i of the sequence.
+            positions = jnp.arange(s) % (s // 2)
+        else:
+            positions = jnp.arange(s)
+
+        def rotate(t):
+            if not self.rope:
+                return t
+            with jax.named_scope("hvd_attn_rotate"):
+                return rope(t, positions, self.rope_theta)
+
         grouped = self.n_kv_heads is not None or self.head_shard != (0, 1)
         with jax.named_scope("hvd_attn_qkv"):
             if grouped:
-                q, k, v = self._grouped_projections(x, head_dim)
+                q, k, v = self._grouped_projections(x, head_dim, rotate)
             else:
                 # One fused qkv projection whose einsum emits q/k/v
-                # *head-major* ('jbhse'): XLA folds the seq<->head transpose
-                # into the matmul's output layout, so no standalone copy
-                # passes appear around the attention kernel.  The inverse
-                # transpose folds into the output projection's einsum the
-                # same way.  Per-matrix fan-in init matches separate q/k/v
-                # Dense layers (fan_in = d).
+                # *head-major* ('jbhse'), and the output projection's einsum
+                # reads the kernels' output head-major: no transpose is
+                # written between them and the attention kernel.  The
+                # compiler writes some all the same: it keeps this einsum's
+                # output sequence-minor and transposes q, k, v, dq, dk and
+                # dO to the kernels' head-minor operands — seven layout
+                # copies a layer, 5.4 ms of a 130 ms step at 4 x 2,048 and
+                # head 64 on the chip (PERF.md section 6, PR 42).
+                # Per-matrix fan-in init matches separate q/k/v Dense
+                # layers (fan_in = d).
                 w_qkv = self.param(
                     "qkv_kernel",
                     nn.initializers.lecun_normal(in_axis=0,
@@ -571,9 +650,10 @@ class Attention(nn.Module):
 
         new_kv = None
         with jax.named_scope("hvd_attn_attend"):
+            q = rotate(q)
+            if not grouped:
+                k = rotate(k)
             if decode_ctx is not None:
-                k_ctx, v_ctx, ctx_mask, positions = decode_ctx
-                q, k = rotate(q, positions), rotate(k, positions)
                 ctx_len = k_ctx.shape[-2]
                 # Context keys all precede the new chunk; within the chunk
                 # positions are consecutive, so causality is a lower
@@ -589,19 +669,12 @@ class Attention(nn.Module):
                                         head_dim ** -0.5)
                 new_kv = (k, v)
             elif self.seq_axis is not None:
-                offset = lax.axis_index(self.seq_axis) * s
-                positions = offset + jnp.arange(s)
-                q, k = rotate(q, positions), rotate(k, positions)
                 if self.capture_kv:
                     self.sow("intermediates", "kv", (k, v))
                 out = ring_attention(q, k, v, axis_name=self.seq_axis,
                                      causal=True, rotate_impl=self.ring_impl)
             else:
                 diffusion = self.block_diffusion
-                # A copy's row i stands at position i of the sequence.
-                positions = jnp.arange(s) if diffusion is None \
-                    else jnp.arange(s) % (s // 2)
-                q, k = rotate(q, positions), rotate(k, positions)
                 if self.capture_kv:
                     self.sow("intermediates", "kv", (k, v))
                 masks = dict(causal=True, window=self.window) \
@@ -644,7 +717,11 @@ class Attention(nn.Module):
         float32 inside; ONE (head_dim,) scale for all heads."""
         scale = self.param(name, nn.initializers.ones, (t.shape[3],),
                            jnp.float32)
-        wide = t.astype(jnp.float32)
+        # With the rotation's backward a product, XLA fused this norm's
+        # last backward step into the operand of q's weight-gradient and dx
+        # products: 5.86 -> 14.68 ms a step in the SDAR cell (my chip runs,
+        # PR 42; PERF.md section 6).
+        wide = _cotangent_written_out(t).astype(jnp.float32)
         mean_sq = jnp.mean(jnp.square(wide), axis=-1, keepdims=True)
         return (wide * lax.rsqrt(mean_sq + self.norm_eps)
                 * scale).astype(t.dtype)

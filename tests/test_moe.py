@@ -628,10 +628,12 @@ def test_grouped_matmul_and_its_gradients(sizes):
 # The dense default: the parameter tree and the program TransformerLM lowers
 # to must be what they were before the sparse-expert layer existed (the
 # three pythia410m cells of the benchmark run it).  The digest is of
-# `jax.jit(grad).lower(...).as_text()` at the parent commit of PR 26 (jax
-# 0.9.0); a later change to the dense block re-records it on purpose.
+# `jax.jit(grad).lower(...).as_text()` (jax 0.9.0): the parent commit of PR
+# 26's until PR 42, which re-recorded it on purpose — `rope` turns the pairs
+# by a product with a signed permutation and has a backward of its own; a
+# later change to the dense block re-records it again.
 DENSE_DIGEST = (
-    "2337d6dbb065945c061bc36f6469d2bd3e634b0488a6ddb83368537b8dfd57a1")
+    "400852088294e0dbc5b908d64a84738b13133dd814620311aa6fcd4e618ce15e")
 
 
 def dense_lm():
@@ -680,12 +682,13 @@ def test_dense_default_lowers_to_the_same_program():
 # renormalised and scaled weights, non-gated experts, a latent width, a shared
 # expert) and Attention its grouped heads, rotary switch and head share: the
 # olmoe1b7b cell runs it.  The digests are of `jax.jit(grad).lower(...)
-# .as_text()` at the parent commit of PR 30 (jax 0.9.0).
+# .as_text()` (jax 0.9.0): the parent commit of PR 30's until PR 42, which
+# re-recorded them on purpose with `rope`'s new form.
 OLMOE_DIGESTS = {
     ((0, 4), 1.5):
-    "e9fd6466282336a4de5cf04714ac377386f2b81754c9a8a3364ef1cfd1b037a0",
+    "b5acec99aefbd636a07145829e752d1c89a1914f461c77b4ab5e75585988cbae",
     ((0, 1), None):
-    "69dda621fcc84cf7dad23434683ac42cbe6df5adb1903cf6058679fadb99da5a"}
+    "22e3811ab4daf100a7b54f82fb5e7c1d5cf66c0e6ee3667f81969cede055fb9c"}
 
 
 @pytest.mark.parametrize("shard,row_bound", list(OLMOE_DIGESTS))

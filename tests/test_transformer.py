@@ -1,6 +1,8 @@
 """TransformerLM tests: causality, sequence-parallel equivalence, and a
 dp x sp 2-D-mesh training step."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -244,6 +246,239 @@ def test_qkv_project_custom_vjp_matches_autodiff():
     g_r = jax.grad(loss_ref, argnums=(0, 1))(x, w)
     for a, b in zip(g_c, g_r):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+# --- rope: the pairs' rotation with the head's width kept in the last axis --
+# against the formula it had until PR 42, kept here as the plain reference.
+
+
+def _plain_rope(x, positions, base=10000.0, seq_dim=-2):
+    """Adjacent pairs through a ``(half, 2)`` view, float32 inside, rounded
+    once: `models.transformer.rope` as it was until PR 42."""
+    half = x.shape[-1] // 2
+    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    angles = positions[..., None].astype(jnp.float32) * freqs
+    shape = [1] * x.ndim
+    if positions.ndim == 2:
+        shape[0] = positions.shape[0]
+    shape[seq_dim] = x.shape[seq_dim]
+    shape[-1] = half
+    cos = jnp.cos(angles).reshape(shape)[..., None]
+    sin = jnp.sin(angles).reshape(shape)[..., None]
+    pairs = x.reshape(x.shape[:-1] + (half, 2))
+    a, b = pairs[..., :1], pairs[..., 1:]
+    rotated = jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                              axis=-1)
+    return rotated.reshape(x.shape).astype(x.dtype)
+
+
+def _rope_case(dtype, head_dim, seq_dim, rank, seed=0):
+    batch, heads, seq = 2, 3, 24
+    shape = (batch, heads, seq, head_dim) if seq_dim == -2 \
+        else (batch, seq, heads, head_dim)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    x = jax.random.normal(keys[0], shape, dtype)
+    # what a bfloat16 cotangent holds exactly
+    mix = jax.random.normal(keys[1], shape, jnp.bfloat16).astype(jnp.float32)
+    positions = 5 + jnp.arange(seq) if rank == 1 \
+        else jnp.stack([3 + jnp.arange(seq), 4000 + jnp.arange(seq)])
+    return x, mix, positions
+
+
+ROPE_CASES = [(64, -2, 1, 1e4), (128, -2, 1, 1e6), (6, -2, 2, 1e4),
+              (64, 1, 2, 1e6), (128, 1, 1, 1e4), (10, 1, 1, 1e6)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("head_dim,seq_dim,rank,base", ROPE_CASES, ids=str)
+def test_rope_is_the_plain_formula(dtype, head_dim, seq_dim, rank, base):
+    """Operation by operation (no fusion, so no contraction the CPU might
+    make of one form and not the other) the two forms are the same float32
+    sums: bfloat16 results agree to the bit."""
+    from horovod_tpu.models.transformer import rope
+
+    x, _, positions = _rope_case(dtype, head_dim, seq_dim, rank)
+    got = rope(x, positions, base, seq_dim)
+    want = _plain_rope(x, positions, base, seq_dim)
+    assert got.dtype == want.dtype
+    if dtype == jnp.bfloat16:
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+    else:
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("head_dim,seq_dim,rank,base", ROPE_CASES[:4],
+                         ids=str)
+def test_rope_gradient_is_autodiff_of_the_plain_formula(dtype, head_dim,
+                                                        seq_dim, rank, base):
+    """The written-out backward — the rotation by the negative angles,
+    rounded once — against autodiff of the plain formula: float32 to 1e-6;
+    bfloat16 to the three roundings autodiff makes of a pair's cotangent
+    (each product's, then their sum's) where the written-out one makes one."""
+    from horovod_tpu.models.transformer import rope
+
+    x, mix, positions = _rope_case(dtype, head_dim, seq_dim, rank, seed=1)
+
+    def through(fn):
+        return jax.grad(lambda x: jnp.sum(
+            fn(x, positions, base, seq_dim).astype(jnp.float32) * mix))(x)
+
+    got, want = through(rope), through(_plain_rope)
+    assert got.dtype == want.dtype == dtype
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+    else:
+        exact = jax.grad(lambda x: jnp.sum(_plain_rope(
+            x, positions, base, seq_dim) * mix))(x.astype(jnp.float32))
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32),
+            np.asarray(exact.astype(dtype), np.float32))
+        np.testing.assert_allclose(got, want, rtol=2**-7, atol=2**-5)
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_rope_keeps_the_head_width_in_the_last_axis(head_dim):
+    """Forward and backward lower with no pair axis: no concatenate, no pad,
+    no tensor whose last axis is 2."""
+    from horovod_tpu.models.transformer import rope
+
+    x = jax.ShapeDtypeStruct((2, 4, 256, head_dim), jnp.bfloat16)
+    text = jax.jit(jax.grad(lambda x: jnp.sum(rope(
+        x, jnp.arange(256), 1e4).astype(jnp.float32) ** 2))).lower(
+            x).as_text()
+    assert "dot_general" in text
+    assert "concatenate" not in text and "stablehlo.pad" not in text
+    assert not re.search(r"x2x(bf16|f32)>", text)
+    assert re.search(rf"tensor<2x4x256x{head_dim}xbf16>", text)
+
+
+def test_rope_refuses_an_odd_width():
+    from horovod_tpu.models.transformer import rope
+
+    with pytest.raises(ValueError, match="must be even"):
+        rope(jnp.ones((1, 1, 4, 5)), jnp.arange(4))
+
+
+def _plain_grouped_attention(params, x, heads, kv_heads, theta, eps):
+    """Grouped-query attention with per-head norms as `Attention` ran it
+    until PR 42: a key head normed, REPEATED, and then turned with the rest."""
+    def normed(t, scale):
+        mean_sq = jnp.mean(jnp.square(t), axis=-1, keepdims=True)
+        return t * jax.lax.rsqrt(mean_sq + eps) * scale
+
+    seq = x.shape[1]
+    q = jnp.einsum("bsd,dhe->bhse", x, params["q_kernel"])
+    k, v = jnp.einsum("bsd,djhe->jbhse", x, params["kv_kernel"])
+    q = normed(q, params["q_head_norm_scale"])
+    k = normed(k, params["k_head_norm_scale"])
+    k, v = (jnp.repeat(t, heads // kv_heads, axis=1) for t in (k, v))
+    positions = jnp.arange(seq)
+    q, k = (_plain_rope(t, positions, theta) for t in (q, k))
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    logits = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), logits,
+                       -jnp.inf)
+    out = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(logits, axis=-1), v)
+    return jnp.einsum("bhse,hed->bsd", out, params["o_kernel"])
+
+
+def test_a_key_head_turned_before_its_repeat_is_the_same_layer():
+    """`Attention(n_kv_heads=4, head_norm=True)` turns 4 key heads for 16
+    query heads and sums a head's 4 cotangents before the inverse rotation:
+    output and every gradient are those of rotating after the repeat."""
+    from horovod_tpu.models.transformer import Attention
+
+    heads, kv_heads, theta, eps = 16, 4, 1e6, 1e-6
+    layer = Attention(n_heads=heads, dtype=jnp.float32, use_flash=False,
+                      n_kv_heads=kv_heads, head_dim=8, head_norm=True,
+                      rope_theta=theta, norm_eps=eps)
+    keys = jax.random.split(jax.random.PRNGKey(2), 3)
+    x = jax.random.normal(keys[0], (2, 32, 48), jnp.float32)
+    mix = jax.random.normal(keys[1], (2, 32, 48), jnp.float32)
+    params = layer.init(keys[2], x)["params"]
+    params = jax.tree.map(            # scales off one, so they matter
+        lambda p: p * (1.0 + 0.1 * jnp.arange(p.size).reshape(p.shape)
+                       / p.size), params)
+
+    def loss(fn):
+        return lambda params, x: jnp.sum(fn(params, x) * mix)
+
+    def ours(params, x):
+        return layer.apply({"params": params}, x)
+
+    def plain(params, x):
+        return _plain_grouped_attention(params, x, heads, kv_heads, theta,
+                                        eps)
+
+    np.testing.assert_allclose(ours(params, x), plain(params, x),
+                               rtol=1e-5, atol=1e-5)
+    got = jax.grad(loss(ours), (0, 1))(params, x)
+    want = jax.grad(loss(plain), (0, 1))(params, x)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        a, b, rtol=1e-4, atol=1e-4), got, want)
+
+
+def test_attention_names_its_rotations_and_only_where_it_turns():
+    """`hvd_attn_rotate` holds q's and k's rotation, forward and backward, a
+    grouped layer's k in front of its repeat (4 heads wide, not 16); a layer
+    that does not rotate lowers without the scope and without a pair swap."""
+    from horovod_tpu.models.transformer import Attention
+
+    def lowered(**kwargs):
+        layer = Attention(n_heads=16, dtype=jnp.bfloat16, use_flash=False,
+                          **kwargs)
+        x = jnp.zeros((1, 64, 128), jnp.bfloat16)
+        params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0),
+                                                   x)["params"])
+        grad = jax.grad(lambda p, x: jnp.sum(layer.apply(
+            {"params": p}, x).astype(jnp.float32)), (0, 1))
+        return jax.jit(grad).lower(params, x).as_text(debug_info=True)
+
+    def swaps(text, heads):
+        """The pair swaps (products with the 8 x 8 permutation) of a tensor
+        `heads` heads wide."""
+        return len(re.findall(
+            rf"dot_general .*tensor<1x{heads}x64x8xbf16>, tensor<8x8xbf16>",
+            text))
+
+    fused = lowered()
+    grouped = lowered(n_kv_heads=4, head_norm=True)
+    plain = lowered(n_kv_heads=4, rope=False)
+    for text in (fused, grouped):
+        assert "hvd_attn_attend/hvd_attn_rotate" in text
+        assert "transpose(" in text
+    in_projections = r'hvd_attn_qkv/[^"]*hvd_attn_rotate'
+    assert re.search(in_projections, grouped)
+    assert not re.search(in_projections, fused)
+    # q and k, forward and backward
+    assert (swaps(fused, 16), swaps(fused, 4)) == (4, 0)
+    assert (swaps(grouped, 16), swaps(grouped, 4)) == (2, 2)
+    assert "hvd_attn_rotate" not in plain and "...d,de->...e" not in plain
+
+
+def test_head_norm_writes_its_input_s_cotangent_out():
+    """The per-head norm's backward ends in a barrier, forward it has none:
+    the cotangent of the projection's output is written out once for the
+    two products that read it (PERF.md section 6, PR 42, has what fusing it
+    into them cost on the chip)."""
+    from horovod_tpu.models.transformer import Attention
+
+    layer = Attention(n_heads=4, dtype=jnp.bfloat16, use_flash=False,
+                      n_kv_heads=2, head_dim=8, head_norm=True)
+    x = jnp.zeros((1, 16, 32), jnp.bfloat16)
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0),
+                                               x)["params"])
+
+    def out(p, x):
+        return jnp.sum(layer.apply({"params": p}, x).astype(jnp.float32))
+
+    assert "optimization_barrier" not in jax.jit(out).lower(
+        params, x).as_text()
+    backward = jax.jit(jax.grad(out)).lower(params, x).as_text()
+    assert backward.count("optimization_barrier") == 2       # q's and k's
 
 
 # --- next_token_loss: a cross-entropy with its own backward pass ----------
