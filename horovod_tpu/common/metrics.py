@@ -333,8 +333,11 @@ class MetricsRegistry:
                      "rows_walked": [], "way_back": []}
         # Windowed attention layers (models.Attention(window=)): the (query
         # block, key block) pairs a head's forward kernel visits, and what
-        # the causal kernel would (models.record_attention_blocks).
-        self._attention = {"blocks_visited": [], "blocks_causal": []}
+        # the causal kernel would; every layer on the flash kernels: the
+        # pairs its masks touch and the steps its kernels' grids take, a head
+        # (models.record_attention_blocks).
+        self._attention = {"blocks_visited": [], "blocks_causal": [],
+                           "grid_live": [], "grid_steps": []}
         # What the compiler made of the last compiled training step's
         # gradient exchange (jax/train.py `_TimedStep.exchange_overlap`).
         self._train_step = {"compiler_options": "not applied",
@@ -432,13 +435,17 @@ class MetricsRegistry:
             self._moe["rows_walked"] = [int(n) for n in rows_walked]
             self._moe["way_back"] = [str(form) for form in way_back]
 
-    def set_attention_blocks(self, blocks_visited, blocks_causal) -> None:
-        """Mirror one forward pass's windowed-attention counters, per
-        windowed layer (overwritten: they are static shapes)."""
+    def set_attention_blocks(self, blocks_visited, blocks_causal,
+                             grid_live=(), grid_steps=()) -> None:
+        """Mirror one forward pass's attention counters — the first two per
+        windowed layer, the grids' per layer on the flash kernels
+        (overwritten: they are static shapes)."""
         with self._lock:
             self._attention = {
                 "blocks_visited": [int(n) for n in blocks_visited],
-                "blocks_causal": [int(n) for n in blocks_causal]}
+                "blocks_causal": [int(n) for n in blocks_causal],
+                "grid_live": [int(n) for n in grid_live],
+                "grid_steps": [int(n) for n in grid_steps]}
 
     def set_train_step(self, exchange_overlap: dict) -> None:
         """Mirror a compiled training step's account of its gradient
@@ -992,10 +999,16 @@ def prometheus_text(snapshot: dict) -> str:
     out.append("# HELP hvd_tpu_attention_blocks (query block, key block) "
                "pairs of one head in each windowed attention layer: visited "
                "by the banded forward kernel, and what the causal kernel "
-               "would visit under the same blocks")
+               "would visit under the same blocks; in each layer on the "
+               "flash kernels: grid_live the pairs its mask touches, summed "
+               "over its forward and backward kernels, grid_steps the steps "
+               "their grids take")
     out.append("# TYPE hvd_tpu_attention_blocks gauge")
-    for kind in ("visited", "causal"):
-        for layer, n in enumerate(attention.get("blocks_" + kind, [])):
+    for kind, key in (("visited", "blocks_visited"),
+                      ("causal", "blocks_causal"),
+                      ("grid_live", "grid_live"),
+                      ("grid_steps", "grid_steps")):
+        for layer, n in enumerate(attention.get(key, [])):
             out.append(f'hvd_tpu_attention_blocks{{layer="{layer}",'
                        f'kind="{kind}"}} {n}')
 

@@ -343,41 +343,76 @@ def _split_scale(sm_scale: float):
     return 2.0 ** (e - 1), m * 2.0
 
 
-def _on_host(block):
-    """The band's arithmetic runs on traced block indices inside a kernel or
-    an index map, and on numpy integers where blocks are counted
-    (`window_blocks`, the grids' extents)."""
-    return isinstance(block, (int, np.integer, np.ndarray))
+_FIRST, _LAST, _WHOLE = 1, 2, 4   # a table step's flags
 
 
-def _div(a, b):
-    # Traced block indices are never negative: lax.div spares the scalar
-    # core floor_divide's sign fix-up.
-    return a // b if _on_host(a) else lax.div(a, b)
+def _tile_masks(num_q, num_k, block_q, block_k, causal=False, window=None,
+                blockdiff=None):
+    """(live, whole): boolean ``(num_q, num_k)`` matrices over the (query
+    tile, key tile) pairs, on numpy from static shapes — ``live`` where some
+    query of the tile sees some key of it, ``whole`` where every query sees
+    every key (the tile needs no mask inside).  A window's pairs are ``0 <=
+    t - s < window``; block diffusion's are :func:`block_diffusion_mask`'s,
+    ``blockdiff = (block, half)``, the blocks dividing ``half`` so that a
+    tile lies in one copy."""
+    q_lo = np.arange(num_q)[:, None] * block_q
+    k_lo = np.arange(num_k)[None, :] * block_k
+    q_hi, k_hi = q_lo + block_q - 1, k_lo + block_k - 1
+    if blockdiff is not None:
+        block, half = blockdiff
+        q_noised, k_noised = q_lo >= half, k_lo >= half
+        q_rel, k_rel = q_noised * half, k_noised * half
+        # d, a query's diffusion block less a key's, over the tile
+        d_min = (q_lo - q_rel) // block - (k_hi - k_rel) // block
+        d_max = (q_hi - q_rel) // block - (k_lo - k_rel) // block
+        # clean on clean sees d >= 0, noised on clean d >= 1, noised on
+        # noised d == 0 (`_blockdiff_mask`)
+        least = q_noised & ~k_noised
+        most = np.where(k_noised, 0, half)
+        copies = q_noised | ~k_noised      # no clean query sees a noised key
+        return (copies & (d_max >= least) & (d_min <= most),
+                copies & (d_min >= least) & (d_max <= most))
+    if not causal:
+        every = np.ones((num_q, num_k), bool)
+        return every, every
+    most = num_q * block_q if window is None else window - 1
+    return ((q_hi - k_lo >= 0) & (q_lo - k_hi <= most),
+            (q_lo - k_hi >= 0) & (q_hi - k_lo <= most))
 
 
-def _keys_of_query_block(qi, block_q, block_k, window):
-    """(first, last) key block that holds a key some query of block ``qi``
-    sees under a window: queries ``[qi bq, (qi+1) bq)``, keys ``s`` with
-    ``0 <= t - s < window``."""
-    xp = np if _on_host(qi) else jnp
-    return (_div(xp.maximum(qi * block_q - (window - 1), 0), block_k),
-            _div(qi * block_q + block_q - 1, block_k))
-
-
-def _queries_of_key_block(ki, block_q, block_k, window, num_q):
-    """(first, last) query block that holds a query seeing some key of
-    block ``ki`` under a window."""
-    xp = np if _on_host(ki) else jnp
-    return (_div(ki * block_k, block_q),
-            xp.minimum(_div(ki * block_k + block_k + window - 2, block_q),
-                       num_q - 1))
-
-
-def _band_steps(first_last) -> int:
-    """The most blocks any one block's band holds: the banded grid's inner
-    extent."""
-    return _walk_steps((first_last,))
+@functools.lru_cache(maxsize=None)
+def _tile_table(num_q, num_k, block_q, block_k, causal=False, window=None,
+                blockdiff=None, by_key=False, every=False):
+    """A flash kernel's schedule: the live (query tile, key tile) pairs of
+    the mask in the order the kernel walks them, an int32 ``(3, steps)``
+    table — row 0 the query tile of each step, row 1 its key tile, row 2 its
+    flags (``_FIRST`` / ``_LAST`` step of its row of the walk; under block
+    diffusion, whose kernels hold an unmasked body beside the masked one,
+    also ``_WHOLE``: the mask leaves the tile whole).  The forward and
+    the dq kernel walk row by QUERY tile, keys inner; the combined backward
+    and the dk/dv kernel (``by_key``) row by KEY tile, queries inner; either
+    way a row's tiles ascend, so each accumulation runs in the order a
+    rectangular grid gave it.  The table is the grid's second axis (scalar
+    prefetch, SMEM): no step computes nothing, and a tile is copied only for
+    a step that uses it.  ``every``: all pairs — the ring, whose shard
+    offsets are traced values, decides a tile's fate on the device."""
+    live, whole = _tile_masks(num_q, num_k, block_q, block_k,
+                              causal and not every, window, blockdiff)
+    assert live.any(0).all() and live.any(1).all(), "a row with no tile"
+    if by_key:
+        k_tile, q_tile = np.nonzero(live.T)
+        outer = k_tile
+    else:
+        q_tile, k_tile = np.nonzero(live)
+        outer = q_tile
+    edge = np.flatnonzero(np.diff(outer)) + 1
+    flags = np.where(whole[q_tile, k_tile], _WHOLE, 0) \
+        if blockdiff is not None else np.zeros_like(outer)
+    flags[np.r_[0, edge]] |= _FIRST
+    flags[np.r_[edge - 1, len(outer) - 1]] |= _LAST
+    table = np.stack([q_tile, k_tile, flags]).astype(np.int32)
+    table.flags.writeable = False
+    return table
 
 
 def _band_mask(q_start, k_start, block_q, block_k, window):
@@ -388,114 +423,12 @@ def _band_mask(q_start, k_start, block_q, block_k, window):
     return (diff >= 0) & (diff < window)
 
 
-def _copy_of_tile(tile, block, half):
-    """(0 for a tile of the clean copy or 1 for one of the noised copy, the
-    position in its copy of the tile's first row); ``block`` divides
-    ``half``, so a tile lies in one copy."""
-    copy = _div(tile, half // block)
-    return copy, (tile - copy * (half // block)) * block
-
-
-def _blockdiff_keys_of_query_block(qi, block_q, block_k, block, half):
-    """The key blocks that hold a key some query of block ``qi`` sees under
-    :func:`block_diffusion_mask`, as TWO runs ``((first, last), (first,
-    last))``: the clean key blocks from 0 on (to the query block's own
-    diffusion block for a clean query block, to the one before it for a
-    noised one: none, ``last = -1``, where that is block 0), then, for a
-    noised query block, the noised key blocks that hold its own diffusion
-    blocks.  An empty second run is ``(last + 1, last)`` of the first."""
-    xp = np if _on_host(qi) else jnp
-    noised, start = _copy_of_tile(qi, block_q, half)
-    blocks_end = _div(start + block_q - 1, block) + 1   # past its last block
-    own_end = xp.minimum(blocks_end * block, half)
-    clean_end = xp.minimum((blocks_end - noised) * block, half)
-    last = _div(clean_end + block_k - 1, block_k) - 1
-    first_own = half // block_k + _div(_div(start, block) * block, block_k)
-    last_own = half // block_k + _div(own_end - 1, block_k)
-    return ((xp.zeros_like(last), last),
-            (xp.where(noised > 0, first_own, last + 1),
-             xp.where(noised > 0, last_own, last)))
-
-
-def _blockdiff_queries_of_key_block(ki, block_q, block_k, block, half):
-    """The query blocks that hold a query seeing some key of block ``ki``
-    under :func:`block_diffusion_mask`, as two runs: for a clean key block
-    the clean query blocks from its first diffusion block on, then the noised
-    query blocks from the NEXT diffusion block on (none where there is no
-    next); for a noised key block the noised query blocks of its own
-    diffusion blocks, and an empty second run."""
-    xp = np if _on_host(ki) else jnp
-    noised, start = _copy_of_tile(ki, block_k, half)
-    per_copy = half // block_q
-    first_block = _div(start, block)
-    own_end = xp.minimum((_div(start + block_k - 1, block) + 1) * block,
-                         half)
-    first = noised * per_copy + _div(first_block * block, block_q)
-    last = xp.where(noised > 0, per_copy + _div(own_end - 1, block_q),
-                    per_copy - 1)
-    later = (first_block + 1) * block     # first position of the next block
-    none = (noised > 0) | (later >= half)
-    return ((first, last),
-            (xp.where(none, last + 1,
-                      per_copy + _div(xp.minimum(later, half - 1), block_q)),
-             xp.where(none, last, 2 * per_copy - 1)))
-
-
-def _tile_of_step(runs, step):
-    """(the block the walk of two ``runs`` — ``(first, last)`` each, walked
-    one after the other — stands on at ``step``, whether the step is one of
-    the walk's).  Past the walk's end the block is its last one."""
-    xp = np if _on_host(step) else jnp
-    (first, last), (first2, last2) = runs
-    steps, steps2 = last - first + 1, last2 - first2 + 1
-    at = xp.minimum(step, steps + steps2 - 1)
-    return (xp.where(at < steps, first + at, first2 + (at - steps)),
-            step < steps + steps2)
-
-
-def _held_tile(runs, step):
-    """The block an index map gives ``step`` of the walk of ``runs`` — one
-    ``(first, last)``, a band, or two: past the walk's end it stays on the
-    last block, and an index that does not move copies nothing."""
-    if len(runs) == 1:
-        (first, last), = runs
-        return jnp.minimum(first + step, last)
-    return _tile_of_step(runs, step)[0]
-
-
-def _walk_steps(runs) -> int:
-    """The most blocks any one block's walk holds (``runs`` on numpy block
-    indices): the grid's inner extent."""
-    return int(sum(last - first + 1 for first, last in runs).max())
-
-
-def _blockdiff_bounds(q_start, k_start, block, half):
-    """(the first query's and the first key's position in their copies, the
-    least and the most ``d`` a seen pair has) for a tile under
-    :func:`block_diffusion_mask`, ``d`` the query's diffusion block less the
-    key's: clean on clean sees ``d >= 0``, noised on clean ``d >= 1``, noised
-    on noised ``d == 0``."""
-    q_noised, k_noised = q_start >= half, k_start >= half
-    return (q_start - jnp.where(q_noised, half, 0),
-            k_start - jnp.where(k_noised, half, 0),
-            jnp.where(q_noised & ~k_noised, 1, 0),
-            jnp.where(k_noised, 0, half))
-
-
-def _blockdiff_whole(q_start, k_start, block_q, block_k, block, half):
-    """Whether every query of the tile sees every key of it (a scalar): such
-    a tile takes the unmasked body."""
-    q_rel, k_rel, least, most = _blockdiff_bounds(q_start, k_start, block,
-                                                  half)
-    return ((_div(q_rel, block) - _div(k_rel + block_k - 1, block) >= least)
-            & (_div(q_rel + block_q - 1, block) - _div(k_rel, block) <= most))
-
-
 def _blockdiff_mask(q_start, k_start, block_q, block_k, block, half):
     """True where key ``s`` of the tile is seen by query ``t``, from the
-    block ids of the tile's rows (``block_q`` of them) and columns."""
-    q_rel, k_rel, least, most = _blockdiff_bounds(q_start, k_start, block,
-                                                  half)
+    block ids of the tile's rows (``block_q`` of them) and columns: with
+    ``d`` the query's diffusion block less the key's, clean on clean sees
+    ``d >= 0``, noised on clean ``d >= 1``, noised on noised ``d == 0``."""
+    q_noised, k_noised = q_start >= half, k_start >= half
 
     def blocks_of(rel, shape, axis):
         pos = rel + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
@@ -503,38 +436,42 @@ def _blockdiff_mask(q_start, k_start, block_q, block_k, block, half):
             return jax.lax.shift_right_logical(pos, block.bit_length() - 1)
         return lax.div(pos, block)
 
-    diff = blocks_of(q_rel, (block_q, 1), 0) \
-        - blocks_of(k_rel, (1, block_k), 1)
-    return (diff >= least) & (diff <= most)
+    diff = blocks_of(q_start - jnp.where(q_noised, half, 0),
+                     (block_q, 1), 0) \
+        - blocks_of(k_start - jnp.where(k_noised, half, 0), (1, block_k), 1)
+    return (diff >= jnp.where(q_noised & ~k_noised, 1, 0)) \
+        & (diff <= jnp.where(k_noised, 0, half))
 
 
-def _blockdiff_key_step(qi, step, block_q, block_k, blockdiff):
-    """(first key row, whether the step is live, whether the tile is whole)
-    at ``step`` of query block ``qi``'s walk of its key blocks."""
-    tile, run = _tile_of_step(_blockdiff_keys_of_query_block(
-        qi, block_q, block_k, *blockdiff), step)
-    return tile * block_k, run, _blockdiff_whole(
-        qi * block_q, tile * block_k, block_q, block_k, *blockdiff)
-
-
-def _blockdiff_query_step(ki, step, block_q, block_k, blockdiff):
-    """(query block, whether the step is live, whether the tile is whole) at
-    ``step`` of key block ``ki``'s walk of its query blocks."""
-    tile, run = _tile_of_step(_blockdiff_queries_of_key_block(
-        ki, block_q, block_k, *blockdiff), step)
-    return tile, run, _blockdiff_whole(
-        tile * block_q, ki * block_k, block_q, block_k, *blockdiff)
+def _masked(s, q_start, k_start, block_q, block_k, causal, window,
+            blockdiff):
+    """The logits ``s`` of a tile the mask cuts, NEG_INF where the query does
+    not see the key."""
+    if blockdiff is not None:
+        seen = _blockdiff_mask(q_start, k_start, block_q, block_k, *blockdiff)
+    elif window is not None:
+        seen = _band_mask(q_start, k_start, block_q, block_k, window)
+    elif causal:
+        seen = q_start + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0) >= k_start \
+            + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    else:
+        return s
+    return jnp.where(seen, s, NEG_INF)
 
 
 def _attend_block(q_ref, k_ref, v_ref, m_scratch, l_scratch, acc_scratch,
                   q_start, k_start, causal, block_q, block_k,
-                  single_k=False, scale_r=1.0, window=None, blockdiff=None):
-    """One online-softmax block update of the VMEM (m, l, acc) state.
+                  single_k=False, scale_r=1.0, window=None, blockdiff=None,
+                  masked=True):
+    """One online-softmax block update of the VMEM (m, l, acc) state
+    (``masked=False``: block diffusion's second body, for a tile the mask
+    leaves whole, with no mask arithmetic).
 
     Shared by the single-shard flash kernel and the fused ring-flash step
     (ops/ring_flash.py) — the only difference between them is where
-    ``q_start``/``k_start`` come from (grid position vs scalar-prefetched
-    absolute shard offsets).
+    ``q_start``/``k_start`` come from (the table's tile at the grid's step vs
+    the ring's grid position and scalar-prefetched absolute shard offsets).
 
     VPU economy (the kernel is elementwise-bound at head_dim 64 — the MXU
     finishes each block's two dots in ~1/3 of the time the softmax passes
@@ -555,18 +492,9 @@ def _attend_block(q_ref, k_ref, v_ref, m_scratch, l_scratch, acc_scratch,
         preferred_element_type=jnp.float32)
     if scale_r != 1.0:
         s *= scale_r
-    if blockdiff is not None:
-        s = jnp.where(_blockdiff_mask(q_start, k_start, block_q, block_k,
-                                      *blockdiff), s, NEG_INF)
-    elif window is not None:
-        s = jnp.where(_band_mask(q_start, k_start, block_q, block_k, window),
-                      s, NEG_INF)
-    elif causal:
-        q_pos = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    if masked:
+        s = _masked(s, q_start, k_start, block_q, block_k, causal, window,
+                    blockdiff)
     if single_k:
         m_new = s.max(axis=-1)
         m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
@@ -612,11 +540,35 @@ def _finalize_flash(o_ref, lse_ref, m_scratch, l_scratch, acc_scratch,
         lse_ref.shape)
 
 
+def _step_of(tab_ref, block_q, block_k, blockdiff=None, offsets_ref=None):
+    """(first query row, first key row, first step of its row, last step of
+    its row, the mask leaves the tile whole, query tile) of the table's step
+    the grid stands on.  ``whole`` is None but under block diffusion, whose
+    tables alone flag it; the rows are absolute where the ring hands its
+    shards' ``offsets_ref``."""
+    step = pl.program_id(1)
+    q_tile, flags = tab_ref[0, step], tab_ref[2, step]
+    q_start, k_start = q_tile * block_q, tab_ref[1, step] * block_k
+    if offsets_ref is not None:
+        q_start, k_start = offsets_ref[0] + q_start, offsets_ref[1] + k_start
+    first, last, whole = ((flags & bit) != 0
+                          for bit in (_FIRST, _LAST, _WHOLE))
+    return (q_start, k_start, first, last,
+            None if blockdiff is None else whole, q_tile)
+
+
 def _when_live(run, whole, body):
-    """``body(masked)`` under ``pl.when(run)``.  ``whole`` None: one body,
-    ``body(True)``, whatever mask the kernel has in every tile.  Else (block
-    diffusion) a tile the mask cuts takes the masked body, ``body(True)``, and
-    a whole one the unmasked, ``body(False)``."""
+    """``body(masked)`` under ``pl.when(run)``: the ring's predicate on its
+    traced offsets, or None — a table of live tiles, whose every step
+    computes.  ``whole`` None: one body, ``body(True)``, whatever mask the
+    kernel has in every tile.  Else (block diffusion) a tile the mask cuts
+    takes the masked body, ``body(True)``, and a whole one the unmasked,
+    ``body(False)``."""
+    if run is None:
+        # Still a `cond`, on what is true at every step: inside `shard_map`
+        # the interpreter lets scratch, which varies over no mesh axis, meet
+        # operands, which do, only there.
+        run = pl.program_id(1) >= 0
     if whole is None:
         pl.when(run)(lambda: body(True))
     else:
@@ -624,47 +576,32 @@ def _when_live(run, whole, body):
         pl.when(run & whole)(lambda: body(False))
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch,
-                  acc_scratch, *, causal, block_q, block_k, num_k_blocks,
-                  scale_r=1.0, window=None, blockdiff=None):
-    """With a ``window`` the grid's key axis walks only query block ``qi``'s
-    band: ``num_k_blocks`` is the band's steps, step ``ki`` is key block
-    ``first + ki``, and the steps past the band's last block do nothing (the
-    index maps hold them on that block, so nothing is copied either).  With
-    ``blockdiff`` it walks the block's two runs the same way."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    single_k = num_k_blocks == 1
+def _flash_kernel(tab_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch,
+                  l_scratch, acc_scratch, *, causal, block_q, block_k,
+                  single_k, scale_r=1.0, window=None, blockdiff=None):
+    """Grid ``(bh, steps)``: the steps are the table's (`_tile_table`, query
+    tiles outer), live tiles only — the first step of a query tile's row
+    starts the online-softmax state, the last writes out and lse.  One body,
+    masked in every tile, under the causal mask and a window; block
+    diffusion's two, by the table's flag.  ``single_k``: every row is one
+    tile (the whole-k layout), which skips the state's rescale."""
+    q_start, k_start, first, last, whole, _ = _step_of(
+        tab_ref, block_q, block_k, blockdiff)
 
     if not single_k:
-        @pl.when(ki == 0)
+        @pl.when(first)
         def _():
             _init_state(m_scratch, l_scratch, acc_scratch)
 
-    q_start = qi * block_q
-    whole = None
-    if blockdiff is not None:
-        k_start, run, whole = _blockdiff_key_step(qi, ki, block_q, block_k,
-                                                  blockdiff)
-    elif window is None:
-        k_start = ki * block_k
-        # Causal pruning: skip key blocks entirely above the diagonal.
-        run = True if not causal else k_start <= q_start + block_q - 1
-    else:
-        first, last = _keys_of_query_block(qi, block_q, block_k, window)
-        k_start = (first + ki) * block_k
-        run = first + ki <= last
-
     def attend(masked):
         _attend_block(q_ref, k_ref, v_ref, m_scratch, l_scratch,
-                      acc_scratch, q_start, k_start, causal,
-                      block_q, block_k, single_k=single_k,
-                      scale_r=scale_r, window=window,
-                      blockdiff=blockdiff if masked else None)
+                      acc_scratch, q_start, k_start, causal, block_q,
+                      block_k, single_k=single_k, scale_r=scale_r,
+                      window=window, blockdiff=blockdiff, masked=masked)
 
-    _when_live(run, whole, attend)
+    _when_live(None, whole, attend)
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(last)
     def _():
         _finalize_flash(o_ref, lse_ref, m_scratch, l_scratch, acc_scratch,
                         block_q)
@@ -672,7 +609,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch,
 
 def _bwd_block_math(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                     causal, q_start, k_start, block_q, block_k, scale_r,
-                    window=None, blockdiff=None):
+                    window=None, blockdiff=None, masked=True):
     """Shared flash-backward block recompute (Dao et al. alg. 2 inner
     body), used by the combined kernel, both split kernels, and the fused
     ring backward (ops/ring_flash.py).
@@ -698,18 +635,9 @@ def _bwd_block_math(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         preferred_element_type=jnp.float32)
     if scale_r != 1.0:
         s *= scale_r
-    if blockdiff is not None:
-        s = jnp.where(_blockdiff_mask(q_start, k_start, block_q, block_k,
-                                      *blockdiff), s, NEG_INF)
-    elif window is not None:
-        s = jnp.where(_band_mask(q_start, k_start, block_q, block_k, window),
-                      s, NEG_INF)
-    elif causal:
-        q_pos = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    if masked:
+        s = _masked(s, q_start, k_start, block_q, block_k, causal, window,
+                    blockdiff)
     p = jnp.exp(s - lse[:, None])  # POS_BIG lse zeroes masked rows
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
@@ -720,44 +648,28 @@ def _bwd_block_math(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     return p.astype(v.dtype), ds.astype(q.dtype), q, do, k
 
 
-def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                           dk_ref, dv_ref, dk_scratch, dv_scratch, *,
-                           causal, block_q, block_k, num_q_blocks, scale_r,
-                           band=None, blockdiff=None):
+def _flash_bwd_dkdv_kernel(tab_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref,
+                           v_ref, dk_ref, dv_ref, dk_scratch, dv_scratch, *,
+                           causal, block_q, block_k, scale_r,
+                           window=None, blockdiff=None):
     """Split backward, dk/dv half: O(block) scoped memory — the long-seq
     path where the combined kernel's whole-seq dq scratch exceeds the
-    chip's scoped-VMEM ceiling (see _bwd_plan).  ``band=(window, query
-    blocks of the sequence)``: the inner axis walks key block ``ki``'s band
-    of ``num_q_blocks`` steps, as `_flash_kernel`'s does."""
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)  # innermost: accumulates over query blocks
+    chip's scoped-VMEM ceiling (see _bwd_plan).  Grid ``(bh, steps)`` over
+    the table's live tiles, key tiles outer: a key tile's queries accumulate
+    from its first step to its last."""
+    q_start, k_start, first, last, whole, _ = _step_of(
+        tab_ref, block_q, block_k, blockdiff)
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _():
         dk_scratch[...] = jnp.zeros_like(dk_scratch)
         dv_scratch[...] = jnp.zeros_like(dv_scratch)
 
-    whole = None
-    if blockdiff is not None:
-        tile, run, whole = _blockdiff_query_step(ki, qi, block_q, block_k,
-                                                 blockdiff)
-        q_start, k_start = tile * block_q, ki * block_k
-    elif band is None:
-        q_start = qi * block_q
-        k_start = ki * block_k
-        run = True if not causal else q_start + block_q - 1 >= k_start
-    else:
-        first, last = _queries_of_key_block(ki, block_q, block_k, *band)
-        q_start = (first + qi) * block_q
-        k_start = ki * block_k
-        run = first + qi <= last
-
     def accumulate(masked):
         pb, ds, q, do, _k = _bwd_block_math(
             q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, causal,
-            q_start, k_start, block_q, block_k, scale_r,
-            window=band and band[0],
-            blockdiff=blockdiff if masked else None)
+            q_start, k_start, block_q, block_k, scale_r, window=window,
+            blockdiff=blockdiff, masked=masked)
         dv_scratch[...] += jax.lax.dot_general(
             pb, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -765,53 +677,40 @@ def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _when_live(run, whole, accumulate)
+    _when_live(None, whole, accumulate)
 
-    @pl.when(qi == num_q_blocks - 1)
+    @pl.when(last)
     def _():
         _st(dk_ref, dk_scratch[...])
         _st(dv_ref, dv_scratch[...])
 
 
-def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                         dq_ref, dq_scratch, *, causal, block_q,
-                         block_k, num_k_blocks, scale_r, dq_scale=1.0,
+def _flash_bwd_dq_kernel(tab_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref,
+                         v_ref, dq_ref, dq_scratch, *, causal, block_q,
+                         block_k, scale_r, dq_scale=1.0,
                          window=None, blockdiff=None):
-    """Split backward, dq half: accumulates one query block over the key
-    loop — O(block) scoped memory (long-seq path, see _bwd_plan).  With a
-    ``window`` the key loop is the band's ``num_k_blocks`` steps."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)  # innermost: accumulates over key blocks
+    """Split backward, dq half: accumulates one query tile over its key
+    tiles — O(block) scoped memory (long-seq path, see _bwd_plan).  Grid
+    ``(bh, steps)`` over the table's live tiles, query tiles outer."""
+    q_start, k_start, first, last, whole, _ = _step_of(
+        tab_ref, block_q, block_k, blockdiff)
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _():
         dq_scratch[...] = jnp.zeros_like(dq_scratch)
-
-    q_start = qi * block_q
-    whole = None
-    if blockdiff is not None:
-        k_start, run, whole = _blockdiff_key_step(qi, ki, block_q, block_k,
-                                                  blockdiff)
-    elif window is None:
-        k_start = ki * block_k
-        run = True if not causal else q_start + block_q - 1 >= k_start
-    else:
-        first, last = _keys_of_query_block(qi, block_q, block_k, window)
-        k_start = (first + ki) * block_k
-        run = first + ki <= last
 
     def accumulate(masked):
         _pb, ds, _q, _do, k = _bwd_block_math(
             q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, causal,
             q_start, k_start, block_q, block_k, scale_r, window=window,
-            blockdiff=blockdiff if masked else None)
+            blockdiff=blockdiff, masked=masked)
         dq_scratch[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _when_live(run, whole, accumulate)
+    _when_live(None, whole, accumulate)
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(last)
     def _():
         # pow2 rescale folded into the f32 flush (see the combined
         # kernel's _flush_dq note).
@@ -819,41 +718,43 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
             else dq_scratch[...])
 
 
-def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
-                         num_k_blocks, bh, rotate, barrier, axis_name,
-                         mesh_axes, scale_r, dq_scale=1.0, band=None,
-                         blockdiff=None):
+def _combined_bwd_kernel(tab_ref, *refs, causal, block_q, block_k, steps, bh,
+                         ring, rotate, barrier, axis_name, mesh_axes,
+                         scale_r, dq_scale=1.0, window=None, blockdiff=None):
     """Flash backward with dk/dv AND dq from ONE probability recompute.
 
-    Grid: (bh, ki, qi) — queries innermost so dk/dv accumulate in scratch
-    and flush per key block; dq accumulates in a whole-sequence VMEM
-    scratch and flushes once per bh row.  The split dkdv/dq kernel pair
+    Grid ``(bh, steps)`` over the table's tiles (`_tile_table`, key tiles
+    outer, queries inner), so dk/dv accumulate in scratch from a key tile's
+    first step to its last and flush there; dq accumulates in a
+    whole-sequence VMEM scratch and flushes once per bh row, at the table's
+    last step.  The split dkdv/dq kernel pair
     pays the s/p/dp/ds recompute twice; here it is paid once (kernel
     times on today's chip: PERF.md sections 5 and 7).
 
-    With ``rotate=True`` this is the fused ring-flash backward step
-    (ops/ring_flash.py): the K/V rotation DMA to the right neighbour
-    starts at the first grid step, flies under the gradient compute, and
-    is waited at the last.  ``offsets_ref`` carries the absolute
-    [q_offset, k_offset] for causal masking across shards (zeros for the
-    single-shard case).  ``q`` arrives pre-scaled by the pow2 part of
-    sm_scale; dq is emitted in q' units (callers rescale once).
-
-    ``band=(window, query blocks of the sequence)``: the inner axis walks key
-    block ``ki``'s band in ``num_q_blocks`` steps, as `_flash_kernel`'s does
-    (single shard only: the ring's offsets move the band between devices).
+    ``ring``: a step of the fused ring-flash backward (ops/ring_flash.py).
+    ``offsets_ref`` carries the absolute [q_offset, k_offset] of the shards
+    for causal masking across them — traced values, so the table holds every
+    pair and whether a tile computes is decided here, the kernel's one
+    dynamic predicate.  With ``rotate=True`` the K/V rotation DMA to the
+    right neighbour starts at the first grid step, flies under the gradient
+    compute, and is waited at the last.  ``q`` arrives pre-scaled by the
+    pow2 part of sm_scale; dq is emitted in q' units (callers rescale once).
     """
+    offsets_ref = None
+    if ring:
+        offsets_ref, *refs = refs
     if rotate:
-        (offsets_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+        (q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
          k_full, v_full, dk_ref, dv_ref, dq_ref, k_next, v_next,
          dk_scratch, dv_scratch, dq_scratch, sems) = refs
     else:
-        (offsets_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+        (q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
          dk_ref, dv_ref, dq_ref,
          dk_scratch, dv_scratch, dq_scratch) = refs
     b = pl.program_id(0)
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(1)
+    q_start, k_start, first, last, whole, q_tile = _step_of(
+        tab_ref, block_q, block_k, blockdiff, offsets_ref)
 
     if rotate:
         from horovod_tpu.ops.rdma import _device_id
@@ -865,7 +766,7 @@ def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
         src, _ = _device_id(jax.lax.rem(my - 1 + n, n), axis_name,
                             mesh_axes)
 
-        @pl.when((b == 0) & (ki == 0) & (qi == 0))
+        @pl.when((b == 0) & (step == 0))
         def _start_rotation():
             if barrier:
                 bar = pltpu.get_barrier_semaphore()
@@ -881,52 +782,36 @@ def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
                 recv_sem=sems.at[3], device_id=dst,
                 device_id_type=id_type).start()
 
-    @pl.when((ki == 0) & (qi == 0))
+    @pl.when(step == 0)
     def _zero_dq():
         dq_scratch[...] = jnp.zeros_like(dq_scratch)
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _zero_dkdv():
         dk_scratch[...] = jnp.zeros_like(dk_scratch)
         dv_scratch[...] = jnp.zeros_like(dv_scratch)
 
-    q_block, whole = qi, None
-    if band is not None:
-        first, last = _queries_of_key_block(ki, block_q, block_k, *band)
-        q_block = first + qi
-    if blockdiff is not None:
-        q_block, run, whole = _blockdiff_query_step(ki, qi, block_q, block_k,
-                                                    blockdiff)
-        q_start, k_start = q_block * block_q, ki * block_k
-    elif causal:
-        q_start = offsets_ref[0] + q_block * block_q  # absolute positions
-        k_start = offsets_ref[1] + ki * block_k
-        run = q_start + block_q - 1 >= k_start if band is None \
-            else q_block <= last
-    else:
-        q_start = k_start = 0
-        run = True
-
     def accumulate(masked):
         pb, ds, q, do, k = _bwd_block_math(
             q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, causal,
-            q_start, k_start, block_q, block_k, scale_r,
-            window=band and band[0],
-            blockdiff=blockdiff if masked else None)
+            q_start, k_start, block_q, block_k, scale_r, window=window,
+            blockdiff=blockdiff, masked=masked)
         dv_scratch[...] += jax.lax.dot_general(
             pb, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dk_scratch[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        row = pl.ds(q_block * block_q, block_q)
+        row = pl.ds(q_tile * block_q, block_q)
         dq_scratch[row, :] = dq_scratch[row, :] + jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _when_live(run, whole, accumulate)
+    # The ring's tiles above the (shifted) diagonal compute nothing.
+    _when_live(q_start + block_q - 1 >= k_start if ring and causal else None,
+               whole, accumulate)
 
-    @pl.when(qi == num_q_blocks - 1)
+    @pl.when(last)
     def _flush_dkdv():
         # _st casts: the scratch accumulates in f32, the output dtype is
         # the caller's grad_dtype (input dtype for the single-shard path
@@ -935,7 +820,7 @@ def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
         _st(dk_ref, dk_scratch[...])
         _st(dv_ref, dv_scratch[...])
 
-    @pl.when((ki == num_k_blocks - 1) & (qi == num_q_blocks - 1))
+    @pl.when(step == steps - 1)
     def _flush_dq():
         # dq accumulated in q' units; the pow2 rescale folds into the
         # flush IN F32, before the grad_dtype cast — no extra XLA pass
@@ -946,8 +831,7 @@ def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
             else dq_scratch[...])
 
     if rotate:
-        @pl.when((b == bh - 1) & (ki == num_k_blocks - 1)
-                 & (qi == num_q_blocks - 1))
+        @pl.when((b == bh - 1) & (step == steps - 1))
         def _finish_rotation():
             pltpu.make_async_remote_copy(
                 src_ref=k_full, dst_ref=k_next, send_sem=sems.at[0],
@@ -959,10 +843,48 @@ def _combined_bwd_kernel(*refs, causal, block_q, block_k, num_q_blocks,
                 device_id_type=id_type).wait()
 
 
-def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
-                       k_offset, *, causal, block_q, block_k, rotate,
-                       collective_id, axis_name, mesh_axes, interpret,
-                       scale_r=1.0, grad_dtype=jnp.float32, dq_scale=1.0,
+def _tile_spec(row, block, d):
+    """BlockSpec over (batch*heads, seq, d) operands whose block of ``block``
+    rows is the tile row ``row`` of the table (0 the query's, 1 the key's)
+    names at the grid's step; the scalar-prefetch refs are an index map's
+    last arguments, the table the first of them.  While the tile stays,
+    nothing is copied.
+
+    (A strided (1, block, 1, d) spec reading (b, s, h, d) directly would
+    skip the host-side transposes, but Mosaic requires the second-minor
+    block dim to be a multiple of 8 or the full array dim — a 1-wide head
+    slot is not lowerable, so the bshd layout transposes at the wrapper
+    instead; see flash_attention.)"""
+    return pl.BlockSpec((1, block, d),
+                        lambda b, s, tab, *_: (b, tab[row, s], 0))
+
+
+def _lse_spec(block_q):
+    """`_tile_spec` for the (batch*heads, 8, seq) row statistics."""
+    return pl.BlockSpec((1, 8, block_q),
+                        lambda b, s, tab, *_: (b, 0, tab[0, s]))
+
+
+def _tiled_call(kernel, table, bh, *, out_shape, name, interpret, prefetch=(),
+                compiler_params=None, **specs):
+    """``pl.pallas_call`` over the grid ``(bh, the table's steps)``, the table
+    the first scalar-prefetch operand (``prefetch``: the ring's offsets
+    behind it); the call takes the kernel's other operands."""
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1 + len(prefetch),
+            grid=(bh, table.shape[1]), **specs),
+        out_shape=out_shape, compiler_params=compiler_params,
+        interpret=interpret, name=name)
+    return functools.partial(call, table, *prefetch)
+
+
+def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset=None,
+                       k_offset=None, *, causal, block_q, block_k,
+                       rotate=False, collective_id=None, axis_name=None,
+                       mesh_axes=(), interpret, scale_r=1.0,
+                       grad_dtype=jnp.float32, dq_scale=1.0,
                        name="hvd_flash_bwd", window=None, blockdiff=None):
     """pallas_call wrapper for `_combined_bwd_kernel` over (bh, sl, d)
     operands (q pre-scaled by the pow2 part of sm_scale; ``do`` and ``v_cur``
@@ -971,57 +893,29 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
     (dk, dv, dq[, k_next, v_next]) with the gradients in ``grad_dtype``
     (accumulation is always f32 in scratch; only the flush casts, after
     applying ``dq_scale`` to dq in f32).  ``name`` is the kernel's name in
-    a device trace: the fused ring's backward step passes its own.  With a
-    ``window`` or ``blockdiff`` (no rotation) the grid's query axis is the
-    steps of a key block's walk."""
+    a device trace: the fused ring's backward step passes its own.  The grid
+    is ``(bh, the table's steps)``: the mask's live tiles, key tiles outer —
+    or, with the ring's ``q_offset`` and ``k_offset`` (traced, so the live
+    tiles are not known here), every pair, the kernel deciding."""
     bh, sl, d = q.shape
     d_v = v_cur.shape[-1]
-    num_q, num_k = sl // block_q, sl // block_k
-    offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
-                         jnp.asarray(k_offset, jnp.int32)])
-    inner_q = lambda qi, ki: qi  # noqa: E731
-    q_steps, band = num_q, None
-    if window is not None:
-        band = (window, num_q)
-        q_steps = _band_steps(_queries_of_key_block(
-            np.arange(num_k), block_q, block_k, *band))
-
-        def inner_q(qi, ki):
-            return _held_tile((_queries_of_key_block(
-                ki, block_q, block_k, *band),), qi)
-    elif blockdiff is not None:
-        q_steps = _walk_steps(_blockdiff_queries_of_key_block(
-            np.arange(num_k), block_q, block_k, *blockdiff))
-
-        def inner_q(qi, ki):
-            return _held_tile(_blockdiff_queries_of_key_block(
-                ki, block_q, block_k, *blockdiff), qi)
-
+    ring = q_offset is not None
+    table = _tile_table(sl // block_q, sl // block_k, block_q, block_k,
+                        causal, window, blockdiff, by_key=True, every=ring)
+    steps = table.shape[1]
     kernel = functools.partial(
         _combined_bwd_kernel, causal=causal, block_q=block_q,
-        block_k=block_k, num_q_blocks=q_steps, num_k_blocks=num_k, bh=bh,
-        rotate=rotate, barrier=rotate and not interpret,
+        block_k=block_k, steps=steps, bh=bh, ring=ring, rotate=rotate,
+        barrier=rotate and not interpret,
         axis_name=axis_name, mesh_axes=mesh_axes, scale_r=scale_r,
-        dq_scale=dq_scale, band=band, blockdiff=blockdiff)
-
-    def qspec(row, width=d):
-        return pl.BlockSpec((1, block_q, width),
-                            lambda b, ki, qi, s, _r=row: (b, _r(qi, ki), 0))
-
-    def kspec(row, width=d):
-        return pl.BlockSpec((1, block_k, width),
-                            lambda b, ki, qi, s, _r=row: (b, _r(qi, ki), 0))
-
-    outer_k = lambda qi, ki: ki  # noqa: E731
+        dq_scale=dq_scale, window=window, blockdiff=blockdiff)
     in_specs = [
-        qspec(inner_q),                                    # q
-        qspec(inner_q, d_v),                               # do
-        pl.BlockSpec((1, 8, block_q),
-                     lambda b, ki, qi, s: (b, 0, inner_q(qi, ki))),
-        pl.BlockSpec((1, 8, block_q),
-                     lambda b, ki, qi, s: (b, 0, inner_q(qi, ki))),
-        kspec(outer_k),                                    # k (blocked)
-        kspec(outer_k, d_v),                               # v (blocked)
+        _tile_spec(0, block_q, d),                         # q
+        _tile_spec(0, block_q, d_v),                       # do
+        _lse_spec(block_q),                                # lse
+        _lse_spec(block_q),                                # delta
+        _tile_spec(1, block_k, d),                         # k (blocked)
+        _tile_spec(1, block_k, d_v),                       # v (blocked)
     ]
     out_shapes = [
         jax.ShapeDtypeStruct((bh, sl, d), grad_dtype),     # dk
@@ -1029,16 +923,18 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
         jax.ShapeDtypeStruct((bh, sl, d), grad_dtype),     # dq
     ]
     out_specs = [
-        kspec(outer_k),                                    # dk
-        kspec(outer_k, d_v),                               # dv
-        pl.BlockSpec((1, sl, d), lambda b, ki, qi, s: (b, 0, 0)),  # dq
+        _tile_spec(1, block_k, d),                         # dk
+        _tile_spec(1, block_k, d_v),                       # dv
+        pl.BlockSpec((1, sl, d), lambda b, s, *_: (b, 0, 0)),  # dq
     ]
     scratch_shapes = [
         pltpu.VMEM((block_k, d), jnp.float32),             # dk accumulator
         pltpu.VMEM((block_k, d_v), jnp.float32),           # dv accumulator
         pltpu.VMEM((sl, d), jnp.float32),                  # whole-seq dq
     ]
-    args = [offsets, q, do, lse8, delta8, k_cur, v_cur]
+    prefetch = [jnp.stack([jnp.asarray(q_offset, jnp.int32),
+                           jnp.asarray(k_offset, jnp.int32)])] if ring else []
+    args = [q, do, lse8, delta8, k_cur, v_cur]
     if rotate:
         in_specs += [
             pl.BlockSpec(memory_space=pl.ANY),             # k (DMA src)
@@ -1057,41 +953,15 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset,
     vma = jax.typeof(q).vma
     out_shapes = [jax.ShapeDtypeStruct(s.shape, s.dtype, vma=vma)
                   for s in out_shapes]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bh, num_k, q_steps),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch_shapes,
-    )
-    compiler_params = pltpu.CompilerParams(
-        collective_id=(collective_id if rotate and not interpret
-                       else None),
-        has_side_effects=rotate)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shapes,
-        compiler_params=compiler_params,
-        interpret=interpret,
-        name=name,
+    return _tiled_call(
+        kernel, table, bh, prefetch=prefetch, in_specs=in_specs,
+        out_specs=out_specs, scratch_shapes=scratch_shapes,
+        out_shape=out_shapes, interpret=interpret, name=name,
+        compiler_params=pltpu.CompilerParams(
+            collective_id=(collective_id if rotate and not interpret
+                           else None),
+            has_side_effects=rotate),
     )(*args)
-
-
-def _row_spec(block, d):
-    """BlockSpec factory for (batch*heads, seq, d) tensors: ``row`` picks
-    which grid dim walks the sequence.
-
-    (A strided (1, block, 1, d) spec reading (b, s, h, d) directly would
-    skip the host-side transposes, but Mosaic requires the second-minor
-    block dim to be a multiple of 8 or the full array dim — a 1-wide head
-    slot is not lowerable, so the bshd layout transposes at the wrapper
-    instead; see flash_attention.)"""
-    def spec(row):
-        return pl.BlockSpec((1, block, d),
-                            lambda b, i, j, _r=row: (b, _r(i, j), 0))
-
-    return spec
 
 
 _MAX_BLOCK = 1024  # largest block edge the VMEM calibration covers
@@ -1223,6 +1093,22 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
     otherwise              any          split, tuned blocks (1024)
     =====================  ==========  =============================
 
+    Re-run 2026-09-30 (PR 44: the kernels' grids became ``(bh, live
+    tiles)`` tables, each kernel keeping the bodies it had;
+    ``tools/vmem_sweep.py --full``, libtpu 0.0.34 compiling for a described
+    v5e, at commit 585dfaa and at the change; the plan's blocks and the
+    benchmark cells' shapes under their three masks also on the chip): 156
+    probes — d in {64, 128} x (seq: bh) in {1024: 128, 1024; 2048: 64, 1024;
+    4096: 32, 128, 512; 8192: 16, 32, 64, 128; 16384: 8, 128} x six block
+    pairs from (256, 256) to (1024, 1024), each forced onto the combined
+    kernel.  The frontier did not move with the grid: probe for probe, parent
+    and change pass (124) and fail (32) alike.  The combined kernel compiles
+    at every probe up to seq 8192 except (1024, 1024) at 8192 (every bh), and
+    at no probe at seq 16384 — this compiler is more permissive than the
+    bands (8192 in (512, 512) passes at bh 64 and 128; 4096 passes in
+    1024-blocks), which stay as calibrated: they choose every benchmark
+    cell's blocks.
+
     ``mode`` is ``"combined"`` (one probability recompute per block,
     whole-seq dq scratch — preferred where it fits because it recomputes
     once; every benchmark cell runs it, PERF.md section 3) or ``"split"``
@@ -1277,86 +1163,46 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
                     block_k, interpret, scale_r, grad_dtype=jnp.float32,
                     dq_scale=1.0, window=None, blockdiff=None):
     """Split flash backward over (bh, sl, d) operands (q pre-scaled by
-    the pow2 part of sm_scale): two pallas_calls — dk/dv (queries inner)
-    and dq (keys inner) — each with O(block) scoped VMEM, so any
+    the pow2 part of sm_scale): two pallas_calls — dk/dv (key tiles outer,
+    queries inner) and dq (query tiles outer, keys inner) — each with
+    O(block) scoped VMEM, so any
     sequence length compiles.  Pays the s/p/dp/ds recompute twice; the
     combined kernel is preferred whenever its whole-seq dq scratch fits
     (see _bwd_plan).  Returns (dk, dv, dq) in ``grad_dtype`` (f32
-    accumulation in scratch; the flush casts).  With a ``window`` each
-    kernel's inner axis is its band's steps (`_flash_kernel`)."""
+    accumulation in scratch; the flush casts).  Each kernel's grid is its
+    table's live tiles (`_tile_table`)."""
     bh, sl, d = q.shape
     d_v = v.shape[-1]              # do, v and dv; q, k, dq and dk have d
-    num_q, num_k = sl // block_q, sl // block_k
-    qspec, kspec = _row_spec(block_q, d), _row_spec(block_k, d)
-    dospec, vspec = _row_spec(block_q, d_v), _row_spec(block_k, d_v)
-
-    def lse_spec(row):
-        return pl.BlockSpec((1, 8, block_q), lambda b, i, j, _r=row:
-                            (b, 0, _r(i, j)))
-
-    inner_q = inner_k = lambda i, j: j  # noqa: E731  (innermost grid dim)
-    outer = lambda i, j: i  # noqa: E731
-    q_steps, k_steps, band = num_q, num_k, None
+    mask = (sl // block_q, sl // block_k, block_q, block_k, causal, window,
+            blockdiff)
+    in_specs = [_tile_spec(0, block_q, d), _tile_spec(0, block_q, d_v),
+                _lse_spec(block_q), _lse_spec(block_q),
+                _tile_spec(1, block_k, d), _tile_spec(1, block_k, d_v)]
     suffix = _walk_suffix(window, blockdiff)
-    if window is not None:
-        band = (window, num_q)
-        q_steps = _band_steps(_queries_of_key_block(
-            np.arange(num_k), block_q, block_k, *band))
-        k_steps = _band_steps(_keys_of_query_block(
-            np.arange(num_q), block_q, block_k, window))
-
-        def inner_q(ki, j):
-            return _held_tile((_queries_of_key_block(
-                ki, block_q, block_k, *band),), j)
-
-        def inner_k(qi, j):
-            return _held_tile((_keys_of_query_block(
-                qi, block_q, block_k, window),), j)
-    elif blockdiff is not None:
-        q_steps = _walk_steps(_blockdiff_queries_of_key_block(
-            np.arange(num_k), block_q, block_k, *blockdiff))
-        k_steps = _walk_steps(_blockdiff_keys_of_query_block(
-            np.arange(num_q), block_q, block_k, *blockdiff))
-
-        def inner_q(ki, j):
-            return _held_tile(_blockdiff_queries_of_key_block(
-                ki, block_q, block_k, *blockdiff), j)
-
-        def inner_k(qi, j):
-            return _held_tile(_blockdiff_keys_of_query_block(
-                qi, block_q, block_k, *blockdiff), j)
     # vma: inside shard_map (build_train_step) the default check refuses
     # an out_shape that does not say how it varies; as q does.
     grad_shape = jax.ShapeDtypeStruct((bh, sl, d), grad_dtype,
                                       vma=jax.typeof(q).vma)
     dv_shape = jax.ShapeDtypeStruct((bh, sl, d_v), grad_dtype,
                                     vma=jax.typeof(q).vma)
-    dkdv = functools.partial(
-        _flash_bwd_dkdv_kernel, causal=causal, block_q=block_q,
-        block_k=block_k, num_q_blocks=q_steps, scale_r=scale_r, band=band,
-        blockdiff=blockdiff)
-    dk, dv = pl.pallas_call(
-        dkdv,
-        grid=(bh, num_k, q_steps),  # queries innermost
-        in_specs=[qspec(inner_q), dospec(inner_q), lse_spec(inner_q),
-                  lse_spec(inner_q), kspec(outer), vspec(outer)],
-        out_specs=(kspec(outer), vspec(outer)),
+    common = dict(causal=causal, block_q=block_q, block_k=block_k,
+                  scale_r=scale_r, window=window, blockdiff=blockdiff)
+    dk, dv = _tiled_call(
+        functools.partial(_flash_bwd_dkdv_kernel, **common),
+        _tile_table(*mask, by_key=True), bh,
+        in_specs=in_specs,
+        out_specs=(_tile_spec(1, block_k, d), _tile_spec(1, block_k, d_v)),
         out_shape=(grad_shape, dv_shape),
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d_v), jnp.float32)],
         interpret=interpret,
         name="hvd_flash_bwd_dkdv" + suffix,
     )(q, do, lse8, delta8, k, v)
-    dqk = functools.partial(
-        _flash_bwd_dq_kernel, causal=causal, block_q=block_q,
-        block_k=block_k, num_k_blocks=k_steps, scale_r=scale_r,
-        dq_scale=dq_scale, window=window, blockdiff=blockdiff)
-    dq = pl.pallas_call(
-        dqk,
-        grid=(bh, num_q, k_steps),  # keys innermost
-        in_specs=[qspec(outer), dospec(outer), lse_spec(outer),
-                  lse_spec(outer), kspec(inner_k), vspec(inner_k)],
-        out_specs=qspec(outer),
+    dq = _tiled_call(
+        functools.partial(_flash_bwd_dq_kernel, dq_scale=dq_scale, **common),
+        _tile_table(*mask), bh,
+        in_specs=in_specs,
+        out_specs=_tile_spec(0, block_q, d),
         out_shape=grad_shape,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
@@ -1365,15 +1211,24 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
     return dk, dv, dq
 
 
+# The most (query tile, key tile) pairs a schedule's table may list: it pads
+# to 16 bytes a step in SMEM, 1 MiB on a v5e (compiled for a described chip:
+# 57,970 steps pass, 65,341 do not).
+_TABLE_STEPS = 56 * 1024
+
+
 def _off_grid(q_len, k_len, block_q, block_k, blockdiff=None) -> bool:
     """Whether the blocks leave the kernels' grid: ragged tails, blocks off
     the TPU tiling (the lse output block puts ``block_q`` in the 128-lane
-    dimension) or, under block diffusion, a tile that would lie across the two
-    copies (the blocks divide ONE copy's rows)."""
+    dimension), under block diffusion a tile that would lie across the two
+    copies (the blocks divide ONE copy's rows), or more tile pairs, counted
+    before any mask, than a table holds (`_TABLE_STEPS`: 128-blocks past
+    30,000 rows)."""
+    pairs = (q_len // block_q) * (k_len // block_k)
     if blockdiff is not None:
         q_len = k_len = blockdiff[1]
     return bool(q_len % block_q or k_len % block_k
-                or block_q % 128 or block_k % 128)
+                or block_q % 128 or block_k % 128 or pairs > _TABLE_STEPS)
 
 
 def _walk_suffix(window, blockdiff) -> str:
@@ -1381,6 +1236,24 @@ def _walk_suffix(window, blockdiff) -> str:
     name in a trace."""
     return "_blockdiff" if blockdiff is not None \
         else "" if window is None else "_window"
+
+
+def _backward_blocks(q_len, k_len, d, d_v, block_q, block_k, bh,
+                     blockdiff=None):
+    """(mode, block_q, block_k) as :func:`_bwd_plan` gives the backward at
+    ``flash_attention``'s blocks, or None where the shape leaves the kernels
+    for the scan."""
+    block_q = min(block_q, q_len)
+    block_k = min(block_k, k_len)
+    if _off_grid(q_len, k_len, block_q, block_k, blockdiff) \
+            or q_len != k_len:
+        return None
+    # One width: the call as every caller and test stand-in has known it.
+    widths = {} if d_v == d else {"d_v": d_v}
+    plan = _bwd_plan(q_len, d, block_q, block_k, bh, **widths)
+    # The plan may step blocks down past what divides this length (rare
+    # non-power-of-two long seqs): the scan impl handles it.
+    return None if _off_grid(q_len, k_len, *plan[1:], blockdiff) else plan
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
@@ -1393,21 +1266,13 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
     memory is O(seq) either way (Dao et al. alg. 2)."""
     batch, heads, q_len, d = q.shape
     k_len, d_v = k.shape[2], v.shape[-1]
-    block_q = min(block_q, q_len)
-    block_k = min(block_k, k_len)
-    if _off_grid(q_len, k_len, block_q, block_k, blockdiff) \
-            or q_len != k_len:
+    plan = _backward_blocks(q_len, k_len, d, d_v, block_q, block_k,
+                            batch * heads, blockdiff)
+    if plan is None:
         return _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale,
-                                   max(block_k, 128), 0, 0, window, blockdiff)
-    # One width: the call as every caller and test stand-in has known it.
-    widths = {} if d_v == d else {"d_v": d_v}
-    mode, block_q, block_k = _bwd_plan(q_len, d, block_q, block_k,
-                                       batch * heads, **widths)
-    if _off_grid(q_len, k_len, block_q, block_k, blockdiff):
-        # Plan stepped blocks down past what divides this length (rare
-        # non-power-of-two long seqs): the scan impl handles it.
-        return _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale,
-                                   max(block_k, 128), 0, 0, window, blockdiff)
+                                   max(min(block_k, k_len), 128), 0, 0,
+                                   window, blockdiff)
+    mode, block_q, block_k = plan
     bh = batch * heads
     # Pre-scaled q (see _flash_forward): exact pow2 factor on q, f32
     # residual inside the kernel; dq comes back in q' units and is
@@ -1435,10 +1300,9 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
     grad_dtype = q.dtype if same_dtype else jnp.float32
     if mode == "combined":
         dk, dv, dq = _combined_bwd_call(
-            qr, dor, lse8, delta8, kr, vr, 0, 0, causal=causal,
-            block_q=block_q, block_k=block_k, rotate=False,
-            collective_id=None, axis_name=None, mesh_axes=(),
-            interpret=interpret, scale_r=scale_r, grad_dtype=grad_dtype,
+            qr, dor, lse8, delta8, kr, vr, causal=causal,
+            block_q=block_q, block_k=block_k, interpret=interpret,
+            scale_r=scale_r, grad_dtype=grad_dtype,
             dq_scale=p2, window=window, blockdiff=blockdiff,
             # A banded call's name keeps the prefix a trace is read by.
             name="hvd_flash_bwd" + _walk_suffix(window, blockdiff))
@@ -1498,43 +1362,17 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     kr = k.reshape(bh, k_len, d)
     vr = v.reshape(bh, k_len, d_v)
     vma = jax.typeof(q).vma  # see _split_bwd_call
-    num_q = q_len // block_q
-    num_k = k_len // block_k
-    qspec, kspec = _row_spec(block_q, d), _row_spec(block_k, d)
-    ospec, vspec = _row_spec(block_q, d_v), _row_spec(block_k, d_v)
-    qrow = lambda i, j: i  # noqa: E731
-    krow = lambda i, j: j  # noqa: E731
-    name = "hvd_flash_fwd" + _walk_suffix(window, blockdiff)
-    if window is not None:
-        # The key axis is the band's steps; past a band's last block the
-        # index stays on it, and an index that does not move copies nothing.
-        num_k = _band_steps(_keys_of_query_block(
-            np.arange(num_q), block_q, block_k, window))
-
-        def krow(i, j):
-            return _held_tile((_keys_of_query_block(
-                i, block_q, block_k, window),), j)
-    elif blockdiff is not None:
-        # The key axis walks a query block's two runs the same way.
-        num_k = _walk_steps(_blockdiff_keys_of_query_block(
-            np.arange(num_q), block_q, block_k, *blockdiff))
-
-        def krow(i, j):
-            return _held_tile(_blockdiff_keys_of_query_block(
-                i, block_q, block_k, *blockdiff), j)
-
+    table = _tile_table(q_len // block_q, k_len // block_k, block_q, block_k,
+                        causal, window, blockdiff)
     kernel = functools.partial(
-        _flash_kernel, causal=causal, block_q=block_q,
-        block_k=block_k, num_k_blocks=num_k, scale_r=scale_r, window=window,
-        blockdiff=blockdiff)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(bh, num_q, num_k),
-        in_specs=[qspec(qrow), kspec(krow), vspec(krow)],
-        out_specs=(
-            ospec(qrow),
-            pl.BlockSpec((1, 8, block_q), lambda b, qi, ki: (b, 0, qi)),
-        ),
+        _flash_kernel, causal=causal, block_q=block_q, block_k=block_k,
+        single_k=table.shape[1] == q_len // block_q, scale_r=scale_r,
+        window=window, blockdiff=blockdiff)
+    out, lse = _tiled_call(
+        kernel, table, bh,
+        in_specs=[_tile_spec(0, block_q, d), _tile_spec(1, block_k, d),
+                  _tile_spec(1, block_k, d_v)],
+        out_specs=(_tile_spec(0, block_q, d_v), _lse_spec(block_q)),
         out_shape=(
             jax.ShapeDtypeStruct((bh, q_len, d_v), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, 8, q_len), jnp.float32, vma=vma),
@@ -1545,7 +1383,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
             pltpu.VMEM((block_q, d_v), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
-        name=name,
+        name="hvd_flash_fwd" + _walk_suffix(window, blockdiff),
     )(qr, kr, vr)
     return (out.reshape(batch, heads, q_len, d_v),
             lse[:, 0, :].reshape(batch, heads, q_len))
@@ -1590,32 +1428,33 @@ def flash_attention(q, k, v, causal: bool = False,
     diffusion model's training pass (:func:`block_diffusion_mask`: a clean
     query sees the clean keys of its block of ``B`` and of the earlier ones,
     a noised query the clean keys of the earlier blocks and the noised keys
-    of its own).  The mask is no function of ``t - s``, so a query block's
-    key blocks are TWO runs — the clean ones from 0 on, then its own noised
-    ones — and each kernel's inner grid axis walks them one after the other
-    as a band is walked (a key block's query blocks likewise: its clean ones,
-    then the noised ones of the later diffusion blocks): 24 of the 64 pairs
-    of 1,024-blocks at ``L = 4,096``, where a causal walk over ``2 L`` takes
-    36 (:func:`blockdiff_blocks`).  Blocks the mask cuts are masked inside
-    from block ids, whole ones take the unmasked body.  The blocks divide
-    ``L``; other shapes take the scan.  Kernels: ``hvd_flash_fwd_blockdiff``,
-    ``hvd_flash_bwd_blockdiff``, ``hvd_flash_bwd_dkdv_blockdiff``,
-    ``hvd_flash_bwd_dq_blockdiff``.
+    of its own): 24 of the 64 pairs of 1,024-blocks at ``L = 4,096``, where a
+    causal walk over ``2 L`` takes 36 (:func:`blockdiff_blocks`).  The blocks
+    divide ``L``; other shapes take the scan.  Kernels:
+    ``hvd_flash_fwd_blockdiff``, ``hvd_flash_bwd_blockdiff``,
+    ``hvd_flash_bwd_dkdv_blockdiff``, ``hvd_flash_bwd_dq_blockdiff``.
 
     ``window=W`` (with ``causal=True``): a sliding window — query ``t`` sees
     the keys ``s`` with ``0 <= t - s < W``, itself and the ``W - 1`` before
-    it.  A (query block, key block) pair wholly outside that band is neither
-    computed nor fetched, forward or backward: each kernel's inner grid axis
-    is as long as the widest band of blocks (``W`` over the block, plus the
-    blocks the band's two edges cut) and its index maps walk the band, holding
-    the index still where a band is shorter — an index that does not move
-    issues no copy.  The blocks the edges cut are masked inside.  ``W`` need
-    not divide by the block; ``W >= seq`` IS the causal call, program for
-    program.  The banded kernels are named ``hvd_flash_fwd_window``,
-    ``hvd_flash_bwd_window``, ``hvd_flash_bwd_dkdv_window`` and
-    ``hvd_flash_bwd_dq_window`` in a trace; the backward takes the plan
-    :func:`_bwd_plan` gives the shape (a band needs no more VMEM), and
-    :func:`window_blocks` counts what the forward visits.
+    it.  ``W`` need not divide by the block; ``W >= seq`` IS the causal call,
+    program for program.  The banded kernels are named
+    ``hvd_flash_fwd_window``, ``hvd_flash_bwd_window``,
+    ``hvd_flash_bwd_dkdv_window`` and ``hvd_flash_bwd_dq_window`` in a trace;
+    the backward takes the plan :func:`_bwd_plan` gives the shape (a band
+    needs no more VMEM), and :func:`window_blocks` counts what the forward
+    visits.
+
+    Under every mask a kernel's grid is ``(batch * heads, live tiles)``: the
+    (query block, key block) pairs the mask touches, listed on the host from
+    the static shapes (:func:`_tile_table`) and handed to the grid by scalar
+    prefetch — the causal mask's pairs on and under the diagonal, a window's
+    band, block diffusion's two runs a row.  A pair wholly outside the mask
+    is neither a grid step nor a copy, forward or backward.  The causal and
+    the banded kernels hold ONE body, which masks every tile it is given;
+    block diffusion's hold two, and the table's flag sends a tile the mask
+    leaves whole to the one with no mask arithmetic
+    (:func:`flash_grid_steps` counts a shape's live pairs, grid steps and the
+    rectangles the tables replaced).
 
     ``v``'s last axis may differ from ``q``'s and ``k``'s (latent attention:
     a 192-wide query and key, a 128-wide value); the output has ``v``'s, and
@@ -1631,9 +1470,10 @@ def flash_attention(q, k, v, causal: bool = False,
     A Pallas kernel (MXU-tiled blocks, VMEM online-softmax state):
     compiled by Mosaic on a TPU backend, run by the Pallas interpreter
     elsewhere (``interpret=None`` asks the backend; pass it explicitly to
-    compile for a described chip).  Two documented routings leave the
+    compile for a described chip).  Three documented routings leave the
     kernel for the mathematically identical :func:`blockwise_attention`
-    scan — ragged block tails, and float16 on the compiled path — so a
+    scan — ragged block tails, more tiles than a schedule's table holds
+    (:func:`_off_grid`), and float16 on the compiled path — so a
     caller who must know which ran reads the compiled HLO for
     ``tpu_custom_call`` (chip_smoke.py does).  Differentiable
     with the flash backward (logsumexp residual + per-block recompute,
@@ -1699,6 +1539,13 @@ def _default_blocks(q_len, k_len, block_q=None, block_k=None):
     return block_q, block_k
 
 
+def _live_tiles(seq, blocks, causal=False, window=None, blockdiff=None):
+    """The (query tile, key tile) pairs of ``seq`` rows in ``blocks`` that a
+    mask touches."""
+    return int(_tile_masks(seq // blocks[0], seq // blocks[1], *blocks,
+                           causal, window, blockdiff)[0].sum())
+
+
 def window_blocks(seq: int, window: int, d: int, d_v: Optional[int] = None,
                   block_q: Optional[int] = None,
                   block_k: Optional[int] = None):
@@ -1712,13 +1559,8 @@ def window_blocks(seq: int, window: int, d: int, d_v: Optional[int] = None,
                              *_default_blocks(seq, seq, block_q, block_k))
     if blocks is None:
         return None
-    rows = np.arange(seq // blocks[0])
-    causal = int((_div(rows * blocks[0] + blocks[0] - 1, blocks[1])
-                  + 1).sum())
-    if _checked_window(window, True, seq) is None:
-        return causal, causal
-    first, last = _keys_of_query_block(rows, *blocks, window)
-    return int((last - first + 1).sum()), causal
+    return (_live_tiles(seq, blocks, True, _checked_window(window, True, seq)),
+            _live_tiles(seq, blocks, True))
 
 
 def blockdiff_blocks(seq: int, block: int, d: int, d_v: Optional[int] = None,
@@ -1737,8 +1579,47 @@ def blockdiff_blocks(seq: int, block: int, d: int, d_v: Optional[int] = None,
                              blockdiff=blockdiff)
     if blocks is None:
         return None
-    rows = np.arange(2 * seq // blocks[0])
-    causal = int((_div(rows * blocks[0] + blocks[0] - 1, blocks[1])
-                  + 1).sum())
-    runs = _blockdiff_keys_of_query_block(rows, *blocks, *blockdiff)
-    return int(sum(last - first + 1 for first, last in runs).sum()), causal
+    return (_live_tiles(2 * seq, blocks, blockdiff=blockdiff),
+            _live_tiles(2 * seq, blocks, True))
+
+
+def flash_grid_steps(seq: int, d: int, bh: int, d_v: Optional[int] = None,
+                     causal: bool = False, window: Optional[int] = None,
+                     block_diffusion: Optional[int] = None,
+                     block_q: Optional[int] = None,
+                     block_k: Optional[int] = None):
+    """``{kernel's name in a trace: (live, steps, rectangle)}`` for the
+    kernels that ``flash_attention`` and its gradient run at ``(batch, heads,
+    seq, d)`` operands (``bh = batch * heads``; ``seq`` counts both copies
+    under ``block_diffusion``) — the forward, and the combined backward or
+    the split pair as :func:`_bwd_plan` decides: ``live`` the (query tile,
+    key tile) pairs of the kernel's blocks that the mask touches, ``steps``
+    the steps one ``bh`` row of the kernel's grid takes (its table's length),
+    ``rectangle`` all the pairs of its blocks, which a grid over (query
+    tiles, key tiles) would step through.  The grid is the table of the live
+    pairs, so the first two are equal: 36 and 136 for the causal forward and
+    backward at 8,192 rows of width 64, of rectangles of 64 and 256.  A pass
+    that leaves the kernels for the scan has no entry."""
+    window = _checked_window(window, causal, seq)
+    blockdiff = _checked_block_diffusion(block_diffusion, causal, window, seq,
+                                         seq)
+    copy = seq if blockdiff is None else seq // 2
+    block_q, block_k = _default_blocks(copy, copy, block_q, block_k)
+    suffix = _walk_suffix(window, blockdiff)
+    tables = {}
+    blocks = _forward_blocks(seq, seq, d, d_v or d, block_q, block_k, window,
+                             blockdiff)
+    if blocks is not None:
+        tables["hvd_flash_fwd" + suffix] = blocks, False
+    plan = _backward_blocks(seq, seq, d, d_v or d, block_q, block_k, bh,
+                            blockdiff)
+    if plan is not None and plan[0] == "combined":
+        tables["hvd_flash_bwd" + suffix] = plan[1:], True
+    elif plan is not None:
+        tables["hvd_flash_bwd_dkdv" + suffix] = plan[1:], True
+        tables["hvd_flash_bwd_dq" + suffix] = plan[1:], False
+    return {name: (_live_tiles(seq, blocks, causal, window, blockdiff),
+                   _tile_table(seq // blocks[0], seq // blocks[1], *blocks,
+                               causal, window, blockdiff, by_key).shape[1],
+                   (seq // blocks[0]) * (seq // blocks[1]))
+            for name, (blocks, by_key) in tables.items()}

@@ -1,0 +1,394 @@
+"""The flash kernels' schedule (`ops.attention._tile_table`): a host-built
+table of the live (query tile, key tile) pairs is each kernel's grid — against
+the masks evaluated element by element, at the benchmark's cells' shapes; the
+counter that holds `grid_steps == grid_live`; the kernels' values against
+`mha_reference` under the three masks, combined and split, and bit for bit
+against what the rectangular grids of commit 585dfaa gave; and the bodies a
+kernel holds (one under the causal mask and a window, block diffusion's two)."""
+
+import functools
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import horovod_tpu.ops.attention as attn
+from horovod_tpu.ops import flash_attention, mha_reference
+from horovod_tpu.ops.attention import (blockdiff_blocks, flash_grid_steps,
+                                       window_blocks)
+
+
+def seen_pairs(seq, causal=False, window=None, block_diffusion=None):
+    """The mask as a (seq, seq) boolean matrix, position by position."""
+    t, s = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    if block_diffusion is not None:
+        return np.asarray(attn.block_diffusion_mask(t, s, block_diffusion,
+                                                    seq // 2))
+    if not causal:
+        return np.ones((seq, seq), bool)
+    return (s <= t) if window is None else (s <= t) & (t - s < window)
+
+
+def equations(jaxpr, primitive):
+    """Every equation of ``primitive`` in ``jaxpr``, sub-programs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from equations(sub, primitive)
+
+
+def pallas_calls(jaxpr):
+    """{kernel name: grid} of every pallas_call, sub-programs included."""
+    return {eqn.params["name"]: tuple(eqn.params["grid_mapping"].grid)
+            for eqn in equations(jaxpr, "pallas_call")}
+
+
+def check_tables(seen, block_q, block_k, causal=False, window=None,
+                 blockdiff=None):
+    """Both walks of the mask ``seen`` (queries outer, keys outer): every
+    tile that holds a seen pair is a step exactly once and no other tile is;
+    a row's steps are contiguous, its tiles ascend, and `first` and `last`
+    are set once a row, on its first and last step; `whole` is block
+    diffusion's flag alone, set where the tile holds no unseen pair (the
+    causal and the banded kernels have one body and no such flag).  Returns
+    the live pairs."""
+    num_q, num_k = seen.shape[0] // block_q, seen.shape[1] // block_k
+    tiles = seen.reshape(num_q, block_q, num_k, block_k)
+    live, whole = tiles.any((1, 3)), tiles.all((1, 3))
+    want = set(zip(*np.nonzero(live)))
+    for by_key in (False, True):
+        q_tile, k_tile, flags = attn._tile_table(
+            num_q, num_k, block_q, block_k, causal, window, blockdiff,
+            by_key=by_key)
+        pairs = list(zip(q_tile.tolist(), k_tile.tolist()))
+        assert len(pairs) == len(set(pairs)) and set(pairs) == want
+        outer, inner = (k_tile, q_tile) if by_key else (q_tile, k_tile)
+        starts = np.flatnonzero(np.r_[True, np.diff(outer) != 0])
+        # contiguous rows, each outer tile one row, in ascending order
+        assert (outer[starts] == np.arange(len(starts))).all()
+        assert len(starts) == (num_k if by_key else num_q)
+        same_row = np.diff(outer) == 0
+        assert (np.diff(inner)[same_row] > 0).all()
+        first = (flags & attn._FIRST) != 0
+        last = (flags & attn._LAST) != 0
+        assert (np.flatnonzero(first) == starts).all()
+        assert (np.flatnonzero(last)
+                == np.r_[starts[1:] - 1, len(outer) - 1]).all()
+        assert (((flags & attn._WHOLE) != 0)
+                == (whole[q_tile, k_tile] if blockdiff is not None
+                    else False)).all()
+        assert flags.max() < 8
+    return want
+
+
+# The benchmark's cells: (rows, mask, head width, batch * heads).  Causal at
+# 2,048 (`_4x2k`, dp4), 4,096 (OLMoE, Nemotron) and 8,192 (`_1x8k`, Trinity's
+# full layer, Ling's two widths); Trinity's window of 2,048 at 8,192; SDAR's
+# blocks of 4 over two copies of 4,096.
+CELLS = [
+    (2048, dict(causal=True), 64, None, 64),
+    (4096, dict(causal=True), 128, None, 32),
+    (8192, dict(causal=True), 64, None, 16),
+    (8192, dict(causal=True), 128, None, 32),
+    (8192, dict(causal=True), 192, 128, 4),
+    (8192, dict(causal=True, window=2048), 128, None, 32),
+    (8192, dict(block_diffusion=4), 128, None, 32),
+]
+
+
+@pytest.mark.parametrize("seq,mask,d,d_v,bh", CELLS, ids=str)
+def test_the_tables_at_the_cells_shapes(seq, mask, d, d_v, bh):
+    """The forward's table at the blocks it takes and the backward's at the
+    plan's: exactly the mask's tiles, and the counter's `live` is what
+    `window_blocks` / `blockdiff_blocks` / the causal count give."""
+    seen = seen_pairs(seq, **mask)
+    blockdiff = (mask["block_diffusion"], seq // 2) \
+        if "block_diffusion" in mask else None
+    table_mask = (mask.get("causal", False), mask.get("window"), blockdiff)
+    mode, plan_q, plan_k = attn._bwd_plan(seq, d, 1024, 1024, bh,
+                                          **({"d_v": d_v} if d_v else {}))
+    forward = len(check_tables(seen, 1024, 1024, *table_mask))
+    backward = len(check_tables(seen, plan_q, plan_k, *table_mask))
+    grids = flash_grid_steps(seq, d, bh, d_v, **mask)
+    suffix = attn._walk_suffix(mask.get("window"), blockdiff)
+    names = {"combined": ["hvd_flash_bwd"],
+             "split": ["hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"]}[mode]
+    rectangle = (seq // plan_q) * (seq // plan_k)
+    assert grids == {
+        "hvd_flash_fwd" + suffix: (forward, forward, (seq // 1024) ** 2),
+        **{name + suffix: (backward, backward, rectangle) for name in names}}
+    if "window" in mask:
+        assert window_blocks(seq, mask["window"], d)[0] == forward
+    elif blockdiff is not None:
+        assert blockdiff_blocks(seq // 2, blockdiff[0], d)[0] == forward
+    else:
+        assert forward == sum(i + 1 for i in range(seq // 1024))
+        assert window_blocks(seq, seq, d) == (forward, forward)
+
+
+MASKS = [dict(causal=True), dict(causal=True, window=200),
+         dict(block_diffusion=32), dict()]
+
+
+@pytest.mark.parametrize("plan", ["combined", "split"])
+@pytest.mark.parametrize("mask", MASKS, ids=str)
+def test_no_grid_step_computes_nothing(monkeypatch, mask, plan):
+    """The grids of the traced program, forward and backward: `(bh, the
+    mask's live tiles)`, which is what the counter reports as both its
+    numbers."""
+    monkeypatch.setattr(attn, "_bwd_plan",
+                        lambda q_len, d, bq, bk, bh=1: (plan, 128, 256))
+    seq, bh = 1024, 2
+    shape = jax.ShapeDtypeStruct((1, bh, seq, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, block_q=256, block_k=128,
+                               interpret=True, **mask
+                               ).astype(jnp.float32).sum()
+
+    grids = pallas_calls(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
+        shape, shape, shape).jaxpr)
+    counted = flash_grid_steps(seq, 64, bh, block_q=256, block_k=128, **mask)
+    assert len(counted) == {"combined": 2, "split": 3}[plan]
+    assert grids == {name: (bh, steps) for name, (_, steps, _)
+                     in counted.items()}
+    seen = seen_pairs(seq, **mask)
+    for name, (live, steps, rectangle) in counted.items():
+        block_q, block_k = (256, 128) if "fwd" in name else (128, 256)
+        tiles = seen.reshape(seq // block_q, block_q, seq // block_k, block_k)
+        assert live == steps == tiles.any((1, 3)).sum(), name
+        assert rectangle == 32 and (live < 32) == bool(mask), name
+
+
+def test_the_ring_reads_all_pairs():
+    """The ring's offsets are traced: its table holds every pair, with the
+    rows' `first` and `last` and nothing else, whatever the mask (the
+    kernel's predicate decides a tile on the device)."""
+    for causal in (True, False):
+        q_tile, k_tile, flags = attn._tile_table(3, 2, 128, 256, causal,
+                                                 by_key=True, every=True)
+        assert list(zip(k_tile, q_tile)) == [(j, i) for j in range(2)
+                                             for i in range(3)]
+        assert flags.tolist() == [attn._FIRST, 0, attn._LAST] * 2
+
+
+def test_more_tiles_than_a_table_holds_take_the_scan():
+    """A table lives in SMEM, so its steps are bounded (`_TABLE_STEPS` pairs,
+    counted before the mask): past it a shape leaves the kernels as a ragged
+    one does, forward and backward, and the counter has no entry for it."""
+    fits = 128 * int(attn._TABLE_STEPS ** 0.5)
+    for seq, kept in ((fits, True), (fits + 128, False)):
+        assert (attn._forward_blocks(seq, seq, 64, 64, 128, 128)
+                is not None) == kept
+        assert (attn._backward_blocks(seq, seq, 64, 64, 128, 128, 1)
+                is not None) == kept
+        assert bool(flash_grid_steps(seq, 64, 1, causal=True, block_q=128,
+                                     block_k=128)) == kept
+    # the default blocks reach a quarter of a million rows
+    assert attn._forward_blocks(1 << 17, 1 << 17, 64, 64, 1024, 1024)
+
+
+@pytest.mark.parametrize("plan", ["combined", "split"])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("mask", MASKS[:3], ids=str)
+def test_values_and_gradients_are_the_references(monkeypatch, mask, d, plan):
+    """Output, lse, dq, dk and dv of the table-driven kernels (interpreted)
+    under the three masks, with tiles the mask cuts and whole ones, against
+    `mha_reference`."""
+    monkeypatch.setattr(attn, "_bwd_plan",
+                        lambda q_len, d, bq, bk, bh=1: (plan, 128, 256))
+    rng = np.random.default_rng(d + len(mask))
+    q, k, v, mix = (jnp.asarray(rng.standard_normal((1, 2, 512, d)),
+                                jnp.float32) for _ in range(4))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, block_q=256, block_k=128,
+                               interpret=True, **mask)
+
+    def reference(q, k, v):
+        return mha_reference(q, k, v, **mask)
+
+    np.testing.assert_allclose(flash(q, k, v), reference(q, k, v),
+                               atol=2e-5, rtol=2e-5)
+    causal, window = mask.get("causal", False), mask.get("window")
+    blockdiff = (mask["block_diffusion"], 256) \
+        if "block_diffusion" in mask else None
+    _, lse = attn._flash_forward(q, k, v, causal, d ** -0.5, 256, 128, True,
+                                 window, blockdiff)
+    logits = jnp.where(seen_pairs(512, **mask), jnp.einsum(
+        "bhqd,bhkd->bhqk", q, k, precision="highest") * d ** -0.5, -jnp.inf)
+    np.testing.assert_allclose(lse, jax.nn.logsumexp(logits, -1),
+                               atol=2e-5, rtol=2e-5)
+    got = jax.grad(lambda *a: (flash(*a) * mix).sum(), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (reference(*a) * mix).sum(),
+                    (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=1e-3, rtol=1e-3)
+
+
+# --- bit for bit what the rectangular grids gave ------------------------------
+
+def kernel_digests(attn, mask, d, plan):
+    """sha256 (16 hex digits) of the float32 bytes of out, lse, dq, dk, dv of
+    the interpreted kernels on a fixed seed: 2 heads of 512 rows, forward in
+    (256, 128) blocks, backward ``plan`` in (128, 256).  ``attn``: the
+    module, so that a copy of commit 585dfaa can be asked the same (which is
+    where `AT_585DFAA` came from), and why the plan is patched by hand."""
+    rng = np.random.default_rng([d, len(mask), plan == "split"])
+    q, k, v, mix = (jnp.asarray(rng.standard_normal((1, 2, 512, d)),
+                                jnp.bfloat16) for _ in range(4))
+    causal, window = mask.get("causal", False), mask.get("window")
+    blockdiff = (mask["block_diffusion"], 256) \
+        if "block_diffusion" in mask else None
+    real, attn._bwd_plan = attn._bwd_plan, \
+        lambda q_len, d, bq, bk, bh=1: (plan, 128, 256)
+    try:
+        out, lse = attn._flash_forward(q, k, v, causal, d ** -0.5, 256, 128,
+                                       True, window, blockdiff)
+        grads = jax.grad(lambda *a: (attn.flash_attention(
+            *a, block_q=256, block_k=128, interpret=True, **mask).astype(
+                jnp.float32) * mix.astype(jnp.float32)).sum(),
+            (0, 1, 2))(q, k, v)
+    finally:
+        attn._bwd_plan = real
+    return {name: hashlib.sha256(np.asarray(
+        x.astype(jnp.float32)).tobytes()).hexdigest()[:16]
+        for name, x in zip(("out", "lse", "dq", "dk", "dv"),
+                           (out, lse) + tuple(grads))}
+
+
+def ring_digests(fused_ring_attention, causal):
+    """The same of the fused ring's dq, dk, dv on four devices: shards of 256
+    rows in 128-blocks, the backward step the combined kernel."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    rng = np.random.default_rng([4, causal])
+    q, k, v, mix = (jnp.asarray(rng.standard_normal((1, 2, 1024, 64)),
+                                jnp.float32) for _ in range(4))
+    mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))
+    spec = P(None, None, "sp", None)
+    fn = functools.partial(fused_ring_attention, axis_name="sp",
+                           causal=causal, block_q=128, block_k=128)
+
+    def loss(q, k, v):
+        out = jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 3,
+                            out_specs=spec, check_vma=False)(q, k, v)
+        return (out * mix).sum()
+
+    grads = jax.jit(jax.grad(loss, (0, 1, 2)))(q, k, v)
+    return {name: hashlib.sha256(np.asarray(x).tobytes()).hexdigest()[:16]
+            for name, x in zip(("dq", "dk", "dv"), grads)}
+
+
+def kernel_dots(attn, mask, plan):
+    """{kernel name: the `dot_general`s its traced body holds}, forward and
+    backward ``plan`` of a 512-row call."""
+    real, attn._bwd_plan = attn._bwd_plan, \
+        lambda q_len, d, bq, bk, bh=1: (plan, 128, 256)
+    try:
+        shape = jax.ShapeDtypeStruct((1, 2, 512, 64), jnp.bfloat16)
+        program = jax.make_jaxpr(jax.grad(
+            lambda *a: attn.flash_attention(
+                *a, block_q=256, block_k=128, interpret=True, **mask).astype(
+                    jnp.float32).sum(), (0, 1, 2)))(shape, shape, shape).jaxpr
+    finally:
+        attn._bwd_plan = real
+    return {eqn.params["name"]: len(list(equations(eqn.params["jaxpr"],
+                                                   "dot_general")))
+            for eqn in equations(program, "pallas_call")}
+
+
+# What the kernels of commit 585dfaa — rectangular grids, a dead step a
+# `pl.when` — gave for `kernel_digests` and `ring_digests` (my sandbox run,
+# PR 44: tests' CPU, one thread, jax 0.9.0): "out lse dq dk dv".
+AT_585DFAA = {
+    ("causal", 64, "combined"):
+        "51b5ac60505070cc 1c28bf07c20f90c9 34811145dfd841c9 "
+        "cacbf0a8b46d3a7a 07ca09b520ab1e9e",
+    ("causal", 128, "combined"):
+        "ab0008f032afdb89 105a28bd76affc86 2264b3669e44a2aa "
+        "5cc892fef96e2a54 dedb7967cc803eb3",
+    ("causal", 64, "split"):
+        "e446efaa7a7ae87c 7d5706aa1d627f69 1f190df0a10c3a3e "
+        "afc998af084cdc5c 7a690e07806a95a4",
+    ("causal", 128, "split"):
+        "82ab99b6d8419934 21d7378f462d0edc 116ca8fabb575eeb "
+        "1362faaa591c759d fbc99d90cc165691",
+    ("window", 64, "combined"):
+        "39888813254f2931 7f2c8855cea9a0be 16b107c10a969b9d "
+        "08481e2850f3ab4b 0d14f36488f38dc3",
+    ("window", 128, "combined"):
+        "5650d5f6f0c088fb cb37851d65d35160 9e81b9bb2456d533 "
+        "64c1a57ff22cbd03 b895055985c97a9d",
+    ("window", 64, "split"):
+        "63546bedb16b0ccd b120014d2691e9bf 9aa2f280d33b1c7b "
+        "053b86da87d1e7d8 40616ef193dced1f",
+    ("window", 128, "split"):
+        "8d2d1919d3458897 d76ae852ce6d2e2c f531e54399ba3cd2 "
+        "c19b503ad4cc807b ff93f42b8cb3dc69",
+    ("blockdiff", 64, "combined"):
+        "c9e6432b9ffc8fa7 67b1fa249f726edf 80485a65ac9f300d "
+        "1f59dd8e6355e8c9 3b4ca7a90d727d7b",
+    ("blockdiff", 128, "combined"):
+        "7d03abd2c314fd0e cf91dc63b569c4fd 13b82d5e2af978f6 "
+        "6460d43b6705936c e87263ccf51a6831",
+    ("blockdiff", 64, "split"):
+        "3ba393d68f76ad23 0f319087303ead93 c8aa3488acfc1fbd "
+        "173bdd5a7296218b 4f46fb65ef1a206a",
+    ("blockdiff", 128, "split"):
+        "058c56ea4f7c3b75 889f53180d26dd85 bf77fcfd6fe0d5e0 "
+        "f44d819c705a1c91 512d5551f1e344ad",
+}
+RING_AT_585DFAA = {   # causal: "dq dk dv"
+    True: "fc3974e071218aad 4fe77be1a11f22fb c8528052c4369502",
+    False: "4f482053f7b04cbf 26a9a65ddadb619a 497f9800819681bd",
+}
+MASK_OF = {"causal": dict(causal=True),
+           "window": dict(causal=True, window=200),
+           "blockdiff": dict(block_diffusion=32)}
+
+
+@pytest.mark.parametrize("name,d,plan", list(AT_585DFAA), ids=str)
+def test_bit_for_bit_what_the_rectangular_grids_gave(name, d, plan):
+    """Same blocks, same arithmetic in a live tile, same order of every
+    accumulation: out, lse, dq, dk, dv are the parent's to the bit."""
+    got = kernel_digests(attn, MASK_OF[name], d, plan)
+    assert " ".join(got.values()) == AT_585DFAA[name, d, plan], got
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_the_rings_backward_step_bit_for_bit(causal):
+    """The fused ring's gradients on a four-device mesh: the combined kernel
+    over the table of every pair, its predicate on the traced offsets."""
+    from horovod_tpu.ops.ring_flash import fused_ring_attention
+
+    got = ring_digests(fused_ring_attention, causal)
+    assert " ".join(got.values()) == RING_AT_585DFAA[causal], got
+
+
+# The products of one body: q k^T and p v forward; s, dp, dv, dk, dq in the
+# combined backward; the dk/dv kernel without dq's, the dq kernel without
+# dk's and dv's.
+ONE_BODY = {"hvd_flash_fwd": 2, "hvd_flash_bwd": 5, "hvd_flash_bwd_dkdv": 4,
+            "hvd_flash_bwd_dq": 3}
+
+
+@pytest.mark.parametrize("plan", ["combined", "split"])
+@pytest.mark.parametrize("name", list(MASK_OF))
+def test_a_kernel_holds_the_bodies_it_had(name, plan):
+    """A causal and a banded kernel hold ONE body (every tile through the
+    masked one), block diffusion's two (masked and unmasked, by the table's
+    flag), as at commit 585dfaa: a body more is 5,600 to 9,800 bundles a
+    kernel in every cell's program and seconds of every run's set-up
+    (PERF.md section 6, PR 44), so one that comes back has to say so here."""
+    suffix = {"causal": "", "window": "_window",
+              "blockdiff": "_blockdiff"}[name]
+    bodies = 2 if name == "blockdiff" else 1
+    names = ["hvd_flash_fwd"] + {
+        "combined": ["hvd_flash_bwd"],
+        "split": ["hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"]}[plan]
+    assert kernel_dots(attn, MASK_OF[name], plan) == {
+        kernel + suffix: bodies * ONE_BODY[kernel] for kernel in names}

@@ -31,6 +31,7 @@ from horovod_tpu.ops import (blockwise_attention, flash_attention,
 from horovod_tpu.ops.attention import blockdiff_blocks
 from tests.test_hybrid import (both_ways, close, spread, trees_close,
                                with_highest)
+from tests.test_flash_table import check_tables
 from tests.test_ops import _pallas_call_names
 from tests.test_trinity import pallas_calls, plan_of, qkv
 
@@ -220,51 +221,23 @@ WALKS = [(512, 4, 64, 64), (512, 4, 128, 128), (512, 32, 128, 128),
 @pytest.mark.parametrize("length,block,block_q,block_k", WALKS, ids=str)
 def test_the_kernels_visit_exactly_the_masks_tiles(length, block, block_q,
                                                    block_k):
-    """Every kernel's index maps and its `run` predicate, walked over the
-    grid as Pallas walks it: the steps that compute are the tile pairs the
-    mask touches, once each; a tile is fetched when the index moves, never
-    for a pair the mask does not touch; a tile the kernels call whole holds
-    no hidden pair; and the count is what `blockdiff_blocks` reports."""
+    """Every kernel's table, walked as the grid walks it, queries outer
+    (forward, dq) and keys outer (dk/dv, the combined backward): the steps
+    are the tile pairs the mask touches, once each and no other, so a tile is
+    fetched only for a pair the mask touches; a row's steps are contiguous
+    with `first` and `last` set once; a tile the table calls whole holds no
+    hidden pair; and the count is what `blockdiff_blocks` reports."""
     want = touching(length, block, block_q, block_k)
-    seen = seen_by_hand(length, block)
-    num_q, num_k = 2 * length // block_q, 2 * length // block_k
-    every_q, every_k = np.arange(num_q), np.arange(num_k)
-    # forward and dq: a query tile's two runs of key tiles
-    runs = attn._blockdiff_keys_of_query_block(every_q, block_q, block_k,
-                                               block, length)
-    steps = attn._walk_steps(runs)
-    ran, fetched = [], []
-    for j in range(steps):
-        tile, live = attn._tile_of_step(runs, np.full(num_q, j))
-        assert (tile == attn._held_tile(runs, jnp.full(num_q, j))).all()
-        ran += [(i, int(tile[i])) for i in every_q if live[i]]
-        fetched.append(tile)
-    assert len(ran) == len(set(ran)) and set(ran) == want
-    moved = {(i, int(t)) for i in every_q for t in np.stack(fetched)[:, i]}
-    assert moved == want
-    # dk/dv and the combined backward: a key tile's two runs of query tiles
-    runs = attn._blockdiff_queries_of_key_block(every_k, block_q, block_k,
-                                                block, length)
-    steps_q = attn._walk_steps(runs)
-    ran_q = []
-    for i in range(steps_q):
-        tile, live = attn._tile_of_step(runs, np.full(num_k, i))
-        ran_q += [(int(tile[j]), j) for j in every_k if live[j]]
-    assert len(ran_q) == len(set(ran_q)) and set(ran_q) == want
-    # whole tiles, by the kernels' own scalar test
-    for i, j in want:
-        whole = bool(attn._blockdiff_whole(
-            jnp.int32(i * block_q), jnp.int32(j * block_k), block_q, block_k,
-            block, length))
-        assert whole == seen[i * block_q:(i + 1) * block_q,
-                             j * block_k:(j + 1) * block_k].all(), (i, j)
+    assert check_tables(seen_by_hand(length, block), block_q, block_k,
+                        blockdiff=(block, length)) == want
+    num_q = 2 * length // block_q
     if block_q % 128 == 0 and block_k % 128 == 0:
         rows = 2 * length
         causal = sum((i * block_q + block_q - 1) // block_k + 1
                      for i in range(num_q))
         assert blockdiff_blocks(length, block, 128, block_q=block_q,
                                 block_k=block_k) == (len(want), causal)
-        # The grids are the walks', not the rows'.
+        # The grids are the tables', not the rows'.
         shape = jax.ShapeDtypeStruct((1, 2, rows, 128), jnp.bfloat16)
 
         def loss(q, k, v):
@@ -274,14 +247,13 @@ def test_the_kernels_visit_exactly_the_masks_tiles(length, block, block_q,
 
         grids = pallas_calls(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
             shape, shape, shape).jaxpr)
-        assert grids["hvd_flash_fwd_blockdiff"] == (2, num_q, steps)
+        assert grids["hvd_flash_fwd_blockdiff"] == (2, len(want))
         _, plan_q, plan_k = attn._bwd_plan(rows, 128, block_q, block_k, 2)
-        plan_steps = attn._walk_steps(attn._blockdiff_queries_of_key_block(
-            np.arange(rows // plan_k), plan_q, plan_k, block, length))
+        plan_steps = len(touching(length, block, plan_q, plan_k))
         backward = {n: g for n, g in grids.items() if "bwd" in n}
         assert backward and all(n.endswith("_blockdiff") for n in backward)
         for name, grid in backward.items():
-            assert grid == (2, rows // plan_k, plan_steps), (name, grid)
+            assert grid == (2, plan_steps), (name, grid)
 
 
 def test_the_cells_counts():
@@ -294,8 +266,8 @@ def test_the_cells_counts():
 
 @pytest.mark.parametrize("plan", ["combined", "split"])
 def test_causal_and_windowed_calls_keep_their_kernels(monkeypatch, plan):
-    """The calls the other cells make name the kernels they named, on the
-    grids they had: nothing of theirs walks two runs."""
+    """The calls the other cells make name the kernels they named, each on
+    the grid of its own mask's live tiles."""
     plan_of(monkeypatch, plan, 128)
     shape = jax.ShapeDtypeStruct((1, 2, 512, 64), jnp.bfloat16)
 
@@ -309,16 +281,18 @@ def test_causal_and_windowed_calls_keep_their_kernels(monkeypatch, plan):
 
     backward = {"combined": ["hvd_flash_bwd"],
                 "split": ["hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"]}[plan]
-    # 256 rows a copy in 128-tiles: a query tile's walk is 3 key tiles at
-    # the most (two clean, its own noised), a clean key tile's 4 query tiles.
-    for mask, suffix, inner in (
-            (dict(causal=True), "", {4}), (dict(), "", {4}),
-            (dict(causal=True, window=128), "_window", {2}),
-            (dict(block_diffusion=4), "_blockdiff", {3, 4})):
+    # 4 x 4 tiles of 128: 10 on and under the diagonal; the diagonal and the
+    # one under it; 256 rows a copy: a clean query tile's clean key tiles to
+    # its own (1 + 2), a noised one's earlier-or-cut clean ones and its own
+    # noised (2 + 3).
+    for mask, suffix, steps in (
+            (dict(causal=True), "", 10), (dict(), "", 16),
+            (dict(causal=True, window=128), "_window", 7),
+            (dict(block_diffusion=4), "_blockdiff", 8)):
         grids = calls(**mask)
         assert set(grids) == {n + suffix
                               for n in ["hvd_flash_fwd"] + backward}
-        assert {g[2] for g in grids.values()} <= inner, (mask, grids)
+        assert set(grids.values()) == {(2, steps)}, (mask, grids)
 
 
 # --- the layers --------------------------------------------------------------
@@ -454,8 +428,10 @@ def test_block_diffusion_layers_count_their_tiles():
     seen = record_attention_blocks(wrote["intermediates"])
     # 128 rows a copy are one 128-tile each: clean on clean, noised on clean,
     # noised on noised; a causal walk over the 256 rows visits as many.
+    # The forward's three tiles and the combined backward's: no step more.
     assert seen == {"blocks_visited": [3] * DEPTH,
-                    "blocks_causal": [3] * DEPTH}
+                    "blocks_causal": [3] * DEPTH,
+                    "grid_live": [6] * DEPTH, "grid_steps": [6] * DEPTH}
     assert blockdiff_blocks(SEQ, BLOCK, HEAD_DIM) == (3, 3)
 
 

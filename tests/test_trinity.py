@@ -34,6 +34,7 @@ from horovod_tpu.ops import (blockwise_attention, flash_attention,
 from horovod_tpu.ops.attention import window_blocks
 from tests.test_hybrid import (both_ways, close, mixer_case, seeded,
                                system_loss, trees_close, with_highest)
+from tests.test_flash_table import check_tables, pallas_calls, seen_pairs
 from tests.test_ops import _pallas_call_names
 
 RTOL = 2e-5
@@ -156,18 +157,6 @@ def test_a_window_wants_causal_and_a_key(window, causal):
             fn(q, k, v, causal=causal, window=window)
 
 
-def pallas_calls(jaxpr):
-    """{kernel name: grid} of every pallas_call, sub-programs included."""
-    found = {}
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found[eqn.params["name"]] = tuple(
-                eqn.params["grid_mapping"].grid)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            found.update(pallas_calls(sub))
-    return found
-
-
 def touching(seq, window, block_q, block_k):
     """By brute force over positions: the (query block, key block) pairs that
     hold at least one (t, s) with 0 <= t - s < window."""
@@ -189,36 +178,15 @@ WALKS = [(8192, 2048, 512, 512), (8192, 2048, 1024, 1024),
 @pytest.mark.parametrize("seq,window,block_q,block_k", WALKS, ids=str)
 def test_the_kernels_visit_exactly_the_bands_blocks(seq, window, block_q,
                                                     block_k):
-    """Every kernel's index maps and its `run` predicate, walked over the
-    grid as Pallas walks it: the steps that compute are the block pairs that
-    touch the band, once each; a key (or query) block is fetched when the
-    index moves, never for a pair outside the band; and the count is what
-    `window_blocks` and the layer's counter report."""
+    """Every kernel's table, walked as the grid walks it, queries outer
+    (forward, dq) and keys outer (dk/dv, the combined backward): the steps
+    are the block pairs that touch the band, once each and no other, so a key
+    (or query) block is never fetched for a pair outside the band; a row's
+    steps are contiguous with `first` and `last` set once; and the count is
+    what `window_blocks` and the layer's counter report."""
     want = touching(seq, window, block_q, block_k)
-    num_q, num_k = seq // block_q, seq // block_k
-    # forward and dq: a query block's band of key blocks
-    first, last = attn._keys_of_query_block(np.arange(num_q), block_q,
-                                            block_k, window)
-    steps = attn._band_steps((first, last))
-    ran, fetched = [], []
-    for i in range(num_q):
-        moved = None
-        for j in range(steps):
-            block = min(first[i] + j, last[i])          # the index map
-            if block != moved:
-                fetched.append((i, int(block)))
-                moved = block
-            if first[i] + j <= last[i]:                 # the kernel's `run`
-                ran.append((i, int(first[i] + j)))
-    assert len(ran) == len(set(ran)) and set(ran) == want
-    assert fetched == ran
-    # dk/dv and the combined backward: a key block's band of query blocks
-    first, last = attn._queries_of_key_block(np.arange(num_k), block_q,
-                                             block_k, window, num_q)
-    steps_q = attn._band_steps((first, last))
-    ran_q = [(int(first[j] + i), j) for j in range(num_k)
-             for i in range(steps_q) if first[j] + i <= last[j]]
-    assert len(ran_q) == len(set(ran_q)) and set(ran_q) == want
+    assert check_tables(seen_pairs(seq, True, window), block_q, block_k,
+                        True, window) == want
     causal = len(touching(seq, seq, block_q, block_k))
     assert window_blocks(seq, window, 128, block_q=block_q,
                          block_k=block_k) == (len(want), causal)
@@ -232,15 +200,14 @@ def test_the_kernels_visit_exactly_the_bands_blocks(seq, window, block_q,
 
     grids = pallas_calls(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
         shape, shape, shape).jaxpr)
-    assert grids["hvd_flash_fwd_window"] == (2, num_q, steps)
+    assert grids["hvd_flash_fwd_window"] == (2, len(want))
     # The backward re-plans its blocks for the shape (`_bwd_plan`).
     _, plan_q, plan_k = attn._bwd_plan(seq, 128, block_q, block_k, 2)
-    plan_steps = attn._band_steps(attn._queries_of_key_block(
-        np.arange(seq // plan_k), plan_q, plan_k, window, seq // plan_q))
+    plan_steps = len(touching(seq, window, plan_q, plan_k))
     backward = {name: grid for name, grid in grids.items() if "bwd" in name}
     assert backward and all(name.endswith("_window") for name in backward)
     for name, grid in backward.items():
-        assert grid == (2, seq // plan_k, plan_steps), (name, grid)
+        assert grid == (2, plan_steps), (name, grid)
 
 
 def test_the_cells_counts():
@@ -369,12 +336,18 @@ def test_windowed_layers_count_their_blocks(monkeypatch):
     monkeypatch.setattr(metrics.registry, "enabled", True)
     seen = record_attention_blocks(wrote["intermediates"])
     # 128 tokens are one 128-block: the three windowed layers visit it.
-    assert seen == {"blocks_visited": [1, 1, 1], "blocks_causal": [1, 1, 1]}
+    # Every layer's forward and combined backward, the full one's too, are
+    # one tile each: no grid step computes nothing.
+    assert seen == {"blocks_visited": [1, 1, 1], "blocks_causal": [1, 1, 1],
+                    "grid_live": [2] * 4, "grid_steps": [2] * 4}
     snapshot = metrics.registry.snapshot()
     assert snapshot["attention"] == seen
     text = metrics.prometheus_text(snapshot)
     assert 'hvd_tpu_attention_blocks{layer="2",kind="visited"} 1' in text
-    assert "layer_6" not in wrote["intermediates"]     # the full layer
+    assert 'hvd_tpu_attention_blocks{layer="3",kind="grid_steps"} 2' in text
+    # the full layer: no windowed layer's counters
+    assert set(wrote["intermediates"]["layer_6"]["mixer"]) \
+        == {"attn_grid_live", "attn_grid_steps"}
 
 
 def test_trains_through_build_train_step_and_replicas_stay_equal():

@@ -2,17 +2,23 @@
 """Scoped-VMEM calibration sweep for the flash-attention backward.
 
 Recompiles `jax.grad(flash_attention)` over (seq, head_dim, block_q,
-block_k) on the attached TPU and reports which configs fit the chip's
+block_k) with the chip's compiler and reports which configs fit the chip's
 scoped-VMEM ceiling — the ground truth behind
 `horovod_tpu.ops.attention._bwd_plan` (r5 calibration; the r4 regression
-was a tuned block choice that stopped compiling at seq 8192).  Compile-
-only: safe to run anywhere a TPU is visible, ~1-2 s per config.
+was a tuned block choice that stopped compiling at seq 8192; re-run at PR 44,
+when the kernels' grids became `(bh, live tiles)` tables).  Compile-only,
+~1-2 s per config: on the attached TPU, or, where there is none, for a
+DESCRIBED v5e (libtpu compiles for an unattached chip and refuses what the
+chip's compiler refuses; one such process at a time).
 
-Usage: python tools/vmem_sweep.py [--full]
+Usage: python tools/vmem_sweep.py [--full] [--cells]
   default: the documented sweep {1k, 4k, 8k, 16k} x {64, 128} with the
   plan's chosen blocks (should print all OK);
-  --full: every block candidate per shape, to re-derive the plan table
-  after a Mosaic/compiler update.
+  --full: every block candidate per shape, forced onto the COMBINED kernel
+  (the split pair compiles everywhere), to re-derive the plan table after a
+  Mosaic/compiler update or a change of the kernels' grids;
+  --cells: the benchmark's cells' shapes under their masks (causal, Trinity's
+  window, SDAR's block diffusion) at the plan's blocks.
 """
 import argparse
 import os
@@ -20,20 +26,46 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax
 import jax.numpy as jnp
 
+import horovod_tpu.ops.attention as attn
 from horovod_tpu.ops.attention import _bwd_plan, flash_attention
 
 
-def try_compile(sl, d, bq, bk, bh=16):
-    q = jnp.zeros((bh // 8, 8, sl, d), jnp.bfloat16)
+def chip():
+    """The sharding that puts an operand on the chip compiled for: the
+    attached TPU's first device, else a described v5e's."""
+    from jax.sharding import SingleDeviceSharding
+
+    if jax.default_backend() == "tpu":
+        return SingleDeviceSharding(jax.devices()[0])
+    from jax.experimental import topologies
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def try_compile(on_chip, sl, d, bq, bk, bh=16, force=None, **mask):
+    """Compile forward and backward at (bh, sl, d) in the blocks (bq, bk);
+    ``force``: that backward mode at exactly these blocks, whatever the plan
+    says."""
+    q = jax.ShapeDtypeStruct((max(bh // 8, 1), min(bh, 8), sl, d),
+                             jnp.bfloat16, sharding=on_chip)
+    mask = mask or dict(causal=True)
 
     def f(q, k, v):
-        return flash_attention(q, k, v, causal=True, block_q=bq,
-                               block_k=bk).astype(jnp.float32).sum()
+        return flash_attention(q, k, v, block_q=bq, block_k=bk,
+                               interpret=False, **mask
+                               ).astype(jnp.float32).sum()
 
+    planned = attn._bwd_plan
+    if force is not None:
+        attn._bwd_plan = lambda *a, **kw: (force, bq, bk)
     t0 = time.time()
     try:
         jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(q, q, q).compile()
@@ -41,41 +73,82 @@ def try_compile(sl, d, bq, bk, bh=16):
     except Exception as e:  # report the Mosaic scoped-vmem line if present
         lines = str(e).splitlines() or [repr(e)]
         key = next((ln.strip() for ln in lines
-                    if "Scoped allocation" in ln), lines[0])
-        return "FAIL", time.time() - t0, key[:110]
+                    if "Scoped allocation" in ln or "scoped" in ln.lower()),
+                   lines[0])
+        return "FAIL", time.time() - t0, key[:160]
+    finally:
+        attn._bwd_plan = planned
+
+
+# The benchmark's cells: rows, head width, batch * heads, mask.
+CELLS = [
+    (2048, 64, 64, dict(causal=True)),                       # _4x2k, dp4
+    (8192, 64, 16, dict(causal=True)),                       # _1x8k
+    (4096, 128, 32, dict(causal=True)),                      # OLMoE
+    (4096, 128, 4, dict(causal=True)),                       # Nemotron
+    (8192, 128, 32, dict(causal=True)),                      # Trinity, full
+    (8192, 128, 32, dict(causal=True, window=2048)),         # Trinity, band
+    (8192, 128, 32, dict(block_diffusion=4)),                # SDAR
+]
+
+
+def sweep_cells(on_chip) -> int:
+    """The benchmark's cells at the plan's blocks, as both backward modes;
+    the failures among the modes the plan picks."""
+    failures = 0
+    for sl, d, bh, mask in CELLS:
+        plan = _bwd_plan(sl, d, 1024, 1024, bh)
+        for force in ("combined", "split"):
+            st, dt, key = try_compile(on_chip, sl, d, *plan[1:], bh,
+                                      force=force, **mask)
+            print(f"d={d} sl={sl} bh={bh} {mask} plan={plan} "
+                  f"as {force}: {st} ({dt:.1f}s) {key}", flush=True)
+            failures += st != "OK" and force == plan[0]
+    return failures
+
+
+def sweep_bands(on_chip, full: bool) -> int:
+    """The documented sweep at the plan's blocks, or (``full``) every block
+    candidate on the combined kernel; the plan's own failures."""
+    cands = [(1024, 1024), (512, 1024), (1024, 512), (512, 512),
+             (256, 512), (256, 256)]
+    # bench-protocol bh (token-constant seq:batch sweep) plus the band
+    # edges' bh per seq: the scoped size varies non-monotonically with the
+    # batch*heads grid dim (see attention._bwd_plan).
+    bench_bh = {1024: (128, 1024), 2048: (64, 1024), 4096: (32, 128, 512),
+                8192: (16, 32, 64, 128), 16384: (8, 128)}
+    failures = 0
+    for d in (64, 128):
+        for sl, bhs in bench_bh.items():
+            for bh in bhs:
+                todo = [_bwd_plan(sl, d, 1024, 1024, bh)[1:]]
+                if full:
+                    todo = [c for c in cands
+                            if sl % c[0] == 0 and sl % c[1] == 0]
+                for bq, bk in todo:
+                    st, dt, key = try_compile(
+                        on_chip, sl, d, bq, bk, bh,
+                        force="combined" if full else None)
+                    plan = _bwd_plan(sl, d, bq, bk, bh)
+                    print(f"d={d} sl={sl} bh={bh} bq={bq} bk={bk} "
+                          f"plan={plan}: {st} ({dt:.1f}s) {key}", flush=True)
+                    failures += st != "OK" and not full
+    return failures
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
-                    help="sweep every block candidate, not just the plan's")
+                    help="sweep every block candidate on the combined "
+                         "kernel, not just the plan's")
+    ap.add_argument("--cells", action="store_true",
+                    help="the benchmark's cells' shapes under their masks")
     args = ap.parse_args()
-    if jax.default_backend() != "tpu":
-        # Without the chip flash_attention would run the interpreter and
-        # every config would "compile": that is no calibration.
-        sys.exit("no TPU backend: this sweep calibrates the chip's compiler")
-    cands = [(1024, 1024), (512, 1024), (1024, 512), (512, 512),
-             (256, 512), (256, 256)]
-    # bench-protocol bh (token-constant seq:batch sweep) plus a high-bh
-    # probe per seq: the scoped size varies non-monotonically with the
-    # batch*heads grid dim (see attention._bwd_plan).
-    bench_bh = {1024: 128, 4096: 32, 8192: 16, 16384: 8}
-    failures = 0
-    for d in (64, 128):
-        for sl in (1024, 4096, 8192, 16384):
-            for bh in dict.fromkeys((bench_bh[sl], 128)):
-                if args.full:
-                    todo = [c for c in cands
-                            if sl % c[0] == 0 and sl % c[1] == 0]
-                else:
-                    mode, bq, bk = _bwd_plan(sl, d, 1024, 1024, bh)
-                    todo = [(bq, bk)]
-                for bq, bk in todo:
-                    st, dt, key = try_compile(sl, d, bq, bk, bh)
-                    plan = _bwd_plan(sl, d, bq, bk, bh)
-                    print(f"d={d} sl={sl} bh={bh} bq={bq} bk={bk} "
-                          f"plan={plan}: {st} ({dt:.1f}s) {key}", flush=True)
-                    failures += st != "OK" and not args.full
+    on_chip = chip()
+    print(f"compiling for {on_chip._device.device_kind} "
+          f"(backend {jax.default_backend()})", flush=True)
+    failures = sweep_cells(on_chip) if args.cells \
+        else sweep_bands(on_chip, args.full)
     if failures:
         sys.exit(f"{failures} plan-chosen config(s) failed to compile")
     print("all plan-chosen configs compile")
