@@ -24,8 +24,7 @@ from horovod_tpu.models.delta import DeltaConfig, DeltaMixer
 from horovod_tpu.models.ssm import Mamba2Config, Mamba2Mixer
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              ring_attention)
-from horovod_tpu.ops.attention import (blockdiff_blocks, flash_grid_steps,
-                                       window_blocks)
+from horovod_tpu.ops.attention import flash_grid_steps, mask_blocks
 from horovod_tpu.ops.moe import (WAYS_BACK, buffer_rows_to_tokens,
                                  column_slabs, dispatch_rows, grouped_matmul,
                                  reduced_to_vma_of, scatters_whole_rows,
@@ -530,7 +529,7 @@ class Attention(nn.Module):
     # with one sows ``attn_blocks_visited`` and ``attn_blocks_causal`` into
     # ``intermediates``: the (query block, key block) pairs a head's forward
     # kernel visits, and what the causal kernel would under the same blocks
-    # (:func:`~horovod_tpu.ops.attention.window_blocks`; both the scan's
+    # (:func:`~horovod_tpu.ops.attention.mask_blocks`; both the scan's
     # every-block count where the shape leaves the kernels).  Training only:
     # no ring, no cached decode.
     window: Optional[int] = None
@@ -548,8 +547,7 @@ class Attention(nn.Module):
     # it (:func:`~horovod_tpu.ops.flash_attention`'s ``block_diffusion``, in
     # place of the causal mask).  The layer sows ``attn_blocks_visited`` and
     # ``attn_blocks_causal`` as a windowed one does, the second what a causal
-    # kernel would visit over all ``seq`` rows
-    # (:func:`~horovod_tpu.ops.attention.blockdiff_blocks`).  Training only.
+    # kernel would visit over all ``seq`` rows.  Training only.
     block_diffusion: Optional[int] = None
 
     def _grouped_projections(self, x, head_dim, rotate):
@@ -685,12 +683,9 @@ class Attention(nn.Module):
                 if self.use_flash:
                     _sow_grid_steps(self, q, masks)
                 if self.window is not None or diffusion is not None:
-                    blocks = None                   # the scan: every block
-                    if self.use_flash and diffusion is None:
-                        blocks = window_blocks(s, self.window, head_dim)
-                    elif self.use_flash:
-                        blocks = blockdiff_blocks(s // 2, diffusion, head_dim)
-                    every = -(-s // min(512, s))       # the scan's blocks
+                    blocks = mask_blocks(s, head_dim, **masks) \
+                        if self.use_flash else None
+                    every = -(-s // min(512, s))   # the scan: every block
                     visited, causal = blocks or (every, every)
                     self.sow("intermediates", "attn_blocks_visited",
                              jnp.int32(visited))

@@ -17,6 +17,7 @@ block's probability matrix (O(seq^2 / block)).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 import warnings
@@ -35,50 +36,231 @@ POS_BIG = 1e30   # logsumexp sentinel for fully-masked rows: exp(s - POS_BIG)
 # underflows to exactly 0 for any finite s
 
 
+@dataclasses.dataclass(frozen=True)
+class Mask:
+    """Which keys a query sees: one static, hashable value that owns
+    everything a kind of mask decides, so that the reference, the scan, the
+    kernels' schedule and the arithmetic inside a tile read ONE definition.
+    This class is the mask that hides nothing; a kind subclasses it (frozen:
+    the schedule's cache and ``custom_vjp`` key on the value) and writes
+
+    * :meth:`seen` — THE definition, position by position;
+    * :meth:`tiles` — the same over (query tile, key tile) pairs, on numpy
+      from static shapes: ``live`` where some query of the tile sees some key
+      of it, ``whole`` where every query sees every key.  Every query tile
+      and every key tile must be live somewhere;
+    * :meth:`cut` — the same inside one LIVE tile of a kernel, on the tile's
+      first rows (traced scalars); on a whole tile it is the identity;
+
+    and, where they differ, the scalars below.  `tests/test_flash_table.py`
+    holds the three forms of every kind against each other (`check_tables`)
+    and runs one this module has never heard of."""
+
+    suffix = ""         # behind a kernel's name in a trace
+    copies = 1          # the blocks divide ``rows // copies``: no tile lies
+    #                     across two copies of the sequence
+    square = False      # the kernels need as many queries as keys
+    whole_body = False  # the table flags whole tiles (`_WHOLE`) and a kernel
+    #                     holds a second body for them, with no `cut`
+
+    @staticmethod
+    def of(q_len, k_len, causal=False, window=None, block_diffusion=None):
+        """The mask that ``flash_attention``'s keywords name, as
+        :meth:`checked` gives it at ``q_len`` queries and ``k_len`` keys."""
+        if block_diffusion is not None:
+            if causal or window is not None:
+                raise ValueError(
+                    f"block_diffusion={block_diffusion!r} is a mask of its "
+                    "own: it takes neither causal= nor window=")
+            mask = BlockDiffusion(block_diffusion, q_len // 2)
+        elif window is not None and not causal:
+            raise ValueError(f"window={window!r} wants causal=True")
+        else:
+            mask = Causal(window) if causal else Mask()
+        return mask.checked(q_len, k_len)
+
+    @staticmethod
+    def bounds(num_q, num_k, block_q, block_k):
+        """(q_lo, q_hi, k_lo, k_hi): the first and last row of every query
+        tile, as a column, and of every key tile, as a row, for `tiles`."""
+        q_lo = np.arange(num_q)[:, None] * block_q
+        k_lo = np.arange(num_k)[None, :] * block_k
+        return q_lo, q_lo + block_q - 1, k_lo, k_lo + block_k - 1
+
+    def checked(self, q_len, k_len):
+        """The mask as the kernels take it at this shape, in the canonical
+        form the programs depend on, or a ValueError.  ``k_len`` None: the
+        keys are a shard at an offset (the scan under the ring), so nothing
+        about the whole sequence is known."""
+        return self
+
+    def seen(self, q_pos, k_pos):
+        """True where the query row at ``q_pos`` sees the key row at
+        ``k_pos``; broadcasts against both."""
+        return jnp.bool_(True)
+
+    def tiles(self, num_q, num_k, block_q, block_k):
+        """(live, whole): boolean ``(num_q, num_k)`` matrices."""
+        every = np.ones((num_q, num_k), bool)
+        return every, every
+
+    def cut(self, s, q_start, k_start, block_q, block_k):
+        """The logits ``s`` of the ``(block_q, block_k)`` tile at rows
+        ``q_start`` and ``k_start``, NEG_INF where the query does not see the
+        key."""
+        return s
+
+
+@dataclasses.dataclass(frozen=True)
+class Causal(Mask):
+    """Query ``t`` sees the keys ``s <= t``; with ``window``, those with ``0
+    <= t - s < window``: itself and the ``window - 1`` before it."""
+
+    window: Optional[int] = None
+
+    @property
+    def suffix(self):
+        return "" if self.window is None else "_window"
+
+    @property
+    def square(self):
+        return self.window is not None
+
+    def checked(self, q_len, k_len):
+        if self.window is None:
+            return self
+        if self.window < 1:
+            raise ValueError(f"window={self.window!r} wants at least one key "
+                             "(the query's own)")
+        # A window that hides no key the causal mask shows IS the causal
+        # mask, program for program.
+        whole = k_len is not None and self.window >= max(q_len, k_len)
+        return Causal() if whole else Causal(int(self.window))
+
+    def seen(self, q_pos, k_pos):
+        if self.window is None:
+            return q_pos >= k_pos
+        return (q_pos >= k_pos) & (q_pos - k_pos < self.window)
+
+    def tiles(self, num_q, num_k, block_q, block_k):
+        q_lo, q_hi, k_lo, k_hi = self.bounds(num_q, num_k, block_q, block_k)
+        most = num_q * block_q if self.window is None else self.window - 1
+        return ((q_hi - k_lo >= 0) & (q_lo - k_hi <= most),
+                (q_lo - k_hi >= 0) & (q_hi - k_lo <= most))
+
+    def cut(self, s, q_start, k_start, block_q, block_k):
+        if self.window is None:
+            return jnp.where(
+                q_start + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0) >= k_start
+                + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1),
+                s, NEG_INF)
+        # a band: one difference serves both bounds
+        diff = (q_start - k_start) + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0) - jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        return jnp.where((diff >= 0) & (diff < self.window), s, NEG_INF)
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion(Mask):
+    """Block diffusion's training pass: rows ``[0, half)`` are a sequence's
+    clean copy, rows ``[half, 2 half)`` its noised copy, position ``p`` of
+    either copy lies in block ``p // block``.  A clean query sees the clean
+    keys of its own block and of the earlier ones; a noised query sees the
+    clean keys of the EARLIER blocks and the noised keys of its OWN block; no
+    clean query sees a noised key.  With ``d`` a query's block less a key's:
+    clean on clean sees ``d >= 0``, noised on clean ``d >= 1``, noised on
+    noised ``d == 0``."""
+
+    block: int
+    half: int
+
+    suffix = "_blockdiff"
+    copies = 2
+    square = True
+    whole_body = True
+
+    def checked(self, q_len, k_len):
+        if k_len is None:
+            raise ValueError("block_diffusion= takes whole sequences: no "
+                             "offsets")
+        if self.block < 1 or q_len != k_len or q_len != 2 * self.half:
+            raise ValueError(
+                f"block_diffusion={self.block!r} is a mask over [clean; "
+                "noised] rows: an even number, queries and keys alike, in "
+                "blocks of at least one")
+        return BlockDiffusion(int(self.block), self.half)
+
+    def seen(self, q_pos, k_pos):
+        block, half = self.block, self.half
+        q_noised, k_noised = q_pos >= half, k_pos >= half
+        q_block = (q_pos - jnp.where(q_noised, half, 0)) // block
+        k_block = (k_pos - jnp.where(k_noised, half, 0)) // block
+        return jnp.where(k_noised, q_noised & (k_block == q_block),
+                         jnp.where(q_noised, k_block < q_block,
+                                   k_block <= q_block))
+
+    def tiles(self, num_q, num_k, block_q, block_k):
+        # the blocks divide ``half``, so a tile lies in one copy
+        block, half = self.block, self.half
+        q_lo, q_hi, k_lo, k_hi = self.bounds(num_q, num_k, block_q, block_k)
+        q_noised, k_noised = q_lo >= half, k_lo >= half
+        q_rel, k_rel = q_noised * half, k_noised * half
+        d_min = (q_lo - q_rel) // block - (k_hi - k_rel) // block
+        d_max = (q_hi - q_rel) // block - (k_lo - k_rel) // block
+        least = q_noised & ~k_noised
+        most = np.where(k_noised, 0, half)
+        copies = q_noised | ~k_noised      # no clean query sees a noised key
+        return (copies & (d_max >= least) & (d_min <= most),
+                copies & (d_min >= least) & (d_max <= most))
+
+    def cut(self, s, q_start, k_start, block_q, block_k):
+        # from the block ids of the tile's rows (``block_q`` of them) and
+        # columns, not of its pairs
+        block, half = self.block, self.half
+        q_noised, k_noised = q_start >= half, k_start >= half
+
+        def blocks_of(rel, shape, axis):
+            pos = rel + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+            if block & (block - 1) == 0:
+                return jax.lax.shift_right_logical(pos,
+                                                   block.bit_length() - 1)
+            return lax.div(pos, block)
+
+        diff = blocks_of(q_start - jnp.where(q_noised, half, 0),
+                         (block_q, 1), 0) \
+            - blocks_of(k_start - jnp.where(k_noised, half, 0),
+                        (1, block_k), 1)
+        return jnp.where(
+            (diff >= jnp.where(q_noised & ~k_noised, 1, 0))
+            & (diff <= jnp.where(k_noised, 0, half)), s, NEG_INF)
+
+
 def block_diffusion_mask(q_pos, k_pos, block: int, half: int):
     """True where the query row at ``q_pos`` sees the key row at ``k_pos``
-    under block diffusion: rows ``[0, half)`` are a sequence's clean copy,
-    rows ``[half, 2 half)`` its noised copy, position ``p`` of either copy
-    lies in block ``p // block``.  A clean query sees the clean keys of its
-    own block and of the earlier ones; a noised query sees the clean keys of
-    the EARLIER blocks and the noised keys of its OWN block; no clean query
-    sees a noised key.  ``q_pos`` and ``k_pos`` broadcast against each
+    under block diffusion (:class:`BlockDiffusion`: blocks of ``block``, two
+    copies of ``half`` rows).  ``q_pos`` and ``k_pos`` broadcast against each
     other."""
-    q_noised, k_noised = q_pos >= half, k_pos >= half
-    q_block = (q_pos - jnp.where(q_noised, half, 0)) // block
-    k_block = (k_pos - jnp.where(k_noised, half, 0)) // block
-    return jnp.where(k_noised, q_noised & (k_block == q_block),
-                     jnp.where(q_noised, k_block < q_block,
-                               k_block <= q_block))
+    return BlockDiffusion(block, half).seen(q_pos, k_pos)
 
 
 def mha_reference(q, k, v, causal: bool = False,
                   sm_scale: Optional[float] = None,
                   window: Optional[int] = None,
                   block_diffusion: Optional[int] = None):
-    """O(seq^2)-memory reference attention (for tests and tiny shapes).
-    ``window`` (with ``causal``): query ``t`` sees the keys ``s`` with
-    ``0 <= t - s < window``, itself and the ``window - 1`` before it.
-    ``block_diffusion``: :func:`flash_attention`'s, as an explicit mask."""
+    """O(seq^2)-memory reference attention (for tests and tiny shapes), under
+    :func:`flash_attention`'s masks as one explicit boolean matrix."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    blockdiff = _checked_block_diffusion(block_diffusion, causal, window,
-                                         q.shape[2], k.shape[2])
+    mask = Mask.of(q.shape[2], k.shape[2], causal, window, block_diffusion)
     # precision="highest": on TPU the default matmul precision truncates f32
     # operands to bf16 passes; the reference must be at least as accurate as
     # the kernels it validates.
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    precision="highest").astype(jnp.float32) * sm_scale
-    if causal:
-        q_pos = jnp.arange(q.shape[2])[:, None]
-        k_pos = jnp.arange(k.shape[2])[None, :]
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        if window is not None:
-            s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
-    if blockdiff is not None:
-        s = jnp.where(block_diffusion_mask(
-            jnp.arange(q.shape[2])[:, None], jnp.arange(k.shape[2])[None, :],
-            *blockdiff), s, NEG_INF)
+    s = jnp.where(mask.seen(jnp.arange(q.shape[2])[:, None],
+                            jnp.arange(k.shape[2])[None, :]), s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
                       precision="highest")
@@ -140,22 +322,14 @@ def _kv_blocks(k, v, block, n_blocks, pad):
     return jnp.moveaxis(kb, -3, 0), jnp.moveaxis(vb, -3, 0)
 
 
-def _block_mask(i, block, q_pos, k_offset, k_len, causal, window=None,
-                blockdiff=None):
+def _block_mask(i, block, q_pos, k_offset, k_len, mask):
     k_pos = k_offset + i * block + jnp.arange(block)
-    mask = (k_pos < k_offset + k_len)[None, :]  # padding rows
-    if causal:
-        mask = mask & (q_pos[:, None] >= k_pos[None, :])
-    if window is not None:
-        mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
-    if blockdiff is not None:
-        mask = mask & block_diffusion_mask(q_pos[:, None], k_pos[None, :],
-                                           *blockdiff)
-    return mask
+    padding = (k_pos < k_offset + k_len)[None, :]
+    return padding & mask.seen(q_pos[:, None], k_pos[None, :])
 
 
-def _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size, q_offset,
-                        k_offset, window=None, blockdiff=None):
+def _blockwise_fwd_impl(q, k, v, mask, sm_scale, block_size, q_offset,
+                        k_offset):
     """Forward scan; returns (out, lse) with lse the per-row logsumexp."""
     q_len, k_len = q.shape[-2], k.shape[-2]
     block = min(block_size, k_len)
@@ -169,9 +343,8 @@ def _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size, q_offset,
     def step(carry, inputs):
         m, l, acc = carry
         i, kblk, vblk = inputs
-        mask = _block_mask(i, block, q_pos, k_offset, k_len, causal, window,
-                           blockdiff)
-        m, l, acc = _block_attend(q, kblk, vblk, m, l, acc, mask, sm_scale)
+        seen = _block_mask(i, block, q_pos, k_offset, k_len, mask)
+        m, l, acc = _block_attend(q, kblk, vblk, m, l, acc, seen, sm_scale)
         return (m, l, acc), None
 
     (m, l, acc), _ = lax.scan(
@@ -179,8 +352,8 @@ def _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size, q_offset,
     return _finalize(m, l, acc, q.dtype), _lse_of(m, l)
 
 
-def _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_size,
-                        q_offset, k_offset, window=None, blockdiff=None):
+def _attention_bwd_impl(q, k, v, out, lse, g, mask, sm_scale, block_size,
+                        q_offset, k_offset):
     """Flash-attention backward: recompute each key block's probabilities
     from (q, k, lse); residual memory O(seq)."""
     q_len, k_len = q.shape[-2], k.shape[-2]
@@ -197,11 +370,10 @@ def _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_size,
         i, kblk, vblk = inputs
         s = jnp.einsum("...qd,...kd->...qk", q, kblk,
                        preferred_element_type=jnp.float32) * sm_scale
-        mask = _block_mask(i, block, q_pos, k_offset, k_len, causal, window,
-                           blockdiff)
-        s = jnp.where(mask, s, NEG_INF)
+        seen = _block_mask(i, block, q_pos, k_offset, k_len, mask)
+        s = jnp.where(seen, s, NEG_INF)
         p = jnp.exp(s - lse[..., None])
-        p = jnp.where(mask, p, 0.0)
+        p = jnp.where(seen, p, 0.0)
         dv_blk = jnp.einsum("...qk,...qd->...kd", p, g32,
                             preferred_element_type=jnp.float32)
         dp = jnp.einsum("...qd,...kd->...qk", g32, vblk,
@@ -223,27 +395,22 @@ def _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_size,
             dv[..., :k_len, :].astype(v.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _blockwise(q, k, v, causal, sm_scale, block_size, q_offset, k_offset,
-               window, blockdiff):
-    out, _ = _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size,
-                                 q_offset, k_offset, window, blockdiff)
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _blockwise(q, k, v, mask, sm_scale, block_size, q_offset, k_offset):
+    return _blockwise_fwd_impl(q, k, v, mask, sm_scale, block_size, q_offset,
+                               k_offset)[0]
 
 
-def _blockwise_fwd(q, k, v, causal, sm_scale, block_size, q_offset,
-                   k_offset, window, blockdiff):
-    out, lse = _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size,
-                                   q_offset, k_offset, window, blockdiff)
+def _blockwise_fwd(q, k, v, mask, sm_scale, block_size, q_offset, k_offset):
+    out, lse = _blockwise_fwd_impl(q, k, v, mask, sm_scale, block_size,
+                                   q_offset, k_offset)
     return out, (q, k, v, out, lse)
 
 
-def _blockwise_bwd(causal, sm_scale, block_size, q_offset, k_offset, window,
-                   blockdiff, res, g):
+def _blockwise_bwd(mask, sm_scale, block_size, q_offset, k_offset, res, g):
     q, k, v, out, lse = res
-    return _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale,
-                               block_size, q_offset, k_offset, window,
-                               blockdiff)
+    return _attention_bwd_impl(q, k, v, out, lse, g, mask, sm_scale,
+                               block_size, q_offset, k_offset)
 
 
 _blockwise.defvjp(_blockwise_fwd, _blockwise_bwd)
@@ -257,10 +424,9 @@ def blockwise_attention(q, k, v, causal: bool = False,
                         block_diffusion: Optional[int] = None):
     """Memory-efficient attention as a `lax.scan` over key/value blocks.
 
-    ``window`` (with ``causal``) and ``block_diffusion``: the sliding window
-    and the block-diffusion mask of :func:`flash_attention`, here a mask over
-    the same scan (every block is still walked: the CPU path and the tests
-    use this one).
+    ``causal``, ``window`` and ``block_diffusion``: :func:`flash_attention`'s
+    masks, here a boolean over the same scan (every block is still walked:
+    the CPU path and the tests use this one).
 
     ``q_offset``/``k_offset`` give the global sequence positions of the
     first query/key row — this is what lets :func:`ring_attention` apply a
@@ -271,46 +437,18 @@ def blockwise_attention(q, k, v, causal: bool = False,
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    window = _checked_window(window, causal, None)
-    blockdiff = _checked_block_diffusion(block_diffusion, causal, window,
-                                         q.shape[-2], k.shape[-2])
+    shard = True        # keys at an offset: nothing is known of the whole
+    attend = _blockwise
     try:
         q_offset, k_offset = int(q_offset), int(k_offset)
+        shard = bool(q_offset or k_offset)
     except (TypeError, jax.errors.ConcretizationTypeError):
         # Traced offsets can't be custom_vjp static args; keep the plain
         # (through-scan) differentiable path for this corner.
-        out, _ = _blockwise_fwd_impl(q, k, v, causal, sm_scale, block_size,
-                                     q_offset, k_offset, window, blockdiff)
-        return out
-    if blockdiff is not None and (q_offset or k_offset):
-        raise ValueError("block_diffusion= takes whole sequences: no offsets")
-    return _blockwise(q, k, v, causal, sm_scale, block_size, q_offset,
-                      k_offset, window, blockdiff)
-
-
-def _checked_window(window, causal, k_len):
-    """``window`` as the kernels take it: None where it hides no key a causal
-    mask shows (``window >= k_len``: the causal program itself)."""
-    if window is None:
-        return None
-    if not causal or window < 1:
-        raise ValueError(f"window={window!r} wants causal=True and at least "
-                         "one key (the query's own)")
-    return None if k_len is not None and window >= k_len else int(window)
-
-
-def _checked_block_diffusion(block, causal, window, q_len, k_len):
-    """``block_diffusion`` as the kernels take it: None, or ``(block length,
-    rows of one copy)`` for ``[clean; noised]`` operands of ``2 L`` rows."""
-    if block is None:
-        return None
-    if causal or window is not None or block < 1 or q_len != k_len \
-            or q_len % 2:
-        raise ValueError(
-            f"block_diffusion={block!r} is a mask of its own over [clean; "
-            "noised] rows (an even number, queries and keys alike): it takes "
-            "neither causal= nor window=")
-    return int(block), q_len // 2
+        attend = lambda *args: _blockwise_fwd_impl(*args)[0]  # noqa: E731
+    mask = Mask.of(q.shape[-2], None if shard else k.shape[-2], causal,
+                   window, block_diffusion)
+    return attend(q, k, v, mask, sm_scale, block_size, q_offset, k_offset)
 
 
 # ---------------------------------------------------------------------------
@@ -346,49 +484,16 @@ def _split_scale(sm_scale: float):
 _FIRST, _LAST, _WHOLE = 1, 2, 4   # a table step's flags
 
 
-def _tile_masks(num_q, num_k, block_q, block_k, causal=False, window=None,
-                blockdiff=None):
-    """(live, whole): boolean ``(num_q, num_k)`` matrices over the (query
-    tile, key tile) pairs, on numpy from static shapes — ``live`` where some
-    query of the tile sees some key of it, ``whole`` where every query sees
-    every key (the tile needs no mask inside).  A window's pairs are ``0 <=
-    t - s < window``; block diffusion's are :func:`block_diffusion_mask`'s,
-    ``blockdiff = (block, half)``, the blocks dividing ``half`` so that a
-    tile lies in one copy."""
-    q_lo = np.arange(num_q)[:, None] * block_q
-    k_lo = np.arange(num_k)[None, :] * block_k
-    q_hi, k_hi = q_lo + block_q - 1, k_lo + block_k - 1
-    if blockdiff is not None:
-        block, half = blockdiff
-        q_noised, k_noised = q_lo >= half, k_lo >= half
-        q_rel, k_rel = q_noised * half, k_noised * half
-        # d, a query's diffusion block less a key's, over the tile
-        d_min = (q_lo - q_rel) // block - (k_hi - k_rel) // block
-        d_max = (q_hi - q_rel) // block - (k_lo - k_rel) // block
-        # clean on clean sees d >= 0, noised on clean d >= 1, noised on
-        # noised d == 0 (`_blockdiff_mask`)
-        least = q_noised & ~k_noised
-        most = np.where(k_noised, 0, half)
-        copies = q_noised | ~k_noised      # no clean query sees a noised key
-        return (copies & (d_max >= least) & (d_min <= most),
-                copies & (d_min >= least) & (d_max <= most))
-    if not causal:
-        every = np.ones((num_q, num_k), bool)
-        return every, every
-    most = num_q * block_q if window is None else window - 1
-    return ((q_hi - k_lo >= 0) & (q_lo - k_hi <= most),
-            (q_lo - k_hi >= 0) & (q_hi - k_lo <= most))
-
-
 @functools.lru_cache(maxsize=None)
-def _tile_table(num_q, num_k, block_q, block_k, causal=False, window=None,
-                blockdiff=None, by_key=False, every=False):
+def _tile_table(num_q, num_k, block_q, block_k, mask, by_key=False,
+                every=False):
     """A flash kernel's schedule: the live (query tile, key tile) pairs of
     the mask in the order the kernel walks them, an int32 ``(3, steps)``
     table — row 0 the query tile of each step, row 1 its key tile, row 2 its
-    flags (``_FIRST`` / ``_LAST`` step of its row of the walk; under block
-    diffusion, whose kernels hold an unmasked body beside the masked one,
-    also ``_WHOLE``: the mask leaves the tile whole).  The forward and
+    flags (``_FIRST`` / ``_LAST`` step of its row of the walk; under a mask
+    whose kernels hold an unmasked body beside the masked one
+    (``whole_body``), also ``_WHOLE``: the mask leaves the tile whole).  The
+    forward and
     the dq kernel walk row by QUERY tile, keys inner; the combined backward
     and the dk/dv kernel (``by_key``) row by KEY tile, queries inner; either
     way a row's tiles ascend, so each accumulation runs in the order a
@@ -396,8 +501,8 @@ def _tile_table(num_q, num_k, block_q, block_k, causal=False, window=None,
     prefetch, SMEM): no step computes nothing, and a tile is copied only for
     a step that uses it.  ``every``: all pairs — the ring, whose shard
     offsets are traced values, decides a tile's fate on the device."""
-    live, whole = _tile_masks(num_q, num_k, block_q, block_k,
-                              causal and not every, window, blockdiff)
+    live, whole = (Mask() if every else mask).tiles(num_q, num_k, block_q,
+                                                    block_k)
     assert live.any(0).all() and live.any(1).all(), "a row with no tile"
     if by_key:
         k_tile, q_tile = np.nonzero(live.T)
@@ -407,7 +512,7 @@ def _tile_table(num_q, num_k, block_q, block_k, causal=False, window=None,
         outer = q_tile
     edge = np.flatnonzero(np.diff(outer)) + 1
     flags = np.where(whole[q_tile, k_tile], _WHOLE, 0) \
-        if blockdiff is not None else np.zeros_like(outer)
+        if mask.whole_body else np.zeros_like(outer)
     flags[np.r_[0, edge]] |= _FIRST
     flags[np.r_[edge - 1, len(outer) - 1]] |= _LAST
     table = np.stack([q_tile, k_tile, flags]).astype(np.int32)
@@ -415,58 +520,12 @@ def _tile_table(num_q, num_k, block_q, block_k, causal=False, window=None,
     return table
 
 
-def _band_mask(q_start, k_start, block_q, block_k, window):
-    """True where key ``s`` of the block is in query ``t``'s window."""
-    diff = (q_start - k_start) + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0) - jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return (diff >= 0) & (diff < window)
-
-
-def _blockdiff_mask(q_start, k_start, block_q, block_k, block, half):
-    """True where key ``s`` of the tile is seen by query ``t``, from the
-    block ids of the tile's rows (``block_q`` of them) and columns: with
-    ``d`` the query's diffusion block less the key's, clean on clean sees
-    ``d >= 0``, noised on clean ``d >= 1``, noised on noised ``d == 0``."""
-    q_noised, k_noised = q_start >= half, k_start >= half
-
-    def blocks_of(rel, shape, axis):
-        pos = rel + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
-        if block & (block - 1) == 0:
-            return jax.lax.shift_right_logical(pos, block.bit_length() - 1)
-        return lax.div(pos, block)
-
-    diff = blocks_of(q_start - jnp.where(q_noised, half, 0),
-                     (block_q, 1), 0) \
-        - blocks_of(k_start - jnp.where(k_noised, half, 0), (1, block_k), 1)
-    return (diff >= jnp.where(q_noised & ~k_noised, 1, 0)) \
-        & (diff <= jnp.where(k_noised, 0, half))
-
-
-def _masked(s, q_start, k_start, block_q, block_k, causal, window,
-            blockdiff):
-    """The logits ``s`` of a tile the mask cuts, NEG_INF where the query does
-    not see the key."""
-    if blockdiff is not None:
-        seen = _blockdiff_mask(q_start, k_start, block_q, block_k, *blockdiff)
-    elif window is not None:
-        seen = _band_mask(q_start, k_start, block_q, block_k, window)
-    elif causal:
-        seen = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0) >= k_start \
-            + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    else:
-        return s
-    return jnp.where(seen, s, NEG_INF)
-
-
 def _attend_block(q_ref, k_ref, v_ref, m_scratch, l_scratch, acc_scratch,
-                  q_start, k_start, causal, block_q, block_k,
-                  single_k=False, scale_r=1.0, window=None, blockdiff=None,
-                  masked=True):
+                  q_start, k_start, mask, block_q, block_k,
+                  single_k=False, scale_r=1.0, masked=True):
     """One online-softmax block update of the VMEM (m, l, acc) state
-    (``masked=False``: block diffusion's second body, for a tile the mask
-    leaves whole, with no mask arithmetic).
+    (``masked=False``: the second body, for a tile the mask leaves whole,
+    with no mask arithmetic).
 
     Shared by the single-shard flash kernel and the fused ring-flash step
     (ops/ring_flash.py) — the only difference between them is where
@@ -493,8 +552,7 @@ def _attend_block(q_ref, k_ref, v_ref, m_scratch, l_scratch, acc_scratch,
     if scale_r != 1.0:
         s *= scale_r
     if masked:
-        s = _masked(s, q_start, k_start, block_q, block_k, causal, window,
-                    blockdiff)
+        s = mask.cut(s, q_start, k_start, block_q, block_k)
     if single_k:
         m_new = s.max(axis=-1)
         m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
@@ -540,11 +598,11 @@ def _finalize_flash(o_ref, lse_ref, m_scratch, l_scratch, acc_scratch,
         lse_ref.shape)
 
 
-def _step_of(tab_ref, block_q, block_k, blockdiff=None, offsets_ref=None):
+def _step_of(tab_ref, block_q, block_k, mask, offsets_ref=None):
     """(first query row, first key row, first step of its row, last step of
     its row, the mask leaves the tile whole, query tile) of the table's step
-    the grid stands on.  ``whole`` is None but under block diffusion, whose
-    tables alone flag it; the rows are absolute where the ring hands its
+    the grid stands on.  ``whole`` is None but under a mask whose tables flag
+    it (``whole_body``); the rows are absolute where the ring hands its
     shards' ``offsets_ref``."""
     step = pl.program_id(1)
     q_tile, flags = tab_ref[0, step], tab_ref[2, step]
@@ -554,16 +612,15 @@ def _step_of(tab_ref, block_q, block_k, blockdiff=None, offsets_ref=None):
     first, last, whole = ((flags & bit) != 0
                           for bit in (_FIRST, _LAST, _WHOLE))
     return (q_start, k_start, first, last,
-            None if blockdiff is None else whole, q_tile)
+            whole if mask.whole_body else None, q_tile)
 
 
 def _when_live(run, whole, body):
     """``body(masked)`` under ``pl.when(run)``: the ring's predicate on its
     traced offsets, or None — a table of live tiles, whose every step
     computes.  ``whole`` None: one body, ``body(True)``, whatever mask the
-    kernel has in every tile.  Else (block diffusion) a tile the mask cuts
-    takes the masked body, ``body(True)``, and a whole one the unmasked,
-    ``body(False)``."""
+    kernel has in every tile.  Else a tile the mask cuts takes the masked
+    body, ``body(True)``, and a whole one the unmasked, ``body(False)``."""
     if run is None:
         # Still a `cond`, on what is true at every step: inside `shard_map`
         # the interpreter lets scratch, which varies over no mesh axis, meet
@@ -577,16 +634,16 @@ def _when_live(run, whole, body):
 
 
 def _flash_kernel(tab_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch,
-                  l_scratch, acc_scratch, *, causal, block_q, block_k,
-                  single_k, scale_r=1.0, window=None, blockdiff=None):
+                  l_scratch, acc_scratch, *, mask, block_q, block_k,
+                  single_k, scale_r=1.0):
     """Grid ``(bh, steps)``: the steps are the table's (`_tile_table`, query
     tiles outer), live tiles only — the first step of a query tile's row
     starts the online-softmax state, the last writes out and lse.  One body,
-    masked in every tile, under the causal mask and a window; block
-    diffusion's two, by the table's flag.  ``single_k``: every row is one
-    tile (the whole-k layout), which skips the state's rescale."""
+    masked in every tile, or, under a mask with ``whole_body``, two, by the
+    table's flag.  ``single_k``: every row is one tile (the whole-k layout),
+    which skips the state's rescale."""
     q_start, k_start, first, last, whole, _ = _step_of(
-        tab_ref, block_q, block_k, blockdiff)
+        tab_ref, block_q, block_k, mask)
 
     if not single_k:
         @pl.when(first)
@@ -595,9 +652,8 @@ def _flash_kernel(tab_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch,
 
     def attend(masked):
         _attend_block(q_ref, k_ref, v_ref, m_scratch, l_scratch,
-                      acc_scratch, q_start, k_start, causal, block_q,
-                      block_k, single_k=single_k, scale_r=scale_r,
-                      window=window, blockdiff=blockdiff, masked=masked)
+                      acc_scratch, q_start, k_start, mask, block_q, block_k,
+                      single_k=single_k, scale_r=scale_r, masked=masked)
 
     _when_live(None, whole, attend)
 
@@ -608,8 +664,8 @@ def _flash_kernel(tab_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch,
 
 
 def _bwd_block_math(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                    causal, q_start, k_start, block_q, block_k, scale_r,
-                    window=None, blockdiff=None, masked=True):
+                    mask, q_start, k_start, block_q, block_k, scale_r,
+                    masked=True):
     """Shared flash-backward block recompute (Dao et al. alg. 2 inner
     body), used by the combined kernel, both split kernels, and the fused
     ring backward (ops/ring_flash.py).
@@ -636,8 +692,7 @@ def _bwd_block_math(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     if scale_r != 1.0:
         s *= scale_r
     if masked:
-        s = _masked(s, q_start, k_start, block_q, block_k, causal, window,
-                    blockdiff)
+        s = mask.cut(s, q_start, k_start, block_q, block_k)
     p = jnp.exp(s - lse[:, None])  # POS_BIG lse zeroes masked rows
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
@@ -650,15 +705,14 @@ def _bwd_block_math(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
 def _flash_bwd_dkdv_kernel(tab_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref,
                            v_ref, dk_ref, dv_ref, dk_scratch, dv_scratch, *,
-                           causal, block_q, block_k, scale_r,
-                           window=None, blockdiff=None):
+                           mask, block_q, block_k, scale_r):
     """Split backward, dk/dv half: O(block) scoped memory — the long-seq
     path where the combined kernel's whole-seq dq scratch exceeds the
     chip's scoped-VMEM ceiling (see _bwd_plan).  Grid ``(bh, steps)`` over
     the table's live tiles, key tiles outer: a key tile's queries accumulate
     from its first step to its last."""
     q_start, k_start, first, last, whole, _ = _step_of(
-        tab_ref, block_q, block_k, blockdiff)
+        tab_ref, block_q, block_k, mask)
 
     @pl.when(first)
     def _():
@@ -667,9 +721,8 @@ def _flash_bwd_dkdv_kernel(tab_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref,
 
     def accumulate(masked):
         pb, ds, q, do, _k = _bwd_block_math(
-            q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, causal,
-            q_start, k_start, block_q, block_k, scale_r, window=window,
-            blockdiff=blockdiff, masked=masked)
+            q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, mask,
+            q_start, k_start, block_q, block_k, scale_r, masked=masked)
         dv_scratch[...] += jax.lax.dot_general(
             pb, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -686,14 +739,13 @@ def _flash_bwd_dkdv_kernel(tab_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref,
 
 
 def _flash_bwd_dq_kernel(tab_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref,
-                         v_ref, dq_ref, dq_scratch, *, causal, block_q,
-                         block_k, scale_r, dq_scale=1.0,
-                         window=None, blockdiff=None):
+                         v_ref, dq_ref, dq_scratch, *, mask, block_q,
+                         block_k, scale_r, dq_scale=1.0):
     """Split backward, dq half: accumulates one query tile over its key
     tiles — O(block) scoped memory (long-seq path, see _bwd_plan).  Grid
     ``(bh, steps)`` over the table's live tiles, query tiles outer."""
     q_start, k_start, first, last, whole, _ = _step_of(
-        tab_ref, block_q, block_k, blockdiff)
+        tab_ref, block_q, block_k, mask)
 
     @pl.when(first)
     def _():
@@ -701,9 +753,8 @@ def _flash_bwd_dq_kernel(tab_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref,
 
     def accumulate(masked):
         _pb, ds, _q, _do, k = _bwd_block_math(
-            q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, causal,
-            q_start, k_start, block_q, block_k, scale_r, window=window,
-            blockdiff=blockdiff, masked=masked)
+            q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, mask,
+            q_start, k_start, block_q, block_k, scale_r, masked=masked)
         dq_scratch[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -718,9 +769,9 @@ def _flash_bwd_dq_kernel(tab_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref,
             else dq_scratch[...])
 
 
-def _combined_bwd_kernel(tab_ref, *refs, causal, block_q, block_k, steps, bh,
-                         ring, rotate, barrier, axis_name, mesh_axes,
-                         scale_r, dq_scale=1.0, window=None, blockdiff=None):
+def _combined_bwd_kernel(tab_ref, *refs, mask, block_q, block_k, steps, bh,
+                         ring, run, rotate, barrier, axis_name, mesh_axes,
+                         scale_r, dq_scale=1.0):
     """Flash backward with dk/dv AND dq from ONE probability recompute.
 
     Grid ``(bh, steps)`` over the table's tiles (`_tile_table`, key tiles
@@ -733,9 +784,10 @@ def _combined_bwd_kernel(tab_ref, *refs, causal, block_q, block_k, steps, bh,
 
     ``ring``: a step of the fused ring-flash backward (ops/ring_flash.py).
     ``offsets_ref`` carries the absolute [q_offset, k_offset] of the shards
-    for causal masking across them — traced values, so the table holds every
-    pair and whether a tile computes is decided here, the kernel's one
-    dynamic predicate.  With ``rotate=True`` the K/V rotation DMA to the
+    for masking across them — traced values, so the table holds every pair
+    and whether a tile computes is decided here, by the ring's ``run(first
+    query row, first key row)``, the kernel's one dynamic predicate (None:
+    every tile computes).  With ``rotate=True`` the K/V rotation DMA to the
     right neighbour starts at the first grid step, flies under the gradient
     compute, and is waited at the last.  ``q`` arrives pre-scaled by the
     pow2 part of sm_scale; dq is emitted in q' units (callers rescale once).
@@ -754,7 +806,7 @@ def _combined_bwd_kernel(tab_ref, *refs, causal, block_q, block_k, steps, bh,
     b = pl.program_id(0)
     step = pl.program_id(1)
     q_start, k_start, first, last, whole, q_tile = _step_of(
-        tab_ref, block_q, block_k, blockdiff, offsets_ref)
+        tab_ref, block_q, block_k, mask, offsets_ref)
 
     if rotate:
         from horovod_tpu.ops.rdma import _device_id
@@ -793,9 +845,8 @@ def _combined_bwd_kernel(tab_ref, *refs, causal, block_q, block_k, steps, bh,
 
     def accumulate(masked):
         pb, ds, q, do, k = _bwd_block_math(
-            q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, causal,
-            q_start, k_start, block_q, block_k, scale_r, window=window,
-            blockdiff=blockdiff, masked=masked)
+            q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, mask,
+            q_start, k_start, block_q, block_k, scale_r, masked=masked)
         dv_scratch[...] += jax.lax.dot_general(
             pb, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -807,9 +858,7 @@ def _combined_bwd_kernel(tab_ref, *refs, causal, block_q, block_k, steps, bh,
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    # The ring's tiles above the (shifted) diagonal compute nothing.
-    _when_live(q_start + block_q - 1 >= k_start if ring and causal else None,
-               whole, accumulate)
+    _when_live(run and run(q_start, k_start), whole, accumulate)
 
     @pl.when(last)
     def _flush_dkdv():
@@ -881,11 +930,11 @@ def _tiled_call(kernel, table, bh, *, out_shape, name, interpret, prefetch=(),
 
 
 def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset=None,
-                       k_offset=None, *, causal, block_q, block_k,
+                       k_offset=None, *, mask, block_q, block_k, run=None,
                        rotate=False, collective_id=None, axis_name=None,
                        mesh_axes=(), interpret, scale_r=1.0,
                        grad_dtype=jnp.float32, dq_scale=1.0,
-                       name="hvd_flash_bwd", window=None, blockdiff=None):
+                       name="hvd_flash_bwd"):
     """pallas_call wrapper for `_combined_bwd_kernel` over (bh, sl, d)
     operands (q pre-scaled by the pow2 part of sm_scale; ``do`` and ``v_cur``
     may have another width than ``q`` and ``k_cur``, and ``dv`` then has
@@ -896,19 +945,20 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset=None,
     a device trace: the fused ring's backward step passes its own.  The grid
     is ``(bh, the table's steps)``: the mask's live tiles, key tiles outer —
     or, with the ring's ``q_offset`` and ``k_offset`` (traced, so the live
-    tiles are not known here), every pair, the kernel deciding."""
+    tiles are not known here), every pair, the ring's ``run`` deciding in the
+    kernel."""
     bh, sl, d = q.shape
     d_v = v_cur.shape[-1]
     ring = q_offset is not None
-    table = _tile_table(sl // block_q, sl // block_k, block_q, block_k,
-                        causal, window, blockdiff, by_key=True, every=ring)
+    table = _tile_table(sl // block_q, sl // block_k, block_q, block_k, mask,
+                        by_key=True, every=ring)
     steps = table.shape[1]
     kernel = functools.partial(
-        _combined_bwd_kernel, causal=causal, block_q=block_q,
-        block_k=block_k, steps=steps, bh=bh, ring=ring, rotate=rotate,
-        barrier=rotate and not interpret,
+        _combined_bwd_kernel, mask=mask, block_q=block_q,
+        block_k=block_k, steps=steps, bh=bh, ring=ring, run=run,
+        rotate=rotate, barrier=rotate and not interpret,
         axis_name=axis_name, mesh_axes=mesh_axes, scale_r=scale_r,
-        dq_scale=dq_scale, window=window, blockdiff=blockdiff)
+        dq_scale=dq_scale)
     in_specs = [
         _tile_spec(0, block_q, d),                         # q
         _tile_spec(0, block_q, d_v),                       # do
@@ -1159,9 +1209,9 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
     return ("split",) + fitted
 
 
-def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
+def _split_bwd_call(q, do, lse8, delta8, k, v, *, mask, block_q,
                     block_k, interpret, scale_r, grad_dtype=jnp.float32,
-                    dq_scale=1.0, window=None, blockdiff=None):
+                    dq_scale=1.0):
     """Split flash backward over (bh, sl, d) operands (q pre-scaled by
     the pow2 part of sm_scale): two pallas_calls — dk/dv (key tiles outer,
     queries inner) and dq (query tiles outer, keys inner) — each with
@@ -1173,40 +1223,38 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
     table's live tiles (`_tile_table`)."""
     bh, sl, d = q.shape
     d_v = v.shape[-1]              # do, v and dv; q, k, dq and dk have d
-    mask = (sl // block_q, sl // block_k, block_q, block_k, causal, window,
-            blockdiff)
+    tiles = (sl // block_q, sl // block_k, block_q, block_k, mask)
     in_specs = [_tile_spec(0, block_q, d), _tile_spec(0, block_q, d_v),
                 _lse_spec(block_q), _lse_spec(block_q),
                 _tile_spec(1, block_k, d), _tile_spec(1, block_k, d_v)]
-    suffix = _walk_suffix(window, blockdiff)
     # vma: inside shard_map (build_train_step) the default check refuses
     # an out_shape that does not say how it varies; as q does.
     grad_shape = jax.ShapeDtypeStruct((bh, sl, d), grad_dtype,
                                       vma=jax.typeof(q).vma)
     dv_shape = jax.ShapeDtypeStruct((bh, sl, d_v), grad_dtype,
                                     vma=jax.typeof(q).vma)
-    common = dict(causal=causal, block_q=block_q, block_k=block_k,
-                  scale_r=scale_r, window=window, blockdiff=blockdiff)
+    common = dict(mask=mask, block_q=block_q, block_k=block_k,
+                  scale_r=scale_r)
     dk, dv = _tiled_call(
         functools.partial(_flash_bwd_dkdv_kernel, **common),
-        _tile_table(*mask, by_key=True), bh,
+        _tile_table(*tiles, by_key=True), bh,
         in_specs=in_specs,
         out_specs=(_tile_spec(1, block_k, d), _tile_spec(1, block_k, d_v)),
         out_shape=(grad_shape, dv_shape),
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d_v), jnp.float32)],
         interpret=interpret,
-        name="hvd_flash_bwd_dkdv" + suffix,
+        name="hvd_flash_bwd_dkdv" + mask.suffix,
     )(q, do, lse8, delta8, k, v)
     dq = _tiled_call(
         functools.partial(_flash_bwd_dq_kernel, dq_scale=dq_scale, **common),
-        _tile_table(*mask), bh,
+        _tile_table(*tiles), bh,
         in_specs=in_specs,
         out_specs=_tile_spec(0, block_q, d),
         out_shape=grad_shape,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-        name="hvd_flash_bwd_dq" + suffix,
+        name="hvd_flash_bwd_dq" + mask.suffix,
     )(q, do, lse8, delta8, k, v)
     return dk, dv, dq
 
@@ -1217,47 +1265,37 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, causal, block_q,
 _TABLE_STEPS = 56 * 1024
 
 
-def _off_grid(q_len, k_len, block_q, block_k, blockdiff=None) -> bool:
+def _off_grid(q_len, k_len, block_q, block_k, mask) -> bool:
     """Whether the blocks leave the kernels' grid: ragged tails, blocks off
     the TPU tiling (the lse output block puts ``block_q`` in the 128-lane
-    dimension), under block diffusion a tile that would lie across the two
-    copies (the blocks divide ONE copy's rows), or more tile pairs, counted
-    before any mask, than a table holds (`_TABLE_STEPS`: 128-blocks past
-    30,000 rows)."""
+    dimension), a tile that would lie across two of the mask's copies (the
+    blocks divide ONE copy's rows), or more tile pairs, counted before any
+    mask, than a table holds (`_TABLE_STEPS`: 128-blocks past 30,000
+    rows)."""
     pairs = (q_len // block_q) * (k_len // block_k)
-    if blockdiff is not None:
-        q_len = k_len = blockdiff[1]
+    q_len, k_len = q_len // mask.copies, k_len // mask.copies
     return bool(q_len % block_q or k_len % block_k
                 or block_q % 128 or block_k % 128 or pairs > _TABLE_STEPS)
 
 
-def _walk_suffix(window, blockdiff) -> str:
-    """What a kernel that walks only part of the blocks carries behind its
-    name in a trace."""
-    return "_blockdiff" if blockdiff is not None \
-        else "" if window is None else "_window"
-
-
-def _backward_blocks(q_len, k_len, d, d_v, block_q, block_k, bh,
-                     blockdiff=None):
+def _backward_blocks(q_len, k_len, d, d_v, block_q, block_k, bh, mask):
     """(mode, block_q, block_k) as :func:`_bwd_plan` gives the backward at
     ``flash_attention``'s blocks, or None where the shape leaves the kernels
     for the scan."""
     block_q = min(block_q, q_len)
     block_k = min(block_k, k_len)
-    if _off_grid(q_len, k_len, block_q, block_k, blockdiff) \
-            or q_len != k_len:
+    if _off_grid(q_len, k_len, block_q, block_k, mask) or q_len != k_len:
         return None
     # One width: the call as every caller and test stand-in has known it.
     widths = {} if d_v == d else {"d_v": d_v}
     plan = _bwd_plan(q_len, d, block_q, block_k, bh, **widths)
     # The plan may step blocks down past what divides this length (rare
     # non-power-of-two long seqs): the scan impl handles it.
-    return None if _off_grid(q_len, k_len, *plan[1:], blockdiff) else plan
+    return None if _off_grid(q_len, k_len, *plan[1:], mask) else plan
 
 
-def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
-                    block_k, interpret, window=None, blockdiff=None):
+def _flash_backward(q, k, v, out, lse, g, mask, sm_scale, block_q,
+                    block_k, interpret):
     """Pallas flash backward.  Two kernel strategies, chosen per shape by
     :func:`_bwd_plan` against the scoped-VMEM ceiling: the combined
     kernel computes dk/dv AND dq from a single probability recompute per
@@ -1267,11 +1305,10 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
     batch, heads, q_len, d = q.shape
     k_len, d_v = k.shape[2], v.shape[-1]
     plan = _backward_blocks(q_len, k_len, d, d_v, block_q, block_k,
-                            batch * heads, blockdiff)
+                            batch * heads, mask)
     if plan is None:
-        return _attention_bwd_impl(q, k, v, out, lse, g, causal, sm_scale,
-                                   max(min(block_k, k_len), 128), 0, 0,
-                                   window, blockdiff)
+        return _attention_bwd_impl(q, k, v, out, lse, g, mask, sm_scale,
+                                   max(min(block_k, k_len), 128), 0, 0)
     mode, block_q, block_k = plan
     bh = batch * heads
     # Pre-scaled q (see _flash_forward): exact pow2 factor on q, f32
@@ -1300,31 +1337,28 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
     grad_dtype = q.dtype if same_dtype else jnp.float32
     if mode == "combined":
         dk, dv, dq = _combined_bwd_call(
-            qr, dor, lse8, delta8, kr, vr, causal=causal,
-            block_q=block_q, block_k=block_k, interpret=interpret,
-            scale_r=scale_r, grad_dtype=grad_dtype,
-            dq_scale=p2, window=window, blockdiff=blockdiff,
-            # A banded call's name keeps the prefix a trace is read by.
-            name="hvd_flash_bwd" + _walk_suffix(window, blockdiff))
-    else:
-        dk, dv, dq = _split_bwd_call(
-            qr, dor, lse8, delta8, kr, vr, causal=causal,
+            qr, dor, lse8, delta8, kr, vr, mask=mask,
             block_q=block_q, block_k=block_k, interpret=interpret,
             scale_r=scale_r, grad_dtype=grad_dtype, dq_scale=p2,
-            window=window, blockdiff=blockdiff)
+            # A masked call's name keeps the prefix a trace is read by.
+            name="hvd_flash_bwd" + mask.suffix)
+    else:
+        dk, dv, dq = _split_bwd_call(
+            qr, dor, lse8, delta8, kr, vr, mask=mask,
+            block_q=block_q, block_k=block_k, interpret=interpret,
+            scale_r=scale_r, grad_dtype=grad_dtype, dq_scale=p2)
     return (dq.astype(q.dtype).reshape(q.shape),
             dk.astype(k.dtype).reshape(k.shape),
             dv.astype(v.dtype).reshape(v.shape))
 
 
-def _forward_blocks(q_len, k_len, d, d_v, block_q, block_k, window=None,
-                    blockdiff=None):
+def _forward_blocks(q_len, k_len, d, d_v, block_q, block_k, mask):
     """The (block_q, block_k) the forward kernel runs ``flash_attention``'s
     blocks at, or None where the shape leaves the kernel for the scan."""
     block_q = min(block_q, q_len)
     block_k = min(block_k, k_len)
-    if _off_grid(q_len, k_len, block_q, block_k, blockdiff) \
-            or (window is not None and q_len != k_len):
+    if _off_grid(q_len, k_len, block_q, block_k, mask) \
+            or (mask.square and q_len != k_len):
         # Ragged tails or blocks off the TPU tiling grid: the blockwise path
         # handles them without padding gymnastics (the kernel targets the
         # aligned hot path).
@@ -1335,22 +1369,18 @@ def _forward_blocks(q_len, k_len, d, d_v, block_q, block_k, window=None,
         "forward", q_len, d, block_q, block_k,
         estimate=lambda _m, s, dd, bq, bk: _fwd_vmem_bytes(s, dd, bq, bk,
                                                            d_v))
-    if blockdiff is not None and _off_grid(q_len, k_len, *blocks, blockdiff):
-        return None       # clamped past what divides a copy
-    return blocks
+    # clamped past what divides the rows (a copy's)
+    return None if _off_grid(q_len, k_len, *blocks, mask) else blocks
 
 
-def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                   window=None, blockdiff=None):
+def _flash_forward(q, k, v, mask, sm_scale, block_q, block_k, interpret):
     """Returns (out, lse); routes off-grid shapes to the blockwise impl."""
     batch, heads, q_len, d = q.shape
     k_len, d_v = k.shape[2], v.shape[-1]
-    blocks = _forward_blocks(q_len, k_len, d, d_v, block_q, block_k, window,
-                             blockdiff)
+    blocks = _forward_blocks(q_len, k_len, d, d_v, block_q, block_k, mask)
     if blocks is None:
-        return _blockwise_fwd_impl(q, k, v, causal, sm_scale,
-                                   max(min(block_k, k_len), 128), 0, 0,
-                                   window, blockdiff)
+        return _blockwise_fwd_impl(q, k, v, mask, sm_scale,
+                                   max(min(block_k, k_len), 128), 0, 0)
     block_q, block_k = blocks
     bh = batch * heads
     # Pre-scale q by the exact power-of-two part of sm_scale: one
@@ -1363,11 +1393,10 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     vr = v.reshape(bh, k_len, d_v)
     vma = jax.typeof(q).vma  # see _split_bwd_call
     table = _tile_table(q_len // block_q, k_len // block_k, block_q, block_k,
-                        causal, window, blockdiff)
+                        mask)
     kernel = functools.partial(
-        _flash_kernel, causal=causal, block_q=block_q, block_k=block_k,
-        single_k=table.shape[1] == q_len // block_q, scale_r=scale_r,
-        window=window, blockdiff=blockdiff)
+        _flash_kernel, mask=mask, block_q=block_q, block_k=block_k,
+        single_k=table.shape[1] == q_len // block_q, scale_r=scale_r)
     out, lse = _tiled_call(
         kernel, table, bh,
         in_specs=[_tile_spec(0, block_q, d), _tile_spec(1, block_k, d),
@@ -1383,31 +1412,28 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
             pltpu.VMEM((block_q, d_v), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
-        name="hvd_flash_fwd" + _walk_suffix(window, blockdiff),
+        name="hvd_flash_fwd" + mask.suffix,
     )(qr, kr, vr)
     return (out.reshape(batch, heads, q_len, d_v),
             lse[:, 0, :].reshape(batch, heads, q_len))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash_attention(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                     window, blockdiff):
-    return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                          interpret, window, blockdiff)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, mask, sm_scale, block_q, block_k, interpret):
+    return _flash_forward(q, k, v, mask, sm_scale, block_q, block_k,
+                          interpret)[0]
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-               window, blockdiff):
-    out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                              interpret, window, blockdiff)
+def _flash_fwd(q, k, v, mask, sm_scale, block_q, block_k, interpret):
+    out, lse = _flash_forward(q, k, v, mask, sm_scale, block_q, block_k,
+                              interpret)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
-               blockdiff, res, g):
+def _flash_bwd(mask, sm_scale, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
-    return _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
-                           block_k, interpret, window, blockdiff)
+    return _flash_backward(q, k, v, out, lse, g, mask, sm_scale, block_q,
+                           block_k, interpret)
 
 
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
@@ -1423,38 +1449,31 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_diffusion: Optional[int] = None):
     """Fused multi-head attention.
 
-    ``block_diffusion=B`` (with neither ``causal`` nor ``window``): the rows
-    are ``[clean; noised]``, two copies of ``L`` positions each, a block-
-    diffusion model's training pass (:func:`block_diffusion_mask`: a clean
-    query sees the clean keys of its block of ``B`` and of the earlier ones,
-    a noised query the clean keys of the earlier blocks and the noised keys
-    of its own): 24 of the 64 pairs of 1,024-blocks at ``L = 4,096``, where a
-    causal walk over ``2 L`` takes 36 (:func:`blockdiff_blocks`).  The blocks
-    divide ``L``; other shapes take the scan.  Kernels:
-    ``hvd_flash_fwd_blockdiff``, ``hvd_flash_bwd_blockdiff``,
-    ``hvd_flash_bwd_dkdv_blockdiff``, ``hvd_flash_bwd_dq_blockdiff``.
-
-    ``window=W`` (with ``causal=True``): a sliding window — query ``t`` sees
-    the keys ``s`` with ``0 <= t - s < W``, itself and the ``W - 1`` before
-    it.  ``W`` need not divide by the block; ``W >= seq`` IS the causal call,
-    program for program.  The banded kernels are named
-    ``hvd_flash_fwd_window``, ``hvd_flash_bwd_window``,
-    ``hvd_flash_bwd_dkdv_window`` and ``hvd_flash_bwd_dq_window`` in a trace;
-    the backward takes the plan :func:`_bwd_plan` gives the shape (a band
-    needs no more VMEM), and :func:`window_blocks` counts what the forward
-    visits.
+    The mask is one of :class:`Mask`'s kinds (which owns what the kind means
+    everywhere: docs/api.md, "adding a mask"): none; ``causal=True``
+    (:class:`Causal`), with ``window=W`` a sliding window of the query's own
+    key and the ``W - 1`` before it — ``W`` need not divide by the block, and
+    ``W >= seq`` IS the causal call, program for program; or
+    ``block_diffusion=B`` (:class:`BlockDiffusion`, with neither of the
+    others): the rows are ``[clean; noised]``, two copies of ``L`` positions
+    in blocks of ``B``, a block-diffusion model's training pass, whose blocks
+    divide ``L``.  A windowed call's kernels carry ``_window`` behind their
+    names in a trace (``hvd_flash_fwd``, ``hvd_flash_bwd``,
+    ``hvd_flash_bwd_dkdv``, ``hvd_flash_bwd_dq``), block diffusion's
+    ``_blockdiff``; :func:`mask_blocks` counts what the forward visits.
 
     Under every mask a kernel's grid is ``(batch * heads, live tiles)``: the
     (query block, key block) pairs the mask touches, listed on the host from
     the static shapes (:func:`_tile_table`) and handed to the grid by scalar
     prefetch — the causal mask's pairs on and under the diagonal, a window's
-    band, block diffusion's two runs a row.  A pair wholly outside the mask
-    is neither a grid step nor a copy, forward or backward.  The causal and
-    the banded kernels hold ONE body, which masks every tile it is given;
-    block diffusion's hold two, and the table's flag sends a tile the mask
-    leaves whole to the one with no mask arithmetic
-    (:func:`flash_grid_steps` counts a shape's live pairs, grid steps and the
-    rectangles the tables replaced).
+    band, block diffusion's two runs a row (24 of the 64 pairs of
+    1,024-blocks at ``L = 4,096``, where a causal walk over ``2 L`` takes
+    36).  A pair wholly outside the mask is neither a grid step nor a copy,
+    forward or backward.  The causal and the banded kernels hold ONE body,
+    which masks every tile it is given; block diffusion's hold two, and the
+    table's flag sends a tile the mask leaves whole to the one with no mask
+    arithmetic (:func:`flash_grid_steps` counts a shape's live pairs, grid
+    steps and the rectangles the tables replaced).
 
     ``v``'s last axis may differ from ``q``'s and ``k``'s (latent attention:
     a 192-wide query and key, a 128-wide value); the output has ``v``'s, and
@@ -1485,7 +1504,8 @@ def flash_attention(q, k, v, causal: bool = False,
     single_k path).  The BACKWARD re-plans blocks per shape against the
     16 MiB scoped-VMEM ceiling and switches to the split dkdv/dq kernel
     pair for long sequences (see :func:`_bwd_plan`: a tuned block choice
-    that fits the forward need not compile for the backward at seq 8192).
+    that fits the forward need not compile for the backward at seq 8192;
+    a mask changes no plan).
     """
     if layout not in ("bhsd", "bshd"):
         raise ValueError(f"unknown layout {layout!r}")
@@ -1500,9 +1520,7 @@ def flash_attention(q, k, v, causal: bool = False,
         sm_scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    window = _checked_window(window, causal, k.shape[-2])
-    blockdiff = _checked_block_diffusion(block_diffusion, causal, window,
-                                         q.shape[-2], k.shape[-2])
+    mask = Mask.of(q.shape[-2], k.shape[-2], causal, window, block_diffusion)
     if not interpret and jnp.float16 in (q.dtype, k.dtype, v.dtype):
         # float16 is not a native TPU type and Mosaic refuses the kernel
         # outright (verified on v5e: even the forward fails to compile) —
@@ -1512,75 +1530,58 @@ def flash_attention(q, k, v, causal: bool = False,
         return blockwise_attention(q, k, v, causal=causal, sm_scale=sm_scale,
                                    window=window,
                                    block_diffusion=block_diffusion)
-    # An explicit block past _MAX_BLOCK is cut to it as the default is:
-    # the chip's compiler refuses both passes there (scoped VMEM: 2048-row
-    # blocks at seq 2048 fail the backward, 4096 at seq 4096 the forward —
-    # compiled for a described v5e, tests/test_ops.py), whatever the
-    # structural estimates say (ADVICE r5 #2).
-    # Under block diffusion the default blocks are one copy's.
-    copies = 1 if blockdiff is None else 2
-    block_q, block_k = _default_blocks(q.shape[-2] // copies,
-                                       k.shape[-2] // copies, block_q,
-                                       block_k)
-    return _flash_attention(q, k, v, causal, sm_scale, block_q, block_k,
-                            interpret, window, blockdiff)
+    return _flash_attention(
+        q, k, v, mask, sm_scale,
+        *_default_blocks(q.shape[-2], k.shape[-2], block_q, block_k, mask),
+        interpret)
 
 
-def _default_blocks(q_len, k_len, block_q=None, block_k=None):
+def _default_blocks(q_len, k_len, block_q, block_k, mask):
+    """The blocks a call takes where it names none: of the rows the blocks
+    must divide (one copy's).  An explicit block past _MAX_BLOCK is cut to it
+    as the default is: the chip's compiler refuses both passes there (scoped
+    VMEM: 2048-row blocks at seq 2048 fail the backward, 4096 at seq 4096 the
+    forward — compiled for a described v5e, tests/test_ops.py), whatever the
+    structural estimates say (ADVICE r5 #2)."""
     if block_q is None or block_q > _MAX_BLOCK:
         # 1024-row query blocks: a grid step has a fixed cost, so the
         # largest block that compiles does the fewest of them (bundles
         # per pair by block shape: PERF.md section 7).
-        block_q = _pick_block(q_len, maximum=_MAX_BLOCK)
+        block_q = _pick_block(q_len // mask.copies, maximum=_MAX_BLOCK)
     if block_k is None or block_k > _MAX_BLOCK:
         # Whole-k key blocks skip the online-softmax rescale entirely
         # (the kernel's single_k fast path) and the backward's key loop.
-        block_k = _pick_block(k_len, maximum=_MAX_BLOCK)
+        block_k = _pick_block(k_len // mask.copies, maximum=_MAX_BLOCK)
     return block_q, block_k
 
 
-def _live_tiles(seq, blocks, causal=False, window=None, blockdiff=None):
+def _live_tiles(seq, blocks, mask):
     """The (query tile, key tile) pairs of ``seq`` rows in ``blocks`` that a
     mask touches."""
-    return int(_tile_masks(seq // blocks[0], seq // blocks[1], *blocks,
-                           causal, window, blockdiff)[0].sum())
+    return int(mask.tiles(seq // blocks[0], seq // blocks[1],
+                          *blocks)[0].sum())
 
 
-def window_blocks(seq: int, window: int, d: int, d_v: Optional[int] = None,
-                  block_q: Optional[int] = None,
-                  block_k: Optional[int] = None):
+def mask_blocks(seq: int, d: int, d_v: Optional[int] = None,
+                causal: bool = False, window: Optional[int] = None,
+                block_diffusion: Optional[int] = None,
+                block_q: Optional[int] = None, block_k: Optional[int] = None):
     """(visited, causal): the (query block, key block) pairs one head's
-    FORWARD kernel visits for ``flash_attention(causal=True, window=window)``
-    at ``seq`` rows of width ``d`` under the blocks it takes, and the pairs
-    the causal kernel visits under the same blocks — 21 and 36 at 8,192 rows
-    with a window of 2,048 in 1,024-blocks, 70 and 136 in 512-blocks.  None
-    where the call leaves the kernel for the scan, which walks every block."""
-    blocks = _forward_blocks(seq, seq, d, d_v or d,
-                             *_default_blocks(seq, seq, block_q, block_k))
+    FORWARD kernel visits for ``flash_attention`` under these mask keywords
+    at ``seq`` rows of width ``d`` (``seq`` counts both copies under
+    ``block_diffusion``) in the blocks it takes, and the pairs the causal
+    kernel visits over the same rows in the same blocks — 21 and 36 at 8,192
+    rows with a window of 2,048 in 1,024-blocks, 70 and 136 in 512-blocks; 24
+    and 36 for block diffusion over two copies of 4,096, 80 and 136 in
+    512-blocks.  None where the call leaves the kernel for the scan, which
+    walks every block."""
+    mask = Mask.of(seq, seq, causal, window, block_diffusion)
+    blocks = _forward_blocks(
+        seq, seq, d, d_v or d,
+        *_default_blocks(seq, seq, block_q, block_k, mask), mask)
     if blocks is None:
         return None
-    return (_live_tiles(seq, blocks, True, _checked_window(window, True, seq)),
-            _live_tiles(seq, blocks, True))
-
-
-def blockdiff_blocks(seq: int, block: int, d: int, d_v: Optional[int] = None,
-                     block_q: Optional[int] = None,
-                     block_k: Optional[int] = None):
-    """(visited, causal over 2 L): the (query block, key block) pairs one
-    head's FORWARD kernel visits for ``flash_attention(block_diffusion=
-    block)`` over the two copies of ``seq`` rows of width ``d`` under the
-    blocks it takes, and the pairs the causal kernel would visit over the same
-    ``2 seq`` rows under the same blocks — 24 and 36 at 4,096 rows a copy in
-    1,024-blocks, 80 and 136 in 512-blocks.  None where the call leaves the
-    kernel for the scan, which walks every block."""
-    blockdiff = (int(block), seq)
-    blocks = _forward_blocks(2 * seq, 2 * seq, d, d_v or d,
-                             *_default_blocks(seq, seq, block_q, block_k),
-                             blockdiff=blockdiff)
-    if blocks is None:
-        return None
-    return (_live_tiles(2 * seq, blocks, blockdiff=blockdiff),
-            _live_tiles(2 * seq, blocks, True))
+    return _live_tiles(seq, blocks, mask), _live_tiles(seq, blocks, Causal())
 
 
 def flash_grid_steps(seq: int, d: int, bh: int, d_v: Optional[int] = None,
@@ -1600,26 +1601,21 @@ def flash_grid_steps(seq: int, d: int, bh: int, d_v: Optional[int] = None,
     pairs, so the first two are equal: 36 and 136 for the causal forward and
     backward at 8,192 rows of width 64, of rectangles of 64 and 256.  A pass
     that leaves the kernels for the scan has no entry."""
-    window = _checked_window(window, causal, seq)
-    blockdiff = _checked_block_diffusion(block_diffusion, causal, window, seq,
-                                         seq)
-    copy = seq if blockdiff is None else seq // 2
-    block_q, block_k = _default_blocks(copy, copy, block_q, block_k)
-    suffix = _walk_suffix(window, blockdiff)
+    mask = Mask.of(seq, seq, causal, window, block_diffusion)
+    block_q, block_k = _default_blocks(seq, seq, block_q, block_k, mask)
     tables = {}
-    blocks = _forward_blocks(seq, seq, d, d_v or d, block_q, block_k, window,
-                             blockdiff)
+    blocks = _forward_blocks(seq, seq, d, d_v or d, block_q, block_k, mask)
     if blocks is not None:
-        tables["hvd_flash_fwd" + suffix] = blocks, False
-    plan = _backward_blocks(seq, seq, d, d_v or d, block_q, block_k, bh,
-                            blockdiff)
+        tables["hvd_flash_fwd"] = blocks, False
+    plan = _backward_blocks(seq, seq, d, d_v or d, block_q, block_k, bh, mask)
     if plan is not None and plan[0] == "combined":
-        tables["hvd_flash_bwd" + suffix] = plan[1:], True
+        tables["hvd_flash_bwd"] = plan[1:], True
     elif plan is not None:
-        tables["hvd_flash_bwd_dkdv" + suffix] = plan[1:], True
-        tables["hvd_flash_bwd_dq" + suffix] = plan[1:], False
-    return {name: (_live_tiles(seq, blocks, causal, window, blockdiff),
-                   _tile_table(seq // blocks[0], seq // blocks[1], *blocks,
-                               causal, window, blockdiff, by_key).shape[1],
-                   (seq // blocks[0]) * (seq // blocks[1]))
+        tables["hvd_flash_bwd_dkdv"] = plan[1:], True
+        tables["hvd_flash_bwd_dq"] = plan[1:], False
+    return {name + mask.suffix: (
+                _live_tiles(seq, blocks, mask),
+                _tile_table(seq // blocks[0], seq // blocks[1], *blocks,
+                            mask, by_key).shape[1],
+                (seq // blocks[0]) * (seq // blocks[1]))
             for name, (blocks, by_key) in tables.items()}

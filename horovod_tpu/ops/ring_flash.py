@@ -56,10 +56,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.attention import (NEG_INF, POS_BIG, _attend_block,
-                                       _bwd_plan, _combined_bwd_call,
-                                       _finalize_flash, _init_state,
-                                       _pick_block, _split_scale)
+from horovod_tpu.ops.attention import (NEG_INF, POS_BIG, Causal, Mask,
+                                       _attend_block, _bwd_plan,
+                                       _combined_bwd_call, _finalize_flash,
+                                       _init_state, _pick_block, _split_scale)
 from horovod_tpu.ops.rdma import _ambient_mesh_axes, _device_id
 
 _COLLECTIVE_IDS = (15, 16)  # phase-alternating barrier namespaces
@@ -138,7 +138,8 @@ def _step_kernel(*refs, causal, block_q, block_k, num_q_blocks,
         # single_k skips the online rescale; the unconditional init above
         # still covers whole-shard-masked ring steps (run stays False).
         _attend_block(q_ref, k_ref, v_ref, m_scratch, l_scratch,
-                      acc_scratch, q_start, k_start, causal,
+                      acc_scratch, q_start, k_start,
+                      Causal() if causal else Mask(),
                       block_q, block_k, single_k=num_k_blocks == 1,
                       scale_r=scale_r)
 
@@ -183,7 +184,10 @@ def _bwd_ring_step(q, do, lse8, delta8, k_cur, v_cur, q_offset, k_offset, *,
     barrier = rotate and not interpret
     results = _combined_bwd_call(
         q, do, lse8, delta8, k_cur, v_cur, q_offset, k_offset,
-        causal=causal, block_q=block_q, block_k=block_k, rotate=rotate,
+        mask=Causal() if causal else Mask(), block_q=block_q, block_k=block_k,
+        # The shard's tiles above the (shifted) diagonal compute nothing.
+        run=(lambda q_start, k_start: q_start + block_q - 1 >= k_start)
+        if causal else None, rotate=rotate,
         collective_id=_COLLECTIVE_IDS[phase % 2] if barrier else None,
         axis_name=axis_name, mesh_axes=_ambient_mesh_axes(axis_name),
         interpret=interpret, scale_r=scale_r, name="hvd_ring_flash_bwd")

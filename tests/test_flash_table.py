@@ -3,8 +3,12 @@ table of the live (query tile, key tile) pairs is each kernel's grid — against
 the masks evaluated element by element, at the benchmark's cells' shapes; the
 counter that holds `grid_steps == grid_live`; the kernels' values against
 `mha_reference` under the three masks, combined and split, and bit for bit
-against what the rectangular grids of commit 585dfaa gave; and the bodies a
-kernel holds (one under the causal mask and a window, block diffusion's two)."""
+against what the rectangular grids of commit 585dfaa gave; the bodies a
+kernel holds (one under the causal mask and a window, block diffusion's two);
+and a kind of mask that `ops/attention.py` has never heard of, through the
+same kernels."""
+
+import dataclasses
 
 import functools
 import hashlib
@@ -16,19 +20,16 @@ import pytest
 
 import horovod_tpu.ops.attention as attn
 from horovod_tpu.ops import flash_attention, mha_reference
-from horovod_tpu.ops.attention import (blockdiff_blocks, flash_grid_steps,
-                                       window_blocks)
+from horovod_tpu.ops.attention import Mask, flash_grid_steps, mask_blocks
 
 
-def seen_pairs(seq, causal=False, window=None, block_diffusion=None):
-    """The mask as a (seq, seq) boolean matrix, position by position."""
-    t, s = np.arange(seq)[:, None], np.arange(seq)[None, :]
-    if block_diffusion is not None:
-        return np.asarray(attn.block_diffusion_mask(t, s, block_diffusion,
-                                                    seq // 2))
-    if not causal:
-        return np.ones((seq, seq), bool)
-    return (s <= t) if window is None else (s <= t) & (t - s < window)
+def seen_pairs(seq, mask):
+    """``mask`` (a `Mask`, or `flash_attention`'s keywords for one) as a
+    (seq, seq) boolean matrix, position by position: its `seen`."""
+    if isinstance(mask, dict):
+        mask = Mask.of(seq, seq, **mask)
+    return np.broadcast_to(np.asarray(mask.seen(
+        np.arange(seq)[:, None], np.arange(seq)[None, :])), (seq, seq))
 
 
 def equations(jaxpr, primitive):
@@ -46,23 +47,38 @@ def pallas_calls(jaxpr):
             for eqn in equations(jaxpr, "pallas_call")}
 
 
-def check_tables(seen, block_q, block_k, causal=False, window=None,
-                 blockdiff=None):
-    """Both walks of the mask ``seen`` (queries outer, keys outer): every
-    tile that holds a seen pair is a step exactly once and no other tile is;
-    a row's steps are contiguous, its tiles ascend, and `first` and `last`
-    are set once a row, on its first and last step; `whole` is block
-    diffusion's flag alone, set where the tile holds no unseen pair (the
-    causal and the banded kernels have one body and no such flag).  Returns
-    the live pairs."""
-    num_q, num_k = seen.shape[0] // block_q, seen.shape[1] // block_k
+def check_tables(mask, seq, block_q, block_k):
+    """The forms of ``mask`` against its `seen` over ``seq`` rows: `tiles` is
+    `live` where a tile holds a seen pair and `whole` where it holds no
+    unseen one, and `cut` leaves a live tile's logits where `seen` is true
+    and nowhere else (in each row of tiles the first live tile that is whole
+    and the first that is not: a kernel asks `cut` of no dead tile).  Then
+    both walks of the table
+    (queries outer, keys outer): every live tile is a step exactly once and
+    no other tile is; a row's steps are contiguous, its tiles ascend, and
+    `first` and `last` are set once a row, on its first and last step;
+    `whole` is flagged for a mask whose kernels hold a second body
+    (`whole_body`: block diffusion's) and for no other.  Returns the live
+    pairs."""
+    seen = seen_pairs(seq, mask)
+    num_q, num_k = seq // block_q, seq // block_k
     tiles = seen.reshape(num_q, block_q, num_k, block_k)
     live, whole = tiles.any((1, 3)), tiles.all((1, 3))
+    got_live, got_whole = mask.tiles(num_q, num_k, block_q, block_k)
+    assert (got_live == live).all() and (got_whole == whole).all()
+    zeros = jnp.zeros((block_q, block_k), jnp.float32)
+    for i in range(num_q):
+        firsts = {}
+        for j in np.flatnonzero(live[i]):
+            firsts.setdefault(whole[i, j], j)
+        for j in firsts.values():
+            cut = mask.cut(zeros, jnp.int32(i * block_q),
+                           jnp.int32(j * block_k), block_q, block_k)
+            assert ((np.asarray(cut) == 0) == tiles[i, :, j]).all(), (i, j)
     want = set(zip(*np.nonzero(live)))
     for by_key in (False, True):
         q_tile, k_tile, flags = attn._tile_table(
-            num_q, num_k, block_q, block_k, causal, window, blockdiff,
-            by_key=by_key)
+            num_q, num_k, block_q, block_k, mask, by_key=by_key)
         pairs = list(zip(q_tile.tolist(), k_tile.tolist()))
         assert len(pairs) == len(set(pairs)) and set(pairs) == want
         outer, inner = (k_tile, q_tile) if by_key else (q_tile, k_tile)
@@ -78,7 +94,7 @@ def check_tables(seen, block_q, block_k, causal=False, window=None,
         assert (np.flatnonzero(last)
                 == np.r_[starts[1:] - 1, len(outer) - 1]).all()
         assert (((flags & attn._WHOLE) != 0)
-                == (whole[q_tile, k_tile] if blockdiff is not None
+                == (whole[q_tile, k_tile] if mask.whole_body
                     else False)).all()
         assert flags.max() < 8
     return want
@@ -103,30 +119,25 @@ CELLS = [
 def test_the_tables_at_the_cells_shapes(seq, mask, d, d_v, bh):
     """The forward's table at the blocks it takes and the backward's at the
     plan's: exactly the mask's tiles, and the counter's `live` is what
-    `window_blocks` / `blockdiff_blocks` / the causal count give."""
-    seen = seen_pairs(seq, **mask)
-    blockdiff = (mask["block_diffusion"], seq // 2) \
-        if "block_diffusion" in mask else None
-    table_mask = (mask.get("causal", False), mask.get("window"), blockdiff)
+    `mask_blocks` and the causal count give."""
+    kind = Mask.of(seq, seq, **mask)
     mode, plan_q, plan_k = attn._bwd_plan(seq, d, 1024, 1024, bh,
                                           **({"d_v": d_v} if d_v else {}))
-    forward = len(check_tables(seen, 1024, 1024, *table_mask))
-    backward = len(check_tables(seen, plan_q, plan_k, *table_mask))
+    forward = len(check_tables(kind, seq, 1024, 1024))
+    backward = len(check_tables(kind, seq, plan_q, plan_k))
     grids = flash_grid_steps(seq, d, bh, d_v, **mask)
-    suffix = attn._walk_suffix(mask.get("window"), blockdiff)
+    suffix = kind.suffix
     names = {"combined": ["hvd_flash_bwd"],
              "split": ["hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"]}[mode]
     rectangle = (seq // plan_q) * (seq // plan_k)
     assert grids == {
         "hvd_flash_fwd" + suffix: (forward, forward, (seq // 1024) ** 2),
         **{name + suffix: (backward, backward, rectangle) for name in names}}
-    if "window" in mask:
-        assert window_blocks(seq, mask["window"], d)[0] == forward
-    elif blockdiff is not None:
-        assert blockdiff_blocks(seq // 2, blockdiff[0], d)[0] == forward
-    else:
+    assert mask_blocks(seq, d, **mask)[0] == forward
+    if mask == dict(causal=True):
         assert forward == sum(i + 1 for i in range(seq // 1024))
-        assert window_blocks(seq, seq, d) == (forward, forward)
+        assert mask_blocks(seq, d, causal=True, window=seq) \
+            == (forward, forward)
 
 
 MASKS = [dict(causal=True), dict(causal=True, window=200),
@@ -155,7 +166,7 @@ def test_no_grid_step_computes_nothing(monkeypatch, mask, plan):
     assert len(counted) == {"combined": 2, "split": 3}[plan]
     assert grids == {name: (bh, steps) for name, (_, steps, _)
                      in counted.items()}
-    seen = seen_pairs(seq, **mask)
+    seen = seen_pairs(seq, mask)
     for name, (live, steps, rectangle) in counted.items():
         block_q, block_k = (256, 128) if "fwd" in name else (128, 256)
         tiles = seen.reshape(seq // block_q, block_q, seq // block_k, block_k)
@@ -167,8 +178,8 @@ def test_the_ring_reads_all_pairs():
     """The ring's offsets are traced: its table holds every pair, with the
     rows' `first` and `last` and nothing else, whatever the mask (the
     kernel's predicate decides a tile on the device)."""
-    for causal in (True, False):
-        q_tile, k_tile, flags = attn._tile_table(3, 2, 128, 256, causal,
+    for mask in (attn.Causal(), Mask()):
+        q_tile, k_tile, flags = attn._tile_table(3, 2, 128, 256, mask,
                                                  by_key=True, every=True)
         assert list(zip(k_tile, q_tile)) == [(j, i) for j in range(2)
                                              for i in range(3)]
@@ -181,14 +192,14 @@ def test_more_tiles_than_a_table_holds_take_the_scan():
     one does, forward and backward, and the counter has no entry for it."""
     fits = 128 * int(attn._TABLE_STEPS ** 0.5)
     for seq, kept in ((fits, True), (fits + 128, False)):
-        assert (attn._forward_blocks(seq, seq, 64, 64, 128, 128)
+        assert (attn._forward_blocks(seq, seq, 64, 64, 128, 128, Mask())
                 is not None) == kept
-        assert (attn._backward_blocks(seq, seq, 64, 64, 128, 128, 1)
+        assert (attn._backward_blocks(seq, seq, 64, 64, 128, 128, 1, Mask())
                 is not None) == kept
         assert bool(flash_grid_steps(seq, 64, 1, causal=True, block_q=128,
                                      block_k=128)) == kept
     # the default blocks reach a quarter of a million rows
-    assert attn._forward_blocks(1 << 17, 1 << 17, 64, 64, 1024, 1024)
+    assert attn._forward_blocks(1 << 17, 1 << 17, 64, 64, 1024, 1024, Mask())
 
 
 @pytest.mark.parametrize("plan", ["combined", "split"])
@@ -213,12 +224,9 @@ def test_values_and_gradients_are_the_references(monkeypatch, mask, d, plan):
 
     np.testing.assert_allclose(flash(q, k, v), reference(q, k, v),
                                atol=2e-5, rtol=2e-5)
-    causal, window = mask.get("causal", False), mask.get("window")
-    blockdiff = (mask["block_diffusion"], 256) \
-        if "block_diffusion" in mask else None
-    _, lse = attn._flash_forward(q, k, v, causal, d ** -0.5, 256, 128, True,
-                                 window, blockdiff)
-    logits = jnp.where(seen_pairs(512, **mask), jnp.einsum(
+    _, lse = attn._flash_forward(q, k, v, Mask.of(512, 512, **mask),
+                                 d ** -0.5, 256, 128, True)
+    logits = jnp.where(seen_pairs(512, mask), jnp.einsum(
         "bhqd,bhkd->bhqk", q, k, precision="highest") * d ** -0.5, -jnp.inf)
     np.testing.assert_allclose(lse, jax.nn.logsumexp(logits, -1),
                                atol=2e-5, rtol=2e-5)
@@ -235,19 +243,16 @@ def kernel_digests(attn, mask, d, plan):
     """sha256 (16 hex digits) of the float32 bytes of out, lse, dq, dk, dv of
     the interpreted kernels on a fixed seed: 2 heads of 512 rows, forward in
     (256, 128) blocks, backward ``plan`` in (128, 256).  ``attn``: the
-    module, so that a copy of commit 585dfaa can be asked the same (which is
-    where `AT_585DFAA` came from), and why the plan is patched by hand."""
+    module (`AT_585DFAA` came from asking a copy of commit 585dfaa the same,
+    through the signatures it had), and why the plan is patched by hand."""
     rng = np.random.default_rng([d, len(mask), plan == "split"])
     q, k, v, mix = (jnp.asarray(rng.standard_normal((1, 2, 512, d)),
                                 jnp.bfloat16) for _ in range(4))
-    causal, window = mask.get("causal", False), mask.get("window")
-    blockdiff = (mask["block_diffusion"], 256) \
-        if "block_diffusion" in mask else None
     real, attn._bwd_plan = attn._bwd_plan, \
         lambda q_len, d, bq, bk, bh=1: (plan, 128, 256)
     try:
-        out, lse = attn._flash_forward(q, k, v, causal, d ** -0.5, 256, 128,
-                                       True, window, blockdiff)
+        out, lse = attn._flash_forward(q, k, v, attn.Mask.of(512, 512, **mask),
+                                       d ** -0.5, 256, 128, True)
         grads = jax.grad(lambda *a: (attn.flash_attention(
             *a, block_q=256, block_k=128, interpret=True, **mask).astype(
                 jnp.float32) * mix.astype(jnp.float32)).sum(),
@@ -392,3 +397,79 @@ def test_a_kernel_holds_the_bodies_it_had(name, plan):
         "split": ["hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"]}[plan]
     assert kernel_dots(attn, MASK_OF[name], plan) == {
         kernel + suffix: bodies * ONE_BODY[kernel] for kernel in names}
+
+
+# --- a kind of mask the module has never heard of -----------------------------
+
+@dataclasses.dataclass(frozen=True)
+class PrefixLM(Mask):
+    """Every query sees the keys below ``prefix``; after it the mask is
+    causal.  The three forms `Mask` asks a kind for, and a second kernel body
+    for the tiles it leaves whole, as block diffusion has."""
+
+    prefix: int
+
+    suffix = "_prefix"
+    whole_body = True
+
+    def seen(self, q_pos, k_pos):
+        return (k_pos < self.prefix) | (q_pos >= k_pos)
+
+    def tiles(self, num_q, num_k, block_q, block_k):
+        q_lo, q_hi, k_lo, k_hi = self.bounds(num_q, num_k, block_q, block_k)
+        return ((k_lo < self.prefix) | (q_hi >= k_lo),
+                (k_hi < self.prefix) | (q_lo >= k_hi))
+
+    def cut(self, s, q_start, k_start, block_q, block_k):
+        key = k_start + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        query = q_start + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        return jnp.where((key < self.prefix) | (query >= key), s,
+                         attn.NEG_INF)
+
+
+@pytest.mark.parametrize("plan", ["combined", "split"])
+def test_a_mask_is_one_class(monkeypatch, plan):
+    """`PrefixLM`, defined above and nowhere else, through the private
+    `_flash_attention`: its tables hold its live tiles and no other and flag
+    its whole ones, its kernels carry its suffix and both bodies, and output
+    and gradients are the dense softmax's under its own `seen` — with no edit
+    to a kernel, a `pallas_call` wrapper, a planner or the table builder."""
+    monkeypatch.setattr(attn, "_bwd_plan",
+                        lambda q_len, d, bq, bk, bh=1: (plan, 128, 256))
+    seq, d = 512, 64
+    mask = PrefixLM(200).checked(seq, seq)
+    live = check_tables(mask, seq, 256, 128)
+    assert live == check_tables(attn.Causal(), seq, 256, 128) | {(0, 1)}
+    check_tables(mask, seq, 128, 256)
+    rng = np.random.default_rng(45)
+    q, k, v, mix = (jnp.asarray(rng.standard_normal((1, 2, seq, d)),
+                                jnp.float32) for _ in range(4))
+
+    def flash(q, k, v):
+        return attn._flash_attention(q, k, v, mask, d ** -0.5, 256, 128, True)
+
+    def dense(q, k, v):
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                            precision="highest") * d ** -0.5
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(jnp.where(
+            seen_pairs(seq, mask), logits, -jnp.inf), -1), v,
+            precision="highest")
+
+    def loss(fn):
+        return lambda *a: (fn(*a) * mix).sum()
+
+    program = jax.make_jaxpr(jax.grad(loss(flash), (0, 1, 2)))(q, k, v).jaxpr
+    names = ["hvd_flash_fwd"] + {
+        "combined": ["hvd_flash_bwd"],
+        "split": ["hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"]}[plan]
+    assert {eqn.params["name"]: len(list(equations(eqn.params["jaxpr"],
+                                                   "dot_general")))
+            for eqn in equations(program, "pallas_call")} == {
+        name + "_prefix": 2 * ONE_BODY[name] for name in names}
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v),
+                               atol=2e-5, rtol=2e-5)
+    for g, w in zip(jax.grad(loss(flash), (0, 1, 2))(q, k, v),
+                    jax.grad(loss(dense), (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(g, w, atol=1e-3, rtol=1e-3)

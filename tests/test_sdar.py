@@ -28,7 +28,7 @@ from horovod_tpu.models.transformer import (LAYER_KINDS, Attention,
                                             MixerLayer, SparseExperts)
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              mha_reference)
-from horovod_tpu.ops.attention import blockdiff_blocks
+from horovod_tpu.ops.attention import mask_blocks
 from tests.test_hybrid import (both_ways, close, spread, trees_close,
                                with_highest)
 from tests.test_flash_table import check_tables
@@ -185,7 +185,7 @@ def test_a_copy_no_tile_divides_takes_the_scan(length, block):
                                       (0, 1, 2)))(q, k, v)
     assert _pallas_call_names(program.jaxpr) == []
     close(masked(q, k, v), masked_softmax(q, k, v, block))
-    assert blockdiff_blocks(length, block, 64) is None
+    assert mask_blocks(2 * length, 64, block_diffusion=block) is None
 
 
 @pytest.mark.parametrize("wrong", [dict(causal=True), dict(window=8),
@@ -226,17 +226,17 @@ def test_the_kernels_visit_exactly_the_masks_tiles(length, block, block_q,
     are the tile pairs the mask touches, once each and no other, so a tile is
     fetched only for a pair the mask touches; a row's steps are contiguous
     with `first` and `last` set once; a tile the table calls whole holds no
-    hidden pair; and the count is what `blockdiff_blocks` reports."""
+    hidden pair; and the count is what `mask_blocks` reports."""
     want = touching(length, block, block_q, block_k)
-    assert check_tables(seen_by_hand(length, block), block_q, block_k,
-                        blockdiff=(block, length)) == want
+    assert check_tables(attn.BlockDiffusion(block, length), 2 * length,
+                        block_q, block_k) == want
     num_q = 2 * length // block_q
     if block_q % 128 == 0 and block_k % 128 == 0:
         rows = 2 * length
         causal = sum((i * block_q + block_q - 1) // block_k + 1
                      for i in range(num_q))
-        assert blockdiff_blocks(length, block, 128, block_q=block_q,
-                                block_k=block_k) == (len(want), causal)
+        assert mask_blocks(rows, 128, block_diffusion=block, block_q=block_q,
+                           block_k=block_k) == (len(want), causal)
         # The grids are the tables', not the rows'.
         shape = jax.ShapeDtypeStruct((1, 2, rows, 128), jnp.bfloat16)
 
@@ -257,10 +257,10 @@ def test_the_kernels_visit_exactly_the_masks_tiles(length, block, block_q,
 
 
 def test_the_cells_counts():
-    assert blockdiff_blocks(4096, 4, 128) == (24, 36)
-    assert blockdiff_blocks(4096, 4, 128, block_q=512, block_k=512) \
-        == (80, 136)
-    assert blockdiff_blocks(4096, 32, 128) == (24, 36)
+    assert mask_blocks(8192, 128, block_diffusion=4) == (24, 36)
+    assert mask_blocks(8192, 128, block_diffusion=4, block_q=512,
+                       block_k=512) == (80, 136)
+    assert mask_blocks(8192, 128, block_diffusion=32) == (24, 36)
     assert ops_count_sdar.blockdiff_pairs(4096, 4) == 4096 ** 2 + 4096 * 4
 
 
@@ -432,7 +432,7 @@ def test_block_diffusion_layers_count_their_tiles():
     assert seen == {"blocks_visited": [3] * DEPTH,
                     "blocks_causal": [3] * DEPTH,
                     "grid_live": [6] * DEPTH, "grid_steps": [6] * DEPTH}
-    assert blockdiff_blocks(SEQ, BLOCK, HEAD_DIM) == (3, 3)
+    assert mask_blocks(2 * SEQ, HEAD_DIM, block_diffusion=BLOCK) == (3, 3)
 
 
 def test_trains_through_build_train_step_and_replicas_stay_equal():

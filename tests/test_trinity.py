@@ -31,10 +31,10 @@ from horovod_tpu.models.transformer import (LAYER_KINDS, Attention,
                                             MixerLayer, SparseExperts)
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              mha_reference)
-from horovod_tpu.ops.attention import window_blocks
+from horovod_tpu.ops.attention import mask_blocks
 from tests.test_hybrid import (both_ways, close, mixer_case, seeded,
                                system_loss, trees_close, with_highest)
-from tests.test_flash_table import check_tables, pallas_calls, seen_pairs
+from tests.test_flash_table import check_tables, pallas_calls
 from tests.test_ops import _pallas_call_names
 
 RTOL = 2e-5
@@ -146,7 +146,7 @@ def test_a_ragged_length_takes_the_blockwise_path():
                                       (0, 1, 2)))(q, k, v)
     assert _pallas_call_names(program.jaxpr) == []
     close(banded(q, k, v), masked_softmax(q, k, v, 50))
-    assert window_blocks(200, 50, 64) is None
+    assert mask_blocks(200, 64, causal=True, window=50) is None
 
 
 @pytest.mark.parametrize("window,causal", [(0, True), (-3, True), (64, False)])
@@ -183,13 +183,12 @@ def test_the_kernels_visit_exactly_the_bands_blocks(seq, window, block_q,
     are the block pairs that touch the band, once each and no other, so a key
     (or query) block is never fetched for a pair outside the band; a row's
     steps are contiguous with `first` and `last` set once; and the count is
-    what `window_blocks` and the layer's counter report."""
+    what `mask_blocks` and the layer's counter report."""
     want = touching(seq, window, block_q, block_k)
-    assert check_tables(seen_pairs(seq, True, window), block_q, block_k,
-                        True, window) == want
+    assert check_tables(attn.Causal(window), seq, block_q, block_k) == want
     causal = len(touching(seq, seq, block_q, block_k))
-    assert window_blocks(seq, window, 128, block_q=block_q,
-                         block_k=block_k) == (len(want), causal)
+    assert mask_blocks(seq, 128, causal=True, window=window, block_q=block_q,
+                       block_k=block_k) == (len(want), causal)
     # The grids are the bands', not the sequence's.
     shape = jax.ShapeDtypeStruct((1, 2, seq, 128), jnp.bfloat16)
 
@@ -211,10 +210,10 @@ def test_the_kernels_visit_exactly_the_bands_blocks(seq, window, block_q,
 
 
 def test_the_cells_counts():
-    assert window_blocks(8192, 2048, 128) == (21, 36)
-    assert window_blocks(8192, 2048, 128, block_q=512, block_k=512) \
-        == (70, 136)
-    assert window_blocks(8192, 8192, 128) == (36, 36)
+    assert mask_blocks(8192, 128, causal=True, window=2048) == (21, 36)
+    assert mask_blocks(8192, 128, causal=True, window=2048, block_q=512,
+                       block_k=512) == (70, 136)
+    assert mask_blocks(8192, 128, causal=True, window=8192) == (36, 36)
 
 
 @pytest.mark.parametrize("plan", ["combined", "split"])
