@@ -1,9 +1,10 @@
-"""The Kimi-delta linear-attention mixer (KDA: Kimi Linear, arXiv:2510.26692)
-as Ling-3.0's layers run it, told which heads it holds.
+"""The delta-rule linear-attention mixer, one module under either of two
+gates over one rule (``ops/delta_rule.py``), told which heads it holds.
 
-With ``u`` the layer's normed input and H heads of ``head_dim`` channels
-(keys, queries and values alike), ``[q, k, v, a, z, b] = u W_in`` (five of
-width H head_dim, ``b`` of H):
+``gate="channel"`` — Kimi delta attention (KDA: Kimi Linear,
+arXiv:2510.26692) as Ling-3.0's layers run it.  With ``u`` the layer's normed
+input and H heads of ``head_dim`` channels (keys, queries and values alike),
+``[q, k, v, a, z, b] = u W_in`` (five of width H head_dim, ``b`` of H):
 
     q, k, v = silu(conv(q)), silu(conv(k)), silu(conv(v))     causal,
                                       depthwise, ``conv`` taps, no bias
@@ -17,7 +18,23 @@ width H head_dim, ``b`` of H):
                                       ``head_dim`` for every head
     out = y W_out
 
-``head_shard=(i, n)``: this process holds heads ``[i H/n, (i+1) H/n)`` — their
+``gate="head"`` — Gated DeltaNet (arXiv:2412.06464) as Qwen3-Next's layers
+run it: ``heads`` KEY heads and ``value_heads`` value heads of ``head_dim``
+channels, value head j reading the q and k of key head ``j // (value_heads /
+heads)``; ``[q, k, v, z, b, a] = u W_in`` (q, k of heads x head_dim; v, z of
+value_heads x head_dim; ``b``, ``a`` of value_heads); the lines above but
+
+    log alpha = -exp(A_log) softplus(a + dt_bias)    a value HEAD, unbounded
+    S_t = alpha_t S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T k_t)^T
+    y = RMSNorm(o) * silu(z)
+
+the same recurrence with one decay for a head's every channel, which the rule
+computes in its own form (no decay broadcast over channels, ``K K^T`` and ``Q
+K^T`` once a key head).  Its stages run under ``hvd_gdn_*`` scopes where the
+channel gate's run under ``hvd_kda_*``.
+
+``head_shard=(i, n)``: this process holds heads ``[i H/n, (i+1) H/n)`` (key
+and value heads alike) — their
 columns of ``W_in``, their channels of the convolution and of ``dt_bias``,
 their ``A_log``, their rows of ``W_out`` — the local part of a layer that is
 tensor-parallel over ``n`` chips: the norm never crosses a head, so nothing
@@ -27,7 +44,7 @@ but the sum of the ``n`` outputs is exchanged, and that sum is the caller's.
 
 from __future__ import annotations
 
-from typing import Any, NamedTuple, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -39,27 +56,34 @@ from horovod_tpu.models.ssm import (_a_log_init, _dt_bias_init,
 from horovod_tpu.ops.delta_rule import chunked_delta_rule
 
 L2_EPS = 1e-6     # under the root of q's and k's norms
+# gate -> (the scopes' and the sown counter's prefix, the output gate).
+GATES = {"channel": ("kda", nn.sigmoid), "head": ("gdn", nn.silu)}
 
 
 class DeltaConfig(NamedTuple):
-    """Sizes of the whole Kimi-delta mixer of a layer (``TransformerLM(
-    delta=)``): ``heads`` of ``head_dim`` channels, ``conv`` taps, the delta
-    rule's ``chunk``, the gate's ``lower_bound`` on a step's log-decay."""
+    """Sizes of the whole delta mixer of a layer (``TransformerLM(delta=)``):
+    ``heads`` of ``head_dim`` channels, ``conv`` taps, the delta rule's
+    ``chunk``, the channel gate's ``lower_bound`` on a step's log-decay, and
+    ``value_heads`` where they outnumber the (key) ``heads`` — a ``"delta"``
+    layer's (the channel gate) or a ``"gated_delta"`` layer's (the head
+    gate)."""
 
     heads: int
     head_dim: int
     conv: int = 4
     chunk: int = 64
     lower_bound: float = -5.0
+    value_heads: Optional[int] = None
 
 
 class DeltaMixer(nn.Module):
     """One mixer's share (module docstring), each stage under a
-    ``jax.named_scope`` a trace can read: ``hvd_kda_in_proj``,
-    ``hvd_kda_conv`` (with the silu and the two norms), ``hvd_kda_gate``,
-    ``hvd_kda_scan`` (by stage beneath it: ``ops/delta_rule.py``),
-    ``hvd_kda_gate_norm``, ``hvd_kda_out_proj``.  Writes
-    ``kda_chunk_log_decay_min`` to the ``intermediates`` collection where the
+    ``jax.named_scope`` a trace can read, ``hvd_kda_`` (``gate="channel"``) or
+    ``hvd_gdn_`` (``gate="head"``) and then: ``in_proj``,
+    ``conv`` (with the silu and the two norms), ``gate``,
+    ``scan`` (by stage beneath it: ``ops/delta_rule.py``),
+    ``gate_norm``, ``out_proj``.  Writes ``kda_chunk_log_decay_min`` or
+    ``gdn_chunk_log_decay_min`` to the ``intermediates`` collection where the
     caller makes it mutable."""
 
     heads: int
@@ -67,6 +91,8 @@ class DeltaMixer(nn.Module):
     conv: int = 4
     chunk: int = 64
     lower_bound: float = -5.0
+    value_heads: Optional[int] = None
+    gate: str = "channel"
     head_shard: Tuple[int, int] = (0, 1)
     dtype: Any = jnp.bfloat16
     norm_eps: float = 1e-6
@@ -74,55 +100,87 @@ class DeltaMixer(nn.Module):
     @nn.compact
     def __call__(self, u):
         shard, n_shards = self.head_shard
-        if self.heads % n_shards or not 0 <= shard < n_shards:
+        all_value_heads = self.value_heads or self.heads
+        if self.gate not in GATES:
+            raise ValueError(f"gate {self.gate!r} is none of {tuple(GATES)}")
+        by_head = self.gate == "head"
+        if all_value_heads != self.heads and not by_head:
+            raise ValueError("value_heads beside heads wants gate='head': "
+                             "the channel gate's decay is a key channel's")
+        if self.heads % n_shards or not 0 <= shard < n_shards \
+                or all_value_heads % self.heads:
             raise ValueError(f"head_shard {self.head_shard} does not divide "
-                             f"{self.heads} heads")
-        heads = self.heads // n_shards
+                             f"{self.heads} heads, or they do not divide "
+                             f"{all_value_heads} value heads")
+        heads, value_heads = (self.heads // n_shards,
+                              all_value_heads // n_shards)
+        prefix, out_gate = GATES[self.gate]
         batch, seq, d = u.shape
-        inner = heads * self.head_dim
-        by_head = (batch, seq, heads, self.head_dim)
+        key_inner, inner = heads * self.head_dim, value_heads * self.head_dim
+        mixed = 2 * key_inner + inner                 # q, k, v: what conv sees
+        of_keys = (batch, seq, heads, self.head_dim)
+        of_values = (batch, seq, value_heads, self.head_dim)
+        # The decay's pre-activation: a channel's or a value head's.
+        decays = value_heads if by_head else inner
         w_in = self.param("in_proj_kernel", nn.initializers.lecun_normal(),
-                          (d, 5 * inner + heads), jnp.float32)
+                          (d, mixed + inner + decays + value_heads),
+                          jnp.float32)
         w_conv = self.param(
             "conv_kernel", nn.initializers.lecun_normal(in_axis=0, out_axis=1),
-            (self.conv, 3 * inner), jnp.float32)
-        dt_bias = self.param("dt_bias", _dt_bias_init, (inner,), jnp.float32)
-        a_log = self.param("A_log", _a_log_init, (heads,), jnp.float32)
+            (self.conv, mixed), jnp.float32)
+        dt_bias = self.param("dt_bias", _dt_bias_init, (decays,), jnp.float32)
+        a_log = self.param("A_log", _a_log_init, (value_heads,), jnp.float32)
         scale = self.param("norm_scale", nn.initializers.ones,
                            (self.head_dim,), jnp.float32)
         w_out = self.param("out_proj_kernel", nn.initializers.lecun_normal(),
                            (inner, d), jnp.float32)
 
-        with jax.named_scope("hvd_kda_in_proj"):
-            qkv, a, z, b = jnp.split(
-                jnp.dot(u.astype(self.dtype), w_in.astype(self.dtype)),
-                [3 * inner, 4 * inner, 5 * inner], axis=-1)
-        with jax.named_scope("hvd_kda_conv"):
-            q, k, v = (t.reshape(by_head) for t in jnp.split(
-                nn.silu(causal_depthwise_conv(qkv, w_conv)), 3, axis=-1))
+        def scoped(stage):
+            return jax.named_scope(f"hvd_{prefix}_{stage}")
+
+        with scoped("in_proj"):
+            projected = jnp.dot(u.astype(self.dtype), w_in.astype(self.dtype))
+            if by_head:                               # [q, k, v | z | b | a]
+                qkv, z, b, a = jnp.split(
+                    projected, [mixed, mixed + inner,
+                                mixed + inner + value_heads], axis=-1)
+            else:                                     # [q, k, v | a | z | b]
+                qkv, a, z, b = jnp.split(
+                    projected, [mixed, mixed + inner, mixed + 2 * inner],
+                    axis=-1)
+        with scoped("conv"):
+            q, k, v = jnp.split(nn.silu(causal_depthwise_conv(qkv, w_conv)),
+                                [key_inner, 2 * key_inner], axis=-1)
+            q, k, v = q.reshape(of_keys), k.reshape(of_keys), \
+                v.reshape(of_values)
 
             def unit(t):
                 return t * lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
                                      + L2_EPS)
 
             q, k = unit(q) * self.head_dim ** -0.5, unit(k)
-        with jax.named_scope("hvd_kda_gate"):
-            log_alpha = self.lower_bound * nn.sigmoid(
-                jnp.exp(a_log)[:, None] * (a.astype(jnp.float32)
-                                           + dt_bias).reshape(by_head))
+        with scoped("gate"):
+            pre = a.astype(jnp.float32) + dt_bias
+            if by_head:
+                log_alpha = -jnp.exp(a_log) * nn.softplus(pre)
+            else:
+                log_alpha = self.lower_bound * nn.sigmoid(
+                    jnp.exp(a_log)[:, None] * pre.reshape(of_values))
             beta = nn.sigmoid(b.astype(jnp.float32))
-        with jax.named_scope("hvd_kda_scan"):
+        with scoped("scan"):
             # The casts are the first of the rule's products inside a chunk:
             # every operation under this scope lies in one of its stages.
-            with jax.named_scope("hvd_kda_scan_chunk"):
+            with scoped("scan_chunk"):
                 q, k, v = (t.astype(self.dtype) for t in (q, k, v))
-            o, decay_min = chunked_delta_rule(q, k, v, log_alpha, beta,
-                                              min(self.chunk, seq))
-            self.sow("intermediates", "kda_chunk_log_decay_min", decay_min)
-        with jax.named_scope("hvd_kda_gate_norm"):
+            o, decay_min = chunked_delta_rule(
+                q, k, v, log_alpha, beta, min(self.chunk, seq),
+                scope=f"hvd_{prefix}_scan")
+            self.sow("intermediates", f"{prefix}_chunk_log_decay_min",
+                     decay_min)
+        with scoped("gate_norm"):
             mean_sq = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
             gated = o * lax.rsqrt(mean_sq + self.norm_eps) * scale \
-                * nn.sigmoid(z.astype(jnp.float32).reshape(by_head))
-        with jax.named_scope("hvd_kda_out_proj"):
+                * out_gate(z.astype(jnp.float32).reshape(of_values))
+        with scoped("out_proj"):
             return jnp.dot(gated.reshape(batch, seq, inner).astype(self.dtype),
                            w_out.astype(self.dtype))

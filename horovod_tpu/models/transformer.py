@@ -135,16 +135,23 @@ def _pair_swap(d: int, dtype):
     return jnp.asarray(swap, dtype)
 
 
-def _turned(x, positions, base, seq_dim, back: bool):
+def _turned(x, positions, base, seq_dim, rotary_dim, back: bool):
     """``x``'s adjacent pairs turned by their angles (``back``: by the
     negative angles), float32 inside, rounded once; every operand keeps
-    ``x``'s last axis."""
+    ``x``'s last axis.  With ``rotary_dim`` only the first ``rotary_dim``
+    channels turn, at the frequencies of a head that wide; the others pass
+    (an angle of zero)."""
     d = x.shape[-1]
-    if d % 2:
+    turning = d if rotary_dim is None else rotary_dim
+    if d % 2 or turning % 2 or not 0 < turning <= d:
         raise ValueError(f"rope turns adjacent pairs: the last axis ({d}) "
-                         "must be even")
+                         f"and rotary_dim ({rotary_dim}) must be even, "
+                         "rotary_dim within the axis")
     # (d,): each frequency written twice, beside the pair it turns.
-    freqs = base ** (-(jnp.arange(d) // 2).astype(jnp.float32) / (d // 2))
+    freqs = base ** (-(jnp.arange(d) // 2).astype(jnp.float32)
+                     / (turning // 2))
+    if rotary_dim is not None:
+        freqs = jnp.where(jnp.arange(d) < turning, freqs, 0.0)
     angles = positions[..., None].astype(jnp.float32) * freqs
     shape = [1] * x.ndim
     if positions.ndim == 2:  # per-batch-row offsets (decode mode)
@@ -159,8 +166,9 @@ def _turned(x, positions, base, seq_dim, back: bool):
     return (x.astype(jnp.float32) * cos + swapped * sin).astype(x.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def rope(x, positions, base: float = 10000.0, seq_dim: int = -2):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def rope(x, positions, base: float = 10000.0, seq_dim: int = -2,
+         rotary_dim: Optional[int] = None):
     """Rotary position embedding, ADJACENT-pair formulation: component
     pairs ``(x[2i], x[2i+1])`` rotate by the i-th frequency, in float32,
     rounded once to ``x.dtype``.  (The [even half | odd half] pairing is the
@@ -185,16 +193,22 @@ def rope(x, positions, base: float = 10000.0, seq_dim: int = -2):
     batch row sits at a different offset (the serving plane's continuous
     decode batch, where slot b's next token lives at its own cache
     length).  ``seq_dim`` names the sequence axis of ``x`` (-2 for
-    (b, h, s, d), 1 for (b, s, h, d))."""
-    return _turned(x, positions, base, seq_dim, back=False)
+    (b, h, s, d), 1 for (b, s, h, d)).  ``rotary_dim``: a partial rotation —
+    the first ``rotary_dim`` channels of the last axis turn as a head of that
+    width would, the rest pass unchanged; the tensor keeps its whole last axis
+    all the same (the channels that pass meet a cosine of one and a sine of
+    zero), so nothing is sliced or joined.  ``None``: all of it."""
+    return _turned(x, positions, base, seq_dim, rotary_dim, back=False)
 
 
-def _rope_fwd(x, positions, base, seq_dim):
-    return _turned(x, positions, base, seq_dim, back=False), positions
+def _rope_fwd(x, positions, base, seq_dim, rotary_dim):
+    return _turned(x, positions, base, seq_dim, rotary_dim,
+                   back=False), positions
 
 
-def _rope_bwd(base, seq_dim, positions, d_out):
-    return _turned(d_out, positions, base, seq_dim, back=True), None
+def _rope_bwd(base, seq_dim, rotary_dim, positions, d_out):
+    return _turned(d_out, positions, base, seq_dim, rotary_dim,
+                   back=True), None
 
 
 rope.defvjp(_rope_fwd, _rope_bwd)
@@ -297,7 +311,11 @@ class MoEConfig(NamedTuple):
       largest ``score + bias``, the ``topk_group`` best groups stay, and the
       ``experts_per_token`` largest ``score + bias`` among THEIR experts are
       chosen (DeepSeek-V3's group-limited choice, arXiv:2412.19437).  One
-      group: a plain top-k, the program above."""
+      group: a plain top-k, the program above.
+    * ``shared_output_gate`` (with ``shared_width``): the shared expert's
+      OUTPUT times ``sigmoid(x w_sg)``, one scalar a token from a ``d x 1``
+      projection of the layer's input (Qwen3-Next; ``shared_gate`` is already
+      the name of the shared expert's own SwiGLU gate projection)."""
 
     num_experts: int
     experts_per_token: int
@@ -312,6 +330,7 @@ class MoEConfig(NamedTuple):
     shared_width: Optional[int] = None
     n_group: int = 1
     topk_group: int = 1
+    shared_output_gate: bool = False
 
     def buffer_rows(self, tokens: int) -> int:
         """Rows of the sorted buffer for ``tokens`` tokens."""
@@ -380,6 +399,9 @@ class SparseExperts(nn.Module):
         if cfg.scoring not in ("softmax", "sigmoid") \
                 or cfg.expert_act not in ("gated_silu", "relu2"):
             raise ValueError(f"unknown scoring or expert_act in {cfg}")
+        if cfg.shared_output_gate and not cfg.shared_width:
+            raise ValueError("shared_output_gate gates a shared expert: it "
+                             "wants shared_width")
         if cfg.n_group > 1 and (cfg.scoring != "sigmoid"
                                 or cfg.num_experts % cfg.n_group
                                 or not 0 < cfg.topk_group <= cfg.n_group):
@@ -475,8 +497,17 @@ class SparseExperts(nn.Module):
 
                 shared = act(wide("shared_gate")) * wide("shared_up") \
                     if gated else act(wide("shared_up"))
-                mixed = mixed + nn.Dense(d, use_bias=False, dtype=self.dtype,
-                                         name="shared_down")(shared)
+                shared = nn.Dense(d, use_bias=False, dtype=self.dtype,
+                                  name="shared_down")(shared)
+                if cfg.shared_output_gate:
+                    w_sg = self.param(
+                        "shared_output_gate_kernel",
+                        nn.initializers.lecun_normal(), (d, 1), jnp.float32)
+                    shared = (shared * nn.sigmoid(jnp.dot(
+                        flat, w_sg.astype(self.dtype),
+                        preferred_element_type=jnp.float32))
+                              ).astype(self.dtype)
+                mixed = mixed + shared
         return mixed.reshape(x.shape)
 
 
@@ -514,6 +545,10 @@ class Attention(nn.Module):
     rope: bool = True
     # The rotary base (:func:`rope`'s).
     rope_theta: float = 10000.0
+    # A partial rotation (:func:`rope`'s ``rotary_dim``): the first
+    # ``rotary_dim`` channels of a head turn, the rest pass.  ``None``: the
+    # whole head.  Training only: no ring, no cached decode.
+    rotary_dim: Optional[int] = None
     # ``(i, n)``: this process holds query heads ``[i H/n, (i+1) H/n)`` and
     # the key/value heads they read (a key/value head that several shards'
     # queries read is held by each of them) — the local part of a layer that
@@ -589,10 +624,12 @@ class Attention(nn.Module):
         b, s, d = x.shape
         head_dim = self.head_dim or d // self.n_heads
         n_heads = self.n_heads // self.head_shard[1]
-        if (self.window is not None or self.block_diffusion is not None) \
+        if (self.window is not None or self.block_diffusion is not None
+                or self.rotary_dim is not None) \
                 and (decode_ctx is not None or self.seq_axis is not None):
-            raise ValueError("window= and block_diffusion= compose with "
-                             "neither decode_ctx= nor sequence parallelism")
+            raise ValueError("window=, block_diffusion= and rotary_dim= "
+                             "compose with neither decode_ctx= nor sequence "
+                             "parallelism")
         if self.block_diffusion is not None and (self.window is not None
                                                  or s % 2):
             raise ValueError("block_diffusion= is a mask of its own over an "
@@ -612,7 +649,8 @@ class Attention(nn.Module):
             if not self.rope:
                 return t
             with jax.named_scope("hvd_attn_rotate"):
-                return rope(t, positions, self.rope_theta)
+                return rope(t, positions, self.rope_theta, -2,
+                            self.rotary_dim)
 
         grouped = self.n_kv_heads is not None or self.head_shard != (0, 1)
         with jax.named_scope("hvd_attn_qkv"):
@@ -901,7 +939,8 @@ LAYER_KINDS = {"ssm": "Mamba2Mixer", "attention": "Attention",
                "experts": "SparseExperts", "delta": "DeltaMixer",
                "latent_attention": "LatentAttention", "gated_mlp": "GatedMLP",
                "window_attention": "Attention",
-               "blockdiff_attention": "Attention"}
+               "blockdiff_attention": "Attention",
+               "gated_delta": "DeltaMixer"}
 
 
 class MixerLayer(nn.Module):
@@ -919,8 +958,10 @@ class MixerLayer(nn.Module):
     rotates where ``rope`` says: one pattern holds rotated windowed layers
     and unrotated full ones.  ``"blockdiff_attention"`` is :class:`Attention`
     under the model's ``block_diffusion`` mask over ``[clean; noised]`` rows,
-    rotated where ``rope`` says.  All three take ``head_dim``, ``head_norm``
-    and ``attn_gate``.""")
+    rotated where ``rope`` says.  All three take ``head_dim``, ``head_norm``,
+    ``attn_gate`` and ``rotary_dim``.  ``"delta"`` is :class:`DeltaMixer`
+    under its channel gate, ``"gated_delta"`` under its head gate over grouped
+    heads, both at ``delta``'s sizes.""")
 
     kind: str
     n_heads: int
@@ -943,6 +984,7 @@ class MixerLayer(nn.Module):
     post_norm: bool = False
     block_diffusion: Optional[int] = None
     rope_theta: float = 10000.0
+    rotary_dim: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
@@ -967,6 +1009,7 @@ class MixerLayer(nn.Module):
                               n_kv_heads=self.n_kv_heads,
                               rope=self.rope or windowed,
                               rope_theta=self.rope_theta,
+                              rotary_dim=self.rotary_dim,
                               head_shard=self.head_shard,
                               head_dim=self.head_dim,
                               window=self.window if windowed else None,
@@ -976,8 +1019,10 @@ class MixerLayer(nn.Module):
                               name="mixer")
         elif self.kind == "experts":
             mixer = SparseExperts(self.moe, self.dtype, name="mixer")
-        elif self.kind == "delta":
-            mixer = DeltaMixer(*self.delta, head_shard=self.head_shard,
+        elif self.kind in ("delta", "gated_delta"):
+            mixer = DeltaMixer(*self.delta,
+                               gate="head" if self.kind == "gated_delta"
+                               else "channel", head_shard=self.head_shard,
                                dtype=self.dtype, norm_eps=self.norm_eps,
                                name="mixer")
         elif self.kind == "latent_attention":
@@ -1036,12 +1081,15 @@ class TransformerLM(nn.Module):
     # norm and one residual (:class:`MixerLayer`) — ``"ssm"`` a Mamba-2 mixer
     # of ``ssm``'s sizes, ``"attention"``, ``"experts"`` the sparse experts of
     # ``moe``, ``"delta"`` a Kimi-delta mixer of ``delta``'s sizes,
+    # ``"gated_delta"`` a Gated DeltaNet mixer of ``delta``'s sizes (its
+    # ``value_heads`` over ``heads`` key heads),
     # ``"latent_attention"`` of ``latent``'s, ``"gated_mlp"`` a dense MLP of
     # ``d_ff``, ``"window_attention"`` attention under the sliding ``window``,
     # always rotated.  ``n_kv_heads`` and ``rope`` are the ``"attention"``
     # layers' as :class:`Attention` has them (so ``rope=False`` with a
     # ``window`` is full layers without rotation among rotated windowed
-    # ones), ``rope_theta`` every rotated pattern layer's rotary base,
+    # ones), ``rope_theta`` every rotated pattern layer's rotary base and
+    # ``rotary_dim`` how much of a head they turn,
     # ``head_dim``, ``head_norm`` and ``attn_gate`` both attention
     # kinds' (:class:`Attention`'s ``head_dim``, ``head_norm``, ``gate``),
     # ``head_shard`` every head-carrying mixer's, ``post_norm`` every
@@ -1071,6 +1119,7 @@ class TransformerLM(nn.Module):
     embed_scale: Optional[float] = None
     block_diffusion: Optional[int] = None
     rope_theta: float = 10000.0
+    rotary_dim: Optional[int] = None
 
     @nn.compact
     def __call__(self, tokens, targets=None, decode_ctx=None, noised=None):
@@ -1113,7 +1162,8 @@ class TransformerLM(nn.Module):
                            self.delta, self.latent, d_ff, self.head_dim,
                            self.window, self.head_norm, self.attn_gate,
                            self.post_norm, self.block_diffusion,
-                           self.rope_theta, name=f"layer_{i}")(x)
+                           self.rope_theta, self.rotary_dim,
+                           name=f"layer_{i}")(x)
         for i in range(0 if self.layers is not None else self.n_layers):
             block = Block(self.n_heads, d_ff, self.dtype, self.seq_axis,
                           self.use_flash, self.ring_impl, self.capture_kv,

@@ -1,5 +1,8 @@
-"""The gated delta rule of a Kimi-delta linear-attention layer (KDA, Kimi
-Linear, arXiv:2510.26692) as matrix products over chunks.
+"""The gated delta rule of a linear-attention layer as matrix products over
+chunks, in two forms that share the solve and the recurrence: a decay a
+CHANNEL of the key (Kimi delta attention, KDA: Kimi Linear, arXiv:2510.26692)
+and a decay a HEAD over grouped heads (Gated DeltaNet, arXiv:2412.06464; below,
+"A decay a head").
 
 Per head, with a state ``S`` of ``d_k x d_v``, a decay ``alpha_t`` a CHANNEL of
 the key (``log_alpha_t <= 0``, ``d_k`` of them) and a step ``beta_t``:
@@ -52,8 +55,39 @@ Every log of a product of decays is a sum of log-decays (within a sub-block,
 to its end, over whole sub-blocks between), never a difference of two
 cumulative sums: those reach -320 in a chunk, where float32's spacing is 3e-5.
 
+A decay a head (``log_alpha`` of (batch, seq, value heads)): ``alpha_t`` is a
+scalar, ``Diag(alpha_t) = alpha_t I``, and the decays leave the products.  With
+``c_t`` the sum of the head's log-decays from the chunk's start through t,
+
+    A[t, s] = beta_t exp(c_t - c_s) (k_t . k_s)              for s < t
+    tril((Q . G) (K / G)^T)[t, s] = exp(c_t - c_s) (q_t . k_s)
+
+so ``K K^T`` and ``Q K^T`` are ONE plain product a chunk each, times a (C, C)
+matrix of exponentials: no sub-blocks, no reference, no ratio.  And the heads
+are grouped: ``q`` and ``k`` have ``key heads``, ``v``, the decay and ``beta``
+``value heads``, value head j reading key head ``j // (value heads / key
+heads)``; the two products depend on the key head alone and are computed once a
+KEY head; the decay matrix, ``beta``, the solve and the recurrence are a value
+head's (the decayed ``Q`` and ``K`` the recurrence reads are a value head's
+too: the decay is).  Every exponent is a SUM of log-decays, never a difference
+of two cumulative sums and never over 0: ``c_t`` a cumulative sum from the
+chunk's start, the decay to the chunk's end a cumulative sum from its end, and
+the (C, C) exponents ``sum_{s < i <= t} log_alpha_i`` a product of the lower
+triangle of ones with the (C, C) matrix that holds ``log_alpha_i`` in row i of
+every column ``s < i``, in full precision.  This gate has no bound, and needs
+none: float32 holds a sum of C terms to ``C 2^-24`` of the largest partial
+sum, so a pair of tokens with nothing but small decays between them has its
+exponent to the size of those decays whatever the rest of the chunk holds
+(at -20 a step a chunk of 64 sums to -1,280: an absolute 1e-4 there, in an
+exponential that is 0 either way), and ``exp`` of a large negative sum
+underflows to 0, which is what crosses.  A length that is no multiple of the
+chunk is padded with tokens that change nothing (log-decay 0, ``beta`` 0, zero
+q, k, v) and cut back.
+
 Four stages, each under a ``jax.named_scope`` of its own beneath the caller's
-(``hvd_kda_scan`` in ``models/delta.py``), so that a device trace tells them
+(``hvd_kda_scan`` or ``hvd_gdn_scan`` in ``models/delta.py``; the stages' names
+are the caller's ``scope`` with ``_decays``, ``_chunk``, ``_solve``,
+``_carry`` behind), so that a device trace tells them
 apart forward and backward; every operation of :func:`chunked_delta_rule` is
 under exactly one:
 
@@ -67,6 +101,9 @@ under exactly one:
 * ``hvd_kda_scan_carry`` — :func:`_carry`: the two ``while``s and their
   bodies, the products over all chunks around them, and the move of ``o``
   back to tokens.
+
+:func:`lowered_plan` says what a call contributes to a compiled training
+step (its loops and kernels), as ``ops.attention._bwd_plan`` says of flash.
 
 Float32: the summed log-decays, their exponentials, ``T`` (by forward
 substitution, exact products), ``W``, ``U0``, ``U`` and the state between
@@ -185,6 +222,8 @@ def _rounded_states_and_u(w, u0, entered):
 def _carry(w, u0, q_in, qk, k_end, carried):
     """``O`` of every chunk, (b, n, h, C, d_v) float32, from the chunks' own
     operands (module docstring), each (b, n, h, ...), from a zero state.
+    ``carried``, the decay over a whole chunk, is (b, n, h, d_k) a channel or
+    (b, n, h, 1) a head.
 
     Only the state is carried from chunk to chunk (:func:`_entered_states`);
     ``O`` is two products over all chunks at once from the states so found.
@@ -231,23 +270,128 @@ def _carry_bwd(kept, d_o):
                       **_WIDE)
     d_k_end = jnp.einsum("bnhtv,bnhcv->bnhtc", rounded, d_left.astype(dtype),
                          **_WIDE)
+    d_carried = (d_left * entered).sum(axis=-1)
+    if carried.shape[-1] == 1:          # a decay a head: one for all channels
+        d_carried = d_carried.sum(axis=-1, keepdims=True)
     return (d_w.astype(dtype), d_u, d_q_in.astype(dtype), d_qk.astype(dtype),
-            d_k_end.astype(dtype), (d_left * entered).sum(axis=-1))
+            d_k_end.astype(dtype), d_carried)
 
 
 _carry.defvjp(_carry_fwd, _carry_bwd)
 
 
-def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int):
+def lowered_plan(seq: int, chunk: int) -> dict:
+    """What one call of :func:`chunked_delta_rule` at this length, forward and
+    backward together, adds to a compiled step, in either form: the
+    recurrence between chunks is a ``while`` forward and one backward (the
+    compiler unrolls a loop of one step, so a single chunk has none), and no
+    kernel of ours."""
+    return {"while": 2 if -(-seq // chunk) > 1 else 0, "tpu_custom_call": 0}
+
+
+def _solved_and_carried(a, beta, k, from_start, v, q_in, qk, k_end, carried,
+                        seq: int, scope: str):
+    """The two stages both forms share, from a chunk's ``A`` (strictly
+    lower), ``beta``, ``K``, ``G`` and ``V`` (float32, each (b, n, h, C, ...)
+    or broadcasting to it) and :func:`_carry`'s other operands: ``o`` (batch,
+    seq, heads, d_v)."""
+    dtype = q_in.dtype
+    exact = dict(precision="highest", **_WIDE)
+    with jax.named_scope(f"{scope}_solve"):
+        solve = _unit_lower_inverse(a)
+        w = jnp.einsum("bnhts,bnhsc->bnhtc", solve, beta * k * from_start,
+                       **exact).astype(dtype)
+        u0 = jnp.einsum("bnhts,bnhsv->bnhtv", solve, beta * v, **exact)
+    with jax.named_scope(f"{scope}_carry"):
+        o = _carry(w, u0, q_in, qk, k_end, carried)
+        # (b, n, h, C, d_v) -> (b, seq, h, d_v)
+        batch, _, heads = o.shape[:3]
+        return jnp.moveaxis(o, 3, 2).reshape(batch, -1, heads,
+                                             o.shape[-1])[:, :seq]
+
+
+def _head_decay_rule(q, k, v, log_alpha, beta, chunk: int, scope: str):
+    """:func:`chunked_delta_rule` with a decay a head over grouped heads
+    (module docstring, "A decay a head")."""
+    batch, seq, key_heads, d_k = q.shape
+    heads = v.shape[2]
+    sub = min(SUB_BLOCK, chunk)
+    if heads % key_heads or chunk % sub \
+            or log_alpha.shape != (batch, seq, heads):
+        raise ValueError(
+            f"chunked_delta_rule: {key_heads} key heads do not divide {heads} "
+            f"value heads, the chunk {chunk} is no multiple of {sub}, or the "
+            f"decay {log_alpha.shape} is not one a value head")
+    per_key = heads // key_heads
+    f32, dtype = jnp.float32, q.dtype
+    tail = -seq % chunk
+    chunks = (seq + tail) // chunk
+
+    def by_chunk(t):                    # (b, seq, h, ...) -> (b, n, h, C, ...)
+        if tail:
+            t = jnp.pad(t, [(0, 0), (0, tail)] + [(0, 0)] * (t.ndim - 2))
+        return jnp.moveaxis(
+            t.reshape(batch, chunks, chunk, *t.shape[2:]), 2, 3)
+
+    def of_value_heads(t):              # a key head's, for each of its heads
+        return jnp.broadcast_to(
+            t[:, :, :, None], (batch, chunks, key_heads, per_key)
+            + t.shape[3:]).reshape(batch, chunks, heads, *t.shape[3:])
+
+    with jax.named_scope(f"{scope}_chunk"):
+        qc, kc = by_chunk(q), by_chunk(k)
+        vc, bc = by_chunk(v).astype(f32), by_chunk(beta.astype(f32))[..., None]
+
+    with jax.named_scope(f"{scope}_decays"):
+        at = jnp.arange(chunk)
+        earlier, lower = at[:, None] > at[None, :], at[:, None] >= at[None, :]
+        steps = by_chunk(log_alpha.astype(f32))              # (b, n, h, C)
+        within = jnp.cumsum(steps, axis=-1)
+        whole = within[..., -1]
+        later = jnp.concatenate(
+            [steps[..., 1:], jnp.zeros_like(steps[..., :1])], -1)
+        to_end = lax.cumsum(later, axis=later.ndim - 1, reverse=True)
+        # between[t, s] = sum of steps[i] over s < i <= t (0 where s >= t).
+        between = jnp.einsum(
+            "ti,bnhis->bnhts", lower.astype(f32),
+            jnp.where(earlier, steps[..., :, None], 0.0), precision="highest")
+        decay = jnp.where(lower, jnp.exp(between), 0.0)      # (b, n, h, C, C)
+        # (b, n, h, C, 1): a token's decay, for every channel of its head.
+        from_start = jnp.exp(within)[..., None]
+        end_decay = jnp.exp(to_end)[..., None]
+        carried, decay_min = jnp.exp(whole)[..., None], whole.min()
+
+    with jax.named_scope(f"{scope}_chunk"):
+        kk = of_value_heads(jnp.einsum("bngtc,bngsc->bngts", kc, kc, **_WIDE))
+        qk = of_value_heads(jnp.einsum("bngtc,bngsc->bngts", qc, kc, **_WIDE))
+        a = jnp.where(earlier, bc * decay * kk, 0.0)
+        qk = (decay * qk).astype(dtype)
+        wide_k = of_value_heads(kc.astype(f32))
+        q_in = (of_value_heads(qc.astype(f32)) * from_start).astype(dtype)
+        k_end = (wide_k * end_decay).astype(dtype)
+    return _solved_and_carried(a, bc, wide_k, from_start, vc, q_in, qk, k_end,
+                               carried, seq, scope), decay_min
+
+
+def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int,
+                       scope: str = "hvd_kda_scan"):
     """``o`` of the recurrence above for every token, from a zero state.
 
-    ``q``, ``k`` (batch, seq, heads, d_k); ``v`` (batch, seq, heads, d_v);
-    ``log_alpha`` (batch, seq, heads, d_k) float32, in (-5.8, 0];
-    ``beta`` (batch, seq, heads) float32.  ``seq`` is a multiple of ``chunk``,
-    ``chunk`` of :data:`SUB_BLOCK` where it is longer.  Returns ``(o,
-    chunk_log_decay_min)``: ``o`` float32 (batch, seq, heads, d_v), and the
-    most negative summed log-decay of any chunk, head and channel — where
+    A decay a channel: ``q``, ``k`` (batch, seq, heads, d_k); ``v`` (batch,
+    seq, heads, d_v); ``log_alpha`` (batch, seq, heads, d_k) float32, in
+    (-5.8, 0]; ``beta`` (batch, seq, heads) float32.  ``seq`` is a multiple of
+    ``chunk``, ``chunk`` of :data:`SUB_BLOCK` where it is longer.
+
+    A decay a head: ``q``, ``k`` (batch, seq, key heads, d_k); ``v`` (batch,
+    seq, value heads, d_v); ``log_alpha`` and ``beta`` (batch, seq, value
+    heads) float32, ``log_alpha <= 0`` without a bound; any ``seq``.
+
+    ``scope``: the stages' names start with it.  Returns ``(o,
+    chunk_log_decay_min)``: ``o`` float32 (batch, seq, value heads, d_v), and
+    the most negative summed log-decay of any chunk, head and channel — where
     ``exp`` of it underflows, nothing crosses that chunk in that channel."""
+    if log_alpha.ndim == 3:
+        return _head_decay_rule(q, k, v, log_alpha, beta, chunk, scope)
     batch, seq, heads, d_k = q.shape
     sub = min(SUB_BLOCK, chunk)
     if seq % chunk or chunk % sub:
@@ -255,17 +399,16 @@ def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int):
                          f"of chunk {chunk}, or the chunk of {sub}")
     chunks, blocks = seq // chunk, chunk // sub
     f32, dtype = jnp.float32, q.dtype
-    exact = dict(precision="highest", **_WIDE)
 
     def by_chunk(t):                    # (b, seq, h, ...) -> (b, n, h, C, ...)
         return jnp.moveaxis(
             t.reshape(batch, chunks, chunk, heads, *t.shape[3:]), 3, 2)
 
-    with jax.named_scope("hvd_kda_scan_chunk"):
+    with jax.named_scope(f"{scope}_chunk"):
         qc, kc = by_chunk(q).astype(f32), by_chunk(k).astype(f32)
         vc, bc = by_chunk(v).astype(f32), by_chunk(beta.astype(f32))[..., None]
 
-    with jax.named_scope("hvd_kda_scan_decays"):
+    with jax.named_scope(f"{scope}_decays"):
         # Sums of log-decays (module docstring): (b, n, h, blocks, sub, d_k)
         # from here on; i, j, m index sub-blocks.
         steps = by_chunk(log_alpha.astype(f32)).reshape(
@@ -323,7 +466,7 @@ def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int):
             [(0, 0)] * 4 + [(0, chunk - (i + 1) * sub)])
             for i in range(blocks)], axis=-2)
 
-    with jax.named_scope("hvd_kda_scan_chunk"):
+    with jax.named_scope(f"{scope}_chunk"):
         k_col = [(kc[..., :(i + 1) * sub, :] * from_ref[i]).astype(dtype)
                  for i in range(blocks)]
         lower = jnp.tril(jnp.ones((chunk, chunk), bool))
@@ -332,12 +475,5 @@ def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int):
         qk = jnp.where(lower, against_earlier(qc), 0.0).astype(dtype)
         q_in = (qc * decayed).astype(dtype)
         k_end = (kc * end_decay).astype(dtype)
-    with jax.named_scope("hvd_kda_scan_solve"):
-        solve = _unit_lower_inverse(a)
-        w = jnp.einsum("bnhts,bnhsc->bnhtc", solve, bc * kc * decayed,
-                       **exact).astype(dtype)
-        u0 = jnp.einsum("bnhts,bnhsv->bnhtv", solve, bc * vc, **exact)
-    with jax.named_scope("hvd_kda_scan_carry"):
-        o = _carry(w, u0, q_in, qk, k_end, carried)
-        # (b, n, h, C, d_v) -> (b, seq, h, d_v)
-        return jnp.moveaxis(o, 3, 2).reshape(batch, seq, heads, -1), decay_min
+    return _solved_and_carried(a, bc, kc, decayed, vc, q_in, qk, k_end,
+                               carried, seq, scope), decay_min

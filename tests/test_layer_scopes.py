@@ -250,3 +250,64 @@ def test_block_diffusion_step_names_its_loss_and_its_kernels(monkeypatch):
         assert not _sdar.BLOCKDIFF[direction].match(
             name.replace("_blockdiff", "_window") + ".7")
         assert _layers.column_of(f"{name}.7|custom-call|x", None) == "flash"
+
+
+GDN_SCOPES = ("in_proj", "conv", "gate", "scan", "gate_norm", "out_proj")
+_GDN = re.compile(r"(?:^|/)hvd_gdn_(%s)(?=/|$)" % "|".join(GDN_SCOPES))
+_GDN_STAGE = re.compile(r"(?:^|/)hvd_gdn_scan_(%s)(?=/|$)"
+                        % "|".join(_layers.STAGES))
+
+
+def test_gated_delta_layers_name_their_stages_under_scopes_of_their_own():
+    """The Qwen3-Next cell's step: every heavy operation of a `gated_delta`
+    layer lies under exactly ONE of the six `hvd_gdn_*` scopes, forward and
+    backward, and under no `hvd_kda_*` one (those stay Ling's); the delta
+    rule's four stages partition `hvd_gdn_scan`, down to its casts; every other
+    heavy operation of the loss lies under one family of `_layers.SCOPES`, as
+    in the other cells.  `benchmark/layer_metrics/_layers.py`'s table has no
+    `gdn_` row, and this PR may not edit that file: `model_unscoped_pct`
+    would file the mixers under `unscoped`, so the new cell is not on that
+    metric's list (PERF.md section 7 names the edit)."""
+    text = lowered_step("qwen3next80b_1chip_ep16share_1x4k").as_text(
+        debug_info=True)
+    inside = [(op, path) for op, path in heavy_operations(text)
+              if FORWARD in path or BACKWARD in path]
+    seen = {direction: set() for direction in (FORWARD, BACKWARD)}
+    stages = {direction: set() for direction in (FORWARD, BACKWARD)}
+    families = set()
+    for op, path in inside:
+        direction = BACKWARD if BACKWARD in path else FORWARD
+        named = set(_GDN.findall(path))
+        assert "hvd_kda_" not in path, path
+        if not named:
+            assert len(families_in(path)) == 1, (op, path)
+            families |= families_in(path)
+            continue
+        assert len(named) == 1 and not families_in(path), (
+            f"{op} at {path} lies under {sorted(named)} of the mixer's "
+            f"scopes and {sorted(families_in(path))} of another layer's")
+        seen[direction] |= named
+        if named == {"scan"}:
+            stage = set(_GDN_STAGE.findall(path))
+            assert len(stage) == 1, (op, path)
+            stages[direction] |= stage
+    assert families == {"head", "embed", "attn_proj", "moe"}
+    # The products: both projections, the rule's; the gate and the norm are
+    # elementwise and hold none.
+    for direction in (FORWARD, BACKWARD):
+        assert seen[direction] >= {"in_proj", "scan", "out_proj"}, direction
+        assert stages[direction] == set(_layers.STAGES), direction
+    paths = scope_paths(text)
+    for direction in (FORWARD, BACKWARD):
+        for scope in GDN_SCOPES:
+            assert any(direction in path and f"/hvd_gdn_{scope}/" in path
+                       for path in paths), (direction, scope)
+    under = [path for path in paths if "/hvd_gdn_scan/" in path]
+    assert len(under) > 50
+    for path in under:
+        assert len(set(_GDN_STAGE.findall(path))) == 1, path
+    # In a `gated_delta` layer nothing but the layer's norm and its residual
+    # add lies outside the mixer's scopes.
+    for path in paths:
+        if "/layer_0/" in path and "/mixer/" in path:
+            assert _GDN.search(path), path

@@ -285,6 +285,22 @@ def test_flash_two_widths_at_latent_attention_shape_compile(v5e):
     assert [g.shape[-1] for g in compiled.out_info] == [192, 192, 128]
 
 
+def test_flash_head256_at_qwen3next_shape_compiles(v5e):
+    """Gated attention as the Qwen3-Next cell runs it — 1 x 16 heads of 256 at
+    4,096 rows (a key/value head repeated for its 8 query heads before the
+    kernels) — in the band `_bwd_plan` sends it to: no combined backward past
+    128 lanes, so the split pair at 1,024-blocks, which with the forward
+    compiles for the described chip (PR 46: the first cell past a head of
+    128)."""
+    from horovod_tpu.ops.attention import _bwd_plan
+
+    assert _bwd_plan(4096, 256, 1024, 1024, 16) == ("split", 1024, 1024)
+    text = _compile_flash_grad(v5e[0], (1, 16, 4096, 256))
+    assert text.count('"tpu_custom_call"') == 3
+    for name in ("hvd_flash_fwd", "hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"):
+        assert name in text
+
+
 def test_grouped_matmul_lowers_to_libtpu_kernels(v5e):
     """ops.moe.grouped_matmul at the sparse-expert cell's shapes — 24,576
     rows of 2,048 against 16 experts of 1,024 — forward and both gradients:

@@ -1,0 +1,536 @@
+"""What Qwen3-Next adds to models.TransformerLM (Gated DeltaNet mixers on a
+delta rule with a decay a head over grouped heads, a partial rotation in
+attention, a gate on the shared expert's output) against the plain float32
+reference the benchmark keeps (benchmark/reference/qwen3next_lm.py): the delta
+rule one step a token with q and k repeated for their value heads, a plain
+softmax over whole rows, a loop over the shard's experts.  CPU, float32,
+seeded weights, small sizes.
+
+Tolerances: both sides are float32 and differ in the order of their sums
+(products over chunks and a solve against a step a token, grouped rows against
+masked whole batches), so they agree to float32 rounding accumulated over a
+few layers: 2e-5 of the largest value (the chunked delta rule, whose solve
+multiplies 64 x 64 matrices, and what holds it, 1e-4).  bfloat16 anywhere
+would read 1e-3 to 1e-2 and fail every case;
+`test_reference_refuses_float8_operands` shows the next precision down is far
+outside them.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+
+from benchmark.reference import qwen3next_lm as reference
+from benchmark.reference.ling_lm import delta_recurrence
+from horovod_tpu.jax.train import build_train_step
+from horovod_tpu.models import (DeltaConfig, DeltaMixer,
+                                MoEConfig, TransformerLM)
+from horovod_tpu.models.transformer import (LAYER_KINDS, Attention,
+                                            SparseExperts, rope)
+from horovod_tpu.ops.delta_rule import chunked_delta_rule, lowered_plan
+from tests.test_hybrid import (both_ways, close, columns, mixer_case, seeded,
+                               system_loss, trees_close, with_highest)
+
+RTOL = 2e-5
+VOCAB, HIDDEN, SEQ = 256, 64, 128
+KEY_HEADS, VALUE_HEADS, LINEAR_DIM = 2, 4, 16
+HEADS, KV_HEADS, HEAD_DIM, ROTARY = 4, 2, 32, 8
+THETA = 1e7
+DELTA = DeltaConfig(heads=KEY_HEADS, head_dim=LINEAR_DIM, conv=4, chunk=32,
+                    value_heads=VALUE_HEADS)
+EXPERTS, PER_TOKEN, WIDTH, SHARED = 16, 4, 48, 40
+# One period: three Gated DeltaNet layers and a gated attention layer, each
+# followed by the experts.
+LAYERS = ("gated_delta", "experts") * 3 + ("attention", "experts")
+
+
+def moe(shard=(0, 1), row_bound=None, experts=EXPERTS, gate=True):
+    return MoEConfig(experts, PER_TOKEN, WIDTH, shard, row_bound,
+                     renormalize=True, shared_width=SHARED,
+                     shared_output_gate=gate)
+
+
+def lm(expert_shard=(0, 1), use_flash=False, chunk=DELTA.chunk):
+    return TransformerLM(
+        vocab_size=VOCAB, d_model=HIDDEN, n_heads=HEADS, dtype=jnp.float32,
+        use_flash=use_flash, norm_eps=1e-6, moe=moe(expert_shard),
+        layers=LAYERS, delta=DELTA._replace(chunk=chunk),
+        n_kv_heads=KV_HEADS, head_dim=HEAD_DIM, head_norm=True,
+        attn_gate=True, rope_theta=THETA, rotary_dim=ROTARY)
+
+
+def reference_config(expert_shard=(0, 1), **more):
+    return dict(layers=LAYERS, head_dim=LINEAR_DIM, rope_theta=THETA,
+                rotary_dim=ROTARY, norm_eps=1e-6, num_experts=EXPERTS,
+                experts_per_token=PER_TOKEN, expert_shard=expert_shard, **more)
+
+
+# --- the delta rule with a decay a head over grouped heads ------------------
+
+def rule_inputs(seed, seq=SEQ, low=-20.0, d_k=16, d_v=8):
+    """Unit keys, queries at d_k^-1/2, a value head's log-decay from 0 down to
+    `low` a step — every seventh token AT `low` in every head, so that at -20
+    a chunk's decay underflows (this gate has no bound) while its neighbours
+    hold decays near one."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    heads = (2, seq, VALUE_HEADS)
+
+    def unit(key):
+        t = jax.random.normal(key, (2, seq, KEY_HEADS, d_k))
+        return t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+
+    q, k = unit(keys[0]) * d_k ** -0.5, unit(keys[1])
+    v = jax.random.normal(keys[2], heads + (d_v,))
+    log_alpha = low * jax.random.uniform(keys[3], heads) ** 3
+    log_alpha = log_alpha.at[:, ::7].set(low)
+    beta = jax.nn.sigmoid(2.0 * jax.random.normal(keys[4], heads))
+    mix = jax.random.normal(keys[5], heads + (d_v,))
+    return (q, k, v, log_alpha, beta), mix
+
+
+def repeated(q, k, v, log_alpha, beta):
+    """The operands as the published code hands them to its rule: q and k
+    repeated for their value heads, and for `delta_recurrence` the decay on
+    every channel."""
+    per_key = v.shape[2] // q.shape[2]
+    q, k = (jnp.repeat(t, per_key, axis=2) for t in (q, k))
+    return q, k, v, jnp.broadcast_to(log_alpha[..., None], q.shape), beta
+
+
+def token_by_token(*operands):
+    return delta_recurrence(*repeated(*operands))
+
+
+# A chunk that divides the sequence and one that does not, log-decays near 0
+# and at -20 a step.
+RULE_CASES = [(128, 64, -20.0), (128, 16, -1e-3), (100, 64, -20.0),
+              (100, 32, -0.5), (72, 16, -1e-3)]
+
+
+@pytest.mark.parametrize("seq,chunk,low", RULE_CASES)
+def test_head_decay_rule_is_the_recurrence(seq, chunk, low):
+    args, _ = rule_inputs(chunk, seq, low)
+    got, decay_min = jax.jit(lambda *a: chunked_delta_rule(
+        *a, chunk, scope="hvd_gdn_scan"))(*args)
+    assert got.shape == args[2].shape
+    close(got, jax.jit(token_by_token)(*args), 1e-4)
+    padded = jnp.pad(args[3], ((0, 0), (0, -seq % chunk), (0, 0)))
+    close(decay_min, padded.reshape(2, -1, chunk, VALUE_HEADS).sum(2).min())
+
+
+@pytest.mark.parametrize("seq,chunk,low", RULE_CASES)
+def test_head_decay_rule_gradients_are_the_recurrences(seq, chunk, low):
+    args, mix = rule_inputs(7 + chunk, seq, low)
+
+    def total(fn):
+        return lambda *a: (fn(*a) * mix).sum()
+
+    got = jax.jit(jax.grad(total(lambda *a: chunked_delta_rule(*a, chunk)[0]),
+                           argnums=range(5)))(*args)
+    want = jax.jit(jax.grad(total(token_by_token), argnums=range(5)))(*args)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and bool(jnp.isfinite(g).all())
+        close(g, w, 1e-4)
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_grouped_heads_are_repeated_heads(chunk):
+    """Value head j reading key head j // 2 is the rule over q and k repeated
+    for their value heads, values and gradients: the repeat's transpose sums
+    the two value heads' cotangents."""
+    args, mix = rule_inputs(chunk)
+
+    def grouped(*a):
+        return (chunked_delta_rule(*a, chunk)[0] * mix).sum()
+
+    def by_repeat(q, k, v, log_alpha, beta):
+        per_key = v.shape[2] // q.shape[2]
+        return (chunked_delta_rule(
+            jnp.repeat(q, per_key, 2), jnp.repeat(k, per_key, 2), v,
+            log_alpha, beta, chunk)[0] * mix).sum()
+
+    got = jax.jit(jax.value_and_grad(grouped, range(5)))(*args)
+    want = jax.jit(jax.value_and_grad(by_repeat, range(5)))(*args)
+    np.testing.assert_allclose(got[0], want[0], rtol=RTOL)
+    for g, w in zip(got[1], want[1]):
+        close(g, w)
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_a_decay_a_head_is_the_channel_form_fed_it_on_every_channel(chunk):
+    """Inside the channel gate's bound the two forms must agree: the one Ling's
+    cell guards, fed the head's decay on each of its channels and repeated
+    heads, against the head form."""
+    args, mix = rule_inputs(chunk, low=-5.0)
+
+    def head_form(*a):
+        return (chunked_delta_rule(*a, chunk)[0] * mix).sum()
+
+    def channel_form(*a):
+        return (chunked_delta_rule(*repeated(*a), chunk)[0] * mix).sum()
+
+    got = jax.jit(jax.value_and_grad(head_form, range(5)))(*args)
+    want = jax.jit(jax.value_and_grad(channel_form, range(5)))(*args)
+    np.testing.assert_allclose(got[0], want[0], rtol=RTOL)
+    for g, w in zip(got[1], want[1]):
+        close(g, w, 1e-4)
+
+
+def test_head_decay_rule_never_broadcasts_the_decay_over_channels():
+    """Every exponential of the lowered head form is of a value head's sums —
+    (batch, chunks, heads, chunk[, chunk]) — and none is d_k wide, where the
+    channel form fed the same decay takes them a channel."""
+    args, _ = rule_inputs(0)
+
+    def exponentials(operands):
+        text = jax.jit(lambda *a: chunked_delta_rule(*a, 64)[0]).lower(
+            *operands).as_text()
+        return set(re.findall(
+            r"stablehlo\.exponential .* : tensor<([0-9x]+)xf32>", text))
+
+    assert exponentials(args) == {"2x2x4x64", "2x2x4x64x64", "2x2x4"}
+    assert any(shape.endswith("x16") for shape in exponentials(
+        repeated(*args)))
+
+
+@pytest.mark.parametrize("values,heads", [(6, 4), (4, 3)])
+def test_head_decay_rule_refuses_heads_it_cannot_group(values, heads):
+    q = jnp.zeros((1, 32, heads, 8))
+    v = jnp.zeros((1, 32, values, 8))
+    with pytest.raises(ValueError, match="key heads"):
+        chunked_delta_rule(q, q, v, jnp.zeros((1, 32, values)),
+                           jnp.zeros((1, 32, values)), 16)
+
+
+@pytest.mark.parametrize("seq,chunk,loops", [(4096, 64, 2), (100, 32, 2),
+                                             (64, 64, 0), (48, 64, 0)])
+def test_lowered_plan_counts_the_rules_loops(seq, chunk, loops):
+    """The plan against the compiled program: a `while` forward and one
+    backward where the recurrence has more than one step (a single chunk's
+    loop is unrolled)."""
+    assert lowered_plan(seq, chunk) == {"while": loops, "tpu_custom_call": 0}
+    if seq > 128:             # the cell's length: the plan alone
+        return
+    args, mix = rule_inputs(0, seq)
+    text = jax.jit(jax.grad(lambda *a: (chunked_delta_rule(
+        *a, chunk)[0] * mix).sum(), range(5))).lower(*args).compile().as_text()
+    assert text.count(" while(") == loops
+
+
+# --- each mixer against the reference's -------------------------------------
+
+@pytest.mark.parametrize("head_shard", [(0, 1), (1, 2)])
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_gated_delta_mixer_is_the_reference(chunk, head_shard):
+    mixer = DeltaMixer(*DELTA._replace(chunk=chunk), gate="head",
+                       head_shard=head_shard, dtype=jnp.float32)
+    u, params, mix = mixer_case(mixer, chunk + head_shard[0])
+    n = head_shard[1]
+    assert params["in_proj_kernel"].shape == (
+        HIDDEN, (2 * KEY_HEADS + 2 * VALUE_HEADS) * LINEAR_DIM // n
+        + 2 * VALUE_HEADS // n)
+    assert params["conv_kernel"].shape == (
+        4, (2 * KEY_HEADS + VALUE_HEADS) * LINEAR_DIM // n)
+    assert params["dt_bias"].shape == params["A_log"].shape == (
+        VALUE_HEADS // n,)
+    both_ways(lambda p, u: mixer.apply({"params": p}, u),
+              lambda p, u: reference.gated_delta(
+                  u, p, head_dim=LINEAR_DIM, norm_eps=1e-6),
+              u, params, mix, 1e-4)
+
+
+def test_gated_delta_mixer_writes_its_chunks_decay_under_its_own_name():
+    mixer = DeltaMixer(*DELTA, gate="head", dtype=jnp.float32)
+    u, params, _ = mixer_case(mixer)
+    _, wrote = mixer.apply({"params": params}, u, mutable=["intermediates"])
+    assert set(wrote["intermediates"]) == {"gdn_chunk_log_decay_min"}
+    decay = wrote["intermediates"]["gdn_chunk_log_decay_min"][0]
+    assert decay.shape == () and float(decay) < 0
+
+
+@pytest.mark.parametrize("mixer,match", [
+    (DeltaMixer(*DELTA), "gate='head'"),                # value heads, channel
+    (DeltaMixer(*DELTA, gate="softplus"), "is none of"),
+    (DeltaMixer(3, 8, value_heads=4, gate="head"), "value heads"),
+    (DeltaMixer(*DELTA, gate="head", head_shard=(0, 4)), "head_shard")])
+def test_delta_mixer_refuses_what_it_cannot_build(mixer, match):
+    with pytest.raises(ValueError, match=match):
+        mixer.init(jax.random.PRNGKey(0), jnp.zeros((1, SEQ, HIDDEN)))
+
+
+def test_value_heads_unset_is_the_channel_gates_mixer_of_before():
+    """`DeltaConfig` and `DeltaMixer` with `value_heads` unset build the
+    parameters and the jaxpr the Kimi-delta mixer had."""
+    old = DeltaMixer(4, 8, 4, 32, -5.0, dtype=jnp.float32)
+    new = DeltaMixer(*DeltaConfig(4, 8, 4, 32), dtype=jnp.float32)
+    u = jnp.zeros((1, SEQ, HIDDEN))
+    shapes = jax.eval_shape(lambda: new.init(jax.random.PRNGKey(0), u))
+    assert shapes["params"]["in_proj_kernel"].shape == (HIDDEN, 5 * 32 + 4)
+    texts = [jax.jit(m.apply).lower(shapes, u).as_text() for m in (old, new)]
+    assert texts[0] == texts[1] and "hvd_gdn" not in texts[0]
+
+
+# --- the partial rotation ---------------------------------------------------
+
+@pytest.mark.parametrize("seq_dim,shape", [(-2, (2, 3, 24, HEAD_DIM)),
+                                           (1, (2, 24, 3, HEAD_DIM))])
+def test_partial_rotation_turns_the_slice_alone(seq_dim, shape):
+    """`rotary_dim` against a rotation of the slice alone, values and the
+    written-out backward; the channels that pass come back bit for bit."""
+    x, mix = (jax.random.normal(jax.random.PRNGKey(i), shape) for i in (0, 1))
+    positions = jnp.arange(24) + 5
+
+    def partial(x):
+        return rope(x, positions, THETA, seq_dim, ROTARY)
+
+    def sliced(x):
+        return jnp.concatenate([rope(x[..., :ROTARY], positions, THETA,
+                                     seq_dim), x[..., ROTARY:]], axis=-1)
+
+    got, want = partial(x), sliced(x)
+    close(got, want)
+    np.testing.assert_array_equal(got[..., ROTARY:], x[..., ROTARY:])
+    close(jax.grad(lambda x: (partial(x) * mix).sum())(x),
+          jax.grad(lambda x: (sliced(x) * mix).sum())(x))
+
+
+def test_rotary_dim_of_the_whole_head_is_todays_rope():
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 3, 24, HEAD_DIM))
+    positions = jnp.arange(24)
+    np.testing.assert_array_equal(rope(x, positions, THETA, -2, HEAD_DIM),
+                                  rope(x, positions, THETA))
+    # Unset, the lowered text is the one before the option.
+    texts = [jax.jit(fn).lower(x).as_text() for fn in (
+        lambda x: rope(x, positions, THETA),
+        lambda x: rope(x, positions, THETA, -2, None))]
+    assert texts[0] == texts[1]
+
+
+@pytest.mark.parametrize("rotary_dim", [3, 0, HEAD_DIM + 2])
+def test_rope_refuses_a_rotation_it_cannot_place(rotary_dim):
+    with pytest.raises(ValueError, match="rotary_dim"):
+        rope(jnp.zeros((1, 1, 8, HEAD_DIM)), jnp.arange(8), THETA, -2,
+             rotary_dim)
+
+
+@pytest.mark.parametrize("use_flash", [False, True])
+def test_partly_rotated_gated_attention_is_the_reference(use_flash):
+    layer = Attention(HEADS, jnp.float32, use_flash=use_flash,
+                      n_kv_heads=KV_HEADS, head_dim=HEAD_DIM, head_norm=True,
+                      gate=True, rope_theta=THETA, rotary_dim=ROTARY)
+    u, params, mix = mixer_case(layer, use_flash)
+    both_ways(lambda p, u: layer.apply({"params": p}, u),
+              lambda p, u: reference.gated_attention(
+                  u, p, rope_theta=THETA, rotary_dim=ROTARY, norm_eps=1e-6),
+              u, params, mix)
+
+
+def test_partial_rotation_is_training_only():
+    layer = Attention(HEADS, jnp.float32, rotary_dim=ROTARY, seq_axis="sp")
+    with pytest.raises(ValueError, match="rotary_dim"):
+        layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 16, HIDDEN)))
+    model = lm()
+    params, batch = seeded(model)
+    with pytest.raises(ValueError, match="decode_ctx"):
+        model.apply({"params": params}, batch[0], decode_ctx=object())
+
+
+# --- the gated shared expert ------------------------------------------------
+
+@pytest.mark.parametrize("shard", [(0, 1), (0, 4), (3, 4)])
+def test_experts_with_a_gated_shared_expert_are_the_dense_loop(shard):
+    layer = SparseExperts(moe(shard), jnp.float32)
+    u, params, mix = mixer_case(layer, shard[0])
+    assert params["shared_output_gate_kernel"].shape == (HIDDEN, 1)
+
+    def plain(p, u):
+        return reference.sparse_experts(
+            u.reshape(-1, HIDDEN), p, num_experts=EXPERTS, expert_shard=shard,
+            experts_per_token=PER_TOKEN)[0].reshape(u.shape)
+
+    both_ways(lambda p, u: layer.apply({"params": p}, u), plain, u, params,
+              mix)
+    _, wrote = layer.apply({"params": params}, u, mutable=["intermediates"])
+    _, want = with_highest(reference.router)(
+        u.reshape(-1, HIDDEN), params["router_kernel"],
+        experts_per_token=PER_TOKEN)
+    np.testing.assert_array_equal(
+        jnp.sort(wrote["intermediates"]["chosen_experts"][0], -1),
+        jnp.sort(want, -1))
+
+
+def test_shared_output_gate_unset_is_the_layer_of_before():
+    """`shared_output_gate=False` is the program before the option: the same
+    jaxpr as a configuration that never names it, and no parameter more."""
+    unnamed = MoEConfig(EXPERTS, PER_TOKEN, WIDTH, (0, 4), None,
+                        renormalize=True, shared_width=SHARED)
+    u = jnp.zeros((2, SEQ, HIDDEN))
+    texts = []
+    for cfg in (moe((0, 4), gate=False), unnamed):
+        layer = SparseExperts(cfg, jnp.float32)
+        params = jax.eval_shape(
+            lambda: layer.init(jax.random.PRNGKey(0), u)["params"])
+        assert "shared_output_gate_kernel" not in params
+        texts.append(jax.jit(jax.grad(
+            lambda p, u: layer.apply({"params": p}, u).sum())).lower(
+                params, u).as_text())
+    assert texts[0] == texts[1]
+
+
+def test_an_output_gate_wants_a_shared_expert():
+    cfg = MoEConfig(EXPERTS, PER_TOKEN, WIDTH, shared_output_gate=True)
+    with pytest.raises(ValueError, match="shared_width"):
+        SparseExperts(cfg, jnp.float32).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, HIDDEN)))
+
+
+# --- the whole model --------------------------------------------------------
+
+@pytest.mark.parametrize("chunk", [32, 64])
+@pytest.mark.parametrize("expert_shard", [(0, 1), (1, 4)])
+def test_qwen3next_lm_loss_and_gradients_are_the_references(expert_shard,
+                                                            chunk):
+    model = lm(expert_shard, chunk=chunk)
+    params, batch = seeded(model, seed=chunk)
+    config = reference_config(expert_shard)
+    got, got_grads = jax.jit(jax.value_and_grad(
+        lambda p: system_loss(model, p, batch)))(params)
+    (want, want_chose), want_grads = with_highest(jax.value_and_grad(
+        lambda p: reference.loss_and_chosen(p, batch, **config),
+        has_aux=True))(params)
+    np.testing.assert_allclose(got, want, rtol=RTOL)
+    trees_close(got_grads, want_grads, 1e-4)
+    _, wrote = model.apply({"params": params}, batch[0],
+                           mutable=["intermediates"])
+    chose = jnp.stack([wrote["intermediates"][f"layer_{i}"]["mixer"][
+        "chosen_experts"][0] for i, kind in enumerate(LAYERS)
+        if kind == "experts"])
+    np.testing.assert_array_equal(jnp.sort(chose, -1),
+                                  jnp.sort(want_chose, -1))
+
+
+def test_reference_refuses_float8_operands():
+    """The reference against itself with every matmul operand, and the q, k, v
+    its recurrence and its attention read, rounded to float8_e4m3fn: the error
+    the benchmark's limits must refuse is far over what float32 reorderings
+    give above."""
+    model = lm()
+    params, batch = seeded(model)
+    losses = [with_highest(jax.value_and_grad(lambda p: reference.loss(
+        p, batch, operand_dtype=dtype, **reference_config())))(params)
+        for dtype in (None, jnp.float8_e4m3fn)]
+    norm = optax.global_norm
+    wrong = norm(jax.tree.map(jnp.subtract, losses[1][1], losses[0][1]))
+    assert float(wrong / norm(losses[0][1])) > 0.05
+
+
+def test_pattern_has_one_norm_and_one_mixer_an_entry():
+    shapes = jax.eval_shape(lambda: lm((0, 4)).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, SEQ), jnp.int32))["params"])
+    assert set(shapes) == {"embed", "final_norm", "lm_head_kernel"} | {
+        f"layer_{i}" for i in range(len(LAYERS))}
+    mixers = {"gated_delta": {"A_log", "conv_kernel", "dt_bias",
+                              "in_proj_kernel", "norm_scale",
+                              "out_proj_kernel"},
+              "attention": {"q_kernel", "kv_kernel", "q_head_norm_scale",
+                            "k_head_norm_scale", "gate_kernel", "o_kernel"},
+              "experts": {"router_kernel", "gate_kernel", "up_kernel",
+                          "down_kernel", "shared_gate", "shared_up",
+                          "shared_down", "shared_output_gate_kernel"}}
+    for i, kind in enumerate(LAYERS):
+        assert set(shapes[f"layer_{i}"]) == {"norm", "mixer"}
+        assert set(shapes[f"layer_{i}"]["mixer"]) == mixers[kind]
+    assert LAYER_KINDS["gated_delta"] == LAYER_KINDS["delta"] == "DeltaMixer"
+    # The share: 4 of 16 experts, the router over all 16, the mixers whole.
+    assert shapes["layer_0"]["mixer"]["A_log"].shape == (VALUE_HEADS,)
+    assert shapes["layer_1"]["mixer"]["up_kernel"].shape == (4, HIDDEN, WIDTH)
+    assert shapes["layer_1"]["mixer"]["router_kernel"].shape == (HIDDEN,
+                                                                 EXPERTS)
+    assert shapes["layer_6"]["mixer"]["kv_kernel"].shape == (
+        HIDDEN, 2, KV_HEADS, HEAD_DIM)
+
+
+def test_trains_through_build_train_step_and_replicas_stay_equal():
+    """Two CPU devices, data parallel: the dense LM's step with the pattern.
+    The replicated weights stay equal and the loss of a repeated batch falls.
+    The flash kernels (interpreted here), as in the benchmark; the delta
+    rule's scan carries a state that varies over the mesh axis."""
+    model = lm((0, 4), use_flash=True)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("hvd",))
+    params, batch = seeded(model, seed=3)
+    tx = optax.adamw(1e-2)
+    step = build_train_step(lambda p, b: system_loss(model, p, b), tx, mesh,
+                            axis_name="hvd", batch_spec=(P("hvd"), P("hvd")))
+    state = (params, tx.init(params))
+    losses = []
+    for _ in range(4):
+        *state, loss = step(*state, batch)
+        losses.append(float(loss))
+    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+    for leaf in jax.tree.leaves(state[0]):
+        first, second = (np.asarray(s.data) for s in leaf.addressable_shards)
+        np.testing.assert_array_equal(first, second)
+
+
+# --- the shares add up to the uncut layer -----------------------------------
+
+def gated_delta_share(p, shard, n):
+    keys, values = KEY_HEADS * LINEAR_DIM, VALUE_HEADS * LINEAR_DIM
+
+    def heads(v, width=VALUE_HEADS):
+        return columns(v, [width], shard, n)
+
+    return {"in_proj_kernel": columns(
+                p["in_proj_kernel"],
+                [keys, keys, values, values, VALUE_HEADS, VALUE_HEADS],
+                shard, n),
+            "conv_kernel": columns(p["conv_kernel"], [keys, keys, values],
+                                   shard, n),
+            "dt_bias": heads(p["dt_bias"]), "A_log": heads(p["A_log"]),
+            "norm_scale": p["norm_scale"],               # one for every head
+            "out_proj_kernel": heads(p["out_proj_kernel"].T, values).T}
+
+
+def test_gated_delta_tensor_shares_add_up_to_the_uncut_layer():
+    whole = DeltaMixer(*DELTA, gate="head", dtype=jnp.float32)
+    u, params, _ = mixer_case(whole, 2)
+    parts = [jax.jit(DeltaMixer(*DELTA, gate="head", head_shard=(i, 2),
+                                dtype=jnp.float32).apply)(
+        {"params": gated_delta_share(params, i, 2)}, u) for i in range(2)]
+    close(sum(parts), with_highest(reference.gated_delta)(
+        u, params, head_dim=LINEAR_DIM, norm_eps=1e-6), 1e-4)
+
+
+@pytest.mark.parametrize("n,experts", [(4, EXPERTS), (16, EXPERTS),
+                                       (16, 512)])
+def test_expert_shares_add_up_with_the_gated_shared_expert_counted_once(
+        n, experts):
+    """The n shares' outputs each hold the gated shared expert; their sum
+    holds it n times and the routed part once.  16 shares of 32 experts: the
+    deployment's count."""
+    whole = SparseExperts(moe(experts=experts), jnp.float32)
+    u, params, _ = mixer_case(whole, n)
+    local = experts // n
+    parts = []
+    for i in range(n):
+        held = slice(i * local, (i + 1) * local)
+        share = dict(params, **{name: params[name][held] for name in (
+            "gate_kernel", "up_kernel", "down_kernel")})
+        parts.append(jax.jit(SparseExperts(
+            moe((i, n), experts=experts), jnp.float32).apply)(
+                {"params": share}, u))
+    flat = u.reshape(-1, HIDDEN)
+    shared = (jax.nn.sigmoid(flat @ params["shared_output_gate_kernel"])
+              * reference.gated_mlp(flat, *(params[name]["kernel"] for name in (
+                  "shared_gate", "shared_up", "shared_down")))).reshape(
+                      u.shape)
+    want = with_highest(reference.sparse_experts)(
+        flat, params, num_experts=experts, expert_shard=(0, 1),
+        experts_per_token=PER_TOKEN)[0]
+    close(sum(part - shared for part in parts) + shared,
+          want.reshape(u.shape))
