@@ -25,20 +25,47 @@ the state ``S`` enters is (the WY / UT transform; rows are tokens):
 
 :func:`chunked_delta_rule` computes ``A``, ``T``, ``W``, ``U0`` and the masked
 ``Q K^T`` for every chunk at once.  Only ``S`` is a true recurrence, and only
-``S`` is carried: a ``lax.scan`` of two products a step (``U``, then ``S'``; a
-``while`` in the compiled program) leaves the state that entered each chunk,
-and ``O`` is two products over all chunks at once from those states.  An
-iteration of the loop costs what its operations cost to launch, a
-microsecond each whatever they compute, so what need not be in it is not.
-The backward pass (:func:`_carry`, a ``jax.custom_vjp``) has the same shape:
-what ``O``'s cotangent gives ``U`` and the state is found for all chunks at
-once, a reverse scan of two products a step carries the state's cotangent,
-and the operands' cotangents are products over all chunks.  It keeps the
-state that entered each chunk and computes ``U`` again.  Everything outside
-:func:`_carry` and the solve is differentiable by autodiff.
+``S`` is carried from chunk to chunk.  Everything outside the carry and the
+solve is differentiable by autodiff.
 
-This is the rule on every backend: XLA products and two loops a layer, no
-kernel of ours (``PERF.md`` section 7 has what pins that).
+The carry, in two forms.  A decay a channel (:func:`_carry`, a
+``jax.custom_vjp``) is XLA products and two loops a layer, no kernel of ours:
+a ``lax.scan`` of two products a step (``U``, then ``S'``; a ``while`` in the
+compiled program) leaves the state that entered each chunk, and ``O`` is two
+products over all chunks at once from those states.  An iteration of the
+loop costs what its operations cost to launch, a microsecond each whatever
+they compute, so what need not be in it is not.  Its backward pass has the
+same shape: what ``O``'s cotangent gives ``U`` and the state is found for all
+chunks at once, a reverse scan of two products a step carries the state's
+cotangent, and the operands' cotangents are products over all chunks.  It
+keeps the state that entered each chunk and computes ``U`` again.  (The Ling
+cell's ``correct`` pins this form's ``while``s and custom calls: ``PERF.md``
+section 7.)
+
+A decay a head (:func:`_head_carry`, a ``jax.custom_vjp`` too) is a pair of
+Pallas kernels, compiled by Mosaic on a TPU and run by the Pallas interpreter
+elsewhere, that hold the state on the chip.  The grid is (batch, key heads,
+chunks), the chunks its sequential axis; a key head's value heads' states,
+(value heads a key head, d_k, d_v) float32, are VMEM scratch, zero at the
+first chunk.  A grid step of the forward kernel reads the chunk's ``W``,
+``U0`` and masked ``Q K^T`` a value head, its ``q`` and ``k`` a KEY head and
+three vectors of decays (from the chunk's start to each token, from each
+token to its end, over the whole chunk), and for each value head computes
+
+    U  = U0 - W S
+    O  = (Q . from_start) S + QK U
+    S' = carried S + (K . end_decay)^T U
+
+with ``S``, ``U`` and the decayed ``Q`` and ``K`` rounded to ``q``'s dtype
+where a product reads them, as the loops round them; it writes ``O`` and the
+float32 state that entered the chunk.  The backward kernel walks the chunks
+last to first with the state's cotangent in VMEM, computes ``U`` once from
+the kept state, and writes every operand's cotangent: ``q``'s and ``k``'s
+summed over the key head's value heads in float32 and rounded once, the
+decay's from the float32 state.  Nothing is written a value head that is a
+key head's, no state is moved by a ``dynamic-update-slice``, and there is no
+product over all chunks outside the kernels.  ``carried`` may be one decay a
+head, (…, 1), or one a channel, (…, d_k): the kernels take either by shape.
 
 ``k_s / G_s`` is never formed: over a chunk of 64 tokens at the gate's bound of
 -5 a step it is ``e^320``.  A chunk is cut into sub-blocks of
@@ -99,8 +126,9 @@ under exactly one:
 * ``hvd_kda_scan_solve`` — ``T`` (:func:`_unit_lower_inverse`, forward and its
   written-out backward) and ``W``, ``U0``;
 * ``hvd_kda_scan_carry`` — :func:`_carry`: the two ``while``s and their
-  bodies, the products over all chunks around them, and the move of ``o``
-  back to tokens.
+  bodies and the products over all chunks around them, or
+  :func:`_head_carry`: the two kernels, named ``<scope>_carry_fwd`` and
+  ``<scope>_carry_bwd``; and the move of ``o`` back to tokens.
 
 :func:`lowered_plan` says what a call contributes to a compiled training
 step (its loops and kernels), as ``ops.attention._bwd_plan`` says of flash.
@@ -114,9 +142,13 @@ every matmul of the model is.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 SUB_BLOCK = 16   # tokens whose decay ratios are taken against one reference
 
@@ -280,34 +312,242 @@ def _carry_bwd(kept, d_o):
 _carry.defvjp(_carry_fwd, _carry_bwd)
 
 
-def lowered_plan(seq: int, chunk: int) -> dict:
+# --- a decay a head: the recurrence as a pair of Pallas kernels -------------
+
+def _dot(a, b, a_axis=1, b_axis=0):
+    """``a`` and ``b`` (two axes each) contracted over one axis of each,
+    summed in float32: (1, 0) ``a b``, (0, 0) ``a^T b``, (1, 1) ``a b^T``."""
+    return lax.dot_general(a, b, (((a_axis,), (b_axis,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _turned(vector):
+    """A (1, n) row as the (n, 1) column or the column as the row, exactly (a
+    sum of one term and zeros): tokens and channels lie along the sublanes
+    of what a decay multiplies, and a vector of them is stored along the
+    lanes.  A vector of one element is either already."""
+    n = max(vector.shape)
+    if n == 1:
+        return vector
+    along = vector.shape.index(n)
+    diagonal = lax.broadcasted_iota(jnp.int32, (n, n), 0) \
+        == lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return jnp.where(diagonal, vector, 0.0).sum(axis=along, keepdims=True)
+
+
+def _over_rows(carried):
+    """A value head's decay over a chunk, (1, 1) or a channel's (1, d_k), as
+    what multiplies the rows of a (d_k, d_v) state: a single decay as a
+    scalar (Mosaic broadcasts a vector along one axis at a time)."""
+    return carried.sum() if carried.size == 1 else _turned(carried)
+
+
+def _at_every_step(body):
+    """``body()`` under a ``cond`` on what holds at every grid step: inside
+    ``shard_map`` the interpreter lets a kernel's scratch, which varies over
+    no mesh axis, meet its operands, which do, only there (as
+    ``ops.attention._when_live``)."""
+    pl.when(pl.program_id(2) >= 0)(body)
+
+
+def _chunk_operands(j, w_ref, u0_ref, q, k, start_ref, end_ref, state):
+    """What both directions compute of value head ``j`` of the grid step's
+    chunk from the float32 ``state`` that entered it: the rounded state and
+    ``U``, the decayed ``Q`` and ``K`` (rounded as the products read them),
+    and the two decays as columns."""
+    dtype = w_ref.dtype
+    narrow = state.astype(dtype)
+    u = u0_ref[j] - _dot(w_ref[j], narrow)
+    start, end = (_turned(ref[j:j + 1, :]) for ref in (start_ref, end_ref))
+    return (narrow, u.astype(dtype), (q * start).astype(dtype),
+            (k * end).astype(dtype), start, end)
+
+
+def _carry_fwd_kernel(w_ref, u0_ref, qk_ref, q_ref, k_ref, start_ref, end_ref,
+                      carried_ref, o_ref, entered_ref, state):
+    """Grid ``(batch, key heads, chunks)``, the chunks in order: a key head's
+    value heads' states (per_key, d_k, d_v) float32 stay in ``state`` from a
+    chunk to the next.  Writes ``O`` of the chunk and the state that entered
+    it."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    @_at_every_step
+    def _():
+        q, k = (ref[...].astype(jnp.float32) for ref in (q_ref, k_ref))
+        for j in range(state.shape[0]):
+            entered = state[j]
+            entered_ref[j] = entered
+            narrow, rounded, q_in, k_end, _, _ = _chunk_operands(
+                j, w_ref, u0_ref, q, k, start_ref, end_ref, entered)
+            o_ref[j] = _dot(q_in, narrow) + _dot(qk_ref[j], rounded)
+            state[j] = _over_rows(carried_ref[j:j + 1, :]) * entered \
+                + _dot(k_end, rounded, 0, 0)
+
+
+def _carry_bwd_kernel(w_ref, u0_ref, qk_ref, q_ref, k_ref, start_ref, end_ref,
+                      carried_ref, entered_ref, d_o_ref, d_w_ref, d_u0_ref,
+                      d_qk_ref, d_q_ref, d_k_ref, d_start_ref, d_end_ref,
+                      d_carried_ref, d_left):
+    """The same grid, the chunks last to first: ``d_left``, the cotangent of
+    the state a chunk leaves, (per_key, d_k, d_v) float32, stays in VMEM.
+    ``U`` is computed once, from the kept state; ``q``'s and ``k``'s
+    cotangents are the key head's, summed over its value heads here."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        d_left[...] = jnp.zeros_like(d_left)
+
+    @_at_every_step
+    def _():
+        dtype = w_ref.dtype
+        q, k = (ref[...].astype(jnp.float32) for ref in (q_ref, k_ref))
+        d_q, d_k = jnp.zeros_like(q), jnp.zeros_like(k)
+        for j in range(d_left.shape[0]):
+            entered, left = entered_ref[j], d_left[j]
+            narrow, rounded, q_in, k_end, start, end = _chunk_operands(
+                j, w_ref, u0_ref, q, k, start_ref, end_ref, entered)
+            d_o, narrow_left = d_o_ref[j].astype(dtype), left.astype(dtype)
+            d_u = _dot(qk_ref[j], d_o, 0, 0) + _dot(k_end, narrow_left)
+            d_u0_ref[j] = d_u
+            d_u = d_u.astype(dtype)
+            d_w_ref[j] = (-_dot(d_u, narrow, 1, 1)).astype(dtype)
+            d_qk_ref[j] = _dot(d_o, rounded, 1, 1).astype(dtype)
+            d_q_in = _dot(d_o, narrow, 1, 1)
+            d_k_end = _dot(rounded, narrow_left, 1, 1)
+            d_q, d_k = d_q + d_q_in * start, d_k + d_k_end * end
+            d_start_ref[j:j + 1, :] = _turned(
+                (d_q_in * q).sum(axis=1, keepdims=True))
+            d_end_ref[j:j + 1, :] = _turned(
+                (d_k_end * k).sum(axis=1, keepdims=True))
+            # d_carried from the float32 state: a channel's, or their sum.
+            by_channel = (left * entered).sum(axis=1, keepdims=True)
+            d_carried_ref[j:j + 1, :] = (
+                by_channel.sum(axis=0, keepdims=True)
+                if carried_ref.shape[1] == 1 else _turned(by_channel))
+            d_left[j] = _over_rows(carried_ref[j:j + 1, :]) * left \
+                + _dot(q_in, d_o, 0, 0) - _dot(w_ref[j], d_u, 0, 0)
+        d_q_ref[...] = d_q.astype(dtype)
+        d_k_ref[...] = d_k.astype(dtype)
+
+
+def _carry_call(scope, backward, operands, outputs, interpret):
+    """``pl.pallas_call`` of the carry's forward or backward kernel, named
+    ``<scope>_fwd`` or ``<scope>_bwd``, over the grid (batch, key heads,
+    chunks), backward the chunks last to first: a key head's block of each of
+    ``operands`` and ``outputs`` (shapes and dtypes), every one
+    (b, n, g, ...)."""
+    batch, chunks, key_heads, per_key, chunk, d_k = operands[0].shape
+    d_v = operands[1].shape[-1]
+    # (C, d_k, d_v) and (C, C, d_v) products a value head's chunk takes: the
+    # compiler's count of the step's operations cannot see into a kernel.
+    kernel, name, products = (
+        (_carry_bwd_kernel, f"{scope}_bwd", (7, 2)) if backward
+        else (_carry_fwd_kernel, f"{scope}_fwd", (3, 1)))
+
+    def spec(t):
+        behind = (0,) * (t.ndim - 3)
+        return pl.BlockSpec(
+            (None, None, None) + t.shape[3:],
+            (lambda b, g, n: (b, chunks - 1 - n, g) + behind) if backward
+            else (lambda b, g, n: (b, n, g) + behind))
+
+    vma = jax.typeof(operands[0]).vma   # inside shard_map: as the inputs vary
+    macs = chunk * d_v * (products[0] * d_k + products[1] * chunk)
+    return pl.pallas_call(
+        kernel, grid=(batch, key_heads, chunks),
+        in_specs=[spec(t) for t in operands],
+        out_specs=[spec(t) for t in outputs],
+        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype, vma=vma)
+                   for t in outputs],
+        scratch_shapes=[pltpu.VMEM((per_key, d_k, d_v), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * batch * chunks * key_heads * per_key * macs,
+            transcendentals=0,
+            bytes_accessed=sum(t.size * t.dtype.itemsize
+                               for t in (*operands, *outputs))),
+        interpret=interpret, name=name)(*operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _head_carry(w, u0, qk, q, k, from_start, end_decay, carried, scope,
+                interpret):
+    """:func:`_carry` of the head form, as two kernels that hold the state on
+    the chip (module docstring, "The carry, in two forms"): ``O`` of every
+    chunk, (b, n, h, C, d_v) float32, from ``w``, ``u0``, ``qk`` a value head
+    (b, n, h, C, .), ``q`` and ``k`` a KEY head (b, n, g, C, d_k) and the
+    value heads' decays ``from_start``, ``end_decay`` (b, n, h, C) and
+    ``carried`` (b, n, h, 1) float32.  ``scope`` names the kernels
+    (``<scope>_fwd``, ``<scope>_bwd``)."""
+    return _head_carry_fwd(w, u0, qk, q, k, from_start, end_decay, carried,
+                           scope, interpret)[0]
+
+
+def _by_key_head(q, operands):
+    """A value head's arrays (b, n, h, ...) as (b, n, g, per_key, ...)."""
+    batch, chunks, key_heads = q.shape[:3]
+    return [t.reshape(batch, chunks, key_heads, -1, *t.shape[3:])
+            for t in operands]
+
+
+def _head_carry_fwd(w, u0, qk, q, k, from_start, end_decay, carried, scope,
+                    interpret):
+    w, u0, qk, from_start, end_decay, carried = _by_key_head(
+        q, (w, u0, qk, from_start, end_decay, carried))
+    operands = (w, u0, qk, q, k, from_start, end_decay, carried)
+    entered = jax.ShapeDtypeStruct(w.shape[:4] + (w.shape[-1], u0.shape[-1]),
+                                   jnp.float32)
+    o, entered = _carry_call(scope, False, operands, (u0, entered), interpret)
+    return o.reshape(o.shape[:2] + (-1,) + o.shape[4:]), operands + (entered,)
+
+
+def _head_carry_bwd(scope, interpret, kept, d_o):
+    operands = kept[:-1]                # a cotangent each, shaped as it is
+    d_o, = _by_key_head(operands[3], (d_o,))
+    cotangents = _carry_call(scope, True, kept + (d_o,), operands, interpret)
+    merged = [t.reshape(t.shape[:2] + (-1,) + t.shape[4:])
+              for t in cotangents]
+    merged[3:5] = cotangents[3:5]       # q's and k's are a key head's
+    return tuple(merged)
+
+
+_head_carry.defvjp(_head_carry_fwd, _head_carry_bwd)
+
+
+def lowered_plan(seq: int, chunk: int, form: str = "head") -> dict:
     """What one call of :func:`chunked_delta_rule` at this length, forward and
-    backward together, adds to a compiled step, in either form: the
-    recurrence between chunks is a ``while`` forward and one backward (the
-    compiler unrolls a loop of one step, so a single chunk has none), and no
-    kernel of ours."""
+    backward together, adds to a compiled step.  ``form="head"`` (a decay a
+    head): the two kernels of :func:`_head_carry`, whatever the length, and
+    no loop.  ``form="channel"`` (a decay a channel): the recurrence between
+    chunks is a ``while`` forward and one backward (the compiler unrolls a
+    loop of one step, so a single chunk has none), and no kernel of ours."""
+    if form == "head":
+        return {"while": 0, "tpu_custom_call": 2}
+    if form != "channel":
+        raise ValueError(f"lowered_plan: form {form!r} is neither 'head' "
+                         "nor 'channel'")
     return {"while": 2 if -(-seq // chunk) > 1 else 0, "tpu_custom_call": 0}
 
 
-def _solved_and_carried(a, beta, k, from_start, v, q_in, qk, k_end, carried,
-                        seq: int, scope: str):
-    """The two stages both forms share, from a chunk's ``A`` (strictly
-    lower), ``beta``, ``K``, ``G`` and ``V`` (float32, each (b, n, h, C, ...)
-    or broadcasting to it) and :func:`_carry`'s other operands: ``o`` (batch,
-    seq, heads, d_v)."""
-    dtype = q_in.dtype
+def _solved(a, beta, k, from_start, v, dtype, scope: str):
+    """The solve both forms share, from a chunk's ``A`` (strictly lower),
+    ``beta``, ``K``, ``G`` and ``V`` (float32, each (b, n, h, C, ...) or
+    broadcasting to it): ``W`` in ``dtype`` and ``U0``."""
     exact = dict(precision="highest", **_WIDE)
     with jax.named_scope(f"{scope}_solve"):
         solve = _unit_lower_inverse(a)
         w = jnp.einsum("bnhts,bnhsc->bnhtc", solve, beta * k * from_start,
                        **exact).astype(dtype)
         u0 = jnp.einsum("bnhts,bnhsv->bnhtv", solve, beta * v, **exact)
-    with jax.named_scope(f"{scope}_carry"):
-        o = _carry(w, u0, q_in, qk, k_end, carried)
-        # (b, n, h, C, d_v) -> (b, seq, h, d_v)
-        batch, _, heads = o.shape[:3]
-        return jnp.moveaxis(o, 3, 2).reshape(batch, -1, heads,
-                                             o.shape[-1])[:, :seq]
+    return w, u0
+
+
+def _to_tokens(o, seq: int):            # (b, n, h, C, d_v) -> (b, seq, h, d_v)
+    batch, _, heads = o.shape[:3]
+    return jnp.moveaxis(o, 3, 2).reshape(batch, -1, heads,
+                                         o.shape[-1])[:, :seq]
 
 
 def _head_decay_rule(q, k, v, log_alpha, beta, chunk: int, scope: str):
@@ -356,9 +596,9 @@ def _head_decay_rule(q, k, v, log_alpha, beta, chunk: int, scope: str):
             "ti,bnhis->bnhts", lower.astype(f32),
             jnp.where(earlier, steps[..., :, None], 0.0), precision="highest")
         decay = jnp.where(lower, jnp.exp(between), 0.0)      # (b, n, h, C, C)
-        # (b, n, h, C, 1): a token's decay, for every channel of its head.
-        from_start = jnp.exp(within)[..., None]
-        end_decay = jnp.exp(to_end)[..., None]
+        # (b, n, h, C): a token's decay, for every channel of its head.
+        from_start, end_decay = jnp.exp(within), jnp.exp(to_end)
+        start_column = from_start[..., None]    # for the solve's d_k channels
         carried, decay_min = jnp.exp(whole)[..., None], whole.min()
 
     with jax.named_scope(f"{scope}_chunk"):
@@ -367,10 +607,11 @@ def _head_decay_rule(q, k, v, log_alpha, beta, chunk: int, scope: str):
         a = jnp.where(earlier, bc * decay * kk, 0.0)
         qk = (decay * qk).astype(dtype)
         wide_k = of_value_heads(kc.astype(f32))
-        q_in = (of_value_heads(qc.astype(f32)) * from_start).astype(dtype)
-        k_end = (wide_k * end_decay).astype(dtype)
-    return _solved_and_carried(a, bc, wide_k, from_start, vc, q_in, qk, k_end,
-                               carried, seq, scope), decay_min
+    w, u0 = _solved(a, bc, wide_k, start_column, vc, dtype, scope)
+    with jax.named_scope(f"{scope}_carry"):
+        o = _head_carry(w, u0, qk, qc, kc, from_start, end_decay, carried,
+                        f"{scope}_carry", jax.default_backend() != "tpu")
+        return _to_tokens(o, seq), decay_min
 
 
 def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int,
@@ -475,5 +716,7 @@ def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int,
         qk = jnp.where(lower, against_earlier(qc), 0.0).astype(dtype)
         q_in = (qc * decayed).astype(dtype)
         k_end = (kc * end_decay).astype(dtype)
-    return _solved_and_carried(a, bc, kc, decayed, vc, q_in, qk, k_end,
-                               carried, seq, scope), decay_min
+    w, u0 = _solved(a, bc, kc, decayed, vc, dtype, scope)
+    with jax.named_scope(f"{scope}_carry"):
+        return _to_tokens(_carry(w, u0, q_in, qk, k_end, carried),
+                          seq), decay_min

@@ -304,6 +304,14 @@ def test_gated_delta_layers_name_their_stages_under_scopes_of_their_own():
                        for path in paths), (direction, scope)
     under = [path for path in paths if "/hvd_gdn_scan/" in path]
     assert len(under) > 50
+    # The recurrence's two kernels (here the interpreter's loops over their
+    # grids) lie under the carry's scope, each in its own direction.
+    for direction, kernel in ((FORWARD, "fwd"), (BACKWARD, "bwd")):
+        named = [path for path in under if f"/hvd_gdn_scan_carry_{kernel}/"
+                 in path]
+        assert named and all(
+            direction in path and "/hvd_gdn_scan_carry/" in path
+            for path in named), (direction, named[:3])
     for path in under:
         assert len(set(_GDN_STAGE.findall(path))) == 1, path
     # In a `gated_delta` layer nothing but the layer's norm and its residual

@@ -254,6 +254,36 @@ def test_flash_head128_at_olmoe_shape_compiles(v5e):
     assert text.count("tpu_custom_call") == 2
 
 
+def test_delta_rule_carry_kernels_at_qwen3next_widths_compile(v5e,
+                                                              monkeypatch):
+    """The head form's recurrence as the Qwen3-Next cell runs it — heads of
+    128 channels, two value heads a key head, chunks of 64, bfloat16 — through
+    the chip's compiler, forward and backward: two Mosaic kernels and no
+    loop, as `lowered_plan` says (the rule asks the backend which way to run
+    its kernels; here it is compiling for the described chip)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.ops.delta_rule import chunked_delta_rule, lowered_plan
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    on_chip = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((1, 512, 2, 128), jnp.bfloat16, sharding=on_chip)
+    v = jax.ShapeDtypeStruct((1, 512, 4, 128), jnp.bfloat16, sharding=on_chip)
+    gate = jax.ShapeDtypeStruct((1, 512, 4), jnp.float32, sharding=on_chip)
+
+    def loss(*operands):
+        return chunked_delta_rule(*operands, 64, scope="hvd_gdn_scan")[0].sum()
+
+    text = jax.jit(jax.grad(loss, range(5))).lower(
+        q, q, v, gate, gate).compile().as_text()
+    plan = lowered_plan(512, 64)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") \
+        == plan["tpu_custom_call"] == 2
+    assert text.count(" while(") == plan["while"] == 0
+    for kernel in ("hvd_gdn_scan_carry_fwd", "hvd_gdn_scan_carry_bwd"):
+        assert f"%{kernel}" in text, kernel
+
+
 def test_flash_two_widths_at_latent_attention_shape_compile(v5e):
     """Latent attention as the Ling-3.0-flash cell runs it — 4 heads, 8,192
     tokens, query and key 192 wide, value 128 — forward and backward through

@@ -32,6 +32,7 @@ from horovod_tpu.models import (DeltaConfig, DeltaMixer,
                                 MoEConfig, TransformerLM)
 from horovod_tpu.models.transformer import (LAYER_KINDS, Attention,
                                             SparseExperts, rope)
+from horovod_tpu.ops import delta_rule
 from horovod_tpu.ops.delta_rule import chunked_delta_rule, lowered_plan
 from tests.test_hybrid import (both_ways, close, columns, mixer_case, seeded,
                                system_loss, trees_close, with_highest)
@@ -72,7 +73,8 @@ def reference_config(expert_shard=(0, 1), **more):
 
 # --- the delta rule with a decay a head over grouped heads ------------------
 
-def rule_inputs(seed, seq=SEQ, low=-20.0, d_k=16, d_v=8):
+def rule_inputs(seed, seq=SEQ, low=-20.0, d_k=16, d_v=8,
+                key_heads=KEY_HEADS):
     """Unit keys, queries at d_k^-1/2, a value head's log-decay from 0 down to
     `low` a step — every seventh token AT `low` in every head, so that at -20
     a chunk's decay underflows (this gate has no bound) while its neighbours
@@ -81,7 +83,7 @@ def rule_inputs(seed, seq=SEQ, low=-20.0, d_k=16, d_v=8):
     heads = (2, seq, VALUE_HEADS)
 
     def unit(key):
-        t = jax.random.normal(key, (2, seq, KEY_HEADS, d_k))
+        t = jax.random.normal(key, (2, seq, key_heads, d_k))
         return t / jnp.linalg.norm(t, axis=-1, keepdims=True)
 
     q, k = unit(keys[0]) * d_k ** -0.5, unit(keys[1])
@@ -136,6 +138,104 @@ def test_head_decay_rule_gradients_are_the_recurrences(seq, chunk, low):
     for g, w in zip(got, want):
         assert g.shape == w.shape and bool(jnp.isfinite(g).all())
         close(g, w, 1e-4)
+
+
+def rule_and_gradients(fn, args, mix):
+    return jax.jit(jax.value_and_grad(
+        lambda *a: (fn(*a) * mix).sum(), argnums=range(5)))(*args)
+
+
+# What the kernels of the carry meet beside RULE_CASES: a single chunk (whole,
+# and one the sequence does not fill), a padded tail behind several chunks, a
+# value head a key head, four value heads a key head.
+KERNEL_CASES = [(64, 64, KEY_HEADS), (48, 64, KEY_HEADS), (150, 32, KEY_HEADS),
+                (128, 32, VALUE_HEADS), (96, 32, 1)]
+
+
+@pytest.mark.parametrize("seq,chunk,key_heads", KERNEL_CASES)
+def test_carry_kernels_are_the_recurrence(seq, chunk, key_heads):
+    """The head form's two kernels (the Pallas interpreter runs their bodies
+    here) against the rule one step a token: `o` and the gradients of all
+    five operands."""
+    args, mix = rule_inputs(seq + key_heads, seq, -2.0, key_heads=key_heads)
+    got = rule_and_gradients(
+        lambda *a: chunked_delta_rule(*a, chunk, scope="hvd_gdn_scan")[0],
+        args, mix)
+    want = rule_and_gradients(token_by_token, args, mix)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4)
+    for g, w in zip(got[1], want[1]):
+        assert g.shape == w.shape and bool(jnp.isfinite(g).all())
+        close(g, w, 1e-4)
+
+
+def test_carry_kernels_under_shard_map_are_the_recurrence():
+    """Head-sharded, as a tensor-parallel mixer would call it: each of two
+    devices runs the kernels on its key head and that head's value heads, the
+    kernels' scratch and outputs varying over the mesh axis as their operands
+    do."""
+    args, mix = rule_inputs(3, 96, -2.0)
+    mesh = Mesh(np.array(jax.devices()[:KEY_HEADS]), ("heads",))
+    by_head = P(None, None, "heads")
+
+    def sharded(*a):
+        return jax.shard_map(
+            lambda *t: chunked_delta_rule(*t, 32, scope="hvd_gdn_scan")[0],
+            mesh=mesh, in_specs=(by_head,) * 5, out_specs=by_head)(*a)
+
+    got = rule_and_gradients(sharded, args, mix)
+    want = rule_and_gradients(token_by_token, args, mix)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4)
+    for g, w in zip(got[1], want[1]):
+        close(g, w, 1e-4)
+
+
+@pytest.mark.parametrize("chunks,per_key", [(3, 2), (1, 2), (4, 1)])
+def test_head_carry_is_the_loops_carry_fed_the_same_operands(chunks, per_key):
+    """`_head_carry` (the kernels: q and k a key head, the decays as vectors)
+    against `_carry` (the channel form's loops: the decayed Q and K written a
+    value head), `O` and the cotangents of all eight operands, to float32
+    rounding."""
+    batch, key_heads, chunk, d_k, d_v = 2, 2, 16, 16, 8
+    heads = key_heads * per_key
+    keys = jax.random.split(jax.random.PRNGKey(chunks + per_key), 9)
+    of_values, of_keys = (batch, chunks, heads), (batch, chunks, key_heads)
+    w = 0.3 * jax.random.normal(keys[0], of_values + (chunk, d_k))
+    u0 = jax.random.normal(keys[1], of_values + (chunk, d_v))
+    qk = 0.3 * jax.random.normal(keys[2], of_values + (chunk, chunk))
+    q = jax.random.normal(keys[3], of_keys + (chunk, d_k))
+    k = 0.3 * jax.random.normal(keys[4], of_keys + (chunk, d_k))
+    from_start, end_decay = (jax.random.uniform(
+        key, of_values + (chunk,), minval=0.2) for key in keys[5:7])
+    carried = jax.random.uniform(keys[7], of_values + (1,), minval=0.2)
+    mix = jax.random.normal(keys[8], of_values + (chunk, d_v))
+
+    def kernels(*a):
+        return (delta_rule._head_carry(*a, "hvd_gdn_scan_carry", True)
+                * mix).sum()
+
+    def loops(w, u0, qk, q, k, from_start, end_decay, carried):
+        q, k = (jnp.repeat(t, per_key, axis=2) for t in (q, k))
+        return (delta_rule._carry(
+            w, u0, q * from_start[..., None], qk, k * end_decay[..., None],
+            carried) * mix).sum()
+
+    operands = (w, u0, qk, q, k, from_start, end_decay, carried)
+    got = jax.jit(jax.value_and_grad(kernels, range(8)))(*operands)
+    want = jax.jit(jax.value_and_grad(loops, range(8)))(*operands)
+    np.testing.assert_allclose(got[0], want[0], rtol=RTOL)
+    for g, w in zip(got[1], want[1]):
+        assert g.shape == w.shape
+        close(g, w)
+
+
+def test_channel_form_lowers_to_its_two_loops_and_no_kernel():
+    """Ling's cell pins its `while`s and custom calls inside `correct`: a
+    four-axis `log_alpha` takes the XLA `_carry`, whatever the backend."""
+    q, k, v, log_alpha, beta = repeated(*rule_inputs(0, low=-5.0)[0])
+    text = jax.jit(jax.grad(lambda *a: chunked_delta_rule(
+        *a, 32)[0].sum(), range(5))).lower(q, k, v, log_alpha, beta).as_text()
+    assert text.count("stablehlo.while") == 2
+    assert "custom_call" not in text and "hvd_gdn" not in text
 
 
 @pytest.mark.parametrize("chunk", [16, 64])
@@ -207,19 +307,43 @@ def test_head_decay_rule_refuses_heads_it_cannot_group(values, heads):
                            jnp.zeros((1, 32, values)), 16)
 
 
+@pytest.mark.parametrize("form", ["head", "channel"])
 @pytest.mark.parametrize("seq,chunk,loops", [(4096, 64, 2), (100, 32, 2),
                                              (64, 64, 0), (48, 64, 0)])
-def test_lowered_plan_counts_the_rules_loops(seq, chunk, loops):
-    """The plan against the compiled program: a `while` forward and one
-    backward where the recurrence has more than one step (a single chunk's
-    loop is unrolled)."""
-    assert lowered_plan(seq, chunk) == {"while": loops, "tpu_custom_call": 0}
+def test_lowered_plan_counts_the_rules_loops(seq, chunk, loops, form):
+    """The plan against the program.  A decay a channel: a `while` forward and
+    one backward where the recurrence has more than one step (a single
+    chunk's loop is unrolled), no kernel.  A decay a head, which is what the
+    plan describes unless told the form: two kernels and no loop, at any
+    length — read from the jaxpr, because off a TPU the interpreter runs the
+    kernels' grids as loops of its own."""
+    channel = form == "channel"
+    assert lowered_plan(seq, chunk, form) == {
+        "while": loops if channel else 0,
+        "tpu_custom_call": 0 if channel else 2}
+    assert lowered_plan(seq, chunk) == lowered_plan(seq, chunk, "head")
     if seq > 128:             # the cell's length: the plan alone
         return
     args, mix = rule_inputs(0, seq)
-    text = jax.jit(jax.grad(lambda *a: (chunked_delta_rule(
-        *a, chunk)[0] * mix).sum(), range(5))).lower(*args).compile().as_text()
-    assert text.count(" while(") == loops
+    if channel and seq % chunk:
+        return                # the channel form takes whole chunks alone
+    grad = jax.grad(lambda *a: (chunked_delta_rule(*a, chunk)[0] * mix).sum(),
+                    range(5))
+    if channel:
+        text = jax.jit(grad).lower(*repeated(*args)).compile().as_text()
+        assert text.count(" while(") == loops
+        return
+    from tests.test_ops import _pallas_call_names
+
+    jaxpr = jax.make_jaxpr(grad)(*args)
+    assert sorted(_pallas_call_names(jaxpr.jaxpr)) == [
+        "hvd_kda_scan_carry_bwd", "hvd_kda_scan_carry_fwd"]
+    assert not re.search(r"\b(scan|while)\[", str(jaxpr))
+
+
+def test_lowered_plan_refuses_a_form_it_does_not_know():
+    with pytest.raises(ValueError, match="neither"):
+        lowered_plan(64, 64, "row")
 
 
 # --- each mixer against the reference's -------------------------------------
