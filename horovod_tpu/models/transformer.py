@@ -123,6 +123,58 @@ def _qkv_project_bwd(res, cots):
 _qkv_project.defvjp(_qkv_project_fwd, _qkv_project_bwd)
 
 
+def _gate_and_gated(out, x, w):
+    """(``out * sigmoid(x w)``, the gate itself), both rounded once to
+    ``out``'s dtype: the product accumulates in float32, and the sigmoid and
+    the multiply are float32 in its epilogue."""
+    gate = nn.sigmoid(jnp.einsum("bsd,dhe->bhse", x, w,
+                                 preferred_element_type=jnp.float32))
+    return (out * gate).astype(out.dtype), gate.astype(out.dtype)
+
+
+@jax.custom_vjp
+def _output_gated(out, x, w):
+    """``out * sigmoid(x w)``: the kernels' output ``out`` (b, h, s, e) times
+    the gate of the layer's input ``x`` (b, s, d) through ``w`` (d, h, e), all
+    three in the layer's dtype, which the result has too.
+
+    Under plain autodiff the float32 gate (b, h, s, e) is written, kept for
+    the backward, and met there by three more float32 passes of its size
+    (``dy * gate``, ``dy * out``, the sigmoid's derivative) before two
+    products that read a float32 operand: at 32 heads of 128 on 8,192 rows
+    five arrays of 134 MB a layer.  Written out, the backward keeps the gate
+    ROUNDED to the layer's dtype, as every other saved activation is, makes
+    ``d_out`` and ``dz`` in one pass (float32 inside, rounded once) and hands
+    ``dz`` to two plain products."""
+    return _gate_and_gated(out, x, w)[0]
+
+
+def _output_gated_fwd(out, x, w):
+    # Both written by the product's own fusion: unheld, XLA writes the float32
+    # gate instead and rounds it again in each of its three readers.
+    gated, gate = lax.optimization_barrier(_gate_and_gated(out, x, w))
+    return gated, (out, x, w, gate)
+
+
+def _output_gated_bwd(res, d_gated):
+    out, x, w, gate = res
+    wide = d_gated.astype(jnp.float32)
+    g = gate.astype(jnp.float32)
+    d_out = (wide * g).astype(out.dtype)
+    dz = (wide * out.astype(jnp.float32) * (g * (1.0 - g))).astype(out.dtype)
+    # Written, both by the one pass that reads dy, out and the gate: left to
+    # itself XLA computes dz again inside each product that reads it (an
+    # element-wise producer in a weight-gradient fusion: `_head_norm`).
+    d_out, dz = lax.optimization_barrier((d_out, dz))
+    dx = jnp.einsum("bhse,dhe->bsd", dz, w)
+    dw = jnp.einsum("bsd,bhse->dhe", x, dz)
+    return (reduced_to_vma_of(out, d_out), reduced_to_vma_of(x, dx),
+            reduced_to_vma_of(w, dw))
+
+
+_output_gated.defvjp(_output_gated_fwd, _output_gated_bwd)
+
+
 def _pair_swap(d: int, dtype):
     """The signed permutation ``S`` (d, d) of the last axis that swaps a
     pair's two components: ``(x @ S)[2i] = -x[2i+1]``, ``(x @ S)[2i+1] =
@@ -574,7 +626,8 @@ class Attention(nn.Module):
     # projection at once).
     head_norm: bool = False
     # An output gate: ``concat_h(o_h) * sigmoid(x W_g)`` elementwise, ``W_g``
-    # as wide as the query projection, before the output projection.
+    # as wide as the query projection, before the output projection
+    # (:func:`_output_gated`, whose backward is written out).
     gate: bool = False
     # Block diffusion's training pass: the rows are ``[clean; noised]``, two
     # copies of ``seq / 2`` positions each; row ``i`` turns at position ``i
@@ -735,11 +788,8 @@ class Attention(nn.Module):
                     in_axis=0, out_axis=(1, 2)),
                 (d, n_heads, head_dim), jnp.float32)
             with jax.named_scope("hvd_attn_gate"):
-                gate = nn.sigmoid(jnp.einsum(
-                    "bsd,dhe->bhse", x.astype(self.dtype),
-                    w_g.astype(self.dtype),
-                    preferred_element_type=jnp.float32))
-                out = (out * gate).astype(self.dtype)
+                out = _output_gated(out, x.astype(self.dtype),
+                                    w_g.astype(self.dtype))
         w_o = self.param(
             "o_kernel",
             nn.initializers.lecun_normal(in_axis=(0, 1), out_axis=2),

@@ -921,6 +921,65 @@ def test_trinity_step_is_banded_and_causal_kernels_and_a_named_gate(
                "hvd_embed", "hvd_lm_head"))
 
 
+@pytest.mark.parametrize("heads,kv_heads,head_dim",
+                         [(32, 4, 128), (16, 2, 256)],
+                         ids=["trinity", "qwen3next"])
+def test_gated_attention_writes_no_float32_array_of_the_kernels_output(
+        v5e, monkeypatch, heads, kv_heads, head_dim):
+    """`Attention(gate=True)` at the two cells' heads over 2,048 rows, forward
+    and backward, compiled for the described chip: no instruction under
+    `hvd_attn_gate` writes a float32 array of the kernels' output's size (the
+    float32 gate plain autodiff kept, 134 MB a layer in the Trinity cell);
+    the gate's product writes the gated output and the rounded gate from one
+    fusion, and the backward's two products are there under the scope."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.models.transformer import Attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    seq = 2048
+    layer = Attention(heads, jnp.bfloat16, use_flash=True, n_kv_heads=kv_heads,
+                      head_dim=head_dim, head_norm=True, gate=True)
+    on_chip = SingleDeviceSharding(v5e[0])
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=on_chip), tree)
+
+    # A hidden width that is not the rows: the weight's float32 gradient
+    # then has a size of its own.
+    x = jax.ShapeDtypeStruct((1, seq, 1024), jnp.bfloat16, sharding=on_chip)
+    params = shaped(jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0),
+                           jnp.zeros(x.shape, x.dtype))["params"]))
+
+    def loss(params, x):
+        with jax.named_scope("hvd_loss"):
+            return layer.apply({"params": params}, x).astype(
+                jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    _assert_scopes_forward_and_backward(text, ("hvd_attn_gate",))
+    entry = text[text.index("ENTRY "):]
+    under = re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) [\w\-]+\(.*"
+                       r"op_name=\"([^\"]*/hvd_attn_gate/[^\"]*)\"", entry,
+                       re.M)
+    for result, op_name in under:
+        for dims in re.findall(r"f32\[([\d,]+)\]", result):
+            assert np.prod([int(n) for n in dims.split(",")]) \
+                != heads * seq * head_dim, (result, op_name)
+    kernels_output = (rf"bf16\[(?:1,)?{heads},"
+                      rf"(?:{seq},{head_dim}|{head_dim},{seq})\]")
+    assert [result for result, op_name in under
+            if "/jvp(hvd_loss)/" in op_name
+            and len(re.findall(kernels_output, result)) == 2], under
+    for product in ("bhse,dhe->bsd", "bsd,bhse->dhe"):
+        assert any(f"hvd_attn_gate/{product}/dot_general" in op_name
+                   and "transpose(" in op_name
+                   for _, op_name in under), product
+
+
 @pytest.mark.parametrize("plan", ["combined", "split"])
 @pytest.mark.parametrize("block", [4, 32, 96])
 def test_blockdiff_flash_at_sdar_shape_compiles(v5e, monkeypatch, plan,

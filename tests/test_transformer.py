@@ -248,6 +248,141 @@ def test_qkv_project_custom_vjp_matches_autodiff():
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
 
+# --- the output gate: `_output_gated` against the expression it replaced -----
+
+
+def _plain_gated(out, x, w):
+    """``Attention(gate=True)``'s gate as it stood until PR 48: the float32
+    gate under plain autodiff."""
+    gate = jax.nn.sigmoid(jnp.einsum("bsd,dhe->bhse", x, w,
+                                     preferred_element_type=jnp.float32))
+    return (out * gate).astype(out.dtype)
+
+
+def _gate_operands(dtype, heads, head_dim, seq=48, d=64, seed=0):
+    rng = np.random.RandomState(seed)
+    return (jnp.asarray(rng.randn(2, heads, seq, head_dim), dtype),
+            jnp.asarray(rng.randn(2, seq, d), dtype),
+            jnp.asarray(rng.randn(d, heads, head_dim) * d ** -0.5, dtype))
+
+
+@pytest.mark.parametrize("head_dim", [128, 256])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=str)
+def test_output_gate_forward_is_the_plain_expression_to_the_bit(dtype,
+                                                                head_dim):
+    from horovod_tpu.models.transformer import _output_gated
+
+    operands = _gate_operands(dtype, 4, head_dim)
+    got = jax.jit(_output_gated)(*operands)
+    # The value a backward pass is built on is the same one.
+    kept, _ = jax.jit(lambda *a: jax.vjp(_output_gated, *a))(*operands)
+    want = jax.jit(_plain_gated)(*operands)
+    assert got.dtype == want.dtype == dtype
+    for value in (got, kept):
+        np.testing.assert_array_equal(np.asarray(value, np.float32),
+                                      np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("heads,head_dim", [(4, 128), (2, 256)])
+def test_output_gate_backward_matches_autodiff(heads, head_dim):
+    """out's, x's and w_g's gradients against `jax.grad` of the plain float32
+    expression: in float32 within `_qkv_project`'s tolerances; in bfloat16
+    (the gate kept rounded, `d_out` and `dz` rounded once) as near the
+    float32 gradients as plain autodiff on the same bfloat16 operands is."""
+    from horovod_tpu.models.transformer import _output_gated
+
+    out, x, w = _gate_operands(jnp.float32, heads, head_dim, seed=1)
+    mix = jnp.asarray(np.random.RandomState(2).randn(*out.shape), jnp.float32)
+
+    def grads(fn, *operands):
+        return jax.jit(jax.grad(lambda *a: (
+            fn(*a).astype(jnp.float32) * mix).sum(), argnums=(0, 1, 2)))(
+                *operands)
+
+    want = grads(_plain_gated, out, x, w)
+    for a, b in zip(grads(_output_gated, out, x, w), want):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+    def off(got):
+        return max(float(jnp.linalg.norm(a.astype(jnp.float32) - b)
+                         / jnp.linalg.norm(b)) for a, b in zip(got, want))
+
+    low = [t.astype(jnp.bfloat16) for t in (out, x, w)]
+    ours, autodiffs = off(grads(_output_gated, *low)), off(
+        grads(_plain_gated, *low))
+    assert ours < 1.25 * autodiffs < 0.02, (ours, autodiffs)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=str)
+@pytest.mark.parametrize("kv_heads", [None, 2], ids=["ungrouped", "grouped"])
+@pytest.mark.parametrize("head_dim", [128, 256])
+def test_gated_attention_is_the_layer_with_the_plain_gate(
+        monkeypatch, head_dim, kv_heads, dtype):
+    """`Attention(gate=True)` whole: its forward to the bit, and in float32
+    its input's and every parameter's gradient, against the same layer with
+    the gate under plain autodiff."""
+    import horovod_tpu.models.transformer as transformer
+
+    layer = transformer.Attention(4, dtype, use_flash=False, head_dim=head_dim,
+                                  n_kv_heads=kv_heads, head_norm=True,
+                                  gate=True)
+    x = jnp.asarray(np.random.RandomState(3).randn(2, 32, 64), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(4), x)["params"]
+    assert params["gate_kernel"].shape == (64, 4, head_dim)
+
+    def value_and_grads():
+        return jax.value_and_grad(
+            lambda p, x: layer.apply({"params": p}, x).astype(
+                jnp.float32).sum(), argnums=(0, 1))(params, x)
+
+    def forward():
+        return np.asarray(layer.apply({"params": params}, x), np.float32)
+
+    got, got_loss_and_grads = forward(), value_and_grads()
+    monkeypatch.setattr(transformer, "_output_gated", _plain_gated)
+    np.testing.assert_array_equal(got, forward())
+    if dtype == jnp.float32:
+        jax.tree.map(
+            lambda a, b: np.testing.assert_allclose(a, b, atol=1e-4,
+                                                    rtol=1e-4),
+            got_loss_and_grads, value_and_grads())
+
+
+def test_output_gate_keeps_and_hands_on_the_layers_dtype_alone():
+    """What crosses `_output_gated`'s edges in bfloat16: the residuals (out,
+    x, w_g and the ROUNDED gate, nothing float32), the two arrays the backward
+    writes before its products (one barrier, both bfloat16) and the products'
+    operands and results.  What the chip's compiler makes of it is in
+    `tests/test_ops.py` (`test_gated_attention_writes_no_float32_array_...`):
+    the CPU backend computes bfloat16 element-wise passes in float32 and keeps
+    no barrier, so its text shows nothing of this."""
+    from horovod_tpu.models.transformer import (_output_gated_bwd,
+                                                _output_gated_fwd)
+
+    operands = _gate_operands(jnp.bfloat16, 4, 128)
+    gated, kept = jax.eval_shape(_output_gated_fwd, *operands)
+    assert [leaf.dtype for leaf in kept] == [jnp.bfloat16] * 4
+    assert [leaf.shape for leaf in kept] == [
+        operands[0].shape, operands[1].shape, operands[2].shape,
+        operands[0].shape]
+    jaxpr = jax.make_jaxpr(_output_gated_bwd)(kept, gated).jaxpr
+    barriers = [eqn for eqn in jaxpr.eqns
+                if eqn.primitive.name == "optimization_barrier"]
+    assert len(barriers) == 1
+    written = barriers[0].outvars
+    assert [(v.aval.shape, v.aval.dtype) for v in written] \
+        == [(operands[0].shape, jnp.bfloat16)] * 2
+    products = [eqn for eqn in jaxpr.eqns
+                if eqn.primitive.name == "dot_general"]
+    assert len(products) == 2
+    for eqn in products:
+        assert written[1] in eqn.invars          # dz, as the barrier wrote it
+        assert {v.aval.dtype for v in eqn.invars + eqn.outvars} \
+            == {jnp.dtype(jnp.bfloat16)}
+    assert jaxpr.outvars[0] is written[0]        # d_out
+    assert [v.aval.dtype for v in jaxpr.outvars] == [jnp.bfloat16] * 3
+
+
 # --- rope: the pairs' rotation with the head's width kept in the last axis --
 # against the formula it had until PR 42, kept here as the plain reference.
 
