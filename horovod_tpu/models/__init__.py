@@ -27,6 +27,7 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     LatentAttention,
     LatentConfig,
     MoEConfig,
+    RopeScaling,
     TransformerLM,
     masked_diffusion_loss,
     moe_next_token_loss,

@@ -187,12 +187,58 @@ def _pair_swap(d: int, dtype):
     return jnp.asarray(swap, dtype)
 
 
-def _turned(x, positions, base, seq_dim, rotary_dim, back: bool):
+class RopeScaling(NamedTuple):
+    """YaRN's scaled rotary frequencies (arXiv:2309.00071), a static
+    description :func:`rope` turns into a table at trace time: a model
+    trained to ``original_positions`` reads ``factor`` times as far.  A pair
+    that turns more than ``beta_fast`` times within ``original_positions``
+    keeps its frequency, one that turns fewer than ``beta_slow`` times has it
+    divided by ``factor``, and the pairs between take a linear ramp between
+    the two (:func:`yarn_frequencies`).  Cosine and sine are multiplied by
+    ``attention_factor`` (the scores of a layer whose q and k both turn
+    carry its square); ``None``: ``0.1 ln(factor) + 1``."""
+
+    factor: float
+    original_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+    @property
+    def magnitude(self) -> float:
+        if self.attention_factor is not None:
+            return float(self.attention_factor)
+        return 0.1 * float(np.log(self.factor)) + 1.0
+
+
+def yarn_frequencies(base: float, pairs: int, scaling: RopeScaling):
+    """(frequencies (pairs,) float64, low, high) of ``scaling`` over the
+    plain ``base ** (-i / pairs)``: with ``c(r) = pairs ln(original_positions
+    / (2 pi r)) / ln(base)`` the pair that turns ``r`` times within the
+    original positions, ``low = floor(c(beta_fast))`` and ``high =
+    ceil(c(beta_slow))`` (both within the head), pair ``i`` keeps ``1 -
+    ramp_i`` of its plain frequency and takes ``ramp_i`` of the one divided
+    by ``factor``, ``ramp_i = clip((i - low) / (high - low), 0, 1)``."""
+    plain = float(base) ** (-np.arange(pairs, dtype=np.float64) / pairs)
+
+    def pair_turning(times):
+        return pairs * np.log(scaling.original_positions
+                              / (2 * np.pi * times)) / np.log(float(base))
+
+    low = max(int(np.floor(pair_turning(scaling.beta_fast))), 0)
+    high = min(int(np.ceil(pair_turning(scaling.beta_slow))), 2 * pairs - 1)
+    ramp = np.clip((np.arange(pairs) - low) / max(high - low, 1e-3), 0, 1)
+    return plain / scaling.factor * ramp + plain * (1 - ramp), low, high
+
+
+def _turned(x, positions, base, seq_dim, rotary_dim, scaling, back: bool):
     """``x``'s adjacent pairs turned by their angles (``back``: by the
     negative angles), float32 inside, rounded once; every operand keeps
     ``x``'s last axis.  With ``rotary_dim`` only the first ``rotary_dim``
     channels turn, at the frequencies of a head that wide; the others pass
-    (an angle of zero)."""
+    (an angle of zero).  With ``scaling`` the frequencies are its table's
+    (a constant of the program, whatever the sequence length) and cosine
+    and sine carry its factor."""
     d = x.shape[-1]
     turning = d if rotary_dim is None else rotary_dim
     if d % 2 or turning % 2 or not 0 < turning <= d:
@@ -200,8 +246,13 @@ def _turned(x, positions, base, seq_dim, rotary_dim, back: bool):
                          f"and rotary_dim ({rotary_dim}) must be even, "
                          "rotary_dim within the axis")
     # (d,): each frequency written twice, beside the pair it turns.
-    freqs = base ** (-(jnp.arange(d) // 2).astype(jnp.float32)
-                     / (turning // 2))
+    if scaling is None:
+        freqs = base ** (-(jnp.arange(d) // 2).astype(jnp.float32)
+                         / (turning // 2))
+    else:
+        table = yarn_frequencies(base, turning // 2, scaling)[0]
+        freqs = jnp.asarray(np.repeat(np.pad(
+            table, (0, (d - turning) // 2)), 2), jnp.float32)
     if rotary_dim is not None:
         freqs = jnp.where(jnp.arange(d) < turning, freqs, 0.0)
     angles = positions[..., None].astype(jnp.float32) * freqs
@@ -212,15 +263,20 @@ def _turned(x, positions, base, seq_dim, rotary_dim, back: bool):
     shape[-1] = d
     cos = jnp.cos(angles).reshape(shape)
     sin = jnp.sin(-angles if back else angles).reshape(shape)
+    if scaling is not None:
+        # On the channels that turn alone: the others pass as they are.
+        factor = jnp.where(jnp.arange(d) < turning, scaling.magnitude, 1.0)
+        cos, sin = cos * factor, sin * scaling.magnitude
     swapped = jnp.einsum("...d,de->...e", x, _pair_swap(d, x.dtype),
                          precision=lax.Precision.HIGHEST,
                          preferred_element_type=jnp.float32)
     return (x.astype(jnp.float32) * cos + swapped * sin).astype(x.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
 def rope(x, positions, base: float = 10000.0, seq_dim: int = -2,
-         rotary_dim: Optional[int] = None):
+         rotary_dim: Optional[int] = None,
+         scaling: Optional[RopeScaling] = None):
     """Rotary position embedding, ADJACENT-pair formulation: component
     pairs ``(x[2i], x[2i+1])`` rotate by the i-th frequency, in float32,
     rounded once to ``x.dtype``.  (The [even half | odd half] pairing is the
@@ -249,17 +305,22 @@ def rope(x, positions, base: float = 10000.0, seq_dim: int = -2,
     the first ``rotary_dim`` channels of the last axis turn as a head of that
     width would, the rest pass unchanged; the tensor keeps its whole last axis
     all the same (the channels that pass meet a cosine of one and a sine of
-    zero), so nothing is sliced or joined.  ``None``: all of it."""
-    return _turned(x, positions, base, seq_dim, rotary_dim, back=False)
+    zero), so nothing is sliced or joined.  ``None``: all of it.
+    ``scaling``: a :class:`RopeScaling` in place of ``base``'s plain
+    frequencies — a table computed at trace time, its attention factor on
+    cosine and sine, forward and backward; ``None``: ``base`` alone, the
+    program it was before there was one."""
+    return _turned(x, positions, base, seq_dim, rotary_dim, scaling,
+                   back=False)
 
 
-def _rope_fwd(x, positions, base, seq_dim, rotary_dim):
-    return _turned(x, positions, base, seq_dim, rotary_dim,
+def _rope_fwd(x, positions, base, seq_dim, rotary_dim, scaling):
+    return _turned(x, positions, base, seq_dim, rotary_dim, scaling,
                    back=False), positions
 
 
-def _rope_bwd(base, seq_dim, rotary_dim, positions, d_out):
-    return _turned(d_out, positions, base, seq_dim, rotary_dim,
+def _rope_bwd(base, seq_dim, rotary_dim, scaling, positions, d_out):
+    return _turned(d_out, positions, base, seq_dim, rotary_dim, scaling,
                    back=True), None
 
 
@@ -595,8 +656,10 @@ class Attention(nn.Module):
     n_kv_heads: Optional[int] = None
     # False: no rotary embedding (a model whose other layers carry position).
     rope: bool = True
-    # The rotary base (:func:`rope`'s).
+    # The rotary base (:func:`rope`'s), and its scaled frequencies
+    # (:class:`RopeScaling`; ``None``: the base's own).
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None
     # A partial rotation (:func:`rope`'s ``rotary_dim``): the first
     # ``rotary_dim`` channels of a head turn, the rest pass.  ``None``: the
     # whole head.  Training only: no ring, no cached decode.
@@ -703,7 +766,7 @@ class Attention(nn.Module):
                 return t
             with jax.named_scope("hvd_attn_rotate"):
                 return rope(t, positions, self.rope_theta, -2,
-                            self.rotary_dim)
+                            self.rotary_dim, self.rope_scaling)
 
         grouped = self.n_kv_heads is not None or self.head_shard != (0, 1)
         with jax.named_scope("hvd_attn_qkv"):
@@ -984,6 +1047,41 @@ class Block(nn.Module):
         return x if new_kv is None else (x, new_kv)
 
 
+def _kept_by_a_recomputing_layer(primitive, *_, **params) -> bool:
+    """The ``jax.checkpoint`` policy of ``MixerLayer(recompute=True)``: what
+    a recomputing layer keeps of its forward pass beside its input.
+
+    - The router's DECISION, ``top_k``'s outputs (1 MB a layer at 16,384
+      tokens).  Not for its time: computed again, the probabilities need not
+      round as they did the first time (XLA fuses the second pass its own
+      way and may skip a rounding), a near-tie between the k-th and the next
+      expert then falls the other way, and ONE token that changes its expert
+      moves every later row of the sorted buffer.  Whatever else is kept by
+      buffer row would then be read in another order than it was written.
+    - The outputs of the grouped expert products (``ragged_dot_general``),
+      rows in that order.
+    - The outputs of the flash FORWARD kernels (a layer's output and its
+      rows' log-sum-exp, which the backward kernels read).
+
+    Everything else — norms, projections, rotations, the router's
+    probabilities, the rows' movement, every element-wise pass — is computed
+    again from the layer's input.
+
+    Decided by traces of the Mellum2 cell (16,384 tokens, four layers; my
+    chip runs, PR 49, PERF.md section 6): with NOTHING kept a step is 561 ms
+    at 9.36 GB, the forward kernels' second calls 31 ms of it; with their
+    outputs kept (0.69 GB) 530 ms; with the grouped products' kept too
+    (1.37 GB) 495 ms.  Kept WITHOUT the router's decision those products gave
+    gradients 0.49 from the reference's (0.023 is the system's) under a right
+    loss and a right gradient norm — the rows of one pass against the order
+    of the other — and with it 0.025.  A policy by primitive and not by
+    ``checkpoint_name``: a name is an equation in every model's program, a
+    recomputing one's or not, and this leaves the others' as they were."""
+    if primitive.name == "pallas_call":
+        return str(params["name"]).startswith("hvd_flash_fwd")
+    return primitive.name in ("top_k", "ragged_dot_general")
+
+
 # kind -> the module a MixerLayer of that kind runs.
 LAYER_KINDS = {"ssm": "Mamba2Mixer", "attention": "Attention",
                "experts": "SparseExperts", "delta": "DeltaMixer",
@@ -1011,7 +1109,19 @@ class MixerLayer(nn.Module):
     rotated where ``rope`` says.  All three take ``head_dim``, ``head_norm``,
     ``attn_gate`` and ``rotary_dim``.  ``"delta"`` is :class:`DeltaMixer`
     under its channel gate, ``"gated_delta"`` under its head gate over grouped
-    heads, both at ``delta``'s sizes.""")
+    heads, both at ``delta``'s sizes.  The rotated attention kinds turn at
+    ``rope_theta`` under ``rope_scaling``; ``window_rope``, a ``(theta,
+    scaling)`` pair, is the ``"window_attention"`` layers' own rotation where
+    it is another (a model whose full layers turn at YaRN's frequencies and
+    whose windowed ones at the plain ones).
+
+    ``recompute``: the layer's forward pass is computed again in the backward
+    pass (``jax.checkpoint`` around norm, mixer and residual) and only its
+    input ``x`` is kept between the two, with the router's decision and the
+    outputs of the grouped expert products and of the flash forward kernels
+    (:func:`_kept_by_a_recomputing_layer` has why).  Loss,
+    gradients and what the layer sows are the unset layer's:
+    the same operations in the same order, sown once.""")
 
     kind: str
     n_heads: int
@@ -1035,9 +1145,19 @@ class MixerLayer(nn.Module):
     block_diffusion: Optional[int] = None
     rope_theta: float = 10000.0
     rotary_dim: Optional[int] = None
+    rope_scaling: Optional[RopeScaling] = None
+    window_rope: Optional[Tuple[float, Optional[RopeScaling]]] = None
+    recompute: bool = False
 
     @nn.compact
     def __call__(self, x):
+        if self.recompute:
+            return nn.remat(MixerLayer._forward,
+                            policy=_kept_by_a_recomputing_layer)(self, x)
+        return self._forward(x)
+
+    @nn.nowrap
+    def _forward(self, x):
         h = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                        name="norm")(x)
         if self.kind == "ssm":
@@ -1053,12 +1173,15 @@ class MixerLayer(nn.Module):
             if diffusion and self.block_diffusion is None:
                 raise ValueError("a 'blockdiff_attention' layer wants "
                                  "block_diffusion=")
+            theta, scaling = self.window_rope \
+                if windowed and self.window_rope is not None \
+                else (self.rope_theta, self.rope_scaling)
             mixer = Attention(self.n_heads, self.dtype,
                               use_flash=self.use_flash, qk_norm=self.qk_norm,
                               norm_eps=self.norm_eps,
                               n_kv_heads=self.n_kv_heads,
                               rope=self.rope or windowed,
-                              rope_theta=self.rope_theta,
+                              rope_theta=theta, rope_scaling=scaling,
                               rotary_dim=self.rotary_dim,
                               head_shard=self.head_shard,
                               head_dim=self.head_dim,
@@ -1138,8 +1261,12 @@ class TransformerLM(nn.Module):
     # always rotated.  ``n_kv_heads`` and ``rope`` are the ``"attention"``
     # layers' as :class:`Attention` has them (so ``rope=False`` with a
     # ``window`` is full layers without rotation among rotated windowed
-    # ones), ``rope_theta`` every rotated pattern layer's rotary base and
-    # ``rotary_dim`` how much of a head they turn,
+    # ones), ``rope_theta`` every rotated pattern layer's rotary base,
+    # ``rope_scaling`` its scaled frequencies (:class:`RopeScaling`) and
+    # ``rotary_dim`` how much of a head they turn; ``window_rope``, a
+    # ``(theta, scaling)`` pair, is the ``"window_attention"`` layers' own
+    # rotation where it is not the ``"attention"`` layers' (unset, the two
+    # kinds agree: one ``rope_theta`` for both),
     # ``head_dim``, ``head_norm`` and ``attn_gate`` both attention
     # kinds' (:class:`Attention`'s ``head_dim``, ``head_norm``, ``gate``),
     # ``head_shard`` every head-carrying mixer's, ``post_norm`` every
@@ -1152,6 +1279,11 @@ class TransformerLM(nn.Module):
     # noised]``, both copies of every sequence in one pass of twice the
     # positions, and gives logits for the NOISED copy alone (``final_norm`` and
     # the head run on that half; :func:`masked_diffusion_loss` is its loss).
+    # ``recompute``: every pattern entry computes its forward pass again in
+    # the backward pass and keeps its input, its router's decision and the
+    # outputs of its grouped products and its flash forward kernel
+    # (:class:`MixerLayer`): activation memory for the time of what is
+    # computed twice.
     # A pattern trains on one sequence shard and has no cached decode: a
     # recurrent layer's state is no key/value cache.
     layers: Optional[Tuple[str, ...]] = None
@@ -1170,6 +1302,9 @@ class TransformerLM(nn.Module):
     block_diffusion: Optional[int] = None
     rope_theta: float = 10000.0
     rotary_dim: Optional[int] = None
+    rope_scaling: Optional[RopeScaling] = None
+    window_rope: Optional[Tuple[float, Optional[RopeScaling]]] = None
+    recompute: bool = False
 
     @nn.compact
     def __call__(self, tokens, targets=None, decode_ctx=None, noised=None):
@@ -1213,7 +1348,8 @@ class TransformerLM(nn.Module):
                            self.window, self.head_norm, self.attn_gate,
                            self.post_norm, self.block_diffusion,
                            self.rope_theta, self.rotary_dim,
-                           name=f"layer_{i}")(x)
+                           self.rope_scaling, self.window_rope,
+                           self.recompute, name=f"layer_{i}")(x)
         for i in range(0 if self.layers is not None else self.n_layers):
             block = Block(self.n_heads, d_ff, self.dtype, self.seq_axis,
                           self.use_flash, self.ring_impl, self.capture_kv,

@@ -1161,11 +1161,21 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
 
     ``mode`` is ``"combined"`` (one probability recompute per block,
     whole-seq dq scratch — preferred where it fits because it recomputes
-    once; every benchmark cell runs it, PERF.md section 3) or ``"split"``
-    (dkdv + dq kernel pair, O(block) scoped memory: full 1024-blocks
-    compile at every probed extreme — seq to 64k, bh to 256, d to 256).
-    Split against combined is not measured on this machine (PERF.md
-    section 7).
+    once; the benchmark's cells up to 8,192 rows of heads up to 128 run it,
+    PERF.md section 3) or ``"split"`` (dkdv + dq kernel pair, O(block)
+    scoped memory: full 1024-blocks compile at every probed extreme — seq to
+    64k, bh to 256, d to 256).  Three cells run the pair: Ling's (192 / 128
+    wide), Qwen3-Next's (256) and, at head 128, Mellum2's — 16,384 rows, bh
+    32, 1,024-blocks, causal and under a window of 1,024 (a band two tiles
+    wide).  There the causal pair takes 27.2 + 21.5 ms a layer for a forward
+    call's 19.2 and reads 57.4 % of the backward's roofline, the banded pair
+    29.0 % (31 tile pairs visited for an eighth of the causal mask's exact
+    pairs; my chip runs, PR 49, PERF.md section 5); the combined kernel reads
+    73.5 % at 8,192 rows in Trinity's cell (ledger, PR 44).  That is the
+    pair's second pass over the probabilities showing (2.5 forwards' work
+    counted, 3.5 done), at another length: the two at ONE shape are still
+    not measured on this machine (both compile at 8,192 rows, where
+    ``tests/test_ops.py`` forces the pair; no cell runs it there).
 
     ``d_v``: the width of v, do and dv where it is not ``d`` (q, k, dq, dk).
     The bands above are entered with the WIDER of the two — every probe of

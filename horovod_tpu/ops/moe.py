@@ -84,7 +84,9 @@ def top_choices(probs, k: int):
     backward hands each chosen expert's probability its weight's cotangent
     by a compare and a sum over the k choices, where autodiff scatters
     them."""
-    return lax.top_k(probs, k)
+    # A tuple, as the forward rule below returns: `lax.top_k` gives a list,
+    # and under `jax.checkpoint` the two structures are compared.
+    return tuple(lax.top_k(probs, k))
 
 
 def _top_choices_fwd(probs, k):

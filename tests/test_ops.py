@@ -921,6 +921,124 @@ def test_trinity_step_is_banded_and_causal_kernels_and_a_named_gate(
                "hvd_embed", "hvd_lm_head"))
 
 
+@pytest.mark.parametrize("window", [1024, None], ids=["band", "causal"])
+def test_split_flash_at_mellum_shape_compiles(v5e, window):
+    """The mellum2 cell's attention: 1 x 32 heads of 128 at 16,384 rows, the
+    windowed layers under a window of 1,024.  The plan leaves the combined
+    backward (its whole-sequence dq scratch) for the split pair in
+    1,024-blocks, banded — a band two tiles wide, 31 tile pairs a head — and
+    causal; forward and pair compile for the described chip."""
+    import horovod_tpu.ops.attention as attn
+
+    assert attn._bwd_plan(16384, 128, 1024, 1024, 32) == ("split", 1024, 1024)
+    text = _compile_flash_grad(v5e[0], (1, 32, 16384, 128), window=window)
+    suffix = "_window" if window else ""
+    for kernel in ("hvd_flash_fwd", "hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"):
+        assert len(re.findall(rf"%\w*?_{kernel}{suffix}_*\.\d+ = ",
+                              text)) == 1, kernel
+    assert text.count('"tpu_custom_call"') == 3
+
+
+@pytest.mark.parametrize("inner,outer", [(2304, 896), (896, 2304)])
+def test_grouped_matmul_at_mellum_widths_compiles(v5e, inner, outer):
+    """Experts 896 = 7 x 128 wide on rows 2,304 = 9 x 256 wide, 49,152 buffer
+    rows over 16 experts, both ways through an expert: libtpu's grouped
+    kernels take the pair of widths, forward and both gradients."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.ops.moe import grouped_matmul
+
+    on_chip = SingleDeviceSharding(v5e[0])
+    rows = jax.ShapeDtypeStruct((49152, inner), jnp.bfloat16,
+                                sharding=on_chip)
+    weights = jax.ShapeDtypeStruct((16, inner, outer), jnp.bfloat16,
+                                   sharding=on_chip)
+    sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=on_chip)
+
+    def loss(rows, weights, sizes):
+        return grouped_matmul(rows, weights, sizes).astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        rows, weights, sizes).compile().as_text()
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 2
+    assert " while(" not in text
+
+
+def test_mellum_step_recomputes_its_layers_under_jaxs_marker(v5e,
+                                                             monkeypatch):
+    """A windowed layer at the plain rotary frequencies, a full one at
+    YaRN's, each followed by softmax-routed experts, every pattern entry
+    recomputed (`TransformerLM(recompute=True)`), through `build_train_step`
+    for the described chip: every flash kernel is in the step once a layer,
+    as without recomputation, and so is every grouped matmul, nine a layer
+    (a recomputing layer keeps the forward kernel's outputs, the grouped
+    products' and its router's decision), no loop; what is computed again — the projections, the rotation of either kind,
+    the rows' movement — carries `rematted_computation` inside the backward
+    phase and keeps its layer's scope, and nothing of the first forward pass
+    does."""
+    import optax
+    from jax.sharding import NamedSharding
+
+    from horovod_tpu.jax.train import build_train_step
+    from horovod_tpu.models import (MoEConfig, RopeScaling, TransformerLM,
+                                    next_token_loss)
+    from horovod_tpu.parallel import data_parallel_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(
+        vocab_size=2048, d_model=512, n_heads=8, dtype=jnp.bfloat16,
+        logits_dtype=jnp.bfloat16, use_flash=True, norm_eps=1e-6,
+        layers=("window_attention", "experts", "attention", "experts"),
+        n_kv_heads=2, head_dim=128, window=512, head_norm=True,
+        rope_theta=500000.0, rope_scaling=RopeScaling(16, 8192),
+        window_rope=(500000.0, None), recompute=True,
+        moe=MoEConfig(64, 8, 256, (0, 4), 1.5, renormalize=True))
+    mesh = data_parallel_mesh(v5e[:1], axis_name="hvd")
+    tx = optax.adamw(1e-4)
+
+    def loss_fn(params, batch):
+        return next_token_loss(model.apply({"params": params}, batch[0]),
+                               batch[1])
+
+    def init(key):
+        params = model.init(key, jnp.zeros((1, 128), jnp.int32))["params"]
+        return params, tx.init(params)
+
+    def shaped(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+
+    params, opt_state = shaped(jax.eval_shape(init, jax.random.PRNGKey(0)),
+                               P())
+    tokens = shaped(jax.ShapeDtypeStruct((1, 2048), jnp.int32), P("hvd"))
+    step = build_train_step(loss_fn, tx, mesh, axis_name="hvd")
+    text = step.lower(params, opt_state,
+                      (tokens, tokens)).compile().as_text()
+    assert len(re.findall(r"\bwhile\(", text)) == 0
+    for kernel in ("hvd_flash_fwd_window", "hvd_flash_fwd",
+                   "hvd_flash_bwd_window", "hvd_flash_bwd"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 18
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    again = [path for path in paths if "rematted_computation" in path]
+    assert again and all("transpose(jvp(hvd_loss))" in path
+                         for path in again)
+    for layer in (0, 2):
+        assert any(f"layer_{layer}" in path and "hvd_attn_rotate" in path
+                   for path in again)
+        assert any(f"layer_{layer}" in path and "hvd_attn_qkv" in path
+                   for path in again)
+    assert not any("hvd_flash" in path for path in again)
+    assert any("hvd_moe_dispatch" in path for path in again)
+    assert not any("hvd_lm_head" in path or "hvd_embed" in path
+                   for path in again)
+    _assert_scopes_forward_and_backward(
+        text, ("hvd_attn_qkv", "hvd_attn_rotate", "hvd_attn_attend",
+               "hvd_attn_out", "hvd_moe_router", "hvd_embed", "hvd_lm_head"))
+
+
 @pytest.mark.parametrize("heads,kv_heads,head_dim",
                          [(32, 4, 128), (16, 2, 256)],
                          ids=["trinity", "qwen3next"])
