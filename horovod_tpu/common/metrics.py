@@ -28,8 +28,10 @@ to assert on them without opting into full metrics.
 from __future__ import annotations
 
 import bisect
+import contextlib
 import json
 import threading
+import time
 from typing import Callable, Dict, List, Optional, Tuple
 
 PLANES = ("engine", "xla")
@@ -126,6 +128,180 @@ ANOMALY_KINDS = ("slow_link", "straggler", "cache_degraded", "slow_phase")
 # kNetLinkBucketUs in engine/cc/net.cc; the engine serializes one extra
 # +Inf overflow bucket after these.
 LINK_SEND_BUCKETS_US = (50, 100, 250, 500, 1000, 2500, 5000, 10000, 50000)
+
+
+# ---------------------------------------------------------------------------
+# What building compiled programs cost (jax/train.py `_TimedStep.setup`,
+# docs/metrics.md#compiled-training-step): the record a step keeps, and the
+# process-wide table beside it.  No JAX here: jax/train.py hands over JAX's
+# own events, ops/ marks its kernels' bodies with `kernel_trace`.
+# ---------------------------------------------------------------------------
+
+SETUP_STAGES = ("trace", "lower", "load")
+_PROGRAMS = {"trace": "traced", "lower": "lowered", "load": "loaded"}
+# JAX reports a trace it answered from its cache as an event of some tens
+# of microseconds; the Python of a training step takes milliseconds.
+_CACHED_TRACE_S = 1e-3
+# The table keeps its newest entries; a process that builds more programs
+# than this keeps the totals.
+_TABLE_ENTRIES = 4096
+
+
+def new_step_setup() -> dict:
+    """A step's ``setup`` record with nothing built yet."""
+    return {"trace_s": 0.0, "lower_s": 0.0, "load_s": 0.0,
+            "programs": {"traced": 0, "lowered": 0, "loaded": 0},
+            "cache_hits": 0, "cache_misses": 0, "cache_retrieval_s": 0.0,
+            "code_bytes": None, "first_call_s": None,
+            "recompiles": 0, "last_compile_call": None, "kernels": {}}
+
+
+def copy_step_setup(setup: dict) -> dict:
+    return dict(setup, programs=dict(setup["programs"]),
+                kernels={name: dict(k)
+                         for name, k in setup["kernels"].items()})
+
+
+class _Open(threading.local):
+    """What is being built on this thread: ``step`` has a ``setup`` record,
+    a ``name`` (its function's, as JAX's trace events give it) and
+    ``_calls``; ``staged`` says a stage the caller drives is open
+    (``step.lower(...)``), not a call of the step."""
+    step = None
+    staged = False
+    carried = 0.0     # seconds of traces too short for an entry of their own
+
+
+class SetupTable:
+    """Every trace, lowering, load and kernel body of the process, as they
+    end: ``entries`` are ``(time.perf_counter() at the end, stage, name,
+    seconds)`` with stage one of `SETUP_STAGES`, ``"kernel"``,
+    ``"cache_hit"`` or ``"cache_miss"``, so that a reader can cut the table
+    at a moment (a benchmark at its window's start); ``totals`` are their
+    sums.  What ends while a step is open on the thread (`building`, or the
+    step's own call) is also filed in that step's record.
+
+    A jit called inside a jit that is being traced ends its own trace
+    first: its seconds are its own, the outer one's are what is left, so
+    that the sum is the outer trace's and nothing is counted twice.  A trace
+    of under a millisecond (``jax.numpy``'s helpers, an answer from JAX's
+    cache) has no entry: its seconds ride in the thread's next one."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.open = _Open()
+        self.entries = []
+        self.totals = {"trace_s": 0.0, "lower_s": 0.0, "load_s": 0.0,
+                       "compiles": 0, "cache_hits": 0, "cache_misses": 0,
+                       "kernels": {}}
+
+    def _entry(self, stage: str, name: str, seconds: float) -> None:
+        if len(self.entries) >= _TABLE_ENTRIES:
+            del self.entries[:_TABLE_ENTRIES // 2]
+        self.entries.append((time.perf_counter(), stage, name, seconds))
+
+    def _own_seconds(self, seconds: float) -> float:
+        """A trace's seconds less those of the traces that began inside it
+        on this thread (each already counted as it ended)."""
+        traces = self.open.__dict__.setdefault("traces", [])
+        start, inner = time.perf_counter() - seconds, 0.0
+        while traces and traces[-1][0] >= start:
+            inner += traces.pop()[1]
+        if len(traces) >= _TABLE_ENTRIES:
+            del traces[:_TABLE_ENTRIES // 2]
+        traces.append((start, seconds))
+        return max(seconds - inner, 0.0)
+
+    def stage(self, stage: str, seconds: float, name: str = "") -> None:
+        """One of JAX's duration events ended on this thread."""
+        opened, step = self.open, self.open.step
+        traced, carried = stage == "trace", 0.0
+        if traced:
+            # The step's own function, and no answer from JAX's cache.
+            a_program = step is not None and name == step.name \
+                and seconds >= _CACHED_TRACE_S
+            seconds = self._own_seconds(seconds)
+            carried, opened.carried = opened.carried, 0.0
+            if seconds < _CACHED_TRACE_S:
+                opened.carried = carried + seconds
+        with self._lock:
+            self.totals[stage + "_s"] += seconds
+            self.totals["compiles"] += stage == "load"
+            if not traced or seconds >= _CACHED_TRACE_S:
+                self._entry(stage, name, seconds + carried)
+            if step is None:
+                return
+            setup = step.setup
+            setup[stage + "_s"] += seconds
+            setup["programs"][_PROGRAMS[stage]] += a_program if traced else 1
+            if stage == "load" and not opened.staged and step._calls > 1:
+                setup["recompiles"] += 1
+                setup["last_compile_call"] = step._calls - 1
+
+    def cache(self, hit: bool, name: str = "") -> None:
+        """The persistent cache answered a load of this thread."""
+        verdict, key = ("cache_hit", "cache_hits") if hit \
+            else ("cache_miss", "cache_misses")
+        step = self.open.step
+        with self._lock:
+            self._entry(verdict, name, 0.0)
+            self.totals[key] += 1
+            if step is not None:
+                step.setup[key] += 1
+
+    def cache_retrieval(self, seconds: float) -> None:
+        step = self.open.step
+        if step is not None:
+            with self._lock:
+                step.setup["cache_retrieval_s"] += seconds
+
+    @contextlib.contextmanager
+    def building(self, step):
+        """``step``'s stage, driven by the caller, is open on this thread."""
+        opened = self.open
+        outer = opened.step, opened.staged
+        opened.step, opened.staged = step, True
+        try:
+            yield
+        finally:
+            opened.step, opened.staged = outer
+
+    @contextlib.contextmanager
+    def kernel_trace(self, name: str):
+        """Around the invocation of a ``pallas_call`` named ``name``: the
+        host seconds Python takes to trace the kernel's body, and a count,
+        in the open step's ``kernels`` and in the table.  It runs while the
+        caller is traced and never in a compiled program, and writes
+        nothing into the jaxpr."""
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            seconds = time.perf_counter() - t0
+            step = self.open.step
+            tables = [self.totals["kernels"]]
+            if step is not None:
+                tables.append(step.setup["kernels"])
+            with self._lock:
+                self._entry("kernel", name, seconds)
+                for kernels in tables:
+                    kernel = kernels.setdefault(
+                        name, {"calls": 0, "trace_s": 0.0})
+                    kernel["calls"] += 1
+                    kernel["trace_s"] += seconds
+
+    def process(self) -> dict:
+        """The process's account: the totals, and the entries with their
+        stamps."""
+        with self._lock:
+            return dict(self.totals,
+                        kernels={name: dict(k) for name, k in
+                                 self.totals["kernels"].items()},
+                        entries=list(self.entries))
+
+
+setup_table = SetupTable()
+kernel_trace = setup_table.kernel_trace
 
 
 class Histogram:
@@ -333,16 +509,15 @@ class MetricsRegistry:
                      "rows_walked": [], "way_back": []}
         # Windowed attention layers (models.Attention(window=)): the (query
         # block, key block) pairs a head's forward kernel visits, and what
-        # the causal kernel would; every layer on the flash kernels: the
-        # pairs its masks touch and the steps its kernels' grids take, a head
-        # (models.record_attention_blocks).
-        self._attention = {"blocks_visited": [], "blocks_causal": [],
-                           "grid_live": [], "grid_steps": []}
+        # the causal kernel would (models.record_attention_blocks).
+        self._attention = {"blocks_visited": [], "blocks_causal": []}
         # What the compiler made of the last compiled training step's
-        # gradient exchange (jax/train.py `_TimedStep.exchange_overlap`).
+        # gradient exchange (jax/train.py `_TimedStep.exchange_overlap`),
+        # and under "setup" what building its programs cost
+        # (`_TimedStep.setup`).
         self._train_step = {"compiler_options": "not applied",
                             "compiled": False, "async_all_reduces": 0,
-                            "sync_all_reduces": 0}
+                            "sync_all_reduces": 0, "setup": new_step_setup()}
         self._hists = {name: Histogram(bounds)
                        for name, (bounds, _) in HISTOGRAMS.items()}
 
@@ -435,24 +610,21 @@ class MetricsRegistry:
             self._moe["rows_walked"] = [int(n) for n in rows_walked]
             self._moe["way_back"] = [str(form) for form in way_back]
 
-    def set_attention_blocks(self, blocks_visited, blocks_causal,
-                             grid_live=(), grid_steps=()) -> None:
-        """Mirror one forward pass's attention counters — the first two per
-        windowed layer, the grids' per layer on the flash kernels
-        (overwritten: they are static shapes)."""
+    def set_attention_blocks(self, blocks_visited, blocks_causal) -> None:
+        """Mirror one forward pass's attention counters, per windowed
+        layer (overwritten: they are static shapes)."""
         with self._lock:
             self._attention = {
                 "blocks_visited": [int(n) for n in blocks_visited],
-                "blocks_causal": [int(n) for n in blocks_causal],
-                "grid_live": [int(n) for n in grid_live],
-                "grid_steps": [int(n) for n in grid_steps]}
+                "blocks_causal": [int(n) for n in blocks_causal]}
 
-    def set_train_step(self, exchange_overlap: dict) -> None:
-        """Mirror a compiled training step's account of its gradient
-        exchange: whether it took the overlap options, and its
-        asynchronous and synchronous all-reduces (a state copy)."""
+    def set_train_step(self, exchange_overlap: dict, setup: dict) -> None:
+        """Mirror a compiled training step's account of itself: whether it
+        took the overlap options, with its asynchronous and synchronous
+        all-reduces, and what building its programs cost (state copies)."""
         with self._lock:
-            self._train_step = dict(exchange_overlap)
+            self._train_step = dict(exchange_overlap,
+                                    setup=copy_step_setup(setup))
 
     def set_flight(self, state: dict) -> None:
         """Mirror the flight recorders' state (a state copy — idempotent
@@ -804,7 +976,9 @@ class MetricsRegistry:
                 },
                 "attention": {name: list(blocks) for name, blocks in
                               self._attention.items()},
-                "train_step": dict(self._train_step),
+                "train_step": dict(
+                    self._train_step,
+                    setup=copy_step_setup(self._train_step["setup"])),
                 "compression": {
                     "mode": self._compression["mode"],
                     "min_bytes": self._compression["min_bytes"],
@@ -999,15 +1173,10 @@ def prometheus_text(snapshot: dict) -> str:
     out.append("# HELP hvd_tpu_attention_blocks (query block, key block) "
                "pairs of one head in each windowed attention layer: visited "
                "by the banded forward kernel, and what the causal kernel "
-               "would visit under the same blocks; in each layer on the "
-               "flash kernels: grid_live the pairs its mask touches, summed "
-               "over its forward and backward kernels, grid_steps the steps "
-               "their grids take")
+               "would visit under the same blocks")
     out.append("# TYPE hvd_tpu_attention_blocks gauge")
     for kind, key in (("visited", "blocks_visited"),
-                      ("causal", "blocks_causal"),
-                      ("grid_live", "grid_live"),
-                      ("grid_steps", "grid_steps")):
+                      ("causal", "blocks_causal")):
         for layer, n in enumerate(attention.get(key, [])):
             out.append(f'hvd_tpu_attention_blocks{{layer="{layer}",'
                        f'kind="{kind}"}} {n}')
@@ -1020,6 +1189,33 @@ def prometheus_text(snapshot: dict) -> str:
     for kind in ("async", "sync"):
         out.append(f'hvd_tpu_train_step_all_reduces{{kind="{kind}"}} '
                    f"{step.get(kind + '_all_reduces', 0)}")
+    setup = step.get("setup") or new_step_setup()
+    out.append("# HELP hvd_tpu_train_step_setup_seconds host seconds the "
+               "last compiled training step's programs took to build, by "
+               "stage: trace (Python to a jaxpr), lower (jaxpr to "
+               "StableHLO, kernel bodies included), load (the backend's "
+               "compile or the persistent cache's retrieval, onto the "
+               "device)")
+    out.append("# TYPE hvd_tpu_train_step_setup_seconds gauge")
+    for stage in SETUP_STAGES:
+        out.append(f'hvd_tpu_train_step_setup_seconds{{stage="{stage}"}} '
+                   f"{_fmt(setup[stage + '_s'])}")
+    out.append("# HELP hvd_tpu_train_step_programs programs of that step "
+               "traced, lowered and loaded (a stage JAX answered from memory "
+               "is not one)")
+    out.append("# TYPE hvd_tpu_train_step_programs gauge")
+    for stage in SETUP_STAGES:
+        out.append(f'hvd_tpu_train_step_programs{{stage="{stage}"}} '
+                   f"{setup['programs'][_PROGRAMS[stage]]}")
+    out.append("# HELP hvd_tpu_train_step_code_bytes generated code of the "
+               "last executable that step held (0: none held, the jit's own "
+               "call compiled)")
+    out.append("# TYPE hvd_tpu_train_step_code_bytes gauge")
+    out.append(f"hvd_tpu_train_step_code_bytes {setup['code_bytes'] or 0}")
+    out.append("# HELP hvd_tpu_train_step_recompiles backend compiles inside "
+               "a call of that step after its first (another batch shape)")
+    out.append("# TYPE hvd_tpu_train_step_recompiles gauge")
+    out.append(f"hvd_tpu_train_step_recompiles {setup['recompiles']}")
 
     tune = snapshot.get("autotune", {})
     out.append("# HELP hvd_tpu_autotune_enabled "

@@ -39,11 +39,16 @@ of the loss and aux.  On the host, around every call: a
 clock, the ``jax.train_step`` timeline span, and two histograms —
 ``step_dispatch_sec`` (the call returning: the enqueue) and ``step_sec``
 (the step completing on the device, observed off the caller's thread).
+What building the step's programs cost is in ``step.setup``: JAX's own
+trace, lowering and compile events and the persistent cache's verdicts,
+filed by this module's two ``jax.monitoring`` listeners, each stage a
+``hvd.step_trace`` / ``hvd.step_lower`` / ``hvd.step_load`` span.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import re
 import threading
 import time
@@ -55,6 +60,44 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu.common import metrics as _metrics
 from horovod_tpu.jax import DistributedOptimizer
+
+# ---------------------------------------------------------------------------
+# JAX's own account of every program it builds, handed to the set-up table
+# (common/metrics.py `SetupTable`), which files it under the step that is
+# open on the thread.  The two listeners fire only when something is traced,
+# lowered or compiled: never in a steady step.
+# ---------------------------------------------------------------------------
+
+_STAGE_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # compile_or_get_cached: the backend's compile or the persistent
+    # cache's retrieval, and the load onto the device.
+    "/jax/core/compile/backend_compile_duration": "load",
+}
+_CACHE_VERDICT = {"/jax/compilation_cache/cache_hits": True,
+                  "/jax/compilation_cache/cache_misses": False}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_setup_table = _metrics.setup_table
+_open = _setup_table.open      # which step is being built on this thread
+
+
+def _on_duration(event, seconds, fun_name="", **_):
+    stage = _STAGE_OF_EVENT.get(event)
+    if stage is not None:
+        _setup_table.stage(stage, seconds, fun_name)
+    elif event == _CACHE_RETRIEVAL:
+        _setup_table.cache_retrieval(seconds)
+
+
+def _on_event(event, **_):
+    hit = _CACHE_VERDICT.get(event)
+    if hit is not None:
+        _setup_table.cache(hit)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)
 
 # ---------------------------------------------------------------------------
 # Checkpoint-resume glue (job-level restart, docs/fault-tolerance.md).
@@ -323,6 +366,33 @@ def count_all_reduces(compiled_text: str) -> tuple[int, int]:
             sum(plain for plain, _ in outside))
 
 
+class _Staged:
+    """A stage of the step's way to an executable (``jax.stages.Traced``,
+    ``Lowered`` or ``Compiled``): everything delegates to JAX's object, and
+    ``lower()`` and ``compile()`` are filed in the step's ``setup``."""
+
+    def __init__(self, step, stage):
+        self._step, self._stage = step, stage
+
+    def lower(self, *args, **kwargs):
+        with self._step._building("hvd.step_lower"):
+            return _Staged(self._step, self._stage.lower(*args, **kwargs))
+
+    def compile(self, *args, **kwargs):
+        with self._step._building("hvd.step_load"):
+            compiled = self._stage.compile(*args, **kwargs)
+            self._step.setup["code_bytes"] = getattr(
+                compiled.memory_analysis(), "generated_code_size_in_bytes",
+                None)
+        return _Staged(self._step, compiled)
+
+    def __call__(self, *args, **kwargs):
+        return self._stage(*args, **kwargs)
+
+    def __getattr__(self, name):
+        return getattr(self._stage, name)
+
+
 class _TimedStep:
     """Callable proxy over the jitted step: the library's own account of a
     training step (docs/metrics.md, docs/timeline.md).
@@ -337,8 +407,32 @@ class _TimedStep:
     the call took to return (jax dispatch is asynchronous: the enqueue) and
     the step's loss goes to `_StepCompletions`, which feeds ``step_sec``
     with the time the step took to complete.  With all three off a call
-    costs two flag reads and the disabled annotation.  Every jit attribute
-    (``lower``, ``trace``, ...) delegates to the wrapped function.
+    after the first costs two flag reads, the disabled annotation and the
+    note of which step is open on the thread.  ``trace`` and ``lower`` are
+    the jit's, accounted for; every other jit attribute delegates to the
+    wrapped function.
+
+    ``setup`` says what building the step's programs cost, by whichever
+    route they were built — the first call, ``step.lower(...).compile()``,
+    a later call with other shapes: ``trace_s``, ``lower_s``, ``load_s``
+    (seconds in Python tracing to a jaxpr; from the jaxpr to StableHLO, the
+    kernels' bodies included; in the backend's compile or the persistent
+    cache's retrieval with the load onto the device) summed over
+    ``programs`` (how many were ``traced``, ``lowered``, ``loaded``: a
+    stage that JAX answers from memory is none), ``cache_hits``,
+    ``cache_misses`` and ``cache_retrieval_s`` of the loads, ``code_bytes``
+    (``generated_code_size_in_bytes`` of the last executable this proxy
+    held; None where only the jit's own call compiled), ``first_call_s``
+    (call 0, entry to return), ``recompiles`` and ``last_compile_call``
+    (loads inside a call after the first, and that call's ``step_num``) and
+    ``kernels`` (``{name: {"calls", "trace_s"}}``: the ``pallas_call``s of
+    ``ops/`` as Python traced them, `common.metrics.kernel_trace`).  The
+    seconds are JAX's own events (this module's listeners).  A stage the
+    caller or the first call drives is a ``hvd.trace_span``:
+    ``hvd.step_trace``, ``hvd.step_lower``, ``hvd.step_load`` (the first
+    call's holds the step's first dispatch too).  Mirrored into
+    ``metrics_snapshot()["train_step"]["setup"]`` when the registry is
+    enabled.
 
     ``exchange_overlap`` says what the compiler made of the gradient
     exchange: whether the step took `_EXCHANGE_OVERLAP`, and, once it has
@@ -353,26 +447,62 @@ class _TimedStep:
 
     def __init__(self, fn, overlap: bool = False):
         self._fn = fn
-        self._run = self._compile_and_count if overlap else fn
+        self.name = getattr(fn, "__name__", "")
+        self._run = self._compile_and_count if overlap \
+            else self._first_call
         self._calls = 0
         self.exchange_overlap = {
             "compiler_options": "applied" if overlap else "not applied",
             "compiled": False,
             "async_all_reduces": 0, "sync_all_reduces": 0}
+        self.setup = _metrics.new_step_setup()
+
+    @contextlib.contextmanager
+    def _building(self, span: str):
+        """A stage of this step is open on the thread: what JAX builds in
+        it is this step's, inside the span ``span``."""
+        from horovod_tpu import common as _common
+
+        with _common.trace_span(span), _setup_table.building(self):
+            try:
+                yield
+            finally:
+                self._mirror()
+
+    def _mirror(self) -> None:
+        if _metrics.registry.enabled:
+            _metrics.registry.set_train_step(self.exchange_overlap,
+                                             self.setup)
+
+    def trace(self, *args, **kwargs):
+        with self._building("hvd.step_trace"):
+            return _Staged(self, self._fn.trace(*args, **kwargs))
+
+    def lower(self, *args, **kwargs):
+        return self.trace(*args, **kwargs).lower()
+
+    def _first_call(self, *args, **kwargs):
+        """Call 0 of a step without compiler options: the jit's own call,
+        with the trace and the lowering it would make made in front of it,
+        each in its span (JAX keeps both, and the call finds them)."""
+        self._run = self._fn
+        if _is_traced(args, kwargs):
+            return self._fn(*args, **kwargs)     # inlined into an outer jit
+        self.lower(*args, **kwargs)
+        with self._building("hvd.step_load"):
+            return self._fn(*args, **kwargs)
 
     def _compile_and_count(self, *args, **kwargs):
-        if any(isinstance(x, jax.core.Tracer)
-               for x in jax.tree.leaves((args, kwargs))):
+        if _is_traced(args, kwargs):
             return self._fn(*args, **kwargs)     # inlined into an outer jit
-        compiled = self._fn.lower(*args, **kwargs).compile()
+        compiled = self.lower(*args, **kwargs).compile()
         n_async, n_sync = count_all_reduces(compiled.as_text())
         self.exchange_overlap.update(
             compiled=True, async_all_reduces=n_async, sync_all_reduces=n_sync)
-        if _metrics.registry.enabled:
-            _metrics.registry.set_train_step(self.exchange_overlap)
-        self._compiled = compiled
+        self._mirror()
+        self._compiled = compiled._stage
         self._run = self._run_compiled
-        return compiled(*args, **kwargs)
+        return self._compiled(*args, **kwargs)
 
     def _run_compiled(self, *args, **kwargs):
         try:
@@ -391,19 +521,28 @@ class _TimedStep:
         self._calls = step_num + 1
         with jax.profiler.StepTraceAnnotation("hvd.train_step",
                                               step_num=step_num):
-            if not tl and not mx:
-                return self._run(*args, **kwargs)
-            if tl:
-                _common._trace_begin("jax.train_step", "TRAIN_STEP")
-            t0 = time.perf_counter()
+            # What JAX builds inside this call (shapes it has not seen) is
+            # this step's.
+            outer, _open.step = _open.step, self
             try:
-                out = self._run(*args, **kwargs)
-            finally:
+                if step_num and not tl and not mx:
+                    return self._run(*args, **kwargs)
                 if tl:
-                    _common._trace_end("jax.train_step")
+                    _common._trace_begin("jax.train_step", "TRAIN_STEP")
+                t0 = time.perf_counter()
+                try:
+                    out = self._run(*args, **kwargs)
+                finally:
+                    if tl:
+                        _common._trace_end("jax.train_step")
+                dispatched = time.perf_counter() - t0
+            finally:
+                _open.step = outer
+            if not step_num:
+                self.setup["first_call_s"] = dispatched
+                self._mirror()
             if mx:
-                _metrics.registry.observe("step_dispatch_sec",
-                                          time.perf_counter() - t0)
+                _metrics.registry.observe("step_dispatch_sec", dispatched)
                 # Under an outer trace the loss is a tracer: no device
                 # will ever complete it.
                 if hasattr(out[2], "is_ready"):
@@ -412,6 +551,13 @@ class _TimedStep:
 
     def __getattr__(self, name):
         return getattr(self._fn, name)
+
+
+def _is_traced(args, kwargs) -> bool:
+    """An outer jit is tracing through the step: there is nothing of its
+    own to build."""
+    return any(isinstance(x, jax.core.Tracer)
+               for x in jax.tree.leaves((args, kwargs)))
 
 
 def build_train_step(loss_fn: Callable, optimizer, mesh: Mesh,

@@ -24,7 +24,7 @@ from horovod_tpu.models.delta import DeltaConfig, DeltaMixer
 from horovod_tpu.models.ssm import Mamba2Config, Mamba2Mixer
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              ring_attention)
-from horovod_tpu.ops.attention import flash_grid_steps, mask_blocks
+from horovod_tpu.ops.attention import mask_blocks
 from horovod_tpu.ops.moe import (GROUPED_KERNELS, WAYS_BACK,
                                  buffer_rows_to_tokens, column_slabs,
                                  dispatch_rows, grouped_matmul,
@@ -840,8 +840,6 @@ class Attention(nn.Module):
                     if diffusion is None else dict(block_diffusion=diffusion)
                 out = (flash_attention(q, k, v, **masks) if self.use_flash
                        else blockwise_attention(q, k, v, **masks))
-                if self.use_flash:
-                    _sow_grid_steps(self, q, masks)
                 if self.window is not None or diffusion is not None:
                     blocks = mask_blocks(s, head_dim, **masks) \
                         if self.use_flash else None
@@ -985,8 +983,6 @@ class LatentAttention(nn.Module):
         with jax.named_scope("hvd_mla_attend"):
             out = flash_attention(q, k, v, causal=True) if self.use_flash \
                 else blockwise_attention(q, k, v, causal=True)
-            if self.use_flash:
-                _sow_grid_steps(self, q, dict(causal=True), d_v=v.shape[-1])
         with jax.named_scope("hvd_mla_out_proj"):
             gate = nn.sigmoid(jnp.einsum(
                 "bsd,dh->bhs", x, w_gate.astype(self.dtype),
@@ -1576,22 +1572,6 @@ def record_expert_rows(intermediates) -> dict:
             "experts_kernel": kernels}
 
 
-def _sow_grid_steps(layer, q, masks, d_v=None):
-    """Sow ``attn_grid_live`` and ``attn_grid_steps`` into ``layer``'s
-    ``intermediates``: summed over the flash kernels that ``flash_attention``
-    and its gradient run at ``q``'s shape under ``masks``, the (query tile,
-    key tile) pairs the mask touches and the steps a row of the kernels'
-    grids takes (:func:`~horovod_tpu.ops.attention.flash_grid_steps`) — equal
-    where no grid step computes nothing; both 0 where the shape leaves the
-    kernels for the scan."""
-    b, heads, s, head_dim = q.shape
-    grids = flash_grid_steps(s, head_dim, b * heads, d_v, **masks).values()
-    layer.sow("intermediates", "attn_grid_live",
-              jnp.int32(sum(live for live, _, _ in grids)))
-    layer.sow("intermediates", "attn_grid_steps",
-              jnp.int32(sum(steps for _, steps, _ in grids)))
-
-
 def record_attention_blocks(intermediates) -> dict:
     """Read what the attention layers wrote to the ``intermediates``
     collection of one ``apply(..., mutable=["intermediates"])`` — outside the
@@ -1600,16 +1580,11 @@ def record_attention_blocks(intermediates) -> dict:
     ``hvd.metrics_snapshot()["attention"]``.  Returns ``{"blocks_visited":
     [the (query block, key block) pairs a head's forward kernel visits, per
     windowed or block-diffusion layer], "blocks_causal": [what the causal
-    kernel would under the same blocks, per such layer], "grid_live": [the
-    pairs the mask touches, summed over a head's forward and backward flash
-    kernels, per layer that runs them], "grid_steps": [the steps those
-    kernels' grids take, per such layer: the same where no step computes
-    nothing]}``."""
+    kernel would under the same blocks, per such layer]}``."""
     from horovod_tpu.common import metrics as _metrics
 
     seen = {kind: [int(n) for n in _sown(intermediates, "attn_" + kind)]
-            for kind in ("blocks_visited", "blocks_causal", "grid_live",
-                         "grid_steps")}
+            for kind in ("blocks_visited", "blocks_causal")}
     if _metrics.registry.enabled:
         _metrics.registry.set_attention_blocks(**seen)
     return seen

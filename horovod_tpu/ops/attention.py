@@ -30,6 +30,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.common.metrics import kernel_trace
+
 NEG_INF = -1e30  # big-negative instead of -inf: keeps exp() NaN-free when a
 # whole row is masked (fully-masked causal blocks)
 POS_BIG = 1e30   # logsumexp sentinel for fully-masked rows: exp(s - POS_BIG)
@@ -918,7 +920,8 @@ def _tiled_call(kernel, table, bh, *, out_shape, name, interpret, prefetch=(),
                 compiler_params=None, **specs):
     """``pl.pallas_call`` over the grid ``(bh, the table's steps)``, the table
     the first scalar-prefetch operand (``prefetch``: the ring's offsets
-    behind it); the call takes the kernel's other operands."""
+    behind it); the call takes the kernel's other operands, and marks the
+    seconds Python takes to trace the body (`kernel_trace`)."""
     call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -926,7 +929,12 @@ def _tiled_call(kernel, table, bh, *, out_shape, name, interpret, prefetch=(),
             grid=(bh, table.shape[1]), **specs),
         out_shape=out_shape, compiler_params=compiler_params,
         interpret=interpret, name=name)
-    return functools.partial(call, table, *prefetch)
+
+    def traced(*operands):
+        with kernel_trace(name):
+            return call(table, *prefetch, *operands)
+
+    return traced
 
 
 def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset=None,

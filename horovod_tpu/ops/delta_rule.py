@@ -150,6 +150,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.common.metrics import kernel_trace
+
 SUB_BLOCK = 16   # tokens whose decay ratios are taken against one reference
 
 
@@ -454,7 +456,7 @@ def _carry_call(scope, backward, operands, outputs, interpret):
 
     vma = jax.typeof(operands[0]).vma   # inside shard_map: as the inputs vary
     macs = chunk * d_v * (products[0] * d_k + products[1] * chunk)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel, grid=(batch, key_heads, chunks),
         in_specs=[spec(t) for t in operands],
         out_specs=[spec(t) for t in outputs],
@@ -468,7 +470,9 @@ def _carry_call(scope, backward, operands, outputs, interpret):
             transcendentals=0,
             bytes_accessed=sum(t.size * t.dtype.itemsize
                                for t in (*operands, *outputs))),
-        interpret=interpret, name=name)(*operands)
+        interpret=interpret, name=name)
+    with kernel_trace(name):
+        return call(*operands)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))

@@ -79,6 +79,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.common.metrics import kernel_trace
+
 
 def reduced_to_vma_of(primal, cotangent):
     """``cotangent`` summed over the mapped axes it varies over and
@@ -704,7 +706,7 @@ def _tiled_call(left, right, group_sizes, form, tiles, interpret):
         scratch = [pltpu.VMEM((tm, tn), jnp.float32)] if k_tiles > 1 else []
     vma = jax.typeof(left).vma          # inside shard_map: as the rows vary
     out = jax.ShapeDtypeStruct(out_shape, left.dtype, vma=vma)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(schedule), grid=grid, in_specs=in_specs,
@@ -716,8 +718,9 @@ def _tiled_call(left, right, group_sizes, form, tiles, interpret):
             flops=2 * m * k * n, transcendentals=0,
             bytes_accessed=sum(t.size * t.dtype.itemsize
                                for t in (left, right, out))),
-        interpret=interpret, name=f"hvd_grouped_{form}")(
-            *schedule, left, right)
+        interpret=interpret, name=f"hvd_grouped_{form}")
+    with kernel_trace(f"hvd_grouped_{form}"):
+        return call(*schedule, left, right)
 
 
 _D_WEIGHTS = lax.RaggedDotDimensionNumbers(

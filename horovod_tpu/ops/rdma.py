@@ -59,6 +59,8 @@ import jax.experimental.pallas as pl
 from jax import lax
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.common.metrics import kernel_trace
+
 # Barrier namespaces: phases 0/1 = chain A (ids 13/14), phases 2/3 =
 # chain B (ids 17/18).  ``phase ^ 1`` flips within a chain — the VJP's
 # move — while ``phase // 2`` names the chain.
@@ -120,7 +122,7 @@ def _ring_permute_raw(x, axis_name, shift, interpret, phase):
                                mesh_axes=_ambient_mesh_axes(axis_name))
     # Propagate the varying-mesh-axes annotation so shard_map's vma check
     # accepts the pallas output (the result varies exactly as the input).
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype,
                                        vma=jax.typeof(x).vma),
@@ -132,7 +134,9 @@ def _ring_permute_raw(x, axis_name, shift, interpret, phase):
             has_side_effects=True),
         interpret=interpret,
         name="hvd_rdma_permute",
-    )(x)
+    )
+    with kernel_trace("hvd_rdma_permute"):
+        return call(x)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))

