@@ -1156,14 +1156,16 @@ def prometheus_text(snapshot: dict) -> str:
                f"{moe.get('rows_over_bound', 0)}")
     out.append("# HELP hvd_tpu_moe_rows_walked rows one pass from each "
                "sparse-expert layer's buffer back to its tokens touches "
-               "(the buffer's rows, or every (token, choice) pair)")
+               "(every (token, choice) pair for the form pairs, at most "
+               "the buffer's rows for the others)")
     out.append("# TYPE hvd_tpu_moe_rows_walked gauge")
     for layer, n in enumerate(moe.get("rows_walked", [])):
         out.append(f'hvd_tpu_moe_rows_walked{{layer="{layer}"}} {n}')
     out.append("# HELP hvd_tpu_moe_way_back the form that pass takes, from "
                "static shapes: pairs (a gather over every pair), rows (a "
-               "scatter-add of the buffer's rows) or row_slabs (the same, a "
-               "slab of columns at a time)")
+               "scatter-add of the buffer's rows), row_slabs (the same, a "
+               "slab of columns at a time) or held_pairs (a kernel that "
+               "fetches only the rows a token has)")
     out.append("# TYPE hvd_tpu_moe_way_back gauge")
     for layer, form in enumerate(moe.get("way_back", [])):
         out.append(f'hvd_tpu_moe_way_back{{layer="{layer}",'

@@ -27,10 +27,10 @@ from horovod_tpu.ops import (blockwise_attention, flash_attention,
 from horovod_tpu.ops.attention import mask_blocks
 from horovod_tpu.ops.moe import (GROUPED_KERNELS, WAYS_BACK,
                                  buffer_rows_to_tokens, column_slabs,
-                                 dispatch_rows, grouped_matmul,
+                                 dispatch_rows, grouped_matmul, pass_back,
                                  product_kernel, reduced_to_vma_of,
                                  scatters_whole_rows, token_rows_to_buffer,
-                                 top_choices, way_back)
+                                 top_choices)
 
 
 def embedding_lookup(table, tokens):
@@ -478,8 +478,10 @@ class SparseExperts(nn.Module):
     gathered into every buffer row that holds one of its pairs; the experts'
     rows come back summed, weighted, per token — gathered through each pair's
     place in the sorted order (no scatter in the layer's program or in its
-    gradient's) or, from ``ops.moe.ROW_WALK_PAIRS_PER_ROW`` pairs a buffer
-    row on, scatter-added through each row's token (rows wider than
+    gradient's; from a buffer of ``ops.moe.HELD_PAIRS_BUFFER_BYTES`` on, on
+    a TPU, by the kernel ``hvd_moe_pair_rows``, which fetches the valid
+    pairs' rows alone) or, from ``ops.moe.ROW_WALK_PAIRS_PER_ROW`` pairs a
+    buffer row on, scatter-added through each row's token (rows wider than
     ``ops.moe.WHOLE_ROW_WIDTH`` a slab of columns at a time).  Pairs held
     elsewhere or cut off by a ``row_bound`` contribute exactly zero, forward
     and backward.
@@ -488,8 +490,10 @@ class SparseExperts(nn.Module):
     ``choices`` (pairs per expert, all experts), ``prob_sum`` (with sigmoid
     scoring the scores'), ``z_sum``, ``tokens``: what :func:`router_losses`
     reads; ``intermediates`` — ``chosen_experts`` (tokens, k), ``rows_per_
-    local_expert``, ``rows_over_bound``, ``rows_walked`` (by one pass back),
-    ``way_back`` (the pass's form, an index into ``ops.moe.WAYS_BACK``),
+    local_expert``, ``rows_over_bound``, ``rows_walked`` (by one pass back:
+    every pair's for ``pairs``, at most the buffer's otherwise), ``way_back``
+    (the form the pass takes in this process, ``ops.moe.pass_back``'s, an
+    index into ``ops.moe.WAYS_BACK``: one of four),
     ``experts_kernel`` (the kernel the grouped products took, an index into
     ``ops.moe.GROUPED_KERNELS``), and with more than one group
     ``groups_chosen`` (tokens, topk_group).
@@ -587,9 +591,9 @@ class SparseExperts(nn.Module):
                      sent.rows_per_expert)
             self.sow("intermediates", "rows_over_bound",
                      sent.rows_over_bound)
-            self.sow("intermediates", "rows_walked", sent.rows_walked)
-            self.sow("intermediates", "way_back",
-                     WAYS_BACK.index(way_back(sent, inner)))
+            back = pass_back(rows, sent)
+            self.sow("intermediates", "rows_walked", sent.rows_walked(back))
+            self.sow("intermediates", "way_back", WAYS_BACK.index(back))
         with jax.named_scope("hvd_moe_experts"):
             sizes = sent.group_sizes
             self.sow("intermediates", "experts_kernel",

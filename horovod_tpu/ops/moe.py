@@ -24,8 +24,9 @@ Every movement between pair space (tokens x choices) and buffer space
 :func:`buffer_rows_to_tokens`, each with a backward pass of its own;
 :func:`top_choices` does the same for the router's top-k).  Into the buffer
 that is always a gather of ``bound`` rows.  Back to the tokens it is one
-indexed segment-sum in three forms, chosen from static shapes by
-:func:`way_back` (pairs a buffer row, then the row's width):
+indexed segment-sum in four forms, chosen from static shapes by
+:func:`way_back` (pairs a buffer row, then the buffer's bytes or the row's
+width):
 
 * ``pairs`` — under ``ROW_WALK_PAIRS_PER_ROW`` pairs a buffer row (a shard
   with a quarter of the experts: 2.7) a GATHER through ``position``, k-wide,
@@ -33,6 +34,16 @@ indexed segment-sum in three forms, chosen from static shapes by
   (2 ms for 24,576 rows of 2,048, PR 26's trace) where the gather costs a
   sixth as much a row, and autodiff would turn every gather back into a
   scatter-add;
+* ``held_pairs`` — the same sum where the buffer is larger than
+  ``HELD_PAIRS_BUFFER_BYTES``, which no on-chip space holds, so that XLA's
+  gather walks every pair's row from HBM, valid or not, writes them all and
+  reads them again (5.6 ms a pass for 131,072 pairs of 2,304, a quarter of
+  them valid): a Pallas kernel (:func:`pair_rows`, calls named
+  ``hvd_moe_pair_rows``) that fetches for a tile of tokens only the RUNS of
+  buffer rows its valid pairs hold, in blocks of 16 rows, and sums them in
+  token order by a product with the pairs' weights at their rows' places.
+  A TPU backend only: elsewhere it runs interpreted, in its own tests, and
+  the process keeps ``pairs`` (:func:`pass_back`);
 * ``rows`` — at or over it (a shard with a sixteenth of the experts or less:
   35 pairs a row at 8 of 512) a SCATTER-ADD of the buffer's rows through
   ``token_of_row``: the k-wide gather would read a row for every pair and
@@ -46,7 +57,7 @@ indexed segment-sum in three forms, chosen from static shapes by
   ``WHOLE_ROW_WIDTH``), and a slab of 512 costs what its bytes do.
 
 Rows at or past ``group_sizes.sum()`` take no part in any form, by the
-mover's own mask.
+mover's own mask (``held_pairs``: selected away before the product).
 
 :func:`grouped_matmul` multiplies a group's rows by one of two kernels,
 chosen from static shapes by :func:`grouped_kernel` (the widths, then the
@@ -151,11 +162,11 @@ class Dispatch(NamedTuple):
         (tokens, choices)."""
         return self.pair // self.position.shape[-1]
 
-    @property
-    def rows_walked(self) -> int:
-        """Rows one pass from the buffer back to the tokens touches: the
-        buffer's where :func:`walks_rows`, every pair's otherwise."""
-        return self.pair.shape[0] if walks_rows(self) else self.position.size
+    def rows_walked(self, form: str) -> int:
+        """Rows one pass of ``form`` (of ``WAYS_BACK``) from the buffer back
+        to the tokens touches: every pair's for ``pairs``, at most the
+        buffer's for the others."""
+        return self.position.size if form == "pairs" else self.pair.shape[0]
 
 
 def dispatch_rows(expert_of_pair, first_expert: int, local_experts: int,
@@ -232,7 +243,35 @@ def walks_rows(sent: Dispatch) -> bool:
 WHOLE_ROW_WIDTH = 1024
 ROW_SLAB_WIDTH = 512
 
-WAYS_BACK = ("pairs", "rows", "row_slabs")
+# Where the pairs' side of ``walks_rows`` reads only the rows a token has
+# (``held_pairs``): a buffer of more bytes than the first constant, or as many
+# pairs a buffer row as the second.  XLA's k-wide gather costs 7-16 ns a pair,
+# valid or not, while it keeps its source on-chip (``S(1)``: the v5e's 128 MiB
+# of VMEM hold OLMoE's 101 MB buffer) and 43-56 ns from HBM; the kernel
+# fetches a tile's runs in blocks of 16 rows and costs with the tiles and the
+# rows that are valid.  One pass alone in a program (tools/pair_rows_sweep.py:
+# a seeded softmax router, shard 0 of the layout, bf16, 8 choices a token; my
+# chip runs, PR 52), ms ``pairs`` / ``held_pairs``, and what the valid rows'
+# bytes would take:
+#   tokens, shard, buffer, pairs a row            combine   dispatch's backward
+#   Mellum 16,384, a quarter of 64 experts, 49,152 x 2,304 = 226 MB, 2.67
+#       (bytes 0.28)                            7.36 / 1.26     6.56 / 0.75
+#   the same at 12,288 tokens, 170 MB (0.21)    5.60 / 0.96     4.99 / 0.57
+#   the same at 8,192 tokens, 113 MB (0.14)     1.50 / 0.64     0.98 / 0.39
+#   OLMoE 8,192, a quarter of 64, 24,576 x 2,048 = 101 MB, 2.67 (0.12)
+#                                               0.89 / 0.60     0.88 / 0.37
+#   SDAR, Trinity 8,192, an eighth of 128, 12,288 x 2,048 = 50 MB, 5.33 (0.08)
+#                                               0.90 / 0.56     0.88 / 0.34
+# The gather falls off its on-chip source between 113 and 170 MB: the first
+# constant is the chip's VMEM.  Under it the kernel wins every shape measured
+# by a third to three fifths of a pass; the second constant takes the shapes
+# with fewer than a quarter of their pairs valid (SDAR's and Trinity's eighth)
+# and leaves OLMoE's quarter, whose builder pins its step's custom calls, on
+# the gather: measured and not yet taken.
+HELD_PAIRS_BUFFER_BYTES = 128 << 20
+HELD_PAIRS_PER_ROW = 4
+
+WAYS_BACK = ("pairs", "rows", "row_slabs", "held_pairs")
 
 
 def scatters_whole_rows(width: int) -> bool:
@@ -248,12 +287,30 @@ def column_slabs(rows):
             for at in range(0, rows.shape[-1], ROW_SLAB_WIDTH)]
 
 
-def way_back(sent: Dispatch, width: int) -> str:
+def way_back(sent: Dispatch, width: int, itemsize: int = 2) -> str:
     """The form the passes from the buffer back to the tokens take for rows
-    of ``width`` elements, one of ``WAYS_BACK``: static shapes only."""
-    if not walks_rows(sent):
+    of ``width`` elements of ``itemsize`` bytes, one of ``WAYS_BACK``: static
+    shapes only."""
+    if walks_rows(sent):
+        return "rows" if scatters_whole_rows(width) else "row_slabs"
+    pairs, bound = sent.position.size, sent.pair.shape[0]
+    if bound * width * itemsize > HELD_PAIRS_BUFFER_BYTES \
+            or pairs >= HELD_PAIRS_PER_ROW * bound:
+        return "held_pairs"
+    return "pairs"
+
+
+def pass_back(rows, sent: Dispatch) -> str:
+    """The form a pass from ``rows`` (bound, d) back to the tokens takes in
+    this process: :func:`way_back`'s, with ``pairs`` for ``held_pairs`` off
+    the TPU and where the shapes are no whole tiles of :func:`pair_rows` (off
+    the TPU the kernel runs interpreted, in its own tests)."""
+    form = way_back(sent, rows.shape[1], rows.dtype.itemsize)
+    if form == "held_pairs" and (
+            jax.default_backend() != "tpu"
+            or not pair_rows_tiles(sent.position.shape[0], *rows.shape)):
         return "pairs"
-    return "rows" if scatters_whole_rows(width) else "row_slabs"
+    return form
 
 
 def _held_or(index, sent: Dispatch, out_of_range: int):
@@ -303,14 +360,251 @@ def _of_pairs(buffer, sent: Dispatch):
     return jnp.where(valid, buffer[index], jnp.zeros((), buffer.dtype))
 
 
+def _pairs_summed(buffer, sent: Dispatch, weight=None):
+    """(tokens, d) float32: the ``pairs`` form's pass — every pair's row
+    gathered, masked, in float32 (times ``weight`` (tokens, choices) where
+    given) and summed over the choices."""
+    picked = _of_pairs(buffer, sent).astype(jnp.float32)
+    if weight is not None:
+        picked = picked * weight[..., None]
+    return picked.sum(axis=1)
+
+
+# The ``held_pairs`` kernel: tokens a tile, buffer rows a DMA (a bf16 tile of
+# VMEM), staged rows a product and the most columns of one.  At Mellum's shape
+# (the table over ``HELD_PAIRS_BUFFER_BYTES``), ms combine / dispatch's
+# backward, alone in a program (my chip runs, PR 52): tiles of 128 tokens
+# 1.82 / 1.30, 256 1.91 / 1.16, 512 2.54 / 1.28; blocks of 32 rows 2.51 /
+# 1.72 (a run of 16 rows lands in two of them); chunks of 128 staged rows
+# 2.50 / 1.92, 512 (tiles of 256) 1.97 / 1.08; products of 128 columns 1.82 /
+# 1.29, 384 1.47 / 0.95, 768 1.36 / 0.86, 1,152 1.31 / 0.80, the whole 2,304
+# 1.26 / 0.75.  With no product at all (the blocks fetched and waited for)
+# 0.58; without the selection of the owned rows 0.07 less; a float32 weight
+# as one bf16 term and not three 0.45 less.
+_PAIR_TILE_TOKENS = 128
+_PAIR_BLOCK_ROWS = 16
+_PAIR_CHUNK_ROWS = 256
+_PAIR_COLUMNS = 2304
+
+
+def _staged_rows(tm: int, k: int, groups: int) -> int:
+    """Rows of VMEM a tile's blocks can need: every pair valid, and each
+    group's run beginning and ending inside a block — in whole chunks."""
+    most = tm * k + 2 * _PAIR_BLOCK_ROWS * groups
+    return -(-most // _PAIR_CHUNK_ROWS) * _PAIR_CHUNK_ROWS
+
+
+def _pair_rows_kernel(starts, counts, source, *rest, tm: int, groups: int,
+                      weighted: bool):
+    """One tile of ``tm`` tokens of :func:`pair_rows`.  ``starts`` /
+    ``counts`` (SMEM, tiles x groups): the tile's RUN of every group, the
+    buffer rows its valid pairs of that group hold; ``source`` (VMEM, (tm,
+    k)): each pair's buffer row, -1 where it has none; ``buffer`` (HBM);
+    ``stage`` (VMEM, (2, staged rows, d)): where the blocks of this tile's
+    runs land, and the next tile's."""
+    weight = rest[0] if weighted else None
+    buffer, out, stage, total, row_of, landed, fetched = rest[weighted:]
+    tile, tiles = pl.program_id(0), pl.num_programs(0)
+    block, chunk = _PAIR_BLOCK_ROWS, _PAIR_CHUNK_ROWS
+    d = out.shape[1]
+    wide = max(c for c in range(_LANES, min(d, _PAIR_COLUMNS) + 1, _LANES)
+               if d % c == 0)
+
+    def blocks_of(i, g):
+        """(first block, blocks, start, rows) of tile i's run of group g."""
+        start, n = starts[i * groups + g], counts[i * groups + g]
+        first = start // block
+        many = jnp.where(n > 0, (start + n - 1) // block - first + 1, 0)
+        return first, many, start, n
+
+    def fetch(i, slot):
+        def run(g, staged):
+            first, many, _, _ = blocks_of(i, g)
+
+            def one(b, carry):
+                pltpu.make_async_copy(
+                    buffer.at[pl.ds(pl.multiple_of((first + b) * block,
+                                                   block), block)],
+                    stage.at[slot, pl.ds(pl.multiple_of((staged + b) * block,
+                                                        block), block)],
+                    landed.at[slot]).start()
+                return carry
+
+            lax.fori_loop(0, many, one, None)
+            return staged + many
+
+        fetched[slot] = lax.fori_loop(0, groups, run, jnp.int32(0))
+
+    @pl.when(tile == 0)
+    def _():
+        fetch(0, 0)
+
+    @pl.when(tile + 1 < tiles)
+    def _():
+        fetch(tile + 1, (tile + 1) % 2)
+
+    slot = tile % 2
+
+    def landing(_, carry):          # every block is as many bytes
+        pltpu.make_async_copy(buffer.at[pl.ds(0, block)],
+                              stage.at[slot, pl.ds(0, block)],
+                              landed.at[slot]).wait()
+        return carry
+
+    lax.fori_loop(0, fetched[slot], landing, None)
+
+    # The buffer row every staged row holds, -2 where it is no valid pair's
+    # of this tile (a block's rows before and after the run).
+    staged_rows = stage.shape[1]
+    at = lax.broadcasted_iota(jnp.int32, (1, staged_rows), 1)
+
+    def run_rows(g, carry):
+        staged, rows = carry
+        first, many, start, n = blocks_of(tile, g)
+        begins = staged * block + start - first * block
+        inside = (at >= begins) & (at < begins + n)
+        return staged + many, jnp.where(inside, at - begins + start, rows)
+
+    row_of[...] = lax.fori_loop(
+        0, groups, run_rows,
+        (jnp.int32(0), jnp.full((1, staged_rows), -2, jnp.int32)))[1]
+    total[...] = jnp.zeros(total.shape, total.dtype)
+    wanted = source[...]
+    exact = stage.dtype == jnp.float32
+    ones = jnp.ones((tm, _LANES), jnp.bfloat16)
+
+    def staged_chunk(c, carry):
+        rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        here = row_of[:, rows]
+        # (tm, chunk): each pair's weight at its row's place, in float32.
+        place = jnp.zeros((tm, chunk), jnp.float32)
+        for choice in range(wanted.shape[1]):
+            hit = wanted[:, choice:choice + 1] == here
+            place += jnp.where(hit, weight[:, choice:choice + 1]
+                               if weighted else 1.0, 0.0)
+        # Whether a pair of the tile owns the row, as a column 128 lanes
+        # wide: a product turns the row of ones and zeros.
+        owned = jnp.broadcast_to((here >= 0).astype(jnp.bfloat16),
+                                 (tm, chunk))
+        kept = lax.dot_general(
+            owned, ones, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) > 0.0
+        if exact or not weighted:
+            parts = [place.astype(stage.dtype)]
+        else:
+            # float32 weights as three bf16 terms: every product exact.
+            high = place.astype(jnp.bfloat16)
+            rest = place - high.astype(jnp.float32)
+            middle = rest.astype(jnp.bfloat16)
+            parts = [high, middle,
+                     (rest - middle.astype(jnp.float32)).astype(jnp.bfloat16)]
+
+        def columns(cols):
+            landed_rows = stage[slot, rows, cols]
+            held = jnp.concatenate(
+                [jnp.where(kept, landed_rows[:, lane:lane + _LANES].astype(
+                    jnp.float32), 0.0).astype(stage.dtype)
+                 for lane in range(0, wide, _LANES)], axis=1)
+            total[:, cols] += sum(
+                jnp.dot(part, held, preferred_element_type=jnp.float32,
+                        precision=lax.Precision.HIGHEST if exact else None)
+                for part in parts)
+
+        _in_chunks(d, wide, columns)
+        return carry
+
+    lax.fori_loop(0, (fetched[slot] * block + chunk - 1) // chunk,
+                  staged_chunk, None)
+    out[...] = total[...].astype(out.dtype)
+
+
+def pair_rows_tiles(tokens: int, rows: int, width: int) -> bool:
+    """Whether ``tokens`` tokens and a buffer of (rows, width) are whole
+    tiles of :func:`pair_rows`."""
+    return tokens % _PAIR_TILE_TOKENS == 0 and rows % _PAIR_BLOCK_ROWS == 0 \
+        and width % _LANES == 0
+
+
+def pair_rows(buffer, sent: Dispatch, weight=None, *, interpret=None):
+    """``mixed[t] = sum over the VALID choices c of weight[t, c] *
+    buffer[position[t, c]]`` (``weight=None``: the plain sum), the ``pairs``
+    form's arithmetic — float32 products and sum, one rounding to
+    ``buffer``'s dtype — as a Pallas call named ``hvd_moe_pair_rows`` that
+    reads from HBM only the rows a tile of tokens has.  The sort of
+    :func:`dispatch_rows` is stable, so the valid pairs a tile of consecutive
+    tokens has of one group hold consecutive buffer rows, a RUN: a tile
+    fetches its run of every group in blocks of 16 rows, a DMA each, and a
+    product with the pairs' weights at their rows' places (bf16: a float32
+    weight as three bf16 terms, so that every product is exact) sums them in
+    token order.  A staged row that is no valid pair's of the tile is
+    selected away before the product: what such a row holds reaches no
+    token.  (A row that IS a valid pair's reaches the tile's other tokens
+    through the product's zeros: the forms agree while the valid rows are
+    finite, and an infinity in one reads NaN in that column of its tile of
+    128 tokens, not of its token alone.)  ``buffer`` (bound, d) bf16 or
+    float32, shapes as :func:`pair_rows_tiles` wants them; the result
+    (tokens, d).  ``interpret=None`` asks the backend.  The layers of a model
+    and the two passes that share a shape share one traced and lowered
+    call."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _pair_rows_call(buffer, jnp.where(sent.valid, sent.position, -1),
+                           weight, sent.group_sizes, interpret)
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _pair_rows_call(buffer, source, weight, group_sizes, interpret):
+    """:func:`pair_rows` with everything static decided: jitted, as
+    :func:`_tiled_call` is and for its reason."""
+    (tokens, k), (rows, d), tm = source.shape, buffer.shape, _PAIR_TILE_TOKENS
+    groups, tiles = group_sizes.shape[0], tokens // tm
+    ends = jnp.cumsum(group_sizes, dtype=jnp.int32)
+    # A tile's valid pairs of a group by a compare and a sum, as
+    # `dispatch_rows` counts an expert's.
+    of_group = (source[..., None] >= ends - group_sizes) \
+        & (source[..., None] < ends)
+    counts = of_group.reshape(tiles, tm * k, groups).sum(axis=1,
+                                                         dtype=jnp.int32)
+    starts = ends - group_sizes + jnp.cumsum(counts, axis=0) - counts
+    operands = [source] + ([weight] if weight is not None else [])
+    staged = _staged_rows(tm, k, groups)
+    vma = jax.typeof(buffer).vma        # inside shard_map: as the rows vary
+    call = pl.pallas_call(
+        functools.partial(_pair_rows_kernel, tm=tm, groups=groups,
+                          weighted=weight is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(tiles,),
+            in_specs=[pl.BlockSpec((tm, k), lambda i, starts, counts: (i, 0))]
+            * len(operands) + [pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tm, d), lambda i, starts, counts: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((2, staged, d), buffer.dtype),
+                            pltpu.VMEM((tm, d), jnp.float32),
+                            pltpu.VMEM((1, staged), jnp.int32),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((2,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((tokens, d), buffer.dtype, vma=vma),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=2 * staged * d * buffer.dtype.itemsize
+            + (16 << 20)),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * tokens * k * d, transcendentals=0,
+            bytes_accessed=(min(rows, tokens * k) + tokens) * d
+            * buffer.dtype.itemsize),
+        interpret=interpret, name="hvd_moe_pair_rows")
+    with kernel_trace("hvd_moe_pair_rows"):
+        return call(starts.reshape(-1), counts.reshape(-1), *operands, buffer)
+
+
 @jax.custom_vjp
 def token_rows_to_buffer(flat, sent: Dispatch):
     """``rows[r] = flat[token_of_row[r]]``: each token's row, once for every
     buffer row that holds one of its pairs.  ``flat`` (tokens, d); the
     result (bound, d).  Backward: ``d_flat[t]`` is the sum over the token's
     choices of ``d_rows[position[t, c]]`` where valid, summed in float32 and
-    rounded once to ``flat``'s dtype — a k-wide gather, or where
-    :func:`walks_rows` a scatter-add of the rows under
+    rounded once to ``flat``'s dtype — a k-wide gather (``held_pairs`` of
+    :func:`pass_back`: :func:`pair_rows`' fetch of the valid pairs' rows
+    alone), or where :func:`walks_rows` a scatter-add of the rows under
     ``group_sizes.sum()``, in column slabs where :func:`way_back` says
     so."""
     return flat[sent.token_of_row]
@@ -322,10 +616,13 @@ def _token_rows_to_buffer_fwd(flat, sent):
 
 def _token_rows_to_buffer_bwd(res, d_rows):
     flat, sent = res
-    if walks_rows(sent):
-        d_flat = _rows_summed_by_token(d_rows, sent)
+    form = pass_back(d_rows, sent)
+    if form == "held_pairs":
+        d_flat = pair_rows(d_rows, sent)
+    elif form == "pairs":
+        d_flat = _pairs_summed(d_rows, sent)
     else:
-        d_flat = _of_pairs(d_rows, sent).astype(jnp.float32).sum(axis=1)
+        d_flat = _rows_summed_by_token(d_rows, sent)
     return reduced_to_vma_of(flat, d_flat.astype(flat.dtype)), None
 
 
@@ -345,18 +642,21 @@ def buffer_rows_to_tokens(out, weight, sent: Dispatch):
     elsewhere.  Where :func:`walks_rows` the forward is a scatter-add of the
     weighted rows under ``group_sizes.sum()`` (in column slabs where
     :func:`way_back` says so) and ``d_weight`` a write of their scalars;
-    otherwise both are gathers over every pair."""
+    otherwise both are gathers over every pair, the forward
+    :func:`pair_rows`' where :func:`pass_back` says ``held_pairs``."""
     return _buffer_rows_to_tokens_fwd(out, weight, sent)[0]
 
 
 def _buffer_rows_to_tokens_fwd(out, weight, sent):
-    if walks_rows(sent):
+    form = pass_back(out, sent)
+    if form == "held_pairs":
+        mixed = pair_rows(out, sent, weight.astype(jnp.float32))
+    elif form == "pairs":
+        mixed = _pairs_summed(out, sent, weight)
+    else:
         weighted = weight.reshape(-1)[sent.pair][:, None] \
             * out.astype(jnp.float32)
         mixed = _rows_summed_by_token(weighted, sent)
-    else:
-        picked = _of_pairs(out, sent).astype(jnp.float32)
-        mixed = (picked * weight[..., None]).sum(axis=1)
     return mixed.astype(out.dtype), (out, weight, sent)
 
 

@@ -32,11 +32,15 @@ from horovod_tpu.models import (MoEConfig, TransformerLM,
                                 moe_next_token_loss, next_token_loss,
                                 record_expert_rows, router_losses)
 from horovod_tpu.models.transformer import SparseExperts
-from horovod_tpu.ops.moe import (ROW_SLAB_WIDTH, ROW_WALK_PAIRS_PER_ROW,
-                                 TILED_FORMS, WHOLE_ROW_WIDTH,
+from horovod_tpu.ops import moe as moe_ops
+from horovod_tpu.ops.moe import (HELD_PAIRS_BUFFER_BYTES, HELD_PAIRS_PER_ROW,
+                                 ROW_SLAB_WIDTH, ROW_WALK_PAIRS_PER_ROW,
+                                 TILED_FORMS,
+                                 WAYS_BACK, WHOLE_ROW_WIDTH,
                                  buffer_rows_to_tokens, dispatch_rows,
                                  grouped_kernel, grouped_matmul,
-                                 grouped_rows, grouped_weights, product_kernel,
+                                 grouped_rows, grouped_weights, pair_rows,
+                                 pair_rows_tiles, pass_back, product_kernel,
                                  token_rows_to_buffer, top_choices,
                                  walks_rows, way_back)
 
@@ -348,8 +352,13 @@ def routed(layout, dtype, seed=9):
     rows = MOVER_TOKENS * k if bound is None else bound
     sent = dispatch_rows(expert, i * local, local, rows)
     assert walks_rows(sent) == (experts == FEW[0])
+    # Static shapes alone; in this process (no TPU) `held_pairs` is `pairs`.
     assert way_back(sent, width) == (
-        "pairs" if experts != FEW[0] else "row_slabs" if wide else "rows")
+        ("row_slabs" if wide else "rows") if experts == FEW[0]
+        else "held_pairs" if MOVER_TOKENS * k >= HELD_PAIRS_PER_ROW * rows
+        else "pairs")
+    assert pass_back(jax.ShapeDtypeStruct((rows, width), dtype), sent) \
+        == way_back(sent, width).replace("held_pairs", "pairs")
     if wide:
         held = np.asarray(sent.token_of_row)[:int(sent.group_sizes.sum())]
         rows_of_token = np.bincount(held, minlength=MOVER_TOKENS)
@@ -464,24 +473,32 @@ def test_rows_past_the_held_ones_take_no_part(layout, dtype):
         np.testing.assert_array_equal(a, b)
 
 
-@pytest.mark.parametrize("pairs,width,form", [
-    (ROW_WALK_PAIRS_PER_ROW * 16, 4, "rows"),
-    (ROW_WALK_PAIRS_PER_ROW * 16 - 1, 4, "pairs"),
-    (ROW_WALK_PAIRS_PER_ROW * 16, WHOLE_ROW_WIDTH, "rows"),
-    (ROW_WALK_PAIRS_PER_ROW * 16, WHOLE_ROW_WIDTH + 1, "row_slabs"),
-    (ROW_WALK_PAIRS_PER_ROW * 16 - 1, WHOLE_ROW_WIDTH + 1, "pairs")],
-    ids=["at", "one_under", "widest_whole", "one_wider", "one_under_wide"])
-def test_the_buffers_rows_are_walked_from_the_constant_on(pairs, width, form):
+@pytest.mark.parametrize("pairs,width,form,rule", [
+    (ROW_WALK_PAIRS_PER_ROW * 16, 4, "rows", "rows"),
+    (ROW_WALK_PAIRS_PER_ROW * 16 - 1, 4, "pairs", "held_pairs"),
+    (ROW_WALK_PAIRS_PER_ROW * 16, WHOLE_ROW_WIDTH, "rows", "rows"),
+    (ROW_WALK_PAIRS_PER_ROW * 16, WHOLE_ROW_WIDTH + 1, "row_slabs",
+     "row_slabs"),
+    (ROW_WALK_PAIRS_PER_ROW * 16 - 1, WHOLE_ROW_WIDTH + 1, "pairs",
+     "held_pairs"),
+    (HELD_PAIRS_PER_ROW * 16, 4, "pairs", "held_pairs"),
+    (HELD_PAIRS_PER_ROW * 16 - 1, 4, "pairs", "pairs")],
+    ids=["at", "one_under", "widest_whole", "one_wider", "one_under_wide",
+         "at_the_kernels", "one_under_the_kernels"])
+def test_the_buffers_rows_are_walked_from_the_constant_on(pairs, width, form,
+                                                          rule):
     """`walks_rows` and `way_back` read static shapes alone: pairs >= C *
     bound, then the row's width against `WHOLE_ROW_WIDTH` — one scatter-add
     of whole rows up to it, one a slab of `ROW_SLAB_WIDTH` columns past
-    it."""
+    it; under C, from `HELD_PAIRS_PER_ROW` pairs a row on, the kernel's form,
+    which a process off the TPU runs as the gather (`pass_back`)."""
     expert = jnp.arange(pairs, dtype=jnp.int32)[:, None] % EXPERTS
     sent = dispatch_rows(expert, 0, 1, 16)
     walks = form != "pairs"
-    assert walks_rows(sent) is walks and way_back(sent, width) == form
-    assert sent.rows_walked == (16 if walks else pairs)
     rows = jnp.ones((16, width), jnp.bfloat16)
+    assert walks_rows(sent) is walks and way_back(sent, width) == rule
+    assert pass_back(rows, sent) == form
+    assert sent.rows_walked(form) == (16 if walks else pairs)
     text = jax.jit(lambda r: buffer_rows_to_tokens(
         r, jnp.ones((pairs, 1)), sent)).lower(rows).as_text()
     assert text.count('"stablehlo.scatter"') == {
@@ -724,6 +741,221 @@ def test_grouped_matmul_takes_the_kernel_its_shapes_choose(monkeypatch,
         np.testing.assert_allclose(g, w, atol=2e-4)
 
 
+# The `held_pairs` kernel (`ops.moe.pair_rows`, interpreted here) against the
+# `pairs` form's gather, mask, products and sum: 256 tokens in two tiles, 4
+# choices of 16 experts, the second quarter of them held here.  The first
+# tokens choose by hand — all 4 choices here (and all of ONE expert: a run of
+# four rows a token), one here, none here — and the rest by a seeded router;
+# the buffer holds every pair that is routed here or, "cut", fewer: the last
+# expert's run ends at the bound and the rest is counted.
+PAIR_TOKENS, PAIR_CHOICES, PAIR_EXPERTS, PAIR_WIDTH = 256, 4, 16, 256
+PAIR_BOUNDS = {"every_pair": PAIR_TOKENS * PAIR_CHOICES, "cut": 160}
+
+
+def pair_routing(bound, dtype, seed=52):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    probs = jax.nn.softmax(2 * jax.random.normal(
+        keys[0], (PAIR_TOKENS, PAIR_EXPERTS)))
+    weight, expert = jax.lax.top_k(probs, PAIR_CHOICES)
+    for token, chosen in {0: [4, 5, 6, 7], 1: [5, 5, 5, 5], 2: [0, 6, 9, 12],
+                          3: [0, 1, 2, 3], 130: [7, 6, 5, 4],
+                          255: [12, 13, 14, 15]}.items():
+        expert = expert.at[token].set(jnp.array(chosen, expert.dtype))
+    sent = dispatch_rows(expert, 4, 4, bound)
+    of_token = np.asarray(sent.valid).sum(axis=1)
+    assert {0, 1, PAIR_CHOICES} <= set(of_token.tolist())
+    buffer = jax.random.normal(keys[1], (bound, PAIR_WIDTH), dtype)
+    return sent, buffer, weight
+
+
+def pairs_form(buffer, sent, weight=None):
+    return moe_ops._pairs_summed(buffer, sent, weight).astype(buffer.dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted", "plain"])
+@pytest.mark.parametrize("bound", list(PAIR_BOUNDS))
+def test_pair_rows_kernel_matches_the_pairs_form(bound, weighted, dtype):
+    sent, buffer, weight = pair_routing(PAIR_BOUNDS[bound], dtype)
+    assert (int(sent.rows_over_bound) > 0) == (bound == "cut")
+    assert pair_rows_tiles(PAIR_TOKENS, *buffer.shape)
+    weight = weight if weighted else None
+    got = pair_rows(buffer, sent, weight, interpret=True)
+    want = pairs_form(buffer, sent, weight)
+    assert got.dtype == want.dtype == dtype and got.shape == want.shape
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    # float32 sums in another order, rounded once: an ulp or two of the
+    # largest entry; a token with no row here reads zero, not garbage.
+    ulp = 2.0 ** -7 if dtype == jnp.bfloat16 else 2.0 ** -20
+    assert np.abs(got - want).max() <= 2 * ulp * max(np.abs(want).max(), 1)
+    assert not got[~np.asarray(sent.valid).any(axis=1)].any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted", "plain"])
+def test_pair_rows_kernel_reads_no_row_a_pair_does_not_own(weighted, dtype):
+    """NaN in every buffer row that no valid pair owns — the rows at and past
+    `group_sizes.sum()`, which the kernel's last block of a run fetches with
+    the run — changes not a bit of the result.  NaN in a row that a pair does
+    own reaches that pair's token, and no token of another tile, whose blocks
+    may hold the row too (inside the owner's tile the product's zeros carry
+    it: `pair_rows` says so)."""
+    sent, buffer, weight = pair_routing(PAIR_BOUNDS["every_pair"], dtype)
+    weight = weight if weighted else None
+    held = int(sent.group_sizes.sum())
+    assert 0 < held < buffer.shape[0] and held % 16
+    row = jnp.arange(buffer.shape[0])[:, None]
+    clean = pair_rows(buffer, sent, weight, interpret=True)
+    dirty = pair_rows(jnp.where(row >= held, jnp.nan, buffer), sent, weight,
+                      interpret=True)
+    assert np.isfinite(np.asarray(dirty, np.float32)).all()
+    np.testing.assert_array_equal(clean, dirty)
+    owned = held // 2
+    one = np.asarray(pair_rows(jnp.where(row == owned, jnp.nan, buffer), sent,
+                               weight, interpret=True), np.float32)
+    owner = int(sent.token_of_row[owned])
+    assert np.isnan(one[owner]).all()
+    others = np.arange(PAIR_TOKENS) // 128 != owner // 128
+    np.testing.assert_array_equal(one[others],
+                                  np.asarray(clean, np.float32)[others])
+
+
+@pytest.fixture
+def held_pairs_here(monkeypatch):
+    """A process that takes `held_pairs` at any buffer's size: the constant at
+    zero, the backend said to be a TPU, the kernel interpreted."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(moe_ops, "HELD_PAIRS_BUFFER_BYTES", 0)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+# A layer of whole tiles: 256 tokens of 128 wide, a buffer of 512 rows.
+def tiled_layer(row_bound=None):
+    layer = SparseExperts(MoEConfig(EXPERTS, PER_TOKEN, WIDTH, (0, 4),
+                                    row_bound), jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(52), 3)
+    x = jax.random.normal(keys[0], (2, SEQ, 128))
+    mix = jax.random.normal(keys[1], (2, SEQ, 128))
+    return layer, layer.init(keys[2], x)["params"], x, mix
+
+
+def test_the_layer_under_held_pairs_matches_the_pairs_form(request):
+    """The layer's output and every gradient, by its weights and by its
+    input, with the way back through the kernel (both callers: the
+    combine's forward, the dispatch's backward) against the same layer
+    through the `pairs` form: the two differ in the order of a token's k
+    float32 terms."""
+    from tests.test_ops import _pallas_call_names
+
+    layer, params, x, mix = tiled_layer()
+
+    def loss(params, x):
+        return (layer.apply({"params": params}, x) * mix).sum()
+
+    grad = jax.value_and_grad(loss, (0, 1))
+    want = grad(params, x)
+    assert "hvd_moe_pair_rows" not in _pallas_call_names(
+        jax.make_jaxpr(grad)(params, x).jaxpr)
+    request.getfixturevalue("held_pairs_here")
+    names = _pallas_call_names(jax.make_jaxpr(grad)(params, x).jaxpr)
+    assert names.count("hvd_moe_pair_rows") == 2
+    got = grad(params, x)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.isfinite(np.asarray(g)).all()
+        assert rel(g, w) <= 10 * RTOL
+
+
+def test_held_pairs_reaches_the_gauge_and_the_registry(held_pairs_here):
+    """The fourth form under its own name: in the `intermediates`
+    collection, in `metrics_snapshot()["moe"]` and in the exposition, with a
+    pass's rows the buffer's and not every pair's."""
+    model = lm(moe=MoEConfig(EXPERTS, PER_TOKEN, WIDTH, (0, 4), 1.0),
+               hidden=128)
+    params, batch = seeded(model, seed=8, batch=8)
+    _, state = model.apply({"params": params}, batch[0],
+                           mutable=["intermediates"])
+    was_on = metrics.registry.enabled
+    metrics.registry.enable()
+    try:
+        seen = record_expert_rows(state["intermediates"])
+        mirrored = metrics.registry.snapshot()["moe"]
+    finally:
+        if not was_on:
+            metrics.registry.disable()
+    assert seen["way_back"] == ["held_pairs"] * LAYERS == mirrored["way_back"]
+    assert seen["rows_walked"] == [512] * LAYERS == mirrored["rows_walked"]
+    assert 512 < 8 * SEQ * PER_TOKEN
+    text = metrics.prometheus_text(
+        {**metrics.registry.snapshot(), "moe": mirrored})
+    assert 'hvd_tpu_moe_way_back{layer="1",form="held_pairs"} 1' in text
+    assert 'hvd_tpu_moe_rows_walked{layer="1"} 512' in text
+
+
+# The form the way back takes in every benchmark cell's expert layers, from
+# the cell's static shapes: (tokens a step a chip, form; None: no experts).
+# OLMoE's builder pins its step's custom calls: its 101 MB buffer is under
+# the one constant and its 2.67 pairs a row under the other; Mellum's 226 MB
+# is past the first, SDAR's and Trinity's 5.33 pairs a row past the second.
+CELL_WAYS_BACK = {
+    "resnet50": (None, None), "pythia410m": (None, None),
+    "olmoe1b7b": (8192, "pairs"), "nemotron3super120b": (4096, "rows"),
+    "ling3flash": (8192, "row_slabs"), "trinitymini": (8192, "held_pairs"),
+    "sdar30ba3b": (8192, "held_pairs"), "qwen3next80b": (4096, "row_slabs"),
+    "mellum2": (16384, "held_pairs")}
+
+
+@pytest.mark.parametrize("name", list(CELL_WAYS_BACK))
+def test_the_way_back_on_the_benchmark_configurations(monkeypatch, name):
+    assert sorted(CELL_WAYS_BACK) == sorted(CELL_KERNELS)
+    tokens, form = CELL_WAYS_BACK[name]
+    if form is None:
+        assert cell_experts(name) is None
+        return
+    moe, width, _, local = cell_experts(name)
+    k = moe.experts_per_token
+    sent = jax.eval_shape(
+        lambda e: dispatch_rows(e, 0, local, moe.buffer_rows(tokens)),
+        jax.ShapeDtypeStruct((tokens, k), jnp.int32))
+    rows = jax.ShapeDtypeStruct((moe.buffer_rows(tokens), width),
+                                jnp.bfloat16)
+    assert way_back(sent, width, 2) == form
+    assert (rows.shape[0] * width * 2 > HELD_PAIRS_BUFFER_BYTES
+            or tokens * k >= HELD_PAIRS_PER_ROW * rows.shape[0]) \
+        == (form == "held_pairs") or walks_rows(sent)
+    # In a process off the TPU the kernel's form falls to the gather's.
+    assert pass_back(rows, sent) == ("pairs" if form == "held_pairs"
+                                     else form)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pass_back(rows, sent) == form
+
+
+@pytest.mark.parametrize("tokens,rows,width,whole", [
+    (256, 512, 128, True), (200, 512, 128, False), (256, 500, 128, False),
+    (256, 512, 192, False)], ids=str)
+def test_held_pairs_wants_whole_tiles(monkeypatch, tokens, rows, width,
+                                      whole):
+    """Past the constant, on a TPU: the kernel where the tokens are whole
+    tiles of 128, the buffer whole blocks of 16 rows and a row whole lanes;
+    the `pairs` form elsewhere."""
+    monkeypatch.setattr(moe_ops, "HELD_PAIRS_BUFFER_BYTES", 0)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sent = jax.eval_shape(lambda e: dispatch_rows(e, 0, 2, rows),
+                          jax.ShapeDtypeStruct((tokens, 4), jnp.int32))
+    buffer = jax.ShapeDtypeStruct((rows, width), jnp.bfloat16)
+    assert way_back(sent, width, 2) == "held_pairs" == WAYS_BACK[3]
+    assert pair_rows_tiles(tokens, rows, width) is whole
+    assert pass_back(buffer, sent) == ("held_pairs" if whole else "pairs")
+    assert sent.rows_walked("held_pairs") == rows
+    assert sent.rows_walked("pairs") == tokens * 4
+
+
 # Tokens a step a chip of every benchmark cell's configuration, and the
 # kernel its expert layers' products take on the chip (None: no experts).
 # OLMoE's, Nemotron's and Ling's builders pin libtpu's custom calls in their
@@ -746,20 +978,28 @@ def test_every_benchmark_configuration_is_in_the_table():
         name[:-5] for name in os.listdir(CONFIGS))
 
 
-@pytest.mark.parametrize("name", list(CELL_KERNELS))
-def test_the_rule_on_the_benchmark_configurations(monkeypatch, name):
+def cell_experts(name):
+    """(MoEConfig, the rows' width, the experts' width, local experts) of a
+    benchmark configuration's expert layers; None where it has none."""
     with open(os.path.join(CONFIGS, name + ".json")) as f:
         config = json.load(f)
-    tokens, kernel = CELL_KERNELS[name]
     experts = config.get("num_experts") or config.get("n_routed_experts")
-    if kernel is None:
-        assert experts is None
-        return
+    if experts is None:
+        return None
     moe = MoEConfig(experts, config["num_experts_per_tok"], 0,
                     tuple(config["expert_shard"]), config["row_bound"])
-    k = config.get("moe_latent_size") or config["hidden_size"]
-    n = config.get("moe_intermediate_size") or config["intermediate_size"]
-    local = experts // moe.expert_shard[1]
+    return (moe, config.get("moe_latent_size") or config["hidden_size"],
+            config.get("moe_intermediate_size") or config["intermediate_size"],
+            experts // moe.expert_shard[1])
+
+
+@pytest.mark.parametrize("name", list(CELL_KERNELS))
+def test_the_rule_on_the_benchmark_configurations(monkeypatch, name):
+    tokens, kernel = CELL_KERNELS[name]
+    if kernel is None:
+        assert cell_experts(name) is None
+        return
+    moe, k, n, local = cell_experts(name)
     rows = jax.ShapeDtypeStruct((moe.buffer_rows(tokens), k), jnp.bfloat16)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for a, b in ((k, n), (n, k)):       # up and down

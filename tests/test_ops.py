@@ -690,7 +690,11 @@ def test_hybrid_step_is_products_and_kernels_with_no_loop(v5e, monkeypatch):
     assert len(re.findall(r"%hvd_flash_fwd[.\d]* = ", text)) == 1
     assert len(re.findall(r"%hvd_flash_bwd[.\d]* = ", text)) == 1
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 6
-    assert text.count('"tpu_custom_call"') == 2 + 6 + 2
+    # 16,384 pairs for a buffer of 3,072 rows: under the row walk's 8 pairs a
+    # row (the cell's own 35 are over it) and past `HELD_PAIRS_PER_ROW`, so
+    # the way back is the kernel's two calls.
+    assert len(re.findall(r"%hvd_moe_pair_rows[.\d]* = ", text)) == 2
+    assert text.count('"tpu_custom_call"') == 2 + 6 + 2 + 2
     _assert_scopes_forward_and_backward(
         text, ("hvd_ssm_in_proj", "hvd_ssm_conv", "hvd_ssm_scan",
                "hvd_ssm_gate_norm", "hvd_ssm_out_proj", "hvd_moe_latent",
@@ -752,7 +756,9 @@ def test_ling_step_is_products_kernels_and_one_loop_a_pass(v5e, monkeypatch):
     for kernel in ("hvd_flash_fwd", "hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"):
         assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 9
-    assert text.count('"tpu_custom_call"') == 3 + 9 + 2
+    # The experts' rows come back by the kernel, as in the hybrid step above.
+    assert len(re.findall(r"%hvd_moe_pair_rows[.\d]* = ", text)) == 2
+    assert text.count('"tpu_custom_call"') == 3 + 9 + 2 + 2
     _assert_scopes_forward_and_backward(
         text, ("hvd_kda_in_proj", "hvd_kda_conv", "hvd_kda_gate",
                "hvd_kda_scan", "hvd_kda_gate_norm", "hvd_kda_out_proj",
@@ -974,6 +980,48 @@ def test_grouped_matmul_at_mellum_and_sdar_widths_compiles(
         assert len(re.findall(rf"%\w*hvd_grouped_{form}[.\d]* = ",
                               text)) == 1, form
     assert "ragged-dot" not in text and " while(" not in text
+
+
+@pytest.mark.parametrize("tokens,rows,width,form", [
+    (16384, 49152, 2304, "held_pairs"), (8192, 24576, 2048, "pairs")],
+    ids=["mellum", "olmoe"])
+def test_the_way_back_at_mellum_and_olmoe_shapes_compiles(
+        v5e, monkeypatch, tokens, rows, width, form):
+    """The rows' two movements and their gradients at a chip's quarter share
+    of 64 experts, 8 a token, for the described chip.  Mellum's 226 MB buffer
+    is past `HELD_PAIRS_BUFFER_BYTES`: the combine's forward and the
+    dispatch's backward are one `hvd_moe_pair_rows` call each (its blocks'
+    landing place and the sum fit the VMEM the call asks for) and no array
+    of every pair's row is left in the program; OLMoE's 101 MB keeps the
+    k-wide gathers and no kernel."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.ops.moe import (buffer_rows_to_tokens, dispatch_rows,
+                                     token_rows_to_buffer, way_back)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    on_chip = SingleDeviceSharding(v5e[0])
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    def loss(flat, weight, expert, mix):
+        sent = dispatch_rows(expert, 0, 16, rows)
+        assert way_back(sent, width, 2) == form
+        mixed = buffer_rows_to_tokens(token_rows_to_buffer(flat, sent),
+                                      weight, sent)
+        return (mixed * mix).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        shaped((tokens, width), jnp.bfloat16), shaped((tokens, 8),
+                                                      jnp.float32),
+        shaped((tokens, 8), jnp.int32),
+        shaped((tokens, width), jnp.bfloat16)).compile().as_text()
+    kernels = len(re.findall(r"%\w*hvd_moe_pair_rows[.\d]* = ", text))
+    every_pairs_row = f"bf16[{tokens},8,{width}]" in text
+    assert (kernels, every_pairs_row) == ((2, False) if form == "held_pairs"
+                                          else (0, True))
+    assert " while(" not in text and "scatter" not in text
 
 
 @pytest.mark.parametrize("d_model,experts,grouped", [
