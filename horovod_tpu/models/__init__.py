@@ -29,10 +29,13 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     MoEConfig,
     RopeScaling,
     TransformerLM,
+    log_exit_distribution,
+    looped_exit_loss,
     masked_diffusion_loss,
     moe_next_token_loss,
     next_token_loss,
     record_attention_blocks,
+    record_exit_distribution,
     record_expert_rows,
     router_losses,
 )

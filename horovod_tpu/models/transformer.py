@@ -1313,9 +1313,31 @@ class TransformerLM(nn.Module):
     rope_scaling: Optional[RopeScaling] = None
     window_rope: Optional[Tuple[float, Optional[RopeScaling]]] = None
     recompute: bool = False
+    # A looped model: the pattern runs ``loops`` times over ONE set of
+    # weights, ``final_norm`` after every pass, and the normed state is what
+    # the next pass reads (``h_t = final_norm(layers(h_{t-1}))``).  The
+    # parameter tree is the one-pass model's; the compiled program holds the
+    # pattern's bodies once, inside a loop over the passes (``nn.scan`` with
+    # the parameters broadcast), and what a layer sows is stacked a pass.
+    # Unset, nothing of it is traced.  With ``loops`` alone the logits are the
+    # last pass's (and ``targets=`` is refused).  ``exit_gate`` adds one gate a model, ``z_t = w_g . h_t +
+    # b_g`` a position in float32 (``exit_gate_kernel``, ``exit_gate_bias``;
+    # scope ``hvd_exit_gate``), applies the head to every pass's state and
+    # returns ``(logits, gate_logits)``, ``(loops, batch, seq, vocab)`` and
+    # ``(loops, batch, seq)`` — or, called with ``targets=``, ``(per-token
+    # cross-entropies, gate_logits)``, both ``(loops, batch, seq)``, a pass's
+    # head and cross-entropy under ``jax.checkpoint`` so that ONE pass's
+    # logits are alive at a time, forward and backward:
+    # :func:`looped_exit_loss` takes the pair.  The gate logits are sown as
+    # ``exit_gate_logits`` (:func:`record_exit_distribution`).  Training over
+    # a pattern on one sequence shard only.
+    loops: Optional[int] = None
+    exit_gate: bool = False
 
     @nn.compact
     def __call__(self, tokens, targets=None, decode_ctx=None, noised=None):
+        if self.loops is not None:
+            return self._looped(tokens, targets, decode_ctx, noised)
         if self.layers is not None and (decode_ctx is not None
                                         or self.seq_axis is not None):
             raise ValueError(
@@ -1349,15 +1371,7 @@ class TransformerLM(nn.Module):
                 x = (x * self.embed_scale).astype(self.dtype)
         new_ks, new_vs = [], []
         for i, kind in enumerate(self.layers or ()):
-            x = MixerLayer(kind, self.n_heads, self.dtype, self.use_flash,
-                           self.moe, self.ssm, self.qk_norm, self.norm_eps,
-                           self.n_kv_heads, self.rope, self.head_shard,
-                           self.delta, self.latent, d_ff, self.head_dim,
-                           self.window, self.head_norm, self.attn_gate,
-                           self.post_norm, self.block_diffusion,
-                           self.rope_theta, self.rotary_dim,
-                           self.rope_scaling, self.window_rope,
-                           self.recompute, name=f"layer_{i}")(x)
+            x = self._pattern_layer(i, kind, d_ff)(x)
         for i in range(0 if self.layers is not None else self.n_layers):
             block = Block(self.n_heads, d_ff, self.dtype, self.seq_axis,
                           self.use_flash, self.ring_impl, self.capture_kv,
@@ -1396,6 +1410,98 @@ class TransformerLM(nn.Module):
             return logits, (jnp.stack(new_ks), jnp.stack(new_vs))
         return logits
 
+    @nn.nowrap
+    def _pattern_layer(self, i, kind, d_ff):
+        return MixerLayer(kind, self.n_heads, self.dtype, self.use_flash,
+                          self.moe, self.ssm, self.qk_norm, self.norm_eps,
+                          self.n_kv_heads, self.rope, self.head_shard,
+                          self.delta, self.latent, d_ff, self.head_dim,
+                          self.window, self.head_norm, self.attn_gate,
+                          self.post_norm, self.block_diffusion,
+                          self.rope_theta, self.rotary_dim,
+                          self.rope_scaling, self.window_rope,
+                          self.recompute, name=f"layer_{i}")
+
+    @nn.nowrap
+    def _looped(self, tokens, targets, decode_ctx, noised):
+        """``__call__`` under ``loops``: the field's comment has what it
+        computes and returns."""
+        if self.layers is None or decode_ctx is not None \
+                or noised is not None or self.seq_axis is not None \
+                or self.block_diffusion is not None or self.loops < 1 \
+                or (targets is not None and not self.exit_gate):
+            raise ValueError(
+                "loops= runs a per-layer pattern (layers=) one or more times; "
+                "it composes with neither decode_ctx=, noised= / "
+                "block_diffusion= nor sequence parallelism, and takes "
+                "targets= only with exit_gate=True.")
+        d_ff = self.d_ff or 4 * self.d_model
+        with jax.named_scope("hvd_embed"):
+            x = TokenEmbed(self.vocab_size, self.d_model,
+                           dtype=self.dtype, name="embed")(tokens)
+            if self.embed_scale is not None:
+                x = (x * self.embed_scale).astype(self.dtype)
+        w = self.param(
+            "lm_head_kernel",
+            nn.initializers.variance_scaling(1.0, "fan_in",
+                                             "truncated_normal"),
+            (self.d_model, self.vocab_size), jnp.float32)
+        gate = None
+        if self.exit_gate:
+            # z = w_g . h + b_g: at 1 / sqrt(d_model) an element over a normed
+            # state the seeded logit is of unit size, lambda about a half.
+            gate = (self.param("exit_gate_kernel",
+                               nn.initializers.normal(self.d_model ** -0.5),
+                               (self.d_model,), jnp.float32),
+                    self.param("exit_gate_bias", nn.initializers.zeros, (),
+                               jnp.float32))
+            with jax.named_scope("hvd_lm_head"):
+                w = w.astype(self.dtype)          # once, not once a pass
+
+        def one_pass(model, x, w, gate, targets):
+            for i, kind in enumerate(model.layers):
+                x = model._pattern_layer(i, kind, d_ff)(x)
+            x = nn.RMSNorm(epsilon=model.norm_eps, dtype=model.dtype,
+                           name="final_norm")(x)
+            if gate is None:
+                return x, None
+            with jax.named_scope("hvd_exit_gate"):
+                # Float32 on the vector unit: a float32 matmul is bfloat16
+                # passes on the MXU.
+                z = (x.astype(jnp.float32) * gate[0]).sum(axis=-1) + gate[1]
+            if targets is None:
+                return x, (_head_logits(x, w, model.dtype,
+                                        model.logits_dtype), z)
+            return x, (jax.checkpoint(_head_token_xent, static_argnums=(3, 4))(
+                x, w, targets, model.dtype, model.logits_dtype), z)
+
+        x, out = nn.scan(
+            one_pass, variable_broadcast="params",
+            variable_axes={"intermediates": 0}, split_rngs={"params": False},
+            in_axes=nn.broadcast, length=self.loops)(
+                self, x, w, gate, targets)
+        if gate is not None:
+            self.sow("intermediates", "exit_gate_logits", out[1])
+            return out
+        return _head_logits(x, w, self.dtype, self.logits_dtype)
+
+
+def _head_logits(x, w, dtype, logits_dtype):
+    """The head's product as :class:`TransformerLM` runs it."""
+    with jax.named_scope("hvd_lm_head"):
+        return jnp.einsum("bsd,dv->bsv", x.astype(dtype), w.astype(dtype),
+                          preferred_element_type=jnp.float32).astype(
+                              logits_dtype)
+
+
+def _head_token_xent(x, w, targets, dtype, logits_dtype):
+    """Per-token cross-entropy ``(batch, seq)`` of one pass's state through
+    the head; a looped model runs it under ``jax.checkpoint``, which keeps
+    ``x`` and computes the logits again in the backward pass."""
+    logits = _head_logits(x, w, dtype, logits_dtype)
+    with jax.named_scope("hvd_token_xent"):
+        return _token_xent(logits, targets)
+
 
 def fused_next_token_loss(hidden, w, targets, dtype=jnp.bfloat16,
                           n_chunks: int = 8):
@@ -1414,7 +1520,11 @@ def fused_next_token_loss(hidden, w, targets, dtype=jnp.bfloat16,
     round-trips, so use it when the logits tensor does not fit comfortably
     (long sequences, big vocab, large batch), not as a throughput knob: no
     benchmark cell runs it, and its speed against the full-logits path is
-    not measured on this machine (PERF.md section 7).
+    not measured on this machine (PERF.md section 7).  It returns the MEAN, a
+    scalar: a loss that weighs each token's cross-entropy by something the
+    model computes (:func:`looped_exit_loss`) needs the per-token values,
+    which a looped model takes from its own head a pass under
+    ``jax.checkpoint`` (``TransformerLM(loops=, exit_gate=True)``).
 
     Head and loss are one loop here, so the whole of it runs under the
     head's scope, ``hvd_lm_head``; there is no ``hvd_token_xent`` inside.
@@ -1499,6 +1609,11 @@ def next_token_loss(logits, targets, mask=None, axis_name=None):
     XLA materialises that cast.  A ``custom_vjp`` has no forward mode:
     ``jax.jvp`` / ``jacfwd`` / ``hessian`` of this loss raise (nothing in
     ``horovod_tpu/``, ``examples/`` or ``tests/`` takes them).
+
+    The cross-entropy is per token (``_token_xent``) and the mean is taken
+    here, at once: :func:`masked_diffusion_loss` and a looped model's head
+    (``TransformerLM(loops=, exit_gate=True)``, for :func:`looped_exit_loss`)
+    weigh the same per-token values their own way.
     """
     with jax.named_scope("hvd_token_xent"):
         loss = _token_xent(logits, targets)
@@ -1532,6 +1647,39 @@ def masked_diffusion_loss(logits, targets, masked, level):
             loss = _token_xent(logits, targets)
         weight = masked.astype(loss.dtype) / level.astype(loss.dtype)
         return (loss * weight).mean()
+
+
+def log_exit_distribution(gate_logits):
+    """``log p`` of a looped model's exit distribution, ``(loops, ...)`` from
+    gate logits ``z`` of that shape, in float32: with ``lambda_t =
+    sigmoid(z_t)``, ``p_1 = lambda_1``, ``p_t = lambda_t prod_{j<t} (1 -
+    lambda_j)`` for ``1 < t < loops``, and the last pass takes what is left,
+    ``p_T = prod_{j<T} (1 - lambda_j)`` (``z_T`` is unused) — so ``p`` sums to
+    one a position.  In logarithms (``log_sigmoid``), so that a saturated gate
+    gives no infinity."""
+    z = gate_logits[:-1].astype(jnp.float32)
+    none = jnp.zeros((1,) + z.shape[1:], jnp.float32)
+    stayed = jnp.concatenate([none, jnp.cumsum(jax.nn.log_sigmoid(-z), 0)])
+    return stayed + jnp.concatenate([jax.nn.log_sigmoid(z), none])
+
+
+def looped_exit_loss(per_token_ce, gate_logits, beta: float = 0.1):
+    """The first-stage objective of a looped language model with learned exits
+    (Ouro, arXiv:2510.25741): ``mean over positions of [sum_t p_t CE_t - beta
+    H(p)]`` — ``per_token_ce`` the passes' per-token cross-entropies and
+    ``gate_logits`` the exit gate's logits, both ``(loops, batch, seq)`` as
+    ``TransformerLM(loops=, exit_gate=True)`` returns them under ``targets=``,
+    ``p`` :func:`log_exit_distribution`'s, ``H(p) = -sum_t p_t log p_t``.
+    Plain float32 arithmetic over its two arguments, under the scope
+    ``hvd_exit_loss``; the gradient reaches the model through the
+    cross-entropies (weighed by ``p``) and through the gate (by ``CE_t`` and
+    the entropy's slope)."""
+    with jax.named_scope("hvd_exit_loss"):
+        log_p = log_exit_distribution(gate_logits)
+        p = jnp.exp(log_p)
+        expected = (p * per_token_ce.astype(jnp.float32)).sum(axis=0)
+        entropy = -(p * log_p).sum(axis=0)
+        return (expected - beta * entropy).mean()
 
 
 def _sown(tree, name):
@@ -1592,6 +1740,23 @@ def record_attention_blocks(intermediates) -> dict:
     if _metrics.registry.enabled:
         _metrics.registry.set_attention_blocks(**seen)
     return seen
+
+
+def record_exit_distribution(intermediates) -> dict:
+    """Read the exit gate's logits a looped model wrote to the
+    ``intermediates`` collection of one ``apply(..., mutable=
+    ["intermediates"])`` — outside the compiled step, on concrete arrays.
+    Returns ``{"mean_p": [mean over positions of p_t, per pass], "entropy":
+    mean H(p), "expected_passes": mean sum_t t p_t}`` (between 1 and
+    ``loops``: how deep the gate sends a token), ``p``
+    :func:`log_exit_distribution`'s."""
+    (z,) = _sown(intermediates, "exit_gate_logits")
+    log_p = log_exit_distribution(z)
+    p = jnp.exp(log_p)
+    mean_p = [float(x) for x in p.mean(axis=tuple(range(1, p.ndim)))]
+    return {"mean_p": mean_p,
+            "entropy": float(-(p * log_p).sum(axis=0).mean()),
+            "expected_passes": sum(t * x for t, x in enumerate(mean_p, 1))}
 
 
 def router_losses(router):

@@ -319,3 +319,36 @@ def test_gated_delta_layers_name_their_stages_under_scopes_of_their_own():
     for path in paths:
         if "/layer_0/" in path and "/mixer/" in path:
             assert _GDN.search(path), path
+
+
+def test_a_looped_models_exits_and_the_layers_in_its_loop_name_themselves():
+    """The Ouro cell's step, compiled at its rehearsal sizes: the exit gate
+    (`hvd_exit_gate`, inside the rolled loop over the passes) and the exit
+    loss (`hvd_exit_loss`, behind it) reach an operation's `op_name` forward
+    and backward; the layers, the head and the per-token cross-entropy keep
+    their scopes inside the loop's body, in both directions, and what is
+    computed again there carries `jax.checkpoint`'s marker.  The benchmark's
+    readers sort a trace by these names
+    (benchmark/layer_metrics/exit_time_share_pct.py)."""
+    text = lowered_step("ouro2p6b_1chip_pp6share_1x4k").compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    in_loop = "/while/body/"
+    for direction in (FORWARD, BACKWARD):
+        def named(scope, inside):
+            return any(direction in name and f"/{scope}/" in name
+                       and (in_loop in name.split(scope)[0]) == inside
+                       for name in names)
+
+        assert named("hvd_exit_loss", False), direction
+        for scope in ("hvd_exit_gate", "hvd_attn_qkv", "hvd_attn_attend",
+                      "hvd_attn_out", "hvd_mlp", "hvd_lm_head",
+                      "hvd_token_xent"):
+            assert named(scope, True), (direction, scope)
+    again = [name for name in names if "rematted_computation" in name]
+    # (A few instructions of a fused computation keep the body's own,
+    # relative name.)
+    assert again and all(BACKWARD in name and in_loop in name
+                         for name in again if name.startswith("jit("))
+    for scope in ("hvd_mlp", "hvd_attn_qkv", "hvd_lm_head", "hvd_token_xent"):
+        assert any(f"/{scope}/" in name for name in again), scope
+    assert not any("hvd_exit_loss" in name for name in again)
