@@ -1,0 +1,570 @@
+"""The chip's compiler, without the chip — whole steps read as programs.
+
+A few layers at each benchmark configuration's per-head widths through
+`build_train_step`, compiled by libtpu for the DESCRIBED v5e 2x2 host (the
+`v5e` fixture of tests/conftest.py; tests/test_chip_kernels.py has the kernels'
+own compiles and says what such a compile can and cannot show): which kernels,
+loops, collectives and scopes the compiled text holds, and which arrays it
+writes."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from horovod_tpu.jax.train import build_train_step
+from horovod_tpu.models import next_token_loss
+from horovod_tpu.parallel import data_parallel_mesh
+
+
+def _lowered_step(model, devices, rows=(1, 2048), loss_fn=None,
+                  batch=("tokens", "tokens"), **init_inputs):
+    """(the step, its parameters' shapes, the step lowered): ``model`` through
+    `build_train_step` under AdamW on a data-parallel mesh of the described
+    ``devices``.  Parameters are `jax.eval_shape`'s, replicated; the batch is
+    ``rows`` of int32 tokens (a name of ``batch`` may be a dtype: an array of
+    that type instead) split over the mesh's axis; the loss is
+    `next_token_loss` unless ``loss_fn`` is given."""
+    mesh = data_parallel_mesh(devices, axis_name="hvd")
+    tx = optax.adamw(1e-4)
+
+    def next_token(params, batch):
+        return next_token_loss(model.apply({"params": params}, batch[0]),
+                               batch[1])
+
+    def init(key):
+        blank = jnp.zeros((1, 128), jnp.int32)
+        params = model.init(key, blank,
+                            **{name: blank for name in init_inputs})["params"]
+        return params, tx.init(params)
+
+    def shaped(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+
+    params, opt_state = shaped(jax.eval_shape(init, jax.random.PRNGKey(0)),
+                               P())
+    batch = tuple(shaped(jax.ShapeDtypeStruct(
+        rows, jnp.int32 if kind == "tokens" else kind), P("hvd"))
+        for kind in batch)
+    step = build_train_step(loss_fn or next_token, tx, mesh, axis_name="hvd")
+    return step, params, step.lower(params, opt_state, batch)
+
+
+_LM_STEPS = {}     # devices -> what _compile_lm_step gave for them
+
+
+def _compile_lm_step(devices):
+    """A two-layer dense LM at pythia-410m's widths (a smaller vocabulary,
+    512 tokens a chip) through `build_train_step` on a data-parallel mesh
+    of the described ``devices``: (the step, its compiled text, the number
+    of weights whose gradient is over a megabyte in either dtype).  Compiled
+    once a process for a number of devices."""
+    if len(devices) not in _LM_STEPS:
+        _LM_STEPS[len(devices)] = _compiled_lm_step(devices)
+    return _LM_STEPS[len(devices)]
+
+
+def _compiled_lm_step(devices):
+    from horovod_tpu.jax.train import _EXCHANGE_OVERLAP
+    from horovod_tpu.models import TransformerLM
+
+    model = TransformerLM(vocab_size=8192, d_model=1024, n_layers=2,
+                          n_heads=16, d_ff=4096, dtype=jnp.bfloat16,
+                          logits_dtype=jnp.bfloat16, use_flash=True)
+    step, params, lowered = _lowered_step(model, devices,
+                                          rows=(len(devices), 512))
+    try:
+        text = lowered.compile().as_text()
+    except Exception as exc:  # noqa: BLE001 - libtpu names the option
+        pytest.fail("this libtpu refuses the step under the compiler options "
+                    f"of jax/train.py _EXCHANGE_OVERLAP "
+                    f"{sorted(_EXCHANGE_OVERLAP)}: {exc}")
+    # Over 2**19 elements a gradient is over a megabyte in bf16 and in f32;
+    # the model's other leaves (norm scales) are under it in both.
+    sizes = [x.size for x in jax.tree.leaves(params)]
+    assert all(n >= 2**19 or n * 4 < 2**20 for n in sizes), sizes
+    return step, text, sum(n >= 2**19 for n in sizes)
+
+
+def test_dp_step_exchanges_large_gradients_asynchronously(v5e, monkeypatch):
+    """What `build_train_step` promises of the gradient exchange, asked of
+    the chip's compiler.  Over four described chips every weight gradient
+    over a megabyte is an `async-collective-start`/`-done` pair of its own,
+    no all-reduce the core waits in has an operand that large (the small
+    leaves and the loss still travel, together), and the text still holds
+    an all-reduce for the benchmark's count; over one described chip the
+    step takes no option and holds neither.  This is the test that fails
+    when a libtpu upgrade renames, drops or re-reads one of the options."""
+    import math
+
+    from horovod_tpu.jax.train import _EXCHANGE_OVERLAP, count_all_reduces
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    options = (f"(jax/train.py _EXCHANGE_OVERLAP: {sorted(_EXCHANGE_OVERLAP)}"
+               "; PERF.md section 6, PR 29)")
+
+    step, text, large = _compile_lm_step(v5e[:4])
+    assert step.exchange_overlap["compiler_options"] == "applied"
+    n_async, n_sync = count_all_reduces(text)
+    dones = len(re.findall(r"^\s*%async-collective-done[\w.\-]* = ", text,
+                           re.M))
+    assert n_async == dones == large, (
+        f"{large} gradients over a megabyte, {n_async} asynchronous "
+        f"all-reduces, {dones} dones: one of the options lost its meaning "
+        f"{options}")
+    assert n_sync >= 1 and re.search(r"\ball-reduce\(", text)
+    waiting = [line.split(" all-reduce(")[0]
+               for line in _instructions_outside_fusions(text)
+               if " all-reduce(" in line]
+    assert len(waiting) == n_sync
+    width = {"bf16": 2, "f32": 4}
+    for result in waiting:
+        for dtype, dims in re.findall(r"\b(bf16|f32)\[([\d,]*)\]", result):
+            nbytes = width[dtype] * math.prod(
+                int(d) for d in dims.split(",") if d)
+            assert nbytes < 2**20, (
+                f"a synchronous all-reduce carries {nbytes} bytes: {result} "
+                f"{options}")
+
+    step, text, _ = _compile_lm_step(v5e[:1])
+    assert step.exchange_overlap["compiler_options"] == "not applied"
+    assert count_all_reduces(text) == (0, 0)
+    assert "async-collective-start" not in text
+    assert "all-reduce" not in text
+
+
+def _assert_scopes_forward_and_backward(text, scopes):
+    """Every scope of ``scopes`` is in the compiled text's op_names under
+    `jvp(hvd_loss)` and under `transpose(jvp(hvd_loss))`."""
+    for scope in scopes:
+        assert re.search(rf'op_name="jit\([^"]*/jvp\(hvd_loss\)/[^"]*{scope}/',
+                         text), f"{scope} is not in the forward pass"
+        assert re.search(
+            rf'op_name="jit\([^"]*transpose\(jvp\(hvd_loss\)\)/[^"]*{scope}/',
+            text), f"{scope} is not in the backward pass"
+
+
+def test_dense_step_names_its_layers(v5e, monkeypatch):
+    """The dense LM's step compiled for one described chip: the embedding,
+    the three parts of attention, the MLP, the head and the loss's own pass
+    each keep a scope of their own in the compiled text's op_names, forward
+    and backward (benchmark/layer_metrics/_layers.py sorts a device trace by
+    them), and the flash kernels lie beneath `hvd_attn_attend`."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, text, _ = _compile_lm_step(v5e[:1])
+    _assert_scopes_forward_and_backward(
+        text, ("hvd_embed", "hvd_attn_qkv", "hvd_attn_attend", "hvd_attn_out",
+               "hvd_mlp", "hvd_lm_head", "hvd_token_xent"))
+    for kernel in ("hvd_flash_fwd", "hvd_flash_bwd"):
+        assert re.search(rf'%{kernel}[.\d]* = .*op_name="[^"]*/hvd_attn_attend/'
+                         rf'{kernel}/pallas_call"', text), kernel
+
+
+def _instructions_outside_fusions(text):
+    """The instruction lines of a compiled program's text that are not in a
+    fused computation: what the core runs one after another."""
+    fused = set(re.findall(r"\bfusion\(.*calls=%([\w.\-]+)", text))
+    skipping = False
+    for line in text.splitlines():
+        opened = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if opened:
+            skipping = opened.group(1) in fused
+        elif not skipping and " = " in line:
+            yield line
+
+
+def _written_float32_elements(text):
+    """Element counts of the float32 arrays that instructions OUTSIDE fused
+    computations yield in a compiled program's text: what is written to
+    memory, where a fusion's body holds values that never leave the core."""
+    import math
+
+    counts = []
+    for line in _instructions_outside_fusions(text):
+        yielded = line.split(" = ", 1)[1].split(", metadata=")[0]
+        counts += [math.prod(map(int, dims.split(",")))
+                   for dims in re.findall(r"\bf32\[([\d,]+)\]", yielded)]
+    return counts
+
+
+def test_lm_loss_keeps_no_float32_logits(v5e):
+    """The gradient of a small TransformerLM under next_token_loss, bf16
+    logits of 2,048 tokens x 8,192 classes, compiled for the described
+    chip, writes no float32 array of the logits' size: the softmax is
+    float32 inside fusions only (plain autodiff of a cross-entropy on
+    ``logits.astype(float32)`` wrote that copy out for its backward)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.models import TransformerLM
+
+    tokens, vocab = 2048, 8192
+    model = TransformerLM(vocab_size=vocab, d_model=128, n_layers=1,
+                          n_heads=2, d_ff=256, dtype=jnp.bfloat16,
+                          logits_dtype=jnp.bfloat16, use_flash=False)
+    shape = jax.ShapeDtypeStruct((1, tokens), jnp.int32)
+    params = jax.eval_shape(
+        lambda t: model.init(jax.random.PRNGKey(0), t)["params"], shape)
+    on_chip = SingleDeviceSharding(v5e[0])
+    params, inputs = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=on_chip),
+        (params, shape))
+
+    def loss(params, inputs, targets):
+        return next_token_loss(model.apply({"params": params}, inputs),
+                               targets)
+
+    text = jax.jit(jax.grad(loss)).lower(params, inputs,
+                                         inputs).compile().as_text()
+    written = _written_float32_elements(text)
+    assert written, "the text's float32 arrays were not found"
+    assert tokens * vocab not in written
+
+
+def test_hybrid_step_is_products_and_kernels_with_no_loop(v5e, monkeypatch):
+    """A Mamba-2, an attention (4 query heads on 1 key/value head of 128, no
+    rotary) and a latent sparse-expert layer at Nemotron-3's per-head widths
+    through `build_train_step`, compiled for the described chip: the chunked
+    scan is products over chunks (no `while` anywhere in the step), attention
+    is the two flash kernels, the experts are libtpu's grouped-matmul kernels
+    (six and two tile schedules), and every scope of the layers is in the
+    text forward and backward."""
+    from horovod_tpu.models import Mamba2Config, MoEConfig, TransformerLM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(
+        vocab_size=2048, d_model=512, n_heads=4, dtype=jnp.bfloat16,
+        logits_dtype=jnp.bfloat16, use_flash=True, norm_eps=1e-5,
+        layers=("ssm", "experts", "attention"),
+        ssm=Mamba2Config(16, 64, 1, 128, 4, 128), n_kv_heads=1, rope=False,
+        moe=MoEConfig(64, 8, 512, (0, 8), 1.5, "sigmoid", True, 5.0, "relu2",
+                      256, 1024))
+    text = _lowered_step(model, v5e[:1])[2].compile().as_text()
+    assert not re.search(r"\bwhile\(", text)
+    assert len(re.findall(r"%hvd_flash_fwd[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%hvd_flash_bwd[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 6
+    # 16,384 pairs for a buffer of 3,072 rows: under the row walk's 8 pairs a
+    # row (the cell's own 35 are over it) and past `HELD_PAIRS_PER_ROW`, so
+    # the way back is the kernel's two calls.
+    assert len(re.findall(r"%hvd_moe_pair_rows[.\d]* = ", text)) == 2
+    assert text.count('"tpu_custom_call"') == 2 + 6 + 2 + 2
+    _assert_scopes_forward_and_backward(
+        text, ("hvd_ssm_in_proj", "hvd_ssm_conv", "hvd_ssm_scan",
+               "hvd_ssm_gate_norm", "hvd_ssm_out_proj", "hvd_moe_latent",
+               "hvd_moe_shared", "hvd_moe_router", "hvd_moe_dispatch",
+               "hvd_moe_combine", "hvd_embed", "hvd_attn_qkv",
+               "hvd_attn_attend", "hvd_attn_out", "hvd_lm_head"))
+
+
+def test_ling_step_is_products_kernels_and_one_loop_a_pass(v5e, monkeypatch):
+    """A Kimi-delta layer, a dense gated MLP, a latent-attention layer (4
+    heads, 192 and 128 wide) and group-limited gated experts with a shared one
+    at Ling-3.0-flash's per-head widths through `build_train_step`, compiled
+    for the described chip: the delta rule's recurrence between chunks is the
+    step's only loops (one `while` forward, one backward, each carrying the
+    state alone through a handful of fusions), latent attention is
+    the flash forward and the split backward pair at two widths, the experts
+    are libtpu's grouped-matmul kernels (nine and two tile schedules), and
+    every scope of the layers and every stage of the delta rule is in the text
+    forward and backward."""
+    from horovod_tpu.models import (DeltaConfig, LatentConfig, MoEConfig,
+                                    TransformerLM)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(
+        vocab_size=2048, d_model=512, n_heads=4, d_ff=1024,
+        dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16, use_flash=True,
+        layers=("delta", "gated_mlp", "latent_attention", "experts"),
+        delta=DeltaConfig(4, 128), latent=LatentConfig(512, 128, 64, 128, 6e6),
+        moe=MoEConfig(64, 8, 256, (0, 8), 1.5, "sigmoid", True, 2.5,
+                      shared_width=256, n_group=8, topk_group=4))
+    text = _lowered_step(model, v5e[:1])[2].compile().as_text()
+    assert len(re.findall(r"\bwhile\(", text)) == 2
+    for kernel in ("hvd_flash_fwd", "hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 9
+    # The experts' rows come back by the kernel, as in the hybrid step above.
+    assert len(re.findall(r"%hvd_moe_pair_rows[.\d]* = ", text)) == 2
+    assert text.count('"tpu_custom_call"') == 3 + 9 + 2 + 2
+    _assert_scopes_forward_and_backward(
+        text, ("hvd_kda_in_proj", "hvd_kda_conv", "hvd_kda_gate",
+               "hvd_kda_scan", "hvd_kda_gate_norm", "hvd_kda_out_proj",
+               "hvd_mla_q_proj", "hvd_mla_kv_latent", "hvd_mla_attend",
+               "hvd_mla_out_proj", "hvd_moe_router", "hvd_moe_shared",
+               "hvd_embed", "hvd_mlp", "hvd_lm_head",
+               "hvd_kda_scan/hvd_kda_scan_decays",
+               "hvd_kda_scan/hvd_kda_scan_chunk",
+               "hvd_kda_scan/hvd_kda_scan_solve",
+               "hvd_kda_scan/hvd_kda_scan_carry"))
+    # The stages partition the scope: no operation, a cast either, lies under
+    # `hvd_kda_scan` and under no stage (their shares must sum to its own).
+    under = re.findall(r'op_name="([^"]*/hvd_kda_scan/[^"]*)"', text)
+    assert len(under) > 100 and all(re.search(
+        r"/hvd_kda_scan/hvd_kda_scan_(decays|chunk|solve|carry)/", path)
+        for path in under), [p for p in under if "scan_" not in p][:3]
+    # The two loops are the carry stage's, one a pass.
+    loops = re.findall(r'^\s*%[\w.\-]+ = .* while\(.*op_name="([^"]*)"', text,
+                       re.M)
+    assert len(loops) == 2 and all(
+        "/hvd_kda_scan/hvd_kda_scan_carry/" in path for path in loops), loops
+    assert sum("transpose(jvp(hvd_loss))" in path for path in loops) == 1
+    # Each loop carries the state alone: its body is the state's two products
+    # and what stores them; an iteration costs a microsecond a fusion whatever
+    # it computes, so nothing else belongs in it.
+    bodies = re.findall(r"\bwhile\(.*body=%([\w.\-]+)", text)
+    for body in bodies:
+        start = text.index(f"\n%{body} ")
+        fusions = text[start:text.index("\n}", start)].count(" fusion(")
+        assert 2 <= fusions <= 5, (body, fusions)
+
+
+@pytest.mark.parametrize("width", [1024, 1280, 2560])
+def test_embedding_gradient_is_slabs_under_its_scope(v5e, width):
+    """The gradient of a one-layer dense LM compiled for the described chip,
+    2,048 tokens into a table of 1,536 rows.  Past `ops.moe.WHOLE_ROW_WIDTH`
+    the table's cotangent is one scatter-add a slab of `ROW_SLAB_WIDTH`
+    columns (the last narrower at 1,280) and none of the whole width; at
+    pythia-410m's 1,024 it is one scatter-add of whole rows.  Every
+    instruction that yields the table in the compute dtype, its cotangent or
+    a slab of it carries an op_name under `hvd_embed`, forward and backward:
+    `embed_time_share_pct` reads the slabs and their join, and
+    `model_unscoped_pct` does not take them."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.models import TransformerLM
+    from horovod_tpu.ops.moe import ROW_SLAB_WIDTH, WHOLE_ROW_WIDTH
+
+    vocab, tokens = 1536, 2048
+    model = TransformerLM(vocab_size=vocab, d_model=width, n_layers=1,
+                          n_heads=width // 128, d_ff=256, dtype=jnp.bfloat16,
+                          logits_dtype=jnp.bfloat16, use_flash=False)
+    shape = jax.ShapeDtypeStruct((1, tokens), jnp.int32)
+    params = jax.eval_shape(
+        lambda t: model.init(jax.random.PRNGKey(0), t)["params"], shape)
+    on_chip = SingleDeviceSharding(v5e[0])
+    params, inputs = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=on_chip),
+        (params, shape))
+
+    def loss(params, inputs, targets):
+        with jax.named_scope("hvd_loss"):
+            return next_token_loss(model.apply({"params": params}, inputs),
+                                   targets)
+
+    text = jax.jit(jax.grad(loss)).lower(params, inputs,
+                                         inputs).compile().as_text()
+    slabs = [width] if width <= WHOLE_ROW_WIDTH else \
+        [min(ROW_SLAB_WIDTH, width - at)
+         for at in range(0, width, ROW_SLAB_WIDTH)]
+    scattered = re.findall(rf"= bf16\[{vocab},(\d+)\]\S* scatter\(", text)
+    assert sorted(map(int, scattered)) == sorted(slabs)
+    _assert_scopes_forward_and_backward(text, ("hvd_embed",))
+    table_shaped = re.compile(
+        rf"= \(?bf16\[{vocab},(?:{'|'.join(map(str, {width, *slabs}))})\]")
+    found = 0
+    for line in text.splitlines():
+        if table_shaped.search(line) and "op_name=" in line \
+                and " parameter(" not in line:
+            found += 1
+            assert re.search(r'op_name="[^"]*/hvd_embed/', line), line
+    assert found >= 2 * len(slabs)
+
+
+
+def test_trinity_step_is_banded_and_causal_kernels_and_a_named_gate(
+        v5e, monkeypatch):
+    """A windowed layer, a dense gated MLP, a full layer and sigmoid-routed
+    experts with a shared one at Trinity-Mini's per-head widths (heads of 128
+    on 2 key/value heads, the gate, the per-head norms, the post-norms, the
+    embedding multiplier) through `build_train_step`, compiled for the
+    described chip: the windowed layer's kernels are the banded ones, the
+    full layer's the causal ones, no loop, and the gate's scope is in the
+    text forward and backward beside the other scopes of `Attention`."""
+    from horovod_tpu.models import MoEConfig, TransformerLM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(
+        vocab_size=2048, d_model=512, n_heads=8, d_ff=1024,
+        dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16, use_flash=True,
+        norm_eps=1e-5,
+        layers=("window_attention", "gated_mlp", "attention", "experts"),
+        n_kv_heads=2, rope=False, head_dim=128, window=512, head_norm=True,
+        attn_gate=True, post_norm=True, embed_scale=512 ** 0.5,
+        moe=MoEConfig(64, 8, 256, (0, 8), 1.5, "sigmoid", True, 2.826,
+                      shared_width=256))
+    text = _lowered_step(model, v5e[:1])[2].compile().as_text()
+    assert len(re.findall(r"\bwhile\(", text)) == 0
+    for kernel in ("hvd_flash_fwd_window", "hvd_flash_bwd_window",
+                   "hvd_flash_fwd", "hvd_flash_bwd"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 9
+    _assert_scopes_forward_and_backward(
+        text, ("hvd_attn_qkv", "hvd_attn_attend", "hvd_attn_gate",
+               "hvd_attn_out", "hvd_mlp", "hvd_moe_router", "hvd_moe_shared",
+               "hvd_embed", "hvd_lm_head"))
+
+
+
+@pytest.mark.parametrize("d_model,experts,grouped", [
+    (512, 64, {"ragged-dot-none": 18}),
+    (384, 32, {"hvd_grouped_fwd": 6, "hvd_grouped_drows": 6,
+               "hvd_grouped_dweights": 6})], ids=["ragged_dot", "tiled"])
+def test_mellum_step_recomputes_its_layers_under_jaxs_marker(
+        v5e, monkeypatch, d_model, experts, grouped):
+    """A windowed layer at the plain rotary frequencies, a full one at
+    YaRN's, each followed by softmax-routed experts, every pattern entry
+    recomputed (`TransformerLM(recompute=True)`), through `build_train_step`
+    for the described chip: every flash kernel is in the step once a layer,
+    as without recomputation, and so is every grouped matmul, nine a layer
+    (a recomputing layer keeps the forward kernel's outputs, the grouped
+    products' and its router's decision) — libtpu's `ragged_dot` kernels at
+    512 wide on 384 rows a group, the tiled kernels of `ops/moe.py` at 384
+    wide on 768, three of each form a layer — no loop; what is computed
+    again — the projections, the rotation of either kind, the rows'
+    movement — carries `rematted_computation` inside the backward phase and
+    keeps its layer's scope, and nothing of the first forward pass does."""
+    from horovod_tpu.models import MoEConfig, RopeScaling, TransformerLM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(
+        vocab_size=2048, d_model=d_model, n_heads=8, dtype=jnp.bfloat16,
+        logits_dtype=jnp.bfloat16, use_flash=True, norm_eps=1e-6,
+        layers=("window_attention", "experts", "attention", "experts"),
+        n_kv_heads=2, head_dim=128, window=512, head_norm=True,
+        rope_theta=500000.0, rope_scaling=RopeScaling(16, 8192),
+        window_rope=(500000.0, None), recompute=True,
+        moe=MoEConfig(experts, 8, 256, (0, 4), 1.5, renormalize=True))
+    text = _lowered_step(model, v5e[:1])[2].compile().as_text()
+    assert len(re.findall(r"\bwhile\(", text)) == 0
+    for kernel in ("hvd_flash_fwd_window", "hvd_flash_fwd",
+                   "hvd_flash_bwd_window", "hvd_flash_bwd"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+    found = {name: len(re.findall(rf"%\w*{name}[.\d]* = ", text))
+             for name in ("ragged-dot-none", "hvd_grouped_fwd",
+                          "hvd_grouped_drows", "hvd_grouped_dweights")}
+    assert {name: n for name, n in found.items() if n} == grouped
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    again = [path for path in paths if "rematted_computation" in path]
+    assert again and all("transpose(jvp(hvd_loss))" in path
+                         for path in again)
+    for layer in (0, 2):
+        assert any(f"layer_{layer}" in path and "hvd_attn_rotate" in path
+                   for path in again)
+        assert any(f"layer_{layer}" in path and "hvd_attn_qkv" in path
+                   for path in again)
+    assert not any("hvd_flash" in path or "hvd_grouped" in path
+                   for path in again)
+    assert any("hvd_moe_dispatch" in path for path in again)
+    assert not any("hvd_lm_head" in path or "hvd_embed" in path
+                   for path in again)
+    _assert_scopes_forward_and_backward(
+        text, ("hvd_attn_qkv", "hvd_attn_rotate", "hvd_attn_attend",
+               "hvd_attn_out", "hvd_moe_router", "hvd_embed", "hvd_lm_head"))
+
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim",
+                         [(32, 4, 128), (16, 2, 256)],
+                         ids=["trinity", "qwen3next"])
+def test_gated_attention_writes_no_float32_array_of_the_kernels_output(
+        v5e, monkeypatch, heads, kv_heads, head_dim):
+    """`Attention(gate=True)` at the two cells' heads over 2,048 rows, forward
+    and backward, compiled for the described chip: no instruction under
+    `hvd_attn_gate` writes a float32 array of the kernels' output's size (the
+    float32 gate plain autodiff kept, 134 MB a layer in the Trinity cell);
+    the gate's product writes the gated output and the rounded gate from one
+    fusion, and the backward's two products are there under the scope."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.models.transformer import Attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    seq = 2048
+    layer = Attention(heads, jnp.bfloat16, use_flash=True, n_kv_heads=kv_heads,
+                      head_dim=head_dim, head_norm=True, gate=True)
+    on_chip = SingleDeviceSharding(v5e[0])
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=on_chip), tree)
+
+    # A hidden width that is not the rows: the weight's float32 gradient
+    # then has a size of its own.
+    x = jax.ShapeDtypeStruct((1, seq, 1024), jnp.bfloat16, sharding=on_chip)
+    params = shaped(jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0),
+                           jnp.zeros(x.shape, x.dtype))["params"]))
+
+    def loss(params, x):
+        with jax.named_scope("hvd_loss"):
+            return layer.apply({"params": params}, x).astype(
+                jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    _assert_scopes_forward_and_backward(text, ("hvd_attn_gate",))
+    entry = text[text.index("ENTRY "):]
+    under = re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) [\w\-]+\(.*"
+                       r"op_name=\"([^\"]*/hvd_attn_gate/[^\"]*)\"", entry,
+                       re.M)
+    for result, op_name in under:
+        for dims in re.findall(r"f32\[([\d,]+)\]", result):
+            assert np.prod([int(n) for n in dims.split(",")]) \
+                != heads * seq * head_dim, (result, op_name)
+    kernels_output = (rf"bf16\[(?:1,)?{heads},"
+                      rf"(?:{seq},{head_dim}|{head_dim},{seq})\]")
+    assert [result for result, op_name in under
+            if "/jvp(hvd_loss)/" in op_name
+            and len(re.findall(kernels_output, result)) == 2], under
+    for product in ("bhse,dhe->bsd", "bsd,bhse->dhe"):
+        assert any(f"hvd_attn_gate/{product}/dot_general" in op_name
+                   and "transpose(" in op_name
+                   for _, op_name in under), product
+
+
+
+def test_sdar_step_is_blockdiff_kernels_and_a_named_loss(v5e, monkeypatch):
+    """Two published layers of SDAR's pattern (block-diffusion attention at
+    heads of 128 on 2 key/value heads with the per-head norms, softmax-routed
+    experts with renormalised weights) through `build_train_step` with
+    `masked_diffusion_loss`, compiled for the described chip: every attention
+    kernel is a block-diffusion one, no causal kernel and no loop is in the
+    text, the head's product has the noised half's rows alone, and the loss's
+    scope is in the text forward and backward beside the attention's."""
+    from horovod_tpu.models import (MoEConfig, TransformerLM,
+                                    masked_diffusion_loss)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(
+        vocab_size=2048, d_model=512, n_heads=8, dtype=jnp.bfloat16,
+        logits_dtype=jnp.bfloat16, use_flash=True,
+        layers=("blockdiff_attention", "experts") * 2, n_kv_heads=2,
+        head_dim=128, head_norm=True, block_diffusion=4, rope_theta=1e6,
+        moe=MoEConfig(64, 8, 256, (0, 8), 1.5, renormalize=True))
+    def loss_fn(params, batch):
+        tokens, noised, masked, level = batch
+        return masked_diffusion_loss(
+            model.apply({"params": params}, tokens, noised=noised), tokens,
+            masked, level)
+
+    text = _lowered_step(
+        model, v5e[:1], (1, 1024), loss_fn,
+        ("tokens", "tokens", jnp.bool_, jnp.float32),
+        noised=True)[2].compile().as_text()
+    assert len(re.findall(r"\bwhile\(", text)) == 0
+    for kernel in ("hvd_flash_fwd_blockdiff", "hvd_flash_bwd_blockdiff"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 2, kernel
+    assert not re.search(r"%hvd_flash_(fwd|bwd)[.\d]* = ", text)
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 18
+    # The head over the noised half: logits of 1,024 rows, not 2,048.
+    assert re.search(r"bf16\[(1,)?1024,2048\]", text)
+    assert not re.search(r"bf16\[(1,)?2048,2048\]", text)
+    _assert_scopes_forward_and_backward(
+        text, ("hvd_attn_qkv", "hvd_attn_attend", "hvd_attn_out",
+               "hvd_moe_router", "hvd_embed", "hvd_lm_head",
+               "hvd_diffusion_loss"))

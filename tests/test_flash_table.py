@@ -12,6 +12,10 @@ import dataclasses
 
 import functools
 import hashlib
+import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -356,11 +360,42 @@ MASK_OF = {"causal": dict(causal=True),
            "blockdiff": dict(block_diffusion=32)}
 
 
+def every_digest():
+    """{"name-d-plan": the kernels' five digests, "ring-causal": the ring's
+    three}: what the two tests below compare, as one JSON-able table."""
+    from horovod_tpu.ops.ring_flash import fused_ring_attention
+
+    table = {f"{name}-{d}-{plan}": kernel_digests(attn, MASK_OF[name], d, plan)
+             for name, d, plan in AT_585DFAA}
+    table.update({f"ring-{causal}": ring_digests(fused_ring_attention, causal)
+                  for causal in RING_AT_585DFAA})
+    return table
+
+
+@functools.cache
+def digests_at_the_default_level():
+    """`every_digest()` from a process of its own whose XLA flags leave the
+    backend's optimisation level alone.  tests/conftest.py compiles the
+    suite's CPU programs at level 0, where LLVM vectorises no sum and a
+    float's last bits move; the digests were taken at the default level, and
+    it is the kernels they pin, not the compiler."""
+    flags = " ".join(flag for flag in os.environ.get("XLA_FLAGS", "").split()
+                     if "xla_backend_optimization_level" not in flag)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, "-c", "import json; from tests import "
+         "test_flash_table as t; print(json.dumps(t.every_digest()))"],
+        env=dict(os.environ, XLA_FLAGS=flags, PYTHONPATH=repo), cwd=repo,
+        capture_output=True, text=True, timeout=280)
+    assert done.returncode == 0, done.stderr[-2000:]
+    return json.loads(done.stdout.splitlines()[-1])
+
+
 @pytest.mark.parametrize("name,d,plan", list(AT_585DFAA), ids=str)
 def test_bit_for_bit_what_the_rectangular_grids_gave(name, d, plan):
     """Same blocks, same arithmetic in a live tile, same order of every
     accumulation: out, lse, dq, dk, dv are the parent's to the bit."""
-    got = kernel_digests(attn, MASK_OF[name], d, plan)
+    got = digests_at_the_default_level()[f"{name}-{d}-{plan}"]
     assert " ".join(got.values()) == AT_585DFAA[name, d, plan], got
 
 
@@ -368,9 +403,7 @@ def test_bit_for_bit_what_the_rectangular_grids_gave(name, d, plan):
 def test_the_rings_backward_step_bit_for_bit(causal):
     """The fused ring's gradients on a four-device mesh: the combined kernel
     over the table of every pair, its predicate on the traced offsets."""
-    from horovod_tpu.ops.ring_flash import fused_ring_attention
-
-    got = ring_digests(fused_ring_attention, causal)
+    got = digests_at_the_default_level()[f"ring-{causal}"]
     assert " ".join(got.values()) == RING_AT_585DFAA[causal], got
 
 

@@ -12,6 +12,8 @@ orders of magnitude, 1e-4).  bfloat16 anywhere would read 1e-3 to 1e-2 and
 fail every case.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -76,11 +78,23 @@ def trees_close(got, want, rtol=RTOL):
         close(leaf, flat_want[path], rtol)
 
 
+@jax.jit
+def relative_error(got, want):
+    """The norm of two trees' difference over the norm of the second."""
+    return optax.global_norm(jax.tree.map(jnp.subtract, got, want)) \
+        / optax.global_norm(want)
+
+
 def with_highest(fn):
-    """`fn` jitted, every float32 product in full precision."""
+    """`fn` jitted, every float32 product in full precision.  Calls without
+    keywords share one jitted function: kept, it compiles once a shape."""
+    jitted = jax.jit(fn)
+
     def call(*args, **kwargs):
         with jax.default_matmul_precision("highest"):
-            return jax.jit(lambda *a: fn(*a, **kwargs))(*args)
+            if kwargs:
+                return jax.jit(lambda *a: fn(*a, **kwargs))(*args)
+            return jitted(*args)
     return call
 
 
@@ -136,24 +150,36 @@ def test_chunked_scan_refuses_a_ragged_length():
 # --- each mixer against the reference's ---------------------------------
 
 def mixer_case(module, seed=0):
-    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
-    u = jax.random.normal(keys[0], (2, SEQ, HIDDEN))
-    params = spread(module.init(keys[1], u)["params"], seed)
-    mix = jax.random.normal(keys[2], u.shape)
-    return u, params, mix
+    """(an input, `module`'s seeded parameters spread, a cotangent), made in
+    one program: op by op every primitive of `init` compiles alone."""
+    def make(key):
+        keys = jax.random.split(key, 3)
+        u = jax.random.normal(keys[0], (2, SEQ, HIDDEN))
+        params = spread(module.init(keys[1], u)["params"], seed)
+        return u, params, jax.random.normal(keys[2], u.shape)
+
+    return jax.jit(make)(jax.random.PRNGKey(seed))
+
+
+def sown(module, variables, u):
+    """What `module` sows into `intermediates` on `u`, from one program."""
+    return jax.jit(lambda v, u: module.apply(
+        v, u, mutable=["intermediates"])[1]["intermediates"])(variables, u)
 
 
 def both_ways(system, plain, u, params, mix, rtol=RTOL):
     """Values and gradients (input and parameters) of `system(params, u)`
-    against `plain(params, u)`."""
-    close(jax.jit(system)(params, u), with_highest(plain)(params, u), rtol)
-
+    against `plain(params, u)`: one program a side."""
     def total(fn):
-        return lambda p, u: (fn(p, u) * mix).sum()
+        def summed(p, u):
+            out = fn(p, u)
+            return (out * mix).sum(), out
+        return jax.value_and_grad(summed, (0, 1), has_aux=True)
 
-    got = jax.jit(jax.grad(total(system), (0, 1)))(params, u)
-    want = with_highest(jax.grad(total(plain), (0, 1)))(params, u)
-    trees_close(got, want, rtol)
+    (_, got), got_grads = jax.jit(total(system))(params, u)
+    (_, want), want_grads = with_highest(total(plain))(params, u)
+    close(got, want, rtol)
+    trees_close(got_grads, want_grads, rtol)
 
 
 @pytest.mark.parametrize("head_shard", [(0, 1), (1, 2), (3, 4)])
@@ -171,8 +197,7 @@ def test_mamba2_mixer_is_the_reference(chunk, head_shard):
 def test_mamba2_mixer_writes_its_chunks_decay():
     mixer = Mamba2Mixer(*SSM, dtype=jnp.float32)
     u, params, _ = mixer_case(mixer)
-    _, wrote = mixer.apply({"params": params}, u, mutable=["intermediates"])
-    (decay,) = wrote["intermediates"]["ssm_chunk_log_decay_min"]
+    (decay,) = sown(mixer, {"params": params}, u)["ssm_chunk_log_decay_min"]
     assert decay.shape == () and -1e4 < float(decay) < 0
 
 
@@ -247,27 +272,24 @@ def test_latent_experts_are_the_dense_loop(shard, bias):
                                         **config)[0].reshape(u.shape)
 
     both_ways(system, plain, u, params, mix)
-    _, wrote = layer.apply({"params": params, "buffers": buffers}, u,
-                           mutable=["intermediates"])
-    chose = wrote["intermediates"]["chosen_experts"][0]
-    want = reference.latent_experts(u.reshape(-1, HIDDEN), params,
-                                    **config)[1]
+    chose = sown(layer, {"params": params, "buffers": buffers}, u)[
+        "chosen_experts"][0]
+    want = with_highest(reference.latent_experts)(
+        u.reshape(-1, HIDDEN), params, **config)[1]
     np.testing.assert_array_equal(jnp.sort(chose, -1), jnp.sort(want, -1))
     if bias:     # the bias moves choices and never the weights
-        unbiased = layer.apply({"params": params}, u,
-                               mutable=["intermediates"])[1]
-        assert (jnp.sort(unbiased["intermediates"]["chosen_experts"][0], -1)
-                != jnp.sort(chose, -1)).any()
+        unbiased = sown(layer, {"params": params}, u)["chosen_experts"][0]
+        assert (jnp.sort(unbiased, -1) != jnp.sort(chose, -1)).any()
 
 
 def test_latent_experts_count_rows_over_a_bound():
     layer = SparseExperts(moe(row_bound=0.25), jnp.float32)
     u, params, _ = mixer_case(layer)
-    _, wrote = layer.apply({"params": params}, u, mutable=["intermediates"])
-    routed = int(wrote["intermediates"]["rows_per_local_expert"][0].sum())
+    wrote = sown(layer, {"params": params}, u)
+    routed = int(wrote["rows_per_local_expert"][0].sum())
     bound = moe(row_bound=0.25).buffer_rows(2 * SEQ)
     assert routed > bound
-    assert int(wrote["intermediates"]["rows_over_bound"][0]) == routed - bound
+    assert int(wrote["rows_over_bound"][0]) == routed - bound
 
 
 @pytest.mark.parametrize("field,value", [("scoring", "tanh"),
@@ -281,10 +303,59 @@ def test_sparse_experts_refuse_an_unknown_choice(field, value):
 # --- the whole model ----------------------------------------------------
 
 def seeded(model, seed=0, batch=2, vocab=VOCAB):
-    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
-    tokens = jax.random.randint(keys[0], (batch, SEQ + 1), 0, vocab)
-    params = spread(model.init(keys[1], tokens[:, :-1])["params"], seed)
-    return params, (tokens[:, :-1], tokens[:, 1:])
+    """(`model`'s seeded parameters spread, (inputs, targets)), made in one
+    program."""
+    def make(key):
+        keys = jax.random.split(key, 2)
+        tokens = jax.random.randint(keys[0], (batch, SEQ + 1), 0, vocab)
+        params = spread(model.init(keys[1], tokens[:, :-1])["params"], seed)
+        return params, (tokens[:, :-1], tokens[:, 1:])
+
+    return jax.jit(make)(jax.random.PRNGKey(seed))
+
+
+def vocabulary_slices_concatenate(lm, n, vocab=VOCAB):
+    """A sliced vocabulary is a smaller vocabulary: the i-th of `n` slices'
+    model — its rows of the embedding, its columns of the head — gives, for
+    ids of the slice, the uncut `lm()`'s logits of those columns."""
+    model = lm()
+    params, _ = seeded(model)
+    rows = vocab // n
+    whole, sliced = jax.jit(model.apply), jax.jit(lm(vocab=rows).apply)
+    width = 0
+    for i in range(n):
+        ids = jax.random.randint(jax.random.PRNGKey(9), (1, SEQ), 0, rows)
+        held = slice(i * rows, (i + 1) * rows)
+        share = dict(params,
+                     embed={"embedding": params["embed"]["embedding"][held]},
+                     lm_head_kernel=params["lm_head_kernel"][:, held])
+        got = sliced({"params": share}, ids)
+        want = whole({"params": params}, ids + i * rows)
+        close(got, want[..., held])
+        width += got.shape[-1]
+    assert width == vocab
+
+
+def trains_and_replicas_stay_equal(model, params, batch, loss=None):
+    """Two CPU devices, data parallel, `model`'s step through
+    `build_train_step`: the loss of a repeated batch falls and the replicated
+    weights stay equal.  Returns the four losses."""
+    loss = loss or system_loss
+    mesh = Mesh(np.array(jax.devices()[:2]), ("hvd",))
+    tx = optax.adamw(1e-2)
+    step = build_train_step(lambda p, b: loss(model, p, b), tx, mesh,
+                            axis_name="hvd",
+                            batch_spec=(P("hvd"),) * len(batch))
+    state = (params, tx.init(params))
+    losses = []
+    for _ in range(4):
+        *state, value = step(*state, batch)
+        losses.append(float(value))
+    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+    for leaf in jax.tree.leaves(state[0]):
+        first, second = (np.asarray(s.data) for s in leaf.addressable_shards)
+        np.testing.assert_array_equal(first, second)
+    return losses
 
 
 def system_loss(model, params, batch):
@@ -292,101 +363,49 @@ def system_loss(model, params, batch):
                            batch[1])
 
 
-@pytest.mark.parametrize("chunk", [32, 64])
-@pytest.mark.parametrize("expert_shard,head_shard",
-                         [((0, 1), (0, 1)), ((1, 4), (1, 2))])
-def test_hybrid_lm_loss_and_gradients_are_the_references(expert_shard,
-                                                         head_shard, chunk):
-    model = lm(expert_shard, head_shard, chunk=chunk)
-    params, batch = seeded(model, seed=chunk)
-    config = reference_config(expert_shard)
-    got, got_grads = jax.jit(jax.value_and_grad(
-        lambda p: system_loss(model, p, batch)))(params)
-    want, want_grads = with_highest(jax.value_and_grad(
-        lambda p: reference.loss(p, batch, **config)))(params)
+def system_side(model, params, batch, loss=next_token_loss):
+    """((the loss, the experts every expert layer chose), every gradient) of
+    `model` from one program: `loss(logits, *batch[1:])` of
+    `model.apply(params, batch[0])`."""
+    def loss_and_chosen(params):
+        logits, wrote = model.apply({"params": params}, batch[0],
+                                    mutable=["intermediates"])
+        chose = jnp.stack([wrote["intermediates"][f"layer_{i}"]["mixer"][
+            "chosen_experts"][0] for i, kind in enumerate(model.layers)
+            if kind == "experts"])
+        return loss(logits, *batch[1:]), chose
+
+    return jax.jit(jax.value_and_grad(loss_and_chosen, has_aux=True))(params)
+
+
+def reference_sides(reference_config, loss_and_chosen):
+    """`side(expert_shard, **more)(params, batch)`: a reference's ((loss,
+    chosen experts), gradients) under `reference_config(expert_shard,
+    **more)`, one program, every product in full precision.  One jitted
+    function a configuration is kept, so cases that differ in the system
+    alone compile the reference once."""
+    @functools.cache
+    def side(expert_shard=(0, 1), **more):
+        config = reference_config(expert_shard, **more)
+        return with_highest(jax.value_and_grad(
+            lambda p, batch: loss_and_chosen(p, batch, **config),
+            has_aux=True))
+    return side
+
+
+reference_side = reference_sides(
+    reference_config, lambda p, batch, **config: (
+        reference.loss(p, batch, **config),
+        reference.chosen_experts(p, batch[0], **config)))
+
+
+def sides_agree(system, plain, grads_rtol=1e-4):
+    (got, chose), got_grads = system
+    (want, want_chose), want_grads = plain
     np.testing.assert_allclose(got, want, rtol=RTOL)
-    trees_close(got_grads, want_grads, 1e-4)
-    _, wrote = model.apply({"params": params}, batch[0],
-                           mutable=["intermediates"])
-    chose = jnp.stack([wrote["intermediates"][f"layer_{i}"]["mixer"][
-        "chosen_experts"][0] for i, kind in enumerate(LAYERS)
-        if kind == "experts"])
-    want = with_highest(reference.chosen_experts)(params, batch[0], **config)
-    np.testing.assert_array_equal(jnp.sort(chose, -1), jnp.sort(want, -1))
-
-
-def test_reference_refuses_float8_operands():
-    """The reference against itself with every matmul operand rounded to
-    float8_e4m3fn: the error the benchmark's limits must refuse is far over
-    what float32 reorderings give above."""
-    model = lm()
-    params, batch = seeded(model)
-    losses = [with_highest(jax.value_and_grad(lambda p: reference.loss(
-        p, batch, operand_dtype=dtype, **reference_config())))(params)
-        for dtype in (None, jnp.float8_e4m3fn)]
-    norm = optax.global_norm
-    wrong = norm(jax.tree.map(jnp.subtract, losses[1][1], losses[0][1]))
-    assert float(wrong / norm(losses[0][1])) > 0.05
-
-
-def test_pattern_has_one_norm_and_one_mixer_a_layer():
-    shapes = jax.eval_shape(lambda: lm((0, 4), (0, 2)).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, SEQ), jnp.int32))["params"])
-    assert set(shapes) == {"embed", "final_norm", "lm_head_kernel"} | {
-        f"layer_{i}" for i in range(len(LAYERS))}
-    mixers = {"ssm": {"A_log", "D", "conv_bias", "conv_kernel", "dt_bias",
-                      "in_proj_kernel", "norm_scale", "out_proj_kernel"},
-              "attention": {"q_kernel", "kv_kernel", "o_kernel"},
-              "experts": {"router_kernel", "up_kernel", "down_kernel",
-                          "latent_down", "latent_up", "shared_up",
-                          "shared_down"}}
-    for i, kind in enumerate(LAYERS):
-        assert set(shapes[f"layer_{i}"]) == {"norm", "mixer"}
-        assert set(shapes[f"layer_{i}"]["mixer"]) == mixers[kind]
-    # The share: 4 of 8 heads in 2 of 4 groups, 4 of 16 experts.
-    inner, bc = 4 * SSM.head_dim, 2 * SSM.state
-    assert shapes["layer_0"]["mixer"]["in_proj_kernel"].shape == (
-        HIDDEN, 2 * inner + 2 * bc + 4)
-    assert shapes["layer_1"]["mixer"]["up_kernel"].shape == (4, LATENT, WIDTH)
-    assert shapes["layer_1"]["mixer"]["router_kernel"].shape == (HIDDEN,
-                                                                 EXPERTS)
-
-
-@pytest.mark.parametrize("how", ["decode_ctx", "seq_axis", "kind"])
-def test_pattern_refuses_what_it_cannot_run(how):
-    tokens = jnp.zeros((1, SEQ), jnp.int32)
-    if how == "kind":
-        with pytest.raises(ValueError, match="layer kind"):
-            TransformerLM(vocab_size=VOCAB, d_model=HIDDEN, n_heads=HEADS,
-                          layers=("mlp",)).init(jax.random.PRNGKey(0), tokens)
-        return
-    model = TransformerLM(
-        vocab_size=VOCAB, d_model=HIDDEN, n_heads=HEADS, layers=LAYERS,
-        ssm=SSM, moe=moe(), seq_axis="sp" if how == "seq_axis" else None)
-    with pytest.raises(ValueError, match="per-layer pattern"):
-        model.init(jax.random.PRNGKey(0), tokens,
-                   decode_ctx=object() if how == "decode_ctx" else None)
-
-
-def test_trains_through_build_train_step_and_replicas_stay_equal():
-    """Two CPU devices, data parallel: the dense LM's step with the pattern.
-    The replicated weights stay equal and the loss of a repeated batch
-    falls.  The flash kernel (interpreted here), as in the benchmark."""
-    model = lm((0, 4), (0, 2), use_flash=True)
-    mesh = Mesh(np.array(jax.devices()[:2]), ("hvd",))
-    params, batch = seeded(model, seed=3)
-    tx = optax.adamw(1e-2)
-    step = build_train_step(lambda p, b: system_loss(model, p, b), tx, mesh,
-                            axis_name="hvd", batch_spec=(P("hvd"), P("hvd")))
-    state = (params, tx.init(params))
-    losses = []
-    for _ in range(4):
-        *state, loss = step(*state, batch)
-        losses.append(float(loss))
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
-    for leaf in jax.tree.leaves(state[0]):
-        first, second = (np.asarray(s.data) for s in leaf.addressable_shards)
-        np.testing.assert_array_equal(first, second)
+    trees_close(got_grads, want_grads, grads_rtol)
+    np.testing.assert_array_equal(jnp.sort(chose, -1),
+                                  jnp.sort(want_chose, -1))
 
 
 # --- the shares add up to the uncut layer ---------------------------------
@@ -401,6 +420,14 @@ def columns(kernel, blocks, shard, n):
                             start + (shard + 1) * part])
         start += width
     return jnp.concatenate(parts, axis=-1)
+
+
+def share_outputs(n, layer_of, share_of, params, u):
+    """The `n` shares' outputs, `layer_of(i)` on `share_of(params, i)`, from
+    one program (a program a share is n compiles of the same few layers)."""
+    return jax.jit(lambda params, u: [
+        layer_of(i).apply({"params": share_of(params, i)}, u)
+        for i in range(n)])(params, u)
 
 
 def mamba2_share(p, shard, n, ssm):
@@ -419,90 +446,3 @@ def mamba2_share(p, shard, n, ssm):
             "norm_scale": columns(p["norm_scale"], [inner], shard, n),
             "out_proj_kernel": columns(p["out_proj_kernel"].T, [inner],
                                        shard, n).T}
-
-
-@pytest.mark.parametrize("n,groups", [(2, 4), (4, 4), (8, 8)])
-def test_mamba2_tensor_shares_add_up_to_the_uncut_layer(n, groups):
-    ssm = SSM._replace(groups=groups)
-    whole = Mamba2Mixer(*ssm, dtype=jnp.float32, norm_eps=1e-5)
-    u, params, _ = mixer_case(whole, n)
-    parts = [jax.jit(Mamba2Mixer(*ssm, head_shard=(i, n), dtype=jnp.float32,
-                                 norm_eps=1e-5).apply)(
-        {"params": mamba2_share(params, i, n, ssm)}, u) for i in range(n)]
-    close(sum(parts), with_highest(reference.mamba2)(
-        u, params, head_dim=ssm.head_dim, state=ssm.state, norm_eps=1e-5),
-        1e-4)
-
-
-@pytest.mark.parametrize("n", [2, 8])
-def test_attention_tensor_shares_add_up_to_the_uncut_layer(n):
-    whole = Attention(HEADS, jnp.float32, use_flash=False,
-                      n_kv_heads=KV_HEADS, rope=False)
-    u, params, _ = mixer_case(whole, n)
-    local, group = HEADS // n, HEADS // KV_HEADS
-    parts = []
-    for i in range(n):
-        kv = slice(i * local // group, max(i * local // group + 1,
-                                           (i + 1) * local // group))
-        share = {"q_kernel": params["q_kernel"][:, i * local:(i + 1) * local],
-                 "kv_kernel": params["kv_kernel"][:, :, kv],
-                 "o_kernel": params["o_kernel"][i * local:(i + 1) * local]}
-        parts.append(jax.jit(Attention(
-            HEADS, jnp.float32, use_flash=False, n_kv_heads=KV_HEADS,
-            rope=False, head_shard=(i, n)).apply)({"params": share}, u))
-    close(sum(parts), with_highest(reference.grouped_query_attention)(
-        u, params))
-
-
-@pytest.mark.parametrize("n,experts", [(4, EXPERTS), (16, EXPERTS),
-                                       (64, 128)])
-def test_expert_shares_add_up_with_what_every_chip_computes_counted_once(
-        n, experts):
-    """The n shares' outputs each hold the shared expert, and (the projection
-    up being linear) their sum holds it n times and the routed part once.
-    64 shares of 2 experts: the deployment's count."""
-    whole = SparseExperts(moe(experts=experts), jnp.float32)
-    u, params, _ = mixer_case(whole, n)
-    local = experts // n
-    parts = []
-    for i in range(n):
-        held = slice(i * local, (i + 1) * local)
-        share = dict(params, up_kernel=params["up_kernel"][held],
-                     down_kernel=params["down_kernel"][held])
-        parts.append(jax.jit(SparseExperts(moe((i, n), experts=experts),
-                                           jnp.float32).apply)(
-            {"params": share}, u))
-    flat = u.reshape(-1, HIDDEN)
-    shared = reference.relu2(flat @ params["shared_up"]["kernel"]) \
-        @ params["shared_down"]["kernel"]
-    want = with_highest(reference.latent_experts)(
-        flat, params, num_experts=experts, experts_per_token=PER_TOKEN,
-        expert_shard=(0, 1), weight_scale=SCALE)[0]
-    shared = shared.reshape(u.shape)
-    # Each share less the shared expert, summed, and the shared expert once
-    # (the same sum in the order that does not cancel n large terms).
-    close(sum(part - shared for part in parts) + shared,
-          want.reshape(u.shape))
-
-
-@pytest.mark.parametrize("n", [2, 8])
-def test_vocabulary_slices_concatenate_to_the_uncut_head(n):
-    """A sliced vocabulary is a smaller vocabulary: the i-th slice's model —
-    its rows of the embedding, its columns of the head — gives, for ids of
-    the slice, the uncut model's logits of those columns."""
-    model = lm()
-    params, _ = seeded(model)
-    rows = VOCAB // n
-    logits = []
-    whole, sliced = jax.jit(model.apply), jax.jit(lm(vocab=rows).apply)
-    for i in range(n):
-        ids = jax.random.randint(jax.random.PRNGKey(9), (1, SEQ), 0, rows)
-        held = slice(i * rows, (i + 1) * rows)
-        share = dict(params,
-                     embed={"embedding": params["embed"]["embedding"][held]},
-                     lm_head_kernel=params["lm_head_kernel"][:, held])
-        got = sliced({"params": share}, ids)
-        want = whole({"params": params}, ids + i * rows)
-        close(got, want[..., held])
-        logits.append(want[..., held].shape[-1])
-    assert sum(logits) == VOCAB

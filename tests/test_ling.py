@@ -13,24 +13,22 @@ span e^-5 a step and whose solve multiplies 64 x 64 matrices, 1e-4).  bfloat16
 anywhere would read 1e-3 to 1e-2 and fail every case.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
-from jax.sharding import Mesh, PartitionSpec as P
 
 from benchmark.reference import ling_lm as reference
-from horovod_tpu.jax.train import build_train_step
 from horovod_tpu.models import (DeltaConfig, DeltaMixer, LatentAttention,
                                 LatentConfig, MoEConfig, TransformerLM)
-from horovod_tpu.models.transformer import (LAYER_KINDS, GatedMLP, MixerLayer,
-                                            SparseExperts)
+from horovod_tpu.models.transformer import GatedMLP, SparseExperts
 from horovod_tpu.ops import flash_attention, mha_reference
 from horovod_tpu.ops import delta_rule
 from horovod_tpu.ops.delta_rule import chunked_delta_rule
-from tests.test_hybrid import (both_ways, close, columns, mixer_case, seeded,
-                         system_loss, trees_close, with_highest)
+from tests.test_hybrid import (both_ways, close, columns, mixer_case,
+                               reference_sides, sown, with_highest)
 
 RTOL = 2e-5
 VOCAB, HIDDEN, SEQ, HEADS, D_FF = 256, 64, 128, 8, 96
@@ -74,8 +72,12 @@ def reference_config(expert_shard=(0, 1), **more):
                 **routing(**more))
 
 
+reference_side = reference_sides(reference_config, reference.loss_and_chosen)
+
+
 # --- the delta rule --------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnames=("seed", "seq", "d_k", "d_v"))
 def delta_inputs(seed, seq=SEQ, d_k=16, d_v=8):
     """Unit keys, queries at d_k^-1/2, log-decays from 0 down to the gate's
     bound of -5 a step — every seventh token AT the bound in every channel,
@@ -135,6 +137,7 @@ def test_chunked_delta_rule_refuses_a_ragged_length(seq, chunk):
 WIDE_HEAD, WIDE_CHUNK = 128, 64
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
 def wide_inputs(seed, chunks, regime):
     """Head width 128, chunks of 64 with sub-blocks of 16.  `bound`:
     `delta_inputs`' own decays, every seventh token AT the gate's bound of -5
@@ -198,6 +201,7 @@ def plain_carry(w, u0, q_in, qk, k_end, carried):
                         0, 1)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
 def carry_operands(seed, chunks, carried_to):
     keys = jax.random.split(jax.random.PRNGKey(seed), 8)
     lead = (2, chunks, 2, WIDE_CHUNK)
@@ -267,9 +271,10 @@ def test_unit_lower_inverse_of_keys_that_resemble_one_another(size):
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     a = jnp.tril(0.9 * jnp.einsum("bic,bjc->bij", k, k), -1)
     wanted = np.linalg.inv(np.eye(size) + np.asarray(a, np.float64))
-    close(delta_rule._unit_lower_inverse(a), wanted, RTOL)
+    close(jax.jit(delta_rule._unit_lower_inverse)(a), wanted, RTOL)
     g = jax.random.normal(keys[2], a.shape)
-    got = jax.grad(lambda a: (delta_rule._unit_lower_inverse(a) * g).sum())(a)
+    got = jax.jit(jax.grad(
+        lambda a: (delta_rule._unit_lower_inverse(a) * g).sum()))(a)
     transposed = wanted.swapaxes(-1, -2)
     close(got, -transposed @ np.asarray(g, np.float64) @ transposed, RTOL)
 
@@ -319,8 +324,7 @@ def test_delta_mixer_is_the_reference(chunk, head_shard):
 def test_delta_mixer_writes_its_chunks_decay_inside_the_gates_bound():
     mixer = DeltaMixer(*DELTA, dtype=jnp.float32)
     u, params, _ = mixer_case(mixer)
-    _, wrote = mixer.apply({"params": params}, u, mutable=["intermediates"])
-    decay = wrote["intermediates"]["kda_chunk_log_decay_min"][0]
+    decay = sown(mixer, {"params": params}, u)["kda_chunk_log_decay_min"][0]
     assert decay.shape == ()
     assert DELTA.lower_bound * DELTA.chunk < float(decay) < 0
 
@@ -370,14 +374,12 @@ def test_group_limited_experts_are_the_dense_loop(shard, bias):
                                         **config)[0].reshape(u.shape)
 
     both_ways(system, plain, u, params, mix)
-    _, wrote = layer.apply({"params": params, **buffers}, u,
-                           mutable=["intermediates"])
+    wrote = sown(layer, {"params": params, **buffers}, u)
     flat = u.reshape(-1, HIDDEN)
     _, want, want_groups = with_highest(reference.router)(
         flat, params["router_kernel"], **routing(
             selection_bias=selection_bias))
-    chose = wrote["intermediates"]["chosen_experts"][0]
-    groups = wrote["intermediates"]["groups_chosen"][0]
+    chose, groups = wrote["chosen_experts"][0], wrote["groups_chosen"][0]
     np.testing.assert_array_equal(jnp.sort(chose, -1), jnp.sort(want, -1))
     np.testing.assert_array_equal(jnp.sort(groups, -1),
                                   jnp.sort(want_groups, -1))
@@ -422,108 +424,6 @@ def test_sparse_experts_refuse_groups_they_cannot_form(field, value):
             jax.random.PRNGKey(0), jnp.zeros((1, 8, HIDDEN)))
 
 
-# --- the whole model ----------------------------------------------------
-
-@pytest.mark.parametrize("chunk", [32, 64])
-@pytest.mark.parametrize("expert_shard,head_shard",
-                         [((0, 1), (0, 1)), ((1, 4), (1, 2))])
-def test_ling_lm_loss_and_gradients_are_the_references(expert_shard,
-                                                       head_shard, chunk):
-    model = lm(expert_shard, head_shard, chunk=chunk)
-    params, batch = seeded(model, seed=chunk)
-    config = reference_config(expert_shard)
-    got, got_grads = jax.jit(jax.value_and_grad(
-        lambda p: system_loss(model, p, batch)))(params)
-    want, want_grads = with_highest(jax.value_and_grad(
-        lambda p: reference.loss(p, batch, **config)))(params)
-    np.testing.assert_allclose(got, want, rtol=RTOL)
-    trees_close(got_grads, want_grads, 1e-4)
-    _, wrote = model.apply({"params": params}, batch[0],
-                           mutable=["intermediates"])
-    chose = jnp.stack([wrote["intermediates"][f"layer_{i}"]["mixer"][
-        "chosen_experts"][0] for i, kind in enumerate(LAYERS)
-        if kind == "experts"])
-    want = with_highest(reference.chosen_experts)(params, batch[0], **config)
-    np.testing.assert_array_equal(jnp.sort(chose, -1), jnp.sort(want, -1))
-
-
-def test_reference_refuses_float8_operands():
-    """The reference against itself with every matmul operand rounded to
-    float8_e4m3fn: the error the benchmark's limits must refuse is far over
-    what float32 reorderings give above."""
-    model = lm()
-    params, batch = seeded(model)
-    losses = [with_highest(jax.value_and_grad(lambda p: reference.loss(
-        p, batch, operand_dtype=dtype, **reference_config())))(params)
-        for dtype in (None, jnp.float8_e4m3fn)]
-    norm = optax.global_norm
-    wrong = norm(jax.tree.map(jnp.subtract, losses[1][1], losses[0][1]))
-    assert float(wrong / norm(losses[0][1])) > 0.05
-
-
-def test_pattern_has_one_norm_and_one_mixer_an_entry():
-    shapes = jax.eval_shape(lambda: lm((0, 4), (0, 2)).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, SEQ), jnp.int32))["params"])
-    assert set(shapes) == {"embed", "final_norm", "lm_head_kernel"} | {
-        f"layer_{i}" for i in range(len(LAYERS))}
-    mixers = {"delta": {"A_log", "conv_kernel", "dt_bias", "in_proj_kernel",
-                        "norm_scale", "out_proj_kernel"},
-              "latent_attention": {"q_kernel", "kv_a_kernel", "kv_norm_scale",
-                                   "kv_b_kernel", "gate_kernel", "o_kernel"},
-              "gated_mlp": {"gate", "up", "down"},
-              "experts": {"router_kernel", "gate_kernel", "up_kernel",
-                          "down_kernel", "shared_gate", "shared_up",
-                          "shared_down"}}
-    for i, kind in enumerate(LAYERS):
-        assert set(shapes[f"layer_{i}"]) == {"norm", "mixer"}
-        assert set(shapes[f"layer_{i}"]["mixer"]) == mixers[kind]
-    # The share: 4 of 8 heads, 4 of 16 experts, the router over all 16.
-    assert shapes["layer_0"]["mixer"]["A_log"].shape == (4,)
-    assert shapes["layer_3"]["mixer"]["up_kernel"].shape == (4, HIDDEN, WIDTH)
-    assert shapes["layer_3"]["mixer"]["router_kernel"].shape == (HIDDEN,
-                                                                 EXPERTS)
-    assert shapes["layer_1"]["mixer"]["up"]["kernel"].shape == (HIDDEN, D_FF)
-
-
-def test_an_unknown_kind_is_refused_with_every_kind_named():
-    with pytest.raises(ValueError) as refused:
-        TransformerLM(vocab_size=VOCAB, d_model=HIDDEN, n_heads=HEADS,
-                      layers=("window",)).init(
-                          jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-    for kind in LAYER_KINDS:
-        assert kind in str(refused.value) and kind in MixerLayer.__doc__
-
-
-@pytest.mark.parametrize("mixer", [DeltaMixer(*DELTA, head_shard=(0, 3)),
-                                   LatentAttention(HEADS, LATENT,
-                                                   head_shard=(2, 2))])
-def test_mixers_refuse_a_share_that_does_not_divide(mixer):
-    with pytest.raises(ValueError, match="head_shard"):
-        mixer.init(jax.random.PRNGKey(0), jnp.zeros((1, SEQ, HIDDEN)))
-
-
-def test_trains_through_build_train_step_and_replicas_stay_equal():
-    """Two CPU devices, data parallel: the dense LM's step with the pattern.
-    The replicated weights stay equal and the loss of a repeated batch
-    falls.  The flash kernels (interpreted here), as in the benchmark; the
-    delta rule's scan carries a state that varies over the mesh axis."""
-    model = lm((0, 4), (0, 2), use_flash=True)
-    mesh = Mesh(np.array(jax.devices()[:2]), ("hvd",))
-    params, batch = seeded(model, seed=3)
-    tx = optax.adamw(1e-2)
-    step = build_train_step(lambda p, b: system_loss(model, p, b), tx, mesh,
-                            axis_name="hvd", batch_spec=(P("hvd"), P("hvd")))
-    state = (params, tx.init(params))
-    losses = []
-    for _ in range(4):
-        *state, loss = step(*state, batch)
-        losses.append(float(loss))
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
-    for leaf in jax.tree.leaves(state[0]):
-        first, second = (np.asarray(s.data) for s in leaf.addressable_shards)
-        np.testing.assert_array_equal(first, second)
-
-
 # --- the shares add up to the uncut layer ---------------------------------
 
 def delta_share(p, shard, n):
@@ -539,93 +439,3 @@ def delta_share(p, shard, n):
             "A_log": heads(p["A_log"]),
             "norm_scale": p["norm_scale"],               # one for every head
             "out_proj_kernel": heads(p["out_proj_kernel"].T, inner).T}
-
-
-@pytest.mark.parametrize("n", [2, 4, 8])
-def test_delta_tensor_shares_add_up_to_the_uncut_layer(n):
-    whole = DeltaMixer(*DELTA, dtype=jnp.float32)
-    u, params, _ = mixer_case(whole, n)
-    parts = [jax.jit(DeltaMixer(*DELTA, head_shard=(i, n),
-                                dtype=jnp.float32).apply)(
-        {"params": delta_share(params, i, n)}, u) for i in range(n)]
-    close(sum(parts), with_highest(reference.kda)(
-        u, params, head_dim=DELTA.head_dim, lower_bound=DELTA.lower_bound,
-        norm_eps=1e-6), 1e-4)
-
-
-@pytest.mark.parametrize("n", [2, 8])
-def test_latent_attention_tensor_shares_add_up_with_the_latent_counted_once(
-        n):
-    """Every share holds the whole `W_kva` and the latent's norm (a chip of
-    the mesh computes the latent alike); the heads' slices of the other four
-    weights partition, and the n outputs sum to the uncut layer's."""
-    whole = LatentAttention(HEADS, LATENT, jnp.float32, use_flash=False)
-    u, params, _ = mixer_case(whole, n)
-    local = HEADS // n
-    parts = []
-    for i in range(n):
-        held = slice(i * local, (i + 1) * local)
-        share = dict(params, q_kernel=params["q_kernel"][:, held],
-                     kv_b_kernel=params["kv_b_kernel"][:, held],
-                     gate_kernel=params["gate_kernel"][:, held],
-                     o_kernel=params["o_kernel"][held])
-        parts.append(jax.jit(LatentAttention(
-            HEADS, LATENT, jnp.float32, use_flash=False,
-            head_shard=(i, n)).apply)({"params": share}, u))
-    close(sum(parts), with_highest(reference.latent_attention)(
-        u, params, nope_dim=LATENT.nope_dim, rope_theta=LATENT.rope_theta,
-        norm_eps=1e-6))
-
-
-@pytest.mark.parametrize("n,experts,groups", [(4, EXPERTS, GROUPS),
-                                              (16, EXPERTS, GROUPS),
-                                              (64, 128, 8)])
-def test_expert_shares_add_up_with_router_and_shared_expert_counted_once(
-        n, experts, groups):
-    """The n shares' outputs each hold the shared expert; their sum holds it
-    n times and the routed part once.  64 shares of 2 experts in 8 groups,
-    4 kept: the deployment's count."""
-    kept = groups // 2
-    whole = SparseExperts(moe(experts=experts, groups=groups, kept=kept),
-                          jnp.float32)
-    u, params, _ = mixer_case(whole, n)
-    local = experts // n
-    parts = []
-    for i in range(n):
-        held = slice(i * local, (i + 1) * local)
-        share = dict(params, **{name: params[name][held] for name in (
-            "gate_kernel", "up_kernel", "down_kernel")})
-        parts.append(jax.jit(SparseExperts(
-            moe((i, n), experts=experts, groups=groups, kept=kept),
-            jnp.float32).apply)({"params": share}, u))
-    flat = u.reshape(-1, HIDDEN)
-    shared = reference.gated_mlp(flat, *(params[name]["kernel"] for name in (
-        "shared_gate", "shared_up", "shared_down"))).reshape(u.shape)
-    want = with_highest(reference.sparse_experts)(
-        flat, params, num_experts=experts, expert_shard=(0, 1),
-        **dict(routing(), n_group=groups, topk_group=kept))[0]
-    close(sum(part - shared for part in parts) + shared,
-          want.reshape(u.shape))
-
-
-@pytest.mark.parametrize("n", [2, 8])
-def test_vocabulary_slices_concatenate_to_the_uncut_head(n):
-    """A sliced vocabulary is a smaller vocabulary: the i-th slice's model —
-    its rows of the embedding, its columns of the head — gives, for ids of
-    the slice, the uncut model's logits of those columns."""
-    model = lm()
-    params, _ = seeded(model)
-    rows = VOCAB // n
-    whole, sliced = jax.jit(model.apply), jax.jit(lm(vocab=rows).apply)
-    width = 0
-    for i in range(n):
-        ids = jax.random.randint(jax.random.PRNGKey(9), (1, SEQ), 0, rows)
-        held = slice(i * rows, (i + 1) * rows)
-        share = dict(params,
-                     embed={"embedding": params["embed"]["embedding"][held]},
-                     lm_head_kernel=params["lm_head_kernel"][:, held])
-        got = sliced({"params": share}, ids)
-        want = whole({"params": params}, ids + i * rows)
-        close(got, want[..., held])
-        width += got.shape[-1]
-    assert width == VOCAB

@@ -16,26 +16,24 @@ would read 1e-3 to 1e-2 and fail every case;
 outside them.
 """
 
+import functools
 import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from benchmark.reference import qwen3next_lm as reference
 from benchmark.reference.ling_lm import delta_recurrence
-from horovod_tpu.jax.train import build_train_step
 from horovod_tpu.models import (DeltaConfig, DeltaMixer,
                                 MoEConfig, TransformerLM)
-from horovod_tpu.models.transformer import (LAYER_KINDS, Attention,
-                                            SparseExperts, rope)
+from horovod_tpu.models.transformer import Attention, SparseExperts, rope
 from horovod_tpu.ops import delta_rule
 from horovod_tpu.ops.delta_rule import chunked_delta_rule, lowered_plan
-from tests.test_hybrid import (both_ways, close, columns, mixer_case, seeded,
-                               system_loss, trees_close, with_highest)
+from tests.test_hybrid import (both_ways, close, columns, mixer_case,
+                               reference_sides, seeded, sown, with_highest)
 
 RTOL = 2e-5
 VOCAB, HIDDEN, SEQ = 256, 64, 128
@@ -71,8 +69,13 @@ def reference_config(expert_shard=(0, 1), **more):
                 experts_per_token=PER_TOKEN, expert_shard=expert_shard, **more)
 
 
+reference_side = reference_sides(reference_config, reference.loss_and_chosen)
+
+
 # --- the delta rule with a decay a head over grouped heads ------------------
 
+@functools.partial(jax.jit, static_argnames=(
+    "seed", "seq", "low", "d_k", "d_v", "key_heads"))
 def rule_inputs(seed, seq=SEQ, low=-20.0, d_k=16, d_v=8,
                 key_heads=KEY_HEADS):
     """Unit keys, queries at d_k^-1/2, a value head's log-decay from 0 down to
@@ -371,9 +374,9 @@ def test_gated_delta_mixer_is_the_reference(chunk, head_shard):
 def test_gated_delta_mixer_writes_its_chunks_decay_under_its_own_name():
     mixer = DeltaMixer(*DELTA, gate="head", dtype=jnp.float32)
     u, params, _ = mixer_case(mixer)
-    _, wrote = mixer.apply({"params": params}, u, mutable=["intermediates"])
-    assert set(wrote["intermediates"]) == {"gdn_chunk_log_decay_min"}
-    decay = wrote["intermediates"]["gdn_chunk_log_decay_min"][0]
+    wrote = sown(mixer, {"params": params}, u)
+    assert set(wrote) == {"gdn_chunk_log_decay_min"}
+    decay = wrote["gdn_chunk_log_decay_min"][0]
     assert decay.shape == () and float(decay) < 0
 
 
@@ -479,12 +482,11 @@ def test_experts_with_a_gated_shared_expert_are_the_dense_loop(shard):
 
     both_ways(lambda p, u: layer.apply({"params": p}, u), plain, u, params,
               mix)
-    _, wrote = layer.apply({"params": params}, u, mutable=["intermediates"])
     _, want = with_highest(reference.router)(
         u.reshape(-1, HIDDEN), params["router_kernel"],
         experts_per_token=PER_TOKEN)
     np.testing.assert_array_equal(
-        jnp.sort(wrote["intermediates"]["chosen_experts"][0], -1),
+        jnp.sort(sown(layer, {"params": params}, u)["chosen_experts"][0], -1),
         jnp.sort(want, -1))
 
 
@@ -513,94 +515,6 @@ def test_an_output_gate_wants_a_shared_expert():
             jax.random.PRNGKey(0), jnp.zeros((1, 8, HIDDEN)))
 
 
-# --- the whole model --------------------------------------------------------
-
-@pytest.mark.parametrize("chunk", [32, 64])
-@pytest.mark.parametrize("expert_shard", [(0, 1), (1, 4)])
-def test_qwen3next_lm_loss_and_gradients_are_the_references(expert_shard,
-                                                            chunk):
-    model = lm(expert_shard, chunk=chunk)
-    params, batch = seeded(model, seed=chunk)
-    config = reference_config(expert_shard)
-    got, got_grads = jax.jit(jax.value_and_grad(
-        lambda p: system_loss(model, p, batch)))(params)
-    (want, want_chose), want_grads = with_highest(jax.value_and_grad(
-        lambda p: reference.loss_and_chosen(p, batch, **config),
-        has_aux=True))(params)
-    np.testing.assert_allclose(got, want, rtol=RTOL)
-    trees_close(got_grads, want_grads, 1e-4)
-    _, wrote = model.apply({"params": params}, batch[0],
-                           mutable=["intermediates"])
-    chose = jnp.stack([wrote["intermediates"][f"layer_{i}"]["mixer"][
-        "chosen_experts"][0] for i, kind in enumerate(LAYERS)
-        if kind == "experts"])
-    np.testing.assert_array_equal(jnp.sort(chose, -1),
-                                  jnp.sort(want_chose, -1))
-
-
-def test_reference_refuses_float8_operands():
-    """The reference against itself with every matmul operand, and the q, k, v
-    its recurrence and its attention read, rounded to float8_e4m3fn: the error
-    the benchmark's limits must refuse is far over what float32 reorderings
-    give above."""
-    model = lm()
-    params, batch = seeded(model)
-    losses = [with_highest(jax.value_and_grad(lambda p: reference.loss(
-        p, batch, operand_dtype=dtype, **reference_config())))(params)
-        for dtype in (None, jnp.float8_e4m3fn)]
-    norm = optax.global_norm
-    wrong = norm(jax.tree.map(jnp.subtract, losses[1][1], losses[0][1]))
-    assert float(wrong / norm(losses[0][1])) > 0.05
-
-
-def test_pattern_has_one_norm_and_one_mixer_an_entry():
-    shapes = jax.eval_shape(lambda: lm((0, 4)).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, SEQ), jnp.int32))["params"])
-    assert set(shapes) == {"embed", "final_norm", "lm_head_kernel"} | {
-        f"layer_{i}" for i in range(len(LAYERS))}
-    mixers = {"gated_delta": {"A_log", "conv_kernel", "dt_bias",
-                              "in_proj_kernel", "norm_scale",
-                              "out_proj_kernel"},
-              "attention": {"q_kernel", "kv_kernel", "q_head_norm_scale",
-                            "k_head_norm_scale", "gate_kernel", "o_kernel"},
-              "experts": {"router_kernel", "gate_kernel", "up_kernel",
-                          "down_kernel", "shared_gate", "shared_up",
-                          "shared_down", "shared_output_gate_kernel"}}
-    for i, kind in enumerate(LAYERS):
-        assert set(shapes[f"layer_{i}"]) == {"norm", "mixer"}
-        assert set(shapes[f"layer_{i}"]["mixer"]) == mixers[kind]
-    assert LAYER_KINDS["gated_delta"] == LAYER_KINDS["delta"] == "DeltaMixer"
-    # The share: 4 of 16 experts, the router over all 16, the mixers whole.
-    assert shapes["layer_0"]["mixer"]["A_log"].shape == (VALUE_HEADS,)
-    assert shapes["layer_1"]["mixer"]["up_kernel"].shape == (4, HIDDEN, WIDTH)
-    assert shapes["layer_1"]["mixer"]["router_kernel"].shape == (HIDDEN,
-                                                                 EXPERTS)
-    assert shapes["layer_6"]["mixer"]["kv_kernel"].shape == (
-        HIDDEN, 2, KV_HEADS, HEAD_DIM)
-
-
-def test_trains_through_build_train_step_and_replicas_stay_equal():
-    """Two CPU devices, data parallel: the dense LM's step with the pattern.
-    The replicated weights stay equal and the loss of a repeated batch falls.
-    The flash kernels (interpreted here), as in the benchmark; the delta
-    rule's scan carries a state that varies over the mesh axis."""
-    model = lm((0, 4), use_flash=True)
-    mesh = Mesh(np.array(jax.devices()[:2]), ("hvd",))
-    params, batch = seeded(model, seed=3)
-    tx = optax.adamw(1e-2)
-    step = build_train_step(lambda p, b: system_loss(model, p, b), tx, mesh,
-                            axis_name="hvd", batch_spec=(P("hvd"), P("hvd")))
-    state = (params, tx.init(params))
-    losses = []
-    for _ in range(4):
-        *state, loss = step(*state, batch)
-        losses.append(float(loss))
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
-    for leaf in jax.tree.leaves(state[0]):
-        first, second = (np.asarray(s.data) for s in leaf.addressable_shards)
-        np.testing.assert_array_equal(first, second)
-
-
 # --- the shares add up to the uncut layer -----------------------------------
 
 def gated_delta_share(p, shard, n):
@@ -618,43 +532,3 @@ def gated_delta_share(p, shard, n):
             "dt_bias": heads(p["dt_bias"]), "A_log": heads(p["A_log"]),
             "norm_scale": p["norm_scale"],               # one for every head
             "out_proj_kernel": heads(p["out_proj_kernel"].T, values).T}
-
-
-def test_gated_delta_tensor_shares_add_up_to_the_uncut_layer():
-    whole = DeltaMixer(*DELTA, gate="head", dtype=jnp.float32)
-    u, params, _ = mixer_case(whole, 2)
-    parts = [jax.jit(DeltaMixer(*DELTA, gate="head", head_shard=(i, 2),
-                                dtype=jnp.float32).apply)(
-        {"params": gated_delta_share(params, i, 2)}, u) for i in range(2)]
-    close(sum(parts), with_highest(reference.gated_delta)(
-        u, params, head_dim=LINEAR_DIM, norm_eps=1e-6), 1e-4)
-
-
-@pytest.mark.parametrize("n,experts", [(4, EXPERTS), (16, EXPERTS),
-                                       (16, 512)])
-def test_expert_shares_add_up_with_the_gated_shared_expert_counted_once(
-        n, experts):
-    """The n shares' outputs each hold the gated shared expert; their sum
-    holds it n times and the routed part once.  16 shares of 32 experts: the
-    deployment's count."""
-    whole = SparseExperts(moe(experts=experts), jnp.float32)
-    u, params, _ = mixer_case(whole, n)
-    local = experts // n
-    parts = []
-    for i in range(n):
-        held = slice(i * local, (i + 1) * local)
-        share = dict(params, **{name: params[name][held] for name in (
-            "gate_kernel", "up_kernel", "down_kernel")})
-        parts.append(jax.jit(SparseExperts(
-            moe((i, n), experts=experts), jnp.float32).apply)(
-                {"params": share}, u))
-    flat = u.reshape(-1, HIDDEN)
-    shared = (jax.nn.sigmoid(flat @ params["shared_output_gate_kernel"])
-              * reference.gated_mlp(flat, *(params[name]["kernel"] for name in (
-                  "shared_gate", "shared_up", "shared_down")))).reshape(
-                      u.shape)
-    want = with_highest(reference.sparse_experts)(
-        flat, params, num_experts=experts, expert_shard=(0, 1),
-        experts_per_token=PER_TOKEN)[0]
-    close(sum(part - shared for part in parts) + shared,
-          want.reshape(u.shape))

@@ -10,26 +10,22 @@ experts.  CPU, float32, seeded weights, small sizes; the kernels interpreted.
 Tolerances: as tests/test_trinity.py's, and for its reasons.
 """
 
+import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
-from jax.sharding import Mesh, PartitionSpec as P
 
 import horovod_tpu.ops.attention as attn
 from benchmark import ops_count_sdar
 from benchmark.reference import compare, sdar_lm as reference
-from horovod_tpu.jax.train import build_train_step
 from horovod_tpu.models import (MoEConfig, TransformerLM,
-                                masked_diffusion_loss, next_token_loss,
-                                record_attention_blocks)
-from horovod_tpu.models.transformer import (LAYER_KINDS, Attention,
-                                            MixerLayer, SparseExperts)
+                                masked_diffusion_loss, next_token_loss)
+from horovod_tpu.models.transformer import LAYER_KINDS, Attention, MixerLayer
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              mha_reference)
 from horovod_tpu.ops.attention import mask_blocks
-from tests.test_hybrid import (both_ways, close, spread, trees_close,
+from tests.test_hybrid import (both_ways, close, reference_sides, spread,
                                with_highest)
 from tests.test_flash_table import check_tables
 from tests.test_ops import _pallas_call_names
@@ -73,11 +69,16 @@ def noised_batch(key, batch=2, seq=SEQ, block=BLOCK, vocab=VOCAB):
 
 
 def seeded(model, seed=0):
-    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
-    batch = noised_batch(keys[0])
-    params = spread(model.init(keys[1], batch[0], noised=batch[1])["params"],
-                    seed)
-    return params, batch
+    """(`model`'s seeded parameters spread, a noised batch), made in one
+    program."""
+    def make(key):
+        keys = jax.random.split(key, 2)
+        batch = noised_batch(keys[0])
+        params = spread(model.init(keys[1], batch[0],
+                                   noised=batch[1])["params"], seed)
+        return params, batch
+
+    return jax.jit(make)(jax.random.PRNGKey(seed))
 
 
 def system_loss(model, params, batch):
@@ -85,6 +86,32 @@ def system_loss(model, params, batch):
     return masked_diffusion_loss(
         model.apply({"params": params}, tokens, noised=noised), tokens,
         masked, level)
+
+
+def system_side(model, params, batch):
+    """((the loss, the experts every expert layer chose), every gradient) of
+    `model` from one program."""
+    tokens, noised, masked, level = batch
+
+    def loss_and_chosen(params):
+        logits, wrote = model.apply({"params": params}, tokens, noised=noised,
+                                    mutable=["intermediates"])
+        chose = jnp.stack([wrote["intermediates"][f"layer_{i}"]["mixer"][
+            "chosen_experts"][0] for i in range(1, 2 * DEPTH, 2)])
+        return masked_diffusion_loss(logits, tokens, masked, level), chose
+
+    return jax.jit(jax.value_and_grad(loss_and_chosen, has_aux=True))(params)
+
+
+reference_side = reference_sides(reference_config, reference.loss_and_chosen)
+
+
+@functools.cache
+def seed_zero():
+    """`seeded(lm())` and the reference's ((loss, chosen), gradients) there:
+    what every wrong program is measured against."""
+    params, batch = seeded(lm())
+    return params, batch, reference_side()(params, batch)
 
 
 # --- the mask and the kernels ------------------------------------------------
@@ -298,10 +325,13 @@ def test_causal_and_windowed_calls_keep_their_kernels(monkeypatch, plan):
 # --- the layers --------------------------------------------------------------
 
 def case(module, seed, rows=2 * SEQ):
-    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
-    u = jax.random.normal(keys[0], (2, rows, HIDDEN))
-    params = spread(module.init(keys[1], u)["params"], seed)
-    return u, params, jax.random.normal(keys[2], u.shape)
+    def make(key):
+        keys = jax.random.split(key, 3)
+        u = jax.random.normal(keys[0], (2, rows, HIDDEN))
+        params = spread(module.init(keys[1], u)["params"], seed)
+        return u, params, jax.random.normal(keys[2], u.shape)
+
+    return jax.jit(make)(jax.random.PRNGKey(seed))
 
 
 @pytest.mark.parametrize("use_flash", [False, True])
@@ -376,166 +406,6 @@ def test_the_loss_weighs_masked_tokens_by_their_level():
                                                level), rtol=1e-5)
 
 
-# --- the model ---------------------------------------------------------------
-
-@pytest.mark.parametrize("expert_shard", [(0, 1), (1, 4)])
-@pytest.mark.parametrize("use_flash", [False, True])
-def test_sdar_lm_loss_and_gradients_are_the_references(expert_shard,
-                                                       use_flash):
-    model = lm(expert_shard, use_flash)
-    params, batch = seeded(model, seed=expert_shard[1])
-    config = reference_config(expert_shard)
-    got, got_grads = jax.jit(jax.value_and_grad(
-        lambda p: system_loss(model, p, batch)))(params)
-    want, want_grads = with_highest(jax.value_and_grad(
-        lambda p: reference.loss(p, batch, **config)))(params)
-    np.testing.assert_allclose(got, want, rtol=RTOL)
-    trees_close(got_grads, want_grads, 1e-4)
-    _, wrote = model.apply({"params": params}, batch[0], noised=batch[1],
-                           mutable=["intermediates"])
-    chose = jnp.stack([wrote["intermediates"][f"layer_{i}"]["mixer"][
-        "chosen_experts"][0] for i in range(1, 2 * DEPTH, 2)])
-    want = with_highest(reference.loss_and_chosen)(params, batch, **config)[1]
-    np.testing.assert_array_equal(jnp.sort(chose, -1), jnp.sort(want, -1))
-
-
-def test_the_head_runs_on_the_noised_half():
-    """Logits for L rows, and none of them moves with the clean copy's LAST
-    block (no later block's noised query sees it), while the noised copy's
-    rows do move their own block's."""
-    model = lm()
-    params, (tokens, noised, _, _) = seeded(model, seed=5)
-    apply = jax.jit(lambda t, n: model.apply({"params": params}, t, noised=n))
-    logits = apply(tokens, noised)
-    assert logits.shape == (2, SEQ, VOCAB)
-    other_tail = tokens.at[:, -BLOCK:].set((tokens[:, -BLOCK:] + 1) % VOCAB)
-    close(apply(other_tail, noised), logits)
-    other_head = tokens.at[:, :BLOCK].set((tokens[:, :BLOCK] + 1) % VOCAB)
-    moved = jnp.abs(apply(other_head, noised) - logits).max(-1)
-    assert float(moved[:, :BLOCK].max()) == 0.0        # its own block: unseen
-    assert float(moved[:, BLOCK:].min()) > 0.0
-    other_noise = noised.at[:, 0].set((noised[:, 0] + 1) % VOCAB)
-    moved = jnp.abs(apply(tokens, other_noise) - logits).max(-1)
-    assert float(moved[:, :BLOCK].min()) > 0.0
-    assert float(moved[:, BLOCK:].max()) == 0.0
-
-
-def test_block_diffusion_layers_count_their_tiles():
-    model = lm(use_flash=True)
-    params, batch = seeded(model, seed=4)
-    _, wrote = model.apply({"params": params}, batch[0], noised=batch[1],
-                           mutable=["intermediates"])
-    seen = record_attention_blocks(wrote["intermediates"])
-    # 128 rows a copy are one 128-tile each: clean on clean, noised on clean,
-    # noised on noised; a causal walk over the 256 rows visits as many.
-    assert seen == {"blocks_visited": [3] * DEPTH,
-                    "blocks_causal": [3] * DEPTH}
-    assert mask_blocks(2 * SEQ, HEAD_DIM, block_diffusion=BLOCK) == (3, 3)
-
-
-def test_trains_through_build_train_step_and_replicas_stay_equal():
-    """Two CPU devices, data parallel: the dense LM's step with the pattern
-    and the block-diffusion flash kernels (interpreted here) as in the
-    benchmark.  The replicated weights stay equal and the loss of a repeated
-    batch falls."""
-    model = lm((0, 4), use_flash=True)
-    mesh = Mesh(np.array(jax.devices()[:2]), ("hvd",))
-    params, batch = seeded(model, seed=3)
-    tx = optax.adamw(1e-2)
-    step = build_train_step(lambda p, b: system_loss(model, p, b), tx, mesh,
-                            axis_name="hvd", batch_spec=(P("hvd"),) * 4)
-    state = (params, tx.init(params))
-    losses = []
-    for _ in range(4):
-        *state, loss = step(*state, batch)
-        losses.append(float(loss))
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
-    for leaf in jax.tree.leaves(state[0]):
-        first, second = (np.asarray(s.data) for s in leaf.addressable_shards)
-        np.testing.assert_array_equal(first, second)
-
-
-# --- the shares add up to the uncut layer ------------------------------------
-
-@pytest.mark.parametrize("n,experts", [(4, EXPERTS), (8, EXPERTS), (8, 128)])
-def test_expert_shares_add_up_with_the_router_counted_once(n, experts):
-    """The n shares' outputs sum to the uncut reference's layer: softmax over
-    all experts and the renormalised weights on every share, each expert on
-    one.  8 shares of 16 experts: the deployment's count."""
-    whole = SparseExperts(moe(experts=experts), jnp.float32)
-    keys = jax.random.split(jax.random.PRNGKey(n), 2)
-    u = jax.random.normal(keys[0], (2, SEQ, HIDDEN))
-    params = whole.init(keys[1], u)["params"]
-    local = experts // n
-    parts = []
-    for i in range(n):
-        held = slice(i * local, (i + 1) * local)
-        share = dict(params, **{name: params[name][held] for name in (
-            "gate_kernel", "up_kernel", "down_kernel")})
-        parts.append(jax.jit(SparseExperts(
-            moe((i, n), experts=experts), jnp.float32).apply)(
-                {"params": share}, u))
-    flat = u.reshape(-1, HIDDEN)
-    weights, chosen = with_highest(reference.router)(
-        flat, params["router_kernel"], experts_per_token=PER_TOKEN)
-    np.testing.assert_allclose(weights.sum(-1), 1.0, rtol=1e-5)
-    want = with_highest(reference.experts_of_shard)(flat, params, weights,
-                                                    chosen, 0)
-    close(sum(parts), want.reshape(u.shape))
-
-
-def test_the_layers_shares_add_up_with_attention_counted_once():
-    """One published layer: every share computes the attention alike (counted
-    once) and its own experts; attention's output plus the shares' expert
-    outputs is the uncut reference's layer."""
-    n = 4
-    attention = MixerLayer("blockdiff_attention", HEADS, jnp.float32, False,
-                           norm_eps=EPS, n_kv_heads=KV_HEADS,
-                           head_dim=HEAD_DIM, head_norm=True,
-                           block_diffusion=BLOCK, rope_theta=THETA)
-
-    def experts(shard):
-        return MixerLayer("experts", HEADS, jnp.float32, False, moe(shard),
-                          norm_eps=EPS)
-
-    x, p_attention, _ = case(attention, seed=7)
-    p_experts = spread(experts((0, 1)).init(jax.random.PRNGKey(8),
-                                            x)["params"], 8)
-    after = attention.apply({"params": p_attention}, x)
-    local = EXPERTS // n
-    total = after
-    for i in range(n):
-        held = slice(i * local, (i + 1) * local)
-        mixer = dict(p_experts["mixer"], **{
-            name: p_experts["mixer"][name][held]
-            for name in ("gate_kernel", "up_kernel", "down_kernel")})
-        total = total + experts((i, n)).apply(
-            {"params": dict(p_experts, mixer=mixer)}, after) - after
-    want = with_highest(reference.layer)(
-        x, p_attention, p_experts, **reference_config())[0]
-    close(total, want)
-
-
-@pytest.mark.parametrize("n", [2, 8])
-def test_vocabulary_slices_concatenate_to_the_uncut_head(n):
-    """A sliced vocabulary is a smaller vocabulary: the i-th slice's model
-    gives, for ids of the slice, the uncut model's logits of those columns."""
-    model = lm()
-    params, _ = seeded(model)
-    rows = VOCAB // n
-    whole = jax.jit(lambda p, t, m: model.apply({"params": p}, t, noised=m))
-    small = lm(vocab=rows)
-    sliced = jax.jit(lambda p, t, m: small.apply({"params": p}, t, noised=m))
-    for i in range(n):
-        tokens, noised, _, _ = noised_batch(jax.random.PRNGKey(9), vocab=rows)
-        held = slice(i * rows, (i + 1) * rows)
-        share = dict(params,
-                     embed={"embedding": params["embed"]["embedding"][held]},
-                     lm_head_kernel=params["lm_head_kernel"][:, held])
-        close(sliced(share, tokens, noised),
-              whole(params, tokens + i * rows, noised + i * rows)[..., held])
-
-
 # --- the reference refuses the wrong programs --------------------------------
 
 def probe_rows(kernel, length=256):
@@ -549,57 +419,3 @@ def probe_rows(kernel, length=256):
         (1, 4, 2 * length, HEAD_DIM), jnp.float32, 7,
         reference.BLOCKDIFF_FWD_ATOL, reference.BLOCKDIFF_GRAD_RTOL,
         "blockdiff_flash_")
-
-
-def test_the_kernels_pass_the_builders_own_rows():
-    rows = probe_rows(lambda q, k, v, scale: flash_attention(
-        q, k, v, block_diffusion=BLOCK, sm_scale=scale, block_q=128,
-        block_k=128, interpret=True))
-    assert len(rows) == 4 and all(row["value"] < 1e-4 * row["limit"]
-                                  for row in rows), rows
-
-
-@pytest.mark.parametrize("wrong", [dict(causal=True),
-                                   dict(block_diffusion=2 * BLOCK),
-                                   dict(block_diffusion=BLOCK // 2)], ids=str)
-def test_a_wrong_mask_fails_the_builders_rows(wrong):
-    """A causal mask over the 2 L rows, and a block twice or half as long,
-    through the kernels themselves: each is over a limit of the cell's
-    comparison, by a wide margin."""
-    rows = probe_rows(lambda q, k, v, scale: flash_attention(
-        q, k, v, sm_scale=scale, block_q=128, block_k=128, interpret=True,
-        **wrong))
-    over = [row for row in rows if row["value"] > 2 * row["limit"]]
-    assert over, rows
-
-
-@pytest.mark.parametrize("drop", ["level_weight", "renormalize",
-                                  "noised_block"])
-def test_a_dropped_term_is_another_program(drop):
-    """The switches that leave a term out do change the loss and its
-    gradients, by more than the cell's limits allow."""
-    params, batch = seeded(lm())
-    right, wrong = (with_highest(jax.value_and_grad(
-        lambda p: reference.loss(p, batch, **reference_config(drop=d))))(
-            params) for d in (None, drop))
-    norm = optax.global_norm
-    off = norm(jax.tree.map(jnp.subtract, wrong[1], right[1])) / norm(right[1])
-    assert abs(float(wrong[0] / right[0] - 1)) > reference.LOSS_RTOL \
-        or float(off) > reference.GRAD_RTOL, (drop, off)
-    assert float(off) > reference.GRAD_RTOL, (drop, off)
-
-
-@pytest.mark.parametrize("dtype,least", [(jnp.float8_e4m3fn,
-                                          reference.GRAD_RTOL),
-                                         (jnp.bfloat16, 50 * 1e-4)],
-                         ids=["float8_under_bfloat16",
-                              "bfloat16_under_float32"])
-def test_reference_refuses_the_next_precision_down(dtype, least):
-    model = lm()
-    params, batch = seeded(model)
-    losses = [with_highest(jax.value_and_grad(lambda p: reference.loss(
-        p, batch, operand_dtype=operand, **reference_config())))(params)
-        for operand in (None, dtype)]
-    norm = optax.global_norm
-    wrong = norm(jax.tree.map(jnp.subtract, losses[1][1], losses[0][1]))
-    assert float(wrong / norm(losses[0][1])) > least

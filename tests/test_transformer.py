@@ -32,8 +32,8 @@ def _tokens(batch=2, seq=32, seed=0):
 def test_forward_shape_and_finite():
     model = _model()
     tokens = _tokens()
-    params = model.init(jax.random.PRNGKey(1), tokens)["params"]
-    logits = model.apply({"params": params}, tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), tokens)["params"]
+    logits = jax.jit(model.apply)({"params": params}, tokens)
     assert logits.shape == (2, 32, VOCAB)
     assert np.isfinite(np.asarray(logits)).all()
 
@@ -81,10 +81,11 @@ def test_causality():
     """Changing a future token must not change earlier logits."""
     model = _model()
     tokens = _tokens(seq=16)
-    params = model.init(jax.random.PRNGKey(1), tokens)["params"]
-    base = model.apply({"params": params}, tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), tokens)["params"]
+    apply = jax.jit(model.apply)
+    base = apply({"params": params}, tokens)
     mutated = tokens.at[:, 10].set((tokens[:, 10] + 1) % VOCAB)
-    out = model.apply({"params": params}, mutated)
+    out = apply({"params": params}, mutated)
     np.testing.assert_allclose(base[:, :10], out[:, :10], atol=1e-6)
     assert not np.allclose(base[:, 10:], out[:, 10:])
 
@@ -327,7 +328,7 @@ def test_gated_attention_is_the_layer_with_the_plain_gate(
                                   n_kv_heads=kv_heads, head_norm=True,
                                   gate=True)
     x = jnp.asarray(np.random.RandomState(3).randn(2, 32, 64), jnp.float32)
-    params = layer.init(jax.random.PRNGKey(4), x)["params"]
+    params = jax.jit(layer.init)(jax.random.PRNGKey(4), x)["params"]
     assert params["gate_kernel"].shape == (64, 4, head_dim)
 
     def value_and_grads():
@@ -353,7 +354,7 @@ def test_output_gate_keeps_and_hands_on_the_layers_dtype_alone():
     x, w_g and the ROUNDED gate, nothing float32), the two arrays the backward
     writes before its products (one barrier, both bfloat16) and the products'
     operands and results.  What the chip's compiler makes of it is in
-    `tests/test_ops.py` (`test_gated_attention_writes_no_float32_array_...`):
+    `tests/test_chip_steps.py` (`test_gated_attention_writes_no_float32_...`):
     the CPU backend computes bfloat16 element-wise passes in float32 and keeps
     no barrier, so its text shows nothing of this."""
     from horovod_tpu.models.transformer import (_output_gated_bwd,
@@ -533,13 +534,18 @@ def test_a_key_head_turned_before_its_repeat_is_the_same_layer():
     keys = jax.random.split(jax.random.PRNGKey(2), 3)
     x = jax.random.normal(keys[0], (2, 32, 48), jnp.float32)
     mix = jax.random.normal(keys[1], (2, 32, 48), jnp.float32)
-    params = layer.init(keys[2], x)["params"]
-    params = jax.tree.map(            # scales off one, so they matter
+    params = jax.jit(lambda key: jax.tree.map(   # scales off one, so they
         lambda p: p * (1.0 + 0.1 * jnp.arange(p.size).reshape(p.shape)
-                       / p.size), params)
+                       / p.size), layer.init(key, x)["params"]))(keys[2])
 
-    def loss(fn):
-        return lambda params, x: jnp.sum(fn(params, x) * mix)
+    def both(fn):
+        """(the output, the gradients of its mix), one program."""
+        def summed(params, x):
+            out = fn(params, x)
+            return jnp.sum(out * mix), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            summed, (0, 1), has_aux=True))(params, x)
+        return out, grads
 
     def ours(params, x):
         return layer.apply({"params": params}, x)
@@ -548,10 +554,8 @@ def test_a_key_head_turned_before_its_repeat_is_the_same_layer():
         return _plain_grouped_attention(params, x, heads, kv_heads, theta,
                                         eps)
 
-    np.testing.assert_allclose(ours(params, x), plain(params, x),
-                               rtol=1e-5, atol=1e-5)
-    got = jax.grad(loss(ours), (0, 1))(params, x)
-    want = jax.grad(loss(plain), (0, 1))(params, x)
+    (out, got), (plain_out, want) = both(ours), both(plain)
+    np.testing.assert_allclose(out, plain_out, rtol=1e-5, atol=1e-5)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         a, b, rtol=1e-4, atol=1e-4), got, want)
 
@@ -805,7 +809,7 @@ def test_wide_model_s_table_gradient_is_autodiffs(embed_scale, monkeypatch):
                           n_heads=2, d_ff=64, dtype=jnp.float32,
                           use_flash=False, embed_scale=embed_scale)
     tokens = _tokens(seed=3)
-    params = model.init(jax.random.PRNGKey(2), tokens)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(2), tokens)["params"]
 
     def loss(params):
         return next_token_loss(model.apply({"params": params}, tokens),
@@ -833,13 +837,13 @@ def test_wide_table_trains_data_parallel_and_replicas_stay_equal():
                           use_flash=True)
     tokens = jax.random.randint(jax.random.PRNGKey(4), (2, 129), 0, vocab)
     batch = (tokens[:, :-1], tokens[:, 1:])
-    params = model.init(jax.random.PRNGKey(5), batch[0])["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(5), batch[0])["params"]
 
     def loss_fn(params, batch):
         return next_token_loss(model.apply({"params": params}, batch[0]),
                                batch[1])
 
-    whole = jax.grad(loss_fn)(params, batch)["embed"]["embedding"]
+    whole = jax.jit(jax.grad(loss_fn))(params, batch)["embed"]["embedding"]
     table = np.asarray(params["embed"]["embedding"])    # the step donates
     mesh = Mesh(np.array(jax.devices()[:2]), ("hvd",))
     rate = 0.01

@@ -14,26 +14,21 @@ layers: 2e-5 of the largest value, 1e-4 for the whole model's gradients.
 bfloat16 anywhere would read 1e-3 to 1e-2 and fail every case.
 """
 
+import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
-from jax.sharding import Mesh, PartitionSpec as P
 
 import horovod_tpu.ops.attention as attn
 from benchmark.reference import compare, trinity_lm as reference
-from horovod_tpu.common import metrics
-from horovod_tpu.jax.train import build_train_step
-from horovod_tpu.models import (MoEConfig, TransformerLM,
-                                record_attention_blocks)
-from horovod_tpu.models.transformer import (LAYER_KINDS, Attention,
-                                            MixerLayer, SparseExperts)
+from horovod_tpu.models import MoEConfig, TransformerLM
+from horovod_tpu.models.transformer import LAYER_KINDS, Attention, MixerLayer
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              mha_reference)
 from horovod_tpu.ops.attention import mask_blocks
-from tests.test_hybrid import (both_ways, close, mixer_case, seeded,
-                               system_loss, trees_close, with_highest)
+from tests.test_hybrid import (both_ways, close, mixer_case, reference_sides,
+                               seeded)
 from tests.test_flash_table import check_tables, pallas_calls
 from tests.test_ops import _pallas_call_names
 
@@ -66,6 +61,17 @@ def reference_config(expert_shard=(0, 1), **more):
                 rope_theta=THETA, norm_eps=EPS, num_experts=EXPERTS,
                 experts_per_token=PER_TOKEN, expert_shard=expert_shard,
                 weight_scale=SCALE, **more)
+
+
+reference_side = reference_sides(reference_config, reference.loss_and_chosen)
+
+
+@functools.cache
+def seed_zero():
+    """`seeded(lm())` and the reference's ((loss, chosen), gradients) there:
+    what every wrong program is measured against."""
+    params, batch = seeded(lm())
+    return params, batch, reference_side()(params, batch)
 
 
 # --- the banded kernels ------------------------------------------------------
@@ -295,130 +301,6 @@ def test_the_kinds_and_the_defaults():
                                                  HIDDEN)
 
 
-# --- the model ---------------------------------------------------------------
-
-@pytest.mark.parametrize("expert_shard", [(0, 1), (1, 4)])
-def test_trinity_lm_loss_and_gradients_are_the_references(expert_shard):
-    model = lm(expert_shard)
-    params, batch = seeded(model, seed=expert_shard[1])
-    config = reference_config(expert_shard)
-    got, got_grads = jax.jit(jax.value_and_grad(
-        lambda p: system_loss(model, p, batch)))(params)
-    want, want_grads = with_highest(jax.value_and_grad(
-        lambda p: reference.loss(p, batch, **config)))(params)
-    np.testing.assert_allclose(got, want, rtol=RTOL)
-    trees_close(got_grads, want_grads, 1e-4)
-    _, wrote = model.apply({"params": params}, batch[0],
-                           mutable=["intermediates"])
-    chose = jnp.stack([wrote["intermediates"][f"layer_{i}"]["mixer"][
-        "chosen_experts"][0] for i, kind in enumerate(LAYERS)
-        if kind == "experts"])
-    want = with_highest(reference.loss_and_chosen)(params, batch, **config)[1]
-    np.testing.assert_array_equal(jnp.sort(chose, -1), jnp.sort(want, -1))
-
-
-def test_the_embedding_multiplier_is_the_references():
-    model = lm()
-    params, batch = seeded(model, seed=2)
-    scaled = jax.jit(model.apply)({"params": params}, batch[0])
-    as_one = jax.jit(lm().clone(embed_scale=None).apply)({"params": dict(
-        params, embed={"embedding": params["embed"]["embedding"]
-                       * HIDDEN ** 0.5})}, batch[0])
-    close(scaled, as_one)
-
-
-def test_windowed_layers_count_their_blocks(monkeypatch):
-    model = lm(use_flash=True)
-    params, batch = seeded(model, seed=4)
-    _, wrote = model.apply({"params": params}, batch[0],
-                           mutable=["intermediates"])
-    monkeypatch.setattr(metrics.registry, "enabled", True)
-    seen = record_attention_blocks(wrote["intermediates"])
-    # 128 tokens are one 128-block: the three windowed layers visit it.
-    assert seen == {"blocks_visited": [1, 1, 1], "blocks_causal": [1, 1, 1]}
-    snapshot = metrics.registry.snapshot()
-    assert snapshot["attention"] == seen
-    text = metrics.prometheus_text(snapshot)
-    assert 'hvd_tpu_attention_blocks{layer="2",kind="visited"} 1' in text
-    assert "grid_" not in text
-    # the full layer: no windowed layer's counters, and none of its own
-    assert "layer_6" not in wrote["intermediates"]
-
-
-def test_trains_through_build_train_step_and_replicas_stay_equal():
-    """Two CPU devices, data parallel: the dense LM's step with the pattern,
-    the banded and the causal flash kernels (interpreted here) as in the
-    benchmark.  The replicated weights stay equal and the loss of a repeated
-    batch falls."""
-    model = lm((0, 4), use_flash=True)
-    mesh = Mesh(np.array(jax.devices()[:2]), ("hvd",))
-    params, batch = seeded(model, seed=3)
-    tx = optax.adamw(1e-2)
-    step = build_train_step(lambda p, b: system_loss(model, p, b), tx, mesh,
-                            axis_name="hvd", batch_spec=(P("hvd"), P("hvd")))
-    state = (params, tx.init(params))
-    losses = []
-    for _ in range(4):
-        *state, loss = step(*state, batch)
-        losses.append(float(loss))
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
-    for leaf in jax.tree.leaves(state[0]):
-        first, second = (np.asarray(s.data) for s in leaf.addressable_shards)
-        np.testing.assert_array_equal(first, second)
-
-
-# --- the shares add up to the uncut layer ------------------------------------
-
-@pytest.mark.parametrize("n,experts", [(4, EXPERTS), (8, EXPERTS), (8, 128)])
-def test_expert_shares_add_up_with_router_and_shared_expert_counted_once(
-        n, experts):
-    """The n shares' outputs each hold the shared expert; their sum holds it
-    n times and the routed part once.  8 shares of 16 experts: the
-    deployment's count."""
-    whole = SparseExperts(moe(experts=experts), jnp.float32)
-    u, params, _ = mixer_case(whole, n)
-    local = experts // n
-    parts = []
-    for i in range(n):
-        held = slice(i * local, (i + 1) * local)
-        share = dict(params, **{name: params[name][held] for name in (
-            "gate_kernel", "up_kernel", "down_kernel")})
-        parts.append(jax.jit(SparseExperts(
-            moe((i, n), experts=experts), jnp.float32).apply)(
-                {"params": share}, u))
-    flat = u.reshape(-1, HIDDEN)
-    shared = reference.gated_mlp(flat, *(params[name]["kernel"] for name in (
-        "shared_gate", "shared_up", "shared_down"))).reshape(u.shape)
-    want = with_highest(reference.sparse_experts)(
-        flat, params, num_experts=experts, expert_shard=(0, 1),
-        experts_per_token=PER_TOKEN, weight_scale=SCALE)[0]
-    close(sum(part - shared for part in parts) + shared,
-          want.reshape(u.shape))
-
-
-@pytest.mark.parametrize("n", [2, 8])
-def test_vocabulary_slices_concatenate_to_the_uncut_head(n):
-    """A sliced vocabulary is a smaller vocabulary: the i-th slice's model —
-    its rows of the embedding, its columns of the head — gives, for ids of
-    the slice, the uncut model's logits of those columns."""
-    model = lm()
-    params, _ = seeded(model)
-    rows = VOCAB // n
-    whole, sliced = jax.jit(model.apply), jax.jit(lm(vocab=rows).apply)
-    width = 0
-    for i in range(n):
-        ids = jax.random.randint(jax.random.PRNGKey(9), (1, SEQ), 0, rows)
-        held = slice(i * rows, (i + 1) * rows)
-        share = dict(params,
-                     embed={"embedding": params["embed"]["embedding"][held]},
-                     lm_head_kernel=params["lm_head_kernel"][:, held])
-        got = sliced({"params": share}, ids)
-        want = whole({"params": params}, ids + i * rows)
-        close(got, want[..., held])
-        width += got.shape[-1]
-    assert width == VOCAB
-
-
 # --- the reference refuses the wrong programs --------------------------------
 
 def probe_rows(kernel, window=WINDOW, seq=256):
@@ -431,58 +313,3 @@ def probe_rows(kernel, window=WINDOW, seq=256):
                                                  sm_scale=sharp),
         (1, 4, seq, HEAD_DIM), jnp.float32, 7, reference.WINDOW_FWD_ATOL,
         reference.WINDOW_GRAD_RTOL, "window_flash_")
-
-
-def test_the_banded_kernels_pass_the_builders_own_rows():
-    rows = probe_rows(lambda q, k, v, scale: flash_attention(
-        q, k, v, causal=True, window=WINDOW, sm_scale=scale, block_q=128,
-        block_k=128, interpret=True))
-    assert len(rows) == 4 and all(row["value"] < 1e-4 * row["limit"]
-                                  for row in rows), rows
-
-
-@pytest.mark.parametrize("wrong", [None, WINDOW + 1, WINDOW - 1],
-                         ids=["causal_for_the_window", "one_key_too_wide",
-                              "one_key_too_narrow"])
-def test_a_wrong_window_fails_the_builders_rows(wrong):
-    """A causal mask where the window is stated, and a window one key off
-    either way, through the kernels themselves: each is over a limit of the
-    cell's comparison, by a wide margin."""
-    rows = probe_rows(lambda q, k, v, scale: flash_attention(
-        q, k, v, causal=True, window=wrong, sm_scale=scale, block_q=128,
-        block_k=128, interpret=True))
-    over = [row for row in rows if row["value"] > 2 * row["limit"]]
-    assert over, rows
-
-
-@pytest.mark.parametrize("wrong", [
-    dict(window_error=1), dict(window_error=-1), dict(causal_for_window=True)],
-    ids=str)
-def test_the_references_wrong_windows_are_other_programs(wrong):
-    """The switches that make the wrong programs do change the loss."""
-    params, batch = seeded(lm())
-    right = with_highest(reference.loss)(params, batch, **reference_config())
-    other = with_highest(reference.loss)(params, batch,
-                                         **reference_config(**wrong))
-    assert abs(float(other - right)) > 1e-6
-
-
-@pytest.mark.parametrize("dtype,least", [(jnp.float8_e4m3fn,
-                                          reference.GRAD_RTOL),
-                                         (jnp.bfloat16, 50 * 1e-4)],
-                         ids=["float8_under_bfloat16",
-                              "bfloat16_under_float32"])
-def test_reference_refuses_the_next_precision_down(dtype, least):
-    """The reference against itself with every matmul operand, and the q, k,
-    v the attention reads, rounded a precision down: float8 where the
-    configuration states bfloat16 is over the cell's gradient limit; bfloat16
-    where float32 is stated (these tests, the rehearsal) is fifty times over
-    what the float32 system is held to above."""
-    model = lm()
-    params, batch = seeded(model)
-    losses = [with_highest(jax.value_and_grad(lambda p: reference.loss(
-        p, batch, operand_dtype=operand, **reference_config())))(params)
-        for operand in (None, dtype)]
-    norm = optax.global_norm
-    wrong = norm(jax.tree.map(jnp.subtract, losses[1][1], losses[0][1]))
-    assert float(wrong / norm(losses[0][1])) > least
