@@ -618,6 +618,15 @@ class MetricsRegistry:
                 "blocks_visited": [int(n) for n in blocks_visited],
                 "blocks_causal": [int(n) for n in blocks_causal]}
 
+    def set_attention_selection(self, **counts) -> None:
+        """Mirror one forward pass's selection counts, a list a selecting
+        layer each (models.record_attention_selection), beside the blocks
+        (overwritten: one batch's)."""
+        with self._lock:
+            self._attention.update(
+                {name: [int(n) for n in layer]
+                 for name, layer in counts.items()})
+
     def set_train_step(self, exchange_overlap: dict, setup: dict) -> None:
         """Mirror a compiled training step's account of itself: whether it
         took the overlap options, with its asynchronous and synchronous

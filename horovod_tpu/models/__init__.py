@@ -24,17 +24,20 @@ from horovod_tpu.models.delta import DeltaConfig, DeltaMixer  # noqa: F401
 from horovod_tpu.models.ssm import Mamba2Config, Mamba2Mixer  # noqa: F401
 from horovod_tpu.models.transformer import (  # noqa: F401
     DecodeContext,
+    IndexerConfig,
     LatentAttention,
     LatentConfig,
     MoEConfig,
     RopeScaling,
     TransformerLM,
+    indexer_loss,
     log_exit_distribution,
     looped_exit_loss,
     masked_diffusion_loss,
     moe_next_token_loss,
     next_token_loss,
     record_attention_blocks,
+    record_attention_selection,
     record_exit_distribution,
     record_expert_rows,
     router_losses,

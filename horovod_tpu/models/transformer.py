@@ -24,7 +24,10 @@ from horovod_tpu.models.delta import DeltaConfig, DeltaMixer
 from horovod_tpu.models.ssm import Mamba2Config, Mamba2Mixer
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              ring_attention)
-from horovod_tpu.ops.attention import mask_blocks
+from horovod_tpu.ops.attention import (Selected, mask_blocks,
+                                       masked_flash_attention)
+from horovod_tpu.ops.dsa import (Selection, head_probs, index_scores,
+                                 indexer_kl, select)
 from horovod_tpu.ops.moe import (GROUPED_KERNELS, WAYS_BACK,
                                  buffer_rows_to_tokens, column_slabs,
                                  dispatch_rows, grouped_matmul, pass_back,
@@ -634,6 +637,21 @@ class SparseExperts(nn.Module):
         return mixed.reshape(x.shape)
 
 
+# What a selecting layer counts, by the names it sows behind ``dsa_``.
+SELECTION_COUNTS = Selection._fields[1:]
+
+
+class IndexerConfig(NamedTuple):
+    """A learned selection of keys in front of an attention layer (the
+    indexer of DeepSeek sparse attention): ``heads`` query heads of
+    ``head_dim`` on ONE key head score every earlier key, and each query
+    keeps its ``topk`` best (``Attention(indexer=)``)."""
+
+    heads: int
+    head_dim: int
+    topk: int
+
+
 class Attention(nn.Module):
     """Causal self-attention of one layer: the q/k/v projections (with the
     q/k norm where asked for) under the scope ``hvd_attn_qkv``, each rotation
@@ -710,6 +728,60 @@ class Attention(nn.Module):
     # ``attn_blocks_causal`` as a windowed one does, the second what a causal
     # kernel would visit over all ``seq`` rows.  Training only.
     block_diffusion: Optional[int] = None
+    # A learned selection (:class:`IndexerConfig`): on the layer's input with
+    # its gradient stopped, ``q_I = rope(x W_qI)`` as ``heads`` heads, ``k_I =
+    # rope(LayerNorm(x W_kI))`` one head, ``w = x W_w / sqrt(heads
+    # head_dim)``; the score ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] .
+    # k_I[s])`` (scope ``hvd_dsa_index``); query ``t`` sees the ``min(t + 1,
+    # topk)`` earlier keys that score highest, the same for every head (scope
+    # ``hvd_dsa_select``; the flash kernels take the selection as their
+    # mask's operand, :class:`~horovod_tpu.ops.attention.Selected`).  The
+    # layer sows ``dsa_kl`` into ``intermediates``: ``mean_t KL(p[t] ||
+    # softmax over the selection of I[t])``, ``p`` the attention's own
+    # probabilities meaned over the heads, gradient stopped (scope
+    # ``hvd_dsa_kl``) — the indexer's loss, which reaches the indexer's four
+    # parameters and no other, as the attention's output reaches every other
+    # and none of those (:func:`indexer_loss` sums the layers').  It also
+    # sows ``dsa_keys_selected``, ``dsa_keys_causal``, ``dsa_threshold_ties``,
+    # ``dsa_tiles_live`` and ``dsa_tiles_causal``
+    # (:func:`record_attention_selection`), and the selection itself,
+    # ``dsa_selection``, ``int8[batch, seq, seq]``.  A sequence of at most ``topk``
+    # positions selects nothing: the layer is the causal one to the last bit,
+    # ``dsa_kl`` is 0 and the indexer takes no gradient.  Training on the
+    # flash kernels only: no ring, no cached decode, no window.
+    indexer: Optional[IndexerConfig] = None
+
+    def _indexed(self, x, positions):
+        """(q_I, k_I, w) of the indexer on ``x`` (b, seq, d), gradient
+        stopped: (b, heads, seq, e) and (b, seq, e) in the compute type, (b,
+        seq, heads) float32."""
+        heads, e, _ = self.indexer
+        d = x.shape[-1]
+        w_q = self.param(
+            "index_q_kernel", nn.initializers.lecun_normal(
+                in_axis=0, out_axis=(1, 2)), (d, heads, e), jnp.float32)
+        w_k = self.param("index_k_kernel", nn.initializers.lecun_normal(),
+                         (d, e), jnp.float32)
+        scale = self.param("index_k_norm_scale", nn.initializers.ones, (e,),
+                           jnp.float32)
+        bias = self.param("index_k_norm_bias", nn.initializers.zeros, (e,),
+                          jnp.float32)
+        w_w = self.param("index_w_kernel", nn.initializers.lecun_normal(),
+                         (d, heads), jnp.float32)
+        x = lax.stop_gradient(x).astype(self.dtype)
+        q = jnp.einsum("bsd,dhe->bhse", x, w_q.astype(self.dtype))
+        k = jnp.einsum("bsd,de->bse", x, w_k.astype(self.dtype),
+                       preferred_element_type=jnp.float32)
+        k = k - k.mean(axis=-1, keepdims=True)
+        k = (k * lax.rsqrt(jnp.mean(jnp.square(k), axis=-1, keepdims=True)
+                           + self.norm_eps) * scale + bias).astype(self.dtype)
+        w = jnp.einsum("bsd,dh->bsh", x, w_w.astype(self.dtype),
+                       preferred_element_type=jnp.float32) \
+            * (heads ** -0.5 * e ** -0.5)
+        turn = functools.partial(rope, positions=positions,
+                                 base=self.rope_theta, seq_dim=-2,
+                                 scaling=self.rope_scaling)
+        return turn(q), turn(k), w
 
     def _grouped_projections(self, x, head_dim, rotate):
         """(q, k, v), each (b, local query heads, seq, head_dim), from a
@@ -761,6 +833,14 @@ class Attention(nn.Module):
             raise ValueError("block_diffusion= is a mask of its own over an "
                              "even number of rows, [clean; noised]: it takes "
                              "no window=")
+        if self.indexer is not None and (
+                decode_ctx is not None or self.seq_axis is not None
+                or self.window is not None or self.block_diffusion is not None
+                or not self.use_flash):
+            raise ValueError("indexer= selects keys for the causal flash "
+                             "kernels: it composes with neither decode_ctx=, "
+                             "sequence parallelism, window= nor "
+                             "block_diffusion=")
         if decode_ctx is not None:
             k_ctx, v_ctx, ctx_mask, positions = decode_ctx
         elif self.seq_axis is not None:
@@ -811,12 +891,23 @@ class Attention(nn.Module):
                 q = self._head_norm("q_head_norm_scale", q)
                 k = self._head_norm("k_head_norm_scale", k)
 
-        new_kv = None
+        new_kv = chosen = None
+        if self.indexer is not None:
+            with jax.named_scope("hvd_dsa_index"):
+                indexed = self._indexed(x, positions)
+                scores = index_scores(*indexed) \
+                    if s > self.indexer.topk else None
+            if scores is not None:
+                with jax.named_scope("hvd_dsa_select"):
+                    chosen = select(scores, self.indexer.topk)
         with jax.named_scope("hvd_attn_attend"):
             q = rotate(q)
             if not grouped:
                 k = rotate(k)
-            if decode_ctx is not None:
+            if chosen is not None:
+                out, lse = masked_flash_attention(
+                    q, k, v, Selected(self.indexer.topk), chosen.chosen)
+            elif decode_ctx is not None:
                 ctx_len = k_ctx.shape[-2]
                 # Context keys all precede the new chunk; within the chunk
                 # positions are consecutive, so causality is a lower
@@ -853,6 +944,16 @@ class Attention(nn.Module):
                              jnp.int32(visited))
                     self.sow("intermediates", "attn_blocks_causal",
                              jnp.int32(causal))
+        if self.indexer is not None:
+            kl = jnp.float32(0.0)
+            if chosen is not None:
+                with jax.named_scope("hvd_dsa_kl"):
+                    kl = indexer_kl(*indexed, scores, chosen.chosen,
+                                    head_probs(q, k, lse, chosen.chosen))
+                for name, count in zip(SELECTION_COUNTS, chosen[1:]):
+                    self.sow("intermediates", "dsa_" + name, count)
+                self.sow("intermediates", "dsa_selection", chosen.chosen)
+            self.sow("intermediates", "dsa_kl", kl)
         if self.gate:
             w_g = self.param(
                 "gate_kernel", nn.initializers.lecun_normal(
@@ -1096,7 +1197,8 @@ LAYER_KINDS = {"ssm": "Mamba2Mixer", "attention": "Attention",
                "latent_attention": "LatentAttention", "gated_mlp": "GatedMLP",
                "window_attention": "Attention",
                "blockdiff_attention": "Attention",
-               "gated_delta": "DeltaMixer"}
+               "gated_delta": "DeltaMixer",
+               "selected_attention": "Attention"}
 
 
 class MixerLayer(nn.Module):
@@ -1117,7 +1219,10 @@ class MixerLayer(nn.Module):
     rotated where ``rope`` says.  All three take ``head_dim``, ``head_norm``,
     ``attn_gate`` and ``rotary_dim``.  ``"delta"`` is :class:`DeltaMixer`
     under its channel gate, ``"gated_delta"`` under its head gate over grouped
-    heads, both at ``delta``'s sizes.  The rotated attention kinds turn at
+    heads, both at ``delta``'s sizes.  ``"selected_attention"`` is
+    :class:`Attention` behind the model's ``indexer``, a learned selection of
+    each query's keys (:class:`IndexerConfig`), rotated where ``rope`` says,
+    with the first three's sizes.  The rotated attention kinds turn at
     ``rope_theta`` under ``rope_scaling``; ``window_rope``, a ``(theta,
     scaling)`` pair, is the ``"window_attention"`` layers' own rotation where
     it is another (a model whose full layers turn at YaRN's frequencies and
@@ -1156,6 +1261,7 @@ class MixerLayer(nn.Module):
     rope_scaling: Optional[RopeScaling] = None
     window_rope: Optional[Tuple[float, Optional[RopeScaling]]] = None
     recompute: bool = False
+    indexer: Optional[IndexerConfig] = None
 
     @nn.compact
     def __call__(self, x):
@@ -1173,9 +1279,13 @@ class MixerLayer(nn.Module):
                                 dtype=self.dtype, norm_eps=self.norm_eps,
                                 name="mixer")
         elif self.kind in ("attention", "window_attention",
-                           "blockdiff_attention"):
+                           "blockdiff_attention", "selected_attention"):
             windowed = self.kind == "window_attention"
             diffusion = self.kind == "blockdiff_attention"
+            selected = self.kind == "selected_attention"
+            if selected and self.indexer is None:
+                raise ValueError("a 'selected_attention' layer wants "
+                                 "indexer=")
             if windowed and self.window is None:
                 raise ValueError("a 'window_attention' layer wants window=")
             if diffusion and self.block_diffusion is None:
@@ -1197,6 +1307,7 @@ class MixerLayer(nn.Module):
                               head_norm=self.head_norm, gate=self.attn_gate,
                               block_diffusion=self.block_diffusion
                               if diffusion else None,
+                              indexer=self.indexer if selected else None,
                               name="mixer")
         elif self.kind == "experts":
             mixer = SparseExperts(self.moe, self.dtype, name="mixer")
@@ -1313,6 +1424,10 @@ class TransformerLM(nn.Module):
     rope_scaling: Optional[RopeScaling] = None
     window_rope: Optional[Tuple[float, Optional[RopeScaling]]] = None
     recompute: bool = False
+    # The ``"selected_attention"`` layers' learned selection of keys
+    # (:class:`IndexerConfig`; :class:`Attention`'s ``indexer``): such a model
+    # trains on ``next_token_loss + indexer_loss(intermediates)``.
+    indexer: Optional[IndexerConfig] = None
     # A looped model: the pattern runs ``loops`` times over ONE set of
     # weights, ``final_norm`` after every pass, and the normed state is what
     # the next pass reads (``h_t = final_norm(layers(h_{t-1}))``).  The
@@ -1420,7 +1535,7 @@ class TransformerLM(nn.Module):
                           self.post_norm, self.block_diffusion,
                           self.rope_theta, self.rotary_dim,
                           self.rope_scaling, self.window_rope,
-                          self.recompute, name=f"layer_{i}")
+                          self.recompute, self.indexer, name=f"layer_{i}")
 
     @nn.nowrap
     def _looped(self, tokens, targets, decode_ctx, noised):
@@ -1739,6 +1854,36 @@ def record_attention_blocks(intermediates) -> dict:
             for kind in ("blocks_visited", "blocks_causal")}
     if _metrics.registry.enabled:
         _metrics.registry.set_attention_blocks(**seen)
+    return seen
+
+
+def indexer_loss(intermediates):
+    """The indexers' loss of a model with ``"selected_attention"`` layers:
+    the sum over those layers of the ``dsa_kl`` each sowed into the
+    ``intermediates`` collection of one ``apply(..., mutable=
+    ["intermediates"])`` — inside the loss function, where its gradient
+    reaches the indexers' parameters and no other."""
+    return sum(_sown(intermediates, "dsa_kl"))
+
+
+def record_attention_selection(intermediates) -> dict:
+    """Read what the ``"selected_attention"`` layers counted into the
+    ``intermediates`` collection of one ``apply(..., mutable=
+    ["intermediates"])`` — outside the compiled step — and, when the metrics
+    registry is on (``HVD_TPU_METRICS=1``), mirror it into
+    ``hvd.metrics_snapshot()["attention"]``.  A list a layer that selected
+    (more positions than its ``topk``), over the batch: ``keys_selected``
+    the (query, key) pairs kept of ``keys_causal``; ``threshold_ties`` the
+    pairs kept beyond ``topk`` a row, keys tied at the threshold;
+    ``tiles_live`` the (512, 512) tiles that hold a kept pair of
+    ``tiles_causal`` on and under the diagonal — what a kernel that skipped
+    dead tiles would visit."""
+    from horovod_tpu.common import metrics as _metrics
+
+    seen = {kind: [int(n) for n in _sown(intermediates, "dsa_" + kind)]
+            for kind in SELECTION_COUNTS}
+    if _metrics.registry.enabled:
+        _metrics.registry.set_attention_selection(**seen)
     return seen
 
 

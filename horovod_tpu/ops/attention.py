@@ -56,7 +56,18 @@ class Mask:
 
     and, where they differ, the scalars below.  `tests/test_flash_table.py`
     holds the three forms of every kind against each other (`check_tables`)
-    and runs one this module has never heard of."""
+    and runs one this module has never heard of.
+
+    A kind whose decision is DATA keeps its static part in the value (hashable
+    as ever) and declares ``operands``: that many arrays ride beside q, k and
+    v (:func:`masked_flash_attention`) to the three ``pallas_call`` sites,
+    each under the block spec :meth:`specs` gives it, and reach :meth:`seen`
+    whole and :meth:`cut` as the tile's slice, behind the arguments above.
+    Its :meth:`tiles` is what the static part leaves ``live`` and, in place of
+    ``whole``, the tiles the static part ALONE decides: with ``whole_body``
+    the table flags them, and the second body cuts them by ``flagged`` (a
+    static kind) and reads no operand.  A kind without operands pays nothing
+    for this: no call site looks at it."""
 
     suffix = ""         # behind a kernel's name in a trace
     copies = 1          # the blocks divide ``rows // copies``: no tile lies
@@ -64,6 +75,9 @@ class Mask:
     square = False      # the kernels need as many queries as keys
     whole_body = False  # the table flags whole tiles (`_WHOLE`) and a kernel
     #                     holds a second body for them, with no `cut`
+    operands = 0        # arrays of data the kind's `seen` and `cut` take
+    flagged = None      # the static kind whose `cut` a flagged tile takes
+    #                     (None: the tile is whole and nothing cuts it)
 
     @staticmethod
     def of(q_len, k_len, causal=False, window=None, block_diffusion=None):
@@ -111,6 +125,13 @@ class Mask:
         ``q_start`` and ``k_start``, NEG_INF where the query does not see the
         key."""
         return s
+
+    def specs(self, block_q, block_k, heads):
+        """One ``pl.BlockSpec`` an operand, over the grid ``(batch * heads,
+        the table's steps)`` with the table the index maps' first scalar
+        (`_tile_spec`): the slice of the operand that `cut` takes at a
+        step."""
+        return ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -237,6 +258,91 @@ class BlockDiffusion(Mask):
         return jnp.where(
             (diff >= jnp.where(q_noised & ~k_noised, 1, 0))
             & (diff <= jnp.where(k_noised, 0, half)), s, NEG_INF)
+
+
+@dataclasses.dataclass(frozen=True)
+class Selected(Mask):
+    """A learned selection under the causal mask: query ``t`` sees the key
+    ``s <= t`` where ``t < topk`` (a row with at most ``topk`` earlier keys
+    keeps them all) or where the operand, the selection as ``int8[batch, seq,
+    seq]``, is not zero at ``[t, s]`` — the same for every head.  The static
+    part is ``topk`` and the causal shape: the live tiles are the causal
+    kind's, whatever the data, and a query tile that ends under ``topk`` rows
+    is flagged, cut as :class:`Causal` cuts it and fetches no operand (its
+    spec stays on the first tile that does)."""
+
+    topk: int
+
+    suffix = "_selected"
+    square = True
+    whole_body = True
+    operands = 1
+    flagged = Causal()
+
+    def checked(self, q_len, k_len):
+        if k_len is None or q_len != k_len:
+            raise ValueError("a selection is over whole sequences, queries "
+                             "and keys alike: no offsets")
+        if self.topk < 1:
+            raise ValueError(f"topk={self.topk!r} wants at least one key")
+        # Every row keeps every earlier key: the causal mask, program for
+        # program.
+        return Causal() if self.topk >= q_len else Selected(int(self.topk))
+
+    def seen(self, q_pos, k_pos, selection):
+        return (q_pos >= k_pos) & ((q_pos < self.topk)
+                                   | (selection[..., q_pos, k_pos] != 0))
+
+    def tiles(self, num_q, num_k, block_q, block_k):
+        q_lo, q_hi, k_lo, _ = self.bounds(num_q, num_k, block_q, block_k)
+        live = q_hi - k_lo >= 0
+        return live, live & (q_hi < self.topk)
+
+    def specs(self, block_q, block_k, heads):
+        first = self.topk // block_q       # the first tile row that reads it
+
+        def tile(b, s, tab, *_):
+            reads = (tab[2, s] & _WHOLE) == 0
+            return (b // heads, jnp.where(reads, tab[0, s], first),
+                    jnp.where(reads, tab[1, s], 0))
+
+        return (pl.BlockSpec((1, block_q, block_k), tile),)
+
+    def cut(self, s, q_start, k_start, block_q, block_k, selection):
+        rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+        diff = (q_start - k_start) + rows - jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        chosen = selection.astype(jnp.int32) != 0
+        if self.topk % block_q:            # a tile astride row ``topk``
+            chosen |= q_start + rows < self.topk
+        return jnp.where((diff >= 0) & chosen, s, NEG_INF)
+
+
+class _Fed:
+    """A kind with operands as a kernel's body sees it: the kind's static
+    part, and `cut` with the operands' tiles of the grid's step behind its
+    arguments."""
+
+    def __init__(self, mask, tiles):
+        self.mask, self.tiles = mask, tiles
+        self.whole_body, self.flagged = mask.whole_body, mask.flagged
+
+    def cut(self, s, q_start, k_start, block_q, block_k):
+        return self.mask.cut(s, q_start, k_start, block_q, block_k,
+                             *(_rd(tile) for tile in self.tiles))
+
+
+def _feed(kernel, mask, in_specs, block_q, block_k, heads):
+    """(kernel, in_specs) of a call under a kind with operands: the operands'
+    specs behind the call's own, and a kernel that takes their refs out of
+    its list and hands its body the mask with them (`_Fed`)."""
+    first, last = len(in_specs), len(in_specs) + mask.operands
+
+    def fed(tab_ref, *refs):
+        return kernel(tab_ref, *refs[:first], *refs[last:],
+                      mask=_Fed(mask, refs[first:last]))
+
+    return fed, list(in_specs) + list(mask.specs(block_q, block_k, heads))
 
 
 def block_diffusion_mask(q_pos, k_pos, block: int, half: int):
@@ -555,6 +661,8 @@ def _attend_block(q_ref, k_ref, v_ref, m_scratch, l_scratch, acc_scratch,
         s *= scale_r
     if masked:
         s = mask.cut(s, q_start, k_start, block_q, block_k)
+    elif mask.flagged is not None:
+        s = mask.flagged.cut(s, q_start, k_start, block_q, block_k)
     if single_k:
         m_new = s.max(axis=-1)
         m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
@@ -695,6 +803,8 @@ def _bwd_block_math(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         s *= scale_r
     if masked:
         s = mask.cut(s, q_start, k_start, block_q, block_k)
+    elif mask.flagged is not None:
+        s = mask.flagged.cut(s, q_start, k_start, block_q, block_k)
     p = jnp.exp(s - lse[:, None])  # POS_BIG lse zeroes masked rows
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
@@ -942,7 +1052,7 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset=None,
                        rotate=False, collective_id=None, axis_name=None,
                        mesh_axes=(), interpret, scale_r=1.0,
                        grad_dtype=jnp.float32, dq_scale=1.0,
-                       name="hvd_flash_bwd"):
+                       name="hvd_flash_bwd", operands=(), heads=1):
     """pallas_call wrapper for `_combined_bwd_kernel` over (bh, sl, d)
     operands (q pre-scaled by the pow2 part of sm_scale; ``do`` and ``v_cur``
     may have another width than ``q`` and ``k_cur``, and ``dv`` then has
@@ -954,7 +1064,8 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset=None,
     is ``(bh, the table's steps)``: the mask's live tiles, key tiles outer —
     or, with the ring's ``q_offset`` and ``k_offset`` (traced, so the live
     tiles are not known here), every pair, the ring's ``run`` deciding in the
-    kernel."""
+    kernel.  ``operands``: the arrays of a kind that has some (`Mask`), the
+    same for each of a batch row's ``heads``; not under the ring."""
     bh, sl, d = q.shape
     d_v = v_cur.shape[-1]
     ring = q_offset is not None
@@ -993,6 +1104,10 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset=None,
     prefetch = [jnp.stack([jnp.asarray(q_offset, jnp.int32),
                            jnp.asarray(k_offset, jnp.int32)])] if ring else []
     args = [q, do, lse8, delta8, k_cur, v_cur]
+    if operands:
+        kernel, in_specs = _feed(kernel, mask, in_specs, block_q, block_k,
+                                 heads)
+        args += operands
     if rotate:
         in_specs += [
             pl.BlockSpec(memory_space=pl.ANY),             # k (DMA src)
@@ -1229,7 +1344,7 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
 
 def _split_bwd_call(q, do, lse8, delta8, k, v, *, mask, block_q,
                     block_k, interpret, scale_r, grad_dtype=jnp.float32,
-                    dq_scale=1.0):
+                    dq_scale=1.0, operands=(), heads=1):
     """Split flash backward over (bh, sl, d) operands (q pre-scaled by
     the pow2 part of sm_scale): two pallas_calls — dk/dv (key tiles outer,
     queries inner) and dq (query tiles outer, keys inner) — each with
@@ -1253,8 +1368,16 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, mask, block_q,
                                     vma=jax.typeof(q).vma)
     common = dict(mask=mask, block_q=block_q, block_k=block_k,
                   scale_r=scale_r)
+    dkdv_kernel = functools.partial(_flash_bwd_dkdv_kernel, **common)
+    dq_kernel = functools.partial(_flash_bwd_dq_kernel, dq_scale=dq_scale,
+                                  **common)
+    if operands:
+        dkdv_kernel, _ = _feed(dkdv_kernel, mask, in_specs, block_q, block_k,
+                               heads)
+        dq_kernel, in_specs = _feed(dq_kernel, mask, in_specs, block_q,
+                                    block_k, heads)
     dk, dv = _tiled_call(
-        functools.partial(_flash_bwd_dkdv_kernel, **common),
+        dkdv_kernel,
         _tile_table(*tiles, by_key=True), bh,
         in_specs=in_specs,
         out_specs=(_tile_spec(1, block_k, d), _tile_spec(1, block_k, d_v)),
@@ -1263,9 +1386,9 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, mask, block_q,
                         pltpu.VMEM((block_k, d_v), jnp.float32)],
         interpret=interpret,
         name="hvd_flash_bwd_dkdv" + mask.suffix,
-    )(q, do, lse8, delta8, k, v)
+    )(q, do, lse8, delta8, k, v, *operands)
     dq = _tiled_call(
-        functools.partial(_flash_bwd_dq_kernel, dq_scale=dq_scale, **common),
+        dq_kernel,
         _tile_table(*tiles), bh,
         in_specs=in_specs,
         out_specs=_tile_spec(0, block_q, d),
@@ -1273,7 +1396,7 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, mask, block_q,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         name="hvd_flash_bwd_dq" + mask.suffix,
-    )(q, do, lse8, delta8, k, v)
+    )(q, do, lse8, delta8, k, v, *operands)
     return dk, dv, dq
 
 
@@ -1313,7 +1436,7 @@ def _backward_blocks(q_len, k_len, d, d_v, block_q, block_k, bh, mask):
 
 
 def _flash_backward(q, k, v, out, lse, g, mask, sm_scale, block_q,
-                    block_k, interpret):
+                    block_k, interpret, operands=()):
     """Pallas flash backward.  Two kernel strategies, chosen per shape by
     :func:`_bwd_plan` against the scoped-VMEM ceiling: the combined
     kernel computes dk/dv AND dq from a single probability recompute per
@@ -1325,9 +1448,12 @@ def _flash_backward(q, k, v, out, lse, g, mask, sm_scale, block_q,
     plan = _backward_blocks(q_len, k_len, d, d_v, block_q, block_k,
                             batch * heads, mask)
     if plan is None:
+        if operands:
+            raise ValueError(_OPERANDS_OFF_GRID)
         return _attention_bwd_impl(q, k, v, out, lse, g, mask, sm_scale,
                                    max(min(block_k, k_len), 128), 0, 0)
     mode, block_q, block_k = plan
+    fed = dict(operands=operands, heads=heads) if operands else {}
     bh = batch * heads
     # Pre-scaled q (see _flash_forward): exact pow2 factor on q, f32
     # residual inside the kernel; dq comes back in q' units and is
@@ -1359,12 +1485,12 @@ def _flash_backward(q, k, v, out, lse, g, mask, sm_scale, block_q,
             block_q=block_q, block_k=block_k, interpret=interpret,
             scale_r=scale_r, grad_dtype=grad_dtype, dq_scale=p2,
             # A masked call's name keeps the prefix a trace is read by.
-            name="hvd_flash_bwd" + mask.suffix)
+            name="hvd_flash_bwd" + mask.suffix, **fed)
     else:
         dk, dv, dq = _split_bwd_call(
             qr, dor, lse8, delta8, kr, vr, mask=mask,
             block_q=block_q, block_k=block_k, interpret=interpret,
-            scale_r=scale_r, grad_dtype=grad_dtype, dq_scale=p2)
+            scale_r=scale_r, grad_dtype=grad_dtype, dq_scale=p2, **fed)
     return (dq.astype(q.dtype).reshape(q.shape),
             dk.astype(k.dtype).reshape(k.shape),
             dv.astype(v.dtype).reshape(v.shape))
@@ -1391,12 +1517,16 @@ def _forward_blocks(q_len, k_len, d, d_v, block_q, block_k, mask):
     return None if _off_grid(q_len, k_len, *blocks, mask) else blocks
 
 
-def _flash_forward(q, k, v, mask, sm_scale, block_q, block_k, interpret):
-    """Returns (out, lse); routes off-grid shapes to the blockwise impl."""
+def _flash_forward(q, k, v, mask, sm_scale, block_q, block_k, interpret,
+                   operands=()):
+    """Returns (out, lse); routes off-grid shapes to the blockwise impl
+    (``operands``: a kind's arrays, `Mask`, which stay on the grid)."""
     batch, heads, q_len, d = q.shape
     k_len, d_v = k.shape[2], v.shape[-1]
     blocks = _forward_blocks(q_len, k_len, d, d_v, block_q, block_k, mask)
     if blocks is None:
+        if operands:
+            raise ValueError(_OPERANDS_OFF_GRID)
         return _blockwise_fwd_impl(q, k, v, mask, sm_scale,
                                    max(min(block_k, k_len), 128), 0, 0)
     block_q, block_k = blocks
@@ -1415,10 +1545,14 @@ def _flash_forward(q, k, v, mask, sm_scale, block_q, block_k, interpret):
     kernel = functools.partial(
         _flash_kernel, mask=mask, block_q=block_q, block_k=block_k,
         single_k=table.shape[1] == q_len // block_q, scale_r=scale_r)
+    in_specs = [_tile_spec(0, block_q, d), _tile_spec(1, block_k, d),
+                _tile_spec(1, block_k, d_v)]
+    if operands:
+        kernel, in_specs = _feed(kernel, mask, in_specs, block_q, block_k,
+                                 heads)
     out, lse = _tiled_call(
         kernel, table, bh,
-        in_specs=[_tile_spec(0, block_q, d), _tile_spec(1, block_k, d),
-                  _tile_spec(1, block_k, d_v)],
+        in_specs=in_specs,
         out_specs=(_tile_spec(0, block_q, d_v), _lse_spec(block_q)),
         out_shape=(
             jax.ShapeDtypeStruct((bh, q_len, d_v), q.dtype, vma=vma),
@@ -1431,7 +1565,7 @@ def _flash_forward(q, k, v, mask, sm_scale, block_q, block_k, interpret):
         ],
         interpret=interpret,
         name="hvd_flash_fwd" + mask.suffix,
-    )(qr, kr, vr)
+    )(qr, kr, vr, *operands)
     return (out.reshape(batch, heads, q_len, d_v),
             lse[:, 0, :].reshape(batch, heads, q_len))
 
@@ -1455,6 +1589,77 @@ def _flash_bwd(mask, sm_scale, block_q, block_k, interpret, res, g):
 
 
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+_OPERANDS_OFF_GRID = (
+    "a mask with operands runs on the kernels' grid alone: as many queries "
+    "as keys, in whole blocks of a multiple of 128 rows")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_attention_fed(q, k, v, operands, mask, sm_scale, block_q, block_k,
+                         interpret):
+    """`_flash_attention` under a kind with ``operands`` (a tuple of arrays,
+    which take no gradient): (out, the rows' log-sum-exp).  The second is
+    there for a pass that reads the probabilities again, and takes no
+    cotangent."""
+    return _flash_forward(q, k, v, mask, sm_scale, block_q, block_k,
+                          interpret, operands)
+
+
+def _flash_fed_fwd(q, k, v, operands, mask, sm_scale, block_q, block_k,
+                   interpret):
+    out, lse = _flash_forward(q, k, v, mask, sm_scale, block_q, block_k,
+                              interpret, operands)
+    return (out, lse), (q, k, v, operands, out, lse)
+
+
+def _flash_fed_bwd(mask, sm_scale, block_q, block_k, interpret, res, g):
+    q, k, v, operands, out, lse = res
+    grads = _flash_backward(q, k, v, out, lse, g[0], mask, sm_scale, block_q,
+                            block_k, interpret, operands)
+    return (*grads, tuple(
+        jnp.zeros_like(o) if jnp.issubdtype(o.dtype, jnp.inexact)
+        else np.zeros(o.shape, jax.dtypes.float0) for o in operands))
+
+
+_flash_attention_fed.defvjp(_flash_fed_fwd, _flash_fed_bwd)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _fed_call(mask, sm_scale, block_q, block_k, interpret, q, k, v,
+              operands):
+    """`_flash_attention_fed` jitted: a model's layers of one shape trace
+    and lower its kernels once (as `ops.moe._tiled_call`, and for its
+    reason)."""
+    return _flash_attention_fed(q, k, v, operands, mask, sm_scale, block_q,
+                                block_k, interpret)
+
+
+def masked_flash_attention(q, k, v, mask: Mask, *operands,
+                           sm_scale: Optional[float] = None,
+                           block_q: Optional[int] = None,
+                           block_k: Optional[int] = None,
+                           interpret: Optional[bool] = None):
+    """:func:`flash_attention` under ``mask``, any kind of :class:`Mask`, with
+    the ``operands`` the kind declares (:class:`Selected`: the selection as
+    ``int8[batch, seq, seq]``) — ``(out, lse)``, ``(batch, heads, seq,
+    head_dim)`` operands and output, ``lse`` the rows' float32 log-sum-exp
+    ``(batch, heads, seq)``.  The kernels carry the kind's suffix behind
+    their names (``hvd_flash_fwd_selected``, ``hvd_flash_bwd_selected``,
+    ``hvd_flash_bwd_dkdv_selected``, ``hvd_flash_bwd_dq_selected``); q, k and
+    v take gradients, the operands and ``lse`` none.  A kind with operands
+    stays on the kernels' grid (a ValueError elsewhere)."""
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    mask = mask.checked(q.shape[-2], k.shape[-2])
+    if len(operands) != mask.operands:
+        raise ValueError(f"{mask!r} takes {mask.operands} operand(s), not "
+                         f"{len(operands)}")
+    blocks = _default_blocks(q.shape[-2], k.shape[-2], block_q, block_k, mask)
+    return _fed_call(mask, float(sm_scale), *blocks, bool(interpret), q, k, v,
+                     tuple(operands))
 
 
 def flash_attention(q, k, v, causal: bool = False,

@@ -27,13 +27,15 @@ from horovod_tpu.ops import flash_attention, mha_reference
 from horovod_tpu.ops.attention import Mask, flash_grid_steps, mask_blocks
 
 
-def seen_pairs(seq, mask):
+def seen_pairs(seq, mask, *operands):
     """``mask`` (a `Mask`, or `flash_attention`'s keywords for one) as a
-    (seq, seq) boolean matrix, position by position: its `seen`."""
+    (seq, seq) boolean matrix, position by position: its `seen`, on its
+    ``operands`` where the kind has some (one batch row's)."""
     if isinstance(mask, dict):
         mask = Mask.of(seq, seq, **mask)
     return np.broadcast_to(np.asarray(mask.seen(
-        np.arange(seq)[:, None], np.arange(seq)[None, :])), (seq, seq))
+        np.arange(seq)[:, None], np.arange(seq)[None, :], *operands)),
+        (seq, seq))
 
 
 def equations(jaxpr, primitive):
@@ -49,6 +51,46 @@ def pallas_calls(jaxpr):
     """{kernel name: grid} of every pallas_call, sub-programs included."""
     return {eqn.params["name"]: tuple(eqn.params["grid_mapping"].grid)
             for eqn in equations(jaxpr, "pallas_call")}
+
+
+def check_fed_tables(mask, seq, block_q, block_k, *operands):
+    """`check_tables` for a kind with operands (one batch row's, as `seen`
+    takes them; ``tile_of(operand, i, j)`` below cuts `cut`'s slice as the
+    kind's `specs` do): `live` covers every tile that holds a seen pair
+    whatever the data; a tile the static part alone decides (`tiles`'
+    second matrix, flagged in the table) is seen as ``flagged`` sees it; in
+    every live tile `cut` — with the operands' tiles, or ``flagged``'s
+    without — leaves the logits exactly where `seen` is true; and the table
+    flags what `tiles` says.  Returns the live pairs."""
+    seen = seen_pairs(seq, mask, *operands)
+    num_q, num_k = seq // block_q, seq // block_k
+    tiles = seen.reshape(num_q, block_q, num_k, block_k)
+    live, flagged = mask.tiles(num_q, num_k, block_q, block_k)
+    assert not (tiles.any((1, 3)) & ~live).any() and not (flagged & ~live).any()
+    plain = seen_pairs(seq, mask.flagged).reshape(tiles.shape)
+    zeros = jnp.zeros((block_q, block_k), jnp.float32)
+    for i, j in zip(*np.nonzero(live)):
+        if flagged[i, j]:
+            assert (tiles[i, :, j] == plain[i, :, j]).all(), (i, j)
+            cut = mask.flagged.cut(zeros, jnp.int32(i * block_q),
+                                   jnp.int32(j * block_k), block_q, block_k)
+        else:
+            cut = mask.cut(
+                zeros, jnp.int32(i * block_q), jnp.int32(j * block_k),
+                block_q, block_k, *(
+                    np.asarray(o)[
+                        i * block_q:(i + 1) * block_q if o.shape[0] > 1
+                        else slice(None),
+                        j * block_k:(j + 1) * block_k if o.shape[1] > 1
+                        else slice(None)] for o in operands))
+        assert ((np.asarray(cut) == 0) == tiles[i, :, j]).all(), (i, j)
+    want = set(zip(*np.nonzero(live)))
+    for by_key in (False, True):
+        q_tile, k_tile, flags = attn._tile_table(
+            num_q, num_k, block_q, block_k, mask, by_key=by_key)
+        assert set(zip(q_tile.tolist(), k_tile.tolist())) == want
+        assert (((flags & attn._WHOLE) != 0) == flagged[q_tile, k_tile]).all()
+    return want
 
 
 def check_tables(mask, seq, block_q, block_k):
@@ -506,3 +548,184 @@ def test_a_mask_is_one_class(monkeypatch, plan):
     for g, w in zip(jax.grad(loss(flash), (0, 1, 2))(q, k, v),
                     jax.grad(loss(dense), (0, 1, 2))(q, k, v)):
         np.testing.assert_allclose(g, w, atol=1e-3, rtol=1e-3)
+
+
+# --- kinds whose decision is data ---------------------------------------------
+
+def selection_of(seq, batch=2, keep=0.5, seed=56):
+    """A seeded int8 selection (batch, seq, seq), not even causal: `seen`
+    and `cut` are."""
+    return jnp.asarray(np.random.default_rng(seed).random(
+        (batch, seq, seq)) < keep, jnp.int8)
+
+
+@pytest.mark.parametrize("topk", [128, 200, 256, 384])
+@pytest.mark.parametrize("block_q,block_k", [(128, 256), (256, 128)])
+def test_the_selected_kinds_tables(topk, block_q, block_k):
+    """`Selected` through the three forms: the causal kind's live tiles, the
+    query tiles that end under `topk` rows flagged (the causal `cut` serves
+    them), a tile astride row `topk` cut by both."""
+    seq = 512
+    mask = attn.Selected(topk).checked(seq, seq)
+    chosen = selection_of(seq)[0]
+    live = check_fed_tables(mask, seq, block_q, block_k, chosen)
+    assert live == check_tables(attn.Causal(), seq, block_q, block_k)
+    flagged = mask.tiles(seq // block_q, seq // block_k, block_q, block_k)[1]
+    assert flagged.any(1).sum() == topk // block_q
+    assert mask.operands == 1 and mask.flagged == attn.Causal()
+    # every earlier key kept IS the causal mask, program for program
+    assert attn.Selected(seq).checked(seq, seq) == attn.Causal()
+    with pytest.raises(ValueError, match="whole sequences"):
+        attn.Selected(topk).checked(seq, None)
+
+
+def test_a_flagged_tile_fetches_no_operand():
+    """The selection's block spec stays on the first tile that reads it while
+    the table's step is flagged."""
+    mask = attn.Selected(256)
+    table = attn._tile_table(4, 4, 128, 128, mask)
+    (spec,) = mask.specs(128, 128, heads=2)
+    at = [tuple(int(x) for x in spec.index_map(3, s, table))
+          for s in range(table.shape[1])]
+    for s, (q_tile, k_tile, flags) in enumerate(table.T):
+        assert at[s] == ((1, 2, 0) if flags & attn._WHOLE
+                         else (1, q_tile, k_tile))
+    assert sum(1 for x in at if x == (1, 2, 0)) == 3 + 1   # and (2, 0) itself
+
+
+def dense_under(seen, d):
+    def attend(q, k, v):
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                            precision="highest") * d ** -0.5
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(jnp.where(
+            seen[:, None], logits, -jnp.inf), -1), v, precision="highest")
+    return attend
+
+
+def fed_against_dense(monkeypatch, plan, mask, operands, seen, seq, d,
+                      blocks=(256, 128), bodies=2):
+    """Output, lse and the three gradients of `masked_flash_attention` under
+    ``mask`` with ``operands`` (interpreted, two batch rows of two heads)
+    against the dense softmax under ``seen`` (batch, seq, seq); the kernels'
+    names, and the bodies each holds."""
+    monkeypatch.setattr(attn, "_bwd_plan",
+                        lambda q_len, d, bq, bk, bh=1: (plan, 128, 256))
+    jax.clear_caches()            # `_fed_call` is jitted: the plan is traced
+    rng = np.random.default_rng(56)
+    q, k, v, mix = (jnp.asarray(rng.standard_normal((2, 2, seq, d)),
+                                jnp.float32) for _ in range(4))
+
+    def flash(q, k, v):
+        return attn.masked_flash_attention(
+            q, k, v, mask, *operands, block_q=blocks[0], block_k=blocks[1],
+            interpret=True)
+
+    def loss(fn):
+        return lambda *a: (fn(*a) * mix).sum()
+
+    dense = dense_under(seen, d)
+    out, lse = flash(q, k, v)
+    np.testing.assert_allclose(out, dense(q, k, v), atol=2e-5, rtol=2e-5)
+    logits = jnp.where(seen[:, None], jnp.einsum(
+        "bhqd,bhkd->bhqk", q, k, precision="highest") * d ** -0.5, -jnp.inf)
+    np.testing.assert_allclose(lse, jax.nn.logsumexp(logits, -1),
+                               atol=2e-5, rtol=2e-5)
+    first = lambda *a: flash(*a)[0]  # noqa: E731
+    for g, w in zip(jax.grad(loss(first), (0, 1, 2))(q, k, v),
+                    jax.grad(loss(dense), (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(g, w, atol=1e-3, rtol=1e-3)
+    program = jax.make_jaxpr(jax.grad(loss(first), (0, 1, 2)))(q, k, v).jaxpr
+    names = ["hvd_flash_fwd"] + {
+        "combined": ["hvd_flash_bwd"],
+        "split": ["hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"]}[plan]
+    assert {eqn.params["name"]: len(list(equations(eqn.params["jaxpr"],
+                                                   "dot_general")))
+            for eqn in equations(program, "pallas_call")} == {
+        name + mask.suffix: bodies * ONE_BODY[name] for name in names}
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("plan", ["combined", "split"])
+@pytest.mark.parametrize("topk", [128, 200])
+def test_the_selected_kernels_are_the_dense_softmax(monkeypatch, plan, topk):
+    """The three kernel sites under `Selected`, fed a seeded selection: two
+    bodies a kernel (the operand's, and the causal one for the flagged
+    tiles), `_selected` behind every name."""
+    seq, d = 512, 64
+    chosen = selection_of(seq)
+    mask = attn.Selected(topk).checked(seq, seq)
+    seen = np.stack([seen_pairs(seq, mask, row) for row in chosen])
+    fed_against_dense(monkeypatch, plan, mask, (chosen,), seen, seq, d)
+
+
+@dataclasses.dataclass(frozen=True)
+class Documents(Mask):
+    """Packed documents, defined here and nowhere else: a query sees the
+    earlier keys of its OWN document.  The operand is the document of every
+    position, ``int32[batch, seq]`` — handed to the kernels twice, as a
+    column for a tile's queries and as a row for its keys, and nothing else.
+    The live tiles are the causal kind's; one body."""
+
+    suffix = "_documents"
+    square = True
+    operands = 2
+
+    def seen(self, q_pos, k_pos, column, row):
+        return (q_pos >= k_pos) & (column[..., q_pos, 0] == row[..., 0, k_pos])
+
+    def tiles(self, num_q, num_k, block_q, block_k):
+        q_lo, q_hi, k_lo, _ = self.bounds(num_q, num_k, block_q, block_k)
+        return q_hi - k_lo >= 0, np.zeros((num_q, num_k), bool)
+
+    def specs(self, block_q, block_k, heads):
+        return (attn.pl.BlockSpec((1, block_q, 1), lambda b, s, tab, *_: (
+                    b // heads, tab[0, s], 0)),
+                attn.pl.BlockSpec((1, 1, block_k), lambda b, s, tab, *_: (
+                    b // heads, 0, tab[1, s])))
+
+    def cut(self, s, q_start, k_start, block_q, block_k, column, row):
+        diff = (q_start - k_start) + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0) - jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        return jnp.where((diff >= 0) & (column.reshape(block_q, 1)
+                                        == row.reshape(1, block_k)), s,
+                         attn.NEG_INF)
+
+
+@pytest.mark.parametrize("plan", ["combined", "split"])
+def test_a_mask_of_data_is_one_class(monkeypatch, plan):
+    """`Documents` through `masked_flash_attention` with no edit to a kernel,
+    a `pallas_call` wrapper, a planner or the table builder: the seam hands a
+    kind's operands to the three sites under the kind's own specs."""
+    seq, d = 512, 64
+    starts = np.array([[0, 100, 256, 300], [0, 7, 130, 500]])
+    documents = jnp.asarray(
+        (np.arange(seq)[None, :, None] >= starts[:, None, :]).sum(-1),
+        jnp.int32)
+    operands = (documents[:, :, None], documents[:, None, :])
+    mask = Documents().checked(seq, seq)
+    seen = np.stack([seen_pairs(seq, mask, c, r)
+                     for c, r in zip(*operands)])
+    assert (seen == (np.tril(np.ones((seq, seq), bool))
+                     & (np.asarray(documents)[:, :, None]
+                        == np.asarray(documents)[:, None, :]))).all()
+    fed_against_dense(monkeypatch, plan, mask, operands, seen, seq, d,
+                      bodies=1)
+
+
+def test_operands_are_counted_and_stay_on_the_grid():
+    q = jnp.zeros((1, 2, 200, 64))
+    with pytest.raises(ValueError, match="operand"):
+        attn.masked_flash_attention(q, q, q, attn.Selected(64), interpret=True)
+    with pytest.raises(ValueError, match="grid"):
+        attn.masked_flash_attention(q, q, q, attn.Selected(64),
+                                    jnp.ones((1, 200, 200), jnp.int8),
+                                    interpret=True)
+    # a kind without operands goes the same way, and is the public call
+    q = jnp.asarray(np.random.default_rng(1).standard_normal((1, 2, 256, 64)),
+                    jnp.float32)
+    out, _ = attn.masked_flash_attention(q, q, q, attn.Causal(),
+                                         interpret=True)
+    np.testing.assert_allclose(out, flash_attention(q, q, q, causal=True,
+                                                    interpret=True),
+                               atol=1e-6, rtol=1e-6)

@@ -727,7 +727,8 @@ CELL_WAYS_BACK = {
     "olmoe1b7b": (8192, "pairs"), "nemotron3super120b": (4096, "rows"),
     "ling3flash": (8192, "row_slabs"), "trinitymini": (8192, "held_pairs"),
     "sdar30ba3b": (8192, "held_pairs"), "qwen3next80b": (4096, "row_slabs"),
-    "mellum2": (16384, "held_pairs"), "ouro2p6b": (None, None)}
+    "mellum2": (16384, "held_pairs"), "ouro2p6b": (None, None),
+    "keyevl2": (8192, "held_pairs")}
 
 
 # Tokens a step a chip of every benchmark cell's configuration, and the
@@ -740,7 +741,8 @@ CELL_KERNELS = {
     "nemotron3super120b": (4096, "ragged_dot"),
     "ling3flash": (8192, "ragged_dot"), "trinitymini": (8192, "ragged_dot"),
     "sdar30ba3b": (8192, "tiled"), "qwen3next80b": (4096, "ragged_dot"),
-    "mellum2": (16384, "tiled"), "ouro2p6b": (None, None)}
+    "mellum2": (16384, "tiled"), "ouro2p6b": (None, None),
+    "keyevl2": (8192, "tiled")}
 
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(

@@ -240,8 +240,9 @@ def test_the_kernels_mark_themselves_while_the_step_is_traced(monkeypatch):
     assert step.setup["programs"] == {"traced": 1, "lowered": 0, "loaded": 0}
     # The process's table has them too, each with its stamp.
     after = metrics.setup_table.process()
-    marked = [e for e in after["entries"][len(before["entries"]):]
-              if e[1] == "kernel"]
+    # (by stamp, not by index: the table drops its older half when full)
+    last = before["entries"][-1][0] if before["entries"] else 0.0
+    marked = [e for e in after["entries"] if e[0] > last and e[1] == "kernel"]
     assert sorted(e[2] for e in marked) == sorted(
         name for name, k in kernels.items() for _ in range(k["calls"]))
     assert sum(e[3] for e in marked) == pytest.approx(
