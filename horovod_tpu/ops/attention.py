@@ -1133,11 +1133,23 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset=None,
         compiler_params=pltpu.CompilerParams(
             collective_id=(collective_id if rotate and not interpret
                            else None),
-            has_side_effects=rotate),
+            has_side_effects=rotate,
+            # None, the parameter's default, but for a call past the budget
+            # a kernel has without asking: every other call hands Mosaic
+            # the parameters it always has.
+            vmem_limit_bytes=_combined_vmem_limit(sl, d, block_q, block_k,
+                                                  d_v)),
     )(*args)
 
 
 _MAX_BLOCK = 1024  # largest block edge the VMEM calibration covers
+# The most scoped VMEM a kernel here asks Mosaic for (`_combined_vmem_limit`):
+# half of a v5e core's 128 MiB.  No band of `_bwd_plan` comes near it (47.1
+# MiB is the most a probe of the sweep asked), so it is asserted, not clipped.
+_MAX_VMEM_LIMIT = 64 << 20
+# What a Mosaic kernel on a v5e has when it asks for nothing, MiB: the
+# default of ``HVD_TPU_VMEM_LIMIT_MB``.
+_DEFAULT_VMEM_MB = 16.0
 
 
 def _pick_block(seq_len: int, maximum: int = 512) -> int:
@@ -1152,12 +1164,17 @@ def _pick_block(seq_len: int, maximum: int = 512) -> int:
 
 
 def _vmem_budget_bytes() -> int:
-    """Scoped-VMEM planning budget, bytes.  Default 16 MiB — the v5e
-    scoped-allocation ceiling the compile sweep calibrated against;
-    ``HVD_TPU_VMEM_LIMIT_MB`` overrides it for chips with different
-    scoped capacity (or to leave headroom under other scoped users)."""
-    return int(float(os.environ.get("HVD_TPU_VMEM_LIMIT_MB") or 16.0)
-               * (1 << 20))
+    """Scoped-VMEM planning budget, bytes: what a kernel has WITHOUT asking
+    Mosaic for more.  Default 16 MiB — the v5e scoped-allocation default the
+    compile sweep calibrated against; ``HVD_TPU_VMEM_LIMIT_MB`` overrides it
+    for chips with a different default (or to leave headroom under other
+    scoped users).  A plan is clamped under it, but for the one band whose
+    call names its own limit (`_combined_vmem_limit`, 16,384 rows) — and that
+    band is taken only where the budget is the default's or more: set below
+    it, the name says the chip has less than the bands were calibrated for,
+    no call asks for more, and the band goes to the pair as it did."""
+    return int(float(os.environ.get("HVD_TPU_VMEM_LIMIT_MB")
+                     or _DEFAULT_VMEM_MB) * (1 << 20))
 
 
 def _plan_vmem_bytes(mode: str, q_len: int, d: int, block_q: int,
@@ -1175,7 +1192,14 @@ def _plan_vmem_bytes(mode: str, q_len: int, d: int, block_q: int,
     (tools/vmem_sweep.py): every measured-pass band lands under 16 MiB
     here and the measured 23.2 MiB seq-8192/1024-block failure lands
     over, so clamping to this estimate can only reject plans the frontier
-    also rejects."""
+    also rejects.  It charges the dq window and every other window at
+    float32, so a bf16 call needs less than it says: at 16,384 x 128 lanes
+    in (512, 512) it reads 27.6 MiB and the kernel compiles with a limit of
+    24 MiB (issue 57's compiles); the call asks for the estimate and a margin
+    all the same (`_combined_vmem_limit`), and all 100 probes of the
+    2026-10-03 re-run whose estimate is past the default (8,192 rows with a
+    1,024-block edge, 11,520, 12,288 and 16,384 rows: `_bwd_plan`) compile
+    with what it asks."""
     lanes = max(d, 128)
     both = lanes + max(d_v or d, 128)   # a q/k-wide and a v-wide window
     w, db = 4, 2              # f32 worst case; double-buffered windows
@@ -1192,6 +1216,28 @@ def _plan_vmem_bytes(mode: str, q_len: int, d: int, block_q: int,
     dqk = (db * w * (both * (block_q + block_k) + lanes * block_q) + lse
            + w * lanes * block_q)
     return max(dkdv, dqk)
+
+
+def _combined_vmem_limit(q_len: int, d: int, block_q: int, block_k: int,
+                         d_v: Optional[int] = None) -> Optional[int]:
+    """The ``vmem_limit_bytes`` the combined backward asks Mosaic for at this
+    shape and these blocks, or None where it asks for nothing: what
+    :func:`_plan_vmem_bytes` computes fits the budget a kernel has without
+    asking (`_vmem_budget_bytes`).  Past it the call asks for the computed
+    need and a margin — four float32 ``(block_q, block_k)`` tiles, the body's
+    s, p, dp and ds, which the structural estimate does not charge and Mosaic
+    puts on the kernel's stack.  The call (:func:`_combined_bwd_call`) asks
+    for this and nothing else does (the fused ring reads it to refuse a shard
+    that would ask), so there is one source for the number."""
+    need = _plan_vmem_bytes("combined", q_len, d, block_q, block_k, d_v)
+    if need <= _vmem_budget_bytes():
+        return None
+    limit = need + 4 * 4 * block_q * block_k
+    assert limit <= _MAX_VMEM_LIMIT, (
+        f"combined flash backward at {q_len} rows of {d} in ({block_q}, "
+        f"{block_k}) blocks would ask for {limit >> 20} MiB of scoped VMEM: "
+        "past every band of _bwd_plan")
+    return limit
 
 
 def _fwd_vmem_bytes(q_len: int, d: int, block_q: int,
@@ -1236,7 +1282,8 @@ def _clamp_blocks(mode: str, q_len: int, d: int, block_q: int,
 def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
               bh: int = 1, d_v: Optional[int] = None):
     """Choose the flash-backward execution mode and blocks against the
-    chip's 16 MiB scoped-VMEM ceiling.
+    16 MiB of scoped VMEM a kernel has without asking and, in one band, the
+    limit its call asks Mosaic for (`_combined_vmem_limit`).
 
     Calibrated by a compile sweep for v5e (tools/vmem_sweep.py; the
     bands the benchmark's cells use are compiled for a described chip in
@@ -1263,8 +1310,32 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
     <= 2048                any(<=1024)  combined, tuned blocks (1024)
     <= 4096                any(<=512)   combined (512, 1024)
     <= 8192                <= 32        combined (512, 512)
+    8192 < .. <= 16384     <= 128       combined (512, 512), ASKING
     otherwise              any          split, tuned blocks (1024)
     =====================  ==========  =============================
+
+    ASKING (PR 57): the band's whole-sequence dq does not fit what a kernel
+    has without asking, so its call names ``vmem_limit_bytes`` — what
+    :func:`_plan_vmem_bytes` computes and a margin, 31.6 MiB of the core's
+    128 at 16,384 rows (:func:`_combined_vmem_limit`, well inside
+    `_MAX_VMEM_LIMIT`).  The band is outside the clamp under
+    ``HVD_TPU_VMEM_LIMIT_MB`` by design (a call that names its limit is not
+    bound by what a kernel has without asking), and is entered only where
+    that budget is the default's 16 MiB or more: set lower, the band's rows
+    take the pair as they did before it.  The bands above it ask for nothing
+    and lower to the text they always lowered to.
+
+    Blocks by measurement, the backward alone in
+    a program at (1, 32, 16384, 128) bf16 (``tools/flash_bwd_sweep.py``,
+    medians of 10 on a v5e, my chip run, PR 57), causal / under a window of
+    1,024, ms: the pair in 1,024-blocks 50.71 / 13.59 and in 512-blocks
+    54.98 / 11.71; combined (512, 512) 38.63 / 8.69, (512, 1024) 36.74 /
+    10.04, (1024, 1024) 35.18 / 9.69.  One causal and three banded calls
+    (Mellum2's layers): 91.5 for the pair, 64.7 and 64.2 combined in 512- and
+    in 1,024-blocks — a tie, taken in 512-blocks (the smaller limit, and a
+    1,024-key band in three tiles where 1,024-blocks walk two); blocks by
+    mask (1,024 causal, 512 banded: 61.2) would save 4.7 % more, under the
+    5 % that would have paid for a plan that reads the mask.
 
     Re-run 2026-09-30 (PR 44: the kernels' grids became ``(bh, live
     tiles)`` tables, each kernel keeping the bodies it had;
@@ -1282,23 +1353,33 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
     1024-blocks), which stay as calibrated: they choose every benchmark
     cell's blocks.
 
+    Re-run 2026-10-03 (PR 57: the combined call asks for the scoped VMEM its
+    plan computes; ``tools/vmem_sweep.py --full``, libtpu 0.0.34 compiling for
+    a described v5e): 208 probes — the 156 above, 16,384 rows at bh 8, 16, 32,
+    64 and 128 where it had 8 and 128, and 12,288 rows (bh 32) and 11,520
+    (bh 8, its 384-blocks) inside the new band.  **208 pass, none fails**: the
+    108 whose estimate fits the default ask for nothing and pass as they did;
+    the 100 past it — every probe at 16,384, 12,288 and 11,520 rows, and 8,192
+    rows with a 1,024-block edge, the 32 failures of the run above among them
+    — compile with the limit their call names (19.7 to 47.1 MiB).  Outside
+    the bands and not in the sweep (issue 57's compiles): 32,768 rows compile
+    at 96–100 MiB and 65,536 at 110 MiB, past `_MAX_VMEM_LIMIT`; 4,096 rows of
+    256 lanes at 32 MiB (wide heads stay on the pair: ROADMAP S1(h)).
+
     ``mode`` is ``"combined"`` (one probability recompute per block,
     whole-seq dq scratch — preferred where it fits because it recomputes
-    once; the benchmark's cells up to 8,192 rows of heads up to 128 run it,
+    once; the benchmark's cells up to 16,384 rows of heads up to 128 run it,
     PERF.md section 3) or ``"split"`` (dkdv + dq kernel pair, O(block)
     scoped memory: full 1024-blocks compile at every probed extreme — seq to
-    64k, bh to 256, d to 256).  Three cells run the pair: Ling's (192 / 128
-    wide), Qwen3-Next's (256) and, at head 128, Mellum2's — 16,384 rows, bh
-    32, 1,024-blocks, causal and under a window of 1,024 (a band two tiles
-    wide).  There the causal pair takes 27.2 + 21.5 ms a layer for a forward
-    call's 19.2 and reads 57.4 % of the backward's roofline, the banded pair
-    29.0 % (31 tile pairs visited for an eighth of the causal mask's exact
-    pairs; my chip runs, PR 49, PERF.md section 5); the combined kernel reads
-    73.5 % at 8,192 rows in Trinity's cell (ledger, PR 44).  That is the
-    pair's second pass over the probabilities showing (2.5 forwards' work
-    counted, 3.5 done), at another length: the two at ONE shape are still
-    not measured on this machine (both compile at 8,192 rows, where
-    ``tests/test_ops.py`` forces the pair; no cell runs it there).
+    64k, bh to 256, d to 256).  Two cells run the pair: Ling's (192 / 128
+    wide) and Qwen3-Next's (256).  Until PR 57 Mellum2's ran it at head 128 —
+    16,384 rows, bh 32, 1,024-blocks — where the causal pair took 27.2 + 21.5
+    ms a layer for a forward call's 19.2 and read 57.4 % of the backward's
+    roofline, the banded pair 29.0 % (ledger, PR 56).  That was the pair's
+    second pass over the probabilities showing (2.5 forwards' work counted,
+    3.5 done), and the two at ONE shape are the table above: in equal
+    512-blocks the combined kernel takes 0.70 and 0.74 of the pair's time
+    (five products a tile where the pair does seven: 0.71).
 
     ``d_v``: the width of v, do and dv where it is not ``d`` (q, k, dq, dk).
     The bands above are entered with the WIDER of the two — every probe of
@@ -1316,7 +1397,10 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
         # backstopped against the COMPUTED budget (_plan_vmem_bytes):
         # a shrunken HVD_TPU_VMEM_LIMIT_MB, or a band edge the sweep's
         # granularity missed, clamps blocks down (warning) or demotes to
-        # split instead of handing Mosaic a plan that cannot compile.
+        # split instead of handing Mosaic a plan that cannot compile.  The
+        # asking band is outside that clamp — its call names its own limit
+        # — and is entered only under a budget of the default or more, so a
+        # shrunken one sends its rows to the pair too.
         choice = None
         if rows128 <= 2048 and bh <= 1024:
             choice = (block_q, block_k)
@@ -1326,6 +1410,14 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
         elif rows128 <= 8192 and bh <= 32:
             choice = (_pick_block(q_len, min(block_q, 512)),
                       _pick_block(q_len, min(block_k, 512)))
+        elif (8192 < rows128 <= 16384 and bh <= 128
+              and _vmem_budget_bytes() >= _DEFAULT_VMEM_MB * (1 << 20)):
+            # Past what a kernel has without asking: the call names its
+            # limit (`_combined_vmem_limit`: the blocks' need and a margin,
+            # or nothing under a budget raised past the need).
+            return ("combined",
+                    _pick_block(q_len, min(block_q, 512)),
+                    _pick_block(q_len, min(block_k, 512)))
         if choice is not None:
             fitted = _clamp_blocks("combined", q_len, d, *choice,
                                    estimate=estimate)
@@ -1349,9 +1441,15 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, mask, block_q,
     the pow2 part of sm_scale): two pallas_calls — dk/dv (key tiles outer,
     queries inner) and dq (query tiles outer, keys inner) — each with
     O(block) scoped VMEM, so any
-    sequence length compiles.  Pays the s/p/dp/ds recompute twice; the
-    combined kernel is preferred whenever its whole-seq dq scratch fits
-    (see _bwd_plan).  Returns (dk, dv, dq) in ``grad_dtype`` (f32
+    sequence length compiles.  Pays the s/p/dp/ds recompute twice (at
+    16,384 rows of 128 it takes 1.3 to 1.6 times the combined kernel's time:
+    the table in `_bwd_plan`, my chip run, PR 57); the combined kernel is
+    preferred wherever its whole-seq dq scratch fits the scoped VMEM it has
+    or asks for — up to 16,384 rows of heads up to 128 — and this pair runs
+    past that: longer sequences, wider heads, bh past a band's probes (see
+    _bwd_plan; the 2026-10-03 re-run of ``tools/vmem_sweep.py`` left it no
+    probe the combined kernel fails).  Returns (dk, dv, dq) in
+    ``grad_dtype`` (f32
     accumulation in scratch; the flush casts).  Each kernel's grid is its
     table's live tiles (`_tile_table`)."""
     bh, sl, d = q.shape
@@ -1438,10 +1536,10 @@ def _backward_blocks(q_len, k_len, d, d_v, block_q, block_k, bh, mask):
 def _flash_backward(q, k, v, out, lse, g, mask, sm_scale, block_q,
                     block_k, interpret, operands=()):
     """Pallas flash backward.  Two kernel strategies, chosen per shape by
-    :func:`_bwd_plan` against the scoped-VMEM ceiling: the combined
-    kernel computes dk/dv AND dq from a single probability recompute per
-    block (whole-seq dq scratch), the split dkdv/dq pair recomputes twice
-    but needs only O(block) scoped memory (long sequences).  Residual
+    :func:`_bwd_plan` against the scoped VMEM a kernel has or asks for: the
+    combined kernel computes dk/dv AND dq from a single probability recompute
+    per block (whole-seq dq scratch), the split dkdv/dq pair recomputes twice
+    but needs only O(block) scoped memory (past 16,384 rows).  Residual
     memory is O(seq) either way (Dao et al. alg. 2)."""
     batch, heads, q_len, d = q.shape
     k_len, d_v = k.shape[2], v.shape[-1]
@@ -1725,10 +1823,11 @@ def flash_attention(q, k, v, causal: bool = False,
     fewest bundles per pair at (1024, 1024): PERF.md section 7) and
     whole-k key blocks skip the online-softmax rescale (the kernel's
     single_k path).  The BACKWARD re-plans blocks per shape against the
-    16 MiB scoped-VMEM ceiling and switches to the split dkdv/dq kernel
-    pair for long sequences (see :func:`_bwd_plan`: a tuned block choice
-    that fits the forward need not compile for the backward at seq 8192;
-    a mask changes no plan).
+    scoped VMEM a kernel has (16 MiB without asking; at 16,384 rows the
+    combined kernel asks Mosaic for what its plan computes) and switches to
+    the split dkdv/dq kernel pair past 16,384 rows or 128 lanes (see
+    :func:`_bwd_plan`: a tuned block choice that fits the forward need not
+    compile for the backward at seq 8192; a mask changes no plan).
     """
     if layout not in ("bhsd", "bshd"):
         raise ValueError(f"unknown layout {layout!r}")

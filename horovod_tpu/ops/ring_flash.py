@@ -59,7 +59,8 @@ from jax.experimental.pallas import tpu as pltpu
 from horovod_tpu.common.metrics import kernel_trace
 from horovod_tpu.ops.attention import (NEG_INF, POS_BIG, Causal, Mask,
                                        _attend_block, _bwd_plan,
-                                       _combined_bwd_call, _finalize_flash,
+                                       _combined_bwd_call,
+                                       _combined_vmem_limit, _finalize_flash,
                                        _init_state, _pick_block, _split_scale)
 from horovod_tpu.ops.rdma import _ambient_mesh_axes, _device_id
 
@@ -532,13 +533,18 @@ def fused_ring_attention(q, k, v, axis_name: str, causal: bool = False,
     # compile time on the backward pass (ADVICE r4).
     bh = q.shape[0] * q.shape[1]
     mode, bq, bk = _bwd_plan(sl, d, bq, bk, bh)
-    if mode != "combined" or sl % bq or sl % bk:
+    # A plan whose call would ask Mosaic for more than the default (past
+    # 8,192 rows a shard) has been probed without the rotation only: refused
+    # as the split pair's shapes are.
+    asking = mode == "combined" \
+        and _combined_vmem_limit(sl, d, bq, bk) is not None
+    if mode != "combined" or sl % bq or sl % bk or asking:
         raise FusedRingUnsupported(
             f"local shard length {sl} at head_dim {d} and batch*heads "
             f"{bh} is past where the fused backward's "
             "whole-shard dq scratch fits scoped VMEM (attention._bwd_plan "
-            f"chose {mode!r}); use more ring devices, or "
-            "rotate_impl=\"ppermute\"")
+            f"chose {mode!r}{', asking for more than the default' * asking}"
+            "); use more ring devices, or rotate_impl=\"ppermute\"")
     if interpret and len(_ambient_mesh_axes(axis_name)) > 1:
         # The CPU interpreter's remote DMA only supports single-axis
         # meshes (upstream dma_start_p limitation), so a dp x sp test

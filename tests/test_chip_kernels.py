@@ -290,21 +290,32 @@ def test_banded_flash_at_trinity_shape_compiles(v5e, monkeypatch, plan):
 
 
 @pytest.mark.parametrize("window", [1024, None], ids=["band", "causal"])
-def test_split_flash_at_mellum_shape_compiles(v5e, window):
+@pytest.mark.parametrize("plan", ["combined", "split"])
+def test_flash_at_mellum_shape_compiles(v5e, monkeypatch, plan, window):
     """The mellum2 cell's attention: 1 x 32 heads of 128 at 16,384 rows, the
-    windowed layers under a window of 1,024.  The plan leaves the combined
-    backward (its whole-sequence dq scratch) for the split pair in
-    1,024-blocks, banded — a band two tiles wide, 31 tile pairs a head — and
-    causal; forward and pair compile for the described chip."""
+    windowed layers under a window of 1,024.  The combined backward the plan
+    gives the shape — (512, 512) blocks, a band three tiles wide, its
+    whole-sequence dq scratch past the 16 MiB a kernel has without asking, so
+    the call names its `vmem_limit_bytes` — and the split pair in
+    1,024-blocks (a band two tiles wide, 31 tile pairs a head), which was the
+    plan until PR 57 and is past 16,384 rows, compile for the described chip
+    beside the forward, banded and causal, each call once by name."""
     import horovod_tpu.ops.attention as attn
 
-    assert attn._bwd_plan(16384, 128, 1024, 1024, 32) == ("split", 1024, 1024)
+    assert attn._bwd_plan(16384, 128, 1024, 1024, 32) == ("combined", 512, 512)
+    assert attn._combined_vmem_limit(16384, 128, 512, 512) > 16 << 20
+    if plan == "split":
+        monkeypatch.setattr(attn, "_bwd_plan",
+                            lambda q_len, d, bq, bk, bh=1: ("split", bq, bk))
     text = _compile_flash_grad(v5e[0], (1, 32, 16384, 128), window=window)
     suffix = "_window" if window else ""
-    for kernel in ("hvd_flash_fwd", "hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"):
+    names = {"combined": ("hvd_flash_fwd", "hvd_flash_bwd"),
+             "split": ("hvd_flash_fwd", "hvd_flash_bwd_dkdv",
+                       "hvd_flash_bwd_dq")}[plan]
+    for kernel in names:
         assert len(re.findall(rf"%\w*?_{kernel}{suffix}_*\.\d+ = ",
                               text)) == 1, kernel
-    assert text.count('"tpu_custom_call"') == 3
+    assert text.count('"tpu_custom_call"') == len(names)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],

@@ -219,19 +219,21 @@ def test_vocabulary_slices_concatenate_to_the_uncut_head():
 # --- the cell's shapes, off the kernels' own tables --------------------------
 
 def test_the_cells_plan_and_counts():
-    """16,384 rows of head 128 leave the combined backward for the split pair
-    in 1,024-blocks; the 1,024-key band is two tiles wide: 31 of the causal
-    mask's 136 tile pairs, for 1,024 x 1,025 / 2 + 15,360 x 1,024 of its
+    """16,384 rows of head 128 run the combined backward in (512, 512) blocks,
+    which asks Mosaic for the scoped VMEM its plan computes (past 16,384 rows
+    the split pair); the forward stays in 1,024-blocks.  The 1,024-key band is
+    two tiles of 1,024 wide, 31 of the causal mask's 136 tile pairs, and three
+    of 512, 93 of 528, for 1,024 x 1,025 / 2 + 15,360 x 1,024 of its
     16,384 x 16,385 / 2 exact pairs (an eighth)."""
-    assert _bwd_plan(16384, 128, 1024, 1024, 32) == ("split", 1024, 1024)
+    assert _bwd_plan(16384, 128, 1024, 1024, 32) == ("combined", 512, 512)
+    assert _bwd_plan(32768, 128, 1024, 1024, 32) == ("split", 1024, 1024)
     assert _bwd_plan(8192, 128, 1024, 1024, 32)[0] == "combined"
     assert mask_blocks(16384, 128, causal=True, window=1024) == (31, 136)
     grids = flash_grid_steps(16384, 128, 32, causal=True, window=1024)
-    assert grids == {name: (31, 31, 256) for name in (
-        "hvd_flash_fwd_window", "hvd_flash_bwd_dkdv_window",
-        "hvd_flash_bwd_dq_window")}
-    assert set(flash_grid_steps(16384, 128, 32, causal=True)) == {
-        "hvd_flash_fwd", "hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"}
+    assert grids == {"hvd_flash_fwd_window": (31, 31, 256),
+                     "hvd_flash_bwd_window": (93, 93, 1024)}
+    assert flash_grid_steps(16384, 128, 32, causal=True) == {
+        "hvd_flash_fwd": (136, 136, 256), "hvd_flash_bwd": (528, 528, 1024)}
     band = ops_count_trinity.band_pairs(16384, 1024)
     assert band == 1024 * 1025 // 2 + 15360 * 1024
     assert 0.12 < band / ops_count_trinity.band_pairs(16384) < 0.13

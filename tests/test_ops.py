@@ -118,7 +118,13 @@ def test_bwd_plan_matches_vmem_calibration():
     assert _bwd_plan(2048, 64, 1024, 1024, 64) == ("combined", 1024, 1024)
     assert _bwd_plan(4096, 64, 1024, 1024, 32) == ("combined", 512, 1024)
     assert _bwd_plan(8192, 64, 1024, 1024, 16) == ("combined", 512, 512)
-    assert _bwd_plan(16384, 64, 1024, 1024, 8)[0] == "split"
+    # 16,384 rows: the combined kernel, asking Mosaic for the scoped VMEM the
+    # plan computes (`_combined_vmem_limit`), at the bh the sweep probed
+    assert _bwd_plan(16384, 64, 1024, 1024, 8) == ("combined", 512, 512)
+    assert _bwd_plan(16384, 128, 1024, 1024, 32) == ("combined", 512, 512)
+    assert _bwd_plan(16384, 128, 1024, 1024, 128) == ("combined", 512, 512)
+    assert _bwd_plan(16384, 128, 1024, 1024, 256)[0] == "split"
+    assert _bwd_plan(12288, 128, 1024, 1024, 32) == ("combined", 512, 512)
     # the bh frontier at seq 8192 (bh=64 measured 0.17 MiB over limit)
     assert _bwd_plan(8192, 64, 1024, 1024, 32)[0] == "combined"
     assert _bwd_plan(8192, 64, 1024, 1024, 64)[0] == "split"
@@ -131,7 +137,10 @@ def test_bwd_plan_matches_vmem_calibration():
     assert _bwd_plan(8192, 128, 1024, 1024, 16) == ("combined", 512, 512)
     assert _bwd_plan(1024, 256, 1024, 1024, 64)[0] == "split"
     assert _bwd_plan(4096, 256, 1024, 1024, 16)[0] == "split"
-    assert _bwd_plan(32768, 128, 1024, 1024, 8)[0] == "split"
+    assert _bwd_plan(16384, 256, 1024, 1024, 8)[0] == "split"
+    # past 16,384 rows the pair, as ever
+    assert _bwd_plan(32768, 128, 1024, 1024, 8) == ("split", 1024, 1024)
+    assert _bwd_plan(32768, 64, 1024, 1024, 32)[0] == "split"
     # plan blocks must divide the sequence even for non-pow2 lengths
     mode, bq, bk = _bwd_plan(11520, 64, 1024, 1024, 8)
     assert 11520 % bq == 0 and 11520 % bk == 0
@@ -145,13 +154,25 @@ def test_bwd_plan_fits_vmem_budget(monkeypatch):
     regression region."""
     import horovod_tpu.ops.attention as attn
 
-    for seq in (8192, 16384):
+    asked = set()
+    for seq in (8192, 16384, 32768):
         for d in (64, 128, 256):
             for bh in (8, 16, 32, 64, 256):
                 mode, bq, bk = attn._bwd_plan(seq, d, 1024, 1024, bh)
                 assert seq % bq == 0 and seq % bk == 0
+                # what the plan's call asks Mosaic for; a plan that asks for
+                # nothing (the pair never does) fits the default
+                limit = attn._combined_vmem_limit(seq, d, bq, bk) \
+                    if mode == "combined" else None
+                assert limit is None or (attn._vmem_budget_bytes() < limit
+                                         <= attn._MAX_VMEM_LIMIT)
                 assert (attn._plan_vmem_bytes(mode, seq, d, bq, bk)
-                        <= attn._vmem_budget_bytes()), (seq, d, bh, mode)
+                        <= (limit or attn._vmem_budget_bytes())), (
+                            seq, d, bh, mode)
+                if limit:
+                    asked.add((seq, d <= 128, bh <= 128))
+    # only the band that the raised limit opened asks
+    assert asked == {(16384, True, True)}
     # The measured r04 failure (combined 1024-blocks at seq 8192:
     # 23.2 MiB) must score over the default 16 MiB budget — the estimate
     # is only a guard if it rejects the shape that actually OOMed.
@@ -166,6 +187,21 @@ def test_bwd_plan_fits_vmem_budget(monkeypatch):
     assert mode == "split"
     assert (attn._plan_vmem_bytes(mode, 8192, 64, bq, bk)
             <= attn._vmem_budget_bytes())
+    # A budget under the default says the chip has less than the bands were
+    # calibrated for: the asking band is not entered, and 16,384 rows take
+    # the pair as they did before the band was there.
+    for bh in (8, 32, 128):
+        mode, bq, bk = attn._bwd_plan(16384, 128, 1024, 1024, bh)
+        assert mode == "split"
+        assert (attn._plan_vmem_bytes(mode, 16384, 128, bq, bk)
+                <= attn._vmem_budget_bytes())
+    assert attn._bwd_plan(12288, 64, 1024, 1024, 32)[0] == "split"
+    # The name bounds what a kernel has WITHOUT asking: raised past the
+    # 16,384-row plan's need, that plan's call asks for nothing.
+    assert attn._combined_vmem_limit(16384, 128, 512, 512) is not None
+    monkeypatch.setenv("HVD_TPU_VMEM_LIMIT_MB", "32")
+    assert attn._bwd_plan(16384, 128, 1024, 1024, 32)[0] == "combined"
+    assert attn._combined_vmem_limit(16384, 128, 512, 512) is None
     monkeypatch.delenv("HVD_TPU_VMEM_LIMIT_MB")
     # The forward guard: explicit oversized blocks clamp to fitting ones
     # instead of compiling a >budget kernel.
@@ -211,15 +247,20 @@ def test_flash_inside_shard_map_default_vma_check(monkeypatch, mode):
         np.testing.assert_allclose(a, b, atol=1e-3, rtol=1e-3)
 
 
-def _pallas_call_names(jaxpr):
-    """The `name` of every pallas_call equation, sub-programs included."""
-    names = []
+def _pallas_eqns(jaxpr):
+    """Every pallas_call equation, sub-programs included."""
+    eqns = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            names.append(eqn.params["name"])
+            eqns.append(eqn)
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            names += _pallas_call_names(sub)
-    return names
+            eqns += _pallas_eqns(sub)
+    return eqns
+
+
+def _pallas_call_names(jaxpr):
+    """The `name` of every pallas_call equation, sub-programs included."""
+    return [eqn.params["name"] for eqn in _pallas_eqns(jaxpr)]
 
 
 def _flash_program(grad, plan=None):
@@ -302,6 +343,65 @@ def test_flash_split_backward_matches(monkeypatch):
     g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_flash, g_ref):
         np.testing.assert_allclose(a, b, atol=1e-3, rtol=1e-3)
+
+
+@pytest.mark.parametrize("window", [None, 400], ids=["causal", "band"])
+def test_flash_combined_backward_equals_the_pair(monkeypatch, window):
+    """One plan's gradients are the other's: the combined kernel and the
+    split pair, each forced at (128, 128) blocks over 1,024 rows — a band of
+    400 keys lies across five key tiles a query tile — give `mha_reference`'s
+    dq, dk and dv, and each other's to a float32 sum's order."""
+    import horovod_tpu.ops.attention as attn
+
+    assert attn._live_tiles(1024, (128, 128), attn.Causal(window)) == (
+        36 if window is None else 8 * 5 - 10)
+    q, k, v = _qkv(batch=1, heads=2, seq=1024, d=64, seed=11)
+
+    def grads(fn):
+        return jax.grad(lambda q, k, v: (fn(q, k, v, causal=True,
+                                            window=window) ** 2).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    by_plan = {}
+    for plan in ("combined", "split"):
+        monkeypatch.setattr(attn, "_bwd_plan",
+                            lambda q_len, d, bq, bk, bh=1, plan=plan:
+                            (plan, 128, 128))
+        by_plan[plan] = grads(functools.partial(flash_attention, block_q=128,
+                                                block_k=128))
+    for one, other, ref in zip(by_plan["combined"], by_plan["split"],
+                               grads(mha_reference)):
+        np.testing.assert_allclose(one, ref, atol=1e-3, rtol=1e-3)
+        np.testing.assert_allclose(one, other, atol=1e-5, rtol=1e-5)
+
+
+def test_combined_backward_asks_for_vmem_only_past_the_default():
+    """A call whose computed need fits the budget a kernel has without asking
+    carries the compiler parameters it always carried (`vmem_limit_bytes`
+    None: the lowered text of every shape up to 8,192 rows is the parent's);
+    Mellum's 16,384 rows of 128 ask for what `_combined_vmem_limit` says, the
+    forward for nothing."""
+    import horovod_tpu.ops.attention as attn
+
+    def asked(seq, window):
+        q = jax.ShapeDtypeStruct((1, 32, seq, 128), jnp.bfloat16)
+        program = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, window=window).astype(
+                    jnp.float32).sum(), argnums=(0, 1, 2)))(q, q, q)
+        return {eqn.params["name"]: getattr(
+            eqn.params["compiler_params"].get("mosaic_tpu"),
+            "vmem_limit_bytes", None) for eqn in _pallas_eqns(program.jaxpr)}
+
+    assert asked(8192, 2048) == {"hvd_flash_fwd_window": None,
+                                 "hvd_flash_bwd_window": None}
+    assert asked(8192, None) == {"hvd_flash_fwd": None, "hvd_flash_bwd": None}
+    limit = attn._combined_vmem_limit(16384, 128, 512, 512)
+    assert attn._vmem_budget_bytes() < limit <= attn._MAX_VMEM_LIMIT
+    assert asked(16384, 1024) == {"hvd_flash_fwd_window": None,
+                                  "hvd_flash_bwd_window": limit}
+    assert asked(16384, None) == {"hvd_flash_fwd": None,
+                                  "hvd_flash_bwd": limit}
 
 
 def test_flash_nonpow2_scale_matches_reference():
@@ -557,6 +657,12 @@ def test_fused_ring_flash_oversized_shard_raises_typed(monkeypatch):
     # ring_flash binds _bwd_plan by value at import; patch its binding.
     monkeypatch.setattr(rf, "_bwd_plan", lambda *a: ("split", 128, 128))
     with pytest.raises(rf.FusedRingUnsupported, match="scoped VMEM"):
+        run(*_qkv(batch=1, heads=2, seq=4 * 32, d=16))
+    # A combined plan whose call would ask Mosaic for more than the default
+    # (16,384 rows a shard) is no shape the ring's rotation was probed at.
+    monkeypatch.setattr(rf, "_bwd_plan", lambda *a: ("combined", 32, 32))
+    monkeypatch.setattr(rf, "_combined_vmem_limit", lambda *a: 32 << 20)
+    with pytest.raises(rf.FusedRingUnsupported, match="asking for more"):
         run(*_qkv(batch=1, heads=2, seq=4 * 32, d=16))
 
 

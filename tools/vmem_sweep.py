@@ -6,7 +6,10 @@ block_k) with the chip's compiler and reports which configs fit the chip's
 scoped-VMEM ceiling — the ground truth behind
 `horovod_tpu.ops.attention._bwd_plan` (r5 calibration; the r4 regression
 was a tuned block choice that stopped compiling at seq 8192; re-run at PR 44,
-when the kernels' grids became `(bh, live tiles)` tables).  Compile-only,
+when the kernels' grids became `(bh, live tiles)` tables, and at PR 57, when
+the combined kernel began to ask Mosaic for the scoped VMEM its plan computes:
+a probe past the default budget compiles with the `vmem_limit_bytes` its call
+names, `ops.attention._combined_vmem_limit`, printed as `asks=`).  Compile-only,
 ~1-2 s per config: on the attached TPU, or, where there is none, for a
 DESCRIBED v5e (libtpu compiles for an unattached chip and refuses what the
 chip's compiler refuses; one such process at a time).
@@ -18,7 +21,7 @@ Usage: python tools/vmem_sweep.py [--full] [--cells]
   (the split pair compiles everywhere), to re-derive the plan table after a
   Mosaic/compiler update or a change of the kernels' grids;
   --cells: the benchmark's cells' shapes under their masks (causal, Trinity's
-  window, SDAR's block diffusion) at the plan's blocks.
+  and Mellum's windows, SDAR's block diffusion) at the plan's blocks.
 """
 import argparse
 import os
@@ -89,7 +92,17 @@ CELLS = [
     (8192, 128, 32, dict(causal=True)),                      # Trinity, full
     (8192, 128, 32, dict(causal=True, window=2048)),         # Trinity, band
     (8192, 128, 32, dict(block_diffusion=4)),                # SDAR
+    (16384, 128, 32, dict(causal=True)),                     # Mellum, full
+    (16384, 128, 32, dict(causal=True, window=1024)),        # Mellum, band
 ]
+
+
+def asks(mode, sl, d, bq, bk):
+    """What a combined call at these blocks asks Mosaic for, in MiB ("-": the
+    default)."""
+    limit = attn._combined_vmem_limit(sl, d, bq, bk) \
+        if mode == "combined" else None
+    return "-" if limit is None else f"{limit / (1 << 20):.1f}MiB"
 
 
 def sweep_cells(on_chip) -> int:
@@ -102,7 +115,8 @@ def sweep_cells(on_chip) -> int:
             st, dt, key = try_compile(on_chip, sl, d, *plan[1:], bh,
                                       force=force, **mask)
             print(f"d={d} sl={sl} bh={bh} {mask} plan={plan} "
-                  f"as {force}: {st} ({dt:.1f}s) {key}", flush=True)
+                  f"as {force} asks={asks(force, sl, d, *plan[1:])}: "
+                  f"{st} ({dt:.1f}s) {key}", flush=True)
             failures += st != "OK" and force == plan[0]
     return failures
 
@@ -115,8 +129,10 @@ def sweep_bands(on_chip, full: bool) -> int:
     # bench-protocol bh (token-constant seq:batch sweep) plus the band
     # edges' bh per seq: the scoped size varies non-monotonically with the
     # batch*heads grid dim (see attention._bwd_plan).
+    # 12,288 and 11,520 rows (384-blocks): inside the 16,384-row band.
     bench_bh = {1024: (128, 1024), 2048: (64, 1024), 4096: (32, 128, 512),
-                8192: (16, 32, 64, 128), 16384: (8, 128)}
+                8192: (16, 32, 64, 128), 11520: (8,), 12288: (32,),
+                16384: (8, 16, 32, 64, 128)}
     failures = 0
     for d in (64, 128):
         for sl, bhs in bench_bh.items():
@@ -124,14 +140,17 @@ def sweep_bands(on_chip, full: bool) -> int:
                 todo = [_bwd_plan(sl, d, 1024, 1024, bh)[1:]]
                 if full:
                     todo = [c for c in cands
-                            if sl % c[0] == 0 and sl % c[1] == 0]
+                            if sl % c[0] == 0 and sl % c[1] == 0
+                            ] + [c for c in todo if c not in cands]
                 for bq, bk in todo:
                     st, dt, key = try_compile(
                         on_chip, sl, d, bq, bk, bh,
                         force="combined" if full else None)
                     plan = _bwd_plan(sl, d, bq, bk, bh)
+                    ran = "combined" if full else plan[0]
                     print(f"d={d} sl={sl} bh={bh} bq={bq} bk={bk} "
-                          f"plan={plan}: {st} ({dt:.1f}s) {key}", flush=True)
+                          f"plan={plan} asks={asks(ran, sl, d, bq, bk)}: "
+                          f"{st} ({dt:.1f}s) {key}", flush=True)
                     failures += st != "OK" and not full
     return failures
 
