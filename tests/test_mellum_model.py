@@ -13,8 +13,11 @@ import pytest
 
 from benchmark import ops_count_mellum, ops_count_trinity
 from benchmark.reference import mellum_lm as reference
-from horovod_tpu.models import (MoEConfig, TransformerLM, next_token_loss,
-                                record_attention_blocks, record_expert_rows)
+from horovod_tpu.models import (DeltaConfig, IndexerConfig, LatentConfig,
+                                Mamba2Config, MoEConfig, TransformerLM,
+                                indexer_loss, looped_exit_loss,
+                                next_token_loss, record_attention_blocks,
+                                record_expert_rows)
 from horovod_tpu.models.transformer import SparseExperts, _sown
 from horovod_tpu.ops import flash_attention
 from horovod_tpu.ops.moe import GROUPED_KERNELS
@@ -51,6 +54,113 @@ def test_unset_the_pattern_lowers_to_the_parents_program():
     model = model.clone(recompute=True)
     again = jax.jit(jax.grad(loss)).lower(params, tokens).as_text()
     assert again != text and "optimization_barrier" in again
+
+
+# One small model a family of pattern whose program no digest above holds —
+# every layer kind and `post_norm`, `attn_gate`, `embed_scale`, `rotary_dim`,
+# `noised=` and `loops` between them — as `TransformerLM`'s keywords, how it
+# is called, and two digests recorded at the parent commit of PR 58 (dbb284f,
+# jax 0.9.0, before `MixerLayer` took one options value): of
+# `jax.jit(grad).lower(...).as_text()` and of the parameters seeded from
+# `PRNGKey(0)` (paths, shapes, types, bytes).  Bfloat16, as the cells run.
+FAMILY_SIZES = dict(vocab_size=256, d_model=64, n_heads=8,
+                    dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16,
+                    use_flash=False)
+FAMILIES = {
+    "nemotron": (dict(
+        layers=("ssm", "experts", "attention"), norm_eps=1e-5, rope=False,
+        n_kv_heads=2, head_shard=(0, 2),
+        ssm=Mamba2Config(heads=8, head_dim=8, groups=4, state=16, chunk=32),
+        moe=MoEConfig(16, 4, 48, (0, 4), 1.5, "sigmoid", True, 2.5, "relu2",
+                      32, 96)), "tokens"),
+    "ling": (dict(
+        layers=("delta", "latent_attention", "gated_mlp", "experts"), d_ff=96,
+        delta=DeltaConfig(heads=8, head_dim=8, chunk=32),
+        latent=LatentConfig(16, 8, 4, 8, rope_theta=6e6),
+        moe=MoEConfig(16, 4, 48, (0, 4), 1.5, "sigmoid", True, 2.5,
+                      shared_width=40, n_group=4, topk_group=2)), "tokens"),
+    "trinity": (dict(
+        layers=("window_attention", "experts", "attention", "experts"),
+        norm_eps=1e-5, rope=False, n_kv_heads=2, head_dim=16, window=32,
+        head_norm=True, attn_gate=True, post_norm=True, embed_scale=8.0,
+        moe=MoEConfig(16, 4, 48, (0, 4), 1.5, "sigmoid", True, 2.826,
+                      shared_width=48)), "tokens"),
+    "sdar": (dict(
+        layers=("blockdiff_attention", "experts"), n_kv_heads=2, head_dim=16,
+        head_norm=True, block_diffusion=4, rope_theta=1e6,
+        moe=MoEConfig(16, 4, 48, (0, 4), 1.5, renormalize=True)), "noised"),
+    "qwen3next": (dict(
+        layers=("gated_delta", "attention"), n_heads=4, n_kv_heads=2,
+        head_dim=32, head_norm=True, attn_gate=True, rope_theta=1e7,
+        rotary_dim=8,
+        delta=DeltaConfig(heads=2, head_dim=16, chunk=32, value_heads=4)),
+        "tokens"),
+    "ouro": (dict(
+        layers=("attention", "gated_mlp") * 2, n_heads=4, d_ff=96,
+        post_norm=True, rope_theta=1e6, loops=3, exit_gate=True), "targets"),
+    # The selection is the flash kernels' operand: their interpreted calls,
+    # over four times `topk` positions.
+    "keye": (dict(
+        layers=("selected_attention", "experts"), n_heads=4, n_kv_heads=2,
+        head_dim=16, head_norm=True, rope_theta=1e7, use_flash=True,
+        indexer=IndexerConfig(4, 16, 64),
+        moe=MoEConfig(16, 4, 48, (0, 4), 1.5, renormalize=True)), "tokens"),
+}
+FAMILY_DIGESTS = {
+    "nemotron": (
+        "b31d5ce8fe39680225988520b8fbae88564269c62d4d4cc366c2bea63d66ec1d",
+        "38e380a4e237e77d4a0e1740c889e39ade0b306b062f36fc6ebc00488bbdd7c2"),
+    "ling": (
+        "aedbbf55266b42d1365e7d8e0ffb658ac6cc15035adda318c6cf1857d108af88",
+        "306dbe1b09f4ec00ac24fe548fbbd391a89ecdbb4643a0eff6a7f3dd0d28fb45"),
+    "trinity": (
+        "65364aaa31e81742bc58204f99e3204d1958690aee626e8cf5fecc62c7cc7d2a",
+        "ac903589fe2ff7ef8bb18ba6b4c2ac4caac38cc680077370174e827b54f64eb3"),
+    "sdar": (
+        "226f2e273d1a677f34065ee963fa237a993d755162817fc118ef4181fcc460e8",
+        "16061fccdea8661316ab18d63dd5c720e9b87007b5839ecdf7857b2385a0ac1b"),
+    "qwen3next": (
+        "559f473414912542305d6a97bd3402d69666153c4a529e9673b10b46863af537",
+        "4139ed284cc607e2ae47c85fb96e215363f754eaf02788b1b805db03fa567bd2"),
+    "ouro": (
+        "506a976b3cd6c36f66d5acba623d61373f7e54481e37c79a7da852483ea18678",
+        "94437dba6b73d113cc9a89971c61b721329bdff0308dc32e6dbeb0e641d99dd9"),
+    "keye": (
+        "674fd4db469babe218c6b90bad614724c60abfa18a65b0bf4adeec4c86a0bc39",
+        "b93e452b2273c2ca85dfd31aa4e892bb00786b2df3aca9d5175651481a76da17"),
+}
+
+
+def family_loss(model, call, params, tokens):
+    if call == "targets":
+        return looped_exit_loss(*model.apply({"params": params}, tokens,
+                                             targets=tokens))
+    out, wrote = model.apply(
+        {"params": params}, tokens, mutable=["intermediates", "router"],
+        **({"noised": tokens} if call == "noised" else {}))
+    if model.indexer is not None:
+        return next_token_loss(out, tokens) \
+            + indexer_loss(wrote["intermediates"])
+    return next_token_loss(out, tokens)
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_a_familys_pattern_lowers_to_the_parents_program(family):
+    sizes, call = FAMILIES[family]
+    model = TransformerLM(**{**FAMILY_SIZES, **sizes})
+    tokens = jnp.zeros((2, 256 if family == "keye" else 128), jnp.int32)
+    seeded = jax.jit(lambda: model.init(
+        jax.random.PRNGKey(0), tokens,
+        **({"noised": tokens} if call == "noised" else {}))["params"])()
+    text = jax.jit(jax.grad(
+        lambda p, t: family_loss(model, call, p, t))).lower(
+            jax.eval_shape(lambda: seeded), tokens).as_text()
+    tree = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_leaves_with_path(seeded):
+        tree.update(f"{jax.tree_util.keystr(path)} {leaf.shape} "
+                    f"{leaf.dtype} ".encode() + np.asarray(leaf).tobytes())
+    assert (hashlib.sha256(text.encode()).hexdigest(), tree.hexdigest()) \
+        == FAMILY_DIGESTS[family]
 
 
 @pytest.mark.parametrize("use_flash", [False, True])
