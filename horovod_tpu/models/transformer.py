@@ -11,8 +11,10 @@ scales linearly with the ring size.
 
 from __future__ import annotations
 
+import collections
+import dataclasses
 import functools
-from typing import Any, NamedTuple, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -1155,8 +1157,8 @@ class Block(nn.Module):
 
 
 def _kept_by_a_recomputing_layer(primitive, *_, **params) -> bool:
-    """The ``jax.checkpoint`` policy of ``MixerLayer(recompute=True)``: what
-    a recomputing layer keeps of its forward pass beside its input.
+    """The ``jax.checkpoint`` policy of a :class:`MixerLayer` under
+    ``recompute``: what it keeps of its forward pass beside its input.
 
     - The router's DECISION, ``top_k``'s outputs (1 MB a layer at 16,384
       tokens).  Not for its time: computed again, the probabilities need not
@@ -1191,144 +1193,138 @@ def _kept_by_a_recomputing_layer(primitive, *_, **params) -> bool:
     return primitive.name in ("top_k", "ragged_dot_general")
 
 
-# kind -> the module a MixerLayer of that kind runs.
-LAYER_KINDS = {"ssm": "Mamba2Mixer", "attention": "Attention",
-               "experts": "SparseExperts", "delta": "DeltaMixer",
-               "latent_attention": "LatentAttention", "gated_mlp": "GatedMLP",
-               "window_attention": "Attention",
-               "blockdiff_attention": "Attention",
-               "gated_delta": "DeltaMixer",
-               "selected_attention": "Attention"}
+class LayerKind(NamedTuple):
+    """A row of :data:`LAYER_KINDS`: the ``mixer`` class a layer of the kind
+    runs, its ``arguments`` by keyword as a function of the model's
+    :class:`LayerOptions`, and the option the kind ``wants`` (is refused
+    without)."""
+
+    mixer: type
+    arguments: Callable[[Any], dict]
+    wants: Optional[str] = None
+
+
+def _attention_kind(passes=None, rotated=False, own_rope=False) -> LayerKind:
+    """The row of a kind that runs :class:`Attention` at the model's sizes
+    (``n_heads``, ``n_kv_heads``, ``head_dim``, ``head_shard``, ``qk_norm``,
+    ``head_norm``, ``attn_gate``, ``rotary_dim``).  The kinds differ in data:
+    ``passes``, the ONE of ``window`` / ``block_diffusion`` / ``indexer`` the
+    kind hands on (and wants set; the other two stay unset whatever the model
+    holds); ``rotated``, whether it turns even where the model's ``rope`` is
+    off; ``own_rope``, whether the model's ``window_rope``, a ``(theta,
+    scaling)`` pair, replaces ``rope_theta`` / ``rope_scaling`` where set."""
+    def arguments(o):
+        theta, scaling = o.window_rope \
+            if own_rope and o.window_rope is not None \
+            else (o.rope_theta, o.rope_scaling)
+        return dict(
+            n_heads=o.n_heads, dtype=o.dtype, use_flash=o.use_flash,
+            qk_norm=o.qk_norm, norm_eps=o.norm_eps, n_kv_heads=o.n_kv_heads,
+            rope=o.rope or rotated, rope_theta=theta, rope_scaling=scaling,
+            rotary_dim=o.rotary_dim, head_shard=o.head_shard,
+            head_dim=o.head_dim, head_norm=o.head_norm, gate=o.attn_gate,
+            **({passes: getattr(o, passes)} if passes else {}))
+
+    return LayerKind(Attention, arguments, wants=passes)
+
+
+def _delta_kind(gate) -> LayerKind:
+    """The row of a kind that runs :class:`DeltaMixer` of ``delta``'s sizes
+    (:class:`DeltaConfig`) under ``gate``."""
+    return LayerKind(DeltaMixer, lambda o: dict(
+        **o.delta._asdict(), gate=gate, head_shard=o.head_shard,
+        dtype=o.dtype, norm_eps=o.norm_eps))
+
+
+# The kinds of a per-layer pattern (``TransformerLM(layers=)``): what a
+# :class:`MixerLayer` of each runs between its norm and its residual.  A new
+# kind is a row here and its mixer.
+LAYER_KINDS = {
+    # A Mamba-2 mixer of ``ssm``'s sizes (:class:`Mamba2Config`).
+    "ssm": LayerKind(Mamba2Mixer, lambda o: dict(
+        **o.ssm._asdict(), head_shard=o.head_shard, dtype=o.dtype,
+        norm_eps=o.norm_eps)),
+    # Sees every earlier key and rotates where ``rope`` says, at
+    # ``rope_theta`` under ``rope_scaling``.
+    "attention": _attention_kind(),
+    # The sparse experts of ``moe`` (:class:`MoEConfig`).
+    "experts": LayerKind(SparseExperts,
+                         lambda o: dict(config=o.moe, dtype=o.dtype)),
+    # A Kimi-delta mixer under its channel gate, of ``delta``'s sizes
+    # (:class:`DeltaConfig`).
+    "delta": _delta_kind("channel"),
+    # Of ``latent``'s sizes (:class:`LatentConfig`).
+    "latent_attention": LayerKind(LatentAttention, lambda o: dict(
+        n_heads=o.n_heads, config=o.latent, dtype=o.dtype,
+        use_flash=o.use_flash, norm_eps=o.norm_eps,
+        head_shard=o.head_shard)),
+    # A dense gated MLP of ``d_ff``.
+    "gated_mlp": LayerKind(GatedMLP,
+                           lambda o: dict(d_ff=o.d_ff, dtype=o.dtype)),
+    # Under the sliding ``window`` and ALWAYS rotated — ``rope=False`` with a
+    # ``window`` is full layers without rotation among rotated windowed ones
+    # — at ``window_rope`` where that is set (a model whose full layers turn
+    # at YaRN's frequencies and whose windowed ones at the plain ones; unset,
+    # the two kinds agree).
+    "window_attention": _attention_kind("window", rotated=True,
+                                        own_rope=True),
+    # Under the ``block_diffusion`` mask over ``[clean; noised]`` rows,
+    # rotated where ``rope`` says.
+    "blockdiff_attention": _attention_kind("block_diffusion"),
+    # A Gated DeltaNet mixer, the head gate over grouped heads
+    # (``delta.value_heads`` over ``delta.heads`` key heads).
+    "gated_delta": _delta_kind("head"),
+    # Behind the model's ``indexer``, a learned selection of each query's
+    # keys (:class:`IndexerConfig`), rotated where ``rope`` says: such a
+    # model trains on ``next_token_loss + indexer_loss(intermediates)``.
+    "selected_attention": _attention_kind("indexer"),
+}
 
 
 class MixerLayer(nn.Module):
     __doc__ = (
         """One layer of a per-layer pattern (``TransformerLM(layers=)``): ONE
     mixer behind one norm and one residual, ``x + mixer(RMSNorm(x))`` — with
-    ``post_norm``, ``x + RMSNorm(mixer(RMSNorm(x)))``, the mixer's output
-    normed again (``post_norm``'s own scale) before the add; a
-    published layer of two sublayers is two consecutive entries.  ``kind``
-    and the mixer it runs: """
-        + ", ".join(f"``{kind!r}`` {module}"
-                    for kind, module in LAYER_KINDS.items())
-        + """.  ``"window_attention"`` is :class:`Attention` with the model's
-    ``window`` and ALWAYS rotated; ``"attention"`` sees every earlier key and
-    rotates where ``rope`` says: one pattern holds rotated windowed layers
-    and unrotated full ones.  ``"blockdiff_attention"`` is :class:`Attention`
-    under the model's ``block_diffusion`` mask over ``[clean; noised]`` rows,
-    rotated where ``rope`` says.  All three take ``head_dim``, ``head_norm``,
-    ``attn_gate`` and ``rotary_dim``.  ``"delta"`` is :class:`DeltaMixer`
-    under its channel gate, ``"gated_delta"`` under its head gate over grouped
-    heads, both at ``delta``'s sizes.  ``"selected_attention"`` is
-    :class:`Attention` behind the model's ``indexer``, a learned selection of
-    each query's keys (:class:`IndexerConfig`), rotated where ``rope`` says,
-    with the first three's sizes.  The rotated attention kinds turn at
-    ``rope_theta`` under ``rope_scaling``; ``window_rope``, a ``(theta,
-    scaling)`` pair, is the ``"window_attention"`` layers' own rotation where
-    it is another (a model whose full layers turn at YaRN's frequencies and
-    whose windowed ones at the plain ones).
+    ``options.post_norm``, ``x + RMSNorm(mixer(RMSNorm(x)))``, the mixer's
+    output normed again (``post_norm``'s own scale) before the add; a
+    published layer of two sublayers is two consecutive entries.  ``options``
+    is the model's :class:`LayerOptions`; ``kind`` is a key of
+    :data:`LAYER_KINDS`, whose row says what the mixer is built from: """
+        + ", ".join(f"``{kind!r}`` {row.mixer.__name__}"
+                    for kind, row in LAYER_KINDS.items())
+        + """.
 
-    ``recompute``: the layer's forward pass is computed again in the backward
-    pass (``jax.checkpoint`` around norm, mixer and residual) and only its
-    input ``x`` is kept between the two, with the router's decision and the
-    outputs of the grouped expert products and of the flash forward kernels
-    (:func:`_kept_by_a_recomputing_layer` has why).  Loss,
+    ``options.recompute``: the layer's forward pass is computed again in the
+    backward pass (``jax.checkpoint`` around norm, mixer and residual) and
+    only its input ``x`` is kept between the two, with the router's decision
+    and the outputs of the grouped expert products and of the flash forward
+    kernels (:func:`_kept_by_a_recomputing_layer` has why).  Loss,
     gradients and what the layer sows are the unset layer's:
     the same operations in the same order, sown once.""")
 
     kind: str
-    n_heads: int
-    dtype: Any = jnp.bfloat16
-    use_flash: bool = True
-    moe: Optional[MoEConfig] = None
-    ssm: Optional[Mamba2Config] = None
-    qk_norm: bool = False
-    norm_eps: float = 1e-6
-    n_kv_heads: Optional[int] = None
-    rope: bool = True
-    head_shard: Tuple[int, int] = (0, 1)
-    delta: Optional[DeltaConfig] = None
-    latent: Optional[LatentConfig] = None
-    d_ff: Optional[int] = None
-    head_dim: Optional[int] = None
-    window: Optional[int] = None
-    head_norm: bool = False
-    attn_gate: bool = False
-    post_norm: bool = False
-    block_diffusion: Optional[int] = None
-    rope_theta: float = 10000.0
-    rotary_dim: Optional[int] = None
-    rope_scaling: Optional[RopeScaling] = None
-    window_rope: Optional[Tuple[float, Optional[RopeScaling]]] = None
-    recompute: bool = False
-    indexer: Optional[IndexerConfig] = None
+    options: LayerOptions
 
     @nn.compact
     def __call__(self, x):
-        if self.recompute:
+        if self.options.recompute:
             return nn.remat(MixerLayer._forward,
                             policy=_kept_by_a_recomputing_layer)(self, x)
         return self._forward(x)
 
     @nn.nowrap
     def _forward(self, x):
-        h = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
-                       name="norm")(x)
-        if self.kind == "ssm":
-            mixer = Mamba2Mixer(*self.ssm, head_shard=self.head_shard,
-                                dtype=self.dtype, norm_eps=self.norm_eps,
-                                name="mixer")
-        elif self.kind in ("attention", "window_attention",
-                           "blockdiff_attention", "selected_attention"):
-            windowed = self.kind == "window_attention"
-            diffusion = self.kind == "blockdiff_attention"
-            selected = self.kind == "selected_attention"
-            if selected and self.indexer is None:
-                raise ValueError("a 'selected_attention' layer wants "
-                                 "indexer=")
-            if windowed and self.window is None:
-                raise ValueError("a 'window_attention' layer wants window=")
-            if diffusion and self.block_diffusion is None:
-                raise ValueError("a 'blockdiff_attention' layer wants "
-                                 "block_diffusion=")
-            theta, scaling = self.window_rope \
-                if windowed and self.window_rope is not None \
-                else (self.rope_theta, self.rope_scaling)
-            mixer = Attention(self.n_heads, self.dtype,
-                              use_flash=self.use_flash, qk_norm=self.qk_norm,
-                              norm_eps=self.norm_eps,
-                              n_kv_heads=self.n_kv_heads,
-                              rope=self.rope or windowed,
-                              rope_theta=theta, rope_scaling=scaling,
-                              rotary_dim=self.rotary_dim,
-                              head_shard=self.head_shard,
-                              head_dim=self.head_dim,
-                              window=self.window if windowed else None,
-                              head_norm=self.head_norm, gate=self.attn_gate,
-                              block_diffusion=self.block_diffusion
-                              if diffusion else None,
-                              indexer=self.indexer if selected else None,
-                              name="mixer")
-        elif self.kind == "experts":
-            mixer = SparseExperts(self.moe, self.dtype, name="mixer")
-        elif self.kind in ("delta", "gated_delta"):
-            mixer = DeltaMixer(*self.delta,
-                               gate="head" if self.kind == "gated_delta"
-                               else "channel", head_shard=self.head_shard,
-                               dtype=self.dtype, norm_eps=self.norm_eps,
-                               name="mixer")
-        elif self.kind == "latent_attention":
-            mixer = LatentAttention(self.n_heads, self.latent, self.dtype,
-                                    self.use_flash, self.norm_eps,
-                                    self.head_shard, name="mixer")
-        elif self.kind == "gated_mlp":
-            mixer = GatedMLP(self.d_ff, self.dtype, name="mixer")
-        else:
+        o = self.options
+        h = nn.RMSNorm(epsilon=o.norm_eps, dtype=o.dtype, name="norm")(x)
+        if self.kind not in LAYER_KINDS:
             raise ValueError(f"layer kind {self.kind!r} is none of "
                              f"{tuple(LAYER_KINDS)}")
-        out = mixer(h)
-        if self.post_norm:
-            out = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+        row = LAYER_KINDS[self.kind]
+        if row.wants is not None and getattr(o, row.wants) is None:
+            raise ValueError(f"a {self.kind!r} layer wants {row.wants}=")
+        out = row.mixer(**row.arguments(o), name="mixer")(h)
+        if o.post_norm:
+            out = nn.RMSNorm(epsilon=o.norm_eps, dtype=o.dtype,
                              name="post_norm")(out)
         return x + out
 
@@ -1369,40 +1365,19 @@ class TransformerLM(nn.Module):
     qk_norm: bool = False
     norm_eps: float = 1e-6
     # A per-layer pattern in place of ``n_layers`` blocks: a tuple of layer
-    # kinds (the keys of ``LAYER_KINDS``), each layer ONE mixer behind one
-    # norm and one residual (:class:`MixerLayer`) — ``"ssm"`` a Mamba-2 mixer
-    # of ``ssm``'s sizes, ``"attention"``, ``"experts"`` the sparse experts of
-    # ``moe``, ``"delta"`` a Kimi-delta mixer of ``delta``'s sizes,
-    # ``"gated_delta"`` a Gated DeltaNet mixer of ``delta``'s sizes (its
-    # ``value_heads`` over ``heads`` key heads),
-    # ``"latent_attention"`` of ``latent``'s, ``"gated_mlp"`` a dense MLP of
-    # ``d_ff``, ``"window_attention"`` attention under the sliding ``window``,
-    # always rotated.  ``n_kv_heads`` and ``rope`` are the ``"attention"``
-    # layers' as :class:`Attention` has them (so ``rope=False`` with a
-    # ``window`` is full layers without rotation among rotated windowed
-    # ones), ``rope_theta`` every rotated pattern layer's rotary base,
-    # ``rope_scaling`` its scaled frequencies (:class:`RopeScaling`) and
-    # ``rotary_dim`` how much of a head they turn; ``window_rope``, a
-    # ``(theta, scaling)`` pair, is the ``"window_attention"`` layers' own
-    # rotation where it is not the ``"attention"`` layers' (unset, the two
-    # kinds agree: one ``rope_theta`` for both),
-    # ``head_dim``, ``head_norm`` and ``attn_gate`` both attention
-    # kinds' (:class:`Attention`'s ``head_dim``, ``head_norm``, ``gate``),
-    # ``head_shard`` every head-carrying mixer's, ``post_norm`` every
-    # layer's (:class:`MixerLayer`).  ``embed_scale`` multiplies the embedding
-    # rows as they are looked up (a muP model's ``sqrt(d_model)``), patterns
-    # and blocks alike.  Unset, the model is the block above, parameter for
-    # parameter.  ``block_diffusion`` is the ``"blockdiff_attention"``
-    # layers' block length, and makes the model a block-diffusion one:
-    # ``__call__(tokens, noised=...)`` runs the pattern over ``[tokens;
-    # noised]``, both copies of every sequence in one pass of twice the
-    # positions, and gives logits for the NOISED copy alone (``final_norm`` and
-    # the head run on that half; :func:`masked_diffusion_loss` is its loss).
-    # ``recompute``: every pattern entry computes its forward pass again in
-    # the backward pass and keeps its input, its router's decision and the
-    # outputs of its grouped products and its flash forward kernel
-    # (:class:`MixerLayer`): activation memory for the time of what is
-    # computed twice.
+    # kinds, the keys of :data:`LAYER_KINDS`, whose rows say what each kind
+    # runs and which of this model's fields it reads; each layer is ONE mixer
+    # behind one norm and one residual (:class:`MixerLayer`, which has what
+    # ``post_norm`` and ``recompute`` do to every layer).  Unset, the model
+    # is the block above, parameter for parameter.  ``embed_scale``
+    # multiplies the embedding rows as they are looked up (a muP model's
+    # ``sqrt(d_model)``), patterns and blocks alike.  ``block_diffusion``,
+    # the ``"blockdiff_attention"`` layers' block length, makes the model a
+    # block-diffusion one: ``__call__(tokens, noised=...)`` runs the pattern
+    # over ``[tokens; noised]``, both copies of every sequence in one pass of
+    # twice the positions, and gives logits for the NOISED copy alone
+    # (``final_norm`` and the head run on that half;
+    # :func:`masked_diffusion_loss` is its loss).
     # A pattern trains on one sequence shard and has no cached decode: a
     # recurrent layer's state is no key/value cache.
     layers: Optional[Tuple[str, ...]] = None
@@ -1424,9 +1399,6 @@ class TransformerLM(nn.Module):
     rope_scaling: Optional[RopeScaling] = None
     window_rope: Optional[Tuple[float, Optional[RopeScaling]]] = None
     recompute: bool = False
-    # The ``"selected_attention"`` layers' learned selection of keys
-    # (:class:`IndexerConfig`; :class:`Attention`'s ``indexer``): such a model
-    # trains on ``next_token_loss + indexer_loss(intermediates)``.
     indexer: Optional[IndexerConfig] = None
     # A looped model: the pattern runs ``loops`` times over ONE set of
     # weights, ``final_norm`` after every pass, and the normed state is what
@@ -1478,20 +1450,18 @@ class TransformerLM(nn.Module):
                 "decode_ctx= (cached KV decode) composes with neither "
                 "targets= nor sequence parallelism: decode is an "
                 "inference-only, single-shard path (docs/inference.md).")
-        d_ff = self.d_ff or 4 * self.d_model
-        with jax.named_scope("hvd_embed"):
-            x = TokenEmbed(self.vocab_size, self.d_model,
-                           dtype=self.dtype, name="embed")(tokens)
-            if self.embed_scale is not None:
-                x = (x * self.embed_scale).astype(self.dtype)
+        options = self._layer_options()
+        x = self._embedded(tokens)
         new_ks, new_vs = [], []
-        for i, kind in enumerate(self.layers or ()):
-            x = self._pattern_layer(i, kind, d_ff)(x)
+        if self.layers is not None:
+            x = self._pattern(x, options)
         for i in range(0 if self.layers is not None else self.n_layers):
-            block = Block(self.n_heads, d_ff, self.dtype, self.seq_axis,
-                          self.use_flash, self.ring_impl, self.capture_kv,
-                          self.moe, self.qk_norm, self.norm_eps,
-                          name=f"layer_{i}")
+            block = Block(
+                n_heads=self.n_heads, d_ff=options.d_ff, dtype=self.dtype,
+                seq_axis=self.seq_axis, use_flash=self.use_flash,
+                ring_impl=self.ring_impl, capture_kv=self.capture_kv,
+                moe=self.moe, qk_norm=self.qk_norm, norm_eps=self.norm_eps,
+                name=f"layer_{i}")
             if decode_ctx is None:
                 x = block(x)
             else:
@@ -1502,23 +1472,11 @@ class TransformerLM(nn.Module):
             x = x[:, noised.shape[1]:]      # the clean copy is context alone
         x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                        name="final_norm")(x)
-        # Logits accumulate in float32 for a numerically stable softmax,
-        # but the matmul runs in bfloat16 on the MXU: an f32xf32 matmul
-        # costs multiple MXU passes, and the lm_head is ~1/3 of the model's
-        # FLOPs at vocab 32k.
-        w = self.param(
-            "lm_head_kernel",
-            nn.initializers.variance_scaling(1.0, "fan_in",
-                                             "truncated_normal"),
-            (self.d_model, self.vocab_size), jnp.float32)
+        w = self._head_kernel()
         if targets is not None:
             # Fused head+loss: see fused_next_token_loss.
             return fused_next_token_loss(x, w, targets, dtype=self.dtype)
-        with jax.named_scope("hvd_lm_head"):
-            logits = jnp.einsum("bsd,dv->bsv", x.astype(self.dtype),
-                                w.astype(self.dtype),
-                                preferred_element_type=jnp.float32).astype(
-                                    self.logits_dtype)
+        logits = _head_logits(x, w, self.dtype, self.logits_dtype)
         if decode_ctx is not None:
             # (n_layers, batch, heads, new_len, head_dim) each: the new
             # chunk's K/V for the caller to persist into its cache.
@@ -1526,16 +1484,39 @@ class TransformerLM(nn.Module):
         return logits
 
     @nn.nowrap
-    def _pattern_layer(self, i, kind, d_ff):
-        return MixerLayer(kind, self.n_heads, self.dtype, self.use_flash,
-                          self.moe, self.ssm, self.qk_norm, self.norm_eps,
-                          self.n_kv_heads, self.rope, self.head_shard,
-                          self.delta, self.latent, d_ff, self.head_dim,
-                          self.window, self.head_norm, self.attn_gate,
-                          self.post_norm, self.block_diffusion,
-                          self.rope_theta, self.rotary_dim,
-                          self.rope_scaling, self.window_rope,
-                          self.recompute, self.indexer, name=f"layer_{i}")
+    def _layer_options(self) -> LayerOptions:
+        """This model's fields as the one value its pattern's layers read,
+        ``d_ff`` at its default where unset."""
+        return LayerOptions(**{name: getattr(self, name)
+                               for name in LayerOptions._fields})._replace(
+                                   d_ff=self.d_ff or 4 * self.d_model)
+
+    @nn.nowrap
+    def _embedded(self, tokens):
+        with jax.named_scope("hvd_embed"):
+            x = TokenEmbed(self.vocab_size, self.d_model,
+                           dtype=self.dtype, name="embed")(tokens)
+            if self.embed_scale is not None:
+                x = (x * self.embed_scale).astype(self.dtype)
+        return x
+
+    @nn.nowrap
+    def _head_kernel(self):
+        """The head's parameter, float32: the logits accumulate in float32
+        for a numerically stable softmax, but the matmul runs in bfloat16 on
+        the MXU (an f32xf32 matmul costs multiple MXU passes, and the lm_head
+        is ~1/3 of the model's FLOPs at vocab 32k)."""
+        return self.param(
+            "lm_head_kernel",
+            nn.initializers.variance_scaling(1.0, "fan_in",
+                                             "truncated_normal"),
+            (self.d_model, self.vocab_size), jnp.float32)
+
+    @nn.nowrap
+    def _pattern(self, x, options):
+        for i, kind in enumerate(self.layers):
+            x = MixerLayer(kind, options, name=f"layer_{i}")(x)
+        return x
 
     @nn.nowrap
     def _looped(self, tokens, targets, decode_ctx, noised):
@@ -1550,17 +1531,9 @@ class TransformerLM(nn.Module):
                 "it composes with neither decode_ctx=, noised= / "
                 "block_diffusion= nor sequence parallelism, and takes "
                 "targets= only with exit_gate=True.")
-        d_ff = self.d_ff or 4 * self.d_model
-        with jax.named_scope("hvd_embed"):
-            x = TokenEmbed(self.vocab_size, self.d_model,
-                           dtype=self.dtype, name="embed")(tokens)
-            if self.embed_scale is not None:
-                x = (x * self.embed_scale).astype(self.dtype)
-        w = self.param(
-            "lm_head_kernel",
-            nn.initializers.variance_scaling(1.0, "fan_in",
-                                             "truncated_normal"),
-            (self.d_model, self.vocab_size), jnp.float32)
+        options = self._layer_options()
+        x = self._embedded(tokens)
+        w = self._head_kernel()
         gate = None
         if self.exit_gate:
             # z = w_g . h + b_g: at 1 / sqrt(d_model) an element over a normed
@@ -1574,8 +1547,7 @@ class TransformerLM(nn.Module):
                 w = w.astype(self.dtype)          # once, not once a pass
 
         def one_pass(model, x, w, gate, targets):
-            for i, kind in enumerate(model.layers):
-                x = model._pattern_layer(i, kind, d_ff)(x)
+            x = model._pattern(x, options)
             x = nn.RMSNorm(epsilon=model.norm_eps, dtype=model.dtype,
                            name="final_norm")(x)
             if gate is None:
@@ -1599,6 +1571,26 @@ class TransformerLM(nn.Module):
             self.sow("intermediates", "exit_gate_logits", out[1])
             return out
         return _head_logits(x, w, self.dtype, self.logits_dtype)
+
+
+def _model_fields_as_one_value():
+    """What a pattern's layers read of their model (``MixerLayer(kind,
+    options)``; the rows of :data:`LAYER_KINDS`): a ``namedtuple`` type of
+    every field of :class:`TransformerLM` that has a default (but flax's own
+    ``parent`` and ``name``), by its name and with its default, hashable — an
+    option is declared once, as the model's field.  The model builds the
+    value from its fields once a call, with ``d_ff`` at ``4 * d_model`` where
+    unset (``TransformerLM._layer_options``); a layer on its own takes
+    ``LayerOptions(n_heads=..., ...)`` by keyword."""
+    fields = [field for field in dataclasses.fields(TransformerLM)
+              if field.default is not dataclasses.MISSING
+              and field.name not in ("parent", "name")]
+    return collections.namedtuple(
+        "LayerOptions", [field.name for field in fields],
+        defaults=[field.default for field in fields])
+
+
+LayerOptions = _model_fields_as_one_value()
 
 
 def _head_logits(x, w, dtype, logits_dtype):

@@ -24,7 +24,8 @@ from horovod_tpu.common import metrics
 from horovod_tpu.models import (IndexerConfig, MoEConfig, TransformerLM,
                                 indexer_loss, next_token_loss,
                                 record_attention_selection)
-from horovod_tpu.models.transformer import LAYER_KINDS, MixerLayer
+from horovod_tpu.models.transformer import (LAYER_KINDS, LayerOptions,
+                                            MixerLayer)
 from horovod_tpu.ops import dsa
 from horovod_tpu.ops.attention import Selected, masked_flash_attention
 from tests.test_hybrid import (close, relative_error, spread,
@@ -234,15 +235,14 @@ def test_the_layers_shares_add_up_with_attention_counted_once():
     (counted once) and its own experts; attention's output plus the eight
     shares' expert outputs is the uncut reference's layer."""
     n = 8
-    common = dict(norm_eps=EPS)
-    attention = MixerLayer("selected_attention", HEADS, jnp.float32, True,
-                           n_kv_heads=KV_HEADS, head_dim=HEAD_DIM,
-                           head_norm=True, rope_theta=THETA, indexer=INDEXER,
-                           **common)
+    common = dict(n_heads=HEADS, dtype=jnp.float32, norm_eps=EPS)
+    attention = MixerLayer("selected_attention", LayerOptions(
+        use_flash=True, n_kv_heads=KV_HEADS, head_dim=HEAD_DIM,
+        head_norm=True, rope_theta=THETA, indexer=INDEXER, **common))
 
     def experts(shard):
-        return MixerLayer("experts", HEADS, jnp.float32, False, moe(shard),
-                          **common)
+        return MixerLayer("experts", LayerOptions(
+            use_flash=False, moe=moe(shard), **common))
 
     keys = jax.random.split(jax.random.PRNGKey(7), 3)
     x = jax.random.normal(keys[0], (1, SEQ, HIDDEN))
@@ -269,10 +269,12 @@ def test_the_layers_shares_add_up_with_attention_counted_once():
 
 
 def test_the_kinds_and_what_they_want():
-    assert LAYER_KINDS["selected_attention"] == LAYER_KINDS["attention"]
+    assert LAYER_KINDS["selected_attention"].mixer \
+        is LAYER_KINDS["attention"].mixer
     x = jnp.zeros((1, 128, HIDDEN))
     with pytest.raises(ValueError, match="indexer="):
-        MixerLayer("selected_attention", HEADS).init(jax.random.PRNGKey(0), x)
+        MixerLayer("selected_attention", LayerOptions(n_heads=HEADS)).init(
+            jax.random.PRNGKey(0), x)
     for wrong in (dict(window=8), dict(block_diffusion=4),
                   dict(use_flash=False), dict(seq_axis="sp")):
         with pytest.raises(ValueError, match="indexer= selects"):

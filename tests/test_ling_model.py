@@ -5,14 +5,17 @@ second half of tests/test_ling.py, whose sizes, helpers and tolerances it
 reads.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from benchmark.reference import ling_lm as reference
-from horovod_tpu.models import DeltaMixer, LatentAttention, TransformerLM
-from horovod_tpu.models.transformer import (LAYER_KINDS, MixerLayer,
-                                            SparseExperts)
+from horovod_tpu.models import (DeltaMixer, LatentAttention, Mamba2Config,
+                                TransformerLM)
+from horovod_tpu.models.transformer import (LAYER_KINDS, LayerOptions,
+                                            MixerLayer, SparseExperts)
 from tests.test_hybrid import (close, mixer_case, relative_error, seeded,
                                share_outputs, sides_agree, system_side,
                                trains_and_replicas_stay_equal,
@@ -78,6 +81,34 @@ def test_an_unknown_kind_is_refused_with_every_kind_named():
                           jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
     for kind in LAYER_KINDS:
         assert kind in str(refused.value) and kind in MixerLayer.__doc__
+
+
+def test_a_layers_options_are_the_models_fields_declared_once():
+    """`LayerOptions` is `TransformerLM`'s defaulted fields by name, each with
+    the model's default (a list declared twice that has drifted fails here);
+    `MixerLayer` declares none of its own; what a model hands its layers is
+    its own fields with `d_ff` resolved; and every kind's row names fields
+    its mixer has."""
+    model_fields = {field.name: field.default
+                    for field in dataclasses.fields(TransformerLM)}
+    assert set(LayerOptions._fields) < set(model_fields)
+    assert LayerOptions._field_defaults == {
+        name: model_fields[name] for name in LayerOptions._fields}
+    assert [field.name for field in dataclasses.fields(MixerLayer)][:2] \
+        == ["kind", "options"] and len(dataclasses.fields(MixerLayer)) == 4
+    model = lm()
+    options = model._layer_options()
+    assert options == LayerOptions(**{
+        name: getattr(model, name) for name in LayerOptions._fields})
+    assert model.clone(d_ff=None)._layer_options() \
+        == options._replace(d_ff=4 * HIDDEN)
+    hash(options)
+    for kind, row in LAYER_KINDS.items():
+        arguments = row.arguments(options._replace(
+            ssm=Mamba2Config(8, 8, 4, 16)))
+        assert set(arguments) <= {
+            field.name for field in dataclasses.fields(row.mixer)}, kind
+        assert row.wants is None or row.wants in arguments
 
 
 @pytest.mark.parametrize("mixer", [DeltaMixer(*DELTA, head_shard=(0, 3)),

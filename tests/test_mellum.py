@@ -32,7 +32,8 @@ import pytest
 from benchmark.reference import compare, mellum_lm as reference
 from horovod_tpu.models import (MoEConfig, RopeScaling, TransformerLM,
                                 next_token_loss)
-from horovod_tpu.models.transformer import MixerLayer, rope, yarn_frequencies
+from horovod_tpu.models.transformer import (LayerOptions, MixerLayer, rope,
+                                            yarn_frequencies)
 from tests.test_hybrid import (both_ways, close, mixer_case, reference_sides,
                                relative_error, seeded, sides_agree,
                                system_loss, system_side)
@@ -195,10 +196,10 @@ def test_base_only_rope_lowers_to_the_parents_text(rotary_dim):
 def test_an_attention_layer_of_either_kind_is_the_reference(kind, use_flash):
     """The windowed layer at the plain frequencies, the full one at YaRN's
     with its factor, one `MixerLayer` configuration for both."""
-    layer = MixerLayer(kind, HEADS, jnp.float32, use_flash, norm_eps=EPS,
-                       n_kv_heads=KV_HEADS, head_dim=HEAD_DIM, window=WINDOW,
-                       head_norm=True, rope_theta=THETA, rope_scaling=YARN,
-                       window_rope=(THETA, None))
+    layer = MixerLayer(kind, LayerOptions(
+        n_heads=HEADS, dtype=jnp.float32, use_flash=use_flash, norm_eps=EPS,
+        n_kv_heads=KV_HEADS, head_dim=HEAD_DIM, window=WINDOW, head_norm=True,
+        rope_theta=THETA, rope_scaling=YARN, window_rope=(THETA, None)))
     x, params, mix = mixer_case(layer, seed=3)
     windowed = kind == "window_attention"
 

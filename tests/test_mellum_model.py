@@ -58,8 +58,8 @@ def test_unset_the_pattern_lowers_to_the_parents_program():
 
 # One small model a family of pattern whose program no digest above holds —
 # every layer kind and `post_norm`, `attn_gate`, `embed_scale`, `rotary_dim`,
-# `noised=` and `loops` between them — as `TransformerLM`'s keywords, how it
-# is called, and two digests recorded at the parent commit of PR 58 (dbb284f,
+# `noised=` and `loops` between them — as `TransformerLM`'s keywords, and two
+# digests recorded at the parent commit of PR 58 (dbb284f,
 # jax 0.9.0, before `MixerLayer` took one options value): of
 # `jax.jit(grad).lower(...).as_text()` and of the parameters seeded from
 # `PRNGKey(0)` (paths, shapes, types, bytes).  Bfloat16, as the cells run.
@@ -67,44 +67,43 @@ FAMILY_SIZES = dict(vocab_size=256, d_model=64, n_heads=8,
                     dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16,
                     use_flash=False)
 FAMILIES = {
-    "nemotron": (dict(
+    "nemotron": dict(
         layers=("ssm", "experts", "attention"), norm_eps=1e-5, rope=False,
         n_kv_heads=2, head_shard=(0, 2),
         ssm=Mamba2Config(heads=8, head_dim=8, groups=4, state=16, chunk=32),
         moe=MoEConfig(16, 4, 48, (0, 4), 1.5, "sigmoid", True, 2.5, "relu2",
-                      32, 96)), "tokens"),
-    "ling": (dict(
+                      32, 96)),
+    "ling": dict(
         layers=("delta", "latent_attention", "gated_mlp", "experts"), d_ff=96,
         delta=DeltaConfig(heads=8, head_dim=8, chunk=32),
         latent=LatentConfig(16, 8, 4, 8, rope_theta=6e6),
         moe=MoEConfig(16, 4, 48, (0, 4), 1.5, "sigmoid", True, 2.5,
-                      shared_width=40, n_group=4, topk_group=2)), "tokens"),
-    "trinity": (dict(
+                      shared_width=40, n_group=4, topk_group=2)),
+    "trinity": dict(
         layers=("window_attention", "experts", "attention", "experts"),
         norm_eps=1e-5, rope=False, n_kv_heads=2, head_dim=16, window=32,
         head_norm=True, attn_gate=True, post_norm=True, embed_scale=8.0,
         moe=MoEConfig(16, 4, 48, (0, 4), 1.5, "sigmoid", True, 2.826,
-                      shared_width=48)), "tokens"),
-    "sdar": (dict(
+                      shared_width=48)),
+    "sdar": dict(
         layers=("blockdiff_attention", "experts"), n_kv_heads=2, head_dim=16,
         head_norm=True, block_diffusion=4, rope_theta=1e6,
-        moe=MoEConfig(16, 4, 48, (0, 4), 1.5, renormalize=True)), "noised"),
-    "qwen3next": (dict(
+        moe=MoEConfig(16, 4, 48, (0, 4), 1.5, renormalize=True)),
+    "qwen3next": dict(
         layers=("gated_delta", "attention"), n_heads=4, n_kv_heads=2,
         head_dim=32, head_norm=True, attn_gate=True, rope_theta=1e7,
         rotary_dim=8,
         delta=DeltaConfig(heads=2, head_dim=16, chunk=32, value_heads=4)),
-        "tokens"),
-    "ouro": (dict(
+    "ouro": dict(
         layers=("attention", "gated_mlp") * 2, n_heads=4, d_ff=96,
-        post_norm=True, rope_theta=1e6, loops=3, exit_gate=True), "targets"),
+        post_norm=True, rope_theta=1e6, loops=3, exit_gate=True),
     # The selection is the flash kernels' operand: their interpreted calls,
     # over four times `topk` positions.
-    "keye": (dict(
+    "keye": dict(
         layers=("selected_attention", "experts"), n_heads=4, n_kv_heads=2,
         head_dim=16, head_norm=True, rope_theta=1e7, use_flash=True,
         indexer=IndexerConfig(4, 16, 64),
-        moe=MoEConfig(16, 4, 48, (0, 4), 1.5, renormalize=True)), "tokens"),
+        moe=MoEConfig(16, 4, 48, (0, 4), 1.5, renormalize=True)),
 }
 FAMILY_DIGESTS = {
     "nemotron": (
@@ -131,29 +130,34 @@ FAMILY_DIGESTS = {
 }
 
 
-def family_loss(model, call, params, tokens):
-    if call == "targets":
+def noised_too(model, tokens):
+    """A block-diffusion model is called with the noised copy."""
+    return {} if model.block_diffusion is None else {"noised": tokens}
+
+
+def family_loss(model, params, tokens):
+    if model.loops is not None:
         return looped_exit_loss(*model.apply({"params": params}, tokens,
                                              targets=tokens))
-    out, wrote = model.apply(
+    logits, wrote = model.apply(
         {"params": params}, tokens, mutable=["intermediates", "router"],
-        **({"noised": tokens} if call == "noised" else {}))
+        **noised_too(model, tokens))
     if model.indexer is not None:
-        return next_token_loss(out, tokens) \
+        return next_token_loss(logits, tokens) \
             + indexer_loss(wrote["intermediates"])
-    return next_token_loss(out, tokens)
+    return next_token_loss(logits, tokens)
 
 
 @pytest.mark.parametrize("family", list(FAMILIES))
 def test_a_familys_pattern_lowers_to_the_parents_program(family):
-    sizes, call = FAMILIES[family]
-    model = TransformerLM(**{**FAMILY_SIZES, **sizes})
-    tokens = jnp.zeros((2, 256 if family == "keye" else 128), jnp.int32)
+    model = TransformerLM(**{**FAMILY_SIZES, **FAMILIES[family]})
+    seq = 128 if model.indexer is None else 4 * model.indexer.topk
+    tokens = jnp.zeros((2, seq), jnp.int32)
     seeded = jax.jit(lambda: model.init(
         jax.random.PRNGKey(0), tokens,
-        **({"noised": tokens} if call == "noised" else {}))["params"])()
+        **noised_too(model, tokens))["params"])()
     text = jax.jit(jax.grad(
-        lambda p, t: family_loss(model, call, p, t))).lower(
+        lambda p, t: family_loss(model, p, t))).lower(
             jax.eval_shape(lambda: seeded), tokens).as_text()
     tree = hashlib.sha256()
     for path, leaf in jax.tree_util.tree_leaves_with_path(seeded):

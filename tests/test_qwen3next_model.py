@@ -61,7 +61,8 @@ def test_pattern_has_one_norm_and_one_mixer_an_entry():
     for i, kind in enumerate(LAYERS):
         assert set(shapes[f"layer_{i}"]) == {"norm", "mixer"}
         assert set(shapes[f"layer_{i}"]["mixer"]) == mixers[kind]
-    assert LAYER_KINDS["gated_delta"] == LAYER_KINDS["delta"] == "DeltaMixer"
+    assert LAYER_KINDS["gated_delta"].mixer is LAYER_KINDS["delta"].mixer \
+        is DeltaMixer
     # The share: 4 of 16 experts, the router over all 16, the mixers whole.
     assert shapes["layer_0"]["mixer"]["A_log"].shape == (VALUE_HEADS,)
     assert shapes["layer_1"]["mixer"]["up_kernel"].shape == (4, HIDDEN, WIDTH)

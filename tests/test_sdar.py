@@ -21,7 +21,8 @@ from benchmark import ops_count_sdar
 from benchmark.reference import compare, sdar_lm as reference
 from horovod_tpu.models import (MoEConfig, TransformerLM,
                                 masked_diffusion_loss, next_token_loss)
-from horovod_tpu.models.transformer import LAYER_KINDS, Attention, MixerLayer
+from horovod_tpu.models.transformer import (LAYER_KINDS, Attention,
+                                            LayerOptions, MixerLayer)
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              mha_reference)
 from horovod_tpu.ops.attention import mask_blocks
@@ -363,10 +364,11 @@ def test_the_rotary_base_is_the_models():
 
 
 def test_the_kinds_and_what_they_want():
-    assert LAYER_KINDS["blockdiff_attention"] == "Attention"
+    assert LAYER_KINDS["blockdiff_attention"].mixer is Attention
     x = jnp.zeros((1, 2 * SEQ, HIDDEN))
     with pytest.raises(ValueError, match="block_diffusion="):
-        MixerLayer("blockdiff_attention", HEADS).init(jax.random.PRNGKey(0), x)
+        MixerLayer("blockdiff_attention", LayerOptions(n_heads=HEADS)).init(
+            jax.random.PRNGKey(0), x)
     with pytest.raises(ValueError, match="block_diffusion="):
         Attention(HEADS, block_diffusion=4, window=8).init(
             jax.random.PRNGKey(0), x)

@@ -12,7 +12,8 @@ import pytest
 
 from benchmark.reference import sdar_lm as reference
 from horovod_tpu.models import record_attention_blocks
-from horovod_tpu.models.transformer import MixerLayer, SparseExperts
+from horovod_tpu.models.transformer import (LayerOptions, MixerLayer,
+                                            SparseExperts)
 from horovod_tpu.ops import flash_attention
 from horovod_tpu.ops.attention import mask_blocks
 from tests.test_hybrid import (close, relative_error, share_outputs,
@@ -117,14 +118,14 @@ def test_the_layers_shares_add_up_with_attention_counted_once():
     once) and its own experts; attention's output plus the shares' expert
     outputs is the uncut reference's layer."""
     n = 4
-    attention = MixerLayer("blockdiff_attention", HEADS, jnp.float32, False,
-                           norm_eps=EPS, n_kv_heads=KV_HEADS,
-                           head_dim=HEAD_DIM, head_norm=True,
-                           block_diffusion=BLOCK, rope_theta=THETA)
+    common = dict(n_heads=HEADS, dtype=jnp.float32, use_flash=False,
+                  norm_eps=EPS)
+    attention = MixerLayer("blockdiff_attention", LayerOptions(
+        n_kv_heads=KV_HEADS, head_dim=HEAD_DIM, head_norm=True,
+        block_diffusion=BLOCK, rope_theta=THETA, **common))
 
     def experts(shard):
-        return MixerLayer("experts", HEADS, jnp.float32, False, moe(shard),
-                          norm_eps=EPS)
+        return MixerLayer("experts", LayerOptions(moe=moe(shard), **common))
 
     x, p_attention, _ = case(attention, seed=7)
     p_experts = spread(experts((0, 1)).init(jax.random.PRNGKey(8),

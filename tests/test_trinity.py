@@ -23,7 +23,8 @@ import pytest
 import horovod_tpu.ops.attention as attn
 from benchmark.reference import compare, trinity_lm as reference
 from horovod_tpu.models import MoEConfig, TransformerLM
-from horovod_tpu.models.transformer import LAYER_KINDS, Attention, MixerLayer
+from horovod_tpu.models.transformer import (LAYER_KINDS, Attention,
+                                            LayerOptions, MixerLayer)
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              mha_reference)
 from horovod_tpu.ops.attention import mask_blocks
@@ -272,10 +273,10 @@ def test_attention_is_the_reference(window, rope, use_flash):
 @pytest.mark.parametrize("kind", ["gated_mlp", "window_attention",
                                   "attention"])
 def test_a_post_norm_layer_is_the_reference(kind):
-    layer = MixerLayer(kind, HEADS, jnp.float32, False, norm_eps=EPS,
-                       n_kv_heads=KV_HEADS, rope=False, d_ff=D_FF,
-                       head_dim=HEAD_DIM, window=WINDOW, head_norm=True,
-                       attn_gate=True, post_norm=True)
+    layer = MixerLayer(kind, LayerOptions(
+        n_heads=HEADS, dtype=jnp.float32, use_flash=False, norm_eps=EPS,
+        n_kv_heads=KV_HEADS, rope=False, d_ff=D_FF, head_dim=HEAD_DIM,
+        window=WINDOW, head_norm=True, attn_gate=True, post_norm=True))
     x, params, mix = mixer_case(layer, seed=5)
     assert set(params) == {"norm", "mixer", "post_norm"}
     config = reference_config()
@@ -287,12 +288,13 @@ def test_a_post_norm_layer_is_the_reference(kind):
 
 
 def test_the_kinds_and_the_defaults():
-    assert LAYER_KINDS["window_attention"] == LAYER_KINDS["attention"] \
-        == "Attention"
+    assert LAYER_KINDS["window_attention"].mixer \
+        is LAYER_KINDS["attention"].mixer is Attention
     with pytest.raises(ValueError, match="window="):
-        MixerLayer("window_attention", HEADS).init(
+        MixerLayer("window_attention", LayerOptions(n_heads=HEADS)).init(
             jax.random.PRNGKey(0), jnp.zeros((1, SEQ, HIDDEN)))
-    plain = MixerLayer("attention", HEADS, jnp.float32, False)
+    plain = MixerLayer("attention", LayerOptions(
+        n_heads=HEADS, dtype=jnp.float32, use_flash=False))
     shapes = jax.eval_shape(lambda: plain.init(
         jax.random.PRNGKey(0), jnp.zeros((1, SEQ, HIDDEN)))["params"])
     assert set(shapes) == {"norm", "mixer"}
