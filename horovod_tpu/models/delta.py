@@ -51,11 +51,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.models.ssm import (_a_log_init, _dt_bias_init,
-                                    causal_depthwise_conv)
+from horovod_tpu.models.ssm import (L2_EPS, _a_log_init, _dt_bias_init,
+                                    causal_depthwise_conv, mixer_opening)
 from horovod_tpu.ops.delta_rule import chunked_delta_rule
 
-L2_EPS = 1e-6     # under the root of q's and k's norms
 # gate -> (the scopes' and the sown counter's prefix, the output gate).
 GATES = {"channel": ("kda", nn.sigmoid), "head": ("gdn", nn.silu)}
 
@@ -80,7 +79,12 @@ class DeltaMixer(nn.Module):
     """One mixer's share (module docstring), each stage under a
     ``jax.named_scope`` a trace can read, ``hvd_kda_`` (``gate="channel"``) or
     ``hvd_gdn_`` (``gate="head"``) and then: ``in_proj``,
-    ``conv`` (with the silu and the two norms), ``gate``,
+    ``conv`` (with the silu and the two norms: under the head gate
+    :func:`~horovod_tpu.models.ssm.mixer_opening`, float32 inside, which
+    hands q, k and v over ROUNDED to ``dtype``, once, and keeps for its
+    written-out backward the projection's output as stored and a float32 a
+    token and key head; under the channel gate the plain composition, whose
+    float32 q, k and v are rounded as the scan begins), ``gate``,
     ``scan`` (by stage beneath it: ``ops/delta_rule.py``),
     ``gate_norm``, ``out_proj``.  Writes ``kda_chunk_log_decay_min`` or
     ``gdn_chunk_log_decay_min`` to the ``intermediates`` collection where the
@@ -118,7 +122,6 @@ class DeltaMixer(nn.Module):
         batch, seq, d = u.shape
         key_inner, inner = heads * self.head_dim, value_heads * self.head_dim
         mixed = 2 * key_inner + inner                 # q, k, v: what conv sees
-        of_keys = (batch, seq, heads, self.head_dim)
         of_values = (batch, seq, value_heads, self.head_dim)
         # The decay's pre-activation: a channel's or a value head's.
         decays = value_heads if by_head else inner
@@ -149,16 +152,30 @@ class DeltaMixer(nn.Module):
                     projected, [mixed, mixed + inner, mixed + 2 * inner],
                     axis=-1)
         with scoped("conv"):
-            q, k, v = jnp.split(nn.silu(causal_depthwise_conv(qkv, w_conv)),
-                                [key_inner, 2 * key_inner], axis=-1)
-            q, k, v = q.reshape(of_keys), k.reshape(of_keys), \
-                v.reshape(of_values)
+            if by_head:
+                q, k, v = mixer_opening(
+                    qkv, w_conv, None,
+                    ((heads, self.head_dim, self.head_dim ** -0.5),
+                     (heads, self.head_dim, 1.0),
+                     (value_heads, self.head_dim, None)))
+            else:
+                # The composition mixer_opening replaces, float32 until the
+                # casts below: the channel form's rule cuts q, k and v into
+                # sub-blocks, and handed one stored array each it compiles
+                # to 12.8 MB more code a mixer, which Ling's step and its
+                # other programs no longer fit the machine's compile cache
+                # with (PERF.md section 7, "From PR 59").
+                q, k, v = jnp.split(
+                    nn.silu(causal_depthwise_conv(qkv, w_conv)),
+                    [key_inner, 2 * key_inner], axis=-1)
+                q, k, v = (t.reshape(batch, seq, -1, self.head_dim)
+                           for t in (q, k, v))
 
-            def unit(t):
-                return t * lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
-                                     + L2_EPS)
+                def unit(t):
+                    return t * lax.rsqrt(
+                        jnp.sum(t * t, axis=-1, keepdims=True) + L2_EPS)
 
-            q, k = unit(q) * self.head_dim ** -0.5, unit(k)
+                q, k = unit(q) * self.head_dim ** -0.5, unit(k)
         with scoped("gate"):
             pre = a.astype(jnp.float32) + dt_bias
             if by_head:
@@ -168,8 +185,9 @@ class DeltaMixer(nn.Module):
                     jnp.exp(a_log)[:, None] * pre.reshape(of_values))
             beta = nn.sigmoid(b.astype(jnp.float32))
         with scoped("scan"):
-            # The casts are the first of the rule's products inside a chunk:
-            # every operation under this scope lies in one of its stages.
+            # The channel gate's casts are the first of the rule's products
+            # inside a chunk (the head gate's q, k, v arrive rounded): every
+            # operation under this scope lies in one of its stages.
             with scoped("scan_chunk"):
                 q, k, v = (t.astype(self.dtype) for t in (q, k, v))
             o, decay_min = chunked_delta_rule(

@@ -14,17 +14,21 @@ fail every case.
 
 import functools
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from benchmark.reference import hybrid_lm as reference
 from horovod_tpu.jax.train import build_train_step
 from horovod_tpu.models import (Mamba2Config, Mamba2Mixer, MoEConfig,
                                 TransformerLM, next_token_loss)
+from horovod_tpu.models.ssm import (L2_EPS, causal_depthwise_conv,
+                                    mixer_opening)
 from horovod_tpu.models.transformer import Attention, SparseExperts
 from horovod_tpu.ops.ssm import chunked_scan
 
@@ -446,3 +450,134 @@ def mamba2_share(p, shard, n, ssm):
             "norm_scale": columns(p["norm_scale"], [inner], shard, n),
             "out_proj_kernel": columns(p["out_proj_kernel"].T, [inner],
                                        shard, n).T}
+
+
+# ---------------------------------------------------------------------------
+# The recurrent mixers' opening stage (`models.ssm.mixer_opening`) against
+# the composition the mixers ran before it: convolution, SiLU, split,
+# reshape, the unit norms, one rounding.
+# ---------------------------------------------------------------------------
+
+# Each caller's form: Mamba-2's (a bias, no norm; heads narrower than a
+# register's lanes), Gated DeltaNet's at Qwen3-Next's heads (16 key and 32
+# value heads of 128), the channel gate's (4 and 4).
+OPENINGS = {
+    "mamba2": (((8, 8, None), (4, 16, None), (4, 16, None)), True),
+    "gdn": (((16, 128, 128 ** -0.5), (16, 128, 1.0), (32, 128, None)),
+            False),
+    "kda": (((4, 16, 16 ** -0.5), (4, 16, 1.0), (4, 16, None)), False),
+}
+
+
+def composed_opening(x, taps, bias, parts, dtype):
+    """`DeltaMixer`'s and `Mamba2Mixer`'s stage as plain autodiff saw it."""
+    active = nn.silu(causal_depthwise_conv(x, taps, bias))
+    edges = np.cumsum([heads * width for heads, width, _ in parts])[:-1]
+    outputs = []
+    for t, (heads, width, unit) in zip(
+            jnp.split(active, edges.tolist(), axis=-1), parts):
+        t = t.reshape(t.shape[:2] + (heads, width))
+        if unit is not None:
+            t = t * lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
+                              + L2_EPS) * unit
+        outputs.append(t.astype(dtype))
+    return tuple(outputs)
+
+
+def opening_case(form, seq, dtype, batch=2):
+    parts, has_bias = OPENINGS[form]
+    channels = sum(heads * width for heads, width, _ in parts)
+    keys = jax.random.split(jax.random.PRNGKey(seq), 3 + len(parts))
+    x = jax.random.normal(keys[0], (batch, seq, channels)).astype(dtype)
+    taps = 0.5 * jax.random.normal(keys[1], (4, channels))
+    bias = 0.1 * jax.random.normal(keys[2], (channels,)) if has_bias else None
+    cotangents = tuple(
+        jax.random.normal(key, (batch, seq, heads, width)).astype(dtype)
+        for key, (heads, width, _) in zip(keys[3:], parts))
+    return parts, (x, taps, bias), cotangents
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("seq", [3, 4, 13])   # under, at, off the taps' 4
+@pytest.mark.parametrize("form", list(OPENINGS))
+def test_mixer_opening_is_the_composition_to_the_bit(form, seq, dtype):
+    """One primitive at a time, so that both sides are the arithmetic they
+    are written as: compiled whole, XLA's CPU backend contracts products and
+    sums differently in differently fused programs."""
+    parts, operands, _ = opening_case(form, seq, dtype)
+    with jax.disable_jit():
+        ours = mixer_opening(*operands, parts)
+        theirs = composed_opening(*operands, parts, dtype)
+    assert len(ours) == len(parts)
+    for one, two, (heads, width, _) in zip(ours, theirs, parts):
+        assert one.dtype == dtype and one.shape == (2, seq, heads, width)
+        np.testing.assert_array_equal(np.asarray(one), np.asarray(two))
+
+
+def opening_gradients(opening, operands, cotangents):
+    """(dx, d_taps[, d_bias]) of sum(outputs * cotangents), float32 sums."""
+    def scalar(*operands):
+        return sum(jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32))
+                   for o, g in zip(opening(*operands), cotangents))
+    given = tuple(i for i, t in enumerate(operands) if t is not None)
+    return jax.jit(jax.grad(scalar, argnums=given))(*operands)
+
+
+@pytest.mark.parametrize("seq", [3, 4, 13])
+@pytest.mark.parametrize("form", list(OPENINGS))
+def test_mixer_opening_gradients_are_float32s(form, seq):
+    """Against `jax.grad` of the composition run in float32: float32 operands
+    to float32 rounding; bfloat16 operands' `dx` (rounded once) no further
+    from it than the composition's own bfloat16 gradient (four roundings and
+    a bfloat16 sum), the parameters' to float32 rounding still."""
+    for dtype in (jnp.float32, jnp.bfloat16):
+        parts, operands, cotangents = opening_case(form, seq, dtype)
+        wide = (operands[0].astype(jnp.float32),) + operands[1:]
+        exact = opening_gradients(
+            lambda *o: composed_opening(*o, parts, jnp.float32), wide,
+            cotangents)
+        ours = opening_gradients(lambda *o: mixer_opening(*o, parts),
+                                 operands, cotangents)
+        theirs = opening_gradients(
+            lambda *o: composed_opening(*o, parts, dtype), operands,
+            cotangents)
+
+        def off(got, want):
+            return float(jnp.linalg.norm((got.astype(jnp.float32)
+                                          - want).ravel()))
+        assert ours[0].dtype == dtype and ours[1].dtype == jnp.float32
+        for got, want in zip(ours[1:], exact[1:]):      # d_taps, d_bias
+            assert off(got, want) <= 1e-5 * float(jnp.linalg.norm(want))
+        if dtype == jnp.float32:
+            assert off(ours[0], exact[0]) \
+                <= 1e-5 * float(jnp.linalg.norm(exact[0]))
+        else:
+            assert off(ours[0], exact[0]) <= off(theirs[0], exact[0])
+
+
+@pytest.mark.parametrize("form", list(OPENINGS))
+def test_mixer_opening_keeps_no_float32_activation(form):
+    """What the backward is handed: the input as stored, the parameters, and
+    a float32 a (token, head) of the normed parts, where plain autodiff kept
+    nine float32 arrays of the activation's size."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    parts, operands, _ = opening_case(form, 64, jnp.bfloat16)
+    given = [t for t in operands if t is not None]
+
+    def kept(opening):
+        def run(*given):
+            x, taps, bias = (*given, None)[:3]
+            return opening(x, taps, bias)
+        return [aval for aval, _ in saved_residuals(run, *given)]
+
+    batch, seq, channels = operands[0].shape
+    ours = kept(lambda *o: mixer_opening(*o, parts))
+    # Of a token's: the input in its own dtype, and a number a head.
+    assert sorted((a.shape, a.dtype) for a in ours if a.ndim > 2) == sorted(
+        [((batch, seq, channels), jnp.bfloat16)]
+        + [((batch, seq, heads, 1), jnp.float32)
+           for heads, _, unit in parts if unit is not None])
+    theirs = kept(lambda *o: composed_opening(*o, parts, jnp.bfloat16))
+    assert len([a for a in theirs if a.dtype == jnp.float32
+                and a.size >= batch * seq * channels // 4]) >= 4

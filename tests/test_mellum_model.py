@@ -60,7 +60,10 @@ def test_unset_the_pattern_lowers_to_the_parents_program():
 # every layer kind and `post_norm`, `attn_gate`, `embed_scale`, `rotary_dim`,
 # `noised=` and `loops` between them — as `TransformerLM`'s keywords, and two
 # digests recorded at the parent commit of PR 58 (dbb284f,
-# jax 0.9.0, before `MixerLayer` took one options value): of
+# jax 0.9.0, before `MixerLayer` took one options value; the lowered texts of
+# `nemotron` and `qwen3next` again at PR 59, whose Mamba-2 and head-gated
+# delta mixers open with `models.ssm.mixer_opening`: their parameters'
+# digests held, and `ling`'s channel gate kept both of its own): of
 # `jax.jit(grad).lower(...).as_text()` and of the parameters seeded from
 # `PRNGKey(0)` (paths, shapes, types, bytes).  Bfloat16, as the cells run.
 FAMILY_SIZES = dict(vocab_size=256, d_model=64, n_heads=8,
@@ -107,7 +110,7 @@ FAMILIES = {
 }
 FAMILY_DIGESTS = {
     "nemotron": (
-        "b31d5ce8fe39680225988520b8fbae88564269c62d4d4cc366c2bea63d66ec1d",
+        "e06543fe20e5cca0d88d98d2b103b1a446ff2d8055d34d6e2e70229a9168cf5a",
         "38e380a4e237e77d4a0e1740c889e39ade0b306b062f36fc6ebc00488bbdd7c2"),
     "ling": (
         "aedbbf55266b42d1365e7d8e0ffb658ac6cc15035adda318c6cf1857d108af88",
@@ -119,7 +122,7 @@ FAMILY_DIGESTS = {
         "226f2e273d1a677f34065ee963fa237a993d755162817fc118ef4181fcc460e8",
         "16061fccdea8661316ab18d63dd5c720e9b87007b5839ecdf7857b2385a0ac1b"),
     "qwen3next": (
-        "559f473414912542305d6a97bd3402d69666153c4a529e9673b10b46863af537",
+        "c676fcc5bccc1e65f46b1bba255b3c73439cfdb5b8ccc8853fd6ccb20d005b5e",
         "4139ed284cc607e2ae47c85fb96e215363f754eaf02788b1b805db03fa567bd2"),
     "ouro": (
         "506a976b3cd6c36f66d5acba623d61373f7e54481e37c79a7da852483ea18678",
