@@ -511,6 +511,10 @@ class MetricsRegistry:
         # block, key block) pairs a head's forward kernel visits, and what
         # the causal kernel would (models.record_attention_blocks).
         self._attention = {"blocks_visited": [], "blocks_causal": []}
+        # Gated DeltaNet layers with a scaled step (models.DeltaConfig(
+        # beta_scale=)): the (token, head) steps with beta over 1, and all of
+        # them, a list a layer each (models.record_delta_steps).
+        self._delta = {"beta_over_one": [], "beta_steps": []}
         # What the compiler made of the last compiled training step's
         # gradient exchange (jax/train.py `_TimedStep.exchange_overlap`),
         # and under "setup" what building its programs cost
@@ -626,6 +630,14 @@ class MetricsRegistry:
             self._attention.update(
                 {name: [int(n) for n in layer]
                  for name, layer in counts.items()})
+
+    def set_delta_steps(self, beta_over_one, beta_steps) -> None:
+        """Mirror one forward pass's count of delta-rule steps over 1, per
+        Gated DeltaNet layer with a scaled step (overwritten: one
+        batch's)."""
+        with self._lock:
+            self._delta = {"beta_over_one": [int(n) for n in beta_over_one],
+                           "beta_steps": [int(n) for n in beta_steps]}
 
     def set_train_step(self, exchange_overlap: dict, setup: dict) -> None:
         """Mirror a compiled training step's account of itself: whether it
@@ -985,6 +997,8 @@ class MetricsRegistry:
                 },
                 "attention": {name: list(blocks) for name, blocks in
                               self._attention.items()},
+                "delta": {name: list(steps) for name, steps in
+                          self._delta.items()},
                 "train_step": dict(
                     self._train_step,
                     setup=copy_step_setup(self._train_step["setup"])),
@@ -1190,6 +1204,16 @@ def prometheus_text(snapshot: dict) -> str:
                       ("causal", "blocks_causal")):
         for layer, n in enumerate(attention.get(key, [])):
             out.append(f'hvd_tpu_attention_blocks{{layer="{layer}",'
+                       f'kind="{kind}"}} {n}')
+
+    delta = snapshot.get("delta", {})
+    out.append("# HELP hvd_tpu_delta_steps (token, head) steps of one "
+               "forward pass in each Gated DeltaNet layer with a scaled "
+               "step: those whose beta is over 1, and all of them")
+    out.append("# TYPE hvd_tpu_delta_steps gauge")
+    for kind, key in (("over_one", "beta_over_one"), ("all", "beta_steps")):
+        for layer, n in enumerate(delta.get(key, [])):
+            out.append(f'hvd_tpu_delta_steps{{layer="{layer}",'
                        f'kind="{kind}"}} {n}')
 
     step = snapshot.get("train_step", {})

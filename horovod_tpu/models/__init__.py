@@ -38,6 +38,7 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     next_token_loss,
     record_attention_blocks,
     record_attention_selection,
+    record_delta_steps,
     record_exit_distribution,
     record_expert_rows,
     router_losses,

@@ -31,7 +31,13 @@ value_heads x head_dim; ``b``, ``a`` of value_heads); the lines above but
 the same recurrence with one decay for a head's every channel, which the rule
 computes in its own form (no decay broadcast over channels, ``K K^T`` and ``Q
 K^T`` once a key head).  Its stages run under ``hvd_gdn_*`` scopes where the
-channel gate's run under ``hvd_kda_*``.
+channel gate's run under ``hvd_kda_*``.  Under this gate alone,
+``value_head_dim`` gives v, z, o, the gate norm's scale and ``W_out``'s rows a
+width of their own beside q's and k's ``head_dim`` (the state is ``head_dim x
+value_head_dim``), and ``beta_scale`` makes the step ``beta = beta_scale
+sigmoid(b)``: at 2 the transition ``I - beta k k^T`` has an eigenvalue in (-1,
+1) where the plain step keeps it in (0, 1) (flash-linear-attention's
+``allow_neg_eigval``: Olmo-Hybrid's layers, 96 and 192 wide).
 
 ``head_shard=(i, n)``: this process holds heads ``[i H/n, (i+1) H/n)`` (key
 and value heads alike) — their
@@ -65,7 +71,9 @@ class DeltaConfig(NamedTuple):
     ``chunk``, the channel gate's ``lower_bound`` on a step's log-decay, and
     ``value_heads`` where they outnumber the (key) ``heads`` — a ``"delta"``
     layer's (the channel gate) or a ``"gated_delta"`` layer's (the head
-    gate)."""
+    gate).  The head gate's alone: ``value_head_dim``, a value head's width
+    where it is not ``head_dim``, and ``beta_scale``, what multiplies the
+    step's sigmoid."""
 
     heads: int
     head_dim: int
@@ -73,6 +81,8 @@ class DeltaConfig(NamedTuple):
     chunk: int = 64
     lower_bound: float = -5.0
     value_heads: Optional[int] = None
+    value_head_dim: Optional[int] = None
+    beta_scale: float = 1.0
 
 
 class DeltaMixer(nn.Module):
@@ -88,7 +98,10 @@ class DeltaMixer(nn.Module):
     ``scan`` (by stage beneath it: ``ops/delta_rule.py``),
     ``gate_norm``, ``out_proj``.  Writes ``kda_chunk_log_decay_min`` or
     ``gdn_chunk_log_decay_min`` to the ``intermediates`` collection where the
-    caller makes it mutable."""
+    caller makes it mutable, and where ``beta_scale`` is not 1 — a condition
+    of Python, so that no other layer's program gains an operation —
+    ``gdn_beta_over_one`` and ``gdn_beta_steps`` beside it: the (token,
+    value head) steps with ``beta > 1``, and all of them."""
 
     heads: int
     head_dim: int
@@ -96,6 +109,8 @@ class DeltaMixer(nn.Module):
     chunk: int = 64
     lower_bound: float = -5.0
     value_heads: Optional[int] = None
+    value_head_dim: Optional[int] = None
+    beta_scale: float = 1.0
     gate: str = "channel"
     head_shard: Tuple[int, int] = (0, 1)
     dtype: Any = jnp.bfloat16
@@ -108,9 +123,13 @@ class DeltaMixer(nn.Module):
         if self.gate not in GATES:
             raise ValueError(f"gate {self.gate!r} is none of {tuple(GATES)}")
         by_head = self.gate == "head"
-        if all_value_heads != self.heads and not by_head:
-            raise ValueError("value_heads beside heads wants gate='head': "
-                             "the channel gate's decay is a key channel's")
+        value_dim = self.value_head_dim or self.head_dim
+        if not by_head and (all_value_heads != self.heads
+                            or value_dim != self.head_dim
+                            or self.beta_scale != 1.0):
+            raise ValueError("value_heads beside heads, value_head_dim beside "
+                             "head_dim and beta_scale want gate='head': the "
+                             "channel gate's decay is a key channel's")
         if self.heads % n_shards or not 0 <= shard < n_shards \
                 or all_value_heads % self.heads:
             raise ValueError(f"head_shard {self.head_shard} does not divide "
@@ -120,9 +139,9 @@ class DeltaMixer(nn.Module):
                               all_value_heads // n_shards)
         prefix, out_gate = GATES[self.gate]
         batch, seq, d = u.shape
-        key_inner, inner = heads * self.head_dim, value_heads * self.head_dim
+        key_inner, inner = heads * self.head_dim, value_heads * value_dim
         mixed = 2 * key_inner + inner                 # q, k, v: what conv sees
-        of_values = (batch, seq, value_heads, self.head_dim)
+        of_values = (batch, seq, value_heads, value_dim)
         # The decay's pre-activation: a channel's or a value head's.
         decays = value_heads if by_head else inner
         w_in = self.param("in_proj_kernel", nn.initializers.lecun_normal(),
@@ -134,7 +153,7 @@ class DeltaMixer(nn.Module):
         dt_bias = self.param("dt_bias", _dt_bias_init, (decays,), jnp.float32)
         a_log = self.param("A_log", _a_log_init, (value_heads,), jnp.float32)
         scale = self.param("norm_scale", nn.initializers.ones,
-                           (self.head_dim,), jnp.float32)
+                           (value_dim,), jnp.float32)
         w_out = self.param("out_proj_kernel", nn.initializers.lecun_normal(),
                            (inner, d), jnp.float32)
 
@@ -157,7 +176,7 @@ class DeltaMixer(nn.Module):
                     qkv, w_conv, None,
                     ((heads, self.head_dim, self.head_dim ** -0.5),
                      (heads, self.head_dim, 1.0),
-                     (value_heads, self.head_dim, None)))
+                     (value_heads, value_dim, None)))
             else:
                 # The composition mixer_opening replaces, float32 until the
                 # casts below: the channel form's rule cuts q, k and v into
@@ -184,6 +203,12 @@ class DeltaMixer(nn.Module):
                 log_alpha = self.lower_bound * nn.sigmoid(
                     jnp.exp(a_log)[:, None] * pre.reshape(of_values))
             beta = nn.sigmoid(b.astype(jnp.float32))
+            if self.beta_scale != 1.0:
+                beta = self.beta_scale * beta
+                self.sow("intermediates", f"{prefix}_beta_over_one",
+                         jnp.sum(beta > 1.0))
+                self.sow("intermediates", f"{prefix}_beta_steps",
+                         jnp.int32(beta.size))
         with scoped("scan"):
             # The channel gate's casts are the first of the rule's products
             # inside a chunk (the head gate's q, k, v arrive rounded): every

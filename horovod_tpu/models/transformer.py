@@ -14,7 +14,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-from typing import Any, Callable, NamedTuple, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
@@ -700,6 +700,14 @@ class Attention(nn.Module):
     # is tensor-parallel over ``n`` chips, whose ``n`` outputs sum to the
     # whole layer's; the sum is the caller's.  ``(0, 1)``: the whole layer.
     head_shard: Tuple[int, int] = (0, 1)
+    # The mapped mesh axis the ``n`` head shards lie over, where the caller
+    # runs them side by side (``shard_map``): what crosses heads inside the
+    # layer is then summed over it — ``qk_norm``'s mean square, one float a
+    # token for q and one for k, so that the statistic is the WHOLE
+    # projection's.  None (one shard alone, as a one-chip share of such a
+    # layer runs): the statistic is over the heads held, the layer without
+    # its exchange.
+    head_shard_axis: Optional[str] = None
     # A head's width where it is not ``d_model // n_heads`` (the projections
     # are then ``n_heads * head_dim`` wide, not ``d_model``).
     head_dim: Optional[int] = None
@@ -988,11 +996,20 @@ class Attention(nn.Module):
 
     def _projection_norm(self, name, t):
         """RMSNorm over heads and head_dim together of ``t`` (b, heads, seq,
-        head_dim), float32 inside; the scale is (heads, head_dim)."""
+        head_dim), float32 inside; the scale is (heads, head_dim).  Under
+        ``head_shard_axis`` the sum of squares and the channel count are
+        summed over that axis: every shard divides by the mean square of all
+        the projection's channels."""
         scale = self.param(name, nn.initializers.ones,
                            (t.shape[1], t.shape[3]), jnp.float32)
         wide = t.astype(jnp.float32)
-        mean_sq = jnp.mean(jnp.square(wide), axis=(1, 3), keepdims=True)
+        if self.head_shard_axis is None:
+            mean_sq = jnp.mean(jnp.square(wide), axis=(1, 3), keepdims=True)
+        else:
+            mean_sq = lax.psum(
+                jnp.sum(jnp.square(wide), axis=(1, 3), keepdims=True),
+                self.head_shard_axis) / lax.psum(
+                    t.shape[1] * t.shape[3], self.head_shard_axis)
         return (wide * lax.rsqrt(mean_sq + self.norm_eps)
                 * scale[:, None, :]).astype(t.dtype)
 
@@ -1206,8 +1223,9 @@ class LayerKind(NamedTuple):
 
 def _attention_kind(passes=None, rotated=False, own_rope=False) -> LayerKind:
     """The row of a kind that runs :class:`Attention` at the model's sizes
-    (``n_heads``, ``n_kv_heads``, ``head_dim``, ``head_shard``, ``qk_norm``,
-    ``head_norm``, ``attn_gate``, ``rotary_dim``).  The kinds differ in data:
+    (``n_heads``, ``n_kv_heads``, ``head_dim``, ``head_shard``,
+    ``head_shard_axis``, ``qk_norm``, ``head_norm``, ``attn_gate``,
+    ``rotary_dim``).  The kinds differ in data:
     ``passes``, the ONE of ``window`` / ``block_diffusion`` / ``indexer`` the
     kind hands on (and wants set; the other two stay unset whatever the model
     holds); ``rotated``, whether it turns even where the model's ``rope`` is
@@ -1222,7 +1240,8 @@ def _attention_kind(passes=None, rotated=False, own_rope=False) -> LayerKind:
             qk_norm=o.qk_norm, norm_eps=o.norm_eps, n_kv_heads=o.n_kv_heads,
             rope=o.rope or rotated, rope_theta=theta, rope_scaling=scaling,
             rotary_dim=o.rotary_dim, head_shard=o.head_shard,
-            head_dim=o.head_dim, head_norm=o.head_norm, gate=o.attn_gate,
+            head_shard_axis=o.head_shard_axis, head_dim=o.head_dim,
+            head_norm=o.head_norm, gate=o.attn_gate,
             **({passes: getattr(o, passes)} if passes else {}))
 
     return LayerKind(Attention, arguments, wants=passes)
@@ -1281,12 +1300,21 @@ LAYER_KINDS = {
 }
 
 
+# ``post_norm`` -> (the mixer's input is normed, its output is): where a
+# pattern's layers have their norms.
+NORM_PLACEMENTS = {False: (True, False), True: (True, True),
+                   "only": (False, True)}
+
+
 class MixerLayer(nn.Module):
     __doc__ = (
         """One layer of a per-layer pattern (``TransformerLM(layers=)``): ONE
-    mixer behind one norm and one residual, ``x + mixer(RMSNorm(x))`` — with
-    ``options.post_norm``, ``x + RMSNorm(mixer(RMSNorm(x)))``, the mixer's
-    output normed again (``post_norm``'s own scale) before the add; a
+    mixer, one residual and its norms where ``options.post_norm``, a key of
+    :data:`NORM_PLACEMENTS`, puts them: ``False`` ``x + mixer(RMSNorm(x))``,
+    the pre-norm; ``True`` ``x + RMSNorm(mixer(RMSNorm(x)))``, the mixer's
+    output normed again (``post_norm``'s own scale) before the add; ``"only"``
+    ``x + RMSNorm(mixer(x))``, the output's norm alone on a mixer that reads
+    the bare residual stream (the parameter tree then has no ``norm``); a
     published layer of two sublayers is two consecutive entries.  ``options``
     is the model's :class:`LayerOptions`; ``kind`` is a key of
     :data:`LAYER_KINDS`, whose row says what the mixer is built from: """
@@ -1294,7 +1322,10 @@ class MixerLayer(nn.Module):
                     for kind, row in LAYER_KINDS.items())
         + """.
 
-    ``options.recompute``: the layer's forward pass is computed again in the
+    ``options.recompute`` — ``True``, every layer; a tuple of kinds, the
+    layers of those kinds alone (a job that has the memory for its MLPs'
+    activations and not for its mixers' recomputes the mixers): the layer's
+    forward pass is computed again in the
     backward pass (``jax.checkpoint`` around norm, mixer and residual) and
     only its input ``x`` is kept between the two, with the router's decision
     and the outputs of the grouped expert products and of the flash forward
@@ -1307,7 +1338,8 @@ class MixerLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        if self.options.recompute:
+        chosen = self.options.recompute
+        if chosen is True or (chosen and self.kind in chosen):
             return nn.remat(MixerLayer._forward,
                             policy=_kept_by_a_recomputing_layer)(self, x)
         return self._forward(x)
@@ -1315,7 +1347,12 @@ class MixerLayer(nn.Module):
     @nn.nowrap
     def _forward(self, x):
         o = self.options
-        h = nn.RMSNorm(epsilon=o.norm_eps, dtype=o.dtype, name="norm")(x)
+        if o.post_norm not in NORM_PLACEMENTS:
+            raise ValueError(f"post_norm {o.post_norm!r} is none of "
+                             f"{tuple(NORM_PLACEMENTS)}")
+        normed_in, normed_out = NORM_PLACEMENTS[o.post_norm]
+        h = nn.RMSNorm(epsilon=o.norm_eps, dtype=o.dtype,
+                       name="norm")(x) if normed_in else x
         if self.kind not in LAYER_KINDS:
             raise ValueError(f"layer kind {self.kind!r} is none of "
                              f"{tuple(LAYER_KINDS)}")
@@ -1323,7 +1360,7 @@ class MixerLayer(nn.Module):
         if row.wants is not None and getattr(o, row.wants) is None:
             raise ValueError(f"a {self.kind!r} layer wants {row.wants}=")
         out = row.mixer(**row.arguments(o), name="mixer")(h)
-        if o.post_norm:
+        if normed_out:
             out = nn.RMSNorm(epsilon=o.norm_eps, dtype=o.dtype,
                              name="post_norm")(out)
         return x + out
@@ -1368,7 +1405,8 @@ class TransformerLM(nn.Module):
     # kinds, the keys of :data:`LAYER_KINDS`, whose rows say what each kind
     # runs and which of this model's fields it reads; each layer is ONE mixer
     # behind one norm and one residual (:class:`MixerLayer`, which has what
-    # ``post_norm`` and ``recompute`` do to every layer).  Unset, the model
+    # ``post_norm`` — ``False``, ``True`` or ``"only"``: where a layer's norms
+    # stand — and ``recompute`` do to every layer).  Unset, the model
     # is the block above, parameter for parameter.  ``embed_scale``
     # multiplies the embedding rows as they are looked up (a muP model's
     # ``sqrt(d_model)``), patterns and blocks alike.  ``block_diffusion``,
@@ -1385,20 +1423,21 @@ class TransformerLM(nn.Module):
     n_kv_heads: Optional[int] = None
     rope: bool = True
     head_shard: Tuple[int, int] = (0, 1)
+    head_shard_axis: Optional[str] = None
     delta: Optional[DeltaConfig] = None
     latent: Optional[LatentConfig] = None
     head_dim: Optional[int] = None
     window: Optional[int] = None
     head_norm: bool = False
     attn_gate: bool = False
-    post_norm: bool = False
+    post_norm: Union[bool, str] = False
     embed_scale: Optional[float] = None
     block_diffusion: Optional[int] = None
     rope_theta: float = 10000.0
     rotary_dim: Optional[int] = None
     rope_scaling: Optional[RopeScaling] = None
     window_rope: Optional[Tuple[float, Optional[RopeScaling]]] = None
-    recompute: bool = False
+    recompute: Union[bool, Tuple[str, ...]] = False
     indexer: Optional[IndexerConfig] = None
     # A looped model: the pattern runs ``loops`` times over ONE set of
     # weights, ``final_norm`` after every pass, and the normed state is what
@@ -1876,6 +1915,25 @@ def record_attention_selection(intermediates) -> dict:
             for kind in SELECTION_COUNTS}
     if _metrics.registry.enabled:
         _metrics.registry.set_attention_selection(**seen)
+    return seen
+
+
+def record_delta_steps(intermediates) -> dict:
+    """Read what the Gated DeltaNet layers with a scaled step
+    (``DeltaConfig(beta_scale=)`` other than 1) counted into the
+    ``intermediates`` collection of one ``apply(..., mutable=
+    ["intermediates"])`` — outside the compiled step — and, when the metrics
+    registry is on (``HVD_TPU_METRICS=1``), mirror it into
+    ``hvd.metrics_snapshot()["delta"]``.  A list a such layer, over the
+    batch: ``beta_over_one`` the (token, value head) steps with ``beta > 1``
+    of ``beta_steps``.  A model without such a layer gives two empty
+    lists."""
+    from horovod_tpu.common import metrics as _metrics
+
+    seen = {kind: [int(n) for n in _sown(intermediates, "gdn_" + kind)]
+            for kind in ("beta_over_one", "beta_steps")}
+    if _metrics.registry.enabled:
+        _metrics.registry.set_delta_steps(**seen)
     return seen
 
 

@@ -66,6 +66,10 @@ decay's from the float32 state.  Nothing is written a value head that is a
 key head's, no state is moved by a ``dynamic-update-slice``, and there is no
 product over all chunks outside the kernels.  ``carried`` may be one decay a
 head, (…, 1), or one a channel, (…, d_k): the kernels take either by shape.
+``d_k`` and ``d_v`` differ where a model's do (Olmo-Hybrid: 96 and 192): a
+block is its true width, and what Mosaic leaves empty of a 128-lane tile — a
+quarter of every q, k, w block and of every v, U, O block and state — is
+computed and moved with the rest.
 
 ``k_s / G_s`` is never formed: over a chunk of 64 tokens at the gate's bound of
 -5 a step it is ``e^320``.  A chunk is cut into sub-blocks of
@@ -629,7 +633,19 @@ def chunked_delta_rule(q, k, v, log_alpha, beta, chunk: int,
 
     A decay a head: ``q``, ``k`` (batch, seq, key heads, d_k); ``v`` (batch,
     seq, value heads, d_v); ``log_alpha`` and ``beta`` (batch, seq, value
-    heads) float32, ``log_alpha <= 0`` without a bound; any ``seq``.
+    heads) float32, ``log_alpha <= 0`` without a bound; any ``seq``.  ``d_k``
+    and ``d_v`` are each their own and need be no multiple of a lane tile: the
+    kernels' blocks are the true widths (Mosaic compiles 96 and 192, laid out
+    in 128 and 256 lanes).  ``beta`` in [0, 2]: the step appears in ``A`` and
+    in the right-hand sides alone, and the solve is a forward substitution in
+    full precision, which a strictly lower ``A`` twice as large does not
+    trouble (``(I + A)^-1`` exists whatever ``A``'s size, and with unit keys
+    its entries stay of order one) — checked against the recurrence one step
+    a token with steps from 0.1 to past 1.9, values and every gradient, at
+    d_k 12 / d_v 24 and at 96 / 192
+    (``tests/test_olmohybrid.py::test_rule_at_two_widths_and_steps_past_one_is_the_recurrence``,
+    ``::test_steps_past_one_are_as_exact_as_steps_under_it``).  A decay a
+    channel was checked on ``beta`` in [0, 1] alone.
 
     ``scope``: the stages' names start with it.  Returns ``(o,
     chunk_log_decay_min)``: ``o`` float32 (batch, seq, value heads, d_v), and

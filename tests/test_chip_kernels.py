@@ -69,28 +69,45 @@ def test_flash_head128_at_olmoe_shape_compiles(v5e):
     assert text.count("tpu_custom_call") == 2
 
 
-def test_delta_rule_carry_kernels_at_qwen3next_widths_compile(v5e,
-                                                              monkeypatch):
-    """The head form's recurrence as the Qwen3-Next cell runs it — heads of
-    128 channels, two value heads a key head, chunks of 64, bfloat16 — through
-    the chip's compiler, forward and backward: two Mosaic kernels and no
-    loop, as `lowered_plan` says (the rule asks the backend which way to run
-    its kernels; here it is compiling for the described chip)."""
+def _compile_delta_rule_grad(device, key_heads, value_heads, d_k, d_v,
+                             seq=512):
+    """The compiled text of the head form's rule and its five gradients at
+    these heads and widths, bfloat16, chunks of 64, for the described chip."""
     from jax.sharding import SingleDeviceSharding
 
-    from horovod_tpu.ops.delta_rule import chunked_delta_rule, lowered_plan
+    from horovod_tpu.ops.delta_rule import chunked_delta_rule
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    on_chip = SingleDeviceSharding(v5e[0])
-    q = jax.ShapeDtypeStruct((1, 512, 2, 128), jnp.bfloat16, sharding=on_chip)
-    v = jax.ShapeDtypeStruct((1, 512, 4, 128), jnp.bfloat16, sharding=on_chip)
-    gate = jax.ShapeDtypeStruct((1, 512, 4), jnp.float32, sharding=on_chip)
+    on_chip = SingleDeviceSharding(device)
+    q = jax.ShapeDtypeStruct((1, seq, key_heads, d_k), jnp.bfloat16,
+                             sharding=on_chip)
+    v = jax.ShapeDtypeStruct((1, seq, value_heads, d_v), jnp.bfloat16,
+                             sharding=on_chip)
+    gate = jax.ShapeDtypeStruct((1, seq, value_heads), jnp.float32,
+                                sharding=on_chip)
 
     def loss(*operands):
         return chunked_delta_rule(*operands, 64, scope="hvd_gdn_scan")[0].sum()
 
-    text = jax.jit(jax.grad(loss, range(5))).lower(
+    return jax.jit(jax.grad(loss, range(5))).lower(
         q, q, v, gate, gate).compile().as_text()
+
+
+@pytest.mark.parametrize("key_heads,value_heads,d_k,d_v", [
+    (2, 4, 128, 128), (15, 15, 96, 192)], ids=["qwen3next", "olmohybrid"])
+def test_delta_rule_carry_kernels_at_the_cells_widths_compile(
+        v5e, monkeypatch, key_heads, value_heads, d_k, d_v):
+    """The head form's recurrence as the two cells with Gated DeltaNet run it
+    — Qwen3-Next's heads of 128 channels, two value heads a key head; Olmo-
+    Hybrid's 15 heads with keys of 96 and values of 192, three quarters of a
+    lane tile and one and a half, blocks at the TRUE widths with nothing
+    padded by us (Mosaic lays a 96-wide block out in 128 lanes) — through the
+    chip's compiler, forward and backward: two Mosaic kernels and no loop, as
+    `lowered_plan` says (the rule asks the backend which way to run its
+    kernels; here it is compiling for the described chip)."""
+    from horovod_tpu.ops.delta_rule import lowered_plan
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compile_delta_rule_grad(v5e[0], key_heads, value_heads, d_k, d_v)
     plan = lowered_plan(512, 64)
     assert text.count("custom_call_target=\"tpu_custom_call\"") \
         == plan["tpu_custom_call"] == 2

@@ -258,8 +258,17 @@ _GDN_STAGE = re.compile(r"(?:^|/)hvd_gdn_scan_(%s)(?=/|$)"
                         % "|".join(_layers.STAGES))
 
 
-def test_gated_delta_layers_name_their_stages_under_scopes_of_their_own():
-    """The Qwen3-Next cell's step: every heavy operation of a `gated_delta`
+@pytest.mark.parametrize("cell,others", [
+    ("qwen3next80b_1chip_ep16share_1x4k",
+     {"head", "embed", "attn_proj", "moe"}),
+    ("olmohybrid7b_1chip_tp2share_1x8k",
+     {"head", "embed", "attn_proj", "mlp"})])
+def test_gated_delta_layers_name_their_stages_under_scopes_of_their_own(
+        cell, others):
+    """The Qwen3-Next cell's step, and Olmo-Hybrid's (the same mixer at a key
+    and a value width of their own, a scaled step, the output's norm alone —
+    no scope of its own: the reordered norm is a layer's norm like any
+    other's): every heavy operation of a `gated_delta`
     layer lies under exactly ONE of the six `hvd_gdn_*` scopes, forward and
     backward, and under no `hvd_kda_*` one (those stay Ling's); the delta
     rule's four stages partition `hvd_gdn_scan`, down to its casts; every other
@@ -268,8 +277,7 @@ def test_gated_delta_layers_name_their_stages_under_scopes_of_their_own():
     `gdn_` row, and this PR may not edit that file: `model_unscoped_pct`
     would file the mixers under `unscoped`, so the new cell is not on that
     metric's list (PERF.md section 7 names the edit)."""
-    text = lowered_step("qwen3next80b_1chip_ep16share_1x4k").as_text(
-        debug_info=True)
+    text = lowered_step(cell).as_text(debug_info=True)
     inside = [(op, path) for op, path in heavy_operations(text)
               if FORWARD in path or BACKWARD in path]
     seen = {direction: set() for direction in (FORWARD, BACKWARD)}
@@ -291,7 +299,7 @@ def test_gated_delta_layers_name_their_stages_under_scopes_of_their_own():
             stage = set(_GDN_STAGE.findall(path))
             assert len(stage) == 1, (op, path)
             stages[direction] |= stage
-    assert families == {"head", "embed", "attn_proj", "moe"}
+    assert families == others
     # The products: both projections, the rule's; the gate and the norm are
     # elementwise and hold none.
     for direction in (FORWARD, BACKWARD):

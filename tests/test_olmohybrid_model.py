@@ -1,0 +1,357 @@
+"""Olmo-Hybrid's whole model through models.TransformerLM (Gated DeltaNet at
+two widths with steps up to 2, full attention with a q/k norm over the whole
+projection and no rotary, a dense gated MLP behind every mixer, every entry
+under the output's norm alone) against the plain float32 reference the
+benchmark keeps (benchmark/reference/olmohybrid_lm.py), at the sizes the
+cell's rehearsal runs; its layers are tests/test_olmohybrid.py's.  CPU,
+float32, seeded weights.
+
+Tolerances: as tests/test_olmohybrid.py — float32 rounding accumulated over
+eight pattern entries, 2e-5 of the largest value for the loss and 1e-4 for the
+gradients (the chunked rule's solve).
+"""
+
+import functools
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+
+from benchmark import ops_count_olmohybrid
+from benchmark.builders import olmohybrid_lm as builder
+from benchmark.layer_metrics import (_olmohybrid, gdn_beta_over_one_pct,
+                                     gdn_kdv_scan_roofline)
+from benchmark.reference import olmohybrid_lm as reference
+from horovod_tpu.models.transformer import LAYER_KINDS
+from tests.test_hybrid import (close, columns, relative_error, seeded,
+                               system_loss, trains_and_replicas_stay_equal,
+                               trees_close, vocabulary_slices_concatenate,
+                               with_highest)
+from tests.test_olmohybrid import attention_share
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(ROOT, "benchmark", "configs",
+                       "olmohybrid7b.json")) as f:
+    PUBLISHED = json.load(f)
+# The cell's rehearsal: the published configuration at the sizes run.py
+# --rehearse walks (heads 0-1 of 4, of both mixers).
+CONFIG = dict(PUBLISHED, **PUBLISHED["rehearsal"])
+VOCAB, HIDDEN = CONFIG["vocab_size"], CONFIG["hidden_size"]
+HEADS, KEY_DIM, VALUE_DIM = (CONFIG["linear_num_key_heads"],
+                             CONFIG["linear_key_head_dim"],
+                             CONFIG["linear_value_head_dim"])
+
+
+@functools.cache
+def lm(tensor_shard=tuple(CONFIG["tensor_shard"]), vocab=VOCAB, axis=None,
+       use_flash=False):
+    """(the builder's model at the rehearsal's sizes, its kinds): on the
+    blockwise attention unless told otherwise — side by side under `axis` and
+    under `build_train_step` the flash kernels, which the interpreter runs
+    here (the blockwise scan's carry does not vary over a mesh axis)."""
+    model, kinds = builder.model_of(dict(
+        CONFIG, tensor_shard=list(tensor_shard), vocab_size=vocab))
+    return model.clone(use_flash=use_flash or axis is not None,
+                       head_shard_axis=axis), kinds
+
+
+def reference_config(**more):
+    return dict(builder.reference_config_of(CONFIG, lm()[1]), **more)
+
+
+@functools.cache
+def case(tensor_shard=tuple(CONFIG["tensor_shard"])):
+    return seeded(lm(tensor_shard)[0], vocab=VOCAB)
+
+
+@functools.cache
+def system_side(tensor_shard=tuple(CONFIG["tensor_shard"])):
+    params, batch = case(tensor_shard)
+    return jax.jit(jax.value_and_grad(functools.partial(
+        system_loss, lm(tensor_shard)[0])))(params, batch)
+
+
+@functools.cache
+def reference_side(tensor_shard=tuple(CONFIG["tensor_shard"]), **more):
+    params, batch = case(tensor_shard)
+    return with_highest(jax.value_and_grad(
+        lambda p, b: reference.loss(p, b, **reference_config(**more))))(
+            params, batch)
+
+
+# --- the model is the reference ---------------------------------------------
+
+def test_the_pattern_is_the_published_period():
+    model, kinds = lm()
+    assert kinds == ("gated_delta", "gated_mlp") * 3 + ("attention",
+                                                        "gated_mlp")
+    assert builder.layer_kinds(dict(PUBLISHED)) == kinds
+    params, _ = case()
+    assert set(params) == {"embed", "final_norm", "lm_head_kernel"} | {
+        f"layer_{i}" for i in range(8)}
+    for i, kind in enumerate(kinds):       # the output's norm and no other
+        assert set(params[f"layer_{i}"]) == {"mixer", "post_norm"}, kind
+    held = HEADS // 2
+    assert params["layer_0"]["mixer"]["in_proj_kernel"].shape == (
+        HIDDEN, held * (2 * KEY_DIM + 2 * VALUE_DIM + 2))
+    assert params["layer_6"]["mixer"]["q_norm_scale"].shape == (
+        CONFIG["num_attention_heads"] // 2,
+        HIDDEN // CONFIG["num_attention_heads"])
+    assert "qkv_kernel" not in params["layer_6"]["mixer"]
+
+
+def test_loss_and_gradients_are_the_references():
+    """The share the cell runs (heads 0-1 of 4, the local q/k statistic):
+    the reference given the same tree."""
+    (got, got_grads), (want, want_grads) = system_side(), reference_side()
+    np.testing.assert_allclose(got, want, rtol=2e-5)
+    trees_close(got_grads, want_grads, 1e-4)
+    for leaf in jax.tree.leaves(got_grads):
+        assert float(jnp.abs(leaf).max()) > 0.0
+
+
+def test_the_uncut_model_is_the_reference():
+    whole = (0, 1)
+    (got, got_grads), (want, want_grads) = (system_side(whole),
+                                            reference_side(whole))
+    np.testing.assert_allclose(got, want, rtol=2e-5)
+    # Twice the heads through three chunked rules with steps to 2: W_in's
+    # gradient reads 1.5e-4 of its largest element where the share's reads
+    # under 1e-4 (float32 on both sides; bfloat16 would read 1e-2).
+    trees_close(got_grads, want_grads, 3e-4)
+
+
+def test_the_builders_rows_pass_and_group_the_layer_kinds():
+    """benchmark/builders/olmohybrid_lm.py's own comparison at this size:
+    every row far inside its limit, a group of gradients a layer kind."""
+    params, batch = case()
+    loss_s, grads_s = system_side()
+    rows = builder.compare_rows(loss_s, with_highest(functools.partial(
+        builder.against_reference, reference_config()))(params, batch,
+                                                        grads_s))
+    assert [row["name"] for row in rows] == [
+        "loss_rel_error", "grad_norm_rel_error"] + [
+        f"{group}_grad_rel_l2_error" for group in builder.GROUPS]
+    assert all(row["value"] <= 1e-2 * row["limit"] for row in rows), rows
+    assert min(row["reference"] for row in rows[2:]) > 0.0
+    assert [builder.group_of(name, lm()[1]) for name in (
+        "layer_0", "layer_1", "layer_6", "final_norm", "lm_head_kernel",
+        "embed")] == ["gated_delta", "gated_mlp", "attention", "head",
+                      "head", "embedding"]
+
+
+@pytest.mark.parametrize("control,group", [
+    (dict(operand_dtype=jnp.float8_e4m3fn), "gated_mlp"),
+    (dict(state_dtype=jnp.bfloat16), "gated_delta")])
+def test_the_reference_in_a_lower_precision_reads_wrong(control, group):
+    """What the cell's limits are read against: every matmul operand at
+    float8, and the recurrence's state rounded to bfloat16 a token, move the
+    group's gradient by more than float32's rounding by orders."""
+    _, exact = reference_side()
+    _, rounded = reference_side(**control)
+    kinds = lm()[1]
+    names = [n for n in exact if builder.group_of(n, kinds) == group]
+    off = float(relative_error({n: rounded[n] for n in names},
+                               {n: exact[n] for n in names}))
+    assert off > (0.05 if "operand_dtype" in control else 1e-3), off
+
+
+def test_vocabulary_slices_concatenate():
+    vocabulary_slices_concatenate(
+        lambda vocab=VOCAB: lm(vocab=vocab)[0], 8, VOCAB)
+
+
+def test_trains_through_build_train_step():
+    params, batch = case()
+    # A copy: the step donates its state, and `case()` is every test's.
+    trains_and_replicas_stay_equal(lm(use_flash=True)[0],
+                                   jax.tree.map(jnp.copy, params), batch)
+
+
+def test_the_kinds_named_recompute_and_no_other():
+    """`recompute=("gated_delta",)`, what the cell runs: the three Gated
+    DeltaNet entries under `jax.checkpoint`, the MLPs and attention not; loss
+    and gradients are the plain model's (the same operations, once more)."""
+    model, kinds = lm()
+    assert model.recompute == ("gated_delta",) \
+        == builder.recomputed(PUBLISHED)
+    assert builder.recomputed(dict(PUBLISHED, recompute_layers=False)) \
+        is False
+    params, batch = case()
+    grad = jax.value_and_grad(functools.partial(system_loss, model))
+    jaxpr = str(jax.make_jaxpr(grad)(params, batch))
+    assert jaxpr.count("remat2[") == kinds.count("gated_delta") == 3
+    plain = model.clone(recompute=False)
+    assert "remat2[" not in str(jax.make_jaxpr(jax.value_and_grad(
+        functools.partial(system_loss, plain)))(params, batch))
+    (got, got_grads) = system_side()
+    want, want_grads = jax.jit(jax.value_and_grad(functools.partial(
+        system_loss, plain)))(params, batch)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    trees_close(got_grads, want_grads, 1e-4)    # XLA fuses the two its own way
+
+
+# --- the shares add up to the uncut layer -----------------------------------
+
+def gated_delta_share(p, shard, n):
+    keys, values = HEADS * KEY_DIM, HEADS * VALUE_DIM
+
+    def heads(v, width=HEADS):
+        return columns(v, [width], shard, n)
+
+    return {"in_proj_kernel": columns(
+                p["in_proj_kernel"],
+                [keys, keys, values, values, HEADS, HEADS], shard, n),
+            "conv_kernel": columns(p["conv_kernel"], [keys, keys, values],
+                                   shard, n),
+            "dt_bias": heads(p["dt_bias"]), "A_log": heads(p["A_log"]),
+            "norm_scale": p["norm_scale"],               # one for every head
+            "out_proj_kernel": heads(p["out_proj_kernel"].T, values).T}
+
+
+SHARES = {"gated_delta": gated_delta_share, "attention": attention_share}
+
+
+def test_the_two_head_shares_add_up_to_the_uncut_reference_layer_for_layer():
+    """The share tied to the model.  The uncut reference walks the eight
+    entries; beside it, each mixer runs as its two head shares side by side
+    under an axis name — so that the q/k statistic is the whole projection's
+    — and their outputs are summed; the output's norm, the residual and the
+    MLP are counted once.  After EVERY entry the two agree.  (Without the
+    axis name each share is the reference given the same share:
+    `test_loss_and_gradients_are_the_references`, and in
+    tests/test_olmohybrid.py the layer alone.)"""
+    params, (tokens, _) = case((0, 1))
+    model, kinds = lm((0, 1))
+    options = lm((0, 2), axis="tensor")[0]._layer_options()
+    mesh = Mesh(np.array(jax.devices()[:2]), ("tensor",))
+    config = {k: v for k, v in reference_config().items() if k != "layers"}
+
+    def mixer_of_shares(kind, p, x):
+        row = LAYER_KINDS[kind]
+        shares = jax.tree.map(lambda *leaves: jnp.stack(leaves),
+                              *(SHARES[kind](p, i, 2) for i in range(2)))
+
+        def local(share, x):
+            out = row.mixer(**row.arguments(options)).apply(
+                {"params": jax.tree.map(lambda t: t[0], share)}, x)
+            return jax.lax.psum(out, "tensor")
+
+        return jax.shard_map(local, mesh=mesh, in_specs=(P("tensor"), P()),
+                             out_specs=P())(shares, x)
+
+    @jax.jit
+    def both(params, tokens):
+        x = want = params["embed"]["embedding"][tokens]
+        pairs = []
+        for i, kind in enumerate(kinds):
+            p = params[f"layer_{i}"]
+            out = mixer_of_shares(kind, p["mixer"], x) if kind in SHARES \
+                else reference.mixer(x, p["mixer"], kind, **config)
+            x = x + reference.rms_norm(out, p["post_norm"]["scale"], 1e-6)
+            want = reference.layer(want, p, kind, **config)
+            pairs.append((x, want))
+        return pairs
+
+    with jax.default_matmul_precision("highest"):
+        pairs = both(params, tokens)
+    assert len(pairs) == 8
+    for got, want in pairs:
+        close(got, want, 1e-4)
+
+
+# --- what the benchmark counts ----------------------------------------------
+
+def test_the_published_configuration_counts_its_parameters():
+    """No width differs from the catalog's row, and the share's parameters
+    are what the issue's arithmetic says: 766.2 M, 12.26 GB at 16 bytes."""
+    with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
+        row = next(r for r in map(json.loads, f)
+                   if r["name"] == "Olmo-Hybrid-7B")
+    for key, value in row["config"].items():
+        if key not in PUBLISHED["reduced"]:
+            assert PUBLISHED[key] == value, key
+    assert PUBLISHED["source"] == row["source_url"]
+    assert PUBLISHED["reduced"] == ["num_hidden_layers", "layer_types",
+                                    "tensor_shard", "vocab_size"]
+    assert PUBLISHED["layer_types"] == row["config"]["layer_types"][:4]
+    assert PUBLISHED["vocab_size"] * 8 == row["config"]["vocab_size"]
+    model, _ = builder.model_of(PUBLISHED)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32))["params"])
+    sizes = {name: sum(leaf.size for leaf in jax.tree.leaves(tree))
+             for name, tree in shapes.items()}
+    gdn = 3840 * (2 * 1440 + 2 * 2880 + 30) + 4 * 5760 + 30 + 192 \
+        + 2880 * 3840 + 3840
+    attention = 4 * 3840 * 1920 + 2 * 1920 + 3840
+    mlp = 3 * 3840 * 11008 + 3840
+    assert [sizes[f"layer_{i}"] for i in (0, 6, 7)] == [gdn, attention, mlp]
+    assert sum(sizes.values()) == 3 * gdn + attention + 4 * mlp \
+        + 2 * 12544 * 3840 + 3840 == 766_241_946
+
+
+def test_ops_count_is_the_shapes_arithmetic():
+    shape = {"hidden": 3840, "vocab": 12544, "gdn_layers": 3,
+             "attention_layers": 1, "mlp_layers": 4, "intermediate": 11008,
+             "gdn": {"heads": 15, "d_k": 96, "d_v": 192, "chunk": 64},
+             "attention": {"heads": 15, "head_dim": 128}}
+    ops = ops_count_olmohybrid.olmohybrid_lm_train_ops_per_token(shape, 8192)
+    assert ops["mlp"] == 4 * 6 * 3 * 3840 * 11008
+    assert ops["head"] == 6 * 3840 * 12544
+    assert ops["attention"] == 3 * 8192 * 15 * 2 * 128
+    rule = 15 * 2 * 64 * 96 + 15 * (64 * 64 / 3 + 64 * (96 + 192)
+                                    + 3 * 96 * 192 + 64 * 192)
+    assert ops["gdn"] == pytest.approx(3 * 6 * (
+        3840 * (2 * 1440 + 2 * 2880 + 30) + 2880 * 3840 + rule))
+    assert ops["total"] == pytest.approx(
+        ops["visible_to_compiler"] + ops["attention"])
+    assert ops["total"] == pytest.approx(
+        ops["gdn"] + ops["mlp"] + ops["head"] + ops["attention"]
+        + 6 * 4 * 3840 * 1920)
+    flash = ops_count_olmohybrid.flash_kernel(8192, 15, 128, 1)
+    assert flash == {"ops": 3.5 * 2 * 8192 * 1920, "bytes": 12 * 1920 * 2}
+
+
+def test_the_new_readers_read_their_cell_and_no_other(monkeypatch):
+    """`gdn_kdv_scan_roofline` from a trace's time under `hvd_gdn_scan` and
+    the kernel's shape, bytes-bound at the true widths and under 100;
+    `gdn_beta_over_one_pct` from the probe's counts; a run without the shape,
+    the scope or the counters (any other cell, the parent) reads None."""
+    peak = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    shape = {"heads": 15, "d_k": 96, "d_v": 192, "chunk": 64, "layers": 3,
+             "itemsize": 2}
+    least = ops_count_olmohybrid.scan_least_seconds(shape, 8192.0, peak)
+    row = 2 * (2 * 15 * 96 + 15 * 192) + 8 * 15
+    bytes_bound = 3 * 8192 * (3 * row + 2 * 4 * 15 * 192) / 819e9
+    assert least == pytest.approx(bytes_bound) and 1e-3 < least < 3e-3
+    names = {"fusion.1": "jit(step)/jvp(hvd_loss)/layer_0/mixer/hvd_gdn_scan"
+                         "/hvd_gdn_scan_carry/x",
+             "fusion.2": "jit(step)/jvp(hvd_loss)/layer_1/mixer/hvd_mlp/y"}
+    run = {"kernels": {_olmohybrid.KERNEL: shape}, "peak": peak,
+           "profiled_steps": 1, "samples": 8192, "steps": 1, "chips": 1,
+           "probes": {"optimizer_time_share_pct": {"op_names": names},
+                      "gdn_beta_over_one_pct": {"over_one": [3, 5],
+                                                "steps": [8, 8]}}}
+    from benchmark import program_trace
+
+    program = {"devices": {"/device:TPU:0": [["fusion.1|fusion||", 0, 4e7],
+                                             ["fusion.2|fusion||", 0, 6e7]]},
+               "program_spans": []}
+    monkeypatch.setattr(program_trace, "of_run", lambda run: program)
+    got = gdn_kdv_scan_roofline.read(run)
+    assert got == pytest.approx(100.0 * least / 0.04) and got < 100.0
+    assert gdn_beta_over_one_pct.read(run) == 50.0
+    other = dict(run, kernels={}, probes={
+        "optimizer_time_share_pct": {"op_names": names}})
+    assert gdn_kdv_scan_roofline.read(other) is None
+    assert gdn_beta_over_one_pct.read(other) is None
+    unscoped = dict(run, probes={"optimizer_time_share_pct": {
+        "op_names": {"fusion.1": "jit(step)/jvp(hvd_loss)/hvd_mlp/x"}}})
+    assert gdn_kdv_scan_roofline.read(unscoped) is None
+    monkeypatch.setattr(program_trace, "of_run", lambda run: None)
+    assert gdn_kdv_scan_roofline.read(run) is None
+    assert _olmohybrid.steps_probe({"built": object()}) is None
