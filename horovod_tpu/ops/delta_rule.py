@@ -1,8 +1,8 @@
 """The gated delta rule of a linear-attention layer as matrix products over
-chunks, in two forms that share the solve and the recurrence: a decay a
-CHANNEL of the key (Kimi delta attention, KDA: Kimi Linear, arXiv:2510.26692)
-and a decay a HEAD over grouped heads (Gated DeltaNet, arXiv:2412.06464; below,
-"A decay a head").
+chunks, in two forms that share the algebra of the solve and the recurrence:
+a decay a CHANNEL of the key (Kimi delta attention, KDA: Kimi Linear,
+arXiv:2510.26692) and a decay a HEAD over grouped heads (Gated DeltaNet,
+arXiv:2412.06464; below, "A decay a head").
 
 Per head, with a state ``S`` of ``d_k x d_v``, a decay ``alpha_t`` a CHANNEL of
 the key (``log_alpha_t <= 0``, ``d_k`` of them) and a step ``beta_t``:
@@ -27,6 +27,50 @@ the state ``S`` enters is (the WY / UT transform; rows are tokens):
 ``Q K^T`` for every chunk at once.  Only ``S`` is a true recurrence, and only
 ``S`` is carried from chunk to chunk.  Everything outside the carry and the
 solve is differentiable by autodiff.
+
+The solve, in two forms.  A decay a channel (:func:`_solved`) is XLA's:
+``T`` by :func:`_unit_lower_inverse` (a ``jax.custom_vjp`` of fifteen einsums
+over the diagonal sub-blocks' rows and three merges that pad and concatenate
+the growing inverse) and two einsums at ``precision="highest"`` against
+``beta K G`` and ``beta V`` written out in float32; autodiff transposes the
+einsums.  A decay a head (:func:`_head_solve`, a ``jax.custom_vjp``) is a
+pair of Pallas kernels, as the carry below is, that hold a chunk's ``T`` on
+the chip.  The grid is (batch, key heads, groups of chunks), every axis
+parallel; a grid step holds :data:`_SOLVES_A_STEP` value heads' chunks and
+works on all of them in every operation, because a chunk's solve is a chain
+of dependent steps and a bundle of the chip's instructions holds steps of
+many.  ``A`` reaches the forward kernel TRANSPOSED over its two chunk axes,
+and the backward kernel writes its cotangent transposed: XLA lays the (C, C)
+matrices around the stage out with those two axes swapped, and a kernel's
+operand is row-major, so the transposed matrix is the one it hands over
+without a copy (0.2 ms a Qwen3-Next layer each way otherwise).  A grid step
+of the forward kernel reads the chunks' ``A^T``, a KEY head's k as
+stored (ONCE for its value heads: no float32 copy a value head is written,
+nor ``beta k G`` nor ``beta v``), a value head's v as stored, ``beta`` and
+the decay from the chunk's start; in VMEM it finds ``T``
+(:func:`_chunk_inverses`: the rows inside every diagonal sub-block on the VPU
+in plain float32, the sub-blocks' coefficients by a lane gather, then the
+sub-blocks two at a time by products), ``W = (T . beta G) K`` rounded to q's
+dtype and ``U0 = (T . beta) V`` float32 — the vectors scale ``T``'s columns,
+so k and v meet the MXU as stored — and writes ``T``, ``W``, ``U0``.  The
+backward kernel, on the same grid, reads the kept ``T``, ``dW``, ``dU0`` and
+k, v and the two vectors (not ``A``), and writes every cotangent: ``A``'s
+``-T^T g T^T`` with ``g = (dW K^T) . beta G + (dU0 V^T) . beta``, k's ``(T .
+beta G)^T dW`` summed over the key head's value heads in float32, v's ``(T .
+beta)^T dU0``, and the vectors' as column sums of ``T . (dW K^T)`` and ``T .
+(dU0 V^T)``.  Nothing of the stage is left to autodiff.  Every product is
+what ``precision="highest"`` computes, by hand (:func:`_exact_dot`): a
+float32 operand is three bfloat16 terms whose sum it is, a bfloat16 operand
+(k, v, ``dW``) is its own one term, and the products of terms i and j with ``i
++ j < 3`` are single passes of the MXU summed in float32 — six for two
+float32 operands, three where one is bfloat16, and nothing dropped that
+``highest`` keeps.  The channel form is not on these kernels yet for one
+reason: ``benchmark/builders/ling_lm.py`` pins its step's count of custom
+calls exactly, and a change of the program may not edit the benchmark
+(``ROADMAP.md`` S11(c2): its decay a channel scales ``K``'s columns where a
+head's scales ``T``'s, which the kernels would take by shape as the carry's
+do; then :func:`_solved`, :func:`_unit_lower_inverse` and
+:func:`_diagonal_inverses` go).
 
 The carry, in two forms.  A decay a channel (:func:`_carry`, a
 ``jax.custom_vjp``) is XLA products and two loops a layer, no kernel of ours:
@@ -128,7 +172,8 @@ under exactly one:
   and beta by chunk, ``K K^T`` and ``Q K^T`` through the sub-blocks
   (``against_earlier``), and the decayed ``Q`` and ``K`` the recurrence reads;
 * ``hvd_kda_scan_solve`` — ``T`` (:func:`_unit_lower_inverse`, forward and its
-  written-out backward) and ``W``, ``U0``;
+  written-out backward) and ``W``, ``U0``; or :func:`_head_solve`: the two
+  kernels, named ``<scope>_solve_fwd`` and ``<scope>_solve_bwd``;
 * ``hvd_kda_scan_carry`` — :func:`_carry`: the two ``while``s and their
   bodies and the products over all chunks around them, or
   :func:`_head_carry`: the two kernels, named ``<scope>_carry_fwd`` and
@@ -138,8 +183,10 @@ under exactly one:
 step (its loops and kernels), as ``ops.attention._bwd_plan`` says of flash.
 
 Float32: the summed log-decays, their exponentials, ``T`` (by forward
-substitution, exact products), ``W``, ``U0``, ``U`` and the state between
-chunks.  The operands of
+substitution, exact products: XLA's at ``highest``, or in the head form's
+kernels plain float32 on the VPU and sums of bfloat16 terms on the MXU that
+drop nothing ``highest`` keeps), ``W`` before it is rounded, ``U0``, every
+cotangent of the solve, ``U`` and the state between chunks.  The operands of
 the other products are rounded to ``q``'s dtype and accumulated in float32, as
 every matmul of the model is.
 """
@@ -500,6 +547,12 @@ def _by_key_head(q, operands):
             for t in operands]
 
 
+def _by_value_head(t):
+    """A key head's (b, n, g, per_key, ...) as its value heads': (b, n, h,
+    ...)."""
+    return t.reshape(t.shape[:2] + (-1,) + t.shape[4:])
+
+
 def _head_carry_fwd(w, u0, qk, q, k, from_start, end_decay, carried, scope,
                     interpret):
     w, u0, qk, from_start, end_decay, carried = _by_key_head(
@@ -508,15 +561,14 @@ def _head_carry_fwd(w, u0, qk, q, k, from_start, end_decay, carried, scope,
     entered = jax.ShapeDtypeStruct(w.shape[:4] + (w.shape[-1], u0.shape[-1]),
                                    jnp.float32)
     o, entered = _carry_call(scope, False, operands, (u0, entered), interpret)
-    return o.reshape(o.shape[:2] + (-1,) + o.shape[4:]), operands + (entered,)
+    return _by_value_head(o), operands + (entered,)
 
 
 def _head_carry_bwd(scope, interpret, kept, d_o):
     operands = kept[:-1]                # a cotangent each, shaped as it is
     d_o, = _by_key_head(operands[3], (d_o,))
     cotangents = _carry_call(scope, True, kept + (d_o,), operands, interpret)
-    merged = [t.reshape(t.shape[:2] + (-1,) + t.shape[4:])
-              for t in cotangents]
+    merged = [_by_value_head(t) for t in cotangents]
     merged[3:5] = cotangents[3:5]       # q's and k's are a key head's
     return tuple(merged)
 
@@ -524,15 +576,305 @@ def _head_carry_bwd(scope, interpret, kept, d_o):
 _head_carry.defvjp(_head_carry_fwd, _head_carry_bwd)
 
 
+# --- a decay a head: the solve as a pair of Pallas kernels -------------------
+
+def _terms(x):
+    """``x`` as bfloat16 arrays whose sum is ``x`` exactly: a bfloat16 array
+    is its own, a float32 one three (24 bits of mantissa, eight a term)."""
+    if x.dtype == jnp.bfloat16:
+        return (x,)
+    x, terms = x.astype(jnp.float32), []
+    for _ in range(3):
+        terms.append(x.astype(jnp.bfloat16))
+        x = x - terms[-1].astype(jnp.float32)
+    return tuple(terms)
+
+
+def _exact_dot(a, b, a_axis=1, b_axis=0):
+    """``a`` and ``b``, a batch of matrices each (m, ., .) float32 or
+    bfloat16, contracted over one axis of each matrix as :func:`_dot` does,
+    as ``_exact`` gives it, by hand: each operand split into :func:`_terms`,
+    and the products of a term i of one with a term j of the other kept
+    where ``i + j < 3`` (what ``precision="highest"`` keeps: six of float32
+    by float32, all three of float32 by bfloat16, the one of bfloat16 by
+    bfloat16), each a single pass of the MXU, summed in float32 from the
+    smallest."""
+    left, right = _terms(a), _terms(b)
+    dims = (((a_axis + 1,), (b_axis + 1,)), ((0,), (0,)))
+    total = None
+    for _, i, j in sorted(((i + j, i, j) for i in range(len(left))
+                           for j in range(len(right)) if i + j < 3),
+                          reverse=True):
+        product = lax.dot_general(left[i], right[j], dims,
+                                  preferred_element_type=jnp.float32)
+        total = product if total is None else total + product
+    return total
+
+
+def _chunk_inverses(turned):
+    """``(I + a)^-1`` of every strictly lower ``a`` of a batch (m, C, C)
+    float32 in VMEM, handed over as ``a^T``, by the substitution of
+    :func:`_unit_lower_inverse`, the batch's matrices side by side in every
+    operation (each is a chain of dependent steps, and a bundle holds steps
+    of many).
+
+    Inside the diagonal sub-blocks row by row, all of them at once: a
+    matrix's sub-blocks lie side by side along the lanes, (sub, C), row r of
+    each is ``e_r - a[r, :r] . rows[:r]`` in plain float32 on the VPU, and
+    the coefficient ``a[r, j]`` of sub-block b reaches row j of its lanes by
+    a lane gather from ``a``'s transposed diagonal.  Then sub-block by
+    sub-block, two at a time: with ``T`` the inverse of the diagonal blocks
+    of s tokens and ``a_s`` what ``a`` holds between the two halves of each
+    block of 2s, the blocks of 2s invert to ``T - T a_s T``."""
+    many, chunk, _ = turned.shape
+    sub = min(SUB_BLOCK, chunk)
+    blocks = chunk // sub
+    a = jnp.swapaxes(turned, 1, 2)
+    lane = lax.broadcasted_iota(jnp.int32, (sub, chunk), 1)
+    row = lax.broadcasted_iota(jnp.int32, (sub, chunk), 0)
+    first = lane // sub * sub           # of the lane's sub-block
+    diagonal = sum(                 # [j, b sub + r] = a[b sub + r, b sub + j]
+        jnp.where(first == b * sub, turned[:, b * sub:(b + 1) * sub, :], 0.0)
+        for b in range(blocks))
+    rows = jnp.broadcast_to(jnp.where(lane - first == row, 1.0, 0.0),
+                            diagonal.shape)
+    flat = diagonal.reshape(many * sub, chunk)
+    for r in range(1, sub):
+        coefficient = jnp.take_along_axis(
+            flat, jnp.concatenate([first + r] * many, axis=0),
+            axis=1).reshape(diagonal.shape)
+        found = (coefficient * rows).sum(axis=1, keepdims=True)
+        rows = rows - jnp.where(row == r, found, 0.0)
+
+    def block_of(size, axis):           # of a row, or of a column
+        return lax.broadcasted_iota(jnp.int32, (chunk, chunk), axis) // size
+
+    inverse = jnp.where(block_of(sub, 0) == block_of(sub, 1),
+                        jnp.concatenate([rows] * blocks, axis=1), 0.0)
+    size = sub
+    while size < chunk:
+        halves = (block_of(size, 0) != block_of(size, 1)) \
+            & (block_of(2 * size, 0) == block_of(2 * size, 1))
+        inverse = inverse - _exact_dot(
+            _exact_dot(inverse, jnp.where(halves, a, 0.0)), inverse)
+        size *= 2
+    return inverse
+
+
+def _a_value_head(ref):
+    """What a grid step holds of a value head's operand, (chunks, value
+    heads a key head, ...), as one batch (chunks * value heads, ...)."""
+    return ref[...].reshape((-1,) + ref.shape[2:])
+
+
+def _a_row_each(ref):
+    """A grid step's vectors a value head, (chunks, value heads a key head,
+    C), as a row each: (chunks * value heads, 1, C)."""
+    return jnp.stack([ref[c, j:j + 1, :] for c in range(ref.shape[0])
+                      for j in range(ref.shape[1])])
+
+
+def _of_value_heads(ref, per_key):
+    """A key head's (chunks, C, d_k) once for each of its value heads."""
+    k = ref[...]
+    return jnp.broadcast_to(k[:, None], (k.shape[0], per_key) + k.shape[1:]
+                            ).reshape((-1,) + k.shape[1:])
+
+
+def _rows_to_value_heads(rows, ref):
+    """:func:`_a_row_each`'s batch of rows as the block ``ref`` takes."""
+    by_chunk = rows.reshape((-1, ref.shape[1]) + rows.shape[1:])
+    return jnp.concatenate([by_chunk[:, j] for j in range(ref.shape[1])],
+                           axis=1)
+
+
+def _solve_fwd_kernel(at_ref, k_ref, v_ref, beta_ref, start_ref, t_ref,
+                      w_ref, u0_ref):
+    """Grid ``(batch, key heads, groups of chunks)``, no step needing
+    another: for each value head of each of the key head's chunks ``T = (I +
+    A)^-1`` (:func:`_chunk_inverses`, from ``A^T``), ``W = T (beta K G)`` and
+    ``U0 = T (beta V)``.  ``beta`` and the decay scale ``T``'s columns, not
+    ``K``'s and ``V``'s rows, so ``k`` and ``v`` meet the MXU as stored."""
+    @_at_every_step
+    def _():
+        solve = _chunk_inverses(_a_value_head(at_ref))
+        beta = _a_row_each(beta_ref)
+        t_ref[...] = solve.reshape(t_ref.shape)
+        w_ref[...] = _exact_dot(
+            solve * (beta * _a_row_each(start_ref)),
+            _of_value_heads(k_ref, at_ref.shape[1])).astype(
+                w_ref.dtype).reshape(w_ref.shape)
+        u0_ref[...] = _exact_dot(solve * beta, _a_value_head(v_ref)).reshape(
+            u0_ref.shape)
+
+
+def _solve_bwd_kernel(k_ref, v_ref, beta_ref, start_ref, t_ref, d_w_ref,
+                      d_u0_ref, d_at_ref, d_k_ref, d_v_ref, d_beta_ref,
+                      d_start_ref):
+    """The same grid: every cotangent of the forward kernel's operands from
+    the kept ``T`` and the cotangents of ``W`` and ``U0`` (``A`` itself is
+    not read).  With ``P = dW K^T`` and ``Q = dU0 V^T``, ``T``'s cotangent
+    is ``g = P (beta G) + Q beta`` by columns, ``A``'s ``-T^T g T^T``,
+    written transposed, ``-T g^T T``; ``k``'s is ``(T beta G)^T dW`` summed
+    over the key head's value heads in float32, ``v``'s ``(T beta)^T dU0``;
+    and the two vectors' are column sums of ``T P`` and ``T Q`` element by
+    element."""
+    @_at_every_step
+    def _():
+        per_key = t_ref.shape[1]
+        solve, d_w, d_u0 = (_a_value_head(ref)
+                            for ref in (t_ref, d_w_ref, d_u0_ref))
+        beta, start = _a_row_each(beta_ref), _a_row_each(start_ref)
+        scale = beta * start            # of T's columns, for W
+        by_k = _exact_dot(d_w, _of_value_heads(k_ref, per_key), 1, 1)
+        by_v = _exact_dot(d_u0, _a_value_head(v_ref), 1, 1)
+        d_scale = (solve * by_k).sum(axis=1, keepdims=True)   # of beta G
+        d_beta_ref[...] = _rows_to_value_heads(
+            d_scale * start + (solve * by_v).sum(axis=1, keepdims=True),
+            d_beta_ref)
+        d_start_ref[...] = _rows_to_value_heads(d_scale * beta, d_start_ref)
+        d_at_ref[...] = -_exact_dot(_exact_dot(       # (-T^T g T^T)^T
+            solve, jnp.swapaxes(by_k * scale + by_v * beta, 1, 2)),
+            solve).reshape(d_at_ref.shape)
+        d_k = _exact_dot(jnp.swapaxes(solve * scale, 1, 2), d_w)
+        d_k_ref[...] = d_k.reshape((-1, per_key) + d_k.shape[1:]).sum(
+            axis=1).astype(d_k_ref.dtype)
+        d_v_ref[...] = _exact_dot(
+            jnp.swapaxes(solve * beta, 1, 2), d_u0).astype(
+                d_v_ref.dtype).reshape(d_v_ref.shape)
+
+
+# Value heads' chunks a grid step of the solve takes where the chunks divide:
+# each is a chain of dependent products, and Mosaic fills a step's bundles
+# from as many chains as it holds (4, 8, 16 a step: 3.68, 3.32, 3.32 ms
+# forward with backward a Qwen3-Next layer alone in a program; 16 is twice
+# the code).
+#
+# `tools/solve_sweep.py` on a v5e, the stage ALONE in a program at a layer of
+# each cell, ms, median of 20 calls (my chip run, PR 61; alone, either form
+# pays for layouts at its boundary that a step's neighbours share: in the
+# Qwen3-Next step XLA's stage is 1.40 forward / 3.95 with backward a layer
+# and the kernels are 1.06 / 1.80, with `dW` relaid for them 1.90):
+#
+#   (tokens, key heads, value heads, d_k, d_v)   `_solved`    the kernels
+#   forward            (4096, 16, 32, 128, 128)     2.48         2.24
+#   with backward                                   5.78         3.32
+#   forward            (8192, 15, 15,  96, 192)     3.13         2.89
+#   with backward                                   6.29         4.95
+#
+# What decided the kernels' shape (my chip runs, PR 61).  Alone in a program:
+# one value head's chunk after another, two a grid step, 3.31 forward / 5.08
+# with backward a Qwen3-Next layer, where eight a step in every operation
+# read 1.72 / 2.53.  In the step, `tokens_per_s_chip` of Qwen3-Next /
+# Olmo-Hybrid over the parent's 34,383 / 20,641: `A`, `T` and `A`'s cotangent
+# crossing HBM as PAIRS of chunks (.., 64, 128) — a kernel that only copies
+# (.., 64, 64) float32 blocks runs at a tenth of the chip's bandwidth — with
+# XLA's moves between chunks and pairs around the kernels 36,729 / 21,202;
+# single chunks 36,510 / 21,434 (the moves gone, and a 0.2 ms copy a layer
+# each way in Qwen3-Next that turns `A` and its cotangent for the kernel);
+# single chunks handed over TRANSPOSED, as XLA holds them, 36,997 / 21,376,
+# which is what stands.
+_SOLVES_A_STEP = 8
+
+
+@functools.partial(jax.jit, static_argnames=("scope", "backward",
+                                             "interpret"))
+def _solve_call(operands, scope, backward, interpret):
+    """``pl.pallas_call`` of the solve's forward kernel over ``operands`` =
+    (A^T, k, v, beta, the decay from the chunk's start), or of its backward
+    kernel over (k, v, beta, the decay, T, dW, dU0), named ``<scope>_fwd``
+    and ``<scope>_bwd``: the grid (batch, key heads, groups of chunks), every
+    axis parallel, a key head's block of each operand and result, every one
+    (b, n, g, ...).  Jitted, so that a model's layers of one shape trace and
+    lower each kernel once (``ops.moe._tiled_call``)."""
+    if backward:
+        k, v, _, _, solve = operands[:5]
+    else:
+        solve, k, v = operands[:3]
+    batch, chunks, key_heads, chunk, d_k = k.shape
+    per_key, d_v = v.shape[3], v.shape[-1]
+    held = max(_SOLVES_A_STEP // per_key, 1)     # chunks a grid step
+    while chunks % held:
+        held -= 1
+    # Single passes of the MXU over (C, C, .) that a value head's chunk takes
+    # (module docstring, "The solve, in two forms").
+    levels = (chunk // min(SUB_BLOCK, chunk) - 1).bit_length()
+    if backward:
+        kernel, name, passes = _solve_bwd_kernel, f"{scope}_bwd", (12, 4, 9)
+        outputs = (solve,) + operands[:4]   # a cotangent each, shaped as it is
+    else:
+        kernel, name, passes = (_solve_fwd_kernel, f"{scope}_fwd",
+                                (12 * levels, 3, 3))
+        outputs = (solve, jax.ShapeDtypeStruct(v.shape[:-1] + (d_k,), k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, jnp.float32))
+
+    def spec(t):
+        return pl.BlockSpec((None, held, None) + t.shape[3:],
+                            lambda b, g, n: (b, n, g) + (0,) * (t.ndim - 3))
+
+    vma = jax.typeof(k).vma             # inside shard_map: as the inputs vary
+    macs = chunk * chunk * (passes[0] * chunk + passes[1] * d_k
+                            + passes[2] * d_v)
+    call = pl.pallas_call(
+        kernel, grid=(batch, key_heads, chunks // held),
+        in_specs=[spec(t) for t in operands],
+        out_specs=[spec(t) for t in outputs],
+        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype, vma=vma)
+                   for t in outputs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * batch * chunks * key_heads * per_key * macs,
+            transcendentals=0,
+            bytes_accessed=sum(t.size * t.dtype.itemsize
+                               for t in (*operands, *outputs))),
+        interpret=interpret, name=name)
+    with kernel_trace(name):
+        return call(*operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _head_solve(a, k, v, beta, from_start, scope, interpret):
+    """:func:`_solved` of the head form, as two kernels that hold a chunk's
+    ``T`` on the chip (module docstring, "The solve, in two forms"): ``W`` in
+    ``k``'s dtype (b, n, h, C, d_k) and ``U0`` float32 (b, n, h, C, d_v) from
+    ``a`` (b, n, h, C, C) float32, strictly lower, ``k`` a KEY head
+    (b, n, g, C, d_k) and ``v`` (b, n, h, C, d_v) as stored, and ``beta`` and
+    ``from_start`` (b, n, h, C) float32.  ``scope`` names the kernels
+    (``<scope>_fwd``, ``<scope>_bwd``)."""
+    return _head_solve_fwd(a, k, v, beta, from_start, scope, interpret)[0]
+
+
+def _head_solve_fwd(a, k, v, beta, from_start, scope, interpret):
+    turned, *given = _by_key_head(
+        k, (jnp.swapaxes(a, -1, -2), v, beta, from_start))
+    given = (k, *given)
+    solve, w, u0 = _solve_call((turned, *given), scope=scope, backward=False,
+                               interpret=interpret)
+    return (_by_value_head(w), _by_value_head(u0)), (*given, solve)
+
+
+def _head_solve_bwd(scope, interpret, kept, cotangents):
+    d_turned, d_k, *value_heads = _solve_call(
+        kept + tuple(_by_key_head(kept[0], cotangents)), scope=scope,
+        backward=True, interpret=interpret)
+    return (jnp.swapaxes(_by_value_head(d_turned), -1, -2), d_k,
+            *map(_by_value_head, value_heads))
+
+
+_head_solve.defvjp(_head_solve_fwd, _head_solve_bwd)
+
+
 def lowered_plan(seq: int, chunk: int, form: str = "head") -> dict:
     """What one call of :func:`chunked_delta_rule` at this length, forward and
     backward together, adds to a compiled step.  ``form="head"`` (a decay a
-    head): the two kernels of :func:`_head_carry`, whatever the length, and
-    no loop.  ``form="channel"`` (a decay a channel): the recurrence between
-    chunks is a ``while`` forward and one backward (the compiler unrolls a
-    loop of one step, so a single chunk has none), and no kernel of ours."""
+    head): the two kernels of :func:`_head_solve` and the two of
+    :func:`_head_carry`, whatever the length, and no loop.
+    ``form="channel"`` (a decay a channel): the recurrence between chunks is
+    a ``while`` forward and one backward (the compiler unrolls a loop of one
+    step, so a single chunk has none), and no kernel of ours."""
     if form == "head":
-        return {"while": 0, "tpu_custom_call": 2}
+        return {"while": 0, "tpu_custom_call": 4}
     if form != "channel":
         raise ValueError(f"lowered_plan: form {form!r} is neither 'head' "
                          "nor 'channel'")
@@ -588,7 +930,7 @@ def _head_decay_rule(q, k, v, log_alpha, beta, chunk: int, scope: str):
 
     with jax.named_scope(f"{scope}_chunk"):
         qc, kc = by_chunk(q), by_chunk(k)
-        vc, bc = by_chunk(v).astype(f32), by_chunk(beta.astype(f32))[..., None]
+        vc, bc = by_chunk(v), by_chunk(beta.astype(f32))
 
     with jax.named_scope(f"{scope}_decays"):
         at = jnp.arange(chunk)
@@ -606,19 +948,20 @@ def _head_decay_rule(q, k, v, log_alpha, beta, chunk: int, scope: str):
         decay = jnp.where(lower, jnp.exp(between), 0.0)      # (b, n, h, C, C)
         # (b, n, h, C): a token's decay, for every channel of its head.
         from_start, end_decay = jnp.exp(within), jnp.exp(to_end)
-        start_column = from_start[..., None]    # for the solve's d_k channels
         carried, decay_min = jnp.exp(whole)[..., None], whole.min()
 
     with jax.named_scope(f"{scope}_chunk"):
         kk = of_value_heads(jnp.einsum("bngtc,bngsc->bngts", kc, kc, **_WIDE))
         qk = of_value_heads(jnp.einsum("bngtc,bngsc->bngts", qc, kc, **_WIDE))
-        a = jnp.where(earlier, bc * decay * kk, 0.0)
+        a = jnp.where(earlier, bc[..., None] * decay * kk, 0.0)
         qk = (decay * qk).astype(dtype)
-        wide_k = of_value_heads(kc.astype(f32))
-    w, u0 = _solved(a, bc, wide_k, start_column, vc, dtype, scope)
+    interpret = jax.default_backend() != "tpu"
+    with jax.named_scope(f"{scope}_solve"):
+        w, u0 = _head_solve(a, kc, vc, bc, from_start, f"{scope}_solve",
+                            interpret)
     with jax.named_scope(f"{scope}_carry"):
         o = _head_carry(w, u0, qk, qc, kc, from_start, end_decay, carried,
-                        f"{scope}_carry", jax.default_backend() != "tpu")
+                        f"{scope}_carry", interpret)
         return _to_tokens(o, seq), decay_min
 
 

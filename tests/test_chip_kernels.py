@@ -96,12 +96,15 @@ def _compile_delta_rule_grad(device, key_heads, value_heads, d_k, d_v,
     (2, 4, 128, 128), (15, 15, 96, 192)], ids=["qwen3next", "olmohybrid"])
 def test_delta_rule_carry_kernels_at_the_cells_widths_compile(
         v5e, monkeypatch, key_heads, value_heads, d_k, d_v):
-    """The head form's recurrence as the two cells with Gated DeltaNet run it
+    """The head form's solve and recurrence as the two cells with Gated
+    DeltaNet run them
     — Qwen3-Next's heads of 128 channels, two value heads a key head; Olmo-
     Hybrid's 15 heads with keys of 96 and values of 192, three quarters of a
     lane tile and one and a half, blocks at the TRUE widths with nothing
     padded by us (Mosaic lays a 96-wide block out in 128 lanes) — through the
-    chip's compiler, forward and backward: two Mosaic kernels and no loop, as
+    chip's compiler, forward and backward: four Mosaic kernels (the solve's
+    pair, whose lane gather, transposes and products of split terms the
+    interpreter cannot refuse, and the carry's) and no loop, as
     `lowered_plan` says (the rule asks the backend which way to run its
     kernels; here it is compiling for the described chip)."""
     from horovod_tpu.ops.delta_rule import lowered_plan
@@ -110,9 +113,10 @@ def test_delta_rule_carry_kernels_at_the_cells_widths_compile(
     text = _compile_delta_rule_grad(v5e[0], key_heads, value_heads, d_k, d_v)
     plan = lowered_plan(512, 64)
     assert text.count("custom_call_target=\"tpu_custom_call\"") \
-        == plan["tpu_custom_call"] == 2
+        == plan["tpu_custom_call"] == 4
     assert text.count(" while(") == plan["while"] == 0
-    for kernel in ("hvd_gdn_scan_carry_fwd", "hvd_gdn_scan_carry_bwd"):
+    for kernel in ("hvd_gdn_scan_carry_fwd", "hvd_gdn_scan_carry_bwd",
+                   "hvd_gdn_scan_solve_fwd", "hvd_gdn_scan_solve_bwd"):
         assert f"%{kernel}" in text, kernel
 
 

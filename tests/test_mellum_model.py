@@ -63,7 +63,8 @@ def test_unset_the_pattern_lowers_to_the_parents_program():
 # jax 0.9.0, before `MixerLayer` took one options value; the lowered texts of
 # `nemotron` and `qwen3next` again at PR 59, whose Mamba-2 and head-gated
 # delta mixers open with `models.ssm.mixer_opening`: their parameters'
-# digests held, and `ling`'s channel gate kept both of its own): of
+# digests held, and `ling`'s channel gate kept both of its own; `qwen3next`'s
+# text once more at PR 61, whose head form solves in two kernels): of
 # `jax.jit(grad).lower(...).as_text()` and of the parameters seeded from
 # `PRNGKey(0)` (paths, shapes, types, bytes).  Bfloat16, as the cells run.
 FAMILY_SIZES = dict(vocab_size=256, d_model=64, n_heads=8,
@@ -122,7 +123,7 @@ FAMILY_DIGESTS = {
         "226f2e273d1a677f34065ee963fa237a993d755162817fc118ef4181fcc460e8",
         "16061fccdea8661316ab18d63dd5c720e9b87007b5839ecdf7857b2385a0ac1b"),
     "qwen3next": (
-        "c676fcc5bccc1e65f46b1bba255b3c73439cfdb5b8ccc8853fd6ccb20d005b5e",
+        "c33520116f794d2e68eaec40ddebad26501c0e7b2ec6e4caed321f370cdd77a2",
         "4139ed284cc607e2ae47c85fb96e215363f754eaf02788b1b805db03fa567bd2"),
     "ouro": (
         "506a976b3cd6c36f66d5acba623d61373f7e54481e37c79a7da852483ea18678",

@@ -109,6 +109,45 @@ def test_steps_past_one_are_as_exact_as_steps_under_it():
     assert max(errors) < 2e-5 and errors[1] < 4 * errors[0] + 1e-6, errors
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("chunk", [64, 16])
+def test_solve_kernels_at_the_published_widths_are_the_float64_solve(
+        chunk, dtype):
+    """The solve's pair of kernels at keys of 96 and values of 192, a value
+    head a key head, `beta` up to 2: `T`, `W`, `U0` and every cotangent
+    against `np.linalg.inv` (`tests/test_qwen3next.py` has the case and the
+    other widths)."""
+    from tests.test_qwen3next import check_solve_kernels
+
+    check_solve_kernels(96, 192, 1, chunk, dtype)
+
+
+@pytest.mark.parametrize("d_k,d_v", [(12, 24), (96, 192)])
+def test_head_form_at_two_widths_is_the_channel_form_on_a_decay_broadcast(
+        d_k, d_v):
+    """The head form (its solve and carry kernels) against the channel form's
+    code path (`_solved`, `_unit_lower_inverse`, the loops) fed the same
+    decay on every channel, at d_k != d_v and `beta` up to 2: output and the
+    five gradients (ungrouped heads and whole chunks, which is what the
+    channel form takes; its gate's bound holds the decays over -5.8)."""
+    (q, k, v, log_alpha, beta), mix = rule_inputs(d_k, 128, 2, d_k, d_v)
+    log_alpha = jnp.maximum(log_alpha, -5.0)
+
+    def wide(q, k, v, log_alpha, beta):
+        return chunked_delta_rule(
+            q, k, v, jnp.broadcast_to(log_alpha[..., None], q.shape), beta,
+            64)[0]
+
+    got = rule_and_gradients(
+        lambda *a: chunked_delta_rule(*a, 64)[0],
+        (q, k, v, log_alpha, beta), mix)
+    want = rule_and_gradients(wide, (q, k, v, log_alpha, beta), mix)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4)
+    for g, w in zip(got[1], want[1]):
+        close(g, w, 1e-4)
+
+
 # --- the mixer --------------------------------------------------------------
 
 def steep(params, scale=3.0):
@@ -351,7 +390,8 @@ def test_the_rules_state_and_solve_are_float32_under_bfloat16_operands():
     bfloat16 operands move it by 2e-2 (PERF.md section 6, PR 60) — is held by
     types: with bfloat16 q, k, v the kernels' state scratch and the state
     they keep for the backward are float32 at (d_k, d_v), and every product
-    of the solve is a float32 product in full precision."""
+    of the solve is a float32 product in full precision: XLA's at `highest`
+    outside the kernels, sums of bfloat16 terms inside them."""
     from tests.test_ops import _pallas_eqns
 
     args, _ = rule_inputs(0, 128, 2, 96, 192)
@@ -365,23 +405,84 @@ def test_the_rules_state_and_solve_are_float32_under_bfloat16_operands():
               if var.aval.shape[-2:] == (96, 192)]
     assert [aval.dtype for aval in states] == [jnp.float32]
 
-    def float32_products(jaxpr):
-        """The precision of every product of two float32 operands outside
-        the kernels, sub-programs included."""
+    def products(jaxpr, inside=False):
+        """Every product's equation, sub-programs included: outside the
+        kernels, or inside them."""
         found = []
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
+                if inside:
+                    found += products(eqn.params["jaxpr"], True)
                 continue
-            if eqn.primitive.name == "dot_general" and all(
-                    var.aval.dtype == jnp.float32 for var in eqn.invars):
-                found.append(eqn.params["precision"])
+            if eqn.primitive.name == "dot_general":
+                found.append(eqn)
             for sub in jax.core.jaxprs_in_params(eqn.params):
-                found += float32_products(sub)
+                found += products(sub, inside)
         return found
 
-    products = float32_products(jaxpr.jaxpr)
-    # The substitution's rows and blocks, T against the right-hand sides,
-    # the sums of log-decays, and their transposes: dozens, all exact.
-    assert len(products) > 30
-    assert all(p is not None and "HIGHEST" in str(p) for p in products), \
-        set(map(str, products))
+    # Outside the kernels: the sums of log-decays and their transposes, exact.
+    exact = [eqn.params["precision"] for eqn in products(jaxpr.jaxpr)
+             if all(var.aval.dtype == jnp.float32 for var in eqn.invars)]
+    assert exact and all("HIGHEST" in str(p) for p in exact), exact
+    # Inside the solve's kernels: T, U0 and the cotangents leave float32, and
+    # every product is of bfloat16 TERMS whose sum is the float32 operand
+    # (`_exact_dot`: what `highest` keeps, by hand), summed in float32 — and
+    # ALL of them: six single passes for two float32 operands, three where
+    # one is bfloat16 as stored, one for two such.  A chunk of 64 is two
+    # levels of sub-blocks, each `T - (T a) T` of float32 matrices.  Forward
+    # besides: `(T . beta G) K` and `(T . beta) V`, k and v bfloat16.
+    # Backward: `dW K^T` of two bfloat16 and `dU0 V^T` of one, the two
+    # products of `-T^T g T^T`, `(T . beta G)^T dW` against bfloat16 and `(T .
+    # beta)^T dU0` against float32.
+    passes = {"hvd_gdn_scan_solve_fwd": 2 * (6 + 6) + 3 + 3,
+              "hvd_gdn_scan_solve_bwd": 1 + 3 + (6 + 6) + 3 + 6}
+    for name, wanted in passes.items():
+        kernel = kernels[name]
+        assert [var.aval.dtype for var in kernel.outvars].count(
+            jnp.float32) >= 2
+        inside = products(kernel.params["jaxpr"], True)
+        assert len(inside) == wanted, (name, len(inside))
+        for eqn in inside:
+            assert all(var.aval.dtype == jnp.bfloat16 for var in eqn.invars)
+            assert eqn.params["preferred_element_type"] == jnp.float32
+
+
+@pytest.mark.parametrize("left,right,passes", [
+    (jnp.float32, jnp.float32, 6), (jnp.float32, jnp.bfloat16, 3),
+    (jnp.bfloat16, jnp.float32, 3), (jnp.bfloat16, jnp.bfloat16, 1)])
+def test_the_kernels_product_is_the_float64_product_to_float32s_rounding(
+        left, right, passes):
+    """`ops.delta_rule._exact_dot`, the one product of the solve's kernels:
+    against float64, within 2^-20 of the sum of the terms' sizes whatever
+    the operands' spread — a float32 sum of 64 terms' own rounding: it reads
+    3.4e-7 to 6.4e-7 where `jnp.matmul` of float32 at `highest` reads 3.7e-7
+    to 5.7e-7 —; in the passes `highest` keeps and no other; and with a
+    float32 operand's third term dropped (what `high` keeps) it reads 7e-6 to
+    1.3e-5, which the limit refuses."""
+    from horovod_tpu.ops import delta_rule
+
+    keys = jax.random.split(jax.random.PRNGKey(passes), 4)
+    a = (jax.random.normal(keys[0], (3, 64, 64))
+         * jnp.exp(4.0 * jax.random.normal(keys[1], (3, 64, 64)))).astype(left)
+    b = (jax.random.normal(keys[2], (3, 64, 96))
+         * jnp.exp(4.0 * jax.random.normal(keys[3], (3, 64, 96)))).astype(
+             right)
+
+    def error(got, a_axis):
+        a64, b64 = (np.asarray(t.astype(jnp.float32), np.float64)
+                    for t in (a, b))
+        if a_axis == 0:
+            a64 = a64.swapaxes(1, 2)
+        return float(np.max(np.abs(np.asarray(got, np.float64) - a64 @ b64)
+                            / (np.abs(a64) @ np.abs(b64))))
+
+    for a_axis in (1, 0):
+        product = functools.partial(delta_rule._exact_dot, a_axis=a_axis)
+        assert error(jax.jit(product)(a, b), a_axis) < 2.0 ** -20
+        assert str(jax.make_jaxpr(product)(a, b)).count(
+            "dot_general") == passes
+    if passes > 1:
+        terms = delta_rule._terms
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(delta_rule, "_terms", lambda x: terms(x)[:2])
+            assert error(delta_rule._exact_dot(a, b), 1) > 2.0 ** -18

@@ -317,13 +317,14 @@ def test_lowered_plan_counts_the_rules_loops(seq, chunk, loops, form):
     """The plan against the program.  A decay a channel: a `while` forward and
     one backward where the recurrence has more than one step (a single
     chunk's loop is unrolled), no kernel.  A decay a head, which is what the
-    plan describes unless told the form: two kernels and no loop, at any
-    length — read from the jaxpr, because off a TPU the interpreter runs the
-    kernels' grids as loops of its own."""
+    plan describes unless told the form: four kernels (the solve's two and
+    the carry's two) and no loop, at any length — read from the jaxpr,
+    because off a TPU the interpreter runs the kernels' grids as loops of its
+    own."""
     channel = form == "channel"
     assert lowered_plan(seq, chunk, form) == {
         "while": loops if channel else 0,
-        "tpu_custom_call": 0 if channel else 2}
+        "tpu_custom_call": 0 if channel else 4}
     assert lowered_plan(seq, chunk) == lowered_plan(seq, chunk, "head")
     if seq > 128:             # the cell's length: the plan alone
         return
@@ -340,8 +341,140 @@ def test_lowered_plan_counts_the_rules_loops(seq, chunk, loops, form):
 
     jaxpr = jax.make_jaxpr(grad)(*args)
     assert sorted(_pallas_call_names(jaxpr.jaxpr)) == [
-        "hvd_kda_scan_carry_bwd", "hvd_kda_scan_carry_fwd"]
+        "hvd_kda_scan_carry_bwd", "hvd_kda_scan_carry_fwd",
+        "hvd_kda_scan_solve_bwd", "hvd_kda_scan_solve_fwd"]
     assert not re.search(r"\b(scan|while)\[", str(jaxpr))
+
+
+# --- the solve's kernels against float64 ------------------------------------
+
+def solve_case(seed, d_k, d_v, per_key, chunk, dtype, key_heads=2, chunks=2,
+               tail=5):
+    """A chunked rule's operands as `_head_decay_rule` hands them to the solve
+    (`A`, k a KEY head, v, beta, the decay from the chunk's start), with keys a
+    hundredth apart (every entry of `K K^T` near one, as in
+    `tests/test_ling.py::test_unit_lower_inverse_of_keys_that_resemble_one_another`),
+    `beta` up to 2 and the last chunk's last `tail` tokens the padding of a
+    length that is no multiple of the chunk (log-decay 0, `beta` 0, zero k, v);
+    and cotangents for `W` and `U0`."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 7)
+    heads = key_heads * per_key
+    real = jnp.arange(chunks * chunk).reshape(chunks, 1, chunk) \
+        < chunks * chunk - tail
+    k = jax.random.normal(keys[0], (1, chunks, key_heads, 1, d_k)) \
+        + 0.01 * jax.random.normal(keys[1], (1, chunks, key_heads, chunk, d_k))
+    k = (k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+         * real[..., None]).astype(dtype)
+    v = (jax.random.normal(keys[2], (1, chunks, heads, chunk, d_v))
+         * real[..., None]).astype(dtype)
+    beta = 2.0 * jax.random.uniform(keys[3], (1, chunks, heads, chunk)) * real
+    steps = -jax.random.uniform(keys[4], (1, chunks, heads, chunk)) * real
+    within = jnp.cumsum(steps, axis=-1)
+    at = jnp.arange(chunk)
+    earlier = at[:, None] > at[None, :]
+    decay = jnp.exp(jnp.where(
+        earlier, within[..., :, None] - within[..., None, :], 0.0))
+    kk = jnp.repeat(jnp.einsum("bngtc,bngsc->bngts", k, k,
+                               preferred_element_type=jnp.float32,
+                               precision="highest"), per_key, axis=2)
+    a = jnp.where(earlier, beta[..., None] * decay * kk, 0.0)
+    d_w = jax.random.normal(keys[5], v.shape[:-1] + (d_k,)).astype(dtype)
+    d_u0 = jax.random.normal(keys[6], v.shape)
+    return (a, k, v, beta, jnp.exp(within)), (d_w, d_u0)
+
+
+def solve_in_float64(operands, cotangents):
+    """`T`, `W`, `U0` and the five cotangents, from `np.linalg.inv`."""
+    a, k, v, beta, start, d_w, d_u0 = (
+        np.asarray(t.astype(jnp.float32), np.float64)
+        for t in (*operands, *cotangents))
+    per_key = v.shape[2] // k.shape[2]
+    k = np.repeat(k, per_key, axis=2)
+    solve = np.linalg.inv(np.eye(a.shape[-1]) + a)
+    turned = solve.swapaxes(-1, -2)
+    scale = (beta * start)[..., None]
+    w, u0 = solve @ (scale * k), solve @ (beta[..., None] * v)
+    g = d_w @ (scale * k).swapaxes(-1, -2) \
+        + d_u0 @ (beta[..., None] * v).swapaxes(-1, -2)
+    from_w, from_u0 = turned @ d_w, turned @ d_u0
+    d_k = (scale * from_w).reshape(
+        k.shape[:2] + (-1, per_key) + k.shape[3:]).sum(axis=3)
+    along_k = (from_w * k).sum(axis=-1)
+    return (solve, w, u0), (
+        -turned @ g @ turned, d_k, beta[..., None] * from_u0,
+        along_k * start + (from_u0 * v).sum(axis=-1), along_k * beta)
+
+
+def check_solve_kernels(d_k, d_v, per_key, chunk, dtype, chunks=2):
+    """The pair of kernels (interpreted off a TPU) against float64: `T`, `U0`
+    and every cotangent to `RTOL` of the largest value; `W` and the
+    cotangents that leave in the operands' dtype to its rounding where that
+    is bfloat16."""
+    operands, cotangents = solve_case(chunk + d_v, d_k, d_v, per_key, chunk,
+                                      dtype, chunks=chunks)
+    rounded = RTOL if dtype == jnp.float32 else 2.0 ** -8
+
+    @jax.jit
+    def both(operands, cotangents):
+        (w, u0), kept = delta_rule._head_solve_fwd(
+            *operands, "hvd_gdn_scan_solve", True)
+        solve = delta_rule._by_value_head(kept[-1])
+        return (solve, w, u0), delta_rule._head_solve_bwd(
+            "hvd_gdn_scan_solve", True, kept, cotangents)
+
+    (solve, w, u0), grads = both(operands, cotangents)
+    wanted, wanted_grads = solve_in_float64(operands, cotangents)
+    close(solve, wanted[0], RTOL)
+    close(w.astype(jnp.float32), wanted[1], rounded)
+    close(u0, wanted[2], RTOL)
+    for got, want, rtol in zip(grads, wanted_grads,
+                               (RTOL, rounded, rounded, RTOL, RTOL)):
+        assert got.shape == want.shape
+        close(got.astype(jnp.float32), want, rtol)
+    # A strictly lower A's cotangent is read under the diagonal alone.
+    assert grads[0].dtype == grads[3].dtype == jnp.float32
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("chunk", [64, 16])
+@pytest.mark.parametrize("d_k,d_v,per_key", [(128, 128, 2), (8, 8, 1)])
+def test_solve_kernels_are_the_float64_solve(d_k, d_v, per_key, chunk, dtype):
+    check_solve_kernels(d_k, d_v, per_key, chunk, dtype)
+
+
+@pytest.mark.parametrize("per_key,chunks", [(2, 8), (1, 3), (1, 11)])
+def test_solve_kernels_over_chunks_a_grid_step_does_not_hold(per_key, chunks):
+    """A grid step holds `_SOLVES_A_STEP` value heads' chunks where the
+    chunks divide: eight chunks of two value heads a key head are two steps
+    of four, three chunks one step of three, eleven eleven of one."""
+    check_solve_kernels(128, 128, per_key, 64, jnp.bfloat16, chunks)
+
+
+@pytest.mark.parametrize("chunk", [64, 16])
+def test_head_form_is_the_channel_form_on_a_decay_broadcast(chunk):
+    """The two solves held to each other while both exist: the head form
+    (its kernels) against the channel form's code path (`_solved`,
+    `_unit_lower_inverse`, the loops) fed the same decay on every channel —
+    ungrouped heads and whole chunks, which is what that form takes — output
+    and the five gradients."""
+    (q, k, v, log_alpha, beta), mix = rule_inputs(
+        chunk, SEQ, low=-0.5, key_heads=VALUE_HEADS)
+    beta = 2.0 * beta
+
+    def total(widen):
+        def loss(q, k, v, log_alpha, beta):
+            decay = jnp.broadcast_to(log_alpha[..., None], q.shape) \
+                if widen else log_alpha
+            return (chunked_delta_rule(q, k, v, decay, beta, chunk)[0]
+                    * mix).sum()
+        return jax.jit(jax.value_and_grad(loss, range(5)))
+
+    got, want = (total(widen)(q, k, v, log_alpha, beta)
+                 for widen in (False, True))
+    close(got[0], want[0], 1e-4)
+    for g, w in zip(got[1], want[1]):
+        close(g, w, 1e-4)
 
 
 def test_lowered_plan_refuses_a_form_it_does_not_know():
