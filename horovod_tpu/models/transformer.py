@@ -26,7 +26,8 @@ from horovod_tpu.models.delta import DeltaConfig, DeltaMixer
 from horovod_tpu.models.ssm import Mamba2Config, Mamba2Mixer
 from horovod_tpu.ops import (blockwise_attention, flash_attention,
                              ring_attention)
-from horovod_tpu.ops.attention import (Selected, mask_blocks,
+from horovod_tpu.ops.attn_prep import normed_and_turned, prep_rows
+from horovod_tpu.ops.attention import (Selected, _split_scale, mask_blocks,
                                        masked_flash_attention)
 from horovod_tpu.ops.dsa import (Selection, head_probs, index_scores,
                                  indexer_kl, select)
@@ -238,15 +239,16 @@ def yarn_frequencies(base: float, pairs: int, scaling: RopeScaling):
     return plain / scaling.factor * ramp + plain * (1 - ramp), low, high
 
 
-def _turned(x, positions, base, seq_dim, rotary_dim, scaling, back: bool):
-    """``x``'s adjacent pairs turned by their angles (``back``: by the
-    negative angles), float32 inside, rounded once; every operand keeps
-    ``x``'s last axis.  With ``rotary_dim`` only the first ``rotary_dim``
+def rotary_tables(positions, d: int, base, rotary_dim=None, scaling=None,
+                  back: bool = False, shape=None):
+    """(cos, sin): the float32 tables of :func:`rope` for a last axis of
+    ``d``, ``positions.shape + (d,)`` (``shape``: reshaped to it), each
+    frequency written twice, beside the pair it turns (``back``: the sine of
+    the negative angles).  With ``rotary_dim`` only the first ``rotary_dim``
     channels turn, at the frequencies of a head that wide; the others pass
-    (an angle of zero).  With ``scaling`` the frequencies are its table's
-    (a constant of the program, whatever the sequence length) and cosine
-    and sine carry its factor."""
-    d = x.shape[-1]
+    (an angle of zero: cosine one, sine zero).  With ``scaling`` the
+    frequencies are its table's (a constant of the program, whatever the
+    sequence length) and cosine and sine carry its factor."""
     turning = d if rotary_dim is None else rotary_dim
     if d % 2 or turning % 2 or not 0 < turning <= d:
         raise ValueError(f"rope turns adjacent pairs: the last axis ({d}) "
@@ -263,17 +265,29 @@ def _turned(x, positions, base, seq_dim, rotary_dim, scaling, back: bool):
     if rotary_dim is not None:
         freqs = jnp.where(jnp.arange(d) < turning, freqs, 0.0)
     angles = positions[..., None].astype(jnp.float32) * freqs
-    shape = [1] * x.ndim
-    if positions.ndim == 2:  # per-batch-row offsets (decode mode)
-        shape[0] = positions.shape[0]
-    shape[seq_dim] = x.shape[seq_dim]
-    shape[-1] = d
+    if shape is None:
+        shape = angles.shape
     cos = jnp.cos(angles).reshape(shape)
     sin = jnp.sin(-angles if back else angles).reshape(shape)
     if scaling is not None:
         # On the channels that turn alone: the others pass as they are.
         factor = jnp.where(jnp.arange(d) < turning, scaling.magnitude, 1.0)
         cos, sin = cos * factor, sin * scaling.magnitude
+    return cos, sin
+
+
+def _turned(x, positions, base, seq_dim, rotary_dim, scaling, back: bool):
+    """``x``'s adjacent pairs turned by their angles (``back``: by the
+    negative angles), float32 inside, rounded once; every operand keeps
+    ``x``'s last axis (:func:`rotary_tables`)."""
+    d = x.shape[-1]
+    shape = [1] * x.ndim
+    if positions.ndim == 2:  # per-batch-row offsets (decode mode)
+        shape[0] = positions.shape[0]
+    shape[seq_dim] = x.shape[seq_dim]
+    shape[-1] = d
+    cos, sin = rotary_tables(positions, d, base, rotary_dim, scaling, back,
+                             shape)
     swapped = jnp.einsum("...d,de->...e", x, _pair_swap(d, x.dtype),
                          precision=lax.Precision.HIGHEST,
                          preferred_element_type=jnp.float32)
@@ -793,12 +807,17 @@ class Attention(nn.Module):
                                  scaling=self.rope_scaling)
         return turn(q), turn(k), w
 
-    def _grouped_projections(self, x, head_dim, rotate):
+    def _grouped_projections(self, x, head_dim, rotate, positions, q_factor):
         """(q, k, v), each (b, local query heads, seq, head_dim), from a
         ``q_kernel`` and a ``kv_kernel`` of this shard's heads; a key/value
         head is normed and turned (``rotate``) once and then repeated for
         every query head that reads it (the repeat's transpose sums their
-        cotangents before the inverse rotation).  q is returned unturned."""
+        cotangents before the inverse rotation).  q is returned unturned —
+        or, with ``positions`` (the rows', where `__call__` takes q's
+        per-head norm and its turn as one pass:
+        :func:`~horovod_tpu.ops.attn_prep.normed_and_turned`), turned as k
+        is and times ``q_factor``, a power of two that rides q's norm scale
+        through the pass."""
         shard, n_shards = self.head_shard
         kv_heads = self.n_kv_heads or self.n_heads
         if self.n_heads % kv_heads or self.n_heads % n_shards \
@@ -821,8 +840,18 @@ class Attention(nn.Module):
         x = x.astype(self.dtype)
         q = jnp.einsum("bsd,dhe->bhse", x, w_q.astype(self.dtype))
         k, v = jnp.einsum("bsd,djhe->jbhse", x, w_kv.astype(self.dtype))
-        if self.head_norm:
+        if positions is not None:
+            with jax.named_scope("hvd_attn_rotate"):
+                q_scale = self.param("q_head_norm_scale",
+                                     nn.initializers.ones, (head_dim,),
+                                     jnp.float32)
+                q = normed_and_turned(
+                    q, q_factor * q_scale, *rotary_tables(
+                        positions, head_dim, self.rope_theta,
+                        scaling=self.rope_scaling), self.norm_eps)
+        elif self.head_norm:
             q = self._head_norm("q_head_norm_scale", q)
+        if self.head_norm:
             k = self._head_norm("k_head_norm_scale", k)
         return (q, jnp.repeat(rotate(k), heads // kv_local, axis=1),
                 jnp.repeat(v, heads // kv_local, axis=1))
@@ -869,9 +898,25 @@ class Attention(nn.Module):
                             self.rotary_dim, self.rope_scaling)
 
         grouped = self.n_kv_heads is not None or self.head_shard != (0, 1)
+        # A grouped layer's per-head norm of q and q's rotation are one pass
+        # each way where the pass pays (`ops.attn_prep.prep_rows`: heads of
+        # 128 turned whole, on whole sequences at the rows' positions); the
+        # key heads, the cached decode, the ring and the other widths keep
+        # the composition.
+        prepared = grouped and self.head_norm and not self.qk_norm \
+            and prep_rows(s, head_dim, self.rotary_dim, self.rope,
+                          decode_ctx is None and self.seq_axis is None) \
+            is not None
+        # The kernels scale q by the power of two in ``head_dim ** -0.5`` on
+        # its way in, a pass of its own behind a custom call: the one pass
+        # takes it along, exactly, and the kernels are told what is left.
+        q_factor, sm_scale = _split_scale(head_dim ** -0.5) if prepared \
+            else (1.0, None)
         with jax.named_scope("hvd_attn_qkv"):
             if grouped:
-                q, k, v = self._grouped_projections(x, head_dim, rotate)
+                q, k, v = self._grouped_projections(
+                    x, head_dim, rotate, positions if prepared else None,
+                    q_factor)
             else:
                 # One fused qkv projection whose einsum emits q/k/v
                 # *head-major* ('jbhse'), and the output projection's einsum
@@ -911,12 +956,14 @@ class Attention(nn.Module):
                 with jax.named_scope("hvd_dsa_select"):
                     chosen = select(scores, self.indexer.topk)
         with jax.named_scope("hvd_attn_attend"):
-            q = rotate(q)
+            if not prepared:
+                q = rotate(q)
             if not grouped:
                 k = rotate(k)
             if chosen is not None:
                 out, lse = masked_flash_attention(
-                    q, k, v, Selected(self.indexer.topk), chosen.chosen)
+                    q, k, v, Selected(self.indexer.topk), chosen.chosen,
+                    sm_scale=sm_scale)
             elif decode_ctx is not None:
                 ctx_len = k_ctx.shape[-2]
                 # Context keys all precede the new chunk; within the chunk
@@ -943,8 +990,9 @@ class Attention(nn.Module):
                     self.sow("intermediates", "kv", (k, v))
                 masks = dict(causal=True, window=self.window) \
                     if diffusion is None else dict(block_diffusion=diffusion)
-                out = (flash_attention(q, k, v, **masks) if self.use_flash
-                       else blockwise_attention(q, k, v, **masks))
+                attend = flash_attention if self.use_flash \
+                    else blockwise_attention
+                out = attend(q, k, v, sm_scale=sm_scale, **masks)
                 if self.window is not None or diffusion is not None:
                     blocks = mask_blocks(s, head_dim, **masks) \
                         if self.use_flash else None
@@ -959,7 +1007,8 @@ class Attention(nn.Module):
             if chosen is not None:
                 with jax.named_scope("hvd_dsa_kl"):
                     kl = indexer_kl(*indexed, scores, chosen.chosen,
-                                    head_probs(q, k, lse, chosen.chosen))
+                                    head_probs(q, k, lse, chosen.chosen,
+                                               sm_scale))
                 for name, count in zip(SELECTION_COUNTS, chosen[1:]):
                     self.sow("intermediates", "dsa_" + name, count)
                 self.sow("intermediates", "dsa_selection", chosen.chosen)

@@ -408,6 +408,9 @@ def test_trinity_step_is_banded_and_causal_kernels_and_a_named_gate(
         text, ("hvd_attn_qkv", "hvd_attn_attend", "hvd_attn_gate",
                "hvd_attn_out", "hvd_mlp", "hvd_moe_router", "hvd_moe_shared",
                "hvd_embed", "hvd_lm_head"))
+    # q of the windowed layer, once a direction; the full layer does not turn
+    # and keeps the norm alone.
+    assert _prep_kernels(text)[0] == (1, 1)
 
 
 
@@ -466,6 +469,10 @@ def test_mellum_step_recomputes_its_layers_under_jaxs_marker(
     _assert_scopes_forward_and_backward(
         text, ("hvd_attn_qkv", "hvd_attn_rotate", "hvd_attn_attend",
                "hvd_attn_out", "hvd_moe_router", "hvd_embed", "hvd_lm_head"))
+    # q of each of the two layers: forward twice (the recomputation),
+    # backward once; the key heads keep `rope`'s products.
+    calls, swaps = _prep_kernels(text)
+    assert calls == (4, 2) and swaps
 
 
 @pytest.mark.parametrize("heads,kv_heads,head_dim",
@@ -568,3 +575,242 @@ def test_sdar_step_is_blockdiff_kernels_and_a_named_loss(v5e, monkeypatch):
         text, ("hvd_attn_qkv", "hvd_attn_attend", "hvd_attn_out",
                "hvd_moe_router", "hvd_embed", "hvd_lm_head",
                "hvd_diffusion_loss"))
+    assert _prep_kernels(text)[0] == (2, 2)
+
+
+def _prep_kernels(text):
+    """(forward, backward) calls of `ops.attn_prep`'s kernels in a compiled
+    text, and the `rope` products (the signed pair permutation's) under the
+    attention's scopes."""
+    calls = tuple(len(re.findall(rf"%hvd_attn_prep_{way}[.\d]* = ", text))
+                  for way in ("fwd", "bwd"))
+    swaps = [path for path in re.findall(r'op_name="([^"]*)"', text)
+             if "hvd_attn_" in path and "...d,de->...e" in path]
+    return calls, swaps
+
+
+def _pair_swaps(text, heads):
+    """The products with the (128, 128) signed pair permutation of a tensor
+    ``heads`` heads of 128 wide in a lowered text."""
+    return len(re.findall(
+        rf"dot_general .*tensor<1x{heads}x\d+x128xbf16>, "
+        r"tensor<128x128xbf16>", text))
+
+
+def _small_prepared_models():
+    """name -> (`TransformerLM`'s keywords, `_lowered_step`'s further
+    arguments): 8 heads of 128 on 2 key/value heads with the per-head norms,
+    the layer kinds and the rotations of the four cells whose q takes
+    `ops.attn_prep`'s pass."""
+    from horovod_tpu.models import (MoEConfig, RopeScaling,
+                                    masked_diffusion_loss)
+    from horovod_tpu.models.transformer import IndexerConfig
+
+    sizes = dict(vocab_size=2048, d_model=512, n_heads=8, n_kv_heads=2,
+                 head_dim=128, head_norm=True, dtype=jnp.bfloat16,
+                 logits_dtype=jnp.bfloat16, use_flash=True)
+    experts = MoEConfig(64, 8, 256, (0, 8), 1.5, renormalize=True)
+
+    def diffusion(model):
+        def loss_fn(params, batch):
+            tokens, noised, masked, level = batch
+            return masked_diffusion_loss(
+                model.apply({"params": params}, tokens, noised=noised),
+                tokens, masked, level)
+        return dict(loss_fn=loss_fn, noised=True,
+                    batch=("tokens", "tokens", jnp.bool_, jnp.float32))
+
+    return {
+        # Both kinds, each at its own table, every entry recomputed: three
+        # prepared layers, five forward calls in the program.
+        "mellum": (dict(
+            sizes, layers=("window_attention", "experts", "attention",
+                           "experts", "window_attention"),
+            window=512, rope_theta=5e5, rope_scaling=RopeScaling(16, 8192),
+            window_rope=(5e5, None), recompute=True, moe=experts), None),
+        # Two banded layers that turn and a full one that does not.
+        "trinity": (dict(
+            sizes, layers=("window_attention", "gated_mlp", "attention",
+                           "window_attention", "experts"),
+            d_ff=1024, norm_eps=1e-5, rope=False, window=512, attn_gate=True,
+            post_norm=True, embed_scale=512 ** 0.5,
+            moe=experts._replace(scoring="sigmoid")), None),
+        "sdar": (dict(sizes, layers=("blockdiff_attention", "experts") * 2,
+                      block_diffusion=4, rope_theta=1e6, moe=experts),
+                 diffusion),
+        "keye": (dict(sizes, layers=("selected_attention", "experts"),
+                      rope_theta=1e7, indexer=IndexerConfig(4, 64, 512),
+                      moe=experts), None),
+    }
+
+
+@pytest.mark.parametrize("cell", ["mellum", "trinity", "sdar", "keye"])
+def test_qs_norm_and_turn_is_one_traced_body_each_way(v5e, monkeypatch, cell):
+    """The tracing bill (ROADMAP S6): a small model of each of the four cells
+    whose q takes `ops.attn_prep`'s pass, lowered once through
+    `build_train_step` for the described chip, adds to the process's kernels
+    table ONE `hvd_attn_prep_fwd` entry for the step and one for the model's
+    `init` at 128 rows, and ONE `hvd_attn_prep_bwd` entry — whatever the
+    number of layers and of kinds, recomputed or not; the lowered text holds
+    both kernels, once a function the layers call, no product of q
+    with the signed pair permutation, and the key heads' `rope` products,
+    forward and backward, as before."""
+    from horovod_tpu.common.metrics import setup_table
+    from horovod_tpu.models import TransformerLM
+    from horovod_tpu.ops import attn_prep
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    keywords, further = _small_prepared_models()[cell]
+    model = TransformerLM(**keywords)
+    rows = 2048
+    # What another test of this process traced is not traced again.
+    attn_prep._fwd_call.clear_cache()
+    attn_prep._bwd_call.clear_cache()
+
+    def entries():
+        table = setup_table.process()["kernels"]
+        return {name: table.get(name, {"calls": 0})["calls"]
+                for name in ("hvd_attn_prep_fwd", "hvd_attn_prep_bwd")}
+
+    before = entries()
+    lowered = _lowered_step(model, v5e[:1], (1, rows),
+                            **(further(model) if further else {}))[2]
+    added = {name: n - before[name] for name, n in entries().items()}
+    assert added == {"hvd_attn_prep_fwd": 2, "hvd_attn_prep_bwd": 1}
+    text = lowered.as_text(debug_info=True)
+    # One function a direction, which the layers call (the recomputed
+    # forward is lowered as a function of its own).
+    for name, most in (("hvd_attn_prep_fwd", 1 + bool(model.recompute)),
+                       ("hvd_attn_prep_bwd", 1)):
+        held = [line for line in text.splitlines()
+                if "custom_call @tpu_custom_call" in line and name in line]
+        assert 1 <= len(held) <= most, (name, len(held))
+    assert _pair_swaps(text, model.n_heads) == 0
+    turned = sum(kind != "attention" or model.rope for kind in model.layers
+                 if kind.endswith("attention"))
+    assert _pair_swaps(text, model.n_kv_heads) >= 2 * turned
+    assert re.search(r'hvd_attn_qkv/[^"]*hvd_attn_rotate', text)
+    assert "hvd_attn_attend/hvd_attn_rotate" not in text
+
+
+def _composed_layers():
+    return {
+        "qwen3next": dict(n_heads=16, n_kv_heads=2, head_dim=256, gate=True,
+                          rope_theta=1e7, rotary_dim=64),
+        "rope_off": dict(n_heads=32, n_kv_heads=4, head_dim=128, rope=False,
+                         gate=True, norm_eps=1e-5),
+        "decode": dict(n_heads=32, n_kv_heads=4, head_dim=128),
+        "ring": dict(n_heads=32, n_kv_heads=4, head_dim=128, seq_axis="sp"),
+    }
+
+
+@pytest.mark.parametrize("cell", ["qwen3next", "rope_off", "decode", "ring"])
+def test_layers_that_keep_the_composition_hold_neither_kernel(
+        v5e, monkeypatch, cell):
+    """A grouped, per-head-normed `Attention` over 2,048 rows lowered for the
+    described chip where `attn_prep.prep_rows` refuses: Qwen3-Next's heads
+    of 256 with 64 channels turned, a layer that does not turn (Trinity's
+    full ones), a cached decode (positions a batch row) and a ring step
+    (`seq_axis`).  Neither kernel's name is in the text, and where the layer
+    turns, `rope`'s products are."""
+    from jax import shard_map
+    from jax.sharding import Mesh, SingleDeviceSharding
+
+    from horovod_tpu.models.transformer import Attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    seq, d_model = 2048, 1024
+    layer = Attention(dtype=jnp.bfloat16, use_flash=True, head_norm=True,
+                      **_composed_layers()[cell])
+    mesh = Mesh(np.array(v5e).reshape(1, 4), ("dp", "sp"))
+    on_chip = NamedSharding(mesh, P()) if cell == "ring" \
+        else SingleDeviceSharding(v5e[0])
+    rows = NamedSharding(mesh, P("dp", "sp", None)) if cell == "ring" \
+        else on_chip
+
+    def shaped(tree, sharding=on_chip):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+
+    plain = layer.clone(seq_axis=None)      # the parameters need no mesh
+    x = jax.ShapeDtypeStruct((1, seq, d_model), jnp.bfloat16, sharding=rows)
+    params = shaped(jax.eval_shape(
+        lambda: plain.init(jax.random.PRNGKey(0),
+                           jnp.zeros(x.shape, x.dtype))["params"]))
+
+    def apply(params, x, *ctx):
+        out = layer.apply({"params": params}, x, *ctx,
+                          mutable=["intermediates"])[0]
+        return out[0] if ctx else out
+
+    if cell == "decode":
+        heads, ctx_len = layer.n_heads, 1024
+        ctx = shaped((
+            jnp.zeros((1, heads, ctx_len, 128), jnp.bfloat16),
+            jnp.zeros((1, heads, ctx_len, 128), jnp.bfloat16),
+            jnp.zeros((1, ctx_len), bool), jnp.zeros((1, seq), jnp.int32)))
+        text = jax.jit(apply).lower(params, x, ctx).as_text(debug_info=True)
+    else:
+        if cell == "ring":
+            apply = shard_map(apply, mesh=mesh,
+                              in_specs=(P(), P("dp", "sp", None)),
+                              out_specs=P("dp", "sp", None))
+
+        def loss(params, x):
+            with jax.named_scope("hvd_loss"):
+                return apply(params, x).astype(jnp.float32).sum()
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, x).as_text(debug_info=True)
+    assert "hvd_attn_prep" not in text
+    assert ("...d,de->...e" in text) == layer.rope
+
+
+def _equations(jaxpr):
+    """The equations of a jaxpr and of every jaxpr its equations hold."""
+    inner = [getattr(value, "jaxpr", value)
+             for eqn in jaxpr.eqns for held in eqn.params.values()
+             for value in (held if isinstance(held, (list, tuple))
+                           else [held])]
+    return len(jaxpr.eqns) + sum(_equations(j) for j in inner
+                                 if hasattr(j, "eqns"))
+
+
+def _kernel_body(fn, *operands):
+    """The jaxpr of the first `pallas_call` in ``fn(*operands)``."""
+    def found(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn.params["jaxpr"]
+            for held in eqn.params.values():
+                inner = getattr(held, "jaxpr", held)
+                if hasattr(inner, "eqns") \
+                        and (body := found(inner)) is not None:
+                    return body
+    return found(jax.make_jaxpr(fn)(*operands).jaxpr)
+
+
+@pytest.mark.parametrize("rows,bound", [(128, (40, 60)), (2048, (90, 125))],
+                         ids=["one_chunk", "a_tile_of_eight"])
+def test_a_prep_body_is_not_written_out_a_chunk(rows, bound):
+    """What Python traces for a tile: a body of eight chunks holds two chunks'
+    equations and a loop's (the first chunk's row sums, the loop's step, the
+    last chunk's turn), forward 67 and backward 95 where one chunk is 32 and
+    47 — eight written out would be some 260 and 380, and sixteen of 128 rows
+    took the chip machine's host 1.2 to 2.1 s a backward body (PERF.md
+    section 6, PR 62 and PR 63).  A later change that unrolls the body in
+    Python again fails here, not on the driver's `setup_s`."""
+    from horovod_tpu.ops import attn_prep
+
+    x = jax.ShapeDtypeStruct((2, 4096, 128), jnp.bfloat16)
+    scale = jax.ShapeDtypeStruct((128,), jnp.float32)
+    table = jax.ShapeDtypeStruct((4096, 128), jnp.float32)
+    forward = _kernel_body(
+        lambda x, s, c, si: attn_prep._fwd_call(x, s, c, si, 1e-6, rows,
+                                                False), x, scale, table, table)
+    backward = _kernel_body(
+        lambda d, x, s, c, si: attn_prep._bwd_call(d, x, s, c, si, 1e-6, rows,
+                                                   False),
+        x, x, scale, table, table)
+    assert _equations(forward) <= bound[0], _equations(forward)
+    assert _equations(backward) <= bound[1], _equations(backward)

@@ -64,7 +64,9 @@ def test_unset_the_pattern_lowers_to_the_parents_program():
 # `nemotron` and `qwen3next` again at PR 59, whose Mamba-2 and head-gated
 # delta mixers open with `models.ssm.mixer_opening`: their parameters'
 # digests held, and `ling`'s channel gate kept both of its own; `qwen3next`'s
-# text once more at PR 61, whose head form solves in two kernels): of
+# text once more at PR 61, whose head form solves in two kernels; all seven
+# held at PR 63, whose pass of q through `ops.attn_prep` engages at heads of
+# 128 turned whole and at none of these small widths): of
 # `jax.jit(grad).lower(...).as_text()` and of the parameters seeded from
 # `PRNGKey(0)` (paths, shapes, types, bytes).  Bfloat16, as the cells run.
 FAMILY_SIZES = dict(vocab_size=256, d_model=64, n_heads=8,
@@ -167,6 +169,7 @@ def test_a_familys_pattern_lowers_to_the_parents_program(family):
     for path, leaf in jax.tree_util.tree_leaves_with_path(seeded):
         tree.update(f"{jax.tree_util.keystr(path)} {leaf.shape} "
                     f"{leaf.dtype} ".encode() + np.asarray(leaf).tobytes())
+    assert "hvd_attn_prep" not in text
     assert (hashlib.sha256(text.encode()).hexdigest(), tree.hexdigest()) \
         == FAMILY_DIGESTS[family]
 
