@@ -1,0 +1,11 @@
+"""Device time of the chunked scan's `ends` stage — each chunk's own end state,
+`(decay . B)^T x` — under `hvd_ssm_scan_ends`, forward and backward, over
+the time of all operations: one of the four parts of the time under
+`hvd_ssm_scan`.  Source: device trace, sorted by the compiled step's
+op_name."""
+
+from benchmark.layer_metrics import _granite
+
+
+def read(run: dict):
+    return _granite.stage_share_pct(run, "ends")

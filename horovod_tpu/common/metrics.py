@@ -515,6 +515,11 @@ class MetricsRegistry:
         # beta_scale=)): the (token, head) steps with beta over 1, and all of
         # them, a list a layer each (models.record_delta_steps).
         self._delta = {"beta_over_one": [], "beta_steps": []}
+        # Mamba-2 layers: of a pass's (sequence, chunk, head) triples, those
+        # that pass on more than models.ssm.CARRY_LIVE of the state that
+        # entered the chunk, and all of them, a list a layer each
+        # (models.record_ssm_carry).
+        self._ssm = {"chunks_carried": [], "chunks": []}
         # What the compiler made of the last compiled training step's
         # gradient exchange (jax/train.py `_TimedStep.exchange_overlap`),
         # and under "setup" what building its programs cost
@@ -638,6 +643,13 @@ class MetricsRegistry:
         with self._lock:
             self._delta = {"beta_over_one": [int(n) for n in beta_over_one],
                            "beta_steps": [int(n) for n in beta_steps]}
+
+    def set_ssm_carry(self, chunks_carried, chunks) -> None:
+        """Mirror one forward pass's count of the chunks and heads that carry
+        state on, per Mamba-2 layer (overwritten: one batch's)."""
+        with self._lock:
+            self._ssm = {"chunks_carried": [int(n) for n in chunks_carried],
+                         "chunks": [int(n) for n in chunks]}
 
     def set_train_step(self, exchange_overlap: dict, setup: dict) -> None:
         """Mirror a compiled training step's account of itself: whether it
@@ -999,6 +1011,8 @@ class MetricsRegistry:
                               self._attention.items()},
                 "delta": {name: list(steps) for name, steps in
                           self._delta.items()},
+                "ssm": {name: list(chunks) for name, chunks in
+                        self._ssm.items()},
                 "train_step": dict(
                     self._train_step,
                     setup=copy_step_setup(self._train_step["setup"])),
@@ -1214,6 +1228,17 @@ def prometheus_text(snapshot: dict) -> str:
     for kind, key in (("over_one", "beta_over_one"), ("all", "beta_steps")):
         for layer, n in enumerate(delta.get(key, [])):
             out.append(f'hvd_tpu_delta_steps{{layer="{layer}",'
+                       f'kind="{kind}"}} {n}')
+
+    ssm = snapshot.get("ssm", {})
+    out.append("# HELP hvd_tpu_ssm_chunks (sequence, chunk, head) triples of "
+               "one forward pass in each Mamba-2 layer: those that pass on "
+               "more than a thousandth of the state that entered the chunk, "
+               "and all of them")
+    out.append("# TYPE hvd_tpu_ssm_chunks gauge")
+    for kind, key in (("carried", "chunks_carried"), ("all", "chunks")):
+        for layer, n in enumerate(ssm.get(key, [])):
+            out.append(f'hvd_tpu_ssm_chunks{{layer="{layer}",'
                        f'kind="{kind}"}} {n}')
 
     step = snapshot.get("train_step", {})

@@ -41,6 +41,7 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     record_delta_steps,
     record_exit_distribution,
     record_expert_rows,
+    record_ssm_carry,
     router_losses,
 )
 from horovod_tpu.models.vgg import VGG11, VGG16, VGG19  # noqa: F401

@@ -1,5 +1,6 @@
-"""The Mamba-2 mixer (Dao & Gu, arXiv:2405.21060) as Nemotron-H's layers run
-it, told which heads it holds.
+"""The Mamba-2 mixer (Dao & Gu, arXiv:2405.21060) as the published hybrids'
+layers run it (Nemotron-H's and Granite-4.0-H's alike: the equations below,
+at their own sizes), told which heads it holds.
 
 With ``u`` the layer's normed input, H heads of P channels, G groups of N
 states (``[z, xBC, dt] = u W_in``: ``z`` of width HP, ``xBC`` of HP + 2GN,
@@ -18,6 +19,17 @@ the convolution and of the norm, their rows of ``W_out`` — the local part of a
 layer that is tensor-parallel over ``n`` chips (what ``n_groups`` is for: the
 norm never crosses a group, so nothing but the sum of the ``n`` outputs is
 exchanged, and that sum is the caller's).  ``(0, 1)`` is the whole layer.
+A layer of ONE group (``Mamba2Config(groups=1)``: every head reads the same
+``B_t``, ``C_t``, and the gated norm is over all HP channels) has no such
+share: ``groups % n`` refuses it, because B and C would have to be held by
+every chip and the norm's statistic exchanged.
+
+What a layer costs a token, at the two points the benchmark runs
+(``ops/ssm.py`` has the scan's): 16 heads of 64 on one group of 128 states at
+d_model 4,096 (an eighth of Nemotron-3-Super's layer) 13.7 M multiply-adds in
+the two projections, 1,280 channels through the convolution and 1,024 through
+the gated norm; 64 heads of 64 on one group of 128 at d_model 2,048
+(Granite-4.0-H-Micro's whole layer) 25.8 M, 4,352 and 4,096.
 """
 
 from __future__ import annotations
@@ -49,9 +61,15 @@ class Mamba2Config(NamedTuple):
 
 
 # Mamba-2's seeding of the step size: softplus(dt_bias) log-uniform over
-# DT_RANGE and at least DT_FLOOR (Nemotron-H's time_step_min, _max, _floor).
+# DT_RANGE and at least DT_FLOOR (the published layers' time_step_min, _max,
+# _floor).
 DT_RANGE = (0.001, 0.1)
 DT_FLOOR = 1e-4
+
+
+# A chunk and head CARRIES where more than this much of the state that entered
+# the chunk leaves it (``exp`` of the chunk's summed ``dt A``).
+CARRY_LIVE = 1e-3
 
 
 def _dt_bias_init(key, shape, dtype=jnp.float32):
@@ -208,8 +226,13 @@ class Mamba2Mixer(nn.Module):
     float32 inside, x, B and C leave it ROUNDED to ``dtype``, once, and its
     written-out backward keeps the projection's output as stored and nothing
     else of a token's), ``hvd_ssm_scan``, ``hvd_ssm_gate_norm``,
-    ``hvd_ssm_out_proj``.  Writes ``ssm_chunk_log_decay_min`` to the
-    ``intermediates`` collection where the caller makes it mutable."""
+    ``hvd_ssm_out_proj``; the scan's four stages under ``hvd_ssm_scan_<stage>``
+    beneath its own (``ops.ssm.STAGES``).  Writes to the ``intermediates``
+    collection, where the caller makes it mutable, ``ssm_chunk_log_decay_min``
+    (the most negative summed ``dt A`` of any chunk and head), and of all
+    (sequence, chunk, head) triples ``ssm_chunks`` how many pass on more than
+    :data:`CARRY_LIVE` of the state that entered, ``ssm_chunks_carried``
+    (:func:`~horovod_tpu.models.record_ssm_carry` reads them a layer)."""
 
     heads: int
     head_dim: int
@@ -257,14 +280,17 @@ class Mamba2Mixer(nn.Module):
                 xbc, w_conv, b_conv,
                 ((1, inner, None), (1, bc, None), (1, bc, None)))
         with jax.named_scope("hvd_ssm_scan"):
-            y, decay_min = chunked_scan(
+            y, whole = chunked_scan(
                 x.reshape(batch, seq, heads, self.head_dim),
                 nn.softplus(dt.astype(jnp.float32) + dt_bias),
                 -jnp.exp(a_log),
                 B.reshape(batch, seq, groups, self.state),
                 C.reshape(batch, seq, groups, self.state), skip,
                 min(self.chunk, seq))
-            self.sow("intermediates", "ssm_chunk_log_decay_min", decay_min)
+            self.sow("intermediates", "ssm_chunk_log_decay_min", whole.min())
+            self.sow("intermediates", "ssm_chunks_carried",
+                     jnp.sum(jnp.exp(whole) > CARRY_LIVE))
+            self.sow("intermediates", "ssm_chunks", jnp.int32(whole.size))
         with jax.named_scope("hvd_ssm_gate_norm"):
             gated = (y.reshape(batch, seq, groups, -1)
                      * nn.silu(z.astype(jnp.float32)).reshape(

@@ -725,6 +725,10 @@ class Attention(nn.Module):
     # A head's width where it is not ``d_model // n_heads`` (the projections
     # are then ``n_heads * head_dim`` wide, not ``d_model``).
     head_dim: Optional[int] = None
+    # The softmax's scale, ``softmax(sm_scale q k^T)``, where it is not
+    # ``head_dim ** -0.5`` (a muP model's ``attention_multiplier``).  On one
+    # sequence shard: the ring has its own.
+    sm_scale: Optional[float] = None
     # A sliding window: query ``t`` sees the keys ``s`` with ``0 <= t - s <
     # window`` (:func:`~horovod_tpu.ops.flash_attention`, whose kernels
     # neither compute nor fetch a block wholly outside that band).  A layer
@@ -861,6 +865,9 @@ class Attention(nn.Module):
         b, s, d = x.shape
         head_dim = self.head_dim or d // self.n_heads
         n_heads = self.n_heads // self.head_shard[1]
+        if self.sm_scale is not None and self.seq_axis is not None:
+            raise ValueError("sm_scale= does not compose with sequence "
+                             "parallelism")
         if (self.window is not None or self.block_diffusion is not None
                 or self.rotary_dim is not None) \
                 and (decode_ctx is not None or self.seq_axis is not None):
@@ -910,8 +917,10 @@ class Attention(nn.Module):
         # The kernels scale q by the power of two in ``head_dim ** -0.5`` on
         # its way in, a pass of its own behind a custom call: the one pass
         # takes it along, exactly, and the kernels are told what is left.
-        q_factor, sm_scale = _split_scale(head_dim ** -0.5) if prepared \
-            else (1.0, None)
+        own_scale = head_dim ** -0.5 if self.sm_scale is None \
+            else self.sm_scale
+        q_factor, sm_scale = _split_scale(own_scale) if prepared \
+            else (1.0, self.sm_scale)
         with jax.named_scope("hvd_attn_qkv"):
             if grouped:
                 q, k, v = self._grouped_projections(
@@ -976,8 +985,7 @@ class Attention(nn.Module):
                 ], axis=-1)
                 keys = jnp.concatenate([k_ctx.astype(k.dtype), k], axis=-2)
                 vals = jnp.concatenate([v_ctx.astype(v.dtype), v], axis=-2)
-                out = _decode_attention(q, keys, vals, mask,
-                                        head_dim ** -0.5)
+                out = _decode_attention(q, keys, vals, mask, own_scale)
                 new_kv = (k, v)
             elif self.seq_axis is not None:
                 if self.capture_kv:
@@ -1274,7 +1282,7 @@ def _attention_kind(passes=None, rotated=False, own_rope=False) -> LayerKind:
     """The row of a kind that runs :class:`Attention` at the model's sizes
     (``n_heads``, ``n_kv_heads``, ``head_dim``, ``head_shard``,
     ``head_shard_axis``, ``qk_norm``, ``head_norm``, ``attn_gate``,
-    ``rotary_dim``).  The kinds differ in data:
+    ``rotary_dim``, ``attn_scale``).  The kinds differ in data:
     ``passes``, the ONE of ``window`` / ``block_diffusion`` / ``indexer`` the
     kind hands on (and wants set; the other two stay unset whatever the model
     holds); ``rotated``, whether it turns even where the model's ``rope`` is
@@ -1290,7 +1298,7 @@ def _attention_kind(passes=None, rotated=False, own_rope=False) -> LayerKind:
             rope=o.rope or rotated, rope_theta=theta, rope_scaling=scaling,
             rotary_dim=o.rotary_dim, head_shard=o.head_shard,
             head_shard_axis=o.head_shard_axis, head_dim=o.head_dim,
-            head_norm=o.head_norm, gate=o.attn_gate,
+            head_norm=o.head_norm, gate=o.attn_gate, sm_scale=o.attn_scale,
             **({passes: getattr(o, passes)} if passes else {}))
 
     return LayerKind(Attention, arguments, wants=passes)
@@ -1364,7 +1372,9 @@ class MixerLayer(nn.Module):
     output normed again (``post_norm``'s own scale) before the add; ``"only"``
     ``x + RMSNorm(mixer(x))``, the output's norm alone on a mixer that reads
     the bare residual stream (the parameter tree then has no ``norm``); a
-    published layer of two sublayers is two consecutive entries.  ``options``
+    published layer of two sublayers is two consecutive entries;
+    ``options.residual_scale``, where set, multiplies what joins the stream,
+    ``x + residual_scale * (...)``.  ``options``
     is the model's :class:`LayerOptions`; ``kind`` is a key of
     :data:`LAYER_KINDS`, whose row says what the mixer is built from: """
         + ", ".join(f"``{kind!r}`` {row.mixer.__name__}"
@@ -1412,6 +1422,8 @@ class MixerLayer(nn.Module):
         if normed_out:
             out = nn.RMSNorm(epsilon=o.norm_eps, dtype=o.dtype,
                              name="post_norm")(out)
+        if o.residual_scale is not None:
+            out = out * o.residual_scale
         return x + out
 
 
@@ -1508,6 +1520,26 @@ class TransformerLM(nn.Module):
     # a pattern on one sequence shard only.
     loops: Optional[int] = None
     exit_gate: bool = False
+    # A muP model's three other multipliers and its tied head (Granite's
+    # ``residual_multiplier``, ``logits_scaling``, ``attention_multiplier``,
+    # ``tie_word_embeddings``; ``embedding_multiplier`` is ``embed_scale``).
+    # Unset, nothing of them is traced and no parameter changes its name.
+    # ``tie_head``: the head reads the EMBEDDING's table, ``logits = x e^T``;
+    # the tree has no ``lm_head_kernel``, and the table's gradient is the sum
+    # of the lookup's and the head's, one AdamW state (blocks and patterns,
+    # ``targets=`` too).  ``logits_divisor``: the head's float32 product is
+    # DIVIDED by it before it is stored or exponentiated, on every path
+    # (``_head_logits``, a looped model's per-pass head,
+    # :func:`fused_next_token_loss`).  ``residual_scale``: every pattern
+    # entry's output times it before it joins the stream
+    # (:class:`MixerLayer`).  ``attn_scale``: the softmax scale of a
+    # pattern's :class:`Attention` layers in place of ``head_dim ** -0.5``
+    # (``Attention(sm_scale=)``).  The last two are a pattern's: a model of
+    # blocks refuses them.
+    tie_head: bool = False
+    residual_scale: Optional[float] = None
+    logits_divisor: Optional[float] = None
+    attn_scale: Optional[float] = None
 
     @nn.compact
     def __call__(self, tokens, targets=None, decode_ctx=None, noised=None):
@@ -1538,8 +1570,12 @@ class TransformerLM(nn.Module):
                 "decode_ctx= (cached KV decode) composes with neither "
                 "targets= nor sequence parallelism: decode is an "
                 "inference-only, single-shard path (docs/inference.md).")
+        if self.layers is None and (self.residual_scale is not None
+                                    or self.attn_scale is not None):
+            raise ValueError("residual_scale= and attn_scale= are read by a "
+                             "per-layer pattern's layers (layers=).")
         options = self._layer_options()
-        x = self._embedded(tokens)
+        x, embed = self._embedded(tokens)
         new_ks, new_vs = [], []
         if self.layers is not None:
             x = self._pattern(x, options)
@@ -1560,11 +1596,13 @@ class TransformerLM(nn.Module):
             x = x[:, noised.shape[1]:]      # the clean copy is context alone
         x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                        name="final_norm")(x)
-        w = self._head_kernel()
+        w = self._head_kernel(embed)
         if targets is not None:
             # Fused head+loss: see fused_next_token_loss.
-            return fused_next_token_loss(x, w, targets, dtype=self.dtype)
-        logits = _head_logits(x, w, self.dtype, self.logits_dtype)
+            return fused_next_token_loss(x, w, targets, dtype=self.dtype,
+                                         logits_divisor=self.logits_divisor)
+        logits = _head_logits(x, w, self.dtype, self.logits_dtype,
+                              self.logits_divisor)
         if decode_ctx is not None:
             # (n_layers, batch, heads, new_len, head_dim) each: the new
             # chunk's K/V for the caller to persist into its cache.
@@ -1581,19 +1619,25 @@ class TransformerLM(nn.Module):
 
     @nn.nowrap
     def _embedded(self, tokens):
+        """(the tokens' rows, scaled where ``embed_scale`` says; the table's
+        module, for a tied head)."""
         with jax.named_scope("hvd_embed"):
-            x = TokenEmbed(self.vocab_size, self.d_model,
-                           dtype=self.dtype, name="embed")(tokens)
+            embed = TokenEmbed(self.vocab_size, self.d_model,
+                               dtype=self.dtype, name="embed")
+            x = embed(tokens)
             if self.embed_scale is not None:
                 x = (x * self.embed_scale).astype(self.dtype)
-        return x
+        return x, embed
 
     @nn.nowrap
-    def _head_kernel(self):
+    def _head_kernel(self, embed):
         """The head's parameter, float32: the logits accumulate in float32
         for a numerically stable softmax, but the matmul runs in bfloat16 on
         the MXU (an f32xf32 matmul costs multiple MXU passes, and the lm_head
-        is ~1/3 of the model's FLOPs at vocab 32k)."""
+        is ~1/3 of the model's FLOPs at vocab 32k).  Under ``tie_head`` the
+        table of ``embed``, transposed: no parameter of the head's own."""
+        if self.tie_head:
+            return embed.embedding.T
         return self.param(
             "lm_head_kernel",
             nn.initializers.variance_scaling(1.0, "fan_in",
@@ -1620,8 +1664,8 @@ class TransformerLM(nn.Module):
                 "block_diffusion= nor sequence parallelism, and takes "
                 "targets= only with exit_gate=True.")
         options = self._layer_options()
-        x = self._embedded(tokens)
-        w = self._head_kernel()
+        x, embed = self._embedded(tokens)
+        w = self._head_kernel(embed)
         gate = None
         if self.exit_gate:
             # z = w_g . h + b_g: at 1 / sqrt(d_model) an element over a normed
@@ -1645,10 +1689,12 @@ class TransformerLM(nn.Module):
                 # passes on the MXU.
                 z = (x.astype(jnp.float32) * gate[0]).sum(axis=-1) + gate[1]
             if targets is None:
-                return x, (_head_logits(x, w, model.dtype,
-                                        model.logits_dtype), z)
-            return x, (jax.checkpoint(_head_token_xent, static_argnums=(3, 4))(
-                x, w, targets, model.dtype, model.logits_dtype), z)
+                return x, (_head_logits(x, w, model.dtype, model.logits_dtype,
+                                        model.logits_divisor), z)
+            return x, (jax.checkpoint(
+                _head_token_xent, static_argnums=(3, 4, 5))(
+                    x, w, targets, model.dtype, model.logits_dtype,
+                    model.logits_divisor), z)
 
         x, out = nn.scan(
             one_pass, variable_broadcast="params",
@@ -1658,7 +1704,8 @@ class TransformerLM(nn.Module):
         if gate is not None:
             self.sow("intermediates", "exit_gate_logits", out[1])
             return out
-        return _head_logits(x, w, self.dtype, self.logits_dtype)
+        return _head_logits(x, w, self.dtype, self.logits_dtype,
+                            self.logits_divisor)
 
 
 def _model_fields_as_one_value():
@@ -1681,25 +1728,29 @@ def _model_fields_as_one_value():
 LayerOptions = _model_fields_as_one_value()
 
 
-def _head_logits(x, w, dtype, logits_dtype):
-    """The head's product as :class:`TransformerLM` runs it."""
+def _head_logits(x, w, dtype, logits_dtype, divisor=None):
+    """The head's product as :class:`TransformerLM` runs it: accumulated in
+    float32, divided there by ``divisor`` where there is one
+    (``logits_divisor``), stored in ``logits_dtype``."""
     with jax.named_scope("hvd_lm_head"):
-        return jnp.einsum("bsd,dv->bsv", x.astype(dtype), w.astype(dtype),
-                          preferred_element_type=jnp.float32).astype(
-                              logits_dtype)
+        logits = jnp.einsum("bsd,dv->bsv", x.astype(dtype), w.astype(dtype),
+                            preferred_element_type=jnp.float32)
+        if divisor is not None:
+            logits = logits / divisor
+        return logits.astype(logits_dtype)
 
 
-def _head_token_xent(x, w, targets, dtype, logits_dtype):
+def _head_token_xent(x, w, targets, dtype, logits_dtype, divisor=None):
     """Per-token cross-entropy ``(batch, seq)`` of one pass's state through
     the head; a looped model runs it under ``jax.checkpoint``, which keeps
     ``x`` and computes the logits again in the backward pass."""
-    logits = _head_logits(x, w, dtype, logits_dtype)
+    logits = _head_logits(x, w, dtype, logits_dtype, divisor)
     with jax.named_scope("hvd_token_xent"):
         return _token_xent(logits, targets)
 
 
 def fused_next_token_loss(hidden, w, targets, dtype=jnp.bfloat16,
-                          n_chunks: int = 8):
+                          n_chunks: int = 8, logits_divisor=None):
     """Mean cross-entropy computed head-chunk by head-chunk.
 
     The full-logits path materializes a ``(batch, seq, vocab)`` float32
@@ -1723,6 +1774,9 @@ def fused_next_token_loss(hidden, w, targets, dtype=jnp.bfloat16,
 
     Head and loss are one loop here, so the whole of it runs under the
     head's scope, ``hvd_lm_head``; there is no ``hvd_token_xent`` inside.
+    ``logits_divisor`` divides each chunk's float32 logits
+    (``TransformerLM(logits_divisor=)``); ``w`` is whatever the head reads, a
+    tied model's transposed table too.
     """
     B, S, D = hidden.shape
     tokens = B * S
@@ -1733,6 +1787,8 @@ def fused_next_token_loss(hidden, w, targets, dtype=jnp.bfloat16,
         x, t = xt
         logits = jnp.einsum("md,dv->mv", x.astype(dtype), wb,
                             preferred_element_type=jnp.float32)
+        if logits_divisor is not None:
+            logits = logits / logits_divisor
         lse = jax.nn.logsumexp(logits, axis=-1)
         correct = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
         return total + (lse - correct).sum(), None
@@ -1983,6 +2039,26 @@ def record_delta_steps(intermediates) -> dict:
             for kind in ("beta_over_one", "beta_steps")}
     if _metrics.registry.enabled:
         _metrics.registry.set_delta_steps(**seen)
+    return seen
+
+
+def record_ssm_carry(intermediates) -> dict:
+    """Read what the Mamba-2 layers counted into the ``intermediates``
+    collection of one ``apply(..., mutable=["intermediates"])`` — outside the
+    compiled step — and, when the metrics registry is on
+    (``HVD_TPU_METRICS=1``), mirror it into ``hvd.metrics_snapshot()["ssm"]``.
+    A list a Mamba-2 layer, over the batch: of the (sequence, chunk, head)
+    triples ``chunks`` the ``chunks_carried`` that pass on more than
+    ``models.ssm.CARRY_LIVE`` of the state that entered the chunk (``exp`` of
+    the chunk's summed ``dt A``): where few do, the chunk-by-chunk carry moves
+    nothing and the layer is local to its chunk.  A model without such a
+    layer gives two empty lists."""
+    from horovod_tpu.common import metrics as _metrics
+
+    seen = {kind: [int(n) for n in _sown(intermediates, "ssm_" + kind)]
+            for kind in ("chunks_carried", "chunks")}
+    if _metrics.registry.enabled:
+        _metrics.registry.set_ssm_carry(**seen)
     return seen
 
 

@@ -19,13 +19,34 @@ the states between chunks — is float32; the operands of the four products are
 rounded to ``x``'s dtype and accumulated in float32, as every matmul of the
 model is.  It is differentiable by autodiff of that form.
 
-The cost is memory passes, not arithmetic: 0.4 M multiply-adds a token of a
-16-head share beside the 13.7 M of the layer's two projections.
+The cost is memory passes, not arithmetic, for any caller: a token's masked
+decay matrix ``L`` is ``heads x chunk`` float32 and the ``mixed`` operand as
+many elements in ``x``'s dtype, written and read again forward and backward,
+beside ``heads x chunk x head_dim + 2 heads x state x head_dim + groups x chunk
+x state`` multiply-adds.  At the two points the benchmark runs: 16 heads on one
+group at a chunk of 128 (an eighth of Nemotron-3-Super's 128 heads in 8
+groups, its tensor share) 8 KB of ``L`` and 0.41 M multiply-adds a token
+beside the 13.7 M of the layer's two projections; 64 whole heads on ONE group
+at a chunk of 256 (Granite-4.0-H-Micro) 64 KB of ``L`` — sixteen times a
+token's residual stream of 2,048 bfloat16 — and 2.13 M beside 25.8 M.
+
+The four stages run under ``jax.named_scope``s a trace can read, forward and
+backward alike (:data:`STAGES`): ``hvd_ssm_scan_decay`` (the cumulative sums,
+the masked ``L``, ``to_end`` and their exponentials), ``hvd_ssm_scan_intra``
+(``dt x``, ``scores``, ``mixed``, the within-chunk product, the skip ``D x``),
+``hvd_ssm_scan_ends`` (each chunk's end state) and ``hvd_ssm_scan_carry`` (the
+chunk-by-chunk matrix, ``entering``, ``from_start`` and its sum into ``y``).
 """
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
+
+# The scopes under a caller's own (``hvd_ssm_scan``), ``hvd_ssm_scan_<stage>``.
+STAGES = ("decay", "intra", "ends", "carry")
 
 
 def _decay_between(log_decay_cumsum):
@@ -47,9 +68,11 @@ def chunked_scan(x, dt, A, B, C, D, chunk: int):
     positive (the softplus already applied); ``A`` (heads,) float32, negative;
     ``B``, ``C`` (batch, seq, groups, state), head ``j`` reading group
     ``j // (heads / groups)``; ``D`` (heads,) float32.  ``seq`` is a multiple
-    of ``chunk``.  Returns ``(y, chunk_log_decay_min)``: ``y`` float32 of
-    ``x``'s shape, and the most negative summed ``dt A`` of any chunk and
-    head — where ``exp`` of it underflows, nothing crosses that chunk."""
+    of ``chunk``.  Returns ``(y, whole)``: ``y`` float32 of ``x``'s shape, and
+    the summed ``dt A`` of every chunk and head, float32 (batch, chunks,
+    groups, heads per group) — ``exp`` of it is the share of the state
+    entering a chunk that leaves it; where that underflows, nothing crosses
+    the chunk."""
     batch, seq, heads, head_dim = x.shape
     groups, state = B.shape[2:]
     if seq % chunk or heads % groups:
@@ -58,41 +81,56 @@ def chunked_scan(x, dt, A, B, C, D, chunk: int):
                          f"{heads} heads")
     chunks, per_group = seq // chunk, heads // groups
     f32, wide = jnp.float32, dict(preferred_element_type=jnp.float32)
+    decay, intra, ends_of, carry = (
+        functools.partial(jax.named_scope, "hvd_ssm_scan_" + stage)
+        for stage in STAGES)
     # (batch, chunks, chunk, groups, heads per group, ...): a head's group is
     # an axis of its own, so that no B or C is repeated per head.
     xc = x.reshape(batch, chunks, chunk, groups, per_group, head_dim)
     dtc = dt.astype(f32).reshape(batch, chunks, chunk, groups, per_group)
     Bc = B.reshape(batch, chunks, chunk, groups, state)
     Cc = C.reshape(batch, chunks, chunk, groups, state)
-    log_decay = dtc * A.astype(f32).reshape(groups, per_group)
-    # (b, c, g, r, l): inclusive sums along the chunk.
-    within = jnp.cumsum(log_decay.transpose(0, 1, 3, 4, 2), axis=-1)
-    whole = within[..., -1]                                  # (b, c, g, r)
-    dt_x = (dtc[..., None] * xc.astype(f32)).astype(x.dtype)
-
+    with decay():
+        log_decay = dtc * A.astype(f32).reshape(groups, per_group)
+        # (b, c, g, r, l): inclusive sums along the chunk.
+        within = jnp.cumsum(log_decay.transpose(0, 1, 3, 4, 2), axis=-1)
+        whole = within[..., -1]                              # (b, c, g, r)
     # Within a chunk: y[i] = sum_{j <= i} (C_i . B_j) L[i, j] dt_j x_j.
-    scores = jnp.einsum("bcign,bcjgn->bcgij", Cc, Bc, **wide)
-    mixed = (scores[:, :, :, None] * _decay_between(within)).astype(x.dtype)
-    y = jnp.einsum("bcgrij,bcjgrp->bcigrp", mixed, dt_x, **wide)
+    with intra():
+        dt_x = (dtc[..., None] * xc.astype(f32)).astype(x.dtype)
+        scores = jnp.einsum("bcign,bcjgn->bcgij", Cc, Bc, **wide)
+        scores = scores[:, :, :, None]             # a group's, for its heads
+    with decay():
+        between = _decay_between(within)
+    with intra():
+        mixed = (scores * between).astype(x.dtype)
+        y = jnp.einsum("bcgrij,bcjgrp->bcigrp", mixed, dt_x, **wide)
 
     # Each chunk's own end state: sum_j exp(whole - within[j]) B_j (x) dt_j x_j.
-    to_end = jnp.exp(whole[..., None] - within)              # (b, c, g, r, l)
-    weighted = (to_end.transpose(0, 1, 4, 2, 3)[..., None]
-                * dt_x.astype(f32)).astype(x.dtype)
-    ends = jnp.einsum("bcjgn,bcjgrp->bcgrpn", Bc, weighted, **wide)
+    with decay():
+        to_end = jnp.exp(whole[..., None] - within)          # (b, c, g, r, l)
+    with ends_of():
+        weighted = (to_end.transpose(0, 1, 4, 2, 3)[..., None]
+                    * dt_x.astype(f32)).astype(x.dtype)
+        ends = jnp.einsum("bcjgn,bcjgrp->bcgrpn", Bc, weighted, **wide)
 
     # The state entering chunk c: sum_{c' < c} exp(sum_{c' < k < c} whole_k)
     # ends[c'].  One (chunks, chunks) matrix a head, strictly lower: with
     # P = [0, cumsum(whole)], the sum is P[c] - P[c' + 1].
-    across = jnp.cumsum(whole.transpose(0, 2, 3, 1), axis=-1)  # (b, g, r, c)
-    carried = _decay_between(
-        jnp.pad(across, [(0, 0)] * 3 + [(1, 0)]))[..., :-1, 1:]
-    entering = jnp.einsum("bgrcz,bzgrpn->bcgrpn", carried, ends,
-                          precision="highest")
+    with carry():
+        across = jnp.cumsum(whole.transpose(0, 2, 3, 1), axis=-1)  # (b,g,r,c)
+        carried = _decay_between(
+            jnp.pad(across, [(0, 0)] * 3 + [(1, 0)]))[..., :-1, 1:]
+        entering = jnp.einsum("bgrcz,bzgrpn->bcgrpn", carried, ends,
+                              precision="highest")
 
-    # What the entering state adds to token i: exp(within[i]) C_i . h.
-    from_start = jnp.einsum("bcign,bcgrpn->bcigrp", Cc,
-                            entering.astype(x.dtype), **wide)
-    y = y + from_start * jnp.exp(within).transpose(0, 1, 4, 2, 3)[..., None]
-    y = y + D.astype(f32).reshape(groups, per_group, 1) * xc.astype(f32)
-    return y.reshape(x.shape), whole.min()
+        # What the entering state adds to token i: exp(within[i]) C_i . h.
+        from_start = jnp.einsum("bcign,bcgrpn->bcigrp", Cc,
+                                entering.astype(x.dtype), **wide)
+    with decay():
+        from_zero = jnp.exp(within).transpose(0, 1, 4, 2, 3)[..., None]
+    with carry():
+        y = y + from_start * from_zero
+    with intra():
+        y = y + D.astype(f32).reshape(groups, per_group, 1) * xc.astype(f32)
+    return y.reshape(x.shape), whole

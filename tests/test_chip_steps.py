@@ -262,6 +262,43 @@ def test_hybrid_step_is_products_and_kernels_with_no_loop(v5e, monkeypatch):
                "hvd_attn_attend", "hvd_attn_out", "hvd_lm_head"))
 
 
+def test_granite_step_is_one_groups_scan_by_stage_under_a_tied_head(
+        v5e, monkeypatch):
+    """A Mamba-2 layer of 64 heads of 64 on ONE group at a chunk of 256, an
+    attention layer of 32 query heads on 8 key/value heads of 64 at a softmax
+    scale of its own, and the 8,192-wide gated MLP behind each, at
+    Granite-4.0-H-Micro's widths with its four multipliers, every entry
+    recomputing, through `build_train_step`, compiled for the described chip:
+    no `while` anywhere in the step, attention is the two flash kernels, the
+    tree has no head of its own, and the scan's four stages keep their scopes
+    in the compiled text's op_names forward and backward."""
+    from horovod_tpu.models import Mamba2Config, TransformerLM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(
+        vocab_size=2048, d_model=2048, n_heads=32, d_ff=8192,
+        dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16, use_flash=True,
+        norm_eps=1e-5, layers=("ssm", "gated_mlp", "attention", "gated_mlp"),
+        ssm=Mamba2Config(64, 64, 1, 128, 4, 256), n_kv_heads=8, head_dim=64,
+        rope=False, recompute=True, embed_scale=12.0, tie_head=True,
+        residual_scale=0.22, logits_divisor=8.0, attn_scale=1.0 / 64)
+    _, params, lowered = _lowered_step(model, v5e[:1])
+    assert set(params) == {"embed", "final_norm"} | {
+        f"layer_{i}" for i in range(4)}
+    text = lowered.compile().as_text()
+    assert not re.search(r"\bwhile\(", text)
+    assert len(re.findall(r"%hvd_flash_fwd[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%hvd_flash_bwd[.\d]* = ", text)) == 1
+    assert text.count('"tpu_custom_call"') == 2
+    _assert_scopes_forward_and_backward(
+        text, ("hvd_ssm_in_proj", "hvd_ssm_conv", "hvd_ssm_scan",
+               "hvd_ssm_scan_decay", "hvd_ssm_scan_intra",
+               "hvd_ssm_scan_ends", "hvd_ssm_scan_carry",
+               "hvd_ssm_gate_norm", "hvd_ssm_out_proj", "hvd_mlp",
+               "hvd_embed", "hvd_attn_qkv", "hvd_attn_attend",
+               "hvd_attn_out", "hvd_lm_head"))
+
+
 def test_ling_step_is_products_kernels_and_one_loop_a_pass(v5e, monkeypatch):
     """A Kimi-delta layer, a dense gated MLP, a latent-attention layer (4
     heads, 192 and 128 wide) and group-limited gated experts with a shared one

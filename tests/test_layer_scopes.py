@@ -35,6 +35,8 @@ CELLS = {
         "head", "embed", "mlp", "attn_proj", "moe"},
     "sdar30ba3b_1chip_ep8share_1x4k_noised": {
         "head", "embed", "attn_proj", "moe"},
+    "granite4hmicro_1chip_pp4share_1x8k": {
+        "head", "embed", "mlp", "attn_proj", "ssm"},
 }
 
 _ALIAS = re.compile(r'^(#loc\d*) = loc\((.*)\)$')
@@ -327,6 +329,50 @@ def test_gated_delta_layers_name_their_stages_under_scopes_of_their_own(
     for path in paths:
         if "/layer_0/" in path and "/mixer/" in path:
             assert _GDN.search(path), path
+
+
+SSM_STAGES = ("decay", "intra", "ends", "carry")      # ops.ssm.STAGES
+_SSM_STAGE = re.compile(r"(?:^|/)hvd_ssm_scan_(%s)(?=/|$)"
+                        % "|".join(SSM_STAGES))
+
+
+@pytest.mark.parametrize("cell", [
+    "nemotron3super120b_1chip_tp8ep64share_1x4k",
+    "granite4hmicro_1chip_pp4share_1x8k"])
+def test_the_chunked_scans_four_stages_partition_its_scope(cell):
+    """Any caller of `ops.ssm.chunked_scan` (Nemotron's 16-head share on one
+    group of eight, Granite's 64 whole heads on its one group): every
+    operation under `hvd_ssm_scan`, a cast or a reshape too, lies under
+    exactly ONE of `hvd_ssm_scan_decay`, `_intra`, `_ends`, `_carry`, forward
+    and backward; the three that hold products hold them in both directions,
+    and `decay` holds none (sums and exponentials)."""
+    from horovod_tpu.ops.ssm import STAGES
+
+    assert STAGES == SSM_STAGES
+    text = lowered_step(cell).as_text(debug_info=True)
+    paths = scope_paths(text)
+    # What the mixer itself does under its scope: the reshapes of its
+    # operands, the softplus of dt, the sown counters.
+    under = [path for path in paths if "/hvd_ssm_scan/hvd_ssm_scan_" in path]
+    assert len(under) > 50
+    for path in under:
+        assert len(set(_SSM_STAGE.findall(path))) == 1, path
+    for direction in (FORWARD, BACKWARD):
+        assert {stage for path in under if direction in path
+                for stage in _SSM_STAGE.findall(path)} == set(STAGES)
+    products = {direction: set() for direction in (FORWARD, BACKWARD)}
+    for op, path in heavy_operations(text):
+        if "/hvd_ssm_scan/" in path and op == "dot_general":
+            stage = set(_SSM_STAGE.findall(path))
+            assert len(stage) == 1, (op, path)
+            products[BACKWARD if BACKWARD in path else FORWARD] |= stage
+    assert products[FORWARD] == products[BACKWARD] \
+        == {"intra", "ends", "carry"}
+    # Outside the four stages the scan's scope holds the mixer's own
+    # preparation alone: no product.
+    for op, path in heavy_operations(text):
+        if "/hvd_ssm_scan/" in path and not _SSM_STAGE.search(path):
+            assert op not in ("dot_general", "while", "custom_call"), path
 
 
 def test_a_looped_models_exits_and_the_layers_in_its_loop_name_themselves():

@@ -728,7 +728,8 @@ CELL_WAYS_BACK = {
     "ling3flash": (8192, "row_slabs"), "trinitymini": (8192, "held_pairs"),
     "sdar30ba3b": (8192, "held_pairs"), "qwen3next80b": (4096, "row_slabs"),
     "mellum2": (16384, "held_pairs"), "ouro2p6b": (None, None),
-    "keyevl2": (8192, "held_pairs"), "olmohybrid7b": (None, None)}
+    "keyevl2": (8192, "held_pairs"), "olmohybrid7b": (None, None),
+    "granite4hmicro": (None, None)}
 
 
 # Tokens a step a chip of every benchmark cell's configuration, and the
@@ -742,7 +743,8 @@ CELL_KERNELS = {
     "ling3flash": (8192, "ragged_dot"), "trinitymini": (8192, "ragged_dot"),
     "sdar30ba3b": (8192, "tiled"), "qwen3next80b": (4096, "ragged_dot"),
     "mellum2": (16384, "tiled"), "ouro2p6b": (None, None),
-    "keyevl2": (8192, "tiled"), "olmohybrid7b": (None, None)}
+    "keyevl2": (8192, "tiled"), "olmohybrid7b": (None, None),
+    "granite4hmicro": (None, None)}
 
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
