@@ -44,7 +44,7 @@ import numpy as np
 from jax import lax
 
 from horovod_tpu.ops.moe import reduced_to_vma_of
-from horovod_tpu.ops.ssm import chunked_scan
+from horovod_tpu.ops.ssm import chunked_scan, held_for_the_scan
 
 
 class Mamba2Config(NamedTuple):
@@ -270,7 +270,10 @@ class Mamba2Mixer(nn.Module):
                            (inner, d), jnp.float32)
 
         with jax.named_scope("hvd_ssm_in_proj"):
-            zxbcdt = jnp.dot(u.astype(self.dtype), w_in.astype(self.dtype))
+            zxbcdt = held_for_the_scan(
+                jnp.dot(u.astype(self.dtype), w_in.astype(self.dtype)),
+                heads, groups, self.head_dim, self.state,
+                min(self.chunk, seq))
             z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * bc],
                                    axis=-1)
         with jax.named_scope("hvd_ssm_conv"):

@@ -6,8 +6,8 @@ refuse — scoped-VMEM overruns, tiling violations, a kernel that cannot be
 partitioned — and interpret mode cannot.  Nothing runs, so these say nothing
 about results or times.  Code that asks jax.default_backend() still sees the
 CPU, so the kernels get interpret=False explicitly (or the test steers the
-backend query).  Here: the flash kernels, the delta rule's carry, the grouped
-products, the rows' way back and the ring's rotations at the benchmark's
+backend query).  Here: the flash kernels, the delta rule's carry, the chunked
+scan's within-chunk pair, the grouped products, the rows' way back and the ring's rotations at the benchmark's
 shapes; tests/test_chip_steps.py reads whole steps.  (tests/test_ops.py keeps
 the interpret-mode numerics.)"""
 
@@ -118,6 +118,49 @@ def test_delta_rule_carry_kernels_at_the_cells_widths_compile(
     for kernel in ("hvd_gdn_scan_carry_fwd", "hvd_gdn_scan_carry_bwd",
                    "hvd_gdn_scan_solve_fwd", "hvd_gdn_scan_solve_bwd"):
         assert f"%{kernel}" in text, kernel
+
+
+@pytest.mark.parametrize("heads,head_dim,chunk,seq,kernels", [
+    (64, 64, 256, 8192, True), (16, 64, 128, 4096, False),
+    (64, 16, 128, 4096, True)],
+    ids=["granite", "nemotron_share", "a_chunk_of_one_register"])
+def test_the_chunked_scan_at_the_cells_shapes(
+        v5e, monkeypatch, heads, head_dim, chunk, seq, kernels):
+    """`chunked_scan` with its gradient at the two shapes the benchmark runs,
+    heads of 64 on ONE group at a state of 128, bfloat16, through the chip's
+    compiler: Granite's 64 heads at a chunk of 256 take the pair of Mosaic
+    kernels (whose dynamic slices of a block's sublanes, lane selects and
+    transposed products the interpreter cannot refuse), Nemotron's share of
+    16 at 128 stays products — as `lowered_plan` says, and no loop either
+    way; and 64 narrow heads at a chunk of ONE register's lanes, which the
+    rule also hands the kernels (a chunk's last token is then a register's
+    last lane: a (1, 1) decay scales a state as a scalar)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.ops.ssm import chunked_scan, lowered_plan
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    here = SingleDeviceSharding(v5e[0])
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=here)
+
+    x = shaped((1, seq, heads, head_dim), jnp.bfloat16)
+    b = shaped((1, seq, 1, 128), jnp.bfloat16)
+    vector = shaped((heads,))
+
+    def loss(x, dt, A, B, C, D):
+        return jnp.square(chunked_scan(x, dt, A, B, C, D, chunk)[0]).sum()
+
+    text = jax.jit(jax.grad(loss, range(6))).lower(
+        x, shaped((1, seq, heads)), vector, b, b, vector).compile().as_text()
+    plan = lowered_plan(heads, 1, head_dim, 128, chunk, jnp.bfloat16)
+    assert plan["scan"] == ("kernels" if kernels else "products")
+    assert text.count("custom_call_target=\"tpu_custom_call\"") \
+        == plan["tpu_custom_call"] == (2 if kernels else 0)
+    assert text.count(" while(") == 0
+    for kernel in ("hvd_ssm_scan_intra_fwd", "hvd_ssm_scan_intra_bwd"):
+        assert (f"%{kernel}" in text) == kernels, kernel
 
 
 def test_flash_two_widths_at_latent_attention_shape_compile(v5e):

@@ -270,11 +270,26 @@ def test_granite_step_is_one_groups_scan_by_stage_under_a_tied_head(
     Granite-4.0-H-Micro's widths with its four multipliers, every entry
     recomputing, through `build_train_step`, compiled for the described chip:
     no `while` anywhere in the step, attention is the two flash kernels, the
-    tree has no head of its own, and the scan's four stages keep their scopes
-    in the compiled text's op_names forward and backward."""
+    scan is its pair of kernels — the forward's in the forward pass and
+    again where the entry recomputes, the backward's once —
+    each body traced ONCE for the step (`ops.ssm._scan_call` is jitted: the
+    cell's nine mixers share a call a direction), the tree has no head of its
+    own, and the scan's scopes are in the compiled text's op_names forward
+    and backward (`decay` for the cumulative sums, `intra` for the
+    kernels)."""
+    from horovod_tpu.common.metrics import setup_table
     from horovod_tpu.models import Mamba2Config, TransformerLM
+    from horovod_tpu.ops import ssm
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ssm._scan_call.clear_cache()    # what another test traced is not again
+
+    def entries():
+        table = setup_table.process()["kernels"]
+        return {name: table.get(name, {"calls": 0})["calls"] for name in (
+            "hvd_ssm_scan_intra_fwd", "hvd_ssm_scan_intra_bwd")}
+
+    before = entries()
     model = TransformerLM(
         vocab_size=2048, d_model=2048, n_heads=32, d_ff=8192,
         dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16, use_flash=True,
@@ -282,18 +297,27 @@ def test_granite_step_is_one_groups_scan_by_stage_under_a_tied_head(
         ssm=Mamba2Config(64, 64, 1, 128, 4, 256), n_kv_heads=8, head_dim=64,
         rope=False, recompute=True, embed_scale=12.0, tie_head=True,
         residual_scale=0.22, logits_divisor=8.0, attn_scale=1.0 / 64)
+    model = model.clone(layers=("ssm", "gated_mlp") + model.layers)
     _, params, lowered = _lowered_step(model, v5e[:1])
     assert set(params) == {"embed", "final_norm"} | {
-        f"layer_{i}" for i in range(4)}
+        f"layer_{i}" for i in range(6)}
+    # (the model's `init` runs 128 tokens: one chunk of 128, on the products)
+    assert {name: n - before[name] for name, n in entries().items()} == {
+        "hvd_ssm_scan_intra_fwd": 1, "hvd_ssm_scan_intra_bwd": 1}
     text = lowered.compile().as_text()
     assert not re.search(r"\bwhile\(", text)
     assert len(re.findall(r"%hvd_flash_fwd[.\d]* = ", text)) == 1
     assert len(re.findall(r"%hvd_flash_bwd[.\d]* = ", text)) == 1
-    assert text.count('"tpu_custom_call"') == 2
+    assert len(re.findall(r"%hvd_ssm_scan_intra_fwd[.\d]* = ", text)) == 4
+    assert len(re.findall(r"%hvd_ssm_scan_intra_bwd[.\d]* = ", text)) == 2
+    assert text.count('"tpu_custom_call"') == 2 + 3 * 2
+    # The states between chunks stay on the chip: no operation of XLA's is
+    # an end state's or the carry's.
+    assert "hvd_ssm_scan_ends" not in text
+    assert "hvd_ssm_scan_carry" not in text
     _assert_scopes_forward_and_backward(
         text, ("hvd_ssm_in_proj", "hvd_ssm_conv", "hvd_ssm_scan",
                "hvd_ssm_scan_decay", "hvd_ssm_scan_intra",
-               "hvd_ssm_scan_ends", "hvd_ssm_scan_carry",
                "hvd_ssm_gate_norm", "hvd_ssm_out_proj", "hvd_mlp",
                "hvd_embed", "hvd_attn_qkv", "hvd_attn_attend",
                "hvd_attn_out", "hvd_lm_head"))

@@ -111,9 +111,11 @@ def scope_paths(text):
             _ALIAS.match, text.splitlines()) if alias) if named}
 
 
-def lowered_step(cell):
+def lowered_step(cell, **resized):
+    """The step of ``cell``'s builder at its rehearsal sizes, lowered;
+    ``resized`` are sizes of the configuration this test asks for instead."""
     spec = harness.load_cell(cell, rehearse=True)
-    config, traffic = spec["config"], spec["traffic"]
+    config, traffic = dict(spec["config"], **resized), spec["traffic"]
     devices = jax.devices()[:spec["cell"]["chips"]]
     built = importlib.import_module(
         f"benchmark.builders.{config['builder']}").build(
@@ -336,20 +338,38 @@ _SSM_STAGE = re.compile(r"(?:^|/)hvd_ssm_scan_(%s)(?=/|$)"
                         % "|".join(SSM_STAGES))
 
 
-@pytest.mark.parametrize("cell", [
-    "nemotron3super120b_1chip_tp8ep64share_1x4k",
-    "granite4hmicro_1chip_pp4share_1x8k"])
-def test_the_chunked_scans_four_stages_partition_its_scope(cell):
+NEMOTRON, GRANITE = ("nemotron3super120b_1chip_tp8ep64share_1x4k",
+                     "granite4hmicro_1chip_pp4share_1x8k")
+# Granite's rehearsal at ONE chunk of 128: eight heads of 16 on one group then
+# hold 4 KB of `L` a token beside 1.2 KB of operands, and `ops.ssm.lowered_plan`
+# hands the scan to its kernels, as at the cell's own sizes (at the
+# rehearsal's chunk of 32 the matrix is lighter than Nemotron's and stays on
+# the products).
+WITH_KERNELS = {"scan_chunk": 128}
+_SCAN_KERNEL = re.compile(r"/hvd_ssm_scan_intra_(fwd|bwd)/")
+
+
+@pytest.mark.parametrize("cell,resized", [
+    (NEMOTRON, {}), (GRANITE, {}), (GRANITE, WITH_KERNELS)],
+    ids=["nemotron", "granite", "granite_with_kernels"])
+def test_the_chunked_scans_four_stages_partition_its_scope(cell, resized):
     """Any caller of `ops.ssm.chunked_scan` (Nemotron's 16-head share on one
-    group of eight, Granite's 64 whole heads on its one group): every
-    operation under `hvd_ssm_scan`, a cast or a reshape too, lies under
-    exactly ONE of `hvd_ssm_scan_decay`, `_intra`, `_ends`, `_carry`, forward
-    and backward; the three that hold products hold them in both directions,
-    and `decay` holds none (sums and exponentials)."""
+    group of eight, Granite's 64 whole heads on its one group), whichever
+    form carries it: every operation under `hvd_ssm_scan`, a cast or a
+    reshape too, lies under exactly ONE of `hvd_ssm_scan_decay`, `_intra`,
+    `_ends`, `_carry`, forward and backward.  On the products the three that
+    hold products hold them in both directions, and `decay` holds none (sums
+    and exponentials), and no operation names a kernel.  On the kernels
+    `decay` keeps the cumulative sums and EVERY product lies under
+    `hvd_ssm_scan_intra`, inside the pair (here the interpreter's loops over
+    their grids), each in its own direction, three calls a mixer — forward,
+    the recomputed forward, backward — of ONE function a direction; `_ends`
+    and `_carry` name nothing (the states stay on the chip)."""
     from horovod_tpu.ops.ssm import STAGES
 
     assert STAGES == SSM_STAGES
-    text = lowered_step(cell).as_text(debug_info=True)
+    staged = set(STAGES) if not resized else {"decay", "intra"}
+    text = lowered_step(cell, **resized).as_text(debug_info=True)
     paths = scope_paths(text)
     # What the mixer itself does under its scope: the reshapes of its
     # operands, the softplus of dt, the sown counters.
@@ -359,20 +379,43 @@ def test_the_chunked_scans_four_stages_partition_its_scope(cell):
         assert len(set(_SSM_STAGE.findall(path))) == 1, path
     for direction in (FORWARD, BACKWARD):
         assert {stage for path in under if direction in path
-                for stage in _SSM_STAGE.findall(path)} == set(STAGES)
+                for stage in _SSM_STAGE.findall(path)} == staged
     products = {direction: set() for direction in (FORWARD, BACKWARD)}
     for op, path in heavy_operations(text):
         if "/hvd_ssm_scan/" in path and op == "dot_general":
             stage = set(_SSM_STAGE.findall(path))
             assert len(stage) == 1, (op, path)
             products[BACKWARD if BACKWARD in path else FORWARD] |= stage
-    assert products[FORWARD] == products[BACKWARD] \
-        == {"intra", "ends", "carry"}
+    assert products[FORWARD] == products[BACKWARD] == staged - {"decay"}
     # Outside the four stages the scan's scope holds the mixer's own
     # preparation alone: no product.
     for op, path in heavy_operations(text):
         if "/hvd_ssm_scan/" in path and not _SSM_STAGE.search(path):
             assert op not in ("dot_general", "while", "custom_call"), path
+    # The kernels' own operations, each path behind its call's (a jitted call
+    # is a function of the module, and its paths start at it).
+    named = [(op, path) for op, path in heavy_operations(text)
+             if _SCAN_KERNEL.search(path)]
+    calls = re.findall(r"call @(_scan_call[\w.]*)\(", text)
+    if not resized:
+        assert not named and not calls and "_scan_call" not in text
+        return
+    for kernel in ("fwd", "bwd"):
+        of_kernel = [path for _, path in named
+                     if f"/hvd_ssm_scan_intra_{kernel}/" in path]
+        assert of_kernel, kernel
+        for path in of_kernel:
+            assert "/hvd_ssm_scan/hvd_ssm_scan_intra/" in path, path
+            again = "rematted_computation" in path
+            assert (BACKWARD in path) == (kernel == "bwd" or again), path
+    # Every product of the scan is the kernels'.
+    assert {path for op, path in heavy_operations(text)
+            if "/hvd_ssm_scan/" in path and op == "dot_general"} \
+        == {path for op, path in named if op == "dot_general"}
+    mixers = harness.load_cell(cell, rehearse=True)["config"][
+        "layer_types"].count("mamba")
+    # One function a direction, and the recomputed forward's own.
+    assert len(calls) == 3 * mixers and len(set(calls)) == 3, calls
 
 
 def test_a_looped_models_exits_and_the_layers_in_its_loop_name_themselves():
