@@ -520,6 +520,10 @@ class MetricsRegistry:
         # entered the chunk, and all of them, a list a layer each
         # (models.record_ssm_carry).
         self._ssm = {"chunks_carried": [], "chunks": []}
+        # A model with multi-token-prediction modules (models.TransformerLM(
+        # mtp=)): one forward pass's mean next-token loss and each module's
+        # (models.record_mtp_losses).
+        self._mtp = {"main": None, "modules": []}
         # What the compiler made of the last compiled training step's
         # gradient exchange (jax/train.py `_TimedStep.exchange_overlap`),
         # and under "setup" what building its programs cost
@@ -650,6 +654,14 @@ class MetricsRegistry:
         with self._lock:
             self._ssm = {"chunks_carried": [int(n) for n in chunks_carried],
                          "chunks": [int(n) for n in chunks]}
+
+    def set_mtp_losses(self, main, modules) -> None:
+        """Mirror one forward pass's mean losses of a model with
+        multi-token-prediction modules: the main head's and each module's
+        (overwritten: one batch's)."""
+        with self._lock:
+            self._mtp = {"main": None if main is None else float(main),
+                         "modules": [float(x) for x in modules]}
 
     def set_train_step(self, exchange_overlap: dict, setup: dict) -> None:
         """Mirror a compiled training step's account of itself: whether it
@@ -1013,6 +1025,8 @@ class MetricsRegistry:
                           self._delta.items()},
                 "ssm": {name: list(chunks) for name, chunks in
                         self._ssm.items()},
+                "mtp": {"main": self._mtp["main"],
+                        "modules": list(self._mtp["modules"])},
                 "train_step": dict(
                     self._train_step,
                     setup=copy_step_setup(self._train_step["setup"])),
@@ -1240,6 +1254,15 @@ def prometheus_text(snapshot: dict) -> str:
         for layer, n in enumerate(ssm.get(key, [])):
             out.append(f'hvd_tpu_ssm_chunks{{layer="{layer}",'
                        f'kind="{kind}"}} {n}')
+
+    mtp = snapshot.get("mtp", {})
+    out.append("# HELP hvd_tpu_mtp_loss mean cross-entropy of one forward "
+               "pass of a model with multi-token-prediction modules: the "
+               "main head's (module 0) and each module's")
+    out.append("# TYPE hvd_tpu_mtp_loss gauge")
+    if mtp.get("main") is not None:
+        for module, loss in enumerate([mtp["main"]] + mtp["modules"]):
+            out.append(f'hvd_tpu_mtp_loss{{module="{module}"}} {loss}')
 
     step = snapshot.get("train_step", {})
     out.append("# HELP hvd_tpu_train_step_all_reduces all-reduces of the "

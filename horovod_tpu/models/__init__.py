@@ -35,12 +35,14 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     looped_exit_loss,
     masked_diffusion_loss,
     moe_next_token_loss,
+    mtp_next_token_loss,
     next_token_loss,
     record_attention_blocks,
     record_attention_selection,
     record_delta_steps,
     record_exit_distribution,
     record_expert_rows,
+    record_mtp_losses,
     record_ssm_carry,
     router_losses,
 )

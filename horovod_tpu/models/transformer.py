@@ -1073,28 +1073,35 @@ class Attention(nn.Module):
 
 class LatentConfig(NamedTuple):
     """Sizes of a latent-attention layer (``TransformerLM(latent=)``;
-    DeepSeek-V2's multi-head latent attention, arXiv:2405.04434, without a
-    query latent): the key/value latent's ``kv_rank``, a head's non-rotary
-    ``nope_dim`` and rotary ``rope_dim`` of query and key, its value's
-    ``v_dim``, the rotary base."""
+    DeepSeek-V2's multi-head latent attention, arXiv:2405.04434): the
+    key/value latent's ``kv_rank``, a head's non-rotary ``nope_dim`` and
+    rotary ``rope_dim`` of query and key, its value's ``v_dim``, the rotary
+    base; ``q_rank``, the QUERY latent's width (DeepSeek-V3's ``q_lora_rank``;
+    unset, the query comes straight from the hidden state); ``gate``, whether
+    the layer has its head-wise output gate (DeepSeek-V3's has none)."""
 
     kv_rank: int
     nope_dim: int
     rope_dim: int
     v_dim: int
     rope_theta: float = 10000.0
+    q_rank: Optional[int] = None
+    gate: bool = True
 
 
 class LatentAttention(nn.Module):
     """Attention whose keys and values come from one low-rank latent a token,
-    with a head-wise output gate.  ``u`` the layer's normed input, H heads:
+    with a head-wise output gate where ``config.gate`` and the query through a
+    latent of its own where ``config.q_rank``.  ``u`` the layer's normed
+    input, H heads:
 
         [q_nope | q_rope]_h = u W_q                   nope_dim + rope_dim
+          or, with q_rank:  c_q = RMSNorm(u W_qa),  [q_nope | q_rope]_h = c_q W_qb
         [c | k_rope] = u W_kva,  c = RMSNorm(c)       kv_rank + rope_dim
         [k_nope | v]_h = c W_kvb                      nope_dim + v_dim
         q_h = [q_nope | rope(q_rope)],  k_h = [k_nope | rope(k_rope)]
         o_h = causal softmax(q_h k_h^T (nope_dim + rope_dim)^-1/2) v_h
-        out = concat_h(o_h * sigmoid(u W_g)_h) W_o
+        out = concat_h(o_h * sigmoid(u W_g)_h) W_o    (no gate: concat_h(o_h) W_o)
 
     ONE ``k_rope`` a token serves every head; the rotation is over all of
     ``rope_dim`` (adjacent pairs, :func:`rope`).  Query and key are
@@ -1102,10 +1109,15 @@ class LatentAttention(nn.Module):
     take the two widths as they are (``ops/attention.py``).
 
     ``head_shard=(i, n)``: heads ``[i H/n, (i+1) H/n)`` — their slices of
-    ``W_q``, ``W_kvb``, ``W_g`` and ``W_o``; ``W_kva`` and the latent's norm
-    are whole on every shard.  The ``n`` outputs sum to the whole layer's;
-    the sum is the caller's.  Scopes: ``hvd_mla_q_proj``,
-    ``hvd_mla_kv_latent``, ``hvd_mla_attend``, ``hvd_mla_out_proj``."""
+    ``W_q`` (``W_qb``), ``W_kvb``, ``W_g`` and ``W_o``; ``W_kva``, ``W_qa``
+    and the two latents' norms are whole on every shard.  The ``n`` outputs
+    sum to the whole layer's; the sum is the caller's.  Parameters:
+    ``q_kernel`` (with a query latent ``q_a_kernel``, ``q_norm_scale``,
+    ``q_b_kernel`` in its place), ``kv_a_kernel``, ``kv_norm_scale``,
+    ``kv_b_kernel``, ``gate_kernel`` (where gated), ``o_kernel``.  Scopes:
+    ``hvd_mla_q_latent`` (``W_qa`` and its norm; with a query latent only),
+    ``hvd_mla_q_proj``, ``hvd_mla_kv_latent``, ``hvd_mla_attend``,
+    ``hvd_mla_out_proj``."""
 
     n_heads: int
     config: LatentConfig
@@ -1124,8 +1136,18 @@ class LatentAttention(nn.Module):
         heads = self.n_heads // n_shards
         b, s, d = x.shape
         per_head = nn.initializers.lecun_normal(in_axis=0, out_axis=(1, 2))
-        w_q = self.param("q_kernel", per_head,
-                         (d, heads, cfg.nope_dim + cfg.rope_dim), jnp.float32)
+        if cfg.q_rank is None:
+            w_q = self.param("q_kernel", per_head,
+                             (d, heads, cfg.nope_dim + cfg.rope_dim),
+                             jnp.float32)
+        else:
+            w_qa = self.param("q_a_kernel", nn.initializers.lecun_normal(),
+                              (d, cfg.q_rank), jnp.float32)
+            q_scale = self.param("q_norm_scale", nn.initializers.ones,
+                                 (cfg.q_rank,), jnp.float32)
+            w_q = self.param("q_b_kernel", per_head,
+                             (cfg.q_rank, heads, cfg.nope_dim + cfg.rope_dim),
+                             jnp.float32)
         w_kva = self.param("kv_a_kernel", nn.initializers.lecun_normal(),
                            (d, cfg.kv_rank + cfg.rope_dim), jnp.float32)
         latent_scale = self.param("kv_norm_scale", nn.initializers.ones,
@@ -1133,8 +1155,9 @@ class LatentAttention(nn.Module):
         w_kvb = self.param("kv_b_kernel", per_head,
                            (cfg.kv_rank, heads, cfg.nope_dim + cfg.v_dim),
                            jnp.float32)
-        w_gate = self.param("gate_kernel", nn.initializers.lecun_normal(),
-                            (d, heads), jnp.float32)
+        if cfg.gate:
+            w_gate = self.param("gate_kernel", nn.initializers.lecun_normal(),
+                                (d, heads), jnp.float32)
         w_o = self.param(
             "o_kernel",
             nn.initializers.lecun_normal(in_axis=(0, 1), out_axis=2),
@@ -1145,17 +1168,24 @@ class LatentAttention(nn.Module):
         def turned(t):
             return rope(t, positions, cfg.rope_theta)
 
+        def normed(latent, scale):
+            wide = latent.astype(jnp.float32)
+            mean_sq = jnp.mean(jnp.square(wide), axis=-1, keepdims=True)
+            return (wide * lax.rsqrt(mean_sq + self.norm_eps)
+                    * scale).astype(self.dtype)
+
+        q_in = x
+        if cfg.q_rank is not None:
+            with jax.named_scope("hvd_mla_q_latent"):
+                q_in = normed(jnp.dot(x, w_qa.astype(self.dtype)), q_scale)
         with jax.named_scope("hvd_mla_q_proj"):
-            q = jnp.einsum("bsd,dhe->bhse", x, w_q.astype(self.dtype))
+            q = jnp.einsum("bsd,dhe->bhse", q_in, w_q.astype(self.dtype))
             q = jnp.concatenate([q[..., :cfg.nope_dim],
                                  turned(q[..., cfg.nope_dim:])], axis=-1)
         with jax.named_scope("hvd_mla_kv_latent"):
             latent, k_rope = jnp.split(
                 jnp.dot(x, w_kva.astype(self.dtype)), [cfg.kv_rank], axis=-1)
-            wide = latent.astype(jnp.float32)
-            mean_sq = jnp.mean(jnp.square(wide), axis=-1, keepdims=True)
-            latent = (wide * lax.rsqrt(mean_sq + self.norm_eps)
-                      * latent_scale).astype(self.dtype)
+            latent = normed(latent, latent_scale)
             kv = jnp.einsum("bsr,rhe->bhse", latent, w_kvb.astype(self.dtype))
             shared = jnp.broadcast_to(turned(k_rope[:, None]),
                                       (b, heads, s, cfg.rope_dim))
@@ -1165,10 +1195,11 @@ class LatentAttention(nn.Module):
             out = flash_attention(q, k, v, causal=True) if self.use_flash \
                 else blockwise_attention(q, k, v, causal=True)
         with jax.named_scope("hvd_mla_out_proj"):
-            gate = nn.sigmoid(jnp.einsum(
-                "bsd,dh->bhs", x, w_gate.astype(self.dtype),
-                preferred_element_type=jnp.float32))
-            out = (out * gate[..., None]).astype(self.dtype)
+            if cfg.gate:
+                gate = nn.sigmoid(jnp.einsum(
+                    "bsd,dh->bhs", x, w_gate.astype(self.dtype),
+                    preferred_element_type=jnp.float32))
+                out = (out * gate[..., None]).astype(self.dtype)
             return jnp.einsum("bhse,hed->bsd", out, w_o.astype(self.dtype))
 
 
@@ -1540,9 +1571,49 @@ class TransformerLM(nn.Module):
     residual_scale: Optional[float] = None
     logits_divisor: Optional[float] = None
     attn_scale: Optional[float] = None
+    # Multi-token-prediction modules (DeepSeek-V3, arXiv:2412.19437, section
+    # 2.2): ``(depth, kinds)``, ``depth`` modules in a chain behind the
+    # pattern and ``final_norm``, each ONE more block of the pattern entries
+    # ``kinds`` (``("latent_attention", "experts")``: a whole published
+    # layer) with weights of its own.  Module ``k`` (from 1) reads, a
+    # position ``i``, the embedding of token ``i + k`` (``tokens`` rolled by
+    # ``k``: the SAME table) and the state before it — the main model's normed
+    # state, or module ``k - 1``'s — and predicts token ``i + k + 1`` through
+    # the SAME head:
+    #
+    #     h  = [RMSNorm_e(E[t_{i+k}]) | RMSNorm_h(state_i)] W_eh     2 d -> d
+    #     h  = the block over h, causal (``MixerLayer``s, ``recompute`` too)
+    #     state_i = RMSNorm_f(h_i);   logits_i = state_i W_head
+    #
+    # Parameters ``mtp_<k-1>_embed_norm``, ``_state_norm``, ``_proj``,
+    # ``_layer_<j>`` and ``_final_norm`` beside the pattern's; ONE ``embed``
+    # and ONE ``lm_head_kernel``, each taking the sum of every use's gradient.
+    # ``__call__(tokens)`` returns ``(logits, module 1's, ...)``, every
+    # position kept (a sequence's last ``k`` positions read a token of its
+    # start, reach no other position and have no target):
+    # :func:`mtp_next_token_loss` takes them with the same ``tokens``.
+    # ``__call__(tokens, targets=)`` returns the mean losses ``(main, module
+    # 1's, ...)`` through :func:`fused_next_token_loss`, ``targets`` the
+    # tokens shifted by one as for any model; the LAST position's target is
+    # not read (the main loss leaves one position out, module ``k``'s ``k +
+    # 1``, as :func:`mtp_next_token_loss` does), and the losses are sown as
+    # ``mtp_losses`` (:func:`record_mtp_losses`).  Everything a module adds
+    # runs under the scope ``hvd_mtp`` (``hvd_mtp_proj`` inside it for the two
+    # norms and ``W_eh``).  Over a per-layer pattern only; unset, nothing of
+    # it is traced.
+    mtp: Optional[Tuple[int, Tuple[str, ...]]] = None
 
     @nn.compact
     def __call__(self, tokens, targets=None, decode_ctx=None, noised=None):
+        if self.mtp is not None and (
+                self.layers is None or self.loops is not None
+                or decode_ctx is not None or noised is not None
+                or self.seq_axis is not None):
+            raise ValueError(
+                "mtp= (multi-token-prediction modules) runs behind a "
+                "per-layer pattern (layers=); it composes with neither "
+                "decode_ctx=, noised= / block_diffusion=, loops= nor "
+                "sequence parallelism.")
         if self.loops is not None:
             return self._looped(tokens, targets, decode_ctx, noised)
         if self.layers is not None and (decode_ctx is not None
@@ -1597,6 +1668,8 @@ class TransformerLM(nn.Module):
         x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                        name="final_norm")(x)
         w = self._head_kernel(embed)
+        if self.mtp is not None:
+            return self._with_mtp(tokens, targets, x, w, embed, options)
         if targets is not None:
             # Fused head+loss: see fused_next_token_loss.
             return fused_next_token_loss(x, w, targets, dtype=self.dtype,
@@ -1621,13 +1694,55 @@ class TransformerLM(nn.Module):
     def _embedded(self, tokens):
         """(the tokens' rows, scaled where ``embed_scale`` says; the table's
         module, for a tied head)."""
+        embed = TokenEmbed(self.vocab_size, self.d_model, dtype=self.dtype,
+                           name="embed")
+        return self._rows(embed, tokens), embed
+
+    @nn.nowrap
+    def _rows(self, embed, tokens):
         with jax.named_scope("hvd_embed"):
-            embed = TokenEmbed(self.vocab_size, self.d_model,
-                               dtype=self.dtype, name="embed")
             x = embed(tokens)
             if self.embed_scale is not None:
                 x = (x * self.embed_scale).astype(self.dtype)
-        return x, embed
+        return x
+
+    @nn.nowrap
+    def _with_mtp(self, tokens, targets, x, w, embed, options):
+        """``__call__`` behind ``final_norm`` under ``mtp``: the field's
+        comment has what it computes and returns."""
+        depth, kinds = self.mtp
+
+        def head(x, k):
+            if targets is None:
+                return _head_logits(x, w, self.dtype, self.logits_dtype,
+                                    self.logits_divisor)
+            return fused_next_token_loss(
+                x, w, jnp.roll(targets, -k, axis=1) if k else targets,
+                dtype=self.dtype, logits_divisor=self.logits_divisor,
+                no_target=k + 1)
+
+        def norm(k, name, t):
+            return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                              name=f"mtp_{k}_{name}")(t)
+
+        out = [head(x, 0)]
+        with jax.named_scope("hvd_mtp"):
+            for k in range(depth):
+                rows = self._rows(embed, jnp.roll(tokens, -(k + 1), axis=1))
+                with jax.named_scope("hvd_mtp_proj"):
+                    x = nn.Dense(
+                        self.d_model, use_bias=False, dtype=self.dtype,
+                        name=f"mtp_{k}_proj")(jnp.concatenate(
+                            [norm(k, "embed_norm", rows),
+                             norm(k, "state_norm", x)], axis=-1))
+                for j, kind in enumerate(kinds):
+                    x = MixerLayer(kind, options,
+                                   name=f"mtp_{k}_layer_{j}")(x)
+                x = norm(k, "final_norm", x)
+                out.append(head(x, k + 1))
+        if targets is not None:
+            self.sow("intermediates", "mtp_losses", jnp.stack(out))
+        return tuple(out)
 
     @nn.nowrap
     def _head_kernel(self, embed):
@@ -1750,7 +1865,8 @@ def _head_token_xent(x, w, targets, dtype, logits_dtype, divisor=None):
 
 
 def fused_next_token_loss(hidden, w, targets, dtype=jnp.bfloat16,
-                          n_chunks: int = 8, logits_divisor=None):
+                          n_chunks: int = 8, logits_divisor=None,
+                          no_target: int = 0):
     """Mean cross-entropy computed head-chunk by head-chunk.
 
     The full-logits path materializes a ``(batch, seq, vocab)`` float32
@@ -1776,7 +1892,10 @@ def fused_next_token_loss(hidden, w, targets, dtype=jnp.bfloat16,
     head's scope, ``hvd_lm_head``; there is no ``hvd_token_xent`` inside.
     ``logits_divisor`` divides each chunk's float32 logits
     (``TransformerLM(logits_divisor=)``); ``w`` is whatever the head reads, a
-    tied model's transposed table too.
+    tied model's transposed table too.  ``no_target``: that many positions at
+    the end of every sequence have no target (a model with ``mtp``); they
+    pass through the chunks as the others do, so that every chunk keeps its
+    shape, and count for nothing in the mean.
     """
     B, S, D = hidden.shape
     tokens = B * S
@@ -1784,22 +1903,29 @@ def fused_next_token_loss(hidden, w, targets, dtype=jnp.bfloat16,
         n_chunks = 1
 
     def chunk(total, xt):
-        x, t = xt
+        x, t = xt[:2]
         logits = jnp.einsum("md,dv->mv", x.astype(dtype), wb,
                             preferred_element_type=jnp.float32)
         if logits_divisor is not None:
             logits = logits / logits_divisor
         lse = jax.nn.logsumexp(logits, axis=-1)
         correct = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
-        return total + (lse - correct).sum(), None
+        loss = lse - correct
+        if no_target:
+            loss = jnp.where(xt[2], loss, 0.0)
+        return total + loss.sum(), None
 
     with jax.named_scope("hvd_lm_head"):
         xc = hidden.reshape(n_chunks, tokens // n_chunks, D)
         tc = targets.reshape(n_chunks, tokens // n_chunks)
         wb = w.astype(dtype)
+        operands = (xc, tc)
+        if no_target:
+            operands += (jnp.broadcast_to(jnp.arange(S) < S - no_target,
+                                          (B, S)).reshape(tc.shape),)
         total, _ = lax.scan(jax.checkpoint(chunk),
-                            jnp.zeros((), jnp.float32), (xc, tc))
-        return total / tokens
+                            jnp.zeros((), jnp.float32), operands)
+        return total / (B * (S - no_target))
 
 
 @jax.custom_vjp
@@ -1879,6 +2005,36 @@ def next_token_loss(logits, targets, mask=None, axis_name=None):
             n_shards *= lax.axis_size(a)
         count = lax.psum(count, axes) / n_shards
     return (loss * mask).sum() / jnp.maximum(count, 1.0)
+
+
+def mtp_next_token_loss(logits, tokens, weight: float = 0.3,
+                        with_terms: bool = False):
+    """The loss of a model with multi-token-prediction modules
+    (``TransformerLM(mtp=)``; DeepSeek-V3, arXiv:2412.19437, equations
+    24–25) on what ``model(tokens)`` returned, ``logits = (main, module
+    1's, ..., module D's)``, and the SAME ``tokens`` ``(batch, seq)``:
+
+        L_main = mean_{i < seq-1}   CE(main_i,     t_{i+1})
+        L_k    = mean_{i < seq-1-k} CE(module_k_i, t_{i+1+k})
+        L      = L_main + weight / D * sum_k L_k
+
+    Every set of logits keeps all ``seq`` positions; the positions that have
+    no target (one for the main head, ``k + 1`` for module ``k``) are left
+    out of its mean.  Each term is :func:`next_token_loss`'s per-token
+    cross-entropy under a mask.  ``weight`` is the report's lambda (0.3 in
+    its first phase, 0.1 after).  ``with_terms``: ``(L, (L_main, L_1,
+    ...))``, the terms a model called with ``targets=`` returns."""
+    seq = tokens.shape[1]
+
+    def term(k, logits):
+        return next_token_loss(
+            logits, jnp.roll(tokens, -(k + 1), axis=1),
+            mask=jnp.broadcast_to(jnp.arange(seq) < seq - 1 - k,
+                                  tokens.shape))
+
+    terms = tuple(term(k, one) for k, one in enumerate(logits))
+    loss = terms[0] + weight / len(terms[1:]) * sum(terms[1:])
+    return (loss, terms) if with_terms else loss
 
 
 def masked_diffusion_loss(logits, targets, masked, level):
@@ -2059,6 +2215,29 @@ def record_ssm_carry(intermediates) -> dict:
             for kind in ("chunks_carried", "chunks")}
     if _metrics.registry.enabled:
         _metrics.registry.set_ssm_carry(**seen)
+    return seen
+
+
+def record_mtp_losses(intermediates) -> dict:
+    """Read the mean losses a model with multi-token-prediction modules
+    (``TransformerLM(mtp=)``) sowed in one ``apply(..., targets=...,
+    mutable=["intermediates"])`` — outside the compiled step — and, when the
+    metrics registry is on (``HVD_TPU_METRICS=1``), mirror them into
+    ``hvd.metrics_snapshot()["mtp"]``.  ``{"main": the next-token loss,
+    "modules": [module 1's, ...]}``: on seeded weights every module's loss
+    is near the main one (the logarithm of the vocabulary), and a module
+    that stopped training reads off it.  A model without modules, or a call
+    without ``targets=`` (its losses are the caller's,
+    :func:`mtp_next_token_loss`), gives ``{"main": None, "modules": []}``."""
+    from horovod_tpu.common import metrics as _metrics
+
+    sown = _sown(intermediates, "mtp_losses")
+    seen = {"main": None, "modules": []}
+    if sown:
+        main, *modules = (float(x) for x in sown[0])
+        seen = {"main": main, "modules": modules}
+    if _metrics.registry.enabled:
+        _metrics.registry.set_mtp_losses(**seen)
     return seen
 
 

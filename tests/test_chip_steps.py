@@ -385,6 +385,66 @@ def test_ling_step_is_products_kernels_and_one_loop_a_pass(v5e, monkeypatch):
         assert 2 <= fusions <= 5, (body, fusions)
 
 
+def test_joyai_step_is_three_kernels_a_block_and_a_module_under_one_scope(
+        v5e, monkeypatch):
+    """Latent attention WITH a query latent and no gate (4 heads, 192 and 128
+    wide), sigmoid-routed gated experts with a shared one and a
+    multi-token-prediction module of one more such layer, at
+    JoyAI-LLM-Flash's per-head widths, every entry recomputing, through
+    `build_train_step` and `mtp_next_token_loss`, compiled for the described
+    chip: two blocks (the module's the second) are the flash forward and the
+    split backward pair at two widths, by their names, once each (a
+    recomputing entry keeps the forward kernel's outputs); the tree holds ONE
+    table and ONE head; the query latent has its scope; and everything the
+    module adds — its lookup, its projection, its block's scopes, its head —
+    nests under `hvd_mtp`, forward and backward."""
+    from horovod_tpu.models import (LatentConfig, MoEConfig, TransformerLM,
+                                    mtp_next_token_loss)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(
+        vocab_size=2048, d_model=512, n_heads=4, d_ff=1024,
+        dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16, use_flash=True,
+        layers=("latent_attention", "experts"),
+        latent=LatentConfig(512, 128, 64, 128, 3.2e7, q_rank=384, gate=False),
+        moe=MoEConfig(64, 8, 256, (0, 8), 1.5, "sigmoid", True, 2.5,
+                      shared_width=256), recompute=True,
+        mtp=(1, ("latent_attention", "experts")))
+
+    def loss(params, batch):
+        return mtp_next_token_loss(
+            model.apply({"params": params}, batch[0]), batch[0])
+
+    _, params, lowered = _lowered_step(model, v5e[:1], rows=(1, 1024),
+                                       loss_fn=loss)
+    names = [name for name in params if not name.startswith(("layer_",
+                                                             "mtp_0_"))]
+    assert sorted(names) == ["embed", "final_norm", "lm_head_kernel"]
+    assert {"mtp_0_embed_norm", "mtp_0_state_norm", "mtp_0_proj",
+            "mtp_0_layer_0", "mtp_0_layer_1", "mtp_0_final_norm"} <= set(
+                params)
+    assert "gate_kernel" not in params["layer_0"]["mixer"]
+    text = lowered.compile().as_text()
+    for kernel in ("hvd_flash_fwd", "hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 2
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 2 * 9
+    _assert_scopes_forward_and_backward(
+        text, ("hvd_mla_q_latent", "hvd_mla_q_proj", "hvd_mla_kv_latent",
+               "hvd_mla_attend", "hvd_mla_out_proj", "hvd_moe_router",
+               "hvd_moe_shared", "hvd_embed", "hvd_lm_head",
+               "hvd_mtp", "hvd_mtp/hvd_mtp_proj", "hvd_mtp/hvd_embed",
+               "hvd_mtp/hvd_lm_head",
+               r"hvd_mtp/[^\"]*hvd_mla_q_latent",
+               r"hvd_mtp/[^\"]*hvd_mla_attend",
+               r"hvd_mtp/[^\"]*hvd_moe_router",
+               r"hvd_mtp/[^\"]*hvd_moe_shared"))
+    # The module's flash kernels keep its scope in their own op_names: a
+    # reader files them by path (benchmark/layer_metrics/_joyai.py).
+    for kernel in ("hvd_flash_fwd", "hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"):
+        assert len(re.findall(
+            rf'%{kernel}[.\d]* = [^\n]*op_name="[^"]*/hvd_mtp/', text)) == 1
+
+
 @pytest.mark.parametrize("width", [1024, 1280, 2560])
 def test_embedding_gradient_is_slabs_under_its_scope(v5e, width):
     """The gradient of a one-layer dense LM compiled for the described chip,

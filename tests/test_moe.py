@@ -729,7 +729,7 @@ CELL_WAYS_BACK = {
     "sdar30ba3b": (8192, "held_pairs"), "qwen3next80b": (4096, "row_slabs"),
     "mellum2": (16384, "held_pairs"), "ouro2p6b": (None, None),
     "keyevl2": (8192, "held_pairs"), "olmohybrid7b": (None, None),
-    "granite4hmicro": (None, None)}
+    "granite4hmicro": (None, None), "joyaiflash": (8192, "row_slabs")}
 
 
 # Tokens a step a chip of every benchmark cell's configuration, and the
@@ -744,7 +744,7 @@ CELL_KERNELS = {
     "sdar30ba3b": (8192, "tiled"), "qwen3next80b": (4096, "ragged_dot"),
     "mellum2": (16384, "tiled"), "ouro2p6b": (None, None),
     "keyevl2": (8192, "tiled"), "olmohybrid7b": (None, None),
-    "granite4hmicro": (None, None)}
+    "granite4hmicro": (None, None), "joyaiflash": (8192, "ragged_dot")}
 
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(

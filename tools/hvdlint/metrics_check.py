@@ -53,6 +53,7 @@ SECTION_FAMILIES = {
     "attention": ("hvd_tpu_attention_blocks",),
     "delta": ("hvd_tpu_delta_steps",),
     "ssm": ("hvd_tpu_ssm_chunks",),
+    "mtp": ("hvd_tpu_mtp_loss",),
     "train_step": ("hvd_tpu_train_step_all_reduces",),
     "compression": ("hvd_tpu_compression_mode",
                     "hvd_tpu_compression_wire_bytes_total",
