@@ -1144,8 +1144,9 @@ def _combined_bwd_call(q, do, lse8, delta8, k_cur, v_cur, q_offset=None,
 
 _MAX_BLOCK = 1024  # largest block edge the VMEM calibration covers
 # The most scoped VMEM a kernel here asks Mosaic for (`_combined_vmem_limit`):
-# half of a v5e core's 128 MiB.  No band of `_bwd_plan` comes near it (47.1
-# MiB is the most a probe of the sweep asked), so it is asserted, not clipped.
+# half of a v5e core's 128 MiB.  No band of `_bwd_plan` passes it (54.1 MiB at
+# 8,192 rows of 256 lanes in 1,024-blocks is the most a band asks; 62.9 the
+# most a probe of the sweeps did), so it is asserted, not clipped.
 _MAX_VMEM_LIMIT = 64 << 20
 # What a Mosaic kernel on a v5e has when it asks for nothing, MiB: the
 # default of ``HVD_TPU_VMEM_LIMIT_MB``.
@@ -1177,6 +1178,12 @@ def _vmem_budget_bytes() -> int:
                      or _DEFAULT_VMEM_MB) * (1 << 20))
 
 
+def _lanes(d: int) -> int:
+    """The lanes Mosaic lays a head of ``d`` out in: whole 128-lane tiles
+    (64 in 128, 192 in 256)."""
+    return -(-d // 128) * 128
+
+
 def _plan_vmem_bytes(mode: str, q_len: int, d: int, block_q: int,
                      block_k: int, d_v: Optional[int] = None) -> int:
     """Conservative scoped-VMEM estimate for a backward plan, bytes.
@@ -1200,8 +1207,8 @@ def _plan_vmem_bytes(mode: str, q_len: int, d: int, block_q: int,
     2026-10-03 re-run whose estimate is past the default (8,192 rows with a
     1,024-block edge, 11,520, 12,288 and 16,384 rows: `_bwd_plan`) compile
     with what it asks."""
-    lanes = max(d, 128)
-    both = lanes + max(d_v or d, 128)   # a q/k-wide and a v-wide window
+    lanes = _lanes(d)
+    both = lanes + _lanes(d_v or d)     # a q/k-wide and a v-wide window
     w, db = 4, 2              # f32 worst case; double-buffered windows
     lse = db * w * 8 * 2 * block_q          # lse8 + delta8 windows
     if mode == "combined":
@@ -1245,9 +1252,9 @@ def _fwd_vmem_bytes(q_len: int, d: int, block_q: int,
     """Same structural estimate for the forward kernel (q in + out + k/v
     windows, lse output, online-softmax scratch); the output and its
     accumulator have v's width."""
-    lanes_v = max(d_v or d, 128)
+    lanes_v = _lanes(d_v or d)
     w, db = 4, 2
-    return (db * w * (max(d, 128) + lanes_v) * (block_q + block_k)
+    return (db * w * (_lanes(d) + lanes_v) * (block_q + block_k)
             + db * w * 8 * block_q                       # lse out
             + w * block_q * (2 * 128 + lanes_v))         # m/l/acc scratch
 
@@ -1282,7 +1289,7 @@ def _clamp_blocks(mode: str, q_len: int, d: int, block_q: int,
 def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
               bh: int = 1, d_v: Optional[int] = None):
     """Choose the flash-backward execution mode and blocks against the
-    16 MiB of scoped VMEM a kernel has without asking and, in one band, the
+    16 MiB of scoped VMEM a kernel has without asking and, in two bands, the
     limit its call asks Mosaic for (`_combined_vmem_limit`).
 
     Calibrated by a compile sweep for v5e (tools/vmem_sweep.py; the
@@ -1290,29 +1297,32 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
     tests/test_ops.py).  Mosaic's scoped allocation for the combined
     kernel is NOT a simple closed form — it grows with the whole-seq dq
     scratch (head_dim <= 128 pads to 128 lanes, so sequence length
-    enters as ``q_len * max(d, 128)``), with block size, and
+    enters as ``q_len * lanes``), with block size, and
     NON-MONOTONICALLY with the batch*heads grid dimension (measured:
     seq 8192 at 1024-blocks is 23.2 MiB at bh=16 but 16.5 MiB at
     bh=32; seq 8192 at 512-blocks fits at bh<=32 and exceeds by 0.17
     MiB at bh=64) — so the bands below come from the measured pass/fail
     frontier with margin, not a model:
 
-    The combined kernel is restricted to head_dim <= 128 outright: wide
-    heads fail at shapes whose 128-lane equivalents fit (measured d=256:
-    17.9 MiB at seq 1024/bh 64 with 1024-blocks, 18.8 MiB at seq
-    2048/bh 64 with (512, 1024) — where d=64 passes both at bh up to
-    1024), and the sweep has no wide-head pass region worth the risk.
-    For d <= 128 (lane-padded, so seq enters as q_len*max(d,128)/128):
+    A head is laid out in whole 128-lane tiles (`_lanes`: 64 in 128, 192 in
+    256), so seq enters as ``rows128 = q_len * lanes / 128``.  Heads past 128
+    lanes fail WITHOUT ASKING at shapes whose 128-lane equivalents fit
+    (measured d=256: 17.9 MiB at seq 1024/bh 64 with 1024-blocks, 18.8 MiB
+    at seq 2048/bh 64 with (512, 1024) — where d=64 passes both at bh up to
+    1024), which kept them on the pair until the call could ask (PR 57) and
+    the wide-head region was swept and timed (PR 67, below):
 
-    =====================  ==========  =============================
-    q_len*max(d,128)/128   bh          choice
-    =====================  ==========  =============================
-    <= 2048                any(<=1024)  combined, tuned blocks (1024)
-    <= 4096                any(<=512)   combined (512, 1024)
-    <= 8192                <= 32        combined (512, 512)
-    8192 < .. <= 16384     <= 128       combined (512, 512), ASKING
-    otherwise              any          split, tuned blocks (1024)
-    =====================  ==========  =============================
+    ==========  ====================  ==========  ==========================
+    lanes       rows128               bh          choice
+    ==========  ====================  ==========  ==========================
+    128         <= 2048               <= 1024     combined, tuned blocks (1024)
+    128         <= 4096               <= 512      combined (512, 1024)
+    128         <= 8192               <= 32       combined (512, 512)
+    128         8192 < .. <= 16384    <= 128      combined (512, 512), ASKING
+    256         <= 8192, ASKING       <= 128      combined (512, 512), ASKING
+    256         8192 < .. <= 16384    <= 128      combined (1024, 1024), ASKING
+    otherwise                         any         split, tuned blocks (1024)
+    ==========  ====================  ==========  ==========================
 
     ASKING (PR 57): the band's whole-sequence dq does not fit what a kernel
     has without asking, so its call names ``vmem_limit_bytes`` — what
@@ -1324,6 +1334,37 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
     that budget is the default's 16 MiB or more: set lower, the band's rows
     take the pair as they did before it.  The bands above it ask for nothing
     and lower to the text they always lowered to.
+
+    WIDE HEADS (PR 67): two tiles of lanes — latent attention's 192 (q, k) /
+    128 (v), which the estimate charges as 256 / 128, and heads of 256 — take
+    the combined kernel up to the whole-sequence dq the asking band holds
+    (8,192 rows of 256 lanes), at the bh the sweep probed, under the same
+    rule of the budget, and ONLY WHERE THE CALL ASKS: 8,192 rows of 192 / 128
+    ask for 50.6 MiB in their 1,024-blocks, 4,096 of 256 for 23.1 in
+    512-blocks, 2,048 of either for nothing.  Blocks by measurement, the
+    backward alone in a program, bf16, causal (``tools/flash_bwd_sweep.py
+    --d-v``, medians of 10 on a v5e, my chip run, PR 67), ms — the pair in
+    1,024-blocks / the combined kernel in (512, 512), (512, 1024),
+    (1024, 1024): JoyAI's (1, 32, 8192, 192 / 128) 21.91 / 17.35, 17.24,
+    **16.75**; Ling's (1, 4, 8192, 192 / 128) 3.449 / **2.822**, 2.890,
+    2.835; Qwen3-Next's (1, 16, 4096, 256) 4.595 (4.435 in 512-blocks) /
+    **3.495**, 3.629, 3.561 — the combined kernel takes 0.76 to 0.82 of the
+    pair's time at every shape, so 256 lanes enter the band with 192 / 128.
+    At 8,192 rows the 1,024-blocks win where there is work to tell (bh 32:
+    3.5 % of the kernel, and IN THE JOYAI STEP 28,057 tok/s/chip against
+    27,700 in (512, 512), +1.29 %, two pairs, the parent 25,391) and tie at
+    bh 4; at 4,096 rows the (512, 512) blocks win by 1.9 % (a 1,024-tile on
+    the diagonal computes its masked half, 4 of a head's 10 tiles there
+    against 8 of 36): the band takes 1,024-blocks past 4,096 rows and 512 up
+    to there.  A wide head short enough to ask for nothing keeps
+    the pair — those kernels compile too (55 probes below), but nobody has
+    timed them, the old frontier above was theirs, and "a wide-head plan
+    always asks" is what lets the fused ring (`ops/ring_flash.py`, whose
+    rotating kernel was never probed past 128 lanes) refuse one by the rule
+    it has.  Past 256 lanes, past the probes' bh and past 8,192 rows
+    (16,384 rows of 192 / 128 compile at 57.3 MiB in (512, 512), 7 MiB under
+    `_MAX_VMEM_LIMIT`, and not in 1,024-blocks; no cell, no timing): the
+    pair.
 
     Blocks by measurement, the backward alone in
     a program at (1, 32, 16384, 128) bf16 (``tools/flash_bwd_sweep.py``,
@@ -1364,15 +1405,28 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
     — compile with the limit their call names (19.7 to 47.1 MiB).  Outside
     the bands and not in the sweep (issue 57's compiles): 32,768 rows compile
     at 96–100 MiB and 65,536 at 110 MiB, past `_MAX_VMEM_LIMIT`; 4,096 rows of
-    256 lanes at 32 MiB (wide heads stay on the pair: ROADMAP S1(h)).
+    256 lanes at 32 MiB (wide heads stayed on the pair until PR 67).
+
+    Wide heads, 2026-10-05 (PR 67: ``tools/vmem_sweep.py --wide``, libtpu
+    0.0.34 compiling for a described v5e, the estimate's widths rounded up to
+    whole tiles): 240 probes — (d, d_v) in {(192, 128), (256, 256)} x rows
+    {2,048, 4,096, 8,192, 16,384} x bh {4, 16, 32, 64, 128} x the six block
+    pairs, each forced onto the combined kernel.  **Every probe whose call
+    may be made compiles, 215 of 215**: the 55 that ask for nothing (2,048
+    rows in all but the largest blocks and 4,096 in (256, 256)) and the 160
+    that name their limit (18.5 to 62.9 MiB; every probe at 8,192 rows, 33.3
+    MiB in (512, 512) and 50.6 in (1024, 1024) at 192 / 128); the other 25
+    — 16,384 rows with a 1,024-block edge — would ask for 65 to 78 MiB, past
+    `_MAX_VMEM_LIMIT`, and the call refuses them itself.  bh moved nothing.
 
     ``mode`` is ``"combined"`` (one probability recompute per block,
     whole-seq dq scratch — preferred where it fits because it recomputes
     once; the benchmark's cells up to 16,384 rows of heads up to 128 run it,
     PERF.md section 3) or ``"split"`` (dkdv + dq kernel pair, O(block)
     scoped memory: full 1024-blocks compile at every probed extreme — seq to
-    64k, bh to 256, d to 256).  Two cells run the pair: Ling's (192 / 128
-    wide) and Qwen3-Next's (256).  Until PR 57 Mellum2's ran it at head 128 —
+    64k, bh to 256, d to 256).  No cell of the benchmark runs the pair since
+    PR 67 (Ling's and JoyAI's 192 / 128 and Qwen3-Next's 256 ran it until
+    then).  Until PR 57 Mellum2's ran it at head 128 —
     16,384 rows, bh 32, 1,024-blocks — where the causal pair took 27.2 + 21.5
     ms a layer for a forward call's 19.2 and read 57.4 % of the backward's
     roofline, the banded pair 29.0 % (ledger, PR 56).  That was the pair's
@@ -1382,13 +1436,14 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
     (five products a tile where the pair does seven: 0.71).
 
     ``d_v``: the width of v, do and dv where it is not ``d`` (q, k, dq, dk).
-    The bands above are entered with the WIDER of the two — every probe of
-    the sweep had one width — so latent attention's 192 and 128 take the
-    split pair, whose windows :func:`_plan_vmem_bytes` charges each at its
-    own width."""
+    The bands above are entered with the WIDER of the two, and
+    :func:`_plan_vmem_bytes` charges each window at its own width: latent
+    attention's 192 and 128 enter as 256 lanes and are charged 256 and
+    128."""
     wide = max(d, d_v or d)
     estimate = functools.partial(_plan_vmem_bytes, d_v=d_v)
-    rows128 = q_len * max(wide, 128) // 128
+    rows128 = q_len * _lanes(wide) // 128
+    may_ask = _vmem_budget_bytes() >= _DEFAULT_VMEM_MB * (1 << 20)
     if wide <= 128:
         # Each band is gated at its CALIBRATED bh bound (the table
         # above); anything beyond falls through to split, which
@@ -1410,8 +1465,7 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
         elif rows128 <= 8192 and bh <= 32:
             choice = (_pick_block(q_len, min(block_q, 512)),
                       _pick_block(q_len, min(block_k, 512)))
-        elif (8192 < rows128 <= 16384 and bh <= 128
-              and _vmem_budget_bytes() >= _DEFAULT_VMEM_MB * (1 << 20)):
+        elif 8192 < rows128 <= 16384 and bh <= 128 and may_ask:
             # Past what a kernel has without asking: the call names its
             # limit (`_combined_vmem_limit`: the blocks' need and a margin,
             # or nothing under a budget raised past the need).
@@ -1429,6 +1483,19 @@ def _bwd_plan(q_len: int, d: int, block_q: int, block_k: int,
                 f"({_vmem_budget_bytes() >> 20} MiB) at any block size "
                 "(whole-seq dq scratch); demoting to the split kernels",
                 stacklevel=2)
+    elif wide <= 256 and rows128 <= 16384 and bh <= 128 and may_ask:
+        # The wide-head band (PR 67): a whole-sequence dq no larger than
+        # the asking band's above, two 128-lane tiles wide, in the blocks
+        # the timing table chose — 1,024 past 4,096 rows, 512 up to there.
+        # Entered only where the call ASKS (the estimate at these blocks is
+        # past what a kernel has without asking: 4,096 rows are, 2,048 are
+        # not): every wide-head combined plan names its limit, which is how
+        # the fused ring knows to refuse one.
+        edge = 1024 if rows128 > 8192 else 512
+        blocks = (_pick_block(q_len, min(block_q, edge)),
+                  _pick_block(q_len, min(block_k, edge)))
+        if _combined_vmem_limit(q_len, d, *blocks, d_v) is not None:
+            return ("combined",) + blocks
     fitted = _clamp_blocks("split", q_len, d, _pick_block(q_len, block_q),
                            _pick_block(q_len, block_k), estimate=estimate)
     return ("split",) + fitted
@@ -1445,10 +1512,11 @@ def _split_bwd_call(q, do, lse8, delta8, k, v, *, mask, block_q,
     16,384 rows of 128 it takes 1.3 to 1.6 times the combined kernel's time:
     the table in `_bwd_plan`, my chip run, PR 57); the combined kernel is
     preferred wherever its whole-seq dq scratch fits the scoped VMEM it has
-    or asks for — up to 16,384 rows of heads up to 128 — and this pair runs
-    past that: longer sequences, wider heads, bh past a band's probes (see
-    _bwd_plan; the 2026-10-03 re-run of ``tools/vmem_sweep.py`` left it no
-    probe the combined kernel fails).  Returns (dk, dv, dq) in
+    or asks for — up to 16,384 rows of heads up to 128 lanes, 8,192 of heads
+    up to 256 — and this pair runs past that: longer sequences, heads past
+    256 lanes, bh past a band's probes, a wide head whose combined call would
+    ask for nothing (see _bwd_plan; the re-runs of ``tools/vmem_sweep.py``
+    left it no probe the combined kernel fails).  Returns (dk, dv, dq) in
     ``grad_dtype`` (f32
     accumulation in scratch; the flush casts).  Each kernel's grid is its
     table's live tiles (`_tile_table`)."""
@@ -1539,7 +1607,8 @@ def _flash_backward(q, k, v, out, lse, g, mask, sm_scale, block_q,
     :func:`_bwd_plan` against the scoped VMEM a kernel has or asks for: the
     combined kernel computes dk/dv AND dq from a single probability recompute
     per block (whole-seq dq scratch), the split dkdv/dq pair recomputes twice
-    but needs only O(block) scoped memory (past 16,384 rows).  Residual
+    but needs only O(block) scoped memory (past 16,384 rows of 128 lanes or
+    8,192 of 256).  Residual
     memory is O(seq) either way (Dao et al. alg. 2)."""
     batch, heads, q_len, d = q.shape
     k_len, d_v = k.shape[2], v.shape[-1]

@@ -505,8 +505,8 @@ def fused_ring_attention(q, k, v, axis_name: str, causal: bool = False,
     ``(batch, heads, seq_local, head_dim)`` inside ``shard_map`` over
     ``axis_name``).  Raises :class:`FusedRingUnsupported` for what the
     kernel cannot run: float16 (compiled path), shard lengths that do not
-    tile into MXU blocks, and local shards too long for the backward's
-    whole-shard dq scratch.
+    tile into MXU blocks, and local shards too long, or heads too wide (past
+    128 lanes), for the backward's whole-shard dq scratch.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
@@ -534,15 +534,17 @@ def fused_ring_attention(q, k, v, axis_name: str, causal: bool = False,
     bh = q.shape[0] * q.shape[1]
     mode, bq, bk = _bwd_plan(sl, d, bq, bk, bh)
     # A plan whose call would ask Mosaic for more than the default (past
-    # 8,192 rows a shard) has been probed without the rotation only: refused
-    # as the split pair's shapes are.
+    # 8,192 rows a shard; every combined plan of a head past 128 lanes, which
+    # the plan sends to that kernel only where it asks) has been probed
+    # without the rotation only: refused as the split pair's shapes are.
     asking = mode == "combined" \
         and _combined_vmem_limit(sl, d, bq, bk) is not None
     if mode != "combined" or sl % bq or sl % bk or asking:
         raise FusedRingUnsupported(
             f"local shard length {sl} at head_dim {d} and batch*heads "
-            f"{bh} is past where the fused backward's "
-            "whole-shard dq scratch fits scoped VMEM (attention._bwd_plan "
+            f"{bh} is outside the shapes whose whole-shard dq scratch the "
+            "fused backward holds in the scoped VMEM a kernel has without "
+            "asking (attention._bwd_plan "
             f"chose {mode!r}{', asking for more than the default' * asking}"
             "); use more ring devices, or rotate_impl=\"ppermute\"")
     if interpret and len(_ambient_mesh_axes(axis_name)) > 1:

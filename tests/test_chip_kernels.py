@@ -39,6 +39,15 @@ def _compile_flash_grad(device, shape, **kwargs):
 
 
 
+def _assert_forward_and_combined_backward(text):
+    """Two custom calls, each once by name: the forward and the combined
+    backward, and nothing of the split pair."""
+    assert text.count('"tpu_custom_call"') == 2
+    for name in ("hvd_flash_fwd", "hvd_flash_bwd"):
+        assert len(re.findall(rf"%\w*?_{name}_*\.\d+ = ", text)) == 1, name
+    assert "hvd_flash_bwd_dkdv" not in text and "hvd_flash_bwd_dq" not in text
+
+
 @pytest.mark.parametrize("d", [64, 128])
 @pytest.mark.parametrize("seq", [1024, 4096, 8192, 16384])
 def test_flash_bwd_seq_sweep_compiles(v5e, seq, d):
@@ -163,23 +172,31 @@ def test_the_chunked_scan_at_the_cells_shapes(
         assert (f"%{kernel}" in text) == kernels, kernel
 
 
-def test_flash_two_widths_at_latent_attention_shape_compile(v5e):
-    """Latent attention as the Ling-3.0-flash cell runs it — 4 heads, 8,192
-    tokens, query and key 192 wide, value 128 — forward and backward through
-    the chip's compiler with nothing padded: the plan enters its bands with
-    the wider width, so the backward is the split pair at 1024-blocks, and
-    the gradients keep their operands' widths (PR 32)."""
+@pytest.mark.parametrize("heads", [4, 32], ids=["ling", "joyai"])
+def test_flash_two_widths_at_latent_attention_shape_compile(v5e, heads):
+    """Latent attention as the Ling-3.0-flash cell runs it (4 heads) and as the
+    JoyAI-LLM-Flash cell does (32) — 8,192 tokens, query and key 192 wide,
+    value 128 — forward and backward through the chip's compiler with nothing
+    padded: the plan's wide-head band (PR 67) sends it to the combined kernel
+    in (1024, 1024) blocks, whose call names the `vmem_limit_bytes` its
+    whole-sequence dq at 256 lanes needs, and the gradients keep their
+    operands' widths (PR 32)."""
     from jax.sharding import SingleDeviceSharding
 
-    from horovod_tpu.ops.attention import _bwd_plan
+    import horovod_tpu.ops.attention as attn
 
-    assert _bwd_plan(8192, 192, 1024, 1024, 4, 128) == ("split", 1024, 1024)
+    assert attn._bwd_plan(8192, 192, 1024, 1024, heads, 128) \
+        == ("combined", 1024, 1024)
+    limit = attn._combined_vmem_limit(8192, 192, 1024, 1024, 128)
+    assert 16 << 20 < limit == 53084160 <= attn._MAX_VMEM_LIMIT
     # One width, as every call before PR 32: the same plan with and without.
-    assert _bwd_plan(8192, 64, 1024, 1024, 16, 64) \
-        == _bwd_plan(8192, 64, 1024, 1024, 16) == ("combined", 512, 512)
+    assert attn._bwd_plan(8192, 64, 1024, 1024, 16, 64) \
+        == attn._bwd_plan(8192, 64, 1024, 1024, 16) == ("combined", 512, 512)
     on_chip = SingleDeviceSharding(v5e[0])
-    q = jax.ShapeDtypeStruct((1, 4, 8192, 192), jnp.bfloat16, sharding=on_chip)
-    v = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16, sharding=on_chip)
+    q = jax.ShapeDtypeStruct((1, heads, 8192, 192), jnp.bfloat16,
+                             sharding=on_chip)
+    v = jax.ShapeDtypeStruct((1, heads, 8192, 128), jnp.bfloat16,
+                             sharding=on_chip)
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True,
@@ -188,26 +205,25 @@ def test_flash_two_widths_at_latent_attention_shape_compile(v5e):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, q, v).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 3
-    for name in ("hvd_flash_fwd", "hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"):
-        assert name in text
+    _assert_forward_and_combined_backward(text)
+    # the limit the call names, as the compiled custom call carries it
+    assert f'"memory_space":"1","offset":"0","size":"{limit}"' in text
     assert [g.shape[-1] for g in compiled.out_info] == [192, 192, 128]
 
 
 def test_flash_head256_at_qwen3next_shape_compiles(v5e):
     """Gated attention as the Qwen3-Next cell runs it — 1 x 16 heads of 256 at
     4,096 rows (a key/value head repeated for its 8 query heads before the
-    kernels) — in the band `_bwd_plan` sends it to: no combined backward past
-    128 lanes, so the split pair at 1,024-blocks, which with the forward
-    compiles for the described chip (PR 46: the first cell past a head of
-    128)."""
-    from horovod_tpu.ops.attention import _bwd_plan
+    kernels) — in the band `_bwd_plan` sends it to since PR 67: the combined
+    backward in (512, 512) blocks asking for the scoped VMEM two tiles of
+    lanes need, which with the forward compiles for the described chip
+    (PR 46: the first cell past a head of 128, then on the split pair)."""
+    import horovod_tpu.ops.attention as attn
 
-    assert _bwd_plan(4096, 256, 1024, 1024, 16) == ("split", 1024, 1024)
+    assert attn._bwd_plan(4096, 256, 1024, 1024, 16) == ("combined", 512, 512)
+    assert attn._combined_vmem_limit(4096, 256, 512, 512) > 16 << 20
     text = _compile_flash_grad(v5e[0], (1, 16, 4096, 256))
-    assert text.count('"tpu_custom_call"') == 3
-    for name in ("hvd_flash_fwd", "hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"):
-        assert name in text
+    _assert_forward_and_combined_backward(text)
 
 
 def test_grouped_matmul_lowers_to_libtpu_kernels(v5e):

@@ -131,19 +131,89 @@ def test_bwd_plan_matches_vmem_calibration():
     # bands never extrapolate past their calibrated bh bound
     assert _bwd_plan(1024, 64, 1024, 1024, 2048)[0] == "split"
     assert _bwd_plan(4096, 64, 1024, 1024, 1024)[0] == "split"
-    # wide heads never take the combined kernel (d=256 measured failing
-    # at seq 1024/bh 64 where the d=64 lane-equivalent passes)
     assert _bwd_plan(2048, 128, 1024, 1024, 16)[0] == "combined"
     assert _bwd_plan(8192, 128, 1024, 1024, 16) == ("combined", 512, 512)
+    # heads past 128 lanes (PR 67's band, `tools/vmem_sweep.py --wide`): the
+    # combined kernel where its call ASKS, up to the whole-sequence dq the
+    # 16,384-row band holds — the three cells that run such heads (JoyAI's
+    # and Ling's latent attention at 192 / 128, Qwen3-Next's 256) ...
+    # in the blocks the timing table chose (`_bwd_plan`): 1,024 past 4,096
+    # rows, 512 up to there
+    assert _bwd_plan(8192, 192, 1024, 1024, 32, 128) \
+        == ("combined", 1024, 1024)
+    assert _bwd_plan(8192, 192, 1024, 1024, 4, 128) == ("combined", 1024, 1024)
+    assert _bwd_plan(4096, 256, 1024, 1024, 16) == ("combined", 512, 512)
+    assert _bwd_plan(4096, 192, 1024, 1024, 128, 128) \
+        == ("combined", 512, 512)
+    assert _bwd_plan(8192, 256, 1024, 1024, 8) == ("combined", 1024, 1024)
+    assert _bwd_plan(6144, 192, 1024, 1024, 8, 128) \
+        == ("combined", 1024, 1024)
+    assert _bwd_plan(8192, 192, 512, 512, 32, 128) == ("combined", 512, 512)
+    # ... and the pair where a kernel that asks for nothing would do (the
+    # old frontier: d=256 measured failing at seq 1024/bh 64 where the d=64
+    # lane-equivalent passes), past the probes' bh, past the rows whose dq
+    # the asking band holds, and past two tiles of lanes
     assert _bwd_plan(1024, 256, 1024, 1024, 64)[0] == "split"
-    assert _bwd_plan(4096, 256, 1024, 1024, 16)[0] == "split"
+    assert _bwd_plan(2048, 192, 1024, 1024, 32, 128)[0] == "split"
+    assert _bwd_plan(8192, 192, 1024, 1024, 256, 128)[0] == "split"
+    assert _bwd_plan(16384, 192, 1024, 1024, 4, 128)[0] == "split"
     assert _bwd_plan(16384, 256, 1024, 1024, 8)[0] == "split"
+    with pytest.warns(UserWarning, match="clamped"):
+        assert _bwd_plan(4096, 320, 1024, 1024, 8)[0] == "split"
     # past 16,384 rows the pair, as ever
     assert _bwd_plan(32768, 128, 1024, 1024, 8) == ("split", 1024, 1024)
     assert _bwd_plan(32768, 64, 1024, 1024, 32)[0] == "split"
     # plan blocks must divide the sequence even for non-pow2 lengths
     mode, bq, bk = _bwd_plan(11520, 64, 1024, 1024, 8)
     assert 11520 % bq == 0 and 11520 % bk == 0
+
+
+# The benchmark's twelve language-model cells whose heads are 64 or 128 wide:
+# rows, head width, batch * heads a chip, and what `_bwd_plan` and
+# `_combined_vmem_limit` gave at commit 8ad772b, PR 67's parent.
+NARROW_CELLS = [
+    ("pythia410m_1chip_4x2k", 2048, 64, 64, ("combined", 1024, 1024), None),
+    ("pythia410m_1chip_1x8k", 8192, 64, 16, ("combined", 512, 512), None),
+    ("pythia410m_dp4_4x2k", 2048, 64, 64, ("combined", 1024, 1024), None),
+    ("olmoe1b7b_1chip_ep4share_2x4k", 4096, 128, 32,
+     ("combined", 512, 1024), None),
+    ("nemotron3super120b_1chip_tp8ep64share_1x4k", 4096, 128, 4,
+     ("combined", 512, 1024), None),
+    ("trinitymini_1chip_ep8share_1x8k", 8192, 128, 32,
+     ("combined", 512, 512), None),
+    ("sdar30ba3b_1chip_ep8share_1x4k_noised", 8192, 128, 32,
+     ("combined", 512, 512), None),
+    ("mellum2_1chip_ep4share_1x16k", 16384, 128, 32,
+     ("combined", 512, 512), 33095680),
+    ("ouro2p6b_1chip_pp6share_1x4k", 4096, 128, 16,
+     ("combined", 512, 1024), None),
+    ("keyevl2_1chip_ep8share_1x8k", 8192, 128, 32,
+     ("combined", 512, 512), None),
+    ("olmohybrid7b_1chip_tp2share_1x8k", 8192, 128, 15,
+     ("combined", 512, 512), None),
+    ("granite4hmicro_1chip_pp4share_1x8k", 8192, 64, 32,
+     ("combined", 512, 512), None),
+]
+
+
+@pytest.mark.parametrize("cell,seq,d,bh,plan,limit", NARROW_CELLS,
+                         ids=[row[0] for row in NARROW_CELLS])
+def test_bwd_plan_of_a_narrow_head_cell_is_its_parents(cell, seq, d, bh, plan,
+                                                       limit):
+    """The wide-head band and the estimate's lane rounding move nothing at a
+    head of 64 or 128: each such cell's backward takes the mode and blocks it
+    took, its call names the limit it named, and the estimate reads the bytes
+    it read at 128 lanes."""
+    import horovod_tpu.ops.attention as attn
+
+    assert attn._bwd_plan(seq, d, 1024, 1024, bh) == plan
+    assert attn._bwd_plan(seq, d, 1024, 1024, bh, d) == plan
+    assert attn._combined_vmem_limit(seq, d, *plan[1:]) == limit
+    for mode in ("combined", "split"):
+        assert attn._plan_vmem_bytes(mode, seq, d, *plan[1:]) \
+            == attn._plan_vmem_bytes(mode, seq, 128, *plan[1:], 128)
+    assert attn._fwd_vmem_bytes(seq, d, 1024, 1024) \
+        == attn._fwd_vmem_bytes(seq, 128, 1024, 1024)
 
 
 def test_bwd_plan_fits_vmem_budget(monkeypatch):
@@ -172,7 +242,25 @@ def test_bwd_plan_fits_vmem_budget(monkeypatch):
                 if limit:
                     asked.add((seq, d <= 128, bh <= 128))
     # only the band that the raised limit opened asks
-    assert asked == {(16384, True, True)}
+    # ... and the wide-head band, every plan of which asks
+    assert asked == {(16384, True, True), (8192, False, True)}
+    for seq, d, d_v, bh in ((8192, 192, 128, 32), (8192, 192, 128, 4),
+                            (4096, 256, 256, 16), (4096, 192, 128, 128),
+                            (6144, 192, 128, 8), (8192, 256, 256, 128)):
+        mode, bq, bk = attn._bwd_plan(seq, d, 1024, 1024, bh, d_v)
+        assert mode == "combined" and seq % bq == 0 and seq % bk == 0
+        limit = attn._combined_vmem_limit(seq, d, bq, bk, d_v)
+        assert attn._vmem_budget_bytes() < limit <= attn._MAX_VMEM_LIMIT
+        assert attn._plan_vmem_bytes(mode, seq, d, bq, bk, d_v) <= limit
+    # Mosaic lays 192 lanes out in two whole tiles of 128: the estimate at
+    # 192 / 128 is the one at 256 / 128, and at one tile or two it is what it
+    # was (every earlier band, every earlier call's `vmem_limit_bytes`)
+    for mode in ("combined", "split"):
+        assert attn._plan_vmem_bytes(mode, 8192, 192, 1024, 1024, 128) \
+            == attn._plan_vmem_bytes(mode, 8192, 256, 1024, 1024, 128)
+    assert attn._combined_vmem_limit(16384, 128, 512, 512) == 33095680
+    assert attn._plan_vmem_bytes("combined", 8192, 64, 512, 512) \
+        == attn._plan_vmem_bytes("combined", 8192, 128, 512, 512) == 16318464
     # The measured r04 failure (combined 1024-blocks at seq 8192:
     # 23.2 MiB) must score over the default 16 MiB budget — the estimate
     # is only a guard if it rejects the shape that actually OOMed.
@@ -196,6 +284,12 @@ def test_bwd_plan_fits_vmem_budget(monkeypatch):
         assert (attn._plan_vmem_bytes(mode, 16384, 128, bq, bk)
                 <= attn._vmem_budget_bytes())
     assert attn._bwd_plan(12288, 64, 1024, 1024, 32)[0] == "split"
+    for seq, d, d_v, bh in ((8192, 192, 128, 32), (4096, 256, 256, 16)):
+        with pytest.warns(UserWarning, match="clamped"):
+            mode, bq, bk = attn._bwd_plan(seq, d, 1024, 1024, bh, d_v)
+        assert mode == "split"
+        assert (attn._plan_vmem_bytes(mode, seq, d, bq, bk, d_v)
+                <= attn._vmem_budget_bytes())
     # The name bounds what a kernel has WITHOUT asking: raised past the
     # 16,384-row plan's need, that plan's call asks for nothing.
     assert attn._combined_vmem_limit(16384, 128, 512, 512) is not None
@@ -371,6 +465,36 @@ def test_flash_combined_backward_equals_the_pair(monkeypatch, window):
                                                 block_k=128))
     for one, other, ref in zip(by_plan["combined"], by_plan["split"],
                                grads(mha_reference)):
+        np.testing.assert_allclose(one, ref, atol=1e-3, rtol=1e-3)
+        np.testing.assert_allclose(one, other, atol=1e-5, rtol=1e-5)
+
+
+def test_flash_combined_backward_at_two_widths_equals_the_pair(monkeypatch):
+    """Latent attention's widths, 192 (q, k) and 128 (v), causal over 512 rows
+    in (128, 128) blocks: the combined kernel — windows, accumulators and the
+    whole-sequence dq each at its own width — gives `mha_reference`'s dq, dk
+    and dv, each of its operand's shape, and the split pair's to a float32
+    sum's order."""
+    import horovod_tpu.ops.attention as attn
+
+    keys = jax.random.split(jax.random.PRNGKey(67), 3)
+    q, k = (jax.random.normal(key, (1, 2, 512, 192)) for key in keys[:2])
+    v = jax.random.normal(keys[2], (1, 2, 512, 128))
+
+    def grads(fn):
+        return jax.grad(lambda q, k, v: (fn(q, k, v, causal=True) ** 2).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    by_plan = {}
+    for plan in ("combined", "split"):
+        monkeypatch.setattr(attn, "_bwd_plan",
+                            lambda q_len, d, bq, bk, bh=1, d_v=None, plan=plan:
+                            (plan, 128, 128))
+        by_plan[plan] = grads(functools.partial(flash_attention, block_q=128,
+                                                block_k=128))
+    for one, other, ref in zip(by_plan["combined"], by_plan["split"],
+                               grads(mha_reference)):
+        assert one.shape == other.shape == ref.shape
         np.testing.assert_allclose(one, ref, atol=1e-3, rtol=1e-3)
         np.testing.assert_allclose(one, other, atol=1e-5, rtol=1e-5)
 
@@ -664,6 +788,25 @@ def test_fused_ring_flash_oversized_shard_raises_typed(monkeypatch):
     monkeypatch.setattr(rf, "_combined_vmem_limit", lambda *a: 32 << 20)
     with pytest.raises(rf.FusedRingUnsupported, match="asking for more"):
         run(*_qkv(batch=1, heads=2, seq=4 * 32, d=16))
+
+
+@pytest.mark.parametrize("rows,d,chose", [
+    (128, 192, "'split'"), (2048, 192, "'split'"),
+    (4096, 192, "'combined', asking for more"),
+    (8192, 192, "'combined', asking for more"),
+    (4096, 256, "'combined', asking for more")])
+def test_fused_ring_refuses_a_head_past_128_lanes(rows, d, chose):
+    """The plan's wide-head band sends heads of 192 and 256 to the combined
+    kernel only where its call asks Mosaic for more than the default, and the
+    ring's rotating kernel was never probed there: a shard of such a head is
+    refused by name at any length, by the plan as it stands (nothing
+    forced) — the pair below the band, an asking plan inside it."""
+    import horovod_tpu.ops.ring_flash as rf
+
+    q = jax.ShapeDtypeStruct((1, 4, rows, d), jnp.bfloat16)
+    with pytest.raises(rf.FusedRingUnsupported, match="scoped VMEM") as err:
+        rf.fused_ring_attention(q, q, q, "sp", causal=True)
+    assert f"head_dim {d}" in str(err.value) and chose in str(err.value)
 
 
 @pytest.mark.slow  # ~15s; ring-flash numerics stay tier-1 in

@@ -4,9 +4,12 @@ shape: the combined kernel (`ops.attention._combined_bwd_call`, asking Mosaic
 for the scoped VMEM `_combined_vmem_limit` computes where the default is too
 little) against the split pair (`_split_bwd_call`), in the blocks named, causal
 and under a band — the measurements behind the 16,384-row band of
-`ops.attention._bwd_plan` (PERF.md section 7 has the table).
+`ops.attention._bwd_plan` and, with `--d-v`, behind its wide-head band (PERF.md
+section 7 has the tables).
 
-A shape is (batch * heads, rows, head width), bf16.  The forward runs once,
+A shape is (batch * heads, rows, head width), bf16; `--d-v` gives v (and the
+output's cotangent) a width of its own, as latent attention has (192 / 128).
+The forward runs once,
 outside the timed program, for the residuals (out, the rows' log-sum-exp); a
 timed program holds `_flash_backward` under the forced plan and nothing else
 (delta's row sums and the three casts ride along, as they do in a step).  A
@@ -20,7 +23,8 @@ Times the chip and nothing else: without a TPU it refuses, as
 `tools/pair_rows_sweep.py` and `tools/grouped_sweep.py` do.
 
 Usage: python tools/flash_bwd_sweep.py [--rows 16384] [--bh 32] [--d 128]
-                                       [--window 1024] [--calls 10]
+                                       [--d-v 128] [--window 1024] [--calls 10]
+(`--window 0`: causal alone.)
 Writes one JSON line a measurement, also to chiprun_out/flash_bwd_sweep.jsonl.
 """
 import argparse
@@ -81,19 +85,24 @@ def main():
     ap.add_argument("--rows", type=int, default=16384)
     ap.add_argument("--bh", type=int, default=32)
     ap.add_argument("--d", type=int, default=128)
+    ap.add_argument("--d-v", type=int, default=None,
+                    help="v's width where it is not --d")
     ap.add_argument("--window", type=int, default=1024)
     ap.add_argument("--calls", type=int, default=10)
     ap.add_argument("--seed", type=int, default=57)
     args = ap.parse_args()
     if jax.default_backend() != "tpu":
         sys.exit("flash_bwd_sweep.py times the chip: no TPU here")
+    d_v = args.d_v or args.d
     shape = (1, args.bh, args.rows, args.d)
-    q, k, v, g = (jax.random.normal(key, shape, jnp.float32
+    q, k, v, g = (jax.random.normal(key, shape[:3] + (width,), jnp.float32
                                     ).astype(jnp.bfloat16)
-                  for key in jax.random.split(jax.random.key(args.seed), 4))
+                  for key, width in zip(
+                      jax.random.split(jax.random.key(args.seed), 4),
+                      (args.d, args.d, d_v, d_v)))
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/flash_bwd_sweep.jsonl", "a") as log:
-        for window in (None, args.window):
+        for window in (None, args.window) if args.window else (None,):
             mask = attn.Mask.of(args.rows, args.rows, True, window)
             out, lse = jax.jit(
                 lambda q, k, v: attn._flash_forward(
@@ -115,11 +124,11 @@ def main():
                             **{name + "_apart": apart(a, b) for name, a, b
                                in zip(("dq", "dk", "dv"), grads, first)}}
                 line = {"device": jax.devices()[0].device_kind,
-                        "shape": list(shape), "window": window,
+                        "shape": list(shape), "d_v": d_v, "window": window,
                         "mode": plan[0], "blocks": list(plan[1:]),
                         "vmem_limit_bytes":
                             attn._combined_vmem_limit(args.rows, args.d,
-                                                      *plan[1:])
+                                                      *plan[1:], d_v)
                             if plan[0] == "combined" else None,
                         "live_tiles": attn._live_tiles(args.rows, plan[1:],
                                                        mask),
