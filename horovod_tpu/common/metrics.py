@@ -530,7 +530,8 @@ class MetricsRegistry:
         # (`_TimedStep.setup`).
         self._train_step = {"compiler_options": "not applied",
                             "compiled": False, "async_all_reduces": 0,
-                            "sync_all_reduces": 0, "setup": new_step_setup()}
+                            "sync_all_reduces": 0, "async_bytes": 0,
+                            "sync_bytes": 0, "setup": new_step_setup()}
         self._hists = {name: Histogram(bounds)
                        for name, (bounds, _) in HISTOGRAMS.items()}
 

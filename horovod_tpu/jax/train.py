@@ -335,35 +335,223 @@ def _exchange_overlaps(mesh: Mesh) -> bool:
         d.platform == "tpu" for d in mesh.devices.flat)
 
 
+_COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+                   "collective-permute")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_INSTRUCTION = re.compile(r"^\s*(ROOT )?%?([\w.\-]+) = ")
+# The instructions the table is made of: a collective in one of its forms,
+# what may hold one in a called computation, and what hands a start's state
+# on untouched.  (A layout's `T(8,128)` has no space in front of it.)
+_OF_INTEREST = re.compile(
+    r" (get-tuple-element|bitcast|fusion|async-(?:start|update|done)|(?:"
+    + "|".join(_COLLECTIVE_OPS) + r")(?:-start|-done)?)\(([^()]*)\)")
+_HANDS_STATE_ON = ("get-tuple-element", "bitcast", "async-update")
+_ARRAY_TYPE = re.compile(r"\b(pred|[a-z]+\d+\w*)\[([\d,]*)\]")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_UNDER_LOSS = re.compile(r"hvd_loss(?!_report)")
+_GROUPS = re.compile(r"(?:replica_groups|source_target_pairs)="
+                     r"(\{\{.*?\}\}|\{\}|\[[\d,]+\]<=\[[\d,]+\](?:T\([\d,]+\))?)")
+
+
+def _result_type(line: str) -> str:
+    """The type an instruction's line gives its result, a tuple's whole."""
+    rest = line.split(" = ", 1)[1]
+    if not rest.startswith("("):
+        return rest.split(" ", 1)[0]
+    depth = 0
+    for i, ch in enumerate(rest):
+        depth += (ch == "(") - (ch == ")")
+        if depth == 0:
+            break
+    return rest[:i + 1]
+
+
+def _bytes_and_dtype(types: list) -> tuple[int, str]:
+    """Bytes of the arrays the type texts name (a tuple's summed) and their
+    dtype, several joined by a comma; a dtype's width is the first number
+    of its name (`bf16`, `f8e4m3fn`), a `pred`'s one byte."""
+    nbytes, dtypes = 0, []
+    for dtype, dims in _ARRAY_TYPE.findall(" ".join(types)):
+        bits = 8 if dtype == "pred" else int(re.search(r"\d+", dtype).group())
+        elements = 1
+        for d in dims.split(","):
+            elements *= int(d or 1)
+        nbytes += (elements * bits + 7) // 8
+        if dtype not in dtypes:
+            dtypes.append(dtype)
+    return nbytes, ",".join(dtypes)
+
+
+def _scope_beneath_loss(op_name: str):
+    """(whether autodiff transposed it, the scope path beneath `hvd_loss`
+    less the primitive's own name) of an op_name under `hvd_loss`; None of
+    one that is not."""
+    under = _UNDER_LOSS.search(op_name)
+    if under is None:
+        return None
+    beneath = op_name[under.start():].split("/")[1:-1]
+    return "transpose(" in op_name[:under.start()], "/".join(beneath)
+
+
+def compiled_collectives(compiled_text: str) -> list:
+    """The table of a compiled program's collectives, from its text: one
+    entry a collective that is an instruction of the entry computation (or
+    of a loop's body: any computation that is not a fusion's), in the
+    program's order:
+
+        {"op": "all-reduce" | "all-gather" | "reduce-scatter" | "all-to-all"
+               | "collective-permute",
+         "asynchronous": bool,
+         "start", "done":  the pair's instruction names (None: synchronous),
+         "instruction":    the synchronous instruction's (None: a pair),
+         "carriers":       [names], "carrier_op_names": [their op_names],
+         "bytes", "dtype": the operands', from shape and dtype, a tuple's
+                           summed (what one chip hands in),
+         "replica_groups": as the text has them (a permute's pairs),
+         "op_name":        the done's, else the collective's own,
+         "role":           "gradient" | "model" | "report"}
+
+    Asynchronous: libtpu's pair of fusions `async-collective-start[.N]` /
+    `async-collective-done[.N]` whose called computation holds the
+    collective, XLA's plain `<op>-start` / `<op>-done`, and its
+    `async-start` / `async-done` around a computation that holds one.  The
+    start has no op_name; the done's names whose value travelled.
+    Synchronous: a collective that is an instruction of its own — the core
+    waits in it.  Carriers: the fusions between a start and its done that
+    take the start's state (the elements of its tuple, through
+    `get-tuple-element`) and hold a slice of the collective in their called
+    computation — the compute the exchange rides on; one without an op_name
+    of its own (a fusion that carries a collective's state loses it) is
+    given its computation's root's, or, the root being a bare tuple, that of
+    the last instruction before it that has one and is no collective.  The
+    names are those of the events on a device trace's `XLA Ops` line.
+
+    `role`, from the op_names and nothing else (read off the four-chip LM
+    and ResNet steps, PERF.md section 3): under `hvd_loss_report` the loss's
+    and aux's average, `report` (XLA's combiner puts the small gradient
+    leaves into that tuple too); a `psum_invariant` (or anything under
+    `hvd_grad_exchange`) that is not in the forward pass is the sum autodiff
+    inserts for a value every replica holds — a weight's gradient,
+    `gradient` — unless a forward collective sits under the same scope path,
+    for then the module exchanges in its own right (sync batch norm's
+    statistics) and this is its cotangent's sum, `model` like the forward
+    one; anything else is the model's own, `model`.  A program whose loss
+    opens no scope gives every path as empty, and there a backward sum is
+    taken for a gradient."""
+    bodies, holds, named, called = {}, {}, {}, set()
+    # holds: a computation -> the first collective its text holds
+    computation = None
+    for line in compiled_text.splitlines():
+        opened = _COMPUTATION.match(line)
+        if opened:
+            computation, defined = opened.group(1), {}
+            bodies[computation] = []
+            continue
+        head = _INSTRUCTION.match(line)
+        if head is None or computation is None:
+            continue
+        name = head.group(2)
+        defined[name] = line
+        path = _OP_NAME.search(line, head.end())
+        path = path.group(1) if path else ""
+        found = _OF_INTEREST.search(line, head.end())
+        # What a computation computes, by name: its root's op_name, or (the
+        # root of a fusion that carries state is a bare tuple) that of the
+        # last instruction before it that has one and is no collective.
+        if path and (head.group(1) or found is None or not found.group(
+                1).startswith(_COLLECTIVE_OPS)):
+            named[computation] = path
+        if found is None:
+            continue
+        opcode = found.group(1)
+        operands = re.findall(r"%([\w.\-]+)", found.group(2))
+        form = next((f for f in ("start", "done", "update")
+                     if opcode.endswith("-" + f)), "")
+        op = opcode[:-len(form) - 1] if form else opcode
+        callee = re.search(r"\bcalls=%?([\w.\-]+)", line[found.end():])
+        if callee:
+            called.add(callee.group(1))
+        entry = None
+        if op in _COLLECTIVE_OPS and form != "done":
+            nbytes, dtype = _bytes_and_dtype(
+                [_result_type(defined[o]) for o in operands if o in defined]
+                or [_result_type(line)])
+            groups = _GROUPS.search(line, found.end())
+            entry = {"op": op, "asynchronous": form == "start",
+                     "start": name if form else None, "done": None,
+                     "instruction": None if form else name,
+                     "carriers": [], "carrier_op_names": [],
+                     "bytes": nbytes, "dtype": dtype,
+                     "replica_groups": groups.group(1) if groups else "",
+                     "op_name": path}
+            holds.setdefault(computation, entry)
+        bodies[computation].append(
+            (name, op if op in _COLLECTIVE_OPS else opcode, form, operands,
+             callee and callee.group(1), path, entry))
+
+    table = []
+    for computation, body in bodies.items():
+        if computation in called:
+            continue
+        carries = {}     # a value -> the open entries whose state it carries
+        flying = []      # indices into `table` of starts without their done
+        for name, opcode, form, operands, callee, path, entry in body:
+            carried = [k for k in dict.fromkeys(
+                k for o in operands for k in carries.get(o, ())) if k in flying]
+            fused = opcode == "fusion" and name.startswith("async-collective-")
+            if callee in holds and (opcode == "async-start" or (
+                    fused and name.startswith("async-collective-start"))):
+                entry = dict(holds[callee], asynchronous=True, start=name,
+                             instruction=None, carriers=[],
+                             carrier_op_names=[])
+            if entry is not None:
+                table.append(entry)
+                if entry["asynchronous"]:
+                    flying.append(len(table) - 1)
+                    carries[name] = (len(table) - 1,)
+            elif form == "done" or (
+                    fused and name.startswith("async-collective-done")):
+                # Its start's state reaches it; where a fusion carried two
+                # collectives at once, libtpu's numbering tells whose it is.
+                suffix = name.partition(".")[2]
+                mine = [k for k in carried if table[k]["start"].partition(
+                    ".")[2] == suffix] or carried
+                if mine:
+                    pair = table[mine[0]]
+                    pair["done"], pair["op_name"] = name, path or pair["op_name"]
+                    flying.remove(mine[0])
+            elif carried and opcode in _HANDS_STATE_ON:
+                carries[name] = carried
+            elif carried and callee in holds:
+                carries[name] = carried
+                for k in carried:
+                    table[k]["carriers"].append(name)
+                    table[k]["carrier_op_names"].append(
+                        path or named.get(callee, ""))
+
+    scopes = [_scope_beneath_loss(entry["op_name"]) for entry in table]
+    forward_scopes = {scope for beneath in scopes if beneath
+                      for backward, scope in [beneath] if not backward} - {""}
+    for entry, beneath in zip(table, scopes):
+        path = entry["op_name"]
+        sums = "psum_invariant" in path or "hvd_grad_exchange" in path
+        if "hvd_loss_report" in path:
+            entry["role"] = "report"
+        elif sums and not (beneath and (
+                not beneath[0] or beneath[1] in forward_scopes)):
+            entry["role"] = "gradient"
+        else:
+            entry["role"] = "model"
+    return table
+
+
 def count_all_reduces(compiled_text: str) -> tuple[int, int]:
     """``(asynchronous, synchronous)`` all-reduces of a compiled program,
-    from its text.  Asynchronous: an ``async-collective-start`` fusion whose
-    computation holds an all-reduce (libtpu's form, with an
-    ``async-collective-done`` further down and the instructions between
-    them running beside it), or a plain ``all-reduce-start``.  Synchronous:
-    an ``all-reduce`` that is an instruction of its own and not part of a
-    fusion — the core waits in it."""
-    # Per computation: its all-reduces, its plain all-reduce-starts.
-    counts, name = {}, None
-    for line in compiled_text.splitlines():
-        opened = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
-        if opened:
-            name = opened.group(1)
-            counts[name] = [0, 0]
-        elif name is not None:
-            counts[name][0] += bool(re.search(r"\ball-reduce\(", line))
-            counts[name][1] += bool(re.search(r"\ball-reduce-start\(", line))
-    fusions = re.findall(
-        r"^\s*(?:ROOT )?%?([\w.\-]+) = .*\bfusion\(.*\bcalls=%?([\w.\-]+)",
-        compiled_text, re.M)
-    fused = {callee for _, callee in fusions}
-    n_async = sum(caller.startswith("async-collective-start")
-                  and counts.get(callee, (0, 0))[0] > 0
-                  for caller, callee in fusions)
-    outside = [c for computation, c in counts.items()
-               if computation not in fused]
-    return (n_async + sum(starts for _, starts in outside),
-            sum(plain for plain, _ in outside))
+    from its text: two sums over `compiled_collectives`."""
+    table = [e for e in compiled_collectives(compiled_text)
+             if e["op"] == "all-reduce"]
+    n_async = sum(e["asynchronous"] for e in table)
+    return n_async, len(table) - n_async
 
 
 class _Staged:
@@ -437,25 +625,73 @@ class _TimedStep:
     ``exchange_overlap`` says what the compiler made of the gradient
     exchange: whether the step took `_EXCHANGE_OVERLAP`, and, once it has
     compiled, how many of its all-reduces run asynchronously and how many
-    stayed synchronous (`count_all_reduces`; mirrored into
-    ``metrics_snapshot()["train_step"]`` when the registry is enabled), so
-    that a job on another slice or another libtpu can see whether it got
-    the overlap without a profiler.  To read its own text such a step
-    compiles at its first call through ``lower().compile()`` and runs that
-    executable from then on: a jit that carries compiler options keeps no
-    executable that a second ``compile()`` could hand back."""
+    stayed synchronous, with the bytes a chip hands to each kind
+    (``async_all_reduces``, ``sync_all_reduces``, ``async_bytes``,
+    ``sync_bytes``: sums over `collectives`), so that a job on another slice
+    or another libtpu can see whether it got the overlap without a profiler.
+    The counts are filled when the record is first read after the compile —
+    reading the executable's text takes seconds on a large program, and call
+    0 does not pay them — or at call 0 where the metrics registry is enabled
+    (mirrored into ``metrics_snapshot()["train_step"]``).  To hold its own
+    executable such a step compiles at its first call through
+    ``lower().compile()`` and runs that executable from then on: a jit that
+    carries compiler options keeps no executable that a second ``compile()``
+    could hand back.
 
-    def __init__(self, fn, overlap: bool = False):
+    ``collectives()`` is the table of the executable that runs, one entry a
+    collective (`compiled_collectives`: start, done, carriers, bytes, whose
+    gradient), read on demand and kept."""
+
+    def __init__(self, fn, overlap: bool = False, devices: int = 0):
         self._fn = fn
         self.name = getattr(fn, "__name__", "")
-        self._run = self._compile_and_count if overlap \
+        self._run = self._compile_and_hold if overlap \
             else self._first_call
         self._calls = 0
-        self.exchange_overlap = {
+        self._devices = devices      # of the mesh; 0: not told
+        self._compiled = None        # the executable an overlap step holds
+        self._table = None           # its collectives, once read
+        self._exchange = {
             "compiler_options": "applied" if overlap else "not applied",
             "compiled": False,
-            "async_all_reduces": 0, "sync_all_reduces": 0}
+            "async_all_reduces": 0, "sync_all_reduces": 0,
+            "async_bytes": 0, "sync_bytes": 0}
         self.setup = _metrics.new_step_setup()
+
+    @property
+    def exchange_overlap(self) -> dict:
+        if self._compiled is not None and self._table is None:
+            self.collectives()
+        return self._exchange
+
+    def collectives(self, *args, **kwargs) -> list:
+        """The collectives of the executable that runs
+        (`compiled_collectives` of its text).  A step that holds its own
+        executable (every step over more than one TPU device, after call 0)
+        reads that text once and keeps the table.  A step over one device
+        answers ``[]`` without reading anything.  Any other — the jit's own
+        call over a CPU mesh of several devices — holds no executable:
+        hand it the call's arguments and it asks JAX for the program the
+        call built."""
+        if self._table is not None:
+            return self._table
+        if self._compiled is not None:
+            self._table = compiled_collectives(self._compiled.as_text())
+            for kind, flag in (("async", True), ("sync", False)):
+                reduces = [e for e in self._table if e["op"] == "all-reduce"
+                           and e["asynchronous"] == flag]
+                self._exchange[kind + "_all_reduces"] = len(reduces)
+                self._exchange[kind + "_bytes"] = sum(
+                    e["bytes"] for e in reduces)
+            return self._table
+        if self._devices == 1:
+            return []
+        if not args:
+            raise ValueError(
+                "this step holds no executable of its own: pass the call's "
+                "arguments, step.collectives(params, opt_state, batch)")
+        return compiled_collectives(
+            self._fn.lower(*args, **kwargs).compile().as_text())
 
     @contextlib.contextmanager
     def _building(self, span: str):
@@ -492,15 +728,12 @@ class _TimedStep:
         with self._building("hvd.step_load"):
             return self._fn(*args, **kwargs)
 
-    def _compile_and_count(self, *args, **kwargs):
+    def _compile_and_hold(self, *args, **kwargs):
         if _is_traced(args, kwargs):
             return self._fn(*args, **kwargs)     # inlined into an outer jit
-        compiled = self.lower(*args, **kwargs).compile()
-        n_async, n_sync = count_all_reduces(compiled.as_text())
-        self.exchange_overlap.update(
-            compiled=True, async_all_reduces=n_async, sync_all_reduces=n_sync)
+        self._compiled = self.lower(*args, **kwargs).compile()._stage
+        self._exchange["compiled"] = True
         self._mirror()
-        self._compiled = compiled._stage
         self._run = self._run_compiled
         return self._compiled(*args, **kwargs)
 
@@ -645,10 +878,11 @@ def build_train_step(loss_fn: Callable, optimizer, mesh: Mesh,
         check_vma=check_vma)
     donate_argnums = (0, 1) if donate else ()
     if not _exchange_overlaps(mesh):
-        return _TimedStep(jax.jit(mapped, donate_argnums=donate_argnums))
+        return _TimedStep(jax.jit(mapped, donate_argnums=donate_argnums),
+                          devices=mesh.devices.size)
     return _TimedStep(jax.jit(mapped, donate_argnums=donate_argnums,
                               compiler_options=_EXCHANGE_OVERLAP),
-                      overlap=True)
+                      overlap=True, devices=mesh.devices.size)
 
 
 # ---------------------------------------------------------------------------

@@ -62,9 +62,9 @@ _LM_STEPS = {}     # devices -> what _compile_lm_step gave for them
 def _compile_lm_step(devices):
     """A two-layer dense LM at pythia-410m's widths (a smaller vocabulary,
     512 tokens a chip) through `build_train_step` on a data-parallel mesh
-    of the described ``devices``: (the step, its compiled text, the number
-    of weights whose gradient is over a megabyte in either dtype).  Compiled
-    once a process for a number of devices."""
+    of the described ``devices``: (the step, its compiled text, the weights
+    whose gradient is over a megabyte in either dtype, ``{path: elements}``).
+    Compiled once a process for a number of devices."""
     if len(devices) not in _LM_STEPS:
         _LM_STEPS[len(devices)] = _compiled_lm_step(devices)
     return _LM_STEPS[len(devices)]
@@ -87,9 +87,10 @@ def _compiled_lm_step(devices):
                     f"{sorted(_EXCHANGE_OVERLAP)}: {exc}")
     # Over 2**19 elements a gradient is over a megabyte in bf16 and in f32;
     # the model's other leaves (norm scales) are under it in both.
-    sizes = [x.size for x in jax.tree.leaves(params)]
-    assert all(n >= 2**19 or n * 4 < 2**20 for n in sizes), sizes
-    return step, text, sum(n >= 2**19 for n in sizes)
+    sizes = {jax.tree_util.keystr(path): x.size
+             for path, x in jax.tree_util.tree_leaves_with_path(params)}
+    assert all(n >= 2**19 or n * 4 < 2**20 for n in sizes.values()), sizes
+    return step, text, {k: n for k, n in sizes.items() if n >= 2**19}
 
 
 def test_dp_step_exchanges_large_gradients_asynchronously(v5e, monkeypatch):
@@ -114,8 +115,8 @@ def test_dp_step_exchanges_large_gradients_asynchronously(v5e, monkeypatch):
     n_async, n_sync = count_all_reduces(text)
     dones = len(re.findall(r"^\s*%async-collective-done[\w.\-]* = ", text,
                            re.M))
-    assert n_async == dones == large, (
-        f"{large} gradients over a megabyte, {n_async} asynchronous "
+    assert n_async == dones == len(large), (
+        f"{len(large)} gradients over a megabyte, {n_async} asynchronous "
         f"all-reduces, {dones} dones: one of the options lost its meaning "
         f"{options}")
     assert n_sync >= 1 and re.search(r"\ball-reduce\(", text)
@@ -137,6 +138,70 @@ def test_dp_step_exchanges_large_gradients_asynchronously(v5e, monkeypatch):
     assert count_all_reduces(text) == (0, 0)
     assert "async-collective-start" not in text
     assert "all-reduce" not in text
+
+
+def test_dp_step_accounts_for_its_exchange(v5e, monkeypatch):
+    """`compiled_collectives` on the same two programs.  Over four described
+    chips every weight over a megabyte is one asynchronous entry with a
+    start, a done, at least one carrier, that weight's bytes in the dtype it
+    is summed in (bf16), role `gradient` and the done's op_name, which ends
+    in the scope the weight is used under; every synchronous entry is under
+    a megabyte, and one of them is the tuple of the small leaves and the
+    loss (the combiner names it after one member: `gradient` here, `report`
+    in the ResNet step, PERF.md section 3); the counts are
+    `count_all_reduces`'s.  Over one described chip there is no table, and
+    the step says so without reading a text."""
+    from horovod_tpu.jax.train import compiled_collectives, count_all_reduces
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step, text, large = _compile_lm_step(v5e[:4])
+    table = compiled_collectives(text)
+    assert all(e["op"] == "all-reduce" and e["replica_groups"]
+               == "{{0,1,2,3}}" for e in table)
+    pairs = [e for e in table if e["asynchronous"]]
+    waiting = [e for e in table if not e["asynchronous"]]
+    assert (len(pairs), len(waiting)) == count_all_reduces(text)
+    assert len(pairs) == len(large)
+    for e in pairs:
+        assert e["start"].startswith("async-collective-start")
+        assert e["done"].startswith("async-collective-done")
+        assert e["instruction"] is None and len(e["carriers"]) >= 1
+        assert len(e["carrier_op_names"]) == len(e["carriers"])
+        assert e["role"] == "gradient" and e["dtype"] == "bf16"
+        assert "/transpose(jvp(hvd_loss))/TransformerLM/" in e["op_name"]
+        assert e["op_name"].endswith("/psum_invariant")
+    # The weights by the scope they are used under, two bytes an element.
+    d, ff, vocab = 1024, 4096, 8192
+    want = {"hvd_embed/embed/jit(_take)": vocab * d,
+            "hvd_lm_head/bsd,dv->bsv": d * vocab}
+    for layer in ("layer_0", "layer_1"):
+        want.update({f"{layer}/attn/hvd_attn_qkv": d * 3 * d,
+                     f"{layer}/attn/hvd_attn_out/bhse,hed->bsd": d * d,
+                     f"{layer}/hvd_mlp/up": d * ff,
+                     f"{layer}/hvd_mlp/down": ff * d})
+    got = {e["op_name"].split("/TransformerLM/")[1].rsplit("/", 1)[0]:
+           e["bytes"] for e in pairs}
+    assert got == {scope: 2 * n for scope, n in want.items()}
+    assert sorted(want.values()) == sorted(large.values())
+    # Every carrier is named: by the weight-gradient product it is, or (a
+    # fusion that carries a collective's state loses its own op_name) by
+    # what its computation computes — a backward pass, an optimizer update.
+    named = [path for e in pairs for path in e["carrier_op_names"]]
+    assert all("transpose(jvp(hvd_loss))" in path or "hvd_optimizer" in path
+               for path in named), named
+    assert any("hvd_optimizer" in path for path in named)
+
+    assert waiting and all(e["bytes"] < 2**20 and e["instruction"]
+                           and not e["carriers"] for e in waiting)
+    leaves = [e for e in waiting if e["dtype"] == "f32"]
+    assert len(leaves) == 1 and leaves[0]["role"] in ("gradient", "report")
+    # 2 scales a layer and the final norm's, 1,024 wide, and the loss.
+    assert leaves[0]["bytes"] == 4 * (5 * d + 1)
+
+    step, text, _ = _compile_lm_step(v5e[:1])
+    assert compiled_collectives(text) == []
+    monkeypatch.setattr(step, "lower", None)     # nothing is lowered for it
+    assert step.collectives() == []
 
 
 def _assert_scopes_forward_and_backward(text, scopes):

@@ -284,25 +284,24 @@ def test_step_takes_no_compiler_option_off_a_tpu_host(monkeypatch, n_devices):
     assert step.lower(*args).as_text() == jit(
         mapped, donate_argnums=(0, 1)).lower(*args).as_text()
     untouched = {"compiler_options": "not applied", "compiled": False,
-                 "async_all_reduces": 0, "sync_all_reduces": 0}
+                 "async_all_reduces": 0, "sync_all_reduces": 0,
+                 "async_bytes": 0, "sync_bytes": 0}
     assert step.exchange_overlap == untouched
     step(*args)
     assert step.exchange_overlap == untouched
 
 
-def test_count_all_reduces_tells_fused_pairs_from_waiting_instructions():
-    """`count_all_reduces` on the forms libtpu writes: an all-reduce inside
-    the computation of an `async-collective-start` fusion is asynchronous
-    (once: the fusions that carry it and the done repeat its text), an
-    `all-reduce` instruction outside any fusion is one the core waits in,
-    a tuple all-reduce is one, and another collective's start is neither."""
-    from horovod_tpu.jax.train import count_all_reduces
-
-    text = """HloModule jit_shard_step, is_scheduled=true
+# A compiled program in the forms libtpu and XLA write, hand-made: a
+# libtpu pair around one carrier (a weight's gradient), a synchronous tuple
+# (a forward statistic and its cotangent's sum, by their scope), another
+# collective's pair without a done, XLA's plain pair (the loss), and a
+# fusion after the done that reads its result.
+_OP = 'metadata={op_name="jit(shard_step)/shard_map/'
+_HAND_MADE_PROGRAM = """HloModule jit_shard_step, is_scheduled=true
 
 %fused_computation.1 (param_0.1: bf16[1024,4096]) -> (bf16[1024,4096], u32[]) {
-  %param_0.1 = bf16[1024,4096]{1,0} parameter(0)
-  %all-reduce.54 = bf16[1024,4096]{1,0} all-reduce(%param_0.1), channel_id=1, to_apply=%region_1.2
+  %param_0.1 = bf16[1024,4096]{1,0:T(8,128)(2,1)} parameter(0)
+  %all-reduce.54 = bf16[1024,4096]{1,0} all-reduce(%param_0.1), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%region_1.2, OP_transpose(jvp(hvd_loss))/hvd_mlp/down/psum_invariant"}
   ROOT %custom-call.9 = (bf16[1024,4096]{1,0}, u32[]) custom-call(%all-reduce.54), custom_call_target="x"
 }
 
@@ -314,24 +313,180 @@ def test_count_all_reduces_tells_fused_pairs_from_waiting_instructions():
 
 %fused_computation.3 (param_0.3: bf16[8,128]) -> (bf16[8,128], u32[]) {
   %param_0.3 = bf16[8,128]{1,0} parameter(0)
-  %collective-permute.1 = bf16[8,128]{1,0} collective-permute(%param_0.3), source_target_pairs={{0,1}}
+  %collective-permute.1 = bf16[8,128]{1,0} collective-permute(%param_0.3), source_target_pairs={{0,1},{1,0}}
   ROOT %custom-call.13 = (bf16[8,128]{1,0}, u32[]) custom-call(%collective-permute.1), custom_call_target="x"
+}
+
+%fused_computation.4 (param_0.4: bf16[1024,4096], param_1.4: u32[], param_2.4: f32[1024]) -> (bf16[1024,4096], u32[], f32[1024]) {
+  %param_0.4 = bf16[1024,4096]{1,0} parameter(0)
+  %param_1.4 = u32[] parameter(1)
+  %param_2.4 = f32[1024]{0} parameter(2)
+  %multiply.4 = f32[1024]{0} multiply(%param_2.4, %param_2.4), OP_hvd_optimizer/mul"}
+  %all-reduce.55 = bf16[1024,4096]{1,0} all-reduce(%param_0.4), channel_id=1, to_apply=%region_1.2, OP_transpose(jvp(hvd_loss))/hvd_mlp/down/psum_invariant"}
+  ROOT %tuple.4 = (bf16[1024,4096]{1,0}, u32[], f32[1024]{0}) tuple(%all-reduce.55, %param_1.4, %multiply.4)
+}
+
+%fused_computation.5 (param_0.5: bf16[1024,4096]) -> bf16[1024,4096] {
+  %param_0.5 = bf16[1024,4096]{1,0} parameter(0)
+  ROOT %negate.5 = bf16[1024,4096]{1,0} negate(%param_0.5)
 }
 
 ENTRY %main.7 (p0: bf16[1024,4096], p1: f32[1024], p2: f32[]) -> bf16[1024,4096] {
   %p0 = bf16[1024,4096]{1,0} parameter(0)
-  %p1 = f32[1024]{0} parameter(1)
+  %p1 = f32[1024]{0:T(1024)} parameter(1)
   %p2 = f32[]{} parameter(2)
   %async-collective-start = (bf16[1024,4096]{1,0}, u32[]) fusion(%p0), kind=kCustom, calls=%fused_computation.1
-  %all-reduce.2 = (f32[1024]{0}, f32[]) all-reduce(%p1, %p2), channel_id=2, to_apply=%region_0.1
+  %get-tuple-element.1 = bf16[1024,4096]{1,0} get-tuple-element(%async-collective-start), index=0
+  %get-tuple-element.2 = u32[] get-tuple-element(%async-collective-start), index=1
+  %fusion.5 = (bf16[1024,4096]{1,0}, u32[], f32[1024]{0}) fusion(%get-tuple-element.1, %get-tuple-element.2, %p1), kind=kLoop, calls=%fused_computation.4
+  %get-tuple-element.3 = bf16[1024,4096]{1,0} get-tuple-element(%fusion.5), index=0
+  %get-tuple-element.4 = u32[] get-tuple-element(%fusion.5), index=1
+  %all-reduce.2 = (f32[1024]{0}, f32[]) all-reduce(%p1, %p2), channel_id=2, replica_groups=[1,4]<=[4], to_apply=%region_0.1, OP_jvp(hvd_loss)/norm/psum_invariant"}
+  %all-reduce.4 = f32[1024]{0} all-reduce(%p1), channel_id=4, replica_groups={{0,1,2,3}}, to_apply=%region_0.1, OP_transpose(jvp(hvd_loss))/norm/psum_invariant"}
   %async-collective-start.1 = (bf16[8,128]{1,0}, u32[]) fusion(%p0), kind=kCustom, calls=%fused_computation.3
-  %all-reduce-start.3 = f32[] all-reduce-start(%p2), channel_id=3, to_apply=%region_0.1
+  %all-reduce-start.3 = f32[] all-reduce-start(%p2), channel_id=3, replica_groups={{0,1,2,3}}, to_apply=%region_0.1, OP_hvd_loss_report/psum_invariant"}
   %all-reduce-done.3 = f32[] all-reduce-done(%all-reduce-start.3)
-  ROOT %async-collective-done = bf16[1024,4096]{1,0} fusion(%async-collective-start), kind=kCustom, calls=%fused_computation.2
+  %async-collective-done = bf16[1024,4096]{1,0} fusion(%get-tuple-element.3, %get-tuple-element.4), kind=kCustom, calls=%fused_computation.2, OP_transpose(jvp(hvd_loss))/hvd_mlp/down/psum_invariant"}
+  ROOT %fusion.6 = bf16[1024,4096]{1,0} fusion(%async-collective-done), kind=kLoop, calls=%fused_computation.5
 }
-"""
-    assert count_all_reduces(text) == (2, 1)
+""".replace("OP_", _OP)
+
+
+def test_count_all_reduces_tells_fused_pairs_from_waiting_instructions():
+    """`count_all_reduces` on the forms libtpu writes: an all-reduce inside
+    the computation of an `async-collective-start` fusion is asynchronous
+    (once: the fusions that carry it and the done repeat its text), an
+    `all-reduce` instruction outside any fusion is one the core waits in,
+    a tuple all-reduce is one, and another collective's start is neither."""
+    from horovod_tpu.jax.train import count_all_reduces
+
+    assert count_all_reduces(_HAND_MADE_PROGRAM) == (2, 2)
     assert count_all_reduces("ENTRY %main.1 () -> f32[] {\n}\n") == (0, 0)
+
+
+def test_compiled_collectives_is_the_table_of_a_programs_text():
+    """`compiled_collectives` on the same text: an entry a collective in the
+    program's order; a pair's start, done and the carrier between them (the
+    fusion that takes the start's state and holds a slice of the all-reduce;
+    without an op_name, and its computation's root a bare tuple, named by
+    the last instruction of that computation that is no collective), never
+    the fusion behind the done; the operands' bytes, a tuple's summed; the done's op_name; the
+    groups as written; and the role from the op_names alone — a backward sum
+    under a scope that exchanges in the forward pass is the model's."""
+    from horovod_tpu.jax.train import compiled_collectives
+
+    table = compiled_collectives(_HAND_MADE_PROGRAM)
+    assert [(e["op"], e["asynchronous"], e["start"] or e["instruction"],
+             e["done"], e["bytes"], e["dtype"], e["role"]) for e in table] == [
+        ("all-reduce", True, "async-collective-start",
+         "async-collective-done", 2 * 1024 * 4096, "bf16", "gradient"),
+        ("all-reduce", False, "all-reduce.2", None, 4 * 1024 + 4, "f32",
+         "model"),
+        ("all-reduce", False, "all-reduce.4", None, 4 * 1024, "f32", "model"),
+        ("collective-permute", True, "async-collective-start.1", None,
+         2 * 8 * 128, "bf16", "model"),
+        ("all-reduce", True, "all-reduce-start.3", "all-reduce-done.3", 4,
+         "f32", "report")]
+    pair = table[0]
+    assert pair["instruction"] is None
+    assert pair["carriers"] == ["fusion.5"]
+    assert pair["carrier_op_names"] == [
+        "jit(shard_step)/shard_map/hvd_optimizer/mul"]
+    assert pair["op_name"].endswith("hvd_mlp/down/psum_invariant")
+    assert pair["replica_groups"] == "{{0,1,2,3}}"
+    assert table[1]["replica_groups"] == "[1,4]<=[4]"
+    assert table[3]["replica_groups"] == "{{0,1},{1,0}}"
+    assert all(not e["carriers"] for e in table[1:])
+    assert compiled_collectives("ENTRY %main.1 () -> f32[] {\n}\n") == []
+    # Without the forward statistic, the same backward sum is a gradient's.
+    alone = _HAND_MADE_PROGRAM.replace("jvp(hvd_loss)/norm", "jvp(hvd_loss)/n", 1)
+    assert [e["role"] for e in compiled_collectives(alone)][1:3] == [
+        "model", "gradient"]
+
+
+@pytest.mark.parametrize("registry_on", [False, True])
+def test_overlap_step_reads_its_text_on_demand(monkeypatch, registry_on):
+    """Call 0 of a step that took the overlap options reads its
+    executable's text not at all with the registry off — the record says
+    `compiled` and nothing more — and once with it on (the operator asked);
+    reading `step.exchange_overlap` afterwards fills the counts and bytes
+    once, from the table `step.collectives()` keeps."""
+    from horovod_tpu.common import metrics
+    from horovod_tpu.jax.train import _TimedStep
+
+    reads = []
+    as_text = jax.stages.Compiled.as_text
+
+    def counted(self, *args, **kwargs):
+        reads.append(self)
+        return as_text(self, *args, **kwargs)
+
+    monkeypatch.setattr(jax.stages.Compiled, "as_text", counted)
+    loss_fn, tx, sub, args = _linear_problem(4)
+    plain = build_train_step(loss_fn, tx, sub, donate=False)
+    step = _TimedStep(plain._fn, overlap=True, devices=4)
+    metrics.registry.reset()
+    if registry_on:
+        metrics.registry.enable()
+    try:
+        step(*args)
+        mirrored = metrics.registry.snapshot()["train_step"]
+    finally:
+        metrics.registry.disable()
+        metrics.registry.reset()
+    assert len(reads) == int(registry_on)
+    assert step._exchange["compiled"]
+    if registry_on:
+        assert mirrored["sync_all_reduces"] >= 1 and mirrored["sync_bytes"] > 0
+    else:
+        assert step._exchange["sync_all_reduces"] == 0
+    step(*args)
+    record = step.exchange_overlap
+    assert len(reads) == 1
+    table = step.collectives()
+    assert table and all(e["op"] == "all-reduce" and not e["asynchronous"]
+                         for e in table)
+    assert record == {"compiler_options": "applied", "compiled": True,
+                      "async_all_reduces": 0, "sync_all_reduces": len(table),
+                      "async_bytes": 0,
+                      "sync_bytes": sum(e["bytes"] for e in table)}
+    # 3 weights and the loss, float32, however the CPU's compiler groups them.
+    assert record["sync_bytes"] == 16
+    assert step.exchange_overlap is record and step.collectives() is table
+    assert len(reads) == 1
+
+
+def test_a_step_without_an_executable_asks_jax_for_its_table():
+    """The jit's own call holds no executable: over one device the step
+    answers `[]` without lowering anything, over a CPU mesh of two it takes
+    the call's arguments (and says so when given none), and a `pmean` the
+    loss makes in its own right is the `model`'s, the weights' sum a
+    `gradient`."""
+    from jax import lax
+
+    def loss_fn(w, batch):
+        x, y = batch
+        with jax.named_scope("norm"):
+            centred = x - lax.pmean(x.mean(0), "hvd")
+        return jnp.mean((centred @ w - y) ** 2)
+
+    _, tx, sub, args = _linear_problem(2)
+    step = build_train_step(loss_fn, tx, sub, donate=False)
+    with pytest.raises(ValueError, match="pass the call's arguments"):
+        step.collectives()
+    table = step.collectives(*args)
+    assert table and all(not e["asynchronous"] for e in table)
+    assert {e["role"] for e in table} == {"model", "gradient"}
+    model, = [e for e in table if e["role"] == "model"]
+    assert model["op_name"].endswith("jvp(hvd_loss)/norm/psum_invariant")
+    assert model["bytes"] == 3 * 4 and model["replica_groups"] == "{{0,1}}"
+    step(*args)
+    assert step.exchange_overlap["sync_all_reduces"] == 0   # "not applied"
+
+    _, tx, one, args = _linear_problem(1)
+    step = build_train_step(loss_fn, tx, one)
+    step.lower = None          # nothing is lowered for the answer
+    assert step.collectives() == [] == step.collectives(*args)
 
 
 def test_overlap_step_reads_its_own_first_compile(mesh):
